@@ -33,7 +33,7 @@ def _engine_stats_brief(engine) -> dict:
     Called at the 10 Hz TUI cadence, so it must stay cheap: per-runtime
     stats only (no core.snapshot — the native TUI reads the queue state
     itself), with device/HBM numbers cached for 2 s (a memory_stats call
-    can be a tunnel round-trip on remote TPU setups).
+    per device is a runtime round trip each).
     """
     import time
 

@@ -43,9 +43,8 @@ def build_parser() -> argparse.ArgumentParser:
     # Engine shape.
     p.add_argument("--max-slots", type=int, default=64,
                    help="decode batch slots (max concurrent generations)")
-    # page-size 32 measured faster than 16 on v5e (r3: 1762 vs ~1600
-    # tok/s/chip); num-pages halved alongside so the default KV pool stays
-    # 32768 slots — same HBM footprint as the old 2048 x 16.
+    # 1024 pages of 32 tokens: a 32768-slot KV pool (1 GiB for
+    # llama3.2:1b in bf16).
     p.add_argument("--num-pages", type=int, default=1024)
     p.add_argument("--page-size", type=int, default=32)
     p.add_argument("--max-pages-per-seq", type=int, default=256)
@@ -376,9 +375,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "env vars)")
     p.add_argument("--cpu", type=int, nargs="?", const=1, default=0,
                    metavar="N",
-                   help="force the CPU platform with N virtual devices "
-                        "(development / CI; wins over a TPU-registering "
-                        "sitecustomize)")
+                   help="run on the CPU platform with N virtual devices "
+                        "(development / CI). Without it — and without "
+                        "JAX_PLATFORMS=cpu in the environment — a real "
+                        "engine refuses to start unless jax finds a TPU")
     return p
 
 
@@ -442,6 +442,33 @@ def _fake_latency() -> float:
         return max(0.0, float(os.environ.get("FAKE_TOKEN_LATENCY_S", 0.0)))
     except ValueError:
         return 0.0
+
+
+def _member_mesh(cfg, index: int):
+    """Fleet member `index`'s own slice of the local devices: without it
+    every in-process replica's weights and KV pool would land on device
+    0. A member needs dp*pp*sp*ep*tp devices; slices follow one another
+    and wrap around when the fleet outgrows the devices (said in the
+    log — members then share a device)."""
+    import jax
+
+    from ollamamq_tpu.parallel.mesh import make_mesh
+
+    if cfg.tp == -1:
+        return None  # "all devices": the engine builds that mesh itself
+    devs = jax.devices()
+    k = cfg.dp * cfg.pp * cfg.sp * cfg.ep * cfg.tp
+    if k > len(devs):
+        raise ValueError(f"a fleet member needs {k} devices, "
+                         f"{len(devs)} available")
+    picked = [devs[(index * k + j) % len(devs)] for j in range(k)]
+    if (index + 1) * k > len(devs):
+        logging.getLogger("ollamamq").warning(
+            "fleet member %d shares device(s) %s with an earlier member "
+            "(%d devices for the fleet)", index,
+            [str(d) for d in picked], len(devs))
+    return make_mesh(dp=cfg.dp, sp=cfg.sp, tp=cfg.tp, pp=cfg.pp, ep=cfg.ep,
+                     devices=picked)
 
 
 def install_graceful_shutdown(engine, grace_s: float) -> None:
@@ -659,14 +686,16 @@ def main(argv=None) -> int:
             log.error("invalid --fault-plan: %s", e)
             return 2
 
+    from ollamamq_tpu.platform_force import force_cpu, place_compile_cache
+
     if args.cpu:
         from ollamamq_tpu.parallel.distributed import multiprocess_configured
-        from ollamamq_tpu.platform_force import force_cpu
 
         # Multi-process only: defer the backend-touch verification, since
         # jax.distributed.initialize below must run before the first
         # backend touch. Single-process keeps the loud platform check.
         force_cpu(args.cpu, check=not multiprocess_configured())
+    log.info("compile cache: %s", place_compile_cache())
 
     from ollamamq_tpu.config import EngineConfig
     from ollamamq_tpu.core import Fairness
@@ -692,6 +721,18 @@ def main(argv=None) -> int:
     from ollamamq_tpu.parallel import distributed
 
     distributed.initialize()
+
+    if not args.fake_engine and args.replicas > 0 and not (
+            args.cpu or os.environ.get("JAX_PLATFORMS", "").lower() == "cpu"):
+        # A server started for the chip that finds none must not carry
+        # on from the host: the CPU is served only when asked for.
+        import jax
+
+        if jax.default_backend() != "tpu":
+            log.error("no TPU: jax's default backend is %r. Pass --cpu N "
+                      "(or set JAX_PLATFORMS=cpu) to serve from the CPU on "
+                      "purpose.", jax.default_backend())
+            return 3
 
     model_names = [m.strip() for m in args.models.split(",") if m.strip()]
     checkpoints = {}
@@ -773,6 +814,7 @@ def main(argv=None) -> int:
         return 2
     if args.replicas > 1 or fleet_urls or args.autoscale:
         import dataclasses
+        import itertools
 
         from ollamamq_tpu.fleet import FleetRouter, HttpMember, LocalMember
 
@@ -796,7 +838,11 @@ def main(argv=None) -> int:
                          for j in range(len(fleet_urls))])
             tier_assign, tier_widths = assign_tiers(args.tiers, roster)
 
-        def _member_factory(base_cfg):
+        # Members provisioned later take the device slices after the
+        # seed members', wrapping around when the devices run out.
+        next_index = itertools.count(args.replicas)
+
+        def _member_factory(base_cfg, index=None):
             def build(tp=None):
                 cfg = (base_cfg if tp in (None, base_cfg.tp)
                        else dataclasses.replace(base_cfg, tp=tp))
@@ -809,8 +855,10 @@ def main(argv=None) -> int:
                                       token_latency_s=_fake_latency())
                 from ollamamq_tpu.engine.engine import TPUEngine
 
+                i = index if index is not None else next(next_index)
                 return TPUEngine(cfg, models=models, blocklist_path=None,
-                                 fairness=fairness)
+                                 fairness=fairness,
+                                 mesh=_member_mesh(cfg, i))
             return build
 
         members = []
@@ -819,7 +867,7 @@ def main(argv=None) -> int:
             width = tier_widths.get(tier_assign.get(name))
             cfg_i = (member_cfg if width in (None, member_cfg.tp)
                      else dataclasses.replace(member_cfg, tp=width))
-            factory = _member_factory(cfg_i)
+            factory = _member_factory(cfg_i, i)
             members.append(LocalMember(name, factory(),
                                        engine_factory=factory))
         for j, url in enumerate(fleet_urls):
@@ -846,7 +894,7 @@ def main(argv=None) -> int:
                 provisioner = SubprocessProvisioner(
                     member_argv, env={"JAX_PLATFORMS": "cpu"})
             else:
-                # Real engines share the local chips: provision in-
+                # Real engines divide the local chips: provision in-
                 # process replicas from the same factory the seed
                 # members use. A cloud provisioner (TPU VM create/
                 # delete) drops in via FleetRouter(provisioner=...).

@@ -199,10 +199,9 @@ class EngineConfig:
     # Decode slots = max sequences generating concurrently in one batch.
     max_slots: int = 64
     # Paged KV cache: total pages in the pool and tokens per page.
-    # page_size 32 measured faster than 16 on v5e (r3 unofficial best:
-    # 1762 tok/s/chip greedy at 64 slots, page 32 > page 16) — larger
-    # pages mean fewer, longer DMA bursts in the ragged decode kernel.
-    # Pool bytes and max context unchanged vs the old 512x16 defaults.
+    # Larger pages mean fewer, longer DMA bursts in the attention
+    # kernels; 32 against 16 or 64 has no recorded measurement yet
+    # (PERF.md, open questions).
     num_pages: int = 256
     page_size: int = 32
     # Max pages a single sequence may hold (=> max context length).

@@ -6,7 +6,7 @@ The reference polls each backend every 10 s (GET /api/tags | /api/ps | /
 analogue watches the things that can actually fail here:
 
   - device liveness: a trivial jitted op must complete within a deadline
-    (a wedged TPU runtime/tunnel hangs rather than erroring);
+    (a wedged TPU runtime hangs rather than erroring);
   - engine-step progress: work exists but no token has been produced —
     or the engine loop's liveness tick has gone stale (a dispatch wedged
     INSIDE a step blocks the loop thread without erroring);
@@ -180,7 +180,9 @@ class HealthMonitor:
         )
         last_tokens, last_ts = self._last_progress
         now = time.monotonic()
-        if tokens != last_tokens or not has_work:
+        compiling = getattr(self.engine, "compiling", None)
+        if (tokens != last_tokens or not has_work
+                or (compiling is not None and compiling())):
             self._last_progress = (tokens, now)
             return True
         if (now - last_ts) < self.stall_s:

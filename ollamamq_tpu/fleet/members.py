@@ -303,6 +303,8 @@ class LocalMember(_MemberBase):
         now = time.monotonic()
         if now < self.forced_stale_until:
             return float("inf")
+        if self.engine.compiling():
+            return 0.0  # the loop thread is inside XLA, not wedged
         return now - self.engine.last_tick_at
 
     def fatal_alerts(self) -> list:

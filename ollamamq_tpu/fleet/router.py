@@ -53,7 +53,7 @@ from typing import Dict, List, Optional
 from ollamamq_tpu.core import Fairness, MQCore
 from ollamamq_tpu.core.mqcore import BlockedError, Family, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
-from ollamamq_tpu.engine.engine import QueueFullError
+from ollamamq_tpu.engine.engine import QueueFullError, device_summary
 from ollamamq_tpu.engine.request import FinishReason, Request
 from ollamamq_tpu.fleet.members import HttpMember, LocalMember  # noqa: F401
 from ollamamq_tpu.telemetry import schema as tm
@@ -432,6 +432,11 @@ class FleetRouter:
         locals_ = self.local_members
         return locals_[0].engine.chip_stats() if locals_ else []
 
+    def compiling(self) -> bool:
+        """Any in-process member in a step that pays a compile: to the
+        stall watchdog that is progress (TPUEngine.compiling)."""
+        return any(m.engine.compiling() for m in self.local_members)
+
     def worker_metric_snapshots(self) -> List[dict]:
         return []  # members share this process's registry
 
@@ -651,9 +656,10 @@ class FleetRouter:
                 time.sleep(0.1)
 
     def _loop_once(self) -> None:
-        self.last_tick_at = time.monotonic()
+        now = time.monotonic()
+        loop_gap, self.last_tick_at = now - self.last_tick_at, now
         self.journal.tick += 1
-        self._probe()
+        self._probe(loop_gap)
         if self.tiers is not None:
             # Balancer tick: retier ONE member toward the observed class
             # mix once the hysteresis clears (no-op most ticks).
@@ -1270,11 +1276,19 @@ class FleetRouter:
         return True
 
     # --------------------------------------------------------------- health
-    def _probe(self) -> None:
+    def _probe(self, loop_gap: float = 0.0) -> None:
         now = time.monotonic()
         if now - self._last_probe < self.probe_period_s:
             return
         self._last_probe = now
+        # The router shares one interpreter with its in-process members.
+        # While one of them traces and lowers a step program (long calls
+        # that keep the GIL: seconds for a large kernel), or when this
+        # loop itself was just held up (`loop_gap`: time since its
+        # previous iteration), the others' loops were held up too: a
+        # stale tick of an in-process member is evidence of nothing.
+        held_up = (loop_gap > self.eject_heartbeat_s / 2
+                   or self.compiling())
         for mem in list(self.members):
             plan_holds_down = self._draw_faults(mem)
             self._draw_preempt(mem)
@@ -1283,7 +1297,8 @@ class FleetRouter:
                 fatal = mem.fatal_alerts()
                 if not mem.alive():
                     self._eject(mem, "crash", age)
-                elif age > self.eject_heartbeat_s:
+                elif age > self.eject_heartbeat_s and not (
+                        held_up and isinstance(mem, LocalMember)):
                     self._eject(mem, "stale_heartbeat", age)
                 elif fatal:
                     self._eject(mem, f"alert:{fatal[0]}", age)
@@ -1939,12 +1954,19 @@ class FleetRouter:
         hbm_used = sum(c["hbm_used"] for c in chips) or sum(
             r["param_bytes"] + r["kv_bytes"] for r in runtime_stats)
         hbm_total = sum(c["hbm_total"] for c in chips) or None
+        engines = [mem.engine for mem in self.local_members]
         return {
             "runtimes": runtime_stats,
             "chips": chips,
             "mesh": None,
             "hbm_used_bytes": hbm_used,
             "hbm_total_bytes": hbm_total,
+            # Only a router with in-process members touches a device; a
+            # pure HTTP front-end must not claim one to report it.
+            **(device_summary() if engines else {}),
+            "runtime_failures": sum(
+                getattr(e, "runtime_failures", 0) for e in engines),
+            "rebuilds": sum(getattr(e, "rebuilds", 0) for e in engines),
             "uptime_s": round(time.time() - self.started_at, 1),
             "health": self.health.status() if self.health else None,
             "queue": self.core.snapshot(),

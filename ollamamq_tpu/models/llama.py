@@ -271,6 +271,7 @@ def forward_ragged(
     page_size: int,
     attn_impl: str = "jnp",  # "jnp" reference | "pallas" ragged TPU kernel
     interpret: bool = False,
+    mesh=None,  # the mesh this forward is jitted over (pallas under tp)
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """ONE forward over a ragged mixed batch: variable-length prefill
     spans and single decode tokens share a flattened [T] token stream —
@@ -301,6 +302,7 @@ def forward_ragged(
             out = ragged_attention_any(
                 attn_impl, q[0], kc, vc, page_table, tok_seq, tok_pos,
                 kv_len, q_start, q_len, page_size, interpret=interpret,
+                mesh=mesh,
             )
             return out[None]
 
@@ -330,6 +332,7 @@ def forward_decode(
     page_size: int,
     attn_impl: str = "jnp",  # "jnp" reference | "pallas" ragged TPU kernel
     active=None,  # [B] int32/bool — live decode slots (None = all live)
+    mesh=None,  # the mesh this forward is jitted over (pallas under tp)
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step for the whole batch; returns (logits [B,V], caches').
 
@@ -353,7 +356,8 @@ def forward_decode(
         kc = kv_write(kc, write_slots, k[:, 0])
         vc = kv_write(vc, write_slots, v[:, 0])
         attn = paged_decode_attention_any(
-            attn_impl, q[:, 0], kc, vc, page_table, seq_lens, page_size
+            attn_impl, q[:, 0], kc, vc, page_table, seq_lens, page_size,
+            mesh=mesh,
         )  # [B,H,hd]
         x = x + qeinsum("be,ed->bd", attn.reshape(B, cfg.q_dim), lp["wo"])[:, None, :]
         h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)

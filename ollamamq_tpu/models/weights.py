@@ -11,6 +11,7 @@ materializes its shard) — the TPU analogue of the reference's
 
 from __future__ import annotations
 
+import functools
 import os
 import re
 from typing import Optional
@@ -73,8 +74,27 @@ _HF_LAYER_MAP = {
 }
 
 
-def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16) -> dict:
-    return llama.init_params(cfg, jax.random.PRNGKey(seed), dtype=dtype)
+def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
+                mesh=None, pp: bool = False) -> dict:
+    """Seeded random weights. With a `mesh` the tree is generated inside
+    one jit whose outputs carry the serving shardings, so every device
+    draws only its own shard (the values do not depend on the sharding:
+    jax's threefry is partitionable) — a model larger than one chip's
+    HBM never has to exist whole on the default device first."""
+    init = functools.partial(llama.init_params, cfg, dtype=dtype)
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        return init(key)
+    from jax.sharding import NamedSharding
+
+    from ollamamq_tpu.parallel.sharding import (param_partition_specs,
+                                                pipeline_param_specs)
+
+    shapes = jax.eval_shape(init, key)
+    specs = (pipeline_param_specs if pp else param_partition_specs)(shapes)
+    shardings = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
@@ -175,11 +195,15 @@ def load_params(
     seed: int = 0,
     dtype=jnp.bfloat16,
     weights_dtype: str = "bfloat16",
+    mesh=None,
+    pp: bool = False,
 ) -> dict:
     """Resolve weights: checkpoint dir (safetensors/orbax) or random init.
     `weights_dtype="int8"` quantizes the loaded tree at load time
     (per-channel symmetric, fp32 scales) — the checkpoint is still read
-    in `dtype` and the full-precision copy is dropped immediately."""
+    in `dtype` and the full-precision copy is dropped immediately.
+    `mesh`/`pp` let a random init land directly in its serving sharding
+    (init_random); checkpoints are placed by the caller's shard_params."""
     if checkpoint_path:
         entries = os.listdir(checkpoint_path)
         if any(e.endswith(".safetensors") for e in entries):
@@ -187,7 +211,7 @@ def load_params(
         else:
             params = load_orbax(checkpoint_path)
     else:
-        params = init_random(cfg, seed=seed, dtype=dtype)
+        params = init_random(cfg, seed=seed, dtype=dtype, mesh=mesh, pp=pp)
     if weights_dtype == "int8":
         params = quantize_params_int8(params, cfg)
     return params

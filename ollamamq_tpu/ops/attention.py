@@ -18,8 +18,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
 
 from ollamamq_tpu.ops.quant import QuantKV, kv_gather
+from ollamamq_tpu.parallel.mesh import AXIS_TENSOR
 
 NEG_INF = -1e30
 
@@ -325,6 +327,38 @@ def ragged_paged_attention_blockwise(
     return out.astype(q.dtype)
 
 
+def _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, *meta):
+    """Run a Pallas attention `kernel(q, k_cache, v_cache, *meta)`.
+
+    GSPMD cannot partition a Mosaic kernel, so on a mesh of more than one
+    device the call is wrapped in a shard_map: attention heads are
+    independent, so q splits on H over the "tensor" axis, the pools (and
+    an int8 pool's scale planes) on Hk, and the page table / length
+    metadata replicate — every shard runs the kernel on its own heads.
+    Callers already inside a shard_map (parallel/pipeline.py) pass no
+    mesh and get the plain per-device call."""
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k_cache, v_cache, *meta)
+    heads = PS(None, AXIS_TENSOR, None)
+    pool = (QuantKV(heads, PS(None, AXIS_TENSOR))
+            if isinstance(k_cache, QuantKV) else heads)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(heads, pool, pool) + (PS(),) * len(meta),
+        out_specs=heads, check_vma=False,
+    )(q, k_cache, v_cache, *meta)
+
+
+def _split_quant(k_cache, v_cache):
+    """(k payload, v payload, scale kwargs) for a bf16 or int8 pool: int8
+    payloads DMA as usual, the per-slot scale rows ride along and
+    dequantize in-kernel."""
+    if isinstance(k_cache, QuantKV):
+        return k_cache.q, v_cache.q, {"k_scale": k_cache.s,
+                                      "v_scale": v_cache.s}
+    return k_cache, v_cache, {}
+
+
 def ragged_attention_any(
     attn_impl: str,
     q: jnp.ndarray,  # [T, H, hd]
@@ -338,6 +372,8 @@ def ragged_attention_any(
     q_lens: jnp.ndarray,  # [B]
     page_size: int,
     interpret: bool = False,
+    mesh=None,  # the GSPMD mesh the caller's jit runs over (None inside
+    #             a shard_map or on one device)
 ) -> jnp.ndarray:
     """The ONE pallas-vs-jnp ragged-attention dispatch (mirror of
     paged_decode_attention_any), shared by models/llama.forward_ragged so
@@ -349,18 +385,14 @@ def ragged_attention_any(
             ragged_paged_attention_pallas,
         )
 
-        if isinstance(k_cache, QuantKV):
-            # Quantized pool: int8 payloads DMA as usual, the per-slot
-            # scale rows ride along and dequantize in-kernel.
+        def kernel(q, kc, vc, page_table, q_start, q_lens, kv_lens):
+            kq, vq, scales = _split_quant(kc, vc)
             return ragged_paged_attention_pallas(
-                q, k_cache.q, v_cache.q, page_table, q_start, q_lens,
-                kv_lens, page_size, interpret=interpret,
-                k_scale=k_cache.s, v_scale=v_cache.s,
-            )
-        return ragged_paged_attention_pallas(
-            q, k_cache, v_cache, page_table, q_start, q_lens, kv_lens,
-            page_size, interpret=interpret,
-        )
+                q, kq, vq, page_table, q_start, q_lens, kv_lens,
+                page_size, interpret=interpret, **scales)
+
+        return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache,
+                                 page_table, q_start, q_lens, kv_lens)
     return ragged_paged_attention_blockwise(
         q, k_cache, v_cache, page_table, tok_seq, tok_pos, kv_lens, page_size
     )
@@ -375,6 +407,7 @@ def paged_decode_attention_any(
     seq_lens: jnp.ndarray,  # [B]
     page_size: int,
     interpret: bool = False,
+    mesh=None,  # see ragged_attention_any
 ) -> jnp.ndarray:
     """The ONE pallas-vs-jnp decode-attention dispatch, shared by the
     single-mesh forward (models/llama.py) and the pipeline stage
@@ -385,16 +418,14 @@ def paged_decode_attention_any(
             paged_decode_attention_pallas,
         )
 
-        if isinstance(k_cache, QuantKV):
+        def kernel(q, kc, vc, page_table, seq_lens):
+            kq, vq, scales = _split_quant(kc, vc)
             return paged_decode_attention_pallas(
-                q, k_cache.q, v_cache.q, page_table, seq_lens, page_size,
-                interpret=interpret,
-                k_scale=k_cache.s, v_scale=v_cache.s,
-            )
-        return paged_decode_attention_pallas(
-            q, k_cache, v_cache, page_table, seq_lens, page_size,
-            interpret=interpret,
-        )
+                q, kq, vq, page_table, seq_lens, page_size,
+                interpret=interpret, **scales)
+
+        return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache,
+                                 page_table, seq_lens)
     return paged_decode_attention(
         q, k_cache, v_cache, page_table, seq_lens, page_size
     )

@@ -356,9 +356,8 @@ def pp_forward_decode(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pipelined single decode step; returns (logits [B, V], caches').
 
-    The ragged Pallas kernel runs per-device inside the shard_map stage
-    (each stage's pallas_call sees its local layer-slice caches), same
-    AOT-probe fallback discipline as the single-mesh path."""
+    The Pallas decode kernel runs per-device inside the shard_map stage
+    (each stage's pallas_call sees its local layer-slice caches)."""
     B = tokens.shape[0]
     pipe = mesh.shape[AXIS_PIPE]
     M = n_microbatches(B, pipe, n_micro)

@@ -20,19 +20,20 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-# Published bf16 dense peak FLOP/s per chip, matched by substring against
-# jax's device_kind (e.g. "TPU v5 lite", "TPU v4", "TPU v6e").
-PEAK_FLOPS_BY_KIND = (
-    ("v6 lite", 918e12),  # Trillium
-    ("v6e", 918e12),
-    ("v5 lite", 394e12),  # v5e
-    ("v5e", 394e12),
-    ("v5p", 459e12),
-    ("v5", 459e12),  # bare "TPU v5" = v5p naming on some stacks
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+# Published bf16 dense peak FLOP/s per chip, keyed by the EXACT
+# `device_kind` jax reports. The one table of peaks in the repository
+# (bench.py reads it too). Source: Google Cloud TPU documentation, the
+# "TPU v5e" page (197 TFLOP/s bf16; its 394 figure is int8) and the
+# sibling pages of the other generations. A device that is not listed
+# has no peak: an unknown kind is never given a neighbour's rate.
+PEAK_FLOPS_BY_KIND = {
+    "TPU v6 lite": 918e12,  # v6e (Trillium)
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5p": 459e12,
+    "TPU v4": 275e12,
+    "TPU v3": 123e12,
+    "TPU v2": 45e12,
+}
 
 
 def peak_flops_per_chip(device_kind: str) -> Optional[float]:
@@ -43,11 +44,7 @@ def peak_flops_per_chip(device_kind: str) -> Optional[float]:
             return float(env)
         except ValueError:
             pass
-    kind = (device_kind or "").lower()
-    for sub, peak in PEAK_FLOPS_BY_KIND:
-        if sub in kind:
-            return peak
-    return None
+    return PEAK_FLOPS_BY_KIND.get(device_kind or "")
 
 
 def active_param_count(cfg) -> int:

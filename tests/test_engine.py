@@ -209,26 +209,45 @@ def test_unknown_model_stuck_then_cancelled(engine):
     assert items[-1].finish_reason == FinishReason.CANCELLED
 
 
-def test_pallas_failure_falls_back_to_jnp():
-    """An unproven Pallas decode path must not take serving down: the first
-    failing dispatch flips the runtime to jnp attention and the request
-    completes (VERDICT r1 weak #2 — serving-path fallback). On CPU the
-    pallas kernel genuinely fails to compile, which is exactly the injected
-    fault."""
+def test_kernel_compile_failure_is_loud_and_never_swaps_attn_impl():
+    """A kernel that fails to compile fails its dispatches LOUDLY: the
+    request ends in an explicit error after its retry, the retry counter
+    says so, and attn_impl — decided once at construction — is never
+    swapped for another implementation behind the operator's back. On
+    CPU the pallas kernel genuinely fails to compile, which is exactly
+    the fault."""
     eng = TPUEngine(small_cfg(), blocklist_path=None)
     eng.start()
     try:
         rt = eng.runtimes["test-tiny"]
-        rt.attn_impl = "pallas"  # pretend auto-select picked the kernel
+        assert rt.attn_impl == "jnp"  # CPU backend: decided at construction
+        assert rt.stats()["attn_impl"] == "jnp"
+        rt.attn_impl = "pallas"  # as a TPU runtime would have been built
         items, req = run_request(eng, user="pallas-u", max_tokens=4)
-        assert items[-1].kind == "done", items[-1]
-        assert rt.attn_impl == "jnp"  # compile probe failed => fell back
-        assert not rt._pallas_proven
-        # And it stays healthy for the next request.
-        items2, _ = run_request(eng, user="pallas-u2", max_tokens=4)
-        assert items2[-1].kind == "done"
+        assert items[-1].kind == "error", items[-1]
+        assert "ragged dispatch failed" in items[-1].error
+        assert "poisoned after" in items[-1].error
+        assert req.generated_ids == []  # nothing served by another path
+        assert rt.attn_impl == "pallas"  # never flipped
+        assert eng.stats()["retries"] >= 1
     finally:
         eng.stop()
+
+
+def test_select_attn_impl_is_decided_from_backend_and_kv_dtype(monkeypatch):
+    """attn_impl comes from what is known at construction: Pallas on a
+    TPU with bf16 pages; the jnp reference off-TPU, under
+    OLLAMAMQ_NO_PALLAS, and for int8 pages (whose scale-row DMA Mosaic
+    refuses — tests/test_chip_compile.py holds the compiler's word)."""
+    from ollamamq_tpu.engine.engine import select_attn_impl
+
+    monkeypatch.delenv("OLLAMAMQ_NO_PALLAS", raising=False)
+    assert select_attn_impl("tpu", "bfloat16")[0] == "pallas"
+    assert select_attn_impl("cpu", "bfloat16")[0] == "jnp"
+    impl, why = select_attn_impl("tpu", "int8")
+    assert impl == "jnp" and "int8" in why
+    monkeypatch.setenv("OLLAMAMQ_NO_PALLAS", "1")
+    assert select_attn_impl("tpu", "bfloat16")[0] == "jnp"
 
 
 def test_embed_admitted_while_decode_saturated():

@@ -271,6 +271,41 @@ def test_ejected_replica_rejoins_after_heal():
         router.stop()
 
 
+def test_process_stall_does_not_eject_in_process_members():
+    """A thread that keeps the GIL for longer than the eject heartbeat
+    (on the chip: a sibling member lowering a large Pallas kernel) holds
+    up the router AND every in-process member's loop. Their stale ticks
+    are then evidence of nothing: nobody is ejected, and the members
+    show fresh heartbeats once the interpreter runs again."""
+    router = _fake_fleet(n=2, router_kw=dict(eject_heartbeat_s=0.3))
+    try:
+        time.sleep(0.3)  # a few clean probes first
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 1.0:
+            sum(range(20_000_000))  # one C call: the GIL is never offered
+        time.sleep(0.5)
+        assert [m.eject_count for m in router.members] == [0, 0]
+        assert router.fleet_counts()["healthy"] == 2
+    finally:
+        router.stop()
+
+
+def test_member_in_a_compiling_step_is_not_stale():
+    """A step that pays an XLA compile blocks its loop thread for as long
+    as the compile takes (tens of seconds for a step program on a TPU):
+    the member's heartbeat reads fresh meanwhile. A compile that outlives
+    the grace is a wedge again."""
+    eng = FakeEngine(EngineConfig(**TINY), blocklist_path=None)
+    mem = LocalMember("r0", eng)
+    rt = next(iter(eng.runtimes.values()))
+    eng.last_tick_at = time.monotonic() - 10.0  # the loop is not ticking
+    assert not eng.compiling() and mem.heartbeat_age() > 9.0
+    rt.compiling_since = time.monotonic()  # as _sp_note_compile sets it
+    assert eng.compiling() and mem.heartbeat_age() == 0.0
+    rt.compiling_since = time.monotonic() - eng.COMPILE_GRACE_S - 1.0
+    assert not eng.compiling() and mem.heartbeat_age() > 9.0
+
+
 def test_slow_fault_forces_stale_heartbeat_eject_and_rejoin():
     plan = FaultPlan([{"site": "replica", "kind": "slow", "delay_s": 0.5,
                        "at": [2]}])  # call 2 = member r1, first sweep

@@ -23,7 +23,6 @@ Contracts pinned here:
     regression.
 """
 
-import glob
 import importlib.util
 import json
 import os
@@ -328,13 +327,25 @@ def _load_bench_compare():
     return mod
 
 
-def test_bench_compare_flags_wedged_history_as_init_failed():
-    """ACCEPTANCE: the checked-in BENCH_r*.json trajectory (every round
-    died at device init) classifies as init-failed — environment
-    casualties, NOT regressions — and the sentinel exits 0."""
+def test_bench_compare_flags_wedged_history_as_init_failed(tmp_path):
+    """ACCEPTANCE: a trajectory in which every round died at device init
+    — a crash before any record (rc 1, nothing parsed) and init-watchdog
+    error records (rc 3, phase "init"), the two shapes bench.py's init
+    path produces — classifies as init-failed: environment casualties,
+    NOT regressions, and the sentinel exits 0."""
     mod = _load_bench_compare()
-    files = sorted(glob.glob(os.path.join(_REPO, "BENCH_r*.json")))
-    assert files, "checked-in bench history missing"
+    rounds = [{"n": 1, "cmd": "bench", "rc": 1, "parsed": None,
+               "tail": "RuntimeError: Unable to initialize backend 'tpu'"}]
+    rounds += [{"n": n, "cmd": "bench", "rc": 3, "tail": "", "parsed": {
+        "metric": "decode_tok_per_s_per_chip", "value": 0.0,
+        "error": "device/runtime init exceeded 300s", "phase": "init",
+        "platform": None, "device_kind": None, "device_count": 0}}
+        for n in range(2, 6)]
+    files = []
+    for rec in rounds:
+        path = tmp_path / f"BENCH_r{rec['n']:02d}.json"
+        path.write_text(json.dumps(rec))
+        files.append(str(path))
     for path in files:
         assert mod.classify(mod.load_round(path)) == "init-failed", path
     assert mod.main(files) == 0
