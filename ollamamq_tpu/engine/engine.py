@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import collections
 import copy
+import itertools
 import logging
 import os
 import threading
@@ -65,6 +66,11 @@ from ollamamq_tpu.telemetry.slo import AlertManager, SLOEngine
 from ollamamq_tpu.telemetry.tracing import DECODE_EVENT_EVERY, Tracer
 
 log = logging.getLogger("ollamamq.engine")
+
+# The step profiler stays stdlib-only; this module imports jax, so it
+# hands over the span type the profiler opens while a capture runs.
+stepprof.PROFILER.span_factory = jax.profiler.TraceAnnotation
+_ENGINE_IDS = itertools.count(1)
 
 
 def sweep_blocked(core: MQCore, held_fn, last_version: int) -> int:
@@ -308,7 +314,7 @@ def serve_embed_batch(rt, core: "MQCore", pending, max_len: int,
     On a dispatch failure the batch's requests are errored BEFORE the
     exception propagates — a popped request must never be left hanging
     (it is in no queue _fail_runtime can see)."""
-    _sp = stepprof.PROFILER.start("embed")
+    _sp = stepprof.PROFILER.start("embed", getattr(rt, "loop_clock", None))
     journal = getattr(rt, "journal", None)
 
     def jfinish(req: Request, reason: str) -> None:
@@ -357,6 +363,7 @@ def serve_embed_batch(rt, core: "MQCore", pending, max_len: int,
     for i, r in enumerate(batch):
         tokens[i, : len(r.prompt_tokens)] = r.prompt_tokens
         lens[i] = len(r.prompt_tokens)
+    _sp.note(T_pad=int(bucket), k_cap=0, tokens=int(lens.sum()))
     _sp.mark("host_prep")
     t0 = time.monotonic()
     try:
@@ -393,8 +400,7 @@ def serve_embed_batch(rt, core: "MQCore", pending, max_len: int,
         jfinish(r, "stop")
         r.finish(FinishReason.STOP)
     _sp.mark("detok")
-    _sp.finish(T_pad=int(bucket), k_cap=0, n_prefill=len(batch),
-               n_decode=0, tokens=int(lens.sum()),
+    _sp.finish(n_prefill=len(batch), n_decode=0,
                padded_tokens=int(B) * int(bucket),
                compiled=_sp_take_compiled(rt))
     return True
@@ -443,6 +449,10 @@ class ModelRuntime:
     # of a split decode (dispatch -> collect).
     _stepprof_compiled = False
     _sp_decode = None
+    # The owning engine thread's loop clock (stepprof.LoopClock), attached
+    # by _attach_hooks: step timers advance it, so step and loop phases
+    # form one gapless chain. None (bench, unit tests) times steps alone.
+    loop_clock = None
 
     def __init__(
         self,
@@ -925,10 +935,11 @@ class ModelRuntime:
             need_pen, need_mask, need_sample = flags
             O = k_cap + 1
 
-            def fn(params, tokens, tok_seq, tok_pos, write_slots, q_start,
-                   q_len, kv_len, ring_len, is_first, append, is_spec,
-                   seed_rows, slot_ids, pt, kc, vc, recent, temp, tk, tp,
-                   pen, pres, freq, seeds, key):
+            def mq_ragged_step(params, tokens, tok_seq, tok_pos, write_slots,
+                               q_start, q_len, kv_len, ring_len, is_first,
+                               append, is_spec, seed_rows, slot_ids, pt, kc,
+                               vc, recent, temp, tk, tp, pen, pres, freq,
+                               seeds, key):
                 spec = is_spec > 0
                 # Logit read positions: non-spec rows read only their
                 # last valid token (every column aliases it — prefill
@@ -1017,7 +1028,8 @@ class ModelRuntime:
                 return toks, n_emit, kc, vc, recent
 
             _sp_note_compile(self, "ragged", key_, self._prefill_jits,
-                             jax.jit(fn, donate_argnums=(15, 16, 17)))
+                             jax.jit(mq_ragged_step,
+                                     donate_argnums=(15, 16, 17)))
         return self._prefill_jits[key_]
 
     def _dev(self, name: str, arr) -> jnp.ndarray:
@@ -1063,8 +1075,8 @@ class ModelRuntime:
             pp, mesh = self._pp, self.mesh
             n_micro = self.ecfg.pp_microbatches
 
-            def fn(params, tokens, seq_lens, kc, vc, recent, slot_ids, pt,
-                   temp, tk, tp, pen, pres, freq, seeds, key):
+            def mq_prefill(params, tokens, seq_lens, kc, vc, recent, slot_ids,
+                           pt, temp, tk, tp, pen, pres, freq, seeds, key):
                 if pp > 1:
                     logits, kc, vc = pipeline.pp_forward_prefill(
                         params, cfg, tokens, seq_lens, kc, vc, pt, ps, mesh,
@@ -1092,7 +1104,7 @@ class ModelRuntime:
                 return tok, kc, vc, recent
 
             _sp_note_compile(self, "prefill", key_, self._prefill_jits,
-                             jax.jit(fn, donate_argnums=(3, 4, 5)))
+                             jax.jit(mq_prefill, donate_argnums=(3, 4, 5)))
         return self._prefill_jits[key_]
 
     def _get_chunk_jit(self, chunk: int, flags=(True, True, True)):
@@ -1106,9 +1118,10 @@ class ModelRuntime:
             pp, mesh = self._pp, self.mesh
             n_micro = self.ecfg.pp_microbatches
 
-            def fn(params, tokens, start, chunk_lens, kc, vc, recent, slot_id,
-                   is_final, is_first, seed_row, pt, temp, tk, tp, pen, pres,
-                   freq, seeds, key):
+            def mq_prefill_chunk(params, tokens, start, chunk_lens, kc, vc,
+                                 recent, slot_id, is_final, is_first, seed_row,
+                                 pt, temp, tk, tp, pen, pres, freq, seeds,
+                                 key):
                 if pp > 1:
                     logits, kc, vc = pipeline.pp_forward_prefill_chunk(
                         params, cfg, tokens, start, chunk_lens, kc, vc, pt,
@@ -1147,7 +1160,8 @@ class ModelRuntime:
 
             _sp_note_compile(self, "chunk", ("chunk", chunk, flags),
                              self._prefill_jits,
-                             jax.jit(fn, donate_argnums=(4, 5, 6)))
+                             jax.jit(mq_prefill_chunk,
+                                     donate_argnums=(4, 5, 6)))
         return self._prefill_jits[("chunk", chunk, flags)]
 
     def _dispatch_prefill_sp(self, T, tokens, lens, slot_ids, pt_rows,
@@ -1174,8 +1188,9 @@ class ModelRuntime:
             cfg, ps, mesh = self.cfg, self.ecfg.page_size, self.mesh
             need_pen, need_mask, need_sample = flags
 
-            def fn(params, tokens, seq_lens, kc, vc, recent, slot_ids, pt,
-                   temp, tk, tp, pen, pres, freq, seeds, key):
+            def mq_prefill_sp(params, tokens, seq_lens, kc, vc, recent,
+                              slot_ids, pt, temp, tk, tp, pen, pres, freq,
+                              seeds, key):
                 logits, k_stack, v_stack = llama.forward_prefill_sp(
                     params, cfg, tokens, seq_lens, mesh
                 )
@@ -1205,7 +1220,7 @@ class ModelRuntime:
                 return tok, kc, vc, recent
 
             _sp_note_compile(self, "sp_prefill", key_, self._prefill_jits,
-                             jax.jit(fn, donate_argnums=(3, 4, 5)))
+                             jax.jit(mq_prefill_sp, donate_argnums=(3, 4, 5)))
         return self._prefill_jits[key_]
 
     def _prefill_sp(self, req: Request, slot: int, n: int, core: MQCore) -> None:
@@ -1270,8 +1285,9 @@ class ModelRuntime:
             pp, mesh = self._pp, self.mesh
             n_micro = self.ecfg.pp_microbatches
 
-            def fn(params, tokens, positions, kc, vc, recent, active, pt,
-                   temp, tk, tp, pen, pres, freq, seeds, key):
+            def mq_decode_scan(params, tokens, positions, kc, vc, recent,
+                               active, pt, temp, tk, tp, pen, pres, freq,
+                               seeds, key):
                 S = tokens.shape[0]
 
                 def step(carry, _):
@@ -1317,7 +1333,7 @@ class ModelRuntime:
                 return toks, kc, vc, recent  # toks: [K, S]
 
             _sp_note_compile(self, "decode", key_, self._decode_jits,
-                             jax.jit(fn, donate_argnums=(3, 4, 5)))
+                             jax.jit(mq_decode_scan, donate_argnums=(3, 4, 5)))
         return self._decode_jits[key_]
 
     # -- slot lifecycle ----------------------------------------------------
@@ -2486,7 +2502,7 @@ class ModelRuntime:
         # Step profiler: phases are contiguous marks of one timer, so an
         # early return or a faulted dispatch just abandons it — no
         # partial samples in the ring.
-        _sp = stepprof.PROFILER.start("ragged")
+        _sp = stepprof.PROFILER.start("ragged", self.loop_clock)
         self._admit_ragged(core)
         if not self.chunking and not self.spec:
             return False
@@ -2740,6 +2756,8 @@ class ModelRuntime:
         if spec_rows:
             batch_fields["n_spec"] = len(spec_rows)
             batch_fields["spec_tokens"] = int(spec_tokens)
+            _sp.mode = "spec_verify"
+        _sp.note(T_pad=int(T_pad), k_cap=int(k_cap), tokens=int(T_real))
         _sp.mark("host_prep")
         t0 = time.monotonic()
         try:
@@ -2842,11 +2860,9 @@ class ModelRuntime:
                                  context_len=mean_ctx)
         self._tm_mfu.set(self.mfu)
         _sp.mark("detok")
-        _sp.mode = "spec_verify" if spec_rows else "ragged"
-        _sp.finish(T_pad=int(T_pad), k_cap=int(k_cap),
-                   n_prefill=len(prefill_rows),
+        _sp.finish(n_prefill=len(prefill_rows),
                    n_decode=n_decode - len(spec_rows),
-                   tokens=int(T_real), padded_tokens=int(T_pad),
+                   padded_tokens=int(T_pad),
                    compiled=_sp_take_compiled(self))
         return True
 
@@ -2914,7 +2930,7 @@ class ModelRuntime:
         # Step profiler: the timer spans dispatch AND collect (the two
         # halves of one step); it rides self._sp_decode between them.
         # Early returns and faulted dispatches abandon it.
-        _sp = stepprof.PROFILER.start("decode")
+        _sp = stepprof.PROFILER.start("decode", self.loop_clock)
         # Reservation-holders first: pages may have freed since they
         # stalled — growth success puts them back into the batch.
         for i in sorted(self._stalled_slots):
@@ -2959,6 +2975,9 @@ class ModelRuntime:
              for i, r in enumerate(self.slot_req)], np.int32
         )
 
+        # `tokens` as planned; the sample takes what was really emitted.
+        _sp.note(T_pad=0, k_cap=int(k_steps),
+                 tokens=len(active) * int(k_steps))
         _sp.mark("host_prep")
         toks, self.kc, self.vc, self.recent = self._dispatch_decode(
             k_steps, self.last_tokens,
@@ -3040,8 +3059,7 @@ class ModelRuntime:
         self._tm_mfu.set(self.mfu)
         if _sp is not None:
             _sp.mark("detok")
-            _sp.finish(T_pad=0, k_cap=int(k_steps), n_prefill=0,
-                       n_decode=len(active), tokens=emitted,
+            _sp.finish(n_prefill=0, n_decode=len(active), tokens=emitted,
                        padded_tokens=int(k_steps) * self.ecfg.max_slots,
                        compiled=_sp_take_compiled(self))
         return emitted
@@ -3075,11 +3093,11 @@ class ModelRuntime:
         if key not in self._embed_jits:
             cfg = self.cfg
 
-            def fn(params, tokens, seq_lens):
+            def mq_embed(params, tokens, seq_lens):
                 return llama.forward_embed(params, cfg, tokens, seq_lens)
 
             _sp_note_compile(self, "embed", key, self._embed_jits,
-                             jax.jit(fn))
+                             jax.jit(mq_embed))
         return self._embed_jits[key]
 
     # Dispatch seam: the SPMD subclass broadcasts (OP_EMBED, payload) to
@@ -3165,6 +3183,7 @@ class EncoderRuntime:
     fault_plan = None  # attached by the engine like ModelRuntime's
     on_preempt = None  # encoders hold no KV pages; attached but unused
     journal = None  # decision journal (the SPMD broadcast seam reads it)
+    loop_clock = None  # the engine thread's stepprof.LoopClock
 
     def __init__(self, name, model_cfg, engine_cfg, mesh=None,
                  checkpoint_path=None, dtype=jnp.bfloat16):
@@ -3216,10 +3235,11 @@ class EncoderRuntime:
         if key not in self._jits:
             cfg = self.cfg
 
-            def fn(params, tokens, seq_lens):
+            def mq_encode(params, tokens, seq_lens):
                 return llama.forward_encoder(params, cfg, tokens, seq_lens)
 
-            _sp_note_compile(self, "embed", key, self._jits, jax.jit(fn))
+            _sp_note_compile(self, "embed", key, self._jits,
+                             jax.jit(mq_encode))
         return self._jits[key]
 
     # Dispatch seam: the SPMD subclass broadcasts (OP_ENCODE, payload) to
@@ -3472,6 +3492,13 @@ class TPUEngine:
         # Request-lifecycle tracing: bounded ring of finished traces plus
         # the in-flight table, exported at GET /debug/trace.
         self.tracer = Tracer(capacity=engine_cfg.trace_ring)
+        # This engine thread's own clock over the time between steps
+        # (telemetry/stepprof.py LOOP_PHASES). Per engine, never the
+        # process-wide profiler's: an in-process fleet runs several
+        # engine threads into one sample ring, and `thread` on a sample
+        # says which.
+        self.loop_clock = stepprof.LoopClock(
+            stepprof.PROFILER, f"engine-{next(_ENGINE_IDS)}")
         # Alerting + SLO burn-rate engine: the one alert table /health,
         # /metrics, /debug/bundle, and the TUI alerts panel all read.
         # Objectives are opt-in (--slo-ttft-ms / --slo-tpot-ms); the
@@ -3571,6 +3598,7 @@ class TPUEngine:
         rep.fault_plan = self.fault_plan
         rep.journal = self.journal
         rep.policy = self.policy
+        rep.loop_clock = self.loop_clock
         if self.ecfg.preempt:
             rep.on_preempt = self._requeue_preempted
 
@@ -3599,6 +3627,7 @@ class TPUEngine:
         raw_prompt: str = "",
         context_ids=None,
         trace_ctx=None,
+        ingress_at: Optional[float] = None,
     ) -> Request:
         """Atomically enqueue into the native core AND register the Request,
         so the engine loop can never pop a req_id it doesn't know yet.
@@ -3610,6 +3639,8 @@ class TPUEngine:
         a propagated fleet-stable trace id this request's spans adopt,
         so a member process's timeline stitches under the router's rid
         at GET /debug/trace/{rid}. None mints a fresh root context.
+        `ingress_at`: monotonic instant the HTTP handler was entered
+        (the trace's `ingress` event; None = the trace starts here).
 
         `context_ids` (Ollama's /api/generate `context` field, also the
         fleet's token-space HTTP failover replay): token ids already
@@ -3667,7 +3698,8 @@ class TPUEngine:
                 req._replay_gen = len(ctx)
                 req.stats.prompt_tokens = len(req.prompt_tokens)
             req.trace = self.tracer.begin(rid, user, model, kind=kind,
-                                          ctx=trace_ctx)
+                                          ctx=trace_ctx,
+                                          ingress_at=ingress_at)
             self.pending[rid] = req
         self.journal.record(
             "enqueue", req=req, n_prompt=len(req.prompt_tokens),
@@ -4460,6 +4492,7 @@ class TPUEngine:
         self._failed_runtimes.append(rt)
 
     def _loop(self) -> None:
+        self.loop_clock.reset()
         while self._running:
             try:
                 self._loop_once()
@@ -4496,6 +4529,11 @@ class TPUEngine:
         stepprof.PROFILER.hbm_record({"models": models})
 
     def _loop_once(self) -> None:
+        # The engine thread's time is accounted for without a gap
+        # (stepprof.LOOP_PHASES): `other` is open wherever nothing below
+        # says otherwise, step timers take the cursor while they run.
+        clock = self.loop_clock
+        clock.tick()
         self.last_tick_at = time.monotonic()
         self.journal.tick += 1
         self._sample_hbm_timeline()
@@ -4505,7 +4543,9 @@ class TPUEngine:
                 and time.monotonic() - self._last_recover_attempt
                 > self.recover_interval):
             self._try_recover()
+        clock.enter("admit")
         self._admit()
+        clock.enter("other")
         did_work = False
         # Phase 1: prefills + decode DISPATCH for every runtime. JAX
         # dispatch is async, so once runtime A's chunk is in flight the
@@ -4596,8 +4636,10 @@ class TPUEngine:
                 log.exception("runtime %s decode collect failed", rt.name)
                 self._kill_runtime(rt)
         if not did_work:
+            clock.enter("wait")
             with self._cond:
                 self._cond.wait(timeout=0.05)
+            clock.enter("other")
 
     def _try_recover(self) -> None:
         """Kick off background rebuilds of failed runtimes. The reference's

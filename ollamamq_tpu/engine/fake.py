@@ -35,6 +35,7 @@ class FakeRuntime:
     # journal — the deterministic seam the replay/simulate harness and
     # the policy tests drive without jax. None behaves exactly as fcfs.
     policy = None
+    loop_clock = None  # the engine thread's stepprof.LoopClock
 
     def __init__(self, name: str, engine_cfg: EngineConfig,
                  token_latency_s: float = 0.0, is_encoder: bool = False):
@@ -116,7 +117,7 @@ class FakeRuntime:
         # latency sleep is the "device dispatch", the emit loop is detok
         # — so stepprof surfaces/tests run without jax. Idle ticks
         # abandon the timer (no zero-sample flood).
-        _sp = stepprof.PROFILER.start("fake")
+        _sp = stepprof.PROFILER.start("fake", self.loop_clock)
         _gen0 = self.tokens_generated
         # Admission: slot-bounded so scheduling-policy order actually
         # decides WHO enters a contended batch (pre-policy the pop gate
@@ -187,10 +188,12 @@ class FakeRuntime:
         self._tm_occupancy.set(len(self.active) / max(1, self.ecfg.max_slots))
         _had_work = bool(admitted or self.active)
         _n_decode = len(self.active)
+        _sp.note(T_pad=0, k_cap=0, tokens=real)
         _sp.mark("host_prep")
         if self.token_latency_s:
             time.sleep(self.token_latency_s)
         _sp.mark("dispatch")
+        _sp.mark("collect")  # nothing to wait for; keeps the fixed order
         for req in list(self.active):
             if req.cancelled.is_set():
                 self.active.remove(req)
@@ -253,8 +256,7 @@ class FakeRuntime:
                     break
         if _had_work:
             _sp.mark("detok")
-            _sp.finish(T_pad=0, k_cap=0, n_prefill=len(admitted),
-                       n_decode=_n_decode,
+            _sp.finish(n_prefill=len(admitted), n_decode=_n_decode,
                        tokens=real + (self.tokens_generated - _gen0),
                        padded_tokens=real + (self.tokens_generated - _gen0),
                        compiled=False)
@@ -352,17 +354,25 @@ class FakeEngine(TPUEngine):
         rt.fault_plan = self.fault_plan
         rt.journal = self.journal
         rt.policy = self.policy
+        rt.loop_clock = self.loop_clock
         self.runtimes[name] = rt
         self.notify()
 
     def _loop(self) -> None:
+        # Same loop-phase marks as TPUEngine._loop_once (stepprof
+        # LOOP_PHASES), so the gapless chain is testable without jax.
+        clock = self.loop_clock
+        clock.reset()
         while self._running:
+            clock.tick()
             self.last_tick_at = time.monotonic()
             self.journal.tick += 1
             # Deferred engine-thread calls (the fleet's migration
             # export/import run through call_on_loop here too).
             self._drain_engine_calls()
+            clock.enter("admit")
             self._admit()
+            clock.enter("other")
             did_work = False
             for rt in list(self.runtimes.values()):
                 rt.check_cancellations(self.core)
@@ -377,5 +387,7 @@ class FakeEngine(TPUEngine):
                         self._fail_runtime(rt, "engine step failed")
                     did_work = True
             if not did_work:
+                clock.enter("wait")
                 with self._cond:
                     self._cond.wait(timeout=0.02)
+                clock.enter("other")

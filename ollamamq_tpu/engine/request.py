@@ -47,6 +47,10 @@ class StreamItem:
     token_id: int = -1
     finish_reason: Optional[FinishReason] = None
     error: str = ""
+    # Monotonic instant of TokenStream.push (0.0 = never pushed): the
+    # stream writers observe now - pushed_at into ollamamq_stream_lag_ms
+    # once the frame is written.
+    pushed_at: float = 0.0
 
 
 class TokenStream:
@@ -82,6 +86,7 @@ class TokenStream:
     def push(self, item: StreamItem) -> None:
         if self._closed:
             return
+        item.pushed_at = time.monotonic()
         tap = self.tap
         if tap is not None:
             try:
@@ -262,8 +267,12 @@ class Request:
 
     def finish(self, reason: FinishReason, error: str = "") -> None:
         self.stats.finished_at = time.monotonic()
-        kind = "error" if reason in ERROR_REASONS else "done"
-        self.stream.push(StreamItem(kind, finish_reason=reason, error=error))
+        # The trace closes BEFORE the terminal item goes out: a client
+        # that has read its last frame finds the request finished at
+        # /debug/requests, not in flight for the engine thread's next
+        # few microseconds.
         tr = self.trace
         if tr is not None:
             tr.finish(reason.value)
+        kind = "error" if reason in ERROR_REASONS else "done"
+        self.stream.push(StreamItem(kind, finish_reason=reason, error=error))

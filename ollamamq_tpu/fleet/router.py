@@ -529,7 +529,8 @@ class FleetRouter:
                         prompt_tokens=None, sampling=None,
                         kind: str = "generate",
                         raw_prompt: str = "",
-                        context_ids=None, trace_ctx=None) -> Request:
+                        context_ids=None, trace_ctx=None,
+                        ingress_at=None) -> Request:
         """Fleet-wide bounded admission + fair-share enqueue. Mirrors
         TPUEngine.enqueue_request; the caps apply to the ROUTER queue
         (members run uncapped — the router already admitted).
@@ -591,7 +592,8 @@ class FleetRouter:
                 req.generated_ids = list(ctx)
                 req._replay_gen = len(ctx)
             req.trace = self.tracer.begin(rid, user, model, kind=kind,
-                                          ctx=trace_ctx)
+                                          ctx=trace_ctx,
+                                          ingress_at=ingress_at)
             flight = _Flight(req, ip, family if family is not None
                              else Family.UNKNOWN)
             if context_ids:

@@ -34,6 +34,13 @@ from ollamamq_tpu.ops.attention import (
 from ollamamq_tpu.ops.quant import embed_lookup, kv_write, logits_head, qeinsum
 from ollamamq_tpu.ops.rope import apply_rope
 
+# Stage names on the device trace (jax.named_scope: op metadata only, the
+# lowered programs compute the same thing). README's span table lists
+# them; tests/test_trace_spans.py finds each in the lowered ragged and
+# decode programs.
+SCOPES = ("embed", "attn_qkv", "kv_write", "attention", "attn_out", "mlp",
+          "lm_head", "sampling")
+
 
 def _adtype(params: dict):
     """Activation dtype for a forward: norm weights are never quantized,
@@ -132,6 +139,7 @@ def _ffn(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     return _mlp(lp, h)
 
 
+@jax.named_scope("lm_head")
 def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head", params["embed"])
@@ -150,14 +158,19 @@ def _layer_step(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     carried cache BEFORE attending.)
     """
     B, T, _ = x.shape
-    h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q, k, v = _qkv(cfg, lp, h)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn_qkv"):
+        h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q, k, v = _qkv(cfg, lp, h)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     attn = attn_fn(q, k, v)
-    x = x + qeinsum("bte,ed->btd", attn.reshape(B, T, cfg.q_dim), lp["wo"])
-    h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    return x + _ffn(cfg, lp, h2, valid=valid), k, v
+    with jax.named_scope("attn_out"):
+        x = x + qeinsum("bte,ed->btd", attn.reshape(B, T, cfg.q_dim),
+                        lp["wo"])
+    with jax.named_scope("mlp"):
+        h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        x = x + _ffn(cfg, lp, h2, valid=valid)
+    return x, k, v
 
 
 def forward_prefill(
@@ -287,7 +300,9 @@ def forward_ragged(
     (logits, caches').
     """
     T = tokens.shape[0]
-    x = embed_lookup(params["embed"], tokens, _adtype(params))[None]  # [1,T,D]
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embed"], tokens,
+                         _adtype(params))[None]  # [1,T,D]
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
 
@@ -297,13 +312,15 @@ def forward_ragged(
 
         def attn_fn(q, k, v):  # [1, T, H, hd]
             nonlocal kc, vc
-            kc = kv_write(kc, write_slots, k[0])
-            vc = kv_write(vc, write_slots, v[0])
-            out = ragged_attention_any(
-                attn_impl, q[0], kc, vc, page_table, tok_seq, tok_pos,
-                kv_len, q_start, q_len, page_size, interpret=interpret,
-                mesh=mesh,
-            )
+            with jax.named_scope("kv_write"):
+                kc = kv_write(kc, write_slots, k[0])
+                vc = kv_write(vc, write_slots, v[0])
+            with jax.named_scope("attention"):
+                out = ragged_attention_any(
+                    attn_impl, q[0], kc, vc, page_table, tok_seq, tok_pos,
+                    kv_len, q_start, q_len, page_size, interpret=interpret,
+                    mesh=mesh,
+                )
             return out[None]
 
         x, _, _ = _layer_step(cfg, lp, x, positions, attn_fn, valid=valid)
@@ -341,7 +358,9 @@ def forward_decode(
     """
     B = tokens.shape[0]
     valid = None if active is None else (active > 0)[:, None]
-    x = embed_lookup(params["embed"], tokens, _adtype(params))[:, None, :]  # [B,1,D]
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embed"], tokens,
+                         _adtype(params))[:, None, :]  # [B,1,D]
     pos2 = positions[:, None]  # [B,1]
     write_slots = flat_slot_indices(page_table, pos2, page_size)[:, 0]  # [B]
     seq_lens = positions + 1
@@ -349,19 +368,25 @@ def forward_decode(
     def body(carry, per_layer):
         x = carry
         lp, kc, vc = per_layer
-        h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(cfg, lp, h)  # [B,1,H,hd]
-        q = apply_rope(q, pos2, cfg.rope_theta)
-        k = apply_rope(k, pos2, cfg.rope_theta)
-        kc = kv_write(kc, write_slots, k[:, 0])
-        vc = kv_write(vc, write_slots, v[:, 0])
-        attn = paged_decode_attention_any(
-            attn_impl, q[:, 0], kc, vc, page_table, seq_lens, page_size,
-            mesh=mesh,
-        )  # [B,H,hd]
-        x = x + qeinsum("be,ed->bd", attn.reshape(B, cfg.q_dim), lp["wo"])[:, None, :]
-        h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(cfg, lp, h2, valid=valid)
+        with jax.named_scope("attn_qkv"):
+            h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _qkv(cfg, lp, h)  # [B,1,H,hd]
+            q = apply_rope(q, pos2, cfg.rope_theta)
+            k = apply_rope(k, pos2, cfg.rope_theta)
+        with jax.named_scope("kv_write"):
+            kc = kv_write(kc, write_slots, k[:, 0])
+            vc = kv_write(vc, write_slots, v[:, 0])
+        with jax.named_scope("attention"):
+            attn = paged_decode_attention_any(
+                attn_impl, q[:, 0], kc, vc, page_table, seq_lens, page_size,
+                mesh=mesh,
+            )  # [B,H,hd]
+        with jax.named_scope("attn_out"):
+            x = x + qeinsum("be,ed->bd", attn.reshape(B, cfg.q_dim),
+                            lp["wo"])[:, None, :]
+        with jax.named_scope("mlp"):
+            h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            x = x + _ffn(cfg, lp, h2, valid=valid)
         return x, (kc, vc)
 
     x, (k_cache, v_cache) = jax.lax.scan(

@@ -210,6 +210,7 @@ def sampling_flags(temp, top_k, top_p, repeat, presence, frequency):
     )
 
 
+@jax.named_scope("sampling")
 def maybe_apply_penalties(logits, recent, repeat, presence, frequency,
                           need_penalties: bool = True):
     """apply_penalties, skipped entirely at trace time when the host knows
@@ -242,6 +243,7 @@ def accept_prefix(
     return jnp.sum(jnp.cumprod(match, axis=1), axis=1).astype(jnp.int32)
 
 
+@jax.named_scope("sampling")
 def per_row_keys(
     key: jax.Array,  # engine-stream key for this dispatch
     seeds: jnp.ndarray,  # [B] int32; >0 = request-provided seed
@@ -259,6 +261,7 @@ def per_row_keys(
     return jnp.where((seeds > 0)[:, None], seeded, unseeded)
 
 
+@jax.named_scope("sampling")
 def sample_tokens_rowwise(
     logits: jnp.ndarray,  # [B, V] float32
     row_keys: jnp.ndarray,  # [B, 2] uint32 (per_row_keys)

@@ -35,6 +35,7 @@ from ollamamq_tpu.engine.engine import QueueFullError
 from ollamamq_tpu.engine.request import FinishReason, Request, StreamItem
 from ollamamq_tpu.ops.sampling import SamplingParams
 from ollamamq_tpu.server.registry import ModelRegistry
+from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.telemetry import stepprof
 from ollamamq_tpu.server.templates import render_chat, template_owns_bos
 
@@ -231,8 +232,6 @@ class Server:
     def _fence(self, got: int, kind: str, path: str):
         """Reject a stale-epoch router call: journal it, count it, 409.
         The zombie gets told exactly why so its logs explain the fence."""
-        from ollamamq_tpu.telemetry import schema as tm
-
         cur = self._ha_epoch
         journal = getattr(self.engine, "journal", None)
         if journal is not None:
@@ -321,13 +320,20 @@ class Server:
 
     def _enqueue(self, user, ip, model, family, prompt_tokens, sampling,
                  kind="generate", raw_prompt="",
-                 context_ids=None, trace_ctx=None) -> Request:
+                 context_ids=None, trace_ctx=None,
+                 ingress_at=None) -> Request:
+        """`ingress_at`: time.monotonic() at the handler's entry — the
+        request's trace opens there (`ingress` phase: JSON parse,
+        templating, tokenisation), so its phases sum to an end-to-end
+        that starts at the handler."""
         try:
             kw = {"kind": kind, "raw_prompt": raw_prompt}
             if context_ids:
                 kw["context_ids"] = context_ids
             if trace_ctx:
                 kw["trace_ctx"] = trace_ctx
+            if ingress_at is not None:
+                kw["ingress_at"] = ingress_at
             return self.engine.enqueue_request(
                 user, ip, model, family, prompt_tokens, sampling, **kw,
             )
@@ -414,6 +420,17 @@ class Server:
                     return
         finally:
             req.stream.on_item = None
+
+    @staticmethod
+    async def _write_frame(resp, item: StreamItem, data: bytes) -> None:
+        """Write the stream frame made from `item`, then observe how long
+        after the engine thread's push it left this process
+        (ollamamq_stream_lag_ms: push -> call_soon_threadsafe -> wake ->
+        resp.write)."""
+        await resp.write(data)
+        if item.pushed_at:
+            tm.STREAM_LAG_MS.observe(
+                (time.monotonic() - item.pushed_at) * 1e3)
 
     @staticmethod
     def _done_reason(item: StreamItem) -> str:
@@ -522,8 +539,6 @@ class Server:
 
     def _render_prometheus(self) -> str:
         from ollamamq_tpu.telemetry import REGISTRY
-        from ollamamq_tpu.telemetry import schema as tm
-
         eng = self.engine
         tm.UPTIME_SECONDS.set(time.time() - eng.started_at)
         # Queue depth per user: rebuilt each scrape so departed users'
@@ -1099,36 +1114,60 @@ class Server:
 
         The output directory is operator-controlled (OLLAMAMQ_PROFILE_DIR
         env, never the request body), duration is clamped to [0.1, 30] s,
-        and only one trace runs at a time.
-        """
-        import os
+        and only one trace runs at a time. Body: `seconds`, and
+        `python_tracer` (bool, default true): false leaves the profiler's
+        Python tracer off, so the engine's own `mq.*` spans and jax's
+        annotations are the only host events — the cheap capture.
 
+        While the capture runs the step profiler also enters every step
+        and loop phase as a span on the profiler's clock
+        (stepprof.SPAN_NAMES), each carrying the `seq` of its sample.
+        """
         self._ident(request)
         body = await self._body_json(request)
         try:
             seconds = max(0.1, min(float(body.get("seconds", 3.0)), 30.0))
         except (TypeError, ValueError):
             raise ApiError(400, "'seconds' must be a number")
+        python_tracer = body.get("python_tracer", True)
+        if not isinstance(python_tracer, bool):
+            raise ApiError(400, "'python_tracer' must be a boolean")
         out_dir = os.environ.get("OLLAMAMQ_PROFILE_DIR", "/tmp/ollamamq-profile")
         if self._profiling:
             raise ApiError(409, "a profile capture is already running")
         self._profiling = True
+        prof = stepprof.PROFILER
 
         def run_trace():
+            """(worker thread) -> (start_epoch, stop_epoch): the epoch
+            instants between which the device trace was recording."""
             import jax
 
-            jax.profiler.start_trace(out_dir)
+            if python_tracer:
+                # No options at all: the capture every earlier
+                # measurement was made with.
+                jax.profiler.start_trace(out_dir)
+            else:
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                jax.profiler.start_trace(out_dir, profiler_options=opts)
             try:
+                t0 = time.time()
+                prof.capturing = True
                 time.sleep(seconds)
             finally:
-                # stop_trace must run even if the sleep is interrupted:
-                # a started-but-never-stopped jax profiler refuses every
-                # later start_trace, wedging the endpoint permanently.
+                # Spans off before the trace stops; and stop_trace must
+                # run even if the sleep is interrupted: a started-but-
+                # never-stopped jax profiler refuses every later
+                # start_trace, wedging the endpoint permanently.
+                prof.capturing = False
+                t1 = time.time()
                 jax.profiler.stop_trace()
+            return t0, t1
 
-        t_start = time.time()
         try:
-            await asyncio.get_running_loop().run_in_executor(None, run_trace)
+            t0, t1 = await asyncio.get_running_loop().run_in_executor(
+                None, run_trace)
         except Exception as e:
             # A failed capture answers 500 and — via the finally below —
             # clears the capture-running flag, so the NEXT capture gets a
@@ -1136,14 +1175,20 @@ class Server:
             raise ApiError(500, f"profile capture failed: {e}")
         finally:
             self._profiling = False
+        # The capture's own step accounting rides along: the samples
+        # that ended while the device trace was recording (stop_trace
+        # itself can take several times the capture's length to return),
+        # so a trace and its per-phase step samples land together and
+        # `seq` joins a sample to its mq.* spans in the trace.
+        samples = prof.window(t0, t1)
+        seqs = [s["seq"] for s in samples]
         return web.json_response({
             "status": "success", "trace_dir": out_dir, "seconds": seconds,
-            # The capture window's step accounting rides along: the
-            # stepprof ring slice taken while the device trace ran, so
-            # a trace and its per-phase step samples land together and
-            # a TensorBoard timeline can be read against the engine's
-            # own host_prep/dispatch/collect/detok attribution.
-            "stepprof": stepprof.PROFILER.window(t_start, time.time()),
+            "python_tracer": python_tracer,
+            "capture": {"start_epoch": t0, "stop_epoch": t1,
+                        "first_seq": min(seqs, default=None),
+                        "last_seq": max(seqs, default=None)},
+            "stepprof": samples,
         })
 
     async def debug_stepprof(self, request: web.Request) -> web.Response:
@@ -1178,6 +1223,7 @@ class Server:
 
     # ------------------------------------------------------------- /api/*
     async def api_generate(self, request: web.Request) -> web.StreamResponse:
+        t_in = time.monotonic()
         user, ip = self._ident(request)
         # A fenced ex-primary must not place work here (member side).
         self._check_epoch(request, "placement")
@@ -1208,7 +1254,8 @@ class Server:
         req = self._enqueue(user, ip, model, Family.OLLAMA, tokens, sampling,
                             raw_prompt=prompt,
                             context_ids=context or None,
-                            trace_ctx=self._trace_ctx(request))
+                            trace_ctx=self._trace_ctx(request),
+                            ingress_at=t_in)
         if body.get("images"):
             req.images_ignored = True
 
@@ -1218,6 +1265,7 @@ class Server:
         return await self._ollama_stream(request, model, req, chat=False)
 
     async def api_chat(self, request: web.Request) -> web.StreamResponse:
+        t_in = time.monotonic()
         user, ip = self._ident(request)
         body = await self._body_json(request)
         model = body.get("model", "")
@@ -1236,7 +1284,8 @@ class Server:
                                 add_bos=not template_owns_bos(chat_cfg))
         req = self._enqueue(user, ip, model, Family.OLLAMA, tokens, sampling,
                             raw_prompt=prompt,
-                            trace_ctx=self._trace_ctx(request))
+                            trace_ctx=self._trace_ctx(request),
+                            ingress_at=t_in)
         if any(isinstance(m, dict) and m.get("images") for m in messages):
             req.images_ignored = True
 
@@ -1297,9 +1346,9 @@ class Server:
                     if item.token_id >= 0:
                         pending_ids.append(item.token_id)
                     if item.text:
-                        await resp.write(chunk(item.text))
+                        await self._write_frame(resp, item, chunk(item.text))
                 elif item.kind == "error":
-                    await resp.write((json.dumps(
+                    await self._write_frame(resp, item, (json.dumps(
                         {"model": model, "created_at": _now_iso(),
                          "done": True, "req_id": req.req_id,
                          "done_reason": self._error_reason(item),
@@ -1319,7 +1368,8 @@ class Server:
                         p["message"] = {"role": "assistant", "content": ""}
                     else:
                         p["response"] = ""
-                    await resp.write((json.dumps(p) + "\n").encode())
+                    await self._write_frame(
+                        resp, item, (json.dumps(p) + "\n").encode())
                     break
         except (ConnectionResetError, asyncio.CancelledError):
             # Client went away mid-stream: cancel + reclaim (dropped count).
@@ -1569,6 +1619,7 @@ class Server:
 
     # --------------------------------------------------------------- /v1/*
     async def v1_chat_completions(self, request: web.Request) -> web.StreamResponse:
+        t_in = time.monotonic()
         user, ip = self._ident(request)
         body = await self._body_json(request)
         model = body.get("model", "")
@@ -1585,7 +1636,8 @@ class Server:
                                 add_bos=not template_owns_bos(chat_cfg))
         req = self._enqueue(user, ip, model, Family.OPENAI, tokens, sampling,
                             raw_prompt=prompt,
-                            trace_ctx=self._trace_ctx(request))
+                            trace_ctx=self._trace_ctx(request),
+                            ingress_at=t_in)
         if any(isinstance(p, dict) and p.get("type") == "image_url"
                for m in messages if isinstance(m, dict)
                for p in (m.get("content") if isinstance(m.get("content"),
@@ -1598,6 +1650,7 @@ class Server:
         return self._openai_final(model, req, items, rid, chat=True)
 
     async def v1_completions(self, request: web.Request) -> web.StreamResponse:
+        t_in = time.monotonic()
         user, ip = self._ident(request)
         body = await self._body_json(request)
         model = body.get("model", "")
@@ -1615,12 +1668,13 @@ class Server:
                 raise ApiError(400, "streaming with multiple prompts is not supported")
             tokens = self._tokenize(model, prompts[0])
             req = self._enqueue(user, ip, model, Family.OPENAI, tokens, sampling,
-                                raw_prompt=prompts[0])
+                                raw_prompt=prompts[0], ingress_at=t_in)
             return await self._openai_stream(request, model, req, rid, chat=False)
         # One choice per prompt (OpenAI list-prompt semantics).
         reqs = [
             self._enqueue(user, ip, model, Family.OPENAI,
-                          self._tokenize(model, p), sampling, raw_prompt=p)
+                          self._tokenize(model, p), sampling, raw_prompt=p,
+                          ingress_at=t_in)
             for p in prompts
         ]
         choices, usage_p, usage_c = [], 0, 0
@@ -1696,13 +1750,16 @@ class Server:
                         if first:
                             delta["role"] = "assistant"
                             first = False
-                        await resp.write(sse({"index": 0, "delta": delta,
-                                              "finish_reason": None}))
+                        await self._write_frame(resp, item, sse(
+                            {"index": 0, "delta": delta,
+                             "finish_reason": None}))
                     else:
-                        await resp.write(sse({"index": 0, "text": item.text,
-                                              "finish_reason": None}))
+                        await self._write_frame(resp, item, sse(
+                            {"index": 0, "text": item.text,
+                             "finish_reason": None}))
                 elif item.kind == "error":
-                    await resp.write(
+                    await self._write_frame(
+                        resp, item,
                         ("data: " + json.dumps(
                             {"error": item.error,
                              "reason": self._error_reason(item)}) +
@@ -1723,7 +1780,7 @@ class Server:
                                  "model": model, "choices": [],
                                  "warnings": [_IMAGES_IGNORED]}) +
                              "\n\n").encode())
-                    await resp.write(sse(fin))
+                    await self._write_frame(resp, item, sse(fin))
                     await resp.write(b"data: [DONE]\n\n")
                     break
         except (ConnectionResetError, asyncio.CancelledError):

@@ -28,6 +28,8 @@ from ollamamq_tpu.telemetry import schema as tm
 # timeline means an engine event was added without updating EVENT_PHASE
 # (and the doc gate makes that loud).
 PHASES = (
+    "ingress",       # HTTP handler entry -> enqueue: JSON parse, chat
+    #                  templating, tokenisation (asyncio thread)
     "queue",         # fair-share queue wait: enqueue/requeue -> admit
     "admission",     # scheduler placement + runtime pending queue
     "prefix_cache",  # prefix-cache lookup/pin on a cache-hit admission
@@ -40,6 +42,7 @@ PHASES = (
 # Event name -> phase of the span that event OPENS (the span lasts until
 # the next event). Terminal events open no span.
 EVENT_PHASE = {
+    "ingress": "ingress",
     "enqueue": "queue",
     "requeue": "queue",
     "admit": "admission",
@@ -125,7 +128,8 @@ def timeline(trace, now: Optional[float] = None,
 
     `trace` is a telemetry.tracing.Trace; its events list is copied (the
     engine thread may still be appending). Timestamps are reported
-    relative to the request's enqueue event, in milliseconds.
+    relative to the request's first event (`ingress` where the handler
+    timed its entry, else `enqueue`), in milliseconds.
     """
     if now is None:
         now = time.monotonic()

@@ -172,9 +172,17 @@ def total_shed() -> float:
 REQUEST_PHASE_MS = REGISTRY.histogram(
     "ollamamq_request_phase_ms",
     "Per-request latency attribution: milliseconds spent in each lifecycle "
-    "phase (queue/admission/prefix_cache/prefill/decode/stream), observed "
-    "at request finish; phases sum to end-to-end latency",
+    "phase (ingress/queue/admission/prefix_cache/prefill/decode/stream), "
+    "observed at request finish; phases sum to end-to-end latency",
     buckets=DEFAULT_LATENCY_BUCKETS_MS, labels=("model", "phase"))
+STREAM_LAG_MS = REGISTRY.histogram(
+    "ollamamq_stream_lag_ms",
+    "Milliseconds between the engine thread pushing a stream item "
+    "(TokenStream.push) and its NDJSON/SSE frame having been written to "
+    "the client socket by the asyncio thread — the far end of the "
+    "request path, which no request phase times",
+    buckets=(0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
+             250.0, 1000.0))
 SLO_VIOLATIONS_TOTAL = REGISTRY.counter(
     "ollamamq_slo_violations_total",
     "Observations over the configured SLO threshold (--slo-ttft-ms / "
@@ -366,8 +374,10 @@ STEP_PHASE_MS = REGISTRY.histogram(
     "phase (host_prep = python batch composition, dispatch = issuing "
     "the jit'd computation — XLA compile on a fresh cache key, "
     "collect = device wait + D2H materialization, detok = the host "
-    "emit loop), by step mode (ragged / spec_verify / decode / embed "
-    "/ fake) — the always-on stepprof ring's metric face",
+    "emit loop; and between steps loop_admit = MQCore pops and "
+    "placement, loop_other = the rest of the engine tick, loop_wait = "
+    "the idle condvar wait), by step mode (ragged / spec_verify / decode "
+    "/ embed / fake) — the always-on stepprof ring's metric face",
     buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
              25.0, 50.0, 100.0, 250.0, 1000.0),
     labels=("phase", "mode"))

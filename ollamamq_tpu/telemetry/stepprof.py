@@ -28,6 +28,16 @@ Contracts the tests pin:
   * Self-overhead is metered: every profiler entry point times itself
     (perf_counter_ns) and `overhead_fraction()` must stay under 1% of
     profiled step time.
+  * The engine thread's time is accounted for without a gap: between
+    steps the thread is in one of the LOOP_PHASES, kept by a LoopClock
+    the ENGINE owns (never this process-wide profiler: an in-process
+    fleet runs several engine threads into one ring) and written into
+    the next sample as `loop_*_ms`. Over consecutive samples of one
+    thread, sum(total_ms + loop_*_ms) is the thread's wall time.
+  * While a device capture runs (`PROFILER.capturing`, set by
+    POST /debug/profile) every phase is also entered as a span on the
+    profiler's clock (SPAN_NAMES), carrying the `seq` its sample will
+    have — one mechanism feeds samples, histograms and spans.
   * Compile events are recorded by the jit-getter seams exactly once
     per cache key (jax.jit traces+compiles synchronously on the first
     call of a fresh cache entry — timing that first call IS the compile
@@ -68,6 +78,31 @@ PHASES = (
     #                stream writes, per-request finish handling
 )
 
+# CLOSED vocabulary of what the engine thread does BETWEEN steps, pinned
+# to the README beside PHASES. Observed into ollamamq_step_phase_ms as
+# phase="loop_<name>" and written into the NEXT sample as loop_<name>_ms.
+LOOP_PHASES = (
+    "admit",   # TPUEngine._admit(): MQCore pops + placement onto runtimes
+    "other",   # everything else outside a step timer: HBM sample, engine
+    #            calls, swap/recover, check_cancellations, the choice of
+    #            k, and step timers that were abandoned (early returns)
+    "wait",    # the condvar wait of a tick that did no work
+)
+
+# The same phases as spans on the device trace's clock, emitted only
+# while a capture runs. Pinned to the README span table (gate 7).
+SPAN_NAMES = (tuple("mq." + p for p in PHASES)
+              + tuple("mq.loop." + p for p in LOOP_PHASES))
+
+# The phase a mark OPENS: marks name the phase that ended, a span needs
+# its name when it begins, and the order is fixed.
+_NEXT_PHASE = dict(zip(PHASES, PHASES[1:]))
+# Every `<phase>_ms` field of a sample = every `phase` label value of
+# ollamamq_step_phase_ms.
+_SAMPLE_PHASES = PHASES + tuple("loop_" + p for p in LOOP_PHASES)
+# Sample fields a span carries once the step has noted them.
+_SPAN_FIELDS = ("T_pad", "k_cap", "tokens")
+
 # Step modes (the `mode` label + the first element of the shape key).
 # Not a validation gate — a sample carries whatever the engine said —
 # but the set the engine emits today, for readers.
@@ -88,41 +123,179 @@ def _pctl(window, q: float) -> Optional[float]:
     return s[min(len(s) - 1, int(q * len(s)))]
 
 
-class StepTimer:
-    """One step's phase clock. `mark(phase)` charges everything since
-    the previous mark to `phase`; `finish(**fields)` records the sample
-    (or never call it — an abandoned timer leaves no trace, which is
-    exactly what a faulted/preempted dispatch should leave). Phases may
-    be marked more than once (chunked host prep); deltas accumulate."""
+class LoopClock:
+    """One engine thread's cursor over its own time: a single open phase
+    at any instant, so nothing the thread does is outside a named phase.
+    The ENGINE owns it (one per engine thread) and hands it to its
+    runtimes; step timers started with it advance the same cursor, so
+    step phases and loop phases form one contiguous chain. Used from
+    its own thread only.
 
-    __slots__ = ("_prof", "mode", "_t0", "_last", "phases", "_done")
+    `enter(phase)` closes whatever is open and opens a LOOP_PHASES
+    entry; a StepTimer's start/mark do the same for PHASES. Time charged
+    to loop phases since the last recorded sample rides in the NEXT
+    sample as loop_*_ms. `tick()` — top of an engine tick, where no step
+    is in flight — folds what abandoned timers were charged into
+    `other`, so early returns and faulted dispatches leave no hole."""
 
-    def __init__(self, prof: "StepProfiler", mode: str):
+    __slots__ = ("name", "_prof", "_last", "_owner", "_open", "_span",
+                 "_loop", "_live", "_timer_ms", "_epoch", "_seq",
+                 "_adopted")
+
+    def __init__(self, prof: "StepProfiler", name: str):
         self._prof = prof
+        self.name = name
+        self._span = None
+        self.reset()
+
+    def reset(self) -> None:
+        """(Re)start the chain now — the engine thread calls it as it
+        starts, so a stopped engine's downtime is in no phase."""
+        self._close_span()
+        self._last = time.perf_counter()
+        self._owner: Optional["StepTimer"] = None  # None = a loop phase
+        self._open = "other"
+        self._loop = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._live = 0          # timers started and not finished
+        self._timer_ms = 0.0    # thread time those timers were charged
+        self._epoch = 0         # bumped when abandoned timers are folded
+        self._seq: Optional[int] = None  # reserved for the next sample
+        self._adopted: Optional["StepTimer"] = None
+
+    def _close_span(self) -> None:
+        span, self._span = self._span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+
+    def _switch(self, t: float, owner: Optional["StepTimer"], phase: str,
+                charge: Optional[tuple] = None) -> None:
+        """Close the open phase at `t` and open `phase` for `owner`. The
+        closed slice goes to `charge` = (timer, phase) when a step mark
+        names it, else to the phase it was opened under."""
+        ms = (t - self._last) * 1e3
+        self._last = t
+        tgt, name = charge if charge is not None \
+            else (self._owner, self._open)
+        if tgt is None:
+            self._loop[name] += ms
+        else:
+            tgt.phases[name] = tgt.phases.get(name, 0.0) + ms
+            self._timer_ms += ms
+        if self._span is not None:
+            self._close_span()
+        self._owner, self._open = owner, phase
+        prof = self._prof
+        if prof.capturing and prof.span_factory is not None:
+            self._open_span(prof, owner, phase)
+
+    def _open_span(self, prof: "StepProfiler",
+                   owner: Optional["StepTimer"], phase: str) -> None:
+        if owner is None:
+            if self._seq is None:
+                self._seq = prof._reserve_seq()
+            span = prof.span_factory("mq.loop." + phase, seq=self._seq)
+        else:
+            if owner.seq is None:
+                owner.seq = prof._reserve_seq()
+            span = prof.span_factory(
+                "mq." + phase, seq=owner.seq, mode=owner.mode,
+                **{k: v for k, v in owner.fields.items()
+                   if k in _SPAN_FIELDS})
+        span.__enter__()
+        self._span = span
+
+    def enter(self, phase: str) -> None:
+        """Open a LOOP_PHASES entry (closing whatever was open)."""
+        t = time.perf_counter()
+        self._switch(t, None, phase)
+        self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+
+    def tick(self) -> None:
+        """Top of an engine tick: no step is in flight here, so a timer
+        still unfinished was abandoned — what it was charged is `other`
+        (and it can no longer record), and the seq its spans carried
+        goes back to the clock when nobody reserved a later one."""
+        if self._live or self._owner is not None:
+            t = time.perf_counter()
+            if self._owner is not None:
+                self._switch(t, None, "other")
+            self._loop["other"] += self._timer_ms
+            self._epoch += 1
+            a = self._adopted
+            if a is not None and not a._done and a.seq == self._prof.seq:
+                self._seq = a.seq
+            self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+        self._live, self._timer_ms, self._adopted = 0, 0.0, None
+
+
+class StepTimer:
+    """One step's phase clock over its thread's LoopClock. `mark(phase)`
+    charges everything since the previous boundary to `phase` and opens
+    the next phase of the fixed order; `finish(**fields)` records the
+    sample (or never call it — an abandoned timer leaves no sample, which
+    is exactly what a faulted/preempted dispatch should leave; its time
+    is folded into the loop's `other` at the next tick). Phases may be
+    marked more than once (chunked host prep); deltas accumulate. Where
+    several timers are live on one thread (one engine, two runtimes'
+    split decode), each phase holds the thread time charged to it, so
+    the timers never count an instant twice."""
+
+    __slots__ = ("_prof", "_clock", "mode", "phases", "fields", "seq",
+                 "_done", "_epoch")
+
+    def __init__(self, prof: "StepProfiler", mode: str,
+                 clock: Optional[LoopClock] = None):
+        t = time.perf_counter()
+        if clock is None:  # a step outside any engine loop (bench, tests)
+            clock = LoopClock(prof, threading.current_thread().name)
+            clock._last = t
+        self._prof = prof
+        self._clock = clock
         self.mode = mode
-        self._t0 = time.perf_counter()
-        self._last = self._t0
         self.phases: Dict[str, float] = {}
+        self.fields: Dict[str, object] = {}
         self._done = False
+        self._epoch = clock._epoch
+        # The seq the loop spans before this step carried, if any: those
+        # spans' time is written into this step's sample.
+        self.seq = clock._seq
+        if self.seq is not None:
+            clock._seq, clock._adopted = None, self
+        clock._live += 1
+        clock._switch(t, self, PHASES[0])
+        prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+
+    def note(self, **fields) -> None:
+        """Sample fields known before the step ends (T_pad, k_cap,
+        tokens): spans opened from here on carry them, and finish()
+        records them unless it names them again."""
+        self.fields.update(fields)
 
     def mark(self, phase: str) -> None:
         t = time.perf_counter()
-        self.phases[phase] = self.phases.get(phase, 0.0) + (t - self._last) * 1e3
-        self._last = t
+        nxt = _NEXT_PHASE.get(phase)
+        self._clock._switch(t, self if nxt else None, nxt or "other",
+                            charge=(self, phase))
         # Self-overhead: the mark itself (two clock reads + a dict op).
         self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
 
     def finish(self, **fields) -> Optional[dict]:
-        if self._done:  # double-finish is a bug upstream; stay silent
+        clock = self._clock
+        # Double-finish is a bug upstream, and a timer the loop already
+        # folded into `other` must not count its time twice: stay silent.
+        if self._done or self._epoch != clock._epoch:
             return None
         self._done = True
         t = time.perf_counter()
         # The step ends at its LAST mark: total is then the exact sum of
-        # the phase deltas (one contiguous chain from _t0), and the
-        # microseconds between that mark and this call — argument
-        # evaluation at the finish() call site — are profiler overhead,
-        # not step time.
-        total_ms = (self._last - self._t0) * 1e3
+        # the phase deltas, and the microseconds between that mark and
+        # this call — argument evaluation at the finish() call site —
+        # are the loop's, not step time.
+        if clock._owner is self:
+            clock._switch(clock._last, None, "other")
+        total_ms = sum(self.phases.values())
+        clock._live -= 1
+        clock._timer_ms -= total_ms
         sample = {
             "ts": time.time(),
             "mode": self.mode,
@@ -130,8 +303,14 @@ class StepTimer:
         }
         for ph in PHASES:
             sample[ph + "_ms"] = round(self.phases.get(ph, 0.0), 4)
+        loop = clock._loop
+        for ph in LOOP_PHASES:
+            sample["loop_" + ph + "_ms"] = round(loop[ph], 4)
+            loop[ph] = 0.0
+        sample["thread"] = clock.name
+        sample.update(self.fields)
         sample.update(fields)
-        self._prof._record(sample, total_ms)
+        self._prof._record(sample, total_ms, self.seq)
         self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
         return sample
 
@@ -145,12 +324,20 @@ class StepProfiler:
         self._lock = threading.Lock()
         self._ring_n = ring
         self._overhead_ns = 0  # time spent inside profiler calls
+        # Spans on the device trace's clock. `span_factory(name, **stats)`
+        # is handed over by the engine module (which imports jax:
+        # jax.profiler.TraceAnnotation) so this file stays stdlib-only;
+        # `capturing` is set by POST /debug/profile after start_trace
+        # returns and cleared before stop_trace. With no capture running
+        # a mark pays one attribute test.
+        self.span_factory = None
+        self.capturing = False
         self._reset_locked()
 
     def _reset_locked(self) -> None:
         self.samples: deque = deque(maxlen=self._ring_n)
         self.seq = 0
-        self._step_ns = 0      # profiled step wall time (denominator)
+        self._step_ns = 0      # accounted thread time: steps + loop phases
         self._overhead_ns = 0
         # (mode, T_pad, k_cap) -> deque of total_ms; insertion-ordered so
         # the oldest shape key is evicted when the table fills.
@@ -168,17 +355,28 @@ class StepProfiler:
             self._reset_locked()
 
     # -- step samples ------------------------------------------------------
-    def start(self, mode: str) -> StepTimer:
-        return StepTimer(self, mode)
+    def start(self, mode: str, clock: Optional[LoopClock] = None) -> StepTimer:
+        """`clock`: the engine thread's LoopClock (runtimes pass the one
+        their engine attached); None times the step alone."""
+        return StepTimer(self, mode, clock)
 
-    def _record(self, sample: dict, total_ms: float) -> None:
-        t0 = time.perf_counter_ns()
-        key = (sample["mode"], sample.get("T_pad", 0), sample.get("k_cap", 0))
+    def _reserve_seq(self) -> int:
         with self._lock:
             self.seq += 1
-            sample["seq"] = self.seq
+            return self.seq
+
+    def _record(self, sample: dict, total_ms: float,
+                seq: Optional[int] = None) -> None:
+        t0 = time.perf_counter_ns()
+        key = (sample["mode"], sample.get("T_pad", 0), sample.get("k_cap", 0))
+        loop_ms = sum(sample["loop_" + ph + "_ms"] for ph in LOOP_PHASES)
+        with self._lock:
+            if seq is None:  # no span carried it: in ring order, as ever
+                self.seq += 1
+                seq = self.seq
+            sample["seq"] = seq
             self.samples.append(sample)
-            self._step_ns += int(total_ms * 1e6)
+            self._step_ns += int((total_ms + loop_ms) * 1e6)
             win = self._shapes.get(key)
             if win is None:
                 while len(self._shapes) >= _SHAPE_KEYS:  # bounded key table
@@ -193,7 +391,7 @@ class StepProfiler:
                         self._phase_sum.get((mode, ph), 0.0) + v
             self._tokens += int(sample.get("tokens", 0) or 0)
             self._padded += int(sample.get("padded_tokens", 0) or 0)
-        for ph in PHASES:
+        for ph in _SAMPLE_PHASES:
             v = sample.get(ph + "_ms", 0.0)
             if v:
                 tm.STEP_PHASE_MS.labels(phase=ph, mode=sample["mode"]) \
@@ -249,7 +447,9 @@ class StepProfiler:
 
     # -- readers -----------------------------------------------------------
     def overhead_fraction(self) -> float:
-        """Profiler-internal time / profiled step wall time. The <1%
+        """Profiler-internal time (step marks, loop accounting, spans
+        while a capture runs) / the engine-thread time the recorded
+        samples account for (step phases + loop phases). The <1%
         always-on budget; 0.0 before any sample."""
         with self._lock:
             if self._step_ns <= 0:

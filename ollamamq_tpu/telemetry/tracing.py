@@ -141,8 +141,15 @@ class Tracer:
 
     def begin(self, req_id: int, user: str, model: str,
               kind: str = "generate", ctx: Optional[str] = None,
-              metered: bool = True) -> Trace:
+              metered: bool = True,
+              ingress_at: Optional[float] = None) -> Trace:
+        """`ingress_at`: the monotonic instant the HTTP handler was
+        entered; the trace then opens with an `ingress` event there, so
+        parse/template/tokenise time is a phase and end-to-end starts at
+        the handler."""
         tr = Trace(self, req_id, user, model, kind, ctx=ctx, metered=metered)
+        if ingress_at is not None:
+            tr.events.append(("ingress", ingress_at, None))
         tr.event("enqueue")
         with self._lock:
             self._live[id(tr)] = tr
@@ -255,7 +262,9 @@ def stitch_events(spans: List[dict], root_origin: str) -> List[tuple]:
     contributes everything including its terminal; member spans
     contribute their lifecycle events but NOT their terminals (a member
     attempt's `cancelled` is a routing ack — eviction, migration commit
-    — not the client outcome) and not their `enqueue` duplicates. The
+    — not the client outcome) and not their `ingress`/`enqueue`
+    duplicates (the member's handler re-parses what the router already
+    timed; that time stays in the phase the router's span has open). The
     result is sorted with the root terminal pinned last, so
     attribution.phase_totals over it sums EXACTLY to the client-observed
     end-to-end wall clock: the fleet-wide attribution invariant,
@@ -270,7 +279,7 @@ def stitch_events(spans: List[dict], root_origin: str) -> List[tuple]:
             if is_root:
                 root_events.append((name, t, tagged))
             elif name not in attribution.TERMINAL_EVENTS \
-                    and name != "enqueue":
+                    and name not in ("ingress", "enqueue"):
                 member_events.append((name, t, tagged))
     if not root_events:
         # No root span (a member asked about its own rid): fall back to

@@ -31,7 +31,16 @@
      of `ollamamq_step_phase_ms`) must match the README "Engine
      performance plane" phase table (between the
      `<!-- stepprof-phases:begin -->` / `<!-- stepprof-phases:end -->`
-     markers) exactly.
+     markers) exactly;
+  7. stepprof loop phases and spans — the closed vocabulary of what the
+     engine thread does between steps (telemetry/stepprof.py
+     LOOP_PHASES: `loop_<name>_ms` on a sample, `phase="loop_<name>"`
+     on the histogram) must match the README loop-phase table (between
+     the `<!-- stepprof-loop-phases:begin -->` / `...:end -->` markers),
+     and the `mq.*` span names the profiler emits during a device
+     capture (SPAN_NAMES) must match the `mq.`-prefixed names of the
+     README span table (between `<!-- stepprof-spans:begin -->` /
+     `<!-- stepprof-spans:end -->`) exactly.
 
 Imports ONLY ollamamq_tpu.telemetry.schema/.attribution/.journal/
 .tracing — the declaration sites — so the check runs without jax, a
@@ -59,6 +68,20 @@ ROUTER_SPANS_BEGIN = "<!-- router-spans:begin -->"
 ROUTER_SPANS_END = "<!-- router-spans:end -->"
 STEPPROF_BEGIN = "<!-- stepprof-phases:begin -->"
 STEPPROF_END = "<!-- stepprof-phases:end -->"
+LOOP_PHASES_BEGIN = "<!-- stepprof-loop-phases:begin -->"
+LOOP_PHASES_END = "<!-- stepprof-loop-phases:end -->"
+SPANS_BEGIN = "<!-- stepprof-spans:begin -->"
+SPANS_END = "<!-- stepprof-spans:end -->"
+
+
+def _documented(readme_text: str, begin: str, end: str,
+                pattern: str = r"`([a-z_]+)`") -> set:
+    """Backticked names matching `pattern` inside a marked region."""
+    start = readme_text.find(begin)
+    stop = readme_text.find(end)
+    if start == -1 or stop == -1 or stop < start:
+        return set()
+    return set(re.findall(pattern, readme_text[start:stop]))
 
 
 def documented_metric_names(readme_text: str) -> set:
@@ -80,11 +103,7 @@ def documented_phase_names(readme_text: str) -> set:
     """Backticked names inside the marked phase-table region. Markers
     (not layout) scope the search, so `queue`-the-word elsewhere in the
     README can't satisfy the check by accident."""
-    start = readme_text.find(PHASES_BEGIN)
-    end = readme_text.find(PHASES_END)
-    if start == -1 or end == -1 or end < start:
-        return set()
-    return set(re.findall(r"`([a-z_]+)`", readme_text[start:end]))
+    return _documented(readme_text, PHASES_BEGIN, PHASES_END)
 
 
 def registered_phase_names() -> set:
@@ -96,11 +115,7 @@ def registered_phase_names() -> set:
 
 def documented_shed_reasons(readme_text: str) -> set:
     """Backticked names inside the marked shed-reason region."""
-    start = readme_text.find(SHED_BEGIN)
-    end = readme_text.find(SHED_END)
-    if start == -1 or end == -1 or end < start:
-        return set()
-    return set(re.findall(r"`([a-z_]+)`", readme_text[start:end]))
+    return _documented(readme_text, SHED_BEGIN, SHED_END)
 
 
 def registered_shed_reasons() -> set:
@@ -112,11 +127,7 @@ def registered_shed_reasons() -> set:
 
 def documented_journal_events(readme_text: str) -> set:
     """Backticked names inside the marked journal-event region."""
-    start = readme_text.find(JOURNAL_BEGIN)
-    end = readme_text.find(JOURNAL_END)
-    if start == -1 or end == -1 or end < start:
-        return set()
-    return set(re.findall(r"`([a-z_]+)`", readme_text[start:end]))
+    return _documented(readme_text, JOURNAL_BEGIN, JOURNAL_END)
 
 
 def registered_journal_events() -> set:
@@ -128,11 +139,7 @@ def registered_journal_events() -> set:
 
 def documented_router_spans(readme_text: str) -> set:
     """Backticked names inside the marked router-span region."""
-    start = readme_text.find(ROUTER_SPANS_BEGIN)
-    end = readme_text.find(ROUTER_SPANS_END)
-    if start == -1 or end == -1 or end < start:
-        return set()
-    return set(re.findall(r"`([a-z_]+)`", readme_text[start:end]))
+    return _documented(readme_text, ROUTER_SPANS_BEGIN, ROUTER_SPANS_END)
 
 
 def registered_router_spans() -> set:
@@ -144,11 +151,7 @@ def registered_router_spans() -> set:
 
 def documented_stepprof_phases(readme_text: str) -> set:
     """Backticked names inside the marked stepprof-phase region."""
-    start = readme_text.find(STEPPROF_BEGIN)
-    end = readme_text.find(STEPPROF_END)
-    if start == -1 or end == -1 or end < start:
-        return set()
-    return set(re.findall(r"`([a-z_]+)`", readme_text[start:end]))
+    return _documented(readme_text, STEPPROF_BEGIN, STEPPROF_END)
 
 
 def registered_stepprof_phases() -> set:
@@ -156,6 +159,34 @@ def registered_stepprof_phases() -> set:
     from ollamamq_tpu.telemetry.stepprof import PHASES
 
     return set(PHASES)
+
+
+def documented_loop_phases(readme_text: str) -> set:
+    """First-column names only: the meanings quote function names."""
+    return _documented(readme_text, LOOP_PHASES_BEGIN, LOOP_PHASES_END,
+                       r"(?m)^\| `([a-z_]+)` \|")
+
+
+def registered_loop_phases() -> set:
+    sys.path.insert(0, _REPO)
+    from ollamamq_tpu.telemetry.stepprof import LOOP_PHASES
+
+    return set(LOOP_PHASES)
+
+
+def documented_span_names(readme_text: str) -> set:
+    """The `mq.`-prefixed names of the span table (it also lists jit
+    function and scope names, which tests/test_trace_spans.py pins
+    against the lowered programs — they need jax)."""
+    return _documented(readme_text, SPANS_BEGIN, SPANS_END,
+                       r"`(mq\.[a-z_.]+)`")
+
+
+def registered_span_names() -> set:
+    sys.path.insert(0, _REPO)
+    from ollamamq_tpu.telemetry.stepprof import SPAN_NAMES
+
+    return set(SPAN_NAMES)
 
 
 def _diff(readme: str, what: str, registered: set, documented: set,
@@ -220,13 +251,27 @@ def main(argv) -> int:
         f"performance-plane table (between {STEPPROF_BEGIN} / "
         f"{STEPPROF_END})",
         "documented stepprof phase(s) the step profiler no longer emits")
+    rc |= _diff(
+        readme, "stepprof loop phases", registered_loop_phases(),
+        documented_loop_phases(text),
+        "step-profiler loop phase(s) missing from the README loop-phase "
+        f"table (between {LOOP_PHASES_BEGIN} / {LOOP_PHASES_END})",
+        "documented loop phase(s) the step profiler no longer emits")
+    rc |= _diff(
+        readme, "stepprof spans", registered_span_names(),
+        documented_span_names(text),
+        "mq.* span name(s) missing from the README span table "
+        f"(between {SPANS_BEGIN} / {SPANS_END})",
+        "documented mq.* span(s) the step profiler no longer emits")
     if rc == 0:
         print(f"ok: {len(registered_metric_names())} metrics, "
               f"{len(registered_phase_names())} phases, "
               f"{len(registered_shed_reasons())} shed reasons, "
               f"{len(registered_journal_events())} journal events, "
-              f"{len(registered_router_spans())} router spans, and "
+              f"{len(registered_router_spans())} router spans, "
               f"{len(registered_stepprof_phases())} stepprof phases, "
+              f"{len(registered_loop_phases())} loop phases, and "
+              f"{len(registered_span_names())} mq.* spans, "
               "all documented")
     return rc
 

@@ -114,3 +114,41 @@ def test_checker_pins_stepprof_phase_table(tmp_path):
     bare.write_text(full.replace(mod.STEPPROF_BEGIN, "").replace(
         mod.STEPPROF_END, ""))
     assert mod.main(["check_metrics_docs.py", str(bare)]) == 1
+
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("begin,end,row,ghost", [
+    ("LOOP_PHASES_BEGIN", "LOOP_PHASES_END", "| `wait` | the condvar",
+     "| `notaloopphase` | bogus |\n"),
+    ("SPANS_BEGIN", "SPANS_END", "`mq.loop.wait`",
+     "| `mq.loop.bogus` | host span | bogus |\n"),
+])
+def test_checker_pins_loop_phase_and_span_tables(tmp_path, begin, end, row,
+                                                 ghost):
+    """PR 24: what the engine thread does between steps
+    (stepprof.LOOP_PHASES) and the `mq.*` span names emitted during a
+    device capture (stepprof.SPAN_NAMES) are pinned to their README
+    tables like PHASES: a missing row, a ghost row and stripped markers
+    each fail the gate."""
+    mod = _load()
+    from ollamamq_tpu.telemetry.stepprof import (LOOP_PHASES, PHASES,
+                                                 SPAN_NAMES)
+
+    assert set(LOOP_PHASES) == {"admit", "other", "wait"}
+    assert set(SPAN_NAMES) == ({"mq." + p for p in PHASES}
+                               | {"mq.loop." + p for p in LOOP_PHASES})
+    begin, end = getattr(mod, begin), getattr(mod, end)
+    with open(os.path.join(_REPO, "README.md"), encoding="utf-8") as f:
+        full = f.read()
+    assert full.count(row) == 1, "table row shape changed"
+    missing = tmp_path / "README_missing.md"
+    missing.write_text(full.replace(row, "gone", 1))
+    assert mod.main(["check_metrics_docs.py", str(missing)]) == 1
+    ghosted = tmp_path / "README_ghost.md"
+    ghosted.write_text(full.replace(end, ghost + end, 1))
+    assert mod.main(["check_metrics_docs.py", str(ghosted)]) == 1
+    bare = tmp_path / "README_bare.md"
+    bare.write_text(full.replace(begin, "").replace(end, ""))
+    assert mod.main(["check_metrics_docs.py", str(bare)]) == 1

@@ -14,6 +14,7 @@ import tempfile
 import threading
 import time
 
+import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
 from ollamamq_tpu.config import EngineConfig
@@ -212,6 +213,52 @@ def test_debug_requests_timeline_sums_streamed():
         for must in ("enqueue", "admit", "place", "prefill", "first_token"):
             assert must in names, names
         assert "decode" in tl["phases_ms"]
+
+    _serve(run)
+
+
+@pytest.mark.parametrize("path,body", [
+    ("/api/generate", {"prompt": "hello world",
+                       "options": {"num_predict": 4}}),
+    ("/api/chat", {"messages": [{"role": "user", "content": "hello"}],
+                   "options": {"num_predict": 4}}),
+    ("/v1/chat/completions", {"messages": [{"role": "user",
+                                            "content": "hello"}],
+                              "max_tokens": 4}),
+    ("/v1/completions", {"prompt": "hello world", "max_tokens": 4}),
+])
+def test_ingress_phase_opens_the_timeline_at_the_handler(path, body):
+    """PR 24: the generate/chat/OpenAI handlers time their own entry, so
+    a request's trace opens with `ingress` (JSON parse, templating,
+    tokenisation) followed by `enqueue`; the phases, ingress included,
+    still sum to an end-to-end that now starts at the handler, and the
+    phase reaches ollamamq_request_phase_ms."""
+    from ollamamq_tpu.telemetry import schema as tm
+
+    def observed():
+        return tm.REQUEST_PHASE_MS.labels(model="test-tiny",
+                                          phase="ingress").count
+
+    async def run(cl):
+        before = observed()
+        t0 = time.monotonic()
+        r = await cl.post(path, json={"model": "test-tiny", "stream": False,
+                                      **body},
+                          headers={"X-User-ID": "ingrid"})
+        wall_ms = (time.monotonic() - t0) * 1e3
+        assert r.status == 200, await r.text()
+        body_ = await (await cl.get("/debug/requests")).json()
+        row = next(rw for rw in body_["recent"] if rw["user"] == "ingrid")
+        tl = await (await cl.get(f"/debug/requests/{row['req_id']}")).json()
+        names = [e["name"] for e in tl["events"]]
+        assert names[:2] == ["ingress", "enqueue"], names
+        assert tl["events"][0]["t_ms"] == 0.0
+        assert tl["phases_ms"]["ingress"] > 0.0
+        assert tl["phases_ms"]["ingress"] == tl["events"][1]["t_ms"]
+        total = sum(tl["phases_ms"].values())
+        assert abs(total - tl["e2e_ms"]) <= max(0.05 * tl["e2e_ms"], 0.5), tl
+        assert tl["e2e_ms"] <= wall_ms   # the handler's, not the client's
+        assert observed() == before + 1
 
     _serve(run)
 

@@ -244,7 +244,9 @@ def test_profiler_survives_dispatch_faults_with_clean_journal():
 # ----------------------------------------------------------- self-overhead
 def test_self_overhead_stays_under_one_percent():
     """ACCEPTANCE: always-on means the profiler's own clock reads and
-    ring appends must cost < 1% of the step wall it measures."""
+    ring appends — since PR 24 also the loop clock's ticks and phase
+    switches of TPUEngine._loop_once — must cost < 1% of the
+    engine-thread time the samples account for."""
     eng = _tpu_engine()
     try:
         for u in ("o1", "o2"):
@@ -255,6 +257,140 @@ def test_self_overhead_stays_under_one_percent():
     frac = PROFILER.overhead_fraction()
     assert PROFILER.seq > 0
     assert 0.0 <= frac < 0.01, f"profiler overhead {frac:.4f} >= 1%"
+
+
+# ------------------------------------------------- gapless engine thread
+def _fake_engine(models=("test-tiny",), latency=0.002):
+    from ollamamq_tpu.engine.fake import FakeEngine
+
+    eng = FakeEngine(EngineConfig(model=models[0], max_slots=4,
+                                  num_pages=64, page_size=8,
+                                  max_pages_per_seq=8),
+                     models={m: None for m in models}, blocklist_path=None,
+                     token_latency_s=latency)
+    eng.start()
+    return eng
+
+
+def _accounted_ms(sample):
+    return sample["total_ms"] + sum(
+        sample["loop_" + ph + "_ms"] for ph in stepprof.LOOP_PHASES)
+
+
+def test_engine_thread_time_is_gapless_over_consecutive_samples():
+    """ACCEPTANCE (PR 24): over any run of consecutive samples of one
+    engine thread, sum(total_ms + loop_*_ms) is the wall time between
+    them to within 1 % — with idle ticks (condvar waits between bursts),
+    abandoned timers (a step that starts its timer and returns early),
+    two runtimes on one engine thread, and a second engine thread
+    recording into the same process-wide ring."""
+    a = _fake_engine(models=("test-tiny", "test-tiny-qwen"))
+    b = _fake_engine()
+    # Every other step of one runtime first opens a timer it abandons.
+    rt = a.runtimes["test-tiny"]
+    orig, n = rt.step, [0]
+
+    def step(core):
+        n[0] += 1
+        if n[0] % 2:
+            stepprof.PROFILER.start("fake", rt.loop_clock).mark("host_prep")
+            time.sleep(0.001)  # work done under the abandoned timer
+        return orig(core)
+
+    rt.step = step
+    try:
+        for burst in range(3):
+            reqs = [eng.enqueue_request(
+                f"u{burst}{i}", "", model,
+                prompt_tokens=[1, 2, 3],
+                sampling=SamplingParams(max_tokens=6))
+                for eng, model in ((a, "test-tiny"), (a, "test-tiny-qwen"),
+                                   (b, "test-tiny")) for i in range(2)]
+            for r in reqs:
+                assert collect(r)[-1].kind == "done"
+            time.sleep(0.12)  # idle ticks: several condvar waits
+    finally:
+        a.stop()
+        b.stop()
+    samples = PROFILER.tail()
+    assert n[0] >= 4, "the abandoning wrapper never ran"
+    by_thread = {}
+    for smp in samples:
+        assert all("loop_" + ph + "_ms" in smp
+                   for ph in stepprof.LOOP_PHASES), smp
+        assert abs(_phase_sum(smp) - smp["total_ms"]) < 0.01, smp
+        by_thread.setdefault(smp["thread"], []).append(smp)
+    assert set(by_thread) == {a.loop_clock.name, b.loop_clock.name}
+    assert a.loop_clock.name != b.loop_clock.name
+    for name, run in by_thread.items():
+        assert len(run) >= 10, (name, len(run))
+        wall_ms = (run[-1]["ts"] - run[0]["ts"]) * 1e3
+        accounted = sum(_accounted_ms(smp) for smp in run[1:])
+        assert abs(accounted - wall_ms) <= 0.01 * wall_ms, \
+            f"{name}: accounted {accounted:.3f} ms of {wall_ms:.3f} ms"
+        # ... and over any sub-run, not just the whole one.
+        mid = len(run) // 2
+        wall_ms = (run[-1]["ts"] - run[mid]["ts"]) * 1e3
+        accounted = sum(_accounted_ms(smp) for smp in run[mid + 1:])
+        assert abs(accounted - wall_ms) <= 0.01 * wall_ms + 0.05
+        # Idle ticks were waits, admission was seen, abandoned timers
+        # and the rest of the tick are `other`.
+        assert sum(smp["loop_wait_ms"] for smp in run) > 150.0
+        assert sum(smp["loop_admit_ms"] for smp in run) > 0.0
+        assert sum(smp["loop_other_ms"] for smp in run) > 0.0
+    # The abandoned timers' millisecond each went to `other`, not lost.
+    assert sum(smp["loop_other_ms"] for smp in by_thread[a.loop_clock.name]) \
+        >= 0.9 * (n[0] // 2)
+
+
+def test_loop_fields_on_every_sample_and_in_the_histogram():
+    """`loop_*_ms` are always present (0.0 when none): on an engine's
+    samples and on a step timed alone; the loop time reaches
+    ollamamq_step_phase_ms as phase="loop_*"."""
+    from ollamamq_tpu.telemetry import schema as tm
+
+    def count(phase):
+        return tm.STEP_PHASE_MS.labels(phase=phase, mode="fake").count
+
+    before = {ph: count("loop_" + ph) for ph in stepprof.LOOP_PHASES}
+    t = PROFILER.start("fake")  # no engine, no clock: a step alone
+    t.mark("dispatch")
+    alone = t.finish(tokens=1, padded_tokens=1, compiled=False)
+    assert [alone["loop_" + ph + "_ms"] for ph in stepprof.LOOP_PHASES] \
+        == [0.0, 0.0, 0.0]
+    assert alone["thread"] and alone["seq"] == 1
+    eng = _fake_engine()
+    try:
+        time.sleep(0.1)
+        assert collect(_run(eng, "u", max_tokens=4))[-1].kind == "done"
+    finally:
+        eng.stop()
+    mine = [smp for smp in PROFILER.tail()
+            if smp["thread"] == eng.loop_clock.name]
+    assert mine and mine[0]["loop_wait_ms"] > 50.0
+    assert mine[0]["T_pad"] == 0 and mine[0]["tokens"] > 0
+    for ph in stepprof.LOOP_PHASES:
+        assert count("loop_" + ph) > before[ph], ph
+
+
+def test_real_engine_loop_is_gapless_too():
+    """TPUEngine._loop_once carries the same marks: its samples account
+    for the engine thread's wall time (idle ticks abandon step_ragged's
+    timer every 50 ms — those fold into `other`)."""
+    eng = _tpu_engine()
+    try:
+        for u in ("g1", "g2"):
+            assert collect(_run(eng, u, max_tokens=6))[-1].kind == "done"
+            time.sleep(0.12)
+    finally:
+        eng.stop()
+    run = [smp for smp in PROFILER.tail()
+           if smp["thread"] == eng.loop_clock.name]
+    assert len(run) >= 4 and len(run) == len(PROFILER.tail())
+    wall_ms = (run[-1]["ts"] - run[0]["ts"]) * 1e3
+    accounted = sum(_accounted_ms(smp) for smp in run[1:])
+    assert abs(accounted - wall_ms) <= 0.01 * wall_ms, (accounted, wall_ms)
+    assert sum(smp["loop_wait_ms"] for smp in run) > 50.0
 
 
 # -------------------------------------------------------------- federation

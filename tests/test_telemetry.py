@@ -11,7 +11,10 @@ import asyncio
 import json
 import re
 import tempfile
+import time
 import unittest.mock
+
+import pytest
 
 from aiohttp.test_utils import TestClient, TestServer
 
@@ -342,8 +345,9 @@ def test_e2e_metrics_json_still_serves_legacy_payload():
 
 
 def test_e2e_fake_engine_trace_chain():
-    """A FakeEngine request's /debug/trace spans cover enqueue->complete
-    with monotonic timestamps and no gaps."""
+    """A FakeEngine request's /debug/trace spans cover handler entry
+    (ingress) -> enqueue -> complete with monotonic timestamps and no
+    gaps."""
     async def run(cl):
         r = await cl.post("/api/generate", json={
             "model": "test-tiny", "prompt": "hello", "stream": False,
@@ -362,7 +366,7 @@ def test_e2e_fake_engine_trace_chain():
         evs = [e for e in out["traceEvents"]
                if e.get("tid") == tid and e.get("ph") in ("X", "i")]
         names = [e["name"] for e in evs]
-        assert names[0] == "enqueue"
+        assert names[:2] == ["ingress", "enqueue"]
         assert names[-1] in ("stop", "length")
         for must in ("admit", "place", "prefill", "first_token"):
             assert must in names, f"span chain missing {must}: {names}"
@@ -376,6 +380,40 @@ def test_e2e_fake_engine_trace_chain():
                 prev_end = e["ts"] + e["dur"]
         # JSON round-trips (chrome://tracing loads it).
         json.dumps(out)
+
+    _serve(run)
+
+
+@pytest.mark.parametrize("path,body,frame", [
+    ("/api/generate", {"prompt": "hello", "options": {"num_predict": 6}},
+     b'"done"'),
+    ("/v1/chat/completions",
+     {"messages": [{"role": "user", "content": "hello"}], "max_tokens": 6},
+     b'"choices"'),
+])
+def test_stream_lag_observed_once_a_frame(path, body, frame):
+    """PR 24: a StreamItem carries the monotonic instant of its push, and
+    the NDJSON and SSE writers observe now - pushed_at into
+    ollamamq_stream_lag_ms when the frame has been written: the count
+    grows by exactly the frames streamed (SSE's `[DONE]` sentinel comes
+    from no item and is not counted), the sum by plausible lags."""
+    from ollamamq_tpu.telemetry import schema as tm
+
+    async def run(cl):
+        lag = tm.STREAM_LAG_MS.labels()
+        n0, s0 = lag.count, lag.sum
+        t0 = time.monotonic()
+        r = await cl.post(path, json={"model": "test-tiny", "stream": True,
+                                      **body})
+        assert r.status == 200
+        frames = [ln for ln in (await r.read()).split(b"\n")
+                  if frame in ln]
+        wall_ms = (time.monotonic() - t0) * 1e3
+        assert len(frames) >= 3
+        assert lag.count - n0 == len(frames)
+        assert 0.0 < lag.sum - s0 < wall_ms * len(frames)
+        text = await (await cl.get("/metrics")).text()
+        assert re.search(r"^ollamamq_stream_lag_ms_count \d+$", text, re.M)
 
     _serve(run)
 

@@ -1,0 +1,330 @@
+"""The engine thread's phases on the device trace's clock (PR 24).
+
+While a capture runs (`PROFILER.capturing`, set by POST /debug/profile)
+the marks that feed the step samples also open `mq.*` spans
+(jax.profiler.TraceAnnotation) on the engine thread, each carrying the
+`seq` of the sample its time is written into. On the device side the jit
+sites are named functions and jax.named_scopes name the stages inside
+the ragged and decode programs. Pinned here, on the CPU:
+
+  - a real start_trace around fake-engine steps yields every name of
+    stepprof.SPAN_NAMES on ONE host line, never nested in one another,
+    with `seq` stats that are recorded samples' seqs, and nothing once
+    the flag is cleared;
+  - POST /debug/profile replies with `capture` bounds and exactly the
+    samples that ended inside them, although the call lasts longer;
+    accepts `python_tracer: false`; clears the flag after a failure;
+  - every scope name is in the lowered text of the engine's ragged and
+    decode programs, whose module names are the stable jit names, and
+    README's span table lists them all.
+"""
+
+import asyncio
+import glob
+import os
+import re
+import time
+import unittest.mock
+
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from ollamamq_tpu.config import EngineConfig
+from ollamamq_tpu.ops.sampling import SamplingParams
+from ollamamq_tpu.telemetry import stepprof
+from ollamamq_tpu.telemetry.stepprof import PROFILER, SPAN_NAMES
+from testutil import collect
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _fresh_profiler():
+    PROFILER.reset()
+    PROFILER.capturing = False
+    yield
+    PROFILER.capturing = False
+    PROFILER.reset()
+
+
+def _fake_engine(latency=0.002):
+    from ollamamq_tpu.engine.fake import FakeEngine
+
+    eng = FakeEngine(EngineConfig(model="test-tiny", max_slots=4),
+                     models={"test-tiny": None}, blocklist_path=None,
+                     token_latency_s=latency)
+    eng.start()
+    return eng
+
+
+def _burst(eng, tag, n=2, max_tokens=5):
+    reqs = [eng.enqueue_request(f"{tag}{i}", "", "test-tiny",
+                                prompt_tokens=[1, 2, 3],
+                                sampling=SamplingParams(max_tokens=max_tokens))
+            for i in range(n)]
+    for r in reqs:
+        assert collect(r)[-1].kind == "done"
+
+
+def _mq_lines(profile_dir):
+    """{line name: [(span name, start_ns, end_ns, stats)]} for every host
+    line of the capture that holds an mq.* span."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            spans = [(e.name, int(e.start_ns),
+                      int(e.start_ns + e.duration_ns), dict(e.stats))
+                     for e in line.events if e.name.startswith("mq.")]
+            if spans:
+                out[line.name] = sorted(spans, key=lambda s: s[1])
+    return out
+
+
+def test_capture_puts_one_unnested_span_chain_on_the_engine_thread(tmp_path):
+    import jax
+
+    eng = _fake_engine()
+    try:
+        _burst(eng, "before")          # no capture: no spans
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            seq_set = PROFILER.seq
+            PROFILER.capturing = True
+            _burst(eng, "in")
+            time.sleep(0.08)           # idle ticks: mq.loop.wait
+            _burst(eng, "in2")
+            PROFILER.capturing = False
+            time.sleep(0.05)           # the span open at the clear closes
+            seq_cleared = PROFILER.seq
+            _burst(eng, "after", n=3)
+        finally:
+            PROFILER.capturing = False
+            jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    samples = {s["seq"]: s for s in PROFILER.tail()}
+    lines = _mq_lines(str(tmp_path))
+    assert len(lines) == 1, f"mq.* spans on {len(lines)} host lines"
+    (spans,) = lines.values()
+    assert {n for n, *_ in spans} == set(SPAN_NAMES)
+    prev_end = 0
+    for name, start, end, stats in spans:
+        assert start >= prev_end, f"{name} nested in / overlaps another mq.*"
+        prev_end = end
+        assert isinstance(stats.get("seq"), int), (name, stats)
+        if not name.startswith("mq.loop."):
+            assert stats["mode"] == "fake"
+    # Identity: a step span's seq is the seq of a recorded sample, and
+    # from mq.dispatch on it carries the shape the sample has.
+    dispatched = [(n, st) for n, _, _, st in spans if n == "mq.dispatch"]
+    assert len(dispatched) >= 6
+    for _, st in dispatched:
+        smp = samples[st["seq"]]
+        assert (st["T_pad"], st["k_cap"]) == (smp["T_pad"], smp["k_cap"])
+        assert "tokens" in st
+    # A loop span carries the seq of the sample its time is written to.
+    waits = [st["seq"] for n, _, _, st in spans if n == "mq.loop.wait"]
+    assert waits and all(samples[q]["loop_wait_ms"] > 0 for q in waits
+                         if q in samples)
+    # Nothing from before the flag was set or after it was cleared (the
+    # one seq reserved while capturing may still be recorded after).
+    span_seqs = {st["seq"] for *_, st in spans}
+    assert seq_set < min(span_seqs) and max(span_seqs) <= seq_cleared + 1
+    assert len([q for q in samples if q <= seq_set]) >= 3
+    assert len([q for q in samples if q > seq_cleared + 1]) >= 3
+
+
+# ------------------------------------------------------------- the endpoint
+def _serve(fn):
+    async def main():
+        from ollamamq_tpu.engine.fake import FakeEngine
+        from ollamamq_tpu.server.app import Server
+
+        eng = FakeEngine(EngineConfig(model="test-tiny", max_slots=8),
+                         models={"test-tiny": None}, blocklist_path=None,
+                         token_latency_s=0.002)
+        eng.start()
+        cl = TestClient(TestServer(Server(eng, timeout_s=30).build_app()))
+        await cl.start_server()
+        try:
+            await fn(cl)
+        finally:
+            await cl.close()
+            eng.stop()
+
+    asyncio.run(main())
+
+
+def test_debug_profile_reply_holds_the_captures_own_samples(
+        tmp_path, monkeypatch):
+    """The call outlasts the capture (stop_trace is slow — here made so)
+    while steps keep running: `stepprof` holds only samples that ended
+    between start_trace returning and stop_trace being called, `capture`
+    says which, and the cheap capture (`python_tracer: false`) passes
+    ProfileOptions with the Python tracer off."""
+    import jax
+
+    monkeypatch.setenv("OLLAMAMQ_PROFILE_DIR", str(tmp_path))
+    real_start, real_stop = jax.profiler.start_trace, jax.profiler.stop_trace
+    seen = {}
+
+    def start(log_dir, **kw):
+        seen["options"] = kw.get("profiler_options")
+        real_start(log_dir, **kw)
+
+    def slow_stop():
+        seen["capturing_at_stop"] = PROFILER.capturing
+        time.sleep(0.4)  # steps go on while the trace is written
+        real_stop()
+
+    async def traffic(cl, stop):
+        while not stop.is_set():
+            r = await cl.post("/api/generate", json={
+                "model": "test-tiny", "prompt": "hi", "stream": False,
+                "options": {"num_predict": 4}})
+            assert r.status == 200
+
+    async def run(cl):
+        stop = asyncio.Event()
+        load = asyncio.ensure_future(traffic(cl, stop))
+        await asyncio.sleep(0.1)
+        with unittest.mock.patch.object(jax.profiler, "start_trace", start), \
+                unittest.mock.patch.object(jax.profiler, "stop_trace",
+                                           slow_stop):
+            t_call = time.time()
+            r = await cl.post("/debug/profile", json={
+                "seconds": 0.3, "python_tracer": False})
+            t_reply = time.time()
+        stop.set()
+        await load
+        assert r.status == 200, await r.text()
+        out = await r.json()
+        cap = out["capture"]
+        assert out["python_tracer"] is False
+        assert seen["options"].python_tracer_level == 0
+        assert seen["capturing_at_stop"] is False and not PROFILER.capturing
+        assert t_call <= cap["start_epoch"] < cap["stop_epoch"] <= t_reply
+        assert 0.3 <= cap["stop_epoch"] - cap["start_epoch"] < 0.4
+        assert t_reply - cap["stop_epoch"] >= 0.4   # the call lasted longer
+        got = out["stepprof"]
+        assert len(got) >= 10
+        assert all(cap["start_epoch"] <= s["ts"] <= cap["stop_epoch"]
+                   for s in got)
+        seqs = [s["seq"] for s in got]
+        assert (cap["first_seq"], cap["last_seq"]) == (min(seqs), max(seqs))
+        # Steps that ended while stop_trace ran are in the ring, not here.
+        assert any(cap["stop_epoch"] < s["ts"] <= t_reply
+                   for s in PROFILER.tail())
+        # The spans reached the trace, python tracer or not.
+        (spans,) = _mq_lines(str(tmp_path)).values()
+        assert {st["seq"] for n, _, _, st in spans
+                if n == "mq.dispatch"} <= set(seqs) | {max(seqs) + 1}
+        # Default: no options at all — the capture as it always was.
+        with unittest.mock.patch.object(jax.profiler, "start_trace", start):
+            r = await cl.post("/debug/profile", json={"seconds": 0.1})
+        assert r.status == 200 and (await r.json())["python_tracer"] is True
+        assert seen["options"] is None
+        r = await cl.post("/debug/profile", json={"python_tracer": "no"})
+        assert r.status == 400
+
+    _serve(run)
+
+
+def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
+        tmp_path, monkeypatch):
+    import jax
+
+    monkeypatch.setenv("OLLAMAMQ_PROFILE_DIR", str(tmp_path))
+
+    async def run(cl):
+        with unittest.mock.patch.object(jax.profiler, "start_trace"), \
+                unittest.mock.patch.object(
+                    jax.profiler, "stop_trace",
+                    side_effect=RuntimeError("disk full")):
+            r = await cl.post("/debug/profile", json={"seconds": 0.1})
+        assert r.status == 500
+        assert PROFILER.capturing is False
+        r = await cl.post("/debug/profile", json={"seconds": 0.1})
+        assert r.status == 200, "the next capture must get a fresh try"
+        assert (await r.json())["capture"]["first_seq"] is None
+
+    _serve(run)
+
+
+# ------------------------------------------- names on the device-side programs
+def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs():
+    """Scopes change op metadata only; every name of llama.SCOPES is in
+    the debug text of the engine's OWN ragged and decode programs, the
+    modules are named after the stable jit functions, and README lists
+    every one of these names in its span table."""
+    import jax
+    import jax.numpy as jnp
+
+    from ollamamq_tpu.engine.engine import TPUEngine
+    from ollamamq_tpu.models import llama
+
+    eng = TPUEngine(EngineConfig(model="test-tiny", max_slots=2, num_pages=64,
+                                 page_size=8, max_pages_per_seq=16,
+                                 prefill_buckets=(16, 32, 64),
+                                 decode_steps_per_iter=2),
+                    models={"test-tiny": None}, blocklist_path=None,
+                    dtype=jnp.float32)
+    rt = eng.runtimes["test-tiny"]
+    seen = {}
+
+    def spy(site, getter):
+        def get(*key):
+            fn = getter(*key)
+
+            def call(*args):
+                seen[site] = (key, jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+                return fn(*args)
+            return call
+        return get
+
+    rt._get_ragged_jit = spy("ragged", rt._get_ragged_jit)
+    rt._get_decode_jit = spy("decode", rt._get_decode_jit)
+    eng.start()
+    try:
+        tok = rt.tokenizer
+        req = eng.enqueue_request(
+            "u", "", "test-tiny", prompt_tokens=tok.encode("count to ten"),
+            sampling=SamplingParams(max_tokens=8))
+        assert collect(req)[-1].kind == "done"
+    finally:
+        eng.stop()
+    assert set(seen) == {"ragged", "decode"}, seen.keys()
+    names = {"ragged": "mq_ragged_step", "decode": "mq_decode_scan"}
+    for site, (key, abstract) in seen.items():
+        cache = rt._prefill_jits if site == "ragged" else rt._decode_jits
+        (jitted,) = [f for k, f in cache.items()
+                     if (k[1:] if site == "ragged" else k) == key]
+        text = jitted.lower(*abstract).as_text(debug_info=True)
+        assert re.search(r"module @jit_%s\b" % names[site], text), \
+            text[:200]
+        for scope in llama.SCOPES:
+            # A whole component of an op's name stack ("embed/gather"),
+            # not a parameter name or a file path that contains the word.
+            assert re.search(r'loc\("(?:[^"/]+/)*%s(?:/[^"]+)?"' % scope,
+                             text), \
+                f"scope {scope!r} not in the lowered {site} program"
+    with open(os.path.join(_REPO, "README.md"), encoding="utf-8") as f:
+        readme = f.read()
+    table = readme[readme.index("<!-- stepprof-spans:begin -->"):
+                   readme.index("<!-- stepprof-spans:end -->")]
+    documented = set(re.findall(r"`([a-z_.]+)`", table))
+    jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_prefill",
+                 "mq_prefill_chunk", "mq_prefill_sp", "mq_embed", "mq_encode"}
+    assert set(llama.SCOPES) | jit_names | set(SPAN_NAMES) <= documented
+    # ... and the seven jit sites really are those functions.
+    with open(os.path.join(_REPO, "ollamamq_tpu", "engine",
+                           "engine.py"), encoding="utf-8") as f:
+        src = f.read()
+    assert set(re.findall(r"jax\.jit\((\w+)", src)) == jit_names

@@ -391,8 +391,8 @@ from ollamamq_tpu.telemetry import stepprof
 stepprof.PROFILER.reset()
 tmr = stepprof.PROFILER.start("decode")
 tmr.mark("dispatch")
-tmr.phases["dispatch"] = 12.34     # pin the rendered p99 exactly
-tmr._last = tmr._t0 + 0.01234
+tmr.phases["dispatch"] = 12.34     # pin the rendered p99 exactly (a
+#                                     step's total is its phases' sum)
 tmr.finish(T_pad=0, k_cap=2, n_prefill=0, n_decode=1, tokens=2,
            padded_tokens=4, compiled=True)
 stepprof.PROFILER.record_compile("decode", "(2,)", 100.0, 1)
