@@ -614,7 +614,10 @@ def kernels_child(rehearse: bool) -> int:
     def normal(shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
 
-    kc, vc = normal((NP * ps, Hk, hd)), normal((NP * ps, Hk, hd))
+    # A whole two-layer pool in its stored layout, [L, S, Hk*hd]; the
+    # kernels read layer LAYER of it by index, as the forwards do.
+    LAYER = 1
+    kc, vc = normal((2, NP * ps, Hk * hd)), normal((2, NP * ps, Hk * hd))
     next_page = [1]  # page 0 is the allocator's trash page
 
     def pages_for(kv_len: int) -> np.ndarray:
@@ -650,7 +653,8 @@ def kernels_child(rehearse: bool) -> int:
 
     def ragged(impl):
         return jax.jit(lambda q, kc, vc, *m: ragged_attention_any(
-            impl, q, kc, vc, *m, ps, interpret=rehearse))(q, kc, vc, *meta)
+            impl, q, kc, vc, LAYER, *m, ps, interpret=rehearse))(
+                q, kc, vc, *meta)
 
     rtol = atol = 2e-2  # bf16 outputs: 8 significant bits
 
@@ -674,13 +678,13 @@ def kernels_child(rehearse: bool) -> int:
     qd, sl = normal((B, H, hd)), jnp.asarray(seq_lens)
     t0 = time.monotonic()
     outd = jax.jit(lambda q, kc, vc, pt, sl: paged_decode_attention_any(
-        "pallas", q, kc, vc, pt, sl, ps, interpret=rehearse))(
+        "pallas", q, kc, vc, LAYER, pt, sl, ps, interpret=rehearse))(
             qd, kc, vc, ptd, sl).block_until_ready()
     decode_s = time.monotonic() - t0
     # The materializing jnp decode reference gathers every sequence's
     # whole page-table width; its blockwise twin is the same softmax.
     refd = jax.jit(lambda q, kc, vc, pt, sl: paged_chunk_attention_blockwise(
-        q[:, None], kc, vc, pt, sl - 1, jnp.ones_like(sl), ps)[:, 0])(
+        q[:, None], kc, vc, LAYER, pt, sl - 1, jnp.ones_like(sl), ps)[:, 0])(
             qd, kc, vc, ptd, sl)
     res_d = closeness(outd, refd, np.ones((B,), bool))
 

@@ -56,8 +56,7 @@ from ollamamq_tpu.ops.sampling import (accept_prefix, maybe_apply_penalties,
 from ollamamq_tpu.parallel import pipeline
 from ollamamq_tpu.parallel.mesh import (make_mesh, replica_submesh,
                                         validate_tp_for_model)
-from ollamamq_tpu.parallel.sharding import (kv_cache_spec, kv_scale_spec,
-                                            shard_params)
+from ollamamq_tpu.parallel.sharding import kv_cache_spec, shard_params
 from ollamamq_tpu.telemetry import mfu as mfu_model
 from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.telemetry import stepprof
@@ -550,18 +549,16 @@ class ModelRuntime:
             self.cfg = model_cfg
             log.info("replicated KV heads x%d for tp=%d (%s)", r, tp_axis,
                      name)
-        kv_sharding = scale_sharding = None
+        kv_sharding = None
         if mesh is not None:
             from jax.sharding import NamedSharding
 
             params = shard_params(params, mesh, pp=self._pp > 1)
             kv_sharding = NamedSharding(mesh, kv_cache_spec(pp=self._pp > 1))
-            scale_sharding = NamedSharding(
-                mesh, kv_scale_spec(pp=self._pp > 1))
         self.params = params
         self.kc, self.vc = kvc.alloc_kv_pool(
             model_cfg, engine_cfg, kv_sharding, dtype,
-            kv_dtype=engine_cfg.kv_dtype, scale_sharding=scale_sharding)
+            kv_dtype=engine_cfg.kv_dtype)
         # Repeat-penalty state: ring of each slot's last-W context token ids
         # (-1 = empty), llama.cpp repeat_last_n semantics. Row S is a trash
         # row so padded/inactive scatter targets never touch a live slot.
@@ -1195,14 +1192,18 @@ class ModelRuntime:
                     params, cfg, tokens, seq_lens, mesh
                 )
                 # Scatter K/V (k_stack: [L, 1, T, Hk, hd]) into the paged
-                # pool; positions past the real length land in the trash
+                # pool ([L, S, Hk*hd]: the rows take the pool's row
+                # shape); positions past the real length land in the trash
                 # page (pt rows beyond the allocation already hold it).
                 t = jnp.arange(T)
                 page_idx = pt[0, t // ps]
                 page_idx = jnp.where(t < seq_lens[0], page_idx, kvc.TRASH_PAGE)
                 dest = page_idx * ps + (t % ps)
-                kc = kc.at[:, dest].set(k_stack[:, 0].astype(kc.dtype))
-                vc = vc.at[:, dest].set(v_stack[:, 0].astype(vc.dtype))
+                L = kc.shape[0]
+                kc = kc.at[:, dest].set(
+                    k_stack[:, 0].reshape(L, T, -1).astype(kc.dtype))
+                vc = vc.at[:, dest].set(
+                    v_stack[:, 0].reshape(L, T, -1).astype(vc.dtype))
                 # First-token sampling + recent ring, as in batched prefill.
                 W = recent.shape[1]
                 idx = seq_lens[:, None] - W + jnp.arange(W)[None, :]
@@ -1843,7 +1844,7 @@ class ModelRuntime:
         request state, and the scheduler predictor's view of the user."""
         pages = list(self.slot_pages[slot])
         data = kvc.gather_page_run(self.kc, self.vc, pages,
-                                   self.ecfg.page_size)
+                                   self.ecfg.page_size, self.cfg.head_dim)
         blob = {
             "version": 1, "kind": "stream", "model": self.name,
             "kv_dtype": self.kv_dtype, "page_size": self.ecfg.page_size,
@@ -1940,7 +1941,8 @@ class ModelRuntime:
         pc.pin(nodes)
         try:
             data = kvc.gather_page_run(self.kc, self.vc, pages,
-                                       self.ecfg.page_size)
+                                       self.ecfg.page_size,
+                                       self.cfg.head_dim)
         finally:
             pc.release(nodes)
         ps = self.ecfg.page_size

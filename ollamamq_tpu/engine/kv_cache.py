@@ -1,9 +1,15 @@
 """Paged KV cache: device slot pool + host-side page allocator.
 
 Device side: two arrays per model, [num_layers, num_pages*page_size,
-kv_heads, head_dim] for K and V, kv-heads sharded over the "tensor" mesh
-axis. The pool is allocated ONCE at engine start (static shape => no
-recompiles, no fragmentation in HBM).
+kv_heads*head_dim] for K and V (an int8 pool adds [num_layers, slots,
+kv_heads] scale planes), the last axis split by kv head over the "tensor"
+mesh axis. That is the layout the attention kernels DMA pages from
+(ops/pallas/): a page of layer l is `pool[l, page*page_size : (page+1)*
+page_size]`, [page_size, Hk*hd] rows, and nothing ever reshapes the pool.
+The pool is allocated ONCE at engine start (static shape => no
+recompiles, no fragmentation in HBM), donated to every step program, and
+carried through its layer loop (models/llama.py:scan_layers), so a step
+updates it in place: there is one copy of it on the device.
 
 Host side: a free-list allocator of page indices. Page 0 is RESERVED as the
 trash page: page-table rows are padded with it so static-shaped prefill
@@ -139,39 +145,34 @@ def alloc_kv_pool(
     sharding=None,
     dtype=jnp.bfloat16,
     kv_dtype: str = "bfloat16",
-    scale_sharding=None,
 ):
-    """Allocate the device K/V slot pools (zeros). Returns (k_cache,
-    v_cache) — plain arrays, or QuantKV pairs when kv_dtype="int8": an
-    int8 payload pool plus fp32 per-slot per-head scale rows stored
+    """Allocate the device K/V slot pools (zeros), [L, S, Hk*hd] — the
+    stored layout IS the kernels' DMA layout. Returns (k_cache, v_cache)
+    — plain arrays, or QuantKV pairs when kv_dtype="int8": an int8
+    payload pool plus fp32 per-slot per-head scale rows [L, S, Hk] stored
     page-aligned alongside it (slot = page * page_size + offset), so the
     page allocator, prefix tree, preemption, and rollback machinery are
-    untouched while every page shrinks ~2x."""
+    untouched while every page shrinks ~2x. `sharding`
+    (parallel/sharding.kv_cache_spec) places payload and scales alike:
+    both split their last axis by kv head."""
     from ollamamq_tpu.ops.quant import QuantKV
 
     S = engine_cfg.num_pages * engine_cfg.page_size
-    shape = (model_cfg.num_layers, S, model_cfg.num_kv_heads,
-             model_cfg.head_dim)
+    shape = (model_cfg.num_layers, S,
+             model_cfg.num_kv_heads * model_cfg.head_dim)
 
-    def zeros(shp, dt, shard):
-        if shard is not None:
-            return jax.jit(lambda: jnp.zeros(shp, dt), out_shardings=shard)()
-        return jnp.zeros(shp, dt)
+    def filled(value, shp, dt):
+        if sharding is not None:
+            return jax.jit(lambda: jnp.full(shp, value, dt),
+                           out_shardings=sharding)()
+        return jnp.full(shp, value, dt)
 
     if kv_dtype == "int8":
-        sshape = shape[:-1]  # [L, S, Hk] scale rows
-        k = QuantKV(zeros(shape, jnp.int8, sharding),
-                    jnp.ones(sshape, jnp.float32) if scale_sharding is None
-                    else jax.jit(lambda: jnp.ones(sshape, jnp.float32),
-                                 out_shardings=scale_sharding)())
-        v = QuantKV(zeros(shape, jnp.int8, sharding),
-                    jnp.ones(sshape, jnp.float32) if scale_sharding is None
-                    else jax.jit(lambda: jnp.ones(sshape, jnp.float32),
-                                 out_shardings=scale_sharding)())
-        return k, v
-    k = zeros(shape, dtype, sharding)
-    v = zeros(shape, dtype, sharding)
-    return k, v
+        sshape = shape[:2] + (model_cfg.num_kv_heads,)  # [L, S, Hk]
+        return tuple(QuantKV(filled(0, shape, jnp.int8),
+                             filled(1, sshape, jnp.float32))
+                     for _ in range(2))
+    return filled(0, shape, dtype), filled(0, shape, dtype)
 
 
 def kv_pool_bytes(model_cfg: ModelConfig, engine_cfg: EngineConfig,
@@ -210,43 +211,51 @@ def _page_index(pages: List[int], page_size: int) -> np.ndarray:
     return idx
 
 
-def gather_page_run(kc, vc, pages: List[int], page_size: int) -> dict:
+def gather_page_run(kc, vc, pages: List[int], page_size: int,
+                    head_dim: int) -> dict:
     """Copy a page run's K/V data to host numpy arrays. Returns
-    {"k_pages", "v_pages"} shaped [n_pages*page_size, ...] sliced along
-    the pool's slot axis (axis 1), plus {"k_scale", "v_scale"} for
-    quantized pools. Read-only with respect to the pool."""
+    {"k_pages", "v_pages"} in the blob's wire format, [L, n_pages*
+    page_size, Hk, hd] (the pool's rows viewed per head HERE, at the
+    boundary — the pool itself stays [L, S, Hk*hd]), plus {"k_scale",
+    "v_scale"} [L, n, Hk] for quantized pools. Read-only with respect to
+    the pool."""
     from ollamamq_tpu.ops.quant import QuantKV
 
     idx = jnp.asarray(_page_index(pages, page_size))
+
+    def rows(pool):  # [L, n, Hk*hd] -> wire [L, n, Hk, hd]
+        r = np.asarray(jnp.take(pool, idx, axis=1))
+        return r.reshape(r.shape[:2] + (-1, head_dim))
+
     if isinstance(kc, QuantKV):
         return {
-            "k_pages": np.asarray(jnp.take(kc.q, idx, axis=1)),
-            "v_pages": np.asarray(jnp.take(vc.q, idx, axis=1)),
+            "k_pages": rows(kc.q),
+            "v_pages": rows(vc.q),
             "k_scale": np.asarray(jnp.take(kc.s, idx, axis=1)),
             "v_scale": np.asarray(jnp.take(vc.s, idx, axis=1)),
         }
-    return {
-        "k_pages": np.asarray(jnp.take(kc, idx, axis=1)),
-        "v_pages": np.asarray(jnp.take(vc, idx, axis=1)),
-    }
+    return {"k_pages": rows(kc), "v_pages": rows(vc)}
 
 
 def scatter_page_run(kc, vc, pages: List[int], page_size: int, data: dict):
-    """Write a gathered page run back into a (possibly different) pool at
-    `pages`. Returns the updated (kc, vc) — functional update, caller
-    reassigns."""
+    """Write a gathered page run (wire format, see gather_page_run) back
+    into a (possibly different) pool at `pages`. Returns the updated
+    (kc, vc) — functional update, caller reassigns."""
     from ollamamq_tpu.ops.quant import QuantKV
 
     idx = jnp.asarray(_page_index(pages, page_size))
+
+    def put(pool, rows):  # wire [L, n, Hk, hd] -> the pool's [L, n, Hk*hd]
+        rows = jnp.asarray(rows, dtype=pool.dtype)
+        return pool.at[:, idx].set(rows.reshape(rows.shape[:2] + (-1,)))
+
     if isinstance(kc, QuantKV):
-        k = QuantKV(kc.q.at[:, idx].set(jnp.asarray(data["k_pages"])),
+        k = QuantKV(put(kc.q, data["k_pages"]),
                     kc.s.at[:, idx].set(jnp.asarray(data["k_scale"])))
-        v = QuantKV(vc.q.at[:, idx].set(jnp.asarray(data["v_pages"])),
+        v = QuantKV(put(vc.q, data["v_pages"]),
                     vc.s.at[:, idx].set(jnp.asarray(data["v_scale"])))
         return k, v
-    k = kc.at[:, idx].set(jnp.asarray(data["k_pages"], dtype=kc.dtype))
-    v = vc.at[:, idx].set(jnp.asarray(data["v_pages"], dtype=vc.dtype))
-    return k, v
+    return put(kc, data["k_pages"]), put(vc, data["v_pages"])
 
 
 def migration_blob_bytes(blob: dict) -> int:

@@ -3,7 +3,11 @@
 Design notes (TPU-first):
   - Layer parameters are STACKED along a leading `num_layers` axis and the
     forward is a `lax.scan` over layers — one traced layer body, fast XLA
-    compile, and the KV cache ([L, S, Hk, hd]) scans naturally alongside.
+    compile. The KV pool ([L, S, Hk*hd], the layout the kernels DMA from)
+    is the scan's CARRY, never its xs/ys (`scan_layers`): each layer
+    scatters the step's rows into `pool[l]` in place and attention reads
+    the whole pool by layer index, so a step moves the rows it writes and
+    the pages it reads — not the pool.
   - Two entry points: `forward_prefill` (padded bucket, causal attention,
     writes the prompt's K/V into paged slots) and `forward_decode` (one
     token per slot, paged attention over the slot pool). Both are shape-
@@ -146,6 +150,32 @@ def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return logits_head(x, head)
 
 
+def scan_layers(body, x, layers, k_cache, v_cache):
+    """The ONE layer loop of every forward that touches the KV pool
+    (prefill, chunk, ragged and decode here, and the pipeline stage of
+    parallel/pipeline.py).
+
+    The pool is the loop's CARRY: `body(x, lp, l, k_cache, v_cache) ->
+    (x, k_cache, v_cache)` gets the layer index `l`, writes with one
+    scatter on the carried pool (ops/quant.kv_write) and attends over
+    `pool[l]` by index, and the loop returns the buffers it was given —
+    with the jit sites' donation, XLA updates the pool in place. A pool
+    passed as a scan's xs and returned as its ys cannot alias: every pass
+    would build a second pool and copy each layer out and back.
+    """
+    n_layers = k_cache.shape[0]
+
+    def step(carry, per_layer):
+        x, kc, vc = carry
+        lp, l = per_layer
+        return body(x, lp, l, kc, vc), None
+
+    (x, k_cache, v_cache), _ = jax.lax.scan(
+        step, (x, k_cache, v_cache),
+        (layers, jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, k_cache, v_cache
+
+
 def _layer_step(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
                 positions: jnp.ndarray, attn_fn, valid=None):
     """One transformer layer over a full [B, T, D] sequence.
@@ -154,8 +184,8 @@ def _layer_step(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     forward (prefill, sequence-parallel prefill, encoder) — only the
     attention schedule differs, injected as `attn_fn(q, k, v) -> [B,T,H,hd]`.
     Returns (x', k, v) so callers can scatter K/V into the paged cache.
-    (forward_decode keeps its own body: it must write K/V into the scan-
-    carried cache BEFORE attending.)
+    (forward_decode keeps its own body: it must write K/V into the
+    loop-carried pool BEFORE attending.)
     """
     B, T, _ = x.shape
     with jax.named_scope("attn_qkv"):
@@ -178,7 +208,7 @@ def forward_prefill(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B, T] int32, right-padded
     seq_lens: jnp.ndarray,  # [B] valid lengths
-    k_cache: jnp.ndarray,  # [L, S, Hk, hd] flat slot pool (donated)
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] flat slot pool (donated; loop carry)
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]; padding rows point at trash page
     page_size: int,
@@ -193,21 +223,16 @@ def forward_prefill(
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, T]
 
-    def body(carry, per_layer):
-        x = carry
-        lp, kc, vc = per_layer
+    def body(x, lp, l, kc, vc):
         x, k, v = _layer_step(
             cfg, lp, x, positions,
             lambda q, k, v: causal_attention(q, k, v, seq_lens),
             valid=positions < seq_lens[:, None],
         )
-        kc = kv_write(kc, slots, k)
-        vc = kv_write(vc, slots, v)
-        return x, (kc, vc)
+        return x, kv_write(kc, l, slots, k), kv_write(vc, l, slots, v)
 
-    x, (k_cache, v_cache) = jax.lax.scan(
-        body, x, (params["layers"], k_cache, v_cache)
-    )
+    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
+                                      v_cache)
     last = jnp.clip(seq_lens - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,D]
     logits = _logits(params, cfg, x_last)[:, 0, :]  # [B, V]
@@ -220,7 +245,7 @@ def forward_prefill_chunk(
     tokens: jnp.ndarray,  # [B, C] one chunk of the prompt, right-padded
     start: jnp.ndarray,  # [B] global position of the chunk's first token
     chunk_lens: jnp.ndarray,  # [B] valid tokens in this chunk
-    k_cache: jnp.ndarray,  # [L, S, Hk, hd] (donated)
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (donated; loop carry)
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages] — covers prefix AND chunk
     page_size: int,
@@ -238,29 +263,25 @@ def forward_prefill_chunk(
     )
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, C]
 
-    def body(carry, per_layer):
-        x = carry
-        lp, kc, vc = per_layer
-
+    def body(x, lp, l, kc, vc):
         def attn_fn(q, k, v):
             nonlocal kc, vc
-            kc = kv_write(kc, slots, k)
-            vc = kv_write(vc, slots, v)
+            kc = kv_write(kc, l, slots, k)
+            vc = kv_write(vc, l, slots, v)
             # Block-wise online-softmax walk over real pages only — HBM
             # reads scale with the actual prefix length, not max context.
             return paged_chunk_attention_blockwise(
-                q, kc, vc, page_table, start, chunk_lens, page_size
+                q, kc, vc, l, page_table, start, chunk_lens, page_size
             )
 
         x, _, _ = _layer_step(
             cfg, lp, x, positions, attn_fn,
             valid=jnp.arange(tokens.shape[1])[None, :] < chunk_lens[:, None],
         )
-        return x, (kc, vc)
+        return x, kc, vc
 
-    x, (k_cache, v_cache) = jax.lax.scan(
-        body, x, (params["layers"], k_cache, v_cache)
-    )
+    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
+                                      v_cache)
     last = jnp.clip(chunk_lens - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
     logits = _logits(params, cfg, x_last)[:, 0, :]
@@ -275,7 +296,7 @@ def forward_ragged(
     tok_pos: jnp.ndarray,  # [T] int32 kv position per token (-1 = pad)
     write_slots: jnp.ndarray,  # [T] int32 flat cache slot per token
     out_idx: jnp.ndarray,  # [B] or [B, O] int32 stream indices to read logits at
-    k_cache: jnp.ndarray,  # [L, S, Hk, hd] (donated)
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (donated; loop carry)
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]
     q_start: jnp.ndarray,  # [B] span offset per sequence
@@ -306,29 +327,25 @@ def forward_ragged(
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
 
-    def body(carry, per_layer):
-        x = carry
-        lp, kc, vc = per_layer
-
+    def body(x, lp, l, kc, vc):
         def attn_fn(q, k, v):  # [1, T, H, hd]
             nonlocal kc, vc
             with jax.named_scope("kv_write"):
-                kc = kv_write(kc, write_slots, k[0])
-                vc = kv_write(vc, write_slots, v[0])
+                kc = kv_write(kc, l, write_slots, k[0])
+                vc = kv_write(vc, l, write_slots, v[0])
             with jax.named_scope("attention"):
                 out = ragged_attention_any(
-                    attn_impl, q[0], kc, vc, page_table, tok_seq, tok_pos,
-                    kv_len, q_start, q_len, page_size, interpret=interpret,
-                    mesh=mesh,
+                    attn_impl, q[0], kc, vc, l, page_table, tok_seq,
+                    tok_pos, kv_len, q_start, q_len, page_size,
+                    interpret=interpret, mesh=mesh,
                 )
             return out[None]
 
         x, _, _ = _layer_step(cfg, lp, x, positions, attn_fn, valid=valid)
-        return x, (kc, vc)
+        return x, kc, vc
 
-    x, (k_cache, v_cache) = jax.lax.scan(
-        body, x, (params["layers"], k_cache, v_cache)
-    )
+    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
+                                      v_cache)
     if out_idx.ndim == 1:
         x_last = x[0][out_idx]  # [B, D]
         logits = _logits(params, cfg, x_last[None])[0]  # [B, V]
@@ -343,7 +360,7 @@ def forward_decode(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B] int32 — last generated token per slot
     positions: jnp.ndarray,  # [B] int32 — position of `tokens` in each seq
-    k_cache: jnp.ndarray,  # [L, S, Hk, hd] (donated)
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (donated; loop carry)
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]
     page_size: int,
@@ -365,21 +382,19 @@ def forward_decode(
     write_slots = flat_slot_indices(page_table, pos2, page_size)[:, 0]  # [B]
     seq_lens = positions + 1
 
-    def body(carry, per_layer):
-        x = carry
-        lp, kc, vc = per_layer
+    def body(x, lp, l, kc, vc):
         with jax.named_scope("attn_qkv"):
             h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
             q, k, v = _qkv(cfg, lp, h)  # [B,1,H,hd]
             q = apply_rope(q, pos2, cfg.rope_theta)
             k = apply_rope(k, pos2, cfg.rope_theta)
         with jax.named_scope("kv_write"):
-            kc = kv_write(kc, write_slots, k[:, 0])
-            vc = kv_write(vc, write_slots, v[:, 0])
+            kc = kv_write(kc, l, write_slots, k[:, 0])
+            vc = kv_write(vc, l, write_slots, v[:, 0])
         with jax.named_scope("attention"):
             attn = paged_decode_attention_any(
-                attn_impl, q[:, 0], kc, vc, page_table, seq_lens, page_size,
-                mesh=mesh,
+                attn_impl, q[:, 0], kc, vc, l, page_table, seq_lens,
+                page_size, mesh=mesh,
             )  # [B,H,hd]
         with jax.named_scope("attn_out"):
             x = x + qeinsum("be,ed->bd", attn.reshape(B, cfg.q_dim),
@@ -387,11 +402,10 @@ def forward_decode(
         with jax.named_scope("mlp"):
             h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _ffn(cfg, lp, h2, valid=valid)
-        return x, (kc, vc)
+        return x, kc, vc
 
-    x, (k_cache, v_cache) = jax.lax.scan(
-        body, x, (params["layers"], k_cache, v_cache)
-    )
+    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
+                                      v_cache)
     logits = _logits(params, cfg, x)[:, 0, :]
     return logits, k_cache, v_cache
 

@@ -8,10 +8,16 @@ ragged-paged-attention kernel (ollamamq_tpu/ops/pallas) is the fast path
 and must match these numerically.
 
 KV cache layout (flat token-slot pool, page-aligned):
-    k_cache, v_cache: [num_layers, num_pages * page_size, kv_heads, head_dim]
+    k_cache, v_cache: [num_layers, num_pages * page_size, kv_heads * head_dim]
+    (an int8 pool's scale planes: [num_layers, slots, kv_heads])
 A "page" is page_size contiguous slots; the host-side allocator
 (engine/kv_cache.py) hands out page indices, and `flat_slot_indices`
-translates (page_table, position) -> slot index.
+translates (page_table, position) -> slot index. Every attention below
+takes the WHOLE pool and a layer index: the pool rides the forwards'
+layer loop as its carry (models/llama.py:scan_layers), the jnp paths
+gather `pool[layer, slots]` and view only the gathered rows per head, and
+the Pallas kernels DMA pages out of `pool[layer]` by index. Nothing here
+slices a layer out of the pool or re-lays it out.
 """
 
 from __future__ import annotations
@@ -88,8 +94,9 @@ def flat_slot_indices(
 
 def paged_chunk_attention(
     q: jnp.ndarray,  # [B, C, H, hd] — a chunk of new tokens per sequence
-    k_cache: jnp.ndarray,  # [S, Hk, hd] flat slot pool for ONE layer
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
     v_cache: jnp.ndarray,
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     start: jnp.ndarray,  # [B] global position of the chunk's first token
     chunk_lens: jnp.ndarray,  # [B] valid tokens in this chunk (<= C)
@@ -104,8 +111,8 @@ def paged_chunk_attention(
     L = max_pages * page_size
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, L]
-    k = kv_gather(k_cache, slots)  # [B, L, Hk, hd] (int8 pools dequantize)
-    v = kv_gather(v_cache, slots)
+    k = kv_gather(k_cache, layer, slots, hd)  # [B, L, Hk, hd] (int8 -> f32)
+    v = kv_gather(v_cache, layer, slots, hd)
     n_rep = H // k.shape[2]
     k = repeat_kv(k, n_rep)
     v = repeat_kv(v, n_rep)
@@ -125,8 +132,9 @@ def paged_chunk_attention(
 
 def paged_chunk_attention_blockwise(
     q: jnp.ndarray,  # [B, C, H, hd] — a chunk of new tokens per sequence
-    k_cache: jnp.ndarray,  # [S, Hk, hd] flat slot pool for ONE layer
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
     v_cache: jnp.ndarray,
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     start: jnp.ndarray,  # [B] global position of the chunk's first token
     chunk_lens: jnp.ndarray,  # [B] valid tokens in this chunk (<= C)
@@ -142,7 +150,7 @@ def paged_chunk_attention_blockwise(
     online softmax, tested in test_model.py)."""
     B, C, H, hd = q.shape
     max_pages = page_table.shape[1]
-    Hk = k_cache.shape[1]
+    Hk = k_cache.shape[-1] // hd
     n_rep = H // Hk
     BLK = block_pages * page_size
     n_blocks = -(-max_pages // block_pages)  # static ceiling
@@ -166,9 +174,10 @@ def paged_chunk_attention_blockwise(
         pos = i * BLK + jnp.arange(BLK, dtype=jnp.int32)  # global positions
         slots = (pages[:, :, None] * page_size
                  + jnp.arange(page_size)[None, None, :]).reshape(B, BLK)
-        k = repeat_kv(kv_gather(k_cache, slots).astype(jnp.float32),
-                      n_rep)  # [B,BLK,H,hd]
-        v = repeat_kv(kv_gather(v_cache, slots).astype(jnp.float32), n_rep)
+        k = repeat_kv(kv_gather(k_cache, layer, slots, hd).astype(
+            jnp.float32), n_rep)  # [B,BLK,H,hd]
+        v = repeat_kv(kv_gather(v_cache, layer, slots, hd).astype(
+            jnp.float32), n_rep)
         logits = jnp.einsum("bchd,blhd->bhcl", qf, k)  # [B, H, C, BLK]
         causal = pos[None, None, None, :] <= q_pos[:, None, :, None]
         in_seq = pos[None, None, None, :] < end[:, None, None, None]
@@ -195,8 +204,9 @@ def paged_chunk_attention_blockwise(
 
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, H, hd] one new token per sequence
-    k_cache: jnp.ndarray,  # [S, Hk, hd] flat slot pool for ONE layer
-    v_cache: jnp.ndarray,  # [S, Hk, hd]
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
+    v_cache: jnp.ndarray,
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     seq_lens: jnp.ndarray,  # [B] context length INCLUDING the new token
     page_size: int,
@@ -209,7 +219,7 @@ def paged_decode_attention(
     no materialization.
     """
     out = paged_chunk_attention(
-        q[:, None], k_cache, v_cache, page_table,
+        q[:, None], k_cache, v_cache, layer, page_table,
         start=seq_lens - 1, chunk_lens=jnp.ones_like(seq_lens),
         page_size=page_size,
     )
@@ -218,8 +228,9 @@ def paged_decode_attention(
 
 def ragged_paged_attention(
     q: jnp.ndarray,  # [T, H, hd] flattened mixed-batch query stream
-    k_cache: jnp.ndarray,  # [S, Hk, hd] flat slot pool for ONE layer
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
     v_cache: jnp.ndarray,
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages] one row per sequence
     tok_seq: jnp.ndarray,  # [T] int32 sequence index of each token
     tok_pos: jnp.ndarray,  # [T] int32 kv position of each token (-1 = pad)
@@ -242,8 +253,8 @@ def ragged_paged_attention(
     rows = page_table[jnp.clip(tok_seq, 0, B - 1)]  # [T, max_pages]
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (T, L))
     slots = flat_slot_indices(rows, positions, page_size)  # [T, L]
-    k = kv_gather(k_cache, slots)  # [T, L, Hk, hd] (int8 pools dequantize)
-    v = kv_gather(v_cache, slots)
+    k = kv_gather(k_cache, layer, slots, hd)  # [T, L, Hk, hd] (int8 -> f32)
+    v = kv_gather(v_cache, layer, slots, hd)
     n_rep = H // k.shape[2]
     k = repeat_kv(k, n_rep)
     v = repeat_kv(v, n_rep)
@@ -262,8 +273,9 @@ def ragged_paged_attention(
 
 def ragged_paged_attention_blockwise(
     q: jnp.ndarray,  # [T, H, hd] flattened mixed-batch query stream
-    k_cache: jnp.ndarray,  # [S, Hk, hd] flat slot pool for ONE layer
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
     v_cache: jnp.ndarray,
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     tok_seq: jnp.ndarray,  # [T] int32 sequence index of each token
     tok_pos: jnp.ndarray,  # [T] int32 kv position of each token (-1 = pad)
@@ -281,7 +293,7 @@ def ragged_paged_attention_blockwise(
     tests/test_ragged_attention.py)."""
     T, H, hd = q.shape
     B, max_pages = page_table.shape
-    Hk = k_cache.shape[1]
+    Hk = k_cache.shape[-1] // hd
     n_rep = H // Hk
     BLK = block_pages * page_size
     n_blocks = -(-max_pages // block_pages)  # static ceiling
@@ -301,9 +313,10 @@ def ragged_paged_attention_blockwise(
         pos = i * BLK + jnp.arange(BLK, dtype=jnp.int32)
         slots = (pages[:, :, None] * page_size
                  + jnp.arange(page_size)[None, None, :]).reshape(T, BLK)
-        k = repeat_kv(kv_gather(k_cache, slots).astype(jnp.float32),
-                      n_rep)  # [T,BLK,H,hd]
-        v = repeat_kv(kv_gather(v_cache, slots).astype(jnp.float32), n_rep)
+        k = repeat_kv(kv_gather(k_cache, layer, slots, hd).astype(
+            jnp.float32), n_rep)  # [T,BLK,H,hd]
+        v = repeat_kv(kv_gather(v_cache, layer, slots, hd).astype(
+            jnp.float32), n_rep)
         logits = jnp.einsum("thd,tlhd->thl", qf, k)  # [T, H, BLK]
         keep = (pos[None, :] <= tok_pos[:, None]) \
             & (pos[None, :] < end[:, None])  # [T, BLK]
@@ -332,16 +345,19 @@ def _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, *meta):
 
     GSPMD cannot partition a Mosaic kernel, so on a mesh of more than one
     device the call is wrapped in a shard_map: attention heads are
-    independent, so q splits on H over the "tensor" axis, the pools (and
-    an int8 pool's scale planes) on Hk, and the page table / length
-    metadata replicate — every shard runs the kernel on its own heads.
-    Callers already inside a shard_map (parallel/pipeline.py) pass no
-    mesh and get the plain per-device call."""
+    independent, so q splits on H over the "tensor" axis, the whole pools
+    keep the sharding they are stored with (lanes split by kv head; an
+    int8 pool's scale planes on Hk), and the layer index, the page table
+    and the length metadata replicate — every shard runs the kernel on
+    its own heads. Callers already inside a shard_map
+    (parallel/pipeline.py) pass no mesh and get the plain per-device
+    call."""
     if mesh is None or mesh.size == 1:
         return kernel(q, k_cache, v_cache, *meta)
     heads = PS(None, AXIS_TENSOR, None)
-    pool = (QuantKV(heads, PS(None, AXIS_TENSOR))
-            if isinstance(k_cache, QuantKV) else heads)
+    pool = PS(None, None, AXIS_TENSOR)  # [L, S, Hk*hd] and [L, S, Hk]
+    if isinstance(k_cache, QuantKV):
+        pool = QuantKV(pool, pool)
     return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(heads, pool, pool) + (PS(),) * len(meta),
@@ -362,8 +378,9 @@ def _split_quant(k_cache, v_cache):
 def ragged_attention_any(
     attn_impl: str,
     q: jnp.ndarray,  # [T, H, hd]
-    k_cache: jnp.ndarray,  # [S, Hk, hd] ONE layer's slot pool
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
     v_cache: jnp.ndarray,
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     tok_seq: jnp.ndarray,  # [T] (jnp path metadata)
     tok_pos: jnp.ndarray,  # [T]
@@ -385,24 +402,26 @@ def ragged_attention_any(
             ragged_paged_attention_pallas,
         )
 
-        def kernel(q, kc, vc, page_table, q_start, q_lens, kv_lens):
+        def kernel(q, kc, vc, layer, page_table, q_start, q_lens, kv_lens):
             kq, vq, scales = _split_quant(kc, vc)
             return ragged_paged_attention_pallas(
-                q, kq, vq, page_table, q_start, q_lens, kv_lens,
+                q, kq, vq, layer, page_table, q_start, q_lens, kv_lens,
                 page_size, interpret=interpret, **scales)
 
-        return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache,
+        return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, layer,
                                  page_table, q_start, q_lens, kv_lens)
     return ragged_paged_attention_blockwise(
-        q, k_cache, v_cache, page_table, tok_seq, tok_pos, kv_lens, page_size
+        q, k_cache, v_cache, layer, page_table, tok_seq, tok_pos, kv_lens,
+        page_size
     )
 
 
 def paged_decode_attention_any(
     attn_impl: str,
     q: jnp.ndarray,  # [B, H, hd]
-    k_cache: jnp.ndarray,  # [S, Hk, hd] ONE layer's slot pool
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
     v_cache: jnp.ndarray,
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     seq_lens: jnp.ndarray,  # [B]
     page_size: int,
@@ -418,14 +437,14 @@ def paged_decode_attention_any(
             paged_decode_attention_pallas,
         )
 
-        def kernel(q, kc, vc, page_table, seq_lens):
+        def kernel(q, kc, vc, layer, page_table, seq_lens):
             kq, vq, scales = _split_quant(kc, vc)
             return paged_decode_attention_pallas(
-                q, kq, vq, page_table, seq_lens, page_size,
+                q, kq, vq, layer, page_table, seq_lens, page_size,
                 interpret=interpret, **scales)
 
-        return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache,
+        return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, layer,
                                  page_table, seq_lens)
     return paged_decode_attention(
-        q, k_cache, v_cache, page_table, seq_lens, page_size
+        q, k_cache, v_cache, layer, page_table, seq_lens, page_size
     )

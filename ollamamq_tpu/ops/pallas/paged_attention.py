@@ -22,8 +22,14 @@ Mosaic layout constraints (learned against the real v5e compiler):
     so every vector op keeps its layout end to end.
 
 Layout contract (matches engine/kv_cache.py):
-    k_cache, v_cache: [S, Hk, hd] flat slot pool; a page is `page_size`
-    contiguous slots starting at page_id * page_size.
+    k_cache, v_cache: [L, S, Hk*hd] — the WHOLE slot pool in the layout
+    it is stored in, left in HBM; `layer` (int32 scalar, scalar-
+    prefetched) picks the layer, and a page is `page_size` contiguous
+    slots starting at page_id * page_size: the kernel DMAs
+    `k_hbm.at[layer, pl.ds(start, page_size)]`. The wrapper never
+    slices, reshapes or copies the pool (the forwards carry it through
+    their layer loop and update it in place); an int8 pool's scale
+    planes are [L, S, Hk] likewise.
     page_table: [B, max_pages] int32 (trash page 0 padding)
     seq_lens:   [B] int32 — context length INCLUDING the current token
 
@@ -46,6 +52,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(
     # scalar prefetch
+    layer_ref,  # [1] SMEM: the pool layer this launch attends over
     page_table_ref,  # [B, max_pages] SMEM
     seq_lens_ref,  # [B] SMEM
     # inputs + output + scratch (quantized pools append scale planes —
@@ -68,6 +75,7 @@ def _decode_kernel(
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
     b = pl.program_id(0)
     nb = pl.num_programs(0)
+    layer = layer_ref[0]
     seq_len = seq_lens_ref[b]
 
     # Clamp to the table width: a seq_len beyond capacity must not index
@@ -87,18 +95,18 @@ def _decode_kernel(
         start = page_id * page_size
         copies = [
             pltpu.make_async_copy(
-                k_hbm.at[pl.ds(start, page_size)], k_buf.at[slot],
+                k_hbm.at[layer, pl.ds(start, page_size)], k_buf.at[slot],
                 sems.at[slot, 0]),
             pltpu.make_async_copy(
-                v_hbm.at[pl.ds(start, page_size)], v_buf.at[slot],
+                v_hbm.at[layer, pl.ds(start, page_size)], v_buf.at[slot],
                 sems.at[slot, 1]),
         ]
         if quantized:
             copies.append(pltpu.make_async_copy(
-                ks_hbm.at[pl.ds(start, page_size)], ks_buf.at[slot],
+                ks_hbm.at[layer, pl.ds(start, page_size)], ks_buf.at[slot],
                 sems.at[slot, 2]))
             copies.append(pltpu.make_async_copy(
-                vs_hbm.at[pl.ds(start, page_size)], vs_buf.at[slot],
+                vs_hbm.at[layer, pl.ds(start, page_size)], vs_buf.at[slot],
                 sems.at[slot, 3]))
         return copies
 
@@ -228,21 +236,22 @@ def _decode_kernel(
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
-    k_cache: jnp.ndarray,  # [S, Hk, hd] (int8 when k_scale is passed)
-    v_cache: jnp.ndarray,  # [S, Hk, hd]
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (int8 when k_scale is passed)
+    v_cache: jnp.ndarray,  # [L, S, Hk*hd]
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     seq_lens: jnp.ndarray,  # [B]
     page_size: int,
     interpret: bool = False,
-    k_scale=None,  # [S, Hk] f32 per-slot per-head scales (int8 pools)
+    k_scale=None,  # [L, S, Hk] f32 per-slot per-head scales (int8 pools)
     v_scale=None,
 ) -> jnp.ndarray:
     quantized = k_scale is not None
     B, H, hd = q.shape
-    _, Hk, _ = k_cache.shape
     max_pages = page_table.shape[1]
+    lanes = k_cache.shape[-1]
+    Hk = lanes // hd
     group = H // Hk
-    lanes = Hk * hd
 
     # Pages in flight per sequence: measured on v5e, 4-16 are within noise
     # of each other (the DMA path is issue-overhead-bound); 8 is the middle.
@@ -284,7 +293,7 @@ def paged_decode_attention_pallas(
         pltpu.SemaphoreType.DMA((ring, 4 if quantized else 2)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, group, lanes), lambda b, *_: (b, 0, 0),
@@ -300,18 +309,16 @@ def paged_decode_attention_pallas(
     q_packed = (
         q.reshape(B, Hk, group, hd).transpose(0, 2, 1, 3).reshape(B, group, lanes)
     )
-    operands = [q_packed, k_cache.reshape(-1, lanes),
-                v_cache.reshape(-1, lanes)]
+    operands = [q_packed, k_cache, v_cache]
     if quantized:
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, group, lanes), q.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      *operands)
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
     return (
         out.reshape(B, group, Hk, hd).transpose(0, 2, 1, 3).reshape(B, H, hd)
     )

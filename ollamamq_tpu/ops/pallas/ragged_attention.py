@@ -11,8 +11,15 @@ ops/pallas/paged_attention.py):
     q:          [T, H, hd] flattened queries; sequence s owns rows
                 [q_start[s], q_start[s] + q_len[s]) and its tokens sit at
                 kv positions kv_len[s] - q_len[s] .. kv_len[s] - 1.
-    k/v cache:  [S, Hk, hd] flat slot pool; page = page_size contiguous
-                slots at page_id * page_size.
+    k/v cache:  [L, S, Hk*hd] the WHOLE slot pool, in the layout it is
+                stored in (engine/kv_cache.py), left in HBM; `layer` (an
+                int32 scalar, scalar-prefetched) picks the layer and
+                page = page_size contiguous slots at page_id * page_size,
+                so a page is the DMA `k_hbm.at[layer, pl.ds(start,
+                page_size)]` — [page_size, Hk*hd] rows. The wrapper never
+                slices, reshapes or copies the pool: the forwards carry
+                it through their layer loop and update it in place.
+                An int8 pool's scale planes are [L, S, Hk] likewise.
     page_table: [B, max_pages] int32 (trash page 0 padding).
     Spans are contiguous and ascending in stream order; padding rows
     carry q_len = 0 with q_start = T.
@@ -63,6 +70,7 @@ G_TILE = 8
 
 def _ragged_kernel(
     # scalar prefetch
+    layer_ref,  # [1] SMEM: the pool layer this launch attends over
     tile_seq_ref,  # [n_tiles] SMEM: first sequence overlapping each tile
     q_start_ref,  # [B] SMEM: stream offset of each sequence's span
     q_len_ref,  # [B] SMEM: span length (0 = padding row)
@@ -87,6 +95,7 @@ def _ragged_kernel(
          k_buf, v_buf, acc, m_i, l_i, sems) = refs
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
     t = pl.program_id(0)
+    layer = layer_ref[0]
     tile_start = t * G_TILE
     group = num_heads // num_kv_heads
     lanes = num_kv_heads * head_dim
@@ -97,20 +106,20 @@ def _ragged_kernel(
         start = page_id * page_size
         copies = [
             pltpu.make_async_copy(
-                k_hbm.at[pl.ds(start, page_size)], k_buf.at[slot],
+                k_hbm.at[layer, pl.ds(start, page_size)], k_buf.at[slot],
                 sems.at[slot, 0]),
             pltpu.make_async_copy(
-                v_hbm.at[pl.ds(start, page_size)], v_buf.at[slot],
+                v_hbm.at[layer, pl.ds(start, page_size)], v_buf.at[slot],
                 sems.at[slot, 1]),
         ]
         if quantized:
-            # Scale rows travel with their page: same slot indexing, a
-            # [page_size, Hk] f32 plane per page.
+            # Scale rows travel with their page: same layer and slot
+            # indexing, a [page_size, Hk] f32 plane per page.
             copies.append(pltpu.make_async_copy(
-                ks_hbm.at[pl.ds(start, page_size)], ks_buf.at[slot],
+                ks_hbm.at[layer, pl.ds(start, page_size)], ks_buf.at[slot],
                 sems.at[slot, 2]))
             copies.append(pltpu.make_async_copy(
-                vs_hbm.at[pl.ds(start, page_size)], vs_buf.at[slot],
+                vs_hbm.at[layer, pl.ds(start, page_size)], vs_buf.at[slot],
                 sems.at[slot, 3]))
         return copies
 
@@ -258,23 +267,24 @@ def _ragged_kernel(
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,  # [T, H, hd] flattened mixed-batch queries
-    k_cache: jnp.ndarray,  # [S, Hk, hd] (int8 when k_scale is passed)
-    v_cache: jnp.ndarray,  # [S, Hk, hd]
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (int8 when k_scale is passed)
+    v_cache: jnp.ndarray,  # [L, S, Hk*hd]
+    layer,  # int32 scalar: the pool layer to attend over
     page_table: jnp.ndarray,  # [B, max_pages]
     q_start: jnp.ndarray,  # [B] span offset per sequence (T for padding)
     q_lens: jnp.ndarray,  # [B] span length per sequence (0 for padding)
     kv_lens: jnp.ndarray,  # [B] context length incl. the span
     page_size: int,
     interpret: bool = False,
-    k_scale=None,  # [S, Hk] f32 per-slot per-head scales (int8 pools)
+    k_scale=None,  # [L, S, Hk] f32 per-slot per-head scales (int8 pools)
     v_scale=None,
 ) -> jnp.ndarray:
     quantized = k_scale is not None
     T, H, hd = q.shape
     B, max_pages = page_table.shape
-    Hk = k_cache.shape[1]
+    lanes = k_cache.shape[-1]
+    Hk = lanes // hd
     group = H // Hk
-    lanes = Hk * hd
 
     Tp = -(-T // G_TILE) * G_TILE
     n_tiles = Tp // G_TILE
@@ -324,7 +334,7 @@ def ragged_paged_attention_pallas(
         pltpu.SemaphoreType.DMA((ring, 4 if quantized else 2)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(n_tiles,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((G_TILE, group, lanes),
@@ -340,17 +350,16 @@ def ragged_paged_attention_pallas(
     )
     if Tp != T:
         q_packed = jnp.pad(q_packed, ((0, Tp - T), (0, 0), (0, 0)))
-    operands = [q_packed, k_cache.reshape(-1, lanes),
-                v_cache.reshape(-1, lanes)]
+    operands = [q_packed, k_cache, v_cache]
     if quantized:
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, group, lanes), q.dtype),
         interpret=interpret,
-    )(tile_first, q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_first,
+      q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
       kv_lens.astype(jnp.int32), page_table.astype(jnp.int32),
       *operands)
     return (

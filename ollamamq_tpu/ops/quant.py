@@ -12,8 +12,8 @@ pytrees, so they flow through jit/scan/donation/sharding unchanged):
     gathered row of the embedding lookup, so one scale vector serves
     both uses).
 
-  QuantKV — one KV slot pool as (q: int8 [..., S, Hk, hd],
-    s: f32 [..., S, Hk]). Scales are per token-slot per kv-head, stored
+  QuantKV — one KV slot pool as (q: int8 [L, S, Hk*hd],
+    s: f32 [L, S, Hk]). Scales are per token-slot per kv-head, stored
     page-aligned alongside the pool (slot index == page * page_size +
     offset), so the allocator/prefix-tree/preemption/rollback machinery
     is untouched: pages just shrink ~2x and their scale rows travel with
@@ -63,8 +63,8 @@ class QuantTensor(NamedTuple):
 
 
 class QuantKV(NamedTuple):
-    """One quantized KV slot pool: q int8 [..., S, Hk, hd] plus
-    page-aligned per-slot per-head scales s f32 [..., S, Hk]."""
+    """One quantized KV slot pool: q int8 [L, S, Hk*hd] plus
+    page-aligned per-slot per-head scales s f32 [L, S, Hk]."""
 
     q: Any
     s: Any
@@ -177,20 +177,32 @@ def kv_quantize(vals):
     return q, s.astype(jnp.float32)
 
 
-def kv_write(cache, slots, vals):
-    """Scatter-write K/V rows into the slot pool, quantizing on the fly
-    when the pool is int8. `slots` indexes the pool's slot axis; `vals`
-    is [..., Hk, hd] matching the indexed shape. Returns the updated
-    pool (same container type — QuantKV scatters payload AND scales)."""
+def kv_write(cache, layer, slots, vals):
+    """Scatter-write the step's K/V rows into layer `layer` of the WHOLE
+    pool ([L, S, Hk*hd]), quantizing on the fly when the pool is int8:
+    ONE scatter on the pool itself, so a pool that rides a loop as its
+    carry is updated in place and no layer slice is ever materialised.
+    `slots` indexes the slot axis; `vals` is [*slots.shape, Hk, hd] and
+    takes the pool's row shape (a few hundred rows: free). Returns the
+    updated pool (same container type — QuantKV scatters payload AND
+    scales)."""
     if isinstance(cache, QuantKV):
         q, s = kv_quantize(vals)
-        return QuantKV(cache.q.at[slots].set(q), cache.s.at[slots].set(s))
-    return cache.at[slots].set(vals)
+        return QuantKV(
+            cache.q.at[layer, slots].set(
+                q.reshape(slots.shape + cache.q.shape[2:])),
+            cache.s.at[layer, slots].set(s))
+    return cache.at[layer, slots].set(
+        vals.reshape(slots.shape + cache.shape[2:]))
 
 
-def kv_gather(cache, slots):
-    """Gather K/V rows from the slot pool, dequantizing int8 pools to
-    f32 (the softmax path consumes f32 regardless of pool dtype)."""
+def kv_gather(cache, layer, slots, head_dim: int):
+    """Gather rows of layer `layer` from the whole pool as
+    [*slots.shape, Hk, hd], dequantizing int8 pools to f32 (the softmax
+    path consumes f32 regardless of pool dtype). Only the gathered rows
+    are viewed per head; the pool is never sliced or reshaped."""
     if isinstance(cache, QuantKV):
-        return cache.q[slots].astype(jnp.float32) * cache.s[slots][..., None]
-    return cache[slots]
+        s = cache.s[layer, slots]
+        q = cache.q[layer, slots].reshape(s.shape + (head_dim,))
+        return q.astype(jnp.float32) * s[..., None]
+    return cache[layer, slots].reshape(slots.shape + (-1, head_dim))

@@ -46,18 +46,20 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ollamamq_tpu.config import ModelConfig
-from ollamamq_tpu.models.llama import rmsnorm
+from ollamamq_tpu.models.llama import rmsnorm, scan_layers
 from ollamamq_tpu.ops.attention import (
     causal_attention,
     flat_slot_indices,
     paged_chunk_attention_blockwise,
     paged_decode_attention_any,
 )
+from ollamamq_tpu.ops.quant import kv_write
 from ollamamq_tpu.ops.rope import apply_rope
 from ollamamq_tpu.parallel.mesh import AXIS_PIPE, AXIS_TENSOR
-from ollamamq_tpu.parallel.sharding import pipeline_param_specs
+from ollamamq_tpu.parallel.sharding import (kv_cache_spec,
+                                            pipeline_param_specs)
 
-KV_SPEC = P(AXIS_PIPE, None, AXIS_TENSOR, None)
+KV_SPEC = kv_cache_spec(pp=True)  # [L, S, Hk*hd]: layers by stage, lanes by kv head
 
 
 def n_microbatches(batch: int, pipe: int, requested: Optional[int] = None) -> int:
@@ -104,39 +106,37 @@ def _tp_mlp(lp: dict, h: jnp.ndarray) -> jnp.ndarray:
     return lax.psum(down, AXIS_TENSOR)
 
 
-def _tp_layer(cfg, lp, x, positions, kcl, vcl, attn_and_cache):
+def _tp_layer(cfg, lp, x, positions, l, kc, vc, attn_and_cache):
     """One transformer layer on this stage — the SINGLE definition of the
     stage layer math (prefill, chunk, and decode inject only the
     attention/KV-write schedule via `attn_and_cache`).
 
-    x: [mb, T, D]; kcl/vcl: ONE local layer's [S, Hk_loc, hd] cache.
-    attn_and_cache(q, k, v, kcl, vcl) -> (attn [mb, T, H_loc*hd], kcl, vcl)
-    writes the new K/V wherever its schedule wants them, then attends.
+    x: [mb, T, D]; kc/vc: this stage's WHOLE local pool
+    [L_loc, S, Hk_loc*hd], carried through the layer loop; l: the local
+    layer index. attn_and_cache(q, k, v, l, kc, vc) -> (attn [mb, T,
+    H_loc, hd], kc, vc) writes the new K/V into pool[l] wherever its
+    schedule wants them, then attends.
     """
     B, T, _ = x.shape
     h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
     q, k, v = _tp_qkv(cfg, lp, h)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    attn, kcl, vcl = attn_and_cache(q, k, v, kcl, vcl)
+    attn, kc, vc = attn_and_cache(q, k, v, l, kc, vc)
     delta = jnp.einsum("bte,ed->btd", attn.reshape(B, T, -1), lp["wo"])
     x = x + lax.psum(delta, AXIS_TENSOR)
     h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    return x + _tp_mlp(lp, h2), kcl, vcl
+    return x + _tp_mlp(lp, h2), kc, vc
 
 
 def _stage(cfg, layers, x, positions, kc, vc, attn_and_cache):
-    """Scan this stage's local layer stack over one microbatch."""
+    """Run this stage's local layer stack over one microbatch; the local
+    pool is the loop's carry (models/llama.py:scan_layers)."""
 
-    def body(carry, per_layer):
-        x = carry
-        lp, kcl, vcl = per_layer
-        x, kcl, vcl = _tp_layer(cfg, lp, x, positions, kcl, vcl,
-                                attn_and_cache)
-        return x, (kcl, vcl)
+    def body(x, lp, l, kc, vc):
+        return _tp_layer(cfg, lp, x, positions, l, kc, vc, attn_and_cache)
 
-    x, (kc, vc) = lax.scan(body, x, (layers, kc, vc))
-    return x, kc, vc
+    return scan_layers(body, x, layers, kc, vc)
 
 
 # ---------------------------------------------------------------------------
@@ -230,7 +230,7 @@ def pp_forward_prefill(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B, T] right-padded
     seq_lens: jnp.ndarray,  # [B]
-    k_cache: jnp.ndarray,  # [L, S, Hk, hd], L sharded over "pipe"
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd], L sharded over "pipe"
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]
     page_size: int,
@@ -255,10 +255,10 @@ def pp_forward_prefill(
             lens = _pick(lens_all, m)
             slots = jnp.where(valid, _pick(slots_all, m), 0)  # bubbles->trash
 
-            def attn_and_cache(q, k, v, kcl, vcl):
-                kcl = kcl.at[slots].set(k)
-                vcl = vcl.at[slots].set(v)
-                return causal_attention(q, k, v, lens), kcl, vcl
+            def attn_and_cache(q, k, v, l, kc, vc):
+                kc = kv_write(kc, l, slots, k)
+                vc = kv_write(vc, l, slots, v)
+                return causal_attention(q, k, v, lens), kc, vc
 
             h_out, kc, vc = _stage(cfg, params["layers"], inp, positions,
                                    kc, vc, attn_and_cache)
@@ -282,7 +282,7 @@ def pp_forward_prefill_chunk(
     tokens: jnp.ndarray,  # [B, C] one chunk of the prompt, right-padded
     start: jnp.ndarray,  # [B] global position of the chunk's first token
     chunk_lens: jnp.ndarray,  # [B] valid tokens in this chunk
-    k_cache: jnp.ndarray,  # [L, S, Hk, hd], L sharded over "pipe"
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd], L sharded over "pipe"
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages] — covers prefix AND chunk
     page_size: int,
@@ -313,15 +313,15 @@ def pp_forward_prefill_chunk(
             ptm = _pick(pt_all, m)
             slots = jnp.where(valid, _pick(slots_all, m), 0)  # bubbles->trash
 
-            def attn_and_cache(q, k, v, kcl, vcl):
-                kcl = kcl.at[slots].set(k)
-                vcl = vcl.at[slots].set(v)
+            def attn_and_cache(q, k, v, l, kc, vc):
+                kc = kv_write(kc, l, slots, k)
+                vc = kv_write(vc, l, slots, v)
                 # Blockwise online-softmax walk over the already-written
                 # prefix + this chunk (mirrors forward_prefill_chunk).
                 attn = paged_chunk_attention_blockwise(
-                    q, kcl, vcl, ptm, st, cl, page_size
+                    q, kc, vc, l, ptm, st, cl, page_size
                 )
-                return attn, kcl, vcl
+                return attn, kc, vc
 
             h_out, kc, vc = _stage(cfg, params["layers"], inp,
                                    _pick(pos_all, m), kc, vc, attn_and_cache)
@@ -345,7 +345,7 @@ def pp_forward_decode(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B] last generated token per slot
     positions: jnp.ndarray,  # [B]
-    k_cache: jnp.ndarray,  # [L, S, Hk, hd], L sharded over "pipe"
+    k_cache: jnp.ndarray,  # [L, S, Hk*hd], L sharded over "pipe"
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]
     page_size: int,
@@ -357,7 +357,7 @@ def pp_forward_decode(
     """Pipelined single decode step; returns (logits [B, V], caches').
 
     The Pallas decode kernel runs per-device inside the shard_map stage
-    (each stage's pallas_call sees its local layer-slice caches)."""
+    (each stage's pallas_call sees its local pool and local layer index)."""
     B = tokens.shape[0]
     pipe = mesh.shape[AXIS_PIPE]
     M = n_microbatches(B, pipe, n_micro)
@@ -375,14 +375,14 @@ def pp_forward_decode(
             ptm = _pick(pt_all, m)
             ws = jnp.where(valid, _pick(ws_all, m), 0)  # bubbles->trash
 
-            def attn_and_cache(q, k, v, kcl, vcl):
-                kcl = kcl.at[ws].set(k[:, 0])
-                vcl = vcl.at[ws].set(v[:, 0])
+            def attn_and_cache(q, k, v, l, kc, vc):
+                kc = kv_write(kc, l, ws, k[:, 0])
+                vc = kv_write(vc, l, ws, v[:, 0])
                 attn = paged_decode_attention_any(
-                    attn_impl, q[:, 0], kcl, vcl, ptm, pos + 1, page_size,
+                    attn_impl, q[:, 0], kc, vc, l, ptm, pos + 1, page_size,
                     interpret=interpret,
                 )
-                return attn[:, None], kcl, vcl  # [mb, 1, H_loc, hd]
+                return attn[:, None], kc, vc  # [mb, 1, H_loc, hd]
 
             h_out, kc, vc = _stage(cfg, params["layers"], inp, pos[:, None],
                                    kc, vc, attn_and_cache)

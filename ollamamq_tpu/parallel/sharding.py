@@ -13,7 +13,8 @@ reference's HTTP fan-out):
   - embed     [V, D]         -> shard vocab on "tensor" (logits computed
                                 shard-local then allgathered by XLA)
   - norms                    -> replicated
-  - KV pages  [L, P, page, kv_heads, hd] -> shard kv_heads on "tensor"
+  - KV pool   [L, slots, kv_heads*hd]  -> shard the lanes by kv head on
+                                "tensor"
 """
 
 from __future__ import annotations
@@ -89,15 +90,10 @@ def pipeline_param_specs(params: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def kv_cache_spec(pp: bool = False) -> PS:
-    """KV slot pool [L, slots, kv_heads, head_dim]: heads on tensor axis;
+    """KV slot pool [L, slots, kv_heads*head_dim] and a quantized pool's
+    scale rows [L, slots, kv_heads]: the last axis splits by kv head over
+    the tensor axis (each shard owns its own heads' lanes and scales);
     under pipeline parallelism layers also split over the pipe axis."""
-    return PS(AXIS_PIPE if pp else None, None, AXIS_TENSOR, None)
-
-
-def kv_scale_spec(pp: bool = False) -> PS:
-    """Quantized-pool scale rows [L, slots, kv_heads]: same layout as
-    the payload minus the head_dim axis, so each tensor shard owns its
-    own heads' scales."""
     return PS(AXIS_PIPE if pp else None, None, AXIS_TENSOR)
 
 

@@ -1,9 +1,12 @@
 """Ask the chip's compiler, without the chip: AOT-compile the attention
-kernels of the serving path for a DESCRIBED TPU v5e at llama3.2:1b widths
-and the CLI's default pool shape. Interpret-mode tests cannot see what
-Mosaic refuses (slices off the tiling, kernels GSPMD cannot partition);
-this file can, at no chip time. Nothing runs — a compile that passes is
-not a chip run. Skipped where the v5e topology cannot be described."""
+kernels of the serving path — and the WHOLE layer loop of the two step
+forwards around them — for a DESCRIBED TPU v5e at llama3.2:1b widths and
+the CLI's default pool shape. Interpret-mode tests cannot see what Mosaic
+refuses (slices off the tiling, kernels GSPMD cannot partition), nor what
+XLA does with the KV pool (a second copy, a layer re-laid out for the
+kernel); this file can, at no chip time. Nothing runs — a compile that
+passes is not a chip run. Skipped where the v5e topology cannot be
+described."""
 
 import os
 
@@ -14,17 +17,22 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from ollamamq_tpu.config import ModelConfig
 from ollamamq_tpu.engine.engine import select_attn_impl
+from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.attention import (paged_decode_attention_any,
                                         ragged_attention_any)
 from ollamamq_tpu.ops.quant import QuantKV
 from ollamamq_tpu.parallel.mesh import make_mesh
+from ollamamq_tpu.parallel.sharding import (kv_cache_spec,
+                                            param_partition_specs)
 
 # llama3.2:1b heads (config.py) under the CLI defaults: 64 slots, 256
 # pages a sequence, a 1024-page pool of 32-token pages.
 H, HK, HD = 32, 8, 64
 B, MP, PS, NP = 64, 256, 32, 1024
 T = 64
+LAYERS = 4  # a small stack: the kernels read layer 2 of it by index
 
 
 @pytest.fixture(scope="module")
@@ -55,10 +63,10 @@ def _shapes(sharding_of, kv_dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding_of(spec))
 
     heads = P(None, "tensor", None)
-    pool = s((NP * PS, HK, HD), kv_dtype, heads)
+    pool = s((LAYERS, NP * PS, HK * HD), kv_dtype, kv_cache_spec())
     if kv_dtype == jnp.int8:
-        pool = QuantKV(pool, s((NP * PS, HK), jnp.float32,
-                               P(None, "tensor")))
+        pool = QuantKV(pool, s((LAYERS, NP * PS, HK), jnp.float32,
+                               kv_cache_spec()))
     return (s((T, H, HD), jnp.bfloat16, heads),
             s((B, H, HD), jnp.bfloat16, heads), pool,
             s((B, MP), jnp.int32), s((T,), jnp.int32), s((B,), jnp.int32))
@@ -68,7 +76,7 @@ def _compile_ragged(shapes, mesh=None):
     q, _, pool, pt, per_tok, per_seq = shapes
     return jax.jit(
         lambda q, kc, vc, pt, ts, tp, kl, qs, ql: ragged_attention_any(
-            "pallas", q, kc, vc, pt, ts, tp, kl, qs, ql, PS, mesh=mesh)
+            "pallas", q, kc, vc, 2, pt, ts, tp, kl, qs, ql, PS, mesh=mesh)
     ).lower(q, pool, pool, pt, per_tok, per_tok, per_seq, per_seq,
             per_seq).compile()
 
@@ -77,7 +85,7 @@ def _compile_decode(shapes, mesh=None):
     _, q, pool, pt, _, per_seq = shapes
     return jax.jit(
         lambda q, kc, vc, pt, sl: paged_decode_attention_any(
-            "pallas", q, kc, vc, pt, sl, PS, mesh=mesh)
+            "pallas", q, kc, vc, 2, pt, sl, PS, mesh=mesh)
     ).lower(q, pool, pool, pt, per_seq).compile()
 
 
@@ -112,3 +120,72 @@ def test_int8_kv_kernel_compiles_or_is_selected_away(v5e, compile_fn):
     except Exception as e:  # noqa: BLE001 — whatever the compiler raises
         assert "aligned to tiling" in str(e), e
         assert select_attn_impl("tpu", "int8")[0] == "jnp"
+
+
+# ---------------------------------------------------------------------------
+# The whole layer loop: the KV pool is updated in place.
+# ---------------------------------------------------------------------------
+
+# llama3.2:1b widths over a small stack and a small vocabulary (the
+# logits are the one temporary that would outgrow a layer's pool here,
+# and they are not what this test is about).
+LOOP_CFG = ModelConfig(
+    name="chip-compile-1b-widths", vocab_size=2048, hidden_size=2048,
+    intermediate_size=8192, num_layers=LAYERS, num_heads=H, num_kv_heads=HK,
+    head_dim=HD, max_seq_len=MP * PS, rope_theta=5e5, rms_norm_eps=1e-5,
+    tie_embeddings=True)
+
+
+def _lower_ragged(params, shapes, mesh):
+    _, _, pool, pt, per_tok, per_seq = shapes
+
+    def step(params, tok, ts, tp, ws, out_idx, kc, vc, pt, qs, ql, kl):
+        return llama.forward_ragged(
+            params, LOOP_CFG, tok, ts, tp, ws, out_idx, kc, vc, pt, qs, ql,
+            kl, PS, attn_impl="pallas", mesh=mesh)
+
+    return jax.jit(step, donate_argnums=(6, 7)).lower(
+        params, per_tok, per_tok, per_tok, per_tok, per_seq, pool, pool, pt,
+        per_seq, per_seq, per_seq)
+
+
+def _lower_decode(params, shapes, mesh):
+    _, _, pool, pt, _, per_seq = shapes
+
+    def step(params, tok, pos, kc, vc, pt):
+        return llama.forward_decode(params, LOOP_CFG, tok, pos, kc, vc, pt,
+                                    PS, attn_impl="pallas", mesh=mesh)
+
+    return jax.jit(step, donate_argnums=(3, 4)).lower(
+        params, per_seq, per_seq, pool, pool, pt)
+
+
+@pytest.mark.parametrize("tp", [1, 4], ids=["one_chip", "tensor4"])
+@pytest.mark.parametrize("lower", [_lower_ragged, _lower_decode],
+                         ids=["forward_ragged", "forward_decode"])
+def test_layer_loop_updates_the_kv_pool_in_place(v5e, lower, tp):
+    """forward_ragged / forward_decode with the Pallas kernels, pools
+    donated as the jit sites donate them: the compiled program aliases
+    both pools to its outputs and ALL its temporaries together are
+    smaller than one layer's K pool on a device — so no second pool, no
+    layer sliced out, re-laid out for the kernel or written back. (With
+    the pool as a scan's xs/ys the one-chip ragged step held 2 pools +
+    several layer slices of temporaries.)"""
+    if tp == 1:
+        mesh, one = None, SingleDeviceSharding(v5e.devices[0])
+        sharding_of = lambda spec: one  # noqa: E731
+    else:
+        mesh = make_mesh(tp=tp, devices=v5e.devices)
+        sharding_of = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(LOOP_CFG, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(
+        lambda a, spec: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=sharding_of(spec)),
+        shapes, param_partition_specs(shapes))
+    compiled = lower(params, _shapes(sharding_of), mesh).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel is in it
+    mem = compiled.memory_analysis()
+    layer_pool = NP * PS * HK * HD * 2 // tp  # one layer's K pool, a device
+    assert mem.alias_size_in_bytes >= 2 * LAYERS * layer_pool, mem
+    assert mem.temp_size_in_bytes < layer_pool, mem

@@ -63,7 +63,7 @@ def test_2k_prompt_chunked_serving_matches_oneshot():
     params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     # The engine seeds its weights identically (random-init path, seed 0).
     S = 192 * ps
-    kc = jnp.zeros((cfg.num_layers, S, cfg.num_kv_heads, cfg.head_dim),
+    kc = jnp.zeros((cfg.num_layers, S, cfg.num_kv_heads * cfg.head_dim),
                    jnp.float32)
     vc = jnp.zeros_like(kc)
     alloc = kvc.PageAllocator(192, ps, 160)

@@ -14,7 +14,8 @@ MAX_PAGES = 8
 
 
 def _fresh_cache(cfg, num_pages=32):
-    shape = (cfg.num_layers, num_pages * PAGE_SIZE, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, num_pages * PAGE_SIZE,
+             cfg.num_kv_heads * cfg.head_dim)  # the stored pool layout
     return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
 
 
@@ -278,8 +279,9 @@ def test_blockwise_chunk_attention_matches_full_gather():
     B, C, H, Hk, hd, ps, MP = 3, 8, 4, 2, 16, 4, 12
     S = 64 * ps
     q = jnp.asarray(rng.normal(size=(B, C, H, hd)), jnp.float32)
-    kc = jnp.asarray(rng.normal(size=(S, Hk, hd)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(S, Hk, hd)), jnp.float32)
+    # A 3-layer pool; the attentions read layer 1 of it by index.
+    kc = jnp.asarray(rng.normal(size=(3, S, Hk * hd)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(3, S, Hk * hd)), jnp.float32)
     # Distinct pages per sequence; tables longer than any sequence needs.
     pt = jnp.asarray(
         rng.permutation(64 - 1)[: B * MP].reshape(B, MP) + 1, jnp.int32
@@ -288,12 +290,12 @@ def test_blockwise_chunk_attention_matches_full_gather():
     # the final partial block is exercised when block_pages doesn't divide MP.
     start = jnp.asarray([0, 9, 44], jnp.int32)
     chunk_lens = jnp.asarray([8, 5, 4], jnp.int32)  # ragged
-    ref = paged_chunk_attention(q, kc, vc, pt, start, chunk_lens, ps)
+    ref = paged_chunk_attention(q, kc, vc, 1, pt, start, chunk_lens, ps)
     # block_pages=5 does NOT divide MP=12: the final partial block must not
     # relabel or double-count pages (clamped-slice regression).
     for bp in (2, 5):
         blk = paged_chunk_attention_blockwise(
-            q, kc, vc, pt, start, chunk_lens, ps, block_pages=bp
+            q, kc, vc, 1, pt, start, chunk_lens, ps, block_pages=bp
         )
         for b in range(B):
             n = int(chunk_lens[b])

@@ -10,12 +10,17 @@ from ollamamq_tpu.ops.attention import paged_decode_attention
 from ollamamq_tpu.ops.pallas.paged_attention import paged_decode_attention_pallas
 
 
+LAYERS = 3  # pool depth of the kernel cases: first, middle, last layer
+
+
 def _case(B, H, Hk, hd, PS_, MP, seq_lens, seed=0):
+    """The pool is whole — [LAYERS, S, Hk*hd], every layer different —
+    and the attentions under test read one layer of it by index."""
     rng = np.random.default_rng(seed)
     S = (MP * B + 2) * PS_
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(S, Hk, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(S, Hk, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
     pt = np.zeros((B, MP), np.int32)
     next_page = 1
     for b, L in enumerate(seq_lens):
@@ -25,18 +30,21 @@ def _case(B, H, Hk, hd, PS_, MP, seq_lens, seed=0):
     return q, k, v, jnp.asarray(pt), jnp.asarray(seq_lens, jnp.int32)
 
 
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("seq_lens", [[20, 9, 37], [1, 48, 16]])
-def test_pallas_matches_reference(seq_lens):
+def test_pallas_matches_reference(seq_lens, layer):
     q, k, v, pt, sl = _case(3, 8, 4, 32, 8, 6, seq_lens)
-    ref = paged_decode_attention(q, k, v, pt, sl, 8)
-    out = paged_decode_attention_pallas(q, k, v, pt, sl, 8, interpret=True)
+    ref = paged_decode_attention(q, k, v, layer, pt, sl, 8)
+    out = paged_decode_attention_pallas(q, k, v, layer, pt, sl, 8,
+                                        interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
 def test_pallas_mqa_single_kv_head():
     q, k, v, pt, sl = _case(2, 4, 1, 16, 8, 4, [8, 25])
-    ref = paged_decode_attention(q, k, v, pt, sl, 8)
-    out = paged_decode_attention_pallas(q, k, v, pt, sl, 8, interpret=True)
+    ref = paged_decode_attention(q, k, v, 2, pt, sl, 8)
+    out = paged_decode_attention_pallas(q, k, v, 2, pt, sl, 8,
+                                        interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
@@ -50,7 +58,7 @@ def test_model_decode_with_pallas_impl(tiny_cfg, tiny_params):
 
     cfg, params = tiny_cfg, tiny_params
     PS_, MP = 8, 8
-    shape = (cfg.num_layers, 32 * PS_, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, 32 * PS_, cfg.num_kv_heads * cfg.head_dim)
     import ollamamq_tpu.ops.pallas.paged_attention as pa
 
     orig = pa.paged_decode_attention_pallas
@@ -103,7 +111,7 @@ def test_forward_prefill_sp_matches(tiny_cfg, tiny_params):
     )
     seq_lens = jnp.array([T])
 
-    shape = (cfg.num_layers, 32 * PS_, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, 32 * PS_, cfg.num_kv_heads * cfg.head_dim)
     kc = jnp.zeros(shape, jnp.float32)
     vc = jnp.zeros(shape, jnp.float32)
     a = kvc.PageAllocator(32, PS_, MP)
@@ -125,7 +133,7 @@ def test_forward_prefill_sp_matches(tiny_cfg, tiny_params):
         [pages[t // PS_] * PS_ + t % PS_ for t in range(T)]
     )
     np.testing.assert_allclose(
-        np.asarray(k_stack[:, 0]),  # [L,T,Hk,hd]
-        np.asarray(ref_kc)[:, slots],
+        np.asarray(k_stack[:, 0]).reshape(cfg.num_layers, T, -1),
+        np.asarray(ref_kc)[:, slots],  # the pool's rows: [L,T,Hk*hd]
         rtol=2e-4, atol=2e-4,
     )

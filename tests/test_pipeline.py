@@ -27,7 +27,7 @@ def _setup(cfg, B=4, T=16, num_pages=64, seed=0):
     tokens = jnp.asarray(rng.randint(1, cfg.vocab_size, size=(B, T)), jnp.int32)
     seq_lens = jnp.asarray(rng.randint(T // 2, T + 1, size=(B,)), jnp.int32)
     S = num_pages * PAGE_SIZE
-    kc = jnp.zeros((cfg.num_layers, S, cfg.num_kv_heads, cfg.head_dim),
+    kc = jnp.zeros((cfg.num_layers, S, cfg.num_kv_heads * cfg.head_dim),
                    jnp.float32)
     vc = jnp.zeros_like(kc)
     max_pages = T // PAGE_SIZE + 1

@@ -85,14 +85,14 @@ def test_kv_roundtrip_per_page_bounds():
     page-aligned (slot index == page * page_size + offset) so a page's
     scale rows travel with its page id."""
     rng = np.random.default_rng(2)
-    S, Hk, hd = 64, 2, 16
-    pool = QuantKV(jnp.zeros((S, Hk, hd), jnp.int8),
-                   jnp.ones((S, Hk), jnp.float32))
+    L, S, Hk, hd = 3, 64, 2, 16  # the whole pool; layer 1 is written
+    pool = QuantKV(jnp.zeros((L, S, Hk * hd), jnp.int8),
+                   jnp.ones((L, S, Hk), jnp.float32))
     vals = jnp.asarray(rng.normal(size=(24, Hk, hd)).astype(np.float32) * 3)
     slots = jnp.asarray(rng.choice(S, size=24, replace=False))
-    pool = kv_write(pool, slots, vals)
-    got = np.asarray(kv_gather(pool, slots))
-    scales = np.asarray(pool.s)[np.asarray(slots)]  # [24, Hk]
+    pool = kv_write(pool, 1, slots, vals)
+    got = np.asarray(kv_gather(pool, 1, slots, hd))
+    scales = np.asarray(pool.s)[1][np.asarray(slots)]  # [24, Hk]
     err = np.abs(got - np.asarray(vals))
     assert (err <= scales[..., None] * 0.5 + 1e-6).all()
     # kv_quantize is the same math the in-jit writer runs.
@@ -132,11 +132,14 @@ def test_quantize_params_rejects_moe():
 
 
 # ------------------------------------------------- quantized pallas kernels
-def _mixed_stream(rng, S=160, Hk=2, hd=16, H=4, ps=8, MP=8):
-    kraw = jnp.asarray(rng.normal(size=(S, Hk, hd)).astype(np.float32))
-    vraw = jnp.asarray(rng.normal(size=(S, Hk, hd)).astype(np.float32))
+def _mixed_stream(rng, S=160, Hk=2, hd=16, H=4, ps=8, MP=8, L=3):
+    """A whole int8 pool ([L, S, Hk*hd] payload + [L, S, Hk] scales,
+    every layer different); the callers attend over layer 1."""
+    kraw = jnp.asarray(rng.normal(size=(L, S, Hk, hd)).astype(np.float32))
+    vraw = jnp.asarray(rng.normal(size=(L, S, Hk, hd)).astype(np.float32))
     kq, ks = kv_quantize(kraw)
     vq, vs = kv_quantize(vraw)
+    kq, vq = kq.reshape(L, S, Hk * hd), vq.reshape(L, S, Hk * hd)
     pt = np.zeros((3, MP), np.int32)
     pt[0, :4] = [1, 2, 3, 4]
     pt[1, :2] = [5, 6]
@@ -164,10 +167,10 @@ def test_pallas_ragged_quantized_matches_jnp_interpret():
     (kc, vc, pt, q_start, q_len, kv_len, tok_seq, tok_pos,
      ps, H, hd) = _mixed_stream(rng)
     q = jnp.asarray(rng.normal(size=(16, H, hd)).astype(np.float32))
-    ref = ragged_paged_attention_blockwise(q, kc, vc, pt, tok_seq, tok_pos,
-                                           kv_len, ps)
-    out = ragged_paged_attention_pallas(q, kc.q, vc.q, pt, q_start, q_len,
-                                        kv_len, ps, interpret=True,
+    ref = ragged_paged_attention_blockwise(q, kc, vc, 1, pt, tok_seq,
+                                           tok_pos, kv_len, ps)
+    out = ragged_paged_attention_pallas(q, kc.q, vc.q, 1, pt, q_start,
+                                        q_len, kv_len, ps, interpret=True,
                                         k_scale=kc.s, v_scale=vc.s)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
@@ -181,8 +184,8 @@ def test_pallas_decode_quantized_matches_jnp_interpret():
     rng = np.random.default_rng(5)
     (kc, vc, pt, _qs, _ql, kv_len, _ts, _tp, ps, H, hd) = _mixed_stream(rng)
     q = jnp.asarray(rng.normal(size=(3, H, hd)).astype(np.float32))
-    ref = paged_decode_attention(q, kc, vc, pt, kv_len, ps)
-    out = paged_decode_attention_pallas(q, kc.q, vc.q, pt, kv_len, ps,
+    ref = paged_decode_attention(q, kc, vc, 1, pt, kv_len, ps)
+    out = paged_decode_attention_pallas(q, kc.q, vc.q, 1, pt, kv_len, ps,
                                         interpret=True,
                                         k_scale=kc.s, v_scale=vc.s)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

@@ -16,15 +16,20 @@ from ollamamq_tpu.ops.pallas.ragged_attention import (
     ragged_paged_attention_pallas)
 
 
+LAYERS = 3  # pool depth of the kernel cases: first, middle, last layer
+
+
 def _case(spans, B, PS=8, MP=8, Hk=2, H=4, hd=16, seed=0):
     """Build one ragged batch: spans = [(q_len, kv_len), ...] laid out
-    contiguously in stream order; trailing rows of B are padding."""
+    contiguously in stream order; trailing rows of B are padding. The
+    pool is whole — [LAYERS, S, Hk*hd], every layer different — and the
+    attentions under test read one layer of it by index."""
     rng = np.random.default_rng(seed)
     T = sum(s for s, _ in spans)
     S = (MP * B + 2) * PS
     q = jnp.asarray(rng.normal(size=(T, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(S, Hk, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(S, Hk, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
     pt = np.zeros((B, MP), np.int32)
     nxt = 1
     q_start = np.full(B, T, np.int32)
@@ -61,19 +66,22 @@ MIXED_CASES = [
 @pytest.mark.parametrize("case", MIXED_CASES)
 def test_blockwise_matches_reference(case):
     q, k, v, pt, tok_seq, tok_pos, kv_len, _qs, _ql, PS = _case(**case)
-    ref = ragged_paged_attention(q, k, v, pt, tok_seq, tok_pos, kv_len, PS)
+    ref = ragged_paged_attention(q, k, v, 1, pt, tok_seq, tok_pos, kv_len,
+                                 PS)
     blk = ragged_paged_attention_blockwise(
-        q, k, v, pt, tok_seq, tok_pos, kv_len, PS, block_pages=2)
+        q, k, v, 1, pt, tok_seq, tok_pos, kv_len, PS, block_pages=2)
     np.testing.assert_allclose(np.asarray(blk), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("case", MIXED_CASES)
-def test_pallas_matches_reference(case):
+def test_pallas_matches_reference(case, layer):
     q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(**case)
-    ref = ragged_paged_attention(q, k, v, pt, tok_seq, tok_pos, kv_len, PS)
-    out = ragged_paged_attention_pallas(q, k, v, pt, qs, ql, kv_len, PS,
-                                        interpret=True)
+    ref = ragged_paged_attention(q, k, v, layer, pt, tok_seq, tok_pos,
+                                 kv_len, PS)
+    out = ragged_paged_attention_pallas(q, k, v, layer, pt, qs, ql, kv_len,
+                                        PS, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -82,9 +90,9 @@ def test_pallas_mqa_and_group1():
     for Hk, H in ((1, 4), (4, 4)):
         q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(
             spans=[(6, 6), (1, 12)], B=3, Hk=Hk, H=H, seed=2)
-        ref = ragged_paged_attention(q, k, v, pt, tok_seq, tok_pos,
+        ref = ragged_paged_attention(q, k, v, 2, pt, tok_seq, tok_pos,
                                      kv_len, PS)
-        out = ragged_paged_attention_pallas(q, k, v, pt, qs, ql, kv_len,
+        out = ragged_paged_attention_pallas(q, k, v, 2, pt, qs, ql, kv_len,
                                             PS, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -97,7 +105,7 @@ def test_forward_ragged_matches_bucketed_composition(tiny_cfg, tiny_params):
     writes, same greedy argmax."""
     cfg, params = tiny_cfg, tiny_params
     PS, MP = 8, 8
-    shape = (cfg.num_layers, 64 * PS, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, 64 * PS, cfg.num_kv_heads * cfg.head_dim)
     rng = np.random.default_rng(3)
     a = kvc.PageAllocator(64, PS, MP)
     pagesA, pagesB = a.alloc(12), a.alloc(6)
@@ -156,7 +164,7 @@ def test_forward_ragged_pallas_interpret_matches_jnp(tiny_cfg, tiny_params):
 
     cfg, params = tiny_cfg, tiny_params
     PS, MP = 8, 8
-    shape = (cfg.num_layers, 64 * PS, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, 64 * PS, cfg.num_kv_heads * cfg.head_dim)
     rng = np.random.default_rng(5)
     a = kvc.PageAllocator(64, PS, MP)
     pages = [a.alloc(10), a.alloc(4)]

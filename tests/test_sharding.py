@@ -73,7 +73,7 @@ def test_tp_forward_matches_single_device(tiny_cfg, tiny_params):
         return llama.forward_prefill(params, cfg, tokens, seq_lens, kc, vc, pt, PAGE_SIZE)
 
     # Unsharded reference.
-    shape = (cfg.num_layers, 32 * PAGE_SIZE, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, 32 * PAGE_SIZE, cfg.num_kv_heads * cfg.head_dim)
     kc = jnp.zeros(shape, jnp.float32)
     vc = jnp.zeros(shape, jnp.float32)
     a = kvc.PageAllocator(32, PAGE_SIZE, MAX_PAGES)

@@ -60,7 +60,7 @@ def test_safetensors_hf_layout(tmp_path):
     from ollamamq_tpu.models import llama
     import jax
 
-    kc = jnp.zeros((cfg.num_layers, 64, cfg.num_kv_heads, cfg.head_dim), jnp.float32)
+    kc = jnp.zeros((cfg.num_layers, 64, cfg.num_kv_heads * cfg.head_dim), jnp.float32)
     from ollamamq_tpu.engine import kv_cache as kvc
     a = kvc.PageAllocator(8, 8, 4)
     pt = jnp.asarray(np.stack([kvc.make_page_table_row(a.alloc(4), 4)]))
@@ -163,7 +163,7 @@ def test_safetensors_mixtral_moe_layout(tmp_path):
     from ollamamq_tpu.engine import kv_cache as kvc
     from ollamamq_tpu.models import llama
 
-    kc = jnp.zeros((L, 64, cfg.num_kv_heads, cfg.head_dim), jnp.float32)
+    kc = jnp.zeros((L, 64, cfg.num_kv_heads * cfg.head_dim), jnp.float32)
     a = kvc.PageAllocator(8, 8, 4)
     pt = jnp.asarray(np.stack([kvc.make_page_table_row(a.alloc(4), 4)]))
     logits, _, _ = llama.forward_prefill(
