@@ -1,10 +1,13 @@
 """Model and engine configuration.
 
 Model architecture configs for the families the framework serves natively:
-Llama 3.x (incl. llama3.2:1b and Llama-3-8B) and Qwen2.5 (attention bias),
-plus a bidirectional encoder config for embedding models (nomic-embed-text
-class). These are the model names the reference's stress test exercises
-(/root/reference/test_dispatcher.sh:5-7) and BASELINE.json's configs list.
+Llama 3.x (incl. llama3.2:1b and Llama-3-8B), Qwen2.5 (attention bias),
+Qwen3 (per-head q/k norm), the sparse families Mixtral (top-2 of 8,
+renormalised) and OLMoE (top-8 of 64, not renormalised, MHA, whole-vector
+q/k norm), plus a bidirectional encoder config for embedding models
+(nomic-embed-text class). The dense names are the ones the reference's
+stress test exercises (/root/reference/test_dispatcher.sh:5-7) and
+BASELINE.json's configs list.
 """
 
 from __future__ import annotations
@@ -31,19 +34,38 @@ class ModelConfig:
     tie_embeddings: bool = False
     # Qwen2-style attention projections carry a bias term; Llama's do not.
     attn_bias: bool = False
-    # Qwen3-style per-head RMSNorm on q and k after projection (pre-RoPE).
-    qk_norm: bool = False
+    # RMSNorm on q and k after projection (pre-RoPE). False: none. True or
+    # "head": Qwen3's, per head (weight over head_dim). "full": OLMoE's,
+    # over the WHOLE projected vector before the split into heads (weight
+    # over q_dim / kv_dim). A value, not a second field, because a
+    # configuration file's `qk_norm` key reaches this field verbatim.
+    qk_norm: object = False
     # Bidirectional attention + mean pooling => embedding encoder, not a LM.
     is_encoder: bool = False
-    # Mixture-of-experts (Mixtral family): 0 = dense FFN. When > 0, each
+    # Mixture-of-experts (Mixtral, OLMoE): 0 = dense FFN. When > 0, each
     # layer's FFN becomes num_experts independent SwiGLU experts with
-    # top-(num_experts_per_tok) routing (models/moe.py); experts shard
-    # over the mesh "expert" axis.
+    # top-(num_experts_per_tok) routing (models/moe.py: dropless — every
+    # token's every routed expert contributes); experts shard over the
+    # mesh "expert" axis.
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # Static per-expert token capacity = ceil(tokens * k / E) * factor;
-    # overflow tokens fall through to the residual (their FFN delta is 0).
-    moe_capacity_factor: float = 2.0
+    # Whether the kept top-k router probabilities are renormalised to sum
+    # to 1. False is the published default of the OLMoE/Qwen-MoE configs
+    # (the key is `norm_topk_prob` there); Mixtral always renormalises.
+    norm_topk_prob: bool = False
+
+    def __post_init__(self):
+        if self.qk_norm not in (False, True, "head", "full"):
+            raise ValueError(
+                f"{self.name}: qk_norm must be false, true, 'head' or "
+                f"'full', got {self.qk_norm!r}")
+
+    @property
+    def qk_norm_kind(self) -> Optional[str]:
+        """None, "head" (per head) or "full" (whole projected vector)."""
+        if not self.qk_norm:
+            return None
+        return "full" if self.qk_norm == "full" else "head"
 
     @property
     def q_dim(self) -> int:
@@ -63,9 +85,15 @@ class ModelConfig:
             d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d  # attn
             + mlp
             + 2 * d  # norms
+            + self.qk_norm_params()
         )
         embed = v * d * (1 if self.tie_embeddings else 2)
         return self.num_layers * per_layer + embed + d
+
+    def qk_norm_params(self) -> int:
+        """q/k norm weights of one layer."""
+        return {None: 0, "head": 2 * self.head_dim,
+                "full": self.q_dim + self.kv_dim}[self.qk_norm_kind]
 
 
 # ---------------------------------------------------------------------------
@@ -152,12 +180,29 @@ MODEL_CONFIGS = {
         intermediate_size=14_336, num_layers=32, num_heads=32,
         num_kv_heads=8, head_dim=128, rope_theta=1_000_000.0,
         max_seq_len=32_768, num_experts=8, num_experts_per_tok=2,
+        norm_topk_prob=True,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe", vocab_size=512, hidden_size=64,
         intermediate_size=96, num_layers=2, num_heads=4, num_kv_heads=2,
         head_dim=16, rope_theta=10_000.0, max_seq_len=512,
-        num_experts=4, num_experts_per_tok=2,
+        num_experts=4, num_experts_per_tok=2, norm_topk_prob=True,
+    ),
+    # OLMoE family (allenai/OLMoE-1B-7B-0125-Instruct config.json): MHA,
+    # RMSNorm over the whole q / k vector, 64 experts of width 1024 with
+    # top-8 routing whose weights are NOT renormalised.
+    "olmoe:1b-7b": ModelConfig(
+        name="olmoe:1b-7b", vocab_size=50_304, hidden_size=2048,
+        intermediate_size=1024, num_layers=16, num_heads=16,
+        num_kv_heads=16, head_dim=128, rope_theta=10_000.0,
+        rms_norm_eps=1e-5, max_seq_len=4096, qk_norm="full",
+        num_experts=64, num_experts_per_tok=8,
+    ),
+    "test-tiny-olmoe": ModelConfig(
+        name="test-tiny-olmoe", vocab_size=512, hidden_size=64,
+        intermediate_size=32, num_layers=2, num_heads=4, num_kv_heads=4,
+        head_dim=16, rope_theta=10_000.0, max_seq_len=512, qk_norm="full",
+        num_experts=16, num_experts_per_tok=4,
     ),
 }
 

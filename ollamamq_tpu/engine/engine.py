@@ -49,7 +49,7 @@ from ollamamq_tpu.engine import kv_cache as kvc
 from ollamamq_tpu.engine.request import FinishReason, Request, StreamItem
 from ollamamq_tpu.engine.scheduler import make_policy
 from ollamamq_tpu.engine.tokenizer import load_tokenizer
-from ollamamq_tpu.models import llama, weights
+from ollamamq_tpu.models import llama, moe, weights
 from ollamamq_tpu.ops.sampling import (accept_prefix, maybe_apply_penalties,
                                        per_row_keys, sample_tokens_rowwise,
                                        sampling_flags)
@@ -508,6 +508,10 @@ class ModelRuntime:
                 raise ValueError(
                     "pp with an MoE model is not supported: the pipeline "
                     "stage body runs the dense FFN (use ep x tp for MoE)")
+            if model_cfg.qk_norm_kind == "full":
+                raise ValueError(
+                    "pp with a whole-vector q/k norm is not supported: the "
+                    "pipeline stage body norms per head")
             # forward_embed is a plain GSPMD scan: over pipe-sharded layer
             # stacks XLA would all-gather every stage's weights into each
             # group — an OOM on exactly the >HBM models pp exists for.
@@ -705,6 +709,12 @@ class ModelRuntime:
         self._tm_prefill = tm.PREFILL_LATENCY_MS.labels(model=name)
         self._tm_occupancy = tm.BATCH_OCCUPANCY.labels(model=name)
         self._tm_padding = tm.BATCH_PADDING_WASTE.labels(model=name)
+        if model_cfg.num_experts:
+            self._tm_moe_assign = tm.MOE_ASSIGNMENTS_TOTAL.labels(model=name)
+            self._tm_moe_hit = tm.MOE_EXPERT_PAIRS_HIT_TOTAL.labels(
+                model=name)
+            self._tm_moe_max = tm.MOE_EXPERT_LOAD_MAX.labels(model=name)
+            self._tm_moe_mean = tm.MOE_EXPERT_LOAD_MEAN.labels(model=name)
         self._tm_pages = tm.KV_PAGES_USED.labels(model=name)
         self._tm_page_util = tm.KV_PAGE_UTILIZATION.labels(model=name)
         self._tm_mfu = tm.MFU.labels(model=name)
@@ -923,7 +933,9 @@ class ModelRuntime:
         advances by the ACCEPTED count — never by k — so ring state is
         byte-identical to emitting the same tokens one step at a time.
         Returns (toks [S, k_cap+1], n_emit [S], caches', recent'): row i
-        emits toks[i, :n_emit[i]]."""
+        emits toks[i, :n_emit[i]]. An MoE model's `toks` has three more
+        rows: the pass's expert-load counters (moe.LOAD_STATS) ride back
+        with the ids, in the transfer the collect makes anyway."""
         key_ = ("ragged", T_pad, k_cap, flags)
         _sp_compile_evict(self, self._prefill_jits, key_)
         if key_ not in self._prefill_jits:
@@ -948,10 +960,11 @@ class ModelRuntime:
                                 jnp.minimum(j, q_len[:, None] - 1),
                                 q_len[:, None] - 1)
                 out_idx = jnp.clip(q_start[:, None] + col, 0, T_pad - 1)
-                logits, kc, vc = llama.forward_ragged(
+                logits, kc, vc, *load = llama.forward_ragged(
                     params, cfg, tokens, tok_seq, tok_pos, write_slots,
                     out_idx, kc, vc, pt, q_start, q_len, kv_len, ps,
                     attn_impl=attn_impl, mesh=mesh,
+                    moe_load=bool(cfg.num_experts),
                 )  # [S, O, V]
                 greedy_all = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 last_logits = logits[:, -1, :]
@@ -1022,12 +1035,31 @@ class ModelRuntime:
                 col0 = jnp.where(spec, greedy_all[:, 0], tok)
                 toks = jnp.concatenate([col0[:, None], greedy_all[:, 1:]],
                                        axis=1)
+                if load:
+                    toks = jnp.concatenate([toks, jnp.broadcast_to(
+                        moe.load_stats(load[0])[:, None], (3, O))])
                 return toks, n_emit, kc, vc, recent
 
             _sp_note_compile(self, "ragged", key_, self._prefill_jits,
                              jax.jit(mq_ragged_step,
                                      donate_argnums=(15, 16, 17)))
         return self._prefill_jits[key_]
+
+    def _note_moe_load(self, _sp, stats: np.ndarray) -> None:
+        """`stats` [passes, 3]: each forward pass's moe.LOAD_STATS as they
+        came back behind the sampled ids. Onto the step's sample (sums
+        over its passes; the largest load of any; the mean rows a (layer,
+        expert) pair got in a pass) and the /metrics series."""
+        cfg = self.cfg
+        n, hit, top = (int(stats[:, 0].sum()), int(stats[:, 1].sum()),
+                       int(stats[:, 2].max()))
+        mean = n / (len(stats) * cfg.num_layers * cfg.num_experts)
+        _sp.note(moe_assignments=n, moe_pairs_hit=hit, moe_load_max=top,
+                 moe_load_mean=round(mean, 4))
+        self._tm_moe_assign.inc(n)
+        self._tm_moe_hit.inc(hit)
+        self._tm_moe_max.set(top)
+        self._tm_moe_mean.set(mean)
 
     def _dev(self, name: str, arr) -> jnp.ndarray:
         """Content-fingerprinted device cache for small per-slot arrays.
@@ -1300,9 +1332,10 @@ class ModelRuntime:
                             mesh, n_micro=n_micro, attn_impl=attn_impl,
                         )
                     else:
-                        logits, kc, vc = llama.forward_decode(
+                        logits, kc, vc, *load = llama.forward_decode(
                             params, cfg, tokens, positions, kc, vc, pt, ps,
                             attn_impl=attn_impl, active=active, mesh=mesh,
+                            moe_load=bool(cfg.num_experts),
                         )
                     key, sub = jax.random.split(key)
                     pen_logits = maybe_apply_penalties(logits, recent[:S],
@@ -1325,13 +1358,16 @@ class ModelRuntime:
                     )
                     new_rows = jnp.where(active[:, None] > 0, rolled, recent[:S])
                     recent = recent.at[:S].set(new_rows)
-                    return (nxt, positions + 1, kc, vc, recent, key), nxt
+                    out = nxt
+                    if pp == 1 and load:  # the pass's counters, behind the ids
+                        out = jnp.concatenate([nxt, moe.load_stats(load[0])])
+                    return (nxt, positions + 1, kc, vc, recent, key), out
 
                 (tokens, positions, kc, vc, recent, key), toks = jax.lax.scan(
                     step, (tokens, positions, kc, vc, recent, key), None,
                     length=k_steps,
                 )
-                return toks, kc, vc, recent  # toks: [K, S]
+                return toks, kc, vc, recent  # toks: [K, S] (MoE: [K, S+3])
 
             _sp_note_compile(self, "decode", key_, self._decode_jits,
                              jax.jit(mq_decode_scan, donate_argnums=(3, 4, 5)))
@@ -2774,6 +2810,8 @@ class ModelRuntime:
             toks = np.asarray(toks_dev)  # [S, k_cap+1]
             n_emit = np.asarray(n_emit_dev)  # [S]
             _sp.mark("collect")
+            if self.cfg.num_experts:
+                self._note_moe_load(_sp, toks[len(n_emit):, :1].T)
         except Exception as e:
             self._jrec("batch", **batch_fields)
             self._ragged_failed(rows, e, core)
@@ -3018,6 +3056,8 @@ class ModelRuntime:
         t_done = time.monotonic()
         if _sp is not None:
             _sp.mark("collect")
+            if self.cfg.num_experts:
+                self._note_moe_load(_sp, toks[:, len(self.slot_req):])
         self.step_latency_ms = (t_done - t_block) * 1e3 / k_steps
         self.step_window.append(self.step_latency_ms)
         self._tm_step.observe(self.step_latency_ms)

@@ -16,7 +16,9 @@ Design notes (TPU-first):
     logits in f32.
   - Qwen2.5 support = `attn_bias=True` in ModelConfig; the same code path
     serves both families (capability parity with the reference's two
-    stress-test models, /root/reference/test_dispatcher.sh:5-7).
+    stress-test models, /root/reference/test_dispatcher.sh:5-7). Qwen3 and
+    OLMoE are `qk_norm` ("head" / "full"); Mixtral and OLMoE replace the
+    FFN by routed experts (`num_experts`, models/moe.py).
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ollamamq_tpu.config import ModelConfig
+from ollamamq_tpu.models.moe import STACKED, init_moe_layer_params, moe_mlp
 from ollamamq_tpu.ops.attention import (
     causal_attention,
     bidirectional_attention,
@@ -41,7 +44,7 @@ from ollamamq_tpu.ops.rope import apply_rope
 # Stage names on the device trace (jax.named_scope: op metadata only, the
 # lowered programs compute the same thing). README's span table lists
 # them; tests/test_trace_spans.py finds each in the lowered ragged and
-# decode programs.
+# decode programs. An MoE model's "mlp" holds models/moe.py:SCOPES.
 SCOPES = ("embed", "attn_qkv", "kv_write", "attention", "attn_out", "mlp",
           "lm_head", "sampling")
 
@@ -83,14 +86,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         layers["bq"] = jnp.zeros((L, qd), dtype)
         layers["bk"] = jnp.zeros((L, kvd), dtype)
         layers["bv"] = jnp.zeros((L, kvd), dtype)
-    if cfg.qk_norm:
+    if cfg.qk_norm_kind == "head":
         # Qwen3: per-head RMSNorm on q/k (weight over head_dim).
         layers["q_norm"] = jnp.ones((L, cfg.head_dim), dtype)
         layers["k_norm"] = jnp.ones((L, cfg.head_dim), dtype)
+    elif cfg.qk_norm_kind == "full":
+        # OLMoE: RMSNorm over the whole projected q / k vector.
+        layers["q_norm"] = jnp.ones((L, qd), dtype)
+        layers["k_norm"] = jnp.ones((L, kvd), dtype)
     if cfg.num_experts:
         # MoE family: the dense FFN is replaced by routed experts.
-        from ollamamq_tpu.models.moe import init_moe_layer_params
-
         for dense in ("w_gate", "w_up", "w_down"):
             del layers[dense]
         layers.update(init_moe_layer_params(cfg, keys[9], dtype))
@@ -114,10 +119,15 @@ def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
+    if cfg.qk_norm_kind == "full":
+        # Over all heads' lanes at once, BEFORE the split into heads (under
+        # tp the lanes are sharded: GSPMD reduces the mean across shards).
+        q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
     q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
     k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
+    if cfg.qk_norm_kind == "head":
         q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
     return q, k, v
@@ -129,18 +139,21 @@ def _mlp(lp: dict, h: jnp.ndarray) -> jnp.ndarray:
     return qeinsum("btf,fd->btd", jax.nn.silu(gate) * up, lp["w_down"])
 
 
-def _ffn(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
-         valid=None) -> jnp.ndarray:
-    """Dense SwiGLU or routed mixture-of-experts, by model family.
+def _ffn(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
+         mesh=None, impl: str = "jnp", layer=None):
+    """Dense SwiGLU or routed mixture-of-experts, by model family; returns
+    (delta, expert load [E] int32 — None for a dense model).
 
-    `valid` ([B, T] bool) marks real tokens; only MoE routing consumes it
-    (padding/inactive rows must not claim expert capacity).
+    `valid` ([B, T] bool) marks real tokens, `mesh` is the caller's jit
+    mesh and `impl` its `attn_impl`; only MoE routing consumes them
+    (padding/inactive rows are routed to no expert; the grouped matmul
+    runs under a shard_map, as a Pallas kernel or its XLA twin). `layer`:
+    inside `scan_layers`, where `lp` holds the expert stacks whole.
     """
     if cfg.num_experts:
-        from ollamamq_tpu.models.moe import moe_mlp
-
-        return moe_mlp(cfg, lp, h, valid=valid)
-    return _mlp(lp, h)
+        return moe_mlp(cfg, lp, h, valid=valid, mesh=mesh, impl=impl,
+                       layer=layer)
+    return _mlp(lp, h), None
 
 
 @jax.named_scope("lm_head")
@@ -156,34 +169,43 @@ def scan_layers(body, x, layers, k_cache, v_cache):
     parallel/pipeline.py).
 
     The pool is the loop's CARRY: `body(x, lp, l, k_cache, v_cache) ->
-    (x, k_cache, v_cache)` gets the layer index `l`, writes with one
-    scatter on the carried pool (ops/quant.kv_write) and attends over
-    `pool[l]` by index, and the loop returns the buffers it was given —
-    with the jit sites' donation, XLA updates the pool in place. A pool
+    (x, k_cache, v_cache, per_layer)` gets the layer index `l`, writes
+    with one scatter on the carried pool (ops/quant.kv_write) and attends
+    over `pool[l]` by index, and the loop returns the buffers it was given
+    — with the jit sites' donation, XLA updates the pool in place. A pool
     passed as a scan's xs and returned as its ys cannot alias: every pass
     would build a second pool and copy each layer out and back.
+    `per_layer` (small: an MoE layer's expert load, else None) comes back
+    stacked [L, ...] as the fourth result. An MoE model's expert stacks
+    (moe.STACKED) are not sliced by the scan either: `lp` holds them
+    whole, for `_ffn(..., layer=l)` to read by index.
     """
     n_layers = k_cache.shape[0]
+    whole = {k: w for k, w in layers.items() if k in STACKED}
+    sliced = {k: w for k, w in layers.items() if k not in STACKED}
 
     def step(carry, per_layer):
         x, kc, vc = carry
         lp, l = per_layer
-        return body(x, lp, l, kc, vc), None
+        x, kc, vc, out = body(x, {**lp, **whole}, l, kc, vc)
+        return (x, kc, vc), out
 
-    (x, k_cache, v_cache), _ = jax.lax.scan(
+    (x, k_cache, v_cache), outs = jax.lax.scan(
         step, (x, k_cache, v_cache),
-        (layers, jnp.arange(n_layers, dtype=jnp.int32)))
-    return x, k_cache, v_cache
+        (sliced, jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, k_cache, v_cache, outs
 
 
 def _layer_step(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-                positions: jnp.ndarray, attn_fn, valid=None):
+                positions: jnp.ndarray, attn_fn, valid=None, mesh=None,
+                impl: str = "jnp", layer=None):
     """One transformer layer over a full [B, T, D] sequence.
 
     The SINGLE definition of the layer math for every full-sequence
     forward (prefill, sequence-parallel prefill, encoder) — only the
     attention schedule differs, injected as `attn_fn(q, k, v) -> [B,T,H,hd]`.
-    Returns (x', k, v) so callers can scatter K/V into the paged cache.
+    Returns (x', k, v, expert load) so callers can scatter K/V into the
+    paged cache; the load is None for a dense model (`_ffn`).
     (forward_decode keeps its own body: it must write K/V into the
     loop-carried pool BEFORE attending.)
     """
@@ -199,8 +221,9 @@ def _layer_step(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
                         lp["wo"])
     with jax.named_scope("mlp"):
         h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(cfg, lp, h2, valid=valid)
-    return x, k, v
+        delta, load = _ffn(cfg, lp, h2, valid=valid, mesh=mesh, impl=impl,
+                           layer=layer)
+    return x + delta, k, v, load
 
 
 def forward_prefill(
@@ -224,15 +247,15 @@ def forward_prefill(
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, T]
 
     def body(x, lp, l, kc, vc):
-        x, k, v = _layer_step(
+        x, k, v, load = _layer_step(
             cfg, lp, x, positions,
             lambda q, k, v: causal_attention(q, k, v, seq_lens),
-            valid=positions < seq_lens[:, None],
+            valid=positions < seq_lens[:, None], layer=l,
         )
-        return x, kv_write(kc, l, slots, k), kv_write(vc, l, slots, v)
+        return x, kv_write(kc, l, slots, k), kv_write(vc, l, slots, v), load
 
-    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
-                                      v_cache)
+    x, k_cache, v_cache, _ = scan_layers(body, x, params["layers"], k_cache,
+                                         v_cache)
     last = jnp.clip(seq_lens - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,D]
     logits = _logits(params, cfg, x_last)[:, 0, :]  # [B, V]
@@ -274,14 +297,15 @@ def forward_prefill_chunk(
                 q, kc, vc, l, page_table, start, chunk_lens, page_size
             )
 
-        x, _, _ = _layer_step(
+        x, _, _, load = _layer_step(
             cfg, lp, x, positions, attn_fn,
             valid=jnp.arange(tokens.shape[1])[None, :] < chunk_lens[:, None],
+            layer=l,
         )
-        return x, kc, vc
+        return x, kc, vc, load
 
-    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
-                                      v_cache)
+    x, k_cache, v_cache, _ = scan_layers(body, x, params["layers"], k_cache,
+                                         v_cache)
     last = jnp.clip(chunk_lens - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
     logits = _logits(params, cfg, x_last)[:, 0, :]
@@ -306,7 +330,8 @@ def forward_ragged(
     attn_impl: str = "jnp",  # "jnp" reference | "pallas" ragged TPU kernel
     interpret: bool = False,
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    moe_load: bool = False,  # also return the [L, E] expert loads
+):
     """ONE forward over a ragged mixed batch: variable-length prefill
     spans and single decode tokens share a flattened [T] token stream —
     no per-sequence bucket padding. Each layer writes the stream's K/V
@@ -318,7 +343,8 @@ def forward_ragged(
     a [B, O] matrix (speculative verification reads a logit at EVERY
     draft position of a span) returns [B, O, V]. Padding rows
     (q_len == 0) yield garbage logits the caller ignores. Returns
-    (logits, caches').
+    (logits, caches'), and with `moe_load` (an MoE model's step program
+    asks) the rows each expert of each layer got, [L, E] int32, fourth.
     """
     T = tokens.shape[0]
     with jax.named_scope("embed"):
@@ -341,17 +367,21 @@ def forward_ragged(
                 )
             return out[None]
 
-        x, _, _ = _layer_step(cfg, lp, x, positions, attn_fn, valid=valid)
-        return x, kc, vc
+        x, _, _, load = _layer_step(cfg, lp, x, positions, attn_fn,
+                                    valid=valid, mesh=mesh, impl=attn_impl,
+                                    layer=l)
+        return x, kc, vc, load
 
-    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
-                                      v_cache)
+    x, k_cache, v_cache, load = scan_layers(body, x, params["layers"],
+                                            k_cache, v_cache)
     if out_idx.ndim == 1:
         x_last = x[0][out_idx]  # [B, D]
         logits = _logits(params, cfg, x_last[None])[0]  # [B, V]
     else:
         x_last = x[0][out_idx]  # [B, O, D]
         logits = _logits(params, cfg, x_last)  # [B, O, V]
+    if moe_load:
+        return logits, k_cache, v_cache, load
     return logits, k_cache, v_cache
 
 
@@ -367,11 +397,13 @@ def forward_decode(
     attn_impl: str = "jnp",  # "jnp" reference | "pallas" ragged TPU kernel
     active=None,  # [B] int32/bool — live decode slots (None = all live)
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One decode step for the whole batch; returns (logits [B,V], caches').
+    moe_load: bool = False,  # also return the [L, E] expert loads
+):
+    """One decode step for the whole batch; returns (logits [B,V], caches')
+    and, with `moe_load`, the [L, E] expert loads (as forward_ragged).
 
     `active` feeds MoE routing only: parked slots carry garbage tokens
-    that must not claim expert capacity (models/moe.py).
+    that are routed to no expert (models/moe.py).
     """
     B = tokens.shape[0]
     valid = None if active is None else (active > 0)[:, None]
@@ -401,12 +433,15 @@ def forward_decode(
                             lp["wo"])[:, None, :]
         with jax.named_scope("mlp"):
             h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _ffn(cfg, lp, h2, valid=valid)
-        return x, kc, vc
+            delta, load = _ffn(cfg, lp, h2, valid=valid, mesh=mesh,
+                               impl=attn_impl, layer=l)
+        return x + delta, kc, vc, load
 
-    x, k_cache, v_cache = scan_layers(body, x, params["layers"], k_cache,
-                                      v_cache)
+    x, k_cache, v_cache, load = scan_layers(body, x, params["layers"],
+                                            k_cache, v_cache)
     logits = _logits(params, cfg, x)[:, 0, :]
+    if moe_load:
+        return logits, k_cache, v_cache, load
     return logits, k_cache, v_cache
 
 
@@ -437,7 +472,7 @@ def forward_prefill_sp(
 
     def body(carry, lp):
         x = carry
-        x, k, v = _layer_step(
+        x, k, v, _ = _layer_step(
             cfg, lp, x, positions,
             lambda q, k, v: ring_attention(q, k, v, seq_lens, mesh),
             valid=positions < seq_lens[:, None],
@@ -469,7 +504,7 @@ def forward_embed(
 
     def body(carry, lp):
         x = carry
-        x, _, _ = _layer_step(
+        x, *_ = _layer_step(
             cfg, lp, x, positions,
             lambda q, k, v: causal_attention(q, k, v, seq_lens),
             valid=positions < seq_lens[:, None],
@@ -496,7 +531,7 @@ def forward_encoder(
 
     def body(carry, lp):
         x = carry
-        x, _, _ = _layer_step(
+        x, *_ = _layer_step(
             cfg, lp, x, positions,
             lambda q, k, v: bidirectional_attention(q, k, v, seq_lens),
             valid=positions < seq_lens[:, None],

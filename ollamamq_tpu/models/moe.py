@@ -1,38 +1,68 @@
-"""Mixture-of-experts FFN (Mixtral family) with expert parallelism.
+"""Mixture-of-experts FFN (Mixtral, OLMoE) with expert parallelism.
 
 The reference serves MoE models only by proxying to an Ollama backend that
 happens to run one (llama.cpp does the routing on CPU/GPU); it has no
-expert-parallel story at all. Here MoE is a first-class layer family:
+expert-parallel story at all. Here MoE is a first-class layer family, with
+ONE dispatch for every model of it:
 
-  - Routing is token-choice top-k (Mixtral semantics: softmax over all
-    experts, take top-k, renormalize the kept probabilities).
-  - Dispatch/combine use the GShard dense formulation — one-hot
-    position-in-expert tensors contracted with einsum — because that is
-    the shape-static, compiler-friendly layout: no gather/scatter with
-    data-dependent sizes, everything tiles onto the MXU, and XLA's SPMD
-    partitioner turns the [E, C, D] dispatch einsum into the expert
-    all-to-all when `we_*` are sharded over the mesh "expert" axis.
-  - Per-expert capacity C = ceil(N*k/E * capacity_factor) is STATIC.
-    Tokens routed past an expert's capacity contribute nothing for that
-    expert slot (their combine weight is zero) and fall through to the
-    residual stream — the standard token-dropping trade, bounded by the
-    capacity factor (config.moe_capacity_factor, default 2.0).
+  - Routing is token-choice top-k: softmax in float32 over all experts,
+    take the top k. `ModelConfig.norm_topk_prob` says whether the kept
+    probabilities are renormalised (Mixtral: yes; OLMoE: no).
+  - The dispatch is DROPLESS: the (token, k-slot) assignments are sorted
+    by expert, each expert's contiguous rows go through its SwiGLU as
+    three grouped matmuls, and the rows are un-sorted and summed with
+    their router weights. Shapes are static (tokens x k rows, whatever
+    the routing), so there is no capacity, nothing to overflow, and every
+    token's every routed expert contributes, exactly, at any token count.
+  - The grouped matmul has a kernel and a twin, chosen with the attention
+    kernels (`impl`, the forwards' `attn_impl`): "pallas" is jax's
+    megablox `gmm` (op `gmm` on the device trace), tiled so that a
+    128-row tile meets a whole [2048, 1024] weight tile — a decode pass
+    is bound by streaming each hit expert's weights once; anything else
+    is `jax.lax.ragged_dot` (XLA's own: `ragged-dot` on a TPU trace, where
+    its 512-row tiles make the same pass compute-bound, 2.3 x slower on a
+    v5e; PERF.md section 6, PR 27).
+  - Padding rows and idle decode slots (`valid` false) are assigned to no
+    expert: they sort behind every group, are multiplied by no weight and
+    count in no statistic.
+  - Sharding: `we_*` split over the mesh axes "expert" (the expert dim)
+    and "tensor" (the per-expert FFN dim), parallel/sharding.py. XLA
+    cannot partition the grouped matmul, so on a mesh the expert FFN runs
+    under a shard_map: every shard sees the step's sorted rows, computes
+    its own experts' (and its own FFN columns') part, and one psum joins
+    the parts. A step is at most a few thousand rows, so no all-to-all is
+    needed.
 
-Expert weights are stacked [L, E, ...] so the layer scan carries them like
-every other layer param; the "expert" dim shards over AXIS_EXPERT and the
-per-expert FFN dim over AXIS_TENSOR (parallel/sharding.py), composing
-EP x TP without any code change here — GSPMD propagates from the weight
-shardings.
+Expert weights are stacked [L, E, ...]. The step forwards do NOT hand the
+layer loop a slice of them: a kernel's operand cannot be a fused slice, so
+XLA would copy each layer's three matrices out of the stack (0.8 GB a layer
+for OLMoE: twice the bytes the matmuls themselves read). Like the KV pool
+they stay whole (`STACKED`, models/llama.py:scan_layers) and `moe_mlp` reads
+them by `layer`: the kernel sees [L*E, ...] and group sizes that are zero
+outside this layer's experts. `moe_mlp` also returns how many rows each
+expert got: the step programs reduce that to the counters of `load_stats`.
 """
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
 
 from ollamamq_tpu.config import ModelConfig
+from ollamamq_tpu.parallel.mesh import AXIS_EXPERT, AXIS_TENSOR
+
+# Stage names inside llama's "mlp" scope on the device trace, in order.
+SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+# Layer params the layer loop reads whole, by layer index.
+STACKED = ("we_gate", "we_up", "we_down")
+# What load_stats() returns, in order (int32 each).
+LOAD_STATS = ("assignments", "pairs_hit", "load_max")
+# megablox tiling (rows, contraction, columns) of the "pallas" grouped
+# matmul, clipped to the matrices' own sizes: of the four measured on a v5e
+# at OLMoE's shapes (PERF.md section 6, PR 27) the one nearest the
+# weight-streaming floor from 128 to 4096 rows.
+GMM_TILING = (128, 2048, 1024)
 
 
 def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
@@ -54,80 +84,136 @@ def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     }
 
 
-def expert_capacity(n_tokens: int, cfg: ModelConfig) -> int:
-    """Static per-expert token capacity for a batch of n_tokens."""
-    ideal = n_tokens * cfg.num_experts_per_tok / cfg.num_experts
-    return max(1, int(math.ceil(ideal * cfg.moe_capacity_factor)))
+def grouped_matmul(impl: str, xs, w, sizes, interpret: bool = False):
+    """Row r of xs [M, k], in group g of the consecutive `sizes` [G], times
+    w[g] ([G, k, n]) -> [M, n]; rows past sum(sizes) come back as whatever.
+    With "pallas", M is a multiple of GMM_TILING[0]."""
+    if impl != "pallas":
+        return jax.lax.ragged_dot(xs, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    tm, tk, tn = GMM_TILING
+    return gmm(xs, w, sizes, xs.dtype,
+               (tm, min(tk, w.shape[1]), min(tn, w.shape[2])),
+               interpret=interpret)
 
 
-def group_size(n_tokens: int, cap: int = 512) -> int:
-    """Tokens per routing group: largest divisor of n_tokens <= cap.
+def _expert_ffn(impl, xs, sizes, w_gate, w_up, w_down, layer=None):
+    """SwiGLU of each expert over its own rows. xs [M, D] sorted by expert,
+    sizes [E] rows per expert (rows past their sum belong to no expert and
+    come back as whatever: the caller masks them). With `layer` the weights
+    are whole stacks [L, E, ...]."""
+    m = xs.shape[0]
+    if layer is not None and impl == "pallas":
+        # Every layer's experts as groups; all but this layer's are empty.
+        n_e = sizes.shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros(w_gate.shape[0] * n_e, sizes.dtype), sizes,
+            (layer * n_e,))
+        w_gate, w_up, w_down = (w.reshape(-1, *w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+    elif layer is not None:
+        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
+    if impl == "pallas":  # whole row tiles; the added rows are no group's
+        xs = jnp.pad(xs, ((0, -m % GMM_TILING[0]), (0, 0)))
+    gate = grouped_matmul(impl, xs, w_gate, sizes)
+    up = grouped_matmul(impl, xs, w_up, sizes)
+    return grouped_matmul(impl, jax.nn.silu(gate) * up, w_down, sizes)[:m]
 
-    Without grouping, capacity C grows with N and the dispatch one-hots /
-    einsums scale O(N^2) — a long-prefill HBM and FLOPs blowup. GShard's
-    fix is a group dimension: capacity is computed per fixed-size group,
-    so dispatch cost stays linear in tokens."""
-    g = min(cap, n_tokens)
-    while n_tokens % g:
-        g -= 1
-    return max(g, 1)
+
+def _expert_ffn_sharded(mesh, impl, xs, sizes, w_gate, w_up, w_down, layer):
+    """_expert_ffn over a mesh, on the whole [L, E, ...] stacks: each shard
+    runs the experts (and the FFN columns) it stores over all of the step's
+    rows; a psum over "expert" and "tensor" joins the parts."""
+    ep = mesh.shape[AXIS_EXPERT]
+
+    def local(xs, sizes, w_gate, w_up, w_down, layer):
+        if ep == 1:
+            y = _expert_ffn(impl, xs, sizes, w_gate, w_up, w_down, layer)
+        else:
+            # This shard's experts own one contiguous run of the sorted
+            # rows; the grouped matmul wants its first group at row 0.
+            n_loc = sizes.shape[0] // ep
+            first = jax.lax.axis_index(AXIS_EXPERT) * n_loc
+            mine = jax.lax.dynamic_slice(sizes, (first,), (n_loc,))
+            start = jnp.sum(jnp.where(jnp.arange(sizes.shape[0]) < first,
+                                      sizes, 0))
+            y = _expert_ffn(impl, jnp.roll(xs, -start, axis=0), mine,
+                            w_gate, w_up, w_down, layer)
+            rows = jnp.arange(xs.shape[0])[:, None]
+            y = jnp.roll(jnp.where(rows < jnp.sum(mine), y, 0), start, axis=0)
+        return jax.lax.psum(y, (AXIS_EXPERT, AXIS_TENSOR))
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(PS(), PS(), PS(None, AXIS_EXPERT, None, AXIS_TENSOR),
+                  PS(None, AXIS_EXPERT, None, AXIS_TENSOR),
+                  PS(None, AXIS_EXPERT, AXIS_TENSOR, None), PS()),
+        out_specs=PS(), check_vma=False,
+    )(xs, sizes, w_gate, w_up, w_down, layer)
 
 
-def moe_mlp(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
-            valid=None) -> jnp.ndarray:
-    """Top-k routed expert FFN over [B, T, D] hiddens; returns [B, T, D].
+def moe_mlp(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
+            mesh=None, impl: str = "jnp", layer=None):
+    """Top-k routed expert FFN over [B, T, D] hiddens; returns ([B, T, D],
+    load [E] int32: the rows each expert got).
 
     Same contract as llama._mlp (the residual add happens in the caller).
     `valid` ([B, T] bool, optional) marks real tokens: padding positions
-    and inactive decode slots must not CLAIM expert capacity, or identical
-    garbage rows (all routing alike) crowd real tokens out of their
-    experts' queues and silently zero their FFN delta.
-
-    Tokens route in groups of <= 512 (GShard's group dim): capacity and
-    the dispatch/combine one-hots are per-group, keeping dispatch cost
-    linear in sequence length.
+    and inactive decode slots are routed to no expert. `mesh`: the mesh the
+    caller's jit runs over when `we_*` are sharded on it (None on one
+    device, or where the caller leaves the layout to GSPMD; with a mesh,
+    `layer` is given too). `impl`: the grouped matmul, as the forward's
+    `attn_impl`. `layer`: with it, `lp[STACKED]` are the whole [L, E, ...]
+    stacks and this is the layer.
     """
     B, T, D = h.shape
     E, K = cfg.num_experts, cfg.num_experts_per_tok
     N = B * T
-    G = group_size(N)
-    n_g = N // G
-    C = expert_capacity(G, cfg)
-    x = h.reshape(n_g, G, D)
+    x = h.reshape(N, D)
 
-    # Router in f32: the softmax is over a handful of experts and feeds
-    # multiplicative gates — bf16 here costs real quality for no speed.
-    logits = jnp.einsum(
-        "gnd,de->gne", x.astype(jnp.float32), lp["w_router"].astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(logits, axis=-1)  # [g, G, E]
-    gate_vals, expert_idx = jax.lax.top_k(probs, K)  # [g, G, K]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    with jax.named_scope("moe_router"):
+        # Router in f32: the softmax feeds multiplicative gates — bf16
+        # here costs real quality for no speed.
+        logits = jnp.dot(x.astype(jnp.float32),
+                         lp["w_router"].astype(jnp.float32))
+        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        if cfg.norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
 
-    # Position of each (token, k-slot) in its expert's per-group queue,
-    # token-major (GShard "first C win"). sel: [g, G, K, E] one-hot on the
-    # routed expert; invalid tokens select nothing (=> no capacity claim).
-    sel = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)
-    if valid is not None:
-        sel = sel * valid.reshape(n_g, G).astype(jnp.int32)[..., None, None]
-    pos = jnp.cumsum(sel.reshape(n_g, G * K, E), axis=1).reshape(sel.shape) - sel
-    keep = (pos < C) & (sel > 0)  # [g, G, K, E]
+    with jax.named_scope("moe_dispatch"):
+        # Assignment a = (token a // K, slot a % K). An invalid token's
+        # assignments go to "expert E": behind every real group.
+        if valid is not None:
+            experts = jnp.where(valid.reshape(N, 1), experts, E)
+        flat = experts.reshape(N * K)
+        order = jnp.argsort(flat, stable=True)
+        load = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
+                       dtype=jnp.int32)
+        xs = x[order // K]  # [N*K, D], sorted by expert
 
-    # One-hot (token, k-slot) -> (expert, capacity-slot); dropped and
-    # unrouted entries point at index C, whose one-hot row is all zeros.
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, C), C, dtype=h.dtype)
-    dispatch = jnp.sum(pos_oh, axis=2)  # [g, G, E, C] 0/1 (k-slots disjoint)
-    combine = jnp.einsum(
-        "gnkec,gnk->gnec", pos_oh, gate_vals.astype(h.dtype)
-    )  # [g, G, E, C] gate weights
+    with jax.named_scope("moe_experts"):
+        if mesh is None or mesh.size == 1:
+            ys = _expert_ffn(impl, xs, load, lp["we_gate"], lp["we_up"],
+                             lp["we_down"], layer)
+        else:
+            ys = _expert_ffn_sharded(mesh, impl, xs, load, lp["we_gate"],
+                                     lp["we_up"], lp["we_down"], layer)
 
-    # Expert compute on the dispatched [g, E, C, D] blocks — the einsums
-    # XLA partitions over "expert"/"tensor" when we_* carry those
-    # shardings (the group dim stays local).
-    xe = jnp.einsum("gnec,gnd->gecd", dispatch, x)
-    gate = jnp.einsum("gecd,edf->gecf", xe, lp["we_gate"])
-    up = jnp.einsum("gecd,edf->gecf", xe, lp["we_up"])
-    out_e = jnp.einsum("gecf,efd->gecd", jax.nn.silu(gate) * up, lp["we_down"])
+    with jax.named_scope("moe_combine"):
+        rank = jnp.zeros_like(order).at[order].set(
+            jnp.arange(N * K, dtype=order.dtype))  # row of assignment a
+        y = ys[rank].reshape(N, K, D)
+        w = jnp.where(experts < E, gates, 0.0)  # [N, K]
+        y = jnp.where((experts < E)[..., None], y, 0)  # unowned rows: anything
+        out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
+    return out.astype(h.dtype).reshape(B, T, D), load
 
-    y = jnp.einsum("gnec,gecd->gnd", combine, out_e)  # gates applied here
-    return y.reshape(B, T, D)
+
+def load_stats(load: jnp.ndarray) -> jnp.ndarray:
+    """int32 [3] (LOAD_STATS) of one forward pass's [L, E] expert loads:
+    assignments made (real tokens x k x layers), (layer, expert) pairs
+    that got at least one row — each streams its weights once — and the
+    most rows any one expert of any layer got."""
+    return jnp.stack([jnp.sum(load), jnp.sum(load > 0),
+                      jnp.max(load)]).astype(jnp.int32)

@@ -80,11 +80,19 @@ def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
     one jit whose outputs carry the serving shardings, so every device
     draws only its own shard (the values do not depend on the sharding:
     jax's threefry is partitionable) — a model larger than one chip's
-    HBM never has to exist whole on the default device first."""
+    HBM never has to exist whole on the default device first.
+
+    Without a mesh a dense model is drawn eagerly, stack by stack (each
+    holds three float32 copies while it is made: that, and not a step
+    program, is a one-chip server's memory peak). An MoE model's expert
+    stacks are too large for that — OLMoE's 64 experts x 10 layers are
+    1.34 G parameters a stack, 3 x 5.4 GB of float32 — so its tree is
+    drawn under one jit, whose fused draw-scale-cast holds no float32
+    stack at all."""
     init = functools.partial(llama.init_params, cfg, dtype=dtype)
     key = jax.random.PRNGKey(seed)
     if mesh is None:
-        return init(key)
+        return jax.jit(init)(key) if cfg.num_experts else init(key)
     from jax.sharding import NamedSharding
 
     from ollamamq_tpu.parallel.sharding import (param_partition_specs,
@@ -137,17 +145,24 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
         layers[ours] = jnp.asarray(stack, dtype=dtype)
 
     if cfg.num_experts:
-        # Mixtral layout: block_sparse_moe.gate + experts.N.w1/w3/w2
-        # (gate/up/down). Stack experts on axis 1 -> [L, E, D, F] etc.
+        # Two layouts, told apart by the router's name. Mixtral:
+        # block_sparse_moe.gate + experts.N.w1/w3/w2 (gate/up/down).
+        # OLMoE: mlp.gate + mlp.experts.N.{gate,up,down}_proj.
+        # Stack experts on axis 1 -> [L, E, D, F] etc.
         # Host-RAM discipline: the expert stacks dominate the checkpoint
         # (~90% of an 8x7b), so cast each LAYER's expert stack to the
         # target dtype immediately and pop the consumed raw tensors —
         # peak host memory stays near one f32 layer-stack (~2 GB for
         # 8x7b) above the raw checkpoint, instead of ~2.5x it.
+        mixtral = "model.layers.0.block_sparse_moe.gate.weight" in raw
+        block = "block_sparse_moe" if mixtral else "mlp"
+        gate_up_down = (("w1", "w3", "w2") if mixtral else
+                        ("gate_proj", "up_proj", "down_proj"))
+
         def estack(w_name: str, transpose: bool):
             per_layer = []
             for i in range(cfg.num_layers):
-                names = [f"model.layers.{i}.block_sparse_moe.experts."
+                names = [f"model.layers.{i}.{block}.experts."
                          f"{e}.{w_name}.weight"
                          for e in range(cfg.num_experts)]
                 stack = np.stack([grab(n, transpose) for n in names])
@@ -157,12 +172,12 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
             return jnp.stack(per_layer)
 
         layers["w_router"] = jnp.asarray(np.stack([
-            grab(f"model.layers.{i}.block_sparse_moe.gate.weight", True)
+            grab(f"model.layers.{i}.{block}.gate.weight", True)
             for i in range(cfg.num_layers)
         ]), dtype=dtype)
-        layers["we_gate"] = estack("w1", True)
-        layers["we_down"] = estack("w2", True)
-        layers["we_up"] = estack("w3", True)
+        layers["we_gate"] = estack(gate_up_down[0], True)
+        layers["we_up"] = estack(gate_up_down[1], True)
+        layers["we_down"] = estack(gate_up_down[2], True)
 
     params = {
         "embed": jnp.asarray(grab("model.embed_tokens.weight", False), dtype=dtype),
@@ -266,7 +281,7 @@ def _full_logits(params: dict, cfg: ModelConfig, tokens) -> jnp.ndarray:
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
     def body(carry, lp):
-        x, _, _ = llama._layer_step(
+        x, *_ = llama._layer_step(
             cfg, lp, carry, positions,
             lambda q, k, v: causal_attention(q, k, v, seq_lens))
         return x, None

@@ -93,7 +93,7 @@ def _tp_qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     q = q.reshape(B, T, q.shape[-1] // hd, hd)
     k = k.reshape(B, T, k.shape[-1] // hd, hd)
     v = v.reshape(B, T, v.shape[-1] // hd, hd)
-    if cfg.qk_norm:
+    if cfg.qk_norm_kind == "head":  # "full" is refused at start-up
         q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
     return q, k, v
@@ -134,9 +134,10 @@ def _stage(cfg, layers, x, positions, kc, vc, attn_and_cache):
     pool is the loop's carry (models/llama.py:scan_layers)."""
 
     def body(x, lp, l, kc, vc):
-        return _tp_layer(cfg, lp, x, positions, l, kc, vc, attn_and_cache)
+        return *_tp_layer(cfg, lp, x, positions, l, kc, vc,
+                          attn_and_cache), None
 
-    return scan_layers(body, x, layers, kc, vc)
+    return scan_layers(body, x, layers, kc, vc)[:3]
 
 
 # ---------------------------------------------------------------------------

@@ -58,6 +58,7 @@ def active_param_count(cfg) -> int:
         d * cfg.q_dim + 2 * d * cfg.kv_dim + cfg.q_dim * d
         + mlp
         + 2 * d
+        + cfg.qk_norm_params()
     )
     embed = v * d * (1 if cfg.tie_embeddings else 2)
     return cfg.num_layers * per_layer + embed + d

@@ -45,6 +45,26 @@ BATCH_PADDING_WASTE = REGISTRY.gauge(
     "padding (0..1): bucket rows minus real tokens on the bucketed path, "
     "the granule tail on the ragged path — the compute burned for shape "
     "stability", labels=("model",))
+# -- mixture-of-experts routing (models/moe.py; MoE models only) -----------
+# Counted on the device inside the step program and read back with the
+# sampled ids. No "dropped" series: the dispatch has no capacity to pass.
+MOE_ASSIGNMENTS_TOTAL = REGISTRY.counter(
+    "ollamamq_moe_assignments_total",
+    "(token, expert) assignments the router made: real tokens x experts "
+    "per token x layers, summed over forward passes", labels=("model",))
+MOE_EXPERT_PAIRS_HIT_TOTAL = REGISTRY.counter(
+    "ollamamq_moe_expert_pairs_hit_total",
+    "(layer, expert) pairs that got at least one token in a forward pass, "
+    "summed over passes — each streams that expert's weights once",
+    labels=("model",))
+MOE_EXPERT_LOAD_MAX = REGISTRY.gauge(
+    "ollamamq_moe_expert_load_max",
+    "Most tokens any one expert of any layer got in a forward pass of the "
+    "last dispatch", labels=("model",))
+MOE_EXPERT_LOAD_MEAN = REGISTRY.gauge(
+    "ollamamq_moe_expert_load_mean",
+    "Mean tokens a (layer, expert) pair got in a forward pass of the last "
+    "dispatch (assignments / passes / layers / experts)", labels=("model",))
 KV_PAGES_USED = REGISTRY.gauge(
     "ollamamq_kv_pages_used",
     "KV cache pages currently allocated", labels=("model",))

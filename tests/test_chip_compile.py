@@ -9,6 +9,7 @@ passes is not a chip run. Skipped where the v5e topology cannot be
 described."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -189,3 +190,62 @@ def test_layer_loop_updates_the_kv_pool_in_place(v5e, lower, tp):
     layer_pool = NP * PS * HK * HD * 2 // tp  # one layer's K pool, a device
     assert mem.alias_size_in_bytes >= 2 * LAYERS * layer_pool, mem
     assert mem.temp_size_in_bytes < layer_pool, mem
+
+
+# ---------------------------------------------------------------------------
+# OLMoE widths: MHA (group 1, 16 kv heads, 2048 lanes) and 64 experts.
+# ---------------------------------------------------------------------------
+
+# olmoe:1b-7b's layer (config.py) over two layers and a small vocabulary.
+OLMOE_CFG = ModelConfig(
+    name="chip-compile-olmoe-widths", vocab_size=2048, hidden_size=2048,
+    intermediate_size=1024, num_layers=2, num_heads=16, num_kv_heads=16,
+    head_dim=128, max_seq_len=MP * PS, rope_theta=1e4, rms_norm_eps=1e-5,
+    qk_norm="full", num_experts=64, num_experts_per_tok=8)
+
+
+@pytest.mark.parametrize("which", ["forward_ragged", "forward_decode"])
+def test_olmoe_width_step_forward_compiles_for_one_v5e_chip(v5e, which):
+    """What Mosaic had never been asked before PR 27: both attention kernels
+    at group 1 with 2048-lane page rows, and the grouped expert matmul
+    (megablox `gmm`) reading the WHOLE [L, E, ...] stacks by layer index —
+    so no layer's expert weights (805 MB here) are copied out of the stack:
+    the program's temporaries stay under one expert matrix's size."""
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    cfg = OLMOE_CFG
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
+    pool = s((cfg.num_layers, NP * PS, cfg.kv_dim), jnp.bfloat16)
+    pt, per_tok, per_seq = (s((B, MP), jnp.int32), s((T,), jnp.int32),
+                            s((B,), jnp.int32))
+    if which == "forward_ragged":
+        def step(params, tok, ts, tp, ws, out_idx, kc, vc, pt, qs, ql, kl):
+            return llama.forward_ragged(
+                params, cfg, tok, ts, tp, ws, out_idx, kc, vc, pt, qs, ql,
+                kl, PS, attn_impl="pallas", moe_load=True)
+
+        lowered = jax.jit(step, donate_argnums=(6, 7)).lower(
+            params, per_tok, per_tok, per_tok, per_tok, per_seq, pool, pool,
+            pt, per_seq, per_seq, per_seq)
+    else:
+        def step(params, tok, pos, kc, vc, pt, active):
+            return llama.forward_decode(
+                params, cfg, tok, pos, kc, vc, pt, PS, attn_impl="pallas",
+                active=active, moe_load=True)
+
+        lowered = jax.jit(step, donate_argnums=(3, 4)).lower(
+            params, per_seq, per_seq, pool, pool, pt, per_seq)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3, \
+        "three grouped matmuls a layer, in the one traced layer body"
+    assert "ragged-dot" not in text
+    assert text.count("tpu_custom_call") >= 4  # + the attention kernel
+    mem = compiled.memory_analysis()
+    one_matrix = 64 * 2048 * 1024 * 2
+    assert mem.temp_size_in_bytes < one_matrix // 4, mem

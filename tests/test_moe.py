@@ -1,23 +1,25 @@
 """Mixture-of-experts layer + expert parallelism.
 
-Covers: routing math against a plain per-token numpy-style reference,
-capacity-overflow fallthrough, EP-sharded == unsharded execution on the
-8-virtual-device mesh, and the engine serving a MoE model end-to-end.
+Covers: routing math against a plain per-token numpy-style reference for
+both families (Mixtral: top-2 of 4, renormalised; OLMoE: top-4 of 16, not),
+that the dispatch drops nothing at any token count, that invalid rows are
+routed nowhere, EP-sharded == unsharded execution on the 8-virtual-device
+mesh, and the engine serving a MoE model end-to-end.
 """
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ollamamq_tpu.config import MODEL_CONFIGS, EngineConfig
 from ollamamq_tpu.models import llama
-from ollamamq_tpu.models.moe import expert_capacity, moe_mlp
+from ollamamq_tpu.models.moe import load_stats, moe_mlp
 from ollamamq_tpu.parallel.mesh import make_mesh
 from ollamamq_tpu.parallel.sharding import shard_params
 
 CFG = MODEL_CONFIGS["test-tiny-moe"]
+OLMOE = MODEL_CONFIGS["test-tiny-olmoe"]
 
 
 def _layer_params(cfg, seed=0):
@@ -27,9 +29,8 @@ def _layer_params(cfg, seed=0):
 
 
 def _reference_moe(cfg, lp, h):
-    """Per-token loop: softmax -> top-k -> renormalize -> sum of expert
-    FFNs. No capacity limit (the dense path must match when capacity is
-    generous)."""
+    """Per-token loop: softmax -> top-k -> (renormalize) -> sum of expert
+    FFNs. Every routed expert contributes, whatever the others got."""
     B, T, D = h.shape
     x = np.asarray(h, np.float32).reshape(-1, D)
     out = np.zeros_like(x)
@@ -39,7 +40,7 @@ def _reference_moe(cfg, lp, h):
         p = np.exp(logits - logits.max())
         p = p / p.sum()
         top = np.argsort(-p)[: cfg.num_experts_per_tok]
-        gates = p[top] / p[top].sum()
+        gates = p[top] / (p[top].sum() if cfg.norm_topk_prob else 1.0)
         for g, e in zip(gates, top):
             gate = x[n] @ np.asarray(lp["we_gate"], np.float32)[e]
             up = x[n] @ np.asarray(lp["we_up"], np.float32)[e]
@@ -48,56 +49,74 @@ def _reference_moe(cfg, lp, h):
     return out.reshape(B, T, D)
 
 
-def test_moe_matches_per_token_reference():
-    lp, _ = _layer_params(CFG)
-    h = jax.random.normal(jax.random.PRNGKey(1), (2, 5, CFG.hidden_size),
-                          jnp.float32)
-    got = moe_mlp(CFG, lp, h)
-    want = _reference_moe(CFG, lp, h)
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
-
-
-def test_capacity_overflow_drops_to_residual():
-    # Force capacity 1: route many identical tokens -> all want the same
-    # experts, only the first per expert is served, the rest contribute 0.
-    cfg = dataclasses.replace(CFG, moe_capacity_factor=1e-9)
+@pytest.mark.parametrize("cfg", [CFG, OLMOE], ids=lambda c: c.name)
+def test_moe_matches_per_token_reference(cfg):
     lp, _ = _layer_params(cfg)
-    h = jnp.ones((1, 6, cfg.hidden_size), jnp.float32)
-    assert expert_capacity(6, cfg) == 1
-    out = np.asarray(moe_mlp(cfg, lp, h))
-    ref_one = _reference_moe(cfg, lp, h[:, :1])
-    # Token 0 got both its experts; identical later tokens were dropped by
-    # at least one expert, so their output is smaller in norm (or zero).
-    np.testing.assert_allclose(out[0, 0], ref_one[0, 0], rtol=2e-4, atol=2e-4)
-    assert np.linalg.norm(out[0, -1]) < np.linalg.norm(out[0, 0]) + 1e-6
-    assert np.isfinite(out).all()
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 5, cfg.hidden_size),
+                          jnp.float32)
+    got, load = moe_mlp(cfg, lp, h)
+    want = _reference_moe(cfg, lp, h)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    assert int(load.sum()) == 10 * cfg.num_experts_per_tok
 
 
-def test_invalid_tokens_do_not_claim_capacity():
-    """Garbage rows (inactive decode slots / prefill padding) routing
-    identically must not evict real tokens from their experts' queues."""
-    cfg = dataclasses.replace(CFG, moe_capacity_factor=1.0)
+def _rows(kind, cfg):
+    if kind == "509-rows":  # no small factor: the old grouping fell to G = 1
+        return jax.random.normal(jax.random.PRNGKey(8),
+                                 (1, 509, cfg.hidden_size), jnp.float32)
+    if kind == "all-alike":  # every token picks the same experts
+        return jnp.broadcast_to(
+            jax.random.normal(jax.random.PRNGKey(9), (1, 1, cfg.hidden_size),
+                              jnp.float32), (1, 64, cfg.hidden_size))
+    return jax.random.normal(jax.random.PRNGKey(8), (1, 16, cfg.hidden_size),
+                             jnp.float32)
+
+
+@pytest.mark.parametrize("kind", ["509-rows", "all-alike", "16-rows"])
+def test_no_assignment_is_dropped_at_any_token_count(kind):
+    """Dropless: every token's every routed expert contributes, whether the
+    token count has a small factor or not and however the load falls —
+    64 rows that all pick the same 4 of 16 experts put 64 rows on each
+    (a capacity of 2 x the even share would have kept 32)."""
+    cfg = OLMOE
+    lp, _ = _layer_params(cfg, seed=7)
+    h = _rows(kind, cfg)
+    got, load = moe_mlp(cfg, lp, h)
+    np.testing.assert_allclose(got, _reference_moe(cfg, lp, h), rtol=2e-4,
+                               atol=2e-4)
+    n = h.shape[1]
+    assert int(load.sum()) == n * cfg.num_experts_per_tok
+    if kind == "all-alike":
+        assert sorted(np.asarray(load))[-cfg.num_experts_per_tok:] == \
+            [n] * cfg.num_experts_per_tok
+        assert int((np.asarray(load) > 0).sum()) == cfg.num_experts_per_tok
+
+
+def test_invalid_tokens_are_routed_nowhere():
+    """Garbage rows (inactive decode slots / prefill padding) get no
+    expert: they add nothing to any expert's load, their own output is
+    zero, and the real rows read what they read alone."""
+    cfg = OLMOE
     lp, _ = _layer_params(cfg, seed=5)
     real = jax.random.normal(jax.random.PRNGKey(6), (1, 2, cfg.hidden_size),
                              jnp.float32)
-    # 14 identical garbage rows ahead of the 2 real tokens (token-major
-    # "first C win" would hand them every expert slot), then the real rows.
-    garbage = jnp.ones((1, 14, cfg.hidden_size), jnp.float32)
+    garbage = jnp.full((1, 14, cfg.hidden_size), jnp.nan, jnp.float32)
     h = jnp.concatenate([garbage, real], axis=1)
     valid = jnp.concatenate(
         [jnp.zeros((1, 14), bool), jnp.ones((1, 2), bool)], axis=1
     )
-    out = moe_mlp(cfg, lp, h, valid=valid)
-    # With the mask, the real tokens see no capacity pressure (C=8 for 16
-    # tokens, demand 2x2): their outputs match the capacity-free reference.
-    want = _reference_moe(cfg, lp, real)
-    np.testing.assert_allclose(out[:, 14:], want, rtol=2e-4, atol=2e-4)
-    # And WITHOUT the mask, the identical garbage rows (routing alike,
-    # ahead in token-major order) really do evict at least one real
-    # token's expert assignment — the bug the mask exists to prevent.
-    out_nomask = moe_mlp(cfg, lp, h)
-    assert not np.allclose(np.asarray(out_nomask[:, 14:]), want,
-                           rtol=2e-4, atol=2e-4)
+    out, load = moe_mlp(cfg, lp, h, valid=valid)
+    np.testing.assert_allclose(out[:, 14:], _reference_moe(cfg, lp, real),
+                               rtol=2e-4, atol=2e-4)
+    assert not np.asarray(out[:, :14]).any()
+    alone, load_alone = moe_mlp(cfg, lp, real)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(load_alone))
+    assert int(load.sum()) == 2 * cfg.num_experts_per_tok
+    # the counters a step program sends back: assignments, pairs hit, max
+    stats = np.asarray(load_stats(jnp.stack([load, load])))
+    assert stats.tolist() == [2 * int(load.sum()),
+                              2 * int((np.asarray(load) > 0).sum()),
+                              int(load.max())]
 
 
 def test_ep_sharded_matches_unsharded():
@@ -116,26 +135,6 @@ def test_ep_sharded_matches_unsharded():
     )(sharded, tokens, seq_lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
-
-
-def test_grouped_routing_matches_reference_across_groups():
-    """N > group cap: per-group capacity must not change results when
-    capacity is generous (routing is per-token; groups only bound C)."""
-    import ollamamq_tpu.models.moe as moe_mod
-
-    lp, _ = _layer_params(CFG, seed=7)
-    # 2 groups of 8 via a tiny cap — compare against one flat group.
-    h = jax.random.normal(jax.random.PRNGKey(8), (1, 16, CFG.hidden_size),
-                          jnp.float32)
-    want = _reference_moe(CFG, lp, h)
-    orig = moe_mod.group_size
-    try:
-        moe_mod.group_size = lambda n, cap=8: orig(n, cap=8)
-        got = moe_mlp(CFG, lp, h)
-    finally:
-        moe_mod.group_size = orig
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
-    assert moe_mod.group_size(16, cap=8) == 8  # really 2 groups
 
 
 def test_dense_model_allowed_on_ep_mesh():

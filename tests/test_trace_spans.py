@@ -258,24 +258,27 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
 
 
 # ------------------------------------------- names on the device-side programs
-def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs():
-    """Scopes change op metadata only; every name of llama.SCOPES is in
-    the debug text of the engine's OWN ragged and decode programs, the
-    modules are named after the stable jit functions, and README lists
-    every one of these names in its span table."""
+@pytest.mark.parametrize("model", ["test-tiny", "test-tiny-olmoe"])
+def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
+    """Scopes change op metadata only; every name of llama.SCOPES (and,
+    for an MoE model, of moe.SCOPES inside `mlp`) is in the debug text of
+    the engine's OWN ragged and decode programs, the modules are named
+    after the stable jit functions, and README lists every one of these
+    names in its span table."""
     import jax
     import jax.numpy as jnp
 
     from ollamamq_tpu.engine.engine import TPUEngine
-    from ollamamq_tpu.models import llama
+    from ollamamq_tpu.models import llama, moe
 
-    eng = TPUEngine(EngineConfig(model="test-tiny", max_slots=2, num_pages=64,
+    eng = TPUEngine(EngineConfig(model=model, max_slots=2, num_pages=64,
                                  page_size=8, max_pages_per_seq=16,
                                  prefill_buckets=(16, 32, 64),
                                  decode_steps_per_iter=2),
-                    models={"test-tiny": None}, blocklist_path=None,
+                    models={model: None}, blocklist_path=None,
                     dtype=jnp.float32)
-    rt = eng.runtimes["test-tiny"]
+    rt = eng.runtimes[model]
+    scopes = llama.SCOPES + (moe.SCOPES if rt.cfg.num_experts else ())
     seen = {}
 
     def spy(site, getter):
@@ -295,7 +298,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs():
     try:
         tok = rt.tokenizer
         req = eng.enqueue_request(
-            "u", "", "test-tiny", prompt_tokens=tok.encode("count to ten"),
+            "u", "", model, prompt_tokens=tok.encode("count to ten"),
             sampling=SamplingParams(max_tokens=8))
         assert collect(req)[-1].kind == "done"
     finally:
@@ -309,7 +312,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs():
         text = jitted.lower(*abstract).as_text(debug_info=True)
         assert re.search(r"module @jit_%s\b" % names[site], text), \
             text[:200]
-        for scope in llama.SCOPES:
+        for scope in scopes:
             # A whole component of an op's name stack ("embed/gather"),
             # not a parameter name or a file path that contains the word.
             assert re.search(r'loc\("(?:[^"/]+/)*%s(?:/[^"]+)?"' % scope,
@@ -322,7 +325,8 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs():
     documented = set(re.findall(r"`([a-z_.]+)`", table))
     jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_prefill",
                  "mq_prefill_chunk", "mq_prefill_sp", "mq_embed", "mq_encode"}
-    assert set(llama.SCOPES) | jit_names | set(SPAN_NAMES) <= documented
+    assert set(llama.SCOPES) | set(moe.SCOPES) | jit_names \
+        | set(SPAN_NAMES) <= documented
     # ... and the seven jit sites really are those functions.
     with open(os.path.join(_REPO, "ollamamq_tpu", "engine",
                            "engine.py"), encoding="utf-8") as f:

@@ -171,3 +171,78 @@ def test_safetensors_mixtral_moe_layout(tmp_path):
         kc, jnp.zeros_like(kc), pt, 8,
     )
     assert np.isfinite(np.asarray(logits)).all()
+
+
+def test_safetensors_olmoe_layout(tmp_path):
+    """OLMoE's checkpoint names: `mlp.gate` (router), `mlp.experts.N.
+    {gate,up,down}_proj`, and q/k norm weights as wide as the whole
+    projected vector. The loaded tree equals what the reference computes."""
+    import jax.numpy as jnp
+    from safetensors.numpy import save_file
+    from testutil import olmoe_reference, reference_keys
+
+    cfg = MODEL_CONFIGS["test-tiny-olmoe"]
+    rng = np.random.default_rng(2)
+    d, f, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+
+    def normal(*shape):
+        return rng.normal(size=shape).astype(np.float32) / np.sqrt(shape[-1])
+
+    tensors = {"model.embed_tokens.weight": normal(cfg.vocab_size, d),
+               "model.norm.weight": np.ones((d,), np.float32),
+               "lm_head.weight": normal(cfg.vocab_size, d)}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        tensors[p + "input_layernorm.weight"] = np.ones((d,), np.float32)
+        tensors[p + "post_attention_layernorm.weight"] = np.ones(
+            (d,), np.float32)
+        tensors[p + "self_attn.q_proj.weight"] = normal(cfg.q_dim, d)
+        tensors[p + "self_attn.k_proj.weight"] = normal(cfg.kv_dim, d)
+        tensors[p + "self_attn.v_proj.weight"] = normal(cfg.kv_dim, d)
+        tensors[p + "self_attn.o_proj.weight"] = normal(d, cfg.q_dim)
+        tensors[p + "self_attn.q_norm.weight"] = 1 + 0.3 * normal(cfg.q_dim)
+        tensors[p + "self_attn.k_norm.weight"] = 1 + 0.3 * normal(cfg.kv_dim)
+        tensors[p + "mlp.gate.weight"] = normal(E, d)
+        for e in range(E):
+            ep = p + f"mlp.experts.{e}."
+            tensors[ep + "gate_proj.weight"] = normal(f, d)
+            tensors[ep + "up_proj.weight"] = normal(f, d)
+            tensors[ep + "down_proj.weight"] = normal(d, f)
+    save_file(tensors, str(tmp_path / "model.safetensors"))
+
+    params = weights.load_safetensors(cfg, str(tmp_path), dtype=jnp.float32)
+    L = cfg.num_layers
+    layers = params["layers"]
+    assert layers["w_router"].shape == (L, d, E)
+    assert layers["we_gate"].shape == layers["we_up"].shape == (L, E, d, f)
+    assert layers["we_down"].shape == (L, E, f, d)
+    assert layers["q_norm"].shape == (L, cfg.q_dim)
+    assert layers["k_norm"].shape == (L, cfg.kv_dim)
+    assert "w_gate" not in layers
+    for ours, theirs in (("we_gate", "gate_proj"), ("we_up", "up_proj"),
+                         ("we_down", "down_proj")):
+        np.testing.assert_allclose(
+            np.asarray(layers[ours][1, 3]),
+            tensors[f"model.layers.1.mlp.experts.3.{theirs}.weight"].T,
+            rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(layers["w_router"][0]),
+                               tensors["model.layers.0.mlp.gate.weight"].T,
+                               rtol=1e-6)
+
+    # The loaded checkpoint runs a prefill, and reads what the plain
+    # reference reads from the same tree.
+    from ollamamq_tpu.engine import kv_cache as kvc
+    from ollamamq_tpu.models import llama
+
+    kc = jnp.zeros((L, 64, cfg.kv_dim), jnp.float32)
+    a = kvc.PageAllocator(8, 8, 4)
+    pt = jnp.asarray(np.stack([kvc.make_page_table_row(a.alloc(4), 4)]))
+    toks = [1, 2, 3, 4, 5]
+    logits, _, _ = llama.forward_prefill(
+        params, cfg, jnp.array([toks], jnp.int32), jnp.array([5]),
+        kc, jnp.zeros_like(kc), pt, 8,
+    )
+    want = olmoe_reference().logits(reference_keys(cfg), params,
+                                    jnp.asarray(toks, jnp.int32))
+    np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want[-1]),
+                               atol=2e-4)

@@ -103,3 +103,30 @@ def single_device_greedy_tokens(model, prompt, max_tokens=6, **ecfg_kw):
     finally:
         eng.stop()
     return req.generated_ids
+
+
+def olmoe_reference():
+    """The benchmark's plain float32 reference of the sparse family
+    (benchmarks/reference/olmoe_decoder.py), as a module."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "reference",
+        "olmoe_decoder.py")
+    spec = importlib.util.spec_from_file_location("olmoe_decoder", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference_keys(mc) -> dict:
+    """What a configuration file says of the ModelConfig `mc`: all that
+    reference reads."""
+    return {"num_attention_heads": mc.num_heads,
+            "num_key_value_heads": mc.num_kv_heads, "head_dim": mc.head_dim,
+            "rms_norm_eps": mc.rms_norm_eps, "rope_theta": mc.rope_theta,
+            "qk_norm": mc.qk_norm, "num_experts": mc.num_experts,
+            "num_experts_per_tok": mc.num_experts_per_tok,
+            "norm_topk_prob": mc.norm_topk_prob,
+            "tie_word_embeddings": mc.tie_embeddings}
