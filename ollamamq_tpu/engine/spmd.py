@@ -1186,10 +1186,11 @@ def _replay(rt, op, a, b, payload):
         (tokens, positions, active, pt, temp, tk, tp, pen, pres,
          freq, seeds, key_data) = payload
         key = jnp.asarray(key_data, jnp.uint32)
-        toks, rt.kc, rt.vc, rt.recent = ModelRuntime._dispatch_decode(
-            rt, k_steps, tokens, positions, active, pt, temp, tk,
-            tp, pen, pres, freq, seeds, key)
-        return (toks, rt.kc, rt.vc, rt.recent)
+        toks, rt.kc, rt.vc, rt.recent, rt.last_ids = \
+            ModelRuntime._dispatch_decode(
+                rt, k_steps, tokens, positions, active, pt, temp, tk,
+                tp, pen, pres, freq, seeds, key)
+        return (toks, rt.kc, rt.vc, rt.recent, rt.last_ids)
     elif op == OP_PREFILL_SP:
         T = a
         (tokens, lens, slot_ids, pt_rows, temp, tk, tp, pen, pres,
@@ -1208,26 +1209,26 @@ def _replay(rt, op, a, b, payload):
         # No verify spans on this wire shape: is_spec is identically
         # zero on every host (k_cap=0 compiles the 1-column output).
         is_spec = np.zeros_like(q_start)
-        toks, n_emit, rt.kc, rt.vc, rt.recent = \
+        toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids = \
             ModelRuntime._dispatch_ragged(
                 rt, T_pad, 0, tokens, tok_seq, tok_pos, write_slots,
                 q_start, q_len, kv_len, ring_len, is_first, append,
                 is_spec, seed_rows, slot_ids, pt, temp, tk, tp, pen,
                 pres, freq, seeds, key)
-        return (toks, n_emit, rt.kc, rt.vc, rt.recent)
+        return (toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids)
     elif op == OP_SPEC:
         T_pad, k_cap = a, b
         (tokens, tok_seq, tok_pos, write_slots, q_start, q_len, kv_len,
          ring_len, is_first, append, is_spec, slot_ids, seed_rows, pt,
          temp, tk, tp, pen, pres, freq, seeds, key_data) = payload
         key = jnp.asarray(key_data, jnp.uint32)
-        toks, n_emit, rt.kc, rt.vc, rt.recent = \
+        toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids = \
             ModelRuntime._dispatch_ragged(
                 rt, T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots,
                 q_start, q_len, kv_len, ring_len, is_first, append,
                 is_spec, seed_rows, slot_ids, pt, temp, tk, tp, pen,
                 pres, freq, seeds, key)
-        return (toks, n_emit, rt.kc, rt.vc, rt.recent)
+        return (toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids)
     elif op == OP_ENCODE:
         B, bucket = a, b
         tokens, lens = payload

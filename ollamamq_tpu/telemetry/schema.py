@@ -401,6 +401,17 @@ STEP_PHASE_MS = REGISTRY.histogram(
     buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
              25.0, 50.0, 100.0, 250.0, 1000.0),
     labels=("phase", "mode"))
+STEPS_OVERLAPPED_TOTAL = REGISTRY.counter(
+    "ollamamq_steps_overlapped_total",
+    "Steps launched while the step before them was still unsettled: the "
+    "engine thread composed and dispatched them while the chip ran, and "
+    "emitted the earlier step's tokens behind them", labels=("model",))
+STEP_WASTED_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_step_wasted_rows_total",
+    "Rows a step served whose output was dropped at settle because the "
+    "request had already finished (EOS or a stop string seen one step "
+    "late, a cancel between launch and settle): extra device work, never "
+    "a token more or less", labels=("model",))
 COMPILE_TOTAL = REGISTRY.counter(
     "ollamamq_compile_total",
     "XLA compiles the engine paid, by jit-cache site (ragged / prefill "

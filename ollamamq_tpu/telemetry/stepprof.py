@@ -19,10 +19,15 @@ vocabulary in CI and bench's error path can always attach a summary.
 
 Contracts the tests pin:
 
-  * Phases are contiguous deltas between marks of one monotonic timer,
-    so a sample's phase milliseconds sum EXACTLY to its recorded step
-    wall clock — and instrumentation covers ≥95% of the measured
-    dispatch wall (the 5% acceptance gate is coverage, not arithmetic).
+  * Phases are deltas between marks of one monotonic timer, so a
+    sample's phase milliseconds sum EXACTLY to its recorded step time —
+    and instrumentation covers ≥95% of the measured dispatch wall (the
+    5% acceptance gate is coverage, not arithmetic). A step is launched
+    in one tick and settled in a later one, behind the NEXT step's
+    launch: between its halves the timer is parked and the thread's
+    time goes to whoever holds the cursor, so two interleaved steps
+    never count an instant twice and `collect` is the time actually
+    blocked on the device.
   * The ring, the per-shape table, the compile-event ring, and the HBM
     timeline are all bounded — always-on means O(1) memory forever.
   * Self-overhead is metered: every profiler entry point times itself
@@ -71,9 +76,9 @@ PHASES = (
     #                a fresh cache key (the `compiled` flag), else just
     #                enqueue — returns with device arrays still in flight
     "collect",     # device wait + D2H materialization (np.asarray /
-    #                block_until_ready) — on the split decode path this
-    #                spans dispatch-issue to collect, i.e. the device
-    #                compute the engine overlapped with other work
+    #                block_until_ready): the time the thread was BLOCKED
+    #                on this step — near zero when the step ran behind
+    #                the next one's composition
     "detok",       # host-side emit loop: sampling bookkeeping, detokenize,
     #                stream writes, per-request finish handling
 )
@@ -132,15 +137,17 @@ class LoopClock:
     its own thread only.
 
     `enter(phase)` closes whatever is open and opens a LOOP_PHASES
-    entry; a StepTimer's start/mark do the same for PHASES. Time charged
-    to loop phases since the last recorded sample rides in the NEXT
-    sample as loop_*_ms. `tick()` — top of an engine tick, where no step
-    is in flight — folds what abandoned timers were charged into
-    `other`, so early returns and faulted dispatches leave no hole."""
+    entry; a StepTimer's start/mark/resume do the same for PHASES. Time
+    charged to loop phases since the last recorded sample rides in the
+    NEXT sample recorded as loop_*_ms. A step in flight across ticks has
+    PARKED its timer (the cursor is the loop's or the next step's
+    meanwhile). `tick()` — top of an engine tick — folds what timers
+    that are neither finished nor parked were charged into `other`: they
+    were abandoned, so early returns and faulted dispatches leave no
+    hole."""
 
     __slots__ = ("name", "_prof", "_last", "_owner", "_open", "_span",
-                 "_loop", "_live", "_timer_ms", "_epoch", "_seq",
-                 "_adopted")
+                 "_loop", "_timers", "_seq", "_adopted")
 
     def __init__(self, prof: "StepProfiler", name: str):
         self._prof = prof
@@ -156,9 +163,7 @@ class LoopClock:
         self._owner: Optional["StepTimer"] = None  # None = a loop phase
         self._open = "other"
         self._loop = dict.fromkeys(LOOP_PHASES, 0.0)
-        self._live = 0          # timers started and not finished
-        self._timer_ms = 0.0    # thread time those timers were charged
-        self._epoch = 0         # bumped when abandoned timers are folded
+        self._timers: List["StepTimer"] = []  # started, not yet finished
         self._seq: Optional[int] = None  # reserved for the next sample
         self._adopted: Optional["StepTimer"] = None
 
@@ -180,7 +185,6 @@ class LoopClock:
             self._loop[name] += ms
         else:
             tgt.phases[name] = tgt.phases.get(name, 0.0) + ms
-            self._timer_ms += ms
         if self._span is not None:
             self._close_span()
         self._owner, self._open = owner, phase
@@ -191,9 +195,18 @@ class LoopClock:
     def _open_span(self, prof: "StepProfiler",
                    owner: Optional["StepTimer"], phase: str) -> None:
         if owner is None:
-            if self._seq is None:
-                self._seq = prof._reserve_seq()
-            span = prof.span_factory("mq.loop." + phase, seq=self._seq)
+            # Loop time rides in the next sample RECORDED: the oldest
+            # parked step's, else the next step's to start.
+            nxt = next((t for t in self._timers if t.parked), None)
+            if nxt is not None:
+                if nxt.seq is None:
+                    nxt.seq = prof._reserve_seq()
+                seq = nxt.seq
+            else:
+                if self._seq is None:
+                    self._seq = prof._reserve_seq()
+                seq = self._seq
+            span = prof.span_factory("mq.loop." + phase, seq=seq)
         else:
             if owner.seq is None:
                 owner.seq = prof._reserve_seq()
@@ -210,22 +223,32 @@ class LoopClock:
         self._switch(t, None, phase)
         self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
 
+    def _fold(self, timer: "StepTimer") -> None:
+        """A timer that will never record: what it was charged is the
+        loop's `other`."""
+        timer._done = True
+        self._loop["other"] += sum(timer.phases.values())
+        self._timers.remove(timer)
+
     def tick(self) -> None:
-        """Top of an engine tick: no step is in flight here, so a timer
-        still unfinished was abandoned — what it was charged is `other`
-        (and it can no longer record), and the seq its spans carried
-        goes back to the clock when nobody reserved a later one."""
-        if self._live or self._owner is not None:
+        """Top of an engine tick: a timer that is neither finished nor
+        parked (parked = its step is in flight, to be settled behind the
+        next launch) was abandoned — what it was charged is `other` (and
+        it can no longer record), and the seq its spans carried goes
+        back to the clock when nobody reserved a later one."""
+        if self._owner is not None \
+                or any(not t.parked for t in self._timers):
             t = time.perf_counter()
             if self._owner is not None:
                 self._switch(t, None, "other")
-            self._loop["other"] += self._timer_ms
-            self._epoch += 1
+            folded = [x for x in self._timers if not x.parked]
+            for x in folded:
+                self._fold(x)
             a = self._adopted
-            if a is not None and not a._done and a.seq == self._prof.seq:
+            if a in folded and a.seq == self._prof.seq:
                 self._seq = a.seq
             self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
-        self._live, self._timer_ms, self._adopted = 0, 0.0, None
+        self._adopted = None
 
 
 class StepTimer:
@@ -236,12 +259,14 @@ class StepTimer:
     is exactly what a faulted/preempted dispatch should leave; its time
     is folded into the loop's `other` at the next tick). Phases may be
     marked more than once (chunked host prep); deltas accumulate. Where
-    several timers are live on one thread (one engine, two runtimes'
-    split decode), each phase holds the thread time charged to it, so
-    the timers never count an instant twice."""
+    several timers are live on one thread (step N settled behind step
+    N+1's launch; one engine, two runtimes), each phase holds the thread
+    time charged to it, so the timers never count an instant twice:
+    `park()` hands the cursor back to the loop between a step's halves
+    and `resume(phase)` takes it again."""
 
     __slots__ = ("_prof", "_clock", "mode", "phases", "fields", "seq",
-                 "_done", "_epoch")
+                 "_done", "parked")
 
     def __init__(self, prof: "StepProfiler", mode: str,
                  clock: Optional[LoopClock] = None):
@@ -255,13 +280,13 @@ class StepTimer:
         self.phases: Dict[str, float] = {}
         self.fields: Dict[str, object] = {}
         self._done = False
-        self._epoch = clock._epoch
+        self.parked = False
         # The seq the loop spans before this step carried, if any: those
         # spans' time is written into this step's sample.
         self.seq = clock._seq
         if self.seq is not None:
             clock._seq, clock._adopted = None, self
-        clock._live += 1
+        clock._timers.append(self)
         clock._switch(t, self, PHASES[0])
         prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
 
@@ -279,11 +304,40 @@ class StepTimer:
         # Self-overhead: the mark itself (two clock reads + a dict op).
         self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
 
+    def park(self) -> None:
+        """The step is in flight and the thread goes on to other work:
+        close the open phase (charged to this timer) and give the cursor
+        to the loop until `resume`. A parked timer survives ticks."""
+        t = time.perf_counter()
+        clock = self._clock
+        if clock._owner is self:
+            clock._switch(t, None, "other")
+        self.parked = True
+        self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+
+    def resume(self, phase: str) -> None:
+        """Take the cursor back: whatever was open closes (charged to its
+        own owner) and `phase` opens for this step."""
+        t = time.perf_counter()
+        self.parked = False
+        self._clock._switch(t, self, phase)
+        self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+
+    def abandon(self) -> None:
+        """A parked step that will never be settled (voided after a
+        fault): what it was charged is the loop's `other`, at once."""
+        if self._done:
+            return
+        clock = self._clock
+        if clock._owner is self:
+            clock._switch(time.perf_counter(), None, "other")
+        clock._fold(self)
+
     def finish(self, **fields) -> Optional[dict]:
         clock = self._clock
         # Double-finish is a bug upstream, and a timer the loop already
         # folded into `other` must not count its time twice: stay silent.
-        if self._done or self._epoch != clock._epoch:
+        if self._done:
             return None
         self._done = True
         t = time.perf_counter()
@@ -294,8 +348,7 @@ class StepTimer:
         if clock._owner is self:
             clock._switch(clock._last, None, "other")
         total_ms = sum(self.phases.values())
-        clock._live -= 1
-        clock._timer_ms -= total_ms
+        clock._timers.remove(self)
         sample = {
             "ts": time.time(),
             "mode": self.mode,

@@ -27,8 +27,9 @@ Each rule names ONE site and ONE trigger:
   site     where the fault fires — a dispatch seam ("prefill", "chunk",
            "sp_prefill", "ragged" for the mixed-batch dispatch,
            "spec_verify" for a mixed dispatch carrying speculative
-           verify spans, "decode", "embed", "encode", "step" for the
-           fake runtime), an allocation seam ("alloc" = admission
+           verify spans, "decode", "collect" where a launched step's
+           ids are read back — the next step may already be launched
+           behind it —, "embed", "encode", "step" for the fake runtime), an allocation seam ("alloc" = admission
            page alloc, "extend" = decode-time page growth), or the
            fleet router's member-probe seam ("replica": the router
            probes members in order each health sweep, so the per-site
@@ -102,7 +103,8 @@ import time
 from typing import Dict, List, Optional
 
 SITES = ("prefill", "chunk", "sp_prefill", "ragged", "spec_verify",
-         "decode", "embed", "encode", "step", "alloc", "extend", "replica",
+         "decode", "collect", "embed", "encode", "step", "alloc", "extend",
+         "replica",
          "migrate", "wal", "preempt", "router", "compile")
 KINDS = ("exception", "slow", "alloc_fail", "device_loss")
 

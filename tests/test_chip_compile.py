@@ -249,3 +249,60 @@ def test_olmoe_width_step_forward_compiles_for_one_v5e_chip(v5e, which):
     mem = compiled.memory_analysis()
     one_matrix = 64 * 2048 * 1024 * 2
     assert mem.temp_size_in_bytes < one_matrix // 4, mem
+
+
+# ---------------------------------------------------------------------------
+# The engine's own step programs: everything they carry is updated in place.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
+def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
+    """The two programs of the pipelined loop as the engine jits them
+    (PR 28 added the `last_ids` carry: a step launched behind an unsettled
+    one reads a row's input token from it): both pools, the penalty ring
+    and the carry are donated and come back aliased — the compiled program
+    holds no second copy of any."""
+    from types import SimpleNamespace
+
+    from ollamamq_tpu.engine import engine as eng_mod
+    from ollamamq_tpu.engine.engine import ModelRuntime
+
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    # The jit itself, not the first-call wrapper that times the compile.
+    monkeypatch.setattr(eng_mod, "_sp_note_compile",
+                        lambda rt, site, key, cache, fn: cache.setdefault(
+                            key, fn))
+    S, W = B, 64
+    rt = object.__new__(ModelRuntime)
+    rt.cfg, rt.attn_impl, rt.mesh, rt._pp = LOOP_CFG, "pallas", None, 1
+    rt.ecfg = SimpleNamespace(page_size=PS, pp_microbatches=None)
+    rt._prefill_jits, rt._decode_jits = {}, {}
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(LOOP_CFG, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
+    pool = s((LAYERS, NP * PS, HK * HD), jnp.bfloat16)
+    recent, last_ids = s((S + 1, W)), s((S,))
+    f32 = lambda: s((S,), jnp.float32)  # noqa: E731
+    sampling = (f32(), s((S,)), f32(), f32(), f32(), f32(), s((S,)))
+    key = s((2,), jnp.uint32)
+    if which == "mq_ragged_step":
+        fn = rt._get_ragged_jit(T, 0, (True, True, True))
+        lowered = fn.lower(
+            params, s((T,)), s((T,)), s((T,)), s((T,)),
+            *[s((S,)) for _ in range(7)], s((S, W)), s((S,)), s((S, MP)),
+            pool, pool, recent, last_ids, *sampling, key)
+    else:
+        fn = rt._get_decode_jit(8, (True, True, True))
+        lowered = fn.lower(params, s((S,)), s((S,)), pool, pool, recent,
+                           last_ids, s((S,)), s((S, MP)), *sampling, key)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    carried = (2 * LAYERS * NP * PS * HK * HD * 2   # both pools
+               + (S + 1) * W * 4 + S * 4)            # the ring, the carry
+    assert mem.alias_size_in_bytes >= carried, (mem, carried)
+    assert mem.temp_size_in_bytes < NP * PS * HK * HD * 2, mem

@@ -161,7 +161,11 @@ def test_dp_decode_dispatches_overlap_before_any_collect():
     dispatch EVERY replica's fused decode chunk before blocking on any —
     replicas on disjoint device sets then execute concurrently. Asserted
     structurally (dispatch/collect event order) rather than by wall-clock,
-    which would be flaky on shared CPU cores."""
+    which would be flaky on shared CPU cores. Since PR 28 a launched step
+    stays in flight into the next tick, where it is collected just before
+    the runtime's next launch: both replicas' first launches still come
+    before either is read, and from then on each replica always has a step
+    queued while the other's is read."""
     from ollamamq_tpu.engine.engine import ModelRuntime
 
     eng = TPUEngine(dp_cfg(), blocklist_path=None)
@@ -170,7 +174,7 @@ def test_dp_decode_dispatches_overlap_before_any_collect():
     events = []
 
     orig_dispatch = ModelRuntime.step_decode_dispatch
-    orig_collect = ModelRuntime.step_decode_collect
+    orig_collect = ModelRuntime.step_collect
 
     def rec_dispatch(self, core, k_steps=1):
         h = orig_dispatch(self, core, k_steps=k_steps)
@@ -179,11 +183,12 @@ def test_dp_decode_dispatches_overlap_before_any_collect():
         return h
 
     def rec_collect(self, handle, core):
-        events.append(("collect", id(self)))
+        if handle.state == "launched":
+            events.append(("collect", id(self)))
         return orig_collect(self, handle, core)
 
     ModelRuntime.step_decode_dispatch = rec_dispatch
-    ModelRuntime.step_decode_collect = rec_collect
+    ModelRuntime.step_collect = rec_collect
     try:
         # One request per replica, installed via direct prefill (no loop
         # thread — we drive ticks by hand for deterministic ordering).
@@ -195,18 +200,16 @@ def test_dp_decode_dispatches_overlap_before_any_collect():
             assert rep.step_prefill(eng.core)
         events.clear()
         eng._loop_once()
-        decode_events = [e for e in events if e[0] in ("dispatch", "collect")]
-        dispatches = [e for e in decode_events if e[0] == "dispatch"]
-        assert len(dispatches) == 2, decode_events
-        # Both dispatches precede the first collect.
-        first_collect = next(
-            i for i, e in enumerate(decode_events) if e[0] == "collect"
-        )
-        assert first_collect == 2, decode_events
+        assert [e[0] for e in events] == ["dispatch", "dispatch"], events
+        eng._loop_once()
+        a, b = (id(rep) for rep in rs.replicas)
+        assert events[2:] == [("collect", a), ("dispatch", a),
+                              ("collect", b), ("dispatch", b)], events
     finally:
         ModelRuntime.step_decode_dispatch = orig_dispatch
-        ModelRuntime.step_decode_collect = orig_collect
+        ModelRuntime.step_collect = orig_collect
         for rep in rs.replicas:
+            rep.void_inflight()
             for s, r in enumerate(rep.slot_req):
                 if r is not None:
                     rep._finish_slot(s, FinishReason.CANCELLED, eng.core)
