@@ -593,8 +593,7 @@ def kernels_child(rehearse: bool) -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from ollamamq_tpu.ops.attention import (paged_chunk_attention_blockwise,
-                                            paged_decode_attention_any,
+    from ollamamq_tpu.ops.attention import (paged_decode_attention_any,
                                             ragged_attention_any)
 
     dev = jax.devices()[0]
@@ -682,9 +681,11 @@ def kernels_child(rehearse: bool) -> int:
             qd, kc, vc, ptd, sl).block_until_ready()
     decode_s = time.monotonic() - t0
     # The materializing jnp decode reference gathers every sequence's
-    # whole page-table width; its blockwise twin is the same softmax.
-    refd = jax.jit(lambda q, kc, vc, pt, sl: paged_chunk_attention_blockwise(
-        q[:, None], kc, vc, LAYER, pt, sl - 1, jnp.ones_like(sl), ps)[:, 0])(
+    # whole page-table width; the blockwise ragged reference over one-
+    # token rows is the same softmax.
+    rows, one = jnp.arange(B, dtype=jnp.int32), jnp.ones_like(sl)
+    refd = jax.jit(lambda q, kc, vc, pt, sl: ragged_attention_any(
+        "jnp", q, kc, vc, LAYER, pt, rows, sl - 1, sl, rows, one, ps))(
             qd, kc, vc, ptd, sl)
     res_d = closeness(outd, refd, np.ones((B,), bool))
 

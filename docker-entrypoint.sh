@@ -12,7 +12,6 @@ set -- --no-tui --host 0.0.0.0
 [ -n "${TP:-}" ] && set -- "$@" --tp "$TP"
 [ -n "${DP:-}" ] && set -- "$@" --dp "$DP"
 [ -n "${SP:-}" ] && set -- "$@" --sp "$SP"
-[ -n "${PP:-}" ] && set -- "$@" --pp "$PP"
 [ -n "${EP:-}" ] && set -- "$@" --ep "$EP"
 [ -n "${PAGE_SIZE:-}" ] && set -- "$@" --page-size "$PAGE_SIZE"
 [ -n "${NUM_PAGES:-}" ] && set -- "$@" --num-pages "$NUM_PAGES"

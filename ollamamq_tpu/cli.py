@@ -64,7 +64,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "(per-page-row fp32 scales stored alongside the "
                         "pool), so ~2x concurrent requests fit the same "
                         "HBM; invalid combinations (MoE weights, "
-                        "--pp/--sp KV) fail at startup")
+                        "--sp KV) fail at startup")
     p.add_argument("--max-batch-tokens", type=int, default=512,
                    help="token budget of one ragged dispatch (decode rows "
                         "+ prefill-span tokens); clamped up so a full "
@@ -116,15 +116,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--sp", type=int, default=1, help="sequence-parallel axis size")
     p.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel axis size (-1 = all devices)")
-    p.add_argument("--pp", type=int, default=1,
-                   help="pipeline-parallel stages (layers split across "
-                        "chip groups; for models beyond one group's HBM)")
     p.add_argument("--ep", type=int, default=1,
                    help="expert-parallel axis size (MoE models)")
-    p.add_argument("--pp-microbatches", type=int, default=0,
-                   help="GPipe microbatches per pp dispatch (0 = one per "
-                        "stage; sweep on hardware — prefill wants more, "
-                        "weight-bound decode may want fewer)")
     # Fleet router: dispatcher-over-engines.
     p.add_argument("--replicas", type=int,
                    default=int(os.environ.get("REPLICAS", 1)),
@@ -447,7 +440,7 @@ def _fake_latency() -> float:
 def _member_mesh(cfg, index: int):
     """Fleet member `index`'s own slice of the local devices: without it
     every in-process replica's weights and KV pool would land on device
-    0. A member needs dp*pp*sp*ep*tp devices; slices follow one another
+    0. A member needs dp*sp*ep*tp devices; slices follow one another
     and wrap around when the fleet outgrows the devices (said in the
     log — members then share a device)."""
     import jax
@@ -457,7 +450,7 @@ def _member_mesh(cfg, index: int):
     if cfg.tp == -1:
         return None  # "all devices": the engine builds that mesh itself
     devs = jax.devices()
-    k = cfg.dp * cfg.pp * cfg.sp * cfg.ep * cfg.tp
+    k = cfg.dp * cfg.sp * cfg.ep * cfg.tp
     if k > len(devs):
         raise ValueError(f"a fleet member needs {k} devices, "
                          f"{len(devs)} available")
@@ -467,7 +460,7 @@ def _member_mesh(cfg, index: int):
             "fleet member %d shares device(s) %s with an earlier member "
             "(%d devices for the fleet)", index,
             [str(d) for d in picked], len(devs))
-    return make_mesh(dp=cfg.dp, sp=cfg.sp, tp=cfg.tp, pp=cfg.pp, ep=cfg.ep,
+    return make_mesh(dp=cfg.dp, sp=cfg.sp, tp=cfg.tp, ep=cfg.ep,
                      devices=picked)
 
 
@@ -670,7 +663,7 @@ def main(argv=None) -> int:
     from ollamamq_tpu.config import validate_quant_config
 
     quant_err = validate_quant_config(
-        args.weights_dtype, args.kv_dtype, pp=args.pp, sp=args.sp,
+        args.weights_dtype, args.kv_dtype, sp=args.sp,
         model_names=[m.strip() for m in args.models.split(",") if m.strip()])
     if quant_err is not None:
         log.error("%s", quant_err)
@@ -761,9 +754,7 @@ def main(argv=None) -> int:
         dp=args.dp,
         sp=args.sp,
         tp=args.tp,
-        pp=args.pp,
         ep=args.ep,
-        pp_microbatches=args.pp_microbatches or None,
         trace_ring=args.trace_ring,
         slo_ttft_ms=args.slo_ttft_ms or None,
         slo_tpot_ms=args.slo_tpot_ms or None,
@@ -925,10 +916,9 @@ def main(argv=None) -> int:
         # SPMD with an unspecified mesh means "the whole pod": default the
         # tensor axis to all global devices so worker hosts own shards.
         tp = args.tp
-        if (args.dp, args.sp, args.pp, args.ep, tp) == (1, 1, 1, 1, 1):
+        if (args.dp, args.sp, args.ep, tp) == (1, 1, 1, 1):
             tp = -1
-        mesh = make_mesh(dp=args.dp, sp=args.sp, tp=tp, pp=args.pp,
-                         ep=args.ep)
+        mesh = make_mesh(dp=args.dp, sp=args.sp, tp=tp, ep=args.ep)
         if not distributed.is_primary():
             # Worker host: replay the primary's step plans until shutdown.
             from ollamamq_tpu.engine import spmd

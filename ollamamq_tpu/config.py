@@ -251,18 +251,17 @@ class EngineConfig:
     page_size: int = 32
     # Max pages a single sequence may hold (=> max context length).
     max_pages_per_seq: int = 16
-    # Prefill length buckets (padded; each bucket compiles once). Used by
-    # the pipeline-parallel (pp > 1) prefill path and, in both modes, as
-    # the chunk ceiling for the sequence-parallel prefill hand-off. The
-    # legacy user-facing bucketed oracle (--attention=bucketed) was
-    # removed one release after the ragged path shipped, as scheduled.
+    # Only the LARGEST entry is read, and only on a sequence-parallel
+    # mesh (--sp > 1): a prompt longer than it takes the one-shot ring-
+    # attention prefill, padded to a multiple of it (one compile per
+    # padded length). Every other prompt rides the ragged step, which
+    # pads to no bucket.
     prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024, 2048)
     # -- ragged mixed-batch attention ----------------------------------------
     # ONE token-budget dispatch packs any mix of variable-length prefill
     # spans and decode tokens into a flattened stream (Pallas ragged
     # kernel on TPU, jnp twin elsewhere) — no power-of-two bucket
-    # padding. pp > 1 runtimes serve the stage-scheduled bucketed
-    # prefill path instead (the ragged forward is single-stage).
+    # padding.
     # Token budget of one ragged dispatch: decode rows (1 token per
     # active slot) plus as many prefill-tail tokens as fit. Clamped up
     # to max_slots + token_granule so a full decode batch always fits.
@@ -290,11 +289,6 @@ class EngineConfig:
     # Decode steps executed per host-loop iteration when no prefill pending
     # (amortizes dispatch overhead via lax.scan).
     decode_steps_per_iter: int = 8
-    # Max batched-prefill forwards admitted per engine tick: TTFT-first,
-    # but bounded so an arrival storm can't starve active decode streams
-    # (the reference's analogue admits one task per loop pass). Chunked
-    # prefills are separately bounded at one chunk per tick.
-    prefill_batches_per_tick: int = 2
     # Repeat-penalty window: how many recent context tokens are penalized
     # (llama.cpp repeat_last_n; engine-wide static).
     repeat_last_n: int = 64
@@ -306,19 +300,12 @@ class EngineConfig:
     # tiny hits aren't worth routing through the chunked prefill.
     prefix_cache_min_pages: int = 1
     # Mesh axis sizes; tp=-1 means "all remaining devices". The engine
-    # builds its (data, pipe, seq, expert, tensor) mesh from these unless
+    # builds its (data, seq, expert, tensor) mesh from these unless
     # an explicit mesh object is passed to TPUEngine.
     dp: int = 1
     sp: int = 1
     tp: int = 1
-    pp: int = 1
     ep: int = 1
-    # GPipe microbatches per pp dispatch (None -> one per stage). The right
-    # value is workload-dependent: prefill is compute-bound (more
-    # microbatches shrink the (P-1)/(M+P-1) bubble) while decode is
-    # weight-streaming-bound (each microbatch step re-streams the stage's
-    # weights, so FEWER can win) — sweep on hardware.
-    pp_microbatches: Optional[int] = None
     dtype: str = "bfloat16"
     # -- int8 quantization (serving density) ---------------------------------
     # weights_dtype="int8": per-channel symmetric int8 weights quantized
@@ -327,7 +314,7 @@ class EngineConfig:
     # weight-streaming-bound dispatch pays. kv_dtype="int8": int8 KV
     # pages with per-page-row fp32 scales stored alongside the pool —
     # every page shrinks ~2x, so ~2x concurrent requests fit the same
-    # HBM. Invalid combinations (MoE weights, pp/sp KV) fail fast at
+    # HBM. Invalid combinations (MoE weights, sp KV) fail fast at
     # startup via validate_quant_config.
     weights_dtype: str = "bfloat16"
     kv_dtype: str = "bfloat16"
@@ -685,8 +672,7 @@ def validate_tiers(spec: Optional[str], members) -> Optional[str]:
 
 
 def validate_quant_config(weights_dtype: str, kv_dtype: str,
-                          pp: int = 1, sp: int = 1,
-                          model_names=()) -> Optional[str]:
+                          sp: int = 1, model_names=()) -> Optional[str]:
     """Fail-fast validation of the quantization flags BEFORE any device
     work: returns an error string (None = valid). One definition shared
     by the CLI, the SPMD worker entry, and ModelRuntime so a typo'd or
@@ -696,10 +682,6 @@ def validate_quant_config(weights_dtype: str, kv_dtype: str,
                 f"got {weights_dtype!r}")
     if kv_dtype not in QUANT_DTYPES:
         return f"--kv-dtype must be one of {QUANT_DTYPES}, got {kv_dtype!r}"
-    if kv_dtype == "int8" and pp > 1:
-        return ("--kv-dtype=int8 needs the ragged attention path; pp > 1 "
-                "runtimes serve the stage-scheduled bucketed prefill whose "
-                "pipeline forwards read bf16 pages")
     if kv_dtype == "int8" and sp > 1:
         return ("--kv-dtype=int8 is unsupported with sequence-parallel "
                 "prefill (its all-layer KV scatter bypasses the quantized "

@@ -10,18 +10,19 @@ share one forward step on the TPU:
     core (cpp/mqcore.cpp) whenever a model runtime has slot+page capacity —
     the queue-side policy is identical to the reference, but what's being
     scheduled is a seat in the decode batch, not a backend slot.
-  - prefill: one padded-bucket forward per new request writes its prompt KV
-    into paged slots and samples the first token (TTFT path).
-  - decode: ONE jitted step advances every active slot by one token; when
-    no admissions are pending the engine runs K steps inside a lax.scan to
-    amortize host dispatch (critical: per-dispatch latency to the chip
-    dominates otherwise).
+  - prefill: a prompt rides the ragged step as a span of tokens — as many
+    as the token budget leaves room for, tick by tick — beside every live
+    decode row; the step that holds a prompt's last token samples its
+    first output token (TTFT path).
+  - decode: when no prompt is waiting mid-span the engine runs K decode
+    steps inside a lax.scan to amortize host dispatch (critical:
+    per-dispatch latency to the chip dominates otherwise).
   - cancellation: client disconnects free the slot and its KV pages
     immediately (reference analogue: dispatcher.rs:537-551 drops the stream
     and frees the backend; here the reclaimed resource is HBM pages).
 
-All step functions are shape-static (fixed slot count, fixed buckets,
-donated caches) => each (bucket, K) compiles exactly once.
+All step functions are shape-static (fixed slot count, a ladder of padded
+token totals, donated caches) => each (total, K) compiles exactly once.
 """
 
 from __future__ import annotations
@@ -53,7 +54,6 @@ from ollamamq_tpu.models import llama, moe, weights
 from ollamamq_tpu.ops.sampling import (accept_prefix, maybe_apply_penalties,
                                        per_row_keys, sample_tokens_rowwise,
                                        sampling_flags)
-from ollamamq_tpu.parallel import pipeline
 from ollamamq_tpu.parallel.mesh import (make_mesh, replica_submesh,
                                         validate_tp_for_model)
 from ollamamq_tpu.parallel.sharding import kv_cache_spec, shard_params
@@ -505,11 +505,10 @@ class ModelRuntime:
         # Int8 quantization (weights and/or KV pages): validated here
         # too — tests and embedders construct runtimes directly, and an
         # unsupported combination must fail at build, not first dispatch.
-        _pp_probe = dict(mesh.shape).get("pipe", 1) if mesh is not None else 1
         _sp_probe = dict(mesh.shape).get("seq", 1) if mesh is not None else 1
         err = validate_quant_config(
             engine_cfg.weights_dtype, engine_cfg.kv_dtype,
-            pp=_pp_probe, sp=_sp_probe, model_names=(name,))
+            sp=_sp_probe, model_names=(name,))
         if err is not None:
             raise ValueError(err)
         self.weights_dtype = engine_cfg.weights_dtype
@@ -518,35 +517,6 @@ class ModelRuntime:
             validate_tp_for_model(
                 mesh.shape["tensor"], model_cfg.num_kv_heads, model_cfg.num_heads
             )
-        # Pipeline parallelism: layers (weights + KV pages) split over the
-        # mesh "pipe" axis; forwards swap to the shard_map'd GPipe schedule
-        # (parallel/pipeline.py).
-        self._pp = dict(mesh.shape).get("pipe", 1) if mesh is not None else 1
-        if self._pp > 1:
-            if model_cfg.num_layers % self._pp != 0:
-                raise ValueError(
-                    f"pp={self._pp} must divide num_layers="
-                    f"{model_cfg.num_layers} ({name})")
-            if dict(mesh.shape).get("seq", 1) > 1:
-                raise ValueError(
-                    "pp and sp cannot combine on one runtime: pipeline "
-                    "stages and sequence shards contend for the same "
-                    "activation layout (use pp x tp, or sp x tp)")
-            if model_cfg.num_experts:
-                raise ValueError(
-                    "pp with an MoE model is not supported: the pipeline "
-                    "stage body runs the dense FFN (use ep x tp for MoE)")
-            if model_cfg.qk_norm_kind == "full":
-                raise ValueError(
-                    "pp with a whole-vector q/k norm is not supported: the "
-                    "pipeline stage body norms per head")
-            # forward_embed is a plain GSPMD scan: over pipe-sharded layer
-            # stacks XLA would all-gather every stage's weights into each
-            # group — an OOM on exactly the >HBM models pp exists for.
-            # Serve generate only; embeds get the kind-gate's clean error.
-            self.SERVES = ("generate",)
-            log.info("%s: pp=%d runtime serves generate only "
-                     "(embed needs pipe-replicated layers)", name, self._pp)
         # Dense models on an --ep mesh are fine (their weights carry no
         # expert-axis spec, so they replicate over it); only an MoE model
         # whose expert count doesn't divide is a real layout error.
@@ -566,7 +536,6 @@ class ModelRuntime:
                 # Random weights are drawn shard by shard on the mesh
                 # (the replicated-group rewrite below needs them whole).
                 mesh=mesh if tp_axis <= model_cfg.num_kv_heads else None,
-                pp=self._pp > 1,
             )
         )
         if tp_axis > model_cfg.num_kv_heads:
@@ -585,8 +554,8 @@ class ModelRuntime:
         if mesh is not None:
             from jax.sharding import NamedSharding
 
-            params = shard_params(params, mesh, pp=self._pp > 1)
-            kv_sharding = NamedSharding(mesh, kv_cache_spec(pp=self._pp > 1))
+            params = shard_params(params, mesh)
+            kv_sharding = NamedSharding(mesh, kv_cache_spec())
         self.params = params
         self.kc, self.vc = kvc.alloc_kv_pool(
             model_cfg, engine_cfg, kv_sharding, dtype,
@@ -661,13 +630,14 @@ class ModelRuntime:
         # Embed-kind requests: stateless batch forwards, no slot/KV claim.
         self.pending_embed: collections.deque = collections.deque()
         self._block_ver = -1  # force one startup sweep (disk-loaded blocklist)
-        # Long prompts mid-chunked-prefill (one chunk advanced per tick).
+        # Admitted prompts mid-prefill (a span of each rides every tick's
+        # ragged step, as the token budget allows).
         self.chunking: collections.deque = collections.deque()
         # Requests inside a prefill forward right now (cancel() must still
         # find them; installation re-checks the cancelled flag).
         self.inflight_prefill: List[Request] = []
-        # Keys carry the trace-time sampling flags: (bucket, B, flags) |
-        # ("chunk", C, flags) | ("sp", T, flags); decode: (k_steps, flags).
+        # Keys carry the trace-time sampling flags: ("ragged", T_pad, k_cap,
+        # flags) | ("sp", T, flags); decode: (k_steps, flags).
         self._prefill_jits: Dict[tuple, callable] = {}
         # name -> (content bytes, device array); see _dev().
         self._dev_cache: Dict[str, tuple] = {}
@@ -687,15 +657,7 @@ class ModelRuntime:
             jax.default_backend(), engine_cfg.kv_dtype)
         log.info("%s: attention=%s (%s)", name, self.attn_impl, why)
         # Ragged mixed-batch scheduling: prefill spans + decode tokens
-        # pack into ONE token-budget dispatch (no bucket padding). The
-        # pipeline-parallel forward is stage-scheduled and keeps the
-        # bucketed prefill path (the --attention=bucketed oracle itself
-        # was removed one release after ragged shipped, as scheduled).
-        self.ragged = self._pp == 1
-        if self._pp > 1:
-            log.warning("%s: pp=%d serves the bucketed prefill path "
-                        "(the ragged forward is single-stage)", name,
-                        self._pp)
+        # pack into ONE token-budget dispatch (no bucket padding).
         g = max(1, engine_cfg.token_granule)
         # A full decode batch (one token per slot) plus at least one
         # granule of prefill must always fit one dispatch.
@@ -703,10 +665,9 @@ class ModelRuntime:
         self._ragged_budget = -(-max(engine_cfg.max_batch_tokens,
                                      engine_cfg.max_slots + g) // g) * g
         # Allowed stream totals: a power-of-two ladder over the granule,
-        # capped by the budget — one compile per rung (like the bucketed
-        # path's per-bucket compiles, but the composer TRIMS the last
-        # span down to a rung instead of padding up to one, so steady-
-        # state dispatches still pay (near) zero padding).
+        # capped by the budget — one compile per rung; the composer TRIMS
+        # the last span down to a rung instead of padding up to one, so
+        # steady-state dispatches pay (near) zero padding.
         ladder = []
         v = g
         while v < self._ragged_budget:
@@ -720,11 +681,7 @@ class ModelRuntime:
         # the ragged span path. Host-side accounting feeds the accept-
         # rate gauge and the per-user auto-throttle; the actual accept/
         # rollback machinery lives in _get_ragged_jit / step_ragged.
-        self.spec = bool(engine_cfg.spec) and self.ragged \
-            and engine_cfg.spec_k > 0
-        if engine_cfg.spec and not self.ragged:
-            log.warning("%s: --spec needs the ragged attention path; "
-                        "speculation disabled on this runtime", name)
+        self.spec = bool(engine_cfg.spec) and engine_cfg.spec_k > 0
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_rollbacks = 0
@@ -859,21 +816,6 @@ class ModelRuntime:
         return True
 
     # -- compiled steps ----------------------------------------------------
-    def _bucket_for(self, n: int) -> int:
-        """Smallest prefill bucket covering n tokens (pp > 1 prefill/
-        chunk path). Oversize pieces must have been routed to the
-        chunked/sequence-parallel path by the caller — silently
-        answering the largest bucket here would truncate the forward's
-        view of the prompt and mask a packing bug, so it must fail
-        loudly, not approximately."""
-        for b in self.ecfg.prefill_buckets:
-            if n <= b:
-                return b
-        raise ValueError(
-            f"piece of {n} tokens exceeds the largest prefill bucket "
-            f"{self.ecfg.prefill_buckets[-1]}; oversize prompts must take "
-            "the chunked or sequence-parallel prefill path")
-
     def _next_key(self):
         self._rng_counter += 1
         return jax.random.PRNGKey(self._rng_counter)
@@ -906,33 +848,6 @@ class ModelRuntime:
     # Each returns (sampled_tokens, kc', vc', recent'); the caller assigns
     # the three state arrays back. The two step programs of the pipelined
     # loop (ragged, decode) also take and return the `last_ids` carry.
-    def _dispatch_prefill(self, bucket, B, tokens, lens, slot_ids, pt_rows,
-                          temp, tk, tp, pen, pres, freq, seeds, key):
-        self._fault("prefill")
-        fn = self._get_prefill_jit(
-            bucket, B, sampling_flags(temp, tk, tp, pen, pres, freq)
-        )
-        return fn(self.params, jnp.asarray(tokens), jnp.asarray(lens),
-                  self.kc, self.vc, self.recent, jnp.asarray(slot_ids),
-                  jnp.asarray(pt_rows), jnp.asarray(temp), jnp.asarray(tk),
-                  jnp.asarray(tp), jnp.asarray(pen), jnp.asarray(pres),
-                  jnp.asarray(freq), jnp.asarray(seeds), key)
-
-    def _dispatch_chunk(self, chunk, tokens, start, cl, slot_id, is_final,
-                        is_first, seed_row, pt_row, temp, tk, tp, pen, pres,
-                        freq, seeds, key):
-        self._fault("chunk")
-        fn = self._get_chunk_jit(
-            chunk, sampling_flags(temp, tk, tp, pen, pres, freq)
-        )
-        return fn(self.params, jnp.asarray(tokens), jnp.asarray(start),
-                  jnp.asarray(cl), self.kc, self.vc, self.recent,
-                  jnp.asarray(slot_id), jnp.asarray(is_final),
-                  jnp.asarray(is_first), jnp.asarray(seed_row),
-                  jnp.asarray(pt_row), jnp.asarray(temp), jnp.asarray(tk),
-                  jnp.asarray(tp), jnp.asarray(pen), jnp.asarray(pres),
-                  jnp.asarray(freq), jnp.asarray(seeds), key)
-
     def _dispatch_ragged(self, T_pad, k_cap, tokens, tok_seq, tok_pos,
                          write_slots, q_start, q_len, kv_len, ring_len,
                          is_first, append, is_spec, seed_rows, slot_ids, pt,
@@ -968,8 +883,7 @@ class ModelRuntime:
         """ONE mixed-batch step: forward the flattened [T_pad] token
         stream (prefill spans + decode tokens + speculative verify
         spans) through forward_ragged, then per-sequence penalty-ring
-        maintenance and sampling — the ragged-mode replacement for the
-        prefill, chunk, AND single-step decode jits. Compiles once per
+        maintenance and sampling. Compiles once per
         (padded token total, draft cap, sampling flags); the engine pads
         totals to the token granule and uses only k_cap in {0, spec_k},
         so the variant count stays small.
@@ -1041,7 +955,7 @@ class ModelRuntime:
                 rows = recent[slot_ids]  # [B, W]
                 # First span of a request: the ring opens from seed_rows
                 # (all -1 fresh, the cached prefix's last W tokens on a
-                # prefix-cache hit) — chunk-jit semantics, vectorized.
+                # prefix-cache hit).
                 rows = jnp.where(is_first[:, None] > 0, seed_rows, rows)
                 # Slide each ring by roll_n tokens taken from the row's
                 # own stream span: span length for prefill rows, 0 for
@@ -1154,105 +1068,6 @@ class ModelRuntime:
                   self._dev("tk", tk), self._dev("tp", tp),
                   self._dev("pen", pen), self._dev("pres", pres),
                   self._dev("freq", freq), self._dev("seeds", seeds), key)
-
-    def _get_prefill_jit(self, bucket: int, batch: int = 1,
-                         flags=(True, True, True)):
-        key_ = (bucket, batch, flags)
-        _sp_compile_evict(self, self._prefill_jits, key_)
-        if key_ not in self._prefill_jits:
-            cfg, ps = self.cfg, self.ecfg.page_size
-            need_pen, need_mask, need_sample = flags
-            pp, mesh = self._pp, self.mesh
-            n_micro = self.ecfg.pp_microbatches
-
-            def mq_prefill(params, tokens, seq_lens, kc, vc, recent, slot_ids,
-                           pt, temp, tk, tp, pen, pres, freq, seeds, key):
-                if pp > 1:
-                    logits, kc, vc = pipeline.pp_forward_prefill(
-                        params, cfg, tokens, seq_lens, kc, vc, pt, ps, mesh,
-                        n_micro=n_micro,
-                    )
-                else:
-                    logits, kc, vc = llama.forward_prefill(
-                        params, cfg, tokens, seq_lens, kc, vc, pt, ps
-                    )
-                B, T = tokens.shape
-                W = recent.shape[1]
-                # Ring rows = the last W prompt tokens of each sequence.
-                idx = seq_lens[:, None] - W + jnp.arange(W)[None, :]  # [B,W]
-                gathered = jnp.take_along_axis(
-                    tokens, jnp.clip(idx, 0, T - 1), axis=1
-                )
-                rows = jnp.where(idx >= 0, gathered, -1)
-                pen_logits = maybe_apply_penalties(logits, rows, pen, pres,
-                                                   freq, need_pen)
-                row_keys = per_row_keys(key, seeds, seq_lens)
-                tok = sample_tokens_rowwise(pen_logits, row_keys, temp, tk,
-                                            tp, need_mask, need_sample)
-                rows = jnp.concatenate([rows[:, 1:], tok[:, None]], axis=1)
-                recent = recent.at[slot_ids].set(rows)
-                return tok, kc, vc, recent
-
-            _sp_note_compile(self, "prefill", key_, self._prefill_jits,
-                             jax.jit(mq_prefill, donate_argnums=(3, 4, 5)))
-        return self._prefill_jits[key_]
-
-    def _get_chunk_jit(self, chunk: int, flags=(True, True, True)):
-        """Chunked prefill step for prompts longer than the largest bucket:
-        each call writes one chunk's K/V and attends over the prefix. The
-        returned sampled token is only meaningful for the final chunk."""
-        _sp_compile_evict(self, self._prefill_jits, ("chunk", chunk, flags))
-        if ("chunk", chunk, flags) not in self._prefill_jits:
-            cfg, ps = self.cfg, self.ecfg.page_size
-            need_pen, need_mask, need_sample = flags
-            pp, mesh = self._pp, self.mesh
-            n_micro = self.ecfg.pp_microbatches
-
-            def mq_prefill_chunk(params, tokens, start, chunk_lens, kc, vc,
-                                 recent, slot_id, is_final, is_first, seed_row,
-                                 pt, temp, tk, tp, pen, pres, freq, seeds,
-                                 key):
-                if pp > 1:
-                    logits, kc, vc = pipeline.pp_forward_prefill_chunk(
-                        params, cfg, tokens, start, chunk_lens, kc, vc, pt,
-                        ps, mesh, n_micro=n_micro,
-                    )
-                else:
-                    logits, kc, vc = llama.forward_prefill_chunk(
-                        params, cfg, tokens, start, chunk_lens, kc, vc, pt, ps
-                    )
-                C = tokens.shape[1]
-                W = recent.shape[1]
-                row = recent[slot_id[0]]  # [W]
-                # First chunk of a request: the penalty ring starts from
-                # seed_row — all -1 for a fresh prompt, the cached
-                # prefix's last W tokens on a prefix-cache hit (start > 0
-                # then, so this can't key off start == 0). Travels on the
-                # SPMD wire like every other input, so hosts stay in step.
-                row = jnp.where(is_first[0] > 0, seed_row[0], row)
-                # Slide the window: prev ++ this chunk's valid tokens, then
-                # keep the last W (dynamic shift by chunk_len).
-                chunk_toks = jnp.where(
-                    jnp.arange(C) < chunk_lens[0], tokens[0], -1
-                )
-                combined = jnp.concatenate([row, chunk_toks])  # [W+C]
-                row = jax.lax.dynamic_slice(combined, (chunk_lens[0],), (W,))
-                pen_logits = maybe_apply_penalties(logits, row[None], pen,
-                                                   pres, freq, need_pen)
-                row_keys = per_row_keys(key, seeds, start + chunk_lens)
-                tok = sample_tokens_rowwise(pen_logits, row_keys, temp, tk,
-                                            tp, need_mask, need_sample)
-                # Append the sampled token only on the final chunk.
-                row_f = jnp.concatenate([row[1:], tok])
-                row = jnp.where(is_final[0] > 0, row_f, row)
-                recent = recent.at[slot_id[0]].set(row)
-                return tok, kc, vc, recent
-
-            _sp_note_compile(self, "chunk", ("chunk", chunk, flags),
-                             self._prefill_jits,
-                             jax.jit(mq_prefill_chunk,
-                                     donate_argnums=(4, 5, 6)))
-        return self._prefill_jits[("chunk", chunk, flags)]
 
     def _dispatch_prefill_sp(self, T, tokens, lens, slot_ids, pt_rows,
                              temp, tk, tp, pen, pres, freq, seeds, key):
@@ -1376,8 +1191,7 @@ class ModelRuntime:
             cfg, ps = self.cfg, self.ecfg.page_size
             attn_impl = self.attn_impl
             need_pen, need_mask, need_sample = flags
-            pp, mesh = self._pp, self.mesh
-            n_micro = self.ecfg.pp_microbatches
+            mesh = self.mesh
 
             def mq_decode_scan(params, tokens, positions, kc, vc, recent,
                                last_ids, active, pt, temp, tk, tp, pen, pres,
@@ -1391,18 +1205,11 @@ class ModelRuntime:
 
                 def step(carry, _):
                     tokens, positions, kc, vc, recent, key = carry
-                    if pp > 1:
-                        # Pallas runs per-device inside the stage.
-                        logits, kc, vc = pipeline.pp_forward_decode(
-                            params, cfg, tokens, positions, kc, vc, pt, ps,
-                            mesh, n_micro=n_micro, attn_impl=attn_impl,
-                        )
-                    else:
-                        logits, kc, vc, *load = llama.forward_decode(
-                            params, cfg, tokens, positions, kc, vc, pt, ps,
-                            attn_impl=attn_impl, active=active, mesh=mesh,
-                            moe_load=bool(cfg.num_experts),
-                        )
+                    logits, kc, vc, *load = llama.forward_decode(
+                        params, cfg, tokens, positions, kc, vc, pt, ps,
+                        attn_impl=attn_impl, active=active, mesh=mesh,
+                        moe_load=bool(cfg.num_experts),
+                    )
                     key, sub = jax.random.split(key)
                     pen_logits = maybe_apply_penalties(logits, recent[:S],
                                                        pen, pres, freq,
@@ -1425,7 +1232,7 @@ class ModelRuntime:
                     new_rows = jnp.where(active[:, None] > 0, rolled, recent[:S])
                     recent = recent.at[:S].set(new_rows)
                     out = nxt
-                    if pp == 1 and load:  # the pass's counters, behind the ids
+                    if load:  # the pass's counters, behind the ids
                         out = jnp.concatenate([nxt, moe.load_stats(load[0])])
                     return (nxt, positions + 1, kc, vc, recent, key), out
 
@@ -1568,242 +1375,9 @@ class ModelRuntime:
                 or int(self.seq_lens[slot]) + 1 >= self._max_ctx)
 
     # -- steps -------------------------------------------------------------
-    MAX_PREFILL_BATCH = 4
-
-    def step_prefill(self, core: MQCore) -> bool:
-        """Admit pending requests into free slots. Same-bucket prompts
-        prefill TOGETHER in one forward (up to MAX_PREFILL_BATCH), which
-        collapses the cold-start TTFT of a burst of arrivals. Long prompts
-        hand off to the incremental chunked path. Returns True if ran."""
-        if self.policy is not None:
-            # Decision point (a): slot-admission order. fcfs/None is a
-            # no-op; srpt/edf stable-sort the released queue in place.
-            self.policy.reorder_pending(self.pending_prefill)
-        batch: List[tuple] = []  # (req, slot, pages, n)
-        bucket = None
-        claimed: set = set()
-        largest = self.ecfg.prefill_buckets[-1]
-        while self.pending_prefill and len(batch) < self.MAX_PREFILL_BATCH:
-            req = self.pending_prefill[0]
-            if req.cancelled.is_set():
-                self.pending_prefill.popleft()
-                core.mark_dropped(req.user)
-                self._jrec("finish", req, reason="cancelled")
-                req.finish(FinishReason.CANCELLED)
-                continue
-            if req._retry_at > time.monotonic():
-                break  # head is backing off after a contained fault
-            if req.expired():
-                # Deadline check BEFORE the prefill dispatch: expired
-                # queued work is dropped without burning TPU time.
-                self.pending_prefill.popleft()
-                drop_expired(req, core, self.name, journal=self.journal)
-                continue
-            n = len(req.prompt_tokens)
-            # Prompts beyond the largest bucket stream through chunked
-            # prefill; the hard ceiling is the paged context itself.
-            max_prompt = min(self.ecfg.max_context - 1, self.cfg.max_seq_len - 1)
-            if n > max_prompt:
-                self.pending_prefill.popleft()
-                core.mark_dropped(req.user)  # mark_started ran at admission
-                self._jrec("finish", req, reason="error")
-                req.finish(
-                    FinishReason.ERROR,
-                    error=f"prompt length {n} exceeds maximum {max_prompt}",
-                )
-                continue
-            # Prefix-cache lookup: pin the longest cached full-page prefix
-            # and prefill only the uncached tail through the chunked path.
-            # SP runtimes keep their one-shot ring-attention forward for
-            # prompts beyond the largest bucket.
-            if (self.prefix_cache is not None
-                    and not (self._sp and n > largest)):
-                nodes, shared = self._match_prefix(req.prompt_tokens)
-                if nodes:
-                    if batch:
-                        break  # run the collected batch first
-                    slot = self._claim_slot(claimed)
-                    if slot is None:
-                        return False
-                    # Pin BEFORE the tail allocation: its eviction
-                    # backstop must never reclaim the very pages we
-                    # matched.
-                    self.prefix_cache.pin(nodes)
-                    tail = self._alloc_tail(len(shared), n + 1)
-                    if tail is None:
-                        self.prefix_cache.release(nodes)
-                        return False  # wait for frees
-                    self.pending_prefill.popleft()
-                    req.stats.prefill_started_at = time.monotonic()
-                    prefix_len = len(shared) * self.ecfg.page_size
-                    self.slot_pins[slot] = list(nodes)
-                    self.slot_pages[slot] = list(shared) + tail
-                    self.prefix_cache.note_hit(prefix_len)
-                    req.trace_event("prefix_hit", cached_tokens=prefix_len,
-                                    tokens=n)
-                    req._pt_row = kvc.make_page_table_row(
-                        self.slot_pages[slot], self.ecfg.max_pages_per_seq
-                    )[None, :]
-                    # The tail rides the chunked path starting at
-                    # prefix_len; decode writes start past the shared
-                    # pages, so they stay read-only (no copy-on-write).
-                    req._chunk_pos = prefix_len
-                    req._chunk_base = prefix_len
-                    req._prefill_slot = slot
-                    self.reserved_slots.add(slot)
-                    self.chunking.append(req)
-                    return True
-            if n > largest:
-                if batch:
-                    break  # run the collected batch first; chunk next tick
-                slot = self._claim_slot(claimed)
-                if slot is None:
-                    return False
-                pages = self._alloc_pages(n + 1)
-                if pages is None:
-                    return False
-                self.pending_prefill.popleft()
-                self._pc_miss()
-                req.stats.prefill_started_at = time.monotonic()
-                self.slot_pages[slot] = pages
-                if self._sp:
-                    # Sequence-parallel prefill: ONE forward with the
-                    # sequence sharded over the mesh "seq" axis (ring
-                    # attention over ICI) instead of serial chunks —
-                    # SURVEY §5 long-context row.
-                    self._prefill_sp(req, slot, n, core)
-                    return True
-                # The row stays OFF the shared page table until the final
-                # chunk installs the slot: interleaved decode steps write
-                # every slot's position through self.page_table, and a
-                # reserved slot must keep pointing at the trash page or the
-                # chunk's KV would be stomped.
-                req._pt_row = kvc.make_page_table_row(
-                    pages, self.ecfg.max_pages_per_seq
-                )[None, :]
-                # Incremental chunked prefill: ONE chunk per engine tick so
-                # concurrent decode streams keep flowing. _chunk_base reset
-                # explicitly: a retry/preemption re-admission may have left
-                # a cache-hit base from its previous life.
-                req._chunk_pos = 0
-                req._chunk_base = 0
-                req._prefill_slot = slot
-                self.reserved_slots.add(slot)
-                self.chunking.append(req)
-                return True
-            b = self._bucket_for(n)
-            if bucket is None:
-                bucket = b
-            elif b != bucket:
-                break  # different bucket: next tick's batch
-            slot = self._claim_slot(claimed)
-            if slot is None:
-                break
-            pages = self._alloc_pages(n + 1)
-            if pages is None:
-                break  # pool exhausted; run what we have, retry after frees
-            self.pending_prefill.popleft()
-            self._pc_miss()
-            req.stats.prefill_started_at = time.monotonic()
-            self.slot_pages[slot] = pages
-            self.page_table[slot, :] = kvc.make_page_table_row(
-                pages, self.ecfg.max_pages_per_seq
-            )
-            claimed.add(slot)
-            batch.append((req, slot, pages, n))
-
-        if not batch:
-            return False
-
-        # Pad multi-request batches to the fixed MAX so each bucket compiles
-        # at most twice (B=1 for sparse traffic, B=MAX for bursts); padding
-        # rows use trash-page tables and zero lengths, so the extra compute
-        # is bounded and writes land in the trash page.
-        B = 1 if len(batch) == 1 else self.MAX_PREFILL_BATCH
-        pt_rows = np.full(
-            (B, self.ecfg.max_pages_per_seq), kvc.TRASH_PAGE, np.int32
-        )
-        tokens = np.zeros((B, bucket), np.int32)
-        lens = np.zeros((B,), np.int32)
-        temp = np.zeros((B,), np.float32)
-        top_k = np.zeros((B,), np.int32)
-        top_p = np.ones((B,), np.float32)
-        pen = np.ones((B,), np.float32)
-        pres = np.zeros((B,), np.float32)
-        freq = np.zeros((B,), np.float32)
-        seeds = np.zeros((B,), np.int32)
-        # Padding rows target the trash ring-row (index max_slots), never a
-        # live slot.
-        slot_ids = np.full((B,), self.ecfg.max_slots, np.int32)
-        for i, (req, slot, _, n) in enumerate(batch):
-            tokens[i, :n] = req.prompt_tokens
-            lens[i] = n
-            temp[i] = req.sampling.temperature
-            top_k[i] = req.sampling.top_k
-            top_p[i] = req.sampling.top_p
-            pen[i] = req.sampling.repeat_penalty
-            pres[i] = req.sampling.presence_penalty
-            freq[i] = req.sampling.frequency_penalty
-            seeds[i] = req.sampling.seed
-            slot_ids[i] = slot
-            pt_rows[i] = self.page_table[slot]
-        self.inflight_prefill = [req for req, *_ in batch]
-        for req, _, _, n in batch:
-            req.trace_event("prefill", bucket=bucket, tokens=n)
-        # Batch-compose decision record: who shares this forward, the
-        # padded shape it pays for, and the occupancy/backlog inputs the
-        # composition saw — the offline analyzer's padding-waste and
-        # occupancy stats read straight off these.
-        real_tokens = int(sum(n for *_, n in batch))
-        self._jrec("batch",
-                   slots=[slot for _, slot, _, _ in batch],
-                   reqs=[req.req_id for req, *_ in batch],
-                   bucket=bucket, batch_size=B,
-                   tokens=real_tokens,
-                   occupancy=round(self.active_count()
-                                   / max(1, self.ecfg.max_slots), 4),
-                   pending=len(self.pending_prefill),
-                   free_pages=self.alloc.free_pages,
-                   mode="bucketed", padded_tokens=int(bucket * B))
-        self._tm_padding.set(
-            round(1.0 - real_tokens / max(1, bucket * B), 4))
-        t0 = time.monotonic()
-        try:
-            toks, self.kc, self.vc, self.recent = self._dispatch_prefill(
-                bucket, B, tokens, lens, slot_ids, pt_rows, temp, top_k,
-                top_p, pen, pres, freq, seeds, self._next_key(),
-            )
-            toks = np.asarray(toks)
-        except Exception as e:
-            # Contain the failure to THIS batch: free its pages, then give
-            # each implicated request one retried dispatch (with backoff)
-            # before poisoning it — one bad input or transient device
-            # fault must neither kill bystanders nor crash-loop.
-            desync = isinstance(e, WorkerDesyncError)
-            for req, slot, pages, _ in batch:
-                self._release_slot_pages(slot)
-                if desync or not self._retry_requeue(
-                        req, self.pending_prefill, f"prefill failed: {e}"):
-                    core.mark_dropped(req.user)
-                    req.finish(FinishReason.ERROR, error=self._poison_msg(
-                        req, f"prefill failed: {e}"))
-            self.inflight_prefill = []
-            log.exception("batched prefill failed (bucket=%d B=%d)", bucket, B)
-            if desync:
-                raise  # diverged SPMD state: the runtime must kill+reload
-            return True
-        finally:
-            self.inflight_prefill = []
-        self.prefill_latency_ms = (time.monotonic() - t0) * 1e3
-        self._tm_prefill.observe(self.prefill_latency_ms)
-
-        for i, (req, slot, _, n) in enumerate(batch):
-            self._install_slot(slot, req, n, int(toks[i]), core)
-        return True
-
-    def _claim_slot(self, claimed: set) -> Optional[int]:
+    def _claim_slot(self) -> Optional[int]:
         for i, r in enumerate(self.slot_req):
-            if r is None and i not in claimed and i not in self.reserved_slots:
+            if r is None and i not in self.reserved_slots:
                 return i
         return None
 
@@ -1919,8 +1493,8 @@ class ModelRuntime:
     def _install_slot(self, slot: int, req: Request, n: int, tok: int,
                       core: MQCore) -> None:
         """Activate a freshly prefilled request in its decode slot and emit
-        the first sampled token (the bucketed paths: the id is on the
-        host already)."""
+        the first sampled token (the sequence-parallel prefill: the id is
+        on the host already)."""
         self._seat_slot(slot, req, n)
         self.tokens_generated += 1
         if self._emit_token(slot, tok, core, n):
@@ -2019,7 +1593,7 @@ class ModelRuntime:
         n = int(blob["n_pages"])
         if n <= 0 or n > self.alloc.max_pages_per_seq:
             return False
-        slot = self._claim_slot(set())
+        slot = self._claim_slot()
         if slot is None:
             return False
         pages = self._alloc_tail(0, n * self.ecfg.page_size)
@@ -2407,104 +1981,6 @@ class ModelRuntime:
                     f"retr{'y' if req.retries == 1 else 'ies'})")
         return msg
 
-    def step_chunk(self, core: MQCore) -> bool:
-        """Advance ONE chunk of one long-prompt prefill. Returns True if a
-        chunk ran (the engine loop interleaves these with decode steps)."""
-        if not self.chunking:
-            return False
-        req = self.chunking[0]
-        slot = req._prefill_slot
-        largest = self.ecfg.prefill_buckets[-1]
-        n = len(req.prompt_tokens)
-
-        if req.cancelled.is_set() or req.stream.overflowed:
-            self.chunking.popleft()
-            self._release_slot_pages(slot)
-            self.reserved_slots.discard(slot)
-            core.mark_dropped(req.user)
-            self._jrec("finish", req, reason="cancelled")
-            req.finish(FinishReason.CANCELLED)
-            return True
-        if req.expired():
-            # Deadline passed mid-chunked-prefill: stop burning chunks on
-            # a response nobody will wait for.
-            self.chunking.popleft()
-            self._release_slot_pages(slot)
-            self.reserved_slots.discard(slot)
-            drop_expired(req, core, self.name, journal=self.journal)
-            return True
-
-        s = req.sampling
-        chunk_start = req._chunk_pos
-        base = getattr(req, "_chunk_base", 0)  # >0: cached-prefix tail
-        # Chunk size = smallest bucket covering the remainder (compiles
-        # once per bucket, like batched prefill): a short cache-hit tail
-        # must not pay a largest-bucket forward.
-        piece = req.prompt_tokens[chunk_start:chunk_start + largest]
-        cl = len(piece)
-        chunk = self._bucket_for(cl)
-        tokens = np.zeros((1, chunk), np.int32)
-        tokens[0, :cl] = piece
-        is_first = 1 if chunk_start == base else 0
-        W = self.ecfg.repeat_last_n
-        seed_row = np.full((1, W), -1, np.int32)
-        if is_first and chunk_start > 0:
-            # Cache hit: the penalty ring opens with the cached prefix's
-            # last W tokens, exactly as a full prefill would set it.
-            prev = req.prompt_tokens[max(0, chunk_start - W):chunk_start]
-            seed_row[0, W - len(prev):] = prev
-        req.trace_event("prefill_chunk", pos=chunk_start, tokens=cl)
-        self._jrec("chunk", req, slot=slot, pos=chunk_start, tokens=cl,
-                   cached=base)
-        t0 = time.monotonic()
-        is_final = 1 if chunk_start + cl >= n else 0
-        try:
-            tok, self.kc, self.vc, self.recent = self._dispatch_chunk(
-                chunk, tokens,
-                np.asarray([chunk_start], np.int32), np.asarray([cl], np.int32),
-                np.asarray([slot], np.int32), np.asarray([is_final], np.int32),
-                np.asarray([is_first], np.int32), seed_row,
-                req._pt_row,
-                np.asarray([s.temperature], np.float32),
-                np.asarray([s.top_k], np.int32),
-                np.asarray([s.top_p], np.float32),
-                np.asarray([s.repeat_penalty], np.float32),
-                np.asarray([s.presence_penalty], np.float32),
-                np.asarray([s.frequency_penalty], np.float32),
-                np.asarray([s.seed], np.int32),
-                self._next_key(),
-            )
-        except Exception as e:
-            # Contain to THIS request: release the reserved slot's pages
-            # (and pinned prefix), retry once from scratch, else poison.
-            log.exception("chunked prefill failed for req %d",
-                          req.req_id, extra={"req_id": req.req_id})
-            self.chunking.popleft()
-            self._release_slot_pages(slot)
-            self.reserved_slots.discard(slot)
-            desync = isinstance(e, WorkerDesyncError)
-            if desync or not self._retry_requeue(
-                    req, self.pending_prefill, f"prefill failed: {e}"):
-                core.mark_dropped(req.user)
-                req.finish(FinishReason.ERROR, error=self._poison_msg(
-                    req, f"prefill failed: {e}"))
-            if desync:
-                raise  # diverged SPMD state: the runtime must kill+reload
-            return True
-        self.prefill_latency_ms = (time.monotonic() - t0) * 1e3
-        self._tm_prefill.observe(self.prefill_latency_ms)
-        req._chunk_pos = chunk_start + cl
-        if req._chunk_pos < n:
-            return True  # more chunks next tick
-
-        # Final chunk: publish the page-table row (decode may write through
-        # it from now on), install the slot, emit the first token.
-        self.chunking.popleft()
-        self.reserved_slots.discard(slot)
-        self.page_table[slot, :] = req._pt_row[0]
-        self._install_slot(slot, req, n, int(np.asarray(tok)[0]), core)
-        return True
-
     # -- ragged mixed-batch scheduling -------------------------------------
     def _admit_ragged(self, core: MQCore) -> bool:
         """Admission for the ragged path: claim a reserved slot + the
@@ -2549,7 +2025,7 @@ class ModelRuntime:
                 # Long prompts on a sequence-parallel mesh keep the
                 # one-shot ring-attention prefill (its activations shard
                 # over the seq axis; the ragged stream does not).
-                slot = self._claim_slot(set())
+                slot = self._claim_slot()
                 if slot is None:
                     return did
                 pages = self._alloc_pages(n + 1)
@@ -2564,7 +2040,7 @@ class ModelRuntime:
             nodes, shared = ([], [])
             if self.prefix_cache is not None:
                 nodes, shared = self._match_prefix(req.prompt_tokens)
-            slot = self._claim_slot(set())
+            slot = self._claim_slot()
             if slot is None:
                 break
             if nodes:
@@ -2631,10 +2107,9 @@ class ModelRuntime:
     def may_overlap(self) -> bool:
         """May a step be launched while the one before it is unsettled?
         Not where composing needs the host to have seen the ids (the
-        n-gram proposer reads generated_ids) and not on the bucketed
-        pipeline-parallel path, whose prefill steps read slot state at
-        rest: those settle every step in the tick that launched it."""
-        return self.ragged and not self.spec
+        n-gram proposer reads generated_ids): such a runtime settles
+        every step in the tick that launched it."""
+        return not self.spec
 
     def settle_inflight(self, core: MQCore) -> None:
         """Bring the runtime to rest: settle the step in flight, if any.
@@ -2762,8 +2237,8 @@ class ModelRuntime:
                 if r.expired():
                     # Deadline check BEFORE composing the verify span —
                     # an expired request must not burn a k-token
-                    # verification (satellite bugfix; prefill and chunk
-                    # already check at their dispatch sites).
+                    # verification (admission and the span composer make
+                    # the same check).
                     self._drop_expired_slot(i, core)
                     continue
                 drafts = self._propose_drafts(r, i)[:max(0, spec_budget)]
@@ -3816,10 +3291,9 @@ class TPUEngine:
         self.core = MQCore(blocklist_path)
         self.core.set_fairness(fairness)
         if mesh is None and (engine_cfg.dp, engine_cfg.sp, engine_cfg.tp,
-                             engine_cfg.pp, engine_cfg.ep) != (1, 1, 1, 1, 1):
+                             engine_cfg.ep) != (1, 1, 1, 1):
             mesh = make_mesh(dp=engine_cfg.dp, sp=engine_cfg.sp,
-                             tp=engine_cfg.tp, pp=engine_cfg.pp,
-                             ep=engine_cfg.ep)
+                             tp=engine_cfg.tp, ep=engine_cfg.ep)
         self.mesh = mesh
         self.dtype = dtype if dtype is not None else jnp.dtype(engine_cfg.dtype)
         self.runtimes: Dict[str, object] = {}
@@ -4916,13 +4390,13 @@ class TPUEngine:
         queued behind an unfinished scan, so an arrival never waits for
         two. Where the next composition needs the host to have seen the
         ids, or state must be at rest, the depth falls to zero and the
-        step is settled in the tick that launched it: a speculating or
-        pipeline-parallel runtime, CPU multi-host (one cross-host
-        computation at a time), pending engine calls and rebuild swaps
-        (settled above, before they run); page exhaustion and failures
-        settle or void inside the step functions. Every runtime's launch
-        comes before any settle, so dp replicas and models on disjoint
-        submeshes run concurrently."""
+        step is settled in the tick that launched it: a speculating
+        runtime, CPU multi-host (one cross-host computation at a time),
+        pending engine calls and rebuild swaps (settled above, before
+        they run); page exhaustion and failures settle or void inside
+        the step functions. Every runtime's launch comes before any
+        settle, so dp replicas and models on disjoint submeshes run
+        concurrently."""
         # The engine thread's time is accounted for without a gap
         # (stepprof.LOOP_PHASES): `other` is open wherever nothing below
         # says otherwise, step timers take the cursor while they run.
@@ -4958,28 +4432,10 @@ class TPUEngine:
                         did_work = True
                         if prev.k_steps:
                             rt.step_collect(prev, self.core)
-                    h = None
-                    if getattr(rt, "ragged", False):
-                        # Ragged mixed batch: admission + ONE token-budget
-                        # dispatch packing prefill spans AND every live
-                        # decode slot (each advances one token inside it).
-                        h = rt.step_ragged_launch(self.core)
-                    else:
-                        # Pipeline-parallel path (pp > 1): stage-scheduled
-                        # bucketed prefill + fused decode.
-                        # TTFT first: admit pending prefills into free
-                        # slots — but bounded per tick, so a sustained
-                        # arrival storm can't starve the active decode
-                        # streams below (VERDICT r3 weak #5).
-                        budget = self.ecfg.prefill_batches_per_tick
-                        while (budget > 0 and rt.pending_prefill
-                               and rt.step_prefill(self.core)):
-                            budget -= 1
-                            did_work = True
-                        # One chunk of any long-prompt prefill per tick,
-                        # interleaved with decode below.
-                        if rt.step_chunk(self.core):
-                            did_work = True
+                    # Ragged mixed batch: admission + ONE token-budget
+                    # dispatch packing prefill spans AND every live
+                    # decode slot (each advances one token inside it).
+                    h = rt.step_ragged_launch(self.core)
                     # Embeds on a generative model: one stateless batch
                     # forward, no slot/page contention with decode.
                     if rt.pending_embed and rt.step_embed(self.core):

@@ -12,9 +12,10 @@ carried through its layer loop (models/llama.py:scan_layers), so a step
 updates it in place: there is one copy of it on the device.
 
 Host side: a free-list allocator of page indices. Page 0 is RESERVED as the
-trash page: page-table rows are padded with it so static-shaped prefill
-scatter writes of padding tokens land harmlessly (see
-models/llama.py:forward_prefill).
+trash page: page-table rows are padded with it, and a step's padding
+tokens (the ragged stream rounds up to a granule; an idle slot's row of
+the decode scan) write their K/V into it, so every scatter is
+static-shaped and lands harmlessly.
 
 Cancellation reclaims pages immediately — the TPU analogue of the
 reference dropping a disconnected client's stream

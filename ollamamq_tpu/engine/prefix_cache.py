@@ -11,10 +11,11 @@ bookkeeping problem). This module is that bookkeeping:
     spells the full token prefix, so equal paths imply bit-identical KV
     content (causal models: K/V at position p depend only on tokens
     [0, p]).
-  - Admission walks the tree (ModelRuntime.step_prefill), pins the
+  - Admission walks the tree (ModelRuntime._admit_ragged), pins the
     longest match (refcount++ on every node of the path — pinned sets
     are upward-closed), seeds the request's page table with the shared
-    pages, and prefills only the uncached tail through the chunked path.
+    pages, and prefills only the uncached tail (its spans start at the
+    cached boundary).
     The last partial prompt page is always private and decode writes
     start strictly after the full prompt pages, so shared pages are
     READ-ONLY on the hot path — no copy-on-write anywhere.

@@ -21,8 +21,7 @@ between the two corrupts the transport; coordinator traffic cannot.
 
 Opcode header (int32[5]: [op, a, b, model_ordinal, replica_ordinal]):
     OP_SHUTDOWN = 0              -> workers exit (no payload)
-    OP_PREFILL  = 1, a=bucket, b=B
-    OP_CHUNK    = 2, a=chunk_size
+    (1 and 2 are not assigned)
     OP_DECODE   = 3, a=k_steps
     OP_ENCODE   = 4, a=B, b=bucket (embedding batch forward, stateless)
     OP_PREFILL_SP = 5, a=T (sequence-parallel long-prompt prefill)
@@ -88,8 +87,6 @@ from ollamamq_tpu.engine.engine import (EncoderRuntime, ModelRuntime,
 log = logging.getLogger("ollamamq.spmd")
 
 OP_SHUTDOWN = 0
-OP_PREFILL = 1
-OP_CHUNK = 2
 OP_DECODE = 3
 OP_ENCODE = 4
 OP_PREFILL_SP = 5
@@ -301,10 +298,10 @@ def payload_spec(op, a, b, S, MP, W):
     place the wire order lives. Senders cast their positional values to
     this spec; workers build a zeros template from it. Broadcast matches
     on tree structure + shape/dtype, so both sides must agree exactly.
-    `W` is the repeat-penalty window (OP_CHUNK carries the first-chunk
-    penalty-ring seed row, which on a prefix-cache hit holds the cached
-    prefix's last W tokens — the tree itself is primary-only host state;
-    only its effects travel)."""
+    `W` is the repeat-penalty window (OP_RAGGED carries each row's
+    first-span penalty-ring seed row, which on a prefix-cache hit holds
+    the cached prefix's last W tokens — the tree itself is primary-only
+    host state; only its effects travel)."""
 
     def samp(n):  # temp, top_k, top_p, repeat, presence, frequency, seed
         return [((n,), np.float32), ((n,), np.int32), ((n,), np.float32),
@@ -312,16 +309,6 @@ def payload_spec(op, a, b, S, MP, W):
                 ((n,), np.int32)]
 
     key = [(KEY_SHAPE, np.uint32)]
-    if op == OP_PREFILL:
-        bucket, B = a, b
-        return [((B, bucket), np.int32), ((B,), np.int32), ((B,), np.int32),
-                ((B, MP), np.int32)] + samp(B) + key
-    if op == OP_CHUNK:
-        # tokens, start, chunk_len, slot, is_final, is_first, seed_row, pt
-        return [((1, a), np.int32), ((1,), np.int32), ((1,), np.int32),
-                ((1,), np.int32), ((1,), np.int32), ((1,), np.int32),
-                ((1, W), np.int32),
-                ((1, MP), np.int32)] + samp(1) + key
     if op == OP_DECODE:
         return [((S,), np.int32), ((S,), np.int32), ((S,), np.int32),
                 ((S, MP), np.int32)] + samp(S) + key
@@ -528,9 +515,9 @@ def _raise_on_worker_failure(flags: Optional[np.ndarray], name: str) -> None:
         )
 
 
-_OP_SITE = {OP_PREFILL: "prefill", OP_CHUNK: "chunk", OP_DECODE: "decode",
-            OP_PREFILL_SP: "sp_prefill", OP_RAGGED: "ragged",
-            OP_SPEC: "spec_verify", OP_EMBED: "embed", OP_ENCODE: "encode"}
+_OP_SITE = {OP_DECODE: "decode", OP_PREFILL_SP: "sp_prefill",
+            OP_RAGGED: "ragged", OP_SPEC: "spec_verify", OP_EMBED: "embed",
+            OP_ENCODE: "encode"}
 
 
 def _mirrored_dispatch(rt, op, a, b, values, dispatch):
@@ -624,35 +611,6 @@ class SPMDModelRuntime(ModelRuntime):
         if self._spmd:
             return 0
         return super().import_prefix(blob)
-
-    def _dispatch_prefill(self, bucket, B, tokens, lens, slot_ids, pt_rows,
-                          temp, tk, tp, pen, pres, freq, seeds, key):
-        if not self._spmd:
-            return super()._dispatch_prefill(
-                bucket, B, tokens, lens, slot_ids, pt_rows, temp, tk, tp,
-                pen, pres, freq, seeds, key)
-        return self._mirrored(
-            OP_PREFILL, bucket, B,
-            (tokens, lens, slot_ids, pt_rows, temp, tk, tp, pen, pres,
-             freq, seeds, key),
-            lambda: super(SPMDModelRuntime, self)._dispatch_prefill(
-                bucket, B, tokens, lens, slot_ids, pt_rows, temp, tk, tp,
-                pen, pres, freq, seeds, key))
-
-    def _dispatch_chunk(self, chunk, tokens, start, cl, slot_id, is_final,
-                        is_first, seed_row, pt_row, temp, tk, tp, pen, pres,
-                        freq, seeds, key):
-        if not self._spmd:
-            return super()._dispatch_chunk(
-                chunk, tokens, start, cl, slot_id, is_final, is_first,
-                seed_row, pt_row, temp, tk, tp, pen, pres, freq, seeds, key)
-        return self._mirrored(
-            OP_CHUNK, chunk, 0,
-            (tokens, start, cl, slot_id, is_final, is_first, seed_row,
-             pt_row, temp, tk, tp, pen, pres, freq, seeds, key),
-            lambda: super(SPMDModelRuntime, self)._dispatch_chunk(
-                chunk, tokens, start, cl, slot_id, is_final, is_first,
-                seed_row, pt_row, temp, tk, tp, pen, pres, freq, seeds, key))
 
     def _dispatch_decode(self, k_steps, tokens, positions, active, pt, temp,
                          tk, tp, pen, pres, freq, seeds, key):
@@ -1026,7 +984,6 @@ def run_worker(
     # (never mid-replay, where the primary would see a desync).
     err = validate_quant_config(
         engine_cfg.weights_dtype, engine_cfg.kv_dtype,
-        pp=dict(mesh.shape).get("pipe", 1),
         sp=dict(mesh.shape).get("seq", 1),
         model_names=list(models))
     if err is not None:
@@ -1042,8 +999,8 @@ def run_worker(
     S = engine_cfg.max_slots
     MP = engine_cfg.max_pages_per_seq
     W = engine_cfg.repeat_last_n
-    DATA_OPS = (OP_PREFILL, OP_CHUNK, OP_DECODE, OP_PREFILL_SP, OP_ENCODE,
-                OP_EMBED, OP_RAGGED, OP_SPEC)
+    DATA_OPS = (OP_DECODE, OP_PREFILL_SP, OP_ENCODE, OP_EMBED, OP_RAGGED,
+                OP_SPEC)
 
     wire_seq = 0
     while max_steps is None or steps < max_steps:
@@ -1163,25 +1120,7 @@ def _replay(rt, op, a, b, payload):
     """Execute one data op against a worker replica, mirroring the
     primary's dispatch exactly (same jit, same inputs). Returns every
     device output of the replayed computation."""
-    if op == OP_PREFILL:
-        bucket, B = a, b
-        (tokens, lens, slot_ids, pt_rows, temp, tk, tp, pen, pres,
-         freq, seeds, key_data) = payload
-        key = jnp.asarray(key_data, jnp.uint32)
-        toks, rt.kc, rt.vc, rt.recent = ModelRuntime._dispatch_prefill(
-            rt, bucket, B, tokens, lens, slot_ids, pt_rows, temp,
-            tk, tp, pen, pres, freq, seeds, key)
-        return (toks, rt.kc, rt.vc, rt.recent)
-    elif op == OP_CHUNK:
-        chunk = a
-        (tokens, start, cl, slot_id, is_final, is_first, seed_row, pt_row,
-         temp, tk, tp, pen, pres, freq, seeds, key_data) = payload
-        key = jnp.asarray(key_data, jnp.uint32)
-        toks, rt.kc, rt.vc, rt.recent = ModelRuntime._dispatch_chunk(
-            rt, chunk, tokens, start, cl, slot_id, is_final, is_first,
-            seed_row, pt_row, temp, tk, tp, pen, pres, freq, seeds, key)
-        return (toks, rt.kc, rt.vc, rt.recent)
-    elif op == OP_DECODE:
+    if op == OP_DECODE:
         k_steps = a
         (tokens, positions, active, pt, temp, tk, tp, pen, pres,
          freq, seeds, key_data) = payload

@@ -218,7 +218,7 @@ class FleetRouter:
         self.shed_counts: Dict[str, int] = {}
         self.failover_count = 0
         self._rr = 0  # least-loaded tie-rotation cursor
-        self._last_probe = 0.0
+        self._last_probe = float("-inf")  # the first sweep is always due
         self._last_stuck_log = 0.0
         self._plan_down: set = set()  # members downed by a device_loss rule
         self._mirrored: Dict[str, set] = {}  # member -> mirrored alert names
@@ -1696,15 +1696,15 @@ class FleetRouter:
 
     def _complete_retier(self, mem) -> None:
         """Drain emptied under a pending retier: restart the member at
-        the target tier's width and commit the label. The "replica"
-        fault site is drawn here too — chaos can crash the member
-        mid-retier, which aborts the regroup (original tier) and rides
-        the normal eject/heal path; its streams already migrated off
-        during the drain, so nothing can drop."""
+        the target tier's width and commit the label. The "retier"
+        fault site is drawn here and nowhere else — chaos can crash the
+        member mid-retier, which aborts the regroup (original tier) and
+        rides the normal eject/heal path; its streams already migrated
+        off during the drain, so nothing can drop."""
         target = mem.retier_to
         if self.fault_plan is not None:
             try:
-                fired = self.fault_plan.draw("replica")
+                fired = self.fault_plan.draw("retier")
             except Exception:  # noqa: BLE001
                 log.exception("fault-plan draw failed")
                 fired = []

@@ -8,10 +8,13 @@ Design notes (TPU-first):
     scatters the step's rows into `pool[l]` in place and attention reads
     the whole pool by layer index, so a step moves the rows it writes and
     the pages it reads — not the pool.
-  - Two entry points: `forward_prefill` (padded bucket, causal attention,
-    writes the prompt's K/V into paged slots) and `forward_decode` (one
-    token per slot, paged attention over the slot pool). Both are shape-
-    static => jit once per (bucket, batch) and never recompile.
+  - Served: `forward_ragged` (one flattened stream of prefill spans and
+    decode tokens over the paged pool), `forward_decode` (one token per
+    slot: the fused scan's body), `forward_prefill_sp`, `forward_embed`
+    and the encoder; all shape-static => one jit per padded shape.
+    `forward_prefill` (whole prompts, dense causal attention) is the
+    plain oracle that tests, the benchmark's reference and the dry run
+    compare them with; the engine does not call it.
   - All matmuls run in the params dtype (bf16 on TPU => MXU), softmax and
     logits in f32.
   - Qwen2.5 support = `attn_bias=True` in ModelConfig; the same code path
@@ -34,7 +37,6 @@ from ollamamq_tpu.ops.attention import (
     causal_attention,
     bidirectional_attention,
     flat_slot_indices,
-    paged_chunk_attention_blockwise,
     paged_decode_attention_any,
     ragged_attention_any,
 )
@@ -165,8 +167,7 @@ def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 
 def scan_layers(body, x, layers, k_cache, v_cache):
     """The ONE layer loop of every forward that touches the KV pool
-    (prefill, chunk, ragged and decode here, and the pipeline stage of
-    parallel/pipeline.py).
+    (prefill, ragged and decode).
 
     The pool is the loop's CARRY: `body(x, lp, l, k_cache, v_cache) ->
     (x, k_cache, v_cache, per_layer)` gets the layer index `l`, writes
@@ -236,7 +237,8 @@ def forward_prefill(
     page_table: jnp.ndarray,  # [B, max_pages]; padding rows point at trash page
     page_size: int,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Process fresh prompts; returns (last_logits [B, V], k_cache', v_cache').
+    """Whole prompts in one dense causal pass (the oracle: see the module
+    docstring); returns (last_logits [B, V], k_cache', v_cache').
 
     Padding positions scatter into the allocator's reserved trash page, so
     the write is fully static-shaped — no dynamic trimming needed.
@@ -259,56 +261,6 @@ def forward_prefill(
     last = jnp.clip(seq_lens - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,D]
     logits = _logits(params, cfg, x_last)[:, 0, :]  # [B, V]
-    return logits, k_cache, v_cache
-
-
-def forward_prefill_chunk(
-    params: dict,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,  # [B, C] one chunk of the prompt, right-padded
-    start: jnp.ndarray,  # [B] global position of the chunk's first token
-    chunk_lens: jnp.ndarray,  # [B] valid tokens in this chunk
-    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (donated; loop carry)
-    v_cache: jnp.ndarray,
-    page_table: jnp.ndarray,  # [B, max_pages] — covers prefix AND chunk
-    page_size: int,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One chunk of a long prompt: writes the chunk's K/V into its pages,
-    attends over the previously-written prefix + the chunk itself
-    (paged_chunk_attention). Chaining chunks reproduces forward_prefill
-    exactly, lifting the prompt-length ceiling from the largest bucket to
-    the full paged context. Returns (last-valid-position logits, caches').
-    """
-    B, C = tokens.shape
-    x = embed_lookup(params["embed"], tokens, _adtype(params))
-    positions = start[:, None] + jnp.broadcast_to(
-        jnp.arange(C, dtype=jnp.int32), (B, C)
-    )
-    slots = flat_slot_indices(page_table, positions, page_size)  # [B, C]
-
-    def body(x, lp, l, kc, vc):
-        def attn_fn(q, k, v):
-            nonlocal kc, vc
-            kc = kv_write(kc, l, slots, k)
-            vc = kv_write(vc, l, slots, v)
-            # Block-wise online-softmax walk over real pages only — HBM
-            # reads scale with the actual prefix length, not max context.
-            return paged_chunk_attention_blockwise(
-                q, kc, vc, l, page_table, start, chunk_lens, page_size
-            )
-
-        x, _, _, load = _layer_step(
-            cfg, lp, x, positions, attn_fn,
-            valid=jnp.arange(tokens.shape[1])[None, :] < chunk_lens[:, None],
-            layer=l,
-        )
-        return x, kc, vc, load
-
-    x, k_cache, v_cache, _ = scan_layers(body, x, params["layers"], k_cache,
-                                         v_cache)
-    last = jnp.clip(chunk_lens - 1, 0, C - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    logits = _logits(params, cfg, x_last)[:, 0, :]
     return logits, k_cache, v_cache
 
 
@@ -336,8 +288,8 @@ def forward_ragged(
     spans and single decode tokens share a flattened [T] token stream —
     no per-sequence bucket padding. Each layer writes the stream's K/V
     into its pages, then every token attends causally over its own
-    sequence's paged context (generalizes forward_prefill_chunk to many
-    sequences and forward_decode to multi-token spans). `out_idx` names
+    sequence's paged context (forward_decode generalized to multi-token
+    spans: a prompt fed span by span reproduces forward_prefill). `out_idx` names
     the stream positions whose logits leave the forward: a [B] vector
     (each sequence's last token — the classic shape) returns [B, V];
     a [B, O] matrix (speculative verification reads a logit at EVERY

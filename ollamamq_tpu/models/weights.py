@@ -75,7 +75,7 @@ _HF_LAYER_MAP = {
 
 
 def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
-                mesh=None, pp: bool = False) -> dict:
+                mesh=None) -> dict:
     """Seeded random weights. With a `mesh` the tree is generated inside
     one jit whose outputs carry the serving shardings, so every device
     draws only its own shard (the values do not depend on the sharding:
@@ -95,11 +95,10 @@ def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
         return jax.jit(init)(key) if cfg.num_experts else init(key)
     from jax.sharding import NamedSharding
 
-    from ollamamq_tpu.parallel.sharding import (param_partition_specs,
-                                                pipeline_param_specs)
+    from ollamamq_tpu.parallel.sharding import param_partition_specs
 
     shapes = jax.eval_shape(init, key)
-    specs = (pipeline_param_specs if pp else param_partition_specs)(shapes)
+    specs = param_partition_specs(shapes)
     shardings = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), specs)
     return jax.jit(init, out_shardings=shardings)(key)
@@ -211,13 +210,12 @@ def load_params(
     dtype=jnp.bfloat16,
     weights_dtype: str = "bfloat16",
     mesh=None,
-    pp: bool = False,
 ) -> dict:
     """Resolve weights: checkpoint dir (safetensors/orbax) or random init.
     `weights_dtype="int8"` quantizes the loaded tree at load time
     (per-channel symmetric, fp32 scales) — the checkpoint is still read
     in `dtype` and the full-precision copy is dropped immediately.
-    `mesh`/`pp` let a random init land directly in its serving sharding
+    `mesh` lets a random init land directly in its serving sharding
     (init_random); checkpoints are placed by the caller's shard_params."""
     if checkpoint_path:
         entries = os.listdir(checkpoint_path)
@@ -226,7 +224,7 @@ def load_params(
         else:
             params = load_orbax(checkpoint_path)
     else:
-        params = init_random(cfg, seed=seed, dtype=dtype, mesh=mesh, pp=pp)
+        params = init_random(cfg, seed=seed, dtype=dtype, mesh=mesh)
     if weights_dtype == "int8":
         params = quantize_params_int8(params, cfg)
     return params

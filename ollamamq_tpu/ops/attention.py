@@ -130,78 +130,6 @@ def paged_chunk_attention(
     return out.astype(q.dtype)
 
 
-def paged_chunk_attention_blockwise(
-    q: jnp.ndarray,  # [B, C, H, hd] — a chunk of new tokens per sequence
-    k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
-    v_cache: jnp.ndarray,
-    layer,  # int32 scalar: the pool layer to attend over
-    page_table: jnp.ndarray,  # [B, max_pages]
-    start: jnp.ndarray,  # [B] global position of the chunk's first token
-    chunk_lens: jnp.ndarray,  # [B] valid tokens in this chunk (<= C)
-    page_size: int,
-    block_pages: int = 8,
-) -> jnp.ndarray:
-    """Non-materializing chunk attention: walks the context in blocks of
-    `block_pages` pages with an online (flash-style) softmax, and the loop
-    trip count is DYNAMIC — ceil(max_needed / block) for the batch — so HBM
-    reads scale with the actual context length instead of gathering the
-    full [B, max_pages*page_size] padded context like paged_chunk_attention
-    (VERDICT r1 weak #4). Numerics match paged_chunk_attention (same f32
-    online softmax, tested in test_model.py)."""
-    B, C, H, hd = q.shape
-    max_pages = page_table.shape[1]
-    Hk = k_cache.shape[-1] // hd
-    n_rep = H // Hk
-    BLK = block_pages * page_size
-    n_blocks = -(-max_pages // block_pages)  # static ceiling
-    end = start + chunk_lens  # [B] tokens visible to the chunk's last query
-    needed = jnp.max(-(-end // BLK))  # dynamic: blocks any sequence needs
-
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    qf = q.astype(jnp.float32) * scale
-    q_pos = start[:, None] + jnp.arange(C)[None, :]  # [B, C]
-
-    def body(i, carry):
-        m, l, acc = carry
-        # Gather (not dynamic_slice: its clamping would silently relabel the
-        # final partial block when max_pages % block_pages != 0). Clipped
-        # rows carry positions >= max_pages*page_size, which the in_seq
-        # mask below always rejects (end <= max_pages*page_size).
-        pidx = jnp.clip(
-            i * block_pages + jnp.arange(block_pages), 0, max_pages - 1
-        )
-        pages = page_table[:, pidx]  # [B, block_pages]
-        pos = i * BLK + jnp.arange(BLK, dtype=jnp.int32)  # global positions
-        slots = (pages[:, :, None] * page_size
-                 + jnp.arange(page_size)[None, None, :]).reshape(B, BLK)
-        k = repeat_kv(kv_gather(k_cache, layer, slots, hd).astype(
-            jnp.float32), n_rep)  # [B,BLK,H,hd]
-        v = repeat_kv(kv_gather(v_cache, layer, slots, hd).astype(
-            jnp.float32), n_rep)
-        logits = jnp.einsum("bchd,blhd->bhcl", qf, k)  # [B, H, C, BLK]
-        causal = pos[None, None, None, :] <= q_pos[:, None, :, None]
-        in_seq = pos[None, None, None, :] < end[:, None, None, None]
-        logits = jnp.where(causal & in_seq, logits, NEG_INF)
-        blk_m = jnp.max(logits, axis=-1)  # [B, H, C]
-        new_m = jnp.maximum(m, blk_m)
-        # Keep exp arguments finite when a row has seen nothing yet.
-        p = jnp.exp(logits - new_m[..., None])
-        p = jnp.where(logits <= NEG_INF / 2, 0.0, p)
-        corr = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - new_m))
-        l = l * corr + jnp.sum(p, axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum("bhcl,blhd->bhcd", p, v)
-        return new_m, l, acc
-
-    m0 = jnp.full((B, H, C), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H, C), jnp.float32)
-    a0 = jnp.zeros((B, H, C, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(
-        0, jnp.minimum(needed, n_blocks), body, (m0, l0, a0)
-    )
-    out = acc / jnp.maximum(l, 1e-30)[..., None]  # [B, H, C, hd]
-    return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
-
-
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, H, hd] one new token per sequence
     k_cache: jnp.ndarray,  # [L, S, Hk*hd] the whole slot pool
@@ -349,9 +277,8 @@ def _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, *meta):
     keep the sharding they are stored with (lanes split by kv head; an
     int8 pool's scale planes on Hk), and the layer index, the page table
     and the length metadata replicate — every shard runs the kernel on
-    its own heads. Callers already inside a shard_map
-    (parallel/pipeline.py) pass no mesh and get the plain per-device
-    call."""
+    its own heads. Without a mesh (one device) the call is the plain
+    kernel."""
     if mesh is None or mesh.size == 1:
         return kernel(q, k_cache, v_cache, *meta)
     heads = PS(None, AXIS_TENSOR, None)
@@ -428,10 +355,9 @@ def paged_decode_attention_any(
     interpret: bool = False,
     mesh=None,  # see ragged_attention_any
 ) -> jnp.ndarray:
-    """The ONE pallas-vs-jnp decode-attention dispatch, shared by the
-    single-mesh forward (models/llama.py) and the pipeline stage
-    (parallel/pipeline.py) so the two paths cannot drift. The pallas
-    import stays deferred: the kernel module only loads when selected."""
+    """The ONE pallas-vs-jnp decode-attention dispatch
+    (models/llama.forward_decode). The pallas import stays deferred: the
+    kernel module only loads when selected."""
     if attn_impl == "pallas":
         from ollamamq_tpu.ops.pallas.paged_attention import (
             paged_decode_attention_pallas,

@@ -1,4 +1,4 @@
-"""Multi-host control plane: jax.distributed + cross-host mesh building.
+"""Multi-host control plane: jax.distributed bring-up.
 
 The reference's distribution story is N independent HTTP backends glued by
 a proxy; here a deployment is one SPMD program across hosts: every host
@@ -16,8 +16,6 @@ import os
 from typing import Optional
 
 import jax
-
-from ollamamq_tpu.parallel.mesh import make_mesh
 
 log = logging.getLogger("ollamamq.distributed")
 
@@ -70,16 +68,6 @@ def initialize(
         jax.local_device_count(), jax.device_count(),
     )
     return True
-
-
-def global_mesh(dp: int = 1, sp: int = 1, tp: int = -1, pp: int = 1,
-                ep: int = 1):
-    """Mesh over ALL processes' devices. Axis order puts "tensor" innermost
-    so TP collectives ride ICI within a host/slice and only the outer axes
-    ("data", "pipe", "seq") cross DCN — the layout the scaling playbook
-    prescribes."""
-    return make_mesh(dp=dp, sp=sp, tp=tp, pp=pp, ep=ep,
-                     devices=jax.devices())
 
 
 def is_primary() -> bool:

@@ -25,7 +25,6 @@ from jax.sharding import Mesh
 AXIS_DATA = "data"
 AXIS_TENSOR = "tensor"
 AXIS_SEQ = "seq"
-AXIS_PIPE = "pipe"
 AXIS_EXPERT = "expert"
 
 
@@ -33,27 +32,25 @@ def make_mesh(
     dp: int = 1,
     tp: int = -1,
     sp: int = 1,
-    pp: int = 1,
     ep: int = 1,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Build a (data, pipe, seq, expert, tensor) mesh.
+    """Build a (data, seq, expert, tensor) mesh.
 
-    `tp=-1` means "all devices not consumed by dp*pp*sp*ep". The tensor
+    `tp=-1` means "all devices not consumed by dp*sp*ep". The tensor
     axis is innermost so TP collectives ride the fastest ICI links
-    (adjacent chips); the pipe axis sits next to data (stage handoffs are
-    one ppermute per microbatch step — the lowest-bandwidth traffic).
+    (adjacent chips).
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
     if tp == -1:
-        if n % (dp * pp * sp * ep) != 0:
+        if n % (dp * sp * ep) != 0:
             raise ValueError(
-                f"{n} devices not divisible by dp*pp*sp*ep={dp * pp * sp * ep}")
-        tp = n // (dp * pp * sp * ep)
-    k = dp * pp * sp * ep * tp
+                f"{n} devices not divisible by dp*sp*ep={dp * sp * ep}")
+        tp = n // (dp * sp * ep)
+    k = dp * sp * ep * tp
     if k > n:
-        raise ValueError(f"dp*pp*sp*ep*tp={k} > {n} available devices")
+        raise ValueError(f"dp*sp*ep*tp={k} > {n} available devices")
     nproc = jax.process_count()
     if dp > 1 and nproc > 1:
         # Multi-host dp replica serving slices the mesh along the data axis
@@ -78,10 +75,10 @@ def make_mesh(
         arr = (np.asarray(_pick_per_process(devices, k, nproc, per_proc))
                .reshape(nproc, dp, per_proc // dp)
                .transpose(1, 0, 2)
-               .reshape(dp, pp, sp, ep, tp))
+               .reshape(dp, sp, ep, tp))
     else:
-        arr = np.asarray(devices[:k]).reshape(dp, pp, sp, ep, tp)
-    return Mesh(arr, (AXIS_DATA, AXIS_PIPE, AXIS_SEQ, AXIS_EXPERT, AXIS_TENSOR))
+        arr = np.asarray(devices[:k]).reshape(dp, sp, ep, tp)
+    return Mesh(arr, (AXIS_DATA, AXIS_SEQ, AXIS_EXPERT, AXIS_TENSOR))
 
 
 def _pick_per_process(devices, k: int, nproc: int, per_proc: int):
@@ -108,18 +105,10 @@ def _pick_per_process(devices, k: int, nproc: int, per_proc: int):
 
 
 def replica_submesh(mesh: Mesh, r: int) -> Mesh:
-    """Replica r's slice of the data axis (a [1, sp, tp] submesh) — THE
+    """Replica r's slice of the data axis (a [1, sp, ep, tp] submesh) — THE
     derivation, shared by the engine's replica construction and the SPMD
     worker's reload path, which must agree on every host."""
     return Mesh(mesh.devices[r:r + 1], mesh.axis_names)
-
-
-def single_device_mesh() -> Mesh:
-    return make_mesh(dp=1, sp=1, tp=1, devices=jax.devices()[:1])
-
-
-def mesh_axis_size(mesh: Mesh, axis: str) -> int:
-    return mesh.shape[axis]
 
 
 def validate_tp_for_model(tp: int, num_kv_heads: int, num_heads: int) -> None:

@@ -25,7 +25,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from ollamamq_tpu.ops.quant import QuantTensor
-from ollamamq_tpu.parallel.mesh import AXIS_EXPERT, AXIS_PIPE, AXIS_TENSOR
+from ollamamq_tpu.parallel.mesh import AXIS_EXPERT, AXIS_TENSOR
 
 
 def param_partition_specs(params: Dict[str, Any]) -> Dict[str, Any]:
@@ -72,40 +72,16 @@ def param_partition_specs(params: Dict[str, Any]) -> Dict[str, Any]:
     return _named_map(spec_for, params)
 
 
-def pipeline_param_specs(params: Dict[str, Any]) -> Dict[str, Any]:
-    """Partition specs for PP(xTP): the usual TP specs, plus every leaf of
-    the stacked `layers` subtree sharded over "pipe" on its leading
-    num_layers dim (parallel/pipeline.py stages scan their local slice)."""
-    specs = param_partition_specs(params)
-
-    def add_pipe(leaf, spec):
-        dims = list(spec) + [None] * (leaf.ndim - len(spec))
-        dims[0] = AXIS_PIPE
-        return PS(*dims)
-
-    specs["layers"] = jax.tree_util.tree_map(
-        add_pipe, params["layers"], specs["layers"]
-    )
-    return specs
-
-
-def kv_cache_spec(pp: bool = False) -> PS:
+def kv_cache_spec() -> PS:
     """KV slot pool [L, slots, kv_heads*head_dim] and a quantized pool's
     scale rows [L, slots, kv_heads]: the last axis splits by kv head over
-    the tensor axis (each shard owns its own heads' lanes and scales);
-    under pipeline parallelism layers also split over the pipe axis."""
-    return PS(AXIS_PIPE if pp else None, None, AXIS_TENSOR)
+    the tensor axis (each shard owns its own heads' lanes and scales)."""
+    return PS(None, None, AXIS_TENSOR)
 
 
-def shard_params(params, mesh: Mesh, pp: bool = False):
-    """Place a params pytree onto the mesh per the partition rules.
-
-    `pp=True` additionally splits layer stacks over the pipe axis — the
-    CALLER decides, because only runtimes that actually run the pipelined
-    forwards (parallel/pipeline.py) want pipe-sharded weights; an encoder
-    or embed runtime sharing a --pp mesh runs plain GSPMD scans and must
-    keep layers pipe-replicated."""
-    specs = pipeline_param_specs(params) if pp else param_partition_specs(params)
+def shard_params(params, mesh: Mesh):
+    """Place a params pytree onto the mesh per the partition rules."""
+    specs = param_partition_specs(params)
     return jax.tree_util.tree_map(
         lambda p, s: jax.device_put(p, NamedSharding(mesh, s)), params, specs
     )

@@ -139,11 +139,12 @@ EVENT_FIELDS: Dict[str, Tuple[tuple, tuple]] = {
     "place": (("runtime",), ("overhead_ms",)),
     "shed": (("reason",),
              ("queued", "limit", "retry_after_s", "n_prompt", "max_tokens")),
-    # `mode` tells the two batch shapes apart: "bucketed" records carry
-    # the bucket they padded to; "ragged" records carry the granule-
-    # padded stream total plus its prefill/decode row split. Both carry
-    # real vs padded token counts, which batch_stats() below turns into
-    # the padding-waste scoreboard.
+    # `mode` tells the batch shapes apart: "ragged" records (all the
+    # engine writes for generate traffic) carry the granule-padded
+    # stream total plus its prefill/decode row split; a journal spilled
+    # by an older build may hold "bucketed" records with the `bucket`
+    # they padded to. Both carry real vs padded token counts, which
+    # batch_stats() below turns into the padding-waste scoreboard.
     "batch": (("slots", "batch_size", "tokens", "occupancy"),
               ("reqs", "pending", "free_pages", "bucket", "mode",
                "padded_tokens", "n_prefill", "n_decode", "n_spec",

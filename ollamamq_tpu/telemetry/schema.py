@@ -42,9 +42,8 @@ BATCH_OCCUPANCY = REGISTRY.gauge(
 BATCH_PADDING_WASTE = REGISTRY.gauge(
     "ollamamq_batch_padding_waste",
     "Fraction of the last dispatched batch's token positions that were "
-    "padding (0..1): bucket rows minus real tokens on the bucketed path, "
-    "the granule tail on the ragged path — the compute burned for shape "
-    "stability", labels=("model",))
+    "padding (0..1): the ragged stream's tail up to its ladder rung — the "
+    "compute burned for shape stability", labels=("model",))
 # -- mixture-of-experts routing (models/moe.py; MoE models only) -----------
 # Counted on the device inside the step program and read back with the
 # sampled ids. No "dropped" series: the dispatch has no capacity to pass.
@@ -386,8 +385,7 @@ RECOVERED_STREAMS_TOTAL = REGISTRY.counter(
 # -- engine performance plane (telemetry/stepprof.py) ----------------------
 # Closed site vocabulary for ollamamq_compile_total{site}: one per jit
 # cache the engine fills (the compile ladder's rungs live in these).
-COMPILE_SITES = ("ragged", "prefill", "chunk", "sp_prefill", "decode",
-                 "embed")
+COMPILE_SITES = ("ragged", "sp_prefill", "decode", "embed")
 STEP_PHASE_MS = REGISTRY.histogram(
     "ollamamq_step_phase_ms",
     "Engine dispatch self-profiling: milliseconds each step spent per "

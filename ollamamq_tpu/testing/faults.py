@@ -14,7 +14,7 @@ Plan file schema (JSON, validated loudly at startup — a malformed
     {
       "seed": 0,                      # optional; seeds probabilistic rules
       "faults": [
-        {"site": "prefill", "kind": "exception", "at": [1, 2]},
+        {"site": "ragged",  "kind": "exception", "at": [1, 2]},
         {"site": "extend",  "kind": "alloc_fail", "every": 5, "times": 2},
         {"site": "decode",  "kind": "slow", "p": 0.1, "delay_s": 0.25},
         {"site": "decode",  "kind": "device_loss", "at": [10],
@@ -24,8 +24,8 @@ Plan file schema (JSON, validated loudly at startup — a malformed
 
 Each rule names ONE site and ONE trigger:
 
-  site     where the fault fires — a dispatch seam ("prefill", "chunk",
-           "sp_prefill", "ragged" for the mixed-batch dispatch,
+  site     where the fault fires — a dispatch seam ("sp_prefill",
+           "ragged" for the mixed-batch dispatch,
            "spec_verify" for a mixed dispatch carrying speculative
            verify spans, "decode", "collect" where a launched step's
            ids are read back — the next step may already be launched
@@ -36,6 +36,12 @@ Each rule names ONE site and ONE trigger:
            call counter indexes (sweep, member) — "exception" crashes
            the probed member, "slow" forces its heartbeat stale for
            delay_s, "device_loss" keeps it down until heal_after_s), or
+           the router's regroup seam ("retier", drawn once per tier
+           move, after the member's drain emptied and right before its
+           restart at the target tier's width — by nothing else, so
+           "at": [1] is the first regroup whatever the health sweeps
+           drew: "exception" / "device_loss" crash the member there,
+           which aborts the regroup), or
            the router's KV-migration seam ("migrate", drawn once per
            attempted stream migration AFTER the source export:
            "exception" fails the transfer mid-flight (fallback to
@@ -102,9 +108,9 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-SITES = ("prefill", "chunk", "sp_prefill", "ragged", "spec_verify",
+SITES = ("sp_prefill", "ragged", "spec_verify",
          "decode", "collect", "embed", "encode", "step", "alloc", "extend",
-         "replica",
+         "replica", "retier",
          "migrate", "wal", "preempt", "router", "compile")
 KINDS = ("exception", "slow", "alloc_fail", "device_loss")
 

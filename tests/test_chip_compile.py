@@ -278,8 +278,8 @@ def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
                             key, fn))
     S, W = B, 64
     rt = object.__new__(ModelRuntime)
-    rt.cfg, rt.attn_impl, rt.mesh, rt._pp = LOOP_CFG, "pallas", None, 1
-    rt.ecfg = SimpleNamespace(page_size=PS, pp_microbatches=None)
+    rt.cfg, rt.attn_impl, rt.mesh = LOOP_CFG, "pallas", None
+    rt.ecfg = SimpleNamespace(page_size=PS)
     rt._prefill_jits, rt._decode_jits = {}, {}
     shapes = jax.eval_shape(
         lambda: llama.init_params(LOOP_CFG, jax.random.PRNGKey(0)))

@@ -83,7 +83,7 @@ def test_fault_plan_schema_rejects_malformed(tmp_path):
     # And a valid file loads.
     good = tmp_path / "plan.json"
     good.write_text(json.dumps({"seed": 3, "faults": [
-        {"site": "prefill", "kind": "exception", "at": [1]}]}))
+        {"site": "ragged", "kind": "exception", "at": [1]}]}))
     assert FaultPlan.load(str(good)).stats()["injected"] == 0
 
 
@@ -101,7 +101,7 @@ def test_fault_plan_device_loss_heals():
     with pytest.raises(DeviceLostError):
         plan.check("decode")
     with pytest.raises(DeviceLostError):
-        plan.check("prefill")  # a lost device fails EVERY site
+        plan.check("ragged")  # a lost device fails EVERY site
     assert plan.blocked("extend")  # ...and can't grow allocations
     time.sleep(0.06)
     plan.check("decode")  # healed

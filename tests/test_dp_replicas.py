@@ -74,6 +74,46 @@ def test_two_requests_land_on_different_replicas(dp_engine):
     assert reqs[0].generated_ids == reqs[1].generated_ids
 
 
+@pytest.mark.parametrize("model", ["test-tiny-gqa", "test-tiny-qwen3"])
+def test_dp2_x_tp2_streams_equal_the_single_mesh_stream(model):
+    """Two replicas, each tensor-parallel over its own two devices, serve
+    two different prompts at once (the longer one in several spans): each
+    greedy stream is the one a plain single-device engine gives — neither
+    the data axis nor the tensor axis shows in the tokens (q/k norm
+    included, for the qwen3 preset)."""
+    import jax.numpy as jnp
+
+    from testutil import single_device_greedy_tokens
+
+    kw = dict(max_slots=2, num_pages=32, page_size=8, max_pages_per_seq=8,
+              max_batch_tokens=16, token_granule=8, decode_steps_per_iter=2)
+    prompts = ["dp by tp, the long one: " + "spans and spans " * 2,
+               "dp by tp"]
+    eng = TPUEngine(EngineConfig(model=model, dp=2, tp=2, **kw),
+                    models={model: None}, blocklist_path=None,
+                    dtype=jnp.float32)
+    eng.start()
+    try:
+        rs = eng.runtimes[model]
+        assert isinstance(rs, ReplicaSet) and len(rs.replicas) == 2
+        assert all(rt.mesh.shape["tensor"] == 2 for rt in rs.replicas)
+        reqs = []
+        for i, text in enumerate(prompts):
+            rid = eng.core.enqueue(f"dt{i}", "", model)
+            reqs.append(Request(rid, f"dt{i}", model, rs.tokenizer.encode(text),
+                                SamplingParams(max_tokens=6)))
+        assert len(reqs[0].prompt_tokens) > 2 * kw["max_batch_tokens"]
+        for r in reqs:
+            eng.submit(r)
+        assert all(collect(r)[-1].kind == "done" for r in reqs)
+        assert all(rt.tokens_generated > 0 for rt in rs.replicas)
+    finally:
+        eng.stop()
+    for r, text in zip(reqs, prompts):
+        assert r.generated_ids == single_device_greedy_tokens(
+            model, text, **kw), text
+
+
 def test_least_loaded_placement_and_rotation():
     """Placement picks the least-loaded replica; ties rotate (reference
     least-conn + rotate-after-last, dispatcher.rs:475-487)."""
@@ -190,14 +230,14 @@ def test_dp_decode_dispatches_overlap_before_any_collect():
     ModelRuntime.step_decode_dispatch = rec_dispatch
     ModelRuntime.step_collect = rec_collect
     try:
-        # One request per replica, installed via direct prefill (no loop
-        # thread — we drive ticks by hand for deterministic ordering).
+        # One request per replica, installed by a ragged step driven by
+        # hand (no loop thread — deterministic ordering).
         for i, rep in enumerate(rs.replicas):
             req = Request(9000 + i, f"ovl{i}", "test-tiny-gqa",
                           tok.encode("overlap probe"),
                           SamplingParams(max_tokens=64))
             assert rep.submit(req)
-            assert rep.step_prefill(eng.core)
+            assert rep.step_ragged(eng.core)
         events.clear()
         eng._loop_once()
         assert [e[0] for e in events] == ["dispatch", "dispatch"], events

@@ -330,9 +330,8 @@ def test_invariant_fuzz_randomized_overload(seed):
             reqs.append(req)
             rt.pending_prefill.append(req)
             issued += 1
-        rt.step_prefill(core)
-        rt.step_chunk(core)
-        if any(r is not None for r in rt.slot_req):
+        if not rt.step_ragged(core) \
+                and any(r is not None for r in rt.slot_req):
             rt.step_decode(core, k_steps=2)
         if issued >= 14 and all(r.stats.finished_at for r in reqs):
             break

@@ -210,99 +210,69 @@ def test_forward_embed_generative():
         np.asarray(emb[0]), np.asarray(emb2[0]), rtol=1e-4, atol=1e-5)
 
 
-def test_chunked_prefill_equivalence(tiny_cfg, tiny_params):
-    """Chaining forward_prefill_chunk chunks == one-shot forward_prefill."""
-    cfg, params = tiny_cfg, tiny_params
-    T, C = 24, 8  # 3 chunks
-    toks = jax.random.randint(jax.random.PRNGKey(7), (1, T), 0, cfg.vocab_size,
-                              dtype=jnp.int32)
+# Span lengths a prompt is fed in. The engine cuts a prompt wherever the
+# tick's token budget runs out, so an edge may fall anywhere in a page.
+CHUNKINGS = {
+    "one-span": (21,),
+    "two-equal": (12, 12),
+    "ragged-last": (8, 8, 3),
+    "edge-inside-page": (5, 6, 10),
+    "one-token-chunk": (9, 1, 7),
+}
+
+
+@pytest.mark.parametrize("preset",
+                         ["test-tiny", "test-tiny-gqa", "test-tiny-qwen3"])
+@pytest.mark.parametrize("spans", CHUNKINGS.values(), ids=CHUNKINGS.keys())
+def test_ragged_span_chaining_matches_one_shot_prefill(preset, spans):
+    """A prompt fed to forward_ragged span by span through the paged pool
+    == the one-shot dense forward_prefill: same logits at the last
+    position, same K and V rows in the pool. Each call is laid out as the
+    engine lays a step out: the stream padded to the token granule
+    (padding writes into the trash page), a padding row beside the span's."""
+    cfg = MODEL_CONFIGS[preset]
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    T, G = sum(spans), 8
+    toks = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(7), (T,), 1, cfg.vocab_size, dtype=jnp.int32))
     a = kvc.PageAllocator(32, PAGE_SIZE, MAX_PAGES)
-    pages = a.alloc(T)
-    pt = _page_table(a, [pages])
+    row = kvc.make_page_table_row(a.alloc(T), MAX_PAGES)
+    pt = np.stack([row, np.full_like(row, kvc.TRASH_PAGE)])
 
     kc, vc = _fresh_cache(cfg)
     ref_logits, ref_kc, ref_vc = llama.forward_prefill(
-        params, cfg, toks, jnp.array([T]), kc, vc, pt, PAGE_SIZE
-    )
-
-    kc2, vc2 = _fresh_cache(cfg)
-    for start in range(0, T, C):
-        chunk = toks[:, start:start + C]
-        logits, kc2, vc2 = llama.forward_prefill_chunk(
-            params, cfg, chunk, jnp.array([start]), jnp.array([C]),
-            kc2, vc2, pt, PAGE_SIZE,
-        )
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(ref_logits), rtol=2e-4, atol=2e-4
-    )
-    np.testing.assert_allclose(
-        np.asarray(kc2), np.asarray(ref_kc), rtol=1e-5, atol=1e-5
-    )
-
-
-def test_chunked_prefill_ragged_last_chunk(tiny_cfg, tiny_params):
-    """Last chunk shorter than the chunk bucket (padding masked)."""
-    cfg, params = tiny_cfg, tiny_params
-    T, C = 19, 8  # chunks of 8, 8, 3
-    toks = jax.random.randint(jax.random.PRNGKey(8), (1, T), 0, cfg.vocab_size,
-                              dtype=jnp.int32)
-    a = kvc.PageAllocator(32, PAGE_SIZE, MAX_PAGES)
-    pt = _page_table(a, [a.alloc(T)])
+        params, cfg, jnp.asarray(toks)[None], jnp.array([T]), kc, vc,
+        jnp.asarray(pt[:1]), PAGE_SIZE)
 
     kc, vc = _fresh_cache(cfg)
-    ref_logits, _, _ = llama.forward_prefill(
-        params, cfg, toks, jnp.array([T]), kc, vc, pt, PAGE_SIZE
-    )
-    kc2, vc2 = _fresh_cache(cfg)
-    for start in range(0, T, C):
-        piece = np.zeros((1, C), np.int32)
-        cl = min(C, T - start)
-        piece[0, :cl] = np.asarray(toks[0, start:start + cl])
-        logits, kc2, vc2 = llama.forward_prefill_chunk(
-            params, cfg, jnp.asarray(piece), jnp.array([start]), jnp.array([cl]),
-            kc2, vc2, pt, PAGE_SIZE,
-        )
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(ref_logits), rtol=2e-4, atol=2e-4
-    )
+    start = 0
+    for n in spans:
+        T_pad = -(-n // G) * G
+        stream = np.zeros(T_pad, np.int32)
+        stream[:n] = toks[start:start + n]
+        tok_pos = np.full(T_pad, -1, np.int32)
+        tok_pos[:n] = np.arange(start, start + n)
+        ws = np.zeros(T_pad, np.int32)  # padding: slot 0 of the trash page
+        ws[:n] = row[tok_pos[:n] // PAGE_SIZE] * PAGE_SIZE \
+            + tok_pos[:n] % PAGE_SIZE
+        logits, kc, vc = llama.forward_ragged(
+            params, cfg, jnp.asarray(stream), jnp.zeros(T_pad, jnp.int32),
+            jnp.asarray(tok_pos), jnp.asarray(ws),
+            jnp.array([n - 1, 0], jnp.int32), kc, vc, jnp.asarray(pt),
+            jnp.array([0, 0], jnp.int32), jnp.array([n, 0], jnp.int32),
+            jnp.array([start + n, 0], jnp.int32), PAGE_SIZE, attn_impl="jnp")
+        start += n
 
-
-def test_blockwise_chunk_attention_matches_full_gather():
-    """paged_chunk_attention_blockwise (dynamic block walk, online softmax)
-    == paged_chunk_attention (full padded gather) on ragged paged batches."""
-    from ollamamq_tpu.ops.attention import (
-        paged_chunk_attention,
-        paged_chunk_attention_blockwise,
-    )
-
-    rng = np.random.default_rng(3)
-    B, C, H, Hk, hd, ps, MP = 3, 8, 4, 2, 16, 4, 12
-    S = 64 * ps
-    q = jnp.asarray(rng.normal(size=(B, C, H, hd)), jnp.float32)
-    # A 3-layer pool; the attentions read layer 1 of it by index.
-    kc = jnp.asarray(rng.normal(size=(3, S, Hk * hd)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(3, S, Hk * hd)), jnp.float32)
-    # Distinct pages per sequence; tables longer than any sequence needs.
-    pt = jnp.asarray(
-        rng.permutation(64 - 1)[: B * MP].reshape(B, MP) + 1, jnp.int32
-    )
-    # Third sequence's context reaches the LAST page (end=48 == MP*ps), so
-    # the final partial block is exercised when block_pages doesn't divide MP.
-    start = jnp.asarray([0, 9, 44], jnp.int32)
-    chunk_lens = jnp.asarray([8, 5, 4], jnp.int32)  # ragged
-    ref = paged_chunk_attention(q, kc, vc, 1, pt, start, chunk_lens, ps)
-    # block_pages=5 does NOT divide MP=12: the final partial block must not
-    # relabel or double-count pages (clamped-slice regression).
-    for bp in (2, 5):
-        blk = paged_chunk_attention_blockwise(
-            q, kc, vc, 1, pt, start, chunk_lens, ps, block_pages=bp
-        )
-        for b in range(B):
-            n = int(chunk_lens[b])
-            np.testing.assert_allclose(
-                np.asarray(blk[b, :n]), np.asarray(ref[b, :n]),
-                rtol=2e-5, atol=2e-5, err_msg=f"block_pages={bp} seq {b}",
-            )
+    np.testing.assert_allclose(np.asarray(logits[0]),
+                               np.asarray(ref_logits[0]),
+                               rtol=2e-4, atol=2e-4)
+    assert int(jnp.argmax(logits[0])) == int(jnp.argmax(ref_logits[0]))
+    live = slice(PAGE_SIZE, None)  # every page but the trash page
+    for got, ref in ((kc, ref_kc), (vc, ref_vc)):
+        np.testing.assert_allclose(np.asarray(got[:, live]),
+                                   np.asarray(ref[:, live]),
+                                   rtol=1e-5, atol=1e-5)
+    assert np.abs(np.asarray(ref_kc[:, live])).sum() > 0
 
 
 def test_apply_penalties_math():

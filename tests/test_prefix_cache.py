@@ -61,9 +61,7 @@ def run_request(rt: ModelRuntime, core: MQCore, prompt, max_tokens=6,
     for _ in range(200):
         if any(r is req for r in rt.slot_req):
             break
-        progressed = rt.step_prefill(core)
-        progressed = rt.step_chunk(core) or progressed
-        assert progressed, "request stuck in admission"
+        assert rt.step_ragged(core), "request stuck in admission"
     else:
         pytest.fail("request never installed")
     while any(r is req for r in rt.slot_req):
@@ -174,10 +172,11 @@ def test_identical_streams_cache_on_vs_off():
 
 def test_cancel_mid_prefill_with_partially_cached_pages():
     core = MQCore(None)
-    # A single 16-token bucket so the 24-token tail needs TWO chunks —
+    # A 16-token dispatch budget so the 24-token tail needs TWO spans —
     # the cancel really lands mid-prefill.
-    rt_on = make_rt(True, prefill_buckets=(16,))
-    rt_off = make_rt(False, prefill_buckets=(16,))
+    small = dict(max_batch_tokens=16, token_granule=8)
+    rt_on = make_rt(True, **small)
+    rt_off = make_rt(False, **small)
     rng = np.random.RandomState(13)
     base = rng.randint(3, 500, size=96).tolist()  # 12 full pages
     run_request(rt_on, core, base)  # populate the tree
@@ -186,19 +185,22 @@ def test_cancel_mid_prefill_with_partially_cached_pages():
     assert cached == 12
 
     # A longer prompt sharing the cached prefix: admission pins 12 pages
-    # and routes the 24-token tail through the chunked path. Cancel it
-    # after the first chunk — pages partially written, prefix pinned.
+    # and the 24-token tail rides the span path from the cached boundary.
+    # Cancel it after the first span — pages partially written, prefix
+    # pinned.
     victim = base + rng.randint(3, 500, size=24).tolist()
     req = Request(next(_IDS), "u", "test-tiny", victim,
                   SamplingParams(max_tokens=4))
     req._inc_decode = rt_on.tokenizer.make_incremental_decoder()
     rt_on.pending_prefill.append(req)
-    assert rt_on.step_prefill(core)  # hit: parked in chunking
+    assert rt_on.step_ragged(core)  # hit: pinned, first tail span runs
     assert rt_on.prefix_cache.hits >= 1
     assert req in rt_on.chunking
-    assert rt_on.step_chunk(core)  # first tail chunk runs
+    assert req._chunk_base == 96 and 96 < req._chunk_pos < len(victim)
+    assert rt_on.prefix_cache.stats()["pinned_pages"] == 12
     req.cancelled.set()
-    assert rt_on.step_chunk(core)  # reaped: pins released, tail freed
+    assert not rt_on.step_ragged(core)  # reaped, nothing left to dispatch:
+    #                                     pins released, tail freed
     assert req not in rt_on.chunking
     assert not rt_on.reserved_slots
     pool_invariant(rt_on)

@@ -367,14 +367,16 @@ def test_validate_quant_config_combinations():
     assert validate_quant_config("int8", "int8") is None
     assert "fp8" in validate_quant_config("fp8", "bfloat16")
     assert "--kv-dtype" in validate_quant_config("bfloat16", "fp8")
-    assert "pp" in validate_quant_config("bfloat16", "int8", pp=2)
     assert "sequence-parallel" in validate_quant_config(
         "bfloat16", "int8", sp=2)
     assert "MoE" in validate_quant_config(
         "int8", "bfloat16", model_names=["mixtral:8x7b"])
-    # int8 weights with pp are fine only when KV stays bf16 and the
-    # model is dense — the validator must not over-reject.
-    assert validate_quant_config("int8", "bfloat16", pp=2) is None
+    # The validator must not over-reject: int8 weights on a dense model
+    # pass with either KV dtype, on a sequence-parallel mesh with bf16 KV.
+    assert validate_quant_config("int8", "bfloat16") is None
+    assert validate_quant_config("int8", "bfloat16", sp=2) is None
+    assert validate_quant_config(
+        "int8", "int8", model_names=["test-tiny"]) is None
 
 
 def test_cli_fails_fast_on_invalid_combinations():
@@ -383,21 +385,23 @@ def test_cli_fails_fast_on_invalid_combinations():
     # MoE model with int8 weights: rejected before any engine work.
     assert main(["--no-tui", "--models", "mixtral:8x7b",
                  "--weights-dtype", "int8"]) == 2
-    # int8 KV on a pipeline mesh: the pp path reads bf16 pages.
-    assert main(["--no-tui", "--models", "test-tiny",
-                 "--kv-dtype", "int8", "--pp", "2"]) == 2
     # int8 KV on a sequence-parallel mesh.
     assert main(["--no-tui", "--models", "test-tiny",
                  "--kv-dtype", "int8", "--sp", "2"]) == 2
 
 
-def test_cli_rejects_removed_bucketed_oracle():
-    """--attention is gone with the bucketed path: argparse must reject
-    it loudly instead of silently serving ragged."""
+@pytest.mark.parametrize("argv", [
+    ["--attention", "bucketed"], ["--pp", "2"], ["--pp-microbatches", "4"],
+], ids=lambda a: a[0])
+def test_cli_rejects_removed_flags(argv):
+    """--attention went with the bucketed oracle, --pp and its
+    microbatch knob with the pipeline path: argparse must reject each
+    loudly (exit 2) instead of silently serving the one path there is."""
     from ollamamq_tpu.cli import build_parser
 
-    with pytest.raises(SystemExit):
-        build_parser().parse_args(["--attention", "bucketed"])
+    with pytest.raises(SystemExit) as exc:
+        build_parser().parse_args(argv)
+    assert exc.value.code == 2
 
 
 def test_runtime_build_fails_fast():

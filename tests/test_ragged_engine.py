@@ -14,9 +14,11 @@ directly:
   - the journal's batch records on the ragged path report padding waste
     <= 0.10 under a synthetic overload (seed baseline on the old
     bucketed path: 0.56) with occupancy above the 0.43 baseline;
-  - _bucket_for (now serving only the pp>1 pipeline prefill path)
-    REFUSES oversize pieces instead of silently answering the largest
-    bucket;
+  - an arrival storm never starves decode: every dispatch carries every
+    live decode row and at most the token budget of prefill;
+  - a prompt longer than one dispatch (and than any prefill bucket) is
+    served in spans with the stream of a one-span run, and one beyond
+    the context limit is refused with an explicit error;
   - a faulted ragged dispatch retries its implicated requests (prefill
     spans AND decode rows) and the streams still finish byte-identical.
 """
@@ -66,6 +68,15 @@ def tick(rt, core):
         rt.step_decode(core, k_steps=1)
 
 
+def _submit(rt, prompt, max_tokens, user="u0", repeat_penalty=1.0):
+    req = Request(next(_IDS), user, "test-tiny", list(prompt),
+                  SamplingParams(max_tokens=max_tokens,
+                                 repeat_penalty=repeat_penalty))
+    req._inc_decode = rt.tokenizer.make_incremental_decoder()
+    rt.pending_prefill.append(req)
+    return req
+
+
 def run_all(rt, prompts, max_tokens=6, repeat_penalty=1.0,
             cancel_mid_prefill=None, max_ticks=800):
     """Drive a batch of prompts to completion; returns each request's
@@ -73,14 +84,9 @@ def run_all(rt, prompts, max_tokens=6, repeat_penalty=1.0,
     names a request index to cancel as soon as its prefill is
     partially done (0 < _chunk_pos < n)."""
     core = MQCore(None)
-    reqs = []
-    for p in prompts:
-        req = Request(next(_IDS), f"u{len(reqs) % 3}", "test-tiny", list(p),
-                      SamplingParams(max_tokens=max_tokens,
-                                     repeat_penalty=repeat_penalty))
-        req._inc_decode = rt.tokenizer.make_incremental_decoder()
-        rt.pending_prefill.append(req)
-        reqs.append(req)
+    reqs = [_submit(rt, p, max_tokens, user=f"u{i % 3}",
+                    repeat_penalty=repeat_penalty)
+            for i, p in enumerate(prompts)]
     victim = (reqs[cancel_mid_prefill]
               if cancel_mid_prefill is not None else None)
     for _ in range(max_ticks):
@@ -172,12 +178,95 @@ def test_mid_prefill_cancel_leaves_survivors_identical():
     assert not rt.reserved_slots and not rt.chunking
 
 
-def test_bucket_for_refuses_oversize():
+def test_arrival_storm_never_starves_decode_rows():
+    """Every slot but one is decoding and a backlog of long prompts keeps
+    arriving: each tick is ONE dispatch that carries every live decode
+    row (each stream gains a token a tick) and no more prefill than the
+    token budget — the backlog waits, the streams do not."""
+    rt = make_rt()  # 4 slots, 48-token dispatches
+    core = MQCore(None)
+    rng = np.random.default_rng(5)
+    streams = [_submit(rt, rng.integers(3, 500, size=6).tolist(), 40,
+                       user=f"d{i}") for i in range(3)]
+    while not all(r.generated_ids for r in streams):
+        tick(rt, core)
+    dispatched = []
+    orig = rt._dispatch_ragged
+
+    def spy(T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots, q_start,
+            q_len, *rest):
+        slot_ids = rest[6]
+        dispatched.append({int(sl): int(n) for sl, n in zip(slot_ids, q_len)
+                           if n > 0})
+        return orig(T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots,
+                    q_start, q_len, *rest)
+
+    rt._dispatch_ragged = spy
+    # One token each: a backlog prompt leaves the free slot with the step
+    # that ends its prefill, and the next one takes it the tick after.
+    backlog = [_submit(rt, rng.integers(3, 500, size=100).tolist(), 1,
+                       user=f"b{i}") for i in range(4)]
+    budget = rt.ecfg.max_batch_tokens
+    for _ in range(12):  # 400 backlog tokens: well over 12 ticks of budget
+        live = {i: len(r.generated_ids) for i, r in enumerate(rt.slot_req)
+                if r in streams}
+        assert len(live) == 3
+        n = len(dispatched)
+        tick(rt, core)
+        assert len(dispatched) == n + 1, "one mixed dispatch a tick"
+        rows = dispatched[-1]
+        assert all(rows.get(i) == 1 for i in live), (rows, live)
+        prefill = sum(rows.values()) - len(live)
+        assert 0 < prefill <= budget - len(live), rows
+        for i, before in live.items():
+            assert len(rt.slot_req[i].generated_ids) == before + 1
+    assert any(not r.stats.finished_at for r in backlog), \
+        "the storm outlasted the window it was watched for"
+
+
+def test_prompt_longer_than_a_dispatch_is_served_in_spans():
+    """A prompt longer than max_batch_tokens AND than the largest prefill
+    bucket rides several spans (no bucket to pad it to, no truncation):
+    its stream is the one a runtime whose budget holds it whole gives."""
+    rng = np.random.default_rng(9)
+    prompt = rng.integers(3, 500, size=BUCKETS[-1] + 40).tolist()  # 104
     rt = make_rt()
-    assert rt._bucket_for(16) == 16
-    assert rt._bucket_for(17) == 64
-    with pytest.raises(ValueError):
-        rt._bucket_for(BUCKETS[-1] + 1)
+    assert len(prompt) > rt.ecfg.max_batch_tokens
+    rt.journal = Journal()
+    out = run_all(rt, [prompt])
+    spans = [r for r in rt.journal.tail(0) if r["kind"] == "chunk"]
+    assert len(spans) >= 3
+    assert sum(r["tokens"] for r in spans) == len(prompt)
+    assert [r["pos"] for r in spans] == sorted(r["pos"] for r in spans)
+    # The composer trims a span down to a rung of its ladder: only a
+    # prompt that IS the top rung goes out whole.
+    whole = make_rt(max_batch_tokens=len(prompt))
+    whole.journal = Journal()
+    assert run_all(whole, [prompt]) == out
+    assert len([r for r in whole.journal.tail(0)
+                if r["kind"] == "chunk"]) == 1
+
+
+def test_prompt_beyond_the_context_limit_is_refused():
+    """One token over what the paged context holds: an explicit error at
+    admission, no slot or page claimed, the queue behind it served."""
+    rt = make_rt()
+    core = MQCore(None)
+    limit = min(rt.ecfg.max_context, rt.cfg.max_seq_len) - 1
+    over = _submit(rt, [5] * (limit + 1), 4)
+    fits = _submit(rt, [5] * limit, 1)
+    tick(rt, core)
+    end = over.stream.drain()[-1]
+    assert end.kind == "error" and end.finish_reason.value == "error"
+    assert f"prompt length {limit + 1} exceeds maximum {limit}" in end.error
+    assert over not in rt.chunking and fits in rt.chunking
+    for _ in range(20):
+        if fits.stats.finished_at:
+            break
+        tick(rt, core)
+    assert fits.stream.drain()[-1].finish_reason.value == "length"
+    assert len(fits.generated_ids) == 1
+    assert rt.alloc.used_pages == 0 and not rt.reserved_slots
 
 
 def test_ragged_dispatch_fault_retries_and_streams_survive():

@@ -267,15 +267,10 @@ def test_other_runtimes_overlap_and_agree(model, over, monkeypatch):
         assert all("moe_assignments" in s for s in samples)
 
 
-@pytest.mark.parametrize("model,over", [
-    ("test-tiny", {"spec": True, "spec_k": 3}),
-    ("test-tiny-gqa", {"pp": 2}),
-], ids=["spec", "pp2"])
-def test_spec_and_pp_runtimes_never_overlap(model, over, monkeypatch):
-    """Their next composition needs the ids on the host (the n-gram
-    proposer) or slot state at rest (bucketed prefill): every step is
-    settled in the tick that launched it."""
-    eng = _engine(model, **over)
+def test_spec_runtime_never_overlaps(monkeypatch):
+    """Its next composition needs the ids on the host (the n-gram
+    proposer): every step is settled in the tick that launched it."""
+    eng = _engine("test-tiny", spec=True, spec_k=3)
     arr = [(i, f"u{i}", (_prompt(i, 6) * 3)[:14 + i],
             SamplingParams(max_tokens=10)) for i in range(3)]
     out, samples = drive(eng, arr, False, monkeypatch)

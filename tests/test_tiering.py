@@ -382,16 +382,17 @@ def test_retier_restarts_local_member_at_tier_width():
 
 
 def test_mid_regroup_crash_aborts_and_rejoins_original_tier():
-    """Chaos (faults.py site "replica" drawn during the regroup): the
+    """Chaos (faults.py site "retier", drawn by the regroup alone): the
     member crashes mid-retier. The fallback ladder holds — its live
     streams already migrated off during the drain (in-tier), nothing
     drops — the regroup ABORTS, and the member rejoins its ORIGINAL
     tier after healing."""
-    # 3 members => the router's first (and only, probe_period is huge)
-    # health sweep consumes replica-site draws 1..3; draw 4 is the one
-    # _complete_retier makes right before the restart.
-    plan = FaultPlan([{"site": "replica", "kind": "exception",
-                       "at": [4]}])
+    # The site's first draw is the one _complete_retier makes right
+    # before the restart, however many health sweeps ran before it (the
+    # huge probe period only keeps the crashed member from healing
+    # before its ejection is asserted).
+    plan = FaultPlan([{"site": "retier", "kind": "exception",
+                       "at": [1]}])
     router = _tiered_fake_fleet("interactive=r0;bulk=r1,r2", n=3,
                                 token_latency_s=0.05, plan=plan,
                                 router_kw=dict(probe_period_s=9999.0))

@@ -323,11 +323,11 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     table = readme[readme.index("<!-- stepprof-spans:begin -->"):
                    readme.index("<!-- stepprof-spans:end -->")]
     documented = set(re.findall(r"`([a-z_.]+)`", table))
-    jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_prefill",
-                 "mq_prefill_chunk", "mq_prefill_sp", "mq_embed", "mq_encode"}
+    jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_prefill_sp",
+                 "mq_embed", "mq_encode"}
     assert set(llama.SCOPES) | set(moe.SCOPES) | jit_names \
         | set(SPAN_NAMES) <= documented
-    # ... and the seven jit sites really are those functions.
+    # ... and the five jit sites really are those functions.
     with open(os.path.join(_REPO, "ollamamq_tpu", "engine",
                            "engine.py"), encoding="utf-8") as f:
         src = f.read()
