@@ -47,6 +47,7 @@ from ollamamq_tpu.config import (EngineConfig, ModelConfig,
 from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
+from ollamamq_tpu.engine import step_pack
 from ollamamq_tpu.engine.request import FinishReason, Request, StreamItem
 from ollamamq_tpu.engine.scheduler import make_policy
 from ollamamq_tpu.engine.tokenizer import load_tokenizer
@@ -639,11 +640,11 @@ class ModelRuntime:
         # Keys carry the trace-time sampling flags: ("ragged", T_pad, k_cap,
         # flags) | ("sp", T, flags); decode: (k_steps, flags).
         self._prefill_jits: Dict[tuple, callable] = {}
-        # name -> (content bytes, device array); see _dev().
-        self._dev_cache: Dict[str, tuple] = {}
         self._decode_jits: Dict[tuple, callable] = {}
         self._embed_jits: Dict[tuple, callable] = {}
         self._rng_counter = engine_cfg.seed
+        # [transfers, bytes] `_upload` made for the step being launched.
+        self._h2d = [0, 0]
         # Sequence-parallel prefill available when the mesh has a seq axis.
         self._sp = mesh is not None and mesh.shape.get("seq", 1) > 1
         # Set after an unrecoverable step failure; the engine stops stepping
@@ -816,9 +817,24 @@ class ModelRuntime:
         return True
 
     # -- compiled steps ----------------------------------------------------
-    def _next_key(self):
-        self._rng_counter += 1
-        return jax.random.PRNGKey(self._rng_counter)
+    def _next_rng(self) -> int:
+        """The next step's RNG counter (a field of its packed input): the
+        step program makes its key from it, `jax.random.PRNGKey(counter)`
+        traced — nothing but the step program is dispatched for a step."""
+        self._rng_counter = (self._rng_counter + 1) & 0x7FFFFFFF
+        return self._rng_counter
+
+    def _upload(self, arr: np.ndarray) -> np.ndarray:
+        """The one place a step's host inputs leave for the device,
+        counted onto the step's sample (`h2d_transfers`, `h2d_bytes`).
+        The transfer itself is the jitted call's: handed the numpy array,
+        its C++ argument path puts it on the chip (replicated over a
+        mesh) with no Python in between — measured against an explicit
+        `jnp.asarray` / `device_put` first, PERF.md §6, PR 30. `arr` is
+        never written again (the transfer may alias host memory)."""
+        self._h2d[0] += 1
+        self._h2d[1] += arr.nbytes
+        return arr
 
     def _fault(self, site: str) -> None:
         """Fault-injection seam, called at the top of every dispatch: a
@@ -848,35 +864,29 @@ class ModelRuntime:
     # Each returns (sampled_tokens, kc', vc', recent'); the caller assigns
     # the three state arrays back. The two step programs of the pipelined
     # loop (ragged, decode) also take and return the `last_ids` carry.
-    def _dispatch_ragged(self, T_pad, k_cap, tokens, tok_seq, tok_pos,
-                         write_slots, q_start, q_len, kv_len, ring_len,
-                         is_first, append, is_spec, seed_rows, slot_ids, pt,
-                         temp, tk, tp, pen, pres, freq, seeds, key):
+    def _dispatch_ragged(self, T_pad, k_cap, buf):
+        """`buf`: the step's packed host inputs (step_pack.ragged_layout)."""
         # Speculative dispatches get their own fault site: a chaos plan
         # can target the verify span without perturbing plain mixed
         # dispatches (and vice versa).
         self._fault("spec_verify" if k_cap else "ragged")
+        lay = self._ragged_layout(T_pad)
         fn = self._get_ragged_jit(
-            T_pad, k_cap, sampling_flags(temp, tk, tp, pen, pres, freq)
-        )
-        # Content-fingerprinted upload cache (_dev, the decode path's
-        # pattern): steady-state decode/spec ticks resend near-identical
-        # per-slot metadata — sampling params, page tables, seed rows,
-        # span flags — every dispatch; skipping unchanged uploads takes
-        # the host cost of a tick from ~20 device_puts to the handful
-        # that really changed. None of these are donated by the jit.
-        d = self._dev
-        return fn(self.params, d("rg_tok", tokens), d("rg_seq", tok_seq),
-                  d("rg_pos", tok_pos), d("rg_ws", write_slots),
-                  d("rg_qs", q_start), d("rg_ql", q_len),
-                  d("rg_kv", kv_len), d("rg_rl", ring_len),
-                  d("rg_first", is_first), d("rg_app", append),
-                  d("rg_spec", is_spec), d("rg_seed_rows", seed_rows),
-                  d("rg_slots", slot_ids), d("rg_pt", pt),
-                  self.kc, self.vc, self.recent, self.last_ids,
-                  d("rg_temp", temp), d("rg_tk", tk), d("rg_tp", tp),
-                  d("rg_pen", pen), d("rg_pres", pres), d("rg_freq", freq),
-                  d("rg_seeds", seeds), key)
+            T_pad, k_cap, sampling_flags(*lay.sampling(buf)))
+        return fn(self.params, self._upload(buf), self.kc, self.vc,
+                  self.recent, self.last_ids)
+
+    def _ragged_layout(self, T_pad: int) -> step_pack.StepLayout:
+        e = self.ecfg
+        return step_pack.ragged_layout(T_pad, e.max_slots,
+                                       e.max_pages_per_seq, e.repeat_last_n)
+
+    def _decode_layout(self) -> step_pack.StepLayout:
+        return step_pack.decode_layout(self.ecfg.max_slots,
+                                       self.ecfg.max_pages_per_seq)
+
+    def _sp_layout(self, T: int) -> step_pack.StepLayout:
+        return step_pack.sp_layout(T, self.ecfg.max_pages_per_seq)
 
     def _get_ragged_jit(self, T_pad: int, k_cap: int = 0,
                         flags=(True, True, True)):
@@ -911,11 +921,14 @@ class ModelRuntime:
             need_pen, need_mask, need_sample = flags
             O = k_cap + 1
 
-            def mq_ragged_step(params, tokens, tok_seq, tok_pos, write_slots,
-                               q_start, q_len, kv_len, ring_len, is_first,
-                               append, is_spec, seed_rows, slot_ids, pt, kc,
-                               vc, recent, last_ids, temp, tk, tp, pen, pres,
-                               freq, seeds, key):
+            lay = self._ragged_layout(T_pad)
+
+            def mq_ragged_step(params, buf, kc, vc, recent, last_ids):
+                (tokens, tok_seq, tok_pos, write_slots, q_start, q_len,
+                 kv_len, ring_len, is_first, append, is_spec, seed_rows,
+                 slot_ids, pt, temp, tk, tp, pen, pres, freq, seeds,
+                 rng) = lay.unpack(buf)
+                key = jax.random.PRNGKey(rng[0])
                 tokens = jnp.where(
                     tokens < 0,
                     last_ids[jnp.clip(-1 - tokens, 0, last_ids.shape[0] - 1)],
@@ -1013,7 +1026,7 @@ class ModelRuntime:
 
             _sp_note_compile(self, "ragged", key_, self._prefill_jits,
                              jax.jit(mq_ragged_step,
-                                     donate_argnums=(15, 16, 17, 18)))
+                                     donate_argnums=(2, 3, 4, 5)))
         return self._prefill_jits[key_]
 
     def _note_moe_load(self, _sp, stats: np.ndarray) -> None:
@@ -1032,54 +1045,22 @@ class ModelRuntime:
         self._tm_moe_max.set(top)
         self._tm_moe_mean.set(mean)
 
-    def _dev(self, name: str, arr) -> jnp.ndarray:
-        """Content-fingerprinted device cache for small per-slot arrays.
-
-        The decode hot loop re-dispatches the same sampling params, page
-        table, and active mask for many consecutive chunks; re-uploading
-        9 host arrays per dispatch costs milliseconds of host work (and a
-        transfer each) for bytes that rarely change. A tobytes() compare
-        (~us for [slots]-sized arrays) skips the upload when content is
-        identical — self-correcting, no dirty-flag bookkeeping to miss a
-        mutation site. None of these buffers are donated by the jits, so
-        reuse across calls is safe."""
-        a = np.asarray(arr)
-        b = a.tobytes()
-        hit = self._dev_cache.get(name)
-        if hit is not None and hit[0] == b:
-            return hit[1]
-        # Upload the SNAPSHOT, never `arr` itself: jnp.asarray may alias
-        # a host array's memory, and the live per-slot arrays change
-        # while the step they were uploaded for is still running.
-        dev = jnp.asarray(np.frombuffer(b, a.dtype).reshape(a.shape))
-        self._dev_cache[name] = (b, dev)
-        return dev
-
-    def _dispatch_decode(self, k_steps, tokens, positions, active, pt, temp,
-                         tk, tp, pen, pres, freq, seeds, key):
+    def _dispatch_decode(self, k_steps, buf):
+        """`buf`: the scan's packed host inputs (step_pack.decode_layout)."""
         self._fault("decode")
         fn = self._get_decode_jit(
-            k_steps, sampling_flags(temp, tk, tp, pen, pres, freq)
-        )
-        return fn(self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                  self.kc, self.vc, self.recent, self.last_ids,
-                  self._dev("active", active),
-                  self._dev("pt", pt), self._dev("temp", temp),
-                  self._dev("tk", tk), self._dev("tp", tp),
-                  self._dev("pen", pen), self._dev("pres", pres),
-                  self._dev("freq", freq), self._dev("seeds", seeds), key)
+            k_steps, sampling_flags(*self._decode_layout().sampling(buf)))
+        return fn(self.params, self._upload(buf), self.kc, self.vc,
+                  self.recent, self.last_ids)
 
-    def _dispatch_prefill_sp(self, T, tokens, lens, slot_ids, pt_rows,
-                             temp, tk, tp, pen, pres, freq, seeds, key):
+    def _dispatch_prefill_sp(self, T, buf):
+        """`buf`: the prompt's packed host inputs (step_pack.sp_layout)."""
         self._fault("sp_prefill")
+        lay = self._sp_layout(T)
         fn = self._get_sp_prefill_jit(
-            T, sampling_flags(temp, tk, tp, pen, pres, freq)
-        )
-        return fn(self.params, jnp.asarray(tokens), jnp.asarray(lens),
-                  self.kc, self.vc, self.recent, jnp.asarray(slot_ids),
-                  jnp.asarray(pt_rows), jnp.asarray(temp), jnp.asarray(tk),
-                  jnp.asarray(tp), jnp.asarray(pen), jnp.asarray(pres),
-                  jnp.asarray(freq), jnp.asarray(seeds), key)
+            T, sampling_flags(*lay.sampling(buf)))
+        return fn(self.params, self._upload(buf), self.kc, self.vc,
+                  self.recent)
 
     def _get_sp_prefill_jit(self, T: int, flags=(True, True, True)):
         """Sequence-parallel long-prompt prefill: the whole prompt in one
@@ -1093,9 +1074,12 @@ class ModelRuntime:
             cfg, ps, mesh = self.cfg, self.ecfg.page_size, self.mesh
             need_pen, need_mask, need_sample = flags
 
-            def mq_prefill_sp(params, tokens, seq_lens, kc, vc, recent,
-                              slot_ids, pt, temp, tk, tp, pen, pres, freq,
-                              seeds, key):
+            lay = self._sp_layout(T)
+
+            def mq_prefill_sp(params, buf, kc, vc, recent):
+                (tokens, seq_lens, slot_ids, pt, temp, tk, tp, pen, pres,
+                 freq, seeds, rng) = lay.unpack(buf)
+                key = jax.random.PRNGKey(rng[0])
                 logits, k_stack, v_stack = llama.forward_prefill_sp(
                     params, cfg, tokens, seq_lens, mesh
                 )
@@ -1129,7 +1113,7 @@ class ModelRuntime:
                 return tok, kc, vc, recent
 
             _sp_note_compile(self, "sp_prefill", key_, self._prefill_jits,
-                             jax.jit(mq_prefill_sp, donate_argnums=(3, 4, 5)))
+                             jax.jit(mq_prefill_sp, donate_argnums=(2, 3, 4)))
         return self._prefill_jits[key_]
 
     def _prefill_sp(self, req: Request, slot: int, n: int, core: MQCore) -> None:
@@ -1143,24 +1127,22 @@ class ModelRuntime:
         self.page_table[slot, :] = kvc.make_page_table_row(
             self.slot_pages[slot], self.ecfg.max_pages_per_seq
         )
-        tokens = np.zeros((1, T), np.int32)
+        lay = self._sp_layout(T)
+        buf = lay.new()
+        (tokens, lens, slot_ids, pt, temp, top_k, top_p, pen, pres, freq,
+         seeds, rng) = lay.views(buf)
         tokens[0, :n] = req.prompt_tokens
+        lens[0], slot_ids[0], pt[0] = n, slot, self.page_table[slot]
+        temp[0], top_k[0], top_p[0] = s.temperature, s.top_k, s.top_p
+        pen[0], pres[0], freq[0] = (s.repeat_penalty, s.presence_penalty,
+                                    s.frequency_penalty)
+        seeds[0], rng[0] = s.seed, self._next_rng()
         self.inflight_prefill = [req]  # cancel() must still find it
         req.trace_event("prefill", mode="sp", tokens=n)
         t0 = time.monotonic()
         try:
             tok, self.kc, self.vc, self.recent = self._dispatch_prefill_sp(
-                T, tokens, np.asarray([n], np.int32),
-                np.asarray([slot], np.int32), self.page_table[slot:slot + 1],
-                np.asarray([s.temperature], np.float32),
-                np.asarray([s.top_k], np.int32),
-                np.asarray([s.top_p], np.float32),
-                np.asarray([s.repeat_penalty], np.float32),
-                np.asarray([s.presence_penalty], np.float32),
-                np.asarray([s.frequency_penalty], np.float32),
-                np.asarray([s.seed], np.int32),
-                self._next_key(),
-            )
+                T, buf)
         except Exception as e:
             # Contain the failure to THIS request (the batched path does the
             # same): release the never-installed slot's pages — _fail_runtime
@@ -1193,9 +1175,12 @@ class ModelRuntime:
             need_pen, need_mask, need_sample = flags
             mesh = self.mesh
 
-            def mq_decode_scan(params, tokens, positions, kc, vc, recent,
-                               last_ids, active, pt, temp, tk, tp, pen, pres,
-                               freq, seeds, key):
+            lay = self._decode_layout()
+
+            def mq_decode_scan(params, buf, kc, vc, recent, last_ids):
+                (tokens, positions, active, pt, temp, tk, tp, pen, pres,
+                 freq, seeds, rng) = lay.unpack(buf)
+                key = jax.random.PRNGKey(rng[0])
                 S = tokens.shape[0]
                 # A token < 0 is -1 - r: the id row r of the (still
                 # unsettled) step before this one left in the carry.
@@ -1246,7 +1231,7 @@ class ModelRuntime:
 
             _sp_note_compile(self, "decode", key_, self._decode_jits,
                              jax.jit(mq_decode_scan,
-                                     donate_argnums=(3, 4, 5, 6)))
+                                     donate_argnums=(2, 3, 4, 5)))
         return self._decode_jits[key_]
 
     # -- slot lifecycle ----------------------------------------------------
@@ -2333,35 +2318,22 @@ class ModelRuntime:
             rows = cut
 
         S = self.ecfg.max_slots
-        MP = self.ecfg.max_pages_per_seq
         W = self.ecfg.repeat_last_n
         ps = self.ecfg.page_size
         T_real = sum(span for *_, span in rows)
         T_pad = L
 
-        tokens = np.zeros(T_pad, np.int32)
-        # Padding tokens belong to padding row len(rows) (trash pages,
-        # position -1 => masked everywhere) and write into the trash page.
-        tok_seq = np.full(T_pad, min(len(rows), S - 1), np.int32)
-        tok_pos = np.full(T_pad, -1, np.int32)
-        write_slots = np.zeros(T_pad, np.int32)  # trash page slot 0
-        q_start = np.full(S, T_pad, np.int32)
-        q_len = np.zeros(S, np.int32)
-        kv_len = np.zeros(S, np.int32)
-        ring_len = np.zeros(S, np.int32)
-        is_first = np.zeros(S, np.int32)
-        append = np.zeros(S, np.int32)
-        is_spec = np.zeros(S, np.int32)
-        seed_rows = np.full((S, W), -1, np.int32)
-        slot_ids = np.full(S, S, np.int32)  # padding -> trash ring row
-        pt_rows = np.full((S, MP), kvc.TRASH_PAGE, np.int32)
-        temp = np.zeros(S, np.float32)
-        top_k = np.zeros(S, np.int32)
-        top_p = np.ones(S, np.float32)
-        pen = np.ones(S, np.float32)
-        pres = np.zeros(S, np.float32)
-        freq = np.zeros(S, np.float32)
-        seeds = np.zeros(S, np.int32)
+        # The step's host inputs are fields of ONE fresh buffer, written
+        # here and never after its launch (step_pack). Padding tokens
+        # belong to padding row len(rows) (trash pages, position -1 =>
+        # masked everywhere) and write into the trash page; padding rows
+        # hold the layout's fill values.
+        lay = self._ragged_layout(T_pad)
+        buf = lay.new()
+        (tokens, tok_seq, tok_pos, write_slots, q_start, q_len, kv_len,
+         ring_len, is_first, append, is_spec, seed_rows, slot_ids, pt_rows,
+         temp, top_k, top_p, pen, pres, freq, seeds, rng) = lay.views(buf)
+        tok_seq[:] = min(len(rows), S - 1)
 
         off = 0
         # Per row: the context length its first sampled id leaves, and
@@ -2478,14 +2450,11 @@ class ModelRuntime:
         _sp.mark("host_prep")
         h = StepInFlight(rows, 0, _sp, batch_fields, ctx0, emits,
                          float(np.mean(kv_len[:len(rows)])))
+        rng[0] = self._next_rng()
+        self._h2d = [0, 0]
         try:
             h.toks_dev, h.n_emit_dev, self.kc, self.vc, self.recent, \
-                self.last_ids = self._dispatch_ragged(
-                    T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots,
-                    q_start, q_len, kv_len, ring_len, is_first, append,
-                    is_spec, seed_rows, slot_ids, pt_rows, temp, top_k,
-                    top_p, pen, pres, freq, seeds, self._next_key(),
-                )
+                self.last_ids = self._dispatch_ragged(T_pad, k_cap, buf)
         except Exception as e:
             # The step before is untouched by this failure: settle it
             # (its ids are good, and the replay below folds them in),
@@ -2494,6 +2463,7 @@ class ModelRuntime:
             self._jrec("batch", **batch_fields)
             self._ragged_failed(rows, e, core)
             return None
+        _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()
 
@@ -2663,8 +2633,21 @@ class ModelRuntime:
             return None
         self._stall_since = None
 
-        active_mask = np.zeros(len(self.slot_req), np.int32)
+        # The scan's host inputs, copied into ONE fresh buffer that is
+        # never written after its launch (step_pack): the live per-slot
+        # arrays advance below, while the program may still be reading
+        # what it was handed.
+        lay = self._decode_layout()
+        buf = lay.new()
+        (tokens, positions, active_mask, pt, temp, top_k, top_p, pen, pres,
+         freq, seeds, rng) = lay.views(buf)
+        tokens[:] = self.last_tokens
+        positions[:] = self.seq_lens  # position of the incoming token
         active_mask[active] = 1
+        pt[:] = self.page_table
+        temp[:], top_k[:], top_p[:] = self.temp, self.top_k, self.top_p
+        pen[:], pres[:], freq[:] = self.rep_pen, self.pres_pen, self.freq_pen
+        seeds[:], rng[0] = self.seeds, self._next_rng()
         rows = [("decode", i, self.slot_req[i], 0, k_steps) for i in active]
         # `tokens` as planned; the sample takes what was really emitted.
         _sp.note(T_pad=0, k_cap=int(k_steps),
@@ -2677,16 +2660,10 @@ class ModelRuntime:
                          [int(self.seq_lens[i]) + 1 for i in active],
                          [True] * len(active),
                          float(np.mean(self.seq_lens[active])))
+        self._h2d = [0, 0]
         h.toks_dev, self.kc, self.vc, self.recent, self.last_ids = \
-            self._dispatch_decode(
-                # Copies: the live arrays advance below, while the
-                # program may still be reading what it was handed.
-                k_steps, self.last_tokens.copy(),
-                self.seq_lens.copy(),  # position of the incoming token
-                active_mask, self.page_table, self.temp, self.top_k,
-                self.top_p, self.rep_pen, self.pres_pen, self.freq_pen,
-                self.seeds, self._next_key(),
-            )
+            self._dispatch_decode(k_steps, buf)
+        _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()
         for i in active:

@@ -9,9 +9,10 @@ over ICI/DCN doing the cross-chip movement.
 Control plane: the primary host (process 0) owns the scheduler, HTTP
 front, and all admission decisions. Before every device step it ships a
 "step plan" — a fixed-shape header (opcode + static dims + routing
-ordinals) plus the op payload (token ids, page tables, sampling params,
-raw RNG key) — over the jax.distributed KV store as a monotonic key
-stream (`_Wire`). Workers sit in `run_worker`, long-poll the stream, and
+ordinals) plus the op payload (for a step program: the ONE packed int32
+buffer the primary hands its own jit, engine/step_pack.py — token ids,
+page tables, sampling params and the RNG counter are fields of it) —
+over the jax.distributed KV store as a monotonic key stream (`_Wire`). Workers sit in `run_worker`, long-poll the stream, and
 issue the SAME jit call with their local shards. Every value feeding the
 computation travels on the wire, never recomputed locally, so all hosts
 trace and execute identical steps. The control plane is deliberately
@@ -36,8 +37,8 @@ Opcode header (int32[5]: [op, a, b, model_ordinal, replica_ordinal]):
                                     decode rows in one flattened stream)
     OP_SPEC     = 11, a=T_pad, b=k_cap (ragged mixed batch carrying
                                     speculative verify spans: the RAGGED
-                                    payload plus the per-row is_spec
-                                    flag; k_cap sizes the multi-token
+                                    payload, byte for byte the same
+                                    layout; k_cap sizes the multi-token
                                     output shape on every host)
 
 Data parallelism under SPMD: dp replicas each live on a slice of the
@@ -81,6 +82,7 @@ import jax
 import jax.numpy as jnp
 
 from ollamamq_tpu.config import EngineConfig
+from ollamamq_tpu.engine import step_pack
 from ollamamq_tpu.engine.engine import (EncoderRuntime, ModelRuntime,
                                         PeerDeadError, WorkerDesyncError)
 
@@ -97,7 +99,6 @@ OP_EMBED = 9  # a=B, b=bucket: embed batch on a GENERATIVE runtime
 OP_RAGGED = 10  # a=T_pad: ragged mixed batch (prefill spans + decode rows)
 OP_SPEC = 11  # a=T_pad, b=k_cap: ragged mixed batch + speculative spans
 
-KEY_SHAPE = (2,)  # raw uint32 threefry key data
 NAME_LEN = 128  # utf-8 bytes, zero-padded, for OP_LOAD/OP_EVICT names
 PATH_LEN = 256  # utf-8 bytes for checkpoint paths ("" = None)
 
@@ -298,42 +299,19 @@ def payload_spec(op, a, b, S, MP, W):
     place the wire order lives. Senders cast their positional values to
     this spec; workers build a zeros template from it. Broadcast matches
     on tree structure + shape/dtype, so both sides must agree exactly.
-    `W` is the repeat-penalty window (OP_RAGGED carries each row's
-    first-span penalty-ring seed row, which on a prefix-cache hit holds
-    the cached prefix's last W tokens — the tree itself is primary-only
-    host state; only its effects travel)."""
+    `W` is the repeat-penalty window (a ragged step's buffer carries each
+    row's first-span penalty-ring seed row, which on a prefix-cache hit
+    holds the cached prefix's last W tokens — the tree itself is
+    primary-only host state; only its effects travel)."""
 
-    def samp(n):  # temp, top_k, top_p, repeat, presence, frequency, seed
-        return [((n,), np.float32), ((n,), np.int32), ((n,), np.float32),
-                ((n,), np.float32), ((n,), np.float32), ((n,), np.float32),
-                ((n,), np.int32)]
-
-    key = [(KEY_SHAPE, np.uint32)]
+    # A step program's payload is its one packed buffer: the layout
+    # (engine/step_pack.py) is the wire order, on both sides.
     if op == OP_DECODE:
-        return [((S,), np.int32), ((S,), np.int32), ((S,), np.int32),
-                ((S, MP), np.int32)] + samp(S) + key
+        return [((step_pack.decode_layout(S, MP).size,), np.int32)]
     if op == OP_PREFILL_SP:
-        return [((1, a), np.int32), ((1,), np.int32), ((1,), np.int32),
-                ((1, MP), np.int32)] + samp(1) + key
-    if op == OP_RAGGED:
-        T = a
-        # tokens, tok_seq, tok_pos, write_slots; then per-sequence
-        # q_start, q_len, kv_len, ring_len, is_first, append, slot_ids,
-        # seed_rows, page tables, sampling, key.
-        return ([((T,), np.int32)] * 4
-                + [((S,), np.int32)] * 7
-                + [((S, W), np.int32), ((S, MP), np.int32)]
-                + samp(S) + key)
-    if op == OP_SPEC:
-        # The RAGGED payload plus the per-row is_spec flag (the eighth
-        # [S] vector, after append / before slot_ids); k_cap rides the
-        # header's b so every host compiles the same multi-token output
-        # shape.
-        T = a
-        return ([((T,), np.int32)] * 4
-                + [((S,), np.int32)] * 8
-                + [((S, W), np.int32), ((S, MP), np.int32)]
-                + samp(S) + key)
+        return [((step_pack.sp_layout(a, MP).size,), np.int32)]
+    if op in (OP_RAGGED, OP_SPEC):
+        return [((step_pack.ragged_layout(a, S, MP, W).size,), np.int32)]
     if op in (OP_ENCODE, OP_EMBED):
         B, bucket = a, b
         return [((B, bucket), np.int32), ((B,), np.int32)]
@@ -612,64 +590,31 @@ class SPMDModelRuntime(ModelRuntime):
             return 0
         return super().import_prefix(blob)
 
-    def _dispatch_decode(self, k_steps, tokens, positions, active, pt, temp,
-                         tk, tp, pen, pres, freq, seeds, key):
+    def _dispatch_decode(self, k_steps, buf):
         if not self._spmd:
-            return super()._dispatch_decode(
-                k_steps, tokens, positions, active, pt, temp, tk, tp, pen,
-                pres, freq, seeds, key)
+            return super()._dispatch_decode(k_steps, buf)
         return self._mirrored(
-            OP_DECODE, k_steps, 0,
-            (tokens, positions, active, pt, temp, tk, tp, pen, pres, freq,
-             seeds, key),
+            OP_DECODE, k_steps, 0, (buf,),
             lambda: super(SPMDModelRuntime, self)._dispatch_decode(
-                k_steps, tokens, positions, active, pt, temp, tk, tp, pen,
-                pres, freq, seeds, key))
+                k_steps, buf))
 
-    def _dispatch_prefill_sp(self, T, tokens, lens, slot_ids, pt_rows,
-                             temp, tk, tp, pen, pres, freq, seeds, key):
+    def _dispatch_prefill_sp(self, T, buf):
         if not self._spmd:
-            return super()._dispatch_prefill_sp(
-                T, tokens, lens, slot_ids, pt_rows, temp, tk, tp, pen,
-                pres, freq, seeds, key)
+            return super()._dispatch_prefill_sp(T, buf)
         return self._mirrored(
-            OP_PREFILL_SP, T, 0,
-            (tokens, lens, slot_ids, pt_rows, temp, tk, tp, pen, pres,
-             freq, seeds, key),
+            OP_PREFILL_SP, T, 0, (buf,),
             lambda: super(SPMDModelRuntime, self)._dispatch_prefill_sp(
-                T, tokens, lens, slot_ids, pt_rows, temp, tk, tp, pen,
-                pres, freq, seeds, key))
+                T, buf))
 
-    def _dispatch_ragged(self, T_pad, k_cap, tokens, tok_seq, tok_pos,
-                         write_slots, q_start, q_len, kv_len, ring_len,
-                         is_first, append, is_spec, seed_rows, slot_ids, pt,
-                         temp, tk, tp, pen, pres, freq, seeds, key):
+    def _dispatch_ragged(self, T_pad, k_cap, buf):
         if not self._spmd:
-            return super()._dispatch_ragged(
-                T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots,
-                q_start, q_len, kv_len, ring_len, is_first, append, is_spec,
-                seed_rows, slot_ids, pt, temp, tk, tp, pen, pres, freq,
-                seeds, key)
-        # Plain mixed batches keep the OP_RAGGED wire shape; only
-        # dispatches actually carrying verify spans pay for (and ship)
-        # the is_spec vector + multi-token output (OP_SPEC, b=k_cap).
-        if k_cap:
-            op, payload = OP_SPEC, (
-                tokens, tok_seq, tok_pos, write_slots, q_start, q_len,
-                kv_len, ring_len, is_first, append, is_spec, slot_ids,
-                seed_rows, pt, temp, tk, tp, pen, pres, freq, seeds, key)
-        else:
-            op, payload = OP_RAGGED, (
-                tokens, tok_seq, tok_pos, write_slots, q_start, q_len,
-                kv_len, ring_len, is_first, append, slot_ids, seed_rows,
-                pt, temp, tk, tp, pen, pres, freq, seeds, key)
+            return super()._dispatch_ragged(T_pad, k_cap, buf)
+        # One payload for both ops; they differ in k_cap (the header's
+        # b) and in the fault site a chaos plan can aim at.
         return self._mirrored(
-            op, T_pad, k_cap, payload,
+            OP_SPEC if k_cap else OP_RAGGED, T_pad, k_cap, (buf,),
             lambda: super(SPMDModelRuntime, self)._dispatch_ragged(
-                T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots,
-                q_start, q_len, kv_len, ring_len, is_first, append, is_spec,
-                seed_rows, slot_ids, pt, temp, tk, tp, pen, pres, freq,
-                seeds, key))
+                T_pad, k_cap, buf))
 
     def _dispatch_embed(self, B, bucket, tokens, lens):
         if not self._spmd:
@@ -1121,52 +1066,19 @@ def _replay(rt, op, a, b, payload):
     primary's dispatch exactly (same jit, same inputs). Returns every
     device output of the replayed computation."""
     if op == OP_DECODE:
-        k_steps = a
-        (tokens, positions, active, pt, temp, tk, tp, pen, pres,
-         freq, seeds, key_data) = payload
-        key = jnp.asarray(key_data, jnp.uint32)
+        (buf,) = payload
         toks, rt.kc, rt.vc, rt.recent, rt.last_ids = \
-            ModelRuntime._dispatch_decode(
-                rt, k_steps, tokens, positions, active, pt, temp, tk,
-                tp, pen, pres, freq, seeds, key)
+            ModelRuntime._dispatch_decode(rt, a, buf)
         return (toks, rt.kc, rt.vc, rt.recent, rt.last_ids)
     elif op == OP_PREFILL_SP:
-        T = a
-        (tokens, lens, slot_ids, pt_rows, temp, tk, tp, pen, pres,
-         freq, seeds, key_data) = payload
-        key = jnp.asarray(key_data, jnp.uint32)
+        (buf,) = payload
         toks, rt.kc, rt.vc, rt.recent = ModelRuntime._dispatch_prefill_sp(
-            rt, T, tokens, lens, slot_ids, pt_rows, temp, tk, tp,
-            pen, pres, freq, seeds, key)
+            rt, a, buf)
         return (toks, rt.kc, rt.vc, rt.recent)
-    elif op == OP_RAGGED:
-        T_pad = a
-        (tokens, tok_seq, tok_pos, write_slots, q_start, q_len, kv_len,
-         ring_len, is_first, append, slot_ids, seed_rows, pt, temp, tk,
-         tp, pen, pres, freq, seeds, key_data) = payload
-        key = jnp.asarray(key_data, jnp.uint32)
-        # No verify spans on this wire shape: is_spec is identically
-        # zero on every host (k_cap=0 compiles the 1-column output).
-        is_spec = np.zeros_like(q_start)
+    elif op in (OP_RAGGED, OP_SPEC):
+        (buf,) = payload  # a=T_pad, b=k_cap (0 on OP_RAGGED)
         toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids = \
-            ModelRuntime._dispatch_ragged(
-                rt, T_pad, 0, tokens, tok_seq, tok_pos, write_slots,
-                q_start, q_len, kv_len, ring_len, is_first, append,
-                is_spec, seed_rows, slot_ids, pt, temp, tk, tp, pen,
-                pres, freq, seeds, key)
-        return (toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids)
-    elif op == OP_SPEC:
-        T_pad, k_cap = a, b
-        (tokens, tok_seq, tok_pos, write_slots, q_start, q_len, kv_len,
-         ring_len, is_first, append, is_spec, slot_ids, seed_rows, pt,
-         temp, tk, tp, pen, pres, freq, seeds, key_data) = payload
-        key = jnp.asarray(key_data, jnp.uint32)
-        toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids = \
-            ModelRuntime._dispatch_ragged(
-                rt, T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots,
-                q_start, q_len, kv_len, ring_len, is_first, append,
-                is_spec, seed_rows, slot_ids, pt, temp, tk, tp, pen,
-                pres, freq, seeds, key)
+            ModelRuntime._dispatch_ragged(rt, a, b, buf)
         return (toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids)
     elif op == OP_ENCODE:
         B, bucket = a, b

@@ -410,6 +410,16 @@ STEP_WASTED_ROWS_TOTAL = REGISTRY.counter(
     "request had already finished (EOS or a stop string seen one step "
     "late, a cancel between launch and settle): extra device work, never "
     "a token more or less", labels=("model",))
+STEP_H2D_TRANSFERS_TOTAL = REGISTRY.counter(
+    "ollamamq_step_h2d_transfers_total",
+    "Host-to-device transfers the engine made for its step programs' "
+    "inputs (`h2d_transfers` on a step sample), by step mode: one a "
+    "step — the step's host inputs travel as one packed array",
+    labels=("mode",))
+STEP_H2D_BYTES_TOTAL = REGISTRY.counter(
+    "ollamamq_step_h2d_bytes_total",
+    "Bytes of those transfers (`h2d_bytes` on a step sample), by step "
+    "mode", labels=("mode",))
 COMPILE_TOTAL = REGISTRY.counter(
     "ollamamq_compile_total",
     "XLA compiles the engine paid, by jit-cache site (ragged / prefill "

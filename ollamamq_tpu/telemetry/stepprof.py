@@ -7,7 +7,11 @@ and SPMD-primary alike — records ONE schema'd sample into a bounded
 ring: where that step's milliseconds went (`host_prep` → `dispatch` →
 `collect` → `detok`, the CLOSED phase vocabulary below), under which
 compiled shape (`(mode, T_pad, k_cap)`), over how many real vs padded
-token positions, and whether the step paid a fresh XLA compile. The
+token positions, whether the step paid a fresh XLA compile, and — for a
+generative step — how many host→device transfers its inputs took and
+their bytes (`h2d_transfers`, `h2d_bytes`; the
+`ollamamq_step_h2d_*_total` counters: one transfer a step, the packed
+buffer of engine/step_pack.py). The
 same samples feed `ollamamq_step_phase_ms{phase,mode}` histograms, a
 rolling per-shape p50/p99 table, `/debug/stepprof`, the TUI `compiles`
 chip, and the `step_profile` block bench.py embeds in every BENCH
@@ -449,6 +453,11 @@ class StepProfiler:
             if v:
                 tm.STEP_PHASE_MS.labels(phase=ph, mode=sample["mode"]) \
                     .observe(v)
+        if "h2d_transfers" in sample:
+            tm.STEP_H2D_TRANSFERS_TOTAL.labels(mode=sample["mode"]) \
+                .inc(sample["h2d_transfers"])
+            tm.STEP_H2D_BYTES_TOTAL.labels(mode=sample["mode"]) \
+                .inc(sample.get("h2d_bytes", 0))
         self._overhead_ns += time.perf_counter_ns() - t0
 
     # -- compile ledger ----------------------------------------------------

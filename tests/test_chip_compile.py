@@ -255,13 +255,9 @@ def test_olmoe_width_step_forward_compiles_for_one_v5e_chip(v5e, which):
 # The engine's own step programs: everything they carry is updated in place.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
-def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
-    """The two programs of the pipelined loop as the engine jits them
-    (PR 28 added the `last_ids` carry: a step launched behind an unsettled
-    one reads a row's input token from it): both pools, the penalty ring
-    and the carry are donated and come back aliased — the compiled program
-    holds no second copy of any."""
+def _lower_step_program(v5e, which, monkeypatch):
+    """One of the pipelined loop's two programs as the engine jits it,
+    lowered for one described chip: (lowered, the packed input's words)."""
     from types import SimpleNamespace
 
     from ollamamq_tpu.engine import engine as eng_mod
@@ -279,26 +275,34 @@ def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
     S, W = B, 64
     rt = object.__new__(ModelRuntime)
     rt.cfg, rt.attn_impl, rt.mesh = LOOP_CFG, "pallas", None
-    rt.ecfg = SimpleNamespace(page_size=PS)
+    rt.ecfg = SimpleNamespace(page_size=PS, max_slots=S,
+                              max_pages_per_seq=MP, repeat_last_n=W)
     rt._prefill_jits, rt._decode_jits = {}, {}
     shapes = jax.eval_shape(
         lambda: llama.init_params(LOOP_CFG, jax.random.PRNGKey(0)))
     params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
     pool = s((LAYERS, NP * PS, HK * HD), jnp.bfloat16)
     recent, last_ids = s((S + 1, W)), s((S,))
-    f32 = lambda: s((S,), jnp.float32)  # noqa: E731
-    sampling = (f32(), s((S,)), f32(), f32(), f32(), f32(), s((S,)))
-    key = s((2,), jnp.uint32)
     if which == "mq_ragged_step":
         fn = rt._get_ragged_jit(T, 0, (True, True, True))
-        lowered = fn.lower(
-            params, s((T,)), s((T,)), s((T,)), s((T,)),
-            *[s((S,)) for _ in range(7)], s((S, W)), s((S,)), s((S, MP)),
-            pool, pool, recent, last_ids, *sampling, key)
+        words = rt._ragged_layout(T).size
     else:
         fn = rt._get_decode_jit(8, (True, True, True))
-        lowered = fn.lower(params, s((S,)), s((S,)), pool, pool, recent,
-                           last_ids, s((S,)), s((S, MP)), *sampling, key)
+        words = rt._decode_layout().size
+    # The step's host inputs are ONE packed int32 array (step_pack).
+    return fn.lower(params, s((words,)), pool, pool, recent,
+                    last_ids), words
+
+
+@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
+def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
+    """The two programs of the pipelined loop as the engine jits them
+    (PR 28 added the `last_ids` carry: a step launched behind an unsettled
+    one reads a row's input token from it): both pools, the penalty ring
+    and the carry are donated and come back aliased — the compiled program
+    holds no second copy of any."""
+    lowered, _ = _lower_step_program(v5e, which, monkeypatch)
+    S, W = B, 64
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
@@ -306,3 +310,16 @@ def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
                + (S + 1) * W * 4 + S * 4)            # the ring, the carry
     assert mem.alias_size_in_bytes >= carried, (mem, carried)
     assert mem.temp_size_in_bytes < NP * PS * HK * HD * 2, mem
+
+
+def test_ragged_step_is_fed_one_host_array(v5e, monkeypatch):
+    """One upload a step: besides `params`, the compiled ragged step has
+    exactly ONE parameter that is not donated device state — the packed
+    int32 buffer of its host inputs. The RNG key is made inside (no key
+    parameter), so nothing else is dispatched or transferred for a step."""
+    lowered, words = _lower_step_program(v5e, "mq_ragged_step", monkeypatch)
+    lowered.compile()
+    _params, *rest = lowered.args_info[0]
+    fed = [a for a in rest if not a.donated]
+    assert len(rest) == 5 and len(fed) == 1, rest
+    assert (fed[0].shape, fed[0].dtype) == ((words,), jnp.int32), fed

@@ -193,13 +193,12 @@ def test_arrival_storm_never_starves_decode_rows():
     dispatched = []
     orig = rt._dispatch_ragged
 
-    def spy(T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots, q_start,
-            q_len, *rest):
-        slot_ids = rest[6]
+    def spy(T_pad, k_cap, buf):
+        lay = rt._ragged_layout(T_pad)  # the step's one packed input
+        slot_ids, q_len = lay.view(buf, "slot_ids"), lay.view(buf, "q_len")
         dispatched.append({int(sl): int(n) for sl, n in zip(slot_ids, q_len)
                            if n > 0})
-        return orig(T_pad, k_cap, tokens, tok_seq, tok_pos, write_slots,
-                    q_start, q_len, *rest)
+        return orig(T_pad, k_cap, buf)
 
     rt._dispatch_ragged = spy
     # One token each: a backlog prompt leaves the free slot with the step
