@@ -12,6 +12,8 @@ TPU they are jax's megablox kernel (`gmm.N`) or, for a program that uses
 """
 import re
 
+from benchmarks.lib import arch
+
 EXPERT_MM = re.compile(r"^(gmm|ragged-dot)")
 MATMULS_A_LAYER = 3
 FIELDS = ("moe_assignments", "moe_pairs_hit", "moe_load_max", "moe_load_mean")
@@ -25,19 +27,19 @@ def has_counters(samples) -> bool:
 def pair_bytes(cfg: dict) -> int:
     """One (layer, expert) pair that got a token streams its three matrices
     once: gate and up [hidden, expert width], down [expert width, hidden]."""
-    return 3 * cfg["hidden_size"] * cfg["intermediate_size"] * WEIGHT_BYTES
+    return 3 * cfg["hidden_size"] * arch.expert_width(cfg) * WEIGHT_BYTES
 
 
 def assignment_bytes(cfg: dict) -> int:
     """One (token, expert) assignment: gate and up each read the token's
     hidden row and write an expert-width row, down reads one and writes a
     hidden row."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
+    d, f = cfg["hidden_size"], arch.expert_width(cfg)
     return (2 * (d + f) + (f + d)) * WEIGHT_BYTES
 
 
 def assignment_flops(cfg: dict) -> int:
-    return 3 * 2 * cfg["hidden_size"] * cfg["intermediate_size"]
+    return 3 * 2 * cfg["hidden_size"] * arch.expert_width(cfg)
 
 
 def least_seconds(cfg: dict, pairs_hit: float, assignments: float,

@@ -16,7 +16,8 @@ def time_of(trace: dict, pattern) -> float:
 
 
 def forward_passes(trace: dict, layers: int) -> float:
-    """Forward passes the device ran inside the traced window: every layer of
-    every pass launches one attention kernel, so launches / layers."""
+    """Forward passes the device ran inside the traced window: every layer
+    that has attention (`layers` of them, lib/arch.py) launches one attention
+    kernel a pass, so launches / layers; 0 for a stack with no such layer."""
     return sum(n for name, n in trace["op_count"].items()
-               if ATTENTION.search(name)) / layers
+               if ATTENTION.search(name)) / layers if layers else 0.0

@@ -5,13 +5,14 @@ FLOPs at the bf16 peak if that is more) over the seconds they took on the
 device trace.
 
 Both sides cover the same passes. The trace says how many forward passes it
-holds: grouped-matmul launches / (3 x layers). The step samples taken during
-the capture say what a pass hit: pairs and assignments, summed, over their
-passes. (Counting the samples' passes instead would put two clocks into one
-ratio; PERF.md section 6, PR 23.) 0 where the trace holds no such op; None for
-a program without the counters, or with no peaks (a rehearsal on the CPU)."""
+holds: grouped-matmul launches / (3 x the layers that have experts,
+lib/arch.py). The step samples taken during the capture say what a pass hit:
+pairs and assignments, summed, over their passes. (Counting the samples'
+passes instead would put two clocks into one ratio; PERF.md section 6, PR 23.)
+0 where the trace holds no such op; None for a program without the counters,
+or with no peaks (a rehearsal on the CPU)."""
 from benchmarks.layer_metrics import _moe
-from benchmarks.lib import steps
+from benchmarks.lib import arch, steps
 
 
 def read(ctx):
@@ -23,7 +24,7 @@ def read(ctx):
         return 0.0
     if not ctx.peaks:
         return None
-    passes = launches / (_moe.MATMULS_A_LAYER * cfg["num_hidden_layers"])
+    passes = launches / (_moe.MATMULS_A_LAYER * arch.expert_layers(cfg))
     sampled = steps.total_passes(ctx.trace_steps)
     pairs = sum(s["moe_pairs_hit"] for s in ctx.trace_steps) / sampled
     rows = sum(s["moe_assignments"] for s in ctx.trace_steps) / sampled

@@ -24,6 +24,7 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import random  # noqa: E402
@@ -34,6 +35,7 @@ import sys  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from benchmarks import serve  # noqa: E402 — jax-free at import
 from benchmarks.lib import loadgen, result, spec, stats, steps  # noqa: E402
 from benchmarks.lib import traffic as tg  # noqa: E402
 from benchmarks.lib.peaks import peaks_of  # noqa: E402
@@ -243,12 +245,16 @@ def main(argv=None) -> int:
     seconds = float(args.seconds if args.seconds is not None
                     else cell.run_seconds)
     traffic = dict(cell.traffic)
-    flags = list(cell.config.get("server_flags", ()))
     if args.rehearse_cpu:
-        from benchmarks import serve  # jax-free at import
-
         traffic = tg.rehearsal(traffic)
-        flags = serve.server_flags(cell.config, True)
+    try:   # the cell's configuration as this run runs it
+        cell = dataclasses.replace(cell, config=serve.as_run(
+            cell.config, args.rehearse_cpu))
+    except serve.Refused as e:
+        print(f"benchmark run failed: {cell.config_file}: {e}",
+              file=sys.stderr)
+        return 1
+    flags = serve.server_flags(cell.config, args.rehearse_cpu)
     out_dir = os.path.join(ROOT, "chiprun_out", "benchmarks", cell.name,
                            f"seed{args.seed}_trace{args.trace}")
     shutil.rmtree(out_dir, ignore_errors=True)
@@ -275,8 +281,7 @@ def main(argv=None) -> int:
 def drive(args, cell, child, traffic, flags, seconds, traced, out_dir) -> int:
     health_s = child.wait_health(HEALTH_TIMEOUT_S)
     gen = loadgen.LoadGen(child.base_url, cell.config["name"], traffic,
-                          int(cell.config["vocab_size"]) if not
-                          args.rehearse_cpu else 512, args.seed, seconds)
+                          int(cell.config["vocab_size"]), args.seed, seconds)
     warm = warm_up(gen, child, flags)
     say("warm_up", health_s=health_s,
         warm_s=time.monotonic() - T_START - health_s,
@@ -405,6 +410,14 @@ def drive(args, cell, child, traffic, flags, seconds, traced, out_dir) -> int:
             deterministic=warm["deterministic"], problems=problems,
             reference_agrees=reference.get("agrees"),
             reference_error=reference.get("error"))
+    # every number compared, beside its limit: the end of standard error
+    print(f"correct={correct}: reference mean_margin_sd "
+          f"{reference.get('mean_margin_sd')} (limit "
+          f"{reference.get('mean_margin_sd_max')}, "
+          f"{reference.get('positions')} positions); requests completed "
+          f"wrong {len(completed_wrong)} (limit 0); the greedy prompt sent "
+          f"twice the same ids: {warm['deterministic']}; server problems "
+          f"{problems or 'none'}", file=sys.stderr, flush=True)
     dev = {k: device[k] for k in result.DEVICE_KEYS}
     bd = None
     if traced:

@@ -4,11 +4,15 @@
 
 It registers the configuration's `ModelConfig` under the configuration's
 name and hands the configuration's own CLI flags to the CLI — scheduler,
-cache, sharding and kernels are the program's, unedited. Because this is the
-process that holds the chip, a thread of its own answers one question the
-server has no endpoint for: when `<out>/dump.request` appears it writes
-`<out>/device.json` (platform, kind, device count, and the largest
-`peak_bytes_in_use` over the chips). When `<out>/reference.request` appears
+cache, sharding and kernels are the program's, unedited. Every architecture
+key of the file reaches a field of the served program's `ModelConfig`; a key
+the program has no field for, or a value it refuses, ends this process with
+one line (file, key, value) and exit code 2 before the CLI starts.
+
+Because this is the process that holds the chip, a thread of its own answers
+one question the server has no endpoint for: when `<out>/dump.request`
+appears it writes `<out>/device.json` (platform, kind, device count, and the
+largest `peak_bytes_in_use` over the chips). When `<out>/reference.request` appears
 (after the window, when the engine is idle) it runs the configuration's plain
 float32 reference (`benchmarks/reference/<name>.py`) over the requests listed
 there, on the weights this process serves, and writes `<out>/reference.json`.
@@ -20,6 +24,7 @@ an untraced run does not pay it).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import importlib.util
 import json
 import os
@@ -30,24 +35,37 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-# configuration-file key -> ModelConfig field
-FIELDS = {
-    "vocab_size": "vocab_size", "hidden_size": "hidden_size",
-    "intermediate_size": "intermediate_size",
+from benchmarks.lib import arch  # noqa: E402 — data only, no jax
+
+# A configuration file is the whole description of its model. These keys are
+# the harness's own (and any key ending in `_reason`); EVERY other key is an
+# architecture key, and reaches the program or stops the run at start.
+HARNESS_KEYS = frozenset((
+    "name", "source", "deployment", "chips", "model_type", "reduced",
+    "reduced_from", "assumed", "arithmetic", "dtype", "routing", "reference",
+    "server_flags", "stream_every_token", "rehearse"))
+# architecture key -> the ModelConfig field of another name that holds it
+# (the published spellings of fields the program has); a key that is not here
+# goes to the field of its own name.
+RENAMES = {
     "num_hidden_layers": "num_layers", "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-    "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+    "num_key_value_heads": "num_kv_heads",
     "max_position_embeddings": "max_seq_len",
     "tie_word_embeddings": "tie_embeddings", "attention_bias": "attn_bias",
-    "qk_norm": "qk_norm", "num_experts": "num_experts",
-    "num_experts_per_tok": "num_experts_per_tok",
+    "norm_eps": "rms_norm_eps",
+    **{key: "num_experts" for key in arch.EXPERT_COUNT_KEYS},
 }
+# A key that names no field passes at the one value the program implements.
+ONLY_VALUE = {"hidden_act": "silu", "rope_scaling": None, "clip_qkv": None}
 # --rehearse-cpu: the same architecture switches at sizes a CPU runs in
-# milliseconds, and a pool to match. Never a measurement.
+# milliseconds, and a pool to match. Never a measurement. A file's own
+# `rehearse` block is laid over these (what a stack whose layers differ
+# needs: a list a layer long, a dense prefix, a width of its own).
 REHEARSE_SIZES = {
     "vocab_size": 512, "hidden_size": 128, "intermediate_size": 256,
-    "num_layers": 2, "num_heads": 8, "num_kv_heads": 4, "head_dim": 16,
-    "max_seq_len": 2048,
+    "num_hidden_layers": 2, "num_attention_heads": 8,
+    "num_key_value_heads": 4, "head_dim": 16,
+    "max_position_embeddings": 2048,
 }
 REHEARSE_FLAGS = {
     "--max-slots": "8", "--num-pages": "256", "--page-size": "8",
@@ -56,21 +74,63 @@ REHEARSE_FLAGS = {
 }
 
 
+class Refused(Exception):
+    """The served program cannot run the configuration file as written: one
+    line naming key and value, told before any device is touched."""
+
+
+def refused(key: str, value, why: str) -> Refused:
+    return Refused(f"key {key!r} = {json.dumps(value)}: {why}")
+
+
+def architecture(cfg: dict) -> dict:
+    return {key: value for key, value in cfg.items()
+            if key not in HARNESS_KEYS and not key.endswith("_reason")}
+
+
 def as_run(cfg: dict, rehearse: bool) -> dict:
     """The configuration file's keys as this process runs them."""
     if not rehearse:
         return cfg
-    return {**cfg, **{key: REHEARSE_SIZES[field]
-                      for key, field in FIELDS.items()
-                      if field in REHEARSE_SIZES}}
+    own = cfg.get("rehearse", {})
+    for key, value in architecture(cfg).items():
+        if isinstance(value, list) and key not in own:
+            raise refused(key, value, "a list is as long as a size the "
+                          "rehearsal shrinks: the file's `rehearse` block "
+                          "has to give its tiny value")
+    return {**cfg, **REHEARSE_SIZES, **own}
 
 
 def model_config(cfg: dict, rehearse: bool):
+    """The served program's `ModelConfig` of the file: every architecture key
+    in the field RENAMES names for it, else the field of its own name."""
     from ollamamq_tpu.config import ModelConfig
 
     cfg = as_run(cfg, rehearse)
-    return ModelConfig(name=cfg["name"], **{
-        field: cfg[key] for key, field in FIELDS.items() if key in cfg})
+    fields = {f.name for f in dataclasses.fields(ModelConfig)} - {"name"}
+    given: dict = {}     # field -> the key that gave it
+    for key, value in architecture(cfg).items():
+        field = RENAMES.get(key, key)
+        if field in fields:
+            if field in given:
+                raise refused(key, value, f"{given[field]!r} has already "
+                              f"given the field {field!r}")
+            given[field] = key
+        elif key not in ONLY_VALUE:
+            raise refused(key, value, "the program's ModelConfig has no "
+                          "field for it")
+        elif value != ONLY_VALUE[key]:
+            raise refused(key, value, "the program implements only "
+                          f"{json.dumps(ONLY_VALUE[key])}")
+    try:
+        return ModelConfig(name=cfg["name"], **{
+            field: cfg[key] for field, key in given.items()})
+    except (TypeError, ValueError) as e:
+        why = f"the program's ModelConfig refuses it: {e}"
+        named = [key for field, key in given.items() if field in str(e)]
+        if len(named) == 1:
+            raise refused(named[0], cfg[named[0]], why) from e
+        raise Refused(f"keys {sorted(given.values())}: {why}") from e
 
 
 def server_flags(cfg: dict, rehearse: bool) -> list:
@@ -104,6 +164,23 @@ def stream_every_token() -> None:
         return lambda token_id: "~" if token_id >= 259 else step(token_id)
 
     tk.ByteTokenizer.make_incremental_decoder = make
+
+
+def end_by_count_only() -> None:
+    """Every request of the harness's traffic ends by count (`num_predict`:
+    a completed request is one that returned all of them, `done_reason:
+    "length"`), as serving benchmarks fix output lengths with `ignore_eos`.
+    The Ollama API has no such option, and the byte tokenizer calls id 2 the
+    end of text: with seeded random weights it is one id among the
+    vocabulary's, which the greedy choice reaches about once in 10^7 to 10^8
+    tokens (PERF.md section 4) - a request cut short, a run `correct: false`,
+    once in some hundred runs of a sound program. So no id ends a request
+    here: the engine's comparison with `eos_id` runs as it does, and never
+    matches. (A shim over the program's tokenizer, as above; what the program
+    should offer instead is in PERF.md, Open questions.)"""
+    from ollamamq_tpu.engine import tokenizer as tk
+
+    tk.ByteTokenizer.eos_id = -1
 
 
 RUNTIMES: list = []  # every ModelRuntime this process built
@@ -202,13 +279,19 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     with open(args.config) as f:
         cfg = json.load(f)
+    try:
+        served = model_config(cfg, args.rehearse_cpu)
+    except Refused as e:   # before the CLI starts: no device is touched
+        print(f"{args.config}: {e}", flush=True)
+        return 2
 
     from ollamamq_tpu import cli
     from ollamamq_tpu.config import MODEL_CONFIGS
 
-    MODEL_CONFIGS[cfg["name"]] = model_config(cfg, args.rehearse_cpu)
+    MODEL_CONFIGS[cfg["name"]] = served
     if cfg.get("stream_every_token"):
         stream_every_token()
+    end_by_count_only()
     keep_runtimes()
     threading.Thread(target=watcher, args=(
         args.out, args.collect_steps, as_run(cfg, args.rehearse_cpu)),

@@ -477,3 +477,49 @@ def test_rehearsal_end_to_end(trace):
     assert notes["reference"]["positions"] > 0, notes["reference"]
     assert "error" not in notes["reference"]
     assert notes["memory"]["param_bytes"] > 0
+
+
+# Prompts whose greedy continuation reaches the byte tokenizer's EOS id on
+# the rehearsal-sized sparse model (CPU, engine seed 0): found by serving the
+# `batch` mix at that size on three seeds, where five requests of 221 ended
+# `done_reason: "stop"` after 15 to 28 of their 32 tokens.
+PROMPTS_THAT_SAMPLE_EOS = [
+    "c24 long fills queues", "c40 fair a long of and while", "c6 queues ",
+    "c29 long in of valu", "c2 fair qu"]
+
+
+def test_no_id_ends_a_request_before_its_count(tmp_path):
+    """A request of the harness's traffic ends by count. Random weights reach
+    the byte tokenizer's EOS id now and then (about once in 2,000 tokens at
+    the rehearsal's vocabulary of 512; once in 10^7 or more on the chip, so
+    once in some hundred runs): the server child goes on past it
+    (`serve.end_by_count_only`), the id is returned like any other, and the
+    request completes as asked — where the program alone ends it `stop`,
+    short, and the run would read `correct: false`."""
+    from ollamamq_tpu.engine.tokenizer import ByteTokenizer
+
+    from benchmarks import serve
+    from benchmarks.lib.server import Child
+
+    eos = ByteTokenizer.eos_id   # the program's, unshimmed in this process
+    assert eos == 2
+    subprocess.run(["make", "-C", os.path.join(ROOT, "cpp")], check=True,
+                   capture_output=True)
+    cell = spec.load_cell("olmoe-1b-7b-d10.batch")
+    cfg = serve.as_run(cell.config, True)
+    child = Child(cell.config_file, str(tmp_path), True, False)
+    try:
+        child.wait_health(300)
+        gen = loadgen.LoadGen(child.base_url, cfg["name"],
+                              {"options": {"temperature": 0}},
+                              int(cfg["vocab_size"]), 0, 1.0)
+        recs = gen.alone([
+            tg.Planned(index=i, user=f"u{i}", prompt=p,
+                       prompt_tokens=len(p) + 1, num_predict=32)
+            for i, p in enumerate(PROMPTS_THAT_SAMPLE_EOS)])
+    finally:
+        child.stop()
+    assert [(r.done_reason, r.tokens, r.ok) for r in recs] == \
+        [("length", 32, True)] * len(recs), [r.error for r in recs]
+    assert any(eos in r.ids for r in recs), \
+        "no prompt reached the EOS id: the test has lost its subject"

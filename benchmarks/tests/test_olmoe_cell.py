@@ -70,9 +70,10 @@ def test_the_cell_its_configuration_and_its_reference_load():
 
 
 def test_the_program_runs_the_configuration_files_model():
-    """serve.py hands the file's keys to ModelConfig verbatim: the value of
-    `qk_norm` selects the whole-vector norm, and `norm_topk_prob`, which
-    serve.py does not pass, is the program's default — the file's value."""
+    """serve.py hands every architecture key of the file to ModelConfig: the
+    value of `qk_norm` selects the whole-vector norm, and `norm_topk_prob`
+    reaches the field of its own name (since PR 31; before, it was the
+    program's default that happened to be the file's value)."""
     from benchmarks import serve
 
     cfg = spec.load_cell(CELL).config

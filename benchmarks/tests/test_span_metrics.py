@@ -3,12 +3,11 @@ thread's loop phases in the step samples, the `ingress` request phase, the
 stream-lag histogram — on the CPU:
     python -m pytest benchmarks/tests/test_span_metrics.py -q
 
-Their `per_layer` entries wait in `span_metric_entries.json`, beside this
-file, and NOT in BENCHMARK.json: a reader that has nothing to read returns
-None, and run.py then refuses the whole line (pinned below) — which is what
-the driver's traced run of the PARENT commit, laid over with these files,
-would meet. The `benchmark` PR that lets run.py leave such a metric out
-appends the entries. Nothing here gives a device number."""
+Their six `per_layer` entries are in BENCHMARK.json since PR 31 (they
+waited in a file beside this one while the PARENT commit of a PR lacked the
+loop fields and histograms: a reader that has nothing to read returns None,
+and run.py then refuses the whole line — pinned below, and still the rule).
+Nothing here gives a device number."""
 
 from __future__ import annotations
 
@@ -28,17 +27,12 @@ from benchmarks import run  # noqa: E402
 from benchmarks.lib import result, spec  # noqa: E402
 from benchmarks.lib import trace as tr  # noqa: E402
 
-ENTRIES = spec.load_json(os.path.join(BENCH, "tests",
-                                      "span_metric_entries.json"))
+BENCHMARK = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+SPAN_METRICS = ("loop_ms_per_step.lat", "loop_ms_per_step.thr",
+                "idle_explained_pct.lat", "idle_explained_pct.thr",
+                "ingress_mean_ms", "stream_lag_mean_ms")
+ENTRIES = [m for m in BENCHMARK["per_layer"] if m["name"] in SPAN_METRICS]
 CHAT = "qwen2.5-7b-d14.chat"
-
-
-def with_entries() -> dict:
-    """BENCHMARK.json as the next `benchmark` PR leaves it: the entries
-    appended at the end of `per_layer`, nothing else touched."""
-    bj = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    bj["per_layer"] = bj["per_layer"] + ENTRIES
-    return bj
 
 
 def reader(name: str):
@@ -119,46 +113,44 @@ def test_ingress_and_stream_lag_are_histogram_deltas():
         assert reader(name).read(ctx(prom0=p0, prom1=p0)) is None
 
 
-# ------------------------------------------------ the entries that wait
+# ------------------------------------------ the entries, in BENCHMARK.json
 def test_the_waiting_entries_fit_the_benchmark_as_it_is():
-    """Appended to BENCHMARK.json they name cells, layers and end-to-end
-    metrics that are there, every metric of every cell finds its reader
-    (a `.lat`/`.thr` pair the one file of its stem), and no other entry
-    moves."""
-    old = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    new = with_entries()
-    assert new["per_layer"][:len(old["per_layer"])] == old["per_layer"]
-    assert {k: v for k, v in new.items() if k != "per_layer"} \
-        == {k: v for k, v in old.items() if k != "per_layer"}
-    names = [m["name"] for m in new["per_layer"]]
-    assert len(names) == len(set(names))
-    layers = {m["layer"] for m in old["per_layer"]}
+    """All six are there, once; each names cells, a layer and an end-to-end
+    metric that are there, every cell it names reports that metric, and it
+    finds its reader (a `.lat`/`.thr` pair the one file of its stem). Each
+    entry is held to the cells it names and to nothing about the others."""
+    assert sorted(e["name"] for e in ENTRIES) == sorted(SPAN_METRICS)
+    cells = {w["name"] for w in BENCHMARK["workloads"]}
+    layers = {m["layer"] for m in BENCHMARK["per_layer"]
+              if m["name"] not in SPAN_METRICS}
     for e in ENTRIES:
         assert set(e) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert e["layer"] in layers, e["layer"]
+        assert e["workloads"] and set(e["workloads"]) <= cells, e["name"]
         for w in e["workloads"]:
             assert any(m["name"] == e["moves"] and w in m["workloads"]
-                       for m in old["end_to_end"]), (e["name"], w)
-        path = os.path.join(BENCH, "layer_metrics",
-                            e["name"].rsplit(".", 1)[0] + ".py")
-        assert os.path.exists(path) or os.path.exists(os.path.join(
-            BENCH, "layer_metrics", e["name"] + ".py")), e["name"]
-    per_cell = {w["name"]: sum(w["name"] in e["workloads"] for e in ENTRIES)
-                for w in old["workloads"]}
-    assert per_cell == {CHAT: 4, "qwen2.5-7b-d14.batch": 2,
-                        "qwen3-8b-tp4.chat48": 2}
+                       for m in BENCHMARK["end_to_end"]), (e["name"], w)
+            cell = spec.load_cell(w)
+            mine = [m for m in cell.metrics_of("per_layer")
+                    if m.name == e["name"]]
+            assert len(mine) == 1
+            assert callable(spec.load_reader(cell, mine[0]).read)
+    # a split pair covers every cell that reports the metric its half moves
+    for stem in ("loop_ms_per_step", "idle_explained_pct"):
+        thr = next(e for e in ENTRIES if e["name"] == stem + ".thr")
+        moved = next(m for m in BENCHMARK["end_to_end"]
+                     if m["name"] == thr["moves"])
+        assert sorted(thr["workloads"]) == sorted(moved["workloads"])
 
 
-def test_a_reader_with_nothing_to_read_fails_the_whole_line_today(tmp_path):
-    """Why the entries wait: run.py lists a metric's unit before it reads
-    it, and the last line must hold exactly the listed metrics — so over a
-    program without the loop fields (the parent commit, in the driver's
-    traced run) the new metrics do not drop out, the run fails. The edit
-    that lifts this is run.py's (PERF.md §7)."""
-    path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(with_entries()))
-    cell = spec.load_cell(CHAT, str(path))
+def test_a_reader_with_nothing_to_read_fails_the_whole_line_today():
+    """The rule, and no longer why entries wait: run.py lists a metric's
+    unit before it reads it, and the last line must hold exactly the listed
+    metrics — so over a program without the loop fields the metrics do not
+    drop out, the run fails. Whether a traced line may omit a listed metric
+    is the contract's to say."""
+    cell = spec.load_cell(CHAT)
     mine = [m for m in cell.metrics_of("per_layer")
             if m.name.startswith("loop_ms_per_step")]
     cell = spec.Cell(cell.name, cell.chips, cell.config, cell.config_file,
@@ -202,24 +194,18 @@ def test_a_gap_across_three_mq_spans_still_goes_to_the_enclosing_frame():
 
 
 # ------------------------------------------------------------- end to end
-def test_rehearsal_with_the_entries_prints_the_four_new_chat_metrics(
-        tmp_path):
-    """A checkout that differs from this one only by BENCHMARK.json with
-    the entries appended (everything else a link): `--rehearse-cpu --trace
-    1` on the chat cell reads all four new metrics from the served
-    program's samples and counters, and the last line validates."""
-    for name in ("benchmarks", "ollamamq_tpu", "cpp"):
-        os.symlink(os.path.join(ROOT, name), tmp_path / name)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(with_entries()))
+def test_rehearsal_with_the_entries_prints_the_four_new_chat_metrics():
+    """`--rehearse-cpu --trace 1` on the chat cell reads all four from the
+    served program's samples and counters, and the last line validates."""
     r = subprocess.run(
-        [sys.executable, str(tmp_path / "benchmarks" / "run.py"),
+        [sys.executable, os.path.join(BENCH, "run.py"),
          "--workload", CHAT, "--seed", "2147483999", "--seconds", "4",
-         "--trace", "1", "--rehearse-cpu"], cwd=tmp_path,
+         "--trace", "1", "--rehearse-cpu"], cwd=ROOT,
         capture_output=True, text=True, timeout=600,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stderr[-2000:]
     line = json.loads(r.stdout.strip().splitlines()[-1])
-    cell = spec.load_cell(CHAT, str(tmp_path / "BENCHMARK.json"))
+    cell = spec.load_cell(CHAT)
     result.validate(line, {m.name: m.unit
                            for m in cell.metrics_of("per_layer")}, True)
     got = line["metrics"]
