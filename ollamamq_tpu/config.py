@@ -4,7 +4,9 @@ Model architecture configs for the families the framework serves natively:
 Llama 3.x (incl. llama3.2:1b and Llama-3-8B), Qwen2.5 (attention bias),
 Qwen3 (per-head q/k norm), the sparse families Mixtral (top-2 of 8,
 renormalised) and OLMoE (top-8 of 64, not renormalised, MHA, whole-vector
-q/k norm), plus a bidirectional encoder config for embedding models
+q/k norm) and the hybrid LFM2 (gated short convolutions beside attention,
+a dense prefix, a sigmoid router with a selection bias), plus a
+bidirectional encoder config for embedding models
 (nomic-embed-text class). The dense names are the ones the reference's
 stress test exercises (/root/reference/test_dispatcher.sh:5-7) and
 BASELINE.json's configs list.
@@ -16,9 +18,25 @@ import dataclasses
 from typing import Optional
 
 
+# A layer's operator (the published `layer_types` spellings) and its FFN.
+ATTENTION, CONV = "full_attention", "conv"
+LAYER_KINDS = (ATTENTION, CONV)
+DENSE, EXPERTS = "dense", "experts"
+# The longest period `ModelConfig.layer_plan` looks for.
+MAX_PERIOD = 8
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Decoder-only transformer architecture description (Llama/Qwen family)."""
+    """Decoder-only architecture description. A stack of `num_layers`
+    blocks `x + Op(norm(x))`, `x + FFN(norm(x))`: by default every block is
+    the same (Llama / Qwen / Mixtral / OLMoE: attention, then a dense SwiGLU
+    or routed experts). `layer_types` makes the stack one whose layers
+    differ: each layer's operator is attention or a gated short convolution
+    with a per-sequence state (LFM2), the first `num_dense_layers` of a
+    sparse stack keep a dense FFN, and the router's score, selection bias,
+    normalisation and scale are fields (`layer_plan()` is what the forwards
+    scan)."""
 
     name: str
     vocab_size: int
@@ -53,12 +71,109 @@ class ModelConfig:
     # to 1. False is the published default of the OLMoE/Qwen-MoE configs
     # (the key is `norm_topk_prob` there); Mixtral always renormalises.
     norm_topk_prob: bool = False
+    # ...and what is added to their sum before the division (LFM2: 1e-6).
+    norm_topk_eps: float = 0.0
+    # The router's score over all experts, in float32: "softmax" (Mixtral,
+    # OLMoE) or "sigmoid" (LFM2: each expert scored on its own).
+    router_score: str = "softmax"
+    # A per-expert bias (a buffer of the checkpoint, `router_bias`) added
+    # to the scores for the SELECTION of the top k only: the weights are
+    # the unbiased scores at the chosen experts.
+    use_expert_bias: bool = False
+    # The routed experts' output is multiplied by this.
+    routed_scaling_factor: float = 1.0
+    # The first `num_dense_layers` layers of a sparse stack keep a dense
+    # SwiGLU of width `intermediate_size`; an expert is
+    # `moe_intermediate_size` wide (0: `intermediate_size`, as OLMoE's).
+    num_dense_layers: int = 0
+    moe_intermediate_size: int = 0
+    # One operator kind a layer (LAYER_KINDS), or None: attention in every
+    # layer. "conv" is LFM2's gated short convolution: [B | C | u] = h W_in,
+    # z = B * u, a depthwise causal convolution of z over `conv_L_cache`
+    # positions, times C, W_out. Its whole state is z at the sequence's
+    # last `conv_L_cache - 1` positions: no pages.
+    layer_types: Optional[tuple] = None
+    conv_L_cache: int = 3
+    conv_bias: bool = False
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head", "full"):
             raise ValueError(
                 f"{self.name}: qk_norm must be false, true, 'head' or "
                 f"'full', got {self.qk_norm!r}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"{self.name}: router_score must be 'softmax' or 'sigmoid', "
+                f"got {self.router_score!r}")
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)  # a file's list: hashable
+            object.__setattr__(self, "layer_types", kinds)
+            if len(kinds) != self.num_layers:
+                raise ValueError(
+                    f"{self.name}: layer_types names {len(kinds)} layers, "
+                    f"num_layers is {self.num_layers}")
+            unknown = sorted(set(kinds) - set(LAYER_KINDS))
+            if unknown:
+                raise ValueError(
+                    f"{self.name}: layer_types holds {unknown}; the program "
+                    f"runs {list(LAYER_KINDS)}")
+            if self.is_encoder and CONV in kinds:
+                raise ValueError(
+                    f"{self.name}: layer_types: a causal convolution in an "
+                    "encoder")
+        if not 0 <= self.num_dense_layers <= self.num_layers:
+            raise ValueError(
+                f"{self.name}: num_dense_layers {self.num_dense_layers} is "
+                f"not within the stack's {self.num_layers} layers")
+        if self.conv_L_cache < 2:
+            raise ValueError(
+                f"{self.name}: conv_L_cache must be at least 2, got "
+                f"{self.conv_L_cache}")
+        if self.conv_bias:
+            raise ValueError(
+                f"{self.name}: conv_bias true: the program's convolution "
+                "layers carry no bias")
+
+    # -- the stack, layer by layer ------------------------------------------
+    @property
+    def kinds(self) -> tuple:
+        """Each layer's (operator, FFN): (ATTENTION | CONV, DENSE | EXPERTS)."""
+        ops = self.layer_types or (ATTENTION,) * self.num_layers
+        first_sparse = self.num_dense_layers if self.num_experts \
+            else self.num_layers
+        return tuple((op, DENSE if i < first_sparse else EXPERTS)
+                     for i, op in enumerate(ops))
+
+    def count(self, kind: str) -> int:
+        """Layers whose operator or FFN is `kind`."""
+        return sum(kind in pair for pair in self.kinds)
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    def layer_plan(self) -> tuple:
+        """The stack as runs of a repeated period: ((first layer, period,
+        repeats), ...) with `period` a tuple of layer kinds. The forwards
+        scan each run (`models/llama.py:scan_layers`) with the period's
+        layers unrolled in the scan's body, so a program traces each
+        DISTINCT layer of a period once, whatever the depth. Greedy: at
+        each layer the (period <= MAX_PERIOD, repeats >= 2) that covers
+        the most layers, the shortest period among equals; a layer that
+        starts no repetition is a run of its own. A uniform stack is one
+        run of period 1."""
+        kinds, runs, i = self.kinds, [], 0
+        while i < len(kinds):
+            best = (1, 1)  # (period, repeats)
+            for p in range(1, min(MAX_PERIOD, (len(kinds) - i) // 2) + 1):
+                r = 1
+                while kinds[i + r * p: i + (r + 1) * p] == kinds[i: i + p]:
+                    r += 1
+                if r > 1 and p * r > best[0] * best[1]:
+                    best = (p, r)
+            runs.append((i, kinds[i: i + best[0]], best[1]))
+            i += best[0] * best[1]
+        return tuple(runs)
 
     @property
     def qk_norm_kind(self) -> Optional[str]:
@@ -75,20 +190,27 @@ class ModelConfig:
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
-    def param_count(self) -> int:
-        """Approximate parameter count (for HBM budgeting)."""
-        d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
-        mlp = 3 * d * f
-        if self.num_experts:
-            mlp = self.num_experts * 3 * d * f + d * self.num_experts
-        per_layer = (
-            d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d  # attn
-            + mlp
-            + 2 * d  # norms
-            + self.qk_norm_params()
-        )
+    def param_count(self, active: bool = False) -> int:
+        """Approximate parameter count (for HBM budgeting); with `active`,
+        the parameters a token touches (the routed experts of the k, not
+        the bank: what the FLOPs model counts)."""
+        d, v = self.hidden_size, self.vocab_size
+        per_op = {
+            ATTENTION: (d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+                        + self.qk_norm_params()),
+            CONV: 3 * d * d + d * d + d * self.conv_L_cache,
+        }
+        n_experts = self.num_experts_per_tok if active else self.num_experts
+        per_ffn = {
+            DENSE: 3 * d * self.intermediate_size,
+            EXPERTS: (n_experts * 3 * d * self.expert_width
+                      + d * self.num_experts
+                      + self.num_experts * self.use_expert_bias),
+        }
+        layers = sum(per_op[op] + per_ffn[ffn] + 2 * d
+                     for op, ffn in self.kinds)
         embed = v * d * (1 if self.tie_embeddings else 2)
-        return self.num_layers * per_layer + embed + d
+        return layers + embed + d
 
     def qk_norm_params(self) -> int:
         """q/k norm weights of one layer."""
@@ -203,6 +325,38 @@ MODEL_CONFIGS = {
         intermediate_size=32, num_layers=2, num_heads=4, num_kv_heads=4,
         head_dim=16, rope_theta=10_000.0, max_seq_len=512, qk_norm="full",
         num_experts=16, num_experts_per_tok=4,
+    ),
+    # LFM2 family (LiquidAI/LFM2-8B-A1B config.json): a stack whose layers
+    # differ. 18 gated short convolutions (window 3, a per-sequence state
+    # of 2 x hidden values a layer) and 6 GQA attention layers with
+    # per-head q/k norm; a dense SwiGLU in the first 2 layers, then 32
+    # experts of width 1792, top 4 by sigmoid score plus a selection bias,
+    # weights renormalised over (sum + 1e-6); head tied to the embedding.
+    "lfm2:8b-a1b": ModelConfig(
+        name="lfm2:8b-a1b", vocab_size=65_536, hidden_size=2048,
+        intermediate_size=7168, num_layers=24, num_heads=32, num_kv_heads=8,
+        head_dim=64, rope_theta=1_000_000.0, rms_norm_eps=1e-5,
+        max_seq_len=128_000, tie_embeddings=True, qk_norm="head",
+        num_experts=32, num_experts_per_tok=4, norm_topk_prob=True,
+        norm_topk_eps=1e-6, router_score="sigmoid", use_expert_bias=True,
+        routed_scaling_factor=1.0, num_dense_layers=2,
+        moe_intermediate_size=1792, conv_L_cache=3,
+        layer_types=("conv", "conv") + ("full_attention", "conv", "conv",
+                                        "conv") * 4
+        + ("full_attention", "conv", "conv") * 2,
+    ),
+    # Tiny LFM2: a dense prefix, a repeated period AND an irregular tail
+    # (three runs in layer_plan()), an expert width of its own.
+    "test-tiny-lfm2": ModelConfig(
+        name="test-tiny-lfm2", vocab_size=512, hidden_size=64,
+        intermediate_size=96, num_layers=9, num_heads=4, num_kv_heads=2,
+        head_dim=16, rope_theta=10_000.0, max_seq_len=512,
+        tie_embeddings=True, qk_norm="head", num_experts=8,
+        num_experts_per_tok=2, norm_topk_prob=True, norm_topk_eps=1e-6,
+        router_score="sigmoid", use_expert_bias=True, num_dense_layers=2,
+        moe_intermediate_size=32, conv_L_cache=3,
+        layer_types=("conv", "conv") + ("full_attention", "conv") * 2
+        + ("conv", "full_attention", "conv"),
     ),
 }
 
@@ -671,6 +825,32 @@ def validate_tiers(spec: Optional[str], members) -> Optional[str]:
     return None
 
 
+def validate_conv_state(cfg: ModelConfig, spec: bool = False,
+                        mesh_shape=None) -> Optional[str]:
+    """What a model with conv layers cannot be served with yet, told
+    BEFORE any device work: returns an error string (None = valid). Each
+    of these touches per-sequence state and knows only the paged KV pool;
+    run on such a model it would serve K and V without the conv layers'
+    state beside them (ROADMAP B-M5 names what each lacks)."""
+    if not cfg.count(CONV):
+        return None
+    shape = dict(mesh_shape or {})
+    why = None
+    if spec:
+        why = ("--spec: a rejected draft has already advanced the conv "
+               "state, and rollback restores pages only")
+    elif shape.get("seq", 1) > 1:
+        why = ("--sp: a convolution over a sequence sharded along T needs "
+               "a halo exchange the ring prefill does not make")
+    elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
+        why = ("--tp / --ep: the conv layers' weights and state have no "
+               "partition specs")
+    if why is None:
+        return None
+    return (f"model {cfg.name} has conv layers (layer_types) and cannot be "
+            f"served with {why}")
+
+
 def validate_quant_config(weights_dtype: str, kv_dtype: str,
                           sp: int = 1, model_names=()) -> Optional[str]:
     """Fail-fast validation of the quantization flags BEFORE any device
@@ -692,4 +872,7 @@ def validate_quant_config(weights_dtype: str, kv_dtype: str,
             if cfg is not None and cfg.num_experts:
                 return (f"--weights-dtype=int8 does not cover MoE expert "
                         f"stacks (model {name}); load it in bfloat16")
+            if cfg is not None and cfg.count(CONV):
+                return (f"--weights-dtype=int8 does not cover conv layers "
+                        f"(model {name}); load it in bfloat16")
     return None

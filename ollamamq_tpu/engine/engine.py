@@ -41,9 +41,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (EngineConfig, ModelConfig,
-                                 get_model_config, smart_match,
-                                 validate_quant_config)
+from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, EngineConfig,
+                                 ModelConfig, get_model_config, smart_match,
+                                 validate_conv_state, validate_quant_config)
 from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
@@ -52,6 +52,7 @@ from ollamamq_tpu.engine.request import FinishReason, Request, StreamItem
 from ollamamq_tpu.engine.scheduler import make_policy
 from ollamamq_tpu.engine.tokenizer import load_tokenizer
 from ollamamq_tpu.models import llama, moe, weights
+from ollamamq_tpu.ops import shortconv
 from ollamamq_tpu.ops.sampling import (accept_prefix, maybe_apply_penalties,
                                        per_row_keys, sample_tokens_rowwise,
                                        sampling_flags)
@@ -512,6 +513,11 @@ class ModelRuntime:
             sp=_sp_probe, model_names=(name,))
         if err is not None:
             raise ValueError(err)
+        err = validate_conv_state(
+            model_cfg, spec=engine_cfg.spec,
+            mesh_shape=dict(mesh.shape) if mesh is not None else {})
+        if err is not None:
+            raise ValueError(err)
         self.weights_dtype = engine_cfg.weights_dtype
         self.kv_dtype = engine_cfg.kv_dtype
         if mesh is not None and mesh.shape.get("tensor", 1) > 1:
@@ -575,6 +581,14 @@ class ModelRuntime:
         # right before can be unsettled at a launch (the loop's depth is
         # one), so one step's rows are all the carry ever has to hold.
         self.last_ids = jnp.zeros((engine_cfg.max_slots,), jnp.int32)
+        # The conv layers' per-slot state (ops/shortconv.py: fixed size, no
+        # pages; None for a model without such layers): with kc, vc,
+        # recent and last_ids a donated argument and result of every step
+        # program. Never reset from the host: a request's first span opens
+        # its slot's rows at zero inside the program (`is_first`).
+        self.conv = shortconv.alloc_state(
+            model_cfg.count(CONV), engine_cfg.max_slots,
+            model_cfg.conv_L_cache, model_cfg.hidden_size, dtype)
         self.alloc = kvc.PageAllocator(
             engine_cfg.num_pages, engine_cfg.page_size, engine_cfg.max_pages_per_seq
         )
@@ -583,7 +597,15 @@ class ModelRuntime:
         # the primary's admission path ever walks it — the page tables it
         # produces already broadcast on the op wire.
         self.prefix_cache = None
-        if engine_cfg.prefix_cache:
+        if engine_cfg.prefix_cache and self.conv is not None:
+            # A cached page holds K and V of its tokens, not the conv
+            # layers' state at its boundary: a hit would resume a
+            # sequence whose convolutions start from nothing. Until a
+            # page can carry a state snapshot, such a model has no
+            # prefix cache (a preempted request replays from token 0).
+            log.warning("%s: prefix cache off: its conv layers' state is "
+                        "not cached with the pages", name)
+        elif engine_cfg.prefix_cache:
             from ollamamq_tpu.engine.prefix_cache import PrefixCache
 
             self.prefix_cache = PrefixCache(
@@ -761,6 +783,17 @@ class ModelRuntime:
         # this runtime — the quantization PR's before/after lever.
         tm.HBM_WEIGHT_BYTES.labels(model=name).set(self.param_bytes)
         tm.HBM_KV_BYTES.labels(model=name).set(self.kv_bytes)
+        # What a deployment is sized by: the fixed per-slot state, and
+        # what each token of context adds to the pool.
+        self.conv_state_bytes = (
+            0 if self.conv is None
+            else self.conv.size * self.conv.dtype.itemsize)
+        tm.HBM_CONV_STATE_BYTES.labels(model=name).set(self.conv_state_bytes)
+        tm.KV_BYTES_PER_TOKEN.labels(model=name).set(
+            kvc.kv_page_bytes(model_cfg, 1, jnp.dtype(dtype).itemsize,
+                              engine_cfg.kv_dtype))
+        self._tm_conv_resets = tm.CONV_STATE_RESETS_TOTAL.labels(model=name)
+        self._tm_conv_carried = tm.CONV_STATE_CARRIED_TOTAL.labels(model=name)
 
     # -- capacity ----------------------------------------------------------
     def free_slots(self) -> int:
@@ -863,7 +896,8 @@ class ModelRuntime:
     # -- dispatch seams (SPMD subclass broadcasts before dispatching) ------
     # Each returns (sampled_tokens, kc', vc', recent'); the caller assigns
     # the three state arrays back. The two step programs of the pipelined
-    # loop (ragged, decode) also take and return the `last_ids` carry.
+    # loop (ragged, decode) also take and return the `last_ids` carry and
+    # the conv layers' state (None for a model without them).
     def _dispatch_ragged(self, T_pad, k_cap, buf):
         """`buf`: the step's packed host inputs (step_pack.ragged_layout)."""
         # Speculative dispatches get their own fault site: a chaos plan
@@ -874,7 +908,7 @@ class ModelRuntime:
         fn = self._get_ragged_jit(
             T_pad, k_cap, sampling_flags(*lay.sampling(buf)))
         return fn(self.params, self._upload(buf), self.kc, self.vc,
-                  self.recent, self.last_ids)
+                  self.recent, self.last_ids, self.conv)
 
     def _ragged_layout(self, T_pad: int) -> step_pack.StepLayout:
         e = self.ecfg
@@ -909,8 +943,10 @@ class ModelRuntime:
         before this one sampled", read from the `last_ids` carry (that
         step may still be running; the host has not seen the id).
         Returns (toks [S, k_cap+1], n_emit [S], caches', recent',
-        last_ids'): row i emits toks[i, :n_emit[i]], and the carry is
-        now this step's last id of every row. An MoE model's `toks` has three more
+        last_ids', conv'): row i emits toks[i, :n_emit[i]], the carry is
+        now this step's last id of every row, and each row's slot of the
+        conv state holds its span's last positions (opened at zero where
+        the span is its request's first: models/llama.py:forward_ragged). An MoE model's `toks` has three more
         rows: the pass's expert-load counters (moe.LOAD_STATS) ride back
         with the ids, in the transfer the collect makes anyway."""
         key_ = ("ragged", T_pad, k_cap, flags)
@@ -923,7 +959,7 @@ class ModelRuntime:
 
             lay = self._ragged_layout(T_pad)
 
-            def mq_ragged_step(params, buf, kc, vc, recent, last_ids):
+            def mq_ragged_step(params, buf, kc, vc, recent, last_ids, conv):
                 (tokens, tok_seq, tok_pos, write_slots, q_start, q_len,
                  kv_len, ring_len, is_first, append, is_spec, seed_rows,
                  slot_ids, pt, temp, tk, tp, pen, pres, freq, seeds,
@@ -944,12 +980,16 @@ class ModelRuntime:
                                 jnp.minimum(j, q_len[:, None] - 1),
                                 q_len[:, None] - 1)
                 out_idx = jnp.clip(q_start[:, None] + col, 0, T_pad - 1)
-                logits, kc, vc, *load = llama.forward_ragged(
+                logits, kc, vc, *rest = llama.forward_ragged(
                     params, cfg, tokens, tok_seq, tok_pos, write_slots,
                     out_idx, kc, vc, pt, q_start, q_len, kv_len, ps,
                     attn_impl=attn_impl, mesh=mesh,
-                    moe_load=bool(cfg.num_experts),
+                    moe_load=bool(cfg.num_experts), conv_state=conv,
+                    slot_ids=slot_ids, is_first=is_first,
                 )  # [S, O, V]
+                if conv is not None:
+                    conv, *rest = rest
+                load = rest
                 greedy_all = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 last_logits = logits[:, -1, :]
                 if k_cap > 0:
@@ -1022,11 +1062,11 @@ class ModelRuntime:
                 if load:
                     toks = jnp.concatenate([toks, jnp.broadcast_to(
                         moe.load_stats(load[0])[:, None], (3, O))])
-                return toks, n_emit, kc, vc, recent, tok
+                return toks, n_emit, kc, vc, recent, tok, conv
 
             _sp_note_compile(self, "ragged", key_, self._prefill_jits,
                              jax.jit(mq_ragged_step,
-                                     donate_argnums=(2, 3, 4, 5)))
+                                     donate_argnums=(2, 3, 4, 5, 6)))
         return self._prefill_jits[key_]
 
     def _note_moe_load(self, _sp, stats: np.ndarray) -> None:
@@ -1037,7 +1077,7 @@ class ModelRuntime:
         cfg = self.cfg
         n, hit, top = (int(stats[:, 0].sum()), int(stats[:, 1].sum()),
                        int(stats[:, 2].max()))
-        mean = n / (len(stats) * cfg.num_layers * cfg.num_experts)
+        mean = n / (len(stats) * cfg.count(EXPERTS) * cfg.num_experts)
         _sp.note(moe_assignments=n, moe_pairs_hit=hit, moe_load_max=top,
                  moe_load_mean=round(mean, 4))
         self._tm_moe_assign.inc(n)
@@ -1045,13 +1085,25 @@ class ModelRuntime:
         self._tm_moe_max.set(top)
         self._tm_moe_mean.set(mean)
 
+    def _note_conv_state(self, _sp, resets: int, carried: int) -> None:
+        """A launched step's use of the conv layers' state, onto its
+        sample and the /metrics series: rows whose slot it opened at zero
+        (a request's first span) and rows that read the state an earlier
+        step left (a later chunk of a prompt, a decode row; a fused scan's
+        active slots). Nothing for a model without conv layers."""
+        if self.conv is None:
+            return
+        _sp.note(conv_state_resets=resets, conv_state_carried=carried)
+        self._tm_conv_resets.inc(resets)
+        self._tm_conv_carried.inc(carried)
+
     def _dispatch_decode(self, k_steps, buf):
         """`buf`: the scan's packed host inputs (step_pack.decode_layout)."""
         self._fault("decode")
         fn = self._get_decode_jit(
             k_steps, sampling_flags(*self._decode_layout().sampling(buf)))
         return fn(self.params, self._upload(buf), self.kc, self.vc,
-                  self.recent, self.last_ids)
+                  self.recent, self.last_ids, self.conv)
 
     def _dispatch_prefill_sp(self, T, buf):
         """`buf`: the prompt's packed host inputs (step_pack.sp_layout)."""
@@ -1177,7 +1229,7 @@ class ModelRuntime:
 
             lay = self._decode_layout()
 
-            def mq_decode_scan(params, buf, kc, vc, recent, last_ids):
+            def mq_decode_scan(params, buf, kc, vc, recent, last_ids, conv):
                 (tokens, positions, active, pt, temp, tk, tp, pen, pres,
                  freq, seeds, rng) = lay.unpack(buf)
                 key = jax.random.PRNGKey(rng[0])
@@ -1189,12 +1241,15 @@ class ModelRuntime:
                     tokens)
 
                 def step(carry, _):
-                    tokens, positions, kc, vc, recent, key = carry
-                    logits, kc, vc, *load = llama.forward_decode(
+                    tokens, positions, kc, vc, recent, key, conv = carry
+                    logits, kc, vc, *rest = llama.forward_decode(
                         params, cfg, tokens, positions, kc, vc, pt, ps,
                         attn_impl=attn_impl, active=active, mesh=mesh,
-                        moe_load=bool(cfg.num_experts),
+                        moe_load=bool(cfg.num_experts), conv_state=conv,
                     )
+                    if conv is not None:
+                        conv, *rest = rest
+                    load = rest
                     key, sub = jax.random.split(key)
                     pen_logits = maybe_apply_penalties(logits, recent[:S],
                                                        pen, pres, freq,
@@ -1219,19 +1274,20 @@ class ModelRuntime:
                     out = nxt
                     if load:  # the pass's counters, behind the ids
                         out = jnp.concatenate([nxt, moe.load_stats(load[0])])
-                    return (nxt, positions + 1, kc, vc, recent, key), out
+                    return (nxt, positions + 1, kc, vc, recent, key,
+                            conv), out
 
-                (tokens, positions, kc, vc, recent, key), toks = jax.lax.scan(
-                    step, (tokens, positions, kc, vc, recent, key), None,
-                    length=k_steps,
-                )
+                (tokens, positions, kc, vc, recent, key, conv), toks = \
+                    jax.lax.scan(
+                        step, (tokens, positions, kc, vc, recent, key, conv),
+                        None, length=k_steps)
                 # toks: [K, S] (MoE: [K, S+3]); the carry: each slot's
                 # last id (a scan's rows are the slots).
-                return toks, kc, vc, recent, tokens
+                return toks, kc, vc, recent, tokens, conv
 
             _sp_note_compile(self, "decode", key_, self._decode_jits,
                              jax.jit(mq_decode_scan,
-                                     donate_argnums=(2, 3, 4, 5)))
+                                     donate_argnums=(2, 3, 4, 5, 6)))
         return self._decode_jits[key_]
 
     # -- slot lifecycle ----------------------------------------------------
@@ -1509,6 +1565,11 @@ class ModelRuntime:
         recompute — only written decode state is worth shipping). The
         detached slot keeps its pages (reserved, undispatchable) until
         release_export resolves the two-phase handoff."""
+        if self.conv is not None:
+            # The blob has no place for the conv layers' state, and pages
+            # without it resume another sequence: not exportable (the
+            # caller's fallback replays the request from its tokens).
+            return None
         for slot, req in enumerate(self.slot_req):
             if req is not None and req.req_id == rid:
                 break
@@ -1532,7 +1593,7 @@ class ModelRuntime:
         blob = {
             "version": 1, "kind": "stream", "model": self.name,
             "kv_dtype": self.kv_dtype, "page_size": self.ecfg.page_size,
-            "num_layers": self.cfg.num_layers,
+            "num_layers": self.cfg.count(ATTENTION),
             "num_kv_heads": self.cfg.num_kv_heads,
             "head_dim": self.cfg.head_dim,
             "kv_len": int(self.seq_lens[slot]),
@@ -1567,11 +1628,17 @@ class ModelRuntime:
         into this pool, and resume the decode cursor exactly where the
         source froze it — no token is ever recomputed. False when the
         blob's shape doesn't match this runtime or capacity is gone
-        (the caller falls back to recompute replay)."""
+        (the caller falls back to recompute replay). A model with conv
+        layers refuses every blob: pages come without its state."""
+        if self.conv is not None:
+            raise MigrationError(
+                f"{self.name}: a migrated stream carries KV pages, not the "
+                "conv layers' state; replay the request instead")
         if (blob.get("kind") != "stream"
                 or int(blob.get("page_size", -1)) != self.ecfg.page_size
                 or blob.get("kv_dtype") != self.kv_dtype
-                or int(blob.get("num_layers", -1)) != self.cfg.num_layers
+                or int(blob.get("num_layers", -1))
+                != self.cfg.count(ATTENTION)
                 or int(blob.get("num_kv_heads", -1)) != self.cfg.num_kv_heads
                 or int(blob.get("head_dim", -1)) != self.cfg.head_dim):
             return False
@@ -1633,7 +1700,7 @@ class ModelRuntime:
         return {
             "version": 1, "kind": "prefix", "model": self.name,
             "kv_dtype": self.kv_dtype, "page_size": ps,
-            "num_layers": self.cfg.num_layers,
+            "num_layers": self.cfg.count(ATTENTION),
             "num_kv_heads": self.cfg.num_kv_heads,
             "head_dim": self.cfg.head_dim,
             "n_pages": len(pages),
@@ -1651,7 +1718,8 @@ class ModelRuntime:
         if (pc is None or blob.get("kind") != "prefix"
                 or int(blob.get("page_size", -1)) != self.ecfg.page_size
                 or blob.get("kv_dtype") != self.kv_dtype
-                or int(blob.get("num_layers", -1)) != self.cfg.num_layers
+                or int(blob.get("num_layers", -1))
+                != self.cfg.count(ATTENTION)
                 or int(blob.get("num_kv_heads", -1)) != self.cfg.num_kv_heads
                 or int(blob.get("head_dim", -1)) != self.cfg.head_dim):
             return 0
@@ -2454,7 +2522,8 @@ class ModelRuntime:
         self._h2d = [0, 0]
         try:
             h.toks_dev, h.n_emit_dev, self.kc, self.vc, self.recent, \
-                self.last_ids = self._dispatch_ragged(T_pad, k_cap, buf)
+                self.last_ids, self.conv = self._dispatch_ragged(
+                    T_pad, k_cap, buf)
         except Exception as e:
             # The step before is untouched by this failure: settle it
             # (its ids are good, and the replay below folds them in),
@@ -2463,6 +2532,8 @@ class ModelRuntime:
             self._jrec("batch", **batch_fields)
             self._ragged_failed(rows, e, core)
             return None
+        opened = int(is_first.sum())
+        self._note_conv_state(_sp, opened, len(rows) - opened)
         _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()
@@ -2661,8 +2732,9 @@ class ModelRuntime:
                          [True] * len(active),
                          float(np.mean(self.seq_lens[active])))
         self._h2d = [0, 0]
-        h.toks_dev, self.kc, self.vc, self.recent, self.last_ids = \
-            self._dispatch_decode(k_steps, buf)
+        h.toks_dev, self.kc, self.vc, self.recent, self.last_ids, \
+            self.conv = self._dispatch_decode(k_steps, buf)
+        self._note_conv_state(_sp, 0, len(active))
         _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()

@@ -1,8 +1,10 @@
 """Paged KV cache: device slot pool + host-side page allocator.
 
-Device side: two arrays per model, [num_layers, num_pages*page_size,
-kv_heads*head_dim] for K and V (an int8 pool adds [num_layers, slots,
-kv_heads] scale planes), the last axis split by kv head over the "tensor"
+Device side: two arrays per model, [attention layers, num_pages*page_size,
+kv_heads*head_dim] for K and V (an int8 pool adds [attention layers, slots,
+kv_heads] scale planes; a stack whose `layer_types` holds other operators
+has fewer attention layers than layers, and their state is not pages:
+ops/shortconv.py), the last axis split by kv head over the "tensor"
 mesh axis. That is the layout the attention kernels DMA pages from
 (ops/pallas/): a page of layer l is `pool[l, page*page_size : (page+1)*
 page_size]`, [page_size, Hk*hd] rows, and nothing ever reshapes the pool.
@@ -33,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ollamamq_tpu.config import EngineConfig, ModelConfig
+from ollamamq_tpu.config import ATTENTION, EngineConfig, ModelConfig
 
 TRASH_PAGE = 0
 
@@ -159,7 +161,7 @@ def alloc_kv_pool(
     from ollamamq_tpu.ops.quant import QuantKV
 
     S = engine_cfg.num_pages * engine_cfg.page_size
-    shape = (model_cfg.num_layers, S,
+    shape = (model_cfg.count(ATTENTION), S,
              model_cfg.num_kv_heads * model_cfg.head_dim)
 
     def filled(value, shp, dt):
@@ -184,7 +186,7 @@ def kv_pool_bytes(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                     else model_cfg.head_dim * bytes_per_el)
     return (
         2
-        * model_cfg.num_layers
+        * model_cfg.count(ATTENTION)
         * engine_cfg.num_pages
         * engine_cfg.page_size
         * model_cfg.num_kv_heads
@@ -325,9 +327,9 @@ def unpack_migration_blob(raw: bytes) -> dict:
 
 def kv_page_bytes(model_cfg: ModelConfig, page_size: int,
                   bytes_per_el=2, kv_dtype: str = "bfloat16") -> int:
-    """Bytes ONE page costs (K and V, all layers) — the density math's
+    """Bytes ONE page costs (K and V, all attention layers) — the density math's
     unit: equal-HBM pool sizing divides a byte budget by this."""
     per_tok_head = (model_cfg.head_dim + 4 if kv_dtype == "int8"
                     else model_cfg.head_dim * bytes_per_el)
-    return (2 * model_cfg.num_layers * page_size
+    return (2 * model_cfg.count(ATTENTION) * page_size
             * model_cfg.num_kv_heads * per_tok_head)

@@ -1,38 +1,56 @@
-"""Llama/Qwen-family decoder in pure functional JAX.
+"""Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2) in pure
+functional JAX.
+
+A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))`. Op is attention
+(`_attention_op`) or a gated short convolution (`_conv_op`); FFN is a dense
+SwiGLU (`_mlp`) or routed experts (models/moe.py). Each is defined ONCE and
+used by every forward; `ModelConfig.kinds` says which pair a layer is. A
+uniform stack (every family but LFM2) is the case of one kind.
 
 Design notes (TPU-first):
-  - Layer parameters are STACKED along a leading `num_layers` axis and the
-    forward is a `lax.scan` over layers — one traced layer body, fast XLA
-    compile. The KV pool ([L, S, Hk*hd], the layout the kernels DMA from)
-    is the scan's CARRY, never its xs/ys (`scan_layers`): each layer
-    scatters the step's rows into `pool[l]` in place and attention reads
-    the whole pool by layer index, so a step moves the rows it writes and
-    the pages it reads — not the pool.
+  - Layer parameters are STACKED by kind — a weight's leading axis counts
+    the layers that HAVE it (`wq` the attention layers, `conv_in` the conv
+    layers, `w_gate` the dense-FFN layers, `we_*` the expert layers; the
+    two norms every layer) — and the forward is a `lax.scan` over each run
+    of `ModelConfig.layer_plan()`: a repeated period whose layers are
+    unrolled in the scan's body, each reading its weights from the stacks
+    by index (`scan_layers`). One traced body a DISTINCT layer of a
+    period, whatever the depth: fast XLA compile. The per-sequence state —
+    the KV pool ([attention layers, S, Hk*hd], the layout the kernels DMA
+    from) and the conv layers' state ([conv layers, slots + 1, K-1, D],
+    ops/shortconv.py) — is the scan's CARRY, never its xs/ys: a layer
+    scatters the step's rows into `pool[a]` / `conv[c]` in place and
+    attention reads the whole pool by layer index, so a step moves the
+    rows it writes and the pages it reads — not the pool.
   - Served: `forward_ragged` (one flattened stream of prefill spans and
     decode tokens over the paged pool), `forward_decode` (one token per
     slot: the fused scan's body), `forward_prefill_sp`, `forward_embed`
     and the encoder; all shape-static => one jit per padded shape.
-    `forward_prefill` (whole prompts, dense causal attention) is the
-    plain oracle that tests, the benchmark's reference and the dry run
-    compare them with; the engine does not call it.
-  - All matmuls run in the params dtype (bf16 on TPU => MXU), softmax and
-    logits in f32.
+    `forward_prefill` (whole prompts, dense causal attention, the
+    convolution over shifted copies: no state) is the plain oracle that
+    tests, the benchmark's reference and the dry run compare them with;
+    the engine does not call it.
+  - All matmuls run in the params dtype (bf16 on TPU => MXU), softmax,
+    the router, the convolution's sum and logits in f32.
   - Qwen2.5 support = `attn_bias=True` in ModelConfig; the same code path
     serves both families (capability parity with the reference's two
-    stress-test models, /root/reference/test_dispatcher.sh:5-7). Qwen3 and
-    OLMoE are `qk_norm` ("head" / "full"); Mixtral and OLMoE replace the
-    FFN by routed experts (`num_experts`, models/moe.py).
+    stress-test models, /root/reference/test_dispatcher.sh:5-7). Qwen3,
+    LFM2 and OLMoE are `qk_norm` ("head" / "full"); Mixtral, OLMoE and
+    LFM2 replace the FFN by routed experts (`num_experts`, models/moe.py),
+    LFM2 after a dense prefix (`num_dense_layers`).
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import ModelConfig
+from ollamamq_tpu.config import (ATTENTION, CONV, DENSE, EXPERTS,
+                                 ModelConfig)
 from ollamamq_tpu.models.moe import STACKED, init_moe_layer_params, moe_mlp
+from ollamamq_tpu.ops import shortconv
 from ollamamq_tpu.ops.attention import (
     causal_attention,
     bidirectional_attention,
@@ -49,6 +67,22 @@ from ollamamq_tpu.ops.rope import apply_rope
 # decode programs. An MoE model's "mlp" holds models/moe.py:SCOPES.
 SCOPES = ("embed", "attn_qkv", "kv_write", "attention", "attn_out", "mlp",
           "lm_head", "sampling")
+# ...and a conv layer's three stages, beside the attention layers' four:
+# the in-projection, the gated convolution with its state read and write,
+# the out-projection.
+CONV_SCOPES = ("conv_in", "conv_mix", "conv_out")
+# fold_in constant of the conv layers' init keys (the other weights' keys
+# are the ten of one split, as before the family existed).
+CONV_KEY = 0x636F6E76
+# The weights of each operator and FFN kind (stacked over the layers of
+# that kind); every other entry of `layers` is stacked over all layers.
+KIND_PARAMS = {
+    ATTENTION: ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm",
+                "k_norm"),
+    CONV: ("conv_in", "conv_w", "conv_out"),
+    DENSE: ("w_gate", "w_up", "w_down"),
+    EXPERTS: ("w_router", "router_bias") + STACKED,
+}
 
 
 def _adtype(params: dict):
@@ -65,41 +99,47 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
-    """Random-init a params pytree (layers stacked on axis 0)."""
+    """Random-init a params pytree (layers stacked by kind on axis 0)."""
     d, qd, kvd, f = cfg.hidden_size, cfg.q_dim, cfg.kv_dim, cfg.intermediate_size
     L, v = cfg.num_layers, cfg.vocab_size
+    La, Lc, Ld = cfg.count(ATTENTION), cfg.count(CONV), cfg.count(DENSE)
     keys = jax.random.split(key, 10)
 
     def w(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dtype)
 
-    layers = {
-        "attn_norm": jnp.ones((L, d), dtype),
-        "wq": w(keys[0], (L, d, qd), d),
-        "wk": w(keys[1], (L, d, kvd), d),
-        "wv": w(keys[2], (L, d, kvd), d),
-        "wo": w(keys[3], (L, qd, d), qd),
-        "mlp_norm": jnp.ones((L, d), dtype),
-        "w_gate": w(keys[4], (L, d, f), d),
-        "w_up": w(keys[5], (L, d, f), d),
-        "w_down": w(keys[6], (L, f, d), f),
-    }
-    if cfg.attn_bias:
-        layers["bq"] = jnp.zeros((L, qd), dtype)
-        layers["bk"] = jnp.zeros((L, kvd), dtype)
-        layers["bv"] = jnp.zeros((L, kvd), dtype)
-    if cfg.qk_norm_kind == "head":
-        # Qwen3: per-head RMSNorm on q/k (weight over head_dim).
-        layers["q_norm"] = jnp.ones((L, cfg.head_dim), dtype)
-        layers["k_norm"] = jnp.ones((L, cfg.head_dim), dtype)
-    elif cfg.qk_norm_kind == "full":
-        # OLMoE: RMSNorm over the whole projected q / k vector.
-        layers["q_norm"] = jnp.ones((L, qd), dtype)
-        layers["k_norm"] = jnp.ones((L, kvd), dtype)
-    if cfg.num_experts:
-        # MoE family: the dense FFN is replaced by routed experts.
-        for dense in ("w_gate", "w_up", "w_down"):
-            del layers[dense]
+    layers = {"attn_norm": jnp.ones((L, d), dtype),
+              "mlp_norm": jnp.ones((L, d), dtype)}
+    if La:
+        layers.update(
+            wq=w(keys[0], (La, d, qd), d), wk=w(keys[1], (La, d, kvd), d),
+            wv=w(keys[2], (La, d, kvd), d), wo=w(keys[3], (La, qd, d), qd))
+        if cfg.attn_bias:
+            layers["bq"] = jnp.zeros((La, qd), dtype)
+            layers["bk"] = jnp.zeros((La, kvd), dtype)
+            layers["bv"] = jnp.zeros((La, kvd), dtype)
+        if cfg.qk_norm_kind == "head":
+            # Qwen3, LFM2: per-head RMSNorm on q/k (weight over head_dim).
+            layers["q_norm"] = jnp.ones((La, cfg.head_dim), dtype)
+            layers["k_norm"] = jnp.ones((La, cfg.head_dim), dtype)
+        elif cfg.qk_norm_kind == "full":
+            # OLMoE: RMSNorm over the whole projected q / k vector.
+            layers["q_norm"] = jnp.ones((La, qd), dtype)
+            layers["k_norm"] = jnp.ones((La, kvd), dtype)
+    if Lc:
+        # Gated short convolution: in-projection to [B | C | u], the
+        # depthwise taps [D, K] (w[:, K-1] meets the token itself), and
+        # the out-projection.
+        ck = jax.random.split(jax.random.fold_in(key, CONV_KEY), 3)
+        layers.update(
+            conv_in=w(ck[0], (Lc, d, 3 * d), d),
+            conv_w=w(ck[1], (Lc, d, cfg.conv_L_cache), cfg.conv_L_cache),
+            conv_out=w(ck[2], (Lc, d, d), d))
+    if Ld:
+        layers.update(
+            w_gate=w(keys[4], (Ld, d, f), d), w_up=w(keys[5], (Ld, d, f), d),
+            w_down=w(keys[6], (Ld, f, d), f))
+    if cfg.count(EXPERTS):
         layers.update(init_moe_layer_params(cfg, keys[9], dtype))
     params = {
         "embed": w(keys[7], (v, d), d),
@@ -141,18 +181,19 @@ def _mlp(lp: dict, h: jnp.ndarray) -> jnp.ndarray:
     return qeinsum("btf,fd->btd", jax.nn.silu(gate) * up, lp["w_down"])
 
 
-def _ffn(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
+def _ffn(cfg: ModelConfig, lp: dict, ffn: str, h: jnp.ndarray, valid=None,
          mesh=None, impl: str = "jnp", layer=None):
-    """Dense SwiGLU or routed mixture-of-experts, by model family; returns
-    (delta, expert load [E] int32 — None for a dense model).
+    """Dense SwiGLU or routed mixture-of-experts, by the layer's FFN kind;
+    returns (delta, expert load [E] int32 — None for a dense layer).
 
     `valid` ([B, T] bool) marks real tokens, `mesh` is the caller's jit
     mesh and `impl` its `attn_impl`; only MoE routing consumes them
     (padding/inactive rows are routed to no expert; the grouped matmul
     runs under a shard_map, as a Pallas kernel or its XLA twin). `layer`:
-    inside `scan_layers`, where `lp` holds the expert stacks whole.
+    inside `scan_layers`, where `lp` holds the expert stacks whole — this
+    layer's index among the layers that have experts.
     """
-    if cfg.num_experts:
+    if ffn == EXPERTS:
         return moe_mlp(cfg, lp, h, valid=valid, mesh=mesh, impl=impl,
                        layer=layer)
     return _mlp(lp, h), None
@@ -165,66 +206,145 @@ def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return logits_head(x, head)
 
 
-def scan_layers(body, x, layers, k_cache, v_cache):
-    """The ONE layer loop of every forward that touches the KV pool
-    (prefill, ragged and decode).
+class LayerIx(NamedTuple):
+    """Where a layer of the scan stands among the layers of its operator's
+    kind (an attention layer's row of the KV pool, a conv layer's of the
+    conv state) and among those of its FFN's kind (an expert layer's block
+    of the expert stacks). int32 scalars, traced inside the scan."""
+    op: jnp.ndarray
+    ffn: jnp.ndarray
 
-    The pool is the loop's CARRY: `body(x, lp, l, k_cache, v_cache) ->
-    (x, k_cache, v_cache, per_layer)` gets the layer index `l`, writes
-    with one scatter on the carried pool (ops/quant.kv_write) and attends
-    over `pool[l]` by index, and the loop returns the buffers it was given
-    — with the jit sites' donation, XLA updates the pool in place. A pool
-    passed as a scan's xs and returned as its ys cannot alias: every pass
-    would build a second pool and copy each layer out and back.
-    `per_layer` (small: an MoE layer's expert load, else None) comes back
-    stacked [L, ...] as the fourth result. An MoE model's expert stacks
-    (moe.STACKED) are not sliced by the scan either: `lp` holds them
-    whole, for `_ffn(..., layer=l)` to read by index.
+
+def scan_layers(cfg: ModelConfig, body, x, layers, *state):
+    """The ONE layer loop of every forward.
+
+    `state` is the loop's CARRY beside `x` — the KV pool and the conv
+    state for the forwards that touch them, nothing for the others:
+    `body(x, lp, kinds, ix, *state) -> (x, *state, per_layer)` gets the
+    layer's (operator, FFN) kinds and its `LayerIx`, writes with one
+    scatter on the carried arrays (ops/quant.kv_write; the conv state's
+    rows) and attends over `pool[ix.op]` by index, and the loop returns the
+    buffers it was given — with the jit sites' donation, XLA updates them
+    in place. A pool passed as a scan's xs and returned as its ys cannot
+    alias: every pass would build a second pool and copy each layer out
+    and back.
+
+    One `lax.scan` a run of `cfg.layer_plan()`, over the run's repeats,
+    with the period's layers unrolled in the body. No weight is a scan's
+    xs: the stacks (by kind, `KIND_PARAMS`) stay whole and a layer reads
+    its slice by index — the same dynamic slice a scan makes of its xs,
+    but a run may start anywhere in a stack and stride through it. An MoE
+    model's expert stacks (moe.STACKED) are not sliced at all: `lp` holds
+    them whole, for `_ffn(..., layer=ix.ffn)` to read by index (a
+    kernel's operand cannot be a fused slice).
+
+    `per_layer` (small: an expert layer's load, else None) comes back as
+    the last result, [expert layers, E] (None for a dense stack).
     """
-    n_layers = k_cache.shape[0]
-    whole = {k: w for k, w in layers.items() if k in STACKED}
-    sliced = {k: w for k, w in layers.items() if k not in STACKED}
+    of_kind = {name: kind for kind, names in KIND_PARAMS.items()
+               for name in names}
+    seen = dict.fromkeys((ATTENTION, CONV, DENSE, EXPERTS), 0)
+    loads = []
+    for first, period, repeats in cfg.layer_plan():
+        per = {k: sum(k in pair for pair in period) for k in seen}
+        base = dict(seen)
 
-    def step(carry, per_layer):
-        x, kc, vc = carry
-        lp, l = per_layer
-        x, kc, vc, out = body(x, {**lp, **whole}, l, kc, vc)
-        return (x, kc, vc), out
+        def step(carry, r, first=first, period=period, per=per, base=base):
+            x, *state = carry
+            outs, n = [], dict.fromkeys(seen, 0)
+            for j, (op, ffn) in enumerate(period):
+                at = {None: first + r * len(period) + j,
+                      op: base[op] + r * per[op] + n[op],
+                      ffn: base[ffn] + r * per[ffn] + n[ffn]}
+                n[op] += 1
+                n[ffn] += 1
+                lp = {}
+                for name, stack in layers.items():
+                    kind = of_kind.get(name)
+                    if name in STACKED:
+                        lp[name] = stack
+                    elif kind in at:
+                        lp[name] = jax.tree_util.tree_map(
+                            lambda w, i=at[kind]:
+                            jax.lax.dynamic_index_in_dim(
+                                w, i, 0, keepdims=False), stack)
+                x, *state, out = body(
+                    x, lp, (op, ffn),
+                    LayerIx(at[op], at[ffn]), *state)
+                outs.append(out)
+            return (x, *state), outs
 
-    (x, k_cache, v_cache), outs = jax.lax.scan(
-        step, (x, k_cache, v_cache),
-        (sliced, jnp.arange(n_layers, dtype=jnp.int32)))
-    return x, k_cache, v_cache, outs
+        (x, *state), outs = jax.lax.scan(
+            step, (x, *state), jnp.arange(repeats, dtype=jnp.int32))
+        loads += [o for o in outs if o is not None]
+        for k in seen:
+            seen[k] += repeats * per[k]
+    load = None
+    if loads:
+        load = loads[0] if len(loads) == 1 else jnp.concatenate(loads)
+    return (x, *state, load)
 
 
-def _layer_step(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-                positions: jnp.ndarray, attn_fn, valid=None, mesh=None,
-                impl: str = "jnp", layer=None):
-    """One transformer layer over a full [B, T, D] sequence.
-
-    The SINGLE definition of the layer math for every full-sequence
-    forward (prefill, sequence-parallel prefill, encoder) — only the
-    attention schedule differs, injected as `attn_fn(q, k, v) -> [B,T,H,hd]`.
-    Returns (x', k, v, expert load) so callers can scatter K/V into the
-    paged cache; the load is None for a dense model (`_ffn`).
-    (forward_decode keeps its own body: it must write K/V into the
-    loop-carried pool BEFORE attending.)
-    """
-    B, T, _ = x.shape
+def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
+                  positions: jnp.ndarray, attn_fn) -> jnp.ndarray:
+    """Attention over normed hiddens h [B, T, D]: projections, q/k norm
+    and RoPE here; the schedule (and any write of k, v into a pool) is the
+    caller's `attn_fn(q, k, v) -> [B, T, H, hd]`."""
+    B, T, _ = h.shape
     with jax.named_scope("attn_qkv"):
-        h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(cfg, lp, h)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     attn = attn_fn(q, k, v)
     with jax.named_scope("attn_out"):
-        x = x + qeinsum("bte,ed->btd", attn.reshape(B, T, cfg.q_dim),
-                        lp["wo"])
+        return qeinsum("bte,ed->btd", attn.reshape(B, T, cfg.q_dim),
+                       lp["wo"])
+
+
+def _conv_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
+             taps_fn) -> jnp.ndarray:
+    """Gated short convolution over normed hiddens h [B, T, D]:
+    [B | C | u] = h W_in, z = B * u, c = the depthwise causal convolution
+    of z (ops/shortconv.short_conv), y = (C * c) W_out. Where each token's
+    predecessors come from (and any write of the state) is the caller's
+    `taps_fn(z) -> the K-1 predecessors of z, oldest first`."""
+    with jax.named_scope("conv_in"):
+        gate_b, gate_c, u = jnp.split(
+            qeinsum("btd,de->bte", h, lp["conv_in"]), 3, axis=-1)
+        z = gate_b * u
+    with jax.named_scope("conv_mix"):
+        c = shortconv.short_conv(lp["conv_w"], taps_fn(z), z)
+    with jax.named_scope("conv_out"):
+        return qeinsum("btd,de->bte", gate_c * c, lp["conv_out"])
+
+
+def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
+                x: jnp.ndarray, positions: jnp.ndarray, attn_fn,
+                taps_fn=None, valid=None, mesh=None, impl: str = "jnp",
+                layer=None):
+    """One layer over [B, T, D] hiddens: the SINGLE definition of the
+    layer math for every forward — full sequences, the ragged stream
+    ([1, T, D]) and the decode batch ([B, 1, D]). Only the operator's
+    schedule differs, injected as `attn_fn(q, k, v) -> [B, T, H, hd]`
+    (attention layers) and `taps_fn(z) -> predecessors` (conv layers); a
+    forward over a pool or a state writes it inside them. Returns (x',
+    expert load — None for a dense FFN)."""
+    op, ffn = kinds
+    h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    if op == CONV:
+        x = x + _conv_op(cfg, lp, h, taps_fn)
+    else:
+        x = x + _attention_op(cfg, lp, h, positions, attn_fn)
     with jax.named_scope("mlp"):
         h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        delta, load = _ffn(cfg, lp, h2, valid=valid, mesh=mesh, impl=impl,
-                           layer=layer)
-    return x + delta, k, v, load
+        delta, load = _ffn(cfg, lp, ffn, h2, valid=valid, mesh=mesh,
+                           impl=impl, layer=layer)
+    return x + delta, load
+
+
+def _no_state(cfg: ModelConfig):
+    """taps_fn of a forward over whole sequences from position 0."""
+    return lambda z: shortconv.taps_full(z, cfg.conv_L_cache)
 
 
 def forward_prefill(
@@ -232,13 +352,15 @@ def forward_prefill(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B, T] int32, right-padded
     seq_lens: jnp.ndarray,  # [B] valid lengths
-    k_cache: jnp.ndarray,  # [L, S, Hk*hd] flat slot pool (donated; loop carry)
+    k_cache: jnp.ndarray,  # [La, S, Hk*hd] flat slot pool (donated; loop carry)
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]; padding rows point at trash page
     page_size: int,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Whole prompts in one dense causal pass (the oracle: see the module
-    docstring); returns (last_logits [B, V], k_cache', v_cache').
+    docstring); returns (last_logits [B, V], k_cache', v_cache'). A conv
+    layer runs over shifted copies of its z and leaves no state: the
+    oracle of a prompt's logits, not the first half of a generation.
 
     Padding positions scatter into the allocator's reserved trash page, so
     the write is fully static-shaped — no dynamic trimming needed.
@@ -248,16 +370,20 @@ def forward_prefill(
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, T]
 
-    def body(x, lp, l, kc, vc):
-        x, k, v, load = _layer_step(
-            cfg, lp, x, positions,
-            lambda q, k, v: causal_attention(q, k, v, seq_lens),
-            valid=positions < seq_lens[:, None], layer=l,
-        )
-        return x, kv_write(kc, l, slots, k), kv_write(vc, l, slots, v), load
+    def body(x, lp, kinds, ix, kc, vc):
+        def attn_fn(q, k, v):
+            nonlocal kc, vc
+            kc = kv_write(kc, ix.op, slots, k)
+            vc = kv_write(vc, ix.op, slots, v)
+            return causal_attention(q, k, v, seq_lens)
 
-    x, k_cache, v_cache, _ = scan_layers(body, x, params["layers"], k_cache,
-                                         v_cache)
+        x, load = _layer_step(
+            cfg, lp, kinds, x, positions, attn_fn, _no_state(cfg),
+            valid=positions < seq_lens[:, None], layer=ix.ffn)
+        return x, kc, vc, load
+
+    x, k_cache, v_cache, _ = scan_layers(cfg, body, x, params["layers"],
+                                         k_cache, v_cache)
     last = jnp.clip(seq_lens - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,D]
     logits = _logits(params, cfg, x_last)[:, 0, :]  # [B, V]
@@ -272,7 +398,7 @@ def forward_ragged(
     tok_pos: jnp.ndarray,  # [T] int32 kv position per token (-1 = pad)
     write_slots: jnp.ndarray,  # [T] int32 flat cache slot per token
     out_idx: jnp.ndarray,  # [B] or [B, O] int32 stream indices to read logits at
-    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (donated; loop carry)
+    k_cache: jnp.ndarray,  # [La, S, Hk*hd] (donated; loop carry)
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]
     q_start: jnp.ndarray,  # [B] span offset per sequence
@@ -282,59 +408,81 @@ def forward_ragged(
     attn_impl: str = "jnp",  # "jnp" reference | "pallas" ragged TPU kernel
     interpret: bool = False,
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
-    moe_load: bool = False,  # also return the [L, E] expert loads
+    moe_load: bool = False,  # also return the [Le, E] expert loads
+    conv_state=None,  # [Lc, slots+1, K-1, D] (donated; loop carry)
+    slot_ids=None,  # [B] each row's slot: its row of conv_state
+    is_first=None,  # [B] the span is its request's first: state opens at 0
 ):
     """ONE forward over a ragged mixed batch: variable-length prefill
     spans and single decode tokens share a flattened [T] token stream —
-    no per-sequence bucket padding. Each layer writes the stream's K/V
-    into its pages, then every token attends causally over its own
-    sequence's paged context (forward_decode generalized to multi-token
-    spans: a prompt fed span by span reproduces forward_prefill). `out_idx` names
-    the stream positions whose logits leave the forward: a [B] vector
-    (each sequence's last token — the classic shape) returns [B, V];
-    a [B, O] matrix (speculative verification reads a logit at EVERY
-    draft position of a span) returns [B, O, V]. Padding rows
+    no per-sequence bucket padding. Each attention layer writes the
+    stream's K/V into its pages, then every token attends causally over
+    its own sequence's paged context (forward_decode generalized to
+    multi-token spans: a prompt fed span by span reproduces
+    forward_prefill); each conv layer reads a span's predecessors from the
+    stream and, before the span, from the row's slot of `conv_state`, and
+    leaves the span's last positions there (ops/shortconv.taps_ragged; a
+    model with conv layers needs `conv_state`, `slot_ids`, `is_first`).
+    `out_idx` names the stream positions whose logits leave the forward: a
+    [B] vector (each sequence's last token — the classic shape) returns
+    [B, V]; a [B, O] matrix (speculative verification reads a logit at
+    EVERY draft position of a span) returns [B, O, V]. Padding rows
     (q_len == 0) yield garbage logits the caller ignores. Returns
-    (logits, caches'), and with `moe_load` (an MoE model's step program
-    asks) the rows each expert of each layer got, [L, E] int32, fourth.
+    (logits, caches'), then conv_state' where one was given, and with
+    `moe_load` (an MoE model's step program asks) the rows each expert of
+    each expert layer got, [Le, E] int32, last.
     """
-    T = tokens.shape[0]
     with jax.named_scope("embed"):
         x = embed_lookup(params["embed"], tokens,
                          _adtype(params))[None]  # [1,T,D]
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
 
-    def body(x, lp, l, kc, vc):
+    def body(x, lp, kinds, ix, kc, vc, conv):
         def attn_fn(q, k, v):  # [1, T, H, hd]
             nonlocal kc, vc
             with jax.named_scope("kv_write"):
-                kc = kv_write(kc, l, write_slots, k[0])
-                vc = kv_write(vc, l, write_slots, v[0])
+                kc = kv_write(kc, ix.op, write_slots, k[0])
+                vc = kv_write(vc, ix.op, write_slots, v[0])
             with jax.named_scope("attention"):
                 out = ragged_attention_any(
-                    attn_impl, q[0], kc, vc, l, page_table, tok_seq,
+                    attn_impl, q[0], kc, vc, ix.op, page_table, tok_seq,
                     tok_pos, kv_len, q_start, q_len, page_size,
                     interpret=interpret, mesh=mesh,
                 )
             return out[None]
 
-        x, _, _, load = _layer_step(cfg, lp, x, positions, attn_fn,
-                                    valid=valid, mesh=mesh, impl=attn_impl,
-                                    layer=l)
-        return x, kc, vc, load
+        def taps_fn(z):  # [1, T, D]
+            nonlocal conv
+            rows = jax.lax.dynamic_index_in_dim(
+                conv, ix.op, 0, keepdims=False)[slot_ids]
+            taps, rows = shortconv.taps_ragged(z[0], rows, tok_seq, q_start,
+                                               q_len, is_first)
+            conv = conv.at[ix.op, slot_ids].set(rows)
+            return [t[None] for t in taps]
 
-    x, k_cache, v_cache, load = scan_layers(body, x, params["layers"],
-                                            k_cache, v_cache)
+        x, load = _layer_step(cfg, lp, kinds, x, positions, attn_fn, taps_fn,
+                              valid=valid, mesh=mesh, impl=attn_impl,
+                              layer=ix.ffn)
+        return x, kc, vc, conv, load
+
+    x, k_cache, v_cache, conv_state, load = scan_layers(
+        cfg, body, x, params["layers"], k_cache, v_cache, conv_state)
     if out_idx.ndim == 1:
         x_last = x[0][out_idx]  # [B, D]
         logits = _logits(params, cfg, x_last[None])[0]  # [B, V]
     else:
         x_last = x[0][out_idx]  # [B, O, D]
         logits = _logits(params, cfg, x_last)  # [B, O, V]
-    if moe_load:
-        return logits, k_cache, v_cache, load
-    return logits, k_cache, v_cache
+    return _results(logits, k_cache, v_cache, conv_state, load, moe_load)
+
+
+def _results(logits, k_cache, v_cache, conv_state, load, moe_load):
+    """(logits, caches'[, conv_state'][, load]) of a step forward."""
+    out = (logits, k_cache, v_cache)
+    if conv_state is not None:
+        out += (conv_state,)
+    return out + (load,) if moe_load else out
 
 
 def forward_decode(
@@ -342,20 +490,23 @@ def forward_decode(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B] int32 — last generated token per slot
     positions: jnp.ndarray,  # [B] int32 — position of `tokens` in each seq
-    k_cache: jnp.ndarray,  # [L, S, Hk*hd] (donated; loop carry)
+    k_cache: jnp.ndarray,  # [La, S, Hk*hd] (donated; loop carry)
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, max_pages]
     page_size: int,
     attn_impl: str = "jnp",  # "jnp" reference | "pallas" ragged TPU kernel
     active=None,  # [B] int32/bool — live decode slots (None = all live)
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
-    moe_load: bool = False,  # also return the [L, E] expert loads
+    moe_load: bool = False,  # also return the [Le, E] expert loads
+    conv_state=None,  # [Lc, >= B, K-1, D] (donated; loop carry): row b is slot b's
 ):
-    """One decode step for the whole batch; returns (logits [B,V], caches')
-    and, with `moe_load`, the [L, E] expert loads (as forward_ragged).
+    """One decode step for the whole batch (row b is slot b); returns
+    (logits [B,V], caches'), then conv_state' where one was given and,
+    with `moe_load`, the [Le, E] expert loads (as forward_ragged).
 
-    `active` feeds MoE routing only: parked slots carry garbage tokens
-    that are routed to no expert (models/moe.py).
+    `active` feeds MoE routing and the conv state: parked slots carry
+    garbage tokens that are routed to no expert (models/moe.py) and leave
+    their slot's state as it was (ops/shortconv.taps_decode).
     """
     B = tokens.shape[0]
     valid = None if active is None else (active > 0)[:, None]
@@ -366,35 +517,38 @@ def forward_decode(
     write_slots = flat_slot_indices(page_table, pos2, page_size)[:, 0]  # [B]
     seq_lens = positions + 1
 
-    def body(x, lp, l, kc, vc):
-        with jax.named_scope("attn_qkv"):
-            h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _qkv(cfg, lp, h)  # [B,1,H,hd]
-            q = apply_rope(q, pos2, cfg.rope_theta)
-            k = apply_rope(k, pos2, cfg.rope_theta)
-        with jax.named_scope("kv_write"):
-            kc = kv_write(kc, l, write_slots, k[:, 0])
-            vc = kv_write(vc, l, write_slots, v[:, 0])
-        with jax.named_scope("attention"):
-            attn = paged_decode_attention_any(
-                attn_impl, q[:, 0], kc, vc, l, page_table, seq_lens,
-                page_size, mesh=mesh,
-            )  # [B,H,hd]
-        with jax.named_scope("attn_out"):
-            x = x + qeinsum("be,ed->bd", attn.reshape(B, cfg.q_dim),
-                            lp["wo"])[:, None, :]
-        with jax.named_scope("mlp"):
-            h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            delta, load = _ffn(cfg, lp, h2, valid=valid, mesh=mesh,
-                               impl=attn_impl, layer=l)
-        return x + delta, kc, vc, load
+    def body(x, lp, kinds, ix, kc, vc, conv):
+        def attn_fn(q, k, v):  # [B, 1, H, hd]
+            nonlocal kc, vc
+            with jax.named_scope("kv_write"):
+                kc = kv_write(kc, ix.op, write_slots, k[:, 0])
+                vc = kv_write(vc, ix.op, write_slots, v[:, 0])
+            with jax.named_scope("attention"):
+                attn = paged_decode_attention_any(
+                    attn_impl, q[:, 0], kc, vc, ix.op, page_table, seq_lens,
+                    page_size, mesh=mesh,
+                )  # [B,H,hd]
+            return attn[:, None]
 
-    x, k_cache, v_cache, load = scan_layers(body, x, params["layers"],
-                                            k_cache, v_cache)
+        def taps_fn(z):  # [B, 1, D]
+            nonlocal conv
+            rows = jax.lax.dynamic_slice_in_dim(
+                jax.lax.dynamic_index_in_dim(conv, ix.op, 0, keepdims=False),
+                0, B, axis=0)
+            taps, rows = shortconv.taps_decode(z[:, 0], rows, active)
+            conv = jax.lax.dynamic_update_slice(
+                conv, rows[None], (ix.op, 0, 0, 0))
+            return [t[:, None] for t in taps]
+
+        x, load = _layer_step(cfg, lp, kinds, x, pos2, attn_fn, taps_fn,
+                              valid=valid, mesh=mesh, impl=attn_impl,
+                              layer=ix.ffn)
+        return x, kc, vc, conv, load
+
+    x, k_cache, v_cache, conv_state, load = scan_layers(
+        cfg, body, x, params["layers"], k_cache, v_cache, conv_state)
     logits = _logits(params, cfg, x)[:, 0, :]
-    if moe_load:
-        return logits, k_cache, v_cache, load
-    return logits, k_cache, v_cache
+    return _results(logits, k_cache, v_cache, conv_state, load, moe_load)
 
 
 def forward_prefill_sp(
@@ -423,15 +577,20 @@ def forward_prefill_sp(
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
     def body(carry, lp):
-        x = carry
-        x, k, v, _ = _layer_step(
-            cfg, lp, x, positions,
-            lambda q, k, v: ring_attention(q, k, v, seq_lens, mesh),
-            valid=positions < seq_lens[:, None],
-        )
-        x = jax.lax.with_sharding_constraint(x, seq_sharded)
-        return x, (k, v)
+        x, kv = carry, None
 
+        def attn_fn(q, k, v):
+            nonlocal kv
+            kv = (k, v)
+            return ring_attention(q, k, v, seq_lens, mesh)
+
+        x, _ = _layer_step(cfg, lp, cfg.kinds[0], x, positions, attn_fn,
+                           valid=positions < seq_lens[:, None])
+        x = jax.lax.with_sharding_constraint(x, seq_sharded)
+        return x, kv
+
+    # A uniform stack only (the engine refuses --sp for any other):
+    # the layers are the scan's xs, K and V its ys.
     x, (k_stack, v_stack) = jax.lax.scan(body, x, params["layers"])
     last = jnp.clip(seq_lens - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
@@ -445,7 +604,8 @@ def forward_embed(
     tokens: jnp.ndarray,  # [B, T]
     seq_lens: jnp.ndarray,  # [B]
 ) -> jnp.ndarray:
-    """Embeddings from a GENERATIVE model: causal forward (no KV write),
+    """Embeddings from a GENERATIVE model: causal forward (no KV write, no
+    conv state: whole sequences),
     masked mean pool of the final-norm hidden states, L2 norm — llama.cpp's
     default pooling for causal models, which is what the reference's Ollama
     backends run for /api/embed on e.g. llama3 (README.md /api/embed row).
@@ -454,16 +614,14 @@ def forward_embed(
     x = embed_lookup(params["embed"], tokens, _adtype(params))
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
-    def body(carry, lp):
-        x = carry
-        x, *_ = _layer_step(
-            cfg, lp, x, positions,
+    def body(x, lp, kinds, ix):
+        return _layer_step(
+            cfg, lp, kinds, x, positions,
             lambda q, k, v: causal_attention(q, k, v, seq_lens),
-            valid=positions < seq_lens[:, None],
-        )
-        return x, None
+            _no_state(cfg), valid=positions < seq_lens[:, None],
+            layer=ix.ffn)
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _ = scan_layers(cfg, body, x, params["layers"])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps).astype(jnp.float32)
     mask = (positions < seq_lens[:, None]).astype(jnp.float32)[:, :, None]
     pooled = jnp.sum(x * mask, axis=1) / jnp.maximum(jnp.sum(mask, axis=1), 1.0)
@@ -481,16 +639,13 @@ def forward_encoder(
     x = embed_lookup(params["embed"], tokens, _adtype(params))
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
-    def body(carry, lp):
-        x = carry
-        x, *_ = _layer_step(
-            cfg, lp, x, positions,
+    def body(x, lp, kinds, ix):
+        return _layer_step(
+            cfg, lp, kinds, x, positions,
             lambda q, k, v: bidirectional_attention(q, k, v, seq_lens),
-            valid=positions < seq_lens[:, None],
-        )
-        return x, None
+            valid=positions < seq_lens[:, None], layer=ix.ffn)
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _ = scan_layers(cfg, body, x, params["layers"])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps).astype(jnp.float32)
     mask = (positions < seq_lens[:, None]).astype(jnp.float32)[:, :, None]
     pooled = jnp.sum(x * mask, axis=1) / jnp.maximum(jnp.sum(mask, axis=1), 1.0)

@@ -1,13 +1,17 @@
-"""Mixture-of-experts FFN (Mixtral, OLMoE) with expert parallelism.
+"""Mixture-of-experts FFN (Mixtral, OLMoE, LFM2) with expert parallelism.
 
 The reference serves MoE models only by proxying to an Ollama backend that
 happens to run one (llama.cpp does the routing on CPU/GPU); it has no
 expert-parallel story at all. Here MoE is a first-class layer family, with
 ONE dispatch for every model of it:
 
-  - Routing is token-choice top-k: softmax in float32 over all experts,
-    take the top k. `ModelConfig.norm_topk_prob` says whether the kept
-    probabilities are renormalised (Mixtral: yes; OLMoE: no).
+  - Routing is token-choice top-k, in float32, and a function of the
+    config (`route`): the score over all experts is a softmax (Mixtral,
+    OLMoE) or a sigmoid (`router_score`, LFM2); with `use_expert_bias` the
+    top k are taken by score + a per-expert bias, but a chosen expert's
+    WEIGHT is its score without the bias; `norm_topk_prob` divides the
+    kept weights by their sum (+ `norm_topk_eps`) (Mixtral, LFM2: yes;
+    OLMoE: no); `routed_scaling_factor` multiplies them.
   - The dispatch is DROPLESS: the (token, k-slot) assignments are sorted
     by expert, each expert's contiguous rows go through its SwiGLU as
     three grouped matmuls, and the rows are un-sorted and summed with
@@ -33,7 +37,9 @@ ONE dispatch for every model of it:
     the parts. A step is at most a few thousand rows, so no all-to-all is
     needed.
 
-Expert weights are stacked [L, E, ...]. The step forwards do NOT hand the
+Expert weights are stacked [L, E, ...] over the layers that HAVE experts
+(a stack with a dense prefix has fewer than `num_layers`; `layer` counts
+them). The step forwards do NOT hand the
 layer loop a slice of them: a kernel's operand cannot be a fused slice, so
 XLA would copy each layer's three matrices out of the stack (0.8 GB a layer
 for OLMoE: twice the bytes the matmuls themselves read). Like the KV pool
@@ -49,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from ollamamq_tpu.config import ModelConfig
+from ollamamq_tpu.config import EXPERTS, ModelConfig
 from ollamamq_tpu.parallel.mesh import AXIS_EXPERT, AXIS_TENSOR
 
 # Stage names inside llama's "mlp" scope on the device trace, in order.
@@ -63,25 +69,37 @@ LOAD_STATS = ("assignments", "pairs_hit", "load_max")
 # at OLMoE's shapes (PERF.md section 6, PR 27) the one nearest the
 # weight-streaming floor from 128 to 4096 rows.
 GMM_TILING = (128, 2048, 1024)
+# Standard deviation of a seeded-random selection bias: a tenth of the
+# router logits', so it moves the choice of some tokens' k-th expert.
+ROUTER_BIAS_SD = 0.1
 
 
 def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
-    """Router + stacked expert weights for every layer: contributes the
-    FFN entries of the `layers` tree when cfg.num_experts > 0."""
-    d, f = cfg.hidden_size, cfg.intermediate_size
-    L, E = cfg.num_layers, cfg.num_experts
-    keys = jax.random.split(key, 4)
+    """Router + stacked expert weights for every layer that has experts:
+    the routed-FFN entries of the `layers` tree when cfg.num_experts > 0.
+    The selection bias is a float32 buffer and is drawn NON-zero: a
+    forward that drops it, or weights by the biased score, computes
+    another model."""
+    d, f = cfg.hidden_size, cfg.expert_width
+    L, E = cfg.count(EXPERTS), cfg.num_experts
+    # (One more key only where there is a bias: the families without one
+    # keep the weights their seeds have always drawn.)
+    keys = jax.random.split(key, 4 + cfg.use_expert_bias)
 
     def w(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 / jnp.sqrt(fan_in)).astype(dtype)
 
-    return {
+    out = {
         "w_router": w(keys[0], (L, d, E), d),
         "we_gate": w(keys[1], (L, E, d, f), d),
         "we_up": w(keys[2], (L, E, d, f), d),
         "we_down": w(keys[3], (L, E, f, d), f),
     }
+    if cfg.use_expert_bias:
+        out["router_bias"] = ROUTER_BIAS_SD * jax.random.normal(
+            keys[4], (L, E), jnp.float32)
+    return out
 
 
 def grouped_matmul(impl: str, xs, w, sizes, interpret: bool = False):
@@ -153,6 +171,30 @@ def _expert_ffn_sharded(mesh, impl, xs, sizes, w_gate, w_up, w_down, layer):
     )(xs, sizes, w_gate, w_up, w_down, layer)
 
 
+def route(cfg: ModelConfig, lp: dict, x: jnp.ndarray):
+    """x [N, D] -> (weights [N, K] float32, experts [N, K] int32): the
+    config's router (the module docstring's first point). In float32: the
+    scores feed multiplicative gates — bf16 here costs real quality for
+    no speed."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     lp["w_router"].astype(jnp.float32))
+    scores = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    if cfg.use_expert_bias:  # the bias selects; it weights nothing
+        _, experts = jax.lax.top_k(scores + lp["router_bias"],
+                                   cfg.num_experts_per_tok)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        gates, experts = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total + cfg.norm_topk_eps if cfg.norm_topk_eps
+                         else total)
+    if cfg.routed_scaling_factor != 1.0:
+        gates = gates * cfg.routed_scaling_factor
+    return gates, experts
+
+
 def moe_mlp(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
             mesh=None, impl: str = "jnp", layer=None):
     """Top-k routed expert FFN over [B, T, D] hiddens; returns ([B, T, D],
@@ -173,13 +215,7 @@ def moe_mlp(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
     x = h.reshape(N, D)
 
     with jax.named_scope("moe_router"):
-        # Router in f32: the softmax feeds multiplicative gates — bf16
-        # here costs real quality for no speed.
-        logits = jnp.dot(x.astype(jnp.float32),
-                         lp["w_router"].astype(jnp.float32))
-        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
-        if cfg.norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        gates, experts = route(cfg, lp, x)
 
     with jax.named_scope("moe_dispatch"):
         # Assignment a = (token a // K, slot a % K). An invalid token's

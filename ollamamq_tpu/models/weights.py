@@ -1,7 +1,8 @@
 """Weight initialization and checkpoint loading.
 
 Checkpoints load from either:
-  - a safetensors directory in the HF layout (Llama/Qwen2 tensor names), or
+  - a safetensors directory in the HF layout (Llama/Qwen2, Mixtral, OLMoE
+    and — assumed, see _HF_LAYER_MAP — LFM2 tensor names), or
   - an orbax checkpoint previously saved by `save_orbax`.
 
 Weights land directly in their mesh sharding (each host/device only
@@ -20,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ollamamq_tpu.config import ModelConfig
+from ollamamq_tpu.config import CONV, ModelConfig
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.quant import QuantTensor, quantize_tensor
 
@@ -39,10 +40,10 @@ def quantize_params_int8(params: dict, cfg: ModelConfig) -> dict:
     Shapes are unchanged — each quantized leaf becomes a QuantTensor
     pytree node, and the dequant-fused helpers in ops/quant.py keep
     every forward's signature identical."""
-    if cfg.num_experts:
+    if cfg.num_experts or cfg.count(CONV):
         raise ValueError(
-            "int8 weight quantization does not cover MoE expert stacks; "
-            f"load {cfg.name} with --weights-dtype=bfloat16")
+            "int8 weight quantization does not cover MoE expert stacks or "
+            f"conv layers; load {cfg.name} with --weights-dtype=bfloat16")
     out = dict(params)
     layers = dict(params["layers"])
     for k in QUANT_LAYER_KEYS:
@@ -71,7 +72,34 @@ _HF_LAYER_MAP = {
     "mlp.gate_proj.weight": ("w_gate", True),
     "mlp.up_proj.weight": ("w_up", True),
     "mlp.down_proj.weight": ("w_down", True),
+    # LFM2 (names ASSUMED from the published modelling code; no checkpoint
+    # can be read offline): norms before the operator and the FFN, the
+    # conv operator's two projections, q/k layernorms, `out_proj` for
+    # attention's output, and a dense FFN as feed_forward.w1 / w3 / w2
+    # (gate / up / down).
+    "operator_norm.weight": ("attn_norm", False),
+    "ffn_norm.weight": ("mlp_norm", False),
+    "self_attn.out_proj.weight": ("wo", True),
+    "self_attn.q_layernorm.weight": ("q_norm", False),
+    "self_attn.k_layernorm.weight": ("k_norm", False),
+    "conv.in_proj.weight": ("conv_in", True),
+    "conv.out_proj.weight": ("conv_out", True),
+    "feed_forward.w1.weight": ("w_gate", True),
+    "feed_forward.w3.weight": ("w_up", True),
+    "feed_forward.w2.weight": ("w_down", True),
 }
+# The depthwise Conv1d weight [D, 1, K] (cross-correlation behind K-1 zeros
+# of left padding: tap K-1 meets the token itself, as `conv_w`'s).
+_HF_CONV_TAPS = "conv.conv.weight"
+# MoE layouts, told apart by the router's name: (block, the router's
+# weight, gate / up / down of one expert, the selection bias or None).
+_HF_MOE_LAYOUTS = (
+    ("block_sparse_moe", "gate.weight", ("w1", "w3", "w2"), None),  # Mixtral
+    ("feed_forward", "gate.weight", ("w1", "w3", "w2"),
+     "expert_bias"),  # LFM2 (assumed, as above)
+    ("mlp", "gate.weight", ("gate_proj", "up_proj", "down_proj"),
+     None),  # OLMoE
+)
 
 
 def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
@@ -133,34 +161,40 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
     if n_layers != cfg.num_layers:
         raise ValueError(f"checkpoint has {n_layers} layers, config {cfg.num_layers}")
 
+    def having(suffix: str) -> list:
+        """The layers that hold `suffix`, in order: a weight is stacked
+        over the layers of its kind (models/llama.py:KIND_PARAMS)."""
+        return [i for i in range(cfg.num_layers)
+                if f"model.layers.{i}.{suffix}" in raw]
+
     layers: dict = {}
     for hf_suffix, (ours, tr) in _HF_LAYER_MAP.items():
-        key0 = f"model.layers.0.{hf_suffix}"
-        if key0 not in raw:
+        at = having(hf_suffix)
+        if not at:
             continue
         stack = np.stack(
-            [grab(f"model.layers.{i}.{hf_suffix}", tr) for i in range(cfg.num_layers)]
-        )
+            [grab(f"model.layers.{i}.{hf_suffix}", tr) for i in at])
         layers[ours] = jnp.asarray(stack, dtype=dtype)
+    if having(_HF_CONV_TAPS):
+        layers["conv_w"] = jnp.asarray(np.stack(
+            [grab(f"model.layers.{i}.{_HF_CONV_TAPS}", False)[:, 0, :]
+             for i in having(_HF_CONV_TAPS)]), dtype=dtype)
 
     if cfg.num_experts:
-        # Two layouts, told apart by the router's name. Mixtral:
-        # block_sparse_moe.gate + experts.N.w1/w3/w2 (gate/up/down).
-        # OLMoE: mlp.gate + mlp.experts.N.{gate,up,down}_proj.
-        # Stack experts on axis 1 -> [L, E, D, F] etc.
+        # Stack experts on axis 1 -> [Le, E, D, F] etc., over the layers
+        # that have a router (a dense prefix has none).
         # Host-RAM discipline: the expert stacks dominate the checkpoint
         # (~90% of an 8x7b), so cast each LAYER's expert stack to the
         # target dtype immediately and pop the consumed raw tensors —
         # peak host memory stays near one f32 layer-stack (~2 GB for
         # 8x7b) above the raw checkpoint, instead of ~2.5x it.
-        mixtral = "model.layers.0.block_sparse_moe.gate.weight" in raw
-        block = "block_sparse_moe" if mixtral else "mlp"
-        gate_up_down = (("w1", "w3", "w2") if mixtral else
-                        ("gate_proj", "up_proj", "down_proj"))
+        block, router, gate_up_down, bias = next(
+            lay for lay in _HF_MOE_LAYOUTS if having(f"{lay[0]}.{lay[1]}"))
+        at = having(f"{block}.{router}")
 
         def estack(w_name: str, transpose: bool):
             per_layer = []
-            for i in range(cfg.num_layers):
+            for i in at:
                 names = [f"model.layers.{i}.{block}.experts."
                          f"{e}.{w_name}.weight"
                          for e in range(cfg.num_experts)]
@@ -171,16 +205,30 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
             return jnp.stack(per_layer)
 
         layers["w_router"] = jnp.asarray(np.stack([
-            grab(f"model.layers.{i}.{block}.gate.weight", True)
-            for i in range(cfg.num_layers)
+            grab(f"model.layers.{i}.{block}.{router}", True) for i in at
         ]), dtype=dtype)
+        if bias is not None and cfg.use_expert_bias:
+            layers["router_bias"] = jnp.asarray(np.stack([
+                grab(f"model.layers.{i}.{block}.{bias}", False) for i in at
+            ]), dtype=jnp.float32)
         layers["we_gate"] = estack(gate_up_down[0], True)
         layers["we_up"] = estack(gate_up_down[1], True)
         layers["we_down"] = estack(gate_up_down[2], True)
 
+    want = jax.eval_shape(lambda: llama.init_params(
+        cfg, jax.random.PRNGKey(0), dtype))["layers"]
+    bad = [f"{k}: {tuple(layers[k].shape) if k in layers else 'absent'} "
+           f"for {tuple(v.shape)}" for k, v in want.items()
+           if k not in layers or layers[k].shape != v.shape]
+    if bad:
+        raise ValueError(f"checkpoint does not hold {cfg.name}'s layers: "
+                         + "; ".join(bad))
+
     params = {
         "embed": jnp.asarray(grab("model.embed_tokens.weight", False), dtype=dtype),
-        "final_norm": jnp.asarray(grab("model.norm.weight", False), dtype=dtype),
+        "final_norm": jnp.asarray(grab(
+            "model.embedding_norm.weight" if "model.embedding_norm.weight"
+            in raw else "model.norm.weight", False), dtype=dtype),
         "layers": layers,
     }
     if "lm_head.weight" in raw and not cfg.tie_embeddings:
@@ -278,13 +326,13 @@ def _full_logits(params: dict, cfg: ModelConfig, tokens) -> jnp.ndarray:
     x = llama.embed_lookup(params["embed"], toks, llama._adtype(params))
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
-    def body(carry, lp):
-        x, *_ = llama._layer_step(
-            cfg, lp, carry, positions,
-            lambda q, k, v: causal_attention(q, k, v, seq_lens))
-        return x, None
+    def body(x, lp, kinds, ix):
+        return llama._layer_step(
+            cfg, lp, kinds, x, positions,
+            lambda q, k, v: causal_attention(q, k, v, seq_lens),
+            layer=ix.ffn)
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _ = llama.scan_layers(cfg, body, x, params["layers"])
     return llama._logits(params, cfg, x[:, -1:, :])[0, 0]  # [V] f32
 
 

@@ -2,7 +2,8 @@
 
 The FLOPs model is the standard decoder estimate (PaLM appendix B /
 Chinchilla): matmul work is 2 x (active) parameters per token, plus
-attention score+value work 4 x layers x context x q_dim per token. For
+attention score+value work 4 x attention layers x context x q_dim per
+token. For
 MoE models only routed-active experts count (a Mixtral 8x7b token pays
 ~13B, not 47B).
 
@@ -11,7 +12,7 @@ accelerators (CPU meshes in CI) yield None and the engine publishes
 mfu=0 rather than a made-up number. OLLAMAMQ_PEAK_FLOPS overrides —
 that is also how CPU tests get a deterministic nonzero MFU.
 
-Stdlib only: the ModelConfig duck-types (num_layers, hidden_size, ...),
+Stdlib only: the ModelConfig duck-types (param_count, count, q_dim),
 so the doc checker and tests can import this without jax.
 """
 
@@ -50,25 +51,14 @@ def peak_flops_per_chip(device_kind: str) -> Optional[float]:
 def active_param_count(cfg) -> int:
     """Params touched per token: for MoE, the top-k routed experts plus
     router, not the full expert bank; dense models = param_count."""
-    d, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
-    mlp = 3 * d * f
-    if cfg.num_experts:
-        mlp = cfg.num_experts_per_tok * 3 * d * f + d * cfg.num_experts
-    per_layer = (
-        d * cfg.q_dim + 2 * d * cfg.kv_dim + cfg.q_dim * d
-        + mlp
-        + 2 * d
-        + cfg.qk_norm_params()
-    )
-    embed = v * d * (1 if cfg.tie_embeddings else 2)
-    return cfg.num_layers * per_layer + embed + d
+    return cfg.param_count(active=True)
 
 
 def flops_per_token(cfg, context_len: float = 0.0) -> float:
     """Forward FLOPs to generate one token at the given KV context."""
     dense = 2.0 * active_param_count(cfg)
     # QK^T and attn x V: each 2 x ctx x q_dim MACs = 2 FLOPs, per layer.
-    attn = 4.0 * cfg.num_layers * max(0.0, context_len) * cfg.q_dim
+    attn = 4.0 * cfg.count("full_attention") * max(0.0, context_len) * cfg.q_dim
     return dense + attn
 
 

@@ -242,6 +242,27 @@ HBM_KV_BYTES = REGISTRY.gauge(
     "Bytes the KV page pool occupies per model runtime (int8 pages + "
     "fp32 scale rows when --kv-dtype=int8; ~2x more concurrent requests "
     "fit the same budget)", labels=("model",))
+HBM_CONV_STATE_BYTES = REGISTRY.gauge(
+    "ollamamq_hbm_conv_state_bytes",
+    "Bytes the conv layers' per-slot state occupies per model runtime "
+    "(conv layers x (slots + 1) x (window - 1) x hidden; fixed, whatever "
+    "the context lengths; 0 for a model without such layers)",
+    labels=("model",))
+KV_BYTES_PER_TOKEN = REGISTRY.gauge(
+    "ollamamq_kv_bytes_per_token",
+    "Bytes one token of context adds to the KV pool: K and V of every "
+    "ATTENTION layer (a hybrid stack's conv layers add none) — what a "
+    "deployment's context capacity is sized by", labels=("model",))
+CONV_STATE_RESETS_TOTAL = REGISTRY.counter(
+    "ollamamq_conv_state_resets_total",
+    "Rows of launched steps whose slot's conv state the program opened "
+    "at zero: a request's first span (every admission, every replay)",
+    labels=("model",))
+CONV_STATE_CARRIED_TOTAL = REGISTRY.counter(
+    "ollamamq_conv_state_carried_total",
+    "Rows of launched steps that read the conv state an earlier step left "
+    "in their slot: later chunks of a prompt, decode rows, a fused scan's "
+    "active slots", labels=("model",))
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

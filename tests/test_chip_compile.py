@@ -255,13 +255,30 @@ def test_olmoe_width_step_forward_compiles_for_one_v5e_chip(v5e, which):
 # The engine's own step programs: everything they carry is updated in place.
 # ---------------------------------------------------------------------------
 
-def _lower_step_program(v5e, which, monkeypatch):
+# LFM2-8B-A1B's layers (config.py) over a short stack that keeps its plan —
+# a dense prefix and a period of (attention, conv, conv, conv) with experts,
+# twice — and a small vocabulary.
+LFM2_CFG = ModelConfig(
+    name="chip-compile-lfm2-widths", vocab_size=2048, hidden_size=2048,
+    intermediate_size=7168, num_layers=9, num_heads=32, num_kv_heads=8,
+    head_dim=64, max_seq_len=MP * PS, rope_theta=1e6, rms_norm_eps=1e-5,
+    tie_embeddings=True, qk_norm="head", num_experts=32,
+    num_experts_per_tok=4, norm_topk_prob=True, norm_topk_eps=1e-6,
+    router_score="sigmoid", use_expert_bias=True, num_dense_layers=1,
+    moe_intermediate_size=1792,
+    layer_types=("conv",) + ("full_attention", "conv", "conv", "conv") * 2)
+
+
+def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     """One of the pipelined loop's two programs as the engine jits it,
-    lowered for one described chip: (lowered, the packed input's words)."""
+    lowered for one described chip: (lowered, the packed input's words,
+    the bytes of the state it carries)."""
     from types import SimpleNamespace
 
+    from ollamamq_tpu.config import ATTENTION, CONV
     from ollamamq_tpu.engine import engine as eng_mod
     from ollamamq_tpu.engine.engine import ModelRuntime
+    from ollamamq_tpu.ops import shortconv
 
     one = SingleDeviceSharding(v5e.devices[0])
 
@@ -274,24 +291,31 @@ def _lower_step_program(v5e, which, monkeypatch):
                             key, fn))
     S, W = B, 64
     rt = object.__new__(ModelRuntime)
-    rt.cfg, rt.attn_impl, rt.mesh = LOOP_CFG, "pallas", None
+    rt.cfg, rt.attn_impl, rt.mesh = cfg, "pallas", None
     rt.ecfg = SimpleNamespace(page_size=PS, max_slots=S,
                               max_pages_per_seq=MP, repeat_last_n=W)
     rt._prefill_jits, rt._decode_jits = {}, {}
     shapes = jax.eval_shape(
-        lambda: llama.init_params(LOOP_CFG, jax.random.PRNGKey(0)))
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
     params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
-    pool = s((LAYERS, NP * PS, HK * HD), jnp.bfloat16)
+    pool = s((cfg.count(ATTENTION), NP * PS, cfg.kv_dim), jnp.bfloat16)
     recent, last_ids = s((S + 1, W)), s((S,))
+    # The conv layers' per-slot state: None (no leaf) without such layers.
+    conv = jax.eval_shape(lambda: shortconv.alloc_state(
+        cfg.count(CONV), S, cfg.conv_L_cache, cfg.hidden_size))
+    if conv is not None:
+        conv = s(conv.shape, conv.dtype)
     if which == "mq_ragged_step":
         fn = rt._get_ragged_jit(T, 0, (True, True, True))
         words = rt._ragged_layout(T).size
     else:
         fn = rt._get_decode_jit(8, (True, True, True))
         words = rt._decode_layout().size
+    carried = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
+        (pool, pool, recent, last_ids, conv)))
     # The step's host inputs are ONE packed int32 array (step_pack).
-    return fn.lower(params, s((words,)), pool, pool, recent,
-                    last_ids), words
+    return fn.lower(params, s((words,)), pool, pool, recent, last_ids,
+                    conv), words, carried
 
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
@@ -301,25 +325,56 @@ def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
     one reads a row's input token from it): both pools, the penalty ring
     and the carry are donated and come back aliased — the compiled program
     holds no second copy of any."""
-    lowered, _ = _lower_step_program(v5e, which, monkeypatch)
+    lowered, _, carried = _lower_step_program(v5e, which, monkeypatch)
     S, W = B, 64
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
-    carried = (2 * LAYERS * NP * PS * HK * HD * 2   # both pools
-               + (S + 1) * W * 4 + S * 4)            # the ring, the carry
+    assert carried == (2 * LAYERS * NP * PS * HK * HD * 2   # both pools
+                       + (S + 1) * W * 4 + S * 4)   # the ring, the carry
     assert mem.alias_size_in_bytes >= carried, (mem, carried)
     assert mem.temp_size_in_bytes < NP * PS * HK * HD * 2, mem
 
 
-def test_ragged_step_is_fed_one_host_array(v5e, monkeypatch):
+@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
+def test_lfm2_width_step_programs_carry_pool_and_conv_state_in_place(
+        v5e, which, monkeypatch):
+    """A stack whose layers differ (PR 32), at LFM2-8B-A1B's widths: the
+    attention kernels at 8 kv heads of 64 (512 lanes, group 4) and the
+    grouped expert matmul at [2048, 1792] compile for the chip; the KV pool
+    — for the 2 attention layers only — the conv layers' per-slot state,
+    the ring and the id carry all come back aliased; no weight stack is
+    copied out for a layer (the temporaries stay under a quarter of ONE
+    expert layer's gate matrix, 235 MB), and the scan traces each distinct
+    layer of the period once: 3 grouped matmuls for each of its 4 layers,
+    one attention kernel."""
+    lowered, _, carried = _lower_step_program(v5e, which, monkeypatch,
+                                              LFM2_CFG)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3 * 4
+    assert "ragged-dot" not in text
+    assert text.count("tpu_custom_call") >= 3 * 4 + 1
+    mem = compiled.memory_analysis()
+    conv_state = 7 * (B + 1) * 2 * 2048 * 2
+    assert carried >= 2 * 2 * NP * PS * 512 * 2 + conv_state
+    assert mem.alias_size_in_bytes >= carried, (mem, carried)
+    assert mem.temp_size_in_bytes < 32 * 2048 * 1792 * 2 // 4, mem
+
+
+@pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG], ids=["dense", "lfm2"])
+def test_ragged_step_is_fed_one_host_array(v5e, monkeypatch, cfg):
     """One upload a step: besides `params`, the compiled ragged step has
     exactly ONE parameter that is not donated device state — the packed
     int32 buffer of its host inputs. The RNG key is made inside (no key
-    parameter), so nothing else is dispatched or transferred for a step."""
-    lowered, words = _lower_step_program(v5e, "mq_ragged_step", monkeypatch)
+    parameter), so nothing else is dispatched or transferred for a step.
+    The conv layers' state is one more donated argument (no leaf at all
+    for a model without such layers)."""
+    lowered, words, _ = _lower_step_program(v5e, "mq_ragged_step",
+                                            monkeypatch, cfg)
     lowered.compile()
     _params, *rest = lowered.args_info[0]
+    rest = jax.tree_util.tree_leaves(rest)
     fed = [a for a in rest if not a.donated]
-    assert len(rest) == 5 and len(fed) == 1, rest
+    assert len(rest) == (6 if cfg is LFM2_CFG else 5) and len(fed) == 1, rest
     assert (fed[0].shape, fed[0].dtype) == ((words,), jnp.int32), fed

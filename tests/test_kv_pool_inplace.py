@@ -10,6 +10,7 @@ the wire format ([L, n, Hk, hd] arrays) it had when the pool was stored
 per head. That the compiled step programs really update the pool in
 place is tests/test_chip_compile.py's to show."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -149,6 +150,64 @@ def test_forward_ragged_writes_its_rows_and_nothing_else(tiny_cfg,
         before, after = np.asarray(before), np.asarray(after)
         assert (after[~written] == before[~written]).all()
         assert (after[written] != before[written]).all()
+
+
+def test_a_hybrid_step_writes_its_rows_of_pool_and_conv_state_only():
+    """A stack whose layers differ (LFM2) carries TWO arrays through the
+    layer loop: the pool, which has a layer for each ATTENTION layer only,
+    and the conv layers' per-slot state. After a ragged step each holds new
+    values at exactly the step's rows — the pool at its write slots, the
+    state at the slots of the rows that had tokens — and every other bit
+    it was given (other slots, the padding row's trash slot aside). After a
+    decode pass: the active slots' state rolled, the others' kept."""
+    from ollamamq_tpu.config import MODEL_CONFIGS
+    from ollamamq_tpu.ops import shortconv
+
+    cfg = MODEL_CONFIGS["test-tiny-lfm2"]
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    rng = np.random.default_rng(7)
+    n_attn, n_conv, n_slots = 3, 6, 5
+    shape = (n_attn, S, cfg.kv_dim)
+    kc = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    conv = jnp.asarray(rng.normal(size=shortconv.alloc_state(
+        n_conv, n_slots, cfg.conv_L_cache, cfg.hidden_size).shape),
+        jnp.float32)
+    assert conv.shape == (n_conv, n_slots + 1, 2, cfg.hidden_size)
+    ws = np.asarray([PT[s][p // PS] * PS + p % PS
+                     for s, p in zip(TOK_SEQ, TOK_POS)], np.int32)
+    tokens = rng.integers(1, cfg.vocab_size, size=10).astype(np.int32)
+    slot_ids = jnp.asarray([3, 1, n_slots], jnp.int32)  # rows 0, 1; padding
+    _, kc2, vc2, conv2 = llama.forward_ragged(
+        params, cfg, jnp.asarray(tokens), TOK_SEQ, TOK_POS, jnp.asarray(ws),
+        jnp.asarray([8, 9, 0], jnp.int32), kc, vc, PT, Q_START, Q_LEN,
+        KV_LEN, PS, conv_state=conv, slot_ids=slot_ids,
+        is_first=jnp.zeros(3, jnp.int32))
+    written = np.zeros(shape[:2], bool)
+    written[:, ws] = True
+    for before, after in ((kc, kc2), (vc, vc2)):
+        before, after = np.asarray(before), np.asarray(after)
+        assert (after[~written] == before[~written]).all()
+        assert (after[written] != before[written]).all()
+    before, after = np.asarray(conv), np.asarray(conv2)
+    assert (after[:, [0, 2, 4]] == before[:, [0, 2, 4]]).all()
+    assert (after[:, 3] != before[:, 3]).all()       # a span of 9: both rows
+    # a span of ONE token: the old last row moved up, the new z behind it
+    assert (after[:, 1, 0] == before[:, 1, 1]).all()
+    assert (after[:, 1, 1] != before[:, 1, 1]).all()
+
+    active = jnp.asarray([0, 1, 0, 1, 0], jnp.int32)
+    pt5 = jnp.where(active[:, None] > 0,
+                    jnp.asarray([[6, 0, 0, 0]] * 5, jnp.int32), 0)
+    _, _, _, conv3 = llama.forward_decode(
+        params, cfg, jnp.asarray([5, 6, 7, 8, 9], jnp.int32),
+        jnp.asarray([0, 13, 0, 20, 0], jnp.int32), kc2, vc2, pt5, PS,
+        active=active, conv_state=conv2)
+    last = np.asarray(conv3)
+    assert (last[:, [0, 2, 4, 5]] == after[:, [0, 2, 4, 5]]).all()
+    for slot in (1, 3):
+        assert (last[:, slot, 0] == after[:, slot, 1]).all()
+        assert (last[:, slot, 1] != after[:, slot, 1]).all()
 
 
 @pytest.mark.parametrize("kind", ["bf16", "int8"])

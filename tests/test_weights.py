@@ -246,3 +246,104 @@ def test_safetensors_olmoe_layout(tmp_path):
                                     jnp.asarray(toks, jnp.int32))
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want[-1]),
                                atol=2e-4)
+
+
+def test_safetensors_lfm2_layout(tmp_path):
+    """LFM2's checkpoint names (ASSUMED from the published modelling code):
+    `operator_norm` / `ffn_norm`, `conv.in_proj / conv / out_proj`,
+    `self_attn.{q,k}_layernorm` and `out_proj`, a dense prefix as
+    `feed_forward.w1 / w3 / w2`, the router as `feed_forward.gate` with its
+    `expert_bias`, experts `feed_forward.experts.N.w1 / w3 / w2`,
+    `embedding_norm`. Each weight is stacked over the layers of its kind,
+    and the loaded tree equals what the reference computes."""
+    import jax.numpy as jnp
+    from safetensors.numpy import save_file
+    from testutil import lfm2_keys, lfm2_reference
+
+    cfg = MODEL_CONFIGS["test-tiny-lfm2"]
+    rng = np.random.default_rng(3)
+    d, f, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    fe, K = cfg.expert_width, cfg.conv_L_cache
+
+    def normal(*shape):
+        return rng.normal(size=shape).astype(np.float32) / np.sqrt(shape[-1])
+
+    tensors = {"model.embed_tokens.weight": normal(cfg.vocab_size, d),
+               "model.embedding_norm.weight": 1 + 0.3 * normal(d)}
+    for i, (op, ffn) in enumerate(cfg.kinds):
+        p = f"model.layers.{i}."
+        tensors[p + "operator_norm.weight"] = 1 + 0.3 * normal(d)
+        tensors[p + "ffn_norm.weight"] = 1 + 0.3 * normal(d)
+        if op == "conv":
+            tensors[p + "conv.in_proj.weight"] = normal(3 * d, d)
+            tensors[p + "conv.conv.weight"] = normal(d, 1, K)
+            tensors[p + "conv.out_proj.weight"] = normal(d, d)
+        else:
+            tensors[p + "self_attn.q_proj.weight"] = normal(cfg.q_dim, d)
+            tensors[p + "self_attn.k_proj.weight"] = normal(cfg.kv_dim, d)
+            tensors[p + "self_attn.v_proj.weight"] = normal(cfg.kv_dim, d)
+            tensors[p + "self_attn.out_proj.weight"] = normal(d, cfg.q_dim)
+            tensors[p + "self_attn.q_layernorm.weight"] = \
+                1 + 0.3 * normal(cfg.head_dim)
+            tensors[p + "self_attn.k_layernorm.weight"] = \
+                1 + 0.3 * normal(cfg.head_dim)
+        if ffn == "dense":
+            tensors[p + "feed_forward.w1.weight"] = normal(f, d)
+            tensors[p + "feed_forward.w3.weight"] = normal(f, d)
+            tensors[p + "feed_forward.w2.weight"] = normal(d, f)
+        else:
+            tensors[p + "feed_forward.gate.weight"] = normal(E, d)
+            tensors[p + "feed_forward.expert_bias"] = 0.1 * normal(E) * 3
+            for e in range(E):
+                ep = p + f"feed_forward.experts.{e}."
+                tensors[ep + "w1.weight"] = normal(fe, d)
+                tensors[ep + "w3.weight"] = normal(fe, d)
+                tensors[ep + "w2.weight"] = normal(d, fe)
+    save_file(tensors, str(tmp_path / "model.safetensors"))
+
+    params = weights.load_safetensors(cfg, str(tmp_path), dtype=jnp.float32)
+    layers = params["layers"]
+    assert layers["attn_norm"].shape == layers["mlp_norm"].shape == (9, d)
+    assert layers["wq"].shape == (3, d, cfg.q_dim)
+    assert layers["q_norm"].shape == (3, cfg.head_dim)
+    assert layers["conv_in"].shape == (6, d, 3 * d)
+    assert layers["conv_w"].shape == (6, d, K)
+    assert layers["w_gate"].shape == (2, d, f)
+    assert layers["w_router"].shape == (7, d, E)
+    assert layers["router_bias"].shape == (7, E)
+    assert layers["router_bias"].dtype == jnp.float32
+    assert layers["we_down"].shape == (7, E, fe, d)
+    assert "lm_head" not in params  # the head is the embedding
+    # by kind, in layer order: the second attention layer is layer 4, the
+    # third conv layer layer 3, the first expert layer layer 2
+    np.testing.assert_allclose(
+        np.asarray(layers["wo"][1]),
+        tensors["model.layers.4.self_attn.out_proj.weight"].T, rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(layers["conv_w"][2]),
+        tensors["model.layers.3.conv.conv.weight"][:, 0, :], rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(layers["we_up"][0, 5]),
+        tensors["model.layers.2.feed_forward.experts.5.w3.weight"].T,
+        rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(params["final_norm"]),
+        tensors["model.embedding_norm.weight"], rtol=1e-6)
+
+    # The loaded tree runs, and computes what the reference computes.
+    from ollamamq_tpu.models import llama
+
+    toks = np.asarray([1, 9, 200, 31, 77, 5, 410, 8], np.int32)
+    kc = jnp.zeros((3, 64, cfg.kv_dim), jnp.float32)
+    logits, _, _ = llama.forward_prefill(
+        params, cfg, jnp.asarray(toks[None]), jnp.array([8]), kc, kc,
+        jnp.arange(8, dtype=jnp.int32)[None], 8)
+    want = lfm2_reference().logits(lfm2_keys(cfg), params, jnp.asarray(toks))
+    np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want[-1]),
+                               atol=2e-4, rtol=0)
+
+    # a checkpoint of another stack is told, not served
+    del tensors["model.layers.3.conv.conv.weight"]
+    save_file(tensors, str(tmp_path / "model.safetensors"))
+    with pytest.raises(ValueError, match="conv_w"):
+        weights.load_safetensors(cfg, str(tmp_path), dtype=jnp.float32)

@@ -130,3 +130,37 @@ def reference_keys(mc) -> dict:
             "num_experts_per_tok": mc.num_experts_per_tok,
             "norm_topk_prob": mc.norm_topk_prob,
             "tie_word_embeddings": mc.tie_embeddings}
+
+
+def lfm2_reference():
+    """The benchmark's plain float32 reference of the hybrid family
+    (benchmarks/reference/lfm2_decoder.py), as a module."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "reference",
+        "lfm2_decoder.py")
+    spec = importlib.util.spec_from_file_location("lfm2_decoder", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def lfm2_keys(mc) -> dict:
+    """What a configuration file says of the hybrid ModelConfig `mc`, in
+    the published spellings: all that reference reads."""
+    return {"hidden_size": mc.hidden_size,
+            "num_attention_heads": mc.num_heads,
+            "num_key_value_heads": mc.num_kv_heads, "head_dim": mc.head_dim,
+            "norm_eps": mc.rms_norm_eps, "rope_theta": mc.rope_theta,
+            "qk_norm": mc.qk_norm, "layer_types": list(mc.layer_types),
+            "num_dense_layers": mc.num_dense_layers,
+            "conv_L_cache": mc.conv_L_cache, "num_experts": mc.num_experts,
+            "num_experts_per_tok": mc.num_experts_per_tok,
+            "norm_topk_prob": mc.norm_topk_prob,
+            "norm_topk_eps": mc.norm_topk_eps,
+            "router_score": mc.router_score,
+            "use_expert_bias": mc.use_expert_bias,
+            "routed_scaling_factor": mc.routed_scaling_factor,
+            "tie_word_embeddings": mc.tie_embeddings}
