@@ -250,11 +250,13 @@ def cache_dir() -> str:
     return compile_cache_dir()
 
 
-def cache_entries() -> int:
+def cache_entries() -> set:
+    """Names, not a count: a cache kept at a size cap (the chip machines
+    come with one) evicts as it writes, so only new names show a write."""
     try:
-        return len(os.listdir(cache_dir()))
+        return set(os.listdir(cache_dir()))
     except OSError:
-        return 0
+        return set()
 
 
 # ----------------------------------------------------------------- phases
@@ -369,6 +371,7 @@ def server_status(server: Server, cfg, want_impl: str = "pallas"):
         "layers": cfg.num_layers, "hidden": cfg.hidden_size,
         "param_bytes": rts[0]["param_bytes"], "kv_bytes": rts[0]["kv_bytes"],
         "attn_impl": [r["attn_impl"] for r in rts],
+        "attn_inner": [r.get("attn_inner") for r in rts],
         "runtime_devices": [r["devices"] for r in rts],
         "hbm_used": [c["hbm_used"] for c in stats["chips"]],
         "compile_total": compiles,
@@ -404,13 +407,14 @@ def one_chip_status(server: Server, cfg, out: dict) -> dict:
 
 
 def warm_request(server: Server, rehearse: bool, first: dict,
-                 entries_before: int) -> dict:
+                 entries_before: set) -> dict:
     """On a second start, over the compile cache the first one filled:
     the same request gives the same tokens; its first-call walls show
     what the cache saves."""
     # (jax caches only compiles of a second or more: test-tiny's on the
     # CPU stay under that, so a rehearsal cannot expect new entries.)
-    check(rehearse or cache_entries() > entries_before,
+    written = cache_entries() - entries_before
+    check(rehearse or bool(written),
           f"the first server wrote nothing to {cache_dir()}")
     t0 = time.monotonic()
     ids = server.generate_ids(first["prompt"], N_PREDICT)
@@ -419,7 +423,8 @@ def warm_request(server: Server, rehearse: bool, first: dict,
           f"warm tokens differ: {ids} vs {first['greedy_ids']}")
     _, prof = http(server.port, "/debug/stepprof")
     return {"first_request_s": first_s, "cache_dir": cache_dir(),
-            "cache_entries": cache_entries(),
+            "cache_entries": len(cache_entries()),
+            "cache_entries_written": len(written),
             "compile_walls_ms": [[e["site"], e["key"], e["wall_ms"]]
                                  for e in prof.get("compile_events", [])]}
 

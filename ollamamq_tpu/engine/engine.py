@@ -678,7 +678,17 @@ class ModelRuntime:
         # its dispatches loudly instead of being swapped out.
         self.attn_impl, why = select_attn_impl(
             jax.default_backend(), engine_cfg.kv_dtype)
-        log.info("%s: attention=%s (%s)", name, self.attn_impl, why)
+        # Which inner product each Pallas kernel is built with at this
+        # model's query group (ops/pallas/kv_contract.py): every launch of
+        # that kernel, for the runtime's life. None without the kernels.
+        self.attn_inner = None
+        if self.attn_impl == "pallas":
+            from ollamamq_tpu.ops.pallas.kv_contract import inner_report
+            self.attn_inner = inner_report(
+                model_cfg.num_heads // model_cfg.num_kv_heads)
+        log.info("%s: attention=%s (%s)%s", name, self.attn_impl, why,
+                 "".join(f" {k}={v}" for k, v in
+                         (self.attn_inner or {}).items()))
         # Ragged mixed-batch scheduling: prefill spans + decode tokens
         # pack into ONE token-budget dispatch (no bucket padding).
         g = max(1, engine_cfg.token_granule)
@@ -3037,6 +3047,7 @@ class ModelRuntime:
             "weights_dtype": self.weights_dtype,
             "kv_dtype": self.kv_dtype,
             "attn_impl": self.attn_impl,
+            "attn_inner": self.attn_inner,
             "devices": self.devices,
             # None = caching disabled (the TUI renders "cache n/a").
             "prefix_cache": (self.prefix_cache.stats()
@@ -3160,6 +3171,7 @@ class EncoderRuntime:
             "weights_dtype": self.ecfg.weights_dtype,
             "kv_dtype": "bfloat16",  # encoders hold no KV pool
             "attn_impl": "jnp",  # bidirectional attention has no kernel
+            "attn_inner": None,
             "prefix_cache": None,  # encoders hold no KV to share
             "spec": None,  # encoders decode nothing to speculate on
         }

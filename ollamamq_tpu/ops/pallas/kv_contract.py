@@ -1,0 +1,490 @@
+"""What the two paged-attention kernels share: the page stream and the
+inner product.
+
+`ragged_attention.py` (one program a G_TILE-token tile of a mixed stream)
+and `paged_attention.py` (one program a decode row) differ in their grid
+and in who starts a sequence's first DMAs. Everything inside a sequence's
+walk is here, once: `PageStream` moves K/V pages HBM→VMEM in blocks
+through a ring of buffers, and an inner product — `Mxu` or `Vpu` —
+folds one buffered block into the online-softmax state `(acc, m_i, l_i)`
+of the rows the program holds.
+
+Which inner product (`choose_inner`, a function of the row-heads M =
+rows × group that share each kv head's K/V in one program — known at
+trace time from the kernel and the model's `(group, head_dim)`; no flag,
+no environment variable, no model name):
+
+  M > 1 → `Mxu`: the ragged kernel always (a tile's 8 rows), the decode
+      kernel when group > 1. Per block and per lane tile ONE contraction
+      serves every query row-head that shares the K/V: scores `[M, blk] =
+      q_t [M, W] · k_t [blk, W]ᵀ` on the MXU with the pool's dtype as
+      operands (bf16 × bf16 products are exact) and float32 accumulation,
+      an online softmax on `[M, blk]` with the block's tokens along the
+      lanes (a block is 128 tokens: 4 pages of 32), `acc_t [M, W] +=
+      p · v_t` on the MXU again. `m_i`, `l_i`, `acc` are touched once a
+      (block, tile), not once a (row, group, page).
+        head_dim % 128 == 0: a lane tile IS a kv head, `k_buf[..., h*hd:
+      (h+1)*hd]` is a free lane-tile slice of the buffer, M = rows*group
+      (56 for Qwen2.5-7B's ragged tile, padded to 64).
+        head_dim < 128 (64: LFM2, llama3.2): 128 // hd heads share a lane
+      tile and Mosaic cannot slice inside one. The WRAPPER packs q so
+      that each head's rows carry zeros in the other heads' lanes and
+      stacks the tile's heads along M; the kernel contracts the whole
+      tile — the zeros drop the other heads' k out of the scores — and
+      the wrapper keeps each row's own lanes of the result. Twice the
+      MXU work, no relayout, and the kernel does not know: it sees
+      `tiles` tiles of width W with `Mp` rows each.
+  M == 1 → `Vpu`: the decode kernel of an MHA model (OLMoE, 16 × 128).
+      The body both kernels had before PR 33: per (group, page) `k * q`
+      over the whole `[page_size, Hk*hd]` page in float32 on the VPU,
+      per-head sums and expansions through constant 0/1 segment matrices
+      `[lanes, Hk]` on the MXU, no relayout at any head_dim. One row-head
+      a kv head is a matrix-vector product, where an MXU pass buys
+      nothing and costs a K-tile weight load a head.
+
+Measured, ms a launch of 64 decode rows over 200-380 tokens of context
+(my chip run, PR 34, `scripts/attn_kernel_bench.py`, one v5e; vpu → mxu):
+  (28, 4, 128)  decode 0.824 → 0.170   ragged 0.981 → 0.180
+  (8, 2, 128)   decode 0.264 → 0.124   ragged 0.329 → 0.138
+  (32, 8, 64)   decode 0.529 → 0.178   ragged 0.620 → 0.191
+  (16, 16, 128) decode 0.368 → 0.473   ragged 0.657 → 0.476
+and with two 228-token prefill spans in a 512-token ragged step 4.48 →
+0.281, 1.01 → 0.206, 2.04 → 0.359, 1.58 → 0.696 (the ragged kernel's vpu
+figures: PR 33's run, before that body was deleted). Only the decode
+kernel at group 1 is faster on the VPU; that is the one shape that keeps
+it.
+
+Which loops are in the program, which in Python, and why. A step program
+is traced, lowered and keyed once a rung of the token ladder at every
+start, compile cache warm or not, and that cost follows the size of the
+kernel's traced body: it is most of `setup_s`, which the benchmark judges
+in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
+128 copies of `Mxu.update` at OLMoE's shape, 8354 equations, a warm rung
+7.7-11.5 s where the VPU body's 64 light copies took 4.5-6.9 s).
+  - IN THE PROGRAM (`lax.fori_loop`): a sequence's blocks (both kernels,
+    always), and since PR 34 the ragged kernel's walk over the at most
+    G_TILE sequences that overlap a tile. The ring restarts a sequence,
+    so no DMA state crosses a trip; the per-sequence scalars are SMEM
+    reads at a dynamic index. Same launch within 2 % at every published
+    shape (0.474 → 0.476, 0.178 → 0.180 ms; PR 34, same run), an eighth
+    of the body: 1146 equations at (16, 16, 128), 450 at (28, 4, 128),
+    whatever G_TILE is (`tests/test_ragged_attention.py` holds that).
+  - IN PYTHON: the lane tiles (`for t in range(self.tiles)` in
+    `Mxu.update` / `finish`) and a block's pages (`PageStream`, at most 4).
+    Mosaic ACCEPTS the lane-tile loop in the program (`bufs[..][slot, :,
+    pl.ds(pl.multiple_of(t * W, 128), W)]`, every published shape, one
+    chip and under `shard_map`), and it would leave ONE copy of the inner
+    product (282 equations at any shape) — but a launch then costs 1.5-2.4
+    times as much: ragged 64 rows 0.180 → 0.361 at (28, 4, 128), 0.138 →
+    0.214 at (8, 2, 128), 0.476 → 1.118 at (16, 16, 128); decode 0.170 →
+    0.331 (PR 34, same run; OLMoE end to end 4170-4259 → 3363-3959
+    tokens/s over 20 s windows). A rolled tile loop serialises what the unrolled one lets
+    the scheduler overlap (tile t+1's MXU pushes under tile t's softmax).
+    So the body grows with the lane tiles alone — 16 at most among the
+    published shapes — and with nothing else.
+`hd % 128 == 0` or `hd == 64` changes none of this: the packing is the
+wrapper's, the kernel sees `tiles` tiles of width W.
+
+P into P·V, and what "float32" meant before: Mosaic's default-precision
+float32 matmul on a v5e rounds its operands to bf16 (my chip run, PR 33:
+`(1 + 2**-10) @ 1` comes back 1.0). So the Vpu body rounds BOTH its
+`(k * q) @ seg` products and the P of `p @ seg_t` to bf16's 8 bits. The
+Mxu body rounds neither: q·k has the stored bf16 values as operands, and
+against a bf16 pool P is split into `PV_TERMS` bf16 terms whose sum is P
+to float32's last bit, stacked along M and contracted ONCE with the
+stored bf16 V (each product exact, float32 accumulation; 3 terms cost
+under 3 % of a launch over 1, same run). Against a float32 (or
+dequantised int8) block both contractions are float32 matmuls at
+Mosaic's default precision, as before.
+
+Mosaic layout constraints (v5e compiler; `tests/test_chip_compile.py`
+asks it at every published shape):
+  - DMA slices are tile-aligned: K/V move as flattened `[page_size,
+    Hk*hd]` rows, `Hk*hd` a multiple of 128; page i of a block lands in
+    rows `i*page_size…` of the block's VMEM buffer.
+  - A static slice of whole 128-lane tiles (`[:, t*W:(t+1)*W]`, W % 128
+    == 0) is free; a slice inside a tile, or a reshape that splits or
+    merges lanes, is an "unsupported shape cast". Hence the wrapper-side
+    packing for head_dim 64 and the segment matrices of the Vpu body.
+  - All head bookkeeping that needs a transpose happens OUTSIDE the
+    kernel, in `pack_q` / `unpack_o` (plain XLA).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = -1e30
+LANE = 128
+# Tokens per grid program of the ragged kernel. 8 keeps the q/o blocks one
+# sublane tile tall and bounds the worst case (8 distinct decode
+# sequences) to the same page-loop total work as 8 decode-kernel programs.
+G_TILE = 8
+_NN = (((1,), (0,)), ((), ()))  # [M, K] · [K, N]
+_NT = (((1,), (1,)), ((), ()))  # [M, K] · [N, K]ᵀ
+
+# bf16 terms P is split into before P·V against a bf16 pool: 3 terms of 8
+# significant bits carry float32's 24 (module docstring).
+PV_TERMS = 3
+
+
+def _dot(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dimension_numbers=dims,
+                               preferred_element_type=jnp.float32)
+
+
+def choose_inner(rows: int, group: int) -> str:
+    """"mxu" or "vpu" for a kernel whose programs hold `rows` query rows
+    (G_TILE in the ragged kernel, 1 in the decode kernel) of `group` query
+    heads a kv head: the module docstring says why."""
+    return "vpu" if rows * group == 1 else "mxu"
+
+
+def inner_report(group: int) -> dict:
+    """Which inner product each kernel of a model with this query group is
+    built with — what a runtime reports beside `attn_impl`."""
+    return {"ragged": choose_inner(G_TILE, group),
+            "decode": choose_inner(1, group)}
+
+
+def make_inner(name, *, rows, group, num_kv_heads, head_dim, page_size):
+    cls = {"mxu": Mxu, "vpu": Vpu}[name or choose_inner(rows, group)]
+    return cls(rows, group, num_kv_heads, head_dim, page_size)
+
+
+def ring_grid_spec(inner, ring, grid, num_scalar_prefetch, pools):
+    """(nbuf, grid spec) of either kernel: one program a grid step, q and
+    o blocked in VMEM by program in the inner product's packed layout, the
+    pools — K, V and an int8 pool's two scale planes — left in HBM, and as
+    scratch a ring of `nbuf` block buffers a pool (`ring` pages in flight,
+    at least two blocks), the softmax state and the DMA semaphores."""
+    nbuf = max(2, ring // inner.block_pages)
+    blk = inner.block_pages * inner.page_size
+    q_block = inner.q_block
+    q_spec = pl.BlockSpec(
+        q_block, lambda i, *_: (i,) + (0,) * (len(q_block) - 1),
+        memory_space=pltpu.VMEM)
+    return nbuf, pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch,
+        grid=grid,
+        in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((nbuf, blk, p.shape[-1]), p.dtype)
+                        for p in pools] + inner.scratch()
+        + [pltpu.SemaphoreType.DMA((nbuf, len(pools)))],
+    )
+
+
+def split_refs(refs):
+    """A kernel's refs after the scalar prefetch, as `ring_grid_spec` lays
+    them out: (q, the n pools in HBM, o, their n ring buffers, (acc, m_i,
+    l_i), the semaphores)."""
+    n = (len(refs) - 6) // 2
+    return (refs[0], refs[1:1 + n], refs[1 + n], refs[2 + n:2 + 2 * n],
+            refs[2 + 2 * n:-1], refs[-1])
+
+
+class PageStream:
+    """One sequence's K/V pages, HBM→VMEM, a block of `block_pages` pages
+    at a time into ring slot `slot` of `[nbuf, block_pages*page_size,
+    lanes]` buffers (page i of the block at rows i*page_size…). Starts and
+    waits share one condition — the page exists — so the semaphores of a
+    slot (one a buffer) always balance."""
+
+    def __init__(self, hbm, bufs, sems, layer, page_table_ref, page_size,
+                 block_pages):
+        self.hbm, self.bufs, self.sems = hbm, bufs, sems
+        self.layer, self.page_table_ref = layer, page_table_ref
+        self.page_size, self.block_pages = page_size, block_pages
+
+    def _each_page(self, slot, row, block, npages, cond, op):
+        ps = self.page_size
+        for i in range(self.block_pages):
+            page_idx = block * self.block_pages + i
+            exists = page_idx < npages
+            if cond is not None:
+                exists = cond & exists
+
+            @pl.when(exists)
+            def _(i=i, page_idx=page_idx):
+                start = self.page_table_ref[row, page_idx] * ps
+                for n, (src, dst) in enumerate(zip(self.hbm, self.bufs)):
+                    op(pltpu.make_async_copy(
+                        src.at[self.layer, pl.ds(start, ps)],
+                        dst.at[slot, pl.ds(i * ps, ps)],
+                        self.sems.at[slot, n]))
+
+    def start(self, slot, row, block, npages, cond=None):
+        self._each_page(slot, row, block, npages, cond, lambda c: c.start())
+
+    def wait(self, slot, row, block, npages):
+        self._each_page(slot, row, block, npages, None, lambda c: c.wait())
+
+
+def _segments(num_kv_heads, head_dim):
+    """SEG[d, h] = 1 iff lane d belongs to kv head h, and its transpose:
+    constant f32 matrices that let the MXU do per-head lane reductions and
+    expansions without relayouts."""
+    lanes = num_kv_heads * head_dim
+
+    def one(shape, lane_dim):
+        return (jax.lax.broadcasted_iota(jnp.int32, shape, lane_dim)
+                // head_dim
+                == jax.lax.broadcasted_iota(jnp.int32, shape, 1 - lane_dim)
+                ).astype(jnp.float32)
+
+    return one((lanes, num_kv_heads), 0), one((num_kv_heads, lanes), 1)
+
+
+def _load_block(bufs, slot, seg_t):
+    """(k, v) of ring slot `slot` as float32 values; an int8 block's scale
+    rows `[blk, Hk]` expand to lane segments through `seg_t`."""
+    k = bufs[0][slot].astype(jnp.float32)
+    v = bufs[1][slot].astype(jnp.float32)
+    if len(bufs) == 4:
+        k = k * _dot(bufs[2][slot], seg_t)
+        v = v * _dot(bufs[3][slot], seg_t)
+    return k, v
+
+
+@dataclasses.dataclass(frozen=True)
+class _Inner:
+    rows: int  # query rows a program holds: G_TILE, or 1 (decode kernel)
+    group: int
+    num_kv_heads: int
+    head_dim: int
+    page_size: int
+
+    @property
+    def lanes(self):
+        return self.num_kv_heads * self.head_dim
+
+    def init(self, bufs, acc, m_i, l_i):
+        # A block's rows past the sequence's last page are never written
+        # by a DMA, and 0 * (what VMEM held at power-on) is not 0: clear
+        # the ring once a launch; afterwards it only ever holds pool rows.
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            for buf in bufs:
+                buf[...] = jnp.zeros_like(buf)
+
+        acc[...] = jnp.zeros_like(acc)
+        m_i[...] = jnp.full_like(m_i, NEG_INF)
+        l_i[...] = jnp.zeros_like(l_i)
+
+
+class Vpu(_Inner):
+    """One VPU pass a (group, page) of ONE decode row; see the module
+    docstring."""
+
+    name = "vpu"
+    block_pages = 1
+
+    def __post_init__(self):
+        assert self.rows == 1, "the Vpu inner product serves a decode row"
+
+    def pack_q(self, q):
+        """[N, H, hd] → [N, group, lanes], query-group-major: row g holds
+        every kv head's group-g query in its lane segment."""
+        n = q.shape[0]
+        return q.reshape(n, self.num_kv_heads, self.group,
+                         self.head_dim).transpose(0, 2, 1, 3).reshape(
+                             n, self.group, self.lanes)
+
+    def unpack_o(self, o):
+        n = o.shape[0]
+        return o.reshape(n, self.group, self.num_kv_heads,
+                         self.head_dim).transpose(0, 2, 1, 3).reshape(
+                             n, self.group * self.num_kv_heads,
+                             self.head_dim)
+
+    @property
+    def q_block(self):
+        return (1, self.group, self.lanes)
+
+    def scratch(self):
+        return [pltpu.VMEM((self.group, self.lanes), jnp.float32),
+                pltpu.VMEM((self.group, self.num_kv_heads), jnp.float32),
+                pltpu.VMEM((self.group, self.num_kv_heads), jnp.float32)]
+
+    def update(self, q_ref, bufs, slot, span, pos0, state, done_reading):
+        kv = span[-1]
+        acc, m_i, l_i = state
+        seg, seg_t = _segments(self.num_kv_heads, self.head_dim)
+        k, v = _load_block(bufs, slot, seg_t)  # [ps, lanes] f32
+        done_reading()  # values are loaded: the slot may refill now
+        scale = 1.0 / (self.head_dim ** 0.5)
+        # Valid-position mask for this page (the last may be partial).
+        valid = pos0 + jax.lax.broadcasted_iota(
+            jnp.int32, (self.page_size, self.num_kv_heads), 0) < kv
+        for g in range(self.group):  # static unroll; group is small (1-8)
+            qg = q_ref[0, g:g + 1, :].astype(jnp.float32)  # [1, lanes]
+            # scores[t, h] = sum_d q[h-seg d] * k[t, d]: masked-lane
+            # elementwise product + segment-sum on the MXU.
+            sc = jnp.where(valid, _dot(k * qg, seg) * scale, NEG_INF)
+            m_prev = m_i[g:g + 1, :]  # [1, Hk]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)  # [1, Hk]
+            p_ij = jnp.exp(sc - m_new)  # [ps, Hk]
+            l_i[g:g + 1, :] = l_i[g:g + 1, :] * alpha + jnp.sum(
+                p_ij, axis=0, keepdims=True)
+            # Per-head weights expanded back to lane segments, then a
+            # sublane reduction contracts over page positions.
+            contrib = jnp.sum(_dot(p_ij, seg_t) * v, axis=0, keepdims=True)
+            acc[g:g + 1, :] = acc[g:g + 1, :] * _dot(alpha, seg_t) + contrib
+            m_i[g:g + 1, :] = m_new
+
+    def finish(self, o_ref, state):
+        acc, _, l_i = state
+        _, seg_t = _segments(self.num_kv_heads, self.head_dim)
+        denom = _dot(jnp.maximum(l_i[...], 1e-20), seg_t)  # [group, lanes]
+        o_ref[0] = (acc[...] / denom).astype(o_ref.dtype)
+
+
+def _lane_fit(x, n):
+    """A lane-replicated `[M, 128]` statistic at width n."""
+    if n == LANE:
+        return x
+    if n % LANE == 0:
+        return jnp.tile(x, (1, n // LANE))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+class Mxu(_Inner):
+    """One MXU contraction a (block, lane tile) for every row-head that
+    shares it; see the module docstring."""
+
+    name = "mxu"
+
+    @property
+    def heads_per_tile(self):
+        if self.head_dim >= LANE:
+            return 1
+        return max(d for d in range(1, self.num_kv_heads + 1)
+                   if self.num_kv_heads % d == 0
+                   and d * self.head_dim <= LANE)
+
+    @property
+    def width(self):  # lanes of one contraction
+        return self.heads_per_tile * self.head_dim
+
+    @property
+    def tiles(self):
+        return self.lanes // self.width
+
+    @property
+    def m(self):  # row-heads of one kv head
+        return self.rows * self.group
+
+    @property
+    def mp(self):  # rows of one contraction: bf16 packs 16 to a sublane tile
+        return -(-self.heads_per_tile * self.m // 16) * 16
+
+    @property
+    def block_pages(self):
+        # Enough pages to fill the scores' 128 lanes, and no more than 4
+        # DMA pairs a block: their issue and wait code is unrolled.
+        return max(1, min(4, LANE // self.page_size))
+
+    def pack_q(self, q):
+        """[N*rows, H, hd] → [N, tiles, Mp, W]: row i*M + g*rows + r of
+        tile t is query head (t*hpt + i)*group + g of row r, in head i's
+        lanes of the tile and zero in the others'."""
+        hpt, hd = self.heads_per_tile, self.head_dim
+        n = q.shape[0] // self.rows
+        x = q.reshape(n, self.rows, self.tiles, hpt, self.group, hd)
+        x = x.transpose(0, 2, 3, 4, 1, 5).reshape(
+            n, self.tiles, hpt, self.m, hd)
+        if hpt > 1:
+            eye = jnp.eye(hpt, dtype=q.dtype)
+            x = x[:, :, :, :, None, :] * eye[:, None, :, None]
+        x = x.reshape(n, self.tiles, hpt * self.m, self.width)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, self.mp - hpt * self.m),
+                           (0, 0)))
+
+    def unpack_o(self, o):
+        hpt, hd = self.heads_per_tile, self.head_dim
+        n = o.shape[0]
+        x = o[:, :, :hpt * self.m].reshape(
+            n, self.tiles, hpt, self.m, hpt, hd)
+        x = jnp.stack([x[:, :, i, :, i] for i in range(hpt)], axis=2)
+        x = x.reshape(n, self.tiles, hpt, self.group, self.rows, hd)
+        return x.transpose(0, 4, 1, 2, 3, 5).reshape(
+            n * self.rows, self.num_kv_heads * self.group, hd)
+
+    @property
+    def q_block(self):
+        return (1, self.tiles, self.mp, self.width)
+
+    def scratch(self):
+        return [pltpu.VMEM((self.tiles, self.mp, self.width), jnp.float32),
+                pltpu.VMEM((self.tiles, self.mp, LANE), jnp.float32),
+                pltpu.VMEM((self.tiles, self.mp, LANE), jnp.float32)]
+
+    def _pv(self, p, v):
+        """p [Mp, blk] f32 · v [blk, W] → [Mp, W] f32, P kept to float32's
+        last bit (module docstring)."""
+        if v.dtype != jnp.bfloat16:
+            return _dot(p, v.astype(jnp.float32))
+        terms, rest = [], p
+        for i in range(PV_TERMS):
+            terms.append(rest.astype(jnp.bfloat16))
+            if i + 1 < PV_TERMS:
+                rest = rest - terms[-1].astype(jnp.float32)
+        out = _dot(jnp.concatenate(terms, axis=0), v)  # [terms*Mp, W]
+        return sum(out[i * self.mp:(i + 1) * self.mp]
+                   for i in range(PV_TERMS))
+
+    def update(self, q_ref, bufs, slot, span, pos0, state, done_reading):
+        tile_start, qs, ql, kv = span
+        acc, m_i, l_i = state
+        W, mp = self.width, self.mp
+        blk = self.block_pages * self.page_size
+        scale = 1.0 / (self.head_dim ** 0.5)
+        pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (mp, blk), 1)
+        if self.rows == 1:
+            valid = pos < kv
+        else:
+            # Row-head i*M + g*rows + r is token tile_start + r: inside
+            # this sequence's span it sees positions up to its own (which
+            # lies below kv), outside it nothing.
+            tok = tile_start + (jax.lax.broadcasted_iota(
+                jnp.int32, (mp, blk), 0) & (self.rows - 1))
+            valid = ((tok >= qs) & (tok < qs + ql)
+                     & (pos <= kv - ql + (tok - qs)))
+        if len(bufs) == 4:  # int8: dequantise the block, then the same
+            _, seg_t = _segments(self.num_kv_heads, self.head_dim)
+            k_all, v_all = _load_block(bufs, slot, seg_t)
+        for t in range(self.tiles):
+            lanes = slice(t * W, (t + 1) * W)
+            if len(bufs) == 4:
+                k, v = k_all[:, lanes], v_all[:, lanes]
+            else:
+                k, v = bufs[0][slot, :, lanes], bufs[1][slot, :, lanes]
+            q = q_ref[0, t]  # [Mp, W]
+            if q.dtype != k.dtype:
+                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+            sc = jnp.where(valid, _dot(q, k, _NT) * scale, NEG_INF)
+            m_prev = m_i[t]  # [Mp, 128], lane-replicated
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            # Rows outside the span, and blocks wholly beyond a row's
+            # causal frontier, leave every score at NEG_INF: guard the
+            # exps so the no-op update stays a no-op.
+            alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                              jnp.exp(m_prev - m_new))
+            p = jnp.where(valid, jnp.exp(sc - _lane_fit(m_new, blk)), 0.0)
+            l_i[t] = l_i[t] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc[t] = acc[t] * _lane_fit(alpha, W) + self._pv(p, v)
+            m_i[t] = m_new
+        done_reading()
+
+    def finish(self, o_ref, state):
+        acc, _, l_i = state
+        for t in range(self.tiles):
+            denom = _lane_fit(jnp.maximum(l_i[t], 1e-20), self.width)
+            o_ref[0, t] = (acc[t] / denom).astype(o_ref.dtype)

@@ -8,18 +8,22 @@ buffered DMA streams K/V pages HBM→VMEM while the previous page's partial
 attention accumulates with an online (flash-style) softmax, so HBM reads
 scale with true context length (ragged), not the padded maximum.
 
-Mosaic layout constraints (learned against the real v5e compiler):
-  - DMA slices must be tile-aligned: a [.., Hk, hd=64] block sits padded
-    inside 128-lane tiles and cannot be sliced, so K/V move as flattened
-    [page_size, Hk*hd] rows (Hk*hd is a multiple of 128).
-  - In-kernel reshapes/transposes that split or merge the lane dim are
-    "unsupported shape cast" relayouts. GQA head bookkeeping therefore
-    happens OUTSIDE the kernel: q arrives packed as [B, group, Hk*hd]
-    (query-group-major, kv-segment lanes) and per-head score/weight
-    segmentation uses constant 0/1 segment matrices on the MXU:
-        scores_g = (k_row * q_g) @ SEG          [ps, Hk]
-        expand_g = p_g @ SEG.T                  [ps, Hk*hd]
-    so every vector op keeps its layout end to end.
+The page walk and the inner product are shared with the ragged kernel
+(`ops/pallas/kv_contract.py`, which also holds the Mosaic layout
+constraints — learned against the real v5e compiler — and which shapes
+take which inner product): K/V move as flattened [page_size, Hk*hd] rows
+in blocks through a ring of VMEM buffers, and each block folds into the
+row's online-softmax state on the MXU — one contraction a (block, kv
+head) for all `group` query heads that share the kv head — when group >
+1, on the VPU with 0/1 segment matrices when group == 1. All GQA head
+bookkeeping that needs a transpose happens OUTSIDE the kernel (`pack_q`
+/ `unpack_o`, plain XLA), so every vector op keeps its layout end to end.
+`head_dim % 128 == 0`: a kv head is a lane tile of the block, sliced for
+free; `head_dim == 64`: the wrapper packs two heads a tile, the kernel
+does not know. The row's blocks are a loop in the program; the lane
+tiles (MXU body) or query-group heads (VPU body) are unrolled in Python —
+at most 16 / 8 copies, a body small enough that a decode scan costs
+~1.9 s to trace and lower (`kv_contract.py`: why not the tiles too).
 
 Layout contract (matches engine/kv_cache.py):
     k_cache, v_cache: [L, S, Hk*hd] — the WHOLE slot pool in the layout
@@ -45,9 +49,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from ollamamq_tpu.ops.pallas.kv_contract import (PageStream, make_inner,
+                                                 ring_grid_spec, split_refs)
+
+# Pages in flight per sequence: measured on v5e, 4-16 are within noise
+# of each other (the DMA path is issue-overhead-bound); 8 is the middle.
+RING = 8
 
 
 def _decode_kernel(
@@ -55,28 +63,18 @@ def _decode_kernel(
     layer_ref,  # [1] SMEM: the pool layer this launch attends over
     page_table_ref,  # [B, max_pages] SMEM
     seq_lens_ref,  # [B] SMEM
-    # inputs + output + scratch (quantized pools append scale planes —
-    # see the unpack below; layouts match the unquantized kernel)
-    *refs,
-    page_size: int,
+    *refs,  # kv_contract.split_refs
+    inner,  # kv_contract.Mxu | Vpu
+    nbuf: int,
     max_pages: int,
-    num_heads: int,
-    num_kv_heads: int,
-    head_dim: int,
-    ring: int,
-    quantized: bool,
 ):
-    if quantized:
-        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-         k_buf, v_buf, ks_buf, vs_buf, acc, m_i, l_i, sems) = refs
-    else:
-        (q_ref, k_hbm, v_hbm, o_ref,
-         k_buf, v_buf, acc, m_i, l_i, sems) = refs
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+    q_ref, hbm, o_ref, bufs, state, sems = split_refs(refs)
     b = pl.program_id(0)
     nb = pl.num_programs(0)
-    layer = layer_ref[0]
     seq_len = seq_lens_ref[b]
+    page_size, bp = inner.page_size, inner.block_pages
+    stream = PageStream(hbm, bufs, sems, layer_ref[0], page_table_ref,
+                        page_size, bp)
 
     # Clamp to the table width: a seq_len beyond capacity must not index
     # page_table out of bounds (the jnp reference implicitly truncates the
@@ -87,153 +85,46 @@ def _decode_kernel(
         )
 
     num_pages = pages_of(b)
-    group = num_heads // num_kv_heads
-    lanes = num_kv_heads * head_dim
-
-    def page_dma(slot, row, page_idx):
-        page_id = page_table_ref[row, page_idx]
-        start = page_id * page_size
-        copies = [
-            pltpu.make_async_copy(
-                k_hbm.at[layer, pl.ds(start, page_size)], k_buf.at[slot],
-                sems.at[slot, 0]),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, pl.ds(start, page_size)], v_buf.at[slot],
-                sems.at[slot, 1]),
-        ]
-        if quantized:
-            copies.append(pltpu.make_async_copy(
-                ks_hbm.at[layer, pl.ds(start, page_size)], ks_buf.at[slot],
-                sems.at[slot, 2]))
-            copies.append(pltpu.make_async_copy(
-                vs_hbm.at[layer, pl.ds(start, page_size)], vs_buf.at[slot],
-                sems.at[slot, 3]))
-        return copies
-
-    def start_page(slot, row, page_idx):
-        for dma in page_dma(slot, row, page_idx):
-            dma.start()
+    inner.init(bufs, *state)
 
     # Fill the ring — but ONLY for the first grid program: every later
-    # program's first `ring` pages were started by its predecessor's
+    # program's first `nbuf` blocks were started by its predecessor's
     # epilogue (cross-program prefetch), so the DMA pipeline never drains
-    # at a program boundary. Starts and waits share the same `i <
-    # num_pages` condition, so semaphore counts always balance.
-    for i in range(ring):
-        @pl.when((b == 0) & (i < num_pages))
-        def _(i=i):
-            start_page(i % ring, b, i)
-
-    acc[...] = jnp.zeros_like(acc)
-    m_i[...] = jnp.full_like(m_i, NEG_INF)
-    l_i[...] = jnp.zeros_like(l_i)
-
-    scale = 1.0 / (head_dim ** 0.5)
-    # Segment matrices: SEG[d, h] = 1 iff lane d belongs to kv head h.
-    # Constant f32 [lanes, Hk] / [Hk, lanes]; they ride VMEM and let the
-    # MXU do per-head lane reductions/expansions without relayouts.
-    seg = (
-        jax.lax.broadcasted_iota(jnp.int32, (lanes, num_kv_heads), 0)
-        // head_dim
-        == jax.lax.broadcasted_iota(jnp.int32, (lanes, num_kv_heads), 1)
-    ).astype(jnp.float32)
-    seg_t = (
-        jax.lax.broadcasted_iota(jnp.int32, (num_kv_heads, lanes), 1)
-        // head_dim
-        == jax.lax.broadcasted_iota(jnp.int32, (num_kv_heads, lanes), 0)
-    ).astype(jnp.float32)
+    # at a program boundary. Starts and waits share the same "the page
+    # exists" condition, so semaphore counts always balance.
+    for i in range(nbuf):
+        stream.start(i, b, i, num_pages, cond=b == 0)
 
     def body(p, _):
-        slot = p % ring
-
-        for dma in page_dma(slot, b, p):
-            dma.wait()
-
-        k = k_buf[slot].astype(jnp.float32)  # [ps, lanes]
-        v = v_buf[slot].astype(jnp.float32)
-        if quantized:
-            # In-kernel dequant: per-head scale rows expand to lane
-            # segments via the seg_t MXU trick (no relayouts).
-            k = k * jax.lax.dot_general(
-                ks_buf[slot], seg_t,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            v = v * jax.lax.dot_general(
-                vs_buf[slot], seg_t,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        # Ring slot consumed (values loaded above): refill it with the
-        # page `ring` ahead, keeping ring-1 copies in flight.
-        @pl.when(p + ring < num_pages)
-        def _():
-            start_page(slot, b, p + ring)
-        # Valid-position mask for this page (final page may be partial).
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, num_kv_heads), 0
-        )
-        valid = pos < seq_len  # [ps, Hk]
-
-        for g in range(group):  # static unroll; group is small (1-8)
-            qg = q_ref[0, g : g + 1, :].astype(jnp.float32)  # [1, lanes]
-            # scores[t, h] = sum_d q[h-seg d] * k[t, d]  via masked-lane
-            # elementwise product + segment-sum on the MXU.
-            s = jax.lax.dot_general(
-                k * qg, seg,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [ps, Hk]
-            s = jnp.where(valid, s, NEG_INF)
-
-            # Online softmax update for this query group.
-            m_prev = m_i[g : g + 1, :]  # [1, Hk]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)  # [1, Hk]
-            p_ij = jnp.exp(s - m_new)  # [ps, Hk]
-            l_i[g : g + 1, :] = l_i[g : g + 1, :] * alpha + jnp.sum(
-                p_ij, axis=0, keepdims=True
-            )
-            # Per-head weights expanded back to lane segments, then a
-            # sublane reduction contracts over page positions.
-            e = jax.lax.dot_general(
-                p_ij, seg_t,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [ps, lanes]
-            contrib = jnp.sum(e * v, axis=0, keepdims=True)  # [1, lanes]
-            alpha_l = jax.lax.dot_general(
-                alpha, seg_t,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [1, lanes]
-            acc[g : g + 1, :] = acc[g : g + 1, :] * alpha_l + contrib
-            m_i[g : g + 1, :] = m_new
+        slot = p % nbuf
+        stream.wait(slot, b, p, num_pages)
+        inner.update(
+            q_ref, bufs, slot, (0, 0, 1, seq_len), p * (bp * page_size),
+            state,
+            # Ring slot consumed: refill it with the block `nbuf` ahead,
+            # keeping nbuf-1 blocks in flight.
+            lambda: stream.start(slot, b, p + nbuf, num_pages))
         return ()
 
-    jax.lax.fori_loop(0, num_pages, body, ())
+    jax.lax.fori_loop(0, pl.cdiv(num_pages, bp), body, ())
 
-    # Cross-program prefetch: start the NEXT batch element's first `ring`
-    # pages. Every one of this program's copies has been consumed by the
-    # loop above (refills are guarded to < num_pages), so all ring slots
-    # are free; the next program starts no DMAs of its own and its body
-    # waits land on copies already in flight. The row index is clamped
-    # BEFORE the predicate so the last program never reads seq_lens_ref
-    # out of bounds (the b+1 < nb guard then discards the dummy value).
+    # Cross-program prefetch: start the NEXT batch element's first `nbuf`
+    # blocks. Every one of this program's copies has been consumed by the
+    # loop above (refills are guarded to existing pages), so all ring
+    # slots are free; the next program starts no DMAs of its own and its
+    # body waits land on copies already in flight. The row index is
+    # clamped BEFORE the predicate so the last program never reads
+    # seq_lens_ref out of bounds (the b+1 < nb guard then discards the
+    # dummy value).
     succ = jnp.minimum(b + 1, nb - 1)
-    for i in range(ring):
-        @pl.when((b + 1 < nb) & (i < pages_of(succ)))
-        def _(i=i):
-            start_page(i % ring, succ, i)
+    for i in range(nbuf):
+        stream.start(i, succ, i, pages_of(succ), cond=b + 1 < nb)
 
-    denom = jax.lax.dot_general(
-        jnp.maximum(l_i[...], 1e-20), seg_t,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [group, lanes]
-    o_ref[0] = (acc[...] / denom).astype(o_ref.dtype)
+    inner.finish(o_ref, state)
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("page_size", "interpret", "inner"))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
     k_cache: jnp.ndarray,  # [L, S, Hk*hd] (int8 when k_scale is passed)
@@ -245,80 +136,34 @@ def paged_decode_attention_pallas(
     interpret: bool = False,
     k_scale=None,  # [L, S, Hk] f32 per-slot per-head scales (int8 pools)
     v_scale=None,
+    inner: str | None = None,  # tests and microbenchmarks only: the
+    #   serving path leaves the inner product to kv_contract.choose_inner
 ) -> jnp.ndarray:
-    quantized = k_scale is not None
     B, H, hd = q.shape
     max_pages = page_table.shape[1]
     lanes = k_cache.shape[-1]
     Hk = lanes // hd
-    group = H // Hk
+    inner = make_inner(inner, rows=1, group=H // Hk, num_kv_heads=Hk,
+                       head_dim=hd, page_size=page_size)
 
-    # Pages in flight per sequence: measured on v5e, 4-16 are within noise
-    # of each other (the DMA path is issue-overhead-bound); 8 is the middle.
-    ring = 8
+    pools = [k_cache, v_cache]
+    if k_scale is not None:  # an int8 pool's scale planes
+        pools += [k_scale, v_scale]
+    nbuf, grid_spec = ring_grid_spec(inner, RING, (B,), 3, pools)
     kernel = functools.partial(
         _decode_kernel,
-        page_size=page_size,
+        inner=inner,
+        nbuf=nbuf,
         max_pages=max_pages,
-        num_heads=H,
-        num_kv_heads=Hk,
-        head_dim=hd,
-        ring=ring,
-        quantized=quantized,
     )
 
-    in_specs = [
-        pl.BlockSpec((1, group, lanes), lambda b, *_: (b, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec(memory_space=pl.ANY),  # k stays in HBM
-        pl.BlockSpec(memory_space=pl.ANY),  # v stays in HBM
-    ]
-    scratch = [
-        pltpu.VMEM((ring, page_size, lanes), k_cache.dtype),
-        pltpu.VMEM((ring, page_size, lanes), v_cache.dtype),
-    ]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec(memory_space=pl.ANY),  # k scale rows (HBM)
-            pl.BlockSpec(memory_space=pl.ANY),  # v scale rows (HBM)
-        ]
-        scratch += [
-            pltpu.VMEM((ring, page_size, Hk), jnp.float32),
-            pltpu.VMEM((ring, page_size, Hk), jnp.float32),
-        ]
-    scratch += [
-        pltpu.VMEM((group, lanes), jnp.float32),
-        pltpu.VMEM((group, Hk), jnp.float32),
-        pltpu.VMEM((group, Hk), jnp.float32),
-        pltpu.SemaphoreType.DMA((ring, 4 if quantized else 2)),
-    ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, group, lanes), lambda b, *_: (b, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=scratch,
-    )
-
-    # Pack q head-group-major so each kernel row g holds every kv head's
-    # group-g query in its lane segment: q_packed[b, g, h*hd + d] =
-    # q[b, h*group + g, d]. (Plain XLA transposes are free of Mosaic's
-    # relayout limits; doing this outside the kernel keeps the kernel
-    # relayout-free.)
-    q_packed = (
-        q.reshape(B, Hk, group, hd).transpose(0, 2, 1, 3).reshape(B, group, lanes)
-    )
-    operands = [q_packed, k_cache, v_cache]
-    if quantized:
-        operands += [k_scale, v_scale]
+    q_packed = inner.pack_q(q)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, group, lanes), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_packed.shape, q.dtype),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
-    return (
-        out.reshape(B, group, Hk, hd).transpose(0, 2, 1, 3).reshape(B, H, hd)
-    )
+      page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      q_packed, *pools)
+    return inner.unpack_o(out)

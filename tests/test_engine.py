@@ -222,6 +222,7 @@ def test_kernel_compile_failure_is_loud_and_never_swaps_attn_impl():
         rt = eng.runtimes["test-tiny"]
         assert rt.attn_impl == "jnp"  # CPU backend: decided at construction
         assert rt.stats()["attn_impl"] == "jnp"
+        assert rt.stats()["attn_inner"] is None  # no kernels, no inner product
         rt.attn_impl = "pallas"  # as a TPU runtime would have been built
         items, req = run_request(eng, user="pallas-u", max_tokens=4)
         assert items[-1].kind == "error", items[-1]
@@ -248,6 +249,21 @@ def test_select_attn_impl_is_decided_from_backend_and_kv_dtype(monkeypatch):
     assert impl == "jnp" and "int8" in why
     monkeypatch.setenv("OLLAMAMQ_NO_PALLAS", "1")
     assert select_attn_impl("tpu", "bfloat16")[0] == "jnp"
+
+
+@pytest.mark.parametrize("group,want", [
+    (7, {"ragged": "mxu", "decode": "mxu"}),   # Qwen2.5-7B
+    (4, {"ragged": "mxu", "decode": "mxu"}),   # Qwen3-8B, LFM2, llama3.2
+    (1, {"ragged": "mxu", "decode": "vpu"}),   # OLMoE (MHA)
+])
+def test_attn_inner_is_a_function_of_the_query_group(group, want):
+    """What a Pallas runtime reports beside attn_impl: the inner product
+    of each kernel follows from the row-heads that share a K/V block — a
+    ragged tile's 8 rows always do, a decode row's heads only when the
+    group is more than one (ops/pallas/kv_contract.py)."""
+    from ollamamq_tpu.ops.pallas.kv_contract import inner_report
+
+    assert inner_report(group) == want
 
 
 def test_embed_admitted_while_decode_saturated():

@@ -13,14 +13,14 @@ from ollamamq_tpu.ops.pallas.paged_attention import paged_decode_attention_palla
 LAYERS = 3  # pool depth of the kernel cases: first, middle, last layer
 
 
-def _case(B, H, Hk, hd, PS_, MP, seq_lens, seed=0):
+def _case(B, H, Hk, hd, PS_, MP, seq_lens, seed=0, dtype=jnp.float32):
     """The pool is whole — [LAYERS, S, Hk*hd], every layer different —
     and the attentions under test read one layer of it by index."""
     rng = np.random.default_rng(seed)
-    S = (MP * B + 2) * PS_
-    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
+    S = (sum(-(-n // PS_) for n in seq_lens) + 2) * PS_
+    q = jnp.asarray(rng.normal(size=(B, H, hd)), dtype)
+    k = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), dtype)
+    v = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), dtype)
     pt = np.zeros((B, MP), np.int32)
     next_page = 1
     for b, L in enumerate(seq_lens):
@@ -30,22 +30,65 @@ def _case(B, H, Hk, hd, PS_, MP, seq_lens, seed=0):
     return q, k, v, jnp.asarray(pt), jnp.asarray(seq_lens, jnp.int32)
 
 
+SMALL_CASES = [dict(B=3, H=8, Hk=4, hd=32, PS_=8, MP=6, seq_lens=sl)
+               for sl in ([20, 9, 37], [1, 48, 16])]
+# The published head shapes (H, Hk, hd) at the engine's page size, cut only
+# in count of pages: Qwen2.5-7B, Qwen3-8B as one tp=4 shard sees it,
+# LFM2-8B-A1B / llama3.2 (two heads a lane tile), OLMoE (group 1: one
+# row-head a kv head, the Vpu inner product). Eight rows from eight
+# sequences: a context of ONE token; ends inside a page, on a page edge,
+# on and just past the 128-token block edge; 6 and 7 pages (no multiple of
+# a block's four).
+HEAD_SHAPES = [(28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128)]
+PUBLISHED_CASES = [
+    dict(B=8, H=H, Hk=Hk, hd=hd, PS_=32, MP=8, seed=H,
+         seq_lens=[1, 33, 128, 129, 163, 200, 64, 100])
+    for H, Hk, hd in HEAD_SHAPES]
+# q and the pool in bf16 against the float32 twin fed the same bf16
+# values. The kernel keeps float32 everywhere (exact bf16 products, f32
+# accumulation and softmax, P into P·V to float32's last bit), so what
+# separates it from the twin is the f32 tolerance below plus ONE rounding
+# of the output to bf16's 8 significant bits: half of a spacing of 2**-7
+# just above a power of two, 2**-8 relative.
+PUBLISHED_CASES.append(dict(PUBLISHED_CASES[0], dtype=jnp.bfloat16))
+F32_TOL = dict(rtol=2e-5, atol=2e-5)
+BF16_TOL = dict(rtol=2 ** -8 + 4e-5, atol=3e-5)
+
+
+def _id(case):
+    return "H{H}-Hk{Hk}-hd{hd}-".format(**case) + (
+        "bf16" if "dtype" in case else "x".join(map(str, case["seq_lens"])))
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("seq_lens", [[20, 9, 37], [1, 48, 16]])
-def test_pallas_matches_reference(seq_lens, layer):
-    q, k, v, pt, sl = _case(3, 8, 4, 32, 8, 6, seq_lens)
-    ref = paged_decode_attention(q, k, v, layer, pt, sl, 8)
-    out = paged_decode_attention_pallas(q, k, v, layer, pt, sl, 8,
+@pytest.mark.parametrize("case", SMALL_CASES + PUBLISHED_CASES, ids=_id)
+def test_pallas_matches_reference(case, layer):
+    q, k, v, pt, sl = _case(**case)
+    ps = case["PS_"]
+    ref = paged_decode_attention(_f32(q), _f32(k), _f32(v), layer, pt, sl, ps)
+    out = paged_decode_attention_pallas(q, k, v, layer, pt, sl, ps,
                                         interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(_f32(out)), np.asarray(ref),
+        **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
 
 
-def test_pallas_mqa_single_kv_head():
-    q, k, v, pt, sl = _case(2, 4, 1, 16, 8, 4, [8, 25])
+@pytest.mark.parametrize("inner", ["mxu", "vpu"])
+@pytest.mark.parametrize("H,Hk", [(4, 1), (4, 4), (8, 4)])
+def test_pallas_mqa_single_kv_head(H, Hk, inner):
+    """Either inner product of ops/pallas/kv_contract.py at any group: the
+    serving path picks by the row-heads that share a kv head (one → vpu),
+    a test may force the other."""
+    q, k, v, pt, sl = _case(2, H, Hk, 16, 8, 4, [8, 25])
     ref = paged_decode_attention(q, k, v, 2, pt, sl, 8)
     out = paged_decode_attention_pallas(q, k, v, 2, pt, sl, 8,
-                                        interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+                                        interpret=True, inner=inner)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **F32_TOL)
 
 
 def test_model_decode_with_pallas_impl(tiny_cfg, tiny_params):

@@ -19,17 +19,18 @@ from ollamamq_tpu.ops.pallas.ragged_attention import (
 LAYERS = 3  # pool depth of the kernel cases: first, middle, last layer
 
 
-def _case(spans, B, PS=8, MP=8, Hk=2, H=4, hd=16, seed=0):
+def _case(spans, B, PS=8, MP=8, Hk=2, H=4, hd=16, seed=0,
+          dtype=jnp.float32):
     """Build one ragged batch: spans = [(q_len, kv_len), ...] laid out
     contiguously in stream order; trailing rows of B are padding. The
     pool is whole — [LAYERS, S, Hk*hd], every layer different — and the
     attentions under test read one layer of it by index."""
     rng = np.random.default_rng(seed)
     T = sum(s for s, _ in spans)
-    S = (MP * B + 2) * PS
-    q = jnp.asarray(rng.normal(size=(T, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), jnp.float32)
+    S = (sum(-(-kv // PS) for _, kv in spans) + 2) * PS
+    q = jnp.asarray(rng.normal(size=(T, H, hd)), dtype)
+    k = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), dtype)
+    v = jnp.asarray(rng.normal(size=(LAYERS, S, Hk * hd)), dtype)
     pt = np.zeros((B, MP), np.int32)
     nxt = 1
     q_start = np.full(B, T, np.int32)
@@ -63,6 +64,37 @@ MIXED_CASES = [
 ]
 
 
+# The published head shapes (H, Hk, hd) at the engine's page size, cut only
+# in count of pages: Qwen2.5-7B, Qwen3-8B as one tp=4 shard sees it,
+# LFM2-8B-A1B / llama3.2 (two heads a lane tile), OLMoE (group 1). Tile 0 is eight decode rows from eight sequences — a
+# context of ONE token; ends inside a page, on a page edge, on and just
+# past the 128-token block edge; 6 and 7 pages (no multiple of a block's
+# four). Then a prefill span over a cached prefix that crosses two tile
+# edges and the block edge, and a decode row sharing its last tile.
+HEAD_SHAPES = [(28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128)]
+_PUBLISHED = dict(
+    spans=[(1, 1), (1, 33), (1, 128), (1, 129), (1, 163), (1, 200),
+           (1, 64), (1, 100), (19, 140), (1, 97)], B=12, PS=32, MP=8)
+PUBLISHED_CASES = [dict(_PUBLISHED, H=H, Hk=Hk, hd=hd, seed=H)
+                   for H, Hk, hd in HEAD_SHAPES]
+# q and the pool in bf16 against the float32 twin fed the same bf16
+# values. The kernel keeps float32 everywhere (exact bf16 products, f32
+# accumulation and softmax, P into P·V to float32's last bit), so what
+# separates it from the twin is the f32 tolerance below plus ONE rounding
+# of the output to bf16's 8 significant bits: half of a spacing of 2**-7
+# just above a power of two, 2**-8 relative.
+PUBLISHED_CASES.append(dict(PUBLISHED_CASES[0], dtype=jnp.bfloat16))
+F32_TOL = dict(rtol=2e-5, atol=2e-5)
+BF16_TOL = dict(rtol=2 ** -8 + 4e-5, atol=3e-5)
+
+
+def _id(case):
+    if "hd" not in case:
+        return "mixed" + str(MIXED_CASES.index(case))
+    return "H{H}-Hk{Hk}-hd{hd}".format(**case) + (
+        "-bf16" if "dtype" in case else "")
+
+
 @pytest.mark.parametrize("case", MIXED_CASES)
 def test_blockwise_matches_reference(case):
     q, k, v, pt, tok_seq, tok_pos, kv_len, _qs, _ql, PS = _case(**case)
@@ -74,28 +106,89 @@ def test_blockwise_matches_reference(case):
                                rtol=2e-5, atol=2e-5)
 
 
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("case", MIXED_CASES)
+@pytest.mark.parametrize("case", MIXED_CASES + PUBLISHED_CASES, ids=_id)
 def test_pallas_matches_reference(case, layer):
     q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(**case)
-    ref = ragged_paged_attention(q, k, v, layer, pt, tok_seq, tok_pos,
-                                 kv_len, PS)
+    ref = ragged_paged_attention(_f32(q), _f32(k), _f32(v), layer, pt,
+                                 tok_seq, tok_pos, kv_len, PS)
     out = ragged_paged_attention_pallas(q, k, v, layer, pt, qs, ql, kv_len,
                                         PS, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(_f32(out)), np.asarray(ref),
+        **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
 
 
-def test_pallas_mqa_and_group1():
-    for Hk, H in ((1, 4), (4, 4)):
-        q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(
-            spans=[(6, 6), (1, 12)], B=3, Hk=Hk, H=H, seed=2)
-        ref = ragged_paged_attention(q, k, v, 2, pt, tok_seq, tok_pos,
-                                     kv_len, PS)
-        out = ragged_paged_attention_pallas(q, k, v, 2, pt, qs, ql, kv_len,
-                                            PS, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
+@pytest.mark.parametrize("Hk,H", [(1, 4), (4, 4)])
+def test_pallas_mqa_and_group1(Hk, H):
+    q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(
+        spans=[(6, 6), (1, 12)], B=3, Hk=Hk, H=H, seed=2)
+    ref = ragged_paged_attention(q, k, v, 2, pt, tok_seq, tok_pos,
+                                 kv_len, PS)
+    out = ragged_paged_attention_pallas(q, k, v, 2, pt, qs, ql, kv_len,
+                                        PS, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **F32_TOL)
+
+
+def _kernel_eqns(H, Hk, hd, T=64, B=64, PS=32, MP=8, top=False):
+    """Every equation of the ragged kernel's traced body at one head
+    shape, sub-jaxprs (loops, `pl.when` branches) included unless `top`.
+    Shapes only: nothing runs."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for v in eqn.params.values():
+                for x in v if isinstance(v, (tuple, list)) else (v,):
+                    x = getattr(x, "jaxpr", x)
+                    if hasattr(x, "eqns"):
+                        yield from walk(x)
+
+    def s(*shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    pool = s(2, (MP * 4 + 2) * PS, Hk * hd, dt=jnp.bfloat16)
+    closed = jax.make_jaxpr(
+        lambda *a: ragged_paged_attention_pallas(*a, PS))(
+            s(T, H, hd, dt=jnp.bfloat16), pool, pool, s(), s(B, MP), s(B),
+            s(B), s(B))
+    calls = [e for e in walk(closed.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1  # one Mosaic call a layer a pass
+    kernel = calls[0].params["jaxpr"]
+    return list(kernel.eqns if top else walk(kernel))
+
+
+def test_traced_body_does_not_grow_with_tile_height_times_kv_heads():
+    """The defect that refused PR 33, held shut without a chip. A ragged
+    step program is traced, lowered and keyed once a rung of the token
+    ladder at every start, and that cost follows the size of the kernel's
+    traced body (`setup_s`, judged in every cell). PR 33 unrolled the walk
+    of a tile's G_TILE successor sequences AND the lane tiles in Python:
+    8 x 16 copies of the inner product at OLMoE's 16 kv heads (8354
+    equations, 256 `dot_general`s, where PR 32's VPU body had 4378). The
+    walk is a loop in the program now, so the body holds one copy a lane
+    tile — a Q.K^T and a P.V contraction each — and nothing a successor."""
+    few = _kernel_eqns(28, 4, 128)     # 4 lane tiles
+    many = _kernel_eqns(16, 16, 128)   # 16
+    packed = _kernel_eqns(32, 8, 64)   # 4, two heads each
+
+    def dots(eqns):
+        return sum(e.primitive.name == "dot_general" for e in eqns)
+
+    assert (dots(few), dots(many), dots(packed)) == (8, 32, 8)
+    # Four times the kv heads, under three times the body; and an eighth
+    # of PR 33's: 1146 equations when written (450 at 4 tiles).
+    assert len(many) <= 3 * len(few)
+    assert len(many) <= 1300 and len(few) <= 520 and len(packed) <= 520
+    # The successor walk is the kernel's one dynamic-trip loop at top
+    # level; inside it, a sequence's blocks.
+    top = [e.primitive.name for e in _kernel_eqns(16, 16, 128, top=True)]
+    assert top.count("while") == 1 and "scan" not in top
 
 
 def test_forward_ragged_matches_bucketed_composition(tiny_cfg, tiny_params):
