@@ -4,9 +4,11 @@ Model architecture configs for the families the framework serves natively:
 Llama 3.x (incl. llama3.2:1b and Llama-3-8B), Qwen2.5 (attention bias),
 Qwen3 (per-head q/k norm), the sparse families Mixtral (top-2 of 8,
 renormalised) and OLMoE (top-8 of 64, not renormalised, MHA, whole-vector
-q/k norm) and the hybrid LFM2 (gated short convolutions beside attention,
-a dense prefix, a sigmoid router with a selection bias), plus a
-bidirectional encoder config for embedding models
+q/k norm) and the hybrids LFM2 (gated short convolutions beside attention,
+a dense prefix, a sigmoid router with a selection bias) and Olmo-Hybrid
+(gated delta-rule linear attention beside attention, norms on the
+sublayers' outputs, no rotary embedding), plus a bidirectional encoder
+config for embedding models
 (nomic-embed-text class). The dense names are the ones the reference's
 stress test exercises (/root/reference/test_dispatcher.sh:5-7) and
 BASELINE.json's configs list.
@@ -19,8 +21,10 @@ from typing import Optional
 
 
 # A layer's operator (the published `layer_types` spellings) and its FFN.
-ATTENTION, CONV = "full_attention", "conv"
-LAYER_KINDS = (ATTENTION, CONV)
+ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
+LAYER_KINDS = (ATTENTION, CONV, LINEAR)
+# The kinds whose layers keep a fixed-size state a SLOT beside the paged pool.
+STATE_KINDS = (CONV, LINEAR)
 DENSE, EXPERTS = "dense", "experts"
 # The longest period `ModelConfig.layer_plan` looks for.
 MAX_PERIOD = 8
@@ -32,11 +36,13 @@ class ModelConfig:
     blocks `x + Op(norm(x))`, `x + FFN(norm(x))`: by default every block is
     the same (Llama / Qwen / Mixtral / OLMoE: attention, then a dense SwiGLU
     or routed experts). `layer_types` makes the stack one whose layers
-    differ: each layer's operator is attention or a gated short convolution
-    with a per-sequence state (LFM2), the first `num_dense_layers` of a
-    sparse stack keep a dense FFN, and the router's score, selection bias,
-    normalisation and scale are fields (`layer_plan()` is what the forwards
-    scan)."""
+    differ: each layer's operator is attention, a gated short convolution
+    with a per-sequence state (LFM2) or gated delta-rule linear attention
+    with a per-sequence matrix state (Olmo-Hybrid), the first
+    `num_dense_layers` of a sparse stack keep a dense FFN, and the router's
+    score, selection bias, normalisation and scale are fields
+    (`layer_plan()` is what the forwards scan). `norm_order` "post" moves
+    each norm from a sublayer's input to its output: `x + norm(Op(x))`."""
 
     name: str
     vocab_size: int
@@ -46,7 +52,9 @@ class ModelConfig:
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    rope_theta: float = 500_000.0
+    # None: attention without a rotary embedding (Olmo-Hybrid: positions
+    # reach its attention layers through the linear layers' recurrence).
+    rope_theta: Optional[float] = 500_000.0
     rms_norm_eps: float = 1e-5
     max_seq_len: int = 8192
     tie_embeddings: bool = False
@@ -95,6 +103,27 @@ class ModelConfig:
     layer_types: Optional[tuple] = None
     conv_L_cache: int = 3
     conv_bias: bool = False
+    # "linear_attention" is the gated delta rule (ops/gated_delta.py): per
+    # head a [key dim, value dim] float32 state S a sequence, S_t =
+    # a_t S_{t-1} + k_t b_t (v_t - (a_t S_{t-1})^T k_t)^T, read by q_t; q, k
+    # and v first pass a causal depthwise convolution of
+    # `linear_conv_kernel_dim` taps and a SiLU (its window is state too).
+    # `linear_allow_neg_eigval`: b in (0, 2) instead of (0, 1). The
+    # published spellings, so a configuration file's keys reach them as
+    # they are.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
+    # "pre": x + Op(norm(x)) (Llama's). "post": x + norm(Op(x)) (OLMo 2's
+    # reordered norm: `attn_norm` / `mlp_norm` weigh the sublayers' OUTPUTS).
+    norm_order: str = "pre"
+    # The published `rope_parameters` group of a configuration file, taken
+    # whole: its `rope_theta` (null: no rotary embedding) sets the field of
+    # that name; any other key of the group is refused.
+    rope_parameters: Optional[object] = None
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head", "full"):
@@ -105,6 +134,21 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: router_score must be 'softmax' or 'sigmoid', "
                 f"got {self.router_score!r}")
+        if self.norm_order not in ("pre", "post"):
+            raise ValueError(
+                f"{self.name}: norm_order must be 'pre' or 'post', got "
+                f"{self.norm_order!r}")
+        if self.rope_parameters is not None:
+            group = dict(self.rope_parameters)  # a file's dict: hashable
+            unknown = sorted(set(group) - {"rope_theta"})
+            if unknown:
+                raise ValueError(
+                    f"{self.name}: rope_parameters holds {unknown}; the "
+                    "program reads 'rope_theta' only")
+            object.__setattr__(self, "rope_parameters",
+                               tuple(sorted(group.items())))
+            if "rope_theta" in group:
+                object.__setattr__(self, "rope_theta", group["rope_theta"])
         if self.layer_types is not None:
             kinds = tuple(self.layer_types)  # a file's list: hashable
             object.__setattr__(self, "layer_types", kinds)
@@ -117,10 +161,16 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: layer_types holds {unknown}; the program "
                     f"runs {list(LAYER_KINDS)}")
-            if self.is_encoder and CONV in kinds:
+            if self.is_encoder and set(kinds) & set(STATE_KINDS):
                 raise ValueError(
-                    f"{self.name}: layer_types: a causal convolution in an "
-                    "encoder")
+                    f"{self.name}: layer_types: a causal convolution or "
+                    "recurrence in an encoder")
+            if CONV in kinds and LINEAR in kinds:
+                raise ValueError(
+                    f"{self.name}: layer_types holds both {CONV!r} and "
+                    f"{LINEAR!r}: the per-slot conv window has one width")
+            if LINEAR in kinds:
+                self._check_linear()
         if not 0 <= self.num_dense_layers <= self.num_layers:
             raise ValueError(
                 f"{self.name}: num_dense_layers {self.num_dense_layers} is "
@@ -134,10 +184,32 @@ class ModelConfig:
                 f"{self.name}: conv_bias true: the program's convolution "
                 "layers carry no bias")
 
+    def _check_linear(self) -> None:
+        """What the linear-attention layers cannot run with, key and value
+        in the message."""
+        hk, hv = self.linear_num_key_heads, self.linear_num_value_heads
+        if hk < 1 or self.linear_key_head_dim < 1 \
+                or self.linear_value_head_dim < 1:
+            raise ValueError(
+                f"{self.name}: linear_attention layers need "
+                f"linear_num_key_heads ({hk}), linear_key_head_dim "
+                f"({self.linear_key_head_dim}) and linear_value_head_dim "
+                f"({self.linear_value_head_dim}) of at least 1")
+        if hv != hk:
+            raise ValueError(
+                f"{self.name}: linear_num_value_heads {hv} is not "
+                f"linear_num_key_heads {hk}: the program's rule has one "
+                "key head a value head")
+        if self.linear_conv_kernel_dim < 2:
+            raise ValueError(
+                f"{self.name}: linear_conv_kernel_dim must be at least 2, "
+                f"got {self.linear_conv_kernel_dim}")
+
     # -- the stack, layer by layer ------------------------------------------
     @property
     def kinds(self) -> tuple:
-        """Each layer's (operator, FFN): (ATTENTION | CONV, DENSE | EXPERTS)."""
+        """Each layer's (operator, FFN): (ATTENTION | CONV | LINEAR, DENSE |
+        EXPERTS)."""
         ops = self.layer_types or (ATTENTION,) * self.num_layers
         first_sparse = self.num_dense_layers if self.num_experts \
             else self.num_layers
@@ -187,6 +259,28 @@ class ModelConfig:
         return self.num_heads * self.head_dim
 
     @property
+    def linear_key_dim(self) -> int:
+        return self.linear_num_key_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.linear_num_value_heads * self.linear_value_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels of a linear layer's convolution: q | k | v."""
+        return 2 * self.linear_key_dim + self.linear_value_dim
+
+    @property
+    def state_window(self) -> tuple:
+        """(taps, channels) of the per-slot conv window of this model's
+        conv or linear-attention layers (config refuses a stack with
+        both)."""
+        if self.count(LINEAR):
+            return self.linear_conv_kernel_dim, self.linear_conv_dim
+        return self.conv_L_cache, self.hidden_size
+
+    @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
@@ -199,6 +293,14 @@ class ModelConfig:
             ATTENTION: (d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
                         + self.qk_norm_params()),
             CONV: 3 * d * d + d * d + d * self.conv_L_cache,
+            # q | k | v | z and the two gates in, the taps, A_log and
+            # dt_bias, the output norm, out.
+            LINEAR: (d * (self.linear_conv_dim + self.linear_value_dim
+                          + 2 * self.linear_num_value_heads)
+                     + self.linear_conv_dim * self.linear_conv_kernel_dim
+                     + 2 * self.linear_num_value_heads
+                     + self.linear_value_head_dim
+                     + self.linear_value_dim * d),
         }
         n_experts = self.num_experts_per_tok if active else self.num_experts
         per_ffn = {
@@ -357,6 +459,34 @@ MODEL_CONFIGS = {
         moe_intermediate_size=32, conv_L_cache=3,
         layer_types=("conv", "conv") + ("full_attention", "conv") * 2
         + ("conv", "full_attention", "conv"),
+    ),
+    # Olmo-Hybrid family (allenai/Olmo-Hybrid-7B config.json): three gated
+    # delta-rule linear-attention layers (30 heads, keys of 96, values of
+    # 192, a convolution of 4 taps; a [96, 192] float32 state a head a
+    # sequence) to one MHA attention layer without rotary embedding; the
+    # norms on the sublayers' outputs, whole-vector q/k norm; dense SwiGLU.
+    "olmo-hybrid:7b": ModelConfig(
+        name="olmo-hybrid:7b", vocab_size=100_352, hidden_size=3840,
+        intermediate_size=11_008, num_layers=32, num_heads=30,
+        num_kv_heads=30, head_dim=128, rope_theta=None, rms_norm_eps=1e-6,
+        max_seq_len=65_536, qk_norm="full", norm_order="post",
+        linear_num_key_heads=30, linear_num_value_heads=30,
+        linear_key_head_dim=96, linear_value_head_dim=192,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 8,
+    ),
+    # Tiny Olmo-Hybrid: two periods and a half (the stack ends inside a
+    # period: layer_plan() has a tail), value heads wider than key heads.
+    "test-tiny-olmo-hybrid": ModelConfig(
+        name="test-tiny-olmo-hybrid", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=10, num_heads=4, num_kv_heads=4,
+        head_dim=16, rope_theta=None, rms_norm_eps=1e-6, max_seq_len=512,
+        qk_norm="full", norm_order="post", linear_num_key_heads=4,
+        linear_num_value_heads=4, linear_key_head_dim=8,
+        linear_value_head_dim=16, linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2
+        + ("linear_attention",) * 2,
     ),
 }
 
@@ -825,30 +955,32 @@ def validate_tiers(spec: Optional[str], members) -> Optional[str]:
     return None
 
 
-def validate_conv_state(cfg: ModelConfig, spec: bool = False,
+def validate_slot_state(cfg: ModelConfig, spec: bool = False,
                         mesh_shape=None) -> Optional[str]:
-    """What a model with conv layers cannot be served with yet, told
-    BEFORE any device work: returns an error string (None = valid). Each
-    of these touches per-sequence state and knows only the paged KV pool;
-    run on such a model it would serve K and V without the conv layers'
+    """What a model with ANY per-slot state (conv layers' windows, the
+    linear-attention layers' matrices: STATE_KINDS) cannot be served with
+    yet, told BEFORE any device work: returns an error string (None =
+    valid). Each of these touches per-sequence state and knows only the
+    paged KV pool; run on such a model it would serve K and V without the
     state beside them (ROADMAP B-M5 names what each lacks)."""
-    if not cfg.count(CONV):
+    held = [kind for kind in STATE_KINDS if cfg.count(kind)]
+    if not held:
         return None
     shape = dict(mesh_shape or {})
     why = None
     if spec:
-        why = ("--spec: a rejected draft has already advanced the conv "
+        why = ("--spec: a rejected draft has already advanced the per-slot "
                "state, and rollback restores pages only")
     elif shape.get("seq", 1) > 1:
         why = ("--sp: a convolution over a sequence sharded along T needs "
                "a halo exchange the ring prefill does not make")
     elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
-        why = ("--tp / --ep: the conv layers' weights and state have no "
-               "partition specs")
+        why = (f"--tp / --ep: the {' and '.join(held)} layers' weights and "
+               "state have no partition specs")
     if why is None:
         return None
-    return (f"model {cfg.name} has conv layers (layer_types) and cannot be "
-            f"served with {why}")
+    return (f"model {cfg.name} has {' and '.join(held)} layers "
+            f"(layer_types) and cannot be served with {why}")
 
 
 def validate_quant_config(weights_dtype: str, kv_dtype: str,
@@ -872,7 +1004,9 @@ def validate_quant_config(weights_dtype: str, kv_dtype: str,
             if cfg is not None and cfg.num_experts:
                 return (f"--weights-dtype=int8 does not cover MoE expert "
                         f"stacks (model {name}); load it in bfloat16")
-            if cfg is not None and cfg.count(CONV):
-                return (f"--weights-dtype=int8 does not cover conv layers "
-                        f"(model {name}); load it in bfloat16")
+            held = [k for k in STATE_KINDS if cfg is not None and cfg.count(k)]
+            if held:
+                return (f"--weights-dtype=int8 does not cover "
+                        f"{' or '.join(held)} layers (model {name}); load "
+                        "it in bfloat16")
     return None

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import ctypes
 import enum
+import fcntl
 import json
 import os
 import subprocess
@@ -18,7 +19,6 @@ from typing import Iterable, Optional, Tuple
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _CPP_DIR = os.path.join(_REPO_ROOT, "cpp")
-_LIB_PATH = os.path.join(_CPP_DIR, "libmqcore.so")
 _BUILD_LOCK = threading.Lock()
 
 
@@ -33,30 +33,43 @@ class Fairness(enum.IntEnum):
     TOKENS = 1
 
 
-def _ensure_built() -> str:
+def _ensure_built(cpp_dir: str = _CPP_DIR) -> str:
+    """Path of the built library, building it first where it is missing
+    or older than a source. The check and `make` run under an exclusive
+    `flock` on `<cpp_dir>/.build.lock` (and the Makefile links to a
+    temporary name it then renames): test workers are PROCESSES, and on a
+    fresh checkout each of them used to find no library and start a `make`
+    of its own, so that one could `dlopen` the file another was still
+    writing ("file too short"). The thread lock alone held only the
+    threads of one process apart."""
+    lib_path = os.path.join(cpp_dir, "libmqcore.so")
     with _BUILD_LOCK:
-        if not os.path.isdir(_CPP_DIR):
+        if not os.path.isdir(cpp_dir):
             # A plain `pip install .` copies only the python package to
             # site-packages; the native core's sources stay in the repo.
             raise RuntimeError(
                 "native scheduler core sources not found at "
-                f"{_CPP_DIR}: ollamamq-tpu must run from a checkout "
+                f"{cpp_dir}: ollamamq-tpu must run from a checkout "
                 "(`pip install -e .`) or the Docker image, which builds "
                 "cpp/libmqcore.so in stage 1"
             )
-        sources = [
-            os.path.join(_CPP_DIR, f)
-            for f in os.listdir(_CPP_DIR)
-            if f.endswith((".cpp", ".h"))
-        ]
-        stale = not os.path.exists(_LIB_PATH) or any(
-            os.path.getmtime(s) > os.path.getmtime(_LIB_PATH) for s in sources
-        )
-        if stale:
-            subprocess.run(
-                ["make", "-C", _CPP_DIR], check=True, capture_output=True, text=True
+        with open(os.path.join(cpp_dir, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)  # released when it closes
+            sources = [
+                os.path.join(cpp_dir, f)
+                for f in os.listdir(cpp_dir)
+                if f.endswith((".cpp", ".h"))
+            ]
+            stale = not os.path.exists(lib_path) or any(
+                os.path.getmtime(s) > os.path.getmtime(lib_path)
+                for s in sources
             )
-    return _LIB_PATH
+            if stale:
+                subprocess.run(
+                    ["make", "-C", cpp_dir], check=True, capture_output=True,
+                    text=True
+                )
+    return lib_path
 
 
 def _load() -> ctypes.CDLL:

@@ -41,9 +41,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, EngineConfig,
-                                 ModelConfig, get_model_config, smart_match,
-                                 validate_conv_state, validate_quant_config)
+from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, LINEAR,
+                                 STATE_KINDS, EngineConfig, ModelConfig,
+                                 get_model_config, smart_match,
+                                 validate_quant_config, validate_slot_state)
 from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
@@ -52,7 +53,6 @@ from ollamamq_tpu.engine.request import FinishReason, Request, StreamItem
 from ollamamq_tpu.engine.scheduler import make_policy
 from ollamamq_tpu.engine.tokenizer import load_tokenizer
 from ollamamq_tpu.models import llama, moe, weights
-from ollamamq_tpu.ops import shortconv
 from ollamamq_tpu.ops.sampling import (accept_prefix, maybe_apply_penalties,
                                        per_row_keys, sample_tokens_rowwise,
                                        sampling_flags)
@@ -513,7 +513,7 @@ class ModelRuntime:
             sp=_sp_probe, model_names=(name,))
         if err is not None:
             raise ValueError(err)
-        err = validate_conv_state(
+        err = validate_slot_state(
             model_cfg, spec=engine_cfg.spec,
             mesh_shape=dict(mesh.shape) if mesh is not None else {})
         if err is not None:
@@ -581,14 +581,17 @@ class ModelRuntime:
         # right before can be unsettled at a launch (the loop's depth is
         # one), so one step's rows are all the carry ever has to hold.
         self.last_ids = jnp.zeros((engine_cfg.max_slots,), jnp.int32)
-        # The conv layers' per-slot state (ops/shortconv.py: fixed size, no
-        # pages; None for a model without such layers): with kc, vc,
-        # recent and last_ids a donated argument and result of every step
-        # program. Never reset from the host: a request's first span opens
-        # its slot's rows at zero inside the program (`is_first`).
-        self.conv = shortconv.alloc_state(
-            model_cfg.count(CONV), engine_cfg.max_slots,
-            model_cfg.conv_L_cache, model_cfg.hidden_size, dtype)
+        # The per-slot state of the layers that keep one (fixed size, no
+        # pages): the conv layers' window (ops/shortconv.py's array), for a
+        # model with linear-attention layers a llama.SlotState of their
+        # convolution's window and the rule's float32 matrices
+        # (ops/gated_delta.py), None for a model without such layers. With
+        # kc, vc, recent and last_ids a donated argument and result of
+        # every step program. Never reset from the host: a request's first
+        # span opens its slot's rows at zero inside the program
+        # (`is_first`).
+        self.slot_state = llama.alloc_slot_state(
+            model_cfg, engine_cfg.max_slots, dtype)
         self.alloc = kvc.PageAllocator(
             engine_cfg.num_pages, engine_cfg.page_size, engine_cfg.max_pages_per_seq
         )
@@ -597,14 +600,16 @@ class ModelRuntime:
         # the primary's admission path ever walks it — the page tables it
         # produces already broadcast on the op wire.
         self.prefix_cache = None
-        if engine_cfg.prefix_cache and self.conv is not None:
-            # A cached page holds K and V of its tokens, not the conv
-            # layers' state at its boundary: a hit would resume a
-            # sequence whose convolutions start from nothing. Until a
+        if engine_cfg.prefix_cache and self.slot_state is not None:
+            # A cached page holds K and V of its tokens, not the per-slot
+            # state at its boundary: a hit would resume a sequence whose
+            # convolutions (and recurrences) start from nothing. Until a
             # page can carry a state snapshot, such a model has no
             # prefix cache (a preempted request replays from token 0).
-            log.warning("%s: prefix cache off: its conv layers' state is "
-                        "not cached with the pages", name)
+            log.warning("%s: prefix cache off: its %s layers' per-slot state "
+                        "is not cached with the pages", name,
+                        " / ".join(k for k in STATE_KINDS
+                                   if model_cfg.count(k)))
         elif engine_cfg.prefix_cache:
             from ollamamq_tpu.engine.prefix_cache import PrefixCache
 
@@ -795,15 +800,27 @@ class ModelRuntime:
         tm.HBM_KV_BYTES.labels(model=name).set(self.kv_bytes)
         # What a deployment is sized by: the fixed per-slot state, and
         # what each token of context adds to the pool.
-        self.conv_state_bytes = (
-            0 if self.conv is None
-            else self.conv.size * self.conv.dtype.itemsize)
+        conv, rule = llama.split_state(self.slot_state)
+        self.conv_state_bytes, self.lin_state_bytes = (
+            0 if a is None else a.size * a.dtype.itemsize
+            for a in (conv, rule))
         tm.HBM_CONV_STATE_BYTES.labels(model=name).set(self.conv_state_bytes)
+        tm.HBM_LIN_STATE_BYTES.labels(model=name).set(self.lin_state_bytes)
+        if self.slot_state is not None:
+            log.info("%s: per-slot state %.1f MB (conv window %.1f MB, rule "
+                     "state %.1f MB, float32) for %d slots beside the KV "
+                     "pool's %.1f MB", name,
+                     (self.conv_state_bytes + self.lin_state_bytes) / 1e6,
+                     self.conv_state_bytes / 1e6, self.lin_state_bytes / 1e6,
+                     engine_cfg.max_slots, self.kv_bytes / 1e6)
         tm.KV_BYTES_PER_TOKEN.labels(model=name).set(
             kvc.kv_page_bytes(model_cfg, 1, jnp.dtype(dtype).itemsize,
                               engine_cfg.kv_dtype))
         self._tm_conv_resets = tm.CONV_STATE_RESETS_TOTAL.labels(model=name)
         self._tm_conv_carried = tm.CONV_STATE_CARRIED_TOTAL.labels(model=name)
+        self._tm_lin = [c.labels(model=name) for c in (
+            tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
+            tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL)]
 
     # -- capacity ----------------------------------------------------------
     def free_slots(self) -> int:
@@ -907,7 +924,7 @@ class ModelRuntime:
     # Each returns (sampled_tokens, kc', vc', recent'); the caller assigns
     # the three state arrays back. The two step programs of the pipelined
     # loop (ragged, decode) also take and return the `last_ids` carry and
-    # the conv layers' state (None for a model without them).
+    # the per-slot state (None for a model without such layers).
     def _dispatch_ragged(self, T_pad, k_cap, buf):
         """`buf`: the step's packed host inputs (step_pack.ragged_layout)."""
         # Speculative dispatches get their own fault site: a chaos plan
@@ -918,7 +935,7 @@ class ModelRuntime:
         fn = self._get_ragged_jit(
             T_pad, k_cap, sampling_flags(*lay.sampling(buf)))
         return fn(self.params, self._upload(buf), self.kc, self.vc,
-                  self.recent, self.last_ids, self.conv)
+                  self.recent, self.last_ids, self.slot_state)
 
     def _ragged_layout(self, T_pad: int) -> step_pack.StepLayout:
         e = self.ecfg
@@ -955,8 +972,10 @@ class ModelRuntime:
         Returns (toks [S, k_cap+1], n_emit [S], caches', recent',
         last_ids', conv'): row i emits toks[i, :n_emit[i]], the carry is
         now this step's last id of every row, and each row's slot of the
-        conv state holds its span's last positions (opened at zero where
-        the span is its request's first: models/llama.py:forward_ragged). An MoE model's `toks` has three more
+        per-slot state (`conv`: the window's array, or a llama.SlotState)
+        holds its span's last positions and, for linear-attention layers,
+        the rule's state after the span (opened at zero where the span is
+        its request's first: models/llama.py:forward_ragged). An MoE model's `toks` has three more
         rows: the pass's expert-load counters (moe.LOAD_STATS) ride back
         with the ids, in the transfer the collect makes anyway."""
         key_ = ("ragged", T_pad, k_cap, flags)
@@ -1095,17 +1114,28 @@ class ModelRuntime:
         self._tm_moe_max.set(top)
         self._tm_moe_mean.set(mean)
 
-    def _note_conv_state(self, _sp, resets: int, carried: int) -> None:
-        """A launched step's use of the conv layers' state, onto its
-        sample and the /metrics series: rows whose slot it opened at zero
-        (a request's first span) and rows that read the state an earlier
-        step left (a later chunk of a prompt, a decode row; a fused scan's
-        active slots). Nothing for a model without conv layers."""
-        if self.conv is None:
-            return
-        _sp.note(conv_state_resets=resets, conv_state_carried=carried)
-        self._tm_conv_resets.inc(resets)
-        self._tm_conv_carried.inc(carried)
+    def _note_slot_state(self, _sp, resets: int, carried: int,
+                         step_rows: int, span_tokens: int) -> None:
+        """A launched step's use of the per-slot state, onto its sample
+        and the /metrics series: rows whose slot it opened at zero (a
+        request's first span) and rows that read the state an earlier step
+        left (a later chunk of a prompt, a decode row; a fused scan's
+        active slots) — as `conv_state_*` for a model with conv layers, as
+        `lin_state_*` for one with linear-attention layers, which also
+        says how the rule ran: `lin_step_rows` (row-passes through the one-
+        token form: a ragged step's 1-token rows, a scan's active slots x
+        its passes) and `lin_span_tokens` (tokens of longer spans, through
+        the chunked form). Nothing for a model with neither."""
+        if self.cfg.count(CONV):
+            _sp.note(conv_state_resets=resets, conv_state_carried=carried)
+            self._tm_conv_resets.inc(resets)
+            self._tm_conv_carried.inc(carried)
+        if self.cfg.count(LINEAR):
+            counts = (resets, carried, step_rows, span_tokens)
+            _sp.note(lin_state_resets=resets, lin_state_carried=carried,
+                     lin_step_rows=step_rows, lin_span_tokens=span_tokens)
+            for series, n in zip(self._tm_lin, counts):
+                series.inc(n)
 
     def _dispatch_decode(self, k_steps, buf):
         """`buf`: the scan's packed host inputs (step_pack.decode_layout)."""
@@ -1113,7 +1143,7 @@ class ModelRuntime:
         fn = self._get_decode_jit(
             k_steps, sampling_flags(*self._decode_layout().sampling(buf)))
         return fn(self.params, self._upload(buf), self.kc, self.vc,
-                  self.recent, self.last_ids, self.conv)
+                  self.recent, self.last_ids, self.slot_state)
 
     def _dispatch_prefill_sp(self, T, buf):
         """`buf`: the prompt's packed host inputs (step_pack.sp_layout)."""
@@ -1575,8 +1605,8 @@ class ModelRuntime:
         recompute — only written decode state is worth shipping). The
         detached slot keeps its pages (reserved, undispatchable) until
         release_export resolves the two-phase handoff."""
-        if self.conv is not None:
-            # The blob has no place for the conv layers' state, and pages
+        if self.slot_state is not None:
+            # The blob has no place for the per-slot state, and pages
             # without it resume another sequence: not exportable (the
             # caller's fallback replays the request from its tokens).
             return None
@@ -1640,10 +1670,11 @@ class ModelRuntime:
         blob's shape doesn't match this runtime or capacity is gone
         (the caller falls back to recompute replay). A model with conv
         layers refuses every blob: pages come without its state."""
-        if self.conv is not None:
+        if self.slot_state is not None:
             raise MigrationError(
                 f"{self.name}: a migrated stream carries KV pages, not the "
-                "conv layers' state; replay the request instead")
+                f"{' / '.join(k for k in STATE_KINDS if self.cfg.count(k))} "
+                "layers' state; replay the request instead")
         if (blob.get("kind") != "stream"
                 or int(blob.get("page_size", -1)) != self.ecfg.page_size
                 or blob.get("kv_dtype") != self.kv_dtype
@@ -2532,7 +2563,7 @@ class ModelRuntime:
         self._h2d = [0, 0]
         try:
             h.toks_dev, h.n_emit_dev, self.kc, self.vc, self.recent, \
-                self.last_ids, self.conv = self._dispatch_ragged(
+                self.last_ids, self.slot_state = self._dispatch_ragged(
                     T_pad, k_cap, buf)
         except Exception as e:
             # The step before is untouched by this failure: settle it
@@ -2543,7 +2574,10 @@ class ModelRuntime:
             self._ragged_failed(rows, e, core)
             return None
         opened = int(is_first.sum())
-        self._note_conv_state(_sp, opened, len(rows) - opened)
+        spans = [span for *_, span in rows]
+        self._note_slot_state(_sp, opened, len(rows) - opened,
+                              sum(n == 1 for n in spans),
+                              sum(n for n in spans if n > 1))
         _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()
@@ -2743,8 +2777,9 @@ class ModelRuntime:
                          float(np.mean(self.seq_lens[active])))
         self._h2d = [0, 0]
         h.toks_dev, self.kc, self.vc, self.recent, self.last_ids, \
-            self.conv = self._dispatch_decode(k_steps, buf)
-        self._note_conv_state(_sp, 0, len(active))
+            self.slot_state = self._dispatch_decode(k_steps, buf)
+        self._note_slot_state(_sp, 0, len(active),
+                              len(active) * int(k_steps), 0)
         _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()
@@ -3044,6 +3079,9 @@ class ModelRuntime:
             "mfu": round(self.mfu, 4),
             "param_bytes": self.param_bytes,
             "kv_bytes": self.kv_bytes,
+            # the per-slot state beside the pool (0 for a model without)
+            "conv_state_bytes": self.conv_state_bytes,
+            "lin_state_bytes": self.lin_state_bytes,
             "weights_dtype": self.weights_dtype,
             "kv_dtype": self.kv_dtype,
             "attn_impl": self.attn_impl,
@@ -4415,7 +4453,10 @@ class TPUEngine:
         models = {}
         for name, rt in self.runtimes.items():
             entry = {"weight_bytes": int(getattr(rt, "param_bytes", 0)),
-                     "kv_bytes": int(getattr(rt, "kv_bytes", 0))}
+                     "kv_bytes": int(getattr(rt, "kv_bytes", 0)),
+                     "slot_state_bytes": int(
+                         getattr(rt, "conv_state_bytes", 0)
+                         + getattr(rt, "lin_state_bytes", 0))}
             alloc = getattr(rt, "alloc", None)
             if alloc is not None:
                 entry.update(free=alloc.free_pages, used=alloc.used_pages,
@@ -4749,7 +4790,8 @@ class TPUEngine:
         # device's counters standing in for the pod — VERDICT r3 weak #6).
         chips = self.chip_stats()
         hbm_used = sum(c["hbm_used"] for c in chips) or sum(
-            r["param_bytes"] + r["kv_bytes"] for r in runtime_stats)
+            r["param_bytes"] + r["kv_bytes"] + r.get("conv_state_bytes", 0)
+            + r.get("lin_state_bytes", 0) for r in runtime_stats)
         hbm_total = sum(c["hbm_total"] for c in chips) or None
         return {
             "runtimes": runtime_stats,

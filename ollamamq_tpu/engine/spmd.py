@@ -1067,9 +1067,9 @@ def _replay(rt, op, a, b, payload):
     device output of the replayed computation."""
     if op == OP_DECODE:
         (buf,) = payload
-        toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.conv = \
+        toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state = \
             ModelRuntime._dispatch_decode(rt, a, buf)
-        return (toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.conv)
+        return (toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state)
     elif op == OP_PREFILL_SP:
         (buf,) = payload
         toks, rt.kc, rt.vc, rt.recent = ModelRuntime._dispatch_prefill_sp(
@@ -1077,9 +1077,9 @@ def _replay(rt, op, a, b, payload):
         return (toks, rt.kc, rt.vc, rt.recent)
     elif op in (OP_RAGGED, OP_SPEC):
         (buf,) = payload  # a=T_pad, b=k_cap (0 on OP_RAGGED)
-        toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.conv = \
+        toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state = \
             ModelRuntime._dispatch_ragged(rt, a, b, buf)
-        return (toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.conv)
+        return (toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state)
     elif op == OP_ENCODE:
         B, bucket = a, b
         tokens, lens = payload

@@ -1,11 +1,13 @@
-"""Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2) in pure
-functional JAX.
+"""Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2 / Olmo-Hybrid) in
+pure functional JAX.
 
-A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))`. Op is attention
-(`_attention_op`) or a gated short convolution (`_conv_op`); FFN is a dense
+A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))` — or, with
+`norm_order` "post", `x + norm(Op(x))`, `x + norm(FFN(x))`. Op is attention
+(`_attention_op`), a gated short convolution (`_conv_op`) or gated
+delta-rule linear attention (`_linear_attention_op`); FFN is a dense
 SwiGLU (`_mlp`) or routed experts (models/moe.py). Each is defined ONCE and
 used by every forward; `ModelConfig.kinds` says which pair a layer is. A
-uniform stack (every family but LFM2) is the case of one kind.
+uniform stack (every family but the hybrids) is the case of one kind.
 
 Design notes (TPU-first):
   - Layer parameters are STACKED by kind — a weight's leading axis counts
@@ -42,15 +44,15 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (ATTENTION, CONV, DENSE, EXPERTS,
+from ollamamq_tpu.config import (ATTENTION, CONV, DENSE, EXPERTS, LINEAR,
                                  ModelConfig)
 from ollamamq_tpu.models.moe import STACKED, init_moe_layer_params, moe_mlp
-from ollamamq_tpu.ops import shortconv
+from ollamamq_tpu.ops import gated_delta, shortconv
 from ollamamq_tpu.ops.attention import (
     causal_attention,
     bidirectional_attention,
@@ -71,15 +73,27 @@ SCOPES = ("embed", "attn_qkv", "kv_write", "attention", "attn_out", "mlp",
 # the in-projection, the gated convolution with its state read and write,
 # the out-projection.
 CONV_SCOPES = ("conv_in", "conv_mix", "conv_out")
+# ...and a linear-attention layer's four: the projections and gates, the
+# convolution over q | k | v with its window, the delta rule with its state,
+# the gated output norm and out-projection.
+LINEAR_SCOPES = ("lin_in", "lin_conv", "lin_rule", "lin_out")
 # fold_in constant of the conv layers' init keys (the other weights' keys
 # are the ten of one split, as before the family existed).
 CONV_KEY = 0x636F6E76
+LINEAR_KEY = 0x6C696E72
+# Seeded random init of the rule's decay (the published code's): A uniform
+# in [1, 16], the step dt log-uniform in [1e-3, 1e-1], dt_bias its inverse
+# softplus — so that a = exp(-A softplus(x W_a + dt_bias)) neither kills
+# the state nor freezes it.
+LINEAR_A_RANGE, LINEAR_DT_RANGE = (1.0, 16.0), (1e-3, 1e-1)
 # The weights of each operator and FFN kind (stacked over the layers of
 # that kind); every other entry of `layers` is stacked over all layers.
 KIND_PARAMS = {
     ATTENTION: ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm",
                 "k_norm"),
     CONV: ("conv_in", "conv_w", "conv_out"),
+    LINEAR: ("lin_in", "lin_ba", "lin_conv_w", "lin_A_log", "lin_dt_bias",
+             "lin_norm", "lin_out"),
     DENSE: ("w_gate", "w_up", "w_down"),
     EXPERTS: ("w_router", "router_bias") + STACKED,
 }
@@ -103,6 +117,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     d, qd, kvd, f = cfg.hidden_size, cfg.q_dim, cfg.kv_dim, cfg.intermediate_size
     L, v = cfg.num_layers, cfg.vocab_size
     La, Lc, Ld = cfg.count(ATTENTION), cfg.count(CONV), cfg.count(DENSE)
+    Ll = cfg.count(LINEAR)
     keys = jax.random.split(key, 10)
 
     def w(k, shape, fan_in):
@@ -135,6 +150,27 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
             conv_in=w(ck[0], (Lc, d, 3 * d), d),
             conv_w=w(ck[1], (Lc, d, cfg.conv_L_cache), cfg.conv_L_cache),
             conv_out=w(ck[2], (Lc, d, d), d))
+    if Ll:
+        # Gated delta rule: in-projection to [q | k | v | z] (the first
+        # three pass the convolution), the two gates [b | a] a head, the
+        # depthwise taps [q | k | v channels, K], the decay's A_log and
+        # dt_bias (float32: they sit inside an exp), the output norm over
+        # a value head, the out-projection.
+        lk = jax.random.split(jax.random.fold_in(key, LINEAR_KEY), 6)
+        cd, vd, H = (cfg.linear_conv_dim, cfg.linear_value_dim,
+                     cfg.linear_num_value_heads)
+        K = cfg.linear_conv_kernel_dim
+        dt = jnp.exp(jax.random.uniform(
+            lk[4], (Ll, H), jnp.float32, *map(jnp.log, LINEAR_DT_RANGE)))
+        layers.update(
+            lin_in=w(lk[0], (Ll, d, cd + vd), d),
+            lin_ba=w(lk[1], (Ll, d, 2 * H), d),
+            lin_conv_w=w(lk[2], (Ll, cd, K), K),
+            lin_A_log=jnp.log(jax.random.uniform(
+                lk[3], (Ll, H), jnp.float32, *LINEAR_A_RANGE)),
+            lin_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            lin_norm=jnp.ones((Ll, cfg.linear_value_head_dim), dtype),
+            lin_out=w(lk[5], (Ll, vd, d), vd))
     if Ld:
         layers.update(
             w_gate=w(keys[4], (Ld, d, f), d), w_up=w(keys[5], (Ld, d, f), d),
@@ -206,10 +242,40 @@ def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return logits_head(x, head)
 
 
+class SlotState(NamedTuple):
+    """The per-slot state of a model with linear-attention layers, as the
+    forwards take it in `conv_state` and give it back: the window of the
+    layers' convolution (ops/shortconv.py's array) and the rule's matrices
+    (ops/gated_delta.py's). A model with conv layers only passes the
+    window's array bare, as before this family; a model with neither
+    passes None."""
+    conv: Optional[jnp.ndarray]
+    rule: Optional[jnp.ndarray] = None
+
+
+def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16):
+    """What `conv_state` of the step forwards is for `cfg`, at zero."""
+    window, width = cfg.state_window
+    conv = shortconv.alloc_state(cfg.count(CONV) + cfg.count(LINEAR),
+                                 max_slots, window, width, dtype)
+    rule = gated_delta.alloc_state(
+        cfg.count(LINEAR), max_slots, cfg.linear_num_value_heads,
+        cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+    return conv if rule is None else SlotState(conv, rule)
+
+
+def split_state(conv_state) -> SlotState:
+    """(conv window, rule state) of a forward's `conv_state`, in any of
+    its three forms; either may be None."""
+    if isinstance(conv_state, SlotState):
+        return conv_state
+    return SlotState(conv_state, None)
+
+
 class LayerIx(NamedTuple):
     """Where a layer of the scan stands among the layers of its operator's
-    kind (an attention layer's row of the KV pool, a conv layer's of the
-    conv state) and among those of its FFN's kind (an expert layer's block
+    kind (an attention layer's row of the KV pool, a conv or linear layer's
+    of the per-slot state) and among those of its FFN's kind (an expert layer's block
     of the expert stacks). int32 scalars, traced inside the scan."""
     op: jnp.ndarray
     ffn: jnp.ndarray
@@ -243,7 +309,7 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
     """
     of_kind = {name: kind for kind, names in KIND_PARAMS.items()
                for name in names}
-    seen = dict.fromkeys((ATTENTION, CONV, DENSE, EXPERTS), 0)
+    seen = dict.fromkeys((ATTENTION, CONV, LINEAR, DENSE, EXPERTS), 0)
     loads = []
     for first, period, repeats in cfg.layer_plan():
         per = {k: sum(k in pair for pair in period) for k in seen}
@@ -293,8 +359,9 @@ def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     B, T, _ = h.shape
     with jax.named_scope("attn_qkv"):
         q, k, v = _qkv(cfg, lp, h)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        if cfg.rope_theta is not None:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
     attn = attn_fn(q, k, v)
     with jax.named_scope("attn_out"):
         return qeinsum("bte,ed->btd", attn.reshape(B, T, cfg.q_dim),
@@ -318,33 +385,82 @@ def _conv_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
         return qeinsum("btd,de->bte", gate_c * c, lp["conv_out"])
 
 
+def _linear_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
+                         taps_fn, rule_fn) -> jnp.ndarray:
+    """Gated delta-rule linear attention over hiddens h [B, T, D]:
+    [q | k | v | z] = h W_in and the gates b, a = h W_ba (float32); q | k |
+    v through the depthwise causal convolution and a SiLU; per head the
+    rule (ops/gated_delta.py: q, k L2-normalised there, the decay from a,
+    the write strength from b); y = (RMSNorm(o) * silu(z)) W_out. Where the
+    convolution's predecessors come from is the caller's `taps_fn` (as a
+    conv layer's), which state the rule continues its `rule_fn(q, k, v, g,
+    beta) -> o [B, T, H, dv] float32`."""
+    B, T, _ = h.shape
+    H, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    kd, cd = cfg.linear_key_dim, cfg.linear_conv_dim
+    with jax.named_scope("lin_in"):
+        u = qeinsum("btd,de->bte", h, lp["lin_in"])
+        qkv, z = u[..., :cd], u[..., cd:]
+        ba = jnp.einsum("btd,de->bte", h, lp["lin_ba"],
+                        preferred_element_type=jnp.float32)
+        g, beta = gated_delta.gates(
+            ba[..., H:], ba[..., :H], lp["lin_A_log"], lp["lin_dt_bias"],
+            cfg.linear_allow_neg_eigval)
+    with jax.named_scope("lin_conv"):
+        c = jax.nn.silu(shortconv.short_conv(lp["lin_conv_w"], taps_fn(qkv),
+                                             qkv))
+    with jax.named_scope("lin_rule"):
+        o = rule_fn(c[..., :kd].reshape(B, T, H, dk),
+                    c[..., kd:2 * kd].reshape(B, T, H, dk),
+                    c[..., 2 * kd:].reshape(B, T, H, dv), g, beta)
+    with jax.named_scope("lin_out"):
+        o = rmsnorm(o, lp["lin_norm"], cfg.rms_norm_eps).reshape(B, T, H * dv)
+        return qeinsum("bte,ed->btd", (o * jax.nn.silu(z)).astype(h.dtype),
+                       lp["lin_out"])
+
+
 def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
                 x: jnp.ndarray, positions: jnp.ndarray, attn_fn,
                 taps_fn=None, valid=None, mesh=None, impl: str = "jnp",
-                layer=None):
+                layer=None, rule_fn=None):
     """One layer over [B, T, D] hiddens: the SINGLE definition of the
     layer math for every forward — full sequences, the ragged stream
     ([1, T, D]) and the decode batch ([B, 1, D]). Only the operator's
     schedule differs, injected as `attn_fn(q, k, v) -> [B, T, H, hd]`
-    (attention layers) and `taps_fn(z) -> predecessors` (conv layers); a
-    forward over a pool or a state writes it inside them. Returns (x',
-    expert load — None for a dense FFN)."""
+    (attention layers), `taps_fn(z) -> predecessors` (conv and linear
+    layers) and `rule_fn` (linear layers); a forward over a pool or a
+    state writes it inside them. Returns (x', expert load — None for a
+    dense FFN)."""
     op, ffn = kinds
-    h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    pre = cfg.norm_order == "pre"  # else the norms weigh the OUTPUTS
+
+    def norm(y, name):
+        return rmsnorm(y, lp[name], cfg.rms_norm_eps)
+
+    h = norm(x, "attn_norm") if pre else x
     if op == CONV:
-        x = x + _conv_op(cfg, lp, h, taps_fn)
+        delta = _conv_op(cfg, lp, h, taps_fn)
+    elif op == LINEAR:
+        delta = _linear_attention_op(cfg, lp, h, taps_fn, rule_fn)
     else:
-        x = x + _attention_op(cfg, lp, h, positions, attn_fn)
+        delta = _attention_op(cfg, lp, h, positions, attn_fn)
+    x = x + (delta if pre else norm(delta, "attn_norm"))
     with jax.named_scope("mlp"):
-        h2 = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        delta, load = _ffn(cfg, lp, ffn, h2, valid=valid, mesh=mesh,
-                           impl=impl, layer=layer)
+        delta, load = _ffn(cfg, lp, ffn, norm(x, "mlp_norm") if pre else x,
+                           valid=valid, mesh=mesh, impl=impl, layer=layer)
+        if not pre:
+            delta = norm(delta, "mlp_norm")
     return x + delta, load
 
 
-def _no_state(cfg: ModelConfig):
-    """taps_fn of a forward over whole sequences from position 0."""
-    return lambda z: shortconv.taps_full(z, cfg.conv_L_cache)
+def _no_state(cfg: ModelConfig, valid=None) -> dict:
+    """taps_fn and rule_fn of a forward over whole sequences from position
+    0: shifted copies, and the chunked rule from an empty state."""
+    return {
+        "taps_fn": lambda z: shortconv.taps_full(z, cfg.state_window[0]),
+        "rule_fn": lambda q, k, v, g, beta: gated_delta.chunked(
+            q, k, v, g, beta, valid)[0]}
 
 
 def forward_prefill(
@@ -377,9 +493,10 @@ def forward_prefill(
             vc = kv_write(vc, ix.op, slots, v)
             return causal_attention(q, k, v, seq_lens)
 
+        valid = positions < seq_lens[:, None]
         x, load = _layer_step(
-            cfg, lp, kinds, x, positions, attn_fn, _no_state(cfg),
-            valid=positions < seq_lens[:, None], layer=ix.ffn)
+            cfg, lp, kinds, x, positions, attn_fn, valid=valid,
+            layer=ix.ffn, **_no_state(cfg, valid))
         return x, kc, vc, load
 
     x, k_cache, v_cache, _ = scan_layers(cfg, body, x, params["layers"],
@@ -409,7 +526,7 @@ def forward_ragged(
     interpret: bool = False,
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
     moe_load: bool = False,  # also return the [Le, E] expert loads
-    conv_state=None,  # [Lc, slots+1, K-1, D] (donated; loop carry)
+    conv_state=None,  # [Lc, slots+1, K-1, D] or a SlotState (donated; carry)
     slot_ids=None,  # [B] each row's slot: its row of conv_state
     is_first=None,  # [B] the span is its request's first: state opens at 0
 ):
@@ -422,7 +539,10 @@ def forward_ragged(
     forward_prefill); each conv layer reads a span's predecessors from the
     stream and, before the span, from the row's slot of `conv_state`, and
     leaves the span's last positions there (ops/shortconv.taps_ragged; a
-    model with conv layers needs `conv_state`, `slot_ids`, `is_first`).
+    model with conv layers needs `conv_state`, `slot_ids`, `is_first`); a
+    linear-attention layer does the same for its convolution and continues
+    each row's rule state through the row's span (ops/gated_delta.ragged;
+    `conv_state` is then a SlotState).
     `out_idx` names the stream positions whose logits leave the forward: a
     [B] vector (each sequence's last token — the classic shape) returns
     [B, V]; a [B, O] matrix (speculative verification reads a logit at
@@ -437,8 +557,9 @@ def forward_ragged(
                          _adtype(params))[None]  # [1,T,D]
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
+    state = split_state(conv_state)
 
-    def body(x, lp, kinds, ix, kc, vc, conv):
+    def body(x, lp, kinds, ix, kc, vc, conv, rule):
         def attn_fn(q, k, v):  # [1, T, H, hd]
             nonlocal kc, vc
             with jax.named_scope("kv_write"):
@@ -461,27 +582,38 @@ def forward_ragged(
             conv = conv.at[ix.op, slot_ids].set(rows)
             return [t[None] for t in taps]
 
+        def rule_fn(q, k, v, g, beta):  # [1, T, H, .]
+            nonlocal rule
+            o, rule = gated_delta.ragged(
+                q[0], k[0], v[0], g[0], beta[0], rule, ix.op, slot_ids,
+                tok_seq, tok_pos, q_start, q_len, is_first, impl=attn_impl,
+                interpret=interpret)
+            return o[None]
+
         x, load = _layer_step(cfg, lp, kinds, x, positions, attn_fn, taps_fn,
                               valid=valid, mesh=mesh, impl=attn_impl,
-                              layer=ix.ffn)
-        return x, kc, vc, conv, load
+                              layer=ix.ffn, rule_fn=rule_fn)
+        return x, kc, vc, conv, rule, load
 
-    x, k_cache, v_cache, conv_state, load = scan_layers(
-        cfg, body, x, params["layers"], k_cache, v_cache, conv_state)
+    x, k_cache, v_cache, conv, rule, load = scan_layers(
+        cfg, body, x, params["layers"], k_cache, v_cache, *state)
     if out_idx.ndim == 1:
         x_last = x[0][out_idx]  # [B, D]
         logits = _logits(params, cfg, x_last[None])[0]  # [B, V]
     else:
         x_last = x[0][out_idx]  # [B, O, D]
         logits = _logits(params, cfg, x_last)  # [B, O, V]
-    return _results(logits, k_cache, v_cache, conv_state, load, moe_load)
+    return _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
+                    moe_load)
 
 
-def _results(logits, k_cache, v_cache, conv_state, load, moe_load):
-    """(logits, caches'[, conv_state'][, load]) of a step forward."""
+def _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
+             moe_load):
+    """(logits, caches'[, conv_state'][, load]) of a step forward;
+    conv_state' in the form `conv_state` was given in."""
     out = (logits, k_cache, v_cache)
     if conv_state is not None:
-        out += (conv_state,)
+        out += (conv if rule is None else SlotState(conv, rule),)
     return out + (load,) if moe_load else out
 
 
@@ -498,7 +630,8 @@ def forward_decode(
     active=None,  # [B] int32/bool — live decode slots (None = all live)
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
     moe_load: bool = False,  # also return the [Le, E] expert loads
-    conv_state=None,  # [Lc, >= B, K-1, D] (donated; loop carry): row b is slot b's
+    conv_state=None,  # [Lc, >= B, K-1, D] or a SlotState (donated; loop
+    # carry): row b is slot b's
 ):
     """One decode step for the whole batch (row b is slot b); returns
     (logits [B,V], caches'), then conv_state' where one was given and,
@@ -506,7 +639,8 @@ def forward_decode(
 
     `active` feeds MoE routing and the conv state: parked slots carry
     garbage tokens that are routed to no expert (models/moe.py) and leave
-    their slot's state as it was (ops/shortconv.taps_decode).
+    their slot's state as it was (ops/shortconv.taps_decode,
+    ops/gated_delta.decode).
     """
     B = tokens.shape[0]
     valid = None if active is None else (active > 0)[:, None]
@@ -516,8 +650,9 @@ def forward_decode(
     pos2 = positions[:, None]  # [B,1]
     write_slots = flat_slot_indices(page_table, pos2, page_size)[:, 0]  # [B]
     seq_lens = positions + 1
+    state = split_state(conv_state)
 
-    def body(x, lp, kinds, ix, kc, vc, conv):
+    def body(x, lp, kinds, ix, kc, vc, conv, rule):
         def attn_fn(q, k, v):  # [B, 1, H, hd]
             nonlocal kc, vc
             with jax.named_scope("kv_write"):
@@ -540,15 +675,23 @@ def forward_decode(
                 conv, rows[None], (ix.op, 0, 0, 0))
             return [t[:, None] for t in taps]
 
+        def rule_fn(q, k, v, g, beta):  # [B, 1, H, .]
+            nonlocal rule
+            o, rule = gated_delta.decode(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], rule, ix.op,
+                active, impl=attn_impl)
+            return o[:, None]
+
         x, load = _layer_step(cfg, lp, kinds, x, pos2, attn_fn, taps_fn,
                               valid=valid, mesh=mesh, impl=attn_impl,
-                              layer=ix.ffn)
-        return x, kc, vc, conv, load
+                              layer=ix.ffn, rule_fn=rule_fn)
+        return x, kc, vc, conv, rule, load
 
-    x, k_cache, v_cache, conv_state, load = scan_layers(
-        cfg, body, x, params["layers"], k_cache, v_cache, conv_state)
+    x, k_cache, v_cache, conv, rule, load = scan_layers(
+        cfg, body, x, params["layers"], k_cache, v_cache, *state)
     logits = _logits(params, cfg, x)[:, 0, :]
-    return _results(logits, k_cache, v_cache, conv_state, load, moe_load)
+    return _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
+                    moe_load)
 
 
 def forward_prefill_sp(
@@ -614,12 +757,13 @@ def forward_embed(
     x = embed_lookup(params["embed"], tokens, _adtype(params))
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
+    valid = positions < seq_lens[:, None]
+
     def body(x, lp, kinds, ix):
         return _layer_step(
             cfg, lp, kinds, x, positions,
             lambda q, k, v: causal_attention(q, k, v, seq_lens),
-            _no_state(cfg), valid=positions < seq_lens[:, None],
-            layer=ix.ffn)
+            valid=valid, layer=ix.ffn, **_no_state(cfg, valid))
 
     x, _ = scan_layers(cfg, body, x, params["layers"])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps).astype(jnp.float32)

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ollamamq_tpu.config import CONV, ModelConfig
+from ollamamq_tpu.config import STATE_KINDS, ModelConfig
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.quant import QuantTensor, quantize_tensor
 
@@ -40,10 +40,11 @@ def quantize_params_int8(params: dict, cfg: ModelConfig) -> dict:
     Shapes are unchanged — each quantized leaf becomes a QuantTensor
     pytree node, and the dequant-fused helpers in ops/quant.py keep
     every forward's signature identical."""
-    if cfg.num_experts or cfg.count(CONV):
+    if cfg.num_experts or any(cfg.count(kind) for kind in STATE_KINDS):
         raise ValueError(
-            "int8 weight quantization does not cover MoE expert stacks or "
-            f"conv layers; load {cfg.name} with --weights-dtype=bfloat16")
+            "int8 weight quantization does not cover MoE expert stacks, conv "
+            f"or linear-attention layers; load {cfg.name} with "
+            "--weights-dtype=bfloat16")
     out = dict(params)
     layers = dict(params["layers"])
     for k in QUANT_LAYER_KEYS:

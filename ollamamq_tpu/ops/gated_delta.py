@@ -1,0 +1,330 @@
+"""Gated delta rule (Gated DeltaNet; Olmo-Hybrid's `linear_attention`
+layers): the recurrence, in the two forms a served step needs, and the
+three schedules that say which tokens continue which state.
+
+Per head, with a float32 state S [dk, dv] a sequence (S = 0 before its
+first token), decay a_t = exp(g_t) in (0, 1], write strength b_t, and q, k
+L2-normalised per head (q also scaled by dk^-1/2):
+
+    S' = a_t S_{t-1};  r_t = b_t (v_t - S'^T k_t);  S_t = S' + k_t r_t^T;
+    o_t = S_t^T q_t
+
+ONE definition of it (`step`: one token a row), and its chunked form for
+spans (`_prepare` + `_apply`: the WY / UT transform over CHUNK tokens — the
+chunk's tokens are solved against each other once, (I + A)^-1 by forward
+substitution, so the state is read once and written once a chunk instead
+of once a token). tests/test_olmo_hybrid.py holds both to the token-serial
+scan of the four lines above. Everything here is float32 at the highest
+matmul precision: the state is an accumulator over the whole sequence.
+
+Schedules (as ops/shortconv.py's):
+
+  - `chunked`: whole sequences [B, T] from position 0 and an empty state
+    (forward_prefill, forward_embed): a scan over the chunks.
+  - `ragged`: the flattened stream of a ragged step, rows continuing their
+    OWN state. Rows with ONE token (decode rows) go through `step`, batched
+    over the rows. Rows with longer spans go through the chunked form on
+    the stream where it lies: the stream is cut into WINDOWS of CHUNK
+    tokens, a window's tokens are solved against each other under a
+    same-row mask (`_prepare`, all windows at once: no gather, no padded
+    [rows, T] layout), and a loop whose trip count is the number of (row,
+    window) pairs the step's spans really touch carries each row's state
+    through its windows (`_apply`): a 200-token span costs 4 or 5 trips
+    whatever else is in the stream, a decode row costs none.
+  - `decode`: one token a slot (the fused scan's body): active slots
+    advance, parked slots keep their state.
+
+State layout: [linear layers, slots + 1, dk, H * dv] float32 — the key
+dimension on sublanes, every head's values side by side on lanes. With
+dv = 192 a [.., H, dk, dv] array would be padded to 256 lanes in HBM (a
+third more bytes to hold and to stream); H * dv = 5760 is a multiple of
+128. Row `slots` is the trash row padding rows write. On the chip the one-
+token rows run in a Pallas kernel that updates the rows in place
+(ops/pallas/gated_delta_step.py); `step` is its definition and the CPU's
+path.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+CHUNK = 64
+_SUB = 16  # forward substitution inside blocks of 16, then block merges
+_HI = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+NORM_EPS = 1e-6  # inside the root of q's and k's L2 norm
+
+
+def alloc_state(num_layers: int, max_slots: int, heads: int, key_dim: int,
+                value_dim: int):
+    """The per-slot rule state of a model's linear-attention layers
+    (zeros, float32), or None for a model that has none."""
+    if not num_layers:
+        return None
+    return jnp.zeros((num_layers, max_slots + 1, key_dim, heads * value_dim),
+                     _F32)
+
+
+def gates(a, b, a_log, dt_bias, allow_neg: bool):
+    """(g, beta) [..., H] float32 from the two gate projections: g =
+    -exp(A_log) softplus(a + dt_bias) (the log of the decay), beta =
+    sigmoid(b), doubled where the configuration allows negative
+    eigenvalues of I - beta k k^T."""
+    g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
+        a.astype(_F32) + dt_bias.astype(_F32))
+    beta = jax.nn.sigmoid(b.astype(_F32))
+    return g, 2.0 * beta if allow_neg else beta
+
+
+def normalise(q, k):
+    """Per head: q / |q| * dk^-1/2 and k / |k|, float32."""
+    q, k = q.astype(_F32), k.astype(_F32)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True) + NORM_EPS)
+
+    return unit(q) * q.shape[-1] ** -0.5, unit(k)
+
+
+def _heads(state, n_heads: int):
+    """[..., dk, H * dv] -> [..., dk, H, dv]."""
+    return state.reshape(*state.shape[:-1], n_heads, -1)
+
+
+def step(state, q, k, v, g, beta, reset=None):
+    """One token a row. state [..., dk, H * dv]; q, k [..., H, dk] as the
+    convolution left them; v [..., H, dv]; g, beta [..., H]; reset [...]:
+    the row's state opens at zero. Returns (o [..., H, dv] float32, the
+    state after the token)."""
+    q, k = normalise(q, k)
+    s = _heads(state, q.shape[-2])
+    if reset is not None:
+        s = jnp.where(reset[..., None, None, None], 0.0, s)
+    kt, qt = jnp.swapaxes(k, -1, -2), jnp.swapaxes(q, -1, -2)  # [..., dk, H]
+    s = s * jnp.exp(g)[..., None, :, None]
+    r = beta[..., None] * (v.astype(_F32)
+                           - jnp.sum(s * kt[..., None], axis=-3))
+    s = s + kt[..., None] * r[..., None, :, :]
+    return jnp.sum(s * qt[..., None], axis=-3), s.reshape(state.shape)
+
+
+# -- the chunked form --------------------------------------------------------
+def _mm(spec: str, a, b):
+    return jnp.einsum(spec, a, b, precision=_HI,
+                      preferred_element_type=_F32)
+
+
+def _tri_inv(a):
+    """(I + a)^-1 for strictly lower-triangular a [..., C, C]: forward
+    substitution inside the diagonal blocks of _SUB (every block of every
+    chunk at once, _SUB - 1 small steps), then pairs of blocks merge,
+    [[P, 0], [R, Q]]^-1 = [[P', 0], [-Q' R P', Q']], until one is left.
+    Exact arithmetic, and as stable as substitution is: no power of `a`
+    is formed (with b = 2 and repeated keys those grow like 2^n C(n, k))."""
+    lead, c = a.shape[:-2], a.shape[-1]
+
+    def blocks(size, row_off):  # block (2i + row_off, 2i) of the grid
+        n = c // size
+        a4 = a.reshape(*lead, n, size, n, size)
+        step_ = 1 if row_off == 0 else 2
+        return jnp.stack([a4[..., i + row_off, :, i, :]
+                          for i in range(0, n, step_)], axis=-3)
+
+    d = blocks(_SUB, 0)  # [..., C / _SUB, _SUB, _SUB]
+    eye = jnp.eye(_SUB, dtype=_F32)
+    rows = [jnp.broadcast_to(eye[0], d.shape[:-2] + (_SUB,))]
+    for i in range(1, _SUB):  # x_i = e_i - sum_{m<i} d[i, m] x_m
+        prev = jnp.stack(rows, axis=-2)
+        rows.append(eye[i] - jnp.sum(d[..., i, :i, None] * prev, axis=-2))
+    x, size = jnp.stack(rows, axis=-2), _SUB
+    while size < c:
+        p, q = x[..., 0::2, :, :], x[..., 1::2, :, :]
+        low = -_mm("...ij,...jk->...ik",
+                   _mm("...ij,...jk->...ik", q, blocks(size, 1)), p)
+        x = jnp.concatenate([
+            jnp.concatenate([p, jnp.zeros_like(p)], axis=-1),
+            jnp.concatenate([low, q], axis=-1)], axis=-2)
+        size *= 2
+    return x[..., 0, :, :]
+
+
+def _prepare(q, k, v, g, beta, same):
+    """A chunk's tokens solved against each other, for any number of chunks
+    at once. q, k [N, C, H, dk] (as the convolution left them), v [N, C, H,
+    dv], g, beta [N, C, H] (0 on tokens that take no part), same [N, C, C]
+    bool: token j is token i's own or an earlier one of the SAME row.
+    Returns what `_apply` needs of each chunk, heads leading: u [N, H, C,
+    dv] and w [N, H, C, dk] (the chunk's corrected values = u - w S for an
+    incoming state S), attn [N, H, C, C], qg (q scaled by its decay), k and
+    gc [N, H, C] (each token's log decay since its row entered the chunk)."""
+    q, k = normalise(q, k)
+    q, k, v = (jnp.moveaxis(x, 2, 1) for x in (q, k, v.astype(_F32)))
+    g, beta = jnp.moveaxis(g, 2, 1), jnp.moveaxis(beta, 2, 1)  # [N, H, C]
+    mask = same[:, None]  # [N, 1, C, C]
+    gc = _mm("nij,nhj->nhi", same.astype(_F32), g)
+    decay = jnp.exp(jnp.where(mask, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))
+    strict = mask & ~jnp.eye(same.shape[-1], dtype=bool)
+    kk = _mm("nhik,nhjk->nhij", k, k)
+    t = _tri_inv(jnp.where(strict, beta[..., None] * kk * decay, 0.0))
+    u = _mm("nhij,nhjd->nhid", t, beta[..., None] * v)
+    w = _mm("nhij,nhjd->nhid", t, (beta * jnp.exp(gc))[..., None] * k)
+    attn = _mm("nhik,nhjk->nhij", q, k) * decay
+    return {"u": u, "w": w, "attn": attn, "qg": q * jnp.exp(gc)[..., None],
+            "k": k, "gc": gc}
+
+
+def _apply(s, c, member):
+    """One row's tokens of one chunk against the row's incoming state.
+    s [..., H, dk, dv]; c: `_prepare`'s results at that chunk [..., H, C,
+    .]; member [..., C] bool: the row's tokens (at least one). Returns
+    (o [..., H, C, dv] — right at the row's tokens only —, the state after
+    the row's last token of the chunk)."""
+    m = member[..., None, :]  # [..., 1, C]
+    v_new = c["u"] - _mm("...hck,...hkd->...hcd", c["w"], s)
+    o = _mm("...hck,...hkd->...hcd", c["qg"], s) \
+        + _mm("...hij,...hjd->...hid", c["attn"], v_new)
+    # gc falls along a row (g <= 0): its least value is the last token's.
+    g_last = jnp.min(jnp.where(m, c["gc"], jnp.inf), axis=-1)  # [..., H]
+    kd = c["k"] * jnp.exp(jnp.where(
+        m, g_last[..., None] - c["gc"], -jnp.inf))[..., None]
+    s = s * jnp.exp(g_last)[..., None, None] \
+        + _mm("...hck,...hcd->...hkd", kd, v_new)
+    return o, s
+
+
+def _to_heads(state, n_heads: int):
+    """State rows [..., dk, H * dv] -> [..., H, dk, dv] (`_apply`'s)."""
+    return jnp.moveaxis(_heads(state, n_heads), -2, -3)
+
+
+def _from_heads(s):
+    s = jnp.moveaxis(s, -3, -2)  # [..., dk, H, dv]
+    return s.reshape(*s.shape[:-2], -1)
+
+
+def chunked(q, k, v, g, beta, valid=None, state=None):
+    """Whole sequences from an empty (or a given) state. q, k [B, T, H,
+    dk], v [B, T, H, dv], g, beta [B, T, H]; valid [B, T] bool (padding
+    takes no part). Returns (o [B, T, H, dv] float32, state [B, dk, H *
+    dv])."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    n = -(-t // CHUNK)
+    pad = n * CHUNK - t
+    if valid is None:
+        valid = jnp.ones((b, t), bool)
+    g = jnp.where(valid[..., None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+
+    def cut(x):  # [B, T, ...] -> [B * n, C, ...]
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return x.reshape(b * n, CHUNK, *x.shape[2:])
+
+    same = jnp.broadcast_to(jnp.tril(jnp.ones((CHUNK, CHUNK), bool)),
+                            (b * n, CHUNK, CHUNK))
+    c = _prepare(cut(q), cut(k), cut(v), cut(g), cut(beta), same)
+    c = {name: jnp.moveaxis(x.reshape(b, n, *x.shape[1:]), 1, 0)
+         for name, x in c.items()}  # chunks leading: the scan's xs
+    s0 = jnp.zeros((b, h, dk, dv), _F32) if state is None \
+        else _to_heads(state, h)
+    member = jnp.ones((b, CHUNK), bool)
+    s, o = jax.lax.scan(lambda s, ci: _apply(s, ci, member)[::-1], s0, c)
+    o = jnp.moveaxis(o, 0, 1)  # [B, n, H, C, dv]
+    o = jnp.moveaxis(o, 2, 3).reshape(b, n * CHUNK, h, dv)[:, :t]
+    return o, _from_heads(s)
+
+
+# -- the served schedules ----------------------------------------------------
+def _step_rows(impl, state, layer, slots, live, reset, q, k, v, g, beta,
+               interpret=False):
+    """`step` over rows of `state[layer]` named by `slots`, in place: live
+    rows advance, the others keep their state and read zeros."""
+    if impl == "pallas":
+        from ollamamq_tpu.ops.pallas.gated_delta_step import (
+            gated_delta_step_pallas)
+
+        return gated_delta_step_pallas(state, layer, slots, live, reset, q, k,
+                                       v, g, beta, interpret=interpret)
+    rows = jax.lax.dynamic_index_in_dim(state, layer, 0,
+                                        keepdims=False)[slots]
+    o, new = step(rows, q, k, v, g, beta, reset)
+    new = jnp.where(live[:, None, None], new, rows)
+    o = jnp.where(live[:, None, None], o, 0.0)
+    return o, state.at[layer, slots].set(new)
+
+
+def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
+           q_start, q_len, is_first, impl: str = "jnp", interpret=False):
+    """The flattened stream of a ragged step (see the module docstring).
+    q, k [T, H, dk], v [T, H, dv], g, beta [T, H]; state the whole carried
+    array, `layer` this layer's index in it; slot_ids, q_start, q_len,
+    is_first [B] per row; tok_seq, tok_pos [T] each token's row and
+    position (-1: padding). Returns (o [T, H, dv] float32, state')."""
+    t, h, dk = q.shape
+    dv = v.shape[-1]
+    single, multi = q_len == 1, q_len > 1
+    # 1. Rows with one token: `step` at the row's stream position.
+    at = jnp.clip(q_start, 0, t - 1)
+    o_rows, state = _step_rows(impl, state, layer, slot_ids, single,
+                               is_first > 0, q[at], k[at], v[at], g[at],
+                               beta[at], interpret=interpret)
+    # 2. Longer spans: windows of CHUNK tokens of the stream, all solved at
+    # once under the same-row mask; one-token rows and padding take no part.
+    n = -(-t // CHUNK)
+    pad = n * CHUNK - t
+    part = multi[tok_seq] & (tok_pos >= 0)
+
+    def cut(x, fill=0):
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
+                    constant_values=fill)
+        return x.reshape(n, CHUNK, *x.shape[1:])
+
+    row_of = cut(jnp.where(part, tok_seq, -1), -1)  # [n, C]
+    same = (row_of[:, :, None] == row_of[:, None, :]) \
+        & jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
+    c = _prepare(cut(q), cut(k), cut(v),
+                 cut(jnp.where(part[:, None], g, 0.0)),
+                 cut(jnp.where(part[:, None], beta, 0.0)), same)
+    # 3. The (row, window) pairs the spans touch, rows in order and each
+    # row's windows in order: pair p is row `b`, its window `w`.
+    first_w = q_start // CHUNK
+    n_w = jnp.where(multi, (q_start + q_len - 1) // CHUNK - first_w + 1, 0)
+    ends = jnp.cumsum(n_w)
+
+    def pair(p, carry):
+        out, state = carry
+        b = jnp.sum(ends <= p).astype(jnp.int32)
+        w = first_w[b] + p - (ends[b] - n_w[b])
+        ci = {name: jax.lax.dynamic_index_in_dim(x, w, 0, keepdims=False)
+              for name, x in c.items()}
+        member = jax.lax.dynamic_index_in_dim(row_of, w, 0,
+                                              keepdims=False) == b
+        opens = (is_first[b] > 0) & (w == first_w[b])
+        s = jax.lax.dynamic_slice(
+            state, (layer, slot_ids[b], 0, 0), (1, 1) + state.shape[2:])[0, 0]
+        o, s = _apply(jnp.where(opens, 0.0, _to_heads(s, h)), ci, member)
+        old = jax.lax.dynamic_index_in_dim(out, w, 0, keepdims=False)
+        out = jax.lax.dynamic_update_index_in_dim(
+            out, jnp.where(member[None, :, None], o, old), w, 0)
+        state = jax.lax.dynamic_update_slice(
+            state, _from_heads(s)[None, None], (layer, slot_ids[b], 0, 0))
+        return out, state
+
+    out, state = jax.lax.fori_loop(
+        0, ends[-1], pair, (jnp.zeros((n, h, CHUNK, dv), _F32), state))
+    o = jnp.moveaxis(out, 1, 2).reshape(n * CHUNK, h, dv)[:t]
+    # a one-token row's output at its stream position (others: dropped)
+    o = o.at[jnp.where(single, q_start, t)].set(o_rows, mode="drop")
+    return o, state
+
+
+def decode(q, k, v, g, beta, state, layer, active=None, impl: str = "jnp"):
+    """One token a slot: q, k [S, H, dk], v [S, H, dv], g, beta [S, H]; row
+    s of `state[layer]` is slot s's. Returns (o [S, H, dv], state')."""
+    n = q.shape[0]
+    live = jnp.ones((n,), bool) if active is None else active > 0
+    return _step_rows(impl, state, layer, jnp.arange(n, dtype=jnp.int32),
+                      live, jnp.zeros((n,), bool), q, k, v, g, beta)

@@ -263,6 +263,33 @@ CONV_STATE_CARRIED_TOTAL = REGISTRY.counter(
     "Rows of launched steps that read the conv state an earlier step left "
     "in their slot: later chunks of a prompt, decode rows, a fused scan's "
     "active slots", labels=("model",))
+HBM_LIN_STATE_BYTES = REGISTRY.gauge(
+    "ollamamq_hbm_lin_state_bytes",
+    "Bytes the linear-attention layers' per-slot rule state occupies per "
+    "model runtime (linear layers x (slots + 1) x key dim x heads x value "
+    "dim, float32; fixed, whatever the context lengths; their "
+    "convolution's window is under ollamamq_hbm_conv_state_bytes; 0 for a "
+    "model without such layers)", labels=("model",))
+LIN_STATE_RESETS_TOTAL = REGISTRY.counter(
+    "ollamamq_lin_state_resets_total",
+    "Rows of launched steps whose slot's linear-attention state the "
+    "program opened at zero: a request's first span (every admission, "
+    "every replay)", labels=("model",))
+LIN_STATE_CARRIED_TOTAL = REGISTRY.counter(
+    "ollamamq_lin_state_carried_total",
+    "Rows of launched steps that continued the linear-attention state an "
+    "earlier step left in their slot: later chunks of a prompt, decode "
+    "rows, a fused scan's active slots", labels=("model",))
+LIN_STEP_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_lin_step_rows_total",
+    "Row-passes through the delta rule's one-token form (a state row read "
+    "once and written once a linear layer): a ragged step's 1-token rows, "
+    "a fused scan's active slots x its passes", labels=("model",))
+LIN_SPAN_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_lin_span_tokens_total",
+    "Tokens of spans longer than one token that went through the delta "
+    "rule's chunked form (the state read and written once a 64-token "
+    "window a span touches)", labels=("model",))
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

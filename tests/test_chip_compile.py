@@ -299,16 +299,29 @@ LFM2_CFG = ModelConfig(
     layer_types=("conv",) + ("full_attention", "conv", "conv", "conv") * 2)
 
 
+# Olmo-Hybrid-7B's layers (config.py) over two of its periods and a small
+# vocabulary: 30 heads of 128 in the attention kernels (3840 lanes, group 1),
+# the rule's state rows [96, 30 x 192] float32 for 6 linear layers.
+OLMO_HYBRID_CFG = ModelConfig(
+    name="chip-compile-olmo-hybrid-widths", vocab_size=2048, hidden_size=3840,
+    intermediate_size=11008, num_layers=8, num_heads=30, num_kv_heads=30,
+    head_dim=128, max_seq_len=MP * PS, rope_theta=None, rms_norm_eps=1e-6,
+    qk_norm="full", norm_order="post", linear_num_key_heads=30,
+    linear_num_value_heads=30, linear_key_head_dim=96,
+    linear_value_head_dim=192, linear_conv_kernel_dim=4,
+    linear_allow_neg_eigval=True,
+    layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2)
+
+
 def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     """One of the pipelined loop's two programs as the engine jits it,
     lowered for one described chip: (lowered, the packed input's words,
     the bytes of the state it carries)."""
     from types import SimpleNamespace
 
-    from ollamamq_tpu.config import ATTENTION, CONV
+    from ollamamq_tpu.config import ATTENTION
     from ollamamq_tpu.engine import engine as eng_mod
     from ollamamq_tpu.engine.engine import ModelRuntime
-    from ollamamq_tpu.ops import shortconv
 
     one = SingleDeviceSharding(v5e.devices[0])
 
@@ -330,11 +343,11 @@ def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
     pool = s((cfg.count(ATTENTION), NP * PS, cfg.kv_dim), jnp.bfloat16)
     recent, last_ids = s((S + 1, W)), s((S,))
-    # The conv layers' per-slot state: None (no leaf) without such layers.
-    conv = jax.eval_shape(lambda: shortconv.alloc_state(
-        cfg.count(CONV), S, cfg.conv_L_cache, cfg.hidden_size))
-    if conv is not None:
-        conv = s(conv.shape, conv.dtype)
+    # The per-slot state: None (no leaf) for a model without such layers,
+    # the conv window's array, or a SlotState with the rule's state too.
+    conv = jax.tree_util.tree_map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.alloc_slot_state(cfg, S)))
     if which == "mq_ragged_step":
         fn = rt._get_ragged_jit(T, 0, (True, True, True))
         words = rt._ragged_layout(T).size
@@ -392,19 +405,46 @@ def test_lfm2_width_step_programs_carry_pool_and_conv_state_in_place(
     assert mem.temp_size_in_bytes < 32 * 2048 * 1792 * 2 // 4, mem
 
 
-@pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG], ids=["dense", "lfm2"])
+@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
+def test_olmo_hybrid_width_step_programs_carry_the_rule_state_in_place(
+        v5e, which, monkeypatch):
+    """Linear-attention layers (PR 35), at Olmo-Hybrid-7B's widths: the
+    attention kernels at 30 kv heads of 128 (3840 lanes, group 1) and the
+    rule's step kernel on [96, 5760] float32 rows compile for the chip; the
+    KV pool — for the 2 attention layers only — the window of the linear
+    layers' convolution, the rule's state (6 x 65 rows of 2.2 MB: 863 MB,
+    held exactly — no lane padding — and never copied), the ring and the id
+    carry all come back aliased; the temporaries stay under a TENTH of the
+    rule's state (a gather of a layer's 64 rows would be a sixth)."""
+    lowered, _, carried = _lower_step_program(v5e, which, monkeypatch,
+                                              OLMO_HYBRID_CFG)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "gated_delta_step_pallas" in text
+    assert text.count("tpu_custom_call") >= 1 + 3  # attention, 3 linear layers
+    mem = compiled.memory_analysis()
+    rule = 6 * (B + 1) * 96 * 30 * 192 * 4
+    window = 6 * (B + 1) * 3 * 11520 * 2
+    assert carried >= 2 * 2 * NP * PS * 3840 * 2 + rule + window
+    assert mem.alias_size_in_bytes >= carried, (mem, carried)
+    assert mem.temp_size_in_bytes < rule // 10, mem
+
+
+@pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG, OLMO_HYBRID_CFG],
+                         ids=["dense", "lfm2", "olmo_hybrid"])
 def test_ragged_step_is_fed_one_host_array(v5e, monkeypatch, cfg):
     """One upload a step: besides `params`, the compiled ragged step has
     exactly ONE parameter that is not donated device state — the packed
     int32 buffer of its host inputs. The RNG key is made inside (no key
     parameter), so nothing else is dispatched or transferred for a step.
     The conv layers' state is one more donated argument (no leaf at all
-    for a model without such layers)."""
+    for a model without such layers), a linear-attention model's two."""
     lowered, words, _ = _lower_step_program(v5e, "mq_ragged_step",
                                             monkeypatch, cfg)
     lowered.compile()
     _params, *rest = lowered.args_info[0]
     rest = jax.tree_util.tree_leaves(rest)
     fed = [a for a in rest if not a.donated]
-    assert len(rest) == (6 if cfg is LFM2_CFG else 5) and len(fed) == 1, rest
+    n_state = {LOOP_CFG: 0, LFM2_CFG: 1, OLMO_HYBRID_CFG: 2}[cfg]
+    assert len(rest) == 5 + n_state and len(fed) == 1, rest
     assert (fed[0].shape, fed[0].dtype) == ((words,), jnp.int32), fed

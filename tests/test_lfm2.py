@@ -24,7 +24,7 @@ import pytest
 
 from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, MODEL_CONFIGS,
                                  EngineConfig, ModelConfig,
-                                 validate_conv_state, validate_quant_config)
+                                 validate_slot_state, validate_quant_config)
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops import shortconv
 from ollamamq_tpu.ops.sampling import SamplingParams
@@ -415,7 +415,7 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert piped == settled
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(hybrid)
-    assert rt.conv.shape == (6, 5, 2, 64) and rt.conv_state_bytes > 0
+    assert rt.slot_state.shape == (6, 5, 2, 64) and rt.conv_state_bytes > 0
     # every launched step says what it did with the conv state, and
     # uploads ONE packed array
     assert all(s["h2d_transfers"] == 1 for s in samples)
@@ -436,7 +436,7 @@ def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
     first = (0, "first", _prompt(2, 37), SamplingParams(max_tokens=11))
     drive(eng, [first], False, monkeypatch)
     rt = _rt(eng)
-    left = np.asarray(rt.conv)
+    left = np.asarray(rt.slot_state)
     assert np.abs(left[:, 0]).max() > 0          # slot 0 holds its state
     reused, _ = drive(eng, [probe], False, monkeypatch)
     assert reused["probe"] == fresh["probe"]
@@ -478,11 +478,11 @@ def test_a_voided_step_leaves_nothing_a_later_request_can_see(monkeypatch):
     (dict(mesh_shape={"expert": 2}), "--tp / --ep: the conv layers"),
 ], ids=["spec", "sp", "tp", "ep"])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
-    err = validate_conv_state(LFM2, **kw)
+    err = validate_slot_state(LFM2, **kw)
     assert err and match in err and "test-tiny-lfm2" in err
     # ...and a model without conv layers is not asked
-    assert validate_conv_state(MODEL_CONFIGS["test-tiny-moe"], **kw) is None
-    assert validate_conv_state(LFM2, mesh_shape={"data": 2}) is None
+    assert validate_slot_state(MODEL_CONFIGS["test-tiny-moe"], **kw) is None
+    assert validate_slot_state(LFM2, mesh_shape={"data": 2}) is None
 
 
 def test_the_runtime_refuses_them_at_construction():

@@ -321,3 +321,34 @@ def test_concurrent_enqueue_drain(core):
     ct.join()
     assert len(popped) == 4 * N
     assert len(set(popped)) == 4 * N  # unique req ids, no double-pop
+
+
+def test_processes_started_at_once_all_load_a_fresh_build(tmp_path):
+    """Six processes (the tier-1 run's xdist workers on a fresh checkout)
+    meet a cpp/ without the library at the same moment: each loads a whole
+    one. Before the build ran under an inter-process lock each started a
+    `make` of its own, and one could dlopen what another was still writing
+    (`OSError: ... file too short`: 11 set-up errors in the driver's run)."""
+    import shutil
+    import subprocess
+    import sys
+
+    from ollamamq_tpu.core import mqcore
+
+    cpp = tmp_path / "cpp"
+    cpp.mkdir()
+    for name in os.listdir(mqcore._CPP_DIR):
+        if name.endswith((".cpp", ".h")) or name == "Makefile":
+            shutil.copy(os.path.join(mqcore._CPP_DIR, name), cpp / name)
+    code = ("import ctypes, sys; from ollamamq_tpu.core import mqcore; "
+            "lib = ctypes.CDLL(mqcore._ensure_built(sys.argv[1])); "
+            "lib.mq_new; print('loaded')")
+    procs = [subprocess.Popen([sys.executable, "-c", code, str(cpp)],
+                              cwd=mqcore._REPO_ROOT, stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True)
+             for _ in range(6)]
+    outs = [p.communicate(timeout=300) for p in procs]
+    assert [p.returncode for p in procs] == [0] * 6, [e[-300:] for _, e in outs]
+    assert all(out.strip() == "loaded" for out, _ in outs)
+    assert (cpp / "libmqcore.so").exists()
+    assert not [n for n in os.listdir(cpp) if ".tmp." in n]

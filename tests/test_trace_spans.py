@@ -259,12 +259,13 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
 
 # ------------------------------------------- names on the device-side programs
 @pytest.mark.parametrize("model", ["test-tiny", "test-tiny-olmoe",
-                                   "test-tiny-lfm2"])
+                                   "test-tiny-lfm2",
+                                   "test-tiny-olmo-hybrid"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
-    layers, of llama.CONV_SCOPES beside the attention layers') is in the
-    debug text of
+    layers, of llama.CONV_SCOPES beside the attention layers'; with
+    linear-attention layers, of llama.LINEAR_SCOPES) is in the debug text of
     the engine's OWN ragged and decode programs, the modules are named
     after the stable jit functions, and README lists every one of these
     names in its span table."""
@@ -282,7 +283,8 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
                     dtype=jnp.float32)
     rt = eng.runtimes[model]
     scopes = llama.SCOPES + (moe.SCOPES if rt.cfg.num_experts else ()) \
-        + (llama.CONV_SCOPES if rt.conv is not None else ())
+        + (llama.CONV_SCOPES if rt.cfg.count("conv") else ()) \
+        + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention") else ())
     seen = {}
 
     def spy(site, getter):
@@ -330,7 +332,8 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_prefill_sp",
                  "mq_embed", "mq_encode"}
     assert set(llama.SCOPES) | set(llama.CONV_SCOPES) | set(moe.SCOPES) \
-        | jit_names | set(SPAN_NAMES) <= documented
+        | set(llama.LINEAR_SCOPES) | jit_names | set(SPAN_NAMES) \
+        <= documented
     # ... and the five jit sites really are those functions.
     with open(os.path.join(_REPO, "ollamamq_tpu", "engine",
                            "engine.py"), encoding="utf-8") as f:

@@ -132,19 +132,49 @@ def reference_keys(mc) -> dict:
             "tie_word_embeddings": mc.tie_embeddings}
 
 
-def lfm2_reference():
-    """The benchmark's plain float32 reference of the hybrid family
-    (benchmarks/reference/lfm2_decoder.py), as a module."""
+def _reference(name: str):
     import importlib.util
     import os
 
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks", "reference",
-        "lfm2_decoder.py")
-    spec = importlib.util.spec_from_file_location("lfm2_decoder", path)
+        os.path.abspath(__file__))), "benchmarks", "reference", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def lfm2_reference():
+    """The benchmark's plain float32 reference of the hybrid family
+    (benchmarks/reference/lfm2_decoder.py), as a module."""
+    return _reference("lfm2_decoder")
+
+
+def olmo_hybrid_reference():
+    """...and of the linear-attention hybrid family
+    (benchmarks/reference/olmo_hybrid_decoder.py)."""
+    return _reference("olmo_hybrid_decoder")
+
+
+def olmo_hybrid_keys(mc) -> dict:
+    """What a configuration file says of the linear-attention hybrid
+    ModelConfig `mc`, in the published spellings: all that reference
+    reads."""
+    return {"hidden_size": mc.hidden_size,
+            "intermediate_size": mc.intermediate_size,
+            "num_attention_heads": mc.num_heads,
+            "num_key_value_heads": mc.num_kv_heads, "head_dim": mc.head_dim,
+            "rms_norm_eps": mc.rms_norm_eps, "qk_norm": mc.qk_norm,
+            "norm_order": mc.norm_order,
+            "rope_parameters": {"rope_theta": mc.rope_theta},
+            "layer_types": list(mc.layer_types),
+            "linear_num_key_heads": mc.linear_num_key_heads,
+            "linear_num_value_heads": mc.linear_num_value_heads,
+            "linear_key_head_dim": mc.linear_key_head_dim,
+            "linear_value_head_dim": mc.linear_value_head_dim,
+            "linear_conv_kernel_dim": mc.linear_conv_kernel_dim,
+            "linear_allow_neg_eigval": mc.linear_allow_neg_eigval,
+            "tie_word_embeddings": mc.tie_embeddings}
 
 
 def lfm2_keys(mc) -> dict:
