@@ -57,10 +57,11 @@ class StreamEntry:
         self.lock = threading.Lock()
         self.recovered = recovered
 
-    def append(self, token_id: int, text: str) -> None:
+    def extend(self, pairs) -> None:
+        """(id, text) pairs, one a token: a stream item's, or a WAL's."""
         with self.lock:
             if self.terminal is None:
-                self.frames.append((int(token_id), text))
+                self.frames.extend((int(t), x) for t, x in pairs)
 
     def finish(self, reason: str, error: str = "") -> None:
         with self.lock:
@@ -264,9 +265,11 @@ class DurabilityManager:
 
         def tap(item) -> None:
             if item.kind == "token":
-                entry.append(item.token_id, item.text)
-                wal.append_tokens(
-                    wal_rid, [[int(item.token_id), item.text]])
+                # One (id, text) pair a TOKEN, however many one item
+                # hands over: ?from=N and the recovery fold count pairs.
+                pairs = item.pairs()
+                entry.extend(pairs)
+                wal.append_tokens(wal_rid, pairs)
             else:
                 reason = (item.finish_reason.value
                           if item.finish_reason is not None
@@ -297,8 +300,7 @@ class DurabilityManager:
                 # client cut off mid-read can still replay the archive
                 # through the resume endpoint.
                 entry = self.registry.create(rid, recovered=True)
-                for tid, text in ent["toks"]:
-                    entry.append(tid, text)
+                entry.extend(ent["toks"])
                 entry.finish(ent["finished"])
                 continue
             admit = ent["admit"]
@@ -307,8 +309,7 @@ class DurabilityManager:
                    + [int(i) for i, _ in toks])
             total = int(admit.get("max_tokens_total") or 0)
             entry = self.registry.create(rid, recovered=True)
-            for tid, text in toks:
-                entry.append(tid, text)
+            entry.extend(toks)
             remaining = total - len(gen)
             if remaining <= 0:
                 # The budget was already spent when the process died:

@@ -34,7 +34,7 @@ import logging
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -49,7 +49,8 @@ from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
 from ollamamq_tpu.engine import step_pack
-from ollamamq_tpu.engine.request import FinishReason, Request, StreamItem
+from ollamamq_tpu.engine.request import (FinishReason, Request, StreamItem,
+                                         wake_batch)
 from ollamamq_tpu.engine.scheduler import make_policy
 from ollamamq_tpu.engine.tokenizer import load_tokenizer
 from ollamamq_tpu.models import llama, moe, weights
@@ -641,6 +642,9 @@ class ModelRuntime:
         # Tokens launched for a slot's request and not yet appended to its
         # generated_ids: what count-based finishes are predicted from.
         self._ahead = np.zeros((S,), np.int32)
+        # Stream items (hand-overs) pushed since the settle in progress
+        # began: `stream_items` on its step sample.
+        self._stream_items = 0
         # The step that samples each slot's next input token (None: the
         # host holds the id), and the newest launched, unsettled step.
         self._tok_step: List[Optional["StepInFlight"]] = [None] * S
@@ -1390,66 +1394,94 @@ class ModelRuntime:
             core.mark_done(req.user, tokens=len(req.generated_ids))
         req.finish(reason, error=error)
 
-    def _emit_token(self, slot: int, tok: int, core: MQCore,
-                    ctx_len: int) -> bool:
-        """Process one sampled token for a slot. Returns True if seq
-        continues. `ctx_len`: the slot's context length once this token
-        is counted, as the step that sampled it planned it — the live
-        seq_lens may already be a step ahead (the next step is launched
-        before this one's tokens are emitted)."""
+    def _emit_row(self, slot: int, toks: Sequence[int], core: MQCore,
+                  ctx_len: int) -> int:
+        """Process the tokens ONE step sampled for a slot, in order, and
+        hand them to its stream as one item. Returns how many of them
+        were taken: all while the sequence continues, up to and including
+        the one that ended it otherwise (EOS, a stop string, a count —
+        the rest of the row is dropped). `ctx_len`: the slot's context
+        length once the first of them is counted, as the step that
+        sampled them planned it — the live seq_lens may already be a step
+        ahead (the next step is launched before this one's tokens are
+        emitted).
+
+        What a token costs stays a token's: the EOS comparison, the id
+        appended, the incremental detokeniser, the stop strings' hold-back,
+        the limits. The hand-over — the item, its push, the consumer's
+        wake-up, the durable tap's call — is once a row."""
         req = self.slot_req[slot]
         if req is None:
-            return False
+            return 0
         if req.cancelled.is_set() or req.stream.overflowed:
             # Overflowed stream == consumer stopped reading == client gone.
             self._finish_slot(slot, FinishReason.CANCELLED, core)
-            return False
-        if tok == self.tokenizer.eos_id:
-            self._finish_slot(slot, FinishReason.STOP, core)
-            return False
-        req.generated_ids.append(tok)
-        if not req.stats.first_token_at:
-            req.stats.first_token_at = time.monotonic()
-            self.ttft_window.append(req.stats.ttft_ms)
-            self._tm_ttft.observe(req.stats.ttft_ms)
-            if self.slo is not None:
-                self.slo.record("ttft", req.stats.ttft_ms)
-            req.trace_event("first_token", ttft_ms=round(req.stats.ttft_ms, 3))
-        elif len(req.generated_ids) % DECODE_EVENT_EVERY == 0:
-            req.trace_event("decode", tokens=len(req.generated_ids))
-        text = req._inc_decode(tok)
-        chunk = req.emit_text(text) if text else ""
-        if chunk is None:  # stop string fired: suppress held-back text
-            self._finish_slot(slot, FinishReason.STOP, core, flush=False)
-            return False
-        # Push EVERY sampled token, text or not (held-back bytes mid
-        # UTF-8 sequence, stop-string holdback): the id stream must be
-        # complete for the fleet's token-space failover replay — text
-        # consumers already skip empty chunks.
-        req.stream.push(StreamItem("token", text=chunk, token_id=tok))
-        # Stream-write stall attribution: a consumer backlog above the
-        # high-water mark opens a "stream" span on the trace; dropping
-        # back under closes it. Transition-edged so the event cap isn't
-        # chewed up by a persistently slow reader.
-        depth = req.stream.depth()
-        if not req._stream_stalled and depth >= req.stream.high_water:
-            req._stream_stalled = True
-            req.trace_event("stream_stall", depth=depth)
-        elif req._stream_stalled and depth < req.stream.high_water // 2:
-            req._stream_stalled = False
-            req.trace_event("stream_resume", depth=depth)
-        if len(req.generated_ids) >= req.sampling.max_tokens:
-            self._finish_slot(slot, FinishReason.LENGTH, core)
-            return False
-        if ctx_len + 1 >= self._max_ctx:
-            self._finish_slot(slot, FinishReason.LENGTH, core)
-            return False
-        return True
+            return 0
+        eos = self.tokenizer.eos_id
+        gen = req.generated_ids
+        decode = req._inc_decode
+        max_tokens = req.sampling.max_tokens
+        ids: List[int] = []
+        texts: List[str] = []
+        end = None  # the finish to make once the item is out
+        tail = ""   # text before a stop string: it has no id of its own
+        taken = 0
+        for tok in toks:
+            taken += 1
+            if tok == eos:
+                end = (FinishReason.STOP, True)
+                break
+            gen.append(tok)
+            if not req.stats.first_token_at:
+                req.stats.first_token_at = time.monotonic()
+                self.ttft_window.append(req.stats.ttft_ms)
+                self._tm_ttft.observe(req.stats.ttft_ms)
+                if self.slo is not None:
+                    self.slo.record("ttft", req.stats.ttft_ms)
+                req.trace_event("first_token",
+                                ttft_ms=round(req.stats.ttft_ms, 3))
+            elif len(gen) % DECODE_EVENT_EVERY == 0:
+                req.trace_event("decode", tokens=len(gen))
+            text = decode(tok)
+            chunk, stopped = req.emit_text(text) if text else ("", False)
+            if stopped:  # suppress the held-back text; the id is counted,
+                tail = chunk  # never pushed
+                end = (FinishReason.STOP, False)
+                break
+            # EVERY sampled token goes out, text or not (held-back bytes
+            # mid UTF-8 sequence, stop-string holdback): the id stream
+            # must be complete for the fleet's token-space failover
+            # replay — text consumers already skip empty chunks.
+            ids.append(tok)
+            texts.append(chunk)
+            if len(gen) >= max_tokens or ctx_len + 1 >= self._max_ctx:
+                end = (FinishReason.LENGTH, True)
+                break
+            ctx_len += 1
+        if ids:
+            req.stream.push(StreamItem.tokens(ids, texts))
+            self._stream_items += 1
+            # Stream-write stall attribution: a consumer backlog above the
+            # high-water mark opens a "stream" span on the trace; dropping
+            # back under closes it. Transition-edged so the event cap isn't
+            # chewed up by a persistently slow reader.
+            depth = req.stream.depth()
+            if not req._stream_stalled and depth >= req.stream.high_water:
+                req._stream_stalled = True
+                req.trace_event("stream_stall", depth=depth)
+            elif req._stream_stalled and depth < req.stream.high_water // 2:
+                req._stream_stalled = False
+                req.trace_event("stream_resume", depth=depth)
+        if tail:
+            req.stream.push(StreamItem("token", text=tail))
+        if end is not None:
+            self._finish_slot(slot, end[0], core, flush=end[1])
+        return taken
 
     def _ends_by_count(self, slot: int, req: Request) -> bool:
         """Will the tokens launched so far for `slot` (all counted in
         `_ahead` and `seq_lens` already) end its request by LENGTH? The
-        finishes _emit_token decides from a count, known before the ids
+        finishes _emit_row decides from a count, known before the ids
         are: such a row is left out of the next step's composition."""
         return (len(req.generated_ids) + int(self._ahead[slot])
                 >= req.sampling.max_tokens
@@ -1578,7 +1610,8 @@ class ModelRuntime:
         on the host already)."""
         self._seat_slot(slot, req, n)
         self.tokens_generated += 1
-        if self._emit_token(slot, tok, core, n):
+        self._emit_row(slot, (tok,), core, n)
+        if self.slot_req[slot] is req:
             # Token written at position n during the next decode step.
             self.last_tokens[slot] = tok
 
@@ -2915,50 +2948,40 @@ class ModelRuntime:
 
         emitted = wasted = 0
         ctx0 = h.ctx0
-
-        def emit(idx: int, j: int) -> bool:
-            """Token j of row idx; False once the row's request is gone
-            (finished/cancelled between launch & emit, or at an earlier
-            token of this step): the output is dropped."""
-            nonlocal emitted
-            kind, slot, req, _cpos, _span = rows[idx]
-            if self.slot_req[slot] is not req:
-                return False
-            self.tokens_generated += 1
-            emitted += kind != "prefill"  # decode-row tokens, as ever
-            self._emit_token(slot, int(toks[j, slot] if K else toks[idx, j]),
-                             core, ctx0[idx] + j)
-            return True
-
-        for idx, (kind, slot, req, cpos, span) in enumerate(rows):
-            if not h.emits[idx]:
-                continue  # a span inside a prompt samples nothing
-            if self.slot_req[slot] is not req:
-                wasted += 1
-                continue
-            n = int(h.n_emit[idx]) if kind == "spec" else max(1, K)
-            self._ahead[slot] -= n
-            if K:
-                continue  # a scan's tokens go out pass by pass, below
-            for j in range(n):
-                if not emit(idx, j):
-                    break  # EOS / stop string / cap hit mid-emission
-            if kind == "spec":
-                proposed, accepted = span - 1, n - 1
-                self._note_spec_outcome(req, proposed, accepted)
-                self._jrec("spec_verify", req, slot=slot,
-                           proposed=proposed, accepted=accepted,
-                           rolled_back=proposed - accepted)
-                if proposed > accepted and self.slot_req[slot] is req:
-                    # Rejected drafts wrote KV past the accepted
-                    # context: release their page claim (the finish
-                    # paths already freed everything when the stream
-                    # ended mid-emission).
-                    self._rollback_spec(slot, req, ctx0[idx] - 1 + span,
-                                        int(self.seq_lens[slot]) + 1)
-        for j in range(K):
-            for idx in range(len(rows)):
-                emit(idx, j)
+        self._stream_items = 0
+        # Row-major: a row's tokens of this step — 1, `n_emit` of a
+        # speculated row, K of a scan — leave as one stream item, and the
+        # consumers hear of the whole step once (`wake_batch`).
+        ids = (toks[:, :len(self.slot_req)].T if K else toks).tolist()
+        with wake_batch() as woken:
+            for idx, (kind, slot, req, cpos, span) in enumerate(rows):
+                if not h.emits[idx]:
+                    continue  # a span inside a prompt samples nothing
+                if self.slot_req[slot] is not req:
+                    wasted += 1  # finished/cancelled between launch & emit
+                    continue
+                n = int(h.n_emit[idx]) if kind == "spec" else max(1, K)
+                self._ahead[slot] -= n
+                taken = self._emit_row(
+                    slot, ids[slot] if K else ids[idx][:n], core, ctx0[idx])
+                self.tokens_generated += taken
+                if kind != "prefill":  # decode-row tokens, as ever
+                    emitted += taken
+                if kind == "spec":
+                    proposed, accepted = span - 1, n - 1
+                    self._note_spec_outcome(req, proposed, accepted)
+                    self._jrec("spec_verify", req, slot=slot,
+                               proposed=proposed, accepted=accepted,
+                               rolled_back=proposed - accepted)
+                    if proposed > accepted and self.slot_req[slot] is req:
+                        # Rejected drafts wrote KV past the accepted
+                        # context: release their page claim (the finish
+                        # paths already freed everything when the stream
+                        # ended mid-emission).
+                        self._rollback_spec(slot, req, ctx0[idx] - 1 + span,
+                                            int(self.seq_lens[slot]) + 1)
+        _sp.note(stream_items=self._stream_items,
+                 stream_wakeups=woken.wakeups)
 
         # Per-step engine telemetry: occupancy, KV-page pressure, MFU.
         self._tm_tokens.inc(emitted)

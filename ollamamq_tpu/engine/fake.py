@@ -16,7 +16,8 @@ from typing import Dict, List, Optional
 
 from ollamamq_tpu.config import EngineConfig, get_model_config
 from ollamamq_tpu.engine.engine import TPUEngine
-from ollamamq_tpu.engine.request import FinishReason, Request, StreamItem
+from ollamamq_tpu.engine.request import (FinishReason, Request, StreamItem,
+                                         wake_batch)
 from ollamamq_tpu.engine.tokenizer import ByteTokenizer
 from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.telemetry import stepprof
@@ -99,14 +100,17 @@ class FakeRuntime:
             pol.observe_finish(req, model=self.name)
         req.finish(reason)
 
+    def _drop_cancelled(self, req: Request, core) -> None:
+        self.active.remove(req)
+        core.mark_dropped(req.user)
+        self._jrec("finish", req, reason="cancelled",
+                   tokens=len(req.generated_ids))
+        req.finish(FinishReason.CANCELLED)
+
     def check_cancellations(self, core) -> None:
         for req in list(self.active):
             if req.cancelled.is_set():
-                self.active.remove(req)
-                core.mark_dropped(req.user)
-                self._jrec("finish", req, reason="cancelled",
-                           tokens=len(req.generated_ids))
-                req.finish(FinishReason.CANCELLED)
+                self._drop_cancelled(req, core)
 
     def step(self, core) -> None:
         # Fault seam: the fake analogue of ModelRuntime's dispatch hooks,
@@ -194,72 +198,80 @@ class FakeRuntime:
             time.sleep(self.token_latency_s)
         _sp.mark("dispatch")
         _sp.mark("collect")  # nothing to wait for; keeps the fixed order
-        for req in list(self.active):
-            if req.cancelled.is_set():
-                self.active.remove(req)
-                core.mark_dropped(req.user)
-                self._jrec("finish", req, reason="cancelled",
-                           tokens=len(req.generated_ids))
-                req.finish(FinishReason.CANCELLED)
-                continue
-            # Speculative fake: with --spec the step emits 1 + k words at
-            # once and journals the speculate/spec_verify decision pair —
-            # the fake word stream is deterministic regardless of
-            # stepping, so spec-on/off streams stay identical while the
-            # journal vocabulary (and its invariants, /debug surfaces,
-            # replay harness) exercise without jax. Fake drafts always
-            # verify: the "model" IS the proposer here.
-            emit_n = 1
-            if (self.ecfg.spec and self.ecfg.spec_k > 0
-                    and req._fake_remaining > 1):
-                k = min(self.ecfg.spec_k, req._fake_remaining - 1)
-                self._jrec("speculate", req, slot=-1, k=k, source="fake")
-                self._jrec("spec_verify", req, slot=-1, proposed=k,
-                           accepted=k, rolled_back=0)
-                tm.SPEC_TOKENS_TOTAL.labels(
-                    model=self.name, outcome="proposed").inc(k)
-                tm.SPEC_TOKENS_TOTAL.labels(
-                    model=self.name, outcome="accepted").inc(k)
-                tm.SPEC_ACCEPT_RATE.labels(model=self.name).set(1.0)
-                emit_n = 1 + k
-            for _ in range(emit_n):
-                word = f"word{req._fake_idx} "
-                req._fake_idx += 1
-                req._fake_remaining -= 1
-                req.generated_ids.append(req._fake_idx)
-                self.tokens_generated += 1
-                self._tm_tokens.inc()
-                if not req.stats.first_token_at:
-                    req.stats.first_token_at = time.monotonic()
-                    self._tm_ttft.observe(req.stats.ttft_ms)
-                    self._tm_tpot.observe(self.token_latency_s * 1e3)
-                    if self.slo is not None:
-                        self.slo.record("ttft", req.stats.ttft_ms)
-                    req.trace_event("first_token",
-                                    ttft_ms=round(req.stats.ttft_ms, 3))
-                elif self.slo is not None:
-                    self.slo.record("tpot", self.token_latency_s * 1e3)
-                chunk = req.emit_text(word)
-                if chunk is None:
-                    self.active.remove(req)
-                    self._finish_served(req, core, FinishReason.STOP)
-                    break
-                if chunk:
-                    req.stream.push(StreamItem("token", text=chunk,
-                                               token_id=req._fake_idx))
-                if req._fake_remaining <= 0:
-                    self.active.remove(req)
-                    tail = req.flush_text()
-                    if tail:
-                        req.stream.push(StreamItem("token", text=tail))
-                    self._finish_served(req, core, FinishReason.LENGTH)
-                    break
+        n_items = 0
+        with wake_batch() as woken:  # one wake-up a step, as the real one
+            for req in list(self.active):
+                n_items += self._emit_step(req, core)
         if _had_work:
             _sp.mark("detok")
             _sp.finish(n_prefill=len(admitted), n_decode=_n_decode,
+                       stream_items=n_items, stream_wakeups=woken.wakeups,
                        tokens=real + (self.tokens_generated - _gen0),
                        padded_tokens=real + (self.tokens_generated - _gen0),
                        compiled=False)
+
+    def _emit_step(self, req: Request, core) -> int:
+        """One step's words for one request, handed to its stream as ONE
+        item (1 word, or 1 + k with --spec). Returns the items pushed."""
+        if req.cancelled.is_set():
+            self._drop_cancelled(req, core)
+            return 0
+        # Speculative fake: with --spec the step emits 1 + k words at
+        # once and journals the speculate/spec_verify decision pair —
+        # the fake word stream is deterministic regardless of
+        # stepping, so spec-on/off streams stay identical while the
+        # journal vocabulary (and its invariants, /debug surfaces,
+        # replay harness) exercise without jax. Fake drafts always
+        # verify: the "model" IS the proposer here.
+        emit_n = 1
+        if (self.ecfg.spec and self.ecfg.spec_k > 0
+                and req._fake_remaining > 1):
+            k = min(self.ecfg.spec_k, req._fake_remaining - 1)
+            self._jrec("speculate", req, slot=-1, k=k, source="fake")
+            self._jrec("spec_verify", req, slot=-1, proposed=k,
+                       accepted=k, rolled_back=0)
+            tm.SPEC_TOKENS_TOTAL.labels(
+                model=self.name, outcome="proposed").inc(k)
+            tm.SPEC_TOKENS_TOTAL.labels(
+                model=self.name, outcome="accepted").inc(k)
+            tm.SPEC_ACCEPT_RATE.labels(model=self.name).set(1.0)
+            emit_n = 1 + k
+        ids, texts, end, tail = [], [], None, ""
+        for _ in range(emit_n):
+            word = f"word{req._fake_idx} "
+            req._fake_idx += 1
+            req._fake_remaining -= 1
+            req.generated_ids.append(req._fake_idx)
+            self.tokens_generated += 1
+            self._tm_tokens.inc()
+            if not req.stats.first_token_at:
+                req.stats.first_token_at = time.monotonic()
+                self._tm_ttft.observe(req.stats.ttft_ms)
+                self._tm_tpot.observe(self.token_latency_s * 1e3)
+                if self.slo is not None:
+                    self.slo.record("ttft", req.stats.ttft_ms)
+                req.trace_event("first_token",
+                                ttft_ms=round(req.stats.ttft_ms, 3))
+            elif self.slo is not None:
+                self.slo.record("tpot", self.token_latency_s * 1e3)
+            chunk, stopped = req.emit_text(word)
+            if stopped:
+                tail, end = chunk, FinishReason.STOP
+                break
+            if chunk:
+                ids.append(req._fake_idx)
+                texts.append(chunk)
+            if req._fake_remaining <= 0:
+                tail, end = req.flush_text(), FinishReason.LENGTH
+                break
+        if ids:
+            req.stream.push(StreamItem.tokens(ids, texts))
+        if tail:
+            req.stream.push(StreamItem("token", text=tail))
+        if end is not None:
+            self.active.remove(req)
+            self._finish_served(req, core, end)
+        return bool(ids) + bool(tail)
 
     # -- KV page migration (fake shape: no pages, just the word cursor) ----
     def export_request(self, rid: int):

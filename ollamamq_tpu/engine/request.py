@@ -4,9 +4,14 @@ A Request is the engine-side unit of work — the analogue of the reference's
 `Task` (/root/reference/src/dispatcher.rs:33-40), but carrying tokenized
 prompts and sampling params instead of opaque HTTP bodies. The TokenStream
 replaces the 32-deep mpsc responder channel (dispatcher.rs:617): the engine
-thread pushes items into a thread-safe queue; an optional callback lets the
-asyncio server mirror items into its event loop without the engine knowing
-about asyncio.
+thread pushes items into a thread-safe queue; a consumer registers how it
+is woken (`TokenStream.set_waker`), so the asyncio server hears of new items
+without the engine knowing about asyncio.
+
+The unit handed over is (step, stream): a "token" item carries ALL the
+tokens one step gave the stream — one on a ragged step, k of a fused scan,
+the accepted run of a speculated row — and a settled step wakes each
+consumer thread ONCE for every stream it touched (`wake_batch`).
 """
 
 from __future__ import annotations
@@ -19,6 +24,7 @@ import time
 from typing import Callable, List, Optional, Sequence
 
 from ollamamq_tpu.ops.sampling import SamplingParams
+from ollamamq_tpu.telemetry import schema as tm
 
 
 class FinishReason(str, enum.Enum):
@@ -43,8 +49,15 @@ ERROR_REASONS = (FinishReason.ERROR, FinishReason.KV_EXHAUSTED,
 @dataclasses.dataclass
 class StreamItem:
     kind: str  # "token" | "done" | "error"
+    # A "token" item: the text to send now — the tokens' emitted chunks
+    # joined (a chunk is "" while a stop string or a UTF-8 sequence holds
+    # its bytes back; held-back text rides a later item, or a flush item
+    # with no ids).
     text: str = ""
-    token_id: int = -1
+    # The sampled ids ONE step gave the stream, in order, and beside each
+    # the chunk it emitted: `"".join(texts) == text`.
+    token_ids: Sequence[int] = ()
+    texts: Sequence[str] = ()
     finish_reason: Optional[FinishReason] = None
     error: str = ""
     # Monotonic instant of TokenStream.push (0.0 = never pushed): the
@@ -52,19 +65,65 @@ class StreamItem:
     # once the frame is written.
     pushed_at: float = 0.0
 
+    @classmethod
+    def tokens(cls, ids: Sequence[int], texts: Sequence[str]) -> "StreamItem":
+        return cls("token", text="".join(texts), token_ids=ids, texts=texts)
+
+    def pairs(self) -> list:
+        """One (id, text) a token, as the WAL and the resume registry
+        keep them; text without an id rides id -1."""
+        if self.token_ids:
+            return [[int(t), x] for t, x in zip(self.token_ids, self.texts)]
+        return [[-1, self.text]]
+
+
+# Wake-ups owed by the pushes of this thread's open `wake_batch`.
+_batch = threading.local()
+
+
+class wake_batch:
+    """While open, this thread's pushes do not wake their consumers one
+    by one: at exit every waker met is called ONCE with the keys of all
+    its streams that were pushed to. The engine opens one around a step's
+    settle, so the server's loop gets one `call_soon_threadsafe` a step,
+    not one a token. `wakeups` reads the calls made at exit."""
+
+    def __enter__(self) -> "wake_batch":
+        self.wakeups = 0
+        self._outer = getattr(_batch, "owed", None)
+        if self._outer is None:
+            _batch.owed = {}
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._outer is not None:
+            return  # nested: the outermost batch makes the calls
+        owed, _batch.owed = _batch.owed, None
+        for waker, keys in owed.items():
+            self.wakeups += 1
+            _wake(waker, list(keys))
+
+
+def _wake(waker: Callable[[list], None], keys: list) -> None:
+    tm.STREAM_WAKEUPS_TOTAL.inc()
+    waker(keys)
+
 
 class TokenStream:
     """Thread-safe token channel, engine thread -> consumer.
 
     Backpressure: bounded queue (default 1024 items — generous vs the
-    reference's 32 because items are single tokens, not HTTP chunks).
-    `on_item` (if set) fires after each push, from the engine thread; the
-    server uses it to wake the asyncio loop.
+    reference's 32: an item is what one step gave the stream, not an HTTP
+    chunk). A consumer that wants to be woken calls `set_waker(waker,
+    key)`: after a push `waker([key, ...])` runs on the pushing thread —
+    at once, or once for all the streams pushed to inside a `wake_batch`
+    that share the waker (the server's: one per event loop).
     """
 
     def __init__(self, maxsize: int = 1024):
         self._q: "queue.Queue[StreamItem]" = queue.Queue(maxsize=maxsize)
-        self.on_item: Optional[Callable[[], None]] = None
+        self._waker: Optional[Callable[[list], None]] = None
+        self._wake_key = None
         # Durability tap (durability/manager.py): observes every pushed
         # item — the WAL's emitted-token log and the resumable-stream
         # frame registry read here, WITHOUT consuming the queue (the
@@ -112,9 +171,21 @@ class TokenStream:
             return
         if item.kind in ("done", "error"):
             self._closed = True
-        cb = self.on_item
-        if cb is not None:
-            cb()
+        waker = self._waker
+        if waker is not None:
+            owed = getattr(_batch, "owed", None)
+            if owed is None:
+                _wake(waker, [self._wake_key])
+            else:  # a dict as an ordered set: a stream is woken once
+                owed.setdefault(waker, {})[self._wake_key] = None
+
+    def set_waker(self, waker: Optional[Callable[[list], None]],
+                  key=None) -> None:
+        """`waker(keys)` is called from the pushing thread after a push;
+        `key` is whatever lets it find this stream's consumer. None
+        unregisters."""
+        self._wake_key = key
+        self._waker = waker
 
     def depth(self) -> int:
         return self._q.qsize()
@@ -216,11 +287,13 @@ class Request:
         self.embedding: Optional[list] = None
 
     # -- stop-string handling ---------------------------------------------
-    def emit_text(self, new_text: str) -> Optional[str]:
+    def emit_text(self, new_text: str) -> tuple:
         """Accumulate detokenized text, honoring stop strings with hold-back.
 
-        Returns the safe-to-emit chunk (may be ""), or None if a stop string
-        fired (caller should finish the request with reason=STOP).
+        Returns (chunk, stopped): the safe-to-emit chunk (may be "") and
+        whether a stop string fired — the chunk is then the text before
+        it, and the caller finishes the request with reason=STOP without
+        flushing (the held-back rest holds the stop string).
         """
         self._detok_text += new_text
         stops = self.sampling.stop
@@ -230,9 +303,7 @@ class Request:
                 if idx != -1:
                     chunk = self._detok_text[self.emitted_len:idx]
                     self.emitted_len = idx
-                    if chunk:
-                        self.stream.push(StreamItem("token", text=chunk))
-                    return None
+                    return chunk, True
             holdback = max(len(s) for s in stops) - 1
         else:
             holdback = 0
@@ -240,8 +311,8 @@ class Request:
         if safe_end > self.emitted_len:
             chunk = self._detok_text[self.emitted_len:safe_end]
             self.emitted_len = safe_end
-            return chunk
-        return ""
+            return chunk, False
+        return "", False
 
     def flush_text(self) -> str:
         """Emit any held-back text (at finish, when no stop matched)."""

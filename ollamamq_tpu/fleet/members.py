@@ -65,7 +65,7 @@ class Attempt:
         self.closed = False          # router asked this attempt to stop
         self.transport_dead = False  # HTTP stream died mid-flight
         self.base_n = 0              # tokens emitted by PRIOR attempts
-        self.n_items = 0             # token items this attempt emitted
+        self.n_items = 0             # tokens this attempt's frames carried
         self.text_mode = False       # replay state is text, not token ids
         self.prior_text = ""         # text emitted by prior attempts
         self.text_parts: list = []
@@ -343,7 +343,7 @@ class LocalMember(_MemberBase):
             return ByteTokenizer().encode(text, add_bos=True)
         return rt.tokenizer.encode(text, add_bos=True)
 
-    def begin(self, flight, resume: Optional[dict], on_item=None) -> Attempt:
+    def begin(self, flight, resume: Optional[dict], waker=None) -> Attempt:
         sampling = flight.sampling
         if resume and resume.get("gen_ids") is not None:
             # Token-space replay: prompt + every already-emitted token,
@@ -378,8 +378,8 @@ class LocalMember(_MemberBase):
         # The client's deadline is absolute; the attempt must not get a
         # fresh budget just because it re-enqueued later.
         req.deadline = flight.req.deadline
-        if on_item is not None:
-            req.stream.on_item = on_item
+        if waker is not None:
+            req.stream.set_waker(waker)
         att = Attempt(req, self)
         if resume and resume.get("gen_ids") is None:
             att.text_mode = True
@@ -416,7 +416,7 @@ class LocalMember(_MemberBase):
         target acked the import, abort otherwise)."""
         self.engine.resolve_export(att.req.req_id, commit=commit, why=why)
 
-    def import_stream(self, blob: dict, flight, on_item=None) -> Attempt:
+    def import_stream(self, blob: dict, flight, waker=None) -> Attempt:
         """Target side: land the shipped state straight into a decode
         slot (raises MigrationError when it cannot — the ack the source
         commit waits on is this returning)."""
@@ -424,8 +424,8 @@ class LocalMember(_MemberBase):
             blob, ip=flight.ip, family=flight.family,
             deadline=flight.req.deadline,
             trace_ctx=flight.ctx, trace_meter=False)
-        if on_item is not None:
-            req.stream.on_item = on_item
+        if waker is not None:
+            req.stream.set_waker(waker)
         return Attempt(req, self)
 
     def export_prefix(self, model: str, tokens):
@@ -645,7 +645,7 @@ class HttpMember(_MemberBase):
         return out
 
     # -- streams -----------------------------------------------------------
-    def begin(self, flight, resume: Optional[dict], on_item=None) -> Attempt:
+    def begin(self, flight, resume: Optional[dict], waker=None) -> Attempt:
         n_prior = int(resume.get("n_gen", 0)) if resume else 0
         prior_text = resume.get("text", "") if resume else ""
         gen_ids = resume.get("gen_ids") if resume else None
@@ -659,8 +659,8 @@ class HttpMember(_MemberBase):
             raw_prompt = flight.raw_prompt + prior_text
         req = Request(0, flight.user, flight.model, [], flight.sampling,
                       kind=flight.kind, raw_prompt=raw_prompt)
-        if on_item is not None:
-            req.stream.on_item = on_item
+        if waker is not None:
+            req.stream.set_waker(waker)
         att = Attempt(req, self)
         att.text_mode = True
         att.base_n = n_prior
@@ -757,15 +757,17 @@ class HttpMember(_MemberBase):
                     stream.push(StreamItem("error", finish_reason=reason,
                                            error=str(obj["error"])))
                     return
-                ids = obj.get("token_ids") or ()
-                att.token_ids.extend(int(t) for t in ids)
+                ids = [int(t) for t in obj.get("token_ids") or ()]
+                att.token_ids.extend(ids)
                 txt = obj.get("response", "")
                 if txt:
-                    att.n_items += 1
+                    att.n_items += max(1, len(ids))
                     att.text_parts.append(txt)
+                    # A frame's text is complete with its LAST id (ids
+                    # whose text was held back lead it).
                     stream.push(StreamItem(
-                        "token", text=txt,
-                        token_id=int(ids[0]) if len(ids) == 1 else -1))
+                        "token", text=txt, token_ids=ids,
+                        texts=([""] * (len(ids) - 1) + [txt]) if ids else ()))
                 if obj.get("done"):
                     reason = _REASONS.get(obj.get("done_reason", "stop"),
                                           FinishReason.STOP)
@@ -853,7 +855,7 @@ class HttpMember(_MemberBase):
         except Exception:  # noqa: BLE001 — a dead source resolves itself
             pass
 
-    def import_stream(self, blob: dict, flight, on_item=None) -> Attempt:
+    def import_stream(self, blob: dict, flight, waker=None) -> Attempt:
         """Target side over the wire: POST the packed blob; a 2xx status
         line IS the import ack (the member installs the slot before it
         starts streaming), then the continuation rides the same NDJSON
@@ -865,8 +867,8 @@ class HttpMember(_MemberBase):
         gen = [int(t) for t in state.get("generated_ids", ())]
         req = Request(0, flight.user, flight.model, [], flight.sampling,
                       kind=flight.kind, raw_prompt=flight.raw_prompt)
-        if on_item is not None:
-            req.stream.on_item = on_item
+        if waker is not None:
+            req.stream.set_waker(waker)
         att = Attempt(req, self)
         att.text_mode = True
         att.base_n = len(gen)

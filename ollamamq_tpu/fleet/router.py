@@ -371,7 +371,9 @@ class FleetRouter:
         return (self.core.total_queued() + len(self.pending)
                 + sum(1 for f in self.flights if not f.done))
 
-    def notify(self) -> None:
+    def notify(self, _streams=None) -> None:
+        """Wake the pump thread; also the waker of every attempt's stream
+        (`TokenStream.set_waker`), which passes the streams' keys."""
         with self._cond:
             self._cond.notify()
 
@@ -822,7 +824,7 @@ class FleetRouter:
 
     def _dispatch(self, flight: _Flight, mem) -> bool:
         try:
-            attempt = mem.begin(flight, flight.resume, on_item=self.notify)
+            attempt = mem.begin(flight, flight.resume, waker=self.notify)
         except Exception as e:  # noqa: BLE001
             log.exception("dispatch of req %d to %s failed",
                           flight.rid0, mem.name)
@@ -929,7 +931,7 @@ class FleetRouter:
         return did
 
     def _forward_token(self, flight: _Flight, item) -> None:
-        if not item.text and item.token_id < 0:
+        if not item.text and not item.token_ids:
             return
         if item.text and not flight.req.stats.first_token_at:
             flight.req.stats.first_token_at = time.monotonic()
@@ -1093,7 +1095,7 @@ class FleetRouter:
             t_import = time.perf_counter_ns()
             try:
                 new_att = target.import_stream(blob, flight,
-                                               on_item=self.notify)
+                                               waker=self.notify)
             except Exception as e:  # noqa: BLE001
                 log.warning("migration import of req %d on %s failed: %s",
                             flight.rid0, target.name, e)

@@ -24,7 +24,7 @@ import logging
 import os
 import time
 import uuid
-
+from json.encoder import encode_basestring_ascii as _json_str
 
 from aiohttp import web
 
@@ -52,8 +52,41 @@ _IMAGES_IGNORED = ("images ignored: this deployment has no vision model; "
 MAX_BODY = 1024 * 1024 * 1024  # 1 GB, main.rs:127
 
 
+_iso = (0, "")  # the whole second last formatted, and its string
+
+
 def _now_iso() -> str:
-    return time.strftime("%Y-%m-%dT%H:%M:%S", time.gmtime()) + ".000000000Z"
+    """`created_at`, to the second: formatted once a second, not once a
+    frame (a settled step's frames share one)."""
+    global _iso
+    sec = int(time.time())
+    if sec != _iso[0]:
+        _iso = (sec, time.strftime("%Y-%m-%dT%H:%M:%S", time.gmtime(sec))
+                + ".000000000Z")
+    return _iso[1]
+
+
+class _LoopWaker:
+    """The one callback pushing threads have into an event loop: what
+    `TokenStream.set_waker` gets for every stream this loop consumes,
+    keyed by the consumer's `asyncio.Event`. A settled step calls it
+    once with the events of all the streams it touched — one
+    `call_soon_threadsafe` (a write on the loop's self-pipe), whatever
+    the number of streams."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop):
+        self.loop = loop
+
+    def __call__(self, events: list) -> None:
+        try:
+            self.loop.call_soon_threadsafe(self._set_all, events)
+        except RuntimeError:  # the loop is closed: nobody left to wake
+            pass
+
+    @staticmethod
+    def _set_all(events: list) -> None:
+        for ev in events:
+            ev.set()
 
 
 # Key substrings whose values never belong in a diagnostics bundle. The
@@ -101,6 +134,7 @@ class Server:
         self.allow_all_routes = allow_all_routes
         self.started_at = time.time()
         self._profiling = False
+        self._waker = None  # the serving event loop's _LoopWaker
         # Router HA epoch fencing (member side): the highest
         # X-Router-Epoch this member has seen. Any call carrying a
         # HIGHER epoch adopts it (the new primary owns us even if its
@@ -387,18 +421,25 @@ class Server:
         return items
 
     async def _aiter(self, req: Request):
-        """Async iterator over a request's TokenStream with timeout and
-        engine wakeup wiring."""
+        """Async iterator over a request's TokenStream. The request's
+        timeout is ONE deadline around the iteration — a timer that wakes
+        this consumer like a push does — and the engine's wake-ups come
+        through the loop's one `_LoopWaker`: an item costs no timer and
+        no callback of its own."""
         loop = asyncio.get_running_loop()
+        waker = self._waker
+        if waker is None or waker.loop is not loop:
+            waker = self._waker = _LoopWaker(loop)
         event = asyncio.Event()
-        req.stream.on_item = lambda: loop.call_soon_threadsafe(event.set)
+        stream = req.stream
+        stream.set_waker(waker, event)
         deadline = loop.time() + self.timeout_s
+        timer = loop.call_at(deadline, event.set)
         try:
             while True:
-                item = req.stream.get_nowait()
+                item = stream.get_nowait()
                 if item is None:
-                    remaining = deadline - loop.time()
-                    if remaining <= 0:
+                    if loop.time() >= deadline:
                         # Cancel ENGINE-side too, directly on the request:
                         # engine.cancel alone resolves through req_id,
                         # which a preemption/retry requeue may have just
@@ -409,25 +450,27 @@ class Server:
                         self.engine.cancel(req.req_id)
                         yield StreamItem("error", error="request timeout")
                         return
-                    try:
-                        await asyncio.wait_for(event.wait(), timeout=min(remaining, 1.0))
-                    except asyncio.TimeoutError:
-                        pass
+                    await event.wait()
                     event.clear()
                     continue
                 yield item
                 if item.kind in ("done", "error"):
                     return
         finally:
-            req.stream.on_item = None
+            timer.cancel()
+            stream.set_waker(None)
 
     @staticmethod
-    async def _write_frame(resp, item: StreamItem, data: bytes) -> None:
-        """Write the stream frame made from `item`, then observe how long
-        after the engine thread's push it left this process
-        (ollamamq_stream_lag_ms: push -> call_soon_threadsafe -> wake ->
-        resp.write)."""
+    async def _write_frame(resp, item: StreamItem, data: bytes,
+                           n_tokens: int = 0) -> None:
+        """Write the stream frame made from `item` (`n_tokens`: the
+        sampled ids it carries or covers), then observe how long after
+        the engine thread's push it left this process
+        (ollamamq_stream_lag_ms: push -> the step's wake-up -> resp.write)."""
         await resp.write(data)
+        tm.STREAM_FRAMES_TOTAL.inc()
+        if n_tokens:
+            tm.STREAM_FRAME_TOKENS_TOTAL.inc(n_tokens)
         if item.pushed_at:
             tm.STREAM_LAG_MS.observe(
                 (time.monotonic() - item.pushed_at) * 1e3)
@@ -1321,32 +1364,39 @@ class Server:
         resp.content_type = "application/x-ndjson"
         await resp.prepare(request)
 
-        # Every frame carries the engine-side request id and the sampled
-        # token ids its text covers (held-back tokens' ids ride the next
-        # written frame, so the id stream is complete): the fleet router
-        # reads these to resume a failed-over stream in TOKEN space —
-        # verified token-identical — and to key /admin/migrate exports.
+        # One frame a stream item: it carries the engine-side request id
+        # and ALL the sampled token ids one step gave the stream (1 on a
+        # ragged step, k of a fused scan, the accepted run of a
+        # speculated row), its text their emitted chunks joined. Ids
+        # whose text is held back ride the next written frame, so the id
+        # stream is complete: the fleet router reads these to resume a
+        # failed-over stream in TOKEN space — verified token-identical —
+        # and to key /admin/migrate exports.
         pending_ids: list = []
+        # What json.dumps of the frame's dict gives, byte for byte, from
+        # pieces encoded once a request: only the text passes through a
+        # JSON string escape (req_id is read a frame: a requeue rotates it).
+        head = '{"model": ' + json.dumps(model) + ', "created_at": "'
+        text_key, close = ((', "message": {"role": "assistant", "content": ',
+                            "}}\n") if chat else (', "response": ', "}\n"))
 
         def chunk(text):
-            p = {"model": model, "created_at": _now_iso(), "done": False,
-                 "req_id": req.req_id}
+            ids = ""
             if pending_ids:
-                p["token_ids"] = pending_ids[:]
+                ids = ', "token_ids": ' + json.dumps(pending_ids)
                 pending_ids.clear()
-            if chat:
-                p["message"] = {"role": "assistant", "content": text}
-            else:
-                p["response"] = text
-            return (json.dumps(p) + "\n").encode()
+            return (head + _now_iso() + '", "done": false, "req_id": '
+                    + str(req.req_id) + ids + text_key + _json_str(text)
+                    + close).encode()
 
         try:
             async for item in self._aiter(req):
                 if item.kind == "token":
-                    if item.token_id >= 0:
-                        pending_ids.append(item.token_id)
+                    pending_ids.extend(item.token_ids)
                     if item.text:
-                        await self._write_frame(resp, item, chunk(item.text))
+                        n = len(pending_ids)
+                        await self._write_frame(resp, item, chunk(item.text),
+                                                n)
                 elif item.kind == "error":
                     await self._write_frame(resp, item, (json.dumps(
                         {"model": model, "created_at": _now_iso(),
@@ -1359,6 +1409,7 @@ class Server:
                          "done_reason": self._done_reason(item),
                          "req_id": req.req_id,
                          **self._gen_stats(req)}
+                    n = len(pending_ids)
                     if pending_ids:
                         p["token_ids"] = pending_ids[:]
                         pending_ids.clear()
@@ -1369,7 +1420,7 @@ class Server:
                     else:
                         p["response"] = ""
                     await self._write_frame(
-                        resp, item, (json.dumps(p) + "\n").encode())
+                        resp, item, (json.dumps(p) + "\n").encode(), n)
                     break
         except (ConnectionResetError, asyncio.CancelledError):
             # Client went away mid-stream: cancel + reclaim (dropped count).
@@ -1730,33 +1781,41 @@ class Server:
         resp.headers["Cache-Control"] = "no-cache"
         await resp.prepare(request)
         obj = "chat.completion.chunk" if chat else "text_completion"
+        # One `created` a completion, as OpenAI's chunks have it.
+        created = int(time.time())
 
         def sse(choice):
             return (
                 "data: "
                 + json.dumps({
-                    "id": rid, "object": obj, "created": int(time.time()),
+                    "id": rid, "object": obj, "created": created,
                     "model": model, "choices": [choice],
                 })
                 + "\n\n"
             ).encode()
 
+        # A text frame, byte for byte what sse() gives, from pieces
+        # encoded once a request: one frame a stream item, its text the
+        # joined chunks of the tokens one step gave the stream.
+        open_, close = sse({"index": 0, **(
+            {"delta": {"content": "\0"}} if chat else {"text": "\0"}),
+            "finish_reason": None}).split(b'"\\u0000"')
+
         first = True
         try:
             async for item in self._aiter(req):
                 if item.kind == "token" and item.text:
-                    if chat:
-                        delta = {"content": item.text}
-                        if first:
-                            delta["role"] = "assistant"
-                            first = False
+                    n = len(item.token_ids)
+                    if chat and first:  # the one delta that names the role
+                        first = False
                         await self._write_frame(resp, item, sse(
-                            {"index": 0, "delta": delta,
-                             "finish_reason": None}))
+                            {"index": 0, "delta": {"content": item.text,
+                                                   "role": "assistant"},
+                             "finish_reason": None}), n)
                     else:
-                        await self._write_frame(resp, item, sse(
-                            {"index": 0, "text": item.text,
-                             "finish_reason": None}))
+                        await self._write_frame(
+                            resp, item,
+                            open_ + _json_str(item.text).encode() + close, n)
                 elif item.kind == "error":
                     await self._write_frame(
                         resp, item,
@@ -1776,7 +1835,7 @@ class Server:
                         await resp.write(
                             ("data: " + json.dumps(
                                 {"id": rid, "object": obj,
-                                 "created": int(time.time()),
+                                 "created": created,
                                  "model": model, "choices": [],
                                  "warnings": [_IMAGES_IGNORED]}) +
                              "\n\n").encode())

@@ -202,6 +202,21 @@ STREAM_LAG_MS = REGISTRY.histogram(
     "request path, which no request phase times",
     buckets=(0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
              250.0, 1000.0))
+STREAM_FRAMES_TOTAL = REGISTRY.counter(
+    "ollamamq_stream_frames_total",
+    "NDJSON/SSE frames written to client sockets for stream items: one a "
+    "(step, stream) hand-over that has text, plus each stream's terminal")
+STREAM_FRAME_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_stream_frame_tokens_total",
+    "Sampled token ids those frames carried; over "
+    "ollamamq_stream_frames_total it is tokens a frame — 1 where ragged "
+    "steps feed the streams, up to --decode-steps where fused scans do")
+STREAM_WAKEUPS_TOTAL = REGISTRY.counter(
+    "ollamamq_stream_wakeups_total",
+    "Wake-ups of a stream consumer's thread by a pushing thread (for the "
+    "server: one call_soon_threadsafe): one a settled step for every "
+    "stream it touched (`stream_wakeups` on a step sample), one a push "
+    "outside a step")
 SLO_VIOLATIONS_TOTAL = REGISTRY.counter(
     "ollamamq_slo_violations_total",
     "Observations over the configured SLO threshold (--slo-ttft-ms / "

@@ -87,8 +87,7 @@ def drive(eng, arrivals, settle_every_step, monkeypatch, during=None):
                 break
             items.append(it)
         assert items[-1].kind in ("done", "error"), name
-        ids = [i.token_id for i in items
-               if i.kind == "token" and i.token_id >= 0]
+        ids = [t for i in items for t in i.token_ids]
         # (the id that completed a stop string is counted, never pushed)
         assert ids == list(r.generated_ids)[:len(ids)], name
         assert len(r.generated_ids) - len(ids) <= bool(r.sampling.stop), name
