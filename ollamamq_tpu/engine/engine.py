@@ -411,7 +411,9 @@ def serve_embed_batch(rt, core: "MQCore", pending, max_len: int,
 class StepInFlight:
     """One launched step: the device futures it left behind and the
     host's plan of it — all `step_collect` and `step_settle` need to
-    finish it while the NEXT step already runs.
+    finish it while the NEXT step already runs. When it left the device
+    is bracketed by its timer (`sp.done`, stepprof.DoneBracket), probed
+    on `toks_dev` wherever the engine thread stamps its time.
 
     `rows`: (kind, slot, req, chunk_pos|drafts, span) as composed; a
     fused scan's are its active slots with span = `k_steps` (0 = a
@@ -433,7 +435,7 @@ class StepInFlight:
         self.toks_dev = self.n_emit_dev = self.toks = self.n_emit = None
         self.ending: set = set()
         self.state = "launched"
-        self.t_launch = time.monotonic()
+        self.t_launch = time.perf_counter()  # the step profiler's clock
         self.dt = 0.0
         self.prev: Optional["StepInFlight"] = None  # unsettled step before
 
@@ -2606,12 +2608,12 @@ class ModelRuntime:
             self._jrec("batch", **batch_fields)
             self._ragged_failed(rows, e, core)
             return None
+        self._note_queued(h)
         opened = int(is_first.sum())
         spans = [span for *_, span in rows]
         self._note_slot_state(_sp, opened, len(rows) - opened,
                               sum(n == 1 for n in spans),
                               sum(n for n in spans if n > 1))
-        _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()
 
@@ -2651,6 +2653,16 @@ class ModelRuntime:
         self._ahead[slot] += n
         if self._ends_by_count(slot, req):
             h.ending.add(slot)
+
+    def _note_queued(self, h: "StepInFlight") -> None:
+        """The jitted call of step `h` has just returned: its program is
+        queued behind the step launched before it. Opens h's done-bracket
+        (probed with `is_ready()` on its ids: non-blocking, no transfer)
+        and notes on its sample how long the chip had had nothing queued
+        — `dry_lo_ms`, `dry_hi_ms`, `dry_phase` — with what the launch
+        uploaded."""
+        h.sp.launched(h.toks_dev.is_ready, model=self.name,
+                      h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
 
     def _note_launch(self, h: "StepInFlight",
                      prev: Optional["StepInFlight"]) -> None:
@@ -2811,9 +2823,9 @@ class ModelRuntime:
         self._h2d = [0, 0]
         h.toks_dev, self.kc, self.vc, self.recent, self.last_ids, \
             self.slot_state = self._dispatch_decode(k_steps, buf)
+        self._note_queued(h)
         self._note_slot_state(_sp, 0, len(active),
                               len(active) * int(k_steps), 0)
-        _sp.note(h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
         _sp.mark("dispatch")
         _sp.park()
         for i in active:
@@ -2856,13 +2868,16 @@ class ModelRuntime:
             self.void_inflight()
             self._ragged_failed(rows, e, core)
             return
-        t_done = time.monotonic()
-        _sp.mark("collect")
+        # When the step left the device: the first probe that saw its
+        # ids ready — this read's return at the latest, and earlier where
+        # the host came back late (the time in between is the host's
+        # lateness, not the step's time on the device).
+        t_done = _sp.collected()
         h.toks, h.toks_dev, h.n_emit_dev = toks, None, None
         h.state = "collected"
         # The step's time on the device: from its launch, or from when
         # the step before it was done if it queued behind that one.
-        h.dt = t_done - max(h.t_launch, self._last_done)
+        h.dt = max(0.0, t_done - max(h.t_launch, self._last_done))
         self._last_done = t_done
         S = len(self.slot_req)
         # Per row (a scan: per slot): its last id, and whether EOS is
@@ -2955,6 +2970,8 @@ class ModelRuntime:
         ids = (toks[:, :len(self.slot_req)].T if K else toks).tolist()
         with wake_batch() as woken:
             for idx, (kind, slot, req, cpos, span) in enumerate(rows):
+                if not idx & 15:
+                    _sp.probe()  # the emit loop is long between marks
                 if not h.emits[idx]:
                     continue  # a span inside a prompt samples nothing
                 if self.slot_req[slot] is not req:
@@ -4449,17 +4466,22 @@ class TPUEngine:
 
     def _loop(self) -> None:
         self.loop_clock.reset()
-        while self._running:
-            try:
-                self._loop_once()
-            except Exception:
-                # The engine thread must never die: a control-plane bug
-                # (admission, recovery bookkeeping) would otherwise stop
-                # ALL serving with requests parked forever. Runtime step
-                # errors are already handled per-runtime inside _loop_once.
-                log.exception("engine loop iteration failed; continuing")
-                time.sleep(0.1)
-        self._settle_all()  # no launched step's tokens are lost at stop
+        stepprof.PROFILER.cpu_register("engine")  # read at a scrape only
+        try:
+            while self._running:
+                try:
+                    self._loop_once()
+                except Exception:
+                    # The engine thread must never die: a control-plane
+                    # bug (admission, recovery bookkeeping) would
+                    # otherwise stop ALL serving with requests parked
+                    # forever. Runtime step errors are already handled
+                    # per-runtime inside _loop_once.
+                    log.exception("engine loop iteration failed; continuing")
+                    time.sleep(0.1)
+            self._settle_all()  # no launched step's tokens are lost at stop
+        finally:
+            stepprof.PROFILER.cpu_unregister("engine")
 
     # HBM/allocator timeline (telemetry/stepprof.py): one bounded-ring
     # sample per period — the engine ticks far faster — of every

@@ -194,10 +194,17 @@ class FakeRuntime:
         _n_decode = len(self.active)
         _sp.note(T_pad=0, k_cap=0, tokens=real)
         _sp.mark("host_prep")
+        # The fake's device is its sleep: the step is launched here and
+        # has left the "chip" when the sleep is over — the done-bracket a
+        # real step gets from its ids' is_ready(), from the fake's own
+        # notion of a step's end. Like a speculating runtime it launches
+        # nothing before it has read the step ahead: dry every step.
+        t_end = time.perf_counter() + self.token_latency_s
+        _sp.launched(lambda: time.perf_counter() >= t_end, model=self.name)
         if self.token_latency_s:
             time.sleep(self.token_latency_s)
         _sp.mark("dispatch")
-        _sp.mark("collect")  # nothing to wait for; keeps the fixed order
+        _sp.collected()  # nothing to wait for; keeps the fixed order
         n_items = 0
         with wake_batch() as woken:  # one wake-up a step, as the real one
             for req in list(self.active):
@@ -370,36 +377,34 @@ class FakeEngine(TPUEngine):
         self.runtimes[name] = rt
         self.notify()
 
-    def _loop(self) -> None:
+    def _loop_once(self) -> None:
         # Same loop-phase marks as TPUEngine._loop_once (stepprof
         # LOOP_PHASES), so the gapless chain is testable without jax.
         clock = self.loop_clock
-        clock.reset()
-        while self._running:
-            clock.tick()
-            self.last_tick_at = time.monotonic()
-            self.journal.tick += 1
-            # Deferred engine-thread calls (the fleet's migration
-            # export/import run through call_on_loop here too).
-            self._drain_engine_calls()
-            clock.enter("admit")
-            self._admit()
+        clock.tick()
+        self.last_tick_at = time.monotonic()
+        self.journal.tick += 1
+        # Deferred engine-thread calls (the fleet's migration
+        # export/import run through call_on_loop here too).
+        self._drain_engine_calls()
+        clock.enter("admit")
+        self._admit()
+        clock.enter("other")
+        did_work = False
+        for rt in list(self.runtimes.values()):
+            rt.check_cancellations(self.core)
+            if rt.has_work():
+                try:
+                    rt.step(self.core)
+                except Exception:
+                    # Same containment contract as the real engine:
+                    # retry-or-poison the implicated requests, keep
+                    # the loop (and the fake runtime) alive.
+                    log.exception("fake runtime %s step failed", rt.name)
+                    self._fail_runtime(rt, "engine step failed")
+                did_work = True
+        if not did_work:
+            clock.enter("wait")
+            with self._cond:
+                self._cond.wait(timeout=0.02)
             clock.enter("other")
-            did_work = False
-            for rt in list(self.runtimes.values()):
-                rt.check_cancellations(self.core)
-                if rt.has_work():
-                    try:
-                        rt.step(self.core)
-                    except Exception:
-                        # Same containment contract as the real engine:
-                        # retry-or-poison the implicated requests, keep
-                        # the loop (and the fake runtime) alive.
-                        log.exception("fake runtime %s step failed", rt.name)
-                        self._fail_runtime(rt, "engine step failed")
-                    did_work = True
-            if not did_work:
-                clock.enter("wait")
-                with self._cond:
-                    self._cond.wait(timeout=0.02)
-                clock.enter("other")

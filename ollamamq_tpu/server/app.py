@@ -126,6 +126,14 @@ class ApiError(web.HTTPException):
         )
 
 
+async def _cpu_register_server(app) -> None:
+    stepprof.PROFILER.cpu_register("server")
+
+
+async def _cpu_unregister_server(app) -> None:
+    stepprof.PROFILER.cpu_unregister("server")
+
+
 class Server:
     def __init__(self, engine, timeout_s: float = 300.0, allow_all_routes: bool = False):
         self.engine = engine
@@ -160,6 +168,11 @@ class Server:
     # ------------------------------------------------------------------ app
     def build_app(self) -> web.Application:
         app = web.Application(client_max_size=MAX_BODY)
+        # Whichever thread runs this app's event loop is the "server"
+        # thread of ollamamq_thread_cpu_seconds_total: it says so itself,
+        # on the loop, as it starts and as it ends.
+        app.on_startup.append(_cpu_register_server)
+        app.on_cleanup.append(_cpu_unregister_server)
         r = app.router
         r.add_route("GET", "/health", self.health)
         r.add_route("*", "/", self.root)
@@ -584,6 +597,9 @@ class Server:
         from ollamamq_tpu.telemetry import REGISTRY
         eng = self.engine
         tm.UPTIME_SECONDS.set(time.time() - eng.started_at)
+        # The engine's and this server's CPU clocks: read here, at a
+        # scrape, and nowhere else.
+        tm.refresh_cpu_seconds(stepprof.PROFILER.cpu_seconds())
         # Queue depth per user: rebuilt each scrape so departed users'
         # series don't linger.
         try:

@@ -27,7 +27,9 @@ TPOT_MS = REGISTRY.histogram(
     buckets=DEFAULT_LATENCY_BUCKETS_MS, labels=("model",))
 STEP_LATENCY_MS = REGISTRY.histogram(
     "ollamamq_step_latency_ms",
-    "Decode step device latency, blocked-collect time per fused step (ms)",
+    "A step's time on the device per forward pass (ms): from its launch "
+    "(or the end of the step it queued behind) to the first probe that "
+    "saw its ids ready, at the latest the return of the blocking read",
     buckets=DEFAULT_LATENCY_BUCKETS_MS, labels=("model",))
 PREFILL_LATENCY_MS = REGISTRY.histogram(
     "ollamamq_prefill_latency_ms",
@@ -483,6 +485,39 @@ STEP_H2D_BYTES_TOTAL = REGISTRY.counter(
     "ollamamq_step_h2d_bytes_total",
     "Bytes of those transfers (`h2d_bytes` on a step sample), by step "
     "mode", labels=("mode",))
+DEVICE_DRY_SECONDS_TOTAL = REGISTRY.counter(
+    "ollamamq_device_dry_seconds_total",
+    "Seconds the chip had NOTHING queued before a step's launch, at "
+    "least (`dry_lo_ms` on a step sample): from the first probe that saw "
+    "the step ahead of it ready to the return of the launch. Time the "
+    "engine thread spent in its idle wait is left out — a chip idle for "
+    "want of requests is not dry. Over wall seconds: the idle share "
+    "late launches cost, without a profiler", labels=("model",))
+DEVICE_DRY_UPPER_SECONDS_TOTAL = REGISTRY.counter(
+    "ollamamq_device_dry_upper_seconds_total",
+    "The same gap at most (`dry_hi_ms`): counted from the last probe "
+    "that saw the step ahead still busy. The true dry time lies between "
+    "the two counters; they lie as far apart as the engine thread's "
+    "marks (and as far as a blocking read lasted, where the step ahead "
+    "was read before the launch)", labels=("model",))
+STEPS_LAUNCHED_DRY_TOTAL = REGISTRY.counter(
+    "ollamamq_steps_launched_dry_total",
+    "Steps launched onto a chip that was known to have nothing queued "
+    "(`dry_lo_ms` > 0): every step after a fused scan and every step of "
+    "a speculating runtime by design, any other step only when the host "
+    "was late", labels=("model",))
+THREAD_CPU_SECONDS_TOTAL = REGISTRY.counter(
+    "ollamamq_thread_cpu_seconds_total",
+    "CPU seconds of the threads that serve: thread=\"engine\" the engine "
+    "loop thread (an in-process fleet's several, summed), "
+    "thread=\"server\" the thread that runs the HTTP event loop. Read "
+    "from the threads' own CPU clocks when /metrics is rendered, never "
+    "on the hot path; left out where the platform has no such clock",
+    labels=("thread",))
+PROCESS_CPU_SECONDS_TOTAL = REGISTRY.counter(
+    "ollamamq_process_cpu_seconds_total",
+    "CPU seconds of the whole server process (every thread), read when "
+    "/metrics is rendered")
 COMPILE_TOTAL = REGISTRY.counter(
     "ollamamq_compile_total",
     "XLA compiles the engine paid, by jit-cache site (ragged / prefill "
@@ -509,6 +544,17 @@ UPTIME_SECONDS = REGISTRY.gauge(
     "ollamamq_uptime_seconds", "Engine uptime")
 
 _LATENCY_HISTOGRAMS = (TTFT_MS, TPOT_MS, STEP_LATENCY_MS, PREFILL_LATENCY_MS)
+
+
+def refresh_cpu_seconds(seconds) -> None:
+    """At a scrape: `seconds` is stepprof.cpu_seconds() — totals the
+    kernel keeps, so the counters are SET to them (never lowered)."""
+    if seconds is None:
+        return
+    for role, v in seconds.items():
+        child = (PROCESS_CPU_SECONDS_TOTAL.labels() if role == "process"
+                 else THREAD_CPU_SECONDS_TOTAL.labels(thread=role))
+        child.inc(max(0.0, v - child.value))
 
 
 def configure_latency_buckets(bounds) -> None:

@@ -47,6 +47,17 @@ Contracts the tests pin:
     POST /debug/profile) every phase is also entered as a span on the
     profiler's clock (SPAN_NAMES), carrying the `seq` its sample will
     have — one mechanism feeds samples, histograms and spans.
+  * The program knows when its chip ran dry without a profiler: every
+    launched step carries a DoneBracket — the last instant its result
+    was seen NOT ready and the first it was seen ready, probed
+    (non-blocking, through a hook the runtime hands over: this file
+    never imports jax) at the instants the thread stamps anyway. A
+    launch whose step ahead had been seen ready by then records
+    `dry_lo_ms <= true gap <= dry_hi_ms` and `dry_phase`; the loop's
+    idle wait is never dry time.
+  * The engine's and the server's threads register for their CPU
+    clocks (`cpu_register`); the clocks are read only when /metrics is
+    rendered (`cpu_seconds`), never on the hot path.
   * Compile events are recorded by the jit-getter seams exactly once
     per cache key (jax.jit traces+compiles synchronously on the first
     call of a fresh cache entry — timing that first call IS the compile
@@ -106,11 +117,16 @@ SPAN_NAMES = (tuple("mq." + p for p in PHASES)
 # The phase a mark OPENS: marks name the phase that ended, a span needs
 # its name when it begins, and the order is fixed.
 _NEXT_PHASE = dict(zip(PHASES, PHASES[1:]))
+_LOOP_KEY = {p: "loop_" + p for p in LOOP_PHASES}
 # Every `<phase>_ms` field of a sample = every `phase` label value of
 # ollamamq_step_phase_ms.
 _SAMPLE_PHASES = PHASES + tuple("loop_" + p for p in LOOP_PHASES)
 # Sample fields a span carries once the step has noted them.
 _SPAN_FIELDS = ("T_pad", "k_cap", "tokens")
+# What `dry_phase` may say: the phase that held most of the time the
+# chip had nothing queued before a launch. The idle wait is not among
+# them — a chip idle for want of requests is not dry.
+DRY_PHASES = tuple(p for p in _SAMPLE_PHASES if p != "loop_wait")
 
 # Step modes (the `mode` label + the first element of the shape key).
 # Not a validation gate — a sample carries whatever the engine said —
@@ -123,6 +139,9 @@ _SHAPE_WINDOW = 256   # rolling per-shape totals window
 _COMPILE_RING = 256   # compile-event ring
 _HBM_RING = 512       # HBM/allocator timeline ring
 _RATE_WINDOW_S = 60.0  # compile-rate lookback
+# thread= of ollamamq_thread_cpu_seconds_total (StepProfiler.cpu_register)
+CPU_THREADS = ("engine", "server")
+_clock_gettime = getattr(time, "clock_gettime", None)
 
 
 def _pctl(window, q: float) -> Optional[float]:
@@ -130,6 +149,25 @@ def _pctl(window, q: float) -> Optional[float]:
         return None
     s = sorted(window)
     return s[min(len(s) - 1, int(q * len(s)))]
+
+
+class DoneBracket:
+    """When a launched step left the device, as two instants of its
+    thread's clock: `seen_busy_at`, the last probe that found its result
+    not ready (to begin with, the instant its launch returned), and
+    `seen_ready_at`, the first that found it ready (None until then; at
+    the latest the return of the blocking read). The step ended between
+    the two. `ready` is the runtime's probe — non-blocking, no transfer:
+    `jax.Array.is_ready` of the step's ids, the fake's own notion of a
+    step's end — dropped once it said yes."""
+
+    __slots__ = ("ready", "seen_busy_at", "seen_ready_at", "_cum")
+
+    def __init__(self, ready, t: float):
+        self.ready = ready
+        self.seen_busy_at = t
+        self.seen_ready_at: Optional[float] = None
+        self._cum: Optional[dict] = None  # its clock's totals when seen ready
 
 
 class LoopClock:
@@ -148,15 +186,25 @@ class LoopClock:
     meanwhile). `tick()` — top of an engine tick — folds what timers
     that are neither finished nor parked were charged into `other`: they
     were abandoned, so early returns and faulted dispatches leave no
-    hole."""
+    hole.
+
+    The same stamps are where the thread LOOKS at its steps in flight
+    (`_probe`): each switch of phase asks the launched steps' brackets,
+    oldest first and without blocking, whether their result is ready —
+    so that a launch (`launched`) can say for how long the chip had had
+    nothing queued."""
 
     __slots__ = ("name", "_prof", "_last", "_owner", "_open", "_span",
-                 "_loop", "_timers", "_seq", "_adopted")
+                 "_loop", "_timers", "_seq", "_adopted", "_watch", "_ahead",
+                 "_cum", "_wait_end", "_cum_wait_end")
 
     def __init__(self, prof: "StepProfiler", name: str):
         self._prof = prof
         self.name = name
         self._span = None
+        # Milliseconds charged to each phase, ever (never reset: a
+        # bracket's snapshot of it must stay comparable).
+        self._cum = dict.fromkeys(_SAMPLE_PHASES, 0.0)
         self.reset()
 
     def reset(self) -> None:
@@ -170,6 +218,14 @@ class LoopClock:
         self._timers: List["StepTimer"] = []  # started, not yet finished
         self._seq: Optional[int] = None  # reserved for the next sample
         self._adopted: Optional["StepTimer"] = None
+        self._watch: List[DoneBracket] = []  # launched, not seen ready
+        # The step launched last: an engine's runtimes share its devices,
+        # so whatever this thread launches next queues behind it.
+        self._ahead: Optional[DoneBracket] = None
+        # A (re)start counts as the end of an idle wait: the time the
+        # engine was down is not time its chip ran dry.
+        self._wait_end = self._last
+        self._cum_wait_end = dict(self._cum)
 
     def _close_span(self) -> None:
         span, self._span = self._span, None
@@ -177,21 +233,29 @@ class LoopClock:
             span.__exit__(None, None, None)
 
     def _switch(self, t: float, owner: Optional["StepTimer"], phase: str,
-                charge: Optional[tuple] = None) -> None:
+                charge: Optional[tuple] = None, probe: bool = True) -> None:
         """Close the open phase at `t` and open `phase` for `owner`. The
         closed slice goes to `charge` = (timer, phase) when a step mark
-        names it, else to the phase it was opened under."""
+        names it, else to the phase it was opened under. `probe`: `t` is
+        now, so the steps in flight can be looked at under this stamp."""
         ms = (t - self._last) * 1e3
         self._last = t
         tgt, name = charge if charge is not None \
             else (self._owner, self._open)
         if tgt is None:
             self._loop[name] += ms
+            name = _LOOP_KEY[name]
+            self._cum[name] += ms
+            if name == "loop_wait":
+                self._wait_end, self._cum_wait_end = t, dict(self._cum)
         else:
             tgt.phases[name] = tgt.phases.get(name, 0.0) + ms
+            self._cum[name] = self._cum.get(name, 0.0) + ms
         if self._span is not None:
             self._close_span()
         self._owner, self._open = owner, phase
+        if probe and self._watch:
+            self._probe(t)
         prof = self._prof
         if prof.capturing and prof.span_factory is not None:
             self._open_span(prof, owner, phase)
@@ -221,6 +285,92 @@ class LoopClock:
         span.__enter__()
         self._span = span
 
+    # -- the done-bracket of every step in flight ---------------------------
+    def _open_key(self) -> str:
+        return self._open if self._owner is not None \
+            else _LOOP_KEY[self._open]
+
+    def _probe(self, t: float) -> None:
+        """Look at the launched steps under the stamp `t`. They run in
+        the order they were launched, so only the oldest is asked: seen
+        ready (once — it leaves the watch, and the next is asked), or it
+        and all behind it are busy still."""
+        watch = self._watch
+        while watch:
+            b = watch[0]
+            try:
+                ready = b.ready()
+            except Exception:  # a failed step has left the device too
+                ready = True
+            if not ready:
+                break
+            del watch[0]
+            self._seen_ready(b, t)
+        for b in watch:
+            b.seen_busy_at = t
+
+    def _seen_ready(self, b: DoneBracket, t: float) -> None:
+        b.seen_ready_at = t
+        b.ready = None
+        # The totals as of `t`: the open phase's slice is not in them
+        # yet (a probe inside a phase; zero at a switch).
+        b._cum = dict(self._cum)
+        b._cum[self._open_key()] += (t - self._last) * 1e3
+
+    def probe(self) -> None:
+        """A look from INSIDE a long phase (the settle's row loop), where
+        the marks lie too far apart to bracket a step's end."""
+        if self._watch:
+            t = time.perf_counter()
+            self._probe(t)
+            self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+
+    def _unwatch(self, b: Optional[DoneBracket]) -> None:
+        """A step that will never be read (voided, abandoned): its end is
+        nobody's to know."""
+        if b is not None and b.seen_ready_at is None:
+            b.ready = None
+            if b in self._watch:
+                self._watch.remove(b)
+
+    def launched(self, t: float, ready) -> tuple:
+        """A step's launch returned at `t`: its program is queued behind
+        the step this thread launched before it (none yet, or one that
+        was voided: nothing is known). Returns the new step's bracket and
+        (dry_lo_ms, dry_hi_ms, dry_phase): if the step ahead had been
+        seen ready by now the chip had nothing queued since that step's
+        end, which lies between the two probes — so the true gap lies
+        between the two numbers — and `dry_phase` is the phase that held
+        most of the lower one; else (0, 0, None). An idle wait in between
+        means the chip was idle for want of requests, not dry: the gap
+        then counts from the wait's end."""
+        if self._watch:
+            self._probe(t)
+        ahead = self._ahead
+        lo = hi = 0.0
+        phase = None
+        if ahead is not None and ahead.seen_ready_at is not None:
+            since = self._wait_end
+            hi = (t - max(ahead.seen_busy_at, since)) * 1e3
+            lo = (t - max(ahead.seen_ready_at, since)) * 1e3
+            if lo > 0.0:
+                phase = self._dry_phase(t, ahead)
+            else:
+                lo = 0.0
+        b = self._ahead = DoneBracket(ready, t)
+        self._watch.append(b)
+        return b, lo, max(hi, lo), phase
+
+    def _dry_phase(self, t: float, ahead: DoneBracket) -> str:
+        """The phase that holds most of [max(ahead seen ready, the last
+        wait's end), t], by this clock's own totals."""
+        base = self._cum_wait_end if ahead.seen_ready_at < self._wait_end \
+            else ahead._cum
+        key = self._open_key()
+        by = {k: self._cum[k] - base[k] for k in DRY_PHASES}
+        by[key] = by.get(key, 0.0) + (t - self._last) * 1e3
+        return max(by, key=by.get)
+
     def enter(self, phase: str) -> None:
         """Open a LOOP_PHASES entry (closing whatever was open)."""
         t = time.perf_counter()
@@ -233,6 +383,7 @@ class LoopClock:
         timer._done = True
         self._loop["other"] += sum(timer.phases.values())
         self._timers.remove(timer)
+        self._unwatch(timer.done)
 
     def tick(self) -> None:
         """Top of an engine tick: a timer that is neither finished nor
@@ -270,7 +421,7 @@ class StepTimer:
     and `resume(phase)` takes it again."""
 
     __slots__ = ("_prof", "_clock", "mode", "phases", "fields", "seq",
-                 "_done", "parked")
+                 "_done", "parked", "done")
 
     def __init__(self, prof: "StepProfiler", mode: str,
                  clock: Optional[LoopClock] = None):
@@ -285,6 +436,7 @@ class StepTimer:
         self.fields: Dict[str, object] = {}
         self._done = False
         self.parked = False
+        self.done: Optional[DoneBracket] = None  # set by launched()
         # The seq the loop spans before this step carried, if any: those
         # spans' time is written into this step's sample.
         self.seq = clock._seq
@@ -307,6 +459,37 @@ class StepTimer:
                             charge=(self, phase))
         # Self-overhead: the mark itself (two clock reads + a dict op).
         self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+
+    def launched(self, ready, **fields) -> None:
+        """The step's launch has just returned (the jitted call; a
+        fake's start of its sleep): open its done-bracket on `ready`, a
+        non-blocking probe of its result, and note how long the chip
+        had had nothing queued (`dry_lo_ms`, `dry_hi_ms`, `dry_phase`,
+        LoopClock.launched) with whatever else the launch knows
+        (`fields`)."""
+        t = time.perf_counter()
+        self.done, lo, hi, phase = self._clock.launched(t, ready)
+        self.fields.update(fields, dry_lo_ms=round(lo, 4),
+                           dry_hi_ms=round(hi, 4), dry_phase=phase)
+        self._prof._overhead_ns += time.perf_counter_ns() - int(t * 1e9)
+
+    def probe(self) -> None:
+        """Look at the steps in flight from inside this step's phase."""
+        self._clock.probe()
+
+    def collected(self) -> float:
+        """The blocking read of the step's result has just returned:
+        mark `collect`, and if no probe saw the step ready before, it is
+        seen ready now. Returns the instant it was seen ready."""
+        self.mark("collect")
+        b, clock = self.done, self._clock
+        if b is None:
+            return clock._last
+        if b.seen_ready_at is None:
+            if b in clock._watch:
+                clock._watch.remove(b)
+            clock._seen_ready(b, clock._last)
+        return b.seen_ready_at
 
     def park(self) -> None:
         """The step is in flight and the thread goes on to other work:
@@ -350,7 +533,7 @@ class StepTimer:
         # this call — argument evaluation at the finish() call site —
         # are the loop's, not step time.
         if clock._owner is self:
-            clock._switch(clock._last, None, "other")
+            clock._switch(clock._last, None, "other", probe=False)
         total_ms = sum(self.phases.values())
         clock._timers.remove(self)
         sample = {
@@ -389,6 +572,11 @@ class StepProfiler:
         # a mark pays one attribute test.
         self.span_factory = None
         self.capturing = False
+        # The serving threads' CPU clocks (cpu_register): role ->
+        # {thread id: clock id}, and the seconds of threads that ended.
+        # Not a sample's business: reset() leaves them alone.
+        self._cpu_clocks: Dict[str, Dict[int, int]] = {}
+        self._cpu_ended: Dict[str, float] = {}
         self._reset_locked()
 
     def _reset_locked(self) -> None:
@@ -402,6 +590,10 @@ class StepProfiler:
         self._phase_sum: Dict[Tuple[str, str], float] = {}
         self._tokens = 0
         self._padded = 0
+        # Launches that knew the step ahead of them, those that found
+        # the chip dry, and for how long (sum of the samples' fields).
+        self._dry = {"launches": 0, "steps": 0, "lo_ms": 0.0, "hi_ms": 0.0,
+                     "by_phase_ms": {}}
         self.compiles: deque = deque(maxlen=_COMPILE_RING)
         self.compile_seq = 0
         self._compile_ts: deque = deque(maxlen=_COMPILE_RING)
@@ -448,6 +640,25 @@ class StepProfiler:
                         self._phase_sum.get((mode, ph), 0.0) + v
             self._tokens += int(sample.get("tokens", 0) or 0)
             self._padded += int(sample.get("padded_tokens", 0) or 0)
+            dry_lo = sample.get("dry_lo_ms")
+            if dry_lo is not None:
+                d = self._dry
+                d["launches"] += 1
+                d["hi_ms"] += sample["dry_hi_ms"]
+                if dry_lo > 0.0:
+                    d["steps"] += 1
+                    d["lo_ms"] += dry_lo
+                    by, ph = d["by_phase_ms"], sample["dry_phase"]
+                    by[ph] = by.get(ph, 0.0) + dry_lo
+        if dry_lo is not None:
+            model = sample.get("model", "")
+            if sample["dry_hi_ms"] > 0.0:
+                tm.DEVICE_DRY_UPPER_SECONDS_TOTAL.labels(model=model) \
+                    .inc(sample["dry_hi_ms"] / 1e3)
+            if dry_lo > 0.0:
+                tm.DEVICE_DRY_SECONDS_TOTAL.labels(model=model) \
+                    .inc(dry_lo / 1e3)
+                tm.STEPS_LAUNCHED_DRY_TOTAL.labels(model=model).inc()
         for ph in _SAMPLE_PHASES:
             v = sample.get(ph + "_ms", 0.0)
             if v:
@@ -563,6 +774,19 @@ class StepProfiler:
                 return 0.0
             return max(0.0, 1.0 - self._tokens / self._padded)
 
+    def dry_summary(self) -> dict:
+        """How often a launch found the chip with nothing queued, for
+        how long (`lo_ms` <= the true total <= `hi_ms`), and the lower
+        total by what the thread was doing (`dry_phase`) — the idle-gap
+        table of a device trace, made without a capture."""
+        with self._lock:
+            d = self._dry
+            return {"launches": d["launches"], "steps": d["steps"],
+                    "lo_ms": round(d["lo_ms"], 4),
+                    "hi_ms": round(d["hi_ms"], 4),
+                    "by_phase_ms": {k: round(v, 4) for k, v
+                                    in sorted(d["by_phase_ms"].items())}}
+
     def step_p99_ms(self) -> Optional[float]:
         with self._lock:
             totals = [s["total_ms"] for s in self.samples]
@@ -600,7 +824,58 @@ class StepProfiler:
             "compile_rate_per_min": round(self.compile_rate_per_min(), 3),
             "padding_waste": round(self.padding_waste(), 4),
             "overhead_fraction": round(self.overhead_fraction(), 6),
+            "dry": self.dry_summary(),
         }
+
+    # -- the threads' CPU clocks, read only at a scrape ---------------------
+    # The engine's loop thread(s) and the thread that runs the server's
+    # event loop register themselves as they start and take themselves
+    # off as they end; their CPU seconds are read when /metrics is
+    # rendered, from another thread, through the per-thread clock id
+    # taken at registration. With them: the engine thread's wall outside
+    # `collect` and `loop_wait` minus its CPU seconds is the time it
+    # wanted to run and did not (the GIL, the scheduler); the server
+    # thread's CPU is the other term of both threads' Python a step.
+    # Nothing here runs on the hot path.
+    def cpu_register(self, role: str) -> None:
+        """The calling thread is one of `role`'s (CPU_THREADS). A
+        platform without per-thread CPU clocks registers nothing."""
+        ident = threading.get_ident()
+        try:
+            clk = time.pthread_getcpuclockid(ident)
+        except (AttributeError, OSError):
+            return
+        with self._lock:
+            self._cpu_clocks.setdefault(role, {})[ident] = clk
+
+    def cpu_unregister(self, role: str) -> None:
+        """The calling thread ends: what it used stays in `role`'s
+        total."""
+        with self._lock:
+            clk = self._cpu_clocks.get(role, {}).pop(
+                threading.get_ident(), None)
+            if clk is not None:
+                self._cpu_ended[role] = (self._cpu_ended.get(role, 0.0)
+                                         + _clock_gettime(clk))
+
+    def cpu_seconds(self) -> Optional[Dict[str, float]]:
+        """CPU seconds by role — every registered thread of it, live or
+        ended, summed — and the whole process's under "process". None
+        where the platform has no per-thread CPU clocks. Called when
+        /metrics is rendered, and by nothing else."""
+        if _clock_gettime is None \
+                or not hasattr(time, "pthread_getcpuclockid"):
+            return None
+        with self._lock:
+            out = dict(self._cpu_ended)
+            for role, clocks in self._cpu_clocks.items():
+                for clk in clocks.values():
+                    try:
+                        out[role] = out.get(role, 0.0) + _clock_gettime(clk)
+                    except OSError:  # the thread ended without saying so
+                        pass
+        out["process"] = time.process_time()
+        return out
 
     def snapshot(self, n: int = 128) -> dict:
         """/debug/stepprof payload."""
