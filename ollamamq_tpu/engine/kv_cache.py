@@ -17,7 +17,11 @@ Host side: a free-list allocator of page indices. Page 0 is RESERVED as the
 trash page: page-table rows are padded with it, and a step's padding
 tokens (the ragged stream rounds up to a granule; an idle slot's row of
 the decode scan) write their K/V into it, so every scatter is
-static-shaped and lands harmlessly.
+static-shaped and lands harmlessly. The attention kernels READ it too —
+a block's pages past a sequence's last are whatever the table holds
+there, masked to a weight of 0 (ops/pallas/kv_contract.py) — and lean on
+one invariant: page 0 holds finite values only (zeros at start, then
+what padding rows wrote), since 0 times a NaN or an infinity is not 0.
 
 Cancellation reclaims pages immediately — the TPU analogue of the
 reference dropping a disconnected client's stream

@@ -3,11 +3,11 @@ inner product.
 
 `ragged_attention.py` (one program a G_TILE-token tile of a mixed stream)
 and `paged_attention.py` (one program a decode row) differ in their grid
-and in who starts a sequence's first DMAs. Everything inside a sequence's
-walk is here, once: `PageStream` moves K/V pages HBM→VMEM in blocks
-through a ring of buffers, and an inner product — `Mxu` or `Vpu` —
-folds one buffered block into the online-softmax state `(acc, m_i, l_i)`
-of the rows the program holds.
+and in what a walk's successor is. Everything inside a sequence's walk is
+here, once: `PageStream` moves K/V pages HBM→VMEM in blocks through a
+ring of buffers, and an inner product — `Mxu` or `Vpu` — folds one
+buffered block into the online-softmax state `(acc, m_i, l_i)` of the
+rows the program holds.
 
 Which inner product (`choose_inner`, a function of the row-heads M =
 rows × group that share each kv head's K/V in one program — known at
@@ -54,6 +54,71 @@ figures: PR 33's run, before that body was deleted). Only the decode
 kernel at group 1 is faster on the VPU; that is the one shape that keeps
 it.
 
+The page stream: what moves, and what a launch's time is made of (PR 38).
+The UNIT IS THE BLOCK: `block_pages` pages (4 of 32 tokens under the Mxu
+body — the scores' 128 lanes — one under the Vpu body) into one ring slot.
+`PageStream.start` has ONE predicate, the block exists (it holds one of
+the walk's pages), and under it copies every page of the block, pool by
+pool, in straight-line code; `PageStream.wait` has none and waits once a
+pool with a block-sized descriptor (a DMA semaphore counts bytes: four
+page-sized starts balance one `[block, lanes]` wait, and the wait needs
+no page-table read). Pages of a block past the walk's last read what the
+table holds there: THE TRASH PAGE (page 0; `engine/kv_cache.py` pads
+every row with it, and `whole_blocks` pads a table whose width is no
+multiple of a block). Their tokens are masked (`pos < kv`, the causal
+test) and p is 0 there, so what the invariant needs is that page 0 holds
+FINITE values — it holds what padding rows wrote — and then 0 · v is 0
+and every output bit is what it was (`tests/`: the trash page poisoned
+with ±1e30 and the dtype's largest). A slot is therefore always written
+whole before it is read, and the ring is never cleared. The over-read is
+the last block's: 615 → 692 pages a launch of 64 rows over 200-380
+tokens (+12.5 %), 771 → 932 with two prefill spans beside 56 rows.
+  One predicate a block for sixteen a block bought NOTHING by itself
+(my chip run, PR 38, call 1: 0.1722 → 0.1720 ms a decode launch at (28, 4,
+128), 0.1247 → 0.1276 at (8, 2, 128), every row within 2 %): a `pl.when`
+whose region RUNS costs the scalar core next to nothing. What a launch's
+time is made of, from the same script's sweeps (64 rows, every row at
+128 … 2048 tokens, and the stream with its copies or its arithmetic
+taken out; calls 2-7), at (8, 2, 128), µs:
+  - a block once a walk is under way: 0.43, of which arithmetic alone
+    0.36 (two lane tiles; 0.62 / 0.55 at four) and copies alone 0.31 —
+    they overlap, and what is left over (0.07) is a block's 8 descriptors
+    issued after its arithmetic. A block of 256 or 512 tokens, or one
+    descriptor a pool a block, changes none of it; a ring of 4 blocks
+    for 2 takes 0.43 to 0.38 (2048 tokens: 0.452 → 0.383 ms a launch) but
+    costs the 200-380 mix 0.089 → 0.122, so the rings stay.
+  - a program (a decode row) before its first block: 0.77 at the parent,
+    0.22 with the copies taken out. THAT was the constant, and three
+    things made it: (1) a page copy started and waited for at once takes
+    0.73 µs (8 copies: 0.90; `--probe-dma`), and the parent started a
+    row's first blocks in its predecessor's epilogue, ~0.2 µs before
+    their wait — every row, and in the ragged kernel every (tile,
+    sequence) walk, began on a cold DMA; (2) `x // d` and `x % d` on the
+    scalar core cost ~0.1-0.2 µs EACH (no divider: `cdiv` and `mod` here
+    are a shift and a mask where d is a power of two, as every page
+    size, block and ring in use is), one a block and three or four a
+    program; (3) a `pl.when` whose region is SKIPPED costs ~0.08 µs — a
+    taken jump — where one that runs is free: the parent skipped two
+    refills at the end of every walk, four starts at the head of every
+    program, and up to seven trailing sequences of every prefill tile.
+  So the ring runs over a launch's walks as ONE stream of blocks
+(`paged_attention.py`, `ragged_attention.py`): the refill of a consumed
+slot that falls past a walk's last block starts the NEXT walk's block,
+a slot's position is carried from walk to walk in SMEM, what a short
+walk must start before its loop sits under one predicate that a walk of
+nbuf blocks skips as a whole, and the ragged kernel's walk over a tile's
+sequences ends at the last that has a row in the tile. Measured, ms a
+launch, parent → this (my chip run, PR 38, call 7; 64 rows over 200-380
+tokens: decode, ragged, ragged with two 228-token prefill spans):
+  (28, 4, 128)   0.1705 → 0.1231   0.1785 → 0.1280   0.2793 → 0.1839
+  (8, 2, 128)    0.1238 → 0.0892   0.1336 → 0.0960   0.2074 → 0.1336
+  (32, 8, 64)    0.1833 → 0.1350   0.1907 → 0.1378   0.3599 → 0.2628
+  (16, 16, 128)  0.3696 → 0.3317*  0.4791 → 0.3477   0.6978 → 0.4860
+  (30, 30, 128)  0.5530 → 0.4936*  0.8193 → 0.6157   1.1832 → 0.8510
+(* the Vpu body, a block of one page.) Per (sequence, block) at (8, 2,
+128): 0.72 → 0.52 µs, of which the arithmetic is 0.36 a block and 0.22 a
+program: what is left to take is ~0.1 µs a block.
+
 Which loops are in the program, which in Python, and why. A step program
 is traced, lowered and keyed once a rung of the token ladder at every
 start, compile cache warm or not, and that cost follows the size of the
@@ -61,16 +126,20 @@ kernel's traced body: it is most of `setup_s`, which the benchmark judges
 in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
 128 copies of `Mxu.update` at OLMoE's shape, 8354 equations, a warm rung
 7.7-11.5 s where the VPU body's 64 light copies took 4.5-6.9 s).
-  - IN THE PROGRAM (`lax.fori_loop`): a sequence's blocks (both kernels,
+  - IN THE PROGRAM: a walk's blocks (`lax.fori_loop`, both kernels,
     always), and since PR 34 the ragged kernel's walk over the at most
-    G_TILE sequences that overlap a tile. The ring restarts a sequence,
-    so no DMA state crosses a trip; the per-sequence scalars are SMEM
-    reads at a dynamic index. Same launch within 2 % at every published
-    shape (0.474 → 0.476, 0.178 → 0.180 ms; PR 34, same run), an eighth
-    of the body: 1146 equations at (16, 16, 128), 450 at (28, 4, 128),
-    whatever G_TILE is (`tests/test_ragged_attention.py` holds that).
+    G_TILE sequences that overlap a tile (since PR 38 a `lax.while_loop`
+    that ends at the last of them). What crosses a trip is scalars: the
+    walk's sequence, its pages and the ring's position; the DMAs in
+    flight are the ring's, whoever waits for them; the per-sequence
+    scalars are SMEM reads at a dynamic index. Same launch within 2 % at
+    every published shape when written (0.474 → 0.476, 0.178 → 0.180 ms;
+    PR 34, same run), an eighth of the body: 1145 equations at (16, 16,
+    128), 449 at (28, 4, 128), whatever G_TILE is
+    (`tests/test_ragged_attention.py` holds that).
   - IN PYTHON: the lane tiles (`for t in range(self.tiles)` in
-    `Mxu.update` / `finish`) and a block's pages (`PageStream`, at most 4).
+    `Mxu.update` / `finish`) and a block's pages (`PageStream`, at most 4:
+    straight-line copies under the block's one predicate).
     Mosaic ACCEPTS the lane-tile loop in the program (`bufs[..][slot, :,
     pl.ds(pl.multiple_of(t * W, 128), W)]`, every published shape, one
     chip and under `shard_map`), and it would leave ONE copy of the inner
@@ -84,6 +153,18 @@ in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
     published shapes — and with nothing else.
 `hd % 128 == 0` or `hd == 64` changes none of this: the packing is the
 wrapper's, the kernel sees `tiles` tiles of width W.
+  What a rung's trace is charged for is not only equations (PR 38): on a
+tracer every operator (`a + b`, `a < b`) and every `jnp.where` /
+`minimum` / `clip` is a NESTED JIT, and inside a serving process — whose
+step program's trace has pushed everything else out of jax's tracing
+caches — each costs ~1-2 ms. The walks' scalar arithmetic written with
+operators read +0.25 s a ragged rung on `.batch` (six rungs a start;
+`JAX_LOG_COMPILES` and a profile of the first call, my chip run, PR 38)
+though the kernel alone traced no slower anywhere; written with `lax`
+primitives (`add`, `mul`, `cdiv`, `mod`, `lax.select` … below) the
+ragged kernel binds 117 nested jits a trace where the parent's bound 191
+and the decode kernel 97 for 196 (at (28, 4, 128); all of them left are
+the inner products' vector code).
 
 P into P·V, and what "float32" meant before: Mosaic's default-precision
 float32 matmul on a v5e rounds its operands to bf16 (my chip run, PR 33:
@@ -116,6 +197,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -136,6 +218,50 @@ PV_TERMS = 3
 def _dot(a, b, dims=_NN):
     return jax.lax.dot_general(a, b, dimension_numbers=dims,
                                preferred_element_type=jnp.float32)
+
+
+# The kernels' scalar arithmetic — which walk, which block, which slot —
+# is written with `lax` primitives, not with operators or `jnp`: on a
+# tracer every operator and every `jnp.where` / `minimum` / `clip` is a
+# nested jit, ~1-2 ms of tracing each inside a serving process, and a
+# step program is traced once a rung of the token ladder at every start
+# (`setup_s`; my chip run, PR 38: the same scalar code with operators
+# cost a ragged rung +0.25 s). `add` / `mul` / `both` / `either` keep
+# static values static, so a static index stays one.
+def _static(*xs):
+    return all(isinstance(x, (int, bool)) for x in xs)
+
+
+def add(a, b):
+    return a + b if _static(a, b) else lax.add(a, b)
+
+
+def mul(a, b):
+    return a * b if _static(a, b) else lax.mul(a, b)
+
+
+def both(a, b):
+    return (a and b) if _static(a, b) else lax.bitwise_and(a, b)
+
+
+def either(a, b):
+    return (a or b) if _static(a, b) else lax.bitwise_or(a, b)
+
+
+def cdiv(x, d: int):
+    """ceil(x / d), x a traced int32 >= 0 and d static: a shift where d is
+    a power of two (the scalar core has no divider: `kv_contract.py`'s
+    docstring has what a `//` costs)."""
+    if d & (d - 1) == 0:
+        return lax.shift_right_arithmetic(lax.add(x, d - 1),
+                                          d.bit_length() - 1)
+    return lax.div(lax.add(x, d - 1), d)
+
+
+def mod(x, d: int):
+    """x % d, x a traced int32 >= 0 and d static: a mask where d is a
+    power of two."""
+    return lax.bitwise_and(x, d - 1) if d & (d - 1) == 0 else lax.rem(x, d)
 
 
 def choose_inner(rows: int, group: int) -> str:
@@ -162,7 +288,8 @@ def ring_grid_spec(inner, ring, grid, num_scalar_prefetch, pools):
     o blocked in VMEM by program in the inner product's packed layout, the
     pools — K, V and an int8 pool's two scale planes — left in HBM, and as
     scratch a ring of `nbuf` block buffers a pool (`ring` pages in flight,
-    at least two blocks), the softmax state and the DMA semaphores."""
+    at least two blocks), the softmax state, the DMA semaphores and the
+    ring's position (`RingWalk`)."""
     nbuf = max(2, ring // inner.block_pages)
     blk = inner.block_pages * inner.page_size
     q_block = inner.q_block
@@ -176,25 +303,38 @@ def ring_grid_spec(inner, ring, grid, num_scalar_prefetch, pools):
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((nbuf, blk, p.shape[-1]), p.dtype)
                         for p in pools] + inner.scratch()
-        + [pltpu.SemaphoreType.DMA((nbuf, len(pools)))],
+        + [pltpu.SemaphoreType.DMA((nbuf, len(pools))),
+           pltpu.SMEM((1,), jnp.int32)],
     )
+
+
+def whole_blocks(page_table, inner):
+    """The page table as the kernels read it: its width a multiple of the
+    inner product's block, padded — as every row already is — with the
+    trash page (page 0), so that a block's every page has an entry. The
+    engine's tables (256 pages a row) come back as they are."""
+    short = -page_table.shape[1] % inner.block_pages
+    page_table = page_table.astype(jnp.int32)
+    return jnp.pad(page_table, ((0, 0), (0, short))) if short else page_table
 
 
 def split_refs(refs):
     """A kernel's refs after the scalar prefetch, as `ring_grid_spec` lays
     them out: (q, the n pools in HBM, o, their n ring buffers, (acc, m_i,
-    l_i), the semaphores)."""
-    n = (len(refs) - 6) // 2
+    l_i), the semaphores, the ring's position)."""
+    n = (len(refs) - 7) // 2
     return (refs[0], refs[1:1 + n], refs[1 + n], refs[2 + n:2 + 2 * n],
-            refs[2 + 2 * n:-1], refs[-1])
+            refs[2 + 2 * n:-2], refs[-2], refs[-1])
 
 
 class PageStream:
-    """One sequence's K/V pages, HBM→VMEM, a block of `block_pages` pages
-    at a time into ring slot `slot` of `[nbuf, block_pages*page_size,
-    lanes]` buffers (page i of the block at rows i*page_size…). Starts and
-    waits share one condition — the page exists — so the semaphores of a
-    slot (one a buffer) always balance."""
+    """One walk's K/V pages, HBM→VMEM, a block of `block_pages` pages at
+    a time into ring slot `slot` of `[nbuf, block_pages*page_size, lanes]`
+    buffers (page i of the block at rows i*page_size…). The unit is the
+    block (module docstring): `start` has one predicate — the block
+    exists — and `wait` none, and a slot's semaphores (one a pool)
+    balance because every block that was started is waited for exactly
+    once, by the walk it belongs to."""
 
     def __init__(self, hbm, bufs, sems, layer, page_table_ref, page_size,
                  block_pages):
@@ -202,28 +342,37 @@ class PageStream:
         self.layer, self.page_table_ref = layer, page_table_ref
         self.page_size, self.block_pages = page_size, block_pages
 
-    def _each_page(self, slot, row, block, npages, cond, op):
-        ps = self.page_size
-        for i in range(self.block_pages):
-            page_idx = block * self.block_pages + i
-            exists = page_idx < npages
-            if cond is not None:
-                exists = cond & exists
-
-            @pl.when(exists)
-            def _(i=i, page_idx=page_idx):
-                start = self.page_table_ref[row, page_idx] * ps
-                for n, (src, dst) in enumerate(zip(self.hbm, self.bufs)):
-                    op(pltpu.make_async_copy(
-                        src.at[self.layer, pl.ds(start, ps)],
-                        dst.at[slot, pl.ds(i * ps, ps)],
-                        self.sems.at[slot, n]))
-
     def start(self, slot, row, block, npages, cond=None):
-        self._each_page(slot, row, block, npages, cond, lambda c: c.start())
+        """Start block `block` of sequence `row` into `slot` if the block
+        holds one of the walk's `npages` pages (and `cond`): every page
+        of it, those past the last included — the table holds the trash
+        page there."""
+        ps, bp = self.page_size, self.block_pages
+        first = mul(block, bp)
+        exists = lax.lt(first, npages)
+        if cond is not None:
+            exists = both(cond, exists)
 
-    def wait(self, slot, row, block, npages):
-        self._each_page(slot, row, block, npages, None, lambda c: c.wait())
+        @pl.when(exists)
+        def _():
+            for i in range(bp):
+                at = lax.mul(self.page_table_ref[row, add(first, i)], ps)
+                for n, (src, dst) in enumerate(zip(self.hbm, self.bufs)):
+                    pltpu.make_async_copy(
+                        src.at[self.layer, pl.ds(at, ps)],
+                        dst.at[slot, pl.ds(i * ps, ps)],
+                        self.sems.at[slot, n]).start()
+
+    def wait(self, slot):
+        """Wait for the block in flight into `slot`: once a pool, for a
+        block's bytes (a DMA semaphore counts bytes, so the block's
+        page-sized starts balance one block-sized wait; the source of
+        the descriptor is only a shape)."""
+        blk = self.block_pages * self.page_size
+        for n, (src, dst) in enumerate(zip(self.hbm, self.bufs)):
+            pltpu.make_async_copy(
+                src.at[self.layer, pl.ds(0, blk)], dst.at[slot],
+                self.sems.at[slot, n]).wait()
 
 
 def _segments(num_kv_heads, head_dim):
@@ -264,15 +413,7 @@ class _Inner:
     def lanes(self):
         return self.num_kv_heads * self.head_dim
 
-    def init(self, bufs, acc, m_i, l_i):
-        # A block's rows past the sequence's last page are never written
-        # by a DMA, and 0 * (what VMEM held at power-on) is not 0: clear
-        # the ring once a launch; afterwards it only ever holds pool rows.
-        @pl.when(pl.program_id(0) == 0)
-        def _():
-            for buf in bufs:
-                buf[...] = jnp.zeros_like(buf)
-
+    def init(self, acc, m_i, l_i):
         acc[...] = jnp.zeros_like(acc)
         m_i[...] = jnp.full_like(m_i, NEG_INF)
         l_i[...] = jnp.zeros_like(l_i)

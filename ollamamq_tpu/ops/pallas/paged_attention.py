@@ -39,7 +39,11 @@ Layout contract (matches engine/kv_cache.py):
 
 Grid: one program per batch element; page_table/seq_lens ride scalar
 prefetch so the DMA offsets are known before the body runs
-(PrefetchScalarGridSpec pattern from the Pallas TPU guide).
+(PrefetchScalarGridSpec pattern from the Pallas TPU guide). The programs
+run in order on one core and share the ring: it runs over the launch's
+rows as one stream of blocks, so a row's first blocks are started from
+inside its predecessor's loop (`_decode_kernel`, and `kv_contract.py` on
+what a cold block costs).
 """
 
 from __future__ import annotations
@@ -48,13 +52,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
-from ollamamq_tpu.ops.pallas.kv_contract import (PageStream, make_inner,
-                                                 ring_grid_spec, split_refs)
+from ollamamq_tpu.ops.pallas.kv_contract import (PageStream, cdiv,
+                                                 make_inner, mod,
+                                                 ring_grid_spec, split_refs,
+                                                 whole_blocks)
 
-# Pages in flight per sequence: measured on v5e, 4-16 are within noise
-# of each other (the DMA path is issue-overhead-bound); 8 is the middle.
+# Pages in flight: two blocks under the Mxu body, eight pages under the
+# Vpu body. Re-measured with the ring running over the launch's rows (my
+# chip run, PR 38, `scripts/attn_kernel_bench.py --set
+# paged_attention.RING=…`): 16 for 8 costs 64 rows over 200-380 tokens
+# 0.089 → 0.122 ms at (8, 2, 128) and 0.332 → 0.376 at (16, 16, 128) — a
+# row of fewer blocks than the ring starts its successor's before its
+# loop, where nothing hides the issue — and buys rows of 2048 tokens
+# 0.452 → 0.383: no cell holds such rows, so 8 stands.
 RING = 8
 
 
@@ -68,7 +81,7 @@ def _decode_kernel(
     nbuf: int,
     max_pages: int,
 ):
-    q_ref, hbm, o_ref, bufs, state, sems = split_refs(refs)
+    q_ref, hbm, o_ref, bufs, state, sems, at_ref = split_refs(refs)
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     seq_len = seq_lens_ref[b]
@@ -80,45 +93,69 @@ def _decode_kernel(
     # page_table out of bounds (the jnp reference implicitly truncates the
     # context the same way).
     def pages_of(row):
-        return jnp.minimum(
-            pl.cdiv(seq_lens_ref[row], page_size), max_pages
-        )
+        return lax.min(cdiv(seq_lens_ref[row], page_size), max_pages)
 
-    num_pages = pages_of(b)
-    inner.init(bufs, *state)
-
-    # Fill the ring — but ONLY for the first grid program: every later
-    # program's first `nbuf` blocks were started by its predecessor's
-    # epilogue (cross-program prefetch), so the DMA pipeline never drains
-    # at a program boundary. Starts and waits share the same "the page
-    # exists" condition, so semaphore counts always balance.
-    for i in range(nbuf):
-        stream.start(i, b, i, num_pages, cond=b == 0)
-
-    def body(p, _):
-        slot = p % nbuf
-        stream.wait(slot, b, p, num_pages)
-        inner.update(
-            q_ref, bufs, slot, (0, 0, 1, seq_len), p * (bp * page_size),
-            state,
-            # Ring slot consumed: refill it with the block `nbuf` ahead,
-            # keeping nbuf-1 blocks in flight.
-            lambda: stream.start(slot, b, p + nbuf, num_pages))
-        return ()
-
-    jax.lax.fori_loop(0, pl.cdiv(num_pages, bp), body, ())
-
-    # Cross-program prefetch: start the NEXT batch element's first `nbuf`
-    # blocks. Every one of this program's copies has been consumed by the
-    # loop above (refills are guarded to existing pages), so all ring
-    # slots are free; the next program starts no DMAs of its own and its
-    # body waits land on copies already in flight. The row index is
+    # The ring runs over the launch's rows as ONE stream of blocks: row
+    # b's block p sits in slot (at + p) % nbuf, `at` the blocks of the
+    # rows before it (carried in SMEM from program to program), and the
+    # refill of a consumed slot that falls past this row's last block
+    # starts the NEXT row's block instead. A row of n >= nbuf blocks thus
+    # starts all of its successor's first nbuf blocks from inside its own
+    # loop, nbuf trips before they are waited for: no row but the first
+    # begins on a cold DMA, and no refill is skipped (`kv_contract.py`:
+    # what either costs, and why the scalars are `lax` calls). Starts and
+    # waits balance because a block is started exactly once — by its own
+    # row's refill, by its predecessor's, or before the predecessor's
+    # loop — and waited for once, by its own row. The row index is
     # clamped BEFORE the predicate so the last program never reads
     # seq_lens_ref out of bounds (the b+1 < nb guard then discards the
     # dummy value).
-    succ = jnp.minimum(b + 1, nb - 1)
-    for i in range(nbuf):
-        stream.start(i, succ, i, pages_of(succ), cond=b + 1 < nb)
+    num_pages = pages_of(b)
+    n = cdiv(num_pages, bp)
+    is_first = lax.eq(b, 0)
+    has_succ = lax.lt(lax.add(b, 1), nb)
+    succ = lax.min(lax.add(b, 1), lax.sub(nb, 1))
+    succ_pages = pages_of(succ)
+
+    at = lax.select(is_first, 0, at_ref[0])
+    inner.init(*state)
+
+    # What a program starts before its loop, under ONE predicate that a
+    # row of nbuf blocks or more (after the first) skips as a whole.
+    @pl.when(lax.bitwise_or(is_first,
+                            lax.bitwise_and(has_succ, lax.lt(n, nbuf))))
+    def _():
+        for i in range(nbuf):
+            # The first program fills its own ring; every other program's
+            # first blocks were started by its predecessor.
+            stream.start(i, b, i, num_pages, cond=is_first)
+            # The successor's blocks that land in slots this row leaves
+            # unused (a row of fewer than nbuf blocks) start right away.
+            stream.start(mod(lax.add(at, lax.add(n, i)), nbuf), succ, i,
+                         succ_pages,
+                         cond=lax.bitwise_and(has_succ,
+                                              lax.lt(lax.add(n, i), nbuf)))
+
+    def body(p, _):
+        slot = mod(lax.add(at, p), nbuf)
+        stream.wait(slot)
+
+        def refill():
+            # Ring slot consumed: the block `nbuf` ahead in the stream,
+            # this row's or the next row's.
+            ahead = lax.add(p, nbuf)
+            own = lax.lt(ahead, n)
+            stream.start(slot, lax.select(own, b, succ),
+                         lax.select(own, ahead, lax.sub(ahead, n)),
+                         lax.select(own, num_pages, succ_pages),
+                         cond=lax.bitwise_or(own, has_succ))
+
+        inner.update(q_ref, bufs, slot, (0, 0, 1, seq_len),
+                     lax.mul(p, bp * page_size), state, refill)
+        return ()
+
+    jax.lax.fori_loop(0, n, body, ())
+    at_ref[0] = mod(lax.add(at, n), nbuf)
 
     inner.finish(o_ref, state)
 
@@ -164,6 +201,6 @@ def paged_decode_attention_pallas(
         out_shape=jax.ShapeDtypeStruct(q_packed.shape, q.dtype),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      whole_blocks(page_table, inner), seq_lens.astype(jnp.int32),
       q_packed, *pools)
     return inner.unpack_o(out)

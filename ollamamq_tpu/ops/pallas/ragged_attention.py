@@ -22,21 +22,21 @@ ops/pallas/paged_attention.py):
                 An int8 pool's scale planes are [L, S, Hk] likewise.
     page_table: [B, max_pages] int32 (trash page 0 padding).
     Spans are contiguous and ascending in stream order; padding rows
-    carry q_len = 0 with q_start = T.
+    carry q_len = 0 with q_start = T and only trail the live rows (a
+    tile's walk ends at the first row that has no token in it).
 
 Grid: one program per G_TILE-token tile of the stream. A tile may span
 several sequences (e.g. 8 decode tokens from 8 different sequences), so
 per-tile scalar-prefetch metadata names the FIRST overlapping sequence
 and the kernel walks forward over the (at most G_TILE) sequences that
 intersect the tile, masking rows by span membership. That walk is a loop
-IN THE PROGRAM (`lax.fori_loop` over the successors, cut at the last
-sequence; `q_start` / `q_len` / `kv_len` / the page table are SMEM reads
-at a dynamic row), not G_TILE predicated copies of the body: the ring
-restarts per sequence, so nothing crosses a trip, a launch costs the
-same within 2 %, and the traced body — which every start-up pays for
-once a rung of the token ladder, `setup_s` — is an eighth of the
-unrolled one (`kv_contract.py` has the numbers and says which loops stay
-in Python). Per sequence it streams that sequence's pages HBM→VMEM
+IN THE PROGRAM (`lax.while_loop` over the successors, ended at the last
+sequence with a row in the tile; `q_start` / `q_len` / `kv_len` / the
+page table are SMEM reads at a dynamic row), not G_TILE predicated
+copies of the body: a launch costs no more, and the traced body — which
+every start-up pays for once a rung of the token ladder, `setup_s` — is
+an eighth of the unrolled one (`kv_contract.py` has the numbers and says
+which loops stay in Python). Per sequence it streams that sequence's pages HBM→VMEM
 through a ring of block buffers and accumulates a flash-style online
 softmax; the page loop is bounded by the tile's deepest causal frontier,
 so an early prefill tile reads only the prefix it can see.
@@ -58,10 +58,11 @@ bf16 pages do (half the bytes), each page's fp32 [page_size, Hk] scale
 rows ride a third/fourth DMA into their own VMEM buffers, and the block
 is dequantised in-kernel right after the wait — scale rows expand to
 lane segments with a 0/1 segment-matrix matmul, no relayout — before the
-same contraction. Softmax/accumulation stay f32. Cross-tile DMA prefetch
-(the decode kernel's cross-program epilogue) is intentionally absent for
-now: sequence boundaries inside a tile make the hand-off non-trivial,
-and the block loop already overlaps DMA with compute within a sequence.
+same contraction. Softmax/accumulation stay f32. The ring of block
+buffers runs over the launch's (tile, sequence) walks as one stream — a
+walk's last refills start its successor's first blocks, within a tile
+and across tiles (the programs run in order on one core) — so only the
+launch's first walk begins on a cold DMA (`_ragged_kernel`).
 """
 
 from __future__ import annotations
@@ -70,13 +71,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
-from ollamamq_tpu.ops.pallas.kv_contract import (G_TILE, PageStream,
-                                                 make_inner, ring_grid_spec,
-                                                 split_refs)
+from ollamamq_tpu.ops.pallas.kv_contract import (G_TILE, PageStream, cdiv,
+                                                 make_inner, mod,
+                                                 ring_grid_spec, split_refs,
+                                                 whole_blocks)
 
-RING = 4  # pages in flight per sequence (the ring restarts per sequence)
+# Pages in flight: one block is the least a ring holds, and it holds two.
+# 12 or 16 pages (3 or 4 blocks) read 3-15 % slower on 64 rows over
+# 200-380 tokens at every shape and 12 % faster at 2048 tokens (my chip
+# run, PR 38; `paged_attention.RING` has the reason).
+RING = 4
 
 
 def _ragged_kernel(
@@ -93,61 +100,102 @@ def _ragged_kernel(
     max_pages: int,
     num_seqs: int,
 ):
-    q_ref, hbm, o_ref, bufs, state, sems = split_refs(refs)
+    q_ref, hbm, o_ref, bufs, state, sems, at_ref = split_refs(refs)
     t = pl.program_id(0)
-    tile_start = t * G_TILE
+    n_tiles = pl.num_programs(0)
+    tile_start = lax.mul(t, G_TILE)
     page_size, bp = inner.page_size, inner.block_pages
     stream = PageStream(hbm, bufs, sems, layer_ref[0], page_table_ref,
                         page_size, bp)
-    inner.init(bufs, *state)
+    inner.init(*state)
 
+    def walk_pages(tile, s):
+        """Pages of sequence `s` that tile `tile` walks — up to the
+        deepest causal frontier among the tile's rows of s, so an early
+        tile of a long prefill reads only the prefix its own queries can
+        see — and 0 where s is no sequence or has no row in the tile.
+        (`lax` calls, not operators: `kv_contract.py` says why.)"""
+        lo = lax.mul(tile, G_TILE)
+        hi = lax.add(lo, G_TILE)
+        row = lax.clamp(0, s, num_seqs - 1)
+        qs, ql, kv = q_start_ref[row], q_len_ref[row], kv_len_ref[row]
+        end = lax.add(qs, ql)
+        overlaps = functools.reduce(lax.bitwise_and, (
+            lax.ge(s, 0), lax.lt(s, num_seqs), lax.gt(ql, 0),
+            lax.lt(qs, hi), lax.gt(end, lo)))
+        # kv - ql + (last_tok - qs) + 1, last_tok = min(hi, end) - 1
+        frontier = lax.sub(lax.add(lax.sub(kv, ql), lax.min(hi, end)), qs)
+        return lax.select(
+            overlaps, lax.min(cdiv(frontier, page_size), max_pages), 0)
+
+    # A launch is ONE stream of walks — (tile, sequence) pairs in grid
+    # order, a tile's sequences ascending — and the ring runs over their
+    # blocks without a break: a walk's block b sits in slot (at + b) %
+    # nbuf, `at` the blocks of the walks before it, and the refill of a
+    # consumed slot that falls past the walk's last block starts the NEXT
+    # walk's block instead, so no walk but the launch's first begins on a
+    # cold DMA (`kv_contract.py` has the numbers). A walk's successor is
+    # the next sequence if it has a row in this tile, else the next
+    # tile's first sequence; every tile runs its first walk even when it
+    # is empty (a tile past the stream's end), so the chain never breaks,
+    # and program 0 begins one walk EARLIER, on an empty walk whose
+    # successor is the launch's first: that is what starts the first
+    # blocks.
+    next_tile = lax.min(lax.add(t, 1), lax.sub(n_tiles, 1))
+    next_first = tile_seq_ref[next_tile]
+    next_pages = lax.select(lax.lt(lax.add(t, 1), n_tiles),
+                            walk_pages(next_tile, next_first), 0)
     s0 = tile_seq_ref[t]
 
-    def one_sequence(j, _):
-        s = s0 + j
-        qs = q_start_ref[s]
-        ql = q_len_ref[s]
-        kv = kv_len_ref[s]
-        overlaps = (
-            (ql > 0)
-            & (qs < tile_start + G_TILE)
-            & (qs + ql > tile_start)
-        )
+    def one_walk(carry):
+        s, pages, _, at = carry
+        n = cdiv(pages, bp)
+        row = lax.max(s, 0)
+        span = (tile_start, q_start_ref[row], q_len_ref[row],
+                kv_len_ref[row])
+        after = lax.add(s, 1)
+        stay_pages = walk_pages(t, after)
+        stays = lax.gt(stay_pages, 0)
+        succ = lax.select(stays, after, next_first)
+        succ_pages = lax.select(stays, stay_pages, next_pages)
 
-        @pl.when(overlaps)
+        # The successor's blocks that land in slots this walk leaves
+        # unused start right away; a walk of nbuf blocks or more skips
+        # them as a whole.
+        @pl.when(lax.lt(n, nbuf))
         def _():
-            # Deepest causal frontier among this tile's rows of s bounds
-            # the page walk: an early tile of a long prefill reads only
-            # the prefix its own queries can see.
-            last_tok = jnp.minimum(tile_start + G_TILE, qs + ql) - 1
-            last_pos = kv - ql + (last_tok - qs)
-            npages = jnp.minimum(
-                pl.cdiv(last_pos + 1, page_size), max_pages
-            )
             for i in range(nbuf):
-                stream.start(i, s, i, npages)
+                stream.start(mod(lax.add(at, lax.add(n, i)), nbuf), succ, i,
+                             succ_pages, cond=lax.lt(lax.add(n, i), nbuf))
 
-            def body(b, _):
-                slot = b % nbuf
-                stream.wait(slot, s, b, npages)
-                inner.update(
-                    q_ref, bufs, slot, (tile_start, qs, ql, kv),
-                    b * (bp * page_size), state,
-                    # Ring slot consumed: refill it with the block `nbuf`
-                    # ahead, keeping nbuf-1 blocks in flight.
-                    lambda: stream.start(slot, s, b + nbuf, npages))
-                return ()
+        def body(b, _):
+            slot = mod(lax.add(at, b), nbuf)
+            stream.wait(slot)
 
-            jax.lax.fori_loop(0, pl.cdiv(npages, bp), body, ())
+            def refill():
+                # Ring slot consumed: the block `nbuf` ahead in the
+                # stream, this walk's or the next walk's.
+                ahead = lax.add(b, nbuf)
+                own = lax.lt(ahead, n)
+                stream.start(slot, lax.select(own, s, succ),
+                             lax.select(own, ahead, lax.sub(ahead, n)),
+                             lax.select(own, pages, succ_pages))
 
-        return ()
+            inner.update(q_ref, bufs, slot, span,
+                         lax.mul(b, bp * page_size), state, refill)
+            return ()
 
-    # At most G_TILE sequences can have a token inside a G_TILE-token
-    # tile (spans are contiguous, zero-length rows only trail the
-    # stream), so a walk of G_TILE successors, cut at the last sequence,
-    # covers every case: one loop in the program (module docstring).
-    jax.lax.fori_loop(0, jnp.minimum(G_TILE, num_seqs - s0), one_sequence,
-                      ())
+        jax.lax.fori_loop(0, n, body, ())
+        return (after, stay_pages, lax.convert_element_type(stays, jnp.int32),
+                mod(lax.add(at, n), nbuf))
+
+    first = lax.eq(t, 0)
+    *_, at = jax.lax.while_loop(
+        lambda carry: lax.gt(carry[2], 0), one_walk,
+        (lax.select(first, lax.sub(s0, 1), s0),
+         lax.select(first, 0, walk_pages(t, s0)),
+         jnp.int32(1), lax.select(first, 0, at_ref[0])))
+    at_ref[0] = at
 
     inner.finish(o_ref, state)
 
@@ -204,6 +252,6 @@ def ragged_paged_attention_pallas(
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), tile_first,
       q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
-      kv_lens.astype(jnp.int32), page_table.astype(jnp.int32),
+      kv_lens.astype(jnp.int32), whole_blocks(page_table, inner),
       q_packed, *pools)
     return inner.unpack_o(out)[:T]

@@ -10,21 +10,31 @@ LAUNCHES calls chained inside one jit (each call's q is the last one's
 output, so they cannot overlap). Traffic: "decode" = the decode kernel,
 64 rows; "ragged64" = the ragged kernel on the same 64 decode rows (a
 ragged step with nothing to prefill); "ragged512" = 56 decode rows and
-two 228-token prefill spans. It also asks what Mosaic's default-precision
-float32 matmul keeps of its operand (`f32_matmul_keeps`): the Vpu body's
+two 228-token prefill spans. `--contexts N…` replaces the 200-380 mix by
+one row a value with every sequence at exactly N tokens: the slope over
+N is what a block costs once a walk is under way, the intercept what a
+program (a row, a tile) costs before its first block. Each row also
+says what its walks need (`pages_live`), what whole blocks move
+(`pages_read`: the over-read of each walk's last block) and µs a
+(sequence, block). It also asks what Mosaic's default-precision float32
+matmul keeps of its operand (`f32_matmul_keeps`): the Vpu body's
 `p @ seg_t` is one. One JSON line a measurement; exits 1 without a TPU.
 
-A variant of a kernel's body is measured here before a whole cell: PR 34
-timed the successor walk and the lane-tile loop each unrolled in Python
-and as a loop in the program, through two module switches that lived for
-that run (the result, and why only the walk is in the program, is in
-`kv_contract.py`'s docstring). Do the same for the next variant: a
-switch this script sets, `jax.clear_caches()`, one more row a shape.
+A variant of a kernel's body is measured here before a whole cell: a
+module constant of the three kernel modules that this script sets
+(`--set kv_contract.PV_TERMS=1`, `--set paged_attention.RING=16,
+ragged_attention.RING=16`), `jax.clear_caches()`, one more row a (shape,
+traffic); a variant that needs code gets a constant that lives for that
+run (PR 34 timed the successor walk and the lane-tile loop each unrolled
+in Python and as a loop in the program so, PR 38 the page stream with a
+predicate a page and a block; the results are in `kv_contract.py`'s
+docstring).
 """
 
 from __future__ import annotations
 
 import argparse
+import ast
 import json
 import os
 import sys
@@ -36,17 +46,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ollamamq_tpu.ops.attention import (paged_decode_attention_any,
                                         ragged_attention_any)
-from ollamamq_tpu.ops.pallas import kv_contract
-from ollamamq_tpu.ops.pallas.paged_attention import (
-    paged_decode_attention_pallas)
-from ollamamq_tpu.ops.pallas.ragged_attention import (
-    ragged_paged_attention_pallas)
+from ollamamq_tpu.ops.pallas import (kv_contract, paged_attention,
+                                     ragged_attention)
 
-SHAPES = ((28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128))
+MODULES = {m.__name__.rsplit(".", 1)[1]: m
+           for m in (kv_contract, paged_attention, ragged_attention)}
+SHAPES = ((28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
+          (30, 30, 128))
 B, MP, PS, NP, LAYER = 64, 256, 32, 1024, 1
+# The jnp reference gathers a table's whole width, [B, width*PS, lanes]:
+# it is fed the columns a context here can reach and no more.
+REF_PAGES = 64
 LAUNCHES = 64
 
 
@@ -68,27 +82,90 @@ def f32_matmul_keeps() -> dict:
     return out
 
 
-def batch(rng, n_decode, spans):
-    """(page_table, tok_seq, tok_pos, kv_len, q_start, q_len, T)."""
-    rows = [(1, int(rng.integers(200, 380))) for _ in range(n_decode)]
+def dma_probe(lanes) -> list:
+    """What a page copy HBM→VMEM costs a kernel that has nothing to hide
+    it behind: TRIPS trips of `k` copies of `rows` pool rows started, then
+    waited for — µs a trip. One copy of one page reads the latency, the
+    slope over `k` what one more descriptor costs to issue and to move."""
+    TRIPS = 2048
+    pool = jnp.zeros((NP * PS, lanes), jnp.bfloat16)
+    out = []
+    for rows, k in ((PS, 1), (PS, 2), (PS, 4), (PS, 8), (4 * PS, 1),
+                    (4 * PS, 2)):
+        def kernel(hbm, o_ref, buf, sems, rows=rows, k=k):
+            def trip(i, _):
+                copies = [pltpu.make_async_copy(
+                    hbm.at[pl.ds(((i * k + j) % (NP // 4)) * rows, rows)],
+                    buf.at[j], sems.at[j]) for j in range(k)]
+                for c in copies:
+                    c.start()
+                for c in copies:
+                    c.wait()
+                return ()
+
+            jax.lax.fori_loop(0, TRIPS, trip, ())
+            o_ref[...] = buf[0, :8].astype(jnp.float32)
+
+        fn = jax.jit(lambda pool, kernel=kernel: pl.pallas_call(
+            kernel,
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_shape=jax.ShapeDtypeStruct((8, lanes), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((k, rows, lanes), jnp.bfloat16),
+                            pltpu.SemaphoreType.DMA((k,))])(pool))
+        fn(pool).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            fn(pool).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        out.append({"dma_probe": {"lanes": lanes, "rows": rows, "copies": k},
+                    "us_a_trip": round(best / TRIPS * 1e6, 4)})
+    return out
+
+
+def batch(rng, n_decode, spans, context=None):
+    """(page_table, tok_seq, tok_pos, kv_len, q_start, q_len, T). Decode
+    rows hold 200-380 tokens of context, or `context` each; pages are
+    handed out in order and wrap around the pool when a sweep asks for
+    more than it holds (the kernels only read)."""
+    rows = [(1, context - 1 if context else int(rng.integers(200, 380)))
+            for _ in range(n_decode)]
     rows += [(n, 0) for n in spans]
     T = sum(n for n, _ in rows)
     pt = np.zeros((B, MP), np.int32)
     q_len, kv_len = np.zeros(B, np.int32), np.zeros(B, np.int32)
     q_start = np.full(B, T, np.int32)
     tok_seq, tok_pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-    off, page = 0, 1
+    off, page = 0, 0
     for s, (n, prefix) in enumerate(rows):
         need = -(-(prefix + n) // PS)
-        pt[s, :need] = np.arange(page, page + need)
+        pt[s, :need] = 1 + (page + np.arange(need)) % (NP - 1)
         page += need
         q_len[s], kv_len[s], q_start[s] = n, prefix + n, off
         tok_seq[off:off + n] = s
         tok_pos[off:off + n] = prefix + np.arange(n)
         off += n
-    assert page <= NP, page
+    assert context or page < NP, page
     return [jnp.asarray(a) for a in (pt, tok_seq, tok_pos, kv_len, q_start,
                                      q_len)], T
+
+
+def walks(traffic, kv_len, q_start, q_len, T) -> list:
+    """Pages of each (program, sequence) walk of a launch: a decode
+    program walks its row's context, a ragged tile each overlapping
+    sequence's up to the tile's deepest causal frontier."""
+    kv_len, q_start, q_len = (np.asarray(a) for a in (kv_len, q_start,
+                                                      q_len))
+    if traffic == "decode":
+        return [-(-int(n) // PS) for n in kv_len]
+    out = []
+    for lo in range(0, T, kv_contract.G_TILE):
+        hi = lo + kv_contract.G_TILE
+        for qs, ql, kv in zip(q_start, q_len, kv_len):
+            if ql > 0 and qs < hi and qs + ql > lo:
+                last_pos = kv - ql + (min(hi, qs + ql) - 1 - qs)
+                out.append(-(-int(last_pos + 1) // PS))
+    return out
 
 
 def timed(fn, q, *args) -> float:
@@ -107,12 +184,42 @@ def timed(fn, q, *args) -> float:
     return best / LAUNCHES
 
 
+def parse_set(text) -> dict:
+    """"mod.NAME=value,mod.NAME=value" → {(module, NAME): value}."""
+    out = {}
+    for item in text.split(","):
+        name, value = item.split("=")
+        mod, attr = name.strip().split(".")
+        getattr(MODULES[mod], attr)  # a constant that exists
+        try:
+            value = ast.literal_eval(value)
+        except ValueError:
+            pass  # a bare word is a string
+        out[MODULES[mod], attr] = value
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--pv-terms", type=int, nargs="*", default=[],
-                    help="also time the Mxu body with P split into this "
-                         "many bf16 terms (kv_contract.PV_TERMS)")
+    ap.add_argument("--set", action="append", default=[], type=parse_set,
+                    metavar="MODULE.NAME=VALUE[,…]", dest="variants",
+                    help="one more row a (shape, traffic) with these "
+                         "constants of kv_contract / paged_attention / "
+                         "ragged_attention set (the served inner product)")
+    ap.add_argument("--only-set", action="store_true",
+                    help="leave out the rows of the tree as it stands")
+    ap.add_argument("--contexts", type=int, nargs="*", default=[],
+                    help="every sequence at exactly this many tokens, a "
+                         "row a value, instead of the 200-380 mix (and "
+                         "no ragged512)")
+    ap.add_argument("--probe-dma", action="store_true",
+                    help="first, what a page copy costs with nothing to "
+                         "hide it behind (latency, issue), at 256 and "
+                         "512 lanes")
+    ap.add_argument("--shapes", type=int, nargs="*",
+                    default=list(range(len(SHAPES))),
+                    help="indices into SHAPES")
     args = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -120,56 +227,83 @@ def main() -> int:
         return 1
     print(json.dumps({"device_kind": dev.device_kind,
                       "f32_matmul_keeps": f32_matmul_keeps()}), flush=True)
+    if args.probe_dma:
+        for lanes in (256, 512):
+            for row in dma_probe(lanes):
+                print(json.dumps(row), flush=True)
     rng = np.random.default_rng(args.seed)
-    default_terms = kv_contract.PV_TERMS
-    variants = [("vpu", None), ("mxu", None)] + [("mxu", n)
-                                                 for n in args.pv_terms]
-    for H, Hk, hd in SHAPES:
+    variants = [] if args.only_set else [("vpu", {}), ("mxu", {})]
+    variants += [(None, v) for v in args.variants]
+    traffics = [("decode", 64, (), c) for c in args.contexts or [None]]
+    traffics += [("ragged64", 64, (), c) for c in args.contexts or [None]]
+    if not args.contexts:
+        traffics.append(("ragged512", 56, (228, 228), None))
+    for H, Hk, hd in (SHAPES[i] for i in args.shapes):
         kc, vc = (jnp.asarray(rng.standard_normal((2, NP * PS, Hk * hd)),
                               jnp.bfloat16) for _ in range(2))
-        for traffic, n_dec, spans in (("decode", 64, ()),
-                                      ("ragged64", 64, ()),
-                                      ("ragged512", 56, (228, 228))):
+        for traffic, n_dec, spans, context in traffics:
             (pt, tok_seq, tok_pos, kv_len, q_start, q_len), T = batch(
-                np.random.default_rng(args.seed), n_dec, spans)
+                np.random.default_rng(args.seed), n_dec, spans, context)
             q = jnp.asarray(rng.standard_normal((T, H, hd)), jnp.bfloat16)
+            ref_pt = pt[:, :max(REF_PAGES, -(-(context or 0) // PS))]
             if traffic == "decode":
                 ref = paged_decode_attention_any(
-                    "jnp", q, kc, vc, LAYER, pt, kv_len, PS)
+                    "jnp", q, kc, vc, LAYER, ref_pt, kv_len, PS)
             else:
                 ref = ragged_attention_any(
-                    "jnp", q, kc, vc, LAYER, pt, tok_seq, tok_pos, kv_len,
-                    q_start, q_len, PS)
-            for inner, terms in variants:
+                    "jnp", q, kc, vc, LAYER, ref_pt, tok_seq, tok_pos,
+                    kv_len, q_start, q_len, PS)
+            for inner, consts in variants:
                 if traffic != "decode" and inner == "vpu":
                     continue  # the ragged kernel has one inner product
-                if terms is not None:
-                    kv_contract.PV_TERMS = terms
+                was = {k: getattr(*k) for k in consts}
+                for (mod, attr), value in consts.items():
+                    setattr(mod, attr, value)
+                if consts:
                     jax.clear_caches()
+                built = kv_contract.make_inner(
+                    inner if traffic == "decode" else None,
+                    rows=1 if traffic == "decode" else kv_contract.G_TILE,
+                    group=H // Hk, num_kv_heads=Hk, head_dim=hd,
+                    page_size=PS)
+                bp = built.block_pages
+                pages = walks(traffic, kv_len, q_start, q_len, T)
+                blocks = sum(-(-n // bp) for n in pages)
                 if traffic == "decode":
                     def fn(q, kc, vc, pt, kv_len, inner=inner):
-                        return paged_decode_attention_pallas(
+                        return paged_attention.paged_decode_attention_pallas(
                             q, kc, vc, LAYER, pt, kv_len, PS, inner=inner)
                     operands = (kc, vc, pt, kv_len)
                 else:
                     def fn(q, kc, vc, pt, qs, ql, kl):
-                        return ragged_paged_attention_pallas(
+                        return ragged_attention.ragged_paged_attention_pallas(
                             q, kc, vc, LAYER, pt, qs, ql, kl, PS)
                     operands = (kc, vc, pt, q_start, q_len, kv_len)
-                out = fn(q, *operands)
-                diff = np.abs(np.asarray(out, np.float32)
-                              - np.asarray(ref, np.float32))
-                print(json.dumps({
+                row = {
                     "shape": [H, Hk, hd], "traffic": traffic, "tokens": T,
-                    "inner": inner, "pv_terms": terms
-                    if terms is not None else kv_contract.PV_TERMS,
-                    "ms_a_launch": round(timed(fn, q, *operands) * 1e3, 4),
-                    "max_abs_diff_vs_jnp": float(diff.max()),
-                    "finite": bool(np.isfinite(np.asarray(
-                        out, np.float32)).all()),
-                }), flush=True)
-                if terms is not None:
-                    kv_contract.PV_TERMS = default_terms
+                    "context": context or "200-380",
+                    "inner": built.name,
+                    "set": {f"{m.__name__.rsplit('.', 1)[1]}.{a}": v
+                            for (m, a), v in consts.items()}}
+                try:
+                    out = np.asarray(fn(q, *operands), np.float32)
+                    ms = timed(fn, q, *operands) * 1e3
+                    row.update({
+                        "ms_a_launch": round(ms, 4),
+                        "pages_live": sum(pages),
+                        "pages_read": sum(-(-n // bp) * bp for n in pages),
+                        "us_a_seq_block": round(ms * 1e3 / blocks, 4),
+                        "max_abs_diff_vs_jnp": float(np.abs(
+                            out - np.asarray(ref, np.float32)).max()),
+                        "finite": bool(np.isfinite(out).all()),
+                    })
+                except Exception as e:  # noqa: BLE001 — a variant the
+                    # compiler refuses is a row, not the end of the run
+                    row["error"] = str(e)[:300]
+                print(json.dumps(row), flush=True)
+                for (mod, attr), value in was.items():
+                    setattr(mod, attr, value)
+                if consts:
                     jax.clear_caches()
     return 0
 

@@ -113,16 +113,18 @@ def test_ragged_kernel_compiles_under_a_4way_tensor_shard_map(v5e):
 # 128-aligned lane offset by the lane-tile loop of
 # ops/pallas/kv_contract.py; at 64 (LFM2) two heads share a tile. Both that
 # loop and the ragged kernel's successor walk are loops in the program, so
-# this is where Mosaic's verdict on them is asked. One chip sees the first
-# and the last two whole and Qwen3-8B as its tp=4 cell shards it; the 4-way
-# shard_map cuts each by four (Qwen2.5 to ONE kv head of group 7, Qwen3-8B
-# to (8, 2, 128), LFM2 to one tile of two heads, OLMoE to four heads of
-# group 1: mxu in the ragged kernel, vpu in the decode kernel).
+# this is where Mosaic's verdict on them is asked. One chip sees the first,
+# the last three whole (Olmo-Hybrid's 30 heads of group 1 among them: 30
+# lane tiles, the widest block a kernel buffers) and Qwen3-8B as its tp=4
+# cell shards it; the 4-way shard_map cuts each by four (Qwen2.5 to ONE kv
+# head of group 7, Qwen3-8B to (8, 2, 128), LFM2 to one tile of two heads,
+# OLMoE to four heads of group 1: mxu in the ragged kernel, vpu in the
+# decode kernel; 30 heads do not divide by four, and its cell has one chip).
 @pytest.mark.parametrize("compile_fn", [_compile_ragged, _compile_decode],
                          ids=["ragged", "decode"])
 @pytest.mark.parametrize("tp,heads", [
     (1, (28, 4, 128)), (1, (8, 2, 128)), (1, (16, 16, 128)),
-    (1, (32, 8, 64)),
+    (1, (32, 8, 64)), (1, (30, 30, 128)),
     (4, (28, 4, 128)), (4, (32, 8, 128)), (4, (16, 16, 128)),
     (4, (32, 8, 64))],
     ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else f"tp{v}")

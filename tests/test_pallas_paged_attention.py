@@ -34,15 +34,28 @@ SMALL_CASES = [dict(B=3, H=8, Hk=4, hd=32, PS_=8, MP=6, seq_lens=sl)
                for sl in ([20, 9, 37], [1, 48, 16])]
 # The published head shapes (H, Hk, hd) at the engine's page size, cut only
 # in count of pages: Qwen2.5-7B, Qwen3-8B as one tp=4 shard sees it,
-# LFM2-8B-A1B / llama3.2 (two heads a lane tile), OLMoE (group 1: one
-# row-head a kv head, the Vpu inner product). Eight rows from eight
-# sequences: a context of ONE token; ends inside a page, on a page edge,
-# on and just past the 128-token block edge; 6 and 7 pages (no multiple of
-# a block's four).
-HEAD_SHAPES = [(28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128)]
+# LFM2-8B-A1B / llama3.2 (two heads a lane tile), OLMoE and Olmo-Hybrid
+# (group 1: one row-head a kv head, the Vpu inner product). Eight rows from
+# eight sequences: a context of ONE token; ends inside a page, on a page
+# edge, on and just past the 128-token block edge; 6 and 7 pages (no
+# multiple of a block's four).
+HEAD_SHAPES = [(28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
+               (30, 30, 128)]
 PUBLISHED_CASES = [
     dict(B=8, H=H, Hk=Hk, hd=hd, PS_=32, MP=8, seed=H,
          seq_lens=[1, 33, 128, 129, 163, 200, 64, 100])
+    for H, Hk, hd in HEAD_SHAPES]
+# The page stream's unit is the block of four pages (kv_contract.py): one
+# predicate a block, every page of it copied, the trash page where the
+# table's padding begins. Whole pages, so that the last block is all that
+# differs: a row of length 0 between live rows (no block: nothing starts,
+# nothing is waited for), contexts that end 1, 2 and 3 pages into their
+# first and their second block and exactly on both block edges — the last
+# a row whose table is full (no padding left to read). SMALL_CASES' table
+# of 6 pages is no multiple of a block: the wrapper pads it.
+BLOCK_CASES = [
+    dict(B=10, H=H, Hk=Hk, hd=hd, PS_=32, MP=8, seed=H + 1,
+         seq_lens=[32, 0, 64, 96, 128, 160, 192, 224, 256, 0])
     for H, Hk, hd in HEAD_SHAPES]
 # q and the pool in bf16 against the float32 twin fed the same bf16
 # values. The kernel keeps float32 everywhere (exact bf16 products, f32
@@ -51,10 +64,9 @@ PUBLISHED_CASES = [
 # of the output to bf16's 8 significant bits: half of a spacing of 2**-7
 # just above a power of two, 2**-8 relative.
 PUBLISHED_CASES.append(dict(PUBLISHED_CASES[0], dtype=jnp.bfloat16))
+BLOCK_CASES.append(dict(BLOCK_CASES[0], dtype=jnp.bfloat16))
 F32_TOL = dict(rtol=2e-5, atol=2e-5)
 BF16_TOL = dict(rtol=2 ** -8 + 4e-5, atol=3e-5)
-
-
 def _id(case):
     return "H{H}-Hk{Hk}-hd{hd}-".format(**case) + (
         "bf16" if "dtype" in case else "x".join(map(str, case["seq_lens"])))
@@ -65,16 +77,25 @@ def _f32(x):
 
 
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("case", SMALL_CASES + PUBLISHED_CASES, ids=_id)
-def test_pallas_matches_reference(case, layer):
+@pytest.mark.parametrize("case", SMALL_CASES + PUBLISHED_CASES + BLOCK_CASES,
+                         ids=_id)
+def test_pallas_matches_reference(case, layer, poison_trash_page):
     q, k, v, pt, sl = _case(**case)
     ps = case["PS_"]
     ref = paged_decode_attention(_f32(q), _f32(k), _f32(v), layer, pt, sl, ps)
-    out = paged_decode_attention_pallas(q, k, v, layer, pt, sl, ps,
-                                        interpret=True)
+    clean = paged_decode_attention_pallas(q, k, v, layer, pt, sl, ps,
+                                          interpret=True)
+    # The kernel reads the trash page where a block runs past a row's last
+    # page; the reference reads the clean pool (conftest.py).
+    out = paged_decode_attention_pallas(
+        q, poison_trash_page(k, ps, layer), poison_trash_page(v, ps, layer),
+        layer, pt, sl, ps, interpret=True)
     assert out.dtype == q.dtype
+    live = np.asarray(sl) > 0  # a row of no context has no defined output
+    np.testing.assert_array_equal(np.asarray(_f32(out))[live],
+                                  np.asarray(_f32(clean))[live])
     np.testing.assert_allclose(
-        np.asarray(_f32(out)), np.asarray(ref),
+        np.asarray(_f32(out))[live], np.asarray(ref)[live],
         **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
 
 

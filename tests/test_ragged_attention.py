@@ -66,17 +66,33 @@ MIXED_CASES = [
 
 # The published head shapes (H, Hk, hd) at the engine's page size, cut only
 # in count of pages: Qwen2.5-7B, Qwen3-8B as one tp=4 shard sees it,
-# LFM2-8B-A1B / llama3.2 (two heads a lane tile), OLMoE (group 1). Tile 0 is eight decode rows from eight sequences — a
+# LFM2-8B-A1B / llama3.2 (two heads a lane tile), OLMoE and Olmo-Hybrid
+# (group 1). Tile 0 is eight decode rows from eight sequences — a
 # context of ONE token; ends inside a page, on a page edge, on and just
 # past the 128-token block edge; 6 and 7 pages (no multiple of a block's
 # four). Then a prefill span over a cached prefix that crosses two tile
 # edges and the block edge, and a decode row sharing its last tile.
-HEAD_SHAPES = [(28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128)]
+HEAD_SHAPES = [(28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
+               (30, 30, 128)]
 _PUBLISHED = dict(
     spans=[(1, 1), (1, 33), (1, 128), (1, 129), (1, 163), (1, 200),
            (1, 64), (1, 100), (19, 140), (1, 97)], B=12, PS=32, MP=8)
 PUBLISHED_CASES = [dict(_PUBLISHED, H=H, Hk=Hk, hd=hd, seed=H)
                    for H, Hk, hd in HEAD_SHAPES]
+# The page stream's unit is the block of four pages (kv_contract.py): one
+# predicate a block, every page of it copied, the trash page where the
+# table's padding begins. Whole pages, so that the last block is all that
+# differs: decode rows whose contexts end 1, 2 and 3 pages into their
+# first and their second block and exactly on both block edges — the
+# last a row whose table is full (no padding left to read) — and a
+# prefill span whose tiles' causal frontiers end 1, 2, 3 and 4 pages into
+# a block. Two rows of length 0 trail them (B = 11). MIXED_CASES' pages
+# of 8 tokens put four pages in a block of 32.
+_BLOCKS = dict(
+    spans=[(1, 32), (1, 64), (1, 96), (1, 128), (1, 160), (1, 192),
+           (1, 224), (1, 256), (128, 128)], B=11, PS=32, MP=8)
+BLOCK_CASES = [dict(_BLOCKS, H=H, Hk=Hk, hd=hd, seed=H + 1)
+               for H, Hk, hd in HEAD_SHAPES]
 # q and the pool in bf16 against the float32 twin fed the same bf16
 # values. The kernel keeps float32 everywhere (exact bf16 products, f32
 # accumulation and softmax, P into P·V to float32's last bit), so what
@@ -84,15 +100,15 @@ PUBLISHED_CASES = [dict(_PUBLISHED, H=H, Hk=Hk, hd=hd, seed=H)
 # of the output to bf16's 8 significant bits: half of a spacing of 2**-7
 # just above a power of two, 2**-8 relative.
 PUBLISHED_CASES.append(dict(PUBLISHED_CASES[0], dtype=jnp.bfloat16))
+BLOCK_CASES.append(dict(BLOCK_CASES[0], dtype=jnp.bfloat16))
 F32_TOL = dict(rtol=2e-5, atol=2e-5)
 BF16_TOL = dict(rtol=2 ** -8 + 4e-5, atol=3e-5)
-
-
 def _id(case):
     if "hd" not in case:
         return "mixed" + str(MIXED_CASES.index(case))
     return "H{H}-Hk{Hk}-hd{hd}".format(**case) + (
-        "-bf16" if "dtype" in case else "")
+        "-bf16" if "dtype" in case else "") + (
+        "-blocks" if case["spans"] is _BLOCKS["spans"] else "")
 
 
 @pytest.mark.parametrize("case", MIXED_CASES)
@@ -111,14 +127,22 @@ def _f32(x):
 
 
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("case", MIXED_CASES + PUBLISHED_CASES, ids=_id)
-def test_pallas_matches_reference(case, layer):
+@pytest.mark.parametrize("case", MIXED_CASES + PUBLISHED_CASES + BLOCK_CASES,
+                         ids=_id)
+def test_pallas_matches_reference(case, layer, poison_trash_page):
     q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(**case)
     ref = ragged_paged_attention(_f32(q), _f32(k), _f32(v), layer, pt,
                                  tok_seq, tok_pos, kv_len, PS)
-    out = ragged_paged_attention_pallas(q, k, v, layer, pt, qs, ql, kv_len,
-                                        PS, interpret=True)
+    clean = ragged_paged_attention_pallas(q, k, v, layer, pt, qs, ql,
+                                          kv_len, PS, interpret=True)
+    # The kernel reads the trash page where a block runs past a walk's
+    # last page; the reference reads the clean pool (conftest.py).
+    out = ragged_paged_attention_pallas(
+        q, poison_trash_page(k, PS, layer), poison_trash_page(v, PS, layer),
+        layer, pt, qs, ql, kv_len, PS, interpret=True)
     assert out.dtype == q.dtype
+    np.testing.assert_array_equal(np.asarray(_f32(out)),
+                                  np.asarray(_f32(clean)))
     np.testing.assert_allclose(
         np.asarray(_f32(out)), np.asarray(ref),
         **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
@@ -135,19 +159,26 @@ def test_pallas_mqa_and_group1(Hk, H):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **F32_TOL)
 
 
-def _kernel_eqns(H, Hk, hd, T=64, B=64, PS=32, MP=8, top=False):
-    """Every equation of the ragged kernel's traced body at one head
-    shape, sub-jaxprs (loops, `pl.when` branches) included unless `top`.
-    Shapes only: nothing runs."""
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for v in eqn.params.values():
-                for x in v if isinstance(v, (tuple, list)) else (v,):
-                    x = getattr(x, "jaxpr", x)
-                    if hasattr(x, "eqns"):
-                        yield from walk(x)
+def _inside(eqn):
+    """Every equation of an equation's sub-jaxprs (a loop's body, a
+    `pl.when`'s branches), nested ones included."""
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield from _walk(x)
 
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        yield from _inside(eqn)
+
+
+def _kernel_jaxpr(H, Hk, hd, T=64, B=64, PS=32, MP=8):
+    """The ragged kernel's traced body at one head shape. Shapes only:
+    nothing runs."""
     def s(*shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt)
 
@@ -156,11 +187,14 @@ def _kernel_eqns(H, Hk, hd, T=64, B=64, PS=32, MP=8, top=False):
         lambda *a: ragged_paged_attention_pallas(*a, PS))(
             s(T, H, hd, dt=jnp.bfloat16), pool, pool, s(), s(B, MP), s(B),
             s(B), s(B))
-    calls = [e for e in walk(closed.jaxpr)
+    calls = [e for e in _walk(closed.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 1  # one Mosaic call a layer a pass
-    kernel = calls[0].params["jaxpr"]
-    return list(kernel.eqns if top else walk(kernel))
+    return calls[0].params["jaxpr"]
+
+
+def _count(eqns, name):
+    return sum(e.primitive.name == name for e in eqns)
 
 
 def test_traced_body_does_not_grow_with_tile_height_times_kv_heads():
@@ -173,22 +207,57 @@ def test_traced_body_does_not_grow_with_tile_height_times_kv_heads():
     equations, 256 `dot_general`s, where PR 32's VPU body had 4378). The
     walk is a loop in the program now, so the body holds one copy a lane
     tile — a Q.K^T and a P.V contraction each — and nothing a successor."""
-    few = _kernel_eqns(28, 4, 128)     # 4 lane tiles
-    many = _kernel_eqns(16, 16, 128)   # 16
-    packed = _kernel_eqns(32, 8, 64)   # 4, two heads each
-
-    def dots(eqns):
-        return sum(e.primitive.name == "dot_general" for e in eqns)
-
-    assert (dots(few), dots(many), dots(packed)) == (8, 32, 8)
+    few, many, packed, widest = (
+        list(_walk(_kernel_jaxpr(*shape))) for shape in
+        ((28, 4, 128),     # 4 lane tiles
+         (16, 16, 128),    # 16
+         (32, 8, 64),      # 4, two heads each
+         (30, 30, 128)))   # 30
+    assert [_count(e, "dot_general") for e in (few, many, packed, widest)
+            ] == [8, 32, 8, 60]
     # Four times the kv heads, under three times the body; and an eighth
-    # of PR 33's: 1146 equations when written (450 at 4 tiles).
+    # of PR 33's. What the body measures when written (PR 38): 449, 1145,
+    # 449 and 1957 equations — 58 a lane tile and 217 of everything else,
+    # none of them a nested jit (`kv_contract.py`: scalars are `lax`
+    # calls, because every operator on a tracer is one).
     assert len(many) <= 3 * len(few)
-    assert len(many) <= 1300 and len(few) <= 520 and len(packed) <= 520
-    # The successor walk is the kernel's one dynamic-trip loop at top
-    # level; inside it, a sequence's blocks.
-    top = [e.primitive.name for e in _kernel_eqns(16, 16, 128, top=True)]
+    assert len(few) <= 470 and len(packed) <= 470
+    assert len(many) <= 1200 and len(widest) <= 2050
+    assert _count(few, "pjit") == 0
+
+
+def test_page_stream_has_one_predicate_a_block():
+    """The unit of the page stream is the block (kv_contract.PageStream):
+    a block's pages start under ONE `pl.when` and are waited for once a
+    pool under none — a predicate a page, or a page-table read in the
+    wait, cannot come back unseen. The walk over a tile's sequences is
+    the kernel's one loop at top level; in it, what a short walk starts
+    for its successor before its loop, under one predicate that a walk of
+    two blocks or more skips as a whole, and the loop over the walk's
+    blocks."""
+    kernel = _kernel_jaxpr(28, 4, 128)
+    top = [e.primitive.name for e in kernel.eqns]
     assert top.count("while") == 1 and "scan" not in top
+    assert "cond" not in top  # nothing at top level is skipped
+    walk = next(e for e in kernel.eqns if e.primitive.name == "while")
+    walk_body = walk.params["body_jaxpr"].jaxpr
+    names = [e.primitive.name for e in walk_body.eqns]
+    assert names.count("while") == 1 and names.count("cond") == 1
+    blocks = next(e for e in walk_body.eqns if e.primitive.name == "while")
+    in_loop = list(_walk(blocks.params["body_jaxpr"].jaxpr))
+    # One block of four pages, two pools: the refill under its one
+    # predicate, the wait once a pool under none.
+    assert _count(in_loop, "cond") == 1
+    assert _count(in_loop, "dma_start") == 8
+    assert _count(in_loop, "dma_wait") == 2
+    waits = [e for e in blocks.params["body_jaxpr"].jaxpr.eqns
+             if e.primitive.name == "dma_wait"]
+    assert len(waits) == 2  # at the loop's own level: no predicate
+    # Before the loop: the ring's two slots, each under its own test
+    # inside the one predicate.
+    before = next(e for e in walk_body.eqns if e.primitive.name == "cond")
+    assert _count(_inside(before), "cond") == 2
+    assert _count(_inside(before), "dma_start") == 16
 
 
 def test_forward_ragged_matches_bucketed_composition(tiny_cfg, tiny_params):
