@@ -668,6 +668,18 @@ def main(argv=None) -> int:
     if quant_err is not None:
         log.error("%s", quant_err)
         return 2
+    from ollamamq_tpu.config import get_model_config, validate_latent_pool
+
+    for name in (m.strip() for m in args.models.split(",") if m.strip()):
+        served = get_model_config(name)
+        latent_err = served and validate_latent_pool(
+            served, kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype,
+            spec=args.spec, prefix_cache=args.prefix_cache,
+            mesh_shape={"seq": args.sp, "tensor": args.tp,
+                        "expert": args.ep})
+        if latent_err:
+            log.error("%s", latent_err)
+            return 2
     if args.fault_plan:
         # Schema-check the plan BEFORE any engine/device work: a typo'd
         # chaos plan must fail the process at startup, not mid-traffic.

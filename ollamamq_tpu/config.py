@@ -17,6 +17,7 @@ BASELINE.json's configs list.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 
@@ -28,6 +29,9 @@ STATE_KINDS = (CONV, LINEAR)
 DENSE, EXPERTS = "dense", "experts"
 # The longest period `ModelConfig.layer_plan` looks for.
 MAX_PERIOD = 8
+# Keys of a published `rope_scaling` group of type "yarn".
+YARN_KEYS = ("type", "factor", "original_max_position_embeddings",
+             "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,6 +128,60 @@ class ModelConfig:
     # whole: its `rope_theta` (null: no rotary embedding) sets the field of
     # that name; any other key of the group is refused.
     rope_parameters: Optional[object] = None
+    # -- latent attention with a learned sparse selection (DeepSeek-V3.2) ----
+    # `kv_lora_rank` > 0 makes every attention layer multi-head LATENT
+    # attention (ops/mla.py): q through a low-rank projection with an
+    # RMSNorm (`q_lora_rank`), per head a `qk_nope_head_dim` part and a
+    # rotary part of `qk_rope_head_dim`; K and V of a token are ONE normed
+    # row of `kv_lora_rank` lanes (expanded a head by W_uk / W_uv, which the
+    # served path absorbs into q and the output) and ONE rotary key of
+    # `qk_rope_head_dim` lanes for all heads; values are `v_head_dim` wide.
+    # The paged cache then holds that row (`latent_dim` lanes) and no V.
+    # The published spellings, so a configuration file's keys reach them.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The lightning indexer: `index_n_heads` heads of `index_head_dim` score
+    # every cached position of a token's sequence (ReLU, a learned weight a
+    # head, summed), its key one row of `index_head_dim` lanes a token in a
+    # second paged pool; attention sees the `index_topk` best positions (all
+    # of them while the context is shorter). Latent attention is served
+    # with it only.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # The published `rope_scaling` group of a configuration file, taken whole
+    # (null: plain RoPE). Only `type` "yarn" is implemented: the frequencies
+    # of ops/rope.yarn_freqs and a softmax scale times mscale^2.
+    rope_scaling: Optional[object] = None
+    # Group-limited routing: the router's experts in `n_group` equal groups,
+    # a group scored by the sum of its best two (biased) scores, the top k
+    # taken inside the `topk_group` best groups. 0: no groups.
+    n_group: int = 0
+    topk_group: int = 0
+    # Experts every token passes (no gate), of the routed experts' width:
+    # one SwiGLU of `n_shared_experts * expert_width`.
+    n_shared_experts: int = 0
+    # The chip's SHARE of an expert layer (model-configs guide, section 4):
+    # the router scores `router_experts` experts (0: `num_experts`) and keeps
+    # its `num_experts_per_tok`, gates normalised over all of them; this
+    # program HOLDS the `num_experts` experts from `expert_offset` on and adds
+    # what those give. What the absent experts would have added is left out.
+    router_experts: int = 0
+    expert_offset: int = 0
+    # Published keys of the family that have ONE value here; any other is
+    # refused. `first_k_dense_replace` is the published spelling of
+    # `num_dense_layers` (either or both, agreeing); every layer after the
+    # dense ones has experts (`moe_layer_freq` 1); `ep_size` is the published
+    # file's own (1: the share held here is said by the two fields above);
+    # the multi-token-prediction module (`num_nextn_predict_layers`) draws
+    # drafts and is no part of the next-token distribution: not served (0).
+    first_k_dense_replace: Optional[int] = None
+    moe_layer_freq: int = 1
+    ep_size: int = 1
+    num_nextn_predict_layers: int = 0
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head", "full"):
@@ -171,6 +229,37 @@ class ModelConfig:
                     f"{LINEAR!r}: the per-slot conv window has one width")
             if LINEAR in kinds:
                 self._check_linear()
+        if self.first_k_dense_replace is not None:
+            if self.num_dense_layers not in (0, self.first_k_dense_replace):
+                raise ValueError(
+                    f"{self.name}: first_k_dense_replace "
+                    f"{self.first_k_dense_replace} is not num_dense_layers "
+                    f"{self.num_dense_layers}")
+            object.__setattr__(self, "num_dense_layers",
+                               self.first_k_dense_replace)
+        for key, only in (("moe_layer_freq", 1), ("ep_size", 1),
+                          ("num_nextn_predict_layers", 0)):
+            if getattr(self, key) != only:
+                raise ValueError(
+                    f"{self.name}: {key} {getattr(self, key)}: the program "
+                    f"implements only {only}")
+        if self.rope_scaling is not None:
+            group = dict(self.rope_scaling)  # a file's dict: hashable
+            if group.get("type") != "yarn":
+                raise ValueError(
+                    f"{self.name}: rope_scaling type {group.get('type')!r}: "
+                    "the program implements only 'yarn'")
+            unknown = sorted(set(group) - set(YARN_KEYS))
+            if unknown or "factor" not in group or \
+                    "original_max_position_embeddings" not in group:
+                raise ValueError(
+                    f"{self.name}: rope_scaling (yarn) takes {YARN_KEYS} "
+                    f"with 'factor' and 'original_max_position_embeddings'; "
+                    f"got {sorted(group)}")
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(group.items())))
+        self._check_latent()
+        self._check_share()
         if not 0 <= self.num_dense_layers <= self.num_layers:
             raise ValueError(
                 f"{self.name}: num_dense_layers {self.num_dense_layers} is "
@@ -183,6 +272,63 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: conv_bias true: the program's convolution "
                 "layers carry no bias")
+
+    def _check_latent(self) -> None:
+        """What latent attention and its indexer cannot run with."""
+        widths = (self.q_lora_rank, self.qk_nope_head_dim,
+                  self.qk_rope_head_dim, self.v_head_dim)
+        if not self.kv_lora_rank:
+            if any(widths) or self.index_topk or self.index_n_heads:
+                raise ValueError(
+                    f"{self.name}: q_lora_rank, qk_*_head_dim, v_head_dim "
+                    "and index_* belong to latent attention: kv_lora_rank "
+                    "is 0")
+            return
+        if min(widths) < 1 or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                f"{self.name}: latent attention needs q_lora_rank, "
+                "qk_nope_head_dim, v_head_dim of at least 1 and an even "
+                f"qk_rope_head_dim; got {widths}")
+        if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
+            raise ValueError(
+                f"{self.name}: head_dim {self.head_dim} is not "
+                f"qk_nope_head_dim + qk_rope_head_dim")
+        if self.num_kv_heads != self.num_heads or self.attn_bias \
+                or self.qk_norm or self.is_encoder or self.layer_types \
+                or self.norm_order != "pre" or self.rope_theta is None:
+            raise ValueError(
+                f"{self.name}: latent attention is served with as many kv "
+                "heads as heads, no attention bias, no q/k head norm, "
+                "pre-norm, a rotary embedding and attention in every layer")
+        has = (self.index_n_heads, self.index_head_dim, self.index_topk)
+        if min(has) < 1 or self.index_head_dim < self.qk_rope_head_dim:
+            raise ValueError(
+                f"{self.name}: latent attention is served with its indexer: "
+                "index_n_heads, index_topk of at least 1 and index_head_dim "
+                f"of at least qk_rope_head_dim; got {has}")
+
+    def _check_share(self) -> None:
+        """Group-limited routing, shared experts and the share held here."""
+        R, E = self.router_width, self.num_experts
+        if (self.n_group or self.n_shared_experts or self.router_experts
+                or self.expert_offset) and not E:
+            raise ValueError(
+                f"{self.name}: n_group, n_shared_experts, router_experts "
+                "and expert_offset belong to an expert layer: num_experts "
+                "is 0")
+        if self.n_group:
+            if R % self.n_group or not 1 <= self.topk_group <= self.n_group \
+                    or self.topk_group * (R // self.n_group) \
+                    < self.num_experts_per_tok or R // self.n_group < 2:
+                raise ValueError(
+                    f"{self.name}: n_group {self.n_group} / topk_group "
+                    f"{self.topk_group} do not divide the router's {R} "
+                    f"experts into groups that hold the top "
+                    f"{self.num_experts_per_tok}")
+        if E and not 0 <= self.expert_offset <= R - E:
+            raise ValueError(
+                f"{self.name}: expert_offset {self.expert_offset}: the "
+                f"{E} experts held here are not within the router's {R}")
 
     def _check_linear(self) -> None:
         """What the linear-attention layers cannot run with, key and value
@@ -223,6 +369,47 @@ class ModelConfig:
     @property
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores (`num_experts` of them are held here)."""
+        return self.router_experts or self.num_experts
+
+    @property
+    def latent_dim(self) -> int:
+        """Lanes of a token's row of the latent pool: [c_kv | k_rope]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """...and the lanes that row occupies: whole tiles of 128. The
+        TPU's tiled layout pads the pool's last axis to that whatever its
+        shape says, and a kernel cannot copy part of a lane tile out of
+        HBM, so the pool states the lanes it holds (zeros past
+        `latent_dim`)."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def kv_row_dims(self) -> tuple:
+        """Lanes a token a layer in each of the two paged pools: K and V
+        rows, or with latent attention the latent row and the index key."""
+        if self.kv_lora_rank:
+            return self.latent_lanes, self.index_head_dim
+        return self.kv_dim, self.kv_dim
+
+    @property
+    def yarn(self) -> Optional[dict]:
+        return dict(self.rope_scaling) if self.rope_scaling else None
+
+    @property
+    def attn_scale(self) -> float:
+        """The softmax scale: head_dim^-1/2, times YaRN's mscale^2."""
+        scale = self.head_dim ** -0.5
+        y = self.yarn
+        if y and y["factor"] > 1:
+            m = 0.1 * y.get("mscale_all_dim", 0) * math.log(y["factor"]) + 1.0
+            scale *= m * m
+        return scale
 
     def layer_plan(self) -> tuple:
         """The stack as runs of a repeated period: ((first layer, period,
@@ -289,9 +476,19 @@ class ModelConfig:
         the parameters a token touches (the routed experts of the k, not
         the bank: what the FLOPs model counts)."""
         d, v = self.hidden_size, self.vocab_size
+        attention = (d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+                     + self.qk_norm_params())
+        if self.kv_lora_rank:
+            H, r, c = self.num_heads, self.q_lora_rank, self.kv_lora_rank
+            attention = (
+                d * r + r + r * self.q_dim + d * self.latent_dim + c
+                + c * H * (self.qk_nope_head_dim + self.v_head_dim)
+                + H * self.v_head_dim * d
+                # the indexer: q, k with its LayerNorm, the head weights
+                + r * self.index_n_heads * self.index_head_dim
+                + (d + 2) * self.index_head_dim + d * self.index_n_heads)
         per_op = {
-            ATTENTION: (d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-                        + self.qk_norm_params()),
+            ATTENTION: attention,
             CONV: 3 * d * d + d * d + d * self.conv_L_cache,
             # q | k | v | z and the two gates in, the taps, A_log and
             # dt_bias, the output norm, out.
@@ -305,9 +502,9 @@ class ModelConfig:
         n_experts = self.num_experts_per_tok if active else self.num_experts
         per_ffn = {
             DENSE: 3 * d * self.intermediate_size,
-            EXPERTS: (n_experts * 3 * d * self.expert_width
-                      + d * self.num_experts
-                      + self.num_experts * self.use_expert_bias),
+            EXPERTS: ((n_experts + self.n_shared_experts) * 3 * d
+                      * self.expert_width + d * self.router_width
+                      + self.router_width * self.use_expert_bias),
         }
         layers = sum(per_op[op] + per_ffn[ffn] + 2 * d
                      for op, ffn in self.kinds)
@@ -487,6 +684,26 @@ MODEL_CONFIGS = {
         linear_allow_neg_eigval=True,
         layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2
         + ("linear_attention",) * 2,
+    ),
+    # Tiny DeepSeek-V3.2: latent attention with the indexer's selection (top
+    # 16: well under the tests' contexts), YaRN, a dense layer then expert
+    # layers with a shared expert, group-limited sigmoid routing over 16
+    # experts of which this program holds 4 (one share of four).
+    "test-tiny-deepseek-v32": ModelConfig(
+        name="test-tiny-deepseek-v32", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=24, rope_theta=10_000.0, rms_norm_eps=1e-6, max_seq_len=512,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, index_n_heads=4,
+        index_head_dim=16, index_topk=16,
+        rope_scaling={"type": "yarn", "factor": 4,
+                      "original_max_position_embeddings": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1},
+        num_experts=4, router_experts=16, expert_offset=0,
+        num_experts_per_tok=4, n_group=4, topk_group=2, n_shared_experts=1,
+        moe_intermediate_size=32, first_k_dense_replace=1,
+        router_score="sigmoid", use_expert_bias=True, norm_topk_prob=True,
+        norm_topk_eps=1e-20, routed_scaling_factor=2.5,
     ),
 }
 
@@ -981,6 +1198,42 @@ def validate_slot_state(cfg: ModelConfig, spec: bool = False,
         return None
     return (f"model {cfg.name} has {' and '.join(held)} layers "
             f"(layer_types) and cannot be served with {why}")
+
+
+def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",
+                         weights_dtype: str = "bfloat16", spec: bool = False,
+                         prefix_cache: bool = False,
+                         mesh_shape=None) -> Optional[str]:
+    """What a model with latent attention (`kv_lora_rank`: a latent pool
+    and an index-key pool where K and V were) cannot be served with yet,
+    told BEFORE any device work: returns an error string (None = valid).
+    Each of these knows K and V pools of kv_heads x head_dim lanes only
+    (ROADMAP B-M3 names what each lacks)."""
+    if not cfg.kv_lora_rank:
+        return None
+    shape = dict(mesh_shape or {})
+    why = None
+    if kv_dtype != "bfloat16":
+        why = ("--kv-dtype int8: the page writer's scales are one a kv head "
+               "and a latent row has no heads")
+    elif weights_dtype != "bfloat16":
+        why = ("--weights-dtype int8: the low-rank projections are "
+               "absorbed into q and the output in bfloat16")
+    elif spec:
+        why = ("--spec: the verify span reads a logit a draft position "
+               "through K and V pools")
+    elif prefix_cache:
+        why = ("--prefix-cache: the radix tree shares K and V pages, not "
+               "latent and index-key pages")
+    elif shape.get("seq", 1) > 1:
+        why = "--sp: the ring prefill scatters K and V of every head"
+    elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
+        why = ("--tp / --ep: the latent and index-key pools and the "
+               "low-rank projections have no partition specs")
+    if why is None:
+        return None
+    return (f"model {cfg.name} has latent attention (kv_lora_rank) and "
+            f"cannot be served with {why}")
 
 
 def validate_quant_config(weights_dtype: str, kv_dtype: str,

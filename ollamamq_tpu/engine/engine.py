@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, LINEAR,
                                  STATE_KINDS, EngineConfig, ModelConfig,
                                  get_model_config, smart_match,
-                                 validate_quant_config, validate_slot_state)
+                                 validate_latent_pool, validate_quant_config,
+                                 validate_slot_state)
 from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
@@ -477,6 +478,10 @@ class ModelRuntime:
     # output-length prediction.
     policy = None
 
+    # What `_note_latent` writes onto a step's sample, in order.
+    LATENT_FIELDS = ("mla_rows", "dsa_ctx_tokens", "dsa_selected_tokens",
+                     "dsa_step_ctx_tokens", "dsa_step_selected_tokens")
+
     # Engine performance plane (telemetry/stepprof.py): the per-step
     # "paid a compile" flag (_sp_note_compile sets, the step's finish
     # read-and-clears). A step's timer rides its StepInFlight handle.
@@ -518,6 +523,13 @@ class ModelRuntime:
             raise ValueError(err)
         err = validate_slot_state(
             model_cfg, spec=engine_cfg.spec,
+            mesh_shape=dict(mesh.shape) if mesh is not None else {})
+        if err is not None:
+            raise ValueError(err)
+        err = validate_latent_pool(
+            model_cfg, kv_dtype=engine_cfg.kv_dtype,
+            weights_dtype=engine_cfg.weights_dtype, spec=engine_cfg.spec,
+            prefix_cache=engine_cfg.prefix_cache,
             mesh_shape=dict(mesh.shape) if mesh is not None else {})
         if err is not None:
             raise ValueError(err)
@@ -827,6 +839,18 @@ class ModelRuntime:
         self._tm_lin = [c.labels(model=name) for c in (
             tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
             tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL)]
+        # Latent attention: the two pools' sizes (both are in kv_bytes) and
+        # what the indexer scored and attention saw.
+        if model_cfg.kv_lora_rank:
+            for gauge, pool in ((tm.HBM_LATENT_POOL_BYTES, self.kc),
+                                (tm.HBM_INDEX_POOL_BYTES, self.vc)):
+                gauge.labels(model=name).set(pool.nbytes)
+            log.info("%s: latent pool %s %.1f MB, index-key pool %s %.1f MB",
+                     name, self.kc.shape, self.kc.nbytes / 1e6,
+                     self.vc.shape, self.vc.nbytes / 1e6)
+        self._tm_dsa = [c.labels(model=name) for c in (
+            tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
+            tm.DSA_SELECTED_TOKENS_TOTAL)]
 
     # -- capacity ----------------------------------------------------------
     def free_slots(self) -> int:
@@ -1142,6 +1166,33 @@ class ModelRuntime:
                      lin_step_rows=step_rows, lin_span_tokens=span_tokens)
             for series, n in zip(self._tm_lin, counts):
                 series.inc(n)
+
+    def _note_latent(self, _sp, spans, scan: bool = False) -> None:
+        """A launched step's latent attention, onto its sample and the
+        /metrics series, from its composition alone: `spans` is (tokens,
+        context at the span's end) a row — a ragged step's spans, or with
+        `scan` a fused scan's active slots with its passes as tokens.
+        `mla_rows` the query tokens, `dsa_ctx_tokens` the cached positions
+        the indexer scored for them (a token at position p scores p + 1),
+        `dsa_selected_tokens` those attention then saw (min(p + 1,
+        index_topk)), and `dsa_step_ctx_tokens` / `dsa_step_selected_tokens`
+        the part of each that ONE-TOKEN rows account for (a decode row, a
+        scan's pass: rows that share their cached positions with no other
+        query of the launch); a layer's worth — every layer does the same.
+        Nothing for a model without latent attention."""
+        if not self.cfg.kv_lora_rank:
+            return
+        counts = np.zeros(5, np.int64)
+        for n, kv in spans:
+            ctx = np.arange(kv - n + 1, kv + 1)
+            both = (int(ctx.sum()),
+                    int(np.minimum(ctx, self.cfg.index_topk).sum()))
+            counts[:3] += (n,) + both
+            if scan or n == 1:
+                counts[3:] += both
+        _sp.note(**dict(zip(self.LATENT_FIELDS, counts.tolist())))
+        for series, n in zip(self._tm_dsa, counts[:3].tolist()):
+            series.inc(n)
 
     def _dispatch_decode(self, k_steps, buf):
         """`buf`: the scan's packed host inputs (step_pack.decode_layout)."""
@@ -1640,10 +1691,11 @@ class ModelRuntime:
         recompute — only written decode state is worth shipping). The
         detached slot keeps its pages (reserved, undispatchable) until
         release_export resolves the two-phase handoff."""
-        if self.slot_state is not None:
+        if self.slot_state is not None or self.cfg.kv_lora_rank:
             # The blob has no place for the per-slot state, and pages
-            # without it resume another sequence: not exportable (the
-            # caller's fallback replays the request from its tokens).
+            # without it resume another sequence; nor for latent and
+            # index-key pages: not exportable (the caller's fallback
+            # replays the request from its tokens).
             return None
         for slot, req in enumerate(self.slot_req):
             if req is not None and req.req_id == rid:
@@ -1710,6 +1762,10 @@ class ModelRuntime:
                 f"{self.name}: a migrated stream carries KV pages, not the "
                 f"{' / '.join(k for k in STATE_KINDS if self.cfg.count(k))} "
                 "layers' state; replay the request instead")
+        if self.cfg.kv_lora_rank:
+            raise MigrationError(
+                f"{self.name}: a migrated stream carries K and V pages, not "
+                "latent and index-key pages; replay the request instead")
         if (blob.get("kind") != "stream"
                 or int(blob.get("page_size", -1)) != self.ecfg.page_size
                 or blob.get("kv_dtype") != self.kv_dtype
@@ -2614,6 +2670,8 @@ class ModelRuntime:
         self._note_slot_state(_sp, opened, len(rows) - opened,
                               sum(n == 1 for n in spans),
                               sum(n for n in spans if n > 1))
+        self._note_latent(_sp, zip(q_len[:len(rows)].tolist(),
+                                   kv_len[:len(rows)].tolist()))
         _sp.mark("dispatch")
         _sp.park()
 
@@ -2826,6 +2884,8 @@ class ModelRuntime:
         self._note_queued(h)
         self._note_slot_state(_sp, 0, len(active),
                               len(active) * int(k_steps), 0)
+        self._note_latent(_sp, [(int(k_steps), int(self.seq_lens[i])
+                                 + int(k_steps)) for i in active], scan=True)
         _sp.mark("dispatch")
         _sp.park()
         for i in active:

@@ -1,5 +1,11 @@
 """Paged KV cache: device slot pool + host-side page allocator.
 
+(With latent attention, `ModelConfig.kv_lora_rank`, the two arrays are not K
+and V but the LATENT pool, [layers, slots, latent_lanes], and the INDEX-KEY
+pool, [layers, slots, index_head_dim]: two kinds of paged state of different
+row widths under one page table and one allocator, allocated, donated,
+carried and written by page exactly as K and V are — ops/mla.py.)
+
 Device side: two arrays per model, [attention layers, num_pages*page_size,
 kv_heads*head_dim] for K and V (an int8 pool adds [attention layers, slots,
 kv_heads] scale planes; a stack whose `layer_types` holds other operators
@@ -174,6 +180,12 @@ def alloc_kv_pool(
                            out_shardings=sharding)()
         return jnp.full(shp, value, dt)
 
+    if model_cfg.kv_lora_rank:
+        # Latent attention: the latent pool and the index-key pool where K
+        # and V were — same pages, same table, rows of another width each.
+        return tuple(filled(0, shape[:2] + (lanes,), dtype)
+                     for lanes in model_cfg.kv_row_dims)
+
     if kv_dtype == "int8":
         sshape = shape[:2] + (model_cfg.num_kv_heads,)  # [L, S, Hk]
         return tuple(QuantKV(filled(0, shape, jnp.int8),
@@ -186,16 +198,8 @@ def kv_pool_bytes(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                   bytes_per_el=2, kv_dtype: str = "bfloat16") -> int:
     """Planning-time pool size; int8 pools count 1 payload byte plus the
     4-byte fp32 scale each (slot, head) row carries."""
-    per_tok_head = (model_cfg.head_dim + 4 if kv_dtype == "int8"
-                    else model_cfg.head_dim * bytes_per_el)
-    return (
-        2
-        * model_cfg.count(ATTENTION)
-        * engine_cfg.num_pages
-        * engine_cfg.page_size
-        * model_cfg.num_kv_heads
-        * per_tok_head
-    )
+    return engine_cfg.num_pages * kv_page_bytes(
+        model_cfg, engine_cfg.page_size, bytes_per_el, kv_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -332,7 +336,11 @@ def unpack_migration_blob(raw: bytes) -> dict:
 def kv_page_bytes(model_cfg: ModelConfig, page_size: int,
                   bytes_per_el=2, kv_dtype: str = "bfloat16") -> int:
     """Bytes ONE page costs (K and V, all attention layers) — the density math's
-    unit: equal-HBM pool sizing divides a byte budget by this."""
+    unit: equal-HBM pool sizing divides a byte budget by this. With
+    latent attention: the latent rows and the index keys."""
+    if model_cfg.kv_lora_rank:
+        return (model_cfg.count(ATTENTION) * page_size
+                * sum(model_cfg.kv_row_dims) * bytes_per_el)
     per_tok_head = (model_cfg.head_dim + 4 if kv_dtype == "int8"
                     else model_cfg.head_dim * bytes_per_el)
     return (2 * model_cfg.count(ATTENTION) * page_size

@@ -51,8 +51,9 @@ import jax.numpy as jnp
 
 from ollamamq_tpu.config import (ATTENTION, CONV, DENSE, EXPERTS, LINEAR,
                                  ModelConfig)
-from ollamamq_tpu.models.moe import STACKED, init_moe_layer_params, moe_mlp
-from ollamamq_tpu.ops import gated_delta, shortconv
+from ollamamq_tpu.models.moe import (SHARED, STACKED, init_moe_layer_params,
+                                     moe_mlp)
+from ollamamq_tpu.ops import gated_delta, mla, shortconv
 from ollamamq_tpu.ops.attention import (
     causal_attention,
     bidirectional_attention,
@@ -61,7 +62,8 @@ from ollamamq_tpu.ops.attention import (
     ragged_attention_any,
 )
 from ollamamq_tpu.ops.quant import embed_lookup, kv_write, logits_head, qeinsum
-from ollamamq_tpu.ops.rope import apply_rope
+from ollamamq_tpu.ops.rope import (apply_rope, apply_rope_freqs, rope_freqs,
+                                   yarn_cos_scale, yarn_freqs)
 
 # Stage names on the device trace (jax.named_scope: op metadata only, the
 # lowered programs compute the same thing). README's span table lists
@@ -81,6 +83,16 @@ LINEAR_SCOPES = ("lin_in", "lin_conv", "lin_rule", "lin_out")
 # are the ten of one split, as before the family existed).
 CONV_KEY = 0x636F6E76
 LINEAR_KEY = 0x6C696E72
+MLA_KEY = 0x6D6C6174
+# A latent-attention layer's weights (config.py: `kv_lora_rank`), beside
+# `wo`: q down, its norm, q up; kv down to [c_kv | k_rope], c_kv's norm,
+# kv up to a head's [k_nope | v]; the indexer's q (from the normed q
+# latent), k (from the hiddens) with its LayerNorm, and head weights.
+MLA_PARAMS = ("mla_wdq", "mla_q_norm", "mla_wuq", "mla_wdkv", "mla_kv_norm",
+              "mla_wukv", "idx_wq", "idx_wk", "idx_k_norm", "idx_k_bias",
+              "idx_ww")
+# The indexer's LayerNorm (the published module's own: not the stack's).
+INDEX_NORM_EPS = 1e-6
 # Seeded random init of the rule's decay (the published code's): A uniform
 # in [1, 16], the step dt log-uniform in [1e-3, 1e-1], dt_bias its inverse
 # softplus — so that a = exp(-A softplus(x W_a + dt_bias)) neither kills
@@ -90,12 +102,12 @@ LINEAR_A_RANGE, LINEAR_DT_RANGE = (1.0, 16.0), (1e-3, 1e-1)
 # that kind); every other entry of `layers` is stacked over all layers.
 KIND_PARAMS = {
     ATTENTION: ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm",
-                "k_norm"),
+                "k_norm") + MLA_PARAMS,
     CONV: ("conv_in", "conv_w", "conv_out"),
     LINEAR: ("lin_in", "lin_ba", "lin_conv_w", "lin_A_log", "lin_dt_bias",
              "lin_norm", "lin_out"),
     DENSE: ("w_gate", "w_up", "w_down"),
-    EXPERTS: ("w_router", "router_bias") + STACKED,
+    EXPERTS: ("w_router", "router_bias") + SHARED + STACKED,
 }
 
 
@@ -125,7 +137,26 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
 
     layers = {"attn_norm": jnp.ones((L, d), dtype),
               "mlp_norm": jnp.ones((L, d), dtype)}
-    if La:
+    if La and cfg.kv_lora_rank:
+        mk = jax.random.split(jax.random.fold_in(key, MLA_KEY), 8)
+        H, r, c = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        od = H * cfg.v_head_dim
+        Hi, di = cfg.index_n_heads, cfg.index_head_dim
+        layers.update(
+            mla_wdq=w(mk[0], (La, d, r), d),
+            mla_q_norm=jnp.ones((La, r), dtype),
+            mla_wuq=w(mk[1], (La, r, qd), r),
+            mla_wdkv=w(mk[2], (La, d, cfg.latent_dim), d),
+            mla_kv_norm=jnp.ones((La, c), dtype),
+            mla_wukv=w(mk[3], (La, c, H * (cfg.qk_nope_head_dim
+                                           + cfg.v_head_dim)), c),
+            wo=w(mk[4], (La, od, d), od),
+            idx_wq=w(mk[5], (La, r, Hi * di), r),
+            idx_wk=w(mk[6], (La, d, di), d),
+            idx_k_norm=jnp.ones((La, di), dtype),
+            idx_k_bias=jnp.zeros((La, di), dtype),
+            idx_ww=w(mk[7], (La, d, Hi), d))
+    elif La:
         layers.update(
             wq=w(keys[0], (La, d, qd), d), wk=w(keys[1], (La, d, kvd), d),
             wv=w(keys[2], (La, d, kvd), d), wo=w(keys[3], (La, qd, d), qd))
@@ -368,6 +399,75 @@ def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
                        lp["wo"])
 
 
+def _latent_rope(cfg: ModelConfig, x: jnp.ndarray, positions) -> jnp.ndarray:
+    """RoPE (rotate-half, YaRN frequencies where the config scales them)
+    over the FIRST `qk_rope_head_dim` lanes of x [B, T, H, >= that]."""
+    dr = cfg.qk_rope_head_dim
+    yarn = cfg.yarn
+    freqs = yarn_freqs(dr, cfg.rope_theta, yarn) if yarn \
+        else rope_freqs(dr, cfg.rope_theta)
+    rot = apply_rope_freqs(x[..., :dr], positions, freqs,
+                           yarn_cos_scale(yarn) if yarn else 1.0)
+    return rot if x.shape[-1] == dr else jnp.concatenate(
+        [rot, x[..., dr:]], axis=-1)
+
+
+def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
+                         positions: jnp.ndarray, attn_fn) -> jnp.ndarray:
+    """Latent attention with the indexer's selection over normed hiddens h
+    [B, T, D] (ops/mla.py has the mathematics): the projections, norms and
+    RoPE here, in the ABSORBED form; the schedule (the write of the token's
+    two cache rows, the indexer, the selection, the softmax) is the caller's
+    `attn_fn(q_abs [B, T, H, lanes], row [B, T, lanes], (q_idx [B, T, Hi,
+    di], k_idx [B, T, di], w_idx [B, T, Hi] float32)) -> [B, T, H, c]`, the
+    attended latent a head."""
+    B, T, _ = h.shape
+    H, c = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    Hi, di = cfg.index_n_heads, cfg.index_head_dim
+    wukv = lp["mla_wukv"].reshape(c, H, dn + dv)
+    with jax.named_scope("mla_proj"):
+        c_q = rmsnorm(qeinsum("btd,de->bte", h, lp["mla_wdq"]),
+                      lp["mla_q_norm"], cfg.rms_norm_eps)
+        q = qeinsum("btr,re->bte", c_q, lp["mla_wuq"]).reshape(
+            B, T, H, dn + dr)
+        q_rope = _latent_rope(cfg, q[..., dn:], positions)
+        kv = qeinsum("btd,de->bte", h, lp["mla_wdkv"])
+        c_kv = rmsnorm(kv[..., :c], lp["mla_kv_norm"], cfg.rms_norm_eps)
+        k_rope = _latent_rope(cfg, kv[..., None, c:], positions)[..., 0, :]
+        pad = cfg.latent_lanes - cfg.latent_dim
+        row = jnp.concatenate(
+            [c_kv, k_rope, jnp.zeros((B, T, pad), c_kv.dtype)], axis=-1)
+        # Absorbed: q_nope W_uk^T meets c_kv itself; the softmax scale (and
+        # YaRN's mscale^2) rides q.
+        q_lat = jnp.einsum("bthn,chn->bthc", q[..., :dn], wukv[..., :dn],
+                           preferred_element_type=jnp.float32)
+        q_abs = (jnp.concatenate(
+            [q_lat, q_rope.astype(jnp.float32),
+             jnp.zeros((B, T, H, pad), jnp.float32)], axis=-1)
+            * cfg.attn_scale).astype(h.dtype)
+        # The indexer: q from the normed q latent, k from the hiddens
+        # through a LayerNorm, RoPE on the first rope lanes of both, and a
+        # learned weight a head.
+        q_idx = _latent_rope(cfg, qeinsum(
+            "btr,re->bte", c_q, lp["idx_wq"]).reshape(B, T, Hi, di),
+            positions)
+        k_idx = qeinsum("btd,de->bte", h, lp["idx_wk"]).astype(jnp.float32)
+        mean = jnp.mean(k_idx, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(k_idx - mean), axis=-1, keepdims=True)
+        k_idx = ((k_idx - mean) * jax.lax.rsqrt(var + INDEX_NORM_EPS)
+                 ).astype(h.dtype) * lp["idx_k_norm"] + lp["idx_k_bias"]
+        k_idx = _latent_rope(cfg, k_idx[..., None, :], positions)[..., 0, :]
+        w_idx = jnp.einsum("btd,dh->bth", h, lp["idx_ww"],
+                           preferred_element_type=jnp.float32) \
+            * (Hi ** -0.5 * di ** -0.5)
+    o_lat = attn_fn(q_abs, row, (q_idx, k_idx, w_idx))
+    with jax.named_scope("attn_out"):
+        o = jnp.einsum("bthc,chv->bthv", o_lat, wukv[..., dn:],
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+        return qeinsum("bte,ed->btd", o.reshape(B, T, H * dv), lp["wo"])
+
+
 def _conv_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
              taps_fn) -> jnp.ndarray:
     """Gated short convolution over normed hiddens h [B, T, D]:
@@ -443,6 +543,8 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
         delta = _conv_op(cfg, lp, h, taps_fn)
     elif op == LINEAR:
         delta = _linear_attention_op(cfg, lp, h, taps_fn, rule_fn)
+    elif cfg.kv_lora_rank:
+        delta = _latent_attention_op(cfg, lp, h, positions, attn_fn)
     else:
         delta = _attention_op(cfg, lp, h, positions, attn_fn)
     x = x + (delta if pre else norm(delta, "attn_norm"))
@@ -461,6 +563,16 @@ def _no_state(cfg: ModelConfig, valid=None) -> dict:
         "taps_fn": lambda z: shortconv.taps_full(z, cfg.state_window[0]),
         "rule_fn": lambda q, k, v, g, beta: gated_delta.chunked(
             q, k, v, g, beta, valid)[0]}
+
+
+def _causal_fn(cfg: ModelConfig, seq_lens):
+    """`attn_fn` of a forward over whole sequences from position 0: dense
+    causal attention — with latent attention over the latent rows, the
+    selection by the span's own index scores."""
+    if cfg.kv_lora_rank:
+        return lambda q_abs, row, index: mla.dense_attention(
+            q_abs, row, *index, seq_lens, cfg.kv_lora_rank, cfg.index_topk)
+    return lambda q, k, v: causal_attention(q, k, v, seq_lens)
 
 
 def forward_prefill(
@@ -489,9 +601,10 @@ def forward_prefill(
     def body(x, lp, kinds, ix, kc, vc):
         def attn_fn(q, k, v):
             nonlocal kc, vc
-            kc = kv_write(kc, ix.op, slots, k)
-            vc = kv_write(vc, ix.op, slots, v)
-            return causal_attention(q, k, v, seq_lens)
+            kc = kv_write(kc, ix.op, slots, k)  # K, or the latent row
+            # V, or the index key (v: the indexer's q, k and head weights)
+            vc = kv_write(vc, ix.op, slots, v[1] if cfg.kv_lora_rank else v)
+            return _causal_fn(cfg, seq_lens)(q, k, v)
 
         valid = positions < seq_lens[:, None]
         x, load = _layer_step(
@@ -562,6 +675,16 @@ def forward_ragged(
     def body(x, lp, kinds, ix, kc, vc, conv, rule):
         def attn_fn(q, k, v):  # [1, T, H, hd]
             nonlocal kc, vc
+            if cfg.kv_lora_rank:
+                q_idx, k_idx, w_idx = v
+                with jax.named_scope("mla_cache_write"):
+                    kc = kv_write(kc, ix.op, write_slots, k[0])
+                    vc = kv_write(vc, ix.op, write_slots, k_idx[0])
+                return mla.attend(
+                    attn_impl, q[0], q_idx[0], w_idx[0], kc, vc, ix.op,
+                    page_table, tok_seq, tok_pos, q_start, q_len, kv_len,
+                    page_size, cfg.kv_lora_rank, cfg.index_topk,
+                    interpret=interpret)[None]
             with jax.named_scope("kv_write"):
                 kc = kv_write(kc, ix.op, write_slots, k[0])
                 vc = kv_write(vc, ix.op, write_slots, v[0])
@@ -655,6 +778,17 @@ def forward_decode(
     def body(x, lp, kinds, ix, kc, vc, conv, rule):
         def attn_fn(q, k, v):  # [B, 1, H, hd]
             nonlocal kc, vc
+            if cfg.kv_lora_rank:  # a stream of B one-token spans
+                q_idx, k_idx, w_idx = v
+                with jax.named_scope("mla_cache_write"):
+                    kc = kv_write(kc, ix.op, write_slots, k[:, 0])
+                    vc = kv_write(vc, ix.op, write_slots, k_idx[:, 0])
+                rows = jnp.arange(B, dtype=jnp.int32)
+                return mla.attend(
+                    attn_impl, q[:, 0], q_idx[:, 0], w_idx[:, 0], kc, vc,
+                    ix.op, page_table, rows, positions, rows,
+                    jnp.ones_like(rows), seq_lens, page_size,
+                    cfg.kv_lora_rank, cfg.index_topk, tile=1)[:, None]
             with jax.named_scope("kv_write"):
                 kc = kv_write(kc, ix.op, write_slots, k[:, 0])
                 vc = kv_write(vc, ix.op, write_slots, v[:, 0])
@@ -761,8 +895,7 @@ def forward_embed(
 
     def body(x, lp, kinds, ix):
         return _layer_step(
-            cfg, lp, kinds, x, positions,
-            lambda q, k, v: causal_attention(q, k, v, seq_lens),
+            cfg, lp, kinds, x, positions, _causal_fn(cfg, seq_lens),
             valid=valid, layer=ix.ffn, **_no_state(cfg, valid))
 
     x, _ = scan_layers(cfg, body, x, params["layers"])

@@ -26,6 +26,13 @@ ONE dispatch for every model of it:
     is `jax.lax.ragged_dot` (XLA's own: `ragged-dot` on a TPU trace, where
     its 512-row tiles make the same pass compute-bound, 2.3 x slower on a
     v5e; PERF.md section 6, PR 27).
+  - `n_group` / `topk_group` limit the selection to the best groups of
+    experts (DeepSeek-V3's), `n_shared_experts` adds a dense SwiGLU every
+    token passes (scope `moe_shared`), and `router_experts` /
+    `expert_offset` make the layer ONE CHIP'S SHARE of a wider one: the
+    router scores all the published experts and normalises over all it
+    chose, this program holds `num_experts` of them and adds what those
+    give — what expert parallelism asks of a layer, without the exchange.
   - Padding rows and idle decode slots (`valid` false) are assigned to no
     expert: they sort behind every group, are multiplied by no weight and
     count in no statistic.
@@ -56,10 +63,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
 from ollamamq_tpu.config import EXPERTS, ModelConfig
+from ollamamq_tpu.ops.quant import qeinsum
 from ollamamq_tpu.parallel.mesh import AXIS_EXPERT, AXIS_TENSOR
 
 # Stage names inside llama's "mlp" scope on the device trace, in order.
 SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+# ...and the shared experts' stage, for a model that has them.
+SHARED_SCOPES = ("moe_shared",)
 # Layer params the layer loop reads whole, by layer index.
 STACKED = ("we_gate", "we_up", "we_down")
 # What load_stats() returns, in order (int32 each).
@@ -72,6 +82,10 @@ GMM_TILING = (128, 2048, 1024)
 # Standard deviation of a seeded-random selection bias: a tenth of the
 # router logits', so it moves the choice of some tokens' k-th expert.
 ROUTER_BIAS_SD = 0.1
+# The shared experts' weights (a dense SwiGLU every token passes, sliced by
+# layer like the router) and the fold_in constant of their init keys.
+SHARED = ("ws_gate", "ws_up", "ws_down")
+SHARED_KEY = 0x73686172
 
 
 def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
@@ -81,7 +95,7 @@ def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     forward that drops it, or weights by the biased score, computes
     another model."""
     d, f = cfg.hidden_size, cfg.expert_width
-    L, E = cfg.count(EXPERTS), cfg.num_experts
+    L, E, R = cfg.count(EXPERTS), cfg.num_experts, cfg.router_width
     # (One more key only where there is a bias: the families without one
     # keep the weights their seeds have always drawn.)
     keys = jax.random.split(key, 4 + cfg.use_expert_bias)
@@ -91,14 +105,19 @@ def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
                 / jnp.sqrt(fan_in)).astype(dtype)
 
     out = {
-        "w_router": w(keys[0], (L, d, E), d),
+        "w_router": w(keys[0], (L, d, R), d),
         "we_gate": w(keys[1], (L, E, d, f), d),
         "we_up": w(keys[2], (L, E, d, f), d),
         "we_down": w(keys[3], (L, E, f, d), f),
     }
     if cfg.use_expert_bias:
         out["router_bias"] = ROUTER_BIAS_SD * jax.random.normal(
-            keys[4], (L, E), jnp.float32)
+            keys[4], (L, R), jnp.float32)
+    if cfg.n_shared_experts:
+        sk = jax.random.split(jax.random.fold_in(key, SHARED_KEY), 3)
+        fs = cfg.n_shared_experts * f
+        out.update(ws_gate=w(sk[0], (L, d, fs), d), ws_up=w(sk[1], (L, d, fs), d),
+                   ws_down=w(sk[2], (L, fs, d), fs))
     return out
 
 
@@ -180,12 +199,25 @@ def route(cfg: ModelConfig, lp: dict, x: jnp.ndarray):
                      lp["w_router"].astype(jnp.float32))
     scores = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
+    choice = scores
     if cfg.use_expert_bias:  # the bias selects; it weights nothing
-        _, experts = jax.lax.top_k(scores + lp["router_bias"],
-                                   cfg.num_experts_per_tok)
-        gates = jnp.take_along_axis(scores, experts, axis=-1)
-    else:
+        choice = scores + lp["router_bias"]
+    if cfg.n_group:
+        # Group-limited: a group scores the sum of its best two, and the
+        # top k are taken inside the `topk_group` best groups.
+        N, R = choice.shape
+        grouped = choice.reshape(N, cfg.n_group, R // cfg.n_group)
+        best2, _ = jax.lax.top_k(grouped, 2)
+        _, groups = jax.lax.top_k(jnp.sum(best2, axis=-1), cfg.topk_group)
+        open_ = jnp.any(groups[:, :, None]
+                        == jnp.arange(cfg.n_group)[None, None, :], axis=1)
+        choice = jnp.where(open_[:, :, None], grouped, -jnp.inf
+                           ).reshape(N, R)
+    if choice is scores:
         gates, experts = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    else:
+        _, experts = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
     if cfg.norm_topk_prob:
         total = jnp.sum(gates, axis=-1, keepdims=True)
         gates = gates / (total + cfg.norm_topk_eps if cfg.norm_topk_eps
@@ -216,6 +248,12 @@ def moe_mlp(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
 
     with jax.named_scope("moe_router"):
         gates, experts = route(cfg, lp, x)
+        if cfg.router_width != E:
+            # The chip's share: the router chose among all its experts and
+            # normalised over all it chose; the ones held here are E from
+            # `expert_offset` on, the others are no expert of this layer.
+            experts = experts - cfg.expert_offset
+            experts = jnp.where((experts >= 0) & (experts < E), experts, E)
 
     with jax.named_scope("moe_dispatch"):
         # Assignment a = (token a // K, slot a % K). An invalid token's
@@ -243,7 +281,14 @@ def moe_mlp(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
         w = jnp.where(experts < E, gates, 0.0)  # [N, K]
         y = jnp.where((experts < E)[..., None], y, 0)  # unowned rows: anything
         out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
-    return out.astype(h.dtype).reshape(B, T, D), load
+    out = out.astype(h.dtype).reshape(B, T, D)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            gate = qeinsum("btd,df->btf", h, lp["ws_gate"])
+            up = qeinsum("btd,df->btf", h, lp["ws_up"])
+            out = out + qeinsum("btf,fd->btd", jax.nn.silu(gate) * up,
+                                lp["ws_down"])
+    return out, load
 
 
 def load_stats(load: jnp.ndarray) -> jnp.ndarray:

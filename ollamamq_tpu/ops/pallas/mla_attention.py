@@ -1,0 +1,355 @@
+"""Pallas TPU kernels of latent attention with a learned sparse selection
+(ops/mla.py has the mathematics, the selection and the jnp twins).
+
+Three kernels, two of them over one walk: a launch of those takes the step's
+flattened token stream — any mix of prefill spans and decode tokens,
+`ragged_attention.py`'s layout contract — in tiles of `tile` tokens (one
+program a tile; the decode scan launches tiles of ONE token, a row of 128
+heads being an MXU pass by itself) and, per sequence with a row in the tile,
+streams that sequence's pages of ONE pool HBM→VMEM in blocks of BLOCK tokens
+through two buffers, up to the tile's deepest causal frontier:
+
+  `dsa_index_pallas`: the lightning indexer. Per block ONE contraction for
+      all the tile's (token, index head) rows against the block's index keys
+      `[tile*Hi, di] · [BLOCK, di]ᵀ`, ReLU, times the head's learned weight,
+      summed over the heads: `I[t, s]`, written as `[T, C]` float32 — one
+      number a (token, cached position), never one a head.
+  `dsa_select_pallas` (no walk: a tile's `[tile, C]` scores resident in
+      VMEM): `thr[t]`, the index_topk-th largest score of t's context, by
+      32 counting passes over the block — exact, where `lax.top_k` is a
+      sort.
+  `mla_sparse_paged_attention_pallas`: the absorbed 128-head contraction
+      over the latent pool. Per block `[tile*H, lanes] · [BLOCK, lanes]ᵀ`
+      (q carries W_uk and the softmax scale; the lanes are the row's 576
+      and the zeros that fill its last 128-lane tile), the SELECTION applied as a mask
+      inside the kernel — position s is attended iff `s <= pos(t)` and
+      `I[t, s] >= thr[t]`, `thr[t]` the index_topk-th largest score of t
+      (ops/mla.select_threshold) — a flash-style online softmax, and
+      `acc += p · block[:, :512]`: the values are the first `kv_lora_rank`
+      lanes of the same rows. No `[tokens, context]` score leaves VMEM and
+      no row is gathered into HBM.
+
+The selection is a MASK over the streamed context, not a gather of the
+selected rows: exact, MXU-shaped at any selection, and it reads and
+multiplies every cached position of a prefill tile where a gather would
+touch index_topk of them (PERF.md section 5 has what that costs at 8-16 k
+tokens and what a gathering kernel is read against: `mla_attn_roofline_pct`
+counts the selected pairs only).
+
+Names: exactly one launch a layer a forward pass carries `paged_attention`
+in its name (benchmarks/layer_metrics/_ops.py divides such launches by the
+attention layers); the indexer's and the selection's do not.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ollamamq_tpu.ops.pallas.kv_contract import cdiv
+
+# Tokens of context a block: 8 pages of 32. The scores' lanes.
+BLOCK = 256
+# Tokens a tile of a ragged step (the decode scan's tiles hold one).
+TILE = 16
+NEG_INF = -1e30
+VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, update):
+    """The tile's walk: for every sequence with a row in tile `t`, its
+    blocks up to the deepest causal frontier among those rows, each waited
+    for in `buf[slot]` and handed to `update(slot, block, lo, hi, base)` —
+    rows [lo, hi) of the tile are the sequence's, row i at position
+    base + i."""
+    layer_ref, first_ref, q_start_ref, q_len_ref, kv_len_ref, pt_ref = refs
+    ppb = BLOCK // page_size
+    layer = layer_ref[0]
+    tile_lo = lax.mul(t, tile)
+    tile_hi = lax.add(tile_lo, tile)
+
+    def fetch(s, b, slot):
+        for j in range(ppb):
+            page = pt_ref[s, lax.add(lax.mul(b, ppb), j)]
+            pltpu.make_async_copy(
+                hbm.at[layer, pl.ds(lax.mul(page, page_size), page_size)],
+                buf.at[slot, pl.ds(j * page_size, page_size)],
+                sem.at[slot]).start()
+
+    def wait(slot):
+        pltpu.make_async_copy(hbm.at[layer, pl.ds(0, BLOCK)], buf.at[slot],
+                              sem.at[slot]).wait()
+
+    def overlaps(s):
+        row = lax.min(s, num_seqs - 1)
+        qs, ql = q_start_ref[row], q_len_ref[row]
+        return functools.reduce(lax.bitwise_and, (
+            lax.lt(s, num_seqs), lax.gt(ql, 0), lax.lt(qs, tile_hi),
+            lax.gt(lax.add(qs, ql), tile_lo)))
+
+    def one_sequence(s):
+        qs, ql, kv = q_start_ref[s], q_len_ref[s], kv_len_ref[s]
+        lo = lax.sub(lax.max(qs, tile_lo), tile_lo)
+        hi = lax.sub(lax.min(lax.add(qs, ql), tile_hi), tile_lo)
+        base = lax.sub(lax.add(lax.sub(kv, ql), tile_lo), qs)
+        n = cdiv(lax.add(base, hi), BLOCK)  # frontier = base + hi
+        fetch(s, 0, 0)
+
+        def body(b, _):
+            slot = lax.bitwise_and(b, 1)
+
+            @pl.when(lax.lt(lax.add(b, 1), n))
+            def _():
+                fetch(s, lax.add(b, 1), lax.sub(1, slot))
+
+            wait(slot)
+            update(slot, b, lo, hi, base)
+            return ()
+
+        lax.fori_loop(0, n, body, ())
+        return lax.add(s, 1)
+
+    lax.while_loop(overlaps, one_sequence, first_ref[t])
+
+
+def _rows_of(shape, lo, hi):
+    row = lax.broadcasted_iota(jnp.int32, shape, 0)
+    return row, lax.bitwise_and(lax.ge(row, lo), lax.lt(row, hi))
+
+
+def _index_kernel(*refs, tile, heads, page_size, num_seqs):
+    meta, (q_ref, w_ref, hbm, o_ref, buf, sem) = refs[:6], refs[6:]
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def update(slot, b, lo, hi, base):
+        s = lax.dot_general(q_ref[...], buf[slot], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        s = jnp.maximum(s, 0.0) * w_ref[...]  # [tile*Hi, BLOCK]
+        score = jnp.sum(s.reshape(tile, heads, BLOCK), axis=1)
+        _, mine = _rows_of((tile, BLOCK), lo, hi)
+        at = pl.ds(pl.multiple_of(lax.mul(b, BLOCK), BLOCK), BLOCK)
+        o_ref[:, at] = jnp.where(mine, score, o_ref[:, at])
+
+    _walk(pl.program_id(0), tile, meta, hbm, buf, sem, page_size, num_seqs,
+          update)
+
+
+def _select_kernel(s_ref, pos_ref, o_ref, *, topk):
+    """thr [tile, 1]: the topk-th largest of each row's scores at positions
+    <= pos, by a bitwise search over the scores' order-preserving int32 keys
+    with the row's block resident in VMEM (ops/mla.select_threshold is the
+    same search in XLA, on unsigned keys)."""
+    b = lax.bitcast_convert_type(s_ref[...], jnp.int32)
+    keys = jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
+    pos = pos_ref[...]  # [tile, 1]
+    lowest = jnp.int32(-(1 << 31))
+    live = lax.broadcasted_iota(jnp.int32, keys.shape, 1) <= pos
+    keys = jnp.where(live, keys, lowest)
+
+    def enough(cand):
+        n = jnp.sum(jnp.where(keys >= cand, 1.0, 0.0), axis=1, keepdims=True)
+        return n >= float(topk)
+
+    ans = jnp.full(pos.shape, lowest, jnp.int32)
+    ans = jnp.where(enough(jnp.zeros_like(ans)), 0, ans)  # the sign bit
+
+    def bit(i, ans):
+        cand = ans | lax.shift_left(jnp.int32(1), jnp.int32(30) - i)
+        return jnp.where(enough(cand), cand, ans)
+
+    ans = lax.fori_loop(0, 31, bit, ans)
+    thr = lax.bitcast_convert_type(
+        jnp.where(ans < 0, ans ^ jnp.int32(0x7FFFFFFF), ans), jnp.float32)
+    o_ref[...] = jnp.where(pos + 1 > topk, thr, NEG_INF)
+
+
+def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs):
+    meta = refs[:6]
+    q_ref, i_ref, thr_ref, hbm, o_ref, buf, sem, m_ref, l_ref, acc_ref = \
+        refs[6:]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def fold(rows, at, keep, n_tok):
+        """One block into the online softmax of the `n_tok * heads`
+        row-heads at `at`; keep [n_tok, BLOCK] says what each token
+        attends."""
+        s = lax.dot_general(q_ref[at, :], rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        keep = jnp.broadcast_to(keep[:, None, :], (n_tok, heads, BLOCK)
+                                ).reshape(n_tok * heads, BLOCK)
+        s = jnp.where(keep, s, NEG_INF)
+        m_old = m_ref[at, :]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_old - m_new)
+        l_ref[at, :] = l_ref[at, :] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[at, :] = acc_ref[at, :] * corr + lax.dot_general(
+            p.astype(rows.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[at, :] = m_new
+
+    # A decode row in a tile of other sequences' tokens: its walk folds its
+    # OWN `heads` row-heads, not the tile's (masked) `tile * heads` — a
+    # sixteenth of the MXU work for each of up to 15 such rows a step. Only
+    # where a token's row-heads are whole sublane tiles (a dynamic slice at
+    # `row * heads`), which every published width is.
+    alone = tile > 1 and heads % 8 == 0
+
+    def update(slot, b, lo, hi, base):
+        rows = buf[slot]  # [BLOCK, lanes]
+        at = pl.ds(pl.multiple_of(lax.mul(b, BLOCK), BLOCK), BLOCK)
+        row, mine = _rows_of((tile, BLOCK), lo, hi)
+        key = lax.add(lax.broadcasted_iota(jnp.int32, (tile, BLOCK), 1),
+                      lax.mul(b, BLOCK))
+        keep = functools.reduce(jnp.logical_and, (
+            mine, key <= row + base, i_ref[:, at] >= thr_ref[...]))
+        if not alone:
+            return fold(rows, pl.ds(0, tile * heads), keep, tile)
+        one = lax.eq(lax.sub(hi, lo), 1)
+
+        @pl.when(one)
+        def _():  # `mine` is the one row: its line of `keep`, by a reduce
+            line = jnp.max(keep.astype(jnp.float32), axis=0, keepdims=True)
+            fold(rows, pl.ds(pl.multiple_of(lax.mul(lo, heads), heads),
+                             heads), line > 0.0, 1)
+
+        @pl.when(jnp.logical_not(one))
+        def _():
+            fold(rows, pl.ds(0, tile * heads), keep, tile)
+
+    _walk(pl.program_id(0), tile, meta, hbm, buf, sem, page_size, num_seqs,
+          update)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+def _launch(kernel, tile, inputs, pool, out_lanes, out_dtype, scratch, layer,
+            page_table, q_start, q_lens, kv_lens, page_size, interpret):
+    """One program a tile: the tile's blocks of `inputs` ([n_tiles, rows,
+    lanes] each) in VMEM, the pool left in HBM, two block buffers."""
+    n_tiles = inputs[0].shape[0]
+    ppb = BLOCK // page_size
+    page_table = page_table.astype(jnp.int32)
+    page_table = jnp.pad(page_table, ((0, 0), (0, -page_table.shape[1] % ppb)))
+    ends = (q_start + q_lens).astype(jnp.int32)
+    tile_first = jnp.searchsorted(
+        ends, jnp.arange(n_tiles, dtype=jnp.int32) * tile, side="right"
+    ).astype(jnp.int32)
+
+    def spec(shape):
+        return pl.BlockSpec((None,) + shape[1:], lambda i, *_: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    out_shape = (n_tiles, out_lanes[0], out_lanes[1])
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(n_tiles,),
+            in_specs=[spec(x.shape) for x in inputs]
+            + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=spec(out_shape),
+            scratch_shapes=[pltpu.VMEM((2, BLOCK, pool.shape[-1]), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))] + scratch),
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_first,
+      q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
+      kv_lens.astype(jnp.int32), page_table, *inputs, pool)
+
+
+def _tiles(x, tile):
+    """[T, ...] -> [n_tiles, tile * prod(mid), lanes], T padded to tiles."""
+    T = x.shape[0]
+    x = jnp.pad(x, ((0, -T % tile),) + ((0, 0),) * (x.ndim - 1))
+    return x.reshape(x.shape[0] // tile, -1, x.shape[-1])
+
+
+def context_lanes(max_pages: int, page_size: int) -> int:
+    """Width of the `[T, C]` index scores: the table's context in whole
+    blocks."""
+    return -(-max_pages * page_size // BLOCK) * BLOCK
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("page_size", "tile", "interpret"))
+def dsa_index_pallas(q_idx, w, idx_pool, layer, page_table, q_start, q_lens,
+                     kv_lens, page_size: int, tile: int = TILE,
+                     interpret: bool = False):
+    """I [T, C] float32 (C = context_lanes): q_idx [T, Hi, di] in the pool's
+    dtype, w [T, Hi] float32, idx_pool [L, S, di]. Positions past a token's
+    sequence's frontier hold 0 or what the trash page scores."""
+    T, Hi, _ = q_idx.shape
+    C = context_lanes(page_table.shape[1], page_size)
+    kernel = functools.partial(_index_kernel, tile=tile, heads=Hi,
+                               page_size=page_size,
+                               num_seqs=page_table.shape[0])
+    out = _launch(kernel, tile,
+                  [_tiles(q_idx, tile),
+                   _tiles(w.astype(jnp.float32)[..., None], tile)], idx_pool,
+                  (tile, C), jnp.float32, [], layer, page_table, q_start,
+                  q_lens, kv_lens, page_size, interpret)
+    return out.reshape(-1, C)[:T]
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "tile", "interpret"))
+def dsa_select_pallas(scores, tok_pos, topk: int, tile: int = TILE,
+                      interpret: bool = False):
+    """thr [T] float32 of scores [T, C] and tok_pos [T] (-1: padding), as
+    ops/mla.select_threshold."""
+    T = scores.shape[0]
+    tok_pos = jnp.pad(tok_pos.astype(jnp.int32), (0, -T % tile),
+                      constant_values=-1)
+    inputs = [_tiles(scores, tile), tok_pos.reshape(-1, tile, 1)]
+
+    def spec(shape):
+        return pl.BlockSpec((None,) + shape[1:], lambda i: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    out_shape = (inputs[0].shape[0], tile, 1)
+    out = pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk),
+        grid=(out_shape[0],),
+        in_specs=[spec(x.shape) for x in inputs], out_specs=spec(out_shape),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(*inputs)
+    return out.reshape(-1)[:T]
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "rank", "tile",
+                                             "interpret"))
+def mla_sparse_paged_attention_pallas(q_abs, scores, thr, lat_pool, layer,
+                                      page_table, q_start, q_lens, kv_lens,
+                                      page_size: int, rank: int,
+                                      tile: int = TILE,
+                                      interpret: bool = False):
+    """o [T, H, rank] in q's dtype: q_abs [T, H, latent] (absorbed, scaled),
+    scores [T, C] and thr [T] float32 (the selection), lat_pool [L, S,
+    latent]."""
+    T, H, _ = q_abs.shape
+    kernel = functools.partial(_attend_kernel, tile=tile, heads=H, rank=rank,
+                               page_size=page_size,
+                               num_seqs=page_table.shape[0])
+    rows = tile * H
+    scratch = [pltpu.VMEM((rows, 1), jnp.float32),
+               pltpu.VMEM((rows, 1), jnp.float32),
+               pltpu.VMEM((rows, rank), jnp.float32)]
+    out = _launch(kernel, tile,
+                  [_tiles(q_abs, tile), _tiles(scores, tile),
+                   _tiles(thr.astype(jnp.float32)[:, None], tile)], lat_pool,
+                  (rows, rank), q_abs.dtype, scratch, layer, page_table,
+                  q_start, q_lens, kv_lens, page_size, interpret)
+    return out.reshape(-1, H, rank)[:T]

@@ -307,6 +307,30 @@ LIN_SPAN_TOKENS_TOTAL = REGISTRY.counter(
     "Tokens of spans longer than one token that went through the delta "
     "rule's chunked form (the state read and written once a 64-token "
     "window a span touches)", labels=("model",))
+HBM_LATENT_POOL_BYTES = REGISTRY.gauge(
+    "ollamamq_hbm_latent_pool_bytes",
+    "Bytes the latent pool of a model with latent attention occupies "
+    "(attention layers x slots x latent lanes: c_kv and the shared rotary "
+    "key, padded to whole 128-lane tiles; counted in ollamamq_hbm_kv_bytes "
+    "too)", labels=("model",))
+HBM_INDEX_POOL_BYTES = REGISTRY.gauge(
+    "ollamamq_hbm_index_pool_bytes",
+    "Bytes the index-key pool of a model with latent attention occupies "
+    "(attention layers x slots x index_head_dim; counted in "
+    "ollamamq_hbm_kv_bytes too)", labels=("model",))
+MLA_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_mla_rows_total",
+    "Query tokens of launched steps that went through latent attention "
+    "(a ragged step's tokens, a fused scan's active slots x its passes)",
+    labels=("model",))
+DSA_CTX_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_dsa_ctx_tokens_total",
+    "Cached positions the indexer scored for those query tokens, a layer: "
+    "a token at position p scores p + 1", labels=("model",))
+DSA_SELECTED_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_dsa_selected_tokens_total",
+    "Cached positions attention then saw for them, a layer: min(p + 1, "
+    "index_topk)", labels=("model",))
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

@@ -343,7 +343,10 @@ def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     shapes = jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
     params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
-    pool = s((cfg.count(ATTENTION), NP * PS, cfg.kv_dim), jnp.bfloat16)
+    # K and V rows — or a latent-attention model's latent rows and index
+    # keys: two pools of different widths (ModelConfig.kv_row_dims).
+    pool, pool2 = (s((cfg.count(ATTENTION), NP * PS, lanes), jnp.bfloat16)
+                   for lanes in cfg.kv_row_dims)
     recent, last_ids = s((S + 1, W)), s((S,))
     # The per-slot state: None (no leaf) for a model without such layers,
     # the conv window's array, or a SlotState with the rule's state too.
@@ -357,9 +360,9 @@ def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
         fn = rt._get_decode_jit(8, (True, True, True))
         words = rt._decode_layout().size
     carried = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
-        (pool, pool, recent, last_ids, conv)))
+        (pool, pool2, recent, last_ids, conv)))
     # The step's host inputs are ONE packed int32 array (step_pack).
-    return fn.lower(params, s((words,)), pool, pool, recent, last_ids,
+    return fn.lower(params, s((words,)), pool, pool2, recent, last_ids,
                     conv), words, carried
 
 
@@ -430,6 +433,58 @@ def test_olmo_hybrid_width_step_programs_carry_the_rule_state_in_place(
     assert carried >= 2 * 2 * NP * PS * 3840 * 2 + rule + window
     assert mem.alias_size_in_bytes >= carried, (mem, carried)
     assert mem.temp_size_in_bytes < rule // 10, mem
+
+
+# DeepSeek-V3.2's layers (config.py) over its dense layer and two expert
+# layers, 16 of the router's 256 experts held, a small vocabulary: the three
+# kernels of ops/pallas/mla_attention.py at 128 heads over a 640-lane latent
+# pool and a 128-lane index-key pool.
+DEEPSEEK_CFG = ModelConfig(
+    name="chip-compile-deepseek-v32-widths", vocab_size=2048,
+    hidden_size=7168, intermediate_size=18432, num_layers=3, num_heads=128,
+    num_kv_heads=128, head_dim=192, max_seq_len=MP * PS, rope_theta=10000.0,
+    rms_norm_eps=1e-6, q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    index_n_heads=64, index_head_dim=128, index_topk=2048,
+    rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                  "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                  "original_max_position_embeddings": 4096},
+    num_experts=16, router_experts=256, num_experts_per_tok=8, n_group=8,
+    topk_group=4, n_shared_experts=1, moe_intermediate_size=2048,
+    first_k_dense_replace=1, router_score="sigmoid", use_expert_bias=True,
+    norm_topk_prob=True, norm_topk_eps=1e-20, routed_scaling_factor=2.5)
+
+
+@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
+def test_deepseek_width_step_programs_carry_both_pools_in_place(
+        v5e, which, monkeypatch):
+    """Latent attention with the indexer's selection (PR 39), at
+    DeepSeek-V3.2's widths: the indexer's, the selection's and the sparse
+    attention's kernels compile for the chip, one launch each a traced layer
+    body (the dense layer's and the expert layers'), exactly one of them
+    named `...paged_attention...` a body; the latent pool [3, S, 640] and
+    the index-key pool [3, S, 128] — two arrays of different widths under
+    one page table — the ring and the id carry all come back aliased (no
+    second copy of either pool); and the temporaries hold no [tokens,
+    context] float32 score a HEAD: the one [T, C] score a token is 2 MB here
+    (64 x 8192 x 4 B), all 128 heads' would be 268 MB, the bound is a quarter
+    of that above what the program holds without the indexer."""
+    lowered, _, carried = _lower_step_program(v5e, which, monkeypatch,
+                                              DEEPSEEK_CFG)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for name, n in (("mla_sparse_paged_attention_pallas", 2),
+                    ("dsa_index_pallas", 2), ("dsa_select_pallas", 2)):
+        assert len(re.findall(r'kernel_name = "%s"' % name, text)) == n \
+            or text.count(name) >= n, name
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3
+    pools = 3 * NP * PS * (640 + 128) * 2
+    assert carried >= pools
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried, (mem, carried)
+    tokens = T if which == "mq_ragged_step" else B
+    per_head = tokens * MP * PS * 4
+    assert mem.temp_size_in_bytes < 128 * per_head // 4 + 512 * 2 ** 20, mem
 
 
 @pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG, OLMO_HYBRID_CFG],

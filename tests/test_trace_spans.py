@@ -260,7 +260,8 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
 # ------------------------------------------- names on the device-side programs
 @pytest.mark.parametrize("model", ["test-tiny", "test-tiny-olmoe",
                                    "test-tiny-lfm2",
-                                   "test-tiny-olmo-hybrid"])
+                                   "test-tiny-olmo-hybrid",
+                                   "test-tiny-deepseek-v32"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
@@ -285,6 +286,12 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     scopes = llama.SCOPES + (moe.SCOPES if rt.cfg.num_experts else ()) \
         + (llama.CONV_SCOPES if rt.cfg.count("conv") else ()) \
         + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention") else ())
+    if rt.cfg.kv_lora_rank:  # latent attention: its stages for the three
+        from ollamamq_tpu.ops import mla
+
+        scopes = tuple(s for s in scopes if s not in (
+            "attn_qkv", "kv_write", "attention")) + mla.SCOPES \
+            + moe.SHARED_SCOPES
     seen = {}
 
     def spy(site, getter):
@@ -331,8 +338,11 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     documented = set(re.findall(r"`([a-z_.]+)`", table))
     jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_prefill_sp",
                  "mq_embed", "mq_encode"}
+    from ollamamq_tpu.ops import mla
+
     assert set(llama.SCOPES) | set(llama.CONV_SCOPES) | set(moe.SCOPES) \
-        | set(llama.LINEAR_SCOPES) | jit_names | set(SPAN_NAMES) \
+        | set(llama.LINEAR_SCOPES) | set(mla.SCOPES) \
+        | set(moe.SHARED_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented
     # ... and the five jit sites really are those functions.
     with open(os.path.join(_REPO, "ollamamq_tpu", "engine",

@@ -194,3 +194,39 @@ def lfm2_keys(mc) -> dict:
             "use_expert_bias": mc.use_expert_bias,
             "routed_scaling_factor": mc.routed_scaling_factor,
             "tie_word_embeddings": mc.tie_embeddings}
+
+
+def deepseek_v32_reference():
+    """...and of the latent-attention family with a learned sparse selection
+    (benchmarks/reference/deepseek_v32_decoder.py)."""
+    return _reference("deepseek_v32_decoder")
+
+
+def deepseek_v32_keys(mc) -> dict:
+    """What a configuration file says of the latent-attention ModelConfig
+    `mc`, in the published spellings: all that reference reads."""
+    return {"num_attention_heads": mc.num_heads,
+            "hidden_size": mc.hidden_size, "rms_norm_eps": mc.rms_norm_eps,
+            "rope_theta": mc.rope_theta, "rope_scaling": dict(mc.rope_scaling),
+            "head_dim": mc.head_dim, "q_lora_rank": mc.q_lora_rank,
+            "kv_lora_rank": mc.kv_lora_rank,
+            "qk_nope_head_dim": mc.qk_nope_head_dim,
+            "qk_rope_head_dim": mc.qk_rope_head_dim,
+            "v_head_dim": mc.v_head_dim, "index_n_heads": mc.index_n_heads,
+            "index_head_dim": mc.index_head_dim, "index_topk": mc.index_topk,
+            "num_hidden_layers": mc.num_layers,
+            "num_dense_layers": mc.num_dense_layers,
+            "n_routed_experts": mc.num_experts,
+            "router_experts": mc.router_width,
+            "expert_offset": mc.expert_offset,
+            "num_experts_per_tok": mc.num_experts_per_tok,
+            "n_group": mc.n_group, "topk_group": mc.topk_group,
+            "n_shared_experts": mc.n_shared_experts,
+            "norm_topk_prob": mc.norm_topk_prob,
+            "norm_topk_eps": mc.norm_topk_eps,
+            "routed_scaling_factor": mc.routed_scaling_factor,
+            "router_score": mc.router_score,
+            "use_expert_bias": mc.use_expert_bias,
+            "moe_intermediate_size": mc.moe_intermediate_size,
+            "intermediate_size": mc.intermediate_size,
+            "vocab_size": mc.vocab_size}
