@@ -147,7 +147,6 @@ def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
     if impl == "pallas":
         from ollamamq_tpu.ops.pallas import mla_attention as kernels
 
-        tile = tile or kernels.TILE
         with jax.named_scope("dsa_index"):
             scores = kernels.dsa_index_pallas(
                 q_idx, w_idx, idx_pool, layer, page_table, q_start, q_lens,

@@ -3,11 +3,13 @@
 
 Three kernels, two of them over one walk: a launch of those takes the step's
 flattened token stream — any mix of prefill spans and decode tokens,
-`ragged_attention.py`'s layout contract — in tiles of `tile` tokens (one
-program a tile; the decode scan launches tiles of ONE token, a row of 128
-heads being an MXU pass by itself) and, per sequence with a row in the tile,
-streams that sequence's pages of ONE pool HBM→VMEM in blocks of BLOCK tokens
-through two buffers, up to the tile's deepest causal frontier:
+`ragged_attention.py`'s layout contract — in tiles of tokens (one program a
+tile: TILE tokens for the indexer and the selection, ATTEND_TILE for the
+attention; the decode scan launches tiles of ONE token, a row of 128 heads
+being an MXU pass by itself) and, per sequence with a row in the tile,
+streams that sequence's pages of ONE pool HBM→VMEM in blocks of tokens
+(BLOCK for the indexer, ATTEND_BLOCK for the attention) through two buffers,
+up to the tile's deepest causal frontier:
 
   `dsa_index_pallas`: the lightning indexer. Per block ONE contraction for
       all the tile's (token, index head) rows against the block's index keys
@@ -19,7 +21,7 @@ through two buffers, up to the tile's deepest causal frontier:
       32 counting passes over the block — exact, where `lax.top_k` is a
       sort.
   `mla_sparse_paged_attention_pallas`: the absorbed 128-head contraction
-      over the latent pool. Per block `[tile*H, lanes] · [BLOCK, lanes]ᵀ`
+      over the latent pool. Per block `[tile*H, lanes] · [block, lanes]ᵀ`
       (q carries W_uk and the softmax scale; the lanes are the row's 576
       and the zeros that fill its last 128-lane tile), the SELECTION applied as a mask
       inside the kernel — position s is attended iff `s <= pos(t)` and
@@ -39,6 +41,49 @@ counts the selected pairs only).
 Names: exactly one launch a layer a forward pass carries `paged_attention`
 in its name (benchmarks/layer_metrics/_ops.py divides such launches by the
 attention layers); the indexer's and the selection's do not.
+
+The attention kernel's trip — one (tile, block) of the walk: at 128 heads
+16 tokens are 2048 row-heads, and a 256-token block costs the MXU
+`[2048, 640] · [256, 640]ᵀ` + `[2048, 256] · [256, 512]` = 1.21 GFLOP, 6.13 µs
+at the bf16 peak. Measured (my chip runs, PR 40, one v5e;
+`scripts/attn_kernel_bench.py --traffic latent`: 5 one-token rows and a
+507-token span, 2048 selected a token; µs a (16 tokens, 256 keys) of the
+span with the one-token trips taken off; 8 k of context unless said):
+
+  PR 39's trip 8.08 (ms a launch at 4 k / 8 k / 12 k / 16 k: 4.31 / 8.37 /
+  12.46 / 16.53). With a part taken out: its page DMAs 7.63; mask, max, exp
+  and row state (scores → bf16 → P·V) 6.87; the second select of `p` 8.05;
+  the `m`, `l`, `corr` updates alone 7.47; `acc`'s read-scale-add 7.47; both
+  contractions 3.05. So Mosaic ALREADY ran the float32 softmax beside the
+  MXU inside one straight-line trip (one chain's latency is not exposed,
+  as it is in `kv_contract.py`'s small blocks), and what stood out was the
+  row state: `m`, `l`, `corr` as `[2048, 1]` arrays — 256 vregs of ONE live
+  lane each, a lane broadcast of each over the scores' 256 and `acc`'s 512
+  lanes, a second cross-lane reduction a row for `l` — made the trip's
+  vector side 3.05 µs, of which ~1.2 showed.
+  This file's trip, same tile and block: **6.93** (1 chain), 6.89 (2), 6.89
+  (4). `m` is kept lane-replicated and `l` lane-PARTIAL in `[rows, 128]`
+  (whole vregs in and out of VMEM, no broadcast; ONE cross-lane reduction a
+  row a trip, the maximum's; `l`'s lanes are added up once a program), and
+  the running maximum starts at M_INIT above the mask's NEG_INF, so a masked
+  score's exp is 0 without a second select. Its vector side is 1.85 µs and
+  hidden: softmax out 6.87, `acc` out 6.93, DMAs out 6.59, contractions out
+  1.85. What is left over 6.13 is the MXU's own (re-latching 18 key/value
+  weight tiles a trip) and ~0.3 of page DMA that no variant hid.
+  Wider blocks LOSE: 512 wide 7.07 / 6.98 / 6.96 (1 / 2 / 4 chains), 1024
+  wide 7.14 / 7.15 / 7.08 / 7.01 (1 / 2 / 4 / 8) — with the state in whole
+  vregs nothing a trip pays is exposed any more, and a walk's last block
+  over-reads half a block a (tile, sequence). A one-token trip (M = 128:
+  weight-tile bound) reads 1.43 → 1.13 a 256 keys, 0.99 at 512 wide, 0.96
+  at 1024: 2.5 % of a launch, not worth the spans' 2–3 %.
+  A larger TILE wins: 32 tokens 6.72 / 6.73 / 6.65 (1 / 2 / 4 chains), 64
+  tokens 6.65 / 6.59 / 6.54 (2 / 4 / 8; ~90 MB of VMEM, not taken) — a
+  block's DMA and weight tiles are paid over twice the rows. Chains of 8
+  tokens (1024 row-heads) are the best at every tile, by ~1 %.
+  Taken: ATTEND_TILE 32, CHAIN 8, ATTEND_BLOCK 256 — **6.68** at 8 k (6.99
+  / 6.68 / 6.57 / 6.53 at 4 k / 8 k / 12 k / 16 k against 8.43 / 8.08 / 7.98
+  / 7.92; ms a launch 3.58 / 6.91 / 10.25 / 13.59: −17 … −18 %), 92 % of the
+  MXU's 6.13.
 """
 
 from __future__ import annotations
@@ -53,22 +98,34 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ollamamq_tpu.ops.pallas.kv_contract import cdiv
 
-# Tokens of context a block: 8 pages of 32. The scores' lanes.
+# Tokens of context a block of the indexer's walk: 8 pages of 32. The scores'
+# lanes.
 BLOCK = 256
 # Tokens a tile of a ragged step (the decode scan's tiles hold one).
 TILE = 16
+# The attention kernel's own walk (the sweep in the docstring): tokens of
+# context a block, tokens a tile of a ragged step, and tokens a chain — a
+# tile's row-heads are folded as tile / CHAIN independent chains.
+ATTEND_BLOCK = 256
+ATTEND_TILE = 32
+CHAIN = 8
 NEG_INF = -1e30
+# Where a row's running maximum starts: above NEG_INF, what a masked score
+# is set to, so that exp(masked - m) is 0 while a row has met no kept key
+# (no second select a score), and below any score.
+M_INIT = -1e20
+LANES = 128
 VMEM_LIMIT = 96 * 1024 * 1024
 
 
-def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, update):
+def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update):
     """The tile's walk: for every sequence with a row in tile `t`, its
-    blocks up to the deepest causal frontier among those rows, each waited
-    for in `buf[slot]` and handed to `update(slot, block, lo, hi, base)` —
-    rows [lo, hi) of the tile are the sequence's, row i at position
-    base + i."""
+    blocks of `block` tokens up to the deepest causal frontier among those
+    rows, each waited for in `buf[slot]` and handed to `update(slot, b, lo,
+    hi, base)` — rows [lo, hi) of the tile are the sequence's, row i at
+    position base + i."""
     layer_ref, first_ref, q_start_ref, q_len_ref, kv_len_ref, pt_ref = refs
-    ppb = BLOCK // page_size
+    ppb = block // page_size
     layer = layer_ref[0]
     tile_lo = lax.mul(t, tile)
     tile_hi = lax.add(tile_lo, tile)
@@ -82,7 +139,7 @@ def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, update):
                 sem.at[slot]).start()
 
     def wait(slot):
-        pltpu.make_async_copy(hbm.at[layer, pl.ds(0, BLOCK)], buf.at[slot],
+        pltpu.make_async_copy(hbm.at[layer, pl.ds(0, block)], buf.at[slot],
                               sem.at[slot]).wait()
 
     def overlaps(s):
@@ -97,7 +154,7 @@ def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, update):
         lo = lax.sub(lax.max(qs, tile_lo), tile_lo)
         hi = lax.sub(lax.min(lax.add(qs, ql), tile_hi), tile_lo)
         base = lax.sub(lax.add(lax.sub(kv, ql), tile_lo), qs)
-        n = cdiv(lax.add(base, hi), BLOCK)  # frontier = base + hi
+        n = cdiv(lax.add(base, hi), block)  # frontier = base + hi
         fetch(s, 0, 0)
 
         def body(b, _):
@@ -115,6 +172,14 @@ def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, update):
         return lax.add(s, 1)
 
     lax.while_loop(overlaps, one_sequence, first_ref[t])
+
+
+def _widen(x, width):
+    """A lane-replicated `[rows, 128]` as `[rows, width]`: the same vregs
+    again where `width` is whole lane tiles."""
+    if width % LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+    return jnp.concatenate([x] * (width // LANES), axis=1)
 
 
 def _rows_of(shape, lo, hi):
@@ -136,7 +201,7 @@ def _index_kernel(*refs, tile, heads, page_size, num_seqs):
         o_ref[:, at] = jnp.where(mine, score, o_ref[:, at])
 
     _walk(pl.program_id(0), tile, meta, hbm, buf, sem, page_size, num_seqs,
-          update)
+          BLOCK, update)
 
 
 def _select_kernel(s_ref, pos_ref, o_ref, *, topk):
@@ -168,74 +233,101 @@ def _select_kernel(s_ref, pos_ref, o_ref, *, topk):
     o_ref[...] = jnp.where(pos + 1 > topk, thr, NEG_INF)
 
 
-def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs):
+def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
+                   chains):
     meta = refs[:6]
     q_ref, i_ref, thr_ref, hbm, o_ref, buf, sem, m_ref, l_ref, acc_ref = \
         refs[6:]
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    m_ref[...] = jnp.full_like(m_ref, M_INIT)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def fold(rows, at, keep, n_tok):
-        """One block into the online softmax of the `n_tok * heads`
-        row-heads at `at`; keep [n_tok, BLOCK] says what each token
-        attends."""
-        s = lax.dot_general(q_ref[at, :], rows, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        keep = jnp.broadcast_to(keep[:, None, :], (n_tok, heads, BLOCK)
-                                ).reshape(n_tok * heads, BLOCK)
+    def scores(at, rows):
+        return lax.dot_general(q_ref[at, :], rows, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+    def fold(at, s, keep, n_tok, rows):
+        """One block's scores `s` into the online softmax of the `n_tok *
+        heads` row-heads at `at`; keep [n_tok, block] says what each token
+        attends. A row's maximum and sum live lane-replicated / lane-partial
+        in `[rows, 128]` (no broadcast a trip; the sum's lanes are added up
+        once a program)."""
+        keep = jnp.broadcast_to(keep[:, None, :], (n_tok, heads, block)
+                                ).reshape(n_tok * heads, block)
         s = jnp.where(keep, s, NEG_INF)
         m_old = m_ref[at, :]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - _widen(m_new, block))
         corr = jnp.exp(m_old - m_new)
-        l_ref[at, :] = l_ref[at, :] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[at, :] = acc_ref[at, :] * corr + lax.dot_general(
+        l_ref[at, :] = l_ref[at, :] * corr + functools.reduce(
+            jnp.add, (p[:, j:j + LANES] for j in range(0, block, LANES)))
+        acc_ref[at, :] = acc_ref[at, :] * _widen(corr, rank) + lax.dot_general(
             p.astype(rows.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[at, :] = m_new
 
+    def keep_of(r0, n, b, lo, hi, base):
+        """What rows [r0, r0 + n) of the tile attend of block `b`."""
+        at = pl.ds(pl.multiple_of(lax.mul(b, block), block), block)
+        row, mine = _rows_of((n, block), lax.sub(lo, r0), lax.sub(hi, r0))
+        key = lax.add(lax.broadcasted_iota(jnp.int32, (n, block), 1),
+                      lax.mul(b, block))
+        return functools.reduce(jnp.logical_and, (
+            mine, key <= row + (base + r0),
+            i_ref[r0:r0 + n, at] >= thr_ref[r0:r0 + n, :]))
+
     # A decode row in a tile of other sequences' tokens: its walk folds its
     # OWN `heads` row-heads, not the tile's (masked) `tile * heads` — a
-    # sixteenth of the MXU work for each of up to 15 such rows a step. Only
+    # `tile`-th of the MXU work for each such row of a step. Only
     # where a token's row-heads are whole sublane tiles (a dynamic slice at
     # `row * heads`), which every published width is.
     alone = tile > 1 and heads % 8 == 0
+    per_chain = tile // chains  # tokens
+
+    def whole(rows, b, lo, hi, base):
+        """The tile's row-heads as `chains` independent chains in one
+        straight-line body: chain h + 1's scores are asked of the MXU before
+        chain h's softmax, chain h's P.V before chain h + 1's softmax (worth
+        ~1 % over one chain: the scheduler overlaps one chain with itself)."""
+        ats = [pl.ds(h * per_chain * heads, per_chain * heads)
+               for h in range(chains)]
+        s = scores(ats[0], rows)
+        for h in range(chains):
+            nxt = scores(ats[h + 1], rows) if h + 1 < chains else None
+            fold(ats[h], s, keep_of(h * per_chain, per_chain, b, lo, hi, base),
+                 per_chain, rows)
+            s = nxt
 
     def update(slot, b, lo, hi, base):
-        rows = buf[slot]  # [BLOCK, lanes]
-        at = pl.ds(pl.multiple_of(lax.mul(b, BLOCK), BLOCK), BLOCK)
-        row, mine = _rows_of((tile, BLOCK), lo, hi)
-        key = lax.add(lax.broadcasted_iota(jnp.int32, (tile, BLOCK), 1),
-                      lax.mul(b, BLOCK))
-        keep = functools.reduce(jnp.logical_and, (
-            mine, key <= row + base, i_ref[:, at] >= thr_ref[...]))
+        rows = buf[slot]  # [block, lanes]
         if not alone:
-            return fold(rows, pl.ds(0, tile * heads), keep, tile)
+            return whole(rows, b, lo, hi, base)
         one = lax.eq(lax.sub(hi, lo), 1)
 
         @pl.when(one)
         def _():  # `mine` is the one row: its line of `keep`, by a reduce
+            keep = keep_of(0, tile, b, lo, hi, base)
             line = jnp.max(keep.astype(jnp.float32), axis=0, keepdims=True)
-            fold(rows, pl.ds(pl.multiple_of(lax.mul(lo, heads), heads),
-                             heads), line > 0.0, 1)
+            at = pl.ds(pl.multiple_of(lax.mul(lo, heads), heads), heads)
+            fold(at, scores(at, rows), line > 0.0, 1, rows)
 
         @pl.when(jnp.logical_not(one))
         def _():
-            fold(rows, pl.ds(0, tile * heads), keep, tile)
+            whole(rows, b, lo, hi, base)
 
     _walk(pl.program_id(0), tile, meta, hbm, buf, sem, page_size, num_seqs,
-          update)
-    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                  ).astype(o_ref.dtype)
+          block, update)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(
+        jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)).astype(o_ref.dtype)
 
 
-def _launch(kernel, tile, inputs, pool, out_lanes, out_dtype, scratch, layer,
-            page_table, q_start, q_lens, kv_lens, page_size, interpret):
+def _launch(kernel, tile, block, inputs, pool, out_lanes, out_dtype, scratch,
+            layer, page_table, q_start, q_lens, kv_lens, page_size, interpret):
     """One program a tile: the tile's blocks of `inputs` ([n_tiles, rows,
-    lanes] each) in VMEM, the pool left in HBM, two block buffers."""
+    lanes] each) in VMEM, the pool left in HBM, two buffers of `block`
+    tokens."""
     n_tiles = inputs[0].shape[0]
-    ppb = BLOCK // page_size
+    ppb = block // page_size
     page_table = page_table.astype(jnp.int32)
     page_table = jnp.pad(page_table, ((0, 0), (0, -page_table.shape[1] % ppb)))
     ends = (q_start + q_lens).astype(jnp.int32)
@@ -255,7 +347,7 @@ def _launch(kernel, tile, inputs, pool, out_lanes, out_dtype, scratch, layer,
             in_specs=[spec(x.shape) for x in inputs]
             + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=spec(out_shape),
-            scratch_shapes=[pltpu.VMEM((2, BLOCK, pool.shape[-1]), pool.dtype),
+            scratch_shapes=[pltpu.VMEM((2, block, pool.shape[-1]), pool.dtype),
                             pltpu.SemaphoreType.DMA((2,))] + scratch),
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         compiler_params=pltpu.CompilerParams(
@@ -276,24 +368,27 @@ def _tiles(x, tile):
 
 def context_lanes(max_pages: int, page_size: int) -> int:
     """Width of the `[T, C]` index scores: the table's context in whole
-    blocks."""
-    return -(-max_pages * page_size // BLOCK) * BLOCK
+    blocks of the wider of the two walks (the indexer writes them a BLOCK,
+    the attention kernel reads them an ATTEND_BLOCK)."""
+    wide = max(BLOCK, ATTEND_BLOCK)
+    return -(-max_pages * page_size // wide) * wide
 
 
 @functools.partial(jax.jit,
                    static_argnames=("page_size", "tile", "interpret"))
 def dsa_index_pallas(q_idx, w, idx_pool, layer, page_table, q_start, q_lens,
-                     kv_lens, page_size: int, tile: int = TILE,
+                     kv_lens, page_size: int, tile: int | None = None,
                      interpret: bool = False):
     """I [T, C] float32 (C = context_lanes): q_idx [T, Hi, di] in the pool's
     dtype, w [T, Hi] float32, idx_pool [L, S, di]. Positions past a token's
     sequence's frontier hold 0 or what the trash page scores."""
     T, Hi, _ = q_idx.shape
+    tile = tile or TILE
     C = context_lanes(page_table.shape[1], page_size)
     kernel = functools.partial(_index_kernel, tile=tile, heads=Hi,
                                page_size=page_size,
                                num_seqs=page_table.shape[0])
-    out = _launch(kernel, tile,
+    out = _launch(kernel, tile, BLOCK,
                   [_tiles(q_idx, tile),
                    _tiles(w.astype(jnp.float32)[..., None], tile)], idx_pool,
                   (tile, C), jnp.float32, [], layer, page_table, q_start,
@@ -302,11 +397,12 @@ def dsa_index_pallas(q_idx, w, idx_pool, layer, page_table, q_start, q_lens,
 
 
 @functools.partial(jax.jit, static_argnames=("topk", "tile", "interpret"))
-def dsa_select_pallas(scores, tok_pos, topk: int, tile: int = TILE,
+def dsa_select_pallas(scores, tok_pos, topk: int, tile: int | None = None,
                       interpret: bool = False):
     """thr [T] float32 of scores [T, C] and tok_pos [T] (-1: padding), as
     ops/mla.select_threshold."""
     T = scores.shape[0]
+    tile = tile or TILE
     tok_pos = jnp.pad(tok_pos.astype(jnp.int32), (0, -T % tile),
                       constant_values=-1)
     inputs = [_tiles(scores, tile), tok_pos.reshape(-1, tile, 1)]
@@ -334,20 +430,25 @@ def dsa_select_pallas(scores, tok_pos, topk: int, tile: int = TILE,
 def mla_sparse_paged_attention_pallas(q_abs, scores, thr, lat_pool, layer,
                                       page_table, q_start, q_lens, kv_lens,
                                       page_size: int, rank: int,
-                                      tile: int = TILE,
+                                      tile: int | None = None,
                                       interpret: bool = False):
     """o [T, H, rank] in q's dtype: q_abs [T, H, latent] (absorbed, scaled),
     scores [T, C] and thr [T] float32 (the selection), lat_pool [L, S,
     latent]."""
     T, H, _ = q_abs.shape
+    # a step no longer than the indexer's tile stays one tile of that size
+    tile = tile or (ATTEND_TILE if T > TILE else TILE)
     kernel = functools.partial(_attend_kernel, tile=tile, heads=H, rank=rank,
                                page_size=page_size,
-                               num_seqs=page_table.shape[0])
+                               num_seqs=page_table.shape[0],
+                               block=ATTEND_BLOCK,
+                               chains=tile // CHAIN if tile % CHAIN == 0
+                               else 1)
     rows = tile * H
-    scratch = [pltpu.VMEM((rows, 1), jnp.float32),
-               pltpu.VMEM((rows, 1), jnp.float32),
+    scratch = [pltpu.VMEM((rows, LANES), jnp.float32),
+               pltpu.VMEM((rows, LANES), jnp.float32),
                pltpu.VMEM((rows, rank), jnp.float32)]
-    out = _launch(kernel, tile,
+    out = _launch(kernel, tile, ATTEND_BLOCK,
                   [_tiles(q_abs, tile), _tiles(scores, tile),
                    _tiles(thr.astype(jnp.float32)[:, None], tile)], lat_pool,
                   (rows, rank), q_abs.dtype, scratch, layer, page_table,

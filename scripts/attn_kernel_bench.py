@@ -20,21 +20,33 @@ says what its walks need (`pages_live`), what whole blocks move
 matmul keeps of its operand (`f32_matmul_keeps`): the Vpu body's
 `p @ seg_t` is one. One JSON line a measurement; exits 1 without a TPU.
 
+`--traffic latent` times the latent-attention kernel instead
+(`ops/pallas/mla_attention.py:mla_sparse_paged_attention_pallas`) on the
+`deepseek-v3.2-ep16-d5.longctx` cell's step: 128 heads over a 640-lane
+latent pool, pages of 32, 5 one-token rows then one 507-token prefill
+span, every sequence at one context (`--contexts`, default 4 k / 8 k /
+12 k / 16 k), 2048 selected a token from seeded scores, checked against
+`ops/mla.sparse_attention` on a sample of the stream. A row a (context,
+`--set mla_attention.NAME=VALUE`): ms a launch and µs a (tile, block)
+trip (`latent`'s docstring has the two normalisations).
+
 A variant of a kernel's body is measured here before a whole cell: a
-module constant of the three kernel modules that this script sets
+module constant of the four kernel modules that this script sets
 (`--set kv_contract.PV_TERMS=1`, `--set paged_attention.RING=16,
 ragged_attention.RING=16`), `jax.clear_caches()`, one more row a (shape,
 traffic); a variant that needs code gets a constant that lives for that
 run (PR 34 timed the successor walk and the lane-tile loop each unrolled
 in Python and as a loop in the program so, PR 38 the page stream with a
-predicate a page and a block; the results are in `kv_contract.py`'s
-docstring).
+predicate a page and a block, PR 40 the latent kernel with its DMAs, its
+softmax, its `acc` update and its contractions each taken out; the results
+are in `kv_contract.py`'s and `mla_attention.py`'s docstrings).
 """
 
 from __future__ import annotations
 
 import argparse
 import ast
+import contextlib
 import json
 import os
 import sys
@@ -48,13 +60,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ollamamq_tpu.ops import mla
 from ollamamq_tpu.ops.attention import (paged_decode_attention_any,
                                         ragged_attention_any)
-from ollamamq_tpu.ops.pallas import (kv_contract, paged_attention,
-                                     ragged_attention)
+from ollamamq_tpu.ops.pallas import (kv_contract, mla_attention,
+                                     paged_attention, ragged_attention)
 
 MODULES = {m.__name__.rsplit(".", 1)[1]: m
-           for m in (kv_contract, paged_attention, ragged_attention)}
+           for m in (kv_contract, mla_attention, paged_attention,
+                     ragged_attention)}
 SHAPES = ((28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
           (30, 30, 128))
 B, MP, PS, NP, LAYER = 64, 256, 32, 1024, 1
@@ -62,6 +76,17 @@ B, MP, PS, NP, LAYER = 64, 256, 32, 1024, 1
 # it is fed the columns a context here can reach and no more.
 REF_PAGES = 64
 LAUNCHES = 64
+# The latent traffic: DeepSeek-V3.2's attention widths (128 heads over a
+# 640-lane latent pool whose first 512 lanes are the values, 2048 selected a
+# token) and the `deepseek-v3.2-ep16-d5.longctx` cell's step — 5 one-token
+# rows, then one 507-token prefill span, every sequence at one context.
+LAT_H, LAT_LANES, LAT_RANK, LAT_TOPK = 128, 640, 512, 2048
+LAT_ROWS, LAT_SPAN, LAT_LAUNCHES = 5, 507, 32
+LAT_CONTEXTS = (4096, 8192, 12288, 16384)
+# Stream tokens the jnp twin is asked for (it gathers [tokens, C, 640]
+# float32): the one-token rows, the span's first and last tokens, tokens at
+# both sides of a tile's edge and of the tile's halves.
+LAT_CHECKED = (0, 1, 2, 3, 4, 5, 12, 13, 15, 16, 23, 24, 255, 256, 511)
 
 
 def f32_matmul_keeps() -> dict:
@@ -168,6 +193,17 @@ def walks(traffic, kv_len, q_start, q_len, T) -> list:
     return out
 
 
+def best_of_three(fn, *args) -> float:
+    """Seconds a call of a jitted `fn`, the least of three after one."""
+    fn(*args).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        fn(*args).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
 def timed(fn, q, *args) -> float:
     """Seconds a launch: LAUNCHES calls chained through q in one jit."""
     @jax.jit
@@ -175,13 +211,150 @@ def timed(fn, q, *args) -> float:
         return jax.lax.fori_loop(
             0, LAUNCHES, lambda _, q: fn(q, *args).astype(q.dtype), q)
 
-    chain(q, *args).block_until_ready()
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        chain(q, *args).block_until_ready()
-        best = min(best, time.perf_counter() - t0)
-    return best / LAUNCHES
+    return best_of_three(chain, q, *args) / LAUNCHES
+
+
+@contextlib.contextmanager
+def constants(consts):
+    """The module constants of a `--set` in place for the block (and the
+    jit caches cleared around it); yields their names for a row."""
+    was = {k: getattr(*k) for k in consts}
+    for (mod, attr), value in consts.items():
+        setattr(mod, attr, value)
+    if consts:
+        jax.clear_caches()
+    try:
+        yield {f"{m.__name__.rsplit('.', 1)[1]}.{a}": v
+               for (m, a), v in consts.items()}
+    finally:
+        for (mod, attr), value in was.items():
+            setattr(mod, attr, value)
+        if consts:
+            jax.clear_caches()
+
+
+def latent_trips(q_start, q_len, kv_len, T, tile, block):
+    """(span trips, one-token trips) of an attention launch that walks
+    tiles of `tile` tokens in blocks of `block`: a tile walks each sequence
+    with a row in it up to the tile's deepest causal frontier; a sequence's
+    ONE row in a tile is the kernel's one-token trip."""
+    span = one = 0
+    for lo in range(0, T, tile):
+        hi = lo + tile
+        for qs, ql, kv in zip(q_start, q_len, kv_len):
+            if ql > 0 and qs < hi and qs + ql > lo:
+                first, last = max(qs, lo), min(hi, qs + ql)
+                n = -(-int(kv - ql + last - qs) // block)
+                if last - first == 1 and tile > 1:
+                    one += n
+                else:
+                    span += n
+    return span, one
+
+
+def latent_batch(rows, mp):
+    """(page_table, q_start, q_len, kv_len), tok_seq, tok_pos, T of `rows`
+    = (tokens, cached prefix) a sequence, each on pages of its own."""
+    B, T = 8, sum(n for n, _ in rows)
+    pt = np.zeros((B, mp), np.int32)
+    q_len, kv_len = np.zeros(B, np.int32), np.zeros(B, np.int32)
+    q_start = np.full(B, T, np.int32)
+    tok_seq, tok_pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
+    off = 0
+    for s, (n, prefix) in enumerate(rows):
+        need = -(-(prefix + n) // PS)
+        pt[s, :need] = 1 + s * mp + np.arange(need)
+        q_len[s], kv_len[s], q_start[s] = n, prefix + n, off
+        tok_seq[off:off + n] = s
+        tok_pos[off:off + n] = prefix + np.arange(n)
+        off += n
+    return (pt, q_start, q_len, kv_len), tok_seq, tok_pos, T
+
+
+def latent(args, variants) -> None:
+    """The latent-attention kernel's rows: for each context the cell's step
+    (`latent`) and its one-token rows by themselves (`latent_rows`, which
+    prices a one-token trip), each checked against ops/mla.sparse_attention
+    on LAT_CHECKED and timed over LAT_LAUNCHES launches chained in one jit
+    (each one's layer index a function of the last one's output). With the
+    one-token trips taken off a launch, `us_a_tile_block` is a (tile,
+    block) trip of the span at the variant's own tile and block, and
+    `us_a_16_256` the same time over the trips tiles of 16 tokens and
+    blocks of 256 would make: what tiles and widths are compared by (it
+    carries a wider block's over-read)."""
+    ka = mla_attention
+    mp = max(args.contexts or LAT_CONTEXTS) // PS
+    key = jax.random.PRNGKey(args.seed)
+    pool = (jax.random.normal(
+        key, (2, (1 + (LAT_ROWS + 1) * mp) * PS, LAT_LANES), jnp.float32)
+        * 0.3).astype(jnp.bfloat16).at[:, :, 576:].set(0)
+
+    def fn(layer, q, scores, thr, pool, pt, qs, ql, kl):
+        return ka.mla_sparse_paged_attention_pallas(
+            q, scores, thr, pool, layer, pt, qs, ql, kl, PS, LAT_RANK)
+
+    def chain(*operands):
+        def body(_, layer):
+            o = fn(layer, *operands)
+            return LAYER + jnp.isnan(o[0, 0, 0]).astype(jnp.int32)
+        return jax.lax.fori_loop(0, LAT_LAUNCHES, body, jnp.int32(LAYER))
+
+    for context in args.contexts or LAT_CONTEXTS:
+        us_one = {}  # a variant's one-token trip, from its `latent_rows`
+        for traffic, spans in (("latent_rows", ()), ("latent", (LAT_SPAN,))):
+            meta, tok_seq, tok_pos, T = latent_batch(
+                [(1, context - 1)] * LAT_ROWS
+                + [(n, context - n) for n in spans], mp)
+            k1, k2 = jax.random.split(jax.random.fold_in(key, context))
+            q = (jax.random.normal(k1, (T, LAT_H, LAT_LANES), jnp.float32)
+                 * 0.1).astype(jnp.bfloat16)
+            checked = np.asarray([t for t in LAT_CHECKED if t < T])
+            for consts in variants:
+                with constants(consts) as names:
+                    tile = getattr(ka, "ATTEND_TILE", ka.TILE)
+                    block = getattr(ka, "ATTEND_BLOCK", ka.BLOCK)
+                    span, one = latent_trips(*meta[1:], T, tile, block)
+                    row = {"shape": [LAT_H, LAT_LANES, LAT_RANK],
+                           "traffic": traffic, "tokens": T,
+                           "context": context, "tile": tile, "block": block,
+                           "trips_span": span, "trips_one": one,
+                           "set": names}
+                    try:
+                        scores = jax.random.normal(
+                            k2, (T, ka.context_lanes(mp, PS)), jnp.float32)
+                        thr = mla.select_threshold(
+                            scores, jnp.asarray(tok_pos), LAT_TOPK)
+                        operands = (q, scores, thr, pool,
+                                    *(jnp.asarray(a) for a in meta))
+                        out = np.asarray(fn(LAYER, *operands)[checked],
+                                         np.float32)
+                        ref = np.asarray(mla.sparse_attention(
+                            q[checked], scores[checked], thr[checked], pool,
+                            LAYER, operands[4], jnp.asarray(tok_seq[checked]),
+                            jnp.asarray(tok_pos[checked]), PS, LAT_RANK),
+                            np.float32)
+                        us = best_of_three(jax.jit(chain), *operands) \
+                            / LAT_LAUNCHES * 1e6
+                        diff = float(np.abs(out - ref).max())
+                        row.update({
+                            "ms_a_launch": round(us / 1e3, 4),
+                            "max_abs_diff_vs_jnp": diff,
+                            "within_tolerance": bool(diff <= 2 ** -8 * max(
+                                1.0, np.abs(ref).max())),
+                            "finite": bool(np.isfinite(out).all())})
+                        if traffic == "latent_rows":
+                            us_one[str(names)] = us / one
+                            row["us_a_one_token_trip"] = round(us / one, 4)
+                        else:
+                            span_us = us - one * us_one[str(names)]
+                            span_16_256, _ = latent_trips(*meta[1:], T, 16,
+                                                          256)
+                            row["us_a_tile_block"] = round(span_us / span, 4)
+                            row["us_a_16_256"] = round(
+                                span_us / span_16_256, 4)
+                    except Exception as e:  # noqa: BLE001 — as in main()
+                        row["error"] = str(e)[:300]
+                    print(json.dumps(row), flush=True)
 
 
 def parse_set(text) -> dict:
@@ -206,7 +379,8 @@ def main() -> int:
                     metavar="MODULE.NAME=VALUE[,…]", dest="variants",
                     help="one more row a (shape, traffic) with these "
                          "constants of kv_contract / paged_attention / "
-                         "ragged_attention set (the served inner product)")
+                         "ragged_attention / mla_attention set (the served "
+                         "inner product)")
     ap.add_argument("--only-set", action="store_true",
                     help="leave out the rows of the tree as it stands")
     ap.add_argument("--contexts", type=int, nargs="*", default=[],
@@ -217,6 +391,13 @@ def main() -> int:
                     help="first, what a page copy costs with nothing to "
                          "hide it behind (latency, issue), at 256 and "
                          "512 lanes")
+    ap.add_argument("--traffic", nargs="*",
+                    default=["decode", "ragged64", "ragged512"],
+                    choices=["decode", "ragged64", "ragged512", "latent"],
+                    help="`latent`: the latent-attention kernel on the "
+                         "DeepSeek-V3.2 cell's step, a row a context "
+                         "(--contexts, default 4096 8192 12288 16384) and "
+                         "a --set mla_attention.NAME=VALUE")
     ap.add_argument("--shapes", type=int, nargs="*",
                     default=list(range(len(SHAPES))),
                     help="indices into SHAPES")
@@ -232,13 +413,16 @@ def main() -> int:
             for row in dma_probe(lanes):
                 print(json.dumps(row), flush=True)
     rng = np.random.default_rng(args.seed)
+    if "latent" in args.traffic:
+        latent(args, ([] if args.only_set else [{}]) + args.variants)
     variants = [] if args.only_set else [("vpu", {}), ("mxu", {})]
     variants += [(None, v) for v in args.variants]
     traffics = [("decode", 64, (), c) for c in args.contexts or [None]]
     traffics += [("ragged64", 64, (), c) for c in args.contexts or [None]]
     if not args.contexts:
         traffics.append(("ragged512", 56, (228, 228), None))
-    for H, Hk, hd in (SHAPES[i] for i in args.shapes):
+    traffics = [t for t in traffics if t[0] in args.traffic]
+    for H, Hk, hd in (SHAPES[i] for i in args.shapes if traffics):
         kc, vc = (jnp.asarray(rng.standard_normal((2, NP * PS, Hk * hd)),
                               jnp.bfloat16) for _ in range(2))
         for traffic, n_dec, spans, context in traffics:
@@ -256,55 +440,50 @@ def main() -> int:
             for inner, consts in variants:
                 if traffic != "decode" and inner == "vpu":
                     continue  # the ragged kernel has one inner product
-                was = {k: getattr(*k) for k in consts}
-                for (mod, attr), value in consts.items():
-                    setattr(mod, attr, value)
-                if consts:
-                    jax.clear_caches()
-                built = kv_contract.make_inner(
-                    inner if traffic == "decode" else None,
-                    rows=1 if traffic == "decode" else kv_contract.G_TILE,
-                    group=H // Hk, num_kv_heads=Hk, head_dim=hd,
-                    page_size=PS)
-                bp = built.block_pages
-                pages = walks(traffic, kv_len, q_start, q_len, T)
-                blocks = sum(-(-n // bp) for n in pages)
-                if traffic == "decode":
-                    def fn(q, kc, vc, pt, kv_len, inner=inner):
-                        return paged_attention.paged_decode_attention_pallas(
-                            q, kc, vc, LAYER, pt, kv_len, PS, inner=inner)
-                    operands = (kc, vc, pt, kv_len)
-                else:
-                    def fn(q, kc, vc, pt, qs, ql, kl):
-                        return ragged_attention.ragged_paged_attention_pallas(
-                            q, kc, vc, LAYER, pt, qs, ql, kl, PS)
-                    operands = (kc, vc, pt, q_start, q_len, kv_len)
-                row = {
-                    "shape": [H, Hk, hd], "traffic": traffic, "tokens": T,
-                    "context": context or "200-380",
-                    "inner": built.name,
-                    "set": {f"{m.__name__.rsplit('.', 1)[1]}.{a}": v
-                            for (m, a), v in consts.items()}}
-                try:
-                    out = np.asarray(fn(q, *operands), np.float32)
-                    ms = timed(fn, q, *operands) * 1e3
-                    row.update({
-                        "ms_a_launch": round(ms, 4),
-                        "pages_live": sum(pages),
-                        "pages_read": sum(-(-n // bp) * bp for n in pages),
-                        "us_a_seq_block": round(ms * 1e3 / blocks, 4),
-                        "max_abs_diff_vs_jnp": float(np.abs(
-                            out - np.asarray(ref, np.float32)).max()),
-                        "finite": bool(np.isfinite(out).all()),
-                    })
-                except Exception as e:  # noqa: BLE001 — a variant the
-                    # compiler refuses is a row, not the end of the run
-                    row["error"] = str(e)[:300]
-                print(json.dumps(row), flush=True)
-                for (mod, attr), value in was.items():
-                    setattr(mod, attr, value)
-                if consts:
-                    jax.clear_caches()
+                with constants(consts) as names:
+                    built = kv_contract.make_inner(
+                        inner if traffic == "decode" else None,
+                        rows=1 if traffic == "decode" else kv_contract.G_TILE,
+                        group=H // Hk, num_kv_heads=Hk, head_dim=hd,
+                        page_size=PS)
+                    bp = built.block_pages
+                    pages = walks(traffic, kv_len, q_start, q_len, T)
+                    blocks = sum(-(-n // bp) for n in pages)
+                    if traffic == "decode":
+                        def fn(q, kc, vc, pt, kv_len, inner=inner):
+                            launch = \
+                                paged_attention.paged_decode_attention_pallas
+                            return launch(q, kc, vc, LAYER, pt, kv_len, PS,
+                                          inner=inner)
+                        operands = (kc, vc, pt, kv_len)
+                    else:
+                        def fn(q, kc, vc, pt, qs, ql, kl):
+                            launch = \
+                                ragged_attention.ragged_paged_attention_pallas
+                            return launch(q, kc, vc, LAYER, pt, qs, ql, kl,
+                                          PS)
+                        operands = (kc, vc, pt, q_start, q_len, kv_len)
+                    row = {
+                        "shape": [H, Hk, hd], "traffic": traffic, "tokens": T,
+                        "context": context or "200-380",
+                        "inner": built.name,
+                        "set": names}
+                    try:
+                        out = np.asarray(fn(q, *operands), np.float32)
+                        ms = timed(fn, q, *operands) * 1e3
+                        row.update({
+                            "ms_a_launch": round(ms, 4),
+                            "pages_live": sum(pages),
+                            "pages_read": sum(-(-n // bp) * bp for n in pages),
+                            "us_a_seq_block": round(ms * 1e3 / blocks, 4),
+                            "max_abs_diff_vs_jnp": float(np.abs(
+                                out - np.asarray(ref, np.float32)).max()),
+                            "finite": bool(np.isfinite(out).all()),
+                        })
+                    except Exception as e:  # noqa: BLE001 — a variant the
+                        # compiler refuses is a row, not the end of the run
+                        row["error"] = str(e)[:300]
+                    print(json.dumps(row), flush=True)
     return 0
 
 

@@ -431,7 +431,10 @@ def test_the_threshold_is_the_kth_largest_exactly(topk):
 
 # (spans = (tokens, context at the span's end) a row, tile, heads). With 8
 # heads a token's row-heads are a whole sublane tile, and a one-token row in
-# a tile of other sequences' tokens takes the kernel's path of its own.
+# a tile of other sequences' tokens takes the kernel's path of its own. The
+# attention kernel folds a tile as chains of CHAIN (8) tokens — a tile of 16
+# as two halves, its own tile of 32 as four — over its own block width: the
+# later mixes are what that trip can get wrong.
 MIXES = {
     "prefill_and_decode": ([(40, 300), (1, 150), (1, 77), (3, 20)], 16, 4),
     "one_token_rows_alone": ([(40, 300), (1, 150), (1, 77), (3, 20), (1, 9)],
@@ -439,24 +442,54 @@ MIXES = {
     "tiles_of_8": ([(16, 16), (1, 150), (9, 80)], 8, 8),
     "decode_rows": ([(1, 300), (1, 150), (1, 77), (1, 20), (1, 1)], 1, 4),
     "a_long_span": ([(64, 64)], 16, 4),
+    # each half of the one tile is another sequence's, at other depths
+    "halves_of_two_sequences": ([(8, 700), (8, 150)], 16, 8),
+    # 5 live rows: the second half of the tile has none
+    "a_half_with_no_live_row": ([(5, 600)], 16, 4),
+    # the second tile holds 5 tokens of the span and nothing else
+    "a_span_ending_inside_the_first_half": ([(21, 540)], 16, 8),
+    # the deepest frontier lies inside the last page of a block
+    "a_frontier_inside_a_blocks_last_page": ([(12, 1020), (4, 508)],
+                                              16, 4),
+    # positions 10..24 of one block: -inf thresholds up to 15, then real ones
+    "crossing_index_topk_inside_one_block": ([(15, 25), (1, 16), (1, 17)],
+                                             16, 8),
+    # a one-token row and a span share a tile, both past two blocks
+    "a_one_token_row_beside_a_span": ([(1, 700), (15, 1100)], 16, 8),
+    # the decode scan's launch: a tile a token, contexts at a block's edges
+    "the_scans_tiles_of_one": ([(1, 1100), (1, 513), (1, 512), (1, 40)], 1,
+                               8),
+    "the_trash_page_at_the_largest_finite": ([(40, 300), (1, 150), (9, 530)],
+                                             16, 8),
+    # the ragged step's own launch: four chains a tile, one-token rows in
+    # the first, a span over two tiles, a short one ending inside a chain
+    "four_chains_a_tile_of_32": ([(1, 150), (1, 77), (45, 700), (3, 20)],
+                                 None, 8),
 }
+# What the trash page holds (latent rows; the index keys hold its negative):
+# a walk's last block reads it past the sequence's last page, and masks it.
+POISON = {"the_trash_page_at_the_largest_finite":
+          float(jnp.finfo(jnp.bfloat16).max)}
 
 
 @pytest.mark.parametrize("mix", sorted(MIXES))
 def test_the_pallas_kernels_match_their_twins_in_interpret_mode(mix):
     """index, select, attend over paged pools whose pages are scattered,
-    contexts past one 256-token block, spans that share a tile with rows
-    of other sequences — and the trash page poisoned with large finite
+    contexts past one block of either walk, spans that share a tile with
+    rows of other sequences — and the trash page poisoned with large finite
     values (its rows are read past a walk's last page and masked)."""
     spans, tile, H = MIXES[mix]
     rng = np.random.default_rng(0)
-    L, ps, n_pages, mp = 2, 8, 96, 40
+    L, ps = 2, 8
+    mp = max(40, max(-(-kv // ps) for _, kv in spans))
+    n_pages = max(96, 1 + sum(-(-kv // ps) for _, kv in spans))
     lanes, rank, Hi, di, topk = 128, 32, 4, 16, 16
+    poison = POISON.get(mix, 3e4)
     lat = jnp.asarray(rng.standard_normal((L, n_pages * ps, lanes)) * 0.3,
                       jnp.bfloat16).at[:, :, 40:].set(0)
-    lat = lat.at[:, :ps].set(3e4)
+    lat = lat.at[:, :ps].set(poison)
     idx = jnp.asarray(rng.standard_normal((L, n_pages * ps, di)),
-                      jnp.bfloat16).at[:, :ps].set(-3e4)
+                      jnp.bfloat16).at[:, :ps].set(-poison)
     rows = max(5, len(spans))
     pt = np.zeros((rows, mp), np.int32)
     perm = rng.permutation(np.arange(1, n_pages))
@@ -470,7 +503,7 @@ def test_the_pallas_kernels_match_their_twins_in_interpret_mode(mix):
         ts += [r] * n
         tp += list(range(kv - n, kv))
     T = len(ts)
-    Tp = -(-T // 16) * 16
+    Tp = -(-T // 32) * 32
     ts += [0] * (Tp - T)
     tp += [-1] * (Tp - T)
     while len(qs) < rows:
