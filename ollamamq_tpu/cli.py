@@ -75,11 +75,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "pays; one compile per padded total)")
     p.add_argument("--spec", action="store_true", default=False,
                    help="speculative multi-token decoding on the ragged "
-                        "path: n-gram prompt-lookup drafts (up to "
-                        "--spec-k per greedy decode slot) verified in "
-                        "one ragged dispatch; accepted drafts emit "
-                        "together, rejected drafts' KV pages roll back. "
-                        "Greedy streams stay byte-identical to --no-spec")
+                        "path: drafts (up to --spec-k per greedy decode "
+                        "slot) verified in one ragged dispatch; accepted "
+                        "drafts emit together, rejected drafts' KV pages "
+                        "roll back. The proposer is the model's own "
+                        "multi-token-prediction module where it has one "
+                        "(num_nextn_predict_layers: one draft a step, "
+                        "computed on the device), n-gram prompt lookup "
+                        "otherwise. Greedy streams stay byte-identical "
+                        "to --no-spec")
     p.add_argument("--no-spec", dest="spec", action="store_false",
                    help="disable speculative decoding (the default)")
     p.add_argument("--spec-k", type=int, default=4,
@@ -674,7 +678,7 @@ def main(argv=None) -> int:
         served = get_model_config(name)
         latent_err = served and validate_latent_pool(
             served, kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype,
-            spec=args.spec, prefix_cache=args.prefix_cache,
+            prefix_cache=args.prefix_cache,
             mesh_shape={"seq": args.sp, "tensor": args.tp,
                         "expert": args.ep})
         if latent_err:

@@ -46,7 +46,9 @@ class ModelConfig:
     `num_dense_layers` of a sparse stack keep a dense FFN, and the router's
     score, selection bias, normalisation and scale are fields
     (`layer_plan()` is what the forwards scan). `norm_order` "post" moves
-    each norm from a sublayer's input to its output: `x + norm(Op(x))`."""
+    each norm from a sublayer's input to its output: `x + norm(Op(x))`;
+    `sandwich_norm` keeps the input norm and adds one on the output:
+    `x + norm'(Op(norm(x)))`."""
 
     name: str
     vocab_size: int
@@ -124,6 +126,10 @@ class ModelConfig:
     # "pre": x + Op(norm(x)) (Llama's). "post": x + norm(Op(x)) (OLMo 2's
     # reordered norm: `attn_norm` / `mlp_norm` weigh the sublayers' OUTPUTS).
     norm_order: str = "pre"
+    # openPangu's published key: a SECOND norm a sublayer, on its output
+    # (`post_attn_norm`, `post_mlp_norm`), beside the pre-norm on its input:
+    # x + norm'(Op(norm(x))). With `norm_order` "pre" only.
+    sandwich_norm: bool = False
     # The published `rope_parameters` group of a configuration file, taken
     # whole: its `rope_theta` (null: no rotary embedding) sets the field of
     # that name; any other key of the group is refused.
@@ -147,8 +153,9 @@ class ModelConfig:
     # every cached position of a token's sequence (ReLU, a learned weight a
     # head, summed), its key one row of `index_head_dim` lanes a token in a
     # second paged pool; attention sees the `index_topk` best positions (all
-    # of them while the context is shorter). Latent attention is served
-    # with it only.
+    # of them while the context is shorter). All three 0: latent attention
+    # with no indexer (DeepSeek-V3's, openPangu's) — every cached position
+    # is attended and there is no index-key pool.
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -176,11 +183,18 @@ class ModelConfig:
     # `num_dense_layers` (either or both, agreeing); every layer after the
     # dense ones has experts (`moe_layer_freq` 1); `ep_size` is the published
     # file's own (1: the share held here is said by the two fields above);
-    # the multi-token-prediction module (`num_nextn_predict_layers`) draws
-    # drafts and is no part of the next-token distribution: not served (0).
     first_k_dense_replace: Optional[int] = None
     moe_layer_freq: int = 1
     ep_size: int = 1
+    # The multi-token-prediction module (DeepSeek-V3's formulation, depth 1):
+    # from the trunk's last hidden of position i (before the final norm) and
+    # the embedding of token i + 1, through two norms, a projection of their
+    # concatenation and ONE more block of the stack's last kind with its own
+    # cache rows, the trunk's head predicts token i + 2 (models/llama.py:
+    # forward_mtp). No part of the next-token distribution: `--spec` serves
+    # it as the draft proposer; without `--spec` it is held and not run.
+    # 0 (no module) or 1, and 1 with latent attention (no indexer) and
+    # experts only.
     num_nextn_predict_layers: int = 0
 
     def __post_init__(self):
@@ -237,8 +251,7 @@ class ModelConfig:
                     f"{self.num_dense_layers}")
             object.__setattr__(self, "num_dense_layers",
                                self.first_k_dense_replace)
-        for key, only in (("moe_layer_freq", 1), ("ep_size", 1),
-                          ("num_nextn_predict_layers", 0)):
+        for key, only in (("moe_layer_freq", 1), ("ep_size", 1)):
             if getattr(self, key) != only:
                 raise ValueError(
                     f"{self.name}: {key} {getattr(self, key)}: the program "
@@ -260,6 +273,7 @@ class ModelConfig:
                                tuple(sorted(group.items())))
         self._check_latent()
         self._check_share()
+        self._check_sandwich_and_module()
         if not 0 <= self.num_dense_layers <= self.num_layers:
             raise ValueError(
                 f"{self.name}: num_dense_layers {self.num_dense_layers} is "
@@ -277,8 +291,9 @@ class ModelConfig:
         """What latent attention and its indexer cannot run with."""
         widths = (self.q_lora_rank, self.qk_nope_head_dim,
                   self.qk_rope_head_dim, self.v_head_dim)
+        has = (self.index_n_heads, self.index_head_dim, self.index_topk)
         if not self.kv_lora_rank:
-            if any(widths) or self.index_topk or self.index_n_heads:
+            if any(widths) or any(has):
                 raise ValueError(
                     f"{self.name}: q_lora_rank, qk_*_head_dim, v_head_dim "
                     "and index_* belong to latent attention: kv_lora_rank "
@@ -300,12 +315,34 @@ class ModelConfig:
                 f"{self.name}: latent attention is served with as many kv "
                 "heads as heads, no attention bias, no q/k head norm, "
                 "pre-norm, a rotary embedding and attention in every layer")
-        has = (self.index_n_heads, self.index_head_dim, self.index_topk)
-        if min(has) < 1 or self.index_head_dim < self.qk_rope_head_dim:
+        if any(has) and (min(has) < 1
+                         or self.index_head_dim < self.qk_rope_head_dim):
             raise ValueError(
-                f"{self.name}: latent attention is served with its indexer: "
-                "index_n_heads, index_topk of at least 1 and index_head_dim "
-                f"of at least qk_rope_head_dim; got {has}")
+                f"{self.name}: an indexer needs index_n_heads, index_topk of "
+                "at least 1 and index_head_dim of at least qk_rope_head_dim "
+                f"(all three 0: no indexer); got {has}")
+
+    def _check_sandwich_and_module(self) -> None:
+        """Sandwich norms and the multi-token-prediction module."""
+        if self.sandwich_norm and self.norm_order != "pre":
+            raise ValueError(
+                f"{self.name}: sandwich_norm adds an output norm to the "
+                f"pre-norm block; norm_order is {self.norm_order!r}")
+        n = self.num_nextn_predict_layers
+        if n not in (0, 1):
+            raise ValueError(
+                f"{self.name}: num_nextn_predict_layers {n}: the program "
+                "serves a prediction module of depth 1 (one draft a step), "
+                "or none (0)")
+        if n and not (self.kv_lora_rank and self.num_experts
+                      and self.num_dense_layers < self.num_layers
+                      and not self.index_topk):
+            raise ValueError(
+                f"{self.name}: num_nextn_predict_layers 1: the prediction "
+                "module is one more latent-attention expert layer "
+                "(kv_lora_rank, num_experts, a layer after the dense ones) "
+                "and is served with no indexer (its block has rows in the "
+                "latent pool only)")
 
     def _check_share(self) -> None:
         """Group-limited routing, shared experts and the share held here."""
@@ -390,9 +427,16 @@ class ModelConfig:
         return -(-self.latent_dim // 128) * 128
 
     @property
+    def cache_layers(self) -> int:
+        """Layers of the paged pools: the attention layers, and behind them
+        the prediction module's block (its own cache rows)."""
+        return self.count(ATTENTION) + self.num_nextn_predict_layers
+
+    @property
     def kv_row_dims(self) -> tuple:
         """Lanes a token a layer in each of the two paged pools: K and V
-        rows, or with latent attention the latent row and the index key."""
+        rows, or with latent attention the latent row and the index key
+        (0 lanes: no indexer, no second pool)."""
         if self.kv_lora_rank:
             return self.latent_lanes, self.index_head_dim
         return self.kv_dim, self.kv_dim
@@ -483,10 +527,12 @@ class ModelConfig:
             attention = (
                 d * r + r + r * self.q_dim + d * self.latent_dim + c
                 + c * H * (self.qk_nope_head_dim + self.v_head_dim)
-                + H * self.v_head_dim * d
+                + H * self.v_head_dim * d)
+            if self.index_topk:
                 # the indexer: q, k with its LayerNorm, the head weights
-                + r * self.index_n_heads * self.index_head_dim
-                + (d + 2) * self.index_head_dim + d * self.index_n_heads)
+                attention += (
+                    r * self.index_n_heads * self.index_head_dim
+                    + (d + 2) * self.index_head_dim + d * self.index_n_heads)
         per_op = {
             ATTENTION: attention,
             CONV: 3 * d * d + d * d + d * self.conv_L_cache,
@@ -506,8 +552,14 @@ class ModelConfig:
                       * self.expert_width + d * self.router_width
                       + self.router_width * self.use_expert_bias),
         }
-        layers = sum(per_op[op] + per_ffn[ffn] + 2 * d
+        norms = (4 if self.sandwich_norm else 2) * d
+        layers = sum(per_op[op] + per_ffn[ffn] + norms
                      for op, ffn in self.kinds)
+        if self.num_nextn_predict_layers:
+            # the prediction module: one more block of the last kind, the
+            # norms of its two inputs and of its output, the projection
+            layers += (per_op[ATTENTION] + per_ffn[EXPERTS] + norms
+                       + 3 * d + 2 * d * d)
         embed = v * d * (1 if self.tie_embeddings else 2)
         return layers + embed + d
 
@@ -704,6 +756,23 @@ MODEL_CONFIGS = {
         moe_intermediate_size=32, first_k_dense_replace=1,
         router_score="sigmoid", use_expert_bias=True, norm_topk_prob=True,
         norm_topk_eps=1e-20, routed_scaling_factor=2.5,
+    ),
+    # Tiny openPangu-Ultra-MoE: latent attention with NO indexer and plain
+    # RoPE, sandwich norms, a dense layer then expert layers with a shared
+    # expert, a sigmoid router with no groups and no bias over 16 experts of
+    # which this program holds 4, and the multi-token-prediction module
+    # (one more block, its own cache rows; `--spec` drafts with it).
+    "test-tiny-openpangu": ModelConfig(
+        name="test-tiny-openpangu", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=24, rope_theta=10_000.0, rms_norm_eps=1e-5, max_seq_len=512,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, sandwich_norm=True,
+        num_experts=4, router_experts=16, expert_offset=0,
+        num_experts_per_tok=4, n_shared_experts=1, moe_intermediate_size=32,
+        first_k_dense_replace=1, router_score="sigmoid", norm_topk_prob=True,
+        norm_topk_eps=1e-20, routed_scaling_factor=2.5,
+        num_nextn_predict_layers=1,
     ),
 }
 
@@ -1187,7 +1256,7 @@ def validate_slot_state(cfg: ModelConfig, spec: bool = False,
     why = None
     if spec:
         why = ("--spec: a rejected draft has already advanced the per-slot "
-               "state, and rollback restores pages only")
+               "conv / recurrent state, and rollback restores pages only")
     elif shape.get("seq", 1) > 1:
         why = ("--sp: a convolution over a sequence sharded along T needs "
                "a halo exchange the ring prefill does not make")
@@ -1201,14 +1270,17 @@ def validate_slot_state(cfg: ModelConfig, spec: bool = False,
 
 
 def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",
-                         weights_dtype: str = "bfloat16", spec: bool = False,
+                         weights_dtype: str = "bfloat16",
                          prefix_cache: bool = False,
                          mesh_shape=None) -> Optional[str]:
     """What a model with latent attention (`kv_lora_rank`: a latent pool
-    and an index-key pool where K and V were) cannot be served with yet,
-    told BEFORE any device work: returns an error string (None = valid).
-    Each of these knows K and V pools of kv_heads x head_dim lanes only
-    (ROADMAP B-M3 names what each lacks)."""
+    and, with an indexer, an index-key pool where K and V were) cannot be
+    served with yet, told BEFORE any device work: returns an error string
+    (None = valid). Each of these knows K and V pools of kv_heads x head_dim
+    lanes only (ROADMAP B-M3 names what each lacks). `--spec` is served: a
+    verify span is a ragged span like any other, its logits read a draft
+    position through the latent pool, a rejected draft's rows lie past the
+    rolled-back length."""
     if not cfg.kv_lora_rank:
         return None
     shape = dict(mesh_shape or {})
@@ -1219,9 +1291,6 @@ def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",
     elif weights_dtype != "bfloat16":
         why = ("--weights-dtype int8: the low-rank projections are "
                "absorbed into q and the output in bfloat16")
-    elif spec:
-        why = ("--spec: the verify span reads a logit a draft position "
-               "through K and V pools")
     elif prefix_cache:
         why = ("--prefix-cache: the radix tree shares K and V pages, not "
                "latent and index-key pages")

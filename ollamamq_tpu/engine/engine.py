@@ -481,11 +481,17 @@ class ModelRuntime:
     # What `_note_latent` writes onto a step's sample, in order.
     LATENT_FIELDS = ("mla_rows", "dsa_ctx_tokens", "dsa_selected_tokens",
                      "dsa_step_ctx_tokens", "dsa_step_selected_tokens")
+    # ...and for latent attention with no indexer (nothing is scored or
+    # selected: the `dsa_*` fields have no honest value there).
+    DENSE_LATENT_FIELDS = ("mla_rows", "mla_pairs", "mla_ctx_rows")
 
     # Engine performance plane (telemetry/stepprof.py): the per-step
     # "paid a compile" flag (_sp_note_compile sets, the step's finish
     # read-and-clears). A step's timer rides its StepInFlight handle.
     _stepprof_compiled = False
+    # Is this runtime's --spec proposer the model's own prediction module
+    # (set in __init__; False for a runtime built without it)?
+    mtp = False
     # The owning engine thread's loop clock (stepprof.LoopClock), attached
     # by _attach_hooks: step timers advance it, so step and loop phases
     # form one gapless chain. None (bench, unit tests) times steps alone.
@@ -528,7 +534,7 @@ class ModelRuntime:
             raise ValueError(err)
         err = validate_latent_pool(
             model_cfg, kv_dtype=engine_cfg.kv_dtype,
-            weights_dtype=engine_cfg.weights_dtype, spec=engine_cfg.spec,
+            weights_dtype=engine_cfg.weights_dtype,
             prefix_cache=engine_cfg.prefix_cache,
             mesh_shape=dict(mesh.shape) if mesh is not None else {})
         if err is not None:
@@ -738,6 +744,20 @@ class ModelRuntime:
         # rate gauge and the per-user auto-throttle; the actual accept/
         # rollback machinery lives in _get_ragged_jit / step_ragged.
         self.spec = bool(engine_cfg.spec) and engine_cfg.spec_k > 0
+        # The proposer: the model's own multi-token-prediction module where
+        # it has one — ONE draft a row, computed on the device inside the
+        # step that verifies the last (llama.forward_mtp) and kept there:
+        # `draft_ids[slot]` (row S: the padding rows') is what the module
+        # predicts to follow the slot's next input token, a donated argument
+        # and result of every ragged step like `last_ids`; the host never
+        # sees a draft, only how many were accepted. `_draft_ok[slot]`: a
+        # step with the module has left the slot's draft there. N-gram
+        # prompt lookup on the host otherwise.
+        self.mtp = self.spec and model_cfg.num_nextn_predict_layers > 0
+        self.spec_k = 1 if self.mtp else engine_cfg.spec_k
+        self.draft_ids = (jnp.zeros((engine_cfg.max_slots + 1,), jnp.int32)
+                          if self.mtp else None)
+        self._draft_ok = np.zeros((engine_cfg.max_slots,), bool)
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_rollbacks = 0
@@ -746,12 +766,13 @@ class ModelRuntime:
         # speculating (the verify FLOPs stopped paying for themselves).
         self._spec_user: Dict[str, list] = {}
         self._spec_throttled: set = set()
+        proposer = self.proposer = "mtp" if self.mtp else "ngram"
         self._tm_spec_prop = tm.SPEC_TOKENS_TOTAL.labels(
-            model=name, outcome="proposed")
+            model=name, outcome="proposed", proposer=proposer)
         self._tm_spec_acc = tm.SPEC_TOKENS_TOTAL.labels(
-            model=name, outcome="accepted")
+            model=name, outcome="accepted", proposer=proposer)
         self._tm_spec_rej = tm.SPEC_TOKENS_TOTAL.labels(
-            model=name, outcome="rejected")
+            model=name, outcome="rejected", proposer=proposer)
         self._tm_spec_rate = tm.SPEC_ACCEPT_RATE.labels(model=name)
 
         # Telemetry.
@@ -848,6 +869,12 @@ class ModelRuntime:
             log.info("%s: latent pool %s %.1f MB, index-key pool %s %.1f MB",
                      name, self.kc.shape, self.kc.nbytes / 1e6,
                      self.vc.shape, self.vc.nbytes / 1e6)
+            if model_cfg.num_nextn_predict_layers:
+                log.info("%s: prediction module held (its block's rows are "
+                         "layer %d of the latent pool); %s", name,
+                         model_cfg.count(ATTENTION),
+                         "--spec drafts with it, on the device" if self.mtp
+                         else "not run without --spec")
         self._tm_dsa = [c.labels(model=name) for c in (
             tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
             tm.DSA_SELECTED_TOKENS_TOTAL)]
@@ -964,8 +991,14 @@ class ModelRuntime:
         lay = self._ragged_layout(T_pad)
         fn = self._get_ragged_jit(
             T_pad, k_cap, sampling_flags(*lay.sampling(buf)))
-        return fn(self.params, self._upload(buf), self.kc, self.vc,
-                  self.recent, self.last_ids, self.slot_state)
+        if not self.mtp:
+            return fn(self.params, self._upload(buf), self.kc, self.vc,
+                      self.recent, self.last_ids, self.slot_state)
+        # The module's drafts stay on the device: one more carry.
+        *out, self.draft_ids = fn(
+            self.params, self._upload(buf), self.kc, self.vc, self.recent,
+            self.last_ids, self.slot_state, self.draft_ids)
+        return tuple(out)
 
     def _ragged_layout(self, T_pad: int) -> step_pack.StepLayout:
         e = self.ecfg
@@ -1007,7 +1040,17 @@ class ModelRuntime:
         the rule's state after the span (opened at zero where the span is
         its request's first: models/llama.py:forward_ragged). An MoE model's `toks` has three more
         rows: the pass's expert-load counters (moe.LOAD_STATS) ride back
-        with the ids, in the transfer the collect makes anyway."""
+        with the ids, in the transfer the collect makes anyway.
+
+        A runtime whose proposer is the model's prediction module (`mtp`)
+        takes and returns one more carry, `drafts` [S + 1] by slot: a spec
+        row's draft is read from it into the stream (the host wrote a
+        placeholder), and after the trunk the module runs over the whole
+        stream — each position with the token that follows it: the next of
+        its span, what the trunk chose at a verify span's positions, the id
+        just sampled at a row's last, `next_tok` where a span ends inside
+        its prompt — and leaves at each row's slot its prediction of the
+        token after next, read at the row's last ACCEPTED position."""
         key_ = ("ragged", T_pad, k_cap, flags)
         _sp_compile_evict(self, self._prefill_jits, key_)
         if key_ not in self._prefill_jits:
@@ -1015,20 +1058,28 @@ class ModelRuntime:
             attn_impl, mesh = self.attn_impl, self.mesh
             need_pen, need_mask, need_sample = flags
             O = k_cap + 1
+            mtp = self.mtp
 
             lay = self._ragged_layout(T_pad)
 
-            def mq_ragged_step(params, buf, kc, vc, recent, last_ids, conv):
+            def mq_ragged_step(params, buf, kc, vc, recent, last_ids, conv,
+                               drafts=None):
                 (tokens, tok_seq, tok_pos, write_slots, q_start, q_len,
-                 kv_len, ring_len, is_first, append, is_spec, seed_rows,
-                 slot_ids, pt, temp, tk, tp, pen, pres, freq, seeds,
-                 rng) = lay.unpack(buf)
+                 kv_len, ring_len, is_first, append, is_spec, next_tok,
+                 seed_rows, slot_ids, pt, temp, tk, tp, pen, pres, freq,
+                 seeds, rng) = lay.unpack(buf)
                 key = jax.random.PRNGKey(rng[0])
                 tokens = jnp.where(
                     tokens < 0,
                     last_ids[jnp.clip(-1 - tokens, 0, last_ids.shape[0] - 1)],
                     tokens)
                 spec = is_spec > 0
+                if mtp:
+                    # A spec row's one draft: the module's, from the carry
+                    # (a row that is no spec row writes past the stream).
+                    tokens = tokens.at[
+                        jnp.where(spec, q_start + 1, T_pad)
+                    ].set(drafts[slot_ids], mode="drop")
                 # Logit read positions: non-spec rows read only their
                 # last valid token (every column aliases it — prefill
                 # spans can be longer than O); spec rows read every span
@@ -1044,8 +1095,10 @@ class ModelRuntime:
                     out_idx, kc, vc, pt, q_start, q_len, kv_len, ps,
                     attn_impl=attn_impl, mesh=mesh,
                     moe_load=bool(cfg.num_experts), conv_state=conv,
-                    slot_ids=slot_ids, is_first=is_first,
+                    slot_ids=slot_ids, is_first=is_first, hidden=mtp,
                 )  # [S, O, V]
+                if mtp:
+                    *rest, hidden = rest
                 if conv is not None:
                     conv, *rest = rest
                 load = rest
@@ -1121,11 +1174,40 @@ class ModelRuntime:
                 if load:
                     toks = jnp.concatenate([toks, jnp.broadcast_to(
                         moe.load_stats(load[0])[:, None], (3, O))])
-                return toks, n_emit, kc, vc, recent, tok, conv
+                if not mtp:
+                    return toks, n_emit, kc, vc, recent, tok, conv
+                # The token that follows each stream position: the next of
+                # its span; at a verify span's positions what the trunk chose
+                # there (column j: the true successor while the drafts before
+                # it were accepted, and nothing reads the others); at a
+                # row's last position the id it just sampled, or the next
+                # prompt token where the span ends inside its prompt.
+                follows = jnp.roll(tokens, -1)
+                last = jnp.where(append > 0, tok, next_tok)
+                follows = follows.at[q_start + q_len - 1].set(
+                    last, mode="drop")
+                jj = jnp.arange(O)[None, :]
+                follows = follows.at[jnp.where(
+                    spec[:, None] & (jj < q_len[:, None]),
+                    q_start[:, None] + jj, T_pad)].set(
+                        greedy_all, mode="drop")
+                # ...and the draft is read at the row's last ACCEPTED
+                # position: the module's view of the token after the one
+                # this step emitted last.
+                at = jnp.clip(q_start + jnp.where(spec, accepted, q_len - 1),
+                              0, T_pad - 1)
+                draft_logits, kc, _ = llama.forward_mtp(
+                    params, cfg, hidden, follows, tok_seq, tok_pos,
+                    write_slots, at, kc, pt, q_start, q_len, kv_len, ps,
+                    attn_impl=attn_impl, mesh=mesh)
+                drafts = drafts.at[slot_ids].set(
+                    jnp.argmax(draft_logits, axis=-1).astype(jnp.int32))
+                return toks, n_emit, kc, vc, recent, tok, conv, drafts
 
             _sp_note_compile(self, "ragged", key_, self._prefill_jits,
                              jax.jit(mq_ragged_step,
-                                     donate_argnums=(2, 3, 4, 5, 6)))
+                                     donate_argnums=(2, 3, 4, 5, 6, 7)
+                                     if mtp else (2, 3, 4, 5, 6)))
         return self._prefill_jits[key_]
 
     def _note_moe_load(self, _sp, stats: np.ndarray) -> None:
@@ -1179,8 +1261,22 @@ class ModelRuntime:
         the part of each that ONE-TOKEN rows account for (a decode row, a
         scan's pass: rows that share their cached positions with no other
         query of the launch); a layer's worth — every layer does the same.
+        With NO indexer (every cached position is attended) instead:
+        `mla_rows`, `mla_pairs` the causal (query, position) pairs — a
+        token at position p attends p + 1 — and `mla_ctx_rows` the cached
+        rows a launch has to read at the least: each span's context once
+        (a scan's pass: each slot's). A launch's worth: the trunk's layers
+        and the prediction module's block do the same.
         Nothing for a model without latent attention."""
         if not self.cfg.kv_lora_rank:
+            return
+        if not self.cfg.index_topk:
+            counts = np.zeros(3, np.int64)
+            for n, kv in spans:
+                pairs = n * (2 * kv - n + 1) // 2  # sum of kv-n+1 .. kv
+                counts += (n, pairs, pairs if scan else kv)
+            _sp.note(**dict(zip(self.DENSE_LATENT_FIELDS, counts.tolist())))
+            self._tm_dsa[0].inc(int(counts[0]))
             return
         counts = np.zeros(5, np.int64)
         for n, kv in spans:
@@ -1402,6 +1498,7 @@ class ModelRuntime:
         self.slot_req[slot] = None
         self._ahead[slot] = 0
         self._tok_step[slot] = None
+        self._draft_ok[slot] = False
         self._stalled_slots.discard(slot)
 
     def _finish_slot(
@@ -1886,6 +1983,21 @@ class ModelRuntime:
                 and req.user not in self._spec_throttled)
 
     def _propose_drafts(self, req: Request, slot: int) -> List[int]:
+        """The slot's drafts for the step being composed, by this
+        runtime's proposer. The model's own prediction module (`mtp`): ONE
+        draft, which the step before left on the device (`draft_ids`) —
+        the host composes its place ([0]: the program writes the id in)
+        and never reads it; none while no step with the module has served
+        the slot, or no budget remains. Otherwise n-gram prompt lookup on
+        the host (`_propose_ngram`)."""
+        if not self.mtp:
+            return self._propose_ngram(req, slot)
+        remaining = req.sampling.max_tokens - len(req.generated_ids) - 1
+        room = self._max_ctx - int(self.seq_lens[slot]) - 2
+        return [0] if self._draft_ok[slot] and min(remaining, room) > 0 \
+            else []
+
+    def _propose_ngram(self, req: Request, slot: int) -> List[int]:
         """Prompt-lookup draft proposal: match the context's trailing
         n-gram (n in SPEC_NGRAMS, longest first) against its most recent
         earlier occurrence and propose the tokens that followed — free
@@ -1894,7 +2006,7 @@ class ModelRuntime:
         the-prompt workloads). Returns [] when nothing matches or no
         budget remains; caps at spec_k, the request's remaining token
         budget, and the context ceiling."""
-        k = self.ecfg.spec_k
+        k = self.spec_k
         remaining = req.sampling.max_tokens - len(req.generated_ids) - 1
         pos = int(self.seq_lens[slot])
         k = min(k, remaining, self._max_ctx - pos - 2)
@@ -1944,7 +2056,7 @@ class ModelRuntime:
                      row[1] / row[0], min_rate, row[0])
 
     def _rollback_spec(self, slot: int, req: Request, kv_before: int,
-                       kv_after: int) -> None:
+                       kv_after: int) -> int:
         """Release the page claim of rejected draft tokens: the slot
         keeps exactly the pages its ACCEPTED context needs. Shared
         prefix-tree pages lead slot_pages and are floored out of the
@@ -1960,7 +2072,9 @@ class ModelRuntime:
             self.page_table[slot, :] = kvc.make_page_table_row(
                 self.slot_pages[slot], self.ecfg.max_pages_per_seq)
         self._jrec("spec_rollback", req, slot=slot, kv_before=kv_before,
-                   kv_after=kv_after, freed=freed, **self._page_state())
+                   kv_after=kv_after, freed=freed, source=self.proposer,
+                   **self._page_state())
+        return freed
 
     def _drop_expired_slot(self, slot: int, core: MQCore) -> None:
         """Deadline enforcement at the speculative composer: an expired
@@ -2292,8 +2406,11 @@ class ModelRuntime:
     def may_overlap(self) -> bool:
         """May a step be launched while the one before it is unsettled?
         Not where composing needs the host to have seen the ids (the
-        n-gram proposer reads generated_ids): such a runtime settles
-        every step in the tick that launched it."""
+        n-gram proposer reads generated_ids) or how many of them a row
+        emitted (a verify span's length sets the next step's positions and
+        page claims — the module's drafts stay on the device, a row's
+        length does not yet: ROADMAP queue A): a speculating runtime
+        settles every step in the tick that launched it."""
         return not self.spec
 
     def settle_inflight(self, core: MQCore) -> None:
@@ -2441,9 +2558,11 @@ class ModelRuntime:
                     spec_plan[i] = drafts
                     spec_budget -= len(drafts)
                     self._jrec("speculate", r, slot=i, k=len(drafts),
-                               source="ngram")
-        if not self.chunking and not spec_plan:
+                               source=self.proposer)
+        if not self.chunking and not spec_plan and not self.mtp:
             return None  # nothing multi-token this tick: decode fused
+        # (With the module every step is a ragged one: its cache has a row
+        # for every position only if it sees every position.)
 
         # Compose: decode/spec rows first (every live stream advances,
         # and the ladder trim below must only ever shorten prefill
@@ -2488,8 +2607,10 @@ class ModelRuntime:
                 continue
             rows.append(("prefill", slot, req, req._chunk_pos, span))
             budget -= span
-        if len(rows) == n_decode and not spec_plan:
+        if len(rows) == n_decode and not spec_plan and not self.mtp:
             return None  # no span ready this tick: decode runs fused
+        if not rows:
+            return None
 
         # Pick the dispatch total from the compile ladder. Prefer the
         # largest rung we can TRIM down to (tail prefill tokens just go
@@ -2531,8 +2652,9 @@ class ModelRuntime:
         lay = self._ragged_layout(T_pad)
         buf = lay.new()
         (tokens, tok_seq, tok_pos, write_slots, q_start, q_len, kv_len,
-         ring_len, is_first, append, is_spec, seed_rows, slot_ids, pt_rows,
-         temp, top_k, top_p, pen, pres, freq, seeds, rng) = lay.views(buf)
+         ring_len, is_first, append, is_spec, next_tok, seed_rows, slot_ids,
+         pt_rows, temp, top_k, top_p, pen, pres, freq, seeds,
+         rng) = lay.views(buf)
         tok_seq[:] = min(len(rows), S - 1)
 
         off = 0
@@ -2609,6 +2731,8 @@ class ModelRuntime:
                     seed_rows[idx, W - len(prev_toks):] = prev_toks
                 final = cpos + span >= len(req.prompt_tokens)
                 append[idx] = 1 if final else 0
+                if not final:  # (what the prediction module reads there)
+                    next_tok[idx] = req.prompt_tokens[cpos + span]
                 pt_rows[idx] = row
                 ctx0.append(cpos + span)
                 emits.append(final)
@@ -2621,8 +2745,9 @@ class ModelRuntime:
         spec_rows = [r for r in rows if r[0] == "spec"]
         spec_tokens = sum(len(r[3]) for r in spec_rows)
         # k_cap in {0, spec_k}: one extra compile variant total when
-        # speculation is live, not one per observed draft length.
-        k_cap = self.ecfg.spec_k if spec_rows else 0
+        # speculation is live, not one per observed draft length (and with
+        # the module, whose every step drafts, the one variant).
+        k_cap = self.spec_k if spec_rows or self.mtp else 0
         # Batch-compose decision inputs, recorded when the step is
         # collected so the record can also carry the per-dispatch
         # accepted-token count (the speculative scoreboard reads straight
@@ -2641,6 +2766,10 @@ class ModelRuntime:
             batch_fields["n_spec"] = len(spec_rows)
             batch_fields["spec_tokens"] = int(spec_tokens)
             _sp.mode = "spec_verify"
+        if self.mtp:
+            # drafts verified this pass, and the positions the module ran
+            # over to leave the next ones (every token of the stream)
+            _sp.note(mtp_drafts=int(spec_tokens), mtp_rows=int(T_real))
         prev = self._settle_before_compile(
             self._prefill_jits,
             ("ragged", T_pad, k_cap,
@@ -2678,6 +2807,8 @@ class ModelRuntime:
         # The plan becomes the host's state: what the NEXT composition
         # reads is all here, whatever ids this step samples.
         for idx, (kind, slot, req, cpos, span) in enumerate(rows):
+            if self.mtp and emits[idx]:
+                self._draft_ok[slot] = True  # this step leaves it there
             if kind == "decode":
                 if self.slot_req[slot] is req:  # (not finished by a settle
                     self._launched(  # this launch itself had to make)
@@ -3022,6 +3153,7 @@ class ModelRuntime:
                 self.slo.record("tpot", dt_ms, n=n_decode * max(1, K))
 
         emitted = wasted = 0
+        spec_accepted = spec_pages = 0
         ctx0 = h.ctx0
         self._stream_items = 0
         # Row-major: a row's tokens of this step — 1, `n_emit` of a
@@ -3046,19 +3178,25 @@ class ModelRuntime:
                     emitted += taken
                 if kind == "spec":
                     proposed, accepted = span - 1, n - 1
+                    spec_accepted += accepted
                     self._note_spec_outcome(req, proposed, accepted)
                     self._jrec("spec_verify", req, slot=slot,
                                proposed=proposed, accepted=accepted,
-                               rolled_back=proposed - accepted)
+                               rolled_back=proposed - accepted,
+                               source=self.proposer)
                     if proposed > accepted and self.slot_req[slot] is req:
                         # Rejected drafts wrote KV past the accepted
                         # context: release their page claim (the finish
                         # paths already freed everything when the stream
                         # ended mid-emission).
-                        self._rollback_spec(slot, req, ctx0[idx] - 1 + span,
-                                            int(self.seq_lens[slot]) + 1)
+                        spec_pages += self._rollback_spec(
+                            slot, req, ctx0[idx] - 1 + span,
+                            int(self.seq_lens[slot]) + 1)
         _sp.note(stream_items=self._stream_items,
                  stream_wakeups=woken.wakeups)
+        if self.mtp:
+            _sp.note(mtp_accepted=spec_accepted,
+                     spec_rollback_pages=spec_pages)
 
         # Per-step engine telemetry: occupancy, KV-page pressure, MFU.
         self._tm_tokens.inc(emitted)
@@ -3198,6 +3336,7 @@ class ModelRuntime:
                     self.spec_accepted / self.spec_proposed, 4)
                 if self.spec_proposed else 0.0,
                 "rollbacks": self.spec_rollbacks,
+                "proposer": self.proposer,
                 "throttled_users": len(self._spec_throttled),
             } if self.spec else None),
         }

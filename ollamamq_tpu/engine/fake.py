@@ -238,9 +238,11 @@ class FakeRuntime:
             self._jrec("spec_verify", req, slot=-1, proposed=k,
                        accepted=k, rolled_back=0)
             tm.SPEC_TOKENS_TOTAL.labels(
-                model=self.name, outcome="proposed").inc(k)
+                model=self.name, outcome="proposed",
+                proposer="fake").inc(k)
             tm.SPEC_TOKENS_TOTAL.labels(
-                model=self.name, outcome="accepted").inc(k)
+                model=self.name, outcome="accepted",
+                proposer="fake").inc(k)
             tm.SPEC_ACCEPT_RATE.labels(model=self.name).set(1.0)
             emit_n = 1 + k
         ids, texts, end, tail = [], [], None, ""

@@ -4,7 +4,10 @@
 and V but the LATENT pool, [layers, slots, latent_lanes], and the INDEX-KEY
 pool, [layers, slots, index_head_dim]: two kinds of paged state of different
 row widths under one page table and one allocator, allocated, donated,
-carried and written by page exactly as K and V are — ops/mla.py.)
+carried and written by page exactly as K and V are — ops/mla.py. A model
+with no indexer has no second pool: the array is there with 0 lanes. A
+model with a prediction module has one more layer in the pool, behind the
+attention layers: the module's block's own rows, `ModelConfig.cache_layers`.)
 
 Device side: two arrays per model, [attention layers, num_pages*page_size,
 kv_heads*head_dim] for K and V (an int8 pool adds [attention layers, slots,
@@ -171,7 +174,7 @@ def alloc_kv_pool(
     from ollamamq_tpu.ops.quant import QuantKV
 
     S = engine_cfg.num_pages * engine_cfg.page_size
-    shape = (model_cfg.count(ATTENTION), S,
+    shape = (model_cfg.cache_layers, S,
              model_cfg.num_kv_heads * model_cfg.head_dim)
 
     def filled(value, shp, dt):
@@ -182,7 +185,9 @@ def alloc_kv_pool(
 
     if model_cfg.kv_lora_rank:
         # Latent attention: the latent pool and the index-key pool where K
-        # and V were — same pages, same table, rows of another width each.
+        # and V were — same pages, same table, rows of another width each
+        # (no indexer: the second has no lanes, and no bytes); one more
+        # layer where the model has a prediction module (its block's rows).
         return tuple(filled(0, shape[:2] + (lanes,), dtype)
                      for lanes in model_cfg.kv_row_dims)
 
@@ -339,7 +344,7 @@ def kv_page_bytes(model_cfg: ModelConfig, page_size: int,
     unit: equal-HBM pool sizing divides a byte budget by this. With
     latent attention: the latent rows and the index keys."""
     if model_cfg.kv_lora_rank:
-        return (model_cfg.count(ATTENTION) * page_size
+        return (model_cfg.cache_layers * page_size
                 * sum(model_cfg.kv_row_dims) * bytes_per_el)
     per_tok_head = (model_cfg.head_dim + 4 if kv_dtype == "int8"
                     else model_cfg.head_dim * bytes_per_el)

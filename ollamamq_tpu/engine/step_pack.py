@@ -113,7 +113,10 @@ def ragged_layout(T_pad: int, S: int, MP: int, W: int) -> StepLayout:
     fields. Padding tokens sit at position -1 and write the trash page's
     slot 0; a padding row has no tokens (`q_start` past the stream), the
     trash ring row `S` and trash pages. The draft cap is not part of it:
-    a plain step carries `is_spec` all zero."""
+    a plain step carries `is_spec` all zero. `next_tok` is the prompt token
+    that FOLLOWS a span which ends inside its prompt (a model with a
+    prediction module reads it; every other row's successor is sampled in
+    the program)."""
     i32 = np.int32
     return StepLayout(
         [("tokens", (T_pad,), i32, 0), ("tok_seq", (T_pad,), i32, 0),
@@ -121,7 +124,8 @@ def ragged_layout(T_pad: int, S: int, MP: int, W: int) -> StepLayout:
          ("q_start", (S,), i32, T_pad), ("q_len", (S,), i32, 0),
          ("kv_len", (S,), i32, 0), ("ring_len", (S,), i32, 0),
          ("is_first", (S,), i32, 0), ("append", (S,), i32, 0),
-         ("is_spec", (S,), i32, 0), ("seed_rows", (S, W), i32, -1),
+         ("is_spec", (S,), i32, 0), ("next_tok", (S,), i32, 0),
+         ("seed_rows", (S, W), i32, -1),
          ("slot_ids", (S,), i32, S), ("pt", (S, MP), i32, kvc.TRASH_PAGE)]
         + _sampling(S) + _RNG)
 

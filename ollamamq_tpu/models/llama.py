@@ -2,7 +2,8 @@
 pure functional JAX.
 
 A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))` — or, with
-`norm_order` "post", `x + norm(Op(x))`, `x + norm(FFN(x))`. Op is attention
+`norm_order` "post", `x + norm(Op(x))`, `x + norm(FFN(x))`, or with
+`sandwich_norm` both norms: `x + norm'(Op(norm(x)))`. Op is attention
 (`_attention_op`), a gated short convolution (`_conv_op`) or gated
 delta-rule linear attention (`_linear_attention_op`); FFN is a dense
 SwiGLU (`_mlp`) or routed experts (models/moe.py). Each is defined ONCE and
@@ -71,6 +72,11 @@ from ollamamq_tpu.ops.rope import (apply_rope, apply_rope_freqs, rope_freqs,
 # decode programs. An MoE model's "mlp" holds models/moe.py:SCOPES.
 SCOPES = ("embed", "attn_qkv", "kv_write", "attention", "attn_out", "mlp",
           "lm_head", "sampling")
+# ...and the prediction module's three (`forward_mtp`): the two norms and
+# the projection of [embedding | hidden], its block (which holds a layer's
+# own scopes), its norm and the trunk's head.
+MTP_SCOPES = ("mtp_embed_proj", "mtp_block", "mtp_head")
+MTP_KEY = 0x6D747030
 # ...and a conv layer's three stages, beside the attention layers' four:
 # the in-projection, the gated convolution with its state read and write,
 # the out-projection.
@@ -127,8 +133,13 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     """Random-init a params pytree (layers stacked by kind on axis 0)."""
     d, qd, kvd, f = cfg.hidden_size, cfg.q_dim, cfg.kv_dim, cfg.intermediate_size
-    L, v = cfg.num_layers, cfg.vocab_size
-    La, Lc, Ld = cfg.count(ATTENTION), cfg.count(CONV), cfg.count(DENSE)
+    # The prediction module's block is ONE MORE entry at the end of every
+    # stack its kind of layer has (the norms, the latent attention's, the
+    # experts'): the trunk's loop never reaches it, `forward_mtp` reads it.
+    n_mtp = cfg.num_nextn_predict_layers
+    L, v = cfg.num_layers + n_mtp, cfg.vocab_size
+    La, Lc, Ld = cfg.count(ATTENTION) + n_mtp, cfg.count(CONV), \
+        cfg.count(DENSE)
     Ll = cfg.count(LINEAR)
     keys = jax.random.split(key, 10)
 
@@ -137,6 +148,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
 
     layers = {"attn_norm": jnp.ones((L, d), dtype),
               "mlp_norm": jnp.ones((L, d), dtype)}
+    if cfg.sandwich_norm:
+        layers.update(post_attn_norm=jnp.ones((L, d), dtype),
+                      post_mlp_norm=jnp.ones((L, d), dtype))
     if La and cfg.kv_lora_rank:
         mk = jax.random.split(jax.random.fold_in(key, MLA_KEY), 8)
         H, r, c = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
@@ -150,12 +164,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
             mla_kv_norm=jnp.ones((La, c), dtype),
             mla_wukv=w(mk[3], (La, c, H * (cfg.qk_nope_head_dim
                                            + cfg.v_head_dim)), c),
-            wo=w(mk[4], (La, od, d), od),
-            idx_wq=w(mk[5], (La, r, Hi * di), r),
-            idx_wk=w(mk[6], (La, d, di), d),
-            idx_k_norm=jnp.ones((La, di), dtype),
-            idx_k_bias=jnp.zeros((La, di), dtype),
-            idx_ww=w(mk[7], (La, d, Hi), d))
+            wo=w(mk[4], (La, od, d), od))
+        if cfg.index_topk:
+            layers.update(
+                idx_wq=w(mk[5], (La, r, Hi * di), r),
+                idx_wk=w(mk[6], (La, d, di), d),
+                idx_k_norm=jnp.ones((La, di), dtype),
+                idx_k_bias=jnp.zeros((La, di), dtype),
+                idx_ww=w(mk[7], (La, d, Hi), d))
     elif La:
         layers.update(
             wq=w(keys[0], (La, d, qd), d), wk=w(keys[1], (La, d, kvd), d),
@@ -215,6 +231,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     }
     if not cfg.tie_embeddings and not cfg.is_encoder:
         params["lm_head"] = w(keys[8], (v, d), d)
+    if n_mtp:
+        # u = [enorm(Emb(next token)) | hnorm(hidden)] W_eh; the module's
+        # own norm before the (trunk's) head.
+        params.update(
+            mtp_enorm=jnp.ones((d,), dtype), mtp_hnorm=jnp.ones((d,), dtype),
+            mtp_eh_proj=w(jax.random.fold_in(key, MTP_KEY), (2 * d, d),
+                          2 * d),
+            mtp_norm=jnp.ones((d,), dtype))
     return params
 
 
@@ -420,12 +444,14 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     two cache rows, the indexer, the selection, the softmax) is the caller's
     `attn_fn(q_abs [B, T, H, lanes], row [B, T, lanes], (q_idx [B, T, Hi,
     di], k_idx [B, T, di], w_idx [B, T, Hi] float32)) -> [B, T, H, c]`, the
-    attended latent a head."""
+    attended latent a head; the third argument is None for a model with no
+    indexer (`index_topk` 0)."""
     B, T, _ = h.shape
     H, c = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     Hi, di = cfg.index_n_heads, cfg.index_head_dim
     wukv = lp["mla_wukv"].reshape(c, H, dn + dv)
+    index = None  # no indexer: every cached position is attended
     with jax.named_scope("mla_proj"):
         c_q = rmsnorm(qeinsum("btd,de->bte", h, lp["mla_wdq"]),
                       lp["mla_q_norm"], cfg.rms_norm_eps)
@@ -446,26 +472,33 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
             [q_lat, q_rope.astype(jnp.float32),
              jnp.zeros((B, T, H, pad), jnp.float32)], axis=-1)
             * cfg.attn_scale).astype(h.dtype)
-        # The indexer: q from the normed q latent, k from the hiddens
-        # through a LayerNorm, RoPE on the first rope lanes of both, and a
-        # learned weight a head.
-        q_idx = _latent_rope(cfg, qeinsum(
-            "btr,re->bte", c_q, lp["idx_wq"]).reshape(B, T, Hi, di),
-            positions)
-        k_idx = qeinsum("btd,de->bte", h, lp["idx_wk"]).astype(jnp.float32)
-        mean = jnp.mean(k_idx, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(k_idx - mean), axis=-1, keepdims=True)
-        k_idx = ((k_idx - mean) * jax.lax.rsqrt(var + INDEX_NORM_EPS)
-                 ).astype(h.dtype) * lp["idx_k_norm"] + lp["idx_k_bias"]
-        k_idx = _latent_rope(cfg, k_idx[..., None, :], positions)[..., 0, :]
-        w_idx = jnp.einsum("btd,dh->bth", h, lp["idx_ww"],
-                           preferred_element_type=jnp.float32) \
-            * (Hi ** -0.5 * di ** -0.5)
-    o_lat = attn_fn(q_abs, row, (q_idx, k_idx, w_idx))
+        if cfg.index_topk:
+            index = _index_inputs(cfg, lp, h, c_q, positions)
+    o_lat = attn_fn(q_abs, row, index)
     with jax.named_scope("attn_out"):
         o = jnp.einsum("bthc,chv->bthv", o_lat, wukv[..., dn:],
                        preferred_element_type=jnp.float32).astype(h.dtype)
         return qeinsum("bte,ed->btd", o.reshape(B, T, H * dv), lp["wo"])
+
+
+def _index_inputs(cfg: ModelConfig, lp: dict, h, c_q, positions):
+    """The lightning indexer's (q_idx, k_idx, w_idx): q from the normed q
+    latent, k from the hiddens through a LayerNorm, RoPE on the first rope
+    lanes of both, and a learned weight a head."""
+    B, T, _ = h.shape
+    Hi, di = cfg.index_n_heads, cfg.index_head_dim
+    q_idx = _latent_rope(cfg, qeinsum(
+        "btr,re->bte", c_q, lp["idx_wq"]).reshape(B, T, Hi, di), positions)
+    k_idx = qeinsum("btd,de->bte", h, lp["idx_wk"]).astype(jnp.float32)
+    mean = jnp.mean(k_idx, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(k_idx - mean), axis=-1, keepdims=True)
+    k_idx = ((k_idx - mean) * jax.lax.rsqrt(var + INDEX_NORM_EPS)
+             ).astype(h.dtype) * lp["idx_k_norm"] + lp["idx_k_bias"]
+    k_idx = _latent_rope(cfg, k_idx[..., None, :], positions)[..., 0, :]
+    w_idx = jnp.einsum("btd,dh->bth", h, lp["idx_ww"],
+                       preferred_element_type=jnp.float32) \
+        * (Hi ** -0.5 * di ** -0.5)
+    return q_idx, k_idx, w_idx
 
 
 def _conv_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
@@ -547,12 +580,16 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
         delta = _latent_attention_op(cfg, lp, h, positions, attn_fn)
     else:
         delta = _attention_op(cfg, lp, h, positions, attn_fn)
+    if cfg.sandwich_norm:
+        delta = norm(delta, "post_attn_norm")
     x = x + (delta if pre else norm(delta, "attn_norm"))
     with jax.named_scope("mlp"):
         delta, load = _ffn(cfg, lp, ffn, norm(x, "mlp_norm") if pre else x,
                            valid=valid, mesh=mesh, impl=impl, layer=layer)
         if not pre:
             delta = norm(delta, "mlp_norm")
+        elif cfg.sandwich_norm:
+            delta = norm(delta, "post_mlp_norm")
     return x + delta, load
 
 
@@ -571,7 +608,8 @@ def _causal_fn(cfg: ModelConfig, seq_lens):
     selection by the span's own index scores."""
     if cfg.kv_lora_rank:
         return lambda q_abs, row, index: mla.dense_attention(
-            q_abs, row, *index, seq_lens, cfg.kv_lora_rank, cfg.index_topk)
+            q_abs, row, *(index or (None,) * 3), seq_lens, cfg.kv_lora_rank,
+            cfg.index_topk)
     return lambda q, k, v: causal_attention(q, k, v, seq_lens)
 
 
@@ -602,8 +640,12 @@ def forward_prefill(
         def attn_fn(q, k, v):
             nonlocal kc, vc
             kc = kv_write(kc, ix.op, slots, k)  # K, or the latent row
-            # V, or the index key (v: the indexer's q, k and head weights)
-            vc = kv_write(vc, ix.op, slots, v[1] if cfg.kv_lora_rank else v)
+            # V, or the index key (v: the indexer's q, k and head weights;
+            # None with no indexer: no second pool)
+            if not cfg.kv_lora_rank:
+                vc = kv_write(vc, ix.op, slots, v)
+            elif v is not None:
+                vc = kv_write(vc, ix.op, slots, v[1])
             return _causal_fn(cfg, seq_lens)(q, k, v)
 
         valid = positions < seq_lens[:, None]
@@ -642,6 +684,7 @@ def forward_ragged(
     conv_state=None,  # [Lc, slots+1, K-1, D] or a SlotState (donated; carry)
     slot_ids=None,  # [B] each row's slot: its row of conv_state
     is_first=None,  # [B] the span is its request's first: state opens at 0
+    hidden: bool = False,  # also return the last hiddens [T, D], last
 ):
     """ONE forward over a ragged mixed batch: variable-length prefill
     spans and single decode tokens share a flattened [T] token stream —
@@ -663,7 +706,9 @@ def forward_ragged(
     (q_len == 0) yield garbage logits the caller ignores. Returns
     (logits, caches'), then conv_state' where one was given, and with
     `moe_load` (an MoE model's step program asks) the rows each expert of
-    each expert layer got, [Le, E] int32, last.
+    each expert layer got, [Le, E] int32, and with `hidden` every stream
+    position's last hidden BEFORE the final norm, [T, D] (what the
+    prediction module reads: `forward_mtp`), last.
     """
     with jax.named_scope("embed"):
         x = embed_lookup(params["embed"], tokens,
@@ -676,15 +721,11 @@ def forward_ragged(
         def attn_fn(q, k, v):  # [1, T, H, hd]
             nonlocal kc, vc
             if cfg.kv_lora_rank:
-                q_idx, k_idx, w_idx = v
-                with jax.named_scope("mla_cache_write"):
-                    kc = kv_write(kc, ix.op, write_slots, k[0])
-                    vc = kv_write(vc, ix.op, write_slots, k_idx[0])
-                return mla.attend(
-                    attn_impl, q[0], q_idx[0], w_idx[0], kc, vc, ix.op,
-                    page_table, tok_seq, tok_pos, q_start, q_len, kv_len,
-                    page_size, cfg.kv_lora_rank, cfg.index_topk,
-                    interpret=interpret)[None]
+                kc, vc, out = _latent_ragged(
+                    cfg, q, k, v, kc, vc, ix.op, write_slots, page_table,
+                    tok_seq, tok_pos, q_start, q_len, kv_len, page_size,
+                    attn_impl, interpret)
+                return out
             with jax.named_scope("kv_write"):
                 kc = kv_write(kc, ix.op, write_slots, k[0])
                 vc = kv_write(vc, ix.op, write_slots, v[0])
@@ -726,8 +767,93 @@ def forward_ragged(
     else:
         x_last = x[0][out_idx]  # [B, O, D]
         logits = _logits(params, cfg, x_last)  # [B, O, V]
-    return _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
-                    moe_load)
+    out = _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
+                   moe_load)
+    return out + (x[0],) if hidden else out
+
+
+def _latent_ragged(cfg, q, row, index, kc, vc, layer, write_slots, page_table,
+                   tok_seq, tok_pos, q_start, q_len, kv_len, page_size,
+                   attn_impl, interpret, name=None):
+    """A ragged stream's latent attention of one layer: the write of the
+    tokens' cache rows (the latent row; the index key where there is an
+    indexer), then ops/mla.attend. q [1, T, H, lanes], row [1, T, lanes];
+    returns (kc', vc', o [1, T, H, c])."""
+    q_idx = w_idx = None
+    with jax.named_scope("mla_cache_write"):
+        kc = kv_write(kc, layer, write_slots, row[0])
+        if index is not None:
+            q_idx, w_idx = index[0][0], index[2][0]
+            vc = kv_write(vc, layer, write_slots, index[1][0])
+    out = mla.attend(
+        attn_impl, q[0], q_idx, w_idx, kc, vc,
+        layer, page_table, tok_seq, tok_pos, q_start, q_len, kv_len,
+        page_size, cfg.kv_lora_rank, cfg.index_topk, interpret=interpret,
+        name=name)
+    return kc, vc, out[None]
+
+
+def forward_mtp(
+    params: dict,
+    cfg: ModelConfig,
+    hidden: jnp.ndarray,  # [T, D] the trunk's last hiddens (forward_ragged)
+    next_tokens: jnp.ndarray,  # [T] int32 the token FOLLOWING each position
+    tok_seq, tok_pos, write_slots,  # [T], as forward_ragged's
+    out_idx: jnp.ndarray,  # [B] stream index a row's draft is read at
+    k_cache: jnp.ndarray,  # the latent pool (donated): the module's block
+    # writes and reads ITS layer of it
+    page_table, q_start, q_len, kv_len,
+    page_size: int,
+    attn_impl: str = "jnp",
+    interpret: bool = False,
+    mesh=None,
+):
+    """The multi-token-prediction module over a ragged step's stream (depth
+    1, DeepSeek-V3's formulation): at stream position t of sequence position
+    i, `u = [enorm(Emb(t_{i+1})) | hnorm(h_i)] W_eh`, `v = Block(u)` — one
+    more block of the stack (sandwich norms, latent attention over the
+    module's OWN rows of the latent pool, layer `count(ATTENTION)`, written
+    here at the span's positions; the expert layer) — and `Head(norm(v))`,
+    embedding and head the trunk's, is the distribution of `t_{i+2}`.
+    Every position of every span passes (the module's cache needs each);
+    the logits leave at `out_idx` only. Returns (logits [B, V], k_cache',
+    expert load [E] int32). (No indexer, so no second pool: config.py.)"""
+    from ollamamq_tpu.ops.pallas.mla_attention import MTP_NAME
+
+    eps = cfg.rms_norm_eps
+    positions = jnp.maximum(tok_pos, 0)[None, :]
+    valid = (tok_pos >= 0)[None, :]
+    layer = cfg.count(ATTENTION)  # behind the trunk's layers
+    with jax.named_scope("mtp_embed_proj"):
+        e = embed_lookup(params["embed"], next_tokens, hidden.dtype)
+        u = jnp.concatenate([rmsnorm(e, params["mtp_enorm"], eps),
+                             rmsnorm(hidden, params["mtp_hnorm"], eps)],
+                            axis=-1)
+        x = qeinsum("td,de->te", u, params["mtp_eh_proj"])[None]  # [1,T,D]
+    # The block's weights: the last entry of every stack its kind of layer
+    # has; the expert stacks whole, read by index (as in scan_layers).
+    mine = KIND_PARAMS[ATTENTION] + KIND_PARAMS[EXPERTS] + (
+        "attn_norm", "mlp_norm", "post_attn_norm", "post_mlp_norm")
+    lp = {name: stack if name in STACKED else stack[-1]
+          for name, stack in params["layers"].items() if name in mine}
+
+    def attn_fn(q, row, index):
+        nonlocal k_cache
+        k_cache, _, out = _latent_ragged(
+            cfg, q, row, index, k_cache, None, layer, write_slots,
+            page_table, tok_seq, tok_pos, q_start, q_len, kv_len, page_size,
+            attn_impl, interpret, name=MTP_NAME)
+        return out
+
+    with jax.named_scope("mtp_block"):
+        x, load = _layer_step(cfg, lp, (ATTENTION, EXPERTS), x, positions,
+                              attn_fn, valid=valid, mesh=mesh,
+                              impl=attn_impl, layer=cfg.count(EXPERTS))
+    with jax.named_scope("mtp_head"):
+        v = rmsnorm(x[0][out_idx], params["mtp_norm"], eps)
+        logits = logits_head(v[None],
+                             params.get("lm_head", params["embed"]))[0]
+    return logits, k_cache, load
 
 
 def _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
@@ -779,14 +905,16 @@ def forward_decode(
         def attn_fn(q, k, v):  # [B, 1, H, hd]
             nonlocal kc, vc
             if cfg.kv_lora_rank:  # a stream of B one-token spans
-                q_idx, k_idx, w_idx = v
+                q_idx = w_idx = None
                 with jax.named_scope("mla_cache_write"):
                     kc = kv_write(kc, ix.op, write_slots, k[:, 0])
-                    vc = kv_write(vc, ix.op, write_slots, k_idx[:, 0])
+                    if v is not None:  # the indexer's (q, k, head weights)
+                        q_idx, w_idx = v[0][:, 0], v[2][:, 0]
+                        vc = kv_write(vc, ix.op, write_slots, v[1][:, 0])
                 rows = jnp.arange(B, dtype=jnp.int32)
                 return mla.attend(
-                    attn_impl, q[:, 0], q_idx[:, 0], w_idx[:, 0], kc, vc,
-                    ix.op, page_table, rows, positions, rows,
+                    attn_impl, q[:, 0], q_idx, w_idx,
+                    kc, vc, ix.op, page_table, rows, positions, rows,
                     jnp.ones_like(rows), seq_lens, page_size,
                     cfg.kv_lora_rank, cfg.index_topk, tile=1)[:, None]
             with jax.named_scope("kv_write"):

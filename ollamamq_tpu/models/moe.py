@@ -95,7 +95,9 @@ def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     forward that drops it, or weights by the biased score, computes
     another model."""
     d, f = cfg.hidden_size, cfg.expert_width
-    L, E, R = cfg.count(EXPERTS), cfg.num_experts, cfg.router_width
+    # (the prediction module's block is one more entry of every stack)
+    L = cfg.count(EXPERTS) + cfg.num_nextn_predict_layers
+    E, R = cfg.num_experts, cfg.router_width
     # (One more key only where there is a bias: the families without one
     # keep the weights their seeds have always drawn.)
     keys = jax.random.split(key, 4 + cfg.use_expert_bias)

@@ -29,6 +29,11 @@ Three steps a layer, each a named scope on the device trace:
               latent pages (`mla_sparse_paged_attention_pallas`).
 The same three serve a ragged step's stream and the decode scan's batch (a
 stream of one-token spans, a tile a token).
+
+A model with NO indexer (`index_topk` 0: DeepSeek-V3's, openPangu's latent
+attention) has no second pool and runs `mla_attend` alone: a causal softmax
+over every cached position — the same walk with the selection's operands and
+comparison compiled out (`scores` and `thr` None), not a mask of all ones.
 """
 
 from __future__ import annotations
@@ -108,30 +113,34 @@ def sparse_attention(q_abs, scores, thr, lat_pool, layer, page_table,
                      tok_seq, tok_pos, page_size, rank: int):
     """The attention's twin: o [T, H, rank] in q's dtype. q_abs [T, H,
     lanes] (absorbed, scaled), scores [T, >= C] and thr [T] the selection,
-    lat_pool [L, S, lanes]."""
+    lat_pool [L, S, lanes]. `scores` None: no selection, every position up
+    to the token's own."""
     rows = _gather_rows(lat_pool, layer, page_table, tok_seq,
                         page_size).astype(jnp.float32)  # [T, C, lanes]
     C = rows.shape[1]
     logits = jnp.einsum("thd,tcd->thc", q_abs.astype(jnp.float32), rows)
-    keep = (jnp.arange(C, dtype=jnp.int32)[None, :] <= tok_pos[:, None]) \
-        & (scores[:, :C] >= thr[:, None])
+    keep = jnp.arange(C, dtype=jnp.int32)[None, :] <= tok_pos[:, None]
+    if scores is not None:
+        keep &= scores[:, :C] >= thr[:, None]
     return _attend(logits, keep, rows, rank).astype(q_abs.dtype)
 
 
 def dense_attention(q_abs, row, q_idx, k_idx, w_idx, seq_lens, rank: int,
                     topk: int):
     """Whole sequences from position 0, no cache: [B, T, H, rank]. The
-    oracle of the step forwards (models/llama.forward_prefill)."""
+    oracle of the step forwards (models/llama.forward_prefill). `topk` 0:
+    no indexer (q_idx, k_idx, w_idx None)."""
     B, T = row.shape[:2]
     pos = jnp.arange(T, dtype=jnp.int32)
-    s = jnp.einsum("bthd,bcd->bthc", q_idx.astype(jnp.float32),
-                   k_idx.astype(jnp.float32))
-    scores = jnp.einsum("bthc,bth->btc", jnp.maximum(s, 0.0), w_idx)
     tok_pos = jnp.where(pos[None, :] < seq_lens[:, None], pos[None, :], -1)
-    thr = select_threshold(scores.reshape(B * T, T), tok_pos.reshape(B * T),
-                           topk).reshape(B, T)
-    keep = (pos[None, None, :] <= tok_pos[:, :, None]) \
-        & (scores >= thr[..., None])
+    keep = pos[None, None, :] <= tok_pos[:, :, None]
+    if topk:
+        s = jnp.einsum("bthd,bcd->bthc", q_idx.astype(jnp.float32),
+                       k_idx.astype(jnp.float32))
+        scores = jnp.einsum("bthc,bth->btc", jnp.maximum(s, 0.0), w_idx)
+        thr = select_threshold(scores.reshape(B * T, T),
+                               tok_pos.reshape(B * T), topk).reshape(B, T)
+        keep &= scores >= thr[..., None]
     rows = row.astype(jnp.float32)
     logits = jnp.einsum("bthd,bcd->bthc", q_abs.astype(jnp.float32), rows)
     return _attend(logits, keep, rows[:, None], rank).astype(q_abs.dtype)
@@ -140,13 +149,22 @@ def dense_attention(q_abs, row, q_idx, k_idx, w_idx, seq_lens, rank: int,
 def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
            page_table, tok_seq, tok_pos, q_start, q_lens, kv_lens,
            page_size: int, rank: int, topk: int, tile=None,
-           interpret: bool = False):
+           interpret: bool = False, name=None):
     """index, select, attend — the ONE pallas-vs-jnp dispatch of both step
     forwards. Both metadata encodings travel together, as in
-    ops/attention.ragged_attention_any."""
+    ops/attention.ragged_attention_any. `topk` 0 (no indexer: q_idx, w_idx
+    None, idx_pool unread): attend alone, over every cached position.
+    `name`: the attention launch's name on the device trace where it is not
+    the kernel's own (the prediction module's)."""
     if impl == "pallas":
         from ollamamq_tpu.ops.pallas import mla_attention as kernels
 
+        if not topk:
+            with jax.named_scope("mla_attend"):
+                return kernels.mla_dense_paged_attention_pallas(
+                    q_abs, lat_pool, layer, page_table, q_start, q_lens,
+                    kv_lens, page_size, rank, tile=tile, interpret=interpret,
+                    name=name)
         with jax.named_scope("dsa_index"):
             scores = kernels.dsa_index_pallas(
                 q_idx, w_idx, idx_pool, layer, page_table, q_start, q_lens,
@@ -159,6 +177,11 @@ def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
                 q_abs, scores, thr, lat_pool, layer, page_table, q_start,
                 q_lens, kv_lens, page_size, rank, tile=tile,
                 interpret=interpret)
+    if not topk:
+        with jax.named_scope("mla_attend"):
+            return sparse_attention(q_abs, None, None, lat_pool, layer,
+                                    page_table, tok_seq, tok_pos, page_size,
+                                    rank)
     with jax.named_scope("dsa_index"):
         scores = index_scores(q_idx, w_idx, idx_pool, layer, page_table,
                               tok_seq, page_size)

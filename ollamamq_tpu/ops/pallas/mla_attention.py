@@ -38,9 +38,19 @@ touch index_topk of them (PERF.md section 5 has what that costs at 8-16 k
 tokens and what a gathering kernel is read against: `mla_attn_roofline_pct`
 counts the selected pairs only).
 
+A model with no indexer launches the attention kernel alone, as
+`mla_dense_paged_attention_pallas`: the same walk and trip with the
+selection's two operands and its comparison compiled out (`masked` false) —
+a causal walk over the latent pages. There a span of at most SHORT (2)
+tokens — a decode row, or `--spec`'s verify span `[t, draft]` of one draft —
+folds the row-heads of those tokens alone, not the tile's (the masked
+kernel's path of its own is for one-token rows).
+
 Names: exactly one launch a layer a forward pass carries `paged_attention`
 in its name (benchmarks/layer_metrics/_ops.py divides such launches by the
-attention layers); the indexer's and the selection's do not.
+attention layers); the indexer's and the selection's do not, and neither
+does the prediction module's launch of the dense kernel (`MTP_NAME`: one
+more launch a pass, of no layer the configuration file counts).
 
 The attention kernel's trip — one (tile, block) of the walk: at 128 heads
 16 tokens are 2048 row-heads, and a 256-token block costs the MXU
@@ -116,6 +126,11 @@ NEG_INF = -1e30
 M_INIT = -1e20
 LANES = 128
 VMEM_LIMIT = 96 * 1024 * 1024
+# Tokens of a span that the dense kernel folds by themselves (`short`): a
+# decode row's one, a verify span's [t, draft].
+SHORT = 2
+# The dense kernel's launch by the prediction module, on the device trace.
+MTP_NAME = "mtp_latent_attention_pallas"
 
 
 def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update):
@@ -234,10 +249,13 @@ def _select_kernel(s_ref, pos_ref, o_ref, *, topk):
 
 
 def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
-                   chains):
+                   chains, masked=True):
     meta = refs[:6]
-    q_ref, i_ref, thr_ref, hbm, o_ref, buf, sem, m_ref, l_ref, acc_ref = \
-        refs[6:]
+    if masked:
+        q_ref, i_ref, thr_ref, *refs = refs[6:]
+    else:  # no selection: neither its scores nor its threshold is here
+        q_ref, *refs = refs[6:]
+    hbm, o_ref, buf, sem, m_ref, l_ref, acc_ref = refs
     m_ref[...] = jnp.full_like(m_ref, M_INIT)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -267,14 +285,17 @@ def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
         m_ref[at, :] = m_new
 
     def keep_of(r0, n, b, lo, hi, base):
-        """What rows [r0, r0 + n) of the tile attend of block `b`."""
-        at = pl.ds(pl.multiple_of(lax.mul(b, block), block), block)
+        """What rows [r0, r0 + n) of the tile attend of block `b` (`r0` a
+        constant where there is a selection to slice)."""
         row, mine = _rows_of((n, block), lax.sub(lo, r0), lax.sub(hi, r0))
         key = lax.add(lax.broadcasted_iota(jnp.int32, (n, block), 1),
                       lax.mul(b, block))
-        return functools.reduce(jnp.logical_and, (
-            mine, key <= row + (base + r0),
-            i_ref[r0:r0 + n, at] >= thr_ref[r0:r0 + n, :]))
+        keep = jnp.logical_and(mine, key <= row + (base + r0))
+        if not masked:
+            return keep
+        at = pl.ds(pl.multiple_of(lax.mul(b, block), block), block)
+        return jnp.logical_and(
+            keep, i_ref[r0:r0 + n, at] >= thr_ref[r0:r0 + n, :])
 
     # A decode row in a tile of other sequences' tokens: its walk folds its
     # OWN `heads` row-heads, not the tile's (masked) `tile * heads` — a
@@ -298,10 +319,27 @@ def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
                  per_chain, rows)
             s = nxt
 
+    def short(rows, b, lo, hi, base):
+        """A span of at most SHORT tokens — a decode row, a `--spec` verify
+        span of one draft — folds the SHORT tokens' row-heads that hold it
+        (from its first row, or the tile's last SHORT), not the tile's:
+        rows of them that are another sequence's are masked, as in
+        `whole`."""
+        r0 = lax.min(lo, tile - SHORT)
+        at = pl.ds(pl.multiple_of(lax.mul(r0, heads), heads), SHORT * heads)
+        fold(at, scores(at, rows), keep_of(r0, SHORT, b, lo, hi, base), SHORT,
+             rows)
+
     def update(slot, b, lo, hi, base):
         rows = buf[slot]  # [block, lanes]
         if not alone:
             return whole(rows, b, lo, hi, base)
+        if not masked and tile >= SHORT:
+            few = lax.le(lax.sub(hi, lo), SHORT)
+            pl.when(few)(lambda: short(rows, b, lo, hi, base))
+            pl.when(jnp.logical_not(few))(
+                lambda: whole(rows, b, lo, hi, base))
+            return
         one = lax.eq(lax.sub(hi, lo), 1)
 
         @pl.when(one)
@@ -322,7 +360,8 @@ def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
 
 
 def _launch(kernel, tile, block, inputs, pool, out_lanes, out_dtype, scratch,
-            layer, page_table, q_start, q_lens, kv_lens, page_size, interpret):
+            layer, page_table, q_start, q_lens, kv_lens, page_size, interpret,
+            name=None):
     """One program a tile: the tile's blocks of `inputs` ([n_tiles, rows,
     lanes] each) in VMEM, the pool left in HBM, two buffers of `block`
     tokens."""
@@ -353,7 +392,7 @@ def _launch(kernel, tile, block, inputs, pool, out_lanes, out_dtype, scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), tile_first,
       q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
       kv_lens.astype(jnp.int32), page_table, *inputs, pool)
@@ -435,6 +474,29 @@ def mla_sparse_paged_attention_pallas(q_abs, scores, thr, lat_pool, layer,
     """o [T, H, rank] in q's dtype: q_abs [T, H, latent] (absorbed, scaled),
     scores [T, C] and thr [T] float32 (the selection), lat_pool [L, S,
     latent]."""
+    return _attention(q_abs, (scores, thr), lat_pool, layer, page_table,
+                      q_start, q_lens, kv_lens, page_size, rank, tile,
+                      interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "rank", "tile",
+                                             "interpret", "name"))
+def mla_dense_paged_attention_pallas(q_abs, lat_pool, layer, page_table,
+                                     q_start, q_lens, kv_lens, page_size: int,
+                                     rank: int, tile: int | None = None,
+                                     interpret: bool = False,
+                                     name: str | None = None):
+    """o [T, H, rank] with NO selection: every cached position up to the
+    token's own (a model with no indexer). `name`: the launch's name on the
+    device trace, where it is not this function's."""
+    return _attention(q_abs, None, lat_pool, layer, page_table, q_start,
+                      q_lens, kv_lens, page_size, rank, tile, interpret,
+                      name or "mla_dense_paged_attention_pallas")
+
+
+def _attention(q_abs, selection, lat_pool, layer, page_table, q_start, q_lens,
+               kv_lens, page_size, rank, tile, interpret, name=None):
+    """The attention kernel's launch; `selection` (scores, thr) or None."""
     T, H, _ = q_abs.shape
     # a step no longer than the indexer's tile stays one tile of that size
     tile = tile or (ATTEND_TILE if T > TILE else TILE)
@@ -443,14 +505,17 @@ def mla_sparse_paged_attention_pallas(q_abs, scores, thr, lat_pool, layer,
                                num_seqs=page_table.shape[0],
                                block=ATTEND_BLOCK,
                                chains=tile // CHAIN if tile % CHAIN == 0
-                               else 1)
+                               else 1, masked=selection is not None)
     rows = tile * H
     scratch = [pltpu.VMEM((rows, LANES), jnp.float32),
                pltpu.VMEM((rows, LANES), jnp.float32),
                pltpu.VMEM((rows, rank), jnp.float32)]
-    out = _launch(kernel, tile, ATTEND_BLOCK,
-                  [_tiles(q_abs, tile), _tiles(scores, tile),
-                   _tiles(thr.astype(jnp.float32)[:, None], tile)], lat_pool,
+    inputs = [_tiles(q_abs, tile)]
+    if selection is not None:
+        scores, thr = selection
+        inputs += [_tiles(scores, tile),
+                   _tiles(thr.astype(jnp.float32)[:, None], tile)]
+    out = _launch(kernel, tile, ATTEND_BLOCK, inputs, lat_pool,
                   (rows, rank), q_abs.dtype, scratch, layer, page_table,
-                  q_start, q_lens, kv_lens, page_size, interpret)
+                  q_start, q_lens, kv_lens, page_size, interpret, name)
     return out.reshape(-1, H, rank)[:T]

@@ -158,9 +158,10 @@ EVENT_FIELDS: Dict[str, Tuple[tuple, tuple]] = {
     # page claim with the allocator post-state, so page conservation
     # (free+used+cached==pool) stays checkable through speculation.
     "speculate": (("slot", "k"), ("source",)),
-    "spec_verify": (("slot", "proposed", "accepted"), ("rolled_back",)),
+    "spec_verify": (("slot", "proposed", "accepted"),
+                    ("rolled_back", "source")),
     "spec_rollback": (("slot", "kv_before", "kv_after", "freed",
-                       "free", "used", "cached", "pool"), ()),
+                       "free", "used", "cached", "pool"), ("source",)),
     "preempt": (("slot", "why"),
                 ("n", "free_pages", "victim_served", "vip")),
     "kv_stall": (("slot",), ("free_pages", "need")),

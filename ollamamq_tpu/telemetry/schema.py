@@ -159,8 +159,10 @@ SPEC_TOKENS_TOTAL = REGISTRY.counter(
     "ollamamq_spec_tokens_total",
     "Speculative draft tokens by outcome: proposed (composed into a "
     "verify span), accepted (matched the model's greedy argmax and "
-    "emitted), rejected (KV pages rolled back)",
-    labels=("model", "outcome"))
+    "emitted), rejected (KV pages rolled back); `proposer` is where the "
+    "drafts came from: ngram (prompt lookup on the host), mtp (the "
+    "model's own prediction module, on the device), fake",
+    labels=("model", "outcome", "proposer"))
 SPEC_ACCEPT_RATE = REGISTRY.gauge(
     "ollamamq_spec_accept_rate",
     "Accepted / proposed speculative draft tokens since start (0..1); "

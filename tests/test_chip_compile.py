@@ -345,7 +345,7 @@ def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
     # K and V rows — or a latent-attention model's latent rows and index
     # keys: two pools of different widths (ModelConfig.kv_row_dims).
-    pool, pool2 = (s((cfg.count(ATTENTION), NP * PS, lanes), jnp.bfloat16)
+    pool, pool2 = (s((cfg.cache_layers, NP * PS, lanes), jnp.bfloat16)
                    for lanes in cfg.kv_row_dims)
     recent, last_ids = s((S + 1, W)), s((S,))
     # The per-slot state: None (no leaf) for a model without such layers,
@@ -353,17 +353,23 @@ def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     conv = jax.tree_util.tree_map(
         lambda a: s(a.shape, a.dtype),
         jax.eval_shape(lambda: llama.alloc_slot_state(cfg, S)))
-    if which == "mq_ragged_step":
+    drafts = ()
+    if which == "mq_spec_step":  # the ragged step of a --spec runtime whose
+        rt.mtp = True  # proposer is the model's prediction module
+        drafts = (s((S + 1,)),)
+        fn = rt._get_ragged_jit(T, 1, (True, True, True))
+        words = rt._ragged_layout(T).size
+    elif which == "mq_ragged_step":
         fn = rt._get_ragged_jit(T, 0, (True, True, True))
         words = rt._ragged_layout(T).size
     else:
         fn = rt._get_decode_jit(8, (True, True, True))
         words = rt._decode_layout().size
     carried = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
-        (pool, pool2, recent, last_ids, conv)))
+        (pool, pool2, recent, last_ids, conv, drafts)))
     # The step's host inputs are ONE packed int32 array (step_pack).
     return fn.lower(params, s((words,)), pool, pool2, recent, last_ids,
-                    conv), words, carried
+                    conv, *drafts), words, carried
 
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
@@ -485,6 +491,58 @@ def test_deepseek_width_step_programs_carry_both_pools_in_place(
     tokens = T if which == "mq_ragged_step" else B
     per_head = tokens * MP * PS * 4
     assert mem.temp_size_in_bytes < 128 * per_head // 4 + 512 * 2 ** 20, mem
+
+
+# openPangu-Ultra-MoE's layers (config.py) over its dense layer and two
+# expert layers, 16 of the router's 256 experts held, a small vocabulary, and
+# the prediction module: the dense latent attention kernel at 128 heads over a
+# 640-lane latent pool of 3 + 1 layers, no second pool.
+OPENPANGU_CFG = ModelConfig(
+    name="chip-compile-openpangu-widths", vocab_size=2048,
+    hidden_size=7680, intermediate_size=18432, num_layers=3, num_heads=128,
+    num_kv_heads=128, head_dim=192, max_seq_len=MP * PS,
+    rope_theta=25_600_000.0, rms_norm_eps=1e-5, q_lora_rank=1536,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, sandwich_norm=True, num_experts=16, router_experts=256,
+    num_experts_per_tok=8, n_shared_experts=1, moe_intermediate_size=2048,
+    first_k_dense_replace=1, router_score="sigmoid", norm_topk_prob=True,
+    norm_topk_eps=1e-20, routed_scaling_factor=2.5,
+    num_nextn_predict_layers=1)
+
+
+def test_openpangu_width_spec_step_carries_the_pool_and_the_drafts_in_place(
+        v5e, monkeypatch):
+    """The `--spec` runtime's ragged step with the prediction module (PR 42),
+    at openPangu-Ultra-MoE's widths: the dense latent attention kernel — the
+    attention kernel with the selection's operands compiled out — compiles
+    for the chip, one launch a traced layer body under the name
+    `_ops.ATTENTION` counts and ONE more for the module's block under its
+    own; the module's expert layer launches the grouped matmul a third time;
+    the latent pool [4, S, 640] (the module's rows its last layer), the
+    second pool of NO lanes, the ring, the id carry and the draft carry all
+    come back aliased."""
+    from ollamamq_tpu.ops.pallas.mla_attention import MTP_NAME
+
+    lowered, _, carried = _lower_step_program(
+        v5e, "mq_spec_step", monkeypatch, OPENPANGU_CFG)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    trunk = [n for n in names if n.startswith(
+        "mla_dense_paged_attention_pallas")]
+    module = [n for n in names if n.startswith(MTP_NAME)]
+    assert (len(trunk), len(module)) == (2, 1), names
+    assert "paged_attention" not in MTP_NAME
+    assert not any("mla_sparse" in n or "dsa_" in n for n in names)
+    assert sum(n.startswith("gmm") for n in names) == 3 * 2  # layers, module
+    pool = 4 * NP * PS * 640 * 2
+    assert carried >= pool + (B + 1) * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried, (mem, carried)
+    # no [tokens, context] score a head leaves the kernel
+    assert mem.temp_size_in_bytes < 128 * T * MP * PS * 4 // 4 \
+        + 512 * 2 ** 20, mem
 
 
 @pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG, OLMO_HYBRID_CFG],

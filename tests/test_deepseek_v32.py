@@ -185,11 +185,11 @@ def test_the_tiny_family_its_plan_its_pools_and_its_counts():
     (dict(rope_scaling={"type": "yarn", "factor": 4}),
      "original_max_position_embeddings"),
     (dict(num_dense_layers=2), "first_k_dense_replace 1 is not"),
-    (dict(num_nextn_predict_layers=1), "num_nextn_predict_layers 1"),
+    (dict(num_nextn_predict_layers=2), "num_nextn_predict_layers 2"),
     (dict(moe_layer_freq=2), "moe_layer_freq 2"),
     (dict(ep_size=16), "ep_size 16"),
     (dict(head_dim=16), "head_dim 16 is not"),
-    (dict(index_topk=0), "served with its indexer"),
+    (dict(index_topk=0), "an indexer needs index_n_heads"),
     (dict(num_kv_heads=2), "as many kv heads as heads"),
     (dict(n_group=3), "n_group 3"),
     (dict(topk_group=1, num_experts_per_tok=5), "hold the top 5"),
@@ -563,12 +563,11 @@ def test_the_engine_serves_it_and_counts_what_the_selection_did(monkeypatch):
 @pytest.mark.parametrize("kw,match", [
     (dict(kv_dtype="int8"), "--kv-dtype int8: the page writer's scales"),
     (dict(weights_dtype="int8"), "--weights-dtype int8"),
-    (dict(spec=True), "--spec: the verify span"),
     (dict(prefix_cache=True), "--prefix-cache: the radix tree"),
     (dict(mesh_shape={"seq": 2}), "--sp: the ring prefill"),
     (dict(mesh_shape={"tensor": 2}), "--tp / --ep: the latent"),
     (dict(mesh_shape={"expert": 2}), "--tp / --ep: the latent"),
-], ids=["kv_int8", "w_int8", "spec", "prefix_cache", "sp", "tp", "ep"])
+], ids=["kv_int8", "w_int8", "prefix_cache", "sp", "tp", "ep"])
 def test_what_the_latent_pools_cannot_do_yet_is_refused_by_one_line(kw,
                                                                     match):
     err = validate_latent_pool(DS, **kw)
@@ -580,9 +579,8 @@ def test_what_the_latent_pools_cannot_do_yet_is_refused_by_one_line(kw,
 
 @pytest.mark.parametrize("over,match", [
     (dict(kv_dtype="int8"), "--kv-dtype int8"),
-    (dict(spec=True), "--spec"),
     (dict(prefix_cache=True), "--prefix-cache"),
-], ids=["kv_int8", "spec", "prefix_cache"])
+], ids=["kv_int8", "prefix_cache"])
 def test_the_runtime_refuses_them_at_construction(over, match):
     with pytest.raises(ValueError, match=match):
         _engine(NAME, **over)
@@ -592,9 +590,8 @@ def test_the_runtime_refuses_them_at_construction(over, match):
     (["--kv-dtype", "int8"], "--kv-dtype int8"),
     (["--tp", "2"], "--tp / --ep"),
     (["--ep", "2"], "--tp / --ep"),
-    (["--spec"], "--spec"),
     (["--prefix-cache"], "--prefix-cache"),
-], ids=["kv_int8", "tp", "ep", "spec", "prefix_cache"])
+], ids=["kv_int8", "tp", "ep", "prefix_cache"])
 def test_the_cli_ends_at_start_with_one_line(flags, match, caplog):
     from ollamamq_tpu import cli
 

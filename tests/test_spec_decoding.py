@@ -391,12 +391,12 @@ def test_spec_metrics_and_stats_surface():
 
     rng = np.random.default_rng(41)
     rt = make_rt(True, copy_weights=True)
-    base = tm.SPEC_TOKENS_TOTAL.labels(model="test-tiny",
-                                       outcome="proposed").value
+    base = tm.SPEC_TOKENS_TOTAL.labels(model="test-tiny", outcome="proposed",
+                                       proposer="ngram").value
     run_all(rt, _mixed_prompts(rng, 3), max_tokens=32)
     assert rt.spec_proposed > 0
-    assert tm.SPEC_TOKENS_TOTAL.labels(model="test-tiny",
-                                       outcome="proposed").value > base
+    assert tm.SPEC_TOKENS_TOTAL.labels(model="test-tiny", outcome="proposed",
+                                       proposer="ngram").value > base
     s = rt.stats()["spec"]
     assert s is not None
     assert s["proposed"] == rt.spec_proposed

@@ -207,7 +207,8 @@ def deepseek_v32_keys(mc) -> dict:
     `mc`, in the published spellings: all that reference reads."""
     return {"num_attention_heads": mc.num_heads,
             "hidden_size": mc.hidden_size, "rms_norm_eps": mc.rms_norm_eps,
-            "rope_theta": mc.rope_theta, "rope_scaling": dict(mc.rope_scaling),
+            "rope_theta": mc.rope_theta,
+            "rope_scaling": dict(mc.rope_scaling or ()),
             "head_dim": mc.head_dim, "q_lora_rank": mc.q_lora_rank,
             "kv_lora_rank": mc.kv_lora_rank,
             "qk_nope_head_dim": mc.qk_nope_head_dim,
@@ -230,3 +231,18 @@ def deepseek_v32_keys(mc) -> dict:
             "moe_intermediate_size": mc.moe_intermediate_size,
             "intermediate_size": mc.intermediate_size,
             "vocab_size": mc.vocab_size}
+
+
+def openpangu_reference():
+    """...and of the latent-attention family with no indexer, sandwich norms
+    and a prediction module (benchmarks/reference/openpangu_ultra_decoder.py)."""
+    return _reference("openpangu_ultra_decoder")
+
+
+def openpangu_keys(mc) -> dict:
+    """What a configuration file says of the openPangu ModelConfig `mc`, in
+    the published spellings: all that reference reads (the latent family's
+    keys — no indexer, no groups, no bias, no rope_scaling: all empty — and
+    its own two)."""
+    return {**deepseek_v32_keys(mc), "sandwich_norm": mc.sandwich_norm,
+            "num_nextn_predict_layers": mc.num_nextn_predict_layers}
