@@ -418,27 +418,30 @@ class StepInFlight:
 
     `rows`: (kind, slot, req, chunk_pos|drafts, span) as composed; a
     fused scan's are its active slots with span = `k_steps` (0 = a
-    ragged step). `ctx0[i]`: the context length row i's first sampled id
-    leaves; `emits[i]`: whether the host emits an id for it (a span
-    inside a prompt samples nothing). `ending`: slots whose request is
+    ragged step). `emits[i]`: whether the host emits an id for row i (a
+    span inside a prompt samples nothing); where a row starts is not
+    kept here: steps settle in launch order, so at its settle it is what
+    the slot's settled ids say (`step_settle`), whatever the verify spans
+    before it accepted. `ending`: slots whose request is
     known to end with this step — left out of the next composition,
     finished when this step is settled. `state`: launched → collected
     (ids on the host, next input tokens set) → settled."""
 
-    __slots__ = ("rows", "k_steps", "sp", "fields", "ctx0", "emits",
+    __slots__ = ("rows", "k_steps", "sp", "fields", "emits",
                  "mean_ctx", "toks_dev", "n_emit_dev", "toks", "n_emit",
-                 "ending", "state", "t_launch", "dt", "prev")
+                 "ending", "state", "t_launch", "dt", "prev", "no")
 
-    def __init__(self, rows, k_steps, sp, fields, ctx0, emits, mean_ctx):
+    def __init__(self, rows, k_steps, sp, fields, emits, mean_ctx):
         self.rows, self.k_steps, self.sp, self.fields = \
             rows, k_steps, sp, fields
-        self.ctx0, self.emits, self.mean_ctx = ctx0, emits, mean_ctx
+        self.emits, self.mean_ctx = emits, mean_ctx
         self.toks_dev = self.n_emit_dev = self.toks = self.n_emit = None
         self.ending: set = set()
         self.state = "launched"
         self.t_launch = time.perf_counter()  # the step profiler's clock
         self.dt = 0.0
         self.prev: Optional["StepInFlight"] = None  # unsettled step before
+        self.no = 0  # which launch composed it (ModelRuntime._launch_no)
 
 
 class ModelRuntime:
@@ -662,6 +665,18 @@ class ModelRuntime:
         # Tokens launched for a slot's request and not yet appended to its
         # generated_ids: what count-based finishes are predicted from.
         self._ahead = np.zeros((S,), np.int32)
+        # Positions a slot's unsettled verify spans may add BEYOND what
+        # `seq_lens` and `_ahead` already count (a span of 1 + d ids is
+        # counted as one until it is collected): while a step is unsettled
+        # the slot's true length lies in [seq_lens, seq_lens + _slack].
+        self._slack = np.zeros((S,), np.int32)
+        # What a speculating runtime's compositions claimed pages for: the
+        # tokens a slot's pages were last grown to hold, and the number of
+        # the launch that composed it — a rollback at the settle of an
+        # EARLIER step must not trim below it (`step_settle`).
+        self._launch_no = 0
+        self._claim = np.zeros((S,), np.int32)
+        self._claim_no = np.zeros((S,), np.int64)
         # Stream items (hand-overs) pushed since the settle in progress
         # began: `stream_items` on its step sample.
         self._stream_items = 0
@@ -753,10 +768,19 @@ class ModelRuntime:
         # sees a draft, only how many were accepted. `_draft_ok[slot]`: a
         # step with the module has left the slot's draft there. N-gram
         # prompt lookup on the host otherwise.
+        # Beside it, `len_ids[slot]`: the slot's LENGTH (the position of its
+        # next input token), which the same program keeps — a verify span
+        # adds 1 or 2 and only the device knows which until the step is
+        # collected. A decode or verify row's positions, write slots and
+        # `kv_len` are derived from it in the program, so the next step is
+        # composed and launched while this one runs (`may_overlap`).
         self.mtp = self.spec and model_cfg.num_nextn_predict_layers > 0
         self.spec_k = 1 if self.mtp else engine_cfg.spec_k
-        self.draft_ids = (jnp.zeros((engine_cfg.max_slots + 1,), jnp.int32)
-                          if self.mtp else None)
+        self.draft_ids = self.len_ids = None
+        if self.mtp:
+            self.draft_ids, self.len_ids = (
+                jnp.zeros((engine_cfg.max_slots + 1,), jnp.int32)
+                for _ in range(2))
         self._draft_ok = np.zeros((engine_cfg.max_slots,), bool)
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -994,10 +1018,11 @@ class ModelRuntime:
         if not self.mtp:
             return fn(self.params, self._upload(buf), self.kc, self.vc,
                       self.recent, self.last_ids, self.slot_state)
-        # The module's drafts stay on the device: one more carry.
-        *out, self.draft_ids = fn(
+        # The module's drafts and the rows' lengths stay on the device:
+        # two more carries.
+        *out, self.draft_ids, self.len_ids = fn(
             self.params, self._upload(buf), self.kc, self.vc, self.recent,
-            self.last_ids, self.slot_state, self.draft_ids)
+            self.last_ids, self.slot_state, self.draft_ids, self.len_ids)
         return tuple(out)
 
     def _ragged_layout(self, T_pad: int) -> step_pack.StepLayout:
@@ -1043,14 +1068,23 @@ class ModelRuntime:
         with the ids, in the transfer the collect makes anyway.
 
         A runtime whose proposer is the model's prediction module (`mtp`)
-        takes and returns one more carry, `drafts` [S + 1] by slot: a spec
-        row's draft is read from it into the stream (the host wrote a
+        takes and returns two more carries, [S + 1] by slot. `drafts`: a
+        spec row's draft is read from it into the stream (the host wrote a
         placeholder), and after the trunk the module runs over the whole
         stream — each position with the token that follows it: the next of
         its span, what the trunk chose at a verify span's positions, the id
         just sampled at a row's last, `next_tok` where a span ends inside
         its prompt — and leaves at each row's slot its prediction of the
-        token after next, read at the row's last ACCEPTED position."""
+        token after next, read at the row's last ACCEPTED position.
+        `lens`: each slot's length, the position of its next input token.
+        The host does not know it while a verify span is unsettled, so a
+        decode or verify row comes marked "from the carry" — `kv_len` < 0,
+        and `tok_pos` -2 - j at the span's j-th token — and the program
+        derives its positions `lens[slot] + j`, its write slots through the
+        row's page-table row and `kv_len = lens[slot] + q_len`; a prompt's
+        span comes host-written as ever. Every row leaves its slot's new
+        length: a span its end, a decode row one more, a verify span
+        `n_emit` more."""
         key_ = ("ragged", T_pad, k_cap, flags)
         _sp_compile_evict(self, self._prefill_jits, key_)
         if key_ not in self._prefill_jits:
@@ -1063,11 +1097,24 @@ class ModelRuntime:
             lay = self._ragged_layout(T_pad)
 
             def mq_ragged_step(params, buf, kc, vc, recent, last_ids, conv,
-                               drafts=None):
+                               drafts=None, lens=None):
                 (tokens, tok_seq, tok_pos, write_slots, q_start, q_len,
                  kv_len, ring_len, is_first, append, is_spec, next_tok,
                  seed_rows, slot_ids, pt, temp, tk, tp, pen, pres, freq,
                  seeds, rng) = lay.unpack(buf)
+                if mtp:
+                    # Rows from the carry: where they are is the device's
+                    # to say (the step before may still be running).
+                    start = lens[slot_ids]
+                    kv_len = jnp.where(kv_len < 0, start + q_len, kv_len)
+                    pos = start[tok_seq] - 2 - tok_pos
+                    MP = pt.shape[1]
+                    page = pt.reshape(-1)[
+                        tok_seq * MP + jnp.clip(pos // ps, 0, MP - 1)]
+                    carried = tok_pos < -1
+                    write_slots = jnp.where(carried, page * ps + pos % ps,
+                                            write_slots)
+                    tok_pos = jnp.where(carried, pos, tok_pos)
                 key = jax.random.PRNGKey(rng[0])
                 tokens = jnp.where(
                     tokens < 0,
@@ -1202,11 +1249,13 @@ class ModelRuntime:
                     attn_impl=attn_impl, mesh=mesh)
                 drafts = drafts.at[slot_ids].set(
                     jnp.argmax(draft_logits, axis=-1).astype(jnp.int32))
-                return toks, n_emit, kc, vc, recent, tok, conv, drafts
+                lens = lens.at[slot_ids].set(
+                    kv_len - q_len + jnp.where(spec, n_emit, q_len))
+                return toks, n_emit, kc, vc, recent, tok, conv, drafts, lens
 
             _sp_note_compile(self, "ragged", key_, self._prefill_jits,
                              jax.jit(mq_ragged_step,
-                                     donate_argnums=(2, 3, 4, 5, 6, 7)
+                                     donate_argnums=(2, 3, 4, 5, 6, 7, 8)
                                      if mtp else (2, 3, 4, 5, 6)))
         return self._prefill_jits[key_]
 
@@ -1497,6 +1546,7 @@ class ModelRuntime:
         self.seeds[slot] = 0
         self.slot_req[slot] = None
         self._ahead[slot] = 0
+        self._slack[slot] = 0
         self._tok_step[slot] = None
         self._draft_ok[slot] = False
         self._stalled_slots.discard(slot)
@@ -1988,12 +2038,15 @@ class ModelRuntime:
         draft, which the step before left on the device (`draft_ids`) —
         the host composes its place ([0]: the program writes the id in)
         and never reads it; none while no step with the module has served
-        the slot, or no budget remains. Otherwise n-gram prompt lookup on
-        the host (`_propose_ngram`)."""
+        the slot, or no budget remains in the LONGER case of a step still
+        unsettled (every id it may emit counted, `_slack`). Otherwise
+        n-gram prompt lookup on the host (`_propose_ngram`)."""
         if not self.mtp:
             return self._propose_ngram(req, slot)
-        remaining = req.sampling.max_tokens - len(req.generated_ids) - 1
-        room = self._max_ctx - int(self.seq_lens[slot]) - 2
+        slack = int(self._slack[slot])
+        remaining = (req.sampling.max_tokens - len(req.generated_ids)
+                     - int(self._ahead[slot]) - slack - 1)
+        room = self._max_ctx - int(self.seq_lens[slot]) - slack - 2
         return [0] if self._draft_ok[slot] and min(remaining, room) > 0 \
             else []
 
@@ -2405,20 +2458,20 @@ class ModelRuntime:
 
     def may_overlap(self) -> bool:
         """May a step be launched while the one before it is unsettled?
-        Not where composing needs the host to have seen the ids (the
-        n-gram proposer reads generated_ids) or how many of them a row
-        emitted (a verify span's length sets the next step's positions and
-        page claims — the module's drafts stay on the device, a row's
-        length does not yet: ROADMAP queue A): a speculating runtime
-        settles every step in the tick that launched it."""
-        return not self.spec
+        Not where composing needs the host to have seen the ids: the
+        n-gram proposer reads generated_ids, so its runtime settles every
+        step in the tick that launched it. The prediction module's drafts
+        and the lengths its verify spans leave stay on the device
+        (`draft_ids`, `len_ids`), and the host plans on the range a
+        length lies in (`_slack`)."""
+        return not self.spec or self.mtp
 
     def settle_inflight(self, core: MQCore) -> None:
         """Bring the runtime to rest: settle the step in flight, if any.
         Called wherever the host must have seen every id, or slot and
         page state must be final — preemption and page exhaustion,
-        engine calls that read slots (migration, /debug), a speculating
-        runtime's next composition, shutdown."""
+        engine calls that read slots (migration, /debug), the next
+        composition of a runtime whose proposer reads the ids, shutdown."""
         h = self.inflight
         if h is not None:
             self.step_settle(h, core)
@@ -2446,6 +2499,7 @@ class ModelRuntime:
                 x.state = "settled"
                 x.sp.abandon()
         self._ahead[:] = 0
+        self._slack[:] = 0
         self._tok_step = [None] * len(self._tok_step)
 
     def _live_rows(self) -> List[int]:
@@ -2459,11 +2513,17 @@ class ModelRuntime:
                 if r is not None and i not in self._stalled_slots
                 and i not in ending]
 
-    def _grow_or_settle(self, slot: int, need: int, core: MQCore) -> None:
-        """Page headroom for a decode row. When the pool cannot give it,
-        the way out (preempt a victim, stall, finish by LENGTH) needs
-        every slot at rest: settle the step in flight first, which may
-        itself free pages — or finish this very slot."""
+    def _reach(self, slot: int) -> int:
+        """The slot's length in the longer case of what is unsettled."""
+        return int(self.seq_lens[slot]) + int(self._slack[slot])
+
+    def _grow_or_settle(self, slot: int, n: int, core: MQCore) -> None:
+        """Page headroom for a decode row's next `n` positions. When the
+        pool cannot give it, the way out (preempt a victim, stall, finish
+        by LENGTH) needs every slot at rest: settle the step in flight
+        first, which may itself free pages, make the slot's length exact
+        — or finish this very slot."""
+        need = self._reach(slot) + n
         if self._extend_pages(self.slot_pages[slot], need):
             return
         if self.inflight is not None:
@@ -2471,9 +2531,11 @@ class ModelRuntime:
             self.settle_inflight(core)
             if self.slot_req[slot] is not req:
                 return
-            if self.alloc.free_pages > free and self._extend_pages(
-                    self.slot_pages[slot], need):
+            exact = self._reach(slot) + n
+            if (self.alloc.free_pages > free or exact < need) \
+                    and self._extend_pages(self.slot_pages[slot], exact):
                 return  # the settled step's finishes freed the pages
+            need = exact
         self._page_exhausted(slot, need, core)
 
     def step_ragged_launch(self, core: MQCore) -> Optional["StepInFlight"]:
@@ -2492,13 +2554,19 @@ class ModelRuntime:
         decode row advanced one position, a span by its length, a final
         span became a decode row, finishes by max_tokens/max_ctx are
         counts — except the sampled id, which the program reads from its
-        `last_ids` carry. A row whose request turns out to have finished
-        (EOS, a stop string, a cancel) rides this step anyway and its
-        output is dropped at settle (`wasted_rows`). Whatever needs the
-        ids or slot state at rest settles the step in flight first: page
-        exhaustion and preemption here; a speculating runtime (the
-        proposer reads generated_ids) has none in flight to begin with
-        (`may_overlap`).
+        `last_ids` carry, and, behind a verify span of the prediction
+        module's runtime, whether the row advanced one position or two:
+        the program reads its length from the `len_ids` carry, and the
+        host plans for the range — `seq_lens` is the least it can be,
+        `_slack` what the unsettled span may add; pages are claimed and
+        the draft's budget is taken for the longer case (`_reach`). A row
+        whose request turns out to have finished (EOS, a stop string, a
+        cancel; a count reached by a draft the unsettled span accepted)
+        rides this step anyway and its output is dropped at settle
+        (`wasted_rows`). Whatever needs the ids or slot state at rest
+        settles the step in flight first: page exhaustion and preemption
+        here; a runtime whose proposer reads generated_ids (n-gram) has
+        none in flight to begin with (`may_overlap`).
 
         Host state advances only after the dispatch returned: a launch
         that raises leaves the step in flight intact, is settled behind
@@ -2526,6 +2594,7 @@ class ModelRuntime:
                                     int(self.seq_lens[i]) + 1):
                 self._stalled_slots.discard(i)
         spec_plan: Dict[int, List[int]] = {}  # slot -> draft tokens
+        self._launch_no += 1
         live = self._live_rows()
         # Draft budget: the stream must always fit every decode row at
         # one token plus whatever drafts we compose.
@@ -2544,12 +2613,12 @@ class ModelRuntime:
                     self._drop_expired_slot(i, core)
                     continue
                 drafts = self._propose_drafts(r, i)[:max(0, spec_budget)]
-            need = int(self.seq_lens[i]) + 1 + len(drafts)
-            if drafts and not self._extend_pages(self.slot_pages[i], need):
+            # (Behind an unsettled verify span: pages for the LONGER case.)
+            if drafts and not self._extend_pages(
+                    self.slot_pages[i], self._reach(i) + 1 + len(drafts)):
                 drafts = []  # no headroom to speculate: plain decode row
-                need = int(self.seq_lens[i]) + 1
             if not drafts:
-                self._grow_or_settle(i, need, core)
+                self._grow_or_settle(i, 1, core)
             if self.slot_req[i] is not None and i not in self._stalled_slots:
                 self.page_table[i, :] = kvc.make_page_table_row(
                     self.slot_pages[i], self.ecfg.max_pages_per_seq
@@ -2557,6 +2626,8 @@ class ModelRuntime:
                 if drafts:
                     spec_plan[i] = drafts
                     spec_budget -= len(drafts)
+                    self._claim[i] = self._reach(i) + 1 + len(drafts)
+                    self._claim_no[i] = self._launch_no
                     self._jrec("speculate", r, slot=i, k=len(drafts),
                                source=self.proposer)
         if not self.chunking and not spec_plan and not self.mtp:
@@ -2658,10 +2729,14 @@ class ModelRuntime:
         tok_seq[:] = min(len(rows), S - 1)
 
         off = 0
-        # Per row: the context length its first sampled id leaves, and
-        # whether it samples one the host emits at all.
-        ctx0: List[int] = []
+        # Per row: whether it samples an id the host emits at all, and its
+        # context once the span is in (a row from the carry: at the least).
         emits: List[bool] = []
+        row_kv: List[int] = []
+        # Decode and verify rows of the module's runtime: where they are,
+        # the device says (`tok_pos` -2 - j marks a span's j-th token).
+        carry_pos = (-2 - np.arange(self.spec_k + 1, dtype=np.int32)
+                     if self.mtp else None)
         for idx, (kind, slot, req, cpos, span) in enumerate(rows):
             s = req.sampling
             slot_ids[idx] = slot
@@ -2680,13 +2755,17 @@ class ModelRuntime:
                 # step before this one sampled" (the carry).
                 tokens[off] = self.last_tokens[slot]
                 tok_seq[off] = idx
-                tok_pos[off] = pos
                 row = self.page_table[slot]
-                write_slots[off] = row[pos // ps] * ps + pos % ps
-                kv_len[idx] = pos + 1
+                if carry_pos is not None:
+                    tok_pos[off] = -2
+                    kv_len[idx] = -1
+                else:
+                    tok_pos[off] = pos
+                    write_slots[off] = row[pos // ps] * ps + pos % ps
+                    kv_len[idx] = pos + 1
                 append[idx] = 1  # ring_len 0: input token already rolled
                 pt_rows[idx] = row
-                ctx0.append(pos + 1)
+                row_kv.append(pos + 1)
                 emits.append(True)
             elif kind == "spec":
                 # Speculative verify span: the slot's input token plus
@@ -2700,16 +2779,20 @@ class ModelRuntime:
                 d = len(drafts)
                 tokens[off:off + d + 1] = [self.last_tokens[slot]] + drafts
                 tok_seq[off:off + d + 1] = idx
-                positions = np.arange(pos, pos + d + 1, dtype=np.int32)
-                tok_pos[off:off + d + 1] = positions
                 row = self.page_table[slot]
-                write_slots[off:off + d + 1] = (
-                    row[positions // ps] * ps + positions % ps)
-                kv_len[idx] = pos + 1 + d
+                if carry_pos is not None:
+                    tok_pos[off:off + d + 1] = carry_pos[:d + 1]
+                    kv_len[idx] = -1
+                else:
+                    positions = np.arange(pos, pos + d + 1, dtype=np.int32)
+                    tok_pos[off:off + d + 1] = positions
+                    write_slots[off:off + d + 1] = (
+                        row[positions // ps] * ps + positions % ps)
+                    kv_len[idx] = pos + 1 + d
                 is_spec[idx] = 1
                 append[idx] = 1
                 pt_rows[idx] = row
-                ctx0.append(pos + 1)
+                row_kv.append(pos + 1 + d)
                 emits.append(True)
             else:
                 piece = req.prompt_tokens[cpos:cpos + span]
@@ -2734,7 +2817,7 @@ class ModelRuntime:
                 if not final:  # (what the prediction module reads there)
                     next_tok[idx] = req.prompt_tokens[cpos + span]
                 pt_rows[idx] = row
-                ctx0.append(cpos + span)
+                row_kv.append(cpos + span)
                 emits.append(final)
                 req.trace_event("prefill_chunk", pos=cpos, tokens=span)
                 self._jrec("chunk", req, slot=slot, pos=cpos, tokens=span,
@@ -2767,9 +2850,11 @@ class ModelRuntime:
             batch_fields["spec_tokens"] = int(spec_tokens)
             _sp.mode = "spec_verify"
         if self.mtp:
-            # drafts verified this pass, and the positions the module ran
-            # over to leave the next ones (every token of the stream)
-            _sp.note(mtp_drafts=int(spec_tokens), mtp_rows=int(T_real))
+            # drafts verified this pass, the positions the module ran over
+            # to leave the next ones (every token of the stream), and the
+            # rows whose positions the program took from its length carry
+            _sp.note(mtp_drafts=int(spec_tokens), mtp_rows=int(T_real),
+                     len_carry_rows=n_decode)
         prev = self._settle_before_compile(
             self._prefill_jits,
             ("ragged", T_pad, k_cap,
@@ -2777,8 +2862,9 @@ class ModelRuntime:
         _sp.note(T_pad=int(T_pad), k_cap=int(k_cap), tokens=int(T_real),
                  overlapped=int(prev is not None))
         _sp.mark("host_prep")
-        h = StepInFlight(rows, 0, _sp, batch_fields, ctx0, emits,
-                         float(np.mean(kv_len[:len(rows)])))
+        h = StepInFlight(rows, 0, _sp, batch_fields, emits,
+                         float(np.mean(row_kv)))
+        h.no = self._launch_no
         rng[0] = self._next_rng()
         self._h2d = [0, 0]
         try:
@@ -2799,8 +2885,7 @@ class ModelRuntime:
         self._note_slot_state(_sp, opened, len(rows) - opened,
                               sum(n == 1 for n in spans),
                               sum(n for n in spans if n > 1))
-        self._note_latent(_sp, zip(q_len[:len(rows)].tolist(),
-                                   kv_len[:len(rows)].tolist()))
+        self._note_latent(_sp, zip(spans, row_kv))
         _sp.mark("dispatch")
         _sp.park()
 
@@ -2809,11 +2894,14 @@ class ModelRuntime:
         for idx, (kind, slot, req, cpos, span) in enumerate(rows):
             if self.mtp and emits[idx]:
                 self._draft_ok[slot] = True  # this step leaves it there
-            if kind == "decode":
+            if kind != "prefill":
+                # A verify span counts as ONE id until it is collected;
+                # the drafts it may accept besides are the slot's slack.
                 if self.slot_req[slot] is req:  # (not finished by a settle
                     self._launched(  # this launch itself had to make)
                         h, idx, slot, req, int(self.seq_lens[slot]) + 1)
-            elif kind == "prefill":
+                    self._slack[slot] += span - 1
+            else:
                 req._chunk_pos = cpos + span
                 if emits[idx]:
                     # Final span: publish the page-table row (decode
@@ -2956,7 +3044,7 @@ class ModelRuntime:
                 continue  # finished when a step was settled, below
             # Never a silent LENGTH: preempt-with-recompute, stall on a
             # reservation, or error explicitly (kv_exhausted).
-            self._grow_or_settle(i, int(self.seq_lens[i]) + k_steps, core)
+            self._grow_or_settle(i, k_steps, core)
             if self.slot_req[i] is not None and i not in self._stalled_slots:
                 self.page_table[i, :] = kvc.make_page_table_row(
                     self.slot_pages[i], self.ecfg.max_pages_per_seq
@@ -3005,9 +3093,7 @@ class ModelRuntime:
         _sp.mark("host_prep")
         # Mean context BEFORE the step advances seq_lens: feeds the
         # attention term of the per-step FLOPs model.
-        h = StepInFlight(rows, k_steps, _sp, None,
-                         [int(self.seq_lens[i]) + 1 for i in active],
-                         [True] * len(active),
+        h = StepInFlight(rows, k_steps, _sp, None, [True] * len(active),
                          float(np.mean(self.seq_lens[active])))
         self._h2d = [0, 0]
         h.toks_dev, self.kc, self.vc, self.recent, self.last_ids, \
@@ -3079,16 +3165,19 @@ class ModelRuntime:
         else:
             last = toks[:, 0].tolist()
             eos = [t == self.tokenizer.eos_id for t in last]
-        for idx, (kind, slot, req, _cpos, _span) in enumerate(h.rows):
+        for idx, (kind, slot, req, _cpos, span) in enumerate(h.rows):
             if self.slot_req[slot] is not req or not h.emits[idx]:
                 continue
             if kind == "spec":
-                # Its length is known only now; a speculating runtime
-                # launches nothing before this (`may_overlap`).
+                # How many ids it emitted is known only now: the launch
+                # counted one, the accepted drafts come off the slack.
                 n = int(h.n_emit[idx])  # accepted + 1
-                self.seq_lens[slot] += n
-                self._ahead[slot] += n
-                self.last_tokens[slot] = int(toks[idx, n - 1])
+                self.seq_lens[slot] += n - 1
+                self._ahead[slot] += n - 1
+                self._slack[slot] -= span - 1
+                if self._tok_step[slot] is h:
+                    self.last_tokens[slot] = int(toks[idx, n - 1])
+                    self._tok_step[slot] = None
                 continue
             i = slot if h.k_steps else idx
             # Ended by its ids or a cancel (by a count: known at launch).
@@ -3154,7 +3243,6 @@ class ModelRuntime:
 
         emitted = wasted = 0
         spec_accepted = spec_pages = 0
-        ctx0 = h.ctx0
         self._stream_items = 0
         # Row-major: a row's tokens of this step — 1, `n_emit` of a
         # speculated row, K of a scan — leave as one stream item, and the
@@ -3170,9 +3258,13 @@ class ModelRuntime:
                     wasted += 1  # finished/cancelled between launch & emit
                     continue
                 n = int(h.n_emit[idx]) if kind == "spec" else max(1, K)
+                # The context its first id leaves: every step before this
+                # one is settled, so the slot's length less what is still
+                # unsettled (this row and any launched behind it) is exact.
+                ctx0 = int(self.seq_lens[slot]) - int(self._ahead[slot]) + 1
                 self._ahead[slot] -= n
                 taken = self._emit_row(
-                    slot, ids[slot] if K else ids[idx][:n], core, ctx0[idx])
+                    slot, ids[slot] if K else ids[idx][:n], core, ctx0)
                 self.tokens_generated += taken
                 if kind != "prefill":  # decode-row tokens, as ever
                     emitted += taken
@@ -3188,10 +3280,17 @@ class ModelRuntime:
                         # Rejected drafts wrote KV past the accepted
                         # context: release their page claim (the finish
                         # paths already freed everything when the stream
-                        # ended mid-emission).
+                        # ended mid-emission) — down to the next id's own
+                        # position, or to what a LATER composition claimed
+                        # for the slot's next verify span (launched behind
+                        # this step, or being composed as a launch settles
+                        # it): the longer case, which the composition after
+                        # it claims again whatever this step accepted.
+                        keep = (int(self._claim[slot])
+                                if self._claim_no[slot] > h.no
+                                else self._reach(slot) + 1)
                         spec_pages += self._rollback_spec(
-                            slot, req, ctx0[idx] - 1 + span,
-                            int(self.seq_lens[slot]) + 1)
+                            slot, req, max(ctx0 - 1 + span, keep), keep)
         _sp.note(stream_items=self._stream_items,
                  stream_wakeups=woken.wakeups)
         if self.mtp:
@@ -4736,11 +4835,11 @@ class TPUEngine:
         queued behind an unfinished scan, so an arrival never waits for
         two. Where the next composition needs the host to have seen the
         ids, or state must be at rest, the depth falls to zero and the
-        step is settled in the tick that launched it: a speculating
-        runtime, CPU multi-host (one cross-host computation at a time),
-        pending engine calls and rebuild swaps (settled above, before
-        they run); page exhaustion and failures settle or void inside
-        the step functions. Every runtime's launch comes before any
+        step is settled in the tick that launched it: a runtime whose
+        `--spec` proposer is the n-gram lookup, CPU multi-host (one
+        cross-host computation at a time), pending engine calls and
+        rebuild swaps (settled above, before they run); page exhaustion
+        and failures settle or void inside the step functions. Every runtime's launch comes before any
         settle, so dp replicas and models on disjoint submeshes run
         concurrently."""
         # The engine thread's time is accounted for without a gap

@@ -197,7 +197,7 @@ class FakeRuntime:
         # The fake's device is its sleep: the step is launched here and
         # has left the "chip" when the sleep is over — the done-bracket a
         # real step gets from its ids' is_ready(), from the fake's own
-        # notion of a step's end. Like a speculating runtime it launches
+        # notion of a step's end. Like an n-gram --spec runtime it launches
         # nothing before it has read the step ahead: dry every step.
         t_end = time.perf_counter() + self.token_latency_s
         _sp.launched(lambda: time.perf_counter() >= t_end, model=self.name)

@@ -530,8 +530,8 @@ STEPS_LAUNCHED_DRY_TOTAL = REGISTRY.counter(
     "ollamamq_steps_launched_dry_total",
     "Steps launched onto a chip that was known to have nothing queued "
     "(`dry_lo_ms` > 0): every step after a fused scan and every step of "
-    "a speculating runtime by design, any other step only when the host "
-    "was late", labels=("model",))
+    "an n-gram --spec runtime by design, any other step only when the "
+    "host was late", labels=("model",))
 THREAD_CPU_SECONDS_TOTAL = REGISTRY.counter(
     "ollamamq_thread_cpu_seconds_total",
     "CPU seconds of the threads that serve: thread=\"engine\" the engine "

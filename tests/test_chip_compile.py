@@ -356,7 +356,7 @@ def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     drafts = ()
     if which == "mq_spec_step":  # the ragged step of a --spec runtime whose
         rt.mtp = True  # proposer is the model's prediction module
-        drafts = (s((S + 1,)),)
+        drafts = (s((S + 1,)),) * 2  # its drafts and its rows' lengths
         fn = rt._get_ragged_jit(T, 1, (True, True, True))
         words = rt._ragged_layout(T).size
     elif which == "mq_ragged_step":
@@ -519,8 +519,8 @@ def test_openpangu_width_spec_step_carries_the_pool_and_the_drafts_in_place(
     `_ops.ATTENTION` counts and ONE more for the module's block under its
     own; the module's expert layer launches the grouped matmul a third time;
     the latent pool [4, S, 640] (the module's rows its last layer), the
-    second pool of NO lanes, the ring, the id carry and the draft carry all
-    come back aliased."""
+    second pool of NO lanes, the ring, the id carry, the draft carry and the
+    length carry (PR 44) all come back aliased."""
     from ollamamq_tpu.ops.pallas.mla_attention import MTP_NAME
 
     lowered, _, carried = _lower_step_program(

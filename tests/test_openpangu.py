@@ -12,8 +12,10 @@ heads, a dense causal softmax, no cache) at `test-tiny-openpangu`, seeded
 random weights, float32, on the CPU; the dense kernel in interpret mode
 against its jnp twin; the shares' sum; what leaving a piece out costs; and the
 engine by id stream: what `--spec` emits is what greedy decoding emits, with
-the module's real drafts and with a proposer forced right and forced wrong (a
-test double: the draft carry overwritten from the host between steps)."""
+the module's real drafts and with a proposer forced right, wrong and both by
+turns (a test double on the device: the draft carry overwritten after every
+launch at the program's own length carry, `test_step_overlap.force_proposer`),
+under the pipelined loop and under the loop settled in its tick (PR 44)."""
 
 import dataclasses
 
@@ -30,7 +32,8 @@ from ollamamq_tpu.engine.engine import ModelRuntime
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops import mla
 from ollamamq_tpu.ops.sampling import SamplingParams
-from test_step_overlap import _engine, _prompt, _rt, drive
+from test_step_overlap import (PROPOSERS, _engine, _prompt, _rt, drive,
+                               force_proposer)
 from testutil import openpangu_keys, openpangu_reference
 
 NAME = "test-tiny-openpangu"
@@ -532,26 +535,6 @@ def greedy():
     return got
 
 
-def _force(monkeypatch, greedy_ids, right):
-    """The proposer's test double: after every settle, overwrite each seated
-    slot's draft ON THE DEVICE with the id greedy decoding emits next
-    (`right(m)`) or with another one — m is how many ids the request has."""
-    real = ModelRuntime.step_settle
-
-    def settle(self, h, core):
-        n = real(self, h, core)
-        for slot, req in enumerate(self.slot_req):
-            if req is None:
-                continue
-            ids, m = greedy_ids[req.user], len(req.generated_ids)
-            if m < len(ids):
-                self.draft_ids = self.draft_ids.at[slot].set(
-                    ids[m] if right(m) else (ids[m] + 1) % 500)
-        return n
-
-    monkeypatch.setattr(ModelRuntime, "step_settle", settle)
-
-
 @pytest.mark.parametrize("proposer", ["module", "right", "wrong", "mixed"])
 def test_speculative_ids_are_the_greedy_ids_request_for_request(
         proposer, greedy, monkeypatch):
@@ -560,38 +543,52 @@ def test_speculative_ids_are_the_greedy_ids_request_for_request(
     module's own drafts (seeded weights: nearly all rejected), a proposer
     forced right (every draft accepted: two ids a row a step), forced wrong
     (every one rejected and its page position rolled back) and both by
-    turns: the ids, texts and finish reasons are greedy decoding's, every
-    page comes back (`drive` holds the allocator to rest)."""
+    turns — each under the pipelined loop (a step composed and launched
+    while the one before it is unsettled: positions from the length carry,
+    pages for the longer case) and under the same loop settled in its tick:
+    the ids, texts and finish reasons are greedy decoding's, every page
+    comes back (`drive` holds the allocator to rest)."""
     if proposer != "module":
         ids = {name: out[0] for name, out in greedy.items()}
-        _force(monkeypatch, ids, {"right": lambda m: True,
-                                  "wrong": lambda m: False,
-                                  "mixed": lambda m: m % 3 != 0}[proposer])
+        force_proposer(monkeypatch, _arrivals(), ids, PROPOSERS[proposer])
     eng = _spec_engine()
-    got, samples = drive(eng, _arrivals(), False, monkeypatch)
-    assert got == greedy
     rt = _rt(eng)
-    assert rt.mtp and rt.proposer == "mtp" and not rt.may_overlap()
-    assert {s["mode"] for s in samples} <= {"ragged", "spec_verify"}
-    drafts = sum(s["mtp_drafts"] for s in samples)
-    accepted = sum(s["mtp_accepted"] for s in samples)
-    assert drafts == rt.spec_proposed > 20 and accepted == rt.spec_accepted
-    assert rt.spec_rollbacks == drafts - accepted
-    if proposer == "right":
-        assert accepted == drafts
-    elif proposer == "wrong":
-        assert accepted == 0
-    elif proposer == "mixed":
-        assert 0 < accepted < drafts
-    # With ONE draft a row the rejected position is the next token's own:
-    # the claim is rolled back to the pages that token needs anyway, so a
-    # rollback frees a page only where k > 1 (the n-gram test below).
-    assert sum(s["spec_rollback_pages"] for s in samples) == 0
-    for s in samples:  # the module ran over every token of every step
-        assert s["mtp_rows"] == s["tokens"] and s["k_cap"] == 1
-        assert s["mla_rows"] == s["tokens"]
-        assert s["mla_pairs"] >= s["mla_ctx_rows"] >= s["mla_rows"]
-        assert "dsa_ctx_tokens" not in s
+    assert rt.mtp and rt.proposer == "mtp" and rt.may_overlap()
+    for settle_every_step in (False, True):
+        was = rt.spec_proposed, rt.spec_accepted, rt.spec_rollbacks
+        got, samples = drive(eng, _arrivals(), settle_every_step,
+                             monkeypatch)
+        assert got == greedy
+        assert {s["mode"] for s in samples} <= {"ragged", "spec_verify"}
+        drafts = sum(s["mtp_drafts"] for s in samples)
+        accepted = sum(s["mtp_accepted"] for s in samples)
+        assert drafts == rt.spec_proposed - was[0] > 20
+        assert accepted == rt.spec_accepted - was[1]
+        assert rt.spec_rollbacks - was[2] == drafts - accepted
+        if proposer == "right":
+            assert accepted == drafts
+        elif proposer == "wrong":
+            assert accepted == 0
+        elif proposer == "mixed":
+            assert 0 < accepted < drafts
+        # With ONE draft a row the rejected position is the next token's
+        # own (or, behind an unsettled span, inside the next row's claim):
+        # a rollback frees a page only where k > 1 (the n-gram test below).
+        assert sum(s["spec_rollback_pages"] for s in samples) == 0
+        overlapped = sum(s["overlapped"] for s in samples)
+        if settle_every_step:
+            assert not overlapped
+        else:
+            assert overlapped >= len(samples) // 2, samples
+        # every decode and verify row took its positions from the carry
+        assert sum(s["len_carry_rows"] for s in samples) \
+            == sum(s["n_decode"] + s.get("mtp_drafts", 0) for s in samples) \
+            > 20
+        for s in samples:  # the module ran over every token of every step
+            assert s["mtp_rows"] == s["tokens"] and s["k_cap"] == 1
+            assert s["mla_rows"] == s["tokens"]
+            assert s["mla_pairs"] >= s["mla_ctx_rows"] >= s["mla_rows"]
+            assert "dsa_ctx_tokens" not in s
 
 
 def test_the_journal_and_the_counters_carry_the_proposers_kind(monkeypatch):
@@ -730,7 +727,7 @@ def test_the_modules_scopes_are_in_the_lowered_step_and_in_the_readme(
         lay = rt._ragged_layout(16)
         args = (rt.params, jnp.zeros((lay.size,), jnp.int32), rt.kc, rt.vc,
                 rt.recent, rt.last_ids, rt.slot_state)
-        args += (rt.draft_ids,) if rt.mtp else ()
+        args += (rt.draft_ids, rt.len_ids) if rt.mtp else ()
         return fn.lower(*jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
         ).as_text(debug_info=True)
