@@ -587,6 +587,12 @@ class ModelRuntime:
 
             params = shard_params(params, mesh)
             kv_sharding = NamedSharding(mesh, kv_cache_spec())
+        # The stacks whose contraction reads another order than row-major
+        # live on the device in that order (models/llama.py:
+        # weight_formats). Every jit site is handed `self.params`, and a
+        # jit with no `in_shardings` compiles for the layout of the
+        # committed array it is given: no step program re-lays a stack.
+        weights.place_formats(model_cfg, params)
         self.params = params
         self.kc, self.vc = kvc.alloc_kv_pool(
             model_cfg, engine_cfg, kv_sharding, dtype,
@@ -861,6 +867,15 @@ class ModelRuntime:
         # this runtime — the quantization PR's before/after lever.
         tm.HBM_WEIGHT_BYTES.labels(model=name).set(self.param_bytes)
         tm.HBM_KV_BYTES.labels(model=name).set(self.kv_bytes)
+        self.weight_stacks_relaid, relaid_bytes = weights.relaid(
+            model_cfg, params)
+        tm.WEIGHT_STACKS_RELAID.labels(model=name).set(
+            self.weight_stacks_relaid)
+        tm.WEIGHT_STACKS_RELAID_BYTES.labels(model=name).set(relaid_bytes)
+        if self.weight_stacks_relaid:
+            log.info("%s: %d weight stacks (%.1f MB) held in the device "
+                     "layout their contractions read", name,
+                     self.weight_stacks_relaid, relaid_bytes / 1e6)
         # What a deployment is sized by: the fixed per-slot state, and
         # what each token of context adds to the pool.
         conv, rule = llama.split_state(self.slot_state)
@@ -3423,6 +3438,7 @@ class ModelRuntime:
             "kv_dtype": self.kv_dtype,
             "attn_impl": self.attn_impl,
             "attn_inner": self.attn_inner,
+            "weight_stacks_relaid": self.weight_stacks_relaid,
             "devices": self.devices,
             # None = caching disabled (the TUI renders "cache n/a").
             "prefix_cache": (self.prefix_cache.stats()

@@ -481,6 +481,42 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
         return qeinsum("bte,ed->btd", o.reshape(B, T, H * dv), lp["wo"])
 
 
+# The stacks `_latent_attention_op` contracts a head at a time ("btr,re->bte"
+# reshaped to heads, "bthn,chn->bthc", "bthc,chv->bthv": `h` a batch
+# dimension, the rank r / c contracted). The chip's compiler reads such an
+# operand head-major with the contracted rank MINOR; held in the default
+# row-major order, every step program re-laid the whole stack a pass (654 MB
+# at the published widths, 2.0 ms of a 15 ms pass: PERF.md section 6, PR 45).
+# So those stacks live on the device layer-major, rank minor.
+CONTRACTED_MINOR = {"mla_wuq": (0, 2, 1), "mla_wukv": (0, 2, 1)}
+
+
+def weight_formats(cfg: ModelConfig, params: dict) -> dict:
+    """{name: jax.experimental.layout.Format} of the entries of
+    `params["layers"]` that are held on the device in another order than the
+    default: the device LAYOUT of a leaf, never its name, logical shape or
+    values. `params` holds arrays or `jax.ShapeDtypeStruct`s (a struct
+    without a sharding lands on the default device). Only a plain array on
+    ONE device is re-laid: under a mesh a leaf keeps the layout its sharding
+    rule was measured with."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    out = {}
+    if not cfg.kv_lora_rank:
+        return out
+    for name, order in CONTRACTED_MINOR.items():
+        leaf = params["layers"].get(name)
+        if not isinstance(leaf, (jax.Array, jax.ShapeDtypeStruct)):
+            continue  # absent, or a QuantTensor
+        sharding = leaf.sharding
+        if sharding is None:
+            sharding = SingleDeviceSharding(jax.local_devices()[0])
+        if len(sharding.device_set) == 1:
+            out[name] = Format(Layout(major_to_minor=order), sharding)
+    return out
+
+
 def _index_inputs(cfg: ModelConfig, lp: dict, h, c_q, positions):
     """The lightning indexer's (q_idx, k_idx, w_idx): q from the normed q
     latent, k from the hiddens through a LayerNorm, RoPE on the first rope

@@ -120,13 +120,20 @@ def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
     stack at all."""
     init = functools.partial(llama.init_params, cfg, dtype=dtype)
     key = jax.random.PRNGKey(seed)
+    if mesh is None and not cfg.num_experts:
+        return init(key)
+    shapes = jax.eval_shape(init, key)
     if mesh is None:
-        return jax.jit(init)(key) if cfg.num_experts else init(key)
+        # The stacks held in another device layout than the default
+        # (llama.weight_formats) are BORN in it: the draw's own results,
+        # so no second copy of such a stack ever exists.
+        formats = jax.tree_util.tree_map(lambda _: None, shapes)
+        formats["layers"].update(llama.weight_formats(cfg, shapes))
+        return jax.jit(init, out_shardings=formats)(key)
     from jax.sharding import NamedSharding
 
     from ollamamq_tpu.parallel.sharding import param_partition_specs
 
-    shapes = jax.eval_shape(init, key)
     specs = param_partition_specs(shapes)
     shardings = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), specs)
@@ -277,6 +284,36 @@ def load_params(
     if weights_dtype == "int8":
         params = quantize_params_int8(params, cfg)
     return params
+
+
+def _held_in(leaf, fmt) -> bool:
+    """Is the placed `leaf` in the dimension order `fmt` names? (The
+    device's own layout also carries its tiling, which `fmt` leaves to the
+    compiler: only the order is compared.)"""
+    return tuple(leaf.format.layout.major_to_minor) == tuple(
+        fmt.layout.major_to_minor)
+
+
+def place_formats(cfg: ModelConfig, params: dict) -> None:
+    """Re-lay, IN PLACE and one stack at a time, the leaves of a tree on one
+    device that `llama.weight_formats` wants in another device layout than
+    they are in (a checkpoint's; `init_random`'s are born so): the tree's
+    entry is replaced as each copy is made, so the stack in its old order is
+    freed before the next is re-laid and the placement adds one stack, not
+    all of them, to what the device holds."""
+    for name, fmt in llama.weight_formats(cfg, params).items():
+        if not _held_in(params["layers"][name], fmt):
+            params["layers"][name] = jax.device_put(
+                params["layers"][name], fmt)
+
+
+def relaid(cfg: ModelConfig, params: dict) -> tuple:
+    """(leaves, bytes) of a placed tree held in the device layout
+    `llama.weight_formats` names for them, not the default."""
+    held = [params["layers"][name]
+            for name, fmt in llama.weight_formats(cfg, params).items()
+            if _held_in(params["layers"][name], fmt)]
+    return len(held), sum(x.nbytes for x in held)
 
 
 def replicate_kv_heads(params: dict, cfg, r: int) -> dict:

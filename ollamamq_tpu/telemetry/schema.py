@@ -320,6 +320,17 @@ HBM_INDEX_POOL_BYTES = REGISTRY.gauge(
     "Bytes the index-key pool of a model with latent attention occupies "
     "(attention layers x slots x index_head_dim; counted in "
     "ollamamq_hbm_kv_bytes too)", labels=("model",))
+WEIGHT_STACKS_RELAID = REGISTRY.gauge(
+    "ollamamq_weight_stacks_relaid",
+    "Weight leaves a model runtime holds in another device layout than the "
+    "default row-major order, because the step programs' contractions read "
+    "that order (2 for a model with latent attention on one device: "
+    "mla_wuq, mla_wukv, rank minor; 0 otherwise). Names, logical shapes and "
+    "values are unchanged", labels=("model",))
+WEIGHT_STACKS_RELAID_BYTES = REGISTRY.gauge(
+    "ollamamq_weight_stacks_relaid_bytes",
+    "Bytes of those leaves: what every step program re-laid a pass while "
+    "they were held row-major", labels=("model",))
 MLA_ROWS_TOTAL = REGISTRY.counter(
     "ollamamq_mla_rows_total",
     "Query tokens of launched steps that went through latent attention "

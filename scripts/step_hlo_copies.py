@@ -1,0 +1,274 @@
+#!/usr/bin/env python3
+"""What a configuration's step programs MOVE that no user asked for: every
+`copy` and every materialised `dynamic-slice` of at least `--min-mb` in the
+programs the engine jits for it, compiled for a DESCRIBED TPU v5e — no chip:
+
+    python scripts/step_hlo_copies.py benchmarks/configs/<name>.json \
+        [--min-mb 32] [--tokens N] [--rehearse] [--default-layouts] \
+        [--dump DIR]
+
+A weight that the compiler reads in another order than it is stored in is
+re-laid once a launch of the program: `copy.126 bf16[6,1536,24576]`, the whole
+`mla_wuq` stack, was 1.40 ms of openPangu's 15 ms pass (PERF.md section 6,
+PR 45) and shows here as a `copy` whose result has the operand's shape and
+another layout. A layer's slice of a stack that a kernel or a contraction
+cannot read in place shows as a `...dynamic-slice_fusion` of the layer's shape.
+
+The configuration file's keys make the `ModelConfig` and its `server_flags`
+the pool and the step shapes, as `benchmarks/serve.py` hands them to the CLI;
+the programs are the engine's own jit sites (`ModelRuntime._get_ragged_jit` at
+`--max-batch-tokens`, with the prediction module's carries under `--spec`;
+`_get_decode_jit` at `--decode-steps` otherwise), lowered with the weights'
+shapes in the formats `models/llama.py:weight_formats` names
+(`--default-layouts`: in row-major order, what the tree was before PR 45) and
+`--tp` over a mesh of the described chips. Nothing runs: a compile that
+passes is not a chip run, and an op listed here has no time until a trace
+gives it one. One JSON line a program, then one last line with the count.
+`--rehearse` compiles the file's tiny `rehearse` sizes (seconds; sizes no
+copy of 32 MB exists at: give `--min-mb 0`).
+
+Not on the serving path: nothing imports this module.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import re
+import sys
+from types import SimpleNamespace
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
+            "bf16": 2, "f16": 2, "s16": 2, "u16": 2, "f32": 4, "s32": 4,
+            "u32": 4, "f64": 8, "s64": 8, "u64": 8}
+# `%name = dtype[dims]{layout} opcode(operands...)`, with or without ROOT.
+_INSTR = re.compile(
+    r"^\s*(?P<root>ROOT )?%(?P<name>[\w.\-]+) = (?P<dtype>\w+)"
+    r"\[(?P<dims>[\d,]*)\](?P<layout>\{[^}]*\})? (?P<op>[\w\-]+)"
+    r"\((?P<args>[^)]*)\)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>[\w.\-]+) \(")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+MOVES = ("copy", "dynamic-slice")
+
+
+def moves(hlo: str, min_bytes: int) -> list:
+    """The device ops of a compiled module's text that only MOVE at least
+    `min_bytes`: a `copy`, a `dynamic-slice`, or a fusion whose root is one
+    (XLA names it `...dynamic-slice_fusion`, `copy_fusion`). Only an
+    instruction of the entry, a loop's body or a branch is an op of its own
+    on the device: one inside a fused computation is part of that fusion's
+    read (a contraction that re-lays its operand as it reads it) and is not
+    listed. Each with its name, which of the two it `moves`, shape, the
+    result's layout, the first operand's name (a parameter's says which
+    weight), shape and layout, and the `op_name` the source gave it."""
+    computations, inside, fused = {}, None, set()
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside = computations.setdefault(head["name"], [])
+            continue
+        if " fusion(" in line:  # also one with a tuple for its result
+            fused.update(_CALLS.findall(line))
+        m = _INSTR.match(line)
+        if m is not None and inside is not None:
+            inside.append((m, line))
+
+    def root_op(computation: str) -> str:
+        """What a fused computation's root does, through bitcasts."""
+        by_name = {m["name"]: m for m, _ in computations.get(computation, ())}
+        m = next((m for m, _ in computations.get(computation, ())
+                  if m["root"]), None)
+        while m is not None and m["op"] == "bitcast":
+            first = re.search(r"%([\w.\-]+)", m["args"])
+            m = by_name.get(first.group(1)) if first else None
+        return m["op"] if m is not None else ""
+
+    found = []
+    for name, instrs in computations.items():
+        if name in fused:
+            continue
+        defined = {m["name"]: (f"{m['dtype']}[{m['dims']}]",
+                               m["layout"] or "") for m, _ in instrs}
+        for m, line in instrs:
+            dims = [int(d) for d in m["dims"].split(",") if d]
+            n = math.prod(dims) * ITEMSIZE.get(m["dtype"], 0)
+            if n < max(min_bytes, 1):
+                continue
+            kind = m["op"] if m["op"] != "fusion" else next(
+                (op for op in map(root_op, _CALLS.findall(line))
+                 if op in MOVES), "")
+            if kind not in MOVES:
+                continue
+            first = re.search(r"%([\w.\-]+)", m["args"])
+            src = defined.get(first.group(1) if first else "", ("?", ""))
+            op_name = _OP_NAME.search(line)
+            found.append({
+                "name": m["name"], "op": m["op"], "moves": kind,
+                "shape": f"{m['dtype']}[{m['dims']}]", "dims": dims,
+                "layout": m["layout"] or "", "bytes": n,
+                "from": first.group(1) if first else "",
+                "from_shape": src[0], "from_layout": src[1],
+                "op_name": op_name.group(1) if op_name else ""})
+    return found
+
+
+def weight_copies(found: list, params) -> list:
+    """Those of `found` that re-lay a weight: a `copy` whose result has the
+    logical shape of a stack of `params["layers"]` (the loop's re-layout
+    hoisted out of it) or of one layer of a stack (left inside), each with
+    the `stacks` of that shape."""
+    named = {}
+    for name, leaf in params["layers"].items():
+        shape = tuple(getattr(leaf, "shape", ()))
+        if len(shape) >= 3:
+            named.setdefault(shape, []).append(name)
+            named.setdefault((1, *shape[1:]), []).append(name)
+    return [{"name": f["name"], "stacks": named[tuple(f["dims"])]}
+            for f in found
+            if f["moves"] == "copy" and tuple(f["dims"]) in named]
+
+
+def describe_v5e():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+def step_programs(mc, flags, topo, tokens: int,
+                  default_layouts: bool = False):
+    """{jit name: jax.stages.Lowered} of the step programs a server of
+    `mc` under the CLI flags `flags` (an argparse namespace of
+    `cli.build_parser`) launches in its steady state, for the described
+    chips of `topo`, the ragged step at a stream of `tokens`; and the
+    abstract params they were lowered with."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, SingleDeviceSharding
+
+    from ollamamq_tpu.engine import engine as eng
+    from ollamamq_tpu.models import llama
+    from ollamamq_tpu.parallel.mesh import make_mesh
+    from ollamamq_tpu.parallel.sharding import (kv_cache_spec,
+                                                param_partition_specs)
+
+    S, ps = flags.max_slots, flags.page_size
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(mc, jax.random.PRNGKey(0)))
+    mesh = None
+    if flags.tp > 1:
+        from jax.sharding import PartitionSpec
+
+        mesh = make_mesh(tp=flags.tp, devices=topo.devices[:flags.tp])
+        rep = NamedSharding(mesh, PartitionSpec())
+        pool_sharding = NamedSharding(mesh, kv_cache_spec())
+        param_shardings = jax.tree_util.tree_map(
+            lambda spec: NamedSharding(mesh, spec),
+            param_partition_specs(shapes))
+    else:
+        rep = pool_sharding = SingleDeviceSharding(topo.devices[0])
+        param_shardings = jax.tree_util.tree_map(lambda _: rep, shapes)
+
+    def s(shape, dt=jnp.int32, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda a, sh: s(a.shape, a.dtype, sh), shapes, param_shardings)
+    if not default_layouts:
+        for name, fmt in llama.weight_formats(mc, params).items():
+            leaf = params["layers"][name]
+            params["layers"][name] = s(leaf.shape, leaf.dtype, fmt)
+
+    rt = object.__new__(eng.ModelRuntime)
+    rt.cfg, rt.attn_impl, rt.mesh = mc, "pallas", mesh
+    rt.ecfg = SimpleNamespace(
+        page_size=ps, max_slots=S, max_pages_per_seq=flags.max_pages_per_seq,
+        repeat_last_n=eng.EngineConfig.repeat_last_n)
+    rt._prefill_jits, rt._decode_jits = {}, {}
+    rt.mtp = bool(flags.spec and mc.num_nextn_predict_layers)
+    pools = tuple(
+        s((mc.cache_layers, flags.num_pages * ps, lanes), jnp.bfloat16,
+          pool_sharding) for lanes in mc.kv_row_dims)
+    state = jax.tree_util.tree_map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.alloc_slot_state(mc, S)))
+    carried = (*pools, s((S + 1, rt.ecfg.repeat_last_n)), s((S,)), state)
+    every = (True, True, True)  # penalties, masks, sampling: the superset
+    # The jit itself, not the first-call wrapper that times its compile.
+    plain = eng._sp_note_compile
+    eng._sp_note_compile = \
+        lambda rt, site, key, cache, fn: cache.setdefault(key, fn)
+    try:
+        drafts = (s((S + 1,)),) * 2 if rt.mtp else ()
+        out = {"mq_ragged_step": rt._get_ragged_jit(
+            tokens, 1 if rt.mtp else 0, every).lower(
+                params, s((rt._ragged_layout(tokens).size,)), *carried,
+                *drafts)}
+        if not flags.spec:
+            out["mq_decode_scan"] = rt._get_decode_jit(
+                flags.decode_steps, every).lower(
+                    params, s((rt._decode_layout().size,)), *carried)
+    finally:
+        eng._sp_note_compile = plain
+    return out, params
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("config", help="a benchmarks/configs/*.json file")
+    ap.add_argument("--min-mb", type=float, default=32.0)
+    ap.add_argument("--tokens", type=int,
+                    help="the ragged step's stream (default: the file's "
+                    "--max-batch-tokens; a pass of decode rows is --max-slots"
+                    " tokens, of verify spans twice that)")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="the file's tiny `rehearse` sizes")
+    ap.add_argument("--default-layouts", action="store_true",
+                    help="every weight row-major (before PR 45)")
+    ap.add_argument("--dump", help="write each program's compiled HLO here")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from benchmarks import serve
+    from ollamamq_tpu import cli
+
+    with open(args.config) as f:
+        cfg = json.load(f)
+    mc = serve.model_config(cfg, args.rehearse)
+    flags = cli.build_parser().parse_args(
+        ["--models", cfg["name"]] + serve.server_flags(cfg, args.rehearse))
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = describe_v5e()
+    lowered, params = step_programs(
+        mc, flags, topo, args.tokens or flags.max_batch_tokens,
+        args.default_layouts)
+    n_weight = 0
+    for name, low in lowered.items():
+        hlo = low.compile().as_text()
+        if args.dump:
+            os.makedirs(args.dump, exist_ok=True)
+            with open(os.path.join(
+                    args.dump, f"{cfg['name']}.{name}.hlo.txt"), "w") as f:
+                f.write(hlo)
+        found = moves(hlo, int(args.min_mb * 2 ** 20))
+        weights = weight_copies(found, params)
+        n_weight += len(weights)
+        print(json.dumps({"config": cfg["name"], "program": name,
+                          "moves": found,
+                          "weight_copies": weights}),
+              flush=True)
+    print(json.dumps({"config": cfg["name"], "programs": len(lowered),
+                      "weight_copies": n_weight}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

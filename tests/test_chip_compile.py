@@ -563,3 +563,54 @@ def test_ragged_step_is_fed_one_host_array(v5e, monkeypatch, cfg):
     n_state = {LOOP_CFG: 0, LFM2_CFG: 1, OLMO_HYBRID_CFG: 2}[cfg]
     assert len(rest) == 5 + n_state and len(fed) == 1, rest
     assert (fed[0].shape, fed[0].dtype) == ((words,), jnp.int32), fed
+
+
+@pytest.mark.parametrize("held", [False, True],
+                         ids=["row_major", "as_served"])
+def test_no_step_program_re_lays_a_latent_stack(v5e, capsys, held):
+    """`scripts/step_hlo_copies.py` on openPangu's configuration file at its
+    `rehearse` sizes (PR 45): with every weight row-major the chip's compiler
+    puts a `copy` of a layer of `mla_wuq` and of `mla_wukv` into the `--spec`
+    step (the re-layout that was 2.0 ms of a 15 ms pass at the published
+    widths); with the two stacks in the formats `llama.weight_formats` names
+    — as a runtime holds them — it puts none. (At these sizes the toy expert
+    stacks' 64 lanes get a copy of their own into the grouped matmul: the
+    check is of the stacks the rule names.) Within its own time limit: two
+    compiles of some ten seconds."""
+    import contextlib
+    import json
+    import signal
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "scripts"))
+    import step_hlo_copies
+
+    @contextlib.contextmanager
+    def time_limit(seconds):
+        def stop(signum, frame):
+            raise TimeoutError(f"no result within {seconds} s")
+        was = signal.signal(signal.SIGALRM, stop)
+        signal.alarm(seconds)
+        try:
+            yield
+        finally:
+            signal.alarm(0)
+            signal.signal(signal.SIGALRM, was)
+
+    config = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                          "configs", "openpangu-ultra-moe-ep16-d5.json")
+    argv = [config, "--rehearse", "--min-mb", "0"]
+    with time_limit(240):
+        assert step_hlo_copies.main(
+            argv + (["--default-layouts"] if not held else [])) == 0
+    lines = [json.loads(line) for line in
+             capsys.readouterr().out.splitlines() if line.startswith("{")]
+    programs, last = lines[:-1], lines[-1]
+    assert [p["program"] for p in programs] == ["mq_ragged_step"]
+    assert last["programs"] == 1
+    re_laid = {name for p in programs for c in p["weight_copies"]
+               for name in c["stacks"]}
+    latent = set(llama.CONTRACTED_MINOR)
+    assert (re_laid & latent == set()) if held else (latent <= re_laid), \
+        programs[0]["weight_copies"]
