@@ -11,7 +11,6 @@ set -- --no-tui --host 0.0.0.0
 [ -n "${TIMEOUT:-}" ] && set -- "$@" --timeout "$TIMEOUT"
 [ -n "${TP:-}" ] && set -- "$@" --tp "$TP"
 [ -n "${DP:-}" ] && set -- "$@" --dp "$DP"
-[ -n "${SP:-}" ] && set -- "$@" --sp "$SP"
 [ -n "${EP:-}" ] && set -- "$@" --ep "$EP"
 [ -n "${PAGE_SIZE:-}" ] && set -- "$@" --page-size "$PAGE_SIZE"
 [ -n "${NUM_PAGES:-}" ] && set -- "$@" --num-pages "$NUM_PAGES"

@@ -63,8 +63,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="KV page dtype: 'int8' shrinks every page ~2x "
                         "(per-page-row fp32 scales stored alongside the "
                         "pool), so ~2x concurrent requests fit the same "
-                        "HBM; invalid combinations (MoE weights, "
-                        "--sp KV) fail at startup")
+                        "HBM; invalid combinations (MoE weights) fail "
+                        "at startup")
     p.add_argument("--max-batch-tokens", type=int, default=512,
                    help="token budget of one ragged dispatch (decode rows "
                         "+ prefill-span tokens); clamped up so a full "
@@ -117,7 +117,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "prefix is reused (smaller hits prefill normally)")
     # Mesh.
     p.add_argument("--dp", type=int, default=1, help="data-parallel axis size")
-    p.add_argument("--sp", type=int, default=1, help="sequence-parallel axis size")
     p.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel axis size (-1 = all devices)")
     p.add_argument("--ep", type=int, default=1,
@@ -222,9 +221,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "cost: the always-on self-profiler "
                         "(ollamamq_router_overhead_ms{site}) feeds a "
                         "windowed p99; above this budget the health "
-                        "monitor fires the router_overhead alert and "
-                        "the bench fleet-chaos gate fails. 0 disables "
-                        "the alert (the timers stay on)")
+                        "monitor fires the router_overhead alert. 0 "
+                        "disables the alert (the timers stay on)")
     # Router HA (fleet/ha.py): warm-standby router with epoch fencing.
     p.add_argument("--ha", action="store_true",
                    default=os.environ.get("HA", "").lower()
@@ -444,7 +442,7 @@ def _fake_latency() -> float:
 def _member_mesh(cfg, index: int):
     """Fleet member `index`'s own slice of the local devices: without it
     every in-process replica's weights and KV pool would land on device
-    0. A member needs dp*sp*ep*tp devices; slices follow one another
+    0. A member needs dp*ep*tp devices; slices follow one another
     and wrap around when the fleet outgrows the devices (said in the
     log — members then share a device)."""
     import jax
@@ -454,7 +452,7 @@ def _member_mesh(cfg, index: int):
     if cfg.tp == -1:
         return None  # "all devices": the engine builds that mesh itself
     devs = jax.devices()
-    k = cfg.dp * cfg.sp * cfg.ep * cfg.tp
+    k = cfg.dp * cfg.ep * cfg.tp
     if k > len(devs):
         raise ValueError(f"a fleet member needs {k} devices, "
                          f"{len(devs)} available")
@@ -464,8 +462,7 @@ def _member_mesh(cfg, index: int):
             "fleet member %d shares device(s) %s with an earlier member "
             "(%d devices for the fleet)", index,
             [str(d) for d in picked], len(devs))
-    return make_mesh(dp=cfg.dp, sp=cfg.sp, tp=cfg.tp, ep=cfg.ep,
-                     devices=picked)
+    return make_mesh(dp=cfg.dp, tp=cfg.tp, ep=cfg.ep, devices=picked)
 
 
 def install_graceful_shutdown(engine, grace_s: float) -> None:
@@ -667,7 +664,7 @@ def main(argv=None) -> int:
     from ollamamq_tpu.config import validate_quant_config
 
     quant_err = validate_quant_config(
-        args.weights_dtype, args.kv_dtype, sp=args.sp,
+        args.weights_dtype, args.kv_dtype,
         model_names=[m.strip() for m in args.models.split(",") if m.strip()])
     if quant_err is not None:
         log.error("%s", quant_err)
@@ -679,8 +676,7 @@ def main(argv=None) -> int:
         latent_err = served and validate_latent_pool(
             served, kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype,
             prefix_cache=args.prefix_cache,
-            mesh_shape={"seq": args.sp, "tensor": args.tp,
-                        "expert": args.ep})
+            mesh_shape={"tensor": args.tp, "expert": args.ep})
         if latent_err:
             log.error("%s", latent_err)
             return 2
@@ -768,7 +764,6 @@ def main(argv=None) -> int:
         prefix_cache=args.prefix_cache,
         prefix_cache_min_pages=args.prefix_cache_min_pages,
         dp=args.dp,
-        sp=args.sp,
         tp=args.tp,
         ep=args.ep,
         trace_ring=args.trace_ring,
@@ -932,9 +927,9 @@ def main(argv=None) -> int:
         # SPMD with an unspecified mesh means "the whole pod": default the
         # tensor axis to all global devices so worker hosts own shards.
         tp = args.tp
-        if (args.dp, args.sp, args.ep, tp) == (1, 1, 1, 1):
+        if (args.dp, args.ep, tp) == (1, 1, 1):
             tp = -1
-        mesh = make_mesh(dp=args.dp, sp=args.sp, tp=tp, ep=args.ep)
+        mesh = make_mesh(dp=args.dp, tp=tp, ep=args.ep)
         if not distributed.is_primary():
             # Worker host: replay the primary's step plans until shutdown.
             from ollamamq_tpu.engine import spmd

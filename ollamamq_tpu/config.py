@@ -821,12 +821,6 @@ class EngineConfig:
     page_size: int = 32
     # Max pages a single sequence may hold (=> max context length).
     max_pages_per_seq: int = 16
-    # Only the LARGEST entry is read, and only on a sequence-parallel
-    # mesh (--sp > 1): a prompt longer than it takes the one-shot ring-
-    # attention prefill, padded to a multiple of it (one compile per
-    # padded length). Every other prompt rides the ragged step, which
-    # pads to no bucket.
-    prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024, 2048)
     # -- ragged mixed-batch attention ----------------------------------------
     # ONE token-budget dispatch packs any mix of variable-length prefill
     # spans and decode tokens into a flattened stream (Pallas ragged
@@ -870,10 +864,9 @@ class EngineConfig:
     # tiny hits aren't worth routing through the chunked prefill.
     prefix_cache_min_pages: int = 1
     # Mesh axis sizes; tp=-1 means "all remaining devices". The engine
-    # builds its (data, seq, expert, tensor) mesh from these unless
+    # builds its (data, expert, tensor) mesh from these unless
     # an explicit mesh object is passed to TPUEngine.
     dp: int = 1
-    sp: int = 1
     tp: int = 1
     ep: int = 1
     dtype: str = "bfloat16"
@@ -884,7 +877,7 @@ class EngineConfig:
     # weight-streaming-bound dispatch pays. kv_dtype="int8": int8 KV
     # pages with per-page-row fp32 scales stored alongside the pool —
     # every page shrinks ~2x, so ~2x concurrent requests fit the same
-    # HBM. Invalid combinations (MoE weights, sp KV) fail fast at
+    # HBM. Invalid combinations (MoE weights) fail fast at
     # startup via validate_quant_config.
     weights_dtype: str = "bfloat16"
     kv_dtype: str = "bfloat16"
@@ -948,8 +941,8 @@ class EngineConfig:
     # Router-overhead bound: the always-on self-profiler times every
     # placement decision (ollamamq_router_overhead_ms{site="place"});
     # a windowed p99 above this budget fires the health monitor's
-    # router_overhead alert and fails the bench fleet-chaos gate —
-    # "router overhead measured and bounded". 0 disables the alert
+    # router_overhead alert — "router overhead measured and
+    # bounded". 0 disables the alert
     # (the timers stay on: measurement is not optional).
     router_overhead_budget_ms: float = 50.0
     # Metrics federation: re-export every HTTP member's series from the
@@ -1257,9 +1250,6 @@ def validate_slot_state(cfg: ModelConfig, spec: bool = False,
     if spec:
         why = ("--spec: a rejected draft has already advanced the per-slot "
                "conv / recurrent state, and rollback restores pages only")
-    elif shape.get("seq", 1) > 1:
-        why = ("--sp: a convolution over a sequence sharded along T needs "
-               "a halo exchange the ring prefill does not make")
     elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
         why = (f"--tp / --ep: the {' and '.join(held)} layers' weights and "
                "state have no partition specs")
@@ -1294,8 +1284,6 @@ def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",
     elif prefix_cache:
         why = ("--prefix-cache: the radix tree shares K and V pages, not "
                "latent and index-key pages")
-    elif shape.get("seq", 1) > 1:
-        why = "--sp: the ring prefill scatters K and V of every head"
     elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
         why = ("--tp / --ep: the latent and index-key pools and the "
                "low-rank projections have no partition specs")
@@ -1306,7 +1294,7 @@ def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",
 
 
 def validate_quant_config(weights_dtype: str, kv_dtype: str,
-                          sp: int = 1, model_names=()) -> Optional[str]:
+                          model_names=()) -> Optional[str]:
     """Fail-fast validation of the quantization flags BEFORE any device
     work: returns an error string (None = valid). One definition shared
     by the CLI, the SPMD worker entry, and ModelRuntime so a typo'd or
@@ -1316,10 +1304,6 @@ def validate_quant_config(weights_dtype: str, kv_dtype: str,
                 f"got {weights_dtype!r}")
     if kv_dtype not in QUANT_DTYPES:
         return f"--kv-dtype must be one of {QUANT_DTYPES}, got {kv_dtype!r}"
-    if kv_dtype == "int8" and sp > 1:
-        return ("--kv-dtype=int8 is unsupported with sequence-parallel "
-                "prefill (its all-layer KV scatter bypasses the quantized "
-                "page writer)")
     if weights_dtype == "int8":
         for name in model_names:
             cfg = get_model_config(name)

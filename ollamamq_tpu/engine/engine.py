@@ -119,7 +119,7 @@ def select_attn_impl(backend: str, kv_dtype: str) -> Tuple[str, str]:
     reference everywhere else. OLLAMAMQ_NO_PALLAS=1 is the reference
     switch (A/B runs, chip_smoke's comparison leg). Int8 pools take the
     jnp path: Mosaic refuses the kernels' [page_size, Hk] f32 scale-row
-    DMA (slice not aligned to the 128-lane tiling; ROADMAP A5)."""
+    DMA (slice not aligned to the 128-lane tiling; ROADMAP A1)."""
     if os.environ.get("OLLAMAMQ_NO_PALLAS", "").lower() not in (
             "", "0", "false", "no"):
         return "jnp", "OLLAMAMQ_NO_PALLAS is set"
@@ -162,7 +162,7 @@ def per_chip_stats() -> List[dict]:
 
 def device_summary() -> dict:
     """What this process's jax runs on, as jax reports it — the fields
-    every status payload and bench record names, so a number can never
+    every status payload and benchmark line names, so a number can never
     be read without the device it came from."""
     devs = jax.devices()
     return {"platform": devs[0].platform,
@@ -476,7 +476,7 @@ class ModelRuntime:
     journal = None
 
     # Scheduling policy (engine/scheduler.py), attached by the owning
-    # engine's _attach_hooks (bench/tests attach directly). None behaves
+    # engine's _attach_hooks (tests attach directly). None behaves
     # exactly like fcfs: identity orderings, legacy victim key, no
     # output-length prediction.
     policy = None
@@ -497,7 +497,7 @@ class ModelRuntime:
     mtp = False
     # The owning engine thread's loop clock (stepprof.LoopClock), attached
     # by _attach_hooks: step timers advance it, so step and loop phases
-    # form one gapless chain. None (bench, unit tests) times steps alone.
+    # form one gapless chain. None (unit tests) times steps alone.
     loop_clock = None
 
     def __init__(
@@ -524,10 +524,9 @@ class ModelRuntime:
         # Int8 quantization (weights and/or KV pages): validated here
         # too — tests and embedders construct runtimes directly, and an
         # unsupported combination must fail at build, not first dispatch.
-        _sp_probe = dict(mesh.shape).get("seq", 1) if mesh is not None else 1
         err = validate_quant_config(
             engine_cfg.weights_dtype, engine_cfg.kv_dtype,
-            sp=_sp_probe, model_names=(name,))
+            model_names=(name,))
         if err is not None:
             raise ValueError(err)
         err = validate_slot_state(
@@ -710,15 +709,13 @@ class ModelRuntime:
         # find them; installation re-checks the cancelled flag).
         self.inflight_prefill: List[Request] = []
         # Keys carry the trace-time sampling flags: ("ragged", T_pad, k_cap,
-        # flags) | ("sp", T, flags); decode: (k_steps, flags).
+        # flags); decode: (k_steps, flags).
         self._prefill_jits: Dict[tuple, callable] = {}
         self._decode_jits: Dict[tuple, callable] = {}
         self._embed_jits: Dict[tuple, callable] = {}
         self._rng_counter = engine_cfg.seed
         # [transfers, bytes] `_upload` made for the step being launched.
         self._h2d = [0, 0]
-        # Sequence-parallel prefill available when the mesh has a seq axis.
-        self._sp = mesh is not None and mesh.shape.get("seq", 1) > 1
         # Set after an unrecoverable step failure; the engine stops stepping
         # this runtime and rebuilds it (weights reloaded) when the device
         # answers again.
@@ -1049,9 +1046,6 @@ class ModelRuntime:
         return step_pack.decode_layout(self.ecfg.max_slots,
                                        self.ecfg.max_pages_per_seq)
 
-    def _sp_layout(self, T: int) -> step_pack.StepLayout:
-        return step_pack.sp_layout(T, self.ecfg.max_pages_per_seq)
-
     def _get_ragged_jit(self, T_pad: int, k_cap: int = 0,
                         flags=(True, True, True)):
         """ONE mixed-batch step: forward the flattened [T_pad] token
@@ -1361,119 +1355,6 @@ class ModelRuntime:
             k_steps, sampling_flags(*self._decode_layout().sampling(buf)))
         return fn(self.params, self._upload(buf), self.kc, self.vc,
                   self.recent, self.last_ids, self.slot_state)
-
-    def _dispatch_prefill_sp(self, T, buf):
-        """`buf`: the prompt's packed host inputs (step_pack.sp_layout)."""
-        self._fault("sp_prefill")
-        lay = self._sp_layout(T)
-        fn = self._get_sp_prefill_jit(
-            T, sampling_flags(*lay.sampling(buf)))
-        return fn(self.params, self._upload(buf), self.kc, self.vc,
-                  self.recent)
-
-    def _get_sp_prefill_jit(self, T: int, flags=(True, True, True)):
-        """Sequence-parallel long-prompt prefill: the whole prompt in one
-        forward with activations sharded along T over the mesh "seq" axis
-        (ring attention rotates K/V blocks over ICI —
-        models/llama.py:forward_prefill_sp), then the returned K/V stacks
-        scatter into the slot's pages. One compile per padded length T."""
-        key_ = ("sp", T, flags)
-        _sp_compile_evict(self, self._prefill_jits, key_)
-        if key_ not in self._prefill_jits:
-            cfg, ps, mesh = self.cfg, self.ecfg.page_size, self.mesh
-            need_pen, need_mask, need_sample = flags
-
-            lay = self._sp_layout(T)
-
-            def mq_prefill_sp(params, buf, kc, vc, recent):
-                (tokens, seq_lens, slot_ids, pt, temp, tk, tp, pen, pres,
-                 freq, seeds, rng) = lay.unpack(buf)
-                key = jax.random.PRNGKey(rng[0])
-                logits, k_stack, v_stack = llama.forward_prefill_sp(
-                    params, cfg, tokens, seq_lens, mesh
-                )
-                # Scatter K/V (k_stack: [L, 1, T, Hk, hd]) into the paged
-                # pool ([L, S, Hk*hd]: the rows take the pool's row
-                # shape); positions past the real length land in the trash
-                # page (pt rows beyond the allocation already hold it).
-                t = jnp.arange(T)
-                page_idx = pt[0, t // ps]
-                page_idx = jnp.where(t < seq_lens[0], page_idx, kvc.TRASH_PAGE)
-                dest = page_idx * ps + (t % ps)
-                L = kc.shape[0]
-                kc = kc.at[:, dest].set(
-                    k_stack[:, 0].reshape(L, T, -1).astype(kc.dtype))
-                vc = vc.at[:, dest].set(
-                    v_stack[:, 0].reshape(L, T, -1).astype(vc.dtype))
-                # First-token sampling + recent ring, as in batched prefill.
-                W = recent.shape[1]
-                idx = seq_lens[:, None] - W + jnp.arange(W)[None, :]
-                gathered = jnp.take_along_axis(
-                    tokens, jnp.clip(idx, 0, T - 1), axis=1
-                )
-                rows = jnp.where(idx >= 0, gathered, -1)
-                pen_logits = maybe_apply_penalties(logits, rows, pen, pres,
-                                                   freq, need_pen)
-                row_keys = per_row_keys(key, seeds, seq_lens)
-                tok = sample_tokens_rowwise(pen_logits, row_keys, temp, tk,
-                                            tp, need_mask, need_sample)
-                rows = jnp.concatenate([rows[:, 1:], tok[:, None]], axis=1)
-                recent = recent.at[slot_ids].set(rows)
-                return tok, kc, vc, recent
-
-            _sp_note_compile(self, "sp_prefill", key_, self._prefill_jits,
-                             jax.jit(mq_prefill_sp, donate_argnums=(2, 3, 4)))
-        return self._prefill_jits[key_]
-
-    def _prefill_sp(self, req: Request, slot: int, n: int, core: MQCore) -> None:
-        """Run the sequence-parallel prefill for one long prompt and install
-        the slot. Caller has claimed the slot and allocated pages."""
-        s = req.sampling
-        sp = self.mesh.shape["seq"]
-        largest = self.ecfg.prefill_buckets[-1]
-        unit = -(-largest // sp) * sp  # bucket rounded up to sp-divisible
-        T = -(-n // unit) * unit  # padded length, divisible by sp
-        self.page_table[slot, :] = kvc.make_page_table_row(
-            self.slot_pages[slot], self.ecfg.max_pages_per_seq
-        )
-        lay = self._sp_layout(T)
-        buf = lay.new()
-        (tokens, lens, slot_ids, pt, temp, top_k, top_p, pen, pres, freq,
-         seeds, rng) = lay.views(buf)
-        tokens[0, :n] = req.prompt_tokens
-        lens[0], slot_ids[0], pt[0] = n, slot, self.page_table[slot]
-        temp[0], top_k[0], top_p[0] = s.temperature, s.top_k, s.top_p
-        pen[0], pres[0], freq[0] = (s.repeat_penalty, s.presence_penalty,
-                                    s.frequency_penalty)
-        seeds[0], rng[0] = s.seed, self._next_rng()
-        self.inflight_prefill = [req]  # cancel() must still find it
-        req.trace_event("prefill", mode="sp", tokens=n)
-        t0 = time.monotonic()
-        try:
-            tok, self.kc, self.vc, self.recent = self._dispatch_prefill_sp(
-                T, buf)
-        except Exception as e:
-            # Contain the failure to THIS request (the batched path does the
-            # same): release the never-installed slot's pages — _fail_runtime
-            # would miss them since slot_req[slot] is still None — retry it
-            # once, and keep every other in-flight request alive.
-            log.exception("sequence-parallel prefill failed for req %d",
-                          req.req_id, extra={"req_id": req.req_id})
-            self._release_slot_pages(slot)
-            desync = isinstance(e, WorkerDesyncError)
-            if desync or not self._retry_requeue(
-                    req, self.pending_prefill, f"sp prefill failed: {e}"):
-                core.mark_dropped(req.user)
-                req.finish(FinishReason.ERROR, error=self._poison_msg(
-                    req, f"sp prefill failed: {e}"))
-            if desync:
-                raise  # diverged SPMD state: the runtime must kill+reload
-            return
-        finally:
-            self.inflight_prefill = []
-        self.prefill_latency_ms = (time.monotonic() - t0) * 1e3
-        self._tm_prefill.observe(self.prefill_latency_ms)
-        self._install_slot(slot, req, n, int(np.asarray(tok)[0]), core)
 
     def _get_decode_jit(self, k_steps: int, flags=(True, True, True)):
         key_ = (k_steps, flags)
@@ -2361,7 +2242,6 @@ class ModelRuntime:
             # released queue (fcfs/None: untouched FIFO).
             self.policy.reorder_pending(self.pending_prefill)
         did = False
-        largest = self.ecfg.prefill_buckets[-1]
         while self.pending_prefill:
             req = self.pending_prefill[0]
             if req.cancelled.is_set():
@@ -2388,22 +2268,6 @@ class ModelRuntime:
                     error=f"prompt length {n} exceeds maximum {max_prompt}",
                 )
                 continue
-            if self._sp and n > largest:
-                # Long prompts on a sequence-parallel mesh keep the
-                # one-shot ring-attention prefill (its activations shard
-                # over the seq axis; the ragged stream does not).
-                slot = self._claim_slot()
-                if slot is None:
-                    return did
-                pages = self._alloc_pages(n + 1)
-                if pages is None:
-                    return did
-                self.pending_prefill.popleft()
-                self._pc_miss()
-                req.stats.prefill_started_at = time.monotonic()
-                self.slot_pages[slot] = pages
-                self._prefill_sp(req, slot, n, core)
-                return True
             nodes, shared = ([], [])
             if self.prefix_cache is not None:
                 nodes, shared = self._match_prefix(req.prompt_tokens)
@@ -2461,7 +2325,7 @@ class ModelRuntime:
     def step_ragged(self, core: MQCore) -> bool:
         """One ragged mixed-batch step, launched and settled at once:
         `step_ragged_launch` then `step_settle` — the pipelined loop's
-        own two halves with nothing between them (tests, bench, and
+        own two halves with nothing between them (tests, and
         every runtime whose next composition needs the ids on the host).
         Returns True when a mixed dispatch ran (decode slots advanced
         inside it); False leaves decode to the fused-scan path."""
@@ -3577,7 +3441,7 @@ def build_model_runtimes(name, cfg, engine_cfg, mesh, dtype, checkpoint_path,
     one copy of the dp-submesh / preloaded-params / encoder branching.
 
     dp generative replicas each land on their own slice of the mesh's
-    data axis (a [1, sp, tp] submesh): N param copies + KV pools serving
+    data axis (a [1, ep, tp] submesh): N param copies + KV pools serving
     concurrently — the reference's "one request per backend, N backends"
     scale-out story with backends = mesh slices. The checkpoint is
     read/parsed once and shared host-side across replicas."""
@@ -3743,10 +3607,10 @@ class TPUEngine:
         self.policy = make_policy(engine_cfg)
         self.core = MQCore(blocklist_path)
         self.core.set_fairness(fairness)
-        if mesh is None and (engine_cfg.dp, engine_cfg.sp, engine_cfg.tp,
-                             engine_cfg.ep) != (1, 1, 1, 1):
-            mesh = make_mesh(dp=engine_cfg.dp, sp=engine_cfg.sp,
-                             tp=engine_cfg.tp, ep=engine_cfg.ep)
+        if mesh is None and (engine_cfg.dp, engine_cfg.tp,
+                             engine_cfg.ep) != (1, 1, 1):
+            mesh = make_mesh(dp=engine_cfg.dp, tp=engine_cfg.tp,
+                             ep=engine_cfg.ep)
         self.mesh = mesh
         self.dtype = dtype if dtype is not None else jnp.dtype(engine_cfg.dtype)
         self.runtimes: Dict[str, object] = {}

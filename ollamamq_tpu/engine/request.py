@@ -275,7 +275,7 @@ class Request:
         self._inc_decode = None
         # Lifecycle trace (telemetry.tracing.Trace), attached by the
         # engine's enqueue path; None for directly-constructed Requests
-        # (bench, unit tests) — every trace hook below no-ops then.
+        # (unit tests) — every trace hook below no-ops then.
         self.trace = None
         # Stream-stall attribution state (engine-owned): True while the
         # consumer's backlog sits above the TokenStream high-water mark.

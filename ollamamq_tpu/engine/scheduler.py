@@ -318,7 +318,7 @@ assert set(_POLICIES) == set(SCHEDULERS)
 def make_policy(ecfg) -> SchedulerPolicy:
     """Build the configured policy; loud on an unknown --scheduler (the
     CLI validates pre-device via config.validate_scheduler, but tests
-    and bench construct EngineConfig directly)."""
+    construct EngineConfig directly)."""
     name = getattr(ecfg, "scheduler", "fcfs") or "fcfs"
     cls = _POLICIES.get(name)
     if cls is None:

@@ -22,10 +22,9 @@ between the two corrupts the transport; coordinator traffic cannot.
 
 Opcode header (int32[5]: [op, a, b, model_ordinal, replica_ordinal]):
     OP_SHUTDOWN = 0              -> workers exit (no payload)
-    (1 and 2 are not assigned)
+    (1, 2 and 5 are not assigned)
     OP_DECODE   = 3, a=k_steps
     OP_ENCODE   = 4, a=B, b=bucket (embedding batch forward, stateless)
-    OP_PREFILL_SP = 5, a=T (sequence-parallel long-prompt prefill)
     OP_RELOAD   = 6              -> rebuild runtime [mi][ri] from pristine
                                     config (multi-host failure recovery)
     OP_LOAD     = 7, a=n_replicas; payload carries (name, ckpt) strings
@@ -91,7 +90,6 @@ log = logging.getLogger("ollamamq.spmd")
 OP_SHUTDOWN = 0
 OP_DECODE = 3
 OP_ENCODE = 4
-OP_PREFILL_SP = 5
 OP_RELOAD = 6
 OP_LOAD = 7
 OP_EVICT = 8
@@ -308,8 +306,6 @@ def payload_spec(op, a, b, S, MP, W):
     # (engine/step_pack.py) is the wire order, on both sides.
     if op == OP_DECODE:
         return [((step_pack.decode_layout(S, MP).size,), np.int32)]
-    if op == OP_PREFILL_SP:
-        return [((step_pack.sp_layout(a, MP).size,), np.int32)]
     if op in (OP_RAGGED, OP_SPEC):
         return [((step_pack.ragged_layout(a, S, MP, W).size,), np.int32)]
     if op in (OP_ENCODE, OP_EMBED):
@@ -493,9 +489,8 @@ def _raise_on_worker_failure(flags: Optional[np.ndarray], name: str) -> None:
         )
 
 
-_OP_SITE = {OP_DECODE: "decode", OP_PREFILL_SP: "sp_prefill",
-            OP_RAGGED: "ragged", OP_SPEC: "spec_verify", OP_EMBED: "embed",
-            OP_ENCODE: "encode"}
+_OP_SITE = {OP_DECODE: "decode", OP_RAGGED: "ragged", OP_SPEC: "spec_verify",
+            OP_EMBED: "embed", OP_ENCODE: "encode"}
 
 
 def _mirrored_dispatch(rt, op, a, b, values, dispatch):
@@ -597,14 +592,6 @@ class SPMDModelRuntime(ModelRuntime):
             OP_DECODE, k_steps, 0, (buf,),
             lambda: super(SPMDModelRuntime, self)._dispatch_decode(
                 k_steps, buf))
-
-    def _dispatch_prefill_sp(self, T, buf):
-        if not self._spmd:
-            return super()._dispatch_prefill_sp(T, buf)
-        return self._mirrored(
-            OP_PREFILL_SP, T, 0, (buf,),
-            lambda: super(SPMDModelRuntime, self)._dispatch_prefill_sp(
-                T, buf))
 
     def _dispatch_ragged(self, T_pad, k_cap, buf):
         if not self._spmd:
@@ -929,7 +916,6 @@ def run_worker(
     # (never mid-replay, where the primary would see a desync).
     err = validate_quant_config(
         engine_cfg.weights_dtype, engine_cfg.kv_dtype,
-        sp=dict(mesh.shape).get("seq", 1),
         model_names=list(models))
     if err is not None:
         raise ValueError(err)
@@ -944,8 +930,7 @@ def run_worker(
     S = engine_cfg.max_slots
     MP = engine_cfg.max_pages_per_seq
     W = engine_cfg.repeat_last_n
-    DATA_OPS = (OP_DECODE, OP_PREFILL_SP, OP_ENCODE, OP_EMBED, OP_RAGGED,
-                OP_SPEC)
+    DATA_OPS = (OP_DECODE, OP_ENCODE, OP_EMBED, OP_RAGGED, OP_SPEC)
 
     wire_seq = 0
     while max_steps is None or steps < max_steps:
@@ -1070,11 +1055,6 @@ def _replay(rt, op, a, b, payload):
         toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state = \
             ModelRuntime._dispatch_decode(rt, a, buf)
         return (toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state)
-    elif op == OP_PREFILL_SP:
-        (buf,) = payload
-        toks, rt.kc, rt.vc, rt.recent = ModelRuntime._dispatch_prefill_sp(
-            rt, a, buf)
-        return (toks, rt.kc, rt.vc, rt.recent)
     elif op in (OP_RAGGED, OP_SPEC):
         (buf,) = payload  # a=T_pad, b=k_cap (0 on OP_RAGGED)
         toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state = \

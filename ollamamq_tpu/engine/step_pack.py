@@ -1,15 +1,15 @@
 """One upload a step: a step program's host inputs as ONE int32 array.
 
-A step program (`mq_ragged_step`, `mq_decode_scan`, `mq_prefill_sp`)
-takes two dozen small host-made arrays — the token stream, per-row span
-bookkeeping, page-table rows, sampling parameters — plus an RNG key.
+A step program (`mq_ragged_step`, `mq_decode_scan`) takes two dozen
+small host-made arrays — the token stream, per-row span bookkeeping,
+page-table rows, sampling parameters — plus an RNG key.
 Handing each to the jitted call separately costs a host→device transfer
 apiece (and, for the key, two eager device programs) in every step of
 every model. Here they are fields of one buffer instead:
 
   * a `StepLayout` is a static table `name -> (offset, shape, dtype)`
     over one flat int32 array, fixed by the shapes the engine already
-    keys its jits on (`ragged_layout`, `decode_layout`, `sp_layout`);
+    keys its jits on (`ragged_layout`, `decode_layout`);
   * on the host, `new()` gives a fresh buffer holding each field's
     padding value and `views()` / `view()` give numpy views into it,
     which the composition writes directly — float fields through a
@@ -139,12 +139,3 @@ def decode_layout(S: int, MP: int) -> StepLayout:
          ("active", (S,), i32, 0), ("pt", (S, MP), i32, kvc.TRASH_PAGE)]
         + _sampling(S) + _RNG)
 
-
-@functools.lru_cache(maxsize=16)
-def sp_layout(T: int, MP: int) -> StepLayout:
-    """`mq_prefill_sp`: one prompt padded to T tokens."""
-    i32 = np.int32
-    return StepLayout(
-        [("tokens", (1, T), i32, 0), ("lens", (1,), i32, 0),
-         ("slot_ids", (1,), i32, 0), ("pt", (1, MP), i32, kvc.TRASH_PAGE)]
-        + _sampling(1) + _RNG)

@@ -22,8 +22,7 @@ the loop from observed load to fleet size:
 
   Provisioner    MemberProvisioner is the seam between the decision
                  loop and capacity. SubprocessProvisioner (the first
-                 real implementation, the crash_restart bench's
-                 subprocess harness) spawns `python -m ollamamq_tpu.cli`
+                 real implementation) spawns `python -m ollamamq_tpu.cli`
                  engine servers on free ports and retires them with
                  SIGTERM; LocalProvisioner builds in-process engine
                  replicas from the CLI's engine factory (tests, and
@@ -154,8 +153,7 @@ class LocalProvisioner(MemberProvisioner):
 
 
 class SubprocessProvisioner(MemberProvisioner):
-    """Subprocess HttpMember engines — the crash_restart bench's
-    harness as a provisioner: spawn `python -m ollamamq_tpu.cli` on a
+    """Subprocess HttpMember engines: spawn `python -m ollamamq_tpu.cli` on a
     free port, wait for /health, hand the router an HttpMember; retire
     is SIGTERM (the member server drains + flushes before exit).
 
@@ -347,7 +345,7 @@ class AutoscalerManager:
         self._spawn_done: "queue.Queue" = queue.Queue()
         self._next_id = 0
         # Member-hours ledger (the metric is cumulative; the float here
-        # backs the bench/status readout).
+        # backs the status readout).
         self.member_seconds = 0.0
         self._hours_at = time.monotonic()
 

@@ -172,7 +172,7 @@ class FleetRouter:
                              origin="router")
         # Router-overhead self-profiling: a rolling window of placement
         # decision costs (ms) behind router_overhead_p99_ms() — the
-        # health monitor's overhead-storm alert and the bench gate read
+        # health monitor's overhead-storm alert reads
         # the windowed p99 so a one-off spike ages out; the cumulative
         # story lives in the ollamamq_router_overhead_ms histogram.
         self._place_window: collections.deque = collections.deque(
@@ -1809,8 +1809,8 @@ class FleetRouter:
     def router_overhead_p99_ms(self) -> Optional[float]:
         """Windowed p99 of the placement-decision overhead (ms) over the
         last 512 placements; None before any placement. The health
-        monitor's overhead-storm alert and the bench fleet-chaos gate
-        both bound THIS number against --router-overhead-budget-ms."""
+        monitor's overhead-storm alert bounds THIS number against
+        --router-overhead-budget-ms."""
         window = sorted(self._place_window)
         if not window:
             return None
@@ -1818,7 +1818,7 @@ class FleetRouter:
 
     def router_overhead_stats(self) -> dict:
         """Per-site overhead readout off the cumulative histogram plus
-        the windowed placement p99 (stats/TUI/bench surface)."""
+        the windowed placement p99 (stats/TUI surface)."""
         sites = {}
         for labelvalues, child in tm.ROUTER_OVERHEAD_MS.series():
             if child.count == 0:

@@ -64,9 +64,9 @@ from ollamamq_tpu.telemetry.slo import DEFAULT_WINDOWS, Objective
 INTERACTIVE_TTFT_MS = 500.0
 BULK_TTFT_MS_FACTOR = 8.0
 
-# Balancer defaults (constructor-overridable; tests and bench shrink
-# them). The deadband + cooldown + sample floor are the hysteresis that
-# keeps an oscillating class mix from flapping members between tiers.
+# Balancer defaults (constructor-overridable; tests shrink them). The
+# deadband + cooldown + sample floor are the hysteresis that keeps an
+# oscillating class mix from flapping members between tiers.
 EMA_ALPHA = 0.05
 BALANCE_DEADBAND = 0.18
 BALANCE_COOLDOWN_S = 30.0

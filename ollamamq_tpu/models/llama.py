@@ -27,8 +27,8 @@ Design notes (TPU-first):
     rows it writes and the pages it reads — not the pool.
   - Served: `forward_ragged` (one flattened stream of prefill spans and
     decode tokens over the paged pool), `forward_decode` (one token per
-    slot: the fused scan's body), `forward_prefill_sp`, `forward_embed`
-    and the encoder; all shape-static => one jit per padded shape.
+    slot: the fused scan's body), `forward_embed` and the encoder; all
+    shape-static => one jit per padded shape.
     `forward_prefill` (whole prompts, dense causal attention, the
     convolution over shifted copies: no state) is the plain oracle that
     tests, the benchmark's reference and the dry run compare them with;
@@ -990,53 +990,6 @@ def forward_decode(
     logits = _logits(params, cfg, x)[:, 0, :]
     return _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
                     moe_load)
-
-
-def forward_prefill_sp(
-    params: dict,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,  # [B, T] — T sharded over the mesh "seq" axis
-    seq_lens: jnp.ndarray,  # [B]
-    mesh,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Sequence-parallel prefill for long contexts: activations sharded
-    along T over the "seq" mesh axis, attention via ring attention
-    (K/V blocks rotate over ICI). Returns (last_logits [B,V],
-    k_stack [L,B,T,Hk,hd], v_stack) — the caller scatters K/V into the
-    paged pool. Numerics match forward_prefill exactly (same f32 online
-    softmax), only the schedule is distributed.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec as PS
-
-    from ollamamq_tpu.parallel.mesh import AXIS_SEQ
-    from ollamamq_tpu.parallel.ring_attention import ring_attention
-
-    B, T = tokens.shape
-    seq_sharded = NamedSharding(mesh, PS(None, AXIS_SEQ, None))
-    x = embed_lookup(params["embed"], tokens, _adtype(params))
-    x = jax.lax.with_sharding_constraint(x, seq_sharded)
-    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-
-    def body(carry, lp):
-        x, kv = carry, None
-
-        def attn_fn(q, k, v):
-            nonlocal kv
-            kv = (k, v)
-            return ring_attention(q, k, v, seq_lens, mesh)
-
-        x, _ = _layer_step(cfg, lp, cfg.kinds[0], x, positions, attn_fn,
-                           valid=positions < seq_lens[:, None])
-        x = jax.lax.with_sharding_constraint(x, seq_sharded)
-        return x, kv
-
-    # A uniform stack only (the engine refuses --sp for any other):
-    # the layers are the scan's xs, K and V its ys.
-    x, (k_stack, v_stack) = jax.lax.scan(body, x, params["layers"])
-    last = jnp.clip(seq_lens - 1, 0, T - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    logits = _logits(params, cfg, x_last)[:, 0, :]
-    return logits, k_stack, v_stack
 
 
 def forward_embed(

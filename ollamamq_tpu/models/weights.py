@@ -386,8 +386,8 @@ def quant_guardrail(
     """Greedy token-match-rate + max-logit-error of the int8 tree vs its
     bf16 source, teacher-forced on the bf16 model's own greedy rollout
     (so one early mismatch can't cascade into a meaningless diff).
-    Publishes `ollamamq_quant_logit_err`; tier-1 pins the bounds and the
-    bench density scenario reports them next to its A/B line."""
+    Publishes `ollamamq_quant_logit_err`; tier-1 pins the bounds
+    (tests/test_quantization.py)."""
     from ollamamq_tpu.telemetry import schema as tm
 
     if base_params is None:

@@ -1,5 +1,5 @@
 from ollamamq_tpu.parallel.mesh import (make_mesh, AXIS_DATA, AXIS_EXPERT,
-                                        AXIS_SEQ, AXIS_TENSOR)
+                                        AXIS_TENSOR)
 from ollamamq_tpu.parallel.sharding import (
     param_partition_specs,
     kv_cache_spec,
@@ -7,6 +7,6 @@ from ollamamq_tpu.parallel.sharding import (
 )
 
 __all__ = [
-    "make_mesh", "AXIS_DATA", "AXIS_EXPERT", "AXIS_SEQ", "AXIS_TENSOR",
+    "make_mesh", "AXIS_DATA", "AXIS_EXPERT", "AXIS_TENSOR",
     "param_partition_specs", "kv_cache_spec", "shard_params",
 ]

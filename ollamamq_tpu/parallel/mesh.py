@@ -7,7 +7,7 @@ is a jax.sharding.Mesh over TPU chips with named axes:
   - "data":   replica/data parallelism (independent batches / model replicas)
   - "tensor": tensor parallelism within a replica — attention heads and MLP
               hidden dim sharded; XLA emits allgather/reduce-scatter over ICI
-  - "seq":    sequence/context parallelism for long-context ring attention
+  - "expert": expert parallelism — an MoE layer's experts sharded
 
 Multi-host: `jax.distributed.initialize` is handled in
 ollamamq_tpu.parallel.distributed; this module only arranges whatever
@@ -24,33 +24,31 @@ from jax.sharding import Mesh
 
 AXIS_DATA = "data"
 AXIS_TENSOR = "tensor"
-AXIS_SEQ = "seq"
 AXIS_EXPERT = "expert"
 
 
 def make_mesh(
     dp: int = 1,
     tp: int = -1,
-    sp: int = 1,
     ep: int = 1,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Build a (data, seq, expert, tensor) mesh.
+    """Build a (data, expert, tensor) mesh.
 
-    `tp=-1` means "all devices not consumed by dp*sp*ep". The tensor
+    `tp=-1` means "all devices not consumed by dp*ep". The tensor
     axis is innermost so TP collectives ride the fastest ICI links
     (adjacent chips).
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
     if tp == -1:
-        if n % (dp * sp * ep) != 0:
+        if n % (dp * ep) != 0:
             raise ValueError(
-                f"{n} devices not divisible by dp*sp*ep={dp * sp * ep}")
-        tp = n // (dp * sp * ep)
-    k = dp * sp * ep * tp
+                f"{n} devices not divisible by dp*ep={dp * ep}")
+        tp = n // (dp * ep)
+    k = dp * ep * tp
     if k > n:
-        raise ValueError(f"dp*sp*ep*tp={k} > {n} available devices")
+        raise ValueError(f"dp*ep*tp={k} > {n} available devices")
     nproc = jax.process_count()
     if dp > 1 and nproc > 1:
         # Multi-host dp replica serving slices the mesh along the data axis
@@ -75,10 +73,10 @@ def make_mesh(
         arr = (np.asarray(_pick_per_process(devices, k, nproc, per_proc))
                .reshape(nproc, dp, per_proc // dp)
                .transpose(1, 0, 2)
-               .reshape(dp, sp, ep, tp))
+               .reshape(dp, ep, tp))
     else:
-        arr = np.asarray(devices[:k]).reshape(dp, sp, ep, tp)
-    return Mesh(arr, (AXIS_DATA, AXIS_SEQ, AXIS_EXPERT, AXIS_TENSOR))
+        arr = np.asarray(devices[:k]).reshape(dp, ep, tp)
+    return Mesh(arr, (AXIS_DATA, AXIS_EXPERT, AXIS_TENSOR))
 
 
 def _pick_per_process(devices, k: int, nproc: int, per_proc: int):
@@ -105,7 +103,7 @@ def _pick_per_process(devices, k: int, nproc: int, per_proc: int):
 
 
 def replica_submesh(mesh: Mesh, r: int) -> Mesh:
-    """Replica r's slice of the data axis (a [1, sp, ep, tp] submesh) — THE
+    """Replica r's slice of the data axis (a [1, ep, tp] submesh) — THE
     derivation, shared by the engine's replica construction and the SPMD
     worker's reload path, which must agree on every host."""
     return Mesh(mesh.devices[r:r + 1], mesh.axis_names)

@@ -33,7 +33,7 @@ PHASES = (
     "queue",         # fair-share queue wait: enqueue/requeue -> admit
     "admission",     # scheduler placement + runtime pending queue
     "prefix_cache",  # prefix-cache lookup/pin on a cache-hit admission
-    "prefill",       # prompt forward(s): ragged spans, or sp
+    "prefill",       # prompt forward(s): ragged spans
     "decode",        # token generation: first token -> finish
     "stream",        # stream-write stall: consumer not draining tokens
     "other",

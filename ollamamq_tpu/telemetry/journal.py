@@ -906,7 +906,7 @@ def check_invariants(records: List[dict],
 
 # ---------------------------------------------------------------------------
 # Batch stats: occupancy / padding-waste from the composed-batch records
-# (bench.py folds this into the BENCH JSON line).
+# (`tools/journal.py stats` prints them).
 # ---------------------------------------------------------------------------
 
 def _padded_of(rec: dict) -> int:

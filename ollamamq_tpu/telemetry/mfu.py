@@ -22,9 +22,9 @@ import os
 from typing import Optional
 
 # Published bf16 dense peak FLOP/s per chip, keyed by the EXACT
-# `device_kind` jax reports. The one table of peaks in the repository
-# (bench.py reads it too). Source: Google Cloud TPU documentation, the
-# "TPU v5e" page (197 TFLOP/s bf16; its 394 figure is int8) and the
+# `device_kind` jax reports. The one table of peaks in the package
+# (benchmarks/lib/peaks.py keeps the benchmark's own). Source: Google
+# Cloud TPU documentation, the "TPU v5e" page (197 TFLOP/s bf16; its 394 figure is int8) and the
 # sibling pages of the other generations. A device that is not listed
 # has no peak: an unknown kind is never given a neighbour's rate.
 PEAK_FLOPS_BY_KIND = {

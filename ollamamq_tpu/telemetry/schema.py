@@ -348,13 +348,13 @@ QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "
     "bf16 source on the guardrail probe (teacher-forced greedy rollout; "
-    "set when the guardrail runs — tests, bench density scenario)",
+    "set when the guardrail runs — tests)",
     labels=("model",))
 
 # -- fleet router (fleet/router.py; dispatcher-over-engines) ---------------
 # Closed site vocabulary for ollamamq_router_overhead_ms{site}: every
 # always-on nanosecond timer around the router hot path. "place" is the
-# bounded one (the bench fleet-chaos gate fails when its p99 exceeds
+# bounded one (the router_overhead alert fires when its p99 exceeds
 # --router-overhead-budget-ms); the rest attribute where the router's
 # own time goes per decision.
 ROUTER_OVERHEAD_SITES = ("place", "journal", "wal_fsync",
@@ -429,7 +429,7 @@ FLEET_MEMBER_HOURS_TOTAL = REGISTRY.counter(
     "ollamamq_fleet_member_hours_total",
     "Cumulative member-serving hours (fractional; accrued each scaler "
     "tick over every non-ejected member) — the resource-cost side of "
-    "the elastic-fleet ledger the diurnal bench gates on")
+    "the elastic-fleet ledger")
 FLEET_PREEMPTIONS_TOTAL = REGISTRY.counter(
     "ollamamq_fleet_preemptions_total",
     "Termination notices served to preemptible members (POST "
@@ -487,7 +487,7 @@ RECOVERED_STREAMS_TOTAL = REGISTRY.counter(
 # -- engine performance plane (telemetry/stepprof.py) ----------------------
 # Closed site vocabulary for ollamamq_compile_total{site}: one per jit
 # cache the engine fills (the compile ladder's rungs live in these).
-COMPILE_SITES = ("ragged", "sp_prefill", "decode", "embed")
+COMPILE_SITES = ("ragged", "decode", "embed")
 STEP_PHASE_MS = REGISTRY.histogram(
     "ollamamq_step_phase_ms",
     "Engine dispatch self-profiling: milliseconds each step spent per "
@@ -557,10 +557,10 @@ PROCESS_CPU_SECONDS_TOTAL = REGISTRY.counter(
     "/metrics is rendered")
 COMPILE_TOTAL = REGISTRY.counter(
     "ollamamq_compile_total",
-    "XLA compiles the engine paid, by jit-cache site (ragged / prefill "
-    "/ chunk / sp_prefill / decode / embed) — exactly one per compile-"
-    "ladder rung in steady state; a climbing rate past warmup is a "
-    "ladder bug (compile_storm alert)", labels=("site",))
+    "XLA compiles the engine paid, by jit-cache site (ragged / decode "
+    "/ embed) — exactly one per compile-ladder rung in steady state; "
+    "a climbing rate past warmup is a ladder bug (compile_storm "
+    "alert)", labels=("site",))
 COMPILE_MS = REGISTRY.histogram(
     "ollamamq_compile_ms",
     "Wall milliseconds one XLA compile held the dispatch path (the "

@@ -14,12 +14,11 @@ their bytes (`h2d_transfers`, `h2d_bytes`; the
 buffer of engine/step_pack.py). The
 same samples feed `ollamamq_step_phase_ms{phase,mode}` histograms, a
 rolling per-shape p50/p99 table, `/debug/stepprof`, the TUI `compiles`
-chip, and the `step_profile` block bench.py embeds in every BENCH
-record (what `scripts/bench_compare.py` diffs across rounds).
+chip, and the `step_profile` section of the diagnostics bundle.
 
 Dependency-free (stdlib only — no jax, no numpy) like the rest of
 `telemetry/`, so scripts/check_metrics_docs.py can import the phase
-vocabulary in CI and bench's error path can always attach a summary.
+vocabulary in CI.
 
 Contracts the tests pin:
 
@@ -66,7 +65,7 @@ Contracts the tests pin:
     the health monitor's `compile_storm` alert after warmup.
 
 Module-global `PROFILER` (same pattern as metrics.REGISTRY): the
-engine, FakeRuntime, and bench feed it; the server and TUI read it;
+engine and FakeRuntime feed it; the server and TUI read it;
 tests call `PROFILER.reset()` for isolation.
 """
 
@@ -426,7 +425,7 @@ class StepTimer:
     def __init__(self, prof: "StepProfiler", mode: str,
                  clock: Optional[LoopClock] = None):
         t = time.perf_counter()
-        if clock is None:  # a step outside any engine loop (bench, tests)
+        if clock is None:  # a step outside any engine loop (tests)
             clock = LoopClock(prof, threading.current_thread().name)
             clock._last = t
         self._prof = prof
@@ -815,7 +814,7 @@ class StepProfiler:
         return out
 
     def summary(self) -> dict:
-        """The bench `step_profile` block / bundle section: per-mode
+        """The diagnostics bundle's `step_profile` section: per-mode
         phase p50/p99, compile count + rate, padding waste, overhead."""
         return {
             "samples": self.seq,
@@ -890,6 +889,6 @@ class StepProfiler:
         }
 
 
-# THE process-wide profiler (metrics.REGISTRY pattern): engine + fake +
-# bench write, server/TUI read, tests reset().
+# THE process-wide profiler (metrics.REGISTRY pattern): engine + fake
+# write, server/TUI read, tests reset().
 PROFILER = StepProfiler()

@@ -24,8 +24,8 @@ Plan file schema (JSON, validated loudly at startup — a malformed
 
 Each rule names ONE site and ONE trigger:
 
-  site     where the fault fires — a dispatch seam ("sp_prefill",
-           "ragged" for the mixed-batch dispatch,
+  site     where the fault fires — a dispatch seam ("ragged" for the
+           mixed-batch dispatch,
            "spec_verify" for a mixed dispatch carrying speculative
            verify spans, "decode", "collect" where a launched step's
            ids are read back — the next step may already be launched
@@ -108,7 +108,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-SITES = ("sp_prefill", "ragged", "spec_verify",
+SITES = ("ragged", "spec_verify",
          "decode", "collect", "embed", "encode", "step", "alloc", "extend",
          "replica", "retier",
          "migrate", "wal", "preempt", "router", "compile")

@@ -116,7 +116,7 @@ def check_no_dropped_streams(records: List[dict]) -> List[str]:
         `migrate_abort` nor a terminal for its req is an orphaned
         two-phase handoff (source state parked forever).
 
-    Run this on COMPLETE journals (a finished bench/chaos run, a drained
+    Run this on COMPLETE journals (a finished chaos run, a drained
     spill) — a live ring mid-failover would report in-flight streams as
     violations, which is why this lives here and not in the health
     monitor's live invariant sweep. A journal cut short by a process

@@ -32,7 +32,7 @@ from ollamamq_tpu.tools.journal import (check_no_dropped_streams,
 from testutil import collect
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
-            max_pages_per_seq=8, prefill_buckets=(16, 32),
+            max_pages_per_seq=8,
             decode_steps_per_iter=2)
 
 FAST = dict(probe_period_s=0.05, eject_heartbeat_s=5.0,
@@ -335,6 +335,32 @@ def test_scale_to_zero_parks_and_wakes_over_http():
         assert check_scale_pairing(router.journal.tail(None)) == []
     finally:
         router.stop()
+
+
+def test_member_hours_accrue_by_live_members_only():
+    """The cost side of elasticity: member-hours accrue by the members
+    that are up — a tier slept to zero, or an ejected member, stops
+    costing the moment it leaves — so a fleet that shrinks overnight
+    bills strictly less than one held at its peak size for the same
+    span. Driven on a clock of its own, not the wall's."""
+    from ollamamq_tpu.fleet.autoscaler import AutoscalerManager
+
+    up = [types.SimpleNamespace(state="healthy") for _ in range(3)]
+    scaler = object.__new__(AutoscalerManager)
+    scaler.router = types.SimpleNamespace(members=list(up))
+    scaler._hours_at, scaler.member_seconds = 1000.0, 0.0
+    billed0 = tm.FLEET_MEMBER_HOURS_TOTAL.value
+    scaler._accrue_member_hours(1000.0 + 3600.0)        # 3 up for an hour
+    assert scaler.member_seconds == 3 * 3600.0
+    scaler.router.members.pop()                         # one retired
+    up[0].state = "ejected"                             # one down
+    scaler._accrue_member_hours(1000.0 + 2 * 3600.0)    # 1 up for an hour
+    assert scaler.member_seconds == 4 * 3600.0          # not 6: the peak's
+    scaler.router.members.clear()                       # slept to zero
+    scaler._accrue_member_hours(1000.0 + 3 * 3600.0)
+    scaler._accrue_member_hours(1000.0)                 # a clock going back
+    assert scaler.member_seconds == 4 * 3600.0
+    assert tm.FLEET_MEMBER_HOURS_TOTAL.value - billed0 == pytest.approx(4.0)
 
 
 # -------------------------------------------------------------- anti-flap

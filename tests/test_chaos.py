@@ -130,7 +130,7 @@ def test_runtime_recovers_after_step_failure():
 
     eng = TPUEngine(
         EngineConfig(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
-                     max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+                     max_pages_per_seq=16,
                      max_new_tokens=8, decode_steps_per_iter=2),
         blocklist_path=None,
     )
@@ -206,7 +206,7 @@ def test_poisoned_request_errors_after_repeated_runtime_failure():
 
     eng = TPUEngine(
         EngineConfig(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
-                     max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+                     max_pages_per_seq=16,
                      max_new_tokens=8, decode_steps_per_iter=2),
         blocklist_path=None,
     )

@@ -390,6 +390,26 @@ def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
     assert mem.temp_size_in_bytes < NP * PS * HK * HD * 2, mem
 
 
+@pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG],
+                         ids=["uniform", "lfm2_widths"])
+@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
+def test_step_programs_lower_to_the_same_text_twice(v5e, which, monkeypatch,
+                                                    cfg):
+    """Two runtimes built one after the other lower a step program to
+    the same StableHLO text — a uniform stack's and one whose layers
+    differ (a scan over a period of kinds, expert matmuls): nothing in the
+    trace depends on what was built before it (a counter, an id, a cache's
+    order). A change that is
+    to leave the step programs alone is shown to by comparing this text,
+    hashed, between its parent and itself (ROADMAP C11) — which says
+    something only if the text is a function of the code."""
+    first, second = (
+        _lower_step_program(v5e, which, monkeypatch, cfg)[0].as_text()
+        for _ in range(2))
+    assert which in first and "stablehlo." in first
+    assert first == second
+
+
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
 def test_lfm2_width_step_programs_carry_pool_and_conv_state_in_place(
         v5e, which, monkeypatch):

@@ -28,7 +28,7 @@ def encoder_only_engine():
     eng = TPUEngine(
         EngineConfig(model="test-tiny-embed", max_slots=2, num_pages=32,
                      page_size=8, max_pages_per_seq=8,
-                     prefill_buckets=(16,), decode_steps_per_iter=2),
+                     decode_steps_per_iter=2),
         models={"test-tiny-embed": None},
         blocklist_path=None, dtype=jnp.float32,
     )
@@ -123,7 +123,7 @@ def test_place_requeues_when_replica_capacity_races_away():
     eng = TPUEngine(
         EngineConfig(model="test-tiny", max_slots=2, num_pages=32,
                      page_size=8, max_pages_per_seq=8,
-                     prefill_buckets=(16,), decode_steps_per_iter=2),
+                     decode_steps_per_iter=2),
         models={"test-tiny": None},
         blocklist_path=None, dtype=jnp.float32,
     )
@@ -180,7 +180,7 @@ def test_call_on_loop_drained_on_stop():
     eng = TPUEngine(
         EngineConfig(model="test-tiny", max_slots=2, num_pages=32,
                      page_size=8, max_pages_per_seq=8,
-                     prefill_buckets=(16,), decode_steps_per_iter=2),
+                     decode_steps_per_iter=2),
         models={"test-tiny": None},
         blocklist_path=None, dtype=jnp.float32,
     )
@@ -242,7 +242,7 @@ def test_multihost_dp_mesh_arrangement_validates():
     orig = M.jax.process_count
     M.jax.process_count = _FakeProc(2)
     try:
-        m = M.make_mesh(dp=2, sp=1, tp=4)
+        m = M.make_mesh(dp=2, tp=4)
         # Each dp slice takes 2 devices from EACH simulated process half.
         ids = np.vectorize(lambda d: d.id)(m.devices)
         for r in range(2):
@@ -252,6 +252,6 @@ def test_multihost_dp_mesh_arrangement_validates():
         import pytest as _pytest
 
         with _pytest.raises(ValueError, match="per-process"):
-            M.make_mesh(dp=8, sp=1, tp=1)
+            M.make_mesh(dp=8, tp=1)
     finally:
         M.jax.process_count = orig

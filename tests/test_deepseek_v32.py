@@ -564,10 +564,9 @@ def test_the_engine_serves_it_and_counts_what_the_selection_did(monkeypatch):
     (dict(kv_dtype="int8"), "--kv-dtype int8: the page writer's scales"),
     (dict(weights_dtype="int8"), "--weights-dtype int8"),
     (dict(prefix_cache=True), "--prefix-cache: the radix tree"),
-    (dict(mesh_shape={"seq": 2}), "--sp: the ring prefill"),
     (dict(mesh_shape={"tensor": 2}), "--tp / --ep: the latent"),
     (dict(mesh_shape={"expert": 2}), "--tp / --ep: the latent"),
-], ids=["kv_int8", "w_int8", "prefix_cache", "sp", "tp", "ep"])
+], ids=["kv_int8", "w_int8", "prefix_cache", "tp", "ep"])
 def test_what_the_latent_pools_cannot_do_yet_is_refused_by_one_line(kw,
                                                                     match):
     err = validate_latent_pool(DS, **kw)

@@ -21,7 +21,7 @@ from ollamamq_tpu.testing.faults import (DeviceLostError, FaultInjected,
 from testutil import collect
 
 TINY = dict(model="test-tiny", max_slots=2, num_pages=64, page_size=8,
-            max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+            max_pages_per_seq=16,
             decode_steps_per_iter=2)
 
 

@@ -18,7 +18,7 @@ from testutil import collect
 def dp_cfg(**kw):
     defaults = dict(
         model="test-tiny-gqa", max_slots=2, num_pages=64, page_size=8,
-        max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+        max_pages_per_seq=16,
         max_new_tokens=8, decode_steps_per_iter=2, dp=2, tp=4,
     )
     defaults.update(kw)
@@ -112,6 +112,51 @@ def test_dp2_x_tp2_streams_equal_the_single_mesh_stream(model):
     for r, text in zip(reqs, prompts):
         assert r.generated_ids == single_device_greedy_tokens(
             model, text, **kw), text
+
+
+def test_full_mesh_dp_tp_serving():
+    """Both axes at once on the whole 8-device mesh: dp=2 replicas, each
+    a [1, 1, tp=4] submesh. A prompt of several spans is prefilled as
+    ragged spans inside a TP-sharded replica — the one prefill program
+    there is — and both replicas' outputs match the plain dp=tp=1 engine
+    token-for-token."""
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    kw = dict(model="test-tiny-gqa", max_slots=2, num_pages=128,
+              page_size=8, max_pages_per_seq=32, max_batch_tokens=32,
+              token_granule=8, max_new_tokens=8, decode_steps_per_iter=2)
+    eng = TPUEngine(EngineConfig(dp=2, tp=4, **kw), blocklist_path=None)
+    ref = TPUEngine(EngineConfig(**kw), blocklist_path=None)
+    eng.start()
+    ref.start()
+    try:
+        rs = eng.runtimes["test-tiny-gqa"]
+        assert len(rs.replicas) == 2
+        assert all(dict(rt.mesh.shape) == {"data": 1, "expert": 1,
+                                           "tensor": 4}
+                   for rt in rs.replicas)
+        prompt = rs.tokenizer.encode("full mesh " * 15)
+        assert len(prompt) > 4 * kw["max_batch_tokens"]
+
+        def run(e, user):
+            rid = e.core.enqueue(user, "", "test-tiny-gqa")
+            req = Request(rid, user, "test-tiny-gqa", prompt,
+                          SamplingParams(max_tokens=5))
+            e.submit(req)
+            items = collect(req)
+            assert items[-1].kind == "done", items[-1]
+            return req.generated_ids
+
+        ids_a = run(eng, "mesh-a")
+        ids_b = run(eng, "mesh-b")  # second request: other replica
+        ids_ref = run(ref, "mesh-ref")
+        assert ids_a == ids_ref and ids_b == ids_ref
+        assert all(rt.tokens_generated > 0 for rt in rs.replicas)
+        assert all(k[0] == "ragged"
+                   for rt in rs.replicas for k in rt._prefill_jits)
+    finally:
+        eng.stop()
+        ref.stop()
 
 
 def test_least_loaded_placement_and_rotation():

@@ -331,7 +331,7 @@ def test_a_fake_engine_run_is_dry_every_step_and_still_gapless():
 
 
 TINY = dict(model="test-tiny", max_slots=2, num_pages=64, page_size=8,
-            max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+            max_pages_per_seq=16,
             decode_steps_per_iter=2)
 
 

@@ -268,7 +268,7 @@ def test_recovery_real_engine_page_conservation(tmp_path, tiny_cfg):
     from ollamamq_tpu.engine.engine import TPUEngine
 
     tiny = dict(model="test-tiny", max_slots=2, num_pages=64, page_size=8,
-                max_pages_per_seq=8, prefill_buckets=(16, 32),
+                max_pages_per_seq=8,
                 decode_steps_per_iter=1)
     prompt = list(range(7, 19))
     # Golden: an uninterrupted greedy run.

@@ -16,7 +16,7 @@ from testutil import collect
 def small_cfg(**kw):
     defaults = dict(
         model="test-tiny", max_slots=4, num_pages=64, page_size=8,
-        max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+        max_pages_per_seq=16,
         max_new_tokens=8, decode_steps_per_iter=4,
     )
     defaults.update(kw)
@@ -534,7 +534,7 @@ def test_chunked_prefill_interleaves_with_decode():
     """A long-prompt prefill must not starve concurrent decode streams:
     chunks advance one per tick while other slots keep decoding."""
     eng = TPUEngine(
-        small_cfg(num_pages=256, max_pages_per_seq=32, prefill_buckets=(16,),
+        small_cfg(num_pages=256, max_pages_per_seq=32,
                   decode_steps_per_iter=1),
         blocklist_path=None,
     )
@@ -569,7 +569,7 @@ def test_chunked_prefill_interleaves_with_decode():
 def test_cancel_during_chunked_prefill():
     """Cancelling mid-chunk frees the reserved slot and its pages."""
     eng = TPUEngine(
-        small_cfg(num_pages=256, max_pages_per_seq=32, prefill_buckets=(16,)),
+        small_cfg(num_pages=256, max_pages_per_seq=32),
         blocklist_path=None,
     )
     eng.start()

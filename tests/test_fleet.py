@@ -27,7 +27,7 @@ from ollamamq_tpu.tools.journal import check_no_dropped_streams
 from testutil import collect, free_port
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
-            max_pages_per_seq=8, prefill_buckets=(16, 32),
+            max_pages_per_seq=8,
             decode_steps_per_iter=2)
 
 FAST = dict(probe_period_s=0.05, eject_heartbeat_s=5.0,
@@ -753,6 +753,48 @@ def test_drain_migrates_streams_instead_of_running_them_out():
         assert router.fleet_counts()["healthy"] == 2
     finally:
         router.stop()
+
+
+def test_migration_recovers_a_killed_member_without_recomputing():
+    """The same kill, migration on and off: a member dies with streams
+    mid-generation. With migration the victims resume from their shipped
+    state (`migrate_import`) and recompute at most a fifth of the tokens
+    the recompute-only fleet replays (`replica_failover.replayed_tokens`)
+    — and either way every stream is whole and nothing drops."""
+    def leg(migrate):
+        router = _fake_fleet(n=2, token_latency_s=0.05,
+                             router_kw=dict(migrate=migrate))
+        try:
+            reqs = [_run(router, f"mk{i}", max_tokens=16) for i in range(4)]
+            deadline = time.monotonic() + 30
+            victim = None
+            while time.monotonic() < deadline and victim is None:
+                for f in list(router.flights):
+                    if f.attempt is not None \
+                            and len(f.attempt.req.generated_ids) >= 4:
+                        victim = f.member
+                        break
+                time.sleep(0.01)
+            assert victim is not None, "no stream reached mid-generation"
+            victim.crash()
+            for r in reqs:
+                items = collect(r)
+                assert items[-1].kind == "done"
+                words = _text(items).split()
+                assert words == [f"word{i}" for i in range(16)]
+            recs = router.journal.tail(None)
+            assert check_no_dropped_streams(recs) == []
+            return (sum(int(r.get("replayed_tokens") or 0) for r in recs
+                        if r["kind"] == "replica_failover"),
+                    router.migration_count)
+        finally:
+            router.stop()
+
+    replayed_on, migrations = leg(True)
+    replayed_off, none = leg(False)
+    assert migrations >= 1 and none == 0
+    assert replayed_off > 0
+    assert replayed_on * 5 <= replayed_off, (replayed_on, replayed_off)
 
 
 def test_migration_mid_transfer_crash_falls_back_to_recompute():

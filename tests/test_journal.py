@@ -281,7 +281,7 @@ PS = 8
 def _overload_rt(**kw) -> ModelRuntime:
     defaults = dict(model="test-tiny", max_slots=3, num_pages=24,
                     page_size=PS, max_pages_per_seq=8,
-                    prefill_buckets=(16, 32), max_new_tokens=8,
+                    max_new_tokens=8,
                     decode_steps_per_iter=2, preempt=True)
     defaults.update(kw)
     rt = ModelRuntime("test-tiny", MODEL_CONFIGS["test-tiny"],

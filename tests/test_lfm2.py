@@ -473,10 +473,9 @@ def test_a_voided_step_leaves_nothing_a_later_request_can_see(monkeypatch):
 # ------------------------------- what else touches per-sequence state
 @pytest.mark.parametrize("kw,match", [
     (dict(spec=True), "--spec: a rejected draft"),
-    (dict(mesh_shape={"seq": 2}), "--sp: a convolution over a sequence"),
     (dict(mesh_shape={"tensor": 2}), "--tp / --ep: the conv layers"),
     (dict(mesh_shape={"expert": 2}), "--tp / --ep: the conv layers"),
-], ids=["spec", "sp", "tp", "ep"])
+], ids=["spec", "tp", "ep"])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
     err = validate_slot_state(LFM2, **kw)
     assert err and match in err and "test-tiny-lfm2" in err

@@ -152,3 +152,43 @@ def test_checker_pins_loop_phase_and_span_tables(tmp_path, begin, end, row,
     bare = tmp_path / "README_bare.md"
     bare.write_text(full.replace(begin, "").replace(end, ""))
     assert mod.main(["check_metrics_docs.py", str(bare)]) == 1
+
+
+_SOURCE_EXT = (".py", ".sh", ".md", ".yml", ".toml", ".cpp", ".h")
+_ROOTS = ("", "ollamamq_tpu", "benchmarks", "tests")
+
+
+def _cited_source_paths(text):
+    """The source files a document cites in backticks: `dir/file.py`,
+    `file.py:120`, `tests/test_x.py::test_y`. Not globs, placeholders,
+    absolute paths or what a run leaves behind (no source extension)."""
+    import re
+
+    for token in re.findall(r"`([^`\n]+)`", text):
+        for word in token.split():
+            path = re.split(r"::|:\d", word.strip(".,;()[]"))[0]
+            if (path.endswith(_SOURCE_EXT) and not path.startswith(("/", "~"))
+                    and not re.search(r"[*<>{}$]|\.\.\.", path)):
+                yield path
+
+
+@pytest.mark.parametrize("doc", ["README.md", "PARITY.md",
+                                 ".claude/skills/verify/SKILL.md"])
+def test_documents_cite_only_files_the_tree_has(doc):
+    """A new owner is sent to the files the README, the parity table and
+    the verify recipe name: each must exist — from the repo's root, or from the package,
+    `benchmarks/` or `tests/` where the document names it from there; a
+    bare file name must be some file's name. (`parallel/pipeline.py` and
+    `bench.py` outlived their files in these documents.)"""
+    with open(os.path.join(_REPO, doc), encoding="utf-8") as f:
+        cited = sorted(set(_cited_source_paths(f.read())))
+    assert len(cited) >= 10, cited
+    names = set(os.listdir(_REPO))  # not what a run leaves: _proof/, ...
+    for top in ("ollamamq_tpu", "scripts", "tests", "benchmarks", "cpp"):
+        for _, _, files in os.walk(os.path.join(_REPO, top)):
+            names.update(files)
+    missing = [p for p in cited
+               if not (any(os.path.exists(os.path.join(_REPO, root, p))
+                           for root in _ROOTS)
+                       or ("/" not in p and p in names))]
+    assert missing == []

@@ -144,7 +144,7 @@ def test_dense_model_allowed_on_ep_mesh():
 
     ecfg = EngineConfig(
         model="test-tiny", max_slots=2, num_pages=32, page_size=8,
-        max_pages_per_seq=8, prefill_buckets=(16,), dtype="float32",
+        max_pages_per_seq=8, dtype="float32",
     )
     mesh = make_mesh(dp=1, ep=2, tp=2)
     import jax.numpy as jnp
@@ -162,7 +162,7 @@ def test_engine_serves_moe_end_to_end():
 
     ecfg = EngineConfig(
         model="test-tiny-moe", max_slots=4, num_pages=64, page_size=8,
-        max_pages_per_seq=16, prefill_buckets=(16, 32), max_new_tokens=8,
+        max_pages_per_seq=16, max_new_tokens=8,
         decode_steps_per_iter=2, ep=4, tp=2, dtype="float32",
     )
     eng = TPUEngine(ecfg, blocklist_path=None)

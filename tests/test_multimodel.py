@@ -19,7 +19,7 @@ def setup():
     eng = TPUEngine(
         EngineConfig(model="test-tiny", max_slots=4, num_pages=128,
                      page_size=8, max_pages_per_seq=16,
-                     prefill_buckets=(16, 32, 64), max_new_tokens=8,
+                     max_new_tokens=8,
                      decode_steps_per_iter=2),
         blocklist_path=None,
     )

@@ -522,12 +522,11 @@ def test_preempt_and_replay_gives_the_same_ids(monkeypatch):
 # ------------------------------- what else touches per-sequence state
 @pytest.mark.parametrize("kw,match", [
     (dict(spec=True), "--spec: a rejected draft"),
-    (dict(mesh_shape={"seq": 2}), "--sp: a convolution over a sequence"),
     (dict(mesh_shape={"tensor": 2}),
      "--tp / --ep: the linear_attention layers"),
     (dict(mesh_shape={"expert": 2}),
      "--tp / --ep: the linear_attention layers"),
-], ids=["spec", "sp", "tp", "ep"])
+], ids=["spec", "tp", "ep"])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
     err = validate_slot_state(OLMO, **kw)
     assert err and match in err and NAME in err

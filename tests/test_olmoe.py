@@ -251,7 +251,7 @@ def test_engine_serves_olmoe_and_its_samples_carry_the_expert_counters():
     name = "test-tiny-olmoe"
     ecfg = EngineConfig(
         model=name, max_slots=4, num_pages=64, page_size=8,
-        max_pages_per_seq=16, prefill_buckets=(16, 32), max_new_tokens=8,
+        max_pages_per_seq=16, max_new_tokens=8,
         decode_steps_per_iter=2, dtype="float32",
     )
     stepprof.PROFILER.reset()

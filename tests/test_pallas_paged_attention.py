@@ -1,5 +1,5 @@
 """Pallas ragged paged-attention kernel vs the jnp reference (interpret
-mode on CPU; the compiled path runs on real TPU via the engine/bench)."""
+mode on CPU; the compiled path runs on real TPU via the engine)."""
 
 import jax
 import jax.numpy as jnp
@@ -153,51 +153,4 @@ def test_model_decode_with_pallas_impl(tiny_cfg, tiny_params):
         pa.paged_decode_attention_pallas = orig
     np.testing.assert_allclose(
         np.asarray(out_pal), np.asarray(out_jnp), rtol=5e-5, atol=5e-5
-    )
-
-
-def test_forward_prefill_sp_matches(tiny_cfg, tiny_params):
-    """Sequence-parallel prefill (ring attention) == single-device prefill."""
-    from jax.sharding import NamedSharding
-    from ollamamq_tpu.engine import kv_cache as kvc
-    from ollamamq_tpu.models import llama
-    from ollamamq_tpu.parallel.mesh import make_mesh
-
-    if len(jax.devices()) < 4:
-        pytest.skip("needs virtual devices")
-    cfg, params = tiny_cfg, tiny_params
-    mesh = make_mesh(dp=1, sp=4, tp=1)
-    PS_, MP = 8, 8
-    T = 32
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(1, cfg.vocab_size, size=(1, T)),
-        jnp.int32,
-    )
-    seq_lens = jnp.array([T])
-
-    shape = (cfg.num_layers, 32 * PS_, cfg.num_kv_heads * cfg.head_dim)
-    kc = jnp.zeros(shape, jnp.float32)
-    vc = jnp.zeros(shape, jnp.float32)
-    a = kvc.PageAllocator(32, PS_, MP)
-    pages = a.alloc(T)
-    pt = jnp.asarray(np.stack([kvc.make_page_table_row(pages, MP)]))
-    ref_logits, ref_kc, _ = llama.forward_prefill(
-        params, cfg, tokens, seq_lens, kc, vc, pt, PS_
-    )
-
-    with jax.set_mesh(mesh):
-        sp_logits, k_stack, v_stack = llama.forward_prefill_sp(
-            params, cfg, tokens, seq_lens, mesh
-        )
-    np.testing.assert_allclose(
-        np.asarray(sp_logits), np.asarray(ref_logits), rtol=2e-4, atol=2e-4
-    )
-    # K stack matches what single-device prefill wrote into the pages.
-    slots = np.asarray(
-        [pages[t // PS_] * PS_ + t % PS_ for t in range(T)]
-    )
-    np.testing.assert_allclose(
-        np.asarray(k_stack[:, 0]).reshape(cfg.num_layers, T, -1),
-        np.asarray(ref_kc)[:, slots],  # the pool's rows: [L,T,Hk*hd]
-        rtol=2e-4, atol=2e-4,
     )

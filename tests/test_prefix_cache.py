@@ -39,7 +39,7 @@ PS = 8  # page size for every runtime-level test here
 def make_rt(prefix_cache: bool, **kw) -> ModelRuntime:
     defaults = dict(
         model="test-tiny", max_slots=4, num_pages=96, page_size=PS,
-        max_pages_per_seq=16, prefill_buckets=(16, 64), max_new_tokens=8,
+        max_pages_per_seq=16, max_new_tokens=8,
         decode_steps_per_iter=2, prefix_cache=prefix_cache,
     )
     defaults.update(kw)
@@ -324,7 +324,7 @@ def test_fuzz_radix_tree_allocator_invariants():
 def engine_streams(prefix_cache: bool, prompts, fake=False):
     ecfg = EngineConfig(model="test-tiny", max_slots=4, num_pages=96,
                         page_size=PS, max_pages_per_seq=16,
-                        prefill_buckets=(16, 64), max_new_tokens=6,
+                        max_new_tokens=6,
                         decode_steps_per_iter=2, prefix_cache=prefix_cache)
     if fake:
         eng = FakeEngine(ecfg, models={"test-tiny": None},

@@ -196,7 +196,7 @@ def test_pallas_decode_quantized_matches_jnp_interpret():
 def make_rt(**kw):
     defaults = dict(
         model="test-tiny", max_slots=4, num_pages=96, page_size=8,
-        max_pages_per_seq=16, prefill_buckets=(16, 64), max_new_tokens=8,
+        max_pages_per_seq=16, max_new_tokens=8,
         decode_steps_per_iter=2, max_batch_tokens=48, token_granule=8,
     )
     defaults.update(kw)
@@ -367,14 +367,11 @@ def test_validate_quant_config_combinations():
     assert validate_quant_config("int8", "int8") is None
     assert "fp8" in validate_quant_config("fp8", "bfloat16")
     assert "--kv-dtype" in validate_quant_config("bfloat16", "fp8")
-    assert "sequence-parallel" in validate_quant_config(
-        "bfloat16", "int8", sp=2)
     assert "MoE" in validate_quant_config(
         "int8", "bfloat16", model_names=["mixtral:8x7b"])
     # The validator must not over-reject: int8 weights on a dense model
-    # pass with either KV dtype, on a sequence-parallel mesh with bf16 KV.
+    # pass with either KV dtype.
     assert validate_quant_config("int8", "bfloat16") is None
-    assert validate_quant_config("int8", "bfloat16", sp=2) is None
     assert validate_quant_config(
         "int8", "int8", model_names=["test-tiny"]) is None
 
@@ -385,18 +382,17 @@ def test_cli_fails_fast_on_invalid_combinations():
     # MoE model with int8 weights: rejected before any engine work.
     assert main(["--no-tui", "--models", "mixtral:8x7b",
                  "--weights-dtype", "int8"]) == 2
-    # int8 KV on a sequence-parallel mesh.
-    assert main(["--no-tui", "--models", "test-tiny",
-                 "--kv-dtype", "int8", "--sp", "2"]) == 2
 
 
 @pytest.mark.parametrize("argv", [
     ["--attention", "bucketed"], ["--pp", "2"], ["--pp-microbatches", "4"],
+    ["--sp", "2"],
 ], ids=lambda a: a[0])
 def test_cli_rejects_removed_flags(argv):
     """--attention went with the bucketed oracle, --pp and its
-    microbatch knob with the pipeline path: argparse must reject each
-    loudly (exit 2) instead of silently serving the one path there is."""
+    microbatch knob with the pipeline path, --sp with the ring-attention
+    prefill: argparse must reject each loudly (exit 2) instead of
+    silently serving the one path there is."""
     from ollamamq_tpu.cli import build_parser
 
     with pytest.raises(SystemExit) as exc:

@@ -51,7 +51,7 @@ GOLDEN = os.path.join(os.path.dirname(__file__), "data",
 def make_rt(**kw):
     defaults = dict(
         model="test-tiny", max_slots=4, num_pages=96, page_size=PS,
-        max_pages_per_seq=16, prefill_buckets=BUCKETS, max_new_tokens=8,
+        max_pages_per_seq=16, max_new_tokens=8,
         decode_steps_per_iter=2, max_batch_tokens=48, token_granule=8,
     )
     defaults.update(kw)

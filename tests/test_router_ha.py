@@ -43,7 +43,7 @@ from testutil import collect, free_port
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
-            max_pages_per_seq=8, prefill_buckets=(16, 32),
+            max_pages_per_seq=8,
             decode_steps_per_iter=2)
 
 FAST = dict(probe_period_s=0.05, eject_heartbeat_s=5.0,

@@ -329,6 +329,55 @@ def test_greedy_streams_byte_identical_across_policies():
     assert len(streams["fcfs"]) == 8
 
 
+@pytest.mark.parametrize("policy_name", SCHEDULERS)
+def test_bimodal_trace_on_a_real_runtime_changes_order_never_tokens(
+        policy_name):
+    """A long batch request queued AHEAD of a burst of short chat ones,
+    over a 2-slot REAL runtime: under every policy each stream runs
+    exactly its max_tokens (eos off — a shorter one was truncated in
+    silence) and the journal is invariant-clean, the anti-starvation
+    bound included. Under fcfs the long is seated first; srpt and edf
+    seat a short ahead of it — the order that wins p99 TTFT in
+    `simulate`, read here off the journal and not off a clock."""
+    ecfg = EngineConfig(model="test-tiny", max_slots=2, num_pages=256,
+                        page_size=8, max_pages_per_seq=16,
+                        decode_steps_per_iter=2, max_batch_tokens=128,
+                        token_granule=8, scheduler=policy_name)
+    rt = ModelRuntime("test-tiny", MODEL_CONFIGS["test-tiny"], ecfg,
+                      dtype=jnp.float32)
+    rt.tokenizer.eos_id = -1
+    rt.policy = make_policy(ecfg)
+    rt.journal = Journal(capacity=65536)
+    core = MQCore(None)
+    rng = random.Random(1234)
+    arrivals = [("batch0", 48, 48)] + [(f"chat{i % 4}", 8, 4)
+                                       for i in range(7)]
+    reqs = []
+    for user, n_prompt, max_tokens in arrivals:
+        req = Request(next(_IDS), user, "test-tiny",
+                      [rng.randrange(3, 500) for _ in range(n_prompt)],
+                      SamplingParams(max_tokens=max_tokens))
+        req._inc_decode = rt.tokenizer.make_incremental_decoder()
+        reqs.append(req)
+        rt.pending_prefill.append(req)
+    guard = 0
+    while not all(r.stats.finished_at for r in reqs):
+        rt.policy.on_admit_tick()
+        rt.step_ragged(core)
+        if any(r is not None for r in rt.slot_req):
+            rt.step_decode(core, k_steps=2)
+        guard += 1
+        assert guard < 4000, f"bimodal drive wedged ({policy_name})"
+    assert [len(r.generated_ids) for r in reqs] == \
+        [r.sampling.max_tokens for r in reqs]
+    recs = rt.journal.tail(None)
+    assert check_invariants(recs) == []
+    seated = [r["req_id"] for r in recs if r["kind"] == "install"]
+    assert sorted(seated) == sorted(r.req_id for r in reqs)
+    long_first = seated[0] == reqs[0].req_id
+    assert long_first == (policy_name == "fcfs"), seated
+
+
 # ---------------------------------------------------------- observability
 def test_engine_stats_and_tui_brief_carry_scheduler(tmp_path):
     from ollamamq_tpu.admin.tui import _engine_stats_brief

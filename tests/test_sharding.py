@@ -21,12 +21,13 @@ MAX_PAGES = 8
 def test_mesh_shapes():
     mesh = make_mesh(dp=2, tp=-1)
     assert mesh.shape["data"] == 2 and mesh.shape["tensor"] == 4
-    mesh = make_mesh(dp=1, sp=2, tp=4)
-    assert mesh.shape["seq"] == 2
+    mesh = make_mesh(dp=1, ep=2, tp=4)
+    assert mesh.axis_names == ("data", "expert", "tensor")
+    assert mesh.shape["expert"] == 2
 
 
 def test_multihost_dp_picks_devices_from_every_process():
-    """When k = dp*sp*tp < total devices, the multi-host dp mesh must take
+    """When k = dp*ep*tp < total devices, the multi-host dp mesh must take
     k/nproc devices FROM EACH process — devices[:k] of a process-major
     list would come entirely from the first host(s) (ADVICE r3)."""
     import pytest
@@ -111,7 +112,7 @@ def test_tp_over_kv_heads_replicated_groups():
     def cfg(tp):
         return EngineConfig(model="test-tiny-gqa", max_slots=2, num_pages=64,
                             page_size=8, max_pages_per_seq=16,
-                            prefill_buckets=(16, 32), max_new_tokens=6,
+                            max_new_tokens=6,
                             decode_steps_per_iter=2, tp=tp)
 
     def run(eng, user):

@@ -47,7 +47,7 @@ PS = 8
 def make_rt(spec, copy_weights=False, **kw):
     defaults = dict(
         model="test-tiny", max_slots=4, num_pages=256, page_size=PS,
-        max_pages_per_seq=32, prefill_buckets=(16, 64), max_new_tokens=96,
+        max_pages_per_seq=32, max_new_tokens=96,
         decode_steps_per_iter=2,
         max_batch_tokens=64, token_granule=8, spec=spec, spec_k=4,
         spec_min_accept=0.0,
@@ -492,3 +492,19 @@ def test_op_spec_payload_roundtrip():
     assert len(out) == len(values)
     for a, b in zip(values, out):
         assert np.array_equal(np.asarray(a), b)
+
+
+def test_wire_op_codes_keep_their_numbers_and_retired_ones_stay_free():
+    """The opcode is what a worker of another build reads first: taking
+    an op out (5 was the sequence-parallel prefill) must leave the others
+    where they were and its number refused, not handed to a new op."""
+    from ollamamq_tpu.engine import spmd
+
+    codes = {name: getattr(spmd, name) for name in dir(spmd)
+             if name.startswith("OP_")}
+    assert codes == {"OP_SHUTDOWN": 0, "OP_DECODE": 3, "OP_ENCODE": 4,
+                     "OP_RELOAD": 6, "OP_LOAD": 7, "OP_EVICT": 8,
+                     "OP_EMBED": 9, "OP_RAGGED": 10, "OP_SPEC": 11}
+    for retired in (1, 2, 5):
+        with pytest.raises(ValueError, match="no payload spec"):
+            spmd.payload_spec(retired, 64, 0, 4, 8, 16)

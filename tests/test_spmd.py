@@ -20,9 +20,9 @@ from ollamamq_tpu.config import MODEL_CONFIGS, EngineConfig
 from ollamamq_tpu.parallel.mesh import make_mesh
 import jax.numpy as jnp
 
-mesh = make_mesh(dp=1, sp=1, tp=2)
+mesh = make_mesh(dp=1, tp=2)
 ecfg = EngineConfig(model="test-tiny", max_slots=2, num_pages=32, page_size=8,
-                    max_pages_per_seq=8, prefill_buckets=(16,),
+                    max_pages_per_seq=8,
                     decode_steps_per_iter=2)
 mcfg = MODEL_CONFIGS["test-tiny"]
 
@@ -85,7 +85,7 @@ def test_spmd_two_process_serving(tmp_path):
 
     eng = TPUEngine(
         EngineConfig(model="test-tiny", max_slots=2, num_pages=32, page_size=8,
-                     max_pages_per_seq=8, prefill_buckets=(16,),
+                     max_pages_per_seq=8,
                      decode_steps_per_iter=2),
         models={"test-tiny": None, "test-tiny-embed": None},
         blocklist_path=None, dtype=jnp.float32,

@@ -20,9 +20,9 @@ from ollamamq_tpu.config import EngineConfig
 from ollamamq_tpu.parallel.mesh import make_mesh
 import jax.numpy as jnp
 
-mesh = make_mesh(dp=1, sp=1, tp=1, ep=2)  # half the experts per host
+mesh = make_mesh(dp=1, tp=1, ep=2)  # half the experts per host
 ecfg = EngineConfig(model="test-tiny-moe", max_slots=2, num_pages=32,
-                    page_size=8, max_pages_per_seq=8, prefill_buckets=(16,),
+                    page_size=8, max_pages_per_seq=8,
                     decode_steps_per_iter=2, ep=2)
 MODELS = {"test-tiny-moe": None}
 

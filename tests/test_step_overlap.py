@@ -34,7 +34,7 @@ from ollamamq_tpu.telemetry.stepprof import PROFILER
 from ollamamq_tpu.testing.faults import FaultPlan
 
 BASE = dict(max_slots=4, num_pages=96, page_size=8, max_pages_per_seq=16,
-            prefill_buckets=(16, 32, 64), max_batch_tokens=32,
+            max_batch_tokens=32,
             token_granule=8, decode_steps_per_iter=4)
 
 

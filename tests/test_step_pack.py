@@ -37,7 +37,6 @@ SHAPES = [
     ("ragged-T512-spec", lambda: step_pack.ragged_layout(512, S, MP, W)),
     ("decode-k1", lambda: step_pack.decode_layout(S, MP)),
     ("decode-k8", lambda: step_pack.decode_layout(S, MP)),
-    ("sp-T2048", lambda: step_pack.sp_layout(2048, MP)),
 ]
 
 # Words a float field must carry untouched: -0.0, the smallest and the
@@ -139,7 +138,7 @@ def test_a_launched_buffer_is_never_written_again(temperature):
     eng = TPUEngine(
         EngineConfig(model="test-tiny", max_slots=4, num_pages=96,
                      page_size=8, max_pages_per_seq=16,
-                     prefill_buckets=(16, 32, 64), max_batch_tokens=32,
+                     max_batch_tokens=32,
                      token_granule=8, decode_steps_per_iter=4),
         models={"test-tiny": None}, blocklist_path=None, dtype=jnp.float32)
     rt = eng.runtimes["test-tiny"]

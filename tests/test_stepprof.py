@@ -1,6 +1,5 @@
 """Engine performance plane (PR 20): the always-on step profiler,
-compile-ladder observability, HBM timeline, and the bench regression
-sentinel.
+compile-ladder observability and HBM timeline.
 
 Contracts pinned here:
 
@@ -17,15 +16,10 @@ Contracts pinned here:
     leaves NO partial sample and the decision journal stays clean;
   - profiler self-overhead stays under the 1% always-on budget;
   - the fleet router federates member `ollamamq_step_phase_ms` series
-    with a replica label;
-  - scripts/bench_compare.py classifies the checked-in wedged rounds
-    as init-failed (exit 0) and exits non-zero on a synthetic >= 20%
-    regression.
+    with a replica label.
 """
 
-import importlib.util
 import json
-import os
 import re
 import time
 
@@ -41,10 +35,9 @@ from ollamamq_tpu.telemetry.stepprof import (_COMPILE_RING, _HBM_RING,
 from ollamamq_tpu.testing.faults import FaultPlan
 from testutil import collect
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY = dict(model="test-tiny", max_slots=2, num_pages=64, page_size=8,
-            max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+            max_pages_per_seq=16,
             decode_steps_per_iter=2)
 
 
@@ -578,67 +571,3 @@ def test_window_slices_ring_by_capture_timestamps():
     assert len(inside) == 1 and inside[0]["mode"] == "fake"
     assert PROFILER.window(t_after + 10, t_after + 20) == []
     assert PROFILER.window(t_before - 20, t_before - 10) == []
-
-
-# -------------------------------------------------------- bench_compare CI
-def _load_bench_compare():
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare", os.path.join(_REPO, "scripts", "bench_compare.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_compare_flags_wedged_history_as_init_failed(tmp_path):
-    """ACCEPTANCE: a trajectory in which every round died at device init
-    — a crash before any record (rc 1, nothing parsed) and init-watchdog
-    error records (rc 3, phase "init"), the two shapes bench.py's init
-    path produces — classifies as init-failed: environment casualties,
-    NOT regressions, and the sentinel exits 0."""
-    mod = _load_bench_compare()
-    rounds = [{"n": 1, "cmd": "bench", "rc": 1, "parsed": None,
-               "tail": "RuntimeError: Unable to initialize backend 'tpu'"}]
-    rounds += [{"n": n, "cmd": "bench", "rc": 3, "tail": "", "parsed": {
-        "metric": "decode_tok_per_s_per_chip", "value": 0.0,
-        "error": "device/runtime init exceeded 300s", "phase": "init",
-        "platform": None, "device_kind": None, "device_count": 0}}
-        for n in range(2, 6)]
-    files = []
-    for rec in rounds:
-        path = tmp_path / f"BENCH_r{rec['n']:02d}.json"
-        path.write_text(json.dumps(rec))
-        files.append(str(path))
-    for path in files:
-        assert mod.classify(mod.load_round(path)) == "init-failed", path
-    assert mod.main(files) == 0
-
-
-def test_bench_compare_detects_synthetic_regressions(tmp_path):
-    def write(n, value, p99):
-        rec = {"n": n, "cmd": "bench", "rc": 0, "tail": "", "parsed": {
-            "metric": "decode_tok_per_s_per_chip", "value": value,
-            "step_profile": {"modes": {"decode": {
-                "step": {"n": 10, "p50_ms": p99 / 2, "p99_ms": p99}}}}}}
-        path = tmp_path / f"BENCH_r{n:02d}.json"
-        path.write_text(json.dumps(rec))
-        return str(path)
-
-    mod = _load_bench_compare()
-    # >= 20% tok/s drop => exit 2.
-    a, b = write(1, 1000.0, 10.0), write(2, 750.0, 10.0)
-    assert mod.main([a, b]) == 2
-    # Step-p99 blowup with flat tok/s => still a regression.
-    b2 = write(3, 990.0, 25.0)
-    assert mod.main([a, b2]) == 2
-    # Small drift under the threshold => clean exit.
-    b3 = write(4, 950.0, 10.5)
-    assert mod.main([a, b3]) == 0
-    # A wedged round interleaved in the trajectory is skipped, and the
-    # comparable neighbours still diff against each other.
-    wedged = tmp_path / "BENCH_r05.json"
-    wedged.write_text(json.dumps({
-        "n": 5, "cmd": "bench", "rc": 3, "tail": "", "parsed": {
-            "metric": "decode_tok_per_s_per_chip", "value": 0.0,
-            "error": "device/runtime init exceeded 300s", "phase": "init"}}))
-    assert mod.main([a, str(wedged)]) == 0
-    assert mod.main([a, str(wedged), b]) == 2

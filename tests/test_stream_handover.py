@@ -30,7 +30,7 @@ from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.telemetry.stepprof import PROFILER
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=96, page_size=8,
-            max_pages_per_seq=16, prefill_buckets=(16, 32, 64),
+            max_pages_per_seq=16,
             max_batch_tokens=32, token_granule=8, decode_steps_per_iter=8)
 
 REPL = "�"
@@ -222,8 +222,7 @@ class _Flag:
 
 
 def test_a_settled_step_of_64_rows_makes_one_call_soon_threadsafe():
-    eng = _engine(max_slots=64, num_pages=512, max_batch_tokens=512,
-                  prefill_buckets=(64, 256, 512))
+    eng = _engine(max_slots=64, num_pages=512, max_batch_tokens=512)
     loop, flags = _StubLoop(), {}
     waker = _LoopWaker(loop)
 

@@ -436,29 +436,6 @@ def test_debug_profile_failure_does_not_wedge():
     _serve(run)
 
 
-def test_bench_without_tpu_is_a_structured_error():
-    """bench.py is a measurement path: without --cpu and without a TPU it
-    prints ONE structured error record naming the platform, device kind
-    and device count it found, and exits non-zero — it never falls back
-    to a CPU run under a device metric's name."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py")],
-        capture_output=True, text=True, timeout=120, cwd=root,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert proc.returncode == 3, proc.stderr[-400:]
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["error"].startswith("no TPU")
-    assert rec["value"] == 0.0 and rec["phase"] == "init"
-    assert (rec["platform"], rec["device_kind"]) == ("cpu", "cpu")
-    assert rec["device_count"] >= 1
-
-
 def test_trace_ring_flag_bounds_engine_ring():
     from ollamamq_tpu.engine.fake import FakeEngine
 

@@ -278,7 +278,6 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
 
     eng = TPUEngine(EngineConfig(model=model, max_slots=2, num_pages=64,
                                  page_size=8, max_pages_per_seq=16,
-                                 prefill_buckets=(16, 32, 64),
                                  decode_steps_per_iter=2),
                     models={model: None}, blocklist_path=None,
                     dtype=jnp.float32)
@@ -336,8 +335,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     table = readme[readme.index("<!-- stepprof-spans:begin -->"):
                    readme.index("<!-- stepprof-spans:end -->")]
     documented = set(re.findall(r"`([a-z_.]+)`", table))
-    jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_prefill_sp",
-                 "mq_embed", "mq_encode"}
+    jit_names = {"mq_ragged_step", "mq_decode_scan", "mq_embed", "mq_encode"}
     from ollamamq_tpu.ops import mla
 
     assert set(llama.SCOPES) | set(llama.CONV_SCOPES) | set(moe.SCOPES) \

@@ -83,7 +83,7 @@ def single_device_greedy_tokens(model, prompt, max_tokens=6, **ecfg_kw):
     from ollamamq_tpu.ops.sampling import SamplingParams
 
     defaults = dict(model=model, max_slots=2, num_pages=32, page_size=8,
-                    max_pages_per_seq=8, prefill_buckets=(16,),
+                    max_pages_per_seq=8,
                     decode_steps_per_iter=2)
     defaults.update(ecfg_kw)
     eng = TPUEngine(EngineConfig(**defaults), models={model: None},
