@@ -7,8 +7,10 @@ renormalised) and OLMoE (top-8 of 64, not renormalised, MHA, whole-vector
 q/k norm) and the hybrids LFM2 (gated short convolutions beside attention,
 a dense prefix, a sigmoid router with a selection bias) and Olmo-Hybrid
 (gated delta-rule linear attention beside attention, norms on the
-sublayers' outputs, no rotary embedding), plus a bidirectional encoder
-config for embedding models
+sublayers' outputs, no rotary embedding) and Qwen3-Next (the rule with
+fewer key heads than value heads beside gated attention with a partial
+rotary embedding, zero-centred norms, experts behind a gated shared
+expert), plus a bidirectional encoder config for embedding models
 (nomic-embed-text class). The dense names are the ones the reference's
 stress test exercises (/root/reference/test_dispatcher.sh:5-7) and
 BASELINE.json's configs list.
@@ -123,6 +125,24 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False
+    # -- Qwen3-Next's attention, norms and shared expert ---------------------
+    # RoPE over the FIRST `head_dim * partial_rotary_factor` lanes of a
+    # head of q and k (an even number of them); the others pass unrotated.
+    # The published spelling.
+    partial_rotary_factor: float = 1.0
+    # `wq_gate` gives a gate a head beside q (the published `q_proj` holds
+    # both): attn = attn * sigmoid(gate) before `wo`. This repo's naming.
+    attn_output_gate: bool = False
+    # The block norms, the final norm and the per-head q/k norms multiply
+    # by (1 + w), in float32: a stored weight of zero is the identity. The
+    # rule's gated output norm (`lin_norm`) keeps the plain weight. This
+    # repo's naming.
+    zero_centred_norm: bool = False
+    # The shared expert's width where it is not the routed experts' (the
+    # published spelling; with it `n_shared_experts` stays 0), and whether
+    # its output is multiplied by sigmoid(x w_sg) (this repo's naming).
+    shared_expert_intermediate_size: int = 0
+    shared_expert_gate: bool = False
     # "pre": x + Op(norm(x)) (Llama's). "post": x + norm(Op(x)) (OLMo 2's
     # reordered norm: `attn_norm` / `mlp_norm` weigh the sublayers' OUTPUTS).
     norm_order: str = "pre"
@@ -183,9 +203,17 @@ class ModelConfig:
     # `num_dense_layers` (either or both, agreeing); every layer after the
     # dense ones has experts (`moe_layer_freq` 1); `ep_size` is the published
     # file's own (1: the share held here is said by the two fields above);
+    # Qwen3-Next's: every layer has experts (`decoder_sparse_step` 1,
+    # `mlp_only_layers` empty), no sliding window, and
+    # `full_attention_interval` n says what `layer_types` must: attention
+    # in each n-th layer, counted from 1, linear attention in the others.
     first_k_dense_replace: Optional[int] = None
     moe_layer_freq: int = 1
     ep_size: int = 1
+    decoder_sparse_step: int = 1
+    mlp_only_layers: tuple = ()
+    use_sliding_window: bool = False
+    full_attention_interval: Optional[int] = None
     # The multi-token-prediction module (DeepSeek-V3's formulation, depth 1):
     # from the trunk's last hidden of position i (before the final norm) and
     # the embedding of token i + 1, through two norms, a projection of their
@@ -251,7 +279,12 @@ class ModelConfig:
                     f"{self.num_dense_layers}")
             object.__setattr__(self, "num_dense_layers",
                                self.first_k_dense_replace)
-        for key, only in (("moe_layer_freq", 1), ("ep_size", 1)):
+        object.__setattr__(self, "mlp_only_layers",
+                           tuple(self.mlp_only_layers))  # a file's list
+        for key, only in (("moe_layer_freq", 1), ("ep_size", 1),
+                          ("decoder_sparse_step", 1),
+                          ("mlp_only_layers", ()),
+                          ("use_sliding_window", False)):
             if getattr(self, key) != only:
                 raise ValueError(
                     f"{self.name}: {key} {getattr(self, key)}: the program "
@@ -273,6 +306,7 @@ class ModelConfig:
                                tuple(sorted(group.items())))
         self._check_latent()
         self._check_share()
+        self._check_gated()
         self._check_sandwich_and_module()
         if not 0 <= self.num_dense_layers <= self.num_layers:
             raise ValueError(
@@ -367,6 +401,52 @@ class ModelConfig:
                 f"{self.name}: expert_offset {self.expert_offset}: the "
                 f"{E} experts held here are not within the router's {R}")
 
+    def _check_gated(self) -> None:
+        """The partial rotary embedding, the attention output gate, the
+        shared expert's own width and gate, `full_attention_interval`."""
+        rot = self.head_dim * self.partial_rotary_factor
+        if not 0 < self.partial_rotary_factor <= 1 or rot != int(rot) \
+                or int(rot) % 2:
+            raise ValueError(
+                f"{self.name}: partial_rotary_factor "
+                f"{self.partial_rotary_factor} of head_dim {self.head_dim} "
+                "is not an even number of lanes")
+        if self.kv_lora_rank and (self.partial_rotary_factor != 1
+                                  or self.attn_output_gate
+                                  or self.zero_centred_norm):
+            raise ValueError(
+                f"{self.name}: partial_rotary_factor, attn_output_gate and "
+                "zero_centred_norm are not served with latent attention "
+                "(kv_lora_rank)")
+        if self.attn_output_gate and self.attn_bias:
+            raise ValueError(
+                f"{self.name}: attn_output_gate with attn_bias: the gate's "
+                "projection carries no bias")
+        if (self.shared_expert_intermediate_size
+                or self.shared_expert_gate) and not self.num_experts:
+            raise ValueError(
+                f"{self.name}: shared_expert_intermediate_size and "
+                "shared_expert_gate belong to an expert layer: num_experts "
+                "is 0")
+        if self.shared_expert_intermediate_size and self.n_shared_experts:
+            raise ValueError(
+                f"{self.name}: shared_expert_intermediate_size "
+                f"{self.shared_expert_intermediate_size} AND "
+                f"n_shared_experts {self.n_shared_experts}: the shared "
+                "expert has one width")
+        if self.shared_expert_gate and not self.shared_width:
+            raise ValueError(
+                f"{self.name}: shared_expert_gate with no shared expert")
+        n = self.full_attention_interval
+        if n is not None:
+            want = tuple(ATTENTION if (i + 1) % n == 0 else LINEAR
+                         for i in range(self.num_layers)) if n >= 1 else None
+            if self.layer_types != want:
+                raise ValueError(
+                    f"{self.name}: full_attention_interval {n} does not "
+                    "agree with layer_types (attention in each n-th layer, "
+                    "linear_attention in the others)")
+
     def _check_linear(self) -> None:
         """What the linear-attention layers cannot run with, key and value
         in the message."""
@@ -378,11 +458,11 @@ class ModelConfig:
                 f"linear_num_key_heads ({hk}), linear_key_head_dim "
                 f"({self.linear_key_head_dim}) and linear_value_head_dim "
                 f"({self.linear_value_head_dim}) of at least 1")
-        if hv != hk:
+        if hv < hk or hv % hk:
             raise ValueError(
-                f"{self.name}: linear_num_value_heads {hv} is not "
-                f"linear_num_key_heads {hk}: the program's rule has one "
-                "key head a value head")
+                f"{self.name}: linear_num_value_heads {hv} is not a "
+                f"multiple of linear_num_key_heads {hk}: a key head serves "
+                "a whole number of value heads")
         if self.linear_conv_kernel_dim < 2:
             raise ValueError(
                 f"{self.name}: linear_conv_kernel_dim must be at least 2, "
@@ -406,6 +486,17 @@ class ModelConfig:
     @property
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the SwiGLU every token passes (0: no shared expert)."""
+        return self.shared_expert_intermediate_size \
+            or self.n_shared_experts * self.expert_width
+
+    @property
+    def rotary_dim(self) -> int:
+        """Lanes of a head that RoPE rotates: the first ones."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def router_width(self) -> int:
@@ -520,7 +611,8 @@ class ModelConfig:
         the parameters a token touches (the routed experts of the k, not
         the bank: what the FLOPs model counts)."""
         d, v = self.hidden_size, self.vocab_size
-        attention = (d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        attention = ((1 + self.attn_output_gate) * d * self.q_dim
+                     + 2 * d * self.kv_dim + self.q_dim * d
                      + self.qk_norm_params())
         if self.kv_lora_rank:
             H, r, c = self.num_heads, self.q_lora_rank, self.kv_lora_rank
@@ -548,8 +640,9 @@ class ModelConfig:
         n_experts = self.num_experts_per_tok if active else self.num_experts
         per_ffn = {
             DENSE: 3 * d * self.intermediate_size,
-            EXPERTS: ((n_experts + self.n_shared_experts) * 3 * d
-                      * self.expert_width + d * self.router_width
+            EXPERTS: (n_experts * 3 * d * self.expert_width
+                      + 3 * d * self.shared_width
+                      + d * self.shared_expert_gate + d * self.router_width
                       + self.router_width * self.use_expert_bias),
         }
         norms = (4 if self.sandwich_norm else 2) * d
@@ -736,6 +829,44 @@ MODEL_CONFIGS = {
         linear_allow_neg_eigval=True,
         layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2
         + ("linear_attention",) * 2,
+    ),
+    # Qwen3-Next family (Qwen/Qwen3-Next-80B-A3B-Instruct config.json):
+    # three gated delta-rule layers (16 key heads under 32 value heads of
+    # 128: a [128, 128] float32 state a value head a sequence; beta in (0,
+    # 1)) to one GATED attention layer (16/2 heads of 256, RoPE on the first
+    # 64 lanes, a sigmoid gate a head on the output); zero-centred norms;
+    # every FFN 512 experts of width 512, softmax top 10 renormalised, plus
+    # a shared expert of width 512 times sigmoid(x w_sg); untied head.
+    "qwen3-next:80b-a3b": ModelConfig(
+        name="qwen3-next:80b-a3b", vocab_size=151_936, hidden_size=2048,
+        intermediate_size=5120, num_layers=48, num_heads=16, num_kv_heads=2,
+        head_dim=256, rope_theta=10_000_000.0, rms_norm_eps=1e-6,
+        max_seq_len=262_144, qk_norm="head", partial_rotary_factor=0.25,
+        attn_output_gate=True, zero_centred_norm=True,
+        linear_num_key_heads=16, linear_num_value_heads=32,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4, full_attention_interval=4,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 12,
+        num_experts=512, num_experts_per_tok=10, norm_topk_prob=True,
+        moe_intermediate_size=512, shared_expert_intermediate_size=512,
+        shared_expert_gate=True,
+    ),
+    # Tiny Qwen3-Next: two periods, 2 key heads under 4 value heads, RoPE on
+    # 8 of 16 lanes, one share (8 held of the router's 16) of the experts,
+    # a shared expert of its own width.
+    "test-tiny-qwen3-next": ModelConfig(
+        name="test-tiny-qwen3-next", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=2,
+        head_dim=16, rope_theta=10_000.0, rms_norm_eps=1e-6, max_seq_len=512,
+        qk_norm="head", partial_rotary_factor=0.5, attn_output_gate=True,
+        zero_centred_norm=True, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=8,
+        linear_value_head_dim=16, linear_conv_kernel_dim=4,
+        full_attention_interval=4,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2,
+        num_experts=8, router_experts=16, expert_offset=0,
+        num_experts_per_tok=4, norm_topk_prob=True, moe_intermediate_size=32,
+        shared_expert_intermediate_size=48, shared_expert_gate=True,
     ),
     # Tiny DeepSeek-V3.2: latent attention with the indexer's selection (top
     # 16: well under the tests' contexts), YaRN, a dense layer then expert

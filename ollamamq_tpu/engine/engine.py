@@ -487,6 +487,8 @@ class ModelRuntime:
     # ...and for latent attention with no indexer (nothing is scored or
     # selected: the `dsa_*` fields have no honest value there).
     DENSE_LATENT_FIELDS = ("mla_rows", "mla_pairs", "mla_ctx_rows")
+    # What `_note_attn` writes (plain K/V attention: no latent pool).
+    ATTN_FIELDS = ("attn_pairs", "attn_ctx_rows")
 
     # Engine performance plane (telemetry/stepprof.py): the per-step
     # "paid a compile" flag (_sp_note_compile sets, the step's finish
@@ -914,6 +916,8 @@ class ModelRuntime:
         self._tm_dsa = [c.labels(model=name) for c in (
             tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
             tm.DSA_SELECTED_TOKENS_TOTAL)]
+        self._tm_attn = [c.labels(model=name) for c in (
+            tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL)]
 
     # -- capacity ----------------------------------------------------------
     def free_slots(self) -> int:
@@ -1347,6 +1351,28 @@ class ModelRuntime:
         _sp.note(**dict(zip(self.LATENT_FIELDS, counts.tolist())))
         for series, n in zip(self._tm_dsa, counts[:3].tolist()):
             series.inc(n)
+
+    def _note_attn(self, _sp, tokens, kv, scan: bool = False) -> None:
+        """A launched step's plain (K and V pages, non-latent) attention,
+        onto its sample and the /metrics series, from its composition alone
+        (as `_note_latent` counts the latent kernels' work): `tokens` and
+        `kv` are each row's span length and its context at the span's end —
+        a ragged step's spans, or with `scan` a fused scan's active slots
+        with its passes as tokens. `attn_pairs` the causal (query token,
+        cached position) pairs — a token at position p attends p + 1 — and
+        `attn_ctx_rows` the cached rows the walks have to read at the
+        least: each span's context once (a scan's pass: each slot's). A
+        layer's worth: every attention layer does the same. Nothing for an
+        encoder, a model with latent attention or one with no attention
+        layer."""
+        if self.cfg.kv_lora_rank or not self.cfg.count(ATTENTION):
+            return
+        n, kv = np.asarray(tokens, np.int64), np.asarray(kv, np.int64)
+        pairs = n * (2 * kv - n + 1) // 2  # sum of kv-n+1 .. kv
+        counts = (int(pairs.sum()), int((pairs if scan else kv).sum()))
+        _sp.note(**dict(zip(self.ATTN_FIELDS, counts)))
+        for series, c in zip(self._tm_attn, counts):
+            series.inc(c)
 
     def _dispatch_decode(self, k_steps, buf):
         """`buf`: the scan's packed host inputs (step_pack.decode_layout)."""
@@ -2765,6 +2791,7 @@ class ModelRuntime:
                               sum(n == 1 for n in spans),
                               sum(n for n in spans if n > 1))
         self._note_latent(_sp, zip(spans, row_kv))
+        self._note_attn(_sp, spans, row_kv)
         _sp.mark("dispatch")
         _sp.park()
 
@@ -2982,6 +3009,8 @@ class ModelRuntime:
                               len(active) * int(k_steps), 0)
         self._note_latent(_sp, [(int(k_steps), int(self.seq_lens[i])
                                  + int(k_steps)) for i in active], scan=True)
+        self._note_attn(_sp, [int(k_steps)] * len(active),
+                        self.seq_lens[active] + int(k_steps), scan=True)
         _sp.mark("dispatch")
         _sp.park()
         for i in active:

@@ -1,5 +1,5 @@
-"""Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2 / Olmo-Hybrid) in
-pure functional JAX.
+"""Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2 / Olmo-Hybrid /
+Qwen3-Next) in pure functional JAX.
 
 A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))` — or, with
 `norm_order` "post", `x + norm(Op(x))`, `x + norm(FFN(x))`, or with
@@ -52,8 +52,8 @@ import jax.numpy as jnp
 
 from ollamamq_tpu.config import (ATTENTION, CONV, DENSE, EXPERTS, LINEAR,
                                  ModelConfig)
-from ollamamq_tpu.models.moe import (SHARED, STACKED, init_moe_layer_params,
-                                     moe_mlp)
+from ollamamq_tpu.models.moe import (SHARED, SHARED_GATE, STACKED,
+                                     init_moe_layer_params, moe_mlp)
 from ollamamq_tpu.ops import gated_delta, mla, shortconv
 from ollamamq_tpu.ops.attention import (
     causal_attention,
@@ -72,6 +72,9 @@ from ollamamq_tpu.ops.rope import (apply_rope, apply_rope_freqs, rope_freqs,
 # decode programs. An MoE model's "mlp" holds models/moe.py:SCOPES.
 SCOPES = ("embed", "attn_qkv", "kv_write", "attention", "attn_out", "mlp",
           "lm_head", "sampling")
+# ...and, inside "attn_out", where a model with `attn_output_gate` multiplies
+# the attended values by its sigmoid gate.
+GATE_SCOPES = ("attn_gate",)
 # ...and the prediction module's three (`forward_mtp`): the two norms and
 # the projection of [embedding | hidden], its block (which holds a layer's
 # own scopes), its norm and the trunk's head.
@@ -90,6 +93,11 @@ LINEAR_SCOPES = ("lin_in", "lin_conv", "lin_rule", "lin_out")
 CONV_KEY = 0x636F6E76
 LINEAR_KEY = 0x6C696E72
 MLA_KEY = 0x6D6C6174
+GATED_KEY = 0x67617465
+# Standard deviation of a seeded-random ZERO-CENTRED norm weight (float32
+# draw, as moe.ROUTER_BIAS_SD): a stored zero is the identity whether a
+# forward multiplies by w or by 1 + w, so the weights are drawn away from it.
+ZERO_CENTRED_NORM_SD = 0.1
 # A latent-attention layer's weights (config.py: `kv_lora_rank`), beside
 # `wo`: q down, its norm, q up; kv down to [c_kv | k_rope], c_kv's norm,
 # kv up to a head's [k_nope | v]; the indexer's q (from the normed q
@@ -107,13 +115,13 @@ LINEAR_A_RANGE, LINEAR_DT_RANGE = (1.0, 16.0), (1e-3, 1e-1)
 # The weights of each operator and FFN kind (stacked over the layers of
 # that kind); every other entry of `layers` is stacked over all layers.
 KIND_PARAMS = {
-    ATTENTION: ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm",
-                "k_norm") + MLA_PARAMS,
+    ATTENTION: ("wq", "wq_gate", "wk", "wv", "wo", "bq", "bk", "bv",
+                "q_norm", "k_norm") + MLA_PARAMS,
     CONV: ("conv_in", "conv_w", "conv_out"),
     LINEAR: ("lin_in", "lin_ba", "lin_conv_w", "lin_A_log", "lin_dt_bias",
              "lin_norm", "lin_out"),
     DENSE: ("w_gate", "w_up", "w_down"),
-    EXPERTS: ("w_router", "router_bias") + SHARED + STACKED,
+    EXPERTS: ("w_router", "router_bias") + SHARED + SHARED_GATE + STACKED,
 }
 
 
@@ -124,10 +132,22 @@ def _adtype(params: dict):
     return params["final_norm"].dtype
 
 
-def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
+def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float,
+            zero_centred: bool = False) -> jnp.ndarray:
+    """x / rms(x) times w — or, `zero_centred` (Qwen3-Next's), times
+    (1 + w) with the product in float32."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    if zero_centred:
+        return (xf * jax.lax.rsqrt(var + eps)
+                * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w
+
+
+def _norm(cfg: ModelConfig, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """The stack's RMSNorm (the block norms, the final norm, the per-head
+    q/k norms): plain or zero-centred, by the config."""
+    return rmsnorm(x, w, cfg.rms_norm_eps, cfg.zero_centred_norm)
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
@@ -146,11 +166,21 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     def w(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dtype)
 
-    layers = {"attn_norm": jnp.ones((L, d), dtype),
-              "mlp_norm": jnp.ones((L, d), dtype)}
+    gk = iter(jax.random.split(jax.random.fold_in(key, GATED_KEY), 8))
+
+    def norm_w(shape):
+        """A norm weight of the stack: one — or, zero-centred, drawn around
+        zero, so that a forward which reads w for 1 + w computes another
+        model."""
+        if not cfg.zero_centred_norm:
+            return jnp.ones(shape, dtype)
+        return (ZERO_CENTRED_NORM_SD * jax.random.normal(
+            next(gk), shape, jnp.float32)).astype(dtype)
+
+    layers = {"attn_norm": norm_w((L, d)), "mlp_norm": norm_w((L, d))}
     if cfg.sandwich_norm:
-        layers.update(post_attn_norm=jnp.ones((L, d), dtype),
-                      post_mlp_norm=jnp.ones((L, d), dtype))
+        layers.update(post_attn_norm=norm_w((L, d)),
+                      post_mlp_norm=norm_w((L, d)))
     if La and cfg.kv_lora_rank:
         mk = jax.random.split(jax.random.fold_in(key, MLA_KEY), 8)
         H, r, c = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
@@ -176,14 +206,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         layers.update(
             wq=w(keys[0], (La, d, qd), d), wk=w(keys[1], (La, d, kvd), d),
             wv=w(keys[2], (La, d, kvd), d), wo=w(keys[3], (La, qd, d), qd))
+        if cfg.attn_output_gate:  # a gate a head, beside q
+            layers["wq_gate"] = w(next(gk), (La, d, qd), d)
         if cfg.attn_bias:
             layers["bq"] = jnp.zeros((La, qd), dtype)
             layers["bk"] = jnp.zeros((La, kvd), dtype)
             layers["bv"] = jnp.zeros((La, kvd), dtype)
         if cfg.qk_norm_kind == "head":
             # Qwen3, LFM2: per-head RMSNorm on q/k (weight over head_dim).
-            layers["q_norm"] = jnp.ones((La, cfg.head_dim), dtype)
-            layers["k_norm"] = jnp.ones((La, cfg.head_dim), dtype)
+            layers["q_norm"] = norm_w((La, cfg.head_dim))
+            layers["k_norm"] = norm_w((La, cfg.head_dim))
         elif cfg.qk_norm_kind == "full":
             # OLMoE: RMSNorm over the whole projected q / k vector.
             layers["q_norm"] = jnp.ones((La, qd), dtype)
@@ -226,7 +258,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         layers.update(init_moe_layer_params(cfg, keys[9], dtype))
     params = {
         "embed": w(keys[7], (v, d), d),
-        "final_norm": jnp.ones((d,), dtype),
+        "final_norm": norm_w((d,)),
         "layers": layers,
     }
     if not cfg.tie_embeddings and not cfg.is_encoder:
@@ -255,14 +287,14 @@ def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     if cfg.qk_norm_kind == "full":
         # Over all heads' lanes at once, BEFORE the split into heads (under
         # tp the lanes are sharded: GSPMD reduces the mean across shards).
-        q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
+        q = _norm(cfg, q, lp["q_norm"])
+        k = _norm(cfg, k, lp["k_norm"])
     q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
     k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     if cfg.qk_norm_kind == "head":
-        q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
+        q = _norm(cfg, q, lp["q_norm"])
+        k = _norm(cfg, k, lp["k_norm"])
     return q, k, v
 
 
@@ -292,7 +324,7 @@ def _ffn(cfg: ModelConfig, lp: dict, ffn: str, h: jnp.ndarray, valid=None,
 
 @jax.named_scope("lm_head")
 def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = _norm(cfg, x, params["final_norm"])
     head = params.get("lm_head", params["embed"])
     return logits_head(x, head)
 
@@ -409,18 +441,27 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
 def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
                   positions: jnp.ndarray, attn_fn) -> jnp.ndarray:
     """Attention over normed hiddens h [B, T, D]: projections, q/k norm
-    and RoPE here; the schedule (and any write of k, v into a pool) is the
-    caller's `attn_fn(q, k, v) -> [B, T, H, hd]`."""
+    and RoPE (over a head's first `rotary_dim` lanes) here; the schedule
+    (and any write of k, v into a pool) is the caller's `attn_fn(q, k, v)
+    -> [B, T, H, hd]`. With `attn_output_gate` the attended values are
+    multiplied by sigmoid(h W_gate), a lane each, before `wo`."""
     B, T, _ = h.shape
+    gate = None
     with jax.named_scope("attn_qkv"):
         q, k, v = _qkv(cfg, lp, h)
+        if cfg.attn_output_gate:
+            gate = qeinsum("btd,de->bte", h, lp["wq_gate"])
         if cfg.rope_theta is not None:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
     attn = attn_fn(q, k, v)
     with jax.named_scope("attn_out"):
-        return qeinsum("bte,ed->btd", attn.reshape(B, T, cfg.q_dim),
-                       lp["wo"])
+        attn = attn.reshape(B, T, cfg.q_dim)
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(h.dtype)
+        return qeinsum("bte,ed->btd", attn, lp["wo"])
 
 
 def _latent_rope(cfg: ModelConfig, x: jnp.ndarray, positions) -> jnp.ndarray:
@@ -559,14 +600,15 @@ def _linear_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     """Gated delta-rule linear attention over hiddens h [B, T, D]:
     [q | k | v | z] = h W_in and the gates b, a = h W_ba (float32); q | k |
     v through the depthwise causal convolution and a SiLU; per head the
-    rule (ops/gated_delta.py: q, k L2-normalised there, the decay from a,
-    the write strength from b); y = (RMSNorm(o) * silu(z)) W_out. Where the
+    rule (ops/gated_delta.py: q, k L2-normalised there and, where the key
+    heads are fewer, repeated a value head; the decay from a, the write
+    strength from b); y = (RMSNorm(o) * silu(z)) W_out. Where the
     convolution's predecessors come from is the caller's `taps_fn` (as a
-    conv layer's), which state the rule continues its `rule_fn(q, k, v, g,
-    beta) -> o [B, T, H, dv] float32`."""
+    conv layer's), which state the rule continues its `rule_fn(q [B, T,
+    Hk, dk], k, v [B, T, H, dv], g, beta) -> o [B, T, H, dv] float32`."""
     B, T, _ = h.shape
-    H, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
-                 cfg.linear_value_head_dim)
+    Hk, H, dk, dv = (cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+                     cfg.linear_key_head_dim, cfg.linear_value_head_dim)
     kd, cd = cfg.linear_key_dim, cfg.linear_conv_dim
     with jax.named_scope("lin_in"):
         u = qeinsum("btd,de->bte", h, lp["lin_in"])
@@ -580,8 +622,8 @@ def _linear_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
         c = jax.nn.silu(shortconv.short_conv(lp["lin_conv_w"], taps_fn(qkv),
                                              qkv))
     with jax.named_scope("lin_rule"):
-        o = rule_fn(c[..., :kd].reshape(B, T, H, dk),
-                    c[..., kd:2 * kd].reshape(B, T, H, dk),
+        o = rule_fn(c[..., :kd].reshape(B, T, Hk, dk),
+                    c[..., kd:2 * kd].reshape(B, T, Hk, dk),
                     c[..., 2 * kd:].reshape(B, T, H, dv), g, beta)
     with jax.named_scope("lin_out"):
         o = rmsnorm(o, lp["lin_norm"], cfg.rms_norm_eps).reshape(B, T, H * dv)
@@ -605,7 +647,7 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
     pre = cfg.norm_order == "pre"  # else the norms weigh the OUTPUTS
 
     def norm(y, name):
-        return rmsnorm(y, lp[name], cfg.rms_norm_eps)
+        return _norm(cfg, y, lp[name])
 
     h = norm(x, "attn_norm") if pre else x
     if op == CONV:
@@ -1016,7 +1058,7 @@ def forward_embed(
             valid=valid, layer=ix.ffn, **_no_state(cfg, valid))
 
     x, _ = scan_layers(cfg, body, x, params["layers"])
-    x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps).astype(jnp.float32)
+    x = _norm(cfg, x, params["final_norm"]).astype(jnp.float32)
     mask = (positions < seq_lens[:, None]).astype(jnp.float32)[:, :, None]
     pooled = jnp.sum(x * mask, axis=1) / jnp.maximum(jnp.sum(mask, axis=1), 1.0)
     return pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
@@ -1040,7 +1082,7 @@ def forward_encoder(
             valid=positions < seq_lens[:, None], layer=ix.ffn)
 
     x, _ = scan_layers(cfg, body, x, params["layers"])
-    x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps).astype(jnp.float32)
+    x = _norm(cfg, x, params["final_norm"]).astype(jnp.float32)
     mask = (positions < seq_lens[:, None]).astype(jnp.float32)[:, :, None]
     pooled = jnp.sum(x * mask, axis=1) / jnp.maximum(jnp.sum(mask, axis=1), 1.0)
     return pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)

@@ -1,4 +1,5 @@
-"""Mixture-of-experts FFN (Mixtral, OLMoE, LFM2) with expert parallelism.
+"""Mixture-of-experts FFN (Mixtral, OLMoE, LFM2, DeepSeek-V3.2, openPangu,
+Qwen3-Next) with expert parallelism.
 
 The reference serves MoE models only by proxying to an Ollama backend that
 happens to run one (llama.cpp does the routing on CPU/GPU); it has no
@@ -27,8 +28,11 @@ ONE dispatch for every model of it:
     its 512-row tiles make the same pass compute-bound, 2.3 x slower on a
     v5e; PERF.md section 6, PR 27).
   - `n_group` / `topk_group` limit the selection to the best groups of
-    experts (DeepSeek-V3's), `n_shared_experts` adds a dense SwiGLU every
-    token passes (scope `moe_shared`), and `router_experts` /
+    experts (DeepSeek-V3's), `n_shared_experts` (or, of a width of its
+    own, `shared_expert_intermediate_size`) adds a dense SwiGLU every
+    token passes (scope `moe_shared`), which `shared_expert_gate`
+    multiplies by sigmoid(x w_sg) (Qwen3-Next's; scope `moe_shared_gate`
+    inside it), and `router_experts` /
     `expert_offset` make the layer ONE CHIP'S SHARE of a wider one: the
     router scores all the published experts and normalises over all it
     chose, this program holds `num_experts` of them and adds what those
@@ -70,6 +74,8 @@ from ollamamq_tpu.parallel.mesh import AXIS_EXPERT, AXIS_TENSOR
 SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 # ...and the shared experts' stage, for a model that has them.
 SHARED_SCOPES = ("moe_shared",)
+# ...and, inside it, the shared expert's sigmoid gate, for a model with one.
+SHARED_GATE_SCOPES = ("moe_shared_gate",)
 # Layer params the layer loop reads whole, by layer index.
 STACKED = ("we_gate", "we_up", "we_down")
 # What load_stats() returns, in order (int32 each).
@@ -86,6 +92,8 @@ ROUTER_BIAS_SD = 0.1
 # layer like the router) and the fold_in constant of their init keys.
 SHARED = ("ws_gate", "ws_up", "ws_down")
 SHARED_KEY = 0x73686172
+# The shared expert's gate w_sg [L, D]: its output times sigmoid(x . w_sg).
+SHARED_GATE = ("w_shared_gate",)
 
 
 def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
@@ -115,11 +123,17 @@ def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     if cfg.use_expert_bias:
         out["router_bias"] = ROUTER_BIAS_SD * jax.random.normal(
             keys[4], (L, R), jnp.float32)
-    if cfg.n_shared_experts:
-        sk = jax.random.split(jax.random.fold_in(key, SHARED_KEY), 3)
-        fs = cfg.n_shared_experts * f
+    fs = cfg.shared_width
+    if fs:
+        # (One more key only where there is a gate, as for the bias.)
+        sk = jax.random.split(jax.random.fold_in(key, SHARED_KEY),
+                              3 + cfg.shared_expert_gate)
         out.update(ws_gate=w(sk[0], (L, d, fs), d), ws_up=w(sk[1], (L, d, fs), d),
                    ws_down=w(sk[2], (L, fs, d), fs))
+        if cfg.shared_expert_gate:
+            # x . w_sg ~ N(0, 1): the gate spreads over (0, 1), so a
+            # forward that drops it computes another model.
+            out["w_shared_gate"] = w(sk[3], (L, d), d)
     return out
 
 
@@ -284,12 +298,19 @@ def moe_mlp(cfg: ModelConfig, lp: dict, h: jnp.ndarray, valid=None,
         y = jnp.where((experts < E)[..., None], y, 0)  # unowned rows: anything
         out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
     out = out.astype(h.dtype).reshape(B, T, D)
-    if cfg.n_shared_experts:
+    if cfg.shared_width:
         with jax.named_scope("moe_shared"):
             gate = qeinsum("btd,df->btf", h, lp["ws_gate"])
             up = qeinsum("btd,df->btf", h, lp["ws_up"])
-            out = out + qeinsum("btf,fd->btd", jax.nn.silu(gate) * up,
-                                lp["ws_down"])
+            shared = qeinsum("btf,fd->btd", jax.nn.silu(gate) * up,
+                             lp["ws_down"])
+            if cfg.shared_expert_gate:
+                with jax.named_scope("moe_shared_gate"):
+                    sg = jax.nn.sigmoid(jnp.einsum(
+                        "btd,d->bt", h, lp["w_shared_gate"],
+                        preferred_element_type=jnp.float32))
+                    shared = (shared * sg[..., None]).astype(h.dtype)
+            out = out + shared
     return out, load
 
 

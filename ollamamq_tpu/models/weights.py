@@ -1,8 +1,8 @@
 """Weight initialization and checkpoint loading.
 
 Checkpoints load from either:
-  - a safetensors directory in the HF layout (Llama/Qwen2, Mixtral, OLMoE
-    and — assumed, see _HF_LAYER_MAP — LFM2 tensor names), or
+  - a safetensors directory in the HF layout (Llama/Qwen2, Mixtral, OLMoE,
+    Qwen3-Next and — assumed, see _HF_LAYER_MAP — LFM2 tensor names), or
   - an orbax checkpoint previously saved by `save_orbax`.
 
 Weights land directly in their mesh sharding (each host/device only
@@ -15,6 +15,7 @@ from __future__ import annotations
 import functools
 import os
 import re
+import logging
 from typing import Optional
 
 import jax
@@ -32,6 +33,7 @@ from ollamamq_tpu.ops.quant import QuantTensor, quantize_tensor
 # one scale vector serves both uses of a tied embedding).
 QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 QUANT_ROW_KEYS = ("embed", "lm_head")
+log = logging.getLogger("ollamamq.weights")
 
 
 def quantize_params_int8(params: dict, cfg: ModelConfig) -> dict:
@@ -89,6 +91,25 @@ _HF_LAYER_MAP = {
     "feed_forward.w3.weight": ("w_up", True),
     "feed_forward.w2.weight": ("w_down", True),
 }
+# Qwen3-Next (the published `qwen3_next` tensor names). The shared expert
+# and its gate beside the routed experts of the "mlp" block (OLMoE's
+# layout below); a linear-attention layer's plain tensors (`A_log` and
+# `dt_bias` stay float32). Its two interleaved projections and `q_proj`'s
+# [q | gate] a head are un-woven in `_unweave_qwen3_next`.
+_HF_LAYER_MAP.update({
+    "mlp.shared_expert.gate_proj.weight": ("ws_gate", True),
+    "mlp.shared_expert.up_proj.weight": ("ws_up", True),
+    "mlp.shared_expert.down_proj.weight": ("ws_down", True),
+    "linear_attn.in_proj_qkvz.weight": ("lin_in", True),
+    "linear_attn.in_proj_ba.weight": ("lin_ba", True),
+    "linear_attn.A_log": ("lin_A_log", False),
+    "linear_attn.dt_bias": ("lin_dt_bias", False),
+    "linear_attn.norm.weight": ("lin_norm", False),
+    "linear_attn.out_proj.weight": ("lin_out", True),
+})
+_HF_SHARED_GATE = "mlp.shared_expert_gate.weight"  # [1, D]
+_HF_LINEAR_TAPS = "linear_attn.conv1d.weight"  # [q | k | v channels, 1, K]
+_FLOAT32_KEYS = ("lin_A_log", "lin_dt_bias")
 # The depthwise Conv1d weight [D, 1, K] (cross-correlation behind K-1 zeros
 # of left padding: tap K-1 meets the token itself, as `conv_w`'s).
 _HF_CONV_TAPS = "conv.conv.weight"
@@ -140,6 +161,34 @@ def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
     return jax.jit(init, out_shardings=shardings)(key)
 
 
+def _unweave_qwen3_next(cfg: ModelConfig, layers: dict) -> None:
+    """The published `qwen3_next` projections into the served layout, in
+    place. `in_proj_qkvz`'s columns come a KEY-HEAD GROUP at a time — [q_g |
+    k_g | v_g | z_g] with dk, dk, r dv, r dv columns, r value heads a key
+    head — and `in_proj_ba`'s [b_g | a_g] with r each; served they are [q |
+    k | v | z] and [b | a], a part's groups side by side. `q_proj`'s columns
+    come a head at a time, [q_h | gate_h]: served apart, `wq` and `wq_gate`."""
+    if cfg.attn_output_gate and "wq" in layers:
+        w = layers["wq"]
+        w = w.reshape(*w.shape[:2], cfg.num_heads, 2, cfg.head_dim)
+        layers["wq"], layers["wq_gate"] = (
+            w[:, :, :, j].reshape(*w.shape[:2], cfg.q_dim) for j in (0, 1))
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    if "lin_in" not in layers or not hk:
+        return
+    r, dk, dv = hv // hk, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+
+    def parts(w, widths):  # [L, D, groups * sum(widths)] -> a part's columns
+        w = w.reshape(*w.shape[:2], hk, sum(widths))
+        cuts = np.cumsum((0,) + widths)
+        return jnp.concatenate(
+            [w[..., a:b].reshape(*w.shape[:2], -1)
+             for a, b in zip(cuts[:-1], cuts[1:])], axis=-1)
+
+    layers["lin_in"] = parts(layers["lin_in"], (dk, dk, r * dv, r * dv))
+    layers["lin_ba"] = parts(layers["lin_ba"], (r, r))
+
+
 def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
     """Load an HF-layout safetensors checkpoint into the stacked-layer tree."""
     from safetensors import safe_open
@@ -164,6 +213,11 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
         t = np.asarray(t, dtype=np.float32)
         return t.T if transpose else t
 
+    skipped = sorted(k for k in raw if k.startswith("mtp."))
+    if skipped:  # a prediction module the program does not serve
+        log.info("%s: %d `mtp.*` tensors of the checkpoint skipped (the "
+                 "prediction module is not served for %s)", path,
+                 len(skipped), cfg.name)
     layer_names = [k for k in raw if re.match(r"model\.layers\.\d+\.", k)]
     n_layers = 1 + max(int(k.split(".")[2]) for k in layer_names)
     if n_layers != cfg.num_layers:
@@ -182,11 +236,19 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
             continue
         stack = np.stack(
             [grab(f"model.layers.{i}.{hf_suffix}", tr) for i in at])
-        layers[ours] = jnp.asarray(stack, dtype=dtype)
-    if having(_HF_CONV_TAPS):
-        layers["conv_w"] = jnp.asarray(np.stack(
-            [grab(f"model.layers.{i}.{_HF_CONV_TAPS}", False)[:, 0, :]
-             for i in having(_HF_CONV_TAPS)]), dtype=dtype)
+        layers[ours] = jnp.asarray(
+            stack, dtype=jnp.float32 if ours in _FLOAT32_KEYS else dtype)
+    for taps, ours in ((_HF_CONV_TAPS, "conv_w"),
+                       (_HF_LINEAR_TAPS, "lin_conv_w")):
+        if having(taps):  # a depthwise Conv1d's [channels, 1, K]
+            layers[ours] = jnp.asarray(np.stack(
+                [grab(f"model.layers.{i}.{taps}", False)[:, 0, :]
+                 for i in having(taps)]), dtype=dtype)
+    if having(_HF_SHARED_GATE):
+        layers["w_shared_gate"] = jnp.asarray(np.stack(
+            [grab(f"model.layers.{i}.{_HF_SHARED_GATE}", False)[0]
+             for i in having(_HF_SHARED_GATE)]), dtype=dtype)
+    _unweave_qwen3_next(cfg, layers)
 
     if cfg.num_experts:
         # Stack experts on axis 1 -> [Le, E, D, F] etc., over the layers
@@ -203,8 +265,9 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
         def estack(w_name: str, transpose: bool):
             per_layer = []
             for i in at:
+                # (a share holds the experts from `expert_offset` on)
                 names = [f"model.layers.{i}.{block}.experts."
-                         f"{e}.{w_name}.weight"
+                         f"{cfg.expert_offset + e}.{w_name}.weight"
                          for e in range(cfg.num_experts)]
                 stack = np.stack([grab(n, transpose) for n in names])
                 for n in names:

@@ -1,10 +1,13 @@
-"""Gated delta rule (Gated DeltaNet; Olmo-Hybrid's `linear_attention`
-layers): the recurrence, in the two forms a served step needs, and the
-three schedules that say which tokens continue which state.
+"""Gated delta rule (Gated DeltaNet; Olmo-Hybrid's and Qwen3-Next's
+`linear_attention` layers): the recurrence, in the two forms a served step
+needs, and the three schedules that say which tokens continue which state.
 
 Per head, with a float32 state S [dk, dv] a sequence (S = 0 before its
 first token), decay a_t = exp(g_t) in (0, 1], write strength b_t, and q, k
-L2-normalised per head (q also scaled by dk^-1/2):
+L2-normalised per head (q also scaled by dk^-1/2). A head is a VALUE head:
+where the model has fewer key heads (Qwen3-Next: 16 under 32), key head j
+serves value heads j * r .. j * r + r - 1 (`a_value_head` repeats q and k
+after `normalise`; with as many key heads as value heads nothing is traced):
 
     S' = a_t S_{t-1};  r_t = b_t (v_t - S'^T k_t);  S_t = S' + k_t r_t^T;
     o_t = S_t^T q_t
@@ -88,17 +91,26 @@ def normalise(q, k):
     return unit(q) * q.shape[-1] ** -0.5, unit(k)
 
 
+def a_value_head(q, k, heads: int):
+    """q, k [..., Hk, dk] (normalised) a VALUE head: where the `heads` value
+    heads are more than the key heads, each key head's q and k repeated for
+    the value heads it serves (j * r .. j * r + r - 1); else as they are."""
+    if heads != q.shape[-2]:
+        q, k = (jnp.repeat(x, heads // x.shape[-2], axis=-2) for x in (q, k))
+    return q, k
+
+
 def _heads(state, n_heads: int):
     """[..., dk, H * dv] -> [..., dk, H, dv]."""
     return state.reshape(*state.shape[:-1], n_heads, -1)
 
 
 def step(state, q, k, v, g, beta, reset=None):
-    """One token a row. state [..., dk, H * dv]; q, k [..., H, dk] as the
-    convolution left them; v [..., H, dv]; g, beta [..., H]; reset [...]:
-    the row's state opens at zero. Returns (o [..., H, dv] float32, the
-    state after the token)."""
-    q, k = normalise(q, k)
+    """One token a row. state [..., dk, H * dv]; q, k [..., Hk, dk] as the
+    convolution left them (Hk key heads, H a multiple of it); v [..., H,
+    dv]; g, beta [..., H]; reset [...]: the row's state opens at zero.
+    Returns (o [..., H, dv] float32, the state after the token)."""
+    q, k = a_value_head(*normalise(q, k), v.shape[-2])
     s = _heads(state, q.shape[-2])
     if reset is not None:
         s = jnp.where(reset[..., None, None, None], 0.0, s)
@@ -152,14 +164,14 @@ def _tri_inv(a):
 
 def _prepare(q, k, v, g, beta, same):
     """A chunk's tokens solved against each other, for any number of chunks
-    at once. q, k [N, C, H, dk] (as the convolution left them), v [N, C, H,
+    at once. q, k [N, C, Hk, dk] (as the convolution left them), v [N, C, H,
     dv], g, beta [N, C, H] (0 on tokens that take no part), same [N, C, C]
     bool: token j is token i's own or an earlier one of the SAME row.
     Returns what `_apply` needs of each chunk, heads leading: u [N, H, C,
     dv] and w [N, H, C, dk] (the chunk's corrected values = u - w S for an
     incoming state S), attn [N, H, C, C], qg (q scaled by its decay), k and
     gc [N, H, C] (each token's log decay since its row entered the chunk)."""
-    q, k = normalise(q, k)
+    q, k = a_value_head(*normalise(q, k), v.shape[2])
     q, k, v = (jnp.moveaxis(x, 2, 1) for x in (q, k, v.astype(_F32)))
     g, beta = jnp.moveaxis(g, 2, 1), jnp.moveaxis(beta, 2, 1)  # [N, H, C]
     mask = same[:, None]  # [N, 1, C, C]
@@ -206,12 +218,12 @@ def _from_heads(s):
 
 
 def chunked(q, k, v, g, beta, valid=None, state=None):
-    """Whole sequences from an empty (or a given) state. q, k [B, T, H,
+    """Whole sequences from an empty (or a given) state. q, k [B, T, Hk,
     dk], v [B, T, H, dv], g, beta [B, T, H]; valid [B, T] bool (padding
     takes no part). Returns (o [B, T, H, dv] float32, state [B, dk, H *
     dv])."""
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
+    b, t, _, dk = q.shape
+    h, dv = v.shape[-2:]
     n = -(-t // CHUNK)
     pad = n * CHUNK - t
     if valid is None:
@@ -256,15 +268,34 @@ def _step_rows(impl, state, layer, slots, live, reset, q, k, v, g, beta,
     return o, state.at[layer, slots].set(new)
 
 
+def _row_major(x):
+    """x, held row-major on the device: a row's state as the pair loop
+    slices it out of the carried array. The step kernel updates that array
+    in place and reads it row-major; left to itself the chip's compiler
+    wants the sliced row with the key dimension MINOR where that is a whole
+    lane tile (dk = 128: Qwen3-Next's), gives the loop's carry that order
+    and re-lays the WHOLE state — 321 MB, ~1 ms — in and out of every
+    scanned period (8 copies a 48 ms step on a v5e; PERF.md section 6,
+    PR 47). Pinned here, it re-lays the row it reads, if it must. A key
+    dimension that is no whole lane tile (Olmo-Hybrid's 96) leaves it no
+    such choice, and that model's programs nothing to pin."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    if x.shape[-2] % 128:
+        return x
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
 def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
            q_start, q_len, is_first, impl: str = "jnp", interpret=False):
     """The flattened stream of a ragged step (see the module docstring).
-    q, k [T, H, dk], v [T, H, dv], g, beta [T, H]; state the whole carried
+    q, k [T, Hk, dk], v [T, H, dv], g, beta [T, H]; state the whole carried
     array, `layer` this layer's index in it; slot_ids, q_start, q_len,
     is_first [B] per row; tok_seq, tok_pos [T] each token's row and
     position (-1: padding). Returns (o [T, H, dv] float32, state')."""
-    t, h, dk = q.shape
-    dv = v.shape[-1]
+    t = q.shape[0]
+    h, dv = v.shape[-2:]
     single, multi = q_len == 1, q_len > 1
     # 1. Rows with one token: `step` at the row's stream position.
     at = jnp.clip(q_start, 0, t - 1)
@@ -303,8 +334,8 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
         member = jax.lax.dynamic_index_in_dim(row_of, w, 0,
                                               keepdims=False) == b
         opens = (is_first[b] > 0) & (w == first_w[b])
-        s = jax.lax.dynamic_slice(
-            state, (layer, slot_ids[b], 0, 0), (1, 1) + state.shape[2:])[0, 0]
+        s = _row_major(jax.lax.dynamic_slice(
+            state, (layer, slot_ids[b], 0, 0), (1, 1) + state.shape[2:]))[0, 0]
         o, s = _apply(jnp.where(opens, 0.0, _to_heads(s, h)), ci, member)
         old = jax.lax.dynamic_index_in_dim(out, w, 0, keepdims=False)
         out = jax.lax.dynamic_update_index_in_dim(
@@ -322,7 +353,7 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
 
 
 def decode(q, k, v, g, beta, state, layer, active=None, impl: str = "jnp"):
-    """One token a slot: q, k [S, H, dk], v [S, H, dv], g, beta [S, H]; row
+    """One token a slot: q, k [S, Hk, dk], v [S, H, dv], g, beta [S, H]; row
     s of `state[layer]` is slot s's. Returns (o [S, H, dv], state')."""
     n = q.shape[0]
     live = jnp.ones((n,), bool) if active is None else active > 0

@@ -87,14 +87,15 @@ def gated_delta_step_pallas(state, layer, slots, live, reset, q, k, v, g,
                             beta, interpret: bool = False):
     """state [L, slots + 1, dk, H * dv] float32 (updated in place: donate
     it); layer an int32 scalar; slots [B] each row's state row; live, reset
-    [B] bool; q, k [B, H, dk] as the convolution left them, v [B, H, dv],
-    g, beta [B, H]. Returns (o [B, H, dv] float32 — zeros for rows that are
+    [B] bool; q, k [B, Hk, dk] as the convolution left them (the kernel
+    takes them a VALUE head: `a_value_head` repeats a key head's for the
+    value heads it serves), v [B, H, dv], g, beta [B, H]. Returns (o [B, H, dv] float32 — zeros for rows that are
     not live —, state')."""
-    n, h, dk = q.shape
-    dv = v.shape[-1]
+    n, _, dk = q.shape
+    h, dv = v.shape[-2:]
     hg, hb = head_blocks(h, dk, dv)
     nblk, lanes = h // hb, hb * dv
-    q, k = gated_delta.normalise(q, k)
+    q, k = gated_delta.a_value_head(*gated_delta.normalise(q, k), h)
 
     def columns(x):  # [B, H, dk] -> [B, blocks, dk, heads of a block]
         return jnp.swapaxes(x.reshape(n, nblk, hb, dk), -1, -2)

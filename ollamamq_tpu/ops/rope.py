@@ -13,13 +13,21 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** exponents)
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               rotary_dim=None) -> jnp.ndarray:
     """Apply RoPE.
 
     x: [..., T, H, head_dim] (positions broadcast over leading dims)
     positions: [..., T] int32
+    rotary_dim: rotate the FIRST that many lanes of a head only (a partial
+    rotary embedding: frequencies over rotary_dim, the other lanes pass);
+    None or head_dim: the whole head.
     """
     head_dim = x.shape[-1]
+    if rotary_dim is not None and rotary_dim != head_dim:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
     inv_freq = rope_freqs(head_dim, theta)  # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., T, hd/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., T, 1, hd/2]

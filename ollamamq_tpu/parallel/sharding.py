@@ -6,6 +6,7 @@ step consumes these shardings (no explicit NCCL-style calls, unlike the
 reference's HTTP fan-out):
 
   - wq/wk/wv  [D, heads*hd]  -> shard output (head) dim on "tensor"
+                                (and wq_gate, a gated model's gate a head)
   - wo        [heads*hd, D]  -> shard input  (head) dim on "tensor"
                                 (row-parallel: psum happens via sharding)
   - w_gate/w_up [D, F]       -> shard F on "tensor"
@@ -42,7 +43,7 @@ def param_partition_specs(params: Dict[str, Any]) -> Dict[str, Any]:
             # w_down — their channel dim is unsharded).
             name = path.split("/")[-1]
             qspec = spec_for(path, leaf.q)
-            if name in ("wq", "wk", "wv", "w_gate", "w_up"):
+            if name in ("wq", "wq_gate", "wk", "wv", "w_gate", "w_up"):
                 sspec = PS(*([None] * (leaf.s.ndim - 1)), AXIS_TENSOR)
             elif name in ("embed", "lm_head"):
                 sspec = PS(AXIS_TENSOR)  # per-row scales follow the rows
@@ -53,7 +54,7 @@ def param_partition_specs(params: Dict[str, Any]) -> Dict[str, Any]:
         nd = leaf.ndim
         # Layer weights are stacked on a leading num_layers axis (scan over
         # layers), so the sharded dim is addressed from the right.
-        if name in ("wq", "wk", "wv", "w_gate", "w_up") and nd >= 2:
+        if name in ("wq", "wq_gate", "wk", "wv", "w_gate", "w_up") and nd >= 2:
             return PS(*([None] * (nd - 1)), AXIS_TENSOR)  # column-parallel
         if name in ("wo", "w_down") and nd >= 2:
             return PS(*([None] * (nd - 2)), AXIS_TENSOR, None)  # row-parallel

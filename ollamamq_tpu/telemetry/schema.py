@@ -344,6 +344,17 @@ DSA_SELECTED_TOKENS_TOTAL = REGISTRY.counter(
     "ollamamq_dsa_selected_tokens_total",
     "Cached positions attention then saw for them, a layer: min(p + 1, "
     "index_topk)", labels=("model",))
+ATTN_PAIRS_TOTAL = REGISTRY.counter(
+    "ollamamq_attn_pairs_total",
+    "Causal (query token, cached position) pairs of launched steps' plain "
+    "(K and V pages, non-latent) attention, a layer: a token at position p "
+    "attends p + 1 (a ragged step's spans, a fused scan's active slots x "
+    "its passes)", labels=("model",))
+ATTN_CTX_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_attn_ctx_rows_total",
+    "Cached K/V rows those steps' walks have to read at the least, a "
+    "layer: each span's context once (a fused scan's pass: each active "
+    "slot's)", labels=("model",))
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

@@ -75,8 +75,8 @@ def test_the_registered_family_and_its_plan():
 
 
 @pytest.mark.parametrize("bad,match", [
-    (dict(linear_num_value_heads=8),
-     "linear_num_value_heads 8 is not linear_num_key_heads 4"),
+    (dict(linear_num_value_heads=6),
+     "linear_num_value_heads 6 is not a multiple of linear_num_key_heads 4"),
     (dict(linear_conv_kernel_dim=1),
      "linear_conv_kernel_dim must be at least 2, got 1"),
     (dict(linear_key_head_dim=0), r"linear_key_head_dim \(0\)"),

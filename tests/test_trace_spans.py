@@ -261,12 +261,15 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
 @pytest.mark.parametrize("model", ["test-tiny", "test-tiny-olmoe",
                                    "test-tiny-lfm2",
                                    "test-tiny-olmo-hybrid",
-                                   "test-tiny-deepseek-v32"])
+                                   "test-tiny-deepseek-v32",
+                                   "test-tiny-qwen3-next"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
     layers, of llama.CONV_SCOPES beside the attention layers'; with
-    linear-attention layers, of llama.LINEAR_SCOPES) is in the debug text of
+    linear-attention layers, of llama.LINEAR_SCOPES; with an attention
+    output gate or a gated shared expert, of llama.GATE_SCOPES and
+    moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES) is in the debug text of
     the engine's OWN ragged and decode programs, the modules are named
     after the stable jit functions, and README lists every one of these
     names in its span table."""
@@ -284,7 +287,10 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     rt = eng.runtimes[model]
     scopes = llama.SCOPES + (moe.SCOPES if rt.cfg.num_experts else ()) \
         + (llama.CONV_SCOPES if rt.cfg.count("conv") else ()) \
-        + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention") else ())
+        + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention") else ()) \
+        + (llama.GATE_SCOPES if rt.cfg.attn_output_gate else ()) \
+        + (moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES
+           if rt.cfg.shared_expert_gate else ())
     if rt.cfg.kv_lora_rank:  # latent attention: its stages for the three
         from ollamamq_tpu.ops import mla
 
@@ -340,7 +346,8 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
 
     assert set(llama.SCOPES) | set(llama.CONV_SCOPES) | set(moe.SCOPES) \
         | set(llama.LINEAR_SCOPES) | set(mla.SCOPES) \
-        | set(moe.SHARED_SCOPES) | jit_names | set(SPAN_NAMES) \
+        | set(moe.SHARED_SCOPES) | set(llama.GATE_SCOPES) \
+        | set(moe.SHARED_GATE_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented
     # ... and the five jit sites really are those functions.
     with open(os.path.join(_REPO, "ollamamq_tpu", "engine",

@@ -347,3 +347,93 @@ def test_safetensors_lfm2_layout(tmp_path):
     save_file(tensors, str(tmp_path / "model.safetensors"))
     with pytest.raises(ValueError, match="conv_w"):
         weights.load_safetensors(cfg, str(tmp_path), dtype=jnp.float32)
+
+
+def test_safetensors_qwen3_next_layout_is_unwoven(tmp_path, caplog):
+    """The published `qwen3_next` tensor names: `in_proj_qkvz` / `in_proj_ba`
+    interleaved a key-head group, `q_proj` holding [q | gate] a head, the
+    gated shared expert, a share's experts from `expert_offset` on, `mtp.*`
+    skipped with a log line — loaded into the served tree, which the seeded
+    tree they were woven from equals."""
+    import dataclasses
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+    from safetensors.numpy import save_file
+
+    from ollamamq_tpu.models import llama
+
+    cfg = dataclasses.replace(MODEL_CONFIGS["test-tiny-qwen3-next"],
+                              num_experts=4, expert_offset=8)
+    src = llama.init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    lp = {k: np.asarray(v) for k, v in src["layers"].items()}
+    d, hk, hv = cfg.hidden_size, 2, 4
+    dk, dv, r, hd = 8, 16, 2, cfg.head_dim
+    kd, vd = hk * dk, hv * dv
+    tensors = {"model.embed_tokens.weight": np.asarray(src["embed"]),
+               "model.norm.weight": np.asarray(src["final_norm"]),
+               "lm_head.weight": np.asarray(src["lm_head"]),
+               "mtp.layers.0.input_layernorm.weight": np.ones((d,), np.float32),
+               "mtp.fc.weight": np.ones((d, 2 * d), np.float32)}
+    a = c = 0
+    for i, kind in enumerate(cfg.layer_types):
+        p = f"model.layers.{i}."
+        tensors[p + "input_layernorm.weight"] = lp["attn_norm"][i]
+        tensors[p + "post_attention_layernorm.weight"] = lp["mlp_norm"][i]
+        if kind == "full_attention":
+            q = lp["wq"][a].reshape(d, cfg.num_heads, 1, hd)
+            g = lp["wq_gate"][a].reshape(d, cfg.num_heads, 1, hd)
+            tensors[p + "self_attn.q_proj.weight"] = np.concatenate(
+                [q, g], axis=2).reshape(d, 2 * cfg.q_dim).T.copy()
+            for hf, ours in (("k_proj", "wk"), ("v_proj", "wv"),
+                             ("o_proj", "wo")):
+                tensors[p + f"self_attn.{hf}.weight"] = lp[ours][a].T.copy()
+            tensors[p + "self_attn.q_norm.weight"] = lp["q_norm"][a]
+            tensors[p + "self_attn.k_norm.weight"] = lp["k_norm"][a]
+            a += 1
+        else:
+            w = lp["lin_in"][c]
+            cuts = (0, kd, 2 * kd, 2 * kd + vd, 2 * kd + 2 * vd)
+            woven = np.concatenate(
+                [w[:, lo:hi].reshape(d, hk, -1)
+                 for lo, hi in zip(cuts[:-1], cuts[1:])], axis=-1)
+            tensors[p + "linear_attn.in_proj_qkvz.weight"] = \
+                woven.reshape(d, -1).T.copy()
+            ba = lp["lin_ba"][c]
+            tensors[p + "linear_attn.in_proj_ba.weight"] = np.concatenate(
+                [ba[:, :hv].reshape(d, hk, r), ba[:, hv:].reshape(d, hk, r)],
+                axis=-1).reshape(d, -1).T.copy()
+            tensors[p + "linear_attn.conv1d.weight"] = \
+                lp["lin_conv_w"][c][:, None, :].copy()
+            tensors[p + "linear_attn.A_log"] = lp["lin_A_log"][c]
+            tensors[p + "linear_attn.dt_bias"] = lp["lin_dt_bias"][c]
+            tensors[p + "linear_attn.norm.weight"] = lp["lin_norm"][c]
+            tensors[p + "linear_attn.out_proj.weight"] = lp["lin_out"][c].T.copy()
+            c += 1
+        tensors[p + "mlp.gate.weight"] = lp["w_router"][i].T.copy()
+        tensors[p + "mlp.shared_expert_gate.weight"] = \
+            lp["w_shared_gate"][i][None].copy()
+        for hf, ours in (("gate_proj", "ws_gate"), ("up_proj", "ws_up"),
+                         ("down_proj", "ws_down")):
+            tensors[p + f"mlp.shared_expert.{hf}.weight"] = lp[ours][i].T.copy()
+        for e in range(cfg.router_width):  # the checkpoint holds ALL experts
+            held = e - cfg.expert_offset
+            for hf, ours in (("gate_proj", "we_gate"), ("up_proj", "we_up"),
+                             ("down_proj", "we_down")):
+                w = lp[ours][i][held] if 0 <= held < 4 \
+                    else np.full_like(lp[ours][i][0], 7.0)
+                tensors[p + f"mlp.experts.{e}.{hf}.weight"] = w.T.copy()
+    save_file(tensors, str(tmp_path / "model.safetensors"))
+    with caplog.at_level(logging.INFO, logger="ollamamq.weights"):
+        params = weights.load_safetensors(cfg, str(tmp_path),
+                                          dtype=jnp.float32)
+    assert "2 `mtp.*` tensors" in caplog.text
+    assert set(params["layers"]) == set(src["layers"])
+    for name, leaf in src["layers"].items():
+        np.testing.assert_array_equal(np.asarray(params["layers"][name]),
+                                      np.asarray(leaf), err_msg=name)
+    assert params["layers"]["lin_A_log"].dtype == jnp.float32
+    for name in ("embed", "final_norm", "lm_head"):
+        np.testing.assert_array_equal(np.asarray(params[name]),
+                                      np.asarray(src[name]))

@@ -233,6 +233,42 @@ def deepseek_v32_keys(mc) -> dict:
             "vocab_size": mc.vocab_size}
 
 
+def qwen3_next_reference():
+    """...and of the Qwen3-Next family: the rule with grouped heads beside
+    gated attention, zero-centred norms, a gated shared expert
+    (benchmarks/reference/qwen3_next_decoder.py)."""
+    return _reference("qwen3_next_decoder")
+
+
+def qwen3_next_keys(mc) -> dict:
+    """What a configuration file says of the Qwen3-Next ModelConfig `mc`, in
+    the published spellings (and this repo's, for what the published file
+    has no key for): all that reference reads."""
+    return {"hidden_size": mc.hidden_size,
+            "num_attention_heads": mc.num_heads,
+            "num_key_value_heads": mc.num_kv_heads, "head_dim": mc.head_dim,
+            "rms_norm_eps": mc.rms_norm_eps, "rope_theta": mc.rope_theta,
+            "partial_rotary_factor": mc.partial_rotary_factor,
+            "qk_norm": mc.qk_norm, "attn_output_gate": mc.attn_output_gate,
+            "zero_centred_norm": mc.zero_centred_norm,
+            "shared_expert_gate": mc.shared_expert_gate,
+            "layer_types": list(mc.layer_types),
+            "linear_num_key_heads": mc.linear_num_key_heads,
+            "linear_num_value_heads": mc.linear_num_value_heads,
+            "linear_key_head_dim": mc.linear_key_head_dim,
+            "linear_value_head_dim": mc.linear_value_head_dim,
+            "linear_conv_kernel_dim": mc.linear_conv_kernel_dim,
+            "linear_allow_neg_eigval": mc.linear_allow_neg_eigval,
+            "num_experts": mc.num_experts,
+            "router_experts": mc.router_width,
+            "expert_offset": mc.expert_offset,
+            "num_experts_per_tok": mc.num_experts_per_tok,
+            "norm_topk_prob": mc.norm_topk_prob,
+            "moe_intermediate_size": mc.expert_width,
+            "shared_expert_intermediate_size": mc.shared_width,
+            "vocab_size": mc.vocab_size}
+
+
 def openpangu_reference():
     """...and of the latent-attention family with no indexer, sandwich norms
     and a prediction module (benchmarks/reference/openpangu_ultra_decoder.py)."""
