@@ -488,7 +488,7 @@ class ModelRuntime:
     # selected: the `dsa_*` fields have no honest value there).
     DENSE_LATENT_FIELDS = ("mla_rows", "mla_pairs", "mla_ctx_rows")
     # What `_note_attn` writes (plain K/V attention: no latent pool).
-    ATTN_FIELDS = ("attn_pairs", "attn_ctx_rows")
+    ATTN_FIELDS = ("attn_pairs", "attn_ctx_rows", "attn_tall_tokens")
 
     # Engine performance plane (telemetry/stepprof.py): the per-step
     # "paid a compile" flag (_sp_note_compile sets, the step's finish
@@ -731,10 +731,15 @@ class ModelRuntime:
         # model's query group (ops/pallas/kv_contract.py): every launch of
         # that kernel, for the runtime's life. None without the kernels.
         self.attn_inner = None
+        # ...and the ragged kernel's own test of which stream tokens it
+        # serves a whole stretch at a time (`_note_attn`).
+        self._tall_tokens = None
         if self.attn_impl == "pallas":
-            from ollamamq_tpu.ops.pallas.kv_contract import inner_report
+            from ollamamq_tpu.ops.pallas.kv_contract import (inner_report,
+                                                             tall_tokens)
             self.attn_inner = inner_report(
                 model_cfg.num_heads // model_cfg.num_kv_heads)
+            self._tall_tokens = tall_tokens
         log.info("%s: attention=%s (%s)%s", name, self.attn_impl, why,
                  "".join(f" {k}={v}" for k, v in
                          (self.attn_inner or {}).items()))
@@ -917,7 +922,8 @@ class ModelRuntime:
             tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
             tm.DSA_SELECTED_TOKENS_TOTAL)]
         self._tm_attn = [c.labels(model=name) for c in (
-            tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL)]
+            tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
+            tm.ATTN_TALL_TOKENS_TOTAL)]
 
     # -- capacity ----------------------------------------------------------
     def free_slots(self) -> int:
@@ -1352,24 +1358,31 @@ class ModelRuntime:
         for series, n in zip(self._tm_dsa, counts[:3].tolist()):
             series.inc(n)
 
-    def _note_attn(self, _sp, tokens, kv, scan: bool = False) -> None:
+    def _note_attn(self, _sp, tokens, kv, scan: bool = False,
+                   stream_len: int = 0) -> None:
         """A launched step's plain (K and V pages, non-latent) attention,
         onto its sample and the /metrics series, from its composition alone
         (as `_note_latent` counts the latent kernels' work): `tokens` and
         `kv` are each row's span length and its context at the span's end —
-        a ragged step's spans, or with `scan` a fused scan's active slots
+        a ragged step's spans in stream order (`stream_len`: the rung the
+        stream is padded to), or with `scan` a fused scan's active slots
         with its passes as tokens. `attn_pairs` the causal (query token,
-        cached position) pairs — a token at position p attends p + 1 — and
+        cached position) pairs — a token at position p attends p + 1 —
         `attn_ctx_rows` the cached rows the walks have to read at the
-        least: each span's context once (a scan's pass: each slot's). A
-        layer's worth: every attention layer does the same. Nothing for an
-        encoder, a model with latent attention or one with no attention
-        layer."""
+        least: each span's context once (a scan's pass: each slot's), and
+        `attn_tall_tokens` the stream tokens the ragged kernel serves a
+        whole stretch at a time (`kv_contract.tall_tokens`; 0 for a scan
+        and without the kernel). A layer's worth: every attention layer
+        does the same. Nothing for an encoder, a model with latent
+        attention or one with no attention layer."""
         if self.cfg.kv_lora_rank or not self.cfg.count(ATTENTION):
             return
         n, kv = np.asarray(tokens, np.int64), np.asarray(kv, np.int64)
         pairs = n * (2 * kv - n + 1) // 2  # sum of kv-n+1 .. kv
-        counts = (int(pairs.sum()), int((pairs if scan else kv).sum()))
+        tall = 0
+        if self._tall_tokens is not None and not scan:
+            tall = self._tall_tokens(tokens, stream_len)
+        counts = (int(pairs.sum()), int((pairs if scan else kv).sum()), tall)
         _sp.note(**dict(zip(self.ATTN_FIELDS, counts)))
         for series, c in zip(self._tm_attn, counts):
             series.inc(c)
@@ -2791,7 +2804,7 @@ class ModelRuntime:
                               sum(n == 1 for n in spans),
                               sum(n for n in spans if n > 1))
         self._note_latent(_sp, zip(spans, row_kv))
-        self._note_attn(_sp, spans, row_kv)
+        self._note_attn(_sp, spans, row_kv, stream_len=T_pad)
         _sp.mark("dispatch")
         _sp.park()
 

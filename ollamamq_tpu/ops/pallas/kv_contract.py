@@ -1,8 +1,9 @@
 """What the two paged-attention kernels share: the page stream and the
 inner product.
 
-`ragged_attention.py` (one program a G_TILE-token tile of a mixed stream)
-and `paged_attention.py` (one program a decode row) differ in their grid
+`ragged_attention.py` (one program a stretch of a mixed stream: a tile of
+G_TILE tokens, or TALL of them on a rung that holds whole stretches) and
+`paged_attention.py` (one program a decode row) differ in their grid
 and in what a walk's successor is. Everything inside a sequence's walk is
 here, once: `PageStream` moves K/V pages HBM→VMEM in blocks through a
 ring of buffers, and an inner product — `Mxu` or `Vpu` — folds one
@@ -14,8 +15,8 @@ rows × group that share each kv head's K/V in one program — known at
 trace time from the kernel and the model's `(group, head_dim)`; no flag,
 no environment variable, no model name):
 
-  M > 1 → `Mxu`: the ragged kernel always (a tile's 8 rows), the decode
-      kernel when group > 1. Per block and per lane tile ONE contraction
+  M > 1 → `Mxu`: the ragged kernel always (a tile's 8 rows, or the 64
+      of a stretch inside one span), the decode kernel when group > 1. Per block and per lane tile ONE contraction
       serves every query row-head that shares the K/V: scores `[M, blk] =
       q_t [M, W] · k_t [blk, W]ᵀ` on the MXU with the pool's dtype as
       operands (bf16 × bf16 products are exact) and float32 accumulation,
@@ -119,6 +120,67 @@ tokens: decode, ragged, ragged with two 228-token prefill spans):
 128): 0.72 → 0.52 µs, of which the arithmetic is 0.36 a block and 0.22 a
 program: what is left to take is ~0.1 µs a block.
 
+How many rows share a block's trip: the tile follows the span (PR 48). A
+trip — one block folded into one tile's state — costs 0.55-0.63 µs at M =
+64 row-heads whatever the rows hold: ~0.17 µs of MXU work, ~0.17 of QKᵀ →
+max → exp → P·V chain latency that no width moves, the VPU's softmax on
+`[64, 128]`, and K/V tiles latched into the MXU for 64 rows each. A
+prefill span's tiles all walk the SAME blocks, so a 507-token chunk over
+8 k of context was 63 tiles × ~64 blocks of them. `Mxu.update(...,
+sub=None)` folds a block into ALL of a program's tiles at once — TALL //
+G_TILE of them, merged along M: `[512, W] · [128, W]ᵀ` at group 8 — and
+the ragged kernel runs it for a program whose TALL = 64 stream tokens lie
+inside one span; every other stretch (decode rows, a span's head and
+tail, padding, any rung of fewer than 2 * TALL tokens) keeps tiles of 8.
+Measured, `scripts/attn_kernel_bench.py --traffic raggedlong` at (16, 2,
+256) — 5 one-token rows then one 507-token span, every sequence at one
+context; ms a launch, then µs a short trip / a tall trip (my chip run,
+PR 48, call 1, one v5e):
+  context   parent          every tile 64   TALL 32        TALL 64        TALL 128
+  4096      1.301 (0.64)    0.857 (2.12)    0.687 (1.03)   0.689 (2.03)   0.736 (3.58)
+  8192      2.627 (0.62)    1.692 (2.06)    1.361 (1.00)   1.369 (1.98)   1.469 (3.53)
+  16384     5.278 (0.61)    3.369 (2.04)    2.707 (0.99)   2.728 (1.95)   2.935 (3.47)
+("every tile 64": `--set ragged_attention.G_TILE=64,kv_contract.G_TILE=64`,
+what decode rows would pay if they were not kept at 8: their trips cost
+2.0 µs for ONE live row.) A tall trip of 64 tokens does the work of eight
+short ones (4.9 µs) in 1.95-2.03, against 1.36 µs of MXU work at the bf16
+peak with P's three terms; 32 tokens a trip cost the same a token, and 128
+a little less a token but leave a longer head to the short tiles, and at
+(30, 30, 128) the compiler refuses them (19.65 MB of scoped VMEM for a
+limit of 16): 64. What is left of the 8 k launch: 808 short trips (the
+five decode rows' 320 at one live row of 8, the span's first 59 tokens'
+488) 0.50 ms, 439 tall trips 0.87 ms.
+  The tall trip's lane tiles are unrolled up to `TALL_UNROLL` = 2 of them
+and a loop in the program beyond — the one place where that loop is
+rolled — because a rung's second body is paid at every start
+(`setup_s`): unrolled it is one more copy of the inner product a lane
+tile, 1142 → 2347 equations at (16, 16, 128) and 1954 → 3985 at (30, 30,
+128), and the three rungs that carry it cost OLMoE's warm start +1.6-2.9 s
+of ~42 and the hybrid's +4.1-4.3 s of ~68 (the `warm_up` note's ms a
+rung: 512 tokens 8.89 → 10.28-10.56 s, 256 4.29 → 5.75-5.90, 128 4.86 →
+5.86-5.96 on the hybrid; my chip run, PR 48, call 2); rolled it is 324
+equations more at any width and the same rungs read +0.5 and +0.7 s, ~1 %
+(9.17-9.54 → 9.50-9.89, 4.28-4.69 → 4.59-4.68, 4.91-5.25 → 5.18-5.45;
+call 3). What the roll costs a launch depends on the rows a lane tile
+holds (`raggedlong` at 4 k, µs a tall trip, rolled / unrolled; call 3):
+  (28, 4, 128) 3.04 / 3.03   (32, 8, 64) 3.38 / 3.35   (16, 2, 256) 2.98 / 2.04
+  (16, 16, 128) 6.85 / 2.72  (30, 30, 128) 12.71 / 4.67
+— nothing at 448 or 512 row-heads a 128-lane tile, where a tile's own
+work (0.76 µs) hides the chain's latency; 2.5-2.7 × at the MHA shapes'
+128 row-heads a tile, where the unrolled loop overlapped sixteen or thirty
+short chains. Rolled, an MHA model's long chunk is still 1.8 × faster a
+launch than on tiles of 8 (4 k: 4.08 → 2.23 ms at (16, 16, 128), 7.31 →
+4.09 at (30, 30, 128), by the short trip's price) where unrolled it was
+3.0 ×: the price of a start-up that every cell pays against a launch no
+cell of those shapes runs. Two tiles unrolled cost no more equations than
+the loop (715 for 709) and are what `qwen3-next-80b-a3b-ep4-d12` runs.
+  The cells' other steps, parent → this, ms a launch (calls 1 and 3):
+`ragged64` and `decode` within 2 % at every shape (a 64-token rung traces
+the parent's body), `ragged512` (56 decode rows, two 228-token spans over
+no prefix: six whole stretches)
+  (28, 4, 128) 0.1863 → 0.1692   (8, 2, 128) 0.1357 → 0.1098   (32, 8, 64) 0.2646 → 0.2528
+  (16, 16, 128) 0.4864 → 0.4199  (30, 30, 128) 0.8542 → 0.7325 (16, 2, 256) 0.1753 → 0.1504
+
 Which loops are in the program, which in Python, and why. A step program
 is traced, lowered and keyed once a rung of the token ladder at every
 start, compile cache warm or not, and that cost follows the size of the
@@ -136,7 +198,11 @@ in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
     every published shape when written (0.474 → 0.476, 0.178 → 0.180 ms;
     PR 34, same run), an eighth of the body: 1145 equations at (16, 16,
     128), 449 at (28, 4, 128), whatever G_TILE is
-    (`tests/test_ragged_attention.py` holds that).
+    (`tests/test_ragged_attention.py` holds that). Since PR 48 a rung of
+    2 * TALL tokens or more holds TWO bodies — a program's tiles are a
+    loop in the program too, and the tall walk beside it is one more copy
+    of the inner product, its lane tiles a loop in the program beyond
+    `TALL_UNROLL`: 770 and 1466 equations there.
   - IN PYTHON: the lane tiles (`for t in range(self.tiles)` in
     `Mxu.update` / `finish`) and a block's pages (`PageStream`, at most 4:
     straight-line copies under the block's one predicate).
@@ -150,7 +216,9 @@ in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
     tokens/s over 20 s windows). A rolled tile loop serialises what the unrolled one lets
     the scheduler overlap (tile t+1's MXU pushes under tile t's softmax).
     So the body grows with the lane tiles alone — 16 at most among the
-    published shapes — and with nothing else.
+    published shapes — and with nothing else. (The tall trip's tile loop
+    IS rolled beyond two tiles, PR 48: see above for what that buys a
+    start and costs a launch.)
 `hd % 128 == 0` or `hd == 64` changes none of this: the packing is the
 wrapper's, the kernel sees `tiles` tiles of width W.
   What a rung's trace is charged for is not only equations (PR 38): on a
@@ -203,10 +271,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANE = 128
-# Tokens per grid program of the ragged kernel. 8 keeps the q/o blocks one
-# sublane tile tall and bounds the worst case (8 distinct decode
-# sequences) to the same page-loop total work as 8 decode-kernel programs.
+# Tokens of one tile of the ragged kernel: the rows that share a block's
+# trip wherever a stretch of the stream is NOT one span's (decode rows, a
+# span's head and tail, padding). 8 keeps the q/o blocks one sublane tile
+# tall and bounds the worst case (8 distinct decode sequences) to the same
+# page-loop total work as 8 decode-kernel programs.
 G_TILE = 8
+# Tokens of one PROGRAM of the ragged kernel on a rung that can hold one
+# (`ragged_attention.py`): a stretch of TALL consecutive stream tokens that
+# lies inside one span shares each block's trip, TALL // G_TILE tiles
+# merged along M. Swept 32 / 64 / 128 on `scripts/attn_kernel_bench.py`
+# (module docstring, PR 48).
+TALL = 64
+# Lane tiles up to which the tall trip's loop over them is unrolled in
+# Python, as the tile's trip is at any width; beyond, it is a loop in the
+# program (module docstring: what either costs a launch and a rung).
+TALL_UNROLL = 2
 _NN = (((1,), (0,)), ((), ()))  # [M, K] · [K, N]
 _NT = (((1,), (1,)), ((), ()))  # [M, K] · [N, K]ᵀ
 
@@ -278,9 +358,35 @@ def inner_report(group: int) -> dict:
             "decode": choose_inner(1, group)}
 
 
-def make_inner(name, *, rows, group, num_kv_heads, head_dim, page_size):
+def make_inner(name, *, rows, group, num_kv_heads, head_dim, page_size,
+               subs=1):
     cls = {"mxu": Mxu, "vpu": Vpu}[name or choose_inner(rows, group)]
-    return cls(rows, group, num_kv_heads, head_dim, page_size)
+    return cls(rows, group, num_kv_heads, head_dim, page_size, subs)
+
+
+def programs_height(stream_len: int) -> int:
+    """Tokens a program of the ragged kernel holds on a rung of
+    `stream_len` tokens: TALL where a whole stretch fits beside a decode
+    row (two programs at the least), else one tile — and then the kernel
+    traces no tall body (`setup_s`)."""
+    return TALL if stream_len >= 2 * TALL else G_TILE
+
+
+def tall_tokens(spans, stream_len: int) -> int:
+    """Stream tokens of a ragged step that the kernel serves TALL at a
+    time: those of its stretches [k*TALL, (k+1)*TALL) that lie inside ONE
+    span, on a rung (`stream_len` tokens, padding included) that holds the
+    tall body at all. `spans`: each row's tokens, in stream order. The
+    kernel's own test (`programs_height`, `ragged_attention.py:whole`), on
+    the host."""
+    if programs_height(stream_len) != TALL:
+        return 0
+    tall = start = 0
+    for n in spans:
+        first = -(-start // TALL) * TALL  # the span's first whole stretch
+        tall += max(0, start + n - first) // TALL * TALL
+        start += n
+    return tall
 
 
 def ring_grid_spec(inner, ring, grid, num_scalar_prefetch, pools):
@@ -403,11 +509,12 @@ def _load_block(bufs, slot, seg_t):
 
 @dataclasses.dataclass(frozen=True)
 class _Inner:
-    rows: int  # query rows a program holds: G_TILE, or 1 (decode kernel)
+    rows: int  # query rows of one tile: G_TILE, or 1 (decode kernel)
     group: int
     num_kv_heads: int
     head_dim: int
     page_size: int
+    subs: int = 1  # tiles a program holds (ragged kernel: TALL // G_TILE)
 
     @property
     def lanes(self):
@@ -427,7 +534,8 @@ class Vpu(_Inner):
     block_pages = 1
 
     def __post_init__(self):
-        assert self.rows == 1, "the Vpu inner product serves a decode row"
+        assert self.rows == self.subs == 1, \
+            "the Vpu inner product serves a decode row"
 
     def pack_q(self, q):
         """[N, H, hd] → [N, group, lanes], query-group-major: row g holds
@@ -488,12 +596,12 @@ class Vpu(_Inner):
 
 
 def _lane_fit(x, n):
-    """A lane-replicated `[M, 128]` statistic at width n."""
+    """A lane-replicated `[..., M, 128]` statistic at width n."""
     if n == LANE:
         return x
     if n % LANE == 0:
-        return jnp.tile(x, (1, n // LANE))
-    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+        return jnp.tile(x, (1,) * (x.ndim - 1) + (n // LANE,))
+    return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
 
 
 class Mxu(_Inner):
@@ -560,12 +668,15 @@ class Mxu(_Inner):
 
     @property
     def q_block(self):
-        return (1, self.tiles, self.mp, self.width)
+        return (self.subs, self.tiles, self.mp, self.width)
 
     def scratch(self):
-        return [pltpu.VMEM((self.tiles, self.mp, self.width), jnp.float32),
-                pltpu.VMEM((self.tiles, self.mp, LANE), jnp.float32),
-                pltpu.VMEM((self.tiles, self.mp, LANE), jnp.float32)]
+        # A lane tile's tiles are contiguous: merged along M they are the
+        # tall trip's `[subs * Mp, ·]` state at no cost.
+        held = (self.tiles, self.subs, self.mp)
+        return [pltpu.VMEM(held + (self.width,), jnp.float32),
+                pltpu.VMEM(held + (LANE,), jnp.float32),
+                pltpu.VMEM(held + (LANE,), jnp.float32)]
 
     def _pv(self, p, v):
         """p [Mp, blk] f32 · v [blk, W] → [Mp, W] f32, P kept to float32's
@@ -578,40 +689,72 @@ class Mxu(_Inner):
             if i + 1 < PV_TERMS:
                 rest = rest - terms[-1].astype(jnp.float32)
         out = _dot(jnp.concatenate(terms, axis=0), v)  # [terms*Mp, W]
-        return sum(out[i * self.mp:(i + 1) * self.mp]
-                   for i in range(PV_TERMS))
+        m = p.shape[0]
+        return sum(out[i * m:(i + 1) * m] for i in range(PV_TERMS))
 
-    def update(self, q_ref, bufs, slot, span, pos0, state, done_reading):
+    def update(self, q_ref, bufs, slot, span, pos0, state, done_reading,
+               sub=0):
+        """Fold the block in ring slot `slot` (its first token at position
+        `pos0`) into the state of tile `sub` of the program — or, `sub`
+        None, of ALL its tiles merged along M: the tall trip, for a
+        program whose every token lies in `span`."""
         tile_start, qs, ql, kv = span
         acc, m_i, l_i = state
         W, mp = self.width, self.mp
         blk = self.block_pages * self.page_size
         scale = 1.0 / (self.head_dim ** 0.5)
-        pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (mp, blk), 1)
+        tall = sub is None
+        held = (self.subs, mp) if tall else (mp,)
+        m = self.subs * mp if tall else mp
+
+        def get(ref, t):
+            if not tall:
+                return ref[t, sub]
+            return ref[t].reshape(m, ref.shape[-1])
+
+        def put(ref, t, x):
+            if tall:
+                ref[t] = x.reshape(held + x.shape[-1:])
+            else:
+                ref[t, sub] = x
+
+        def iota(dim):  # of the rows' (tile, row in it, position) index
+            return jax.lax.broadcasted_iota(
+                jnp.int32, held + (blk,), dim).reshape(m, blk)
+
+        pos = pos0 + iota(len(held))
         if self.rows == 1:
             valid = pos < kv
         else:
             # Row-head i*M + g*rows + r is token tile_start + r: inside
             # this sequence's span it sees positions up to its own (which
             # lies below kv), outside it nothing.
-            tok = tile_start + (jax.lax.broadcasted_iota(
-                jnp.int32, (mp, blk), 0) & (self.rows - 1))
-            valid = ((tok >= qs) & (tok < qs + ql)
-                     & (pos <= kv - ql + (tok - qs)))
+            tok = tile_start + (iota(len(held) - 1) & (self.rows - 1))
+            if tall:  # tile j's rows are `rows` tokens further on each
+                tok = tok + iota(0) * self.rows
+                valid = pos <= kv - ql + (tok - qs)
+            else:
+                valid = ((tok >= qs) & (tok < qs + ql)
+                         & (pos <= kv - ql + (tok - qs)))
         if len(bufs) == 4:  # int8: dequantise the block, then the same
             _, seg_t = _segments(self.num_kv_heads, self.head_dim)
             k_all, v_all = _load_block(bufs, slot, seg_t)
-        for t in range(self.tiles):
-            lanes = slice(t * W, (t + 1) * W)
+        def fold(t):  # lane tile t: a static index, or a loop's
+            if isinstance(t, int):
+                lanes = slice(t * W, (t + 1) * W)
+            else:
+                lanes = pl.ds(pl.multiple_of(lax.mul(t, W), LANE), W)
             if len(bufs) == 4:
-                k, v = k_all[:, lanes], v_all[:, lanes]
+                k, v = (x[:, lanes] if isinstance(t, int)
+                        else lax.dynamic_slice_in_dim(x, lax.mul(t, W), W, 1)
+                        for x in (k_all, v_all))
             else:
                 k, v = bufs[0][slot, :, lanes], bufs[1][slot, :, lanes]
-            q = q_ref[0, t]  # [Mp, W]
+            q = q_ref[:, t].reshape(m, W) if tall else q_ref[sub, t]
             if q.dtype != k.dtype:
                 q, k = q.astype(jnp.float32), k.astype(jnp.float32)
             sc = jnp.where(valid, _dot(q, k, _NT) * scale, NEG_INF)
-            m_prev = m_i[t]  # [Mp, 128], lane-replicated
+            m_prev = get(m_i, t)  # [M, 128], lane-replicated
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
             # Rows outside the span, and blocks wholly beyond a row's
             # causal frontier, leave every score at NEG_INF: guard the
@@ -619,13 +762,20 @@ class Mxu(_Inner):
             alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
                               jnp.exp(m_prev - m_new))
             p = jnp.where(valid, jnp.exp(sc - _lane_fit(m_new, blk)), 0.0)
-            l_i[t] = l_i[t] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc[t] = acc[t] * _lane_fit(alpha, W) + self._pv(p, v)
-            m_i[t] = m_new
+            put(l_i, t, get(l_i, t) * alpha
+                + jnp.sum(p, axis=1, keepdims=True))
+            put(acc, t, get(acc, t) * _lane_fit(alpha, W) + self._pv(p, v))
+            put(m_i, t, m_new)
+
+        if tall and self.tiles > TALL_UNROLL:
+            jax.lax.fori_loop(0, self.tiles, lambda t, _: fold(t), None)
+        else:
+            for t in range(self.tiles):
+                fold(t)
         done_reading()
 
     def finish(self, o_ref, state):
         acc, _, l_i = state
-        for t in range(self.tiles):
+        for t in range(self.tiles):  # every tile of the program at once
             denom = _lane_fit(jnp.maximum(l_i[t], 1e-20), self.width)
-            o_ref[0, t] = (acc[t] / denom).astype(o_ref.dtype)
+            o_ref[:, t] = (acc[t] / denom).astype(o_ref.dtype)

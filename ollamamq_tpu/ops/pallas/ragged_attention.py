@@ -25,21 +25,42 @@ ops/pallas/paged_attention.py):
     carry q_len = 0 with q_start = T and only trail the live rows (a
     tile's walk ends at the first row that has no token in it).
 
-Grid: one program per G_TILE-token tile of the stream. A tile may span
-several sequences (e.g. 8 decode tokens from 8 different sequences), so
-per-tile scalar-prefetch metadata names the FIRST overlapping sequence
-and the kernel walks forward over the (at most G_TILE) sequences that
-intersect the tile, masking rows by span membership. That walk is a loop
-IN THE PROGRAM (`lax.while_loop` over the successors, ended at the last
-sequence with a row in the tile; `q_start` / `q_len` / `kv_len` / the
-page table are SMEM reads at a dynamic row), not G_TILE predicated
-copies of the body: a launch costs no more, and the traced body — which
-every start-up pays for once a rung of the token ladder, `setup_s` — is
-an eighth of the unrolled one (`kv_contract.py` has the numbers and says
-which loops stay in Python). Per sequence it streams that sequence's pages HBM→VMEM
-through a ring of block buffers and accumulates a flash-style online
-softmax; the page loop is bounded by the tile's deepest causal frontier,
-so an early prefill tile reads only the prefix it can see.
+Grid: one program a stretch of the stream, and THE TILE FOLLOWS THE SPAN
+(PR 48). A tile is G_TILE = 8 stream tokens: the rows that share a K/V
+block's trip. It may span several sequences (8 decode tokens from 8
+different sequences), so per-tile scalar-prefetch metadata names the FIRST
+overlapping sequence and the kernel walks forward over the (at most
+G_TILE) sequences that intersect the tile, masking rows by span
+membership. That walk is a loop IN THE PROGRAM (`lax.while_loop` over the
+successors, ended at the last sequence with a row in the tile; `q_start` /
+`q_len` / `kv_len` / the page table are SMEM reads at a dynamic row), not
+G_TILE predicated copies of the body: a launch costs no more, and the
+traced body — which every start-up pays for once a rung of the token
+ladder, `setup_s` — is an eighth of the unrolled one (`kv_contract.py` has
+the numbers and says which loops stay in Python). Per sequence it streams
+that sequence's pages HBM→VMEM through a ring of block buffers and
+accumulates a flash-style online softmax; the page loop is bounded by the
+tile's deepest causal frontier, so an early prefill tile reads only the
+prefix it can see.
+    But a prefill span's tiles all walk the SAME blocks: 63 tiles of a
+507-token chunk over 8 k of context stream and contract its ~64 blocks 63
+times, each trip bound by a fixed chain latency and by K/V tiles latched
+into the MXU for 64 row-heads. So on a rung of 2 * TALL tokens or more
+(`kv_contract.programs_height`: a static shape) a program holds TALL = 64
+tokens, TALL // G_TILE tiles of the packed q (`q_ref[:, t]`, merged over
+its leading dimension at no cost), and chooses ONE of two bodies by a
+scalar test on `q_start` / `q_len` (`whole`): a program whose stretch
+[p * TALL, (p + 1) * TALL) lies inside one span runs ONE walk, up to the
+stretch's causal frontier, whose every block is contracted once for all
+TALL × group row-heads (`Mxu.update(..., sub=None)`; the mask's token of a
+row from the row's index); any other program — decode rows, a span's head
+and tail, padding — runs its tiles' walks one after another as above, each
+on its slice of the same scratch. The arithmetic a row sees is the same:
+same pairs, same blocks, same operands; only how many rows share a trip
+changes. A rung of fewer than 2 * TALL tokens cannot hold a whole stretch
+beside a decode row: its programs are one tile and the tall body is not
+traced (the 64-row decode-only step is such a rung). One `pallas_call` a
+layer either way.
 
 The page walk and the inner product are shared with the decode kernel
 (`ops/pallas/kv_contract.py`, which also holds the Mosaic layout
@@ -76,6 +97,7 @@ from jax.experimental import pallas as pl
 
 from ollamamq_tpu.ops.pallas.kv_contract import (G_TILE, PageStream, cdiv,
                                                  make_inner, mod,
+                                                 programs_height,
                                                  ring_grid_spec, split_refs,
                                                  whole_blocks)
 
@@ -101,22 +123,23 @@ def _ragged_kernel(
     num_seqs: int,
 ):
     q_ref, hbm, o_ref, bufs, state, sems, at_ref = split_refs(refs)
-    t = pl.program_id(0)
-    n_tiles = pl.num_programs(0)
-    tile_start = lax.mul(t, G_TILE)
+    subs = inner.subs  # tiles a program holds; 1: no tall body is traced
+    height = subs * G_TILE
+    prog = pl.program_id(0)
+    n_progs = pl.num_programs(0)
+    n_tiles = n_progs if subs == 1 else lax.mul(n_progs, subs)
     page_size, bp = inner.page_size, inner.block_pages
     stream = PageStream(hbm, bufs, sems, layer_ref[0], page_table_ref,
                         page_size, bp)
     inner.init(*state)
 
-    def walk_pages(tile, s):
-        """Pages of sequence `s` that tile `tile` walks — up to the
-        deepest causal frontier among the tile's rows of s, so an early
-        tile of a long prefill reads only the prefix its own queries can
-        see — and 0 where s is no sequence or has no row in the tile.
-        (`lax` calls, not operators: `kv_contract.py` says why.)"""
-        lo = lax.mul(tile, G_TILE)
-        hi = lax.add(lo, G_TILE)
+    def walk_pages(lo, hi, s):
+        """Pages of sequence `s` that a walk for stream tokens [lo, hi)
+        reads — up to the deepest causal frontier among those of them
+        that are s's, so an early tile of a long prefill reads only the
+        prefix its own queries can see — and 0 where s is no sequence or
+        has no token there. (`lax` calls, not operators: `kv_contract.py`
+        says why.)"""
         row = lax.clamp(0, s, num_seqs - 1)
         qs, ql, kv = q_start_ref[row], q_len_ref[row], kv_len_ref[row]
         end = lax.add(qs, ql)
@@ -128,36 +151,32 @@ def _ragged_kernel(
         return lax.select(
             overlaps, lax.min(cdiv(frontier, page_size), max_pages), 0)
 
-    # A launch is ONE stream of walks — (tile, sequence) pairs in grid
-    # order, a tile's sequences ascending — and the ring runs over their
+    def whole(p):
+        """Is program p's stretch of the stream wholly inside ONE span?
+        Then one tall walk serves it: its first sequence's, every tile
+        merged along M (`kv_contract.tall_tokens` is this test on the
+        host)."""
+        lo = lax.mul(p, height)
+        row = lax.min(tile_seq_ref[lax.mul(p, subs)], num_seqs - 1)
+        qs = q_start_ref[row]
+        return lax.bitwise_and(
+            lax.le(qs, lo),
+            lax.ge(lax.add(qs, q_len_ref[row]), lax.add(lo, height)))
+
+    # A launch is ONE stream of walks — in grid order; a tall program is
+    # one walk, any other program's are (tile, sequence) pairs, its tiles
+    # and a tile's sequences ascending — and the ring runs over their
     # blocks without a break: a walk's block b sits in slot (at + b) %
     # nbuf, `at` the blocks of the walks before it, and the refill of a
     # consumed slot that falls past the walk's last block starts the NEXT
     # walk's block instead, so no walk but the launch's first begins on a
-    # cold DMA (`kv_contract.py` has the numbers). A walk's successor is
-    # the next sequence if it has a row in this tile, else the next
-    # tile's first sequence; every tile runs its first walk even when it
-    # is empty (a tile past the stream's end), so the chain never breaks,
-    # and program 0 begins one walk EARLIER, on an empty walk whose
-    # successor is the launch's first: that is what starts the first
-    # blocks.
-    next_tile = lax.min(lax.add(t, 1), lax.sub(n_tiles, 1))
-    next_first = tile_seq_ref[next_tile]
-    next_pages = lax.select(lax.lt(lax.add(t, 1), n_tiles),
-                            walk_pages(next_tile, next_first), 0)
-    s0 = tile_seq_ref[t]
-
-    def one_walk(carry):
-        s, pages, _, at = carry
+    # cold DMA (`kv_contract.py` has the numbers).
+    def one_walk(s, pages, at, lo, succ, succ_pages, sub):
+        """Sequence s's walk for the tile at `lo` (`sub`; None: for the
+        whole program, tall), then the ring's position after it."""
         n = cdiv(pages, bp)
         row = lax.max(s, 0)
-        span = (tile_start, q_start_ref[row], q_len_ref[row],
-                kv_len_ref[row])
-        after = lax.add(s, 1)
-        stay_pages = walk_pages(t, after)
-        stays = lax.gt(stay_pages, 0)
-        succ = lax.select(stays, after, next_first)
-        succ_pages = lax.select(stays, stay_pages, next_pages)
+        span = (lo, q_start_ref[row], q_len_ref[row], kv_len_ref[row])
 
         # The successor's blocks that land in slots this walk leaves
         # unused start right away; a walk of nbuf blocks or more skips
@@ -182,20 +201,86 @@ def _ragged_kernel(
                              lax.select(own, pages, succ_pages))
 
             inner.update(q_ref, bufs, slot, span,
-                         lax.mul(b, bp * page_size), state, refill)
+                         lax.mul(b, bp * page_size), state, refill, sub)
             return ()
 
         jax.lax.fori_loop(0, n, body, ())
-        return (after, stay_pages, lax.convert_element_type(stays, jnp.int32),
-                mod(lax.add(at, n), nbuf))
+        return mod(lax.add(at, n), nbuf)
 
-    first = lax.eq(t, 0)
-    *_, at = jax.lax.while_loop(
-        lambda carry: lax.gt(carry[2], 0), one_walk,
-        (lax.select(first, lax.sub(s0, 1), s0),
-         lax.select(first, 0, walk_pages(t, s0)),
-         jnp.int32(1), lax.select(first, 0, at_ref[0])))
-    at_ref[0] = at
+    def entry(tile, tokens):
+        """(sequence, pages) of the first walk of the program or tile that
+        begins at tile `tile` (clamped to the launch) and holds `tokens`
+        tokens; no pages past the launch's end."""
+        at_tile = lax.min(tile, lax.sub(n_tiles, 1))
+        lo = lax.mul(at_tile, G_TILE)
+        s = tile_seq_ref[at_tile]
+        return s, lax.select(lax.lt(tile, n_tiles),
+                             walk_pages(lo, lax.add(lo, tokens), s), 0)
+
+    tall = next_tall = False  # static: a rung of one tile a program
+    if subs > 1:
+        tall = whole(prog)
+        after = lax.add(prog, 1)
+        next_tall = lax.bitwise_and(
+            lax.lt(after, n_progs),
+            whole(lax.min(after, lax.sub(n_progs, 1))))
+    at0 = lax.select(lax.eq(prog, 0), 0, at_ref[0])
+
+    def tile_walks(j, at):
+        """The walks of tile j of this program, as every program's were
+        before there was a tall one: the sequences with a row in the
+        tile, in a loop that ends at the last of them. A walk's successor
+        is the next sequence if it has a row in this tile, else the next
+        tile's first walk — the next PROGRAM's after the last tile, which
+        may be a tall one; every tile runs its first walk even when it is
+        empty (a tile past the stream's end), so the chain never breaks,
+        and the launch's first tile begins one walk EARLIER, on an empty
+        walk whose successor is the launch's first: that is what starts
+        the first blocks."""
+        tile = prog if subs == 1 else lax.add(lax.mul(prog, subs), j)
+        lo = lax.mul(tile, G_TILE)
+        hi = lax.add(lo, G_TILE)
+        next_height = G_TILE if subs == 1 else lax.select(
+            lax.bitwise_and(lax.eq(j, subs - 1), next_tall), height, G_TILE)
+        next_first, next_pages = entry(lax.add(tile, 1), next_height)
+        s0 = tile_seq_ref[tile]
+
+        def walk(carry):
+            s, pages, _, at = carry
+            after = lax.add(s, 1)
+            stay_pages = walk_pages(lo, hi, after)
+            stays = lax.gt(stay_pages, 0)
+            at = one_walk(s, pages, at, lo,
+                          lax.select(stays, after, next_first),
+                          lax.select(stays, stay_pages, next_pages), j)
+            return (after, stay_pages,
+                    lax.convert_element_type(stays, jnp.int32), at)
+
+        first = lax.eq(tile, 0)
+        *_, at = jax.lax.while_loop(
+            lambda carry: lax.gt(carry[2], 0), walk,
+            (lax.select(first, lax.sub(s0, 1), s0),
+             lax.select(first, 0, walk_pages(lo, hi, s0)),
+             jnp.int32(1), at))
+        return at
+
+    if subs == 1:
+        at_ref[0] = tile_walks(0, at0)
+    else:
+        @pl.when(lax.bitwise_not(tall))
+        def _():
+            at_ref[0] = jax.lax.fori_loop(0, subs, tile_walks, at0)
+
+        @pl.when(tall)
+        def _():
+            lo = lax.mul(prog, height)
+            s, pages = entry(lax.mul(prog, subs), height)
+            for i in range(nbuf):  # the launch's first blocks
+                stream.start(i, s, i, pages, cond=lax.eq(prog, 0))
+            at_ref[0] = one_walk(
+                s, pages, at0, lo,
+                *entry(lax.mul(lax.add(prog, 1), subs),
+                       lax.select(next_tall, height, G_TILE)), None)
 
     inner.finish(o_ref, state)
 
@@ -219,11 +304,14 @@ def ragged_paged_attention_pallas(
     B, max_pages = page_table.shape
     lanes = k_cache.shape[-1]
     Hk = lanes // hd
-    # Always the Mxu inner product: a tile's G_TILE rows share each block.
+    # Always the Mxu inner product: a tile's G_TILE rows share each block,
+    # and on a rung that holds a whole stretch a program's tiles do.
+    height = programs_height(T)
     inner = make_inner(None, rows=G_TILE, group=H // Hk, num_kv_heads=Hk,
-                       head_dim=hd, page_size=page_size)
+                       head_dim=hd, page_size=page_size,
+                       subs=height // G_TILE)
 
-    Tp = -(-T // G_TILE) * G_TILE
+    Tp = -(-T // height) * height
     n_tiles = Tp // G_TILE
     # First sequence overlapping each tile: spans are contiguous and
     # ascending, so it is the first whose END lies past the tile start.
@@ -235,7 +323,7 @@ def ragged_paged_attention_pallas(
     pools = [k_cache, v_cache]
     if k_scale is not None:  # an int8 pool's scale planes
         pools += [k_scale, v_scale]
-    nbuf, grid_spec = ring_grid_spec(inner, RING, (n_tiles,), 6, pools)
+    nbuf, grid_spec = ring_grid_spec(inner, RING, (Tp // height,), 6, pools)
     kernel = functools.partial(
         _ragged_kernel,
         inner=inner,

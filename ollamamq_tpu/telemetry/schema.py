@@ -355,6 +355,12 @@ ATTN_CTX_ROWS_TOTAL = REGISTRY.counter(
     "Cached K/V rows those steps' walks have to read at the least, a "
     "layer: each span's context once (a fused scan's pass: each active "
     "slot's)", labels=("model",))
+ATTN_TALL_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_attn_tall_tokens_total",
+    "Stream tokens of launched ragged steps that the ragged attention "
+    "kernel served a whole stretch at a time (kv_contract.TALL consecutive "
+    "tokens inside one span share each K/V block's trip); 0 without the "
+    "kernel", labels=("model",))
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

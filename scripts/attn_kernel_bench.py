@@ -1,9 +1,11 @@
 """Seconds a launch of the two paged-attention kernels, by inner product
 and head shape, on the chip: `chiprun -- python scripts/attn_kernel_bench.py`.
 
-For each published head shape (H, Hk, hd) and each inner product a kernel
-can be built with (`ops/pallas/kv_contract.py`: the decode kernel takes
-either, the ragged kernel has one) it builds a bf16 pool at the CLI's defaults
+For each published head shape (H, Hk, hd) — the sixth, (16, 2, 256), is
+Qwen3-Next's gated attention: two lane tiles a kv head — and each inner
+product a kernel can be built with (`ops/pallas/kv_contract.py`: the decode
+kernel takes either, the ragged kernel has one) it builds a bf16 pool at the
+CLI's defaults
 (1024 pages of 32 tokens, 64 slots) with 64 sequences of 200-380 tokens
 of context, checks the kernel against the jnp reference, and times
 LAUNCHES calls chained inside one jit (each call's q is the last one's
@@ -19,6 +21,24 @@ says what its walks need (`pages_live`), what whole blocks move
 (sequence, block). It also asks what Mosaic's default-precision float32
 matmul keeps of its operand (`f32_matmul_keeps`): the Vpu body's
 `p @ seg_t` is one. One JSON line a measurement; exits 1 without a TPU.
+
+`--traffic raggedlong` (PR 48) is a long prompt's chunk on the ragged
+kernel: 5 one-token rows, then one 507-token span that ENDS at the context
+(a later chunk of its prompt), every sequence at one context (`--contexts`,
+default 4 k / 8 k / 16 k) in a pool sized for them, checked against the jnp
+reference on a sample of the stream (`LONG_CHECKED`) — the step of
+`qwen3-next-80b-a3b-ep4-d12.longctx` at (16, 2, 256), `--shapes 5`. Two
+rows a (context, variant): `raggedlong_head` is the stream's first 64
+tokens by themselves (the rows and the span's first 59: a rung of one tile
+a program, every trip a short one — `us_a_short_trip`), `raggedlong` the
+step: `trips_short` / `trips_tall` the (tile, block) trips of its walks of
+8 tokens and of its walks of a whole stretch
+(`kv_contract.programs_height`), `us_a_tall_trip` what is left of its
+launch after its short trips at the head row's price, a trip. `--set
+kv_contract.TALL=32` sweeps the stretch, `--set
+ragged_attention.G_TILE=64,kv_contract.G_TILE=64` makes EVERY tile tall
+(what decode rows would pay), `--set kv_contract.TALL=8` is the kernel of
+one tile a program at every rung.
 
 `--traffic latent` times the latent-attention kernel instead
 (`ops/pallas/mla_attention.py:mla_sparse_paged_attention_pallas`) on the
@@ -70,7 +90,7 @@ MODULES = {m.__name__.rsplit(".", 1)[1]: m
            for m in (kv_contract, mla_attention, paged_attention,
                      ragged_attention)}
 SHAPES = ((28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
-          (30, 30, 128))
+          (30, 30, 128), (16, 2, 256))
 B, MP, PS, NP, LAYER = 64, 256, 32, 1024, 1
 # The jnp reference gathers a table's whole width, [B, width*PS, lanes]:
 # it is fed the columns a context here can reach and no more.
@@ -87,6 +107,15 @@ LAT_CONTEXTS = (4096, 8192, 12288, 16384)
 # float32): the one-token rows, the span's first and last tokens, tokens at
 # both sides of a tile's edge and of the tile's halves.
 LAT_CHECKED = (0, 1, 2, 3, 4, 5, 12, 13, 15, 16, 23, 24, 255, 256, 511)
+# The long-prefill traffic (`raggedlong`): the step of the
+# `qwen3-next-80b-a3b-ep4-d12.longctx` cell, which has the latent cell's
+# composition. The jnp reference gathers [tokens, block, H, hd] float32 a
+# block, so it is asked for a sample of the stream: the one-token rows, the
+# span's first tokens, both sides of tile and stretch edges, the last token.
+LONG_ROWS, LONG_SPAN = LAT_ROWS, LAT_SPAN
+LONG_CONTEXTS = (4096, 8192, 16384)
+LONG_CHECKED = (0, 1, 2, 3, 4, 5, 6, 7, 8, 15, 16, 58, 59, 63, 64, 65, 71, 72,
+                127, 128, 255, 256, 300, 447, 448, 504, 511)
 
 
 def f32_matmul_keeps() -> dict:
@@ -148,48 +177,79 @@ def dma_probe(lanes) -> list:
     return out
 
 
-def batch(rng, n_decode, spans, context=None):
+def batch(rng, n_decode, spans, context=None, span_ends=None, B=B, mp=MP,
+          pool_pages=NP):
     """(page_table, tok_seq, tok_pos, kv_len, q_start, q_len, T). Decode
-    rows hold 200-380 tokens of context, or `context` each; pages are
-    handed out in order and wrap around the pool when a sweep asks for
-    more than it holds (the kernels only read)."""
+    rows hold 200-380 tokens of context, or `context` each; a span is a
+    prompt's first tokens, or with `span_ends` a later chunk that ends at
+    that context; pages are handed out in order and wrap around the pool
+    when a sweep asks for more than it holds (the kernels only read)."""
     rows = [(1, context - 1 if context else int(rng.integers(200, 380)))
             for _ in range(n_decode)]
-    rows += [(n, 0) for n in spans]
+    rows += [(n, span_ends - n if span_ends else 0) for n in spans]
     T = sum(n for n, _ in rows)
-    pt = np.zeros((B, MP), np.int32)
+    pt = np.zeros((B, mp), np.int32)
     q_len, kv_len = np.zeros(B, np.int32), np.zeros(B, np.int32)
     q_start = np.full(B, T, np.int32)
     tok_seq, tok_pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
     off, page = 0, 0
     for s, (n, prefix) in enumerate(rows):
         need = -(-(prefix + n) // PS)
-        pt[s, :need] = 1 + (page + np.arange(need)) % (NP - 1)
+        pt[s, :need] = 1 + (page + np.arange(need)) % (pool_pages - 1)
         page += need
         q_len[s], kv_len[s], q_start[s] = n, prefix + n, off
         tok_seq[off:off + n] = s
         tok_pos[off:off + n] = prefix + np.arange(n)
         off += n
-    assert context or page < NP, page
+    assert context or page < pool_pages, page
     return [jnp.asarray(a) for a in (pt, tok_seq, tok_pos, kv_len, q_start,
                                      q_len)], T
 
 
-def walks(traffic, kv_len, q_start, q_len, T) -> list:
-    """Pages of each (program, sequence) walk of a launch: a decode
+def walks(traffic, kv_len, q_start, q_len, T) -> tuple:
+    """Pages of each walk of a launch, (short walks, tall walks): a decode
     program walks its row's context, a ragged tile each overlapping
-    sequence's up to the tile's deepest causal frontier."""
+    sequence's up to the tile's deepest causal frontier — and a program of
+    the ragged kernel whose stretch of the stream lies inside one span
+    (`kv_contract.programs_height`: a tree that has none walks tiles
+    alone) walks that span's once, tall, up to the stretch's."""
     kv_len, q_start, q_len = (np.asarray(a) for a in (kv_len, q_start,
                                                       q_len))
     if traffic == "decode":
-        return [-(-int(n) // PS) for n in kv_len]
-    out = []
-    for lo in range(0, T, kv_contract.G_TILE):
-        hi = lo + kv_contract.G_TILE
+        return [-(-int(n) // PS) for n in kv_len], []
+    tile = kv_contract.G_TILE
+    height = getattr(kv_contract, "programs_height", lambda T: tile)(T)
+    short, tall = [], []
+    for lo in range(0, T, tile):
+        at = lo // height * height
+        whole = height > tile and any(
+            qs <= at and qs + ql >= at + height
+            for qs, ql in zip(q_start, q_len))
+        if whole and lo > at:
+            continue  # its program's one walk is counted at its first tile
+        hi = lo + (height if whole else tile)
         for qs, ql, kv in zip(q_start, q_len, kv_len):
             if ql > 0 and qs < hi and qs + ql > lo:
                 last_pos = kv - ql + (min(hi, qs + ql) - 1 - qs)
-                out.append(-(-int(last_pos + 1) // PS))
+                (tall if whole else short).append(-(-int(last_pos + 1) // PS))
+    return short, tall
+
+
+def long_trips(traffic, us_short, variant, us, short, tall) -> dict:
+    """µs a (tile, block) trip of a `raggedlong` launch, short trips and
+    tall ones apart: the variant's `raggedlong_head` row — the step's first
+    64 tokens by themselves, every trip of it a short one — prices a short
+    trip, and what is left of the step's launch after its short trips is
+    its tall trips'. A tree or a `--set` with no tall walk has `tall` 0."""
+    out = {"trips_short": short, "trips_tall": tall}
+    if traffic == "raggedlong_head":
+        us_short[variant] = us / short
+    out["us_a_short_trip"] = round(us_short[variant], 4)
+    if tall:
+        out["us_a_tall_trip"] = round(
+            (us - short * us_short[variant]) / tall, 4)
+    elif traffic == "raggedlong":
+        out["us_a_trip"] = round(us / short, 4)
     return out
 
 
@@ -393,11 +453,15 @@ def main() -> int:
                          "512 lanes")
     ap.add_argument("--traffic", nargs="*",
                     default=["decode", "ragged64", "ragged512"],
-                    choices=["decode", "ragged64", "ragged512", "latent"],
-                    help="`latent`: the latent-attention kernel on the "
-                         "DeepSeek-V3.2 cell's step, a row a context "
-                         "(--contexts, default 4096 8192 12288 16384) and "
-                         "a --set mla_attention.NAME=VALUE")
+                    choices=["decode", "ragged64", "ragged512", "raggedlong",
+                             "latent"],
+                    help="`raggedlong`: the ragged kernel on a long "
+                         "prompt's chunk, two rows a context (--contexts, "
+                         "default 4096 8192 16384); `latent`: the "
+                         "latent-attention kernel on the DeepSeek-V3.2 "
+                         "cell's step, a row a context (--contexts, default "
+                         "4096 8192 12288 16384) and a --set "
+                         "mla_attention.NAME=VALUE")
     ap.add_argument("--shapes", type=int, nargs="*",
                     default=list(range(len(SHAPES))),
                     help="indices into SHAPES")
@@ -417,26 +481,53 @@ def main() -> int:
         latent(args, ([] if args.only_set else [{}]) + args.variants)
     variants = [] if args.only_set else [("vpu", {}), ("mxu", {})]
     variants += [(None, v) for v in args.variants]
-    traffics = [("decode", 64, (), c) for c in args.contexts or [None]]
-    traffics += [("ragged64", 64, (), c) for c in args.contexts or [None]]
+    # (traffic, decode rows, spans, context, where a span ends)
+    traffics = [("decode", 64, (), c, None) for c in args.contexts or [None]]
+    traffics += [("ragged64", 64, (), c, None)
+                 for c in args.contexts or [None]]
     if not args.contexts:
-        traffics.append(("ragged512", 56, (228, 228), None))
+        traffics.append(("ragged512", 56, (228, 228), None, None))
     traffics = [t for t in traffics if t[0] in args.traffic]
+    if "raggedlong" in args.traffic:
+        # The stream's first 64 tokens by themselves price a short trip
+        # (one tile a program, before and after PR 48), then the step.
+        head = 64 - LONG_ROWS
+        for c in args.contexts or LONG_CONTEXTS:
+            traffics += [
+                ("raggedlong_head", LONG_ROWS, (head,), c,
+                 c - (LONG_SPAN - head)),
+                ("raggedlong", LONG_ROWS, (LONG_SPAN,), c, c)]
+    long_mp = max(args.contexts or LONG_CONTEXTS) // PS
     for H, Hk, hd in (SHAPES[i] for i in args.shapes if traffics):
         kc, vc = (jnp.asarray(rng.standard_normal((2, NP * PS, Hk * hd)),
                               jnp.bfloat16) for _ in range(2))
-        for traffic, n_dec, spans, context in traffics:
+        if "raggedlong" in args.traffic:  # a pool that holds every context
+            long_pages = (LONG_ROWS + 1) * long_mp + 1
+            kc_long, vc_long = (jax.random.normal(
+                key, (2, long_pages * PS, Hk * hd), jnp.bfloat16)
+                for key in jax.random.split(jax.random.PRNGKey(args.seed)))
+        us_short = {}  # a variant's short trip, from its `raggedlong_head`
+        for traffic, n_dec, spans, context, span_ends in traffics:
+            long = traffic.startswith("raggedlong")
             (pt, tok_seq, tok_pos, kv_len, q_start, q_len), T = batch(
-                np.random.default_rng(args.seed), n_dec, spans, context)
+                np.random.default_rng(args.seed), n_dec, spans, context,
+                span_ends, **(dict(B=8, mp=long_mp, pool_pages=long_pages)
+                              if long else {}))
             q = jnp.asarray(rng.standard_normal((T, H, hd)), jnp.bfloat16)
             ref_pt = pt[:, :max(REF_PAGES, -(-(context or 0) // PS))]
+            checked = np.arange(T)
+            pools = (kc, vc)
+            if long:
+                pools = (kc_long, vc_long)
+                checked = np.asarray([t for t in LONG_CHECKED if t < T])
             if traffic == "decode":
                 ref = paged_decode_attention_any(
-                    "jnp", q, kc, vc, LAYER, ref_pt, kv_len, PS)
+                    "jnp", q, *pools, LAYER, ref_pt, kv_len, PS)
             else:
                 ref = ragged_attention_any(
-                    "jnp", q, kc, vc, LAYER, ref_pt, tok_seq, tok_pos,
-                    kv_len, q_start, q_len, PS)
+                    "jnp", q[checked], *pools, LAYER, ref_pt,
+                    tok_seq[checked], tok_pos[checked], kv_len, q_start,
+                    q_len, PS)
             for inner, consts in variants:
                 if traffic != "decode" and inner == "vpu":
                     continue  # the ragged kernel has one inner product
@@ -447,7 +538,8 @@ def main() -> int:
                         group=H // Hk, num_kv_heads=Hk, head_dim=hd,
                         page_size=PS)
                     bp = built.block_pages
-                    pages = walks(traffic, kv_len, q_start, q_len, T)
+                    short, tall = walks(traffic, kv_len, q_start, q_len, T)
+                    pages = short + tall
                     blocks = sum(-(-n // bp) for n in pages)
                     if traffic == "decode":
                         def fn(q, kc, vc, pt, kv_len, inner=inner):
@@ -455,21 +547,22 @@ def main() -> int:
                                 paged_attention.paged_decode_attention_pallas
                             return launch(q, kc, vc, LAYER, pt, kv_len, PS,
                                           inner=inner)
-                        operands = (kc, vc, pt, kv_len)
+                        operands = (*pools, pt, kv_len)
                     else:
                         def fn(q, kc, vc, pt, qs, ql, kl):
                             launch = \
                                 ragged_attention.ragged_paged_attention_pallas
                             return launch(q, kc, vc, LAYER, pt, qs, ql, kl,
                                           PS)
-                        operands = (kc, vc, pt, q_start, q_len, kv_len)
+                        operands = (*pools, pt, q_start, q_len, kv_len)
                     row = {
                         "shape": [H, Hk, hd], "traffic": traffic, "tokens": T,
                         "context": context or "200-380",
                         "inner": built.name,
                         "set": names}
                     try:
-                        out = np.asarray(fn(q, *operands), np.float32)
+                        out = np.asarray(fn(q, *operands)[checked],
+                                         np.float32)
                         ms = timed(fn, q, *operands) * 1e3
                         row.update({
                             "ms_a_launch": round(ms, 4),
@@ -480,6 +573,11 @@ def main() -> int:
                                 out - np.asarray(ref, np.float32)).max()),
                             "finite": bool(np.isfinite(out).all()),
                         })
+                        if long:
+                            row.update(long_trips(
+                                traffic, us_short, str(names), ms * 1e3,
+                                *(sum(-(-n // bp) for n in w)
+                                  for w in (short, tall))))
                     except Exception as e:  # noqa: BLE001 — a variant the
                         # compiler refuses is a row, not the end of the run
                         row["error"] = str(e)[:300]

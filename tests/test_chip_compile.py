@@ -57,7 +57,7 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _shapes(sharding_of, kv_dtype=jnp.bfloat16, heads=(H, HK, HD)):
+def _shapes(sharding_of, kv_dtype=jnp.bfloat16, heads=(H, HK, HD), T=T):
     """(q_ragged, q_decode, pool, page_table, [T] meta, [B] meta) as
     ShapeDtypeStructs; `sharding_of(spec)` places each."""
     def s(shape, dt, spec=P()):
@@ -124,19 +124,41 @@ def test_ragged_kernel_compiles_under_a_4way_tensor_shard_map(v5e):
                          ids=["ragged", "decode"])
 @pytest.mark.parametrize("tp,heads", [
     (1, (28, 4, 128)), (1, (8, 2, 128)), (1, (16, 16, 128)),
-    (1, (32, 8, 64)), (1, (30, 30, 128)),
+    (1, (32, 8, 64)), (1, (30, 30, 128)), (1, (16, 2, 256)),
     (4, (28, 4, 128)), (4, (32, 8, 128)), (4, (16, 16, 128)),
     (4, (32, 8, 64))],
     ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else f"tp{v}")
 def test_published_head_shapes_compile_for_v5e(v5e, compile_fn, tp, heads):
+    _compile_at(v5e, compile_fn, tp, heads, T)
+
+
+def _compile_at(v5e, compile_fn, tp, heads, tokens):
     if tp == 1:
         mesh, one = None, SingleDeviceSharding(v5e.devices[0])
         sharding_of = lambda spec: one  # noqa: E731
     else:
         mesh = make_mesh(tp=tp, devices=v5e.devices)
         sharding_of = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
-    compiled = compile_fn(_shapes(sharding_of, heads=heads), mesh=mesh)
+    compiled = compile_fn(_shapes(sharding_of, heads=heads, T=tokens),
+                          mesh=mesh)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The ragged kernel on a rung that holds whole stretches (PR 48: 128
+# tokens and more; the ladder's largest here): a program of 64 tokens, its
+# tiles merged along M for the tall trip (`q_ref[:, t]` and the state's
+# `[subs, Mp, .]` reshaped to `[subs * Mp, .]`, free where Mp is a multiple
+# of 16), a tile's state at a DYNAMIC index of the scratch in the other
+# body, scores of `[512, 128]` float32 and P's three terms in VMEM — at the
+# widest block (30 lane tiles) and the widest tile (256 lanes) a cell has,
+# one chip and under the 4-way shard_map.
+@pytest.mark.parametrize("tp,heads", [
+    (1, (28, 4, 128)), (1, (8, 2, 128)), (1, (16, 16, 128)),
+    (1, (32, 8, 64)), (1, (30, 30, 128)), (1, (16, 2, 256)),
+    (4, (28, 4, 128)), (4, (32, 8, 128))],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else f"tp{v}")
+def test_tall_rung_compiles_for_v5e(v5e, tp, heads):
+    _compile_at(v5e, _compile_ragged, tp, heads, 512)
 
 
 @pytest.mark.parametrize("compile_fn", [_compile_ragged, _compile_decode],

@@ -211,6 +211,7 @@ def test_the_engine_serves_it_and_counts_its_attention(monkeypatch):
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     for s in samples:
         assert s["attn_pairs"] >= s["attn_ctx_rows"] >= 1
+        assert s["attn_tall_tokens"] == 0  # the jnp path serves here
         assert "mla_rows" not in s and "lin_step_rows" in s
         assert s["moe_assignments"] >= 0
         if s["mode"] == "decode":  # a scan's pass reads each slot's context

@@ -12,6 +12,8 @@ from ollamamq_tpu.engine import kv_cache as kvc
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.attention import (ragged_paged_attention,
                                         ragged_paged_attention_blockwise)
+from ollamamq_tpu.ops.pallas import kv_contract
+from ollamamq_tpu.ops.pallas.kv_contract import TALL, tall_tokens
 from ollamamq_tpu.ops.pallas.ragged_attention import (
     ragged_paged_attention_pallas)
 
@@ -148,6 +150,154 @@ def test_pallas_matches_reference(case, layer, poison_trash_page):
         **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
 
 
+# The tile follows the span (PR 48): on a rung of 2 * TALL tokens or more a
+# program holds TALL tokens, and one whose stretch lies inside ONE span
+# walks that span's context once for all of them; every other program
+# walks its tiles as before. `tall`: the stream tokens in whole stretches,
+# which `kv_contract.tall_tokens` — the engine's counter — must say too.
+# Pages of 8 tokens put four in a block of 32; a table of 48 pages holds
+# contexts up to 384.
+_DECODE5 = [(1, 9), (1, 33), (1, 64), (1, 65), (1, 100)]
+TALL_CASES = {
+    # 5 decode rows, then a span from mid-stretch to mid-stretch: tokens
+    # [5, 205), whole over [64, 192)
+    "mid-to-mid": dict(spans=_DECODE5 + [(200, 200)], tall=128),
+    # the cell's step in small: the span is NOT its prompt's first chunk
+    "later-chunk": dict(spans=_DECODE5 + [(187, 379)], tall=128),
+    # a span of exactly TALL tokens on a stretch's edges, a shorter one
+    "exactly-tall": dict(spans=[(TALL, TALL), (30, 41), (34, 34)], tall=TALL),
+    # TALL - 1 tokens fill no stretch; the next span's TALL do, exactly,
+    # over a cached prefix
+    "one-short": dict(spans=[(TALL - 1, TALL - 1), (1, 7), (TALL, 80)],
+                      tall=TALL),
+    # two whole stretches and a tail of 3 that shares its tile with a
+    # decode row and the launch's padding
+    "twice-and-3": dict(spans=[(2 * TALL + 3, 2 * TALL + 3), (1, 3)],
+                        tall=2 * TALL),
+    # two spans: the first ends inside stretch 0, the second fills [64, 128)
+    "one-of-two": dict(spans=[(40, 40), (100, 120)], tall=TALL),
+    # the launch's FIRST program is tall (it starts the ring itself), and
+    # so is its last
+    "first-and-last": dict(spans=[(2 * TALL, 2 * TALL + 16)], tall=2 * TALL),
+    # a rung of fewer than 2 * TALL tokens holds no tall body
+    "short-rung": dict(spans=[(TALL + 8, TALL + 8), (1, 5)], tall=0),
+    # 2 * TALL tokens and more, and no stretch inside one span
+    "no-stretch": dict(spans=[(1, 5 + 3 * i) for i in range(70)]
+                       + [(TALL - 4, TALL)], tall=0),
+}
+for _c in TALL_CASES.values():
+    _c.update(B=len(_c["spans"]) + 2, MP=48)
+TALL_SHAPES = [(28, 4, 128), (32, 8, 64), (16, 16, 128), (16, 2, 256)]
+for H, Hk, hd in TALL_SHAPES:
+    # at a block of 128 tokens: the span's tall walks read 2 and 3 blocks
+    TALL_CASES["H%d-Hk%d-hd%d" % (H, Hk, hd)] = dict(
+        spans=[(1, 1), (1, 33), (1, 128), (1, 129), (1, 200), (200, 290)],
+        B=8, PS=32, MP=12, H=H, Hk=Hk, hd=hd, seed=H, tall=128)
+TALL_CASES["H28-Hk4-hd128-bf16"] = dict(TALL_CASES["H28-Hk4-hd128"],
+                                        dtype=jnp.bfloat16)
+
+
+def _tall_case(name):
+    case = dict(TALL_CASES[name])
+    tall = case.pop("tall")
+    return case, tall, sum(n for n, _ in case["spans"])
+
+
+@pytest.mark.parametrize("name", TALL_CASES)
+def test_tall_tokens_counts_the_whole_stretches(name):
+    case, tall, T = _tall_case(name)
+    spans = [n for n, _ in case["spans"]]
+    assert tall_tokens(spans, T) == tall
+    # On a rung that holds the tall body: the stretches whose every token
+    # is one sequence's, counted token by token.
+    seq = np.repeat(np.arange(len(spans)), spans)
+    seq = np.pad(seq, (0, -len(seq) % TALL), constant_values=-1)
+    stretches = seq.reshape(-1, TALL)
+    assert tall_tokens(spans, max(T, 2 * TALL)) == TALL * int(
+        ((stretches == stretches[:, :1]).all(1) & (stretches[:, 0] >= 0)
+         ).sum())
+
+
+@pytest.mark.parametrize("name", TALL_CASES)
+def test_step_sample_carries_attn_tall_tokens(name):
+    """`ModelRuntime._note_attn` puts the kernel's own count on the step's
+    sample and the /metrics series, beside `attn_pairs`: a ragged step's,
+    where the kernel serves; nothing tall in a fused scan or on the jnp
+    path."""
+    import types
+
+    from ollamamq_tpu.engine.engine import ModelRuntime
+    from ollamamq_tpu.telemetry import schema as tm
+
+    case, tall, T = _tall_case(name)
+    spans, kv = zip(*case["spans"])
+    series = [c.labels(model="tall-" + name) for c in (
+        tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
+        tm.ATTN_TALL_TOKENS_TOTAL)]
+    rt = types.SimpleNamespace(
+        cfg=MODEL_CONFIGS["test-tiny"], ATTN_FIELDS=ModelRuntime.ATTN_FIELDS,
+        _tm_attn=series, _tall_tokens=tall_tokens)
+    noted = {}
+    sp = types.SimpleNamespace(note=noted.update)
+    ModelRuntime._note_attn(rt, sp, list(spans), list(kv), stream_len=T)
+    assert noted["attn_tall_tokens"] == tall
+    assert noted["attn_ctx_rows"] == sum(kv)
+    assert series[2].value == tall
+    ModelRuntime._note_attn(rt, sp, list(spans), list(kv), scan=True)
+    assert noted["attn_tall_tokens"] == 0
+    rt._tall_tokens = None  # the jnp path: no kernel, nothing tall
+    ModelRuntime._note_attn(rt, sp, list(spans), list(kv), stream_len=T)
+    assert noted["attn_tall_tokens"] == 0 and series[2].value == tall
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("name", TALL_CASES)
+def test_tall_stretches_match_reference(name, layer, poison_trash_page):
+    case, _, _ = _tall_case(name)
+    q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(**case)
+    ref = ragged_paged_attention(_f32(q), _f32(k), _f32(v), layer, pt,
+                                 tok_seq, tok_pos, kv_len, PS)
+    clean = ragged_paged_attention_pallas(q, k, v, layer, pt, qs, ql,
+                                          kv_len, PS, interpret=True)
+    # A tall walk's last block, too, reads the trash page past the span's
+    # last page.
+    out = ragged_paged_attention_pallas(
+        q, poison_trash_page(k, PS, layer), poison_trash_page(v, PS, layer),
+        layer, pt, qs, ql, kv_len, PS, interpret=True)
+    np.testing.assert_array_equal(np.asarray(_f32(out)),
+                                  np.asarray(_f32(clean)))
+    np.testing.assert_allclose(
+        np.asarray(_f32(out)), np.asarray(ref),
+        **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
+
+
+@pytest.mark.parametrize("name", TALL_CASES)
+def test_tokens_outside_a_whole_stretch_keep_every_bit(name, monkeypatch):
+    """A stream with no whole stretch gives bit for bit what the kernel
+    gave when every program was one tile (its parent's body: TALL beyond
+    every rung) — and in a stream that has one, so does every token
+    outside it: decode rows, a span's head and tail. Inside, the rows of
+    TALL tokens share one contraction where 8 did; what a row sees is the
+    same pairs, in a matmul of another height."""
+    case, _, T = _tall_case(name)
+    q, k, v, pt, _, _, kv_len, qs, ql, PS = _case(**case)
+    out = ragged_paged_attention_pallas(q, k, v, 1, pt, qs, ql, kv_len, PS,
+                                        interpret=True)
+    monkeypatch.setattr(kv_contract, "TALL", 1 << 30)  # no rung holds one
+    parent = ragged_paged_attention_pallas.__wrapped__(
+        q, k, v, 1, pt, qs, ql, kv_len, PS, interpret=True)
+    out, parent = np.asarray(_f32(out)), np.asarray(_f32(parent))
+    tall = np.zeros(T, bool)
+    if T >= 2 * TALL:
+        for start, n in zip(np.asarray(qs), np.asarray(ql)):
+            first = -(-start // TALL) * TALL
+            tall[first:first + (start + n - first) // TALL * TALL] = True
+    np.testing.assert_array_equal(out[~tall], parent[~tall])
+    np.testing.assert_allclose(
+        out[tall], parent[tall],
+        **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
+
+
 @pytest.mark.parametrize("Hk,H", [(1, 4), (4, 4)])
 def test_pallas_mqa_and_group1(Hk, H):
     q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(
@@ -197,6 +347,12 @@ def _count(eqns, name):
     return sum(e.primitive.name == name for e in eqns)
 
 
+BODY_SHAPES = ((28, 4, 128),     # 4 lane tiles
+               (16, 16, 128),    # 16
+               (32, 8, 64),      # 4, two heads each
+               (30, 30, 128))    # 30
+
+
 def test_traced_body_does_not_grow_with_tile_height_times_kv_heads():
     """The defect that refused PR 33, held shut without a chip. A ragged
     step program is traced, lowered and keyed once a rung of the token
@@ -206,58 +362,117 @@ def test_traced_body_does_not_grow_with_tile_height_times_kv_heads():
     8 x 16 copies of the inner product at OLMoE's 16 kv heads (8354
     equations, 256 `dot_general`s, where PR 32's VPU body had 4378). The
     walk is a loop in the program now, so the body holds one copy a lane
-    tile — a Q.K^T and a P.V contraction each — and nothing a successor."""
+    tile — a Q.K^T and a P.V contraction each — and nothing a successor.
+    A rung of fewer than 2 * TALL tokens holds no whole stretch beside a
+    decode row and traces this body alone: its parent's."""
     few, many, packed, widest = (
-        list(_walk(_kernel_jaxpr(*shape))) for shape in
-        ((28, 4, 128),     # 4 lane tiles
-         (16, 16, 128),    # 16
-         (32, 8, 64),      # 4, two heads each
-         (30, 30, 128)))   # 30
+        list(_walk(_kernel_jaxpr(*shape))) for shape in BODY_SHAPES)
     assert [_count(e, "dot_general") for e in (few, many, packed, widest)
             ] == [8, 32, 8, 60]
     # Four times the kv heads, under three times the body; and an eighth
     # of PR 33's. What the body measures when written (PR 38): 449, 1145,
     # 449 and 1957 equations — 58 a lane tile and 217 of everything else,
     # none of them a nested jit (`kv_contract.py`: scalars are `lax`
-    # calls, because every operator on a tracer is one).
+    # calls, because every operator on a tracer is one). PR 48: 446, 1142,
+    # 446, 1954.
     assert len(many) <= 3 * len(few)
     assert len(few) <= 470 and len(packed) <= 470
     assert len(many) <= 1200 and len(widest) <= 2050
     assert _count(few, "pjit") == 0
 
 
-def test_page_stream_has_one_predicate_a_block():
-    """The unit of the page stream is the block (kv_contract.PageStream):
-    a block's pages start under ONE `pl.when` and are waited for once a
-    pool under none — a predicate a page, or a page-table read in the
-    wait, cannot come back unseen. The walk over a tile's sequences is
-    the kernel's one loop at top level; in it, what a short walk starts
-    for its successor before its loop, under one predicate that a walk of
-    two blocks or more skips as a whole, and the loop over the walk's
-    blocks."""
-    kernel = _kernel_jaxpr(28, 4, 128)
-    top = [e.primitive.name for e in kernel.eqns]
-    assert top.count("while") == 1 and "scan" not in top
-    assert "cond" not in top  # nothing at top level is skipped
-    walk = next(e for e in kernel.eqns if e.primitive.name == "while")
-    walk_body = walk.params["body_jaxpr"].jaxpr
+def test_a_tall_rung_holds_two_bodies_and_no_more():
+    """The tile follows the span (PR 48): a rung of 2 * TALL tokens or
+    more traces the tall body beside the tile's, for the program whose
+    stretch of the stream is one span's — and still nothing a successor, a
+    tile of the program or a block: the program's tiles are a loop in the
+    program. The tall trip's lane tiles are a loop in the program too
+    beyond `TALL_UNROLL` of them (ONE more copy of the inner product at
+    any width: what a rung costs every start does not grow with the kv
+    heads again), and unrolled up to it: one more copy a lane tile. When
+    written: 770, 1466, 770 and 2278 equations — 324 more than the short
+    rung's at any width — and 715 for 334 at (16, 2, 256), two tiles."""
+    short = [list(_walk(_kernel_jaxpr(*shape))) for shape in BODY_SHAPES]
+    tall = [list(_walk(_kernel_jaxpr(*shape, T=2 * TALL)))
+            for shape in BODY_SHAPES]
+    assert [_count(two, "dot_general") - _count(one, "dot_general")
+            for one, two in zip(short, tall)] == [2, 2, 2, 2]
+    for one, two in zip(short, tall):
+        assert len(two) <= len(one) + 350
+        assert _count(two, "pjit") == 0
+    one, two = (list(_walk(_kernel_jaxpr(16, 2, 256, T=T)))
+                for T in (TALL, 2 * TALL))
+    assert kv_contract.TALL_UNROLL == 2  # (16, 2, 256): unrolled
+    assert _count(one, "dot_general") == 4 and _count(two, "dot_general") == 8
+    assert len(one) <= 350 and len(two) <= len(one) + 400
+    # ...whatever the rung: the programs are a grid, not a trace.
+    assert len(list(_walk(_kernel_jaxpr(*BODY_SHAPES[0], T=512)))) == len(
+        tall[0])
+
+
+def _stream_of_one_walk(walk_body):
+    """What a walk of either body is made of: before its loop, under one
+    predicate that a walk of two blocks or more skips as a whole, what it
+    starts for its successor — the ring's two slots, each under its own
+    test; then the loop over its blocks, a block of four pages and two
+    pools refilled under its one predicate and waited for once a pool
+    under none."""
     names = [e.primitive.name for e in walk_body.eqns]
-    assert names.count("while") == 1 and names.count("cond") == 1
+    assert names.count("while") == 1
     blocks = next(e for e in walk_body.eqns if e.primitive.name == "while")
     in_loop = list(_walk(blocks.params["body_jaxpr"].jaxpr))
-    # One block of four pages, two pools: the refill under its one
-    # predicate, the wait once a pool under none.
     assert _count(in_loop, "cond") == 1
     assert _count(in_loop, "dma_start") == 8
     assert _count(in_loop, "dma_wait") == 2
     waits = [e for e in blocks.params["body_jaxpr"].jaxpr.eqns
              if e.primitive.name == "dma_wait"]
     assert len(waits) == 2  # at the loop's own level: no predicate
-    # Before the loop: the ring's two slots, each under its own test
-    # inside the one predicate.
-    before = next(e for e in walk_body.eqns if e.primitive.name == "cond")
+    before = [e for e in walk_body.eqns if e.primitive.name == "cond"][-1]
     assert _count(_inside(before), "cond") == 2
     assert _count(_inside(before), "dma_start") == 16
+    return names.count("cond")
+
+
+def test_page_stream_has_one_predicate_a_block():
+    """The unit of the page stream is the block (kv_contract.PageStream):
+    a block's pages start under ONE `pl.when` and are waited for once a
+    pool under none — a predicate a page, or a page-table read in the
+    wait, cannot come back unseen. On a rung of one tile a program the
+    walk over the tile's sequences is the kernel's one loop at top level;
+    in it, what a short walk starts for its successor and the loop over
+    the walk's blocks."""
+    kernel = _kernel_jaxpr(28, 4, 128)
+    top = [e.primitive.name for e in kernel.eqns]
+    assert top.count("while") == 1 and "scan" not in top
+    assert "cond" not in top  # nothing at top level is skipped
+    walk = next(e for e in kernel.eqns if e.primitive.name == "while")
+    assert _stream_of_one_walk(walk.params["body_jaxpr"].jaxpr) == 1
+
+
+def test_a_tall_rung_chooses_its_body_once_a_program():
+    """On a rung that holds whole stretches the program runs ONE of two
+    bodies, chosen by a scalar test on `q_start` / `q_len` in SMEM: its
+    tiles' walks in a loop (the walk over a tile's sequences inside it),
+    or one tall walk — which, where the program is the launch's first,
+    starts the ring's two slots itself. Both stream their pages the same
+    way."""
+    kernel = _kernel_jaxpr(28, 4, 128, T=2 * TALL)
+    top = [e.primitive.name for e in kernel.eqns]
+    assert top.count("cond") == 2 and "while" not in top and "scan" not in top
+
+    def taken(cond):  # the branch that holds the body
+        return max((b.jaxpr for b in cond.params["branches"]),
+                   key=lambda j: len(j.eqns))
+
+    tiles, tall = (taken(e) for e in kernel.eqns
+                   if e.primitive.name == "cond")
+    assert [e.primitive.name for e in tiles.eqns].count("scan") == 1
+    tile = next(e for e in tiles.eqns if e.primitive.name == "scan")
+    walks = [e for e in tile.params["jaxpr"].jaxpr.eqns
+             if e.primitive.name == "while"]
+    assert len(walks) == 1
+    assert _stream_of_one_walk(walks[0].params["body_jaxpr"].jaxpr) == 1
+    assert _stream_of_one_walk(tall) == 3  # + the launch's first blocks
 
 
 def test_forward_ragged_matches_bucketed_composition(tiny_cfg, tiny_params):
