@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import collections
 import copy
+import functools
 import itertools
 import logging
 import os
@@ -483,7 +484,8 @@ class ModelRuntime:
 
     # What `_note_latent` writes onto a step's sample, in order.
     LATENT_FIELDS = ("mla_rows", "dsa_ctx_tokens", "dsa_selected_tokens",
-                     "dsa_step_ctx_tokens", "dsa_step_selected_tokens")
+                     "dsa_step_ctx_tokens", "dsa_step_selected_tokens",
+                     "mla_wide_tokens")
     # ...and for latent attention with no indexer (nothing is scored or
     # selected: the `dsa_*` fields have no honest value there).
     DENSE_LATENT_FIELDS = ("mla_rows", "mla_pairs", "mla_ctx_rows")
@@ -734,12 +736,21 @@ class ModelRuntime:
         # ...and the ragged kernel's own test of which stream tokens it
         # serves a whole stretch at a time (`_note_attn`).
         self._tall_tokens = None
+        # ...and the masked latent kernel's of which it attends in the
+        # expanded form (`_note_latent`).
+        self._wide_tokens = None
         if self.attn_impl == "pallas":
             from ollamamq_tpu.ops.pallas.kv_contract import (inner_report,
                                                              tall_tokens)
             self.attn_inner = inner_report(
                 model_cfg.num_heads // model_cfg.num_kv_heads)
             self._tall_tokens = tall_tokens
+            if model_cfg.index_topk:
+                from ollamamq_tpu.ops.pallas.mla_attention import wide_tokens
+                self._wide_tokens = functools.partial(
+                    wide_tokens, heads=model_cfg.num_heads,
+                    lanes=model_cfg.latent_lanes, rank=model_cfg.kv_lora_rank,
+                    nope=model_cfg.qk_nope_head_dim, v=model_cfg.v_head_dim)
         log.info("%s: attention=%s (%s)%s", name, self.attn_impl, why,
                  "".join(f" {k}={v}" for k, v in
                          (self.attn_inner or {}).items()))
@@ -920,7 +931,7 @@ class ModelRuntime:
                          else "not run without --spec")
         self._tm_dsa = [c.labels(model=name) for c in (
             tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
-            tm.DSA_SELECTED_TOKENS_TOTAL)]
+            tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL)]
         self._tm_attn = [c.labels(model=name) for c in (
             tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
             tm.ATTN_TALL_TOKENS_TOTAL)]
@@ -1317,18 +1328,24 @@ class ModelRuntime:
             for series, n in zip(self._tm_lin, counts):
                 series.inc(n)
 
-    def _note_latent(self, _sp, spans, scan: bool = False) -> None:
+    def _note_latent(self, _sp, spans, scan: bool = False,
+                     stream_len: int = 0) -> None:
         """A launched step's latent attention, onto its sample and the
         /metrics series, from its composition alone: `spans` is (tokens,
-        context at the span's end) a row — a ragged step's spans, or with
-        `scan` a fused scan's active slots with its passes as tokens.
+        context at the span's end) a row — a ragged step's spans
+        (`stream_len`: the rung the stream is padded to), or with `scan` a
+        fused scan's active slots with its passes as tokens.
         `mla_rows` the query tokens, `dsa_ctx_tokens` the cached positions
         the indexer scored for them (a token at position p scores p + 1),
         `dsa_selected_tokens` those attention then saw (min(p + 1,
         index_topk)), and `dsa_step_ctx_tokens` / `dsa_step_selected_tokens`
         the part of each that ONE-TOKEN rows account for (a decode row, a
         scan's pass: rows that share their cached positions with no other
-        query of the launch); a layer's worth — every layer does the same.
+        query of the launch); `mla_wide_tokens` the query tokens the
+        attention kernel served in the EXPANDED form (spans of at least
+        `mla_attention.WIDE` tokens on a rung that holds that body: the
+        kernel's own test, `wide_tokens`; 0 for a scan and on the jnp path);
+        a layer's worth — every layer does the same.
         With NO indexer (every cached position is attended) instead:
         `mla_rows`, `mla_pairs` the causal (query, position) pairs — a
         token at position p attends p + 1 — and `mla_ctx_rows` the cached
@@ -1346,16 +1363,19 @@ class ModelRuntime:
             _sp.note(**dict(zip(self.DENSE_LATENT_FIELDS, counts.tolist())))
             self._tm_dsa[0].inc(int(counts[0]))
             return
-        counts = np.zeros(5, np.int64)
+        counts = np.zeros(6, np.int64)
+        spans = list(spans)
         for n, kv in spans:
             ctx = np.arange(kv - n + 1, kv + 1)
             both = (int(ctx.sum()),
                     int(np.minimum(ctx, self.cfg.index_topk).sum()))
             counts[:3] += (n,) + both
             if scan or n == 1:
-                counts[3:] += both
+                counts[3:5] += both
+        if self._wide_tokens is not None and not scan:
+            counts[5] = self._wide_tokens([n for n, _ in spans], stream_len)
         _sp.note(**dict(zip(self.LATENT_FIELDS, counts.tolist())))
-        for series, n in zip(self._tm_dsa, counts[:3].tolist()):
+        for series, n in zip(self._tm_dsa, counts[[0, 1, 2, 5]].tolist()):
             series.inc(n)
 
     def _note_attn(self, _sp, tokens, kv, scan: bool = False,
@@ -2803,7 +2823,7 @@ class ModelRuntime:
         self._note_slot_state(_sp, opened, len(rows) - opened,
                               sum(n == 1 for n in spans),
                               sum(n for n in spans if n > 1))
-        self._note_latent(_sp, zip(spans, row_kv))
+        self._note_latent(_sp, zip(spans, row_kv), stream_len=T_pad)
         self._note_attn(_sp, spans, row_kv, stream_len=T_pad)
         _sp.mark("dispatch")
         _sp.park()

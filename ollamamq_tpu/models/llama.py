@@ -486,13 +486,20 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     `attn_fn(q_abs [B, T, H, lanes], row [B, T, lanes], (q_idx [B, T, Hi,
     di], k_idx [B, T, di], w_idx [B, T, Hi] float32)) -> [B, T, H, c]`, the
     attended latent a head; the third argument is None for a model with no
-    indexer (`index_topk` 0)."""
+    indexer (`index_topk` 0). With an indexer the schedule also gets the
+    EXPANDED form's operands, `expanded=(q [B, T, H, dn + lanes - c]:
+    [q_nope | q_rope | 0] scaled, w [H, dn + dv, c]: [W_uk,h | W_uv,h]^T a
+    head)`, and may answer `(o_lat, o_v [B, T, H, dv], wide [B, T] bool)`:
+    the tokens of `wide` have their result in `o_v`, through W_uv already
+    (a prefill span wide enough to pay for expanding its context's keys and
+    values: ops/pallas/mla_attention.py)."""
     B, T, _ = h.shape
     H, c = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     Hi, di = cfg.index_n_heads, cfg.index_head_dim
     wukv = lp["mla_wukv"].reshape(c, H, dn + dv)
     index = None  # no indexer: every cached position is attended
+    expanded = o_v = None
     with jax.named_scope("mla_proj"):
         c_q = rmsnorm(qeinsum("btd,de->bte", h, lp["mla_wdq"]),
                       lp["mla_q_norm"], cfg.rms_norm_eps)
@@ -515,10 +522,19 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
             * cfg.attn_scale).astype(h.dtype)
         if cfg.index_topk:
             index = _index_inputs(cfg, lp, h, c_q, positions)
-    o_lat = attn_fn(q_abs, row, index)
+            expanded = ((jnp.concatenate(
+                [q[..., :dn].astype(jnp.float32), q_rope.astype(jnp.float32),
+                 jnp.zeros((B, T, H, pad), jnp.float32)], axis=-1)
+                * cfg.attn_scale).astype(h.dtype),
+                jnp.transpose(wukv, (1, 2, 0)))
+    o_lat = attn_fn(q_abs, row, index, expanded)
+    if isinstance(o_lat, tuple):
+        o_lat, o_v, wide = o_lat
     with jax.named_scope("attn_out"):
         o = jnp.einsum("bthc,chv->bthv", o_lat, wukv[..., dn:],
                        preferred_element_type=jnp.float32).astype(h.dtype)
+        if o_v is not None:
+            o = jnp.where(wide[..., None, None], o_v, o)
         return qeinsum("bte,ed->btd", o.reshape(B, T, H * dv), lp["wo"])
 
 
@@ -685,7 +701,7 @@ def _causal_fn(cfg: ModelConfig, seq_lens):
     causal attention — with latent attention over the latent rows, the
     selection by the span's own index scores."""
     if cfg.kv_lora_rank:
-        return lambda q_abs, row, index: mla.dense_attention(
+        return lambda q_abs, row, index, expanded=None: mla.dense_attention(
             q_abs, row, *(index or (None,) * 3), seq_lens, cfg.kv_lora_rank,
             cfg.index_topk)
     return lambda q, k, v: causal_attention(q, k, v, seq_lens)
@@ -715,7 +731,7 @@ def forward_prefill(
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, T]
 
     def body(x, lp, kinds, ix, kc, vc):
-        def attn_fn(q, k, v):
+        def attn_fn(q, k, v, expanded=None):
             nonlocal kc, vc
             kc = kv_write(kc, ix.op, slots, k)  # K, or the latent row
             # V, or the index key (v: the indexer's q, k and head weights;
@@ -796,13 +812,13 @@ def forward_ragged(
     state = split_state(conv_state)
 
     def body(x, lp, kinds, ix, kc, vc, conv, rule):
-        def attn_fn(q, k, v):  # [1, T, H, hd]
+        def attn_fn(q, k, v, expanded=None):  # [1, T, H, hd]
             nonlocal kc, vc
             if cfg.kv_lora_rank:
                 kc, vc, out = _latent_ragged(
                     cfg, q, k, v, kc, vc, ix.op, write_slots, page_table,
                     tok_seq, tok_pos, q_start, q_len, kv_len, page_size,
-                    attn_impl, interpret)
+                    attn_impl, interpret, expanded=expanded)
                 return out
             with jax.named_scope("kv_write"):
                 kc = kv_write(kc, ix.op, write_slots, k[0])
@@ -852,11 +868,12 @@ def forward_ragged(
 
 def _latent_ragged(cfg, q, row, index, kc, vc, layer, write_slots, page_table,
                    tok_seq, tok_pos, q_start, q_len, kv_len, page_size,
-                   attn_impl, interpret, name=None):
+                   attn_impl, interpret, name=None, expanded=None):
     """A ragged stream's latent attention of one layer: the write of the
     tokens' cache rows (the latent row; the index key where there is an
     indexer), then ops/mla.attend. q [1, T, H, lanes], row [1, T, lanes];
-    returns (kc', vc', o [1, T, H, c])."""
+    returns (kc', vc', o [1, T, H, c]) — or, where the kernel expanded a
+    span (`expanded`: _latent_attention_op), o as its three."""
     q_idx = w_idx = None
     with jax.named_scope("mla_cache_write"):
         kc = kv_write(kc, layer, write_slots, row[0])
@@ -867,7 +884,10 @@ def _latent_ragged(cfg, q, row, index, kc, vc, layer, write_slots, page_table,
         attn_impl, q[0], q_idx, w_idx, kc, vc,
         layer, page_table, tok_seq, tok_pos, q_start, q_len, kv_len,
         page_size, cfg.kv_lora_rank, cfg.index_topk, interpret=interpret,
-        name=name)
+        name=name,
+        expanded=expanded and (expanded[0][0], expanded[1]))
+    if isinstance(out, tuple):
+        return kc, vc, tuple(o[None] for o in out)
     return kc, vc, out[None]
 
 
@@ -915,7 +935,7 @@ def forward_mtp(
     lp = {name: stack if name in STACKED else stack[-1]
           for name, stack in params["layers"].items() if name in mine}
 
-    def attn_fn(q, row, index):
+    def attn_fn(q, row, index, expanded=None):
         nonlocal k_cache
         k_cache, _, out = _latent_ragged(
             cfg, q, row, index, k_cache, None, layer, write_slots,
@@ -980,7 +1000,7 @@ def forward_decode(
     state = split_state(conv_state)
 
     def body(x, lp, kinds, ix, kc, vc, conv, rule):
-        def attn_fn(q, k, v):  # [B, 1, H, hd]
+        def attn_fn(q, k, v, expanded=None):  # [B, 1, H, hd]
             nonlocal kc, vc
             if cfg.kv_lora_rank:  # a stream of B one-token spans
                 q_idx = w_idx = None

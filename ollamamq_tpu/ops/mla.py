@@ -9,7 +9,11 @@ pads a row to anyway (`latent_lanes`) — and the INDEX KEY, `index_head_dim`
 lanes. Attention runs in the ABSORBED form: `q_abs[t, i] = [q_nope[t, i]
 W_uk,i^T | q_rope[t, i]] * scale` against the latent row, the output's latent
 `sum_s p(t, i, s) c_kv(s)` through W_uv,i afterwards — the expanded keys and
-values never exist.
+values never exist in HBM. (One query token cannot pay for expanding its
+context. A prefill span of a few hundred tokens can: the masked Pallas kernel
+attends such a span in the EXPANDED form — `(q W_uk^T) . c = q . (c W_uk)^T`
+— a block's keys and values expanded in VMEM once a group of heads for all
+the span's tokens; ops/pallas/mla_attention.py, `WIDE`.)
 
 Three steps a layer, each a named scope on the device trace:
   dsa_index   I(t, s) = sum_j w_j(t) ReLU(q_I,j(t) . k_I(s)) for every cached
@@ -149,13 +153,15 @@ def dense_attention(q_abs, row, q_idx, k_idx, w_idx, seq_lens, rank: int,
 def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
            page_table, tok_seq, tok_pos, q_start, q_lens, kv_lens,
            page_size: int, rank: int, topk: int, tile=None,
-           interpret: bool = False, name=None):
+           interpret: bool = False, name=None, expanded=None):
     """index, select, attend — the ONE pallas-vs-jnp dispatch of both step
     forwards. Both metadata encodings travel together, as in
     ops/attention.ragged_attention_any. `topk` 0 (no indexer: q_idx, w_idx
     None, idx_pool unread): attend alone, over every cached position.
     `name`: the attention launch's name on the device trace where it is not
-    the kernel's own (the prediction module's)."""
+    the kernel's own (the prediction module's). `expanded`: the expanded
+    form's (q [T, H, .], w [H, ., rank]), for the masked kernel alone — which
+    then may return (o, o_v, wide) (mla_sparse_paged_attention_pallas)."""
     if impl == "pallas":
         from ollamamq_tpu.ops.pallas import mla_attention as kernels
 
@@ -176,7 +182,7 @@ def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
             return kernels.mla_sparse_paged_attention_pallas(
                 q_abs, scores, thr, lat_pool, layer, page_table, q_start,
                 q_lens, kv_lens, page_size, rank, tile=tile,
-                interpret=interpret)
+                interpret=interpret, expanded=expanded)
     if not topk:
         with jax.named_scope("mla_attend"):
             return sparse_attention(q_abs, None, None, lat_pool, layer,

@@ -29,7 +29,9 @@ up to the tile's deepest causal frontier:
       (ops/mla.select_threshold) — a flash-style online softmax, and
       `acc += p · block[:, :512]`: the values are the first `kv_lora_rank`
       lanes of the same rows. No `[tokens, context]` score leaves VMEM and
-      no row is gathered into HBM.
+      no row is gathered into HBM. A prefill span of WIDE tokens or more is
+      attended in the EXPANDED form by programs of the same launch (the
+      docstring's last part).
 
 The selection is a MASK over the streamed context, not a gather of the
 selected rows: exact, MXU-shaped at any selection, and it reads and
@@ -94,11 +96,76 @@ span with the one-token trips taken off; 8 k of context unless said):
   / 6.68 / 6.57 / 6.53 at 4 k / 8 k / 12 k / 16 k against 8.43 / 8.08 / 7.98
   / 7.92; ms a launch 3.58 / 6.91 / 10.25 / 13.59: −17 … −18 %), 92 % of the
   MXU's 6.13.
+
+The expanded body (PR 49). What the absorbed trip multiplies is 640 + 512 =
+1152 lanes a (head, pair); expanded keys and values need 192 + 128 = 320,
+and expanding a cached position's key and value of one head from its latent
+row costs 512 x 256 x 2 FLOPs ONCE for every query token that attends it —
+nothing a decode row can pay, half the absorbed form's work for a span of
+512. So a launch of the masked kernel over a stream of WIDE..WIDE_STREAM
+tokens (`expands`: the 512-token rung; a trace-time choice, smaller rungs
+hold the tiles alone) takes the expanded form's operands too — q as `[q_nope
+| q_rope | 0]` head-major and `[W_uk,h | W_uv,h]^T` a head, the rank minor as
+the stack lives on the device (llama.CONTRACTED_MINOR: no re-laid copy) — and
+runs `heads / WIDE_GROUP` EXPANDED programs before its tiles, in the ONE
+`pallas_call` (the readers count launches by name): `_expanded`. A program's
+rows are the stream's tokens themselves; it walks the context of every span
+of at least WIDE tokens (run time, from `q_lens`) once, with the block's
+`[tokens, 256]` selection scores prefetched beside its pages; a block: the
+mask once for the group; `[K_h^T ; V_h^T]` of the group's 16 heads in ONE
+contraction `[16 x 256, 512] . [256, 512]^T` whose stationary operand is the
+block (a contraction a head re-latches the eight weight tiles a head: below);
+then a head at a time `[512, 256] . [256, 256]` scores, the tile's online
+softmax (`m` lane-replicated, `l` lane-partial), `[512, 256] . [128, 256]^T`
+into `acc [512, 128]`, WIDE_CHAIN heads in one straight-line body. The
+result leaves in `v_head_dim` lanes. The tiles skip a wide span's sequence,
+and a tile inside one returns at once; every other row — decode rows,
+shorter spans — is the absorbed tiles' to the bit.
+  Measured (my chip runs, PR 49, one v5e; the same script and step, `latent`
+  against `latent_wide`; µs a (16 tokens, 256 keys) of the span, one-token
+  trips taken off):
+  The absorbed tiles 7.21 / 6.79 / 6.65 / 6.58 at 4 k / 8 k / 12 k / 16 k
+  (ms a launch 3.71 / 7.05 / 10.38 / 13.72); this body **4.87 / 4.28 / 4.08
+  / 3.98** (2.54 / 4.51 / 6.47 / 8.44: −31 / −36 / −38 / −38 %). At the MXU's
+  peak the expanded trip is 3.41 (1.37 expansion, 1.37 scores — 192 lanes
+  are two passes of 128 — 0.68 P·V).
+  How it got there, 8 k: a head's own expansion `[256, 512] . [256, 512]^T`
+  inside a loop over the heads, chunks of 256 rows under a `pl.when` each:
+  7.35 (8 heads), SLOWER than the tiles; chunks of 512: 6.12, of 128: 9.87 —
+  every chunk's region costs ~0.3 µs a (block, head), and a `pl.when` inside
+  the head's body keeps the scheduler from overlapping anything across it.
+  With a part taken out of that one (6.12): the expansion 4.20, the scores
+  4.25, P·V 5.07, the softmax 5.75, the scores' DMA and the mask 5.96, all
+  three contractions 1.80 — the contractions cost 4.83 where the peak needs
+  3.41, and the vector side (1.80) ran BESIDE nothing. Both had one cause
+  each. A weight tile's latch (~128 cycles) is not hidden behind the rows
+  streamed through it: a contraction costs tiles x (rows + ~128) cycles over
+  the four MXUs — 8 x (256 + 128) a head for the expansion, 1.5 x its peak
+  (the tiles' 18 x (1024 + 128) is PR 40's 6.9 against 6.13) — so the
+  expansion became ONE contraction a group with the block stationary and
+  `[W_uk | W_uv]^T`'s 4096 rows streamed: 5.51 (a head a loop trip). And
+  heads in one straight-line body overlap one's softmax with the next one's
+  contractions: 2 heads 4.91, 4 heads 4.64, 8 heads 4.50 (8 heads a
+  program); 16 heads a program 4.41 / 4.25 / 4.17 at 4 / 8 / 16 heads a
+  body (8 is taken: compiled here for a described v5e the kernel takes
+  5.6 / 6.5 / 8.3 s at 4 / 8 / 16 against 2.7 s for the tiles alone;
+  `setup_s`).
+  This body (4.28) with a part taken out: the expansion 2.87, the scores
+  3.57, P·V 3.47, the softmax 4.16, the scores' DMA and the mask 4.22, the
+  three contractions 1.50. By the latch arithmetic the contractions cost
+  1.41 + 1.71 + 0.85 = 3.97: the body is MXU-bound at 93 % of that, and
+  what is left over the peak's 3.41 is latches — 512 rows a weight tile is
+  all a head's scores and P·V can stream (the span's tokens), and the
+  padded 64 lanes of the rope pass.
+  WIDE: a program's rows are the rung's whatever the span's length, so the
+  expanded form costs a span of L tokens what it costs 512 and the tiles
+  cost it L / 512 of theirs: it wins from L = 318 (8 k, 16 k) to 352 (4 k).
 """
 
 from __future__ import annotations
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -131,14 +198,26 @@ VMEM_LIMIT = 96 * 1024 * 1024
 SHORT = 2
 # The dense kernel's launch by the prediction module, on the device trace.
 MTP_NAME = "mtp_latent_attention_pallas"
+# The masked kernel's EXPANDED body (the docstring's last part): tokens of a
+# prefill span from which its programs expand each block's keys and values
+# (under it the absorbed tiles cost less), heads a program, and heads a
+# straight-line body of a program's loop over them.
+WIDE = 320
+WIDE_GROUP = 16
+WIDE_CHAIN = 8
+# ...and the longest stream whose tokens a program holds as its rows.
+WIDE_STREAM = 512
 
 
-def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update):
+def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update,
+          want=None, also=None):
     """The tile's walk: for every sequence with a row in tile `t`, its
     blocks of `block` tokens up to the deepest causal frontier among those
     rows, each waited for in `buf[slot]` and handed to `update(slot, b, lo,
     hi, base)` — rows [lo, hi) of the tile are the sequence's, row i at
-    position base + i."""
+    position base + i. `want(s)`: which of those sequences are walked at
+    all; `also` = (start(b, slot), wait(slot)): what else travels with a
+    block (the masked kernel's expanded body: module docstring)."""
     layer_ref, first_ref, q_start_ref, q_len_ref, kv_len_ref, pt_ref = refs
     ppb = block // page_size
     layer = layer_ref[0]
@@ -152,10 +231,14 @@ def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update):
                 hbm.at[layer, pl.ds(lax.mul(page, page_size), page_size)],
                 buf.at[slot, pl.ds(j * page_size, page_size)],
                 sem.at[slot]).start()
+        if also:
+            also[0](b, slot)
 
     def wait(slot):
         pltpu.make_async_copy(hbm.at[layer, pl.ds(0, block)], buf.at[slot],
                               sem.at[slot]).wait()
+        if also:
+            also[1](slot)
 
     def overlaps(s):
         row = lax.min(s, num_seqs - 1)
@@ -165,6 +248,13 @@ def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update):
             lax.gt(lax.add(qs, ql), tile_lo)))
 
     def one_sequence(s):
+        if want is None:
+            walk(s)
+        else:
+            pl.when(want(s))(lambda: walk(s))
+        return lax.add(s, 1)
+
+    def walk(s):
         qs, ql, kv = q_start_ref[s], q_len_ref[s], kv_len_ref[s]
         lo = lax.sub(lax.max(qs, tile_lo), tile_lo)
         hi = lax.sub(lax.min(lax.add(qs, ql), tile_hi), tile_lo)
@@ -184,7 +274,6 @@ def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update):
             return ()
 
         lax.fori_loop(0, n, body, ())
-        return lax.add(s, 1)
 
     lax.while_loop(overlaps, one_sequence, first_ref[t])
 
@@ -249,16 +338,22 @@ def _select_kernel(s_ref, pos_ref, o_ref, *, topk):
 
 
 def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
-                   chains, masked=True):
+                   chains, masked=True, wide=None):
     meta = refs[:6]
     if masked:
         q_ref, i_ref, thr_ref, *refs = refs[6:]
     else:  # no selection: neither its scores nor its threshold is here
         q_ref, *refs = refs[6:]
-    hbm, o_ref, buf, sem, m_ref, l_ref, acc_ref = refs
-    m_ref[...] = jnp.full_like(m_ref, M_INIT)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    if wide:  # the expanded body's operands, result and scratch (_expanded)
+        hbm, *ins, o_ref, ow_ref, buf, sem, m_ref, l_ref, acc_ref = refs[:12]
+        q_len_ref = meta[3]
+
+        @pl.when(lax.lt(pl.program_id(0), wide.lead))
+        def _():
+            _expanded(meta, ins, hbm, ow_ref, buf, sem, refs[12:], wide,
+                      rank, page_size, num_seqs, block)
+    else:
+        hbm, o_ref, buf, sem, m_ref, l_ref, acc_ref = refs
 
     def scores(at, rows):
         return lax.dot_general(q_ref[at, :], rows, (((1,), (1,)), ((), ())),
@@ -353,18 +448,127 @@ def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
         def _():
             whole(rows, b, lo, hi, base)
 
-    _walk(pl.program_id(0), tile, meta, hbm, buf, sem, page_size, num_seqs,
-          block, update)
-    o_ref[...] = (acc_ref[...] / jnp.maximum(
-        jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)).astype(o_ref.dtype)
+    def tile_program(t=None, want=None):
+        m_ref[...] = jnp.full_like(m_ref, M_INIT)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _walk(pl.program_id(0) if t is None else t, tile, meta, hbm, buf, sem,
+              page_size, num_seqs, block, update, want)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(
+            jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+        ).astype(o_ref.dtype)
+
+    if not wide:
+        return tile_program()
+    # Behind the expanded programs: the tiles, which leave a wide span's
+    # rows to those — a tile that lies inside one returns at once (its block
+    # of `o` is never read: `_attention`).
+    t = lax.sub(pl.program_id(0), wide.lead)
+    first = lax.min(meta[1][lax.max(t, 0)], num_seqs - 1)
+    qs, ql = meta[2][first], q_len_ref[first]
+    inside = functools.reduce(lax.bitwise_and, (
+        lax.ge(ql, WIDE), lax.le(qs, lax.mul(t, tile)),
+        lax.ge(lax.add(qs, ql), lax.mul(lax.add(t, 1), tile))))
+    pl.when(lax.bitwise_and(lax.ge(t, 0), jnp.logical_not(inside)))(
+        lambda: tile_program(t, lambda s: lax.lt(q_len_ref[s], WIDE)))
+
+
+def _expanded(meta, ins, hbm, o_ref, buf, sem, scratch, wide, rank,
+              page_size, num_seqs, block):
+    """One expanded program: the step's wide spans (at least WIDE tokens)
+    for `wide.group` heads. Its rows are the STREAM's tokens, all of
+    them (row i is stream token i: the selection's scores and the result
+    need no re-alignment to a span; another sequence's rows are masked, as
+    in a tile). It walks each wide span's context once and, a block,
+    expands `[K_h^T ; V_h^T] = [W_uk,h | W_uv,h]^T . c_kv^T` for all its
+    heads in ONE contraction with the block's rows the stationary operand
+    (float32 sums, rounded to the pool's dtype as the absorbed q is), then a
+    head at a time scores the rows `[q_nope | q_rope] . [K_h^T ; k_rope^T]`
+    under the same mask and folds them into the same online softmax as a
+    tile does, `acc += p . V_h`: the result leaves in `v_head_dim` lanes."""
+    q_ref, w_ref, thr_ref, i_hbm = ins
+    ibuf, isem, bias_ref, m_ref, l_ref, acc_ref, kvt_ref = scratch
+    q_len_ref = meta[3]
+    tokens, per, dn = q_ref.shape[1], w_ref.shape[1], wide.nope
+    m_ref[...] = jnp.full_like(m_ref, M_INIT)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def columns(b):
+        return pl.ds(pl.multiple_of(lax.mul(b, block), block), block)
+
+    def start(b, slot):  # the selection's scores of the block's positions
+        pltpu.make_async_copy(i_hbm.at[:, columns(b)], ibuf.at[slot],
+                              isem.at[slot]).start()
+
+    def wait(slot):
+        pltpu.make_async_copy(i_hbm.at[:, columns(0)], ibuf.at[slot],
+                              isem.at[slot]).wait()
+
+    def contract(a, b, b_dim):
+        return lax.dot_general(a, b, (((1,), (b_dim,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+    def update(slot, b, lo, hi, base):
+        key0 = lax.mul(b, block)
+        # Rows past the span's last position are the page's, not the
+        # sequence's (the trash page's, an earlier request's): zero, so that
+        # what is expanded from them is finite under the mask.
+        rows = buf[slot]
+        own = lax.lt(lax.add(lax.broadcasted_iota(
+            jnp.int32, rows.shape, 0), key0), lax.add(base, hi))
+        rows = jnp.where(own, rows, jnp.zeros_like(rows))
+        # The mask, once for the heads: 0 where row i attends the key.
+        row, mine = _rows_of((tokens, block), lo, hi)
+        key = lax.add(lax.broadcasted_iota(jnp.int32, (tokens, block), 1),
+                      key0)
+        keep = functools.reduce(jnp.logical_and, (
+            mine, key <= row + base, ibuf[slot] >= thr_ref[...]))
+        bias_ref[...] = jnp.where(keep, 0.0, NEG_INF)
+        kvt_ref[...] = contract(w_ref[...].reshape(-1, rank), rows[:, :rank],
+                                1).astype(rows.dtype)
+        # k_rope^T through the MXU too: an identity's rows, exact
+        rope_t = contract(jnp.eye(rows.shape[1] - rank, dtype=rows.dtype),
+                          rows[:, rank:], 1).astype(rows.dtype)
+
+        def head(h):
+            at = pl.multiple_of(lax.mul(h, per), per)
+            k_t = jnp.concatenate([kvt_ref[pl.ds(at, dn), :], rope_t], axis=0)
+            v_t = kvt_ref[pl.ds(lax.add(at, dn), per - dn), :]
+            s = contract(q_ref[h], k_t, 0) + bias_ref[...]
+            m_old = m_ref[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _widen(m_new, block))
+            corr = jnp.exp(m_old - m_new)
+            l_ref[h] = l_ref[h] * corr + functools.reduce(jnp.add, (
+                p[:, j:j + LANES] for j in range(0, block, LANES)))
+            acc_ref[h] = acc_ref[h] * _widen(corr, per - dn) + contract(
+                p.astype(rows.dtype), v_t, 1)
+            m_ref[h] = m_new
+
+        def heads(i, _):  # `wide.chain` heads in one straight-line body
+            for j in range(wide.chain):
+                head(lax.add(lax.mul(i, wide.chain), j))
+            return ()
+
+        lax.fori_loop(0, wide.group // wide.chain, heads, ())
+
+    _walk(0, tokens, meta, hbm, buf, sem, page_size, num_seqs, block, update,
+          lambda s: lax.ge(q_len_ref[s], WIDE), (start, wait))
+    for h in range(wide.group):
+        o_ref[h] = (acc_ref[h] / jnp.maximum(
+            jnp.sum(l_ref[h], axis=1, keepdims=True), 1e-30)
+        ).astype(o_ref.dtype)
 
 
 def _launch(kernel, tile, block, inputs, pool, out_lanes, out_dtype, scratch,
             layer, page_table, q_start, q_lens, kv_lens, page_size, interpret,
-            name=None):
+            name=None, wide=None):
     """One program a tile: the tile's blocks of `inputs` ([n_tiles, rows,
     lanes] each) in VMEM, the pool left in HBM, two buffers of `block`
-    tokens."""
+    tokens. `wide` (the masked attention kernel's, `_wide_launch`): its
+    `lead` expanded programs run before the tiles', their operands behind
+    the pool, their result and scratch last."""
     n_tiles = inputs[0].shape[0]
     ppb = block // page_size
     page_table = page_table.astype(jnp.int32)
@@ -373,29 +577,40 @@ def _launch(kernel, tile, block, inputs, pool, out_lanes, out_dtype, scratch,
     tile_first = jnp.searchsorted(
         ends, jnp.arange(n_tiles, dtype=jnp.int32) * tile, side="right"
     ).astype(jnp.int32)
+    lead = wide.static.lead if wide else 0
 
     def spec(shape):
-        return pl.BlockSpec((None,) + shape[1:], lambda i, *_: (i, 0, 0),
-                            memory_space=pltpu.VMEM)
+        at = (lambda i, *_: (i, 0, 0)) if not lead else (
+            lambda i, *_: (jnp.maximum(i - lead, 0), 0, 0))
+        return pl.BlockSpec((None,) + shape[1:], at, memory_space=pltpu.VMEM)
 
     out_shape = (n_tiles, out_lanes[0], out_lanes[1])
+    operands = [*inputs, pool]
+    in_specs = [spec(x.shape) for x in inputs] \
+        + [pl.BlockSpec(memory_space=pl.ANY)]
+    out_specs = spec(out_shape)
+    out_shapes = jax.ShapeDtypeStruct(out_shape, out_dtype)
+    scratch = [pltpu.VMEM((2, block, pool.shape[-1]), pool.dtype),
+               pltpu.SemaphoreType.DMA((2,))] + scratch
+    if wide:
+        operands += wide.operands
+        in_specs += wide.in_specs
+        out_specs, out_shapes = [out_specs, wide.out_spec], [
+            out_shapes, wide.out_shape]
+        scratch += wide.scratch
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6, grid=(n_tiles,),
-            in_specs=[spec(x.shape) for x in inputs]
-            + [pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=spec(out_shape),
-            scratch_shapes=[pltpu.VMEM((2, block, pool.shape[-1]), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,))] + scratch),
-        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
+            num_scalar_prefetch=6, grid=(lead + n_tiles,),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shapes,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret, name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), tile_first,
       q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
-      kv_lens.astype(jnp.int32), page_table, *inputs, pool)
+      kv_lens.astype(jnp.int32), page_table, *operands)
 
 
 def _tiles(x, tile):
@@ -470,13 +685,18 @@ def mla_sparse_paged_attention_pallas(q_abs, scores, thr, lat_pool, layer,
                                       page_table, q_start, q_lens, kv_lens,
                                       page_size: int, rank: int,
                                       tile: int | None = None,
-                                      interpret: bool = False):
+                                      interpret: bool = False, expanded=None):
     """o [T, H, rank] in q's dtype: q_abs [T, H, latent] (absorbed, scaled),
     scores [T, C] and thr [T] float32 (the selection), lat_pool [L, S,
-    latent]."""
+    latent]. With `expanded` = (q [T, H, nope + latent - rank]: `[q_nope |
+    q_rope | 0]`, scaled; w [H, nope + v, rank]: `[W_uk,h | W_uv,h]^T` a
+    head, the rank minor) and a launch that holds the expanded body
+    (`expands`): (o, o_v [T, H, v], wide [T] bool) — a token of a span of
+    at least WIDE tokens has its result in `o_v`, through W_uv already, and
+    nothing in `o`."""
     return _attention(q_abs, (scores, thr), lat_pool, layer, page_table,
                       q_start, q_lens, kv_lens, page_size, rank, tile,
-                      interpret)
+                      interpret, expanded=expanded)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "rank", "tile",
@@ -494,18 +714,51 @@ def mla_dense_paged_attention_pallas(q_abs, lat_pool, layer, page_table,
                       name or "mla_dense_paged_attention_pallas")
 
 
+def expands(tokens: int, heads: int, lanes: int, rank: int, nope: int,
+            v: int) -> bool:
+    """Does the masked kernel's launch over a stream of `tokens` hold the
+    expanded body? Where a span of WIDE tokens fits the rung and the rung a
+    program's rows, and the head widths are whole lane tiles (the body
+    slices and joins at them)."""
+    group = min(WIDE_GROUP, heads)
+    return (WIDE <= tokens <= WIDE_STREAM and tokens % 8 == 0
+            and heads % group == 0 and group % min(WIDE_CHAIN, group) == 0
+            and not any(n % LANES for n in (nope, v, rank, lanes - rank)))
+
+
+def wide_tokens(spans, stream_len: int, heads: int, lanes: int, rank: int,
+                nope: int, v: int) -> int:
+    """Stream tokens of a ragged step that the masked kernel attends in the
+    expanded form: those of its spans of at least WIDE tokens, on a rung
+    (`stream_len` tokens, padding included) that holds the expanded body.
+    `spans`: each row's tokens. The kernel's own test (`expands`,
+    `_expanded`'s `want`), on the host."""
+    if not expands(stream_len, heads, lanes, rank, nope, v):
+        return 0
+    return sum(n for n in spans if n >= WIDE)
+
+
 def _attention(q_abs, selection, lat_pool, layer, page_table, q_start, q_lens,
-               kv_lens, page_size, rank, tile, interpret, name=None):
-    """The attention kernel's launch; `selection` (scores, thr) or None."""
-    T, H, _ = q_abs.shape
+               kv_lens, page_size, rank, tile, interpret, name=None,
+               expanded=None):
+    """The attention kernel's launch; `selection` (scores, thr) or None;
+    `expanded`: mla_sparse_paged_attention_pallas."""
+    T, H, lanes = q_abs.shape
     # a step no longer than the indexer's tile stays one tile of that size
     tile = tile or (ATTEND_TILE if T > TILE else TILE)
+    wide = None
+    if expanded is not None:
+        q, w = expanded
+        nope = q.shape[-1] - (lanes - rank)
+        if expands(T, H, lanes, rank, nope, w.shape[1] - nope):
+            wide = _wide_launch(q, w, nope, *selection)
     kernel = functools.partial(_attend_kernel, tile=tile, heads=H, rank=rank,
                                page_size=page_size,
                                num_seqs=page_table.shape[0],
                                block=ATTEND_BLOCK,
                                chains=tile // CHAIN if tile % CHAIN == 0
-                               else 1, masked=selection is not None)
+                               else 1, masked=selection is not None,
+                               wide=wide and wide.static)
     rows = tile * H
     scratch = [pltpu.VMEM((rows, LANES), jnp.float32),
                pltpu.VMEM((rows, LANES), jnp.float32),
@@ -517,5 +770,46 @@ def _attention(q_abs, selection, lat_pool, layer, page_table, q_start, q_lens,
                    _tiles(thr.astype(jnp.float32)[:, None], tile)]
     out = _launch(kernel, tile, ATTEND_BLOCK, inputs, lat_pool,
                   (rows, rank), q_abs.dtype, scratch, layer, page_table,
-                  q_start, q_lens, kv_lens, page_size, interpret, name)
-    return out.reshape(-1, H, rank)[:T]
+                  q_start, q_lens, kv_lens, page_size, interpret, name, wide)
+    if not wide:
+        return out.reshape(-1, H, rank)[:T]
+    out, o_v = out
+    at = jnp.arange(T, dtype=jnp.int32)[:, None]
+    served = jnp.any((q_lens >= WIDE) & (q_start <= at)
+                     & (at < q_start + q_lens), axis=1)
+    return (out.reshape(-1, H, rank)[:T], jnp.swapaxes(o_v, 0, 1), served)
+
+
+def _wide_launch(q, w, nope, scores, thr):
+    """What `_launch` and the kernel need of the expanded programs: q [T,
+    H, lanes] and the result head-major, a group of heads a program."""
+    T, H, _ = q.shape
+    group = min(WIDE_GROUP, H)
+    v = w.shape[1] - nope
+    lead = H // group
+
+    def a_group(shape):
+        return pl.BlockSpec(
+            (group,) + shape[1:],
+            lambda i, *_: (jnp.minimum(i, lead - 1), 0, 0),
+            memory_space=pltpu.VMEM)
+
+    f32 = jnp.float32
+    return types.SimpleNamespace(
+        static=types.SimpleNamespace(lead=lead, group=group, nope=nope,
+                                     chain=min(WIDE_CHAIN, group)),
+        operands=[jnp.swapaxes(q, 0, 1), w, thr.astype(f32)[:, None],
+                  scores],
+        in_specs=[a_group((H, T, q.shape[-1])), a_group(w.shape),
+                  pl.BlockSpec((T, 1), lambda i, *_: (0, 0),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_spec=a_group((H, T, v)),
+        out_shape=jax.ShapeDtypeStruct((H, T, v), q.dtype),
+        scratch=[pltpu.VMEM((2, T, ATTEND_BLOCK), f32),
+                 pltpu.SemaphoreType.DMA((2,)),
+                 pltpu.VMEM((T, ATTEND_BLOCK), f32),
+                 pltpu.VMEM((group, T, LANES), f32),
+                 pltpu.VMEM((group, T, LANES), f32),
+                 pltpu.VMEM((group, T, v), f32),
+                 pltpu.VMEM((group * w.shape[1], ATTEND_BLOCK), q.dtype)])

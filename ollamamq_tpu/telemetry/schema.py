@@ -336,6 +336,13 @@ MLA_ROWS_TOTAL = REGISTRY.counter(
     "Query tokens of launched steps that went through latent attention "
     "(a ragged step's tokens, a fused scan's active slots x its passes)",
     labels=("model",))
+MLA_WIDE_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_mla_wide_tokens_total",
+    "Those of them that the sparse latent attention kernel attended in the "
+    "EXPANDED form: tokens of prefill spans of at least mla_attention.WIDE "
+    "tokens, whose programs expand each block's keys and values once a head "
+    "group; 0 without the kernel, for a fused scan and with no indexer",
+    labels=("model",))
 DSA_CTX_TOKENS_TOTAL = REGISTRY.counter(
     "ollamamq_dsa_ctx_tokens_total",
     "Cached positions the indexer scored for those query tokens, a layer: "

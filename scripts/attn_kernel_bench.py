@@ -46,9 +46,14 @@ one tile a program at every rung.
 latent pool, pages of 32, 5 one-token rows then one 507-token prefill
 span, every sequence at one context (`--contexts`, default 4 k / 8 k /
 12 k / 16 k), 2048 selected a token from seeded scores, checked against
-`ops/mla.sparse_attention` on a sample of the stream. A row a (context,
-`--set mla_attention.NAME=VALUE`): ms a launch and µs a (tile, block)
-trip (`latent`'s docstring has the two normalisations).
+`ops/mla.sparse_attention` on a sample of the stream. Three rows a (context,
+`--set mla_attention.NAME=VALUE`): the step's one-token rows alone
+(`latent_rows`), the step through the absorbed tiles (`latent`) and the
+step as the engine launches it since PR 49, its span in the expanded form
+(`latent_wide`: the same launch with the expanded form's q and `[W_uk |
+W_uv]`, checked through W_uv) — ms a launch and µs a (tile, block) trip
+(`latent`'s docstring has the two normalisations; a `--set` of a `WIDE…`
+constant alone skips the absorbed row).
 
 A variant of a kernel's body is measured here before a whole cell: a
 module constant of the four kernel modules that this script sets
@@ -333,43 +338,79 @@ def latent_batch(rows, mp):
 
 def latent(args, variants) -> None:
     """The latent-attention kernel's rows: for each context the cell's step
-    (`latent`) and its one-token rows by themselves (`latent_rows`, which
-    prices a one-token trip), each checked against ops/mla.sparse_attention
-    on LAT_CHECKED and timed over LAT_LAUNCHES launches chained in one jit
-    (each one's layer index a function of the last one's output). With the
+    in the absorbed form (`latent`: every row through the tiles), with the
+    span in the expanded form (`latent_wide`, PR 49: the kernel as the
+    engine launches it) and its one-token rows by themselves (`latent_rows`,
+    which prices a one-token trip), each checked on LAT_CHECKED against
+    ops/mla.sparse_attention (followed by W_uv where the expanded body
+    serves) and timed over LAT_LAUNCHES launches chained in one jit (each
+    one's layer index a function of the last one's output). With the
     one-token trips taken off a launch, `us_a_tile_block` is a (tile,
     block) trip of the span at the variant's own tile and block, and
     `us_a_16_256` the same time over the trips tiles of 16 tokens and
-    blocks of 256 would make: what tiles and widths are compared by (it
-    carries a wider block's over-read)."""
+    blocks of 256 would make: what tiles, widths and the two forms are
+    compared by (it carries a wider block's over-read, and the expanded
+    form's walk of the whole span to its last frontier)."""
     ka = mla_attention
     mp = max(args.contexts or LAT_CONTEXTS) // PS
     key = jax.random.PRNGKey(args.seed)
     pool = (jax.random.normal(
         key, (2, (1 + (LAT_ROWS + 1) * mp) * PS, LAT_LANES), jnp.float32)
         * 0.3).astype(jnp.bfloat16).at[:, :, 576:].set(0)
+    nope = LAT_V = 128
+    # [W_uk,h | W_uv,h] a head, as the model holds it: [rank, H, nope + v]
+    wukv = (jax.random.normal(jax.random.fold_in(key, 1), (
+        LAT_RANK, LAT_H, nope + LAT_V), jnp.float32) * nope ** -0.5
+    ).astype(jnp.bfloat16)
+    w_t = jnp.transpose(wukv, (1, 2, 0))
 
-    def fn(layer, q, scores, thr, pool, pt, qs, ql, kl):
+    def fn(layer, q, scores, thr, pool, pt, qs, ql, kl, *expanded):
         return ka.mla_sparse_paged_attention_pallas(
-            q, scores, thr, pool, layer, pt, qs, ql, kl, PS, LAT_RANK)
+            q, scores, thr, pool, layer, pt, qs, ql, kl, PS, LAT_RANK,
+            **({"expanded": expanded} if expanded else {}))
+
+    def through_w_uv(out):
+        """[T, H, v] of a launch's result, float32."""
+        o, o_v, served = out if isinstance(out, tuple) else (out, None, None)
+        o = jnp.einsum("thc,chv->thv", o, wukv[..., nope:],
+                       preferred_element_type=jnp.float32)
+        return o if o_v is None else jnp.where(
+            served[:, None, None], o_v.astype(jnp.float32), o)
 
     def chain(*operands):
         def body(_, layer):
-            o = fn(layer, *operands)
-            return LAYER + jnp.isnan(o[0, 0, 0]).astype(jnp.int32)
+            # row 0 is a one-token row's, the last row the span's: both
+            # finite, so the next launch reads the same layer
+            o = through_w_uv(fn(layer, *operands))
+            bad = jnp.isnan(o[0, 0, 0]) | jnp.isnan(o[-1, 0, 0])
+            return LAYER * (1 - bad.astype(jnp.int32))
         return jax.lax.fori_loop(0, LAT_LAUNCHES, body, jnp.int32(LAYER))
 
     for context in args.contexts or LAT_CONTEXTS:
         us_one = {}  # a variant's one-token trip, from its `latent_rows`
-        for traffic, spans in (("latent_rows", ()), ("latent", (LAT_SPAN,))):
+        for traffic, spans in (("latent_rows", ()), ("latent", (LAT_SPAN,)),
+                               ("latent_wide", (LAT_SPAN,))):
             meta, tok_seq, tok_pos, T = latent_batch(
                 [(1, context - 1)] * LAT_ROWS
                 + [(n, context - n) for n in spans], mp)
-            k1, k2 = jax.random.split(jax.random.fold_in(key, context))
-            q = (jax.random.normal(k1, (T, LAT_H, LAT_LANES), jnp.float32)
-                 * 0.1).astype(jnp.bfloat16)
+            k1, k2, k3 = jax.random.split(jax.random.fold_in(key, context), 3)
+            q_nope, q_rope = (
+                (jax.random.normal(k, (T, LAT_H, n), jnp.float32) * 0.1
+                 ).astype(jnp.bfloat16) for k, n in ((k1, nope), (k3, 64)))
+            pad = jnp.zeros((T, LAT_H, LAT_LANES - LAT_RANK - 64), jnp.float32)
+            q = jnp.concatenate([jnp.einsum(
+                "thn,chn->thc", q_nope, wukv[..., :nope],
+                preferred_element_type=jnp.float32),
+                q_rope.astype(jnp.float32), pad], -1).astype(jnp.bfloat16)
+            expanded = ()
+            if traffic == "latent_wide":
+                expanded = (jnp.concatenate(
+                    [q_nope, q_rope, pad.astype(jnp.bfloat16)], -1), w_t)
             checked = np.asarray([t for t in LAT_CHECKED if t < T])
             for consts in variants:
+                if traffic == "latent" and consts and all(
+                        a.startswith(("WIDE", "_")) for _, a in consts):
+                    continue  # a constant of the expanded body alone
                 with constants(consts) as names:
                     tile = getattr(ka, "ATTEND_TILE", ka.TILE)
                     block = getattr(ka, "ATTEND_BLOCK", ka.BLOCK)
@@ -385,14 +426,16 @@ def latent(args, variants) -> None:
                         thr = mla.select_threshold(
                             scores, jnp.asarray(tok_pos), LAT_TOPK)
                         operands = (q, scores, thr, pool,
-                                    *(jnp.asarray(a) for a in meta))
-                        out = np.asarray(fn(LAYER, *operands)[checked],
-                                         np.float32)
-                        ref = np.asarray(mla.sparse_attention(
+                                    *(jnp.asarray(a) for a in meta),
+                                    *expanded)
+                        out = fn(LAYER, *operands)
+                        if expanded:
+                            row["wide_tokens"] = int(out[2].sum())
+                        out = np.asarray(through_w_uv(out)[checked])
+                        ref = np.asarray(through_w_uv(mla.sparse_attention(
                             q[checked], scores[checked], thr[checked], pool,
                             LAYER, operands[4], jnp.asarray(tok_seq[checked]),
-                            jnp.asarray(tok_pos[checked]), PS, LAT_RANK),
-                            np.float32)
+                            jnp.asarray(tok_pos[checked]), PS, LAT_RANK)))
                         us = best_of_three(jax.jit(chain), *operands) \
                             / LAT_LAUNCHES * 1e6
                         diff = float(np.abs(out - ref).max())

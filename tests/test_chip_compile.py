@@ -535,6 +535,45 @@ def test_deepseek_width_step_programs_carry_both_pools_in_place(
     assert mem.temp_size_in_bytes < 128 * per_head // 4 + 512 * 2 ** 20, mem
 
 
+@pytest.mark.parametrize("tokens", [256, 512])
+def test_the_sparse_latent_kernel_compiles_with_its_expanded_body(v5e,
+                                                                  tokens):
+    """The masked latent attention kernel at DeepSeek-V3.2's widths on the
+    512-token rung (PR 49): its absorbed tiles AND its expanded programs —
+    16 heads' keys and values of a 256-token block expanded in VMEM, the
+    512 stream tokens a program's rows — are one Mosaic kernel the chip's
+    compiler takes, under the file's VMEM limit, over the cell's own pool
+    and table (12,384 pages of 32, 520 a sequence); a rung under WIDE holds
+    the tiles alone and gives the one result."""
+    from ollamamq_tpu.ops.pallas import mla_attention as ka
+
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    heads, lanes, rank, rows, pages = 128, 640, 512, 17, 520
+    C = ka.context_lanes(pages, PS)
+    lowered = jax.jit(
+        lambda q, sc, thr, pool, pt, qs, ql, kl, qe, w:
+        ka.mla_sparse_paged_attention_pallas(
+            q, sc, thr, pool, 2, pt, qs, ql, kl, PS, rank,
+            expanded=(qe, w))).lower(
+        s((tokens, heads, lanes), bf), s((tokens, C), f32),
+        s((tokens,), f32), s((5, 12384 * PS, lanes), bf),
+        s((rows, pages), i32), s((rows,), i32), s((rows,), i32),
+        s((rows,), i32), s((tokens, heads, 256), bf),
+        s((heads, 256, rank), bf))
+    compiled = lowered.compile()
+    out = jax.tree.leaves(compiled.out_info)
+    expands = ka.expands(tokens, heads, lanes, rank, 128, 128)
+    assert expands == (tokens >= ka.WIDE) and len(out) == (3 if expands
+                                                           else 1)
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          compiled.as_text())) == 1
+
+
 # openPangu-Ultra-MoE's layers (config.py) over its dense layer and two
 # expert layers, 16 of the router's 256 experts held, a small vocabulary, and
 # the prediction module: the dense latent attention kernel at 128 heads over a

@@ -522,6 +522,295 @@ def test_the_pallas_kernels_match_their_twins_in_interpret_mode(mix):
     assert np.abs(got - twin).max() <= 2 ** -8 * max(1.0, np.abs(twin).max())
 
 
+def _paged_stream(spans, ps, mp, n_pages, rng, pad_to=32):
+    """A ragged step's metadata for `spans` = (tokens, context at the span's
+    end) a row, each sequence on scattered pages of its own: (page table
+    with two spare rows, tok_seq, tok_pos, q_start, q_len, kv_len) as int32
+    arrays and the stream's real length; the stream is padded to `pad_to`."""
+    rows = len(spans) + 2
+    pt = np.zeros((rows, mp), np.int32)
+    perm = rng.permutation(np.arange(1, n_pages))
+    used = 0
+    qs, ql, kl, ts, tp = [], [], [], [], []
+    for r, (n, kv) in enumerate(spans):
+        need = -(-kv // ps)
+        pt[r, :need] = perm[used:used + need]
+        used += need
+        qs.append(len(ts)); ql.append(n); kl.append(kv)
+        ts += [r] * n
+        tp += list(range(kv - n, kv))
+    T = len(ts)
+    Tp = -(-T // pad_to) * pad_to
+    ts += [0] * (Tp - T)
+    tp += [-1] * (Tp - T)
+    while len(qs) < rows:
+        qs.append(Tp); ql.append(0); kl.append(0)
+    return (jnp.asarray(pt), *(jnp.asarray(a, jnp.int32)
+                               for a in (ts, tp, qs, ql, kl)), T)
+
+
+# A prefill span of at least WIDE tokens is attended in the EXPANDED form
+# (PR 49): programs of the same launch expand each block's keys and values
+# once a group of heads and leave the span's rows in v_head_dim lanes; every
+# other row keeps the absorbed tiles, bit for bit. (spans = (tokens, context
+# at the span's end) a row, under a WIDE of 48; mp the table's pages a
+# sequence.) Head widths of whole lane tiles, which the body needs; pages of
+# 8 put 32 in a block of the walk, so a table of 90 (or 41) pages needs
+# padding to whole blocks; 32 heads are two programs of WIDE_GROUP.
+WIDE_CASES = {
+    # a prompt's first chunk: thr is -inf, every position is kept
+    "alone_at_base_0": dict(spans=[(64, 64)], served=64, mp=41),
+    # a later chunk, the selection real; the last block holds 44 positions
+    "alone_past_index_topk": dict(spans=[(64, 300)], served=64),
+    # two blocks and 188 positions of a third, a table of 90 pages
+    "a_partial_last_block": dict(spans=[(48, 700)], served=48),
+    # the cell's step in small: decode rows first, one wide span, a short one
+    "decode_rows_a_wide_span_a_short_one": dict(
+        spans=[(1, 150), (1, 77), (1, 513), (70, 600), (9, 40)], served=70),
+    # both take the expanded programs, each over its own sequence's pages
+    "two_wide_spans": dict(spans=[(1, 9), (50, 520), (60, 90)], served=110),
+    # one token short: the tiles, and today's bits
+    "one_short_of_wide": dict(spans=[(1, 30), (47, 400)], served=0),
+    # the trash page at the largest finite value: a walk's last block reads
+    # it past the span's last page, and what is expanded from it is masked
+    "the_trash_page_at_the_largest_finite": dict(
+        spans=[(56, 530)], served=56,
+        poison=float(jnp.finfo(jnp.bfloat16).max)),
+}
+
+
+@pytest.fixture
+def wide_of_48(monkeypatch):
+    """The kernel's WIDE at 48 tokens (a constant it reads as it traces: the
+    traces kept from before, and these, are dropped)."""
+    from ollamamq_tpu.ops.pallas import mla_attention as ka
+
+    monkeypatch.setattr(ka, "WIDE", 48)
+    jax.clear_caches()
+    yield ka
+    jax.clear_caches()
+
+
+def _wide_case(name, H=32):
+    from ollamamq_tpu.ops.pallas import mla_attention as ka
+
+    case = WIDE_CASES[name]
+    spans = case["spans"]
+    rng = np.random.default_rng(len(name))
+    ps, rank, lanes, dn, dv, dr, topk = 8, 128, 256, 128, 128, 64, 16
+    mp = case.get("mp", 90)
+    n_pages = 2 + sum(-(-kv // ps) for _, kv in spans)
+    lat = jnp.asarray(rng.standard_normal((2, n_pages * ps, lanes)) * 0.3,
+                      jnp.bfloat16).at[:, :, rank + dr:].set(0)
+    lat = lat.at[:, :ps].set(case.get("poison", 3e4))
+    pt, tok_seq, tok_pos, qs, ql, kl, T = _paged_stream(spans, ps, mp,
+                                                        n_pages, rng)
+    Tp = tok_pos.shape[0]
+    scores = jnp.asarray(rng.standard_normal(
+        (Tp, ka.context_lanes(mp, ps))), jnp.float32)
+    thr = mla.select_threshold(scores, tok_pos, topk)
+    q_nope, q_rope = (jnp.asarray(rng.standard_normal((Tp, H, n)) * 0.3,
+                                  jnp.bfloat16) for n in (dn, dr))
+    wukv = jnp.asarray(rng.standard_normal((rank, H, dn + dv))
+                       * rank ** -0.5, jnp.bfloat16)
+    pad = jnp.zeros((Tp, H, lanes - rank - dr), jnp.float32)
+    q_abs = jnp.concatenate([jnp.einsum(
+        "thn,chn->thc", q_nope, wukv[..., :dn],
+        preferred_element_type=jnp.float32), q_rope.astype(jnp.float32),
+        pad], axis=-1).astype(jnp.bfloat16)
+    expanded = (jnp.concatenate([q_nope, q_rope, pad.astype(jnp.bfloat16)],
+                                axis=-1), jnp.transpose(wukv, (1, 2, 0)))
+    args = (q_abs, scores, thr, lat, 1, pt, qs, ql, kl, ps, rank)
+
+    def w_uv(o):
+        return np.asarray(jnp.einsum(
+            "thc,chv->thv", o, wukv[..., dn:],
+            preferred_element_type=jnp.float32), np.float32)
+
+    twin = w_uv(mla.sparse_attention(q_abs, scores, thr, lat, 1, pt, tok_seq,
+                                     tok_pos, ps, rank))
+    return case, T, args, expanded, twin, w_uv
+
+
+@pytest.mark.parametrize("name", sorted(WIDE_CASES))
+def test_a_wide_span_is_attended_in_the_expanded_form(name, wide_of_48):
+    """Interpret mode against ops/mla.sparse_attention followed by W_uv: the
+    tokens of spans of at least WIDE tokens, and no others, come back in
+    `o_v`, through W_uv already; every other row is what the launch without
+    the expanded operands gives, bit for bit."""
+    ka = wide_of_48
+    case, T, args, expanded, twin, w_uv = _wide_case(name)
+    today = ka.mla_sparse_paged_attention_pallas(*args, interpret=True)
+    o, o_v, served = ka.mla_sparse_paged_attention_pallas(
+        *args, interpret=True, expanded=expanded)
+    served = np.asarray(served)
+    want = np.zeros(len(served), bool)
+    for start, (n, _) in zip(np.cumsum([0] + [n for n, _ in case["spans"]]),
+                             case["spans"]):
+        want[start:start + n] = n >= ka.WIDE
+    assert (served == want).all() and served.sum() == case["served"]
+    others = ~served
+    others[T:] = False
+    assert np.array_equal(np.asarray(o, np.float32)[others],
+                          np.asarray(today, np.float32)[others])
+    got = np.where(served[:, None, None], np.asarray(o_v, np.float32),
+                   w_uv(o))[:T]
+    assert np.isfinite(got).all()
+    # two roundings apart: K and V are rounded where the absorbed q is
+    assert np.abs(got - twin[:T]).max() <= 2 ** -7 * max(
+        1.0, np.abs(twin[:T]).max())
+
+
+def test_a_layer_takes_a_wide_spans_rows_as_the_kernel_leaves_them(
+        wide_of_48):
+    """`_latent_attention_op` with the kernel's schedule (interpret mode)
+    against the jnp twin's, at head widths of whole lane tiles: the layer
+    builds the expanded form's q and `[W_uk | W_uv]^T`, the kernel serves
+    the wide span from them, and `attn_out` takes those rows through W_uv
+    already and the others through it — one answer, and `wide` names the
+    span's rows."""
+    mc = dataclasses.replace(
+        DS, name="wide-lanes", num_heads=16, num_kv_heads=16, head_dim=192,
+        kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, index_head_dim=64)
+    assert mc.latent_lanes == 256
+    params = make_params(mc, dtype=jnp.bfloat16)
+    lp = {k: v[0] for k, v in params["layers"].items()
+          if k in llama.MLA_PARAMS or k == "wo"}
+    ps, n_pages, T = 8, 64, 64
+    spans = [(1, 90), (1, 33), (50, 300), (5, 20)]  # tokens, context
+    pt, ts, tp, qs, ql, kl, real = _paged_stream(
+        spans, ps, 40, n_pages, np.random.default_rng(3), pad_to=T)
+    rng = np.random.default_rng(4)
+    kc = jnp.asarray(rng.standard_normal((1, n_pages * ps, 256)) * 0.3,
+                     jnp.bfloat16).at[:, :, 192:].set(0)
+    vc = jnp.asarray(rng.standard_normal((1, n_pages * ps, 64)),
+                     jnp.bfloat16)
+    slots = jnp.where(tp >= 0, llama.flat_slot_indices(
+        pt[ts], jnp.maximum(tp, 0)[:, None], ps)[:, 0], 0)
+    h = jnp.asarray(rng.standard_normal((1, T, mc.hidden_size)),
+                    jnp.bfloat16)
+    seen = {}
+
+    def layer(impl):
+        def attn_fn(q, row, index, expanded=None):
+            _, _, out = llama._latent_ragged(
+                mc, q, row, index, kc, vc, 0, slots, pt, ts, tp, qs, ql, kl,
+                ps, impl, True, expanded=expanded)
+            seen[impl] = out
+            return out
+        return np.asarray(llama._latent_attention_op(
+            mc, lp, h, jnp.maximum(tp, 0)[None], attn_fn), np.float32)[0]
+
+    twin, got = layer("jnp"), layer("pallas")
+    assert not isinstance(seen["jnp"], tuple)
+    wide = np.asarray(seen["pallas"][2])[0]
+    assert wide[2:52].all() and wide.sum() == 50
+    assert np.abs(got - twin)[:real].max() <= 2 ** -6 * max(
+        1.0, np.abs(twin[:real]).max())
+
+
+def _kernel_jaxpr(fn, *shapes):
+    """The pallas_call a traced launch holds, as text: its grid and blocks,
+    and the kernel's body."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found = find(sub)
+                if found is not None:
+                    return found
+
+    eqn = find(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    return str(eqn.params["grid_mapping"]) + str(eqn.params["jaxpr"])
+
+
+def test_no_other_launch_holds_the_expanded_body():
+    """The dense kernel's traced program is the one PR 48's tree traced (a
+    digest of its text at openPangu's widths, a ragged rung and the scan's
+    tiles of one: take it again from `_kernel_jaxpr` only with a change that
+    means to touch that kernel), and the masked kernel's on a rung under
+    WIDE is the same program with the expanded operands as without."""
+    import hashlib
+
+    from ollamamq_tpu.ops.pallas import mla_attention as ka
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    pool = s((2, 4096, 640), bf)
+    meta = (s((8, 40), i32), s((8,), i32), s((8,), i32), s((8,), i32))
+    for (T, tile), digest in (((512, None), "896b332f0bdee44d"),
+                              ((16, 1), "c9460fcf403aa502")):
+        text = str(jax.make_jaxpr(
+            lambda q, pool, *m: ka.mla_dense_paged_attention_pallas(
+                q, pool, 1, *m, 32, 512, tile=tile))(
+                    s((T, 128, 640), bf), pool, *meta))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+    T = ka.WIDE - ka.WIDE % -64 - 64  # the rung under WIDE
+    C = ka.context_lanes(40, 32)
+    sel = (s((T, 128, 640), bf), s((T, C), f32), s((T,), f32), pool)
+    plain = _kernel_jaxpr(
+        lambda q, sc, thr, pool, *m: ka.mla_sparse_paged_attention_pallas(
+            q, sc, thr, pool, 1, *m, 32, 512), *sel, *meta)
+    given = _kernel_jaxpr(
+        lambda q, sc, thr, pool, qe, w, *m:
+        ka.mla_sparse_paged_attention_pallas(
+            q, sc, thr, pool, 1, *m, 32, 512, expanded=(qe, w)),
+        *sel, s((T, 128, 256), bf), s((128, 256, 512), bf), *meta)
+    assert ka.expands(512, 128, 640, 512, 128, 128)
+    assert not ka.expands(T, 128, 640, 512, 128, 128)
+    assert plain == given
+
+
+# `ModelRuntime._note_latent` puts the kernel's own count of those tokens on
+# the step's sample: (spans, rung, wide tokens) at DeepSeek-V3.2's widths.
+WIDE_STEPS = {
+    "the_cells_step": ([(1, 9000)] * 5 + [(507, 12000)], 512, 507),
+    "a_tail_and_a_head": ([(1, 9000)] * 4 + [(188, 16000), (320, 320)], 512,
+                          320),
+    "two_short_spans": ([(250, 8200), (262, 262)], 512, 0),
+    "a_rung_under_wide": ([(1, 9000), (255, 255)], 256, 0),
+    "decode_rows_alone": ([(1, 9000)] * 16, 16, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WIDE_STEPS))
+def test_step_sample_carries_mla_wide_tokens(name):
+    """From a step's composition alone, beside `mla_rows`: a ragged step's,
+    where the kernel serves; nothing in a fused scan or on the jnp path."""
+    import functools
+    import types
+
+    from ollamamq_tpu.engine.engine import ModelRuntime
+    from ollamamq_tpu.ops.pallas.mla_attention import WIDE, wide_tokens
+    from ollamamq_tpu.telemetry import schema as tm
+
+    spans, rung, wide = WIDE_STEPS[name]
+    assert wide == sum(n for n, _ in spans if n >= WIDE) * (rung >= WIDE)
+    series = [c.labels(model="wide-" + name) for c in (
+        tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
+        tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL)]
+    cfg = types.SimpleNamespace(kv_lora_rank=512, index_topk=2048)
+    rt = types.SimpleNamespace(
+        cfg=cfg, LATENT_FIELDS=ModelRuntime.LATENT_FIELDS, _tm_dsa=series,
+        _wide_tokens=functools.partial(wide_tokens, heads=128, lanes=640,
+                                       rank=512, nope=128, v=128))
+    noted = {}
+    sp = types.SimpleNamespace(note=noted.update)
+    ModelRuntime._note_latent(rt, sp, spans, stream_len=rung)
+    assert noted["mla_wide_tokens"] == wide
+    assert noted["mla_rows"] == sum(n for n, _ in spans)
+    assert series[3].value == wide
+    ModelRuntime._note_latent(rt, sp, [(8, kv) for _, kv in spans], scan=True)
+    assert noted["mla_wide_tokens"] == 0
+    rt._wide_tokens = None  # the jnp path: no kernel, nothing expanded
+    ModelRuntime._note_latent(rt, sp, spans, stream_len=rung)
+    assert noted["mla_wide_tokens"] == 0 and series[3].value == wide
+
+
 # ------------------------------------------------- the engine, by id stream
 def _arrivals(n=5, lens=(5, 40, 9, 23, 31), every=2, out=9):
     return [(every * i, f"u{i}", _prompt(i, lens[i % len(lens)]),
