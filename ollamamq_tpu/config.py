@@ -10,7 +10,9 @@ a dense prefix, a sigmoid router with a selection bias) and Olmo-Hybrid
 sublayers' outputs, no rotary embedding) and Qwen3-Next (the rule with
 fewer key heads than value heads beside gated attention with a partial
 rotary embedding, zero-centred norms, experts behind a gated shared
-expert), plus a bidirectional encoder config for embedding models
+expert) and K-EXAONE (window attention over the last `sliding_window`
+positions beside full attention, a rotary embedding on the window layers
+only), plus a bidirectional encoder config for embedding models
 (nomic-embed-text class). The dense names are the ones the reference's
 stress test exercises (/root/reference/test_dispatcher.sh:5-7) and
 BASELINE.json's configs list.
@@ -25,9 +27,14 @@ from typing import Optional
 
 # A layer's operator (the published `layer_types` spellings) and its FFN.
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
-LAYER_KINDS = (ATTENTION, CONV, LINEAR)
-# The kinds whose layers keep a fixed-size state a SLOT beside the paged pool.
-STATE_KINDS = (CONV, LINEAR)
+WINDOW = "sliding_attention"
+LAYER_KINDS = (ATTENTION, CONV, LINEAR, WINDOW)
+# The kinds whose layers keep a fixed-size state a SLOT beside the paged pool
+# (a window layer's: a ring of its last K and V rows).
+STATE_KINDS = (CONV, LINEAR, WINDOW)
+# The kinds that are attention over K and V: they share the attention
+# weights' stacks (`wq` ... one entry a layer of EITHER kind, in layer order).
+ATTENTION_KINDS = (ATTENTION, WINDOW)
 DENSE, EXPERTS = "dense", "experts"
 # The longest period `ModelConfig.layer_plan` looks for.
 MAX_PERIOD = 8
@@ -214,6 +221,38 @@ class ModelConfig:
     mlp_only_layers: tuple = ()
     use_sliding_window: bool = False
     full_attention_interval: Optional[int] = None
+    # -- window attention beside full attention (K-EXAONE) -------------------
+    # A "sliding_attention" layer attends over the last `sliding_window`
+    # positions only: query i sees key j iff i - sliding_window < j <= i
+    # (itself and the sliding_window - 1 before it). Its K and V rows are
+    # dead that many positions later, so they do not live in the paged pool:
+    # each slot owns a RING of `ring_rows` rows a window layer
+    # (ops/attention.py: position p at row p % ring_rows of the slot's),
+    # and `cache_layers` counts the full layers only. The published
+    # spelling; 0 where no layer has a window.
+    sliding_window: int = 0
+    # Published keys that say what `layer_types`, `sliding_window` and
+    # `first_k_dense_replace` say already, read and held to them: a layer's
+    # window (0: full attention), the pattern the list repeats ("L" a window
+    # layer, "G" a full one: `layer_types[i]` is pattern[i % len]), a
+    # layer's FFN ("dense" | "sparse").
+    sliding_windows: Optional[tuple] = None
+    sliding_window_pattern: Optional[str] = None
+    mlp_layer_types: Optional[tuple] = None
+    # ...and the prediction module's layer kind and window: read, and
+    # refused unless `num_nextn_predict_layers` is 0 (the module is served
+    # for latent attention only).
+    mtp_layer_types: Optional[tuple] = None
+    mtp_sliding_windows: Optional[tuple] = None
+    # The attention kinds whose q and k take the rotary embedding (None:
+    # every kind, where `rope_theta` is set). K-EXAONE rotates on the
+    # window layers only: its full layers attend without positions. This
+    # repo's naming.
+    rope_layer_types: Optional[tuple] = None
+    # Published spellings of `n_shared_experts` and `router_score` (either
+    # or both, agreeing).
+    num_shared_experts: Optional[int] = None
+    scoring_func: Optional[str] = None
     # The multi-token-prediction module (DeepSeek-V3's formulation, depth 1):
     # from the trunk's last hidden of position i (before the final norm) and
     # the embedding of token i + 1, through two norms, a projection of their
@@ -240,11 +279,14 @@ class ModelConfig:
                 f"{self.norm_order!r}")
         if self.rope_parameters is not None:
             group = dict(self.rope_parameters)  # a file's dict: hashable
-            unknown = sorted(set(group) - {"rope_theta"})
+            plain = group.get("rope_type", "default") == "default"
+            unknown = sorted(set(group) - {"rope_theta"}
+                             - ({"rope_type"} if plain else set()))
             if unknown:
                 raise ValueError(
                     f"{self.name}: rope_parameters holds {unknown}; the "
-                    "program reads 'rope_theta' only")
+                    "program reads 'rope_theta' only (and a 'rope_type' of "
+                    "'default': plain frequencies)")
             object.__setattr__(self, "rope_parameters",
                                tuple(sorted(group.items())))
             if "rope_theta" in group:
@@ -283,8 +325,7 @@ class ModelConfig:
                            tuple(self.mlp_only_layers))  # a file's list
         for key, only in (("moe_layer_freq", 1), ("ep_size", 1),
                           ("decoder_sparse_step", 1),
-                          ("mlp_only_layers", ()),
-                          ("use_sliding_window", False)):
+                          ("mlp_only_layers", ())):
             if getattr(self, key) != only:
                 raise ValueError(
                     f"{self.name}: {key} {getattr(self, key)}: the program "
@@ -304,6 +345,8 @@ class ModelConfig:
                     f"got {sorted(group)}")
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(group.items())))
+        self._fold_router_spellings()
+        self._check_window()
         self._check_latent()
         self._check_share()
         self._check_gated()
@@ -320,6 +363,93 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: conv_bias true: the program's convolution "
                 "layers carry no bias")
+
+    def _fold_router_spellings(self) -> None:
+        """The `exaone_moe` spellings of the router's fields, folded into
+        the program's."""
+        for alias, field in (("num_shared_experts", "n_shared_experts"),
+                             ("scoring_func", "router_score")):
+            value = getattr(self, alias)
+            if value is None:
+                continue
+            default = type(self).__dataclass_fields__[field].default
+            if getattr(self, field) not in (default, value):
+                raise ValueError(
+                    f"{self.name}: {alias} {value!r} is not {field} "
+                    f"{getattr(self, field)!r}")
+            object.__setattr__(self, field, value)
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"{self.name}: scoring_func must be 'softmax' or 'sigmoid', "
+                f"got {self.router_score!r}")
+        if self.n_group == 1 and self.topk_group == 1:
+            # one group that is always chosen: no group limit
+            object.__setattr__(self, "n_group", 0)
+            object.__setattr__(self, "topk_group", 0)
+
+    def _check_window(self) -> None:
+        """Window layers: the published keys that describe them agree."""
+        for key in ("sliding_windows", "mlp_layer_types", "mtp_layer_types",
+                    "mtp_sliding_windows", "rope_layer_types"):
+            if getattr(self, key) is not None:  # a file's list: hashable
+                object.__setattr__(self, key, tuple(getattr(self, key)))
+        kinds = self.layer_types or (ATTENTION,) * self.num_layers
+        has = WINDOW in kinds
+        if has != (self.sliding_window > 0) or self.sliding_window < 0:
+            raise ValueError(
+                f"{self.name}: sliding_window {self.sliding_window} with "
+                f"{'a' if has else 'no'} {WINDOW!r} layer in layer_types: "
+                "the window layers and their width come together")
+        if self.use_sliding_window and not has:
+            raise ValueError(
+                f"{self.name}: use_sliding_window {self.use_sliding_window}: "
+                f"the program's window layers are {WINDOW!r} entries of "
+                "layer_types, and there is none")
+        if has and (self.kv_lora_rank or self.attn_output_gate):
+            raise ValueError(
+                f"{self.name}: {WINDOW!r} layers are served with plain K/V "
+                "attention (no kv_lora_rank, no attn_output_gate)")
+        want = tuple(self.sliding_window if k == WINDOW else 0 for k in kinds)
+        if self.sliding_windows not in (None, want):
+            raise ValueError(
+                f"{self.name}: sliding_windows does not agree with "
+                "layer_types and sliding_window (a window layer's entry is "
+                "sliding_window, every other layer's 0)")
+        pattern = self.sliding_window_pattern
+        if pattern is not None:
+            letters = {WINDOW: "L", ATTENTION: "G"}
+            if not pattern or any(
+                    letters.get(k) != pattern[i % len(pattern)]
+                    for i, k in enumerate(kinds)):
+                raise ValueError(
+                    f"{self.name}: sliding_window_pattern {pattern!r} does "
+                    "not agree with layer_types ('L' a sliding_attention "
+                    "layer, 'G' a full_attention one, repeated)")
+        if self.mlp_layer_types is not None:
+            first = self.first_k_dense_replace
+            if first is None:
+                first = self.num_dense_layers
+            want = tuple("dense" if i < first or not self.num_experts
+                         else "sparse" for i in range(self.num_layers))
+            if self.mlp_layer_types != want:
+                raise ValueError(
+                    f"{self.name}: mlp_layer_types does not agree with "
+                    "first_k_dense_replace / num_dense_layers (the leading "
+                    "layers 'dense', every other layer 'sparse')")
+        if self.num_nextn_predict_layers and (
+                self.mtp_layer_types or self.mtp_sliding_windows):
+            raise ValueError(
+                f"{self.name}: mtp_layer_types / mtp_sliding_windows with "
+                "num_nextn_predict_layers "
+                f"{self.num_nextn_predict_layers}: the prediction module is "
+                "served for latent attention only (ROADMAP B-M6)")
+        if self.rope_layer_types is not None and (
+                self.rope_theta is None
+                or set(self.rope_layer_types) - set(ATTENTION_KINDS)):
+            raise ValueError(
+                f"{self.name}: rope_layer_types {self.rope_layer_types}: "
+                f"kinds of {list(ATTENTION_KINDS)} that rotate, with a "
+                "rope_theta")
 
     def _check_latent(self) -> None:
         """What latent attention and its indexer cannot run with."""
@@ -471,8 +601,8 @@ class ModelConfig:
     # -- the stack, layer by layer ------------------------------------------
     @property
     def kinds(self) -> tuple:
-        """Each layer's (operator, FFN): (ATTENTION | CONV | LINEAR, DENSE |
-        EXPERTS)."""
+        """Each layer's (operator, FFN): (ATTENTION | CONV | LINEAR | WINDOW,
+        DENSE | EXPERTS)."""
         ops = self.layer_types or (ATTENTION,) * self.num_layers
         first_sparse = self.num_dense_layers if self.num_experts \
             else self.num_layers
@@ -482,6 +612,25 @@ class ModelConfig:
     def count(self, kind: str) -> int:
         """Layers whose operator or FFN is `kind`."""
         return sum(kind in pair for pair in self.kinds)
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that are attention over K and V, window or full: the
+        entries of the attention weights' stacks."""
+        return sum(self.count(kind) for kind in ATTENTION_KINDS)
+
+    def rotates(self, kind: str) -> bool:
+        """Do q and k of an attention layer of `kind` take RoPE?"""
+        return self.rope_theta is not None and (
+            self.rope_layer_types is None or kind in self.rope_layer_types)
+
+    def ring_rows(self, max_span: int, page_size: int) -> int:
+        """Rows of a slot's ring a window layer: whole pages that hold the
+        window, the longest span a step writes (`max_span` tokens: its
+        first query still sees the window before it) and one page (a walk
+        starts at a page boundary)."""
+        need = self.sliding_window + max_span + page_size
+        return -(-need // page_size) * page_size
 
     @property
     def expert_width(self) -> int:
@@ -519,8 +668,9 @@ class ModelConfig:
 
     @property
     def cache_layers(self) -> int:
-        """Layers of the paged pools: the attention layers, and behind them
-        the prediction module's block (its own cache rows)."""
+        """Layers of the paged pools: the FULL attention layers (a window
+        layer's rows live in its slots' rings), and behind them the
+        prediction module's block (its own cache rows)."""
         return self.count(ATTENTION) + self.num_nextn_predict_layers
 
     @property
@@ -626,7 +776,7 @@ class ModelConfig:
                     r * self.index_n_heads * self.index_head_dim
                     + (d + 2) * self.index_head_dim + d * self.index_n_heads)
         per_op = {
-            ATTENTION: attention,
+            ATTENTION: attention, WINDOW: attention,
             CONV: 3 * d * d + d * d + d * self.conv_L_cache,
             # q | k | v | z and the two gates in, the taps, A_log and
             # dt_bias, the output norm, out.
@@ -867,6 +1017,24 @@ MODEL_CONFIGS = {
         num_experts=8, router_experts=16, expert_offset=0,
         num_experts_per_tok=4, norm_topk_prob=True, moe_intermediate_size=32,
         shared_expert_intermediate_size=48, shared_expert_gate=True,
+    ),
+    # Tiny K-EXAONE: the published 3 : 1 of window (8 positions) and full
+    # layers behind a leading dense layer, RoPE on the window layers only,
+    # per-head q/k norm at group 2, a sigmoid router with a selection bias
+    # over 16 experts of which this program holds 4, one shared expert.
+    "test-tiny-k-exaone": ModelConfig(
+        name="test-tiny-k-exaone", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=5, num_heads=4, num_kv_heads=2,
+        head_dim=16, rope_theta=10_000.0, rms_norm_eps=1e-5, max_seq_len=512,
+        qk_norm="head", sliding_window=8, sliding_window_pattern="LLLG",
+        layer_types=("sliding_attention",) * 3 + ("full_attention",
+                                                  "sliding_attention"),
+        rope_layer_types=("sliding_attention",),
+        num_experts=4, router_experts=16, expert_offset=0,
+        num_experts_per_tok=4, n_group=1, topk_group=1, num_shared_experts=1,
+        moe_intermediate_size=32, first_k_dense_replace=1,
+        scoring_func="sigmoid", use_expert_bias=True, norm_topk_prob=True,
+        norm_topk_eps=1e-20, routed_scaling_factor=2.5,
     ),
     # Tiny DeepSeek-V3.2: latent attention with the indexer's selection (top
     # 16: well under the tests' contexts), YaRN, a dense layer then expert
@@ -1366,24 +1534,35 @@ def validate_tiers(spec: Optional[str], members) -> Optional[str]:
 
 
 def validate_slot_state(cfg: ModelConfig, spec: bool = False,
-                        mesh_shape=None) -> Optional[str]:
+                        mesh_shape=None,
+                        kv_dtype: str = "bfloat16") -> Optional[str]:
     """What a model with ANY per-slot state (conv layers' windows, the
-    linear-attention layers' matrices: STATE_KINDS) cannot be served with
-    yet, told BEFORE any device work: returns an error string (None =
-    valid). Each of these touches per-sequence state and knows only the
-    paged KV pool; run on such a model it would serve K and V without the
-    state beside them (ROADMAP B-M5 names what each lacks)."""
+    linear-attention layers' matrices, the window layers' K/V rings:
+    STATE_KINDS) cannot be served with yet, told BEFORE any device work:
+    returns an error string (None = valid). Each of these touches
+    per-sequence state and knows only the paged KV pool; run on such a
+    model it would serve K and V without the state beside them (ROADMAP
+    B-M5 names what each lacks; B-M2 for the window layers' rings)."""
     held = [kind for kind in STATE_KINDS if cfg.count(kind)]
     if not held:
         return None
     shape = dict(mesh_shape or {})
+    ring = cfg.count(WINDOW) > 0
     why = None
-    if spec:
+    if spec and held == [WINDOW]:
+        why = ("--spec: a verify span writes the window layers' rings, and "
+               "neither the draft cap nor the rollback has been written "
+               "for them (ROADMAP B-M2)")
+    elif spec:
         why = ("--spec: a rejected draft has already advanced the per-slot "
                "conv / recurrent state, and rollback restores pages only")
     elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
         why = (f"--tp / --ep: the {' and '.join(held)} layers' weights and "
-               "state have no partition specs")
+               "state have no partition specs"
+               + (" (the rings: ROADMAP B-M2)" if ring else ""))
+    elif ring and kv_dtype != "bfloat16":
+        why = ("--kv-dtype int8: the window layers' rings hold bfloat16 "
+               "rows and no scale planes (ROADMAP B-M2)")
     if why is None:
         return None
     return (f"model {cfg.name} has {' and '.join(held)} layers "

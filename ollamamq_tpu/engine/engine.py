@@ -42,7 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, LINEAR,
+from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, LINEAR, WINDOW,
                                  STATE_KINDS, EngineConfig, ModelConfig,
                                  get_model_config, smart_match,
                                  validate_latent_pool, validate_quant_config,
@@ -56,6 +56,7 @@ from ollamamq_tpu.engine.request import (FinishReason, Request, StreamItem,
 from ollamamq_tpu.engine.scheduler import make_policy
 from ollamamq_tpu.engine.tokenizer import load_tokenizer
 from ollamamq_tpu.models import llama, moe, weights
+from ollamamq_tpu.ops.attention import ring_first_page
 from ollamamq_tpu.ops.sampling import (accept_prefix, maybe_apply_penalties,
                                        per_row_keys, sample_tokens_rowwise,
                                        sampling_flags)
@@ -112,6 +113,15 @@ def drop_expired(req: Request, core: MQCore, model: str,
                        slack_ms=round(slack, 3))
     req.finish(FinishReason.DEADLINE,
                error="deadline expired before completion")
+
+
+def ragged_budget(engine_cfg: EngineConfig) -> int:
+    """Tokens of the longest ragged stream a runtime launches: the token
+    budget — or a full decode batch (one token a slot) plus one granule of
+    prefill, which must always fit one dispatch — in whole granules."""
+    g = max(1, engine_cfg.token_granule)
+    return -(-max(engine_cfg.max_batch_tokens,
+                  engine_cfg.max_slots + g) // g) * g
 
 
 def select_attn_impl(backend: str, kv_dtype: str) -> Tuple[str, str]:
@@ -535,7 +545,8 @@ class ModelRuntime:
             raise ValueError(err)
         err = validate_slot_state(
             model_cfg, spec=engine_cfg.spec,
-            mesh_shape=dict(mesh.shape) if mesh is not None else {})
+            mesh_shape=dict(mesh.shape) if mesh is not None else {},
+            kv_dtype=engine_cfg.kv_dtype)
         if err is not None:
             raise ValueError(err)
         err = validate_latent_pool(
@@ -618,13 +629,19 @@ class ModelRuntime:
         # pages): the conv layers' window (ops/shortconv.py's array), for a
         # model with linear-attention layers a llama.SlotState of their
         # convolution's window and the rule's float32 matrices
-        # (ops/gated_delta.py), None for a model without such layers. With
+        # (ops/gated_delta.py) — for a model with window layers a
+        # llama.WindowState with their K/V rings too
+        # (ops/attention.py:WindowRing: `ring_rows` rows a slot a
+        # layer whatever the context; the paged pool then holds the FULL
+        # layers only) — None for a model without such layers. With
         # kc, vc, recent and last_ids a donated argument and result of
         # every step program. Never reset from the host: a request's first
         # span opens its slot's rows at zero inside the program
-        # (`is_first`).
+        # (`is_first`); a ring's rows past a sequence's end are masked.
         self.slot_state = llama.alloc_slot_state(
-            model_cfg, engine_cfg.max_slots, dtype)
+            model_cfg, engine_cfg.max_slots, dtype,
+            ring_rows=model_cfg.ring_rows(ragged_budget(engine_cfg),
+                                          engine_cfg.page_size))
         self.alloc = kvc.PageAllocator(
             engine_cfg.num_pages, engine_cfg.page_size, engine_cfg.max_pages_per_seq
         )
@@ -757,11 +774,8 @@ class ModelRuntime:
         # Ragged mixed-batch scheduling: prefill spans + decode tokens
         # pack into ONE token-budget dispatch (no bucket padding).
         g = max(1, engine_cfg.token_granule)
-        # A full decode batch (one token per slot) plus at least one
-        # granule of prefill must always fit one dispatch.
         self._granule = g
-        self._ragged_budget = -(-max(engine_cfg.max_batch_tokens,
-                                     engine_cfg.max_slots + g) // g) * g
+        self._ragged_budget = ragged_budget(engine_cfg)
         # Allowed stream totals: a power-of-two ladder over the granule,
         # capped by the budget — one compile per rung; the composer TRIMS
         # the last span down to a rung instead of padding up to one, so
@@ -893,13 +907,24 @@ class ModelRuntime:
                      self.weight_stacks_relaid, relaid_bytes / 1e6)
         # What a deployment is sized by: the fixed per-slot state, and
         # what each token of context adds to the pool.
-        conv, rule = llama.split_state(self.slot_state)
-        self.conv_state_bytes, self.lin_state_bytes = (
-            0 if a is None else a.size * a.dtype.itemsize
-            for a in (conv, rule))
+        conv, rule, ring = llama.split_state(self.slot_state)
+        self.conv_state_bytes, self.lin_state_bytes, self.ring_bytes = (
+            0 if a is None else int(a.nbytes) for a in (conv, rule, ring))
         tm.HBM_CONV_STATE_BYTES.labels(model=name).set(self.conv_state_bytes)
         tm.HBM_LIN_STATE_BYTES.labels(model=name).set(self.lin_state_bytes)
-        if self.slot_state is not None:
+        tm.HBM_SWA_RING_BYTES.labels(model=name).set(self.ring_bytes)
+        self._tm_swa = [c.labels(model=name) for c in (
+            tm.SWA_PAIRS_TOTAL, tm.SWA_CTX_ROWS_TOTAL,
+            tm.SWA_WALK_ROWS_TOTAL, tm.SWA_FULL_ROWS_TOTAL)]
+        if ring is not None:
+            log.info("%s: window layers' K/V rings %.1f MB (%d layers x %d "
+                     "slots x %d rows x %d B, whatever the context) beside "
+                     "the paged pool's %.1f MB (%d full layers)", name,
+                     self.ring_bytes / 1e6, model_cfg.count(WINDOW),
+                     engine_cfg.max_slots, ring.rows,
+                     2 * model_cfg.kv_dim * jnp.dtype(dtype).itemsize,
+                     self.kv_bytes / 1e6, model_cfg.cache_layers)
+        elif self.slot_state is not None:
             log.info("%s: per-slot state %.1f MB (conv window %.1f MB, rule "
                      "state %.1f MB, float32) for %d slots beside the KV "
                      "pool's %.1f MB", name,
@@ -1405,6 +1430,48 @@ class ModelRuntime:
         counts = (int(pairs.sum()), int((pairs if scan else kv).sum()), tall)
         _sp.note(**dict(zip(self.ATTN_FIELDS, counts)))
         for series, c in zip(self._tm_attn, counts):
+            series.inc(c)
+
+    # What `_note_swa` writes (a model with window layers).
+    SWA_FIELDS = ("swa_pairs", "swa_ctx_rows", "swa_walk_rows",
+                  "swa_full_rows")
+
+    def _note_swa(self, _sp, tokens, kv, scan: bool = False) -> None:
+        """A launched step's WINDOW attention, onto its sample and the
+        /metrics series, from its composition alone — `_note_attn`'s
+        sibling (that one stands for the FULL layers), with its arguments.
+        `swa_pairs` the in-window (query token, cached position) pairs: a
+        token at position p attends min(p + 1, sliding_window);
+        `swa_ctx_rows` the cached rows a window launch has to read at the
+        least: a span of n tokens that ends at context kv reads min(kv, n +
+        sliding_window - 1) of them (a scan's pass: each slot's min(kv,
+        sliding_window)); `swa_walk_rows` the rows the launch's walks DO
+        cover: from the page its table starts at
+        (ops/attention.py:ring_first_page, the table's own rule) to the
+        span's end; `swa_full_rows` what a walk of the same contexts from
+        position 0 would have covered (`attn_ctx_rows`' count). A window
+        layer's worth: every window layer does the same. Nothing for a model
+        without window layers."""
+        w = self.cfg.sliding_window
+        if not w:
+            return
+        ps = self.ecfg.page_size
+        n, kv = np.asarray(tokens, np.int64), np.asarray(kv, np.int64)
+        if scan:  # each pass is a span of one token at its own context
+            k = int(n.max(initial=0))
+            kv = (kv[:, None] - n[:, None] + 1 + np.arange(k)[None, :]
+                  )[np.arange(k)[None, :] < n[:, None]]
+            n = np.ones_like(kv)
+        # positions kv-n .. kv-1 attend min(p + 1, w): all w but the first
+        # w - 1 positions of a sequence, which attend p + 1
+        first = kv - n  # the span's first position
+        short = np.clip(w - 1 - first, 0, n)  # its tokens at p < w - 1
+        pairs = (n - short) * w + short * (2 * first + short + 1) // 2
+        walk = kv - ring_first_page(kv, n, w, ps) * ps
+        counts = tuple(int(a.sum()) for a in (
+            pairs, np.minimum(kv, n + w - 1), walk, kv))
+        _sp.note(**dict(zip(self.SWA_FIELDS, counts)))
+        for series, c in zip(self._tm_swa, counts):
             series.inc(c)
 
     def _dispatch_decode(self, k_steps, buf):
@@ -2825,6 +2892,7 @@ class ModelRuntime:
                               sum(n for n in spans if n > 1))
         self._note_latent(_sp, zip(spans, row_kv), stream_len=T_pad)
         self._note_attn(_sp, spans, row_kv, stream_len=T_pad)
+        self._note_swa(_sp, spans, row_kv)
         _sp.mark("dispatch")
         _sp.park()
 
@@ -3044,6 +3112,8 @@ class ModelRuntime:
                                  + int(k_steps)) for i in active], scan=True)
         self._note_attn(_sp, [int(k_steps)] * len(active),
                         self.seq_lens[active] + int(k_steps), scan=True)
+        self._note_swa(_sp, [int(k_steps)] * len(active),
+                       self.seq_lens[active] + int(k_steps), scan=True)
         _sp.mark("dispatch")
         _sp.park()
         for i in active:
@@ -3360,6 +3430,7 @@ class ModelRuntime:
             # the per-slot state beside the pool (0 for a model without)
             "conv_state_bytes": self.conv_state_bytes,
             "lin_state_bytes": self.lin_state_bytes,
+            "swa_ring_bytes": self.ring_bytes,
             "weights_dtype": self.weights_dtype,
             "kv_dtype": self.kv_dtype,
             "attn_impl": self.attn_impl,
@@ -4741,7 +4812,8 @@ class TPUEngine:
                      "kv_bytes": int(getattr(rt, "kv_bytes", 0)),
                      "slot_state_bytes": int(
                          getattr(rt, "conv_state_bytes", 0)
-                         + getattr(rt, "lin_state_bytes", 0))}
+                         + getattr(rt, "lin_state_bytes", 0)
+                         + getattr(rt, "ring_bytes", 0))}
             alloc = getattr(rt, "alloc", None)
             if alloc is not None:
                 entry.update(free=alloc.free_pages, used=alloc.used_pages,

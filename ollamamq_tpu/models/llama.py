@@ -1,10 +1,12 @@
 """Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2 / Olmo-Hybrid /
-Qwen3-Next) in pure functional JAX.
+Qwen3-Next / K-EXAONE) in pure functional JAX.
 
 A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))` — or, with
 `norm_order` "post", `x + norm(Op(x))`, `x + norm(FFN(x))`, or with
 `sandwich_norm` both norms: `x + norm'(Op(norm(x)))`. Op is attention
-(`_attention_op`), a gated short convolution (`_conv_op`) or gated
+(`_attention_op`: over the whole context, or — a `sliding_attention` layer
+— over the last `sliding_window` positions, its K and V in a per-slot ring
+beside the pool), a gated short convolution (`_conv_op`) or gated
 delta-rule linear attention (`_linear_attention_op`); FFN is a dense
 SwiGLU (`_mlp`) or routed experts (models/moe.py). Each is defined ONCE and
 used by every forward; `ModelConfig.kinds` says which pair a layer is. A
@@ -50,17 +52,21 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (ATTENTION, CONV, DENSE, EXPERTS, LINEAR,
-                                 ModelConfig)
+from ollamamq_tpu.config import (ATTENTION, ATTENTION_KINDS, CONV, DENSE,
+                                 EXPERTS, LINEAR, WINDOW, ModelConfig)
 from ollamamq_tpu.models.moe import (SHARED, SHARED_GATE, STACKED,
                                      init_moe_layer_params, moe_mlp)
 from ollamamq_tpu.ops import gated_delta, mla, shortconv
 from ollamamq_tpu.ops.attention import (
+    WindowRing,
+    alloc_ring,
     causal_attention,
     bidirectional_attention,
     flat_slot_indices,
     paged_decode_attention_any,
     ragged_attention_any,
+    ring_table,
+    ring_write_slots,
 )
 from ollamamq_tpu.ops.quant import embed_lookup, kv_write, logits_head, qeinsum
 from ollamamq_tpu.ops.rope import (apply_rope, apply_rope_freqs, rope_freqs,
@@ -113,7 +119,9 @@ INDEX_NORM_EPS = 1e-6
 # the state nor freezes it.
 LINEAR_A_RANGE, LINEAR_DT_RANGE = (1.0, 16.0), (1e-3, 1e-1)
 # The weights of each operator and FFN kind (stacked over the layers of
-# that kind); every other entry of `layers` is stacked over all layers.
+# that kind; the attention weights over the layers of EITHER attention kind,
+# window or full, in layer order: `scan_layers`); every other entry of
+# `layers` is stacked over all layers.
 KIND_PARAMS = {
     ATTENTION: ("wq", "wq_gate", "wk", "wv", "wo", "bq", "bk", "bv",
                 "q_norm", "k_norm") + MLA_PARAMS,
@@ -158,8 +166,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     # experts'): the trunk's loop never reaches it, `forward_mtp` reads it.
     n_mtp = cfg.num_nextn_predict_layers
     L, v = cfg.num_layers + n_mtp, cfg.vocab_size
-    La, Lc, Ld = cfg.count(ATTENTION) + n_mtp, cfg.count(CONV), \
-        cfg.count(DENSE)
+    La, Lc, Ld = cfg.attn_layers + n_mtp, cfg.count(CONV), cfg.count(DENSE)
     Ll = cfg.count(LINEAR)
     keys = jax.random.split(key, 10)
 
@@ -340,29 +347,55 @@ class SlotState(NamedTuple):
     rule: Optional[jnp.ndarray] = None
 
 
-def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16):
-    """What `conv_state` of the step forwards is for `cfg`, at zero."""
+class WindowState(NamedTuple):
+    """...and of a model with window layers: their K/V rings
+    (ops/attention.py:WindowRing) beside whatever else its layers keep."""
+    conv: Optional[jnp.ndarray]
+    rule: Optional[jnp.ndarray]
+    ring: Optional[WindowRing]
+
+
+def _as_given(conv, rule, ring):
+    """A forward's `conv_state` in the form the docstrings above name."""
+    if ring is not None:
+        return WindowState(conv, rule, ring)
+    return conv if rule is None else SlotState(conv, rule)
+
+
+def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16,
+                     ring_rows: int = 0):
+    """What `conv_state` of the step forwards is for `cfg`, at zero.
+    `ring_rows`: rows of a slot's ring a window layer
+    (`ModelConfig.ring_rows` of the longest span a step writes)."""
     window, width = cfg.state_window
     conv = shortconv.alloc_state(cfg.count(CONV) + cfg.count(LINEAR),
                                  max_slots, window, width, dtype)
     rule = gated_delta.alloc_state(
         cfg.count(LINEAR), max_slots, cfg.linear_num_value_heads,
         cfg.linear_key_head_dim, cfg.linear_value_head_dim)
-    return conv if rule is None else SlotState(conv, rule)
+    if cfg.count(WINDOW) and ring_rows < cfg.sliding_window:
+        raise ValueError(
+            f"{cfg.name}: a slot's ring holds the window at the least: "
+            f"ring_rows {ring_rows}, sliding_window {cfg.sliding_window}")
+    ring = alloc_ring(cfg.count(WINDOW), max_slots, ring_rows, cfg.kv_dim,
+                      dtype)
+    return _as_given(conv, rule, ring)
 
 
-def split_state(conv_state) -> SlotState:
-    """(conv window, rule state) of a forward's `conv_state`, in any of
-    its three forms; either may be None."""
-    if isinstance(conv_state, SlotState):
+def split_state(conv_state) -> WindowState:
+    """(conv window, rule state, rings) of a forward's `conv_state`, in any
+    of its four forms; each may be None."""
+    if isinstance(conv_state, WindowState):
         return conv_state
-    return SlotState(conv_state, None)
+    if isinstance(conv_state, SlotState):
+        return WindowState(*conv_state, None)
+    return WindowState(conv_state, None, None)
 
 
 class LayerIx(NamedTuple):
     """Where a layer of the scan stands among the layers of its operator's
-    kind (an attention layer's row of the KV pool, a conv or linear layer's
-    of the per-slot state) and among those of its FFN's kind (an expert layer's block
+    kind (an attention layer's row of the KV pool, a conv, linear or window
+    layer's of the per-slot state) and among those of its FFN's kind (an expert layer's block
     of the expert stacks). int32 scalars, traced inside the scan."""
     op: jnp.ndarray
     ffn: jnp.ndarray
@@ -396,7 +429,8 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
     """
     of_kind = {name: kind for kind, names in KIND_PARAMS.items()
                for name in names}
-    seen = dict.fromkeys((ATTENTION, CONV, LINEAR, DENSE, EXPERTS), 0)
+    seen = dict.fromkeys((ATTENTION, CONV, LINEAR, WINDOW, DENSE, EXPERTS), 0)
+    windowed = cfg.count(WINDOW) > 0
     loads = []
     for first, period, repeats in cfg.layer_plan():
         per = {k: sum(k in pair for pair in period) for k in seen}
@@ -409,6 +443,13 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
                 at = {None: first + r * len(period) + j,
                       op: base[op] + r * per[op] + n[op],
                       ffn: base[ffn] + r * per[ffn] + n[ffn]}
+                # ...and among the weights' stacks: a window layer reads
+                # the attention weights, which count both attention kinds.
+                held = dict(at)
+                if windowed and op in ATTENTION_KINDS:
+                    held[ATTENTION] = r * sum(
+                        per[k] for k in ATTENTION_KINDS) + sum(
+                            base[k] + n[k] for k in ATTENTION_KINDS)
                 n[op] += 1
                 n[ffn] += 1
                 lp = {}
@@ -416,9 +457,9 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
                     kind = of_kind.get(name)
                     if name in STACKED:
                         lp[name] = stack
-                    elif kind in at:
+                    elif kind in held:
                         lp[name] = jax.tree_util.tree_map(
-                            lambda w, i=at[kind]:
+                            lambda w, i=held[kind]:
                             jax.lax.dynamic_index_in_dim(
                                 w, i, 0, keepdims=False), stack)
                 x, *state, out = body(
@@ -439,19 +480,22 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
 
 
 def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
-                  positions: jnp.ndarray, attn_fn) -> jnp.ndarray:
+                  positions: jnp.ndarray, attn_fn,
+                  rotate: bool = True) -> jnp.ndarray:
     """Attention over normed hiddens h [B, T, D]: projections, q/k norm
-    and RoPE (over a head's first `rotary_dim` lanes) here; the schedule
-    (and any write of k, v into a pool) is the caller's `attn_fn(q, k, v)
-    -> [B, T, H, hd]`. With `attn_output_gate` the attended values are
-    multiplied by sigmoid(h W_gate), a lane each, before `wo`."""
+    and RoPE (over a head's first `rotary_dim` lanes; `rotate`: the layer's
+    kind takes it, `ModelConfig.rotates`) here; the schedule (the window or
+    the whole context, and any write of k, v into a pool or a ring) is the
+    caller's `attn_fn(q, k, v) -> [B, T, H, hd]`. With `attn_output_gate`
+    the attended values are multiplied by sigmoid(h W_gate), a lane each,
+    before `wo`."""
     B, T, _ = h.shape
     gate = None
     with jax.named_scope("attn_qkv"):
         q, k, v = _qkv(cfg, lp, h)
         if cfg.attn_output_gate:
             gate = qeinsum("btd,de->bte", h, lp["wq_gate"])
-        if cfg.rope_theta is not None:
+        if rotate:
             q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
     attn = attn_fn(q, k, v)
@@ -672,8 +716,9 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
         delta = _linear_attention_op(cfg, lp, h, taps_fn, rule_fn)
     elif cfg.kv_lora_rank:
         delta = _latent_attention_op(cfg, lp, h, positions, attn_fn)
-    else:
-        delta = _attention_op(cfg, lp, h, positions, attn_fn)
+    else:  # attention over K and V: the whole context, or a window
+        delta = _attention_op(cfg, lp, h, positions, attn_fn,
+                              rotate=cfg.rotates(op))
     if cfg.sandwich_norm:
         delta = norm(delta, "post_attn_norm")
     x = x + (delta if pre else norm(delta, "attn_norm"))
@@ -696,15 +741,17 @@ def _no_state(cfg: ModelConfig, valid=None) -> dict:
             q, k, v, g, beta, valid)[0]}
 
 
-def _causal_fn(cfg: ModelConfig, seq_lens):
+def _causal_fn(cfg: ModelConfig, seq_lens, op: str = ATTENTION):
     """`attn_fn` of a forward over whole sequences from position 0: dense
-    causal attention — with latent attention over the latent rows, the
-    selection by the span's own index scores."""
+    causal attention (a window layer's, `op`: under its window) — with
+    latent attention over the latent rows, the selection by the span's own
+    index scores."""
     if cfg.kv_lora_rank:
         return lambda q_abs, row, index, expanded=None: mla.dense_attention(
             q_abs, row, *(index or (None,) * 3), seq_lens, cfg.kv_lora_rank,
             cfg.index_topk)
-    return lambda q, k, v: causal_attention(q, k, v, seq_lens)
+    window = cfg.sliding_window if op == WINDOW else 0
+    return lambda q, k, v: causal_attention(q, k, v, seq_lens, window)
 
 
 def forward_prefill(
@@ -719,7 +766,8 @@ def forward_prefill(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Whole prompts in one dense causal pass (the oracle: see the module
     docstring); returns (last_logits [B, V], k_cache', v_cache'). A conv
-    layer runs over shifted copies of its z and leaves no state: the
+    layer runs over shifted copies of its z and leaves no state (nor does
+    a window layer: its rows belong to no page): the
     oracle of a prompt's logits, not the first half of a generation.
 
     Padding positions scatter into the allocator's reserved trash page, so
@@ -733,6 +781,8 @@ def forward_prefill(
     def body(x, lp, kinds, ix, kc, vc):
         def attn_fn(q, k, v, expanded=None):
             nonlocal kc, vc
+            if kinds[0] == WINDOW:  # its rows are no pool's: no state left
+                return _causal_fn(cfg, seq_lens, WINDOW)(q, k, v)
             kc = kv_write(kc, ix.op, slots, k)  # K, or the latent row
             # V, or the index key (v: the indexer's q, k and head weights;
             # None with no indexer: no second pool)
@@ -792,7 +842,11 @@ def forward_ragged(
     model with conv layers needs `conv_state`, `slot_ids`, `is_first`); a
     linear-attention layer does the same for its convolution and continues
     each row's rule state through the row's span (ops/gated_delta.ragged;
-    `conv_state` is then a SlotState).
+    `conv_state` is then a SlotState); a window layer writes the stream's
+    K/V into the ring of each row's slot (`WindowState.ring`) and every token
+    attends over its last `sliding_window` positions there, the walk
+    starting at the ring page that holds the first of them
+    (ops/attention.py:ring_table).
     `out_idx` names the stream positions whose logits leave the forward: a
     [B] vector (each sequence's last token — the classic shape) returns
     [B, V]; a [B, O] matrix (speculative verification reads a logit at
@@ -810,16 +864,32 @@ def forward_ragged(
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
     state = split_state(conv_state)
+    if state.ring is not None:  # one table for every window layer
+        rows = state.ring.rows
+        ring_slots = ring_write_slots(
+            slot_ids[tok_seq], tok_pos, tok_pos >= 0, rows, state.ring.trash)
+        ring_pt, ring_base = ring_table(
+            slot_ids, kv_len, q_len, cfg.sliding_window, rows, page_size,
+            tokens.shape[0])
 
-    def body(x, lp, kinds, ix, kc, vc, conv, rule):
+    def body(x, lp, kinds, ix, kc, vc, conv, rule, ring):
         def attn_fn(q, k, v, expanded=None):  # [1, T, H, hd]
-            nonlocal kc, vc
+            nonlocal kc, vc, ring
             if cfg.kv_lora_rank:
                 kc, vc, out = _latent_ragged(
                     cfg, q, k, v, kc, vc, ix.op, write_slots, page_table,
                     tok_seq, tok_pos, q_start, q_len, kv_len, page_size,
                     attn_impl, interpret, expanded=expanded)
                 return out
+            if kinds[0] == WINDOW:
+                with jax.named_scope("kv_write"):
+                    ring = ring.write(ix.op, ring_slots, k[0], v[0])
+                with jax.named_scope("attention"):
+                    return ragged_attention_any(
+                        attn_impl, q[0], ring.k, ring.v, ix.op, ring_pt,
+                        tok_seq, tok_pos, kv_len, q_start, q_len, page_size,
+                        interpret=interpret, mesh=mesh,
+                        window=cfg.sliding_window, pos_base=ring_base)[None]
             with jax.named_scope("kv_write"):
                 kc = kv_write(kc, ix.op, write_slots, k[0])
                 vc = kv_write(vc, ix.op, write_slots, v[0])
@@ -851,9 +921,9 @@ def forward_ragged(
         x, load = _layer_step(cfg, lp, kinds, x, positions, attn_fn, taps_fn,
                               valid=valid, mesh=mesh, impl=attn_impl,
                               layer=ix.ffn, rule_fn=rule_fn)
-        return x, kc, vc, conv, rule, load
+        return x, kc, vc, conv, rule, ring, load
 
-    x, k_cache, v_cache, conv, rule, load = scan_layers(
+    x, k_cache, v_cache, conv, rule, ring, load = scan_layers(
         cfg, body, x, params["layers"], k_cache, v_cache, *state)
     if out_idx.ndim == 1:
         x_last = x[0][out_idx]  # [B, D]
@@ -861,8 +931,8 @@ def forward_ragged(
     else:
         x_last = x[0][out_idx]  # [B, O, D]
         logits = _logits(params, cfg, x_last)  # [B, O, V]
-    out = _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
-                   moe_load)
+    out = _results(logits, k_cache, v_cache, conv_state, conv, rule, ring,
+                   load, moe_load)
     return out + (x[0],) if hidden else out
 
 
@@ -954,13 +1024,13 @@ def forward_mtp(
     return logits, k_cache, load
 
 
-def _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
+def _results(logits, k_cache, v_cache, conv_state, conv, rule, ring, load,
              moe_load):
     """(logits, caches'[, conv_state'][, load]) of a step forward;
     conv_state' in the form `conv_state` was given in."""
     out = (logits, k_cache, v_cache)
     if conv_state is not None:
-        out += (conv if rule is None else SlotState(conv, rule),)
+        out += (_as_given(conv, rule, ring),)
     return out + (load,) if moe_load else out
 
 
@@ -978,7 +1048,7 @@ def forward_decode(
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
     moe_load: bool = False,  # also return the [Le, E] expert loads
     conv_state=None,  # [Lc, >= B, K-1, D] or a SlotState (donated; loop
-    # carry): row b is slot b's
+    # carry): row b is slot b's (of the rings too)
 ):
     """One decode step for the whole batch (row b is slot b); returns
     (logits [B,V], caches'), then conv_state' where one was given and,
@@ -998,10 +1068,28 @@ def forward_decode(
     write_slots = flat_slot_indices(page_table, pos2, page_size)[:, 0]  # [B]
     seq_lens = positions + 1
     state = split_state(conv_state)
+    if state.ring is not None:  # row b is slot b: one table, every layer
+        rows = state.ring.rows
+        every = jnp.arange(B, dtype=jnp.int32)
+        ring_slots = ring_write_slots(
+            every, positions, True if active is None else active > 0, rows,
+            state.ring.trash)
+        ring_pt, ring_base = ring_table(
+            every, seq_lens, jnp.ones_like(seq_lens), cfg.sliding_window,
+            rows, page_size, 1)
 
-    def body(x, lp, kinds, ix, kc, vc, conv, rule):
+    def body(x, lp, kinds, ix, kc, vc, conv, rule, ring):
         def attn_fn(q, k, v, expanded=None):  # [B, 1, H, hd]
-            nonlocal kc, vc
+            nonlocal kc, vc, ring
+            if kinds[0] == WINDOW:
+                with jax.named_scope("kv_write"):
+                    ring = ring.write(ix.op, ring_slots, k[:, 0], v[:, 0])
+                with jax.named_scope("attention"):
+                    return paged_decode_attention_any(
+                        attn_impl, q[:, 0], ring.k, ring.v, ix.op, ring_pt,
+                        seq_lens, page_size, mesh=mesh,
+                        window=cfg.sliding_window,
+                        pos_base=ring_base)[:, None]
             if cfg.kv_lora_rank:  # a stream of B one-token spans
                 q_idx = w_idx = None
                 with jax.named_scope("mla_cache_write"):
@@ -1045,13 +1133,13 @@ def forward_decode(
         x, load = _layer_step(cfg, lp, kinds, x, pos2, attn_fn, taps_fn,
                               valid=valid, mesh=mesh, impl=attn_impl,
                               layer=ix.ffn, rule_fn=rule_fn)
-        return x, kc, vc, conv, rule, load
+        return x, kc, vc, conv, rule, ring, load
 
-    x, k_cache, v_cache, conv, rule, load = scan_layers(
+    x, k_cache, v_cache, conv, rule, ring, load = scan_layers(
         cfg, body, x, params["layers"], k_cache, v_cache, *state)
     logits = _logits(params, cfg, x)[:, 0, :]
-    return _results(logits, k_cache, v_cache, conv_state, conv, rule, load,
-                    moe_load)
+    return _results(logits, k_cache, v_cache, conv_state, conv, rule, ring,
+                    load, moe_load)
 
 
 def forward_embed(
@@ -1074,7 +1162,7 @@ def forward_embed(
 
     def body(x, lp, kinds, ix):
         return _layer_step(
-            cfg, lp, kinds, x, positions, _causal_fn(cfg, seq_lens),
+            cfg, lp, kinds, x, positions, _causal_fn(cfg, seq_lens, kinds[0]),
             valid=valid, layer=ix.ffn, **_no_state(cfg, valid))
 
     x, _ = scan_layers(cfg, body, x, params["layers"])

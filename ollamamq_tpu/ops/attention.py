@@ -18,15 +18,27 @@ layer loop as its carry (models/llama.py:scan_layers), the jnp paths
 gather `pool[layer, slots]` and view only the gathered rows per head, and
 the Pallas kernels DMA pages out of `pool[layer]` by index. Nothing here
 slices a layer out of the pool or re-lays it out.
+
+A WINDOW layer (`sliding_attention`: a query at position p sees the positions
+p - window < j <= p) keeps its K and V in the same layout, but in a per-slot
+RING and not in the paged pool (`WindowRing`): its rows are dead `window`
+positions later. The same attentions serve it — `window` is a static
+argument, 0 for a full layer — over a page table that is arithmetic
+(`ring_table`): the ring pages of the row's slot from the page that holds the
+earliest position any of the span's queries sees, with the position that page
+stands for (`pos_base`), so a walk reads the window and not the context.
 """
 
 from __future__ import annotations
+
+import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from ollamamq_tpu.ops.quant import QuantKV, kv_gather
+from ollamamq_tpu.ops.quant import QuantKV, kv_gather, kv_write
 from ollamamq_tpu.parallel.mesh import AXIS_TENSOR
 
 NEG_INF = -1e30
@@ -44,6 +56,7 @@ def causal_attention(
     k: jnp.ndarray,  # [B, T, Hk, hd]
     v: jnp.ndarray,  # [B, T, Hk, hd]
     seq_lens: jnp.ndarray,  # [B] valid lengths (padding masked out)
+    window: int = 0,  # > 0: the last `window` positions only
 ) -> jnp.ndarray:
     """Causal self-attention over a padded prefill batch. f32 softmax."""
     B, T, H, hd = q.shape
@@ -55,6 +68,8 @@ def causal_attention(
     logits = logits * scale
     pos = jnp.arange(T)
     causal = pos[None, :] <= pos[:, None]  # [q, k]
+    if window:
+        causal = causal & (pos[None, :] > pos[:, None] - window)
     valid = pos[None, None, :] < seq_lens[:, None, None]  # [B, 1, k]
     mask = causal[None, None, :, :] & valid[:, None, :, :]
     logits = jnp.where(mask, logits, NEG_INF)
@@ -101,6 +116,8 @@ def paged_chunk_attention(
     start: jnp.ndarray,  # [B] global position of the chunk's first token
     chunk_lens: jnp.ndarray,  # [B] valid tokens in this chunk (<= C)
     page_size: int,
+    window: int = 0,  # a window layer: each query's last `window` positions,
+    pos_base=None,  # [B] and the position the table's first page stands for
 ) -> jnp.ndarray:
     """Chunked-prefill attention: the chunk's K/V are already scattered
     into the cache, so each query at global position start+i attends to
@@ -109,8 +126,9 @@ def paged_chunk_attention(
     B, C, H, hd = q.shape
     max_pages = page_table.shape[1]
     L = max_pages * page_size
-    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
-    slots = flat_slot_indices(page_table, positions, page_size)  # [B, L]
+    index = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+    slots = flat_slot_indices(page_table, index, page_size)  # [B, L]
+    positions = index if pos_base is None else index + pos_base[:, None]
     k = kv_gather(k_cache, layer, slots, hd)  # [B, L, Hk, hd] (int8 -> f32)
     v = kv_gather(v_cache, layer, slots, hd)
     n_rep = H // k.shape[2]
@@ -122,6 +140,8 @@ def paged_chunk_attention(
     ) * scale  # [B, H, C, L]
     q_pos = start[:, None] + jnp.arange(C)[None, :]  # [B, C] global positions
     causal = positions[:, None, :] <= q_pos[:, :, None]  # [B, C, L]
+    if window:
+        causal = causal & (positions[:, None, :] > q_pos[:, :, None] - window)
     in_seq = positions[:, None, :] < (start + chunk_lens)[:, None, None]
     mask = (causal & in_seq)[:, None, :, :]
     logits = jnp.where(mask, logits, NEG_INF)
@@ -138,6 +158,8 @@ def paged_decode_attention(
     page_table: jnp.ndarray,  # [B, max_pages]
     seq_lens: jnp.ndarray,  # [B] context length INCLUDING the new token
     page_size: int,
+    window: int = 0,
+    pos_base=None,
 ) -> jnp.ndarray:
     """Decode attention: each query attends to its own paged context.
 
@@ -149,7 +171,7 @@ def paged_decode_attention(
     out = paged_chunk_attention(
         q[:, None], k_cache, v_cache, layer, page_table,
         start=seq_lens - 1, chunk_lens=jnp.ones_like(seq_lens),
-        page_size=page_size,
+        page_size=page_size, window=window, pos_base=pos_base,
     )
     return out[:, 0]
 
@@ -164,6 +186,8 @@ def ragged_paged_attention(
     tok_pos: jnp.ndarray,  # [T] int32 kv position of each token (-1 = pad)
     kv_lens: jnp.ndarray,  # [B] context length incl. each seq's new tokens
     page_size: int,
+    window: int = 0,
+    pos_base=None,
 ) -> jnp.ndarray:
     """Ragged mixed-batch attention, materializing reference.
 
@@ -178,9 +202,11 @@ def ragged_paged_attention(
     T, H, hd = q.shape
     B, max_pages = page_table.shape
     L = max_pages * page_size
-    rows = page_table[jnp.clip(tok_seq, 0, B - 1)]  # [T, max_pages]
-    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (T, L))
-    slots = flat_slot_indices(rows, positions, page_size)  # [T, L]
+    seq = jnp.clip(tok_seq, 0, B - 1)
+    rows = page_table[seq]  # [T, max_pages]
+    index = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (T, L))
+    slots = flat_slot_indices(rows, index, page_size)  # [T, L]
+    positions = index if pos_base is None else index + pos_base[seq][:, None]
     k = kv_gather(k_cache, layer, slots, hd)  # [T, L, Hk, hd] (int8 -> f32)
     v = kv_gather(v_cache, layer, slots, hd)
     n_rep = H // k.shape[2]
@@ -191,7 +217,9 @@ def ragged_paged_attention(
         "thd,tlhd->thl", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale  # [T, H, L]
     causal = positions <= tok_pos[:, None]  # [T, L]
-    in_seq = positions < kv_lens[jnp.clip(tok_seq, 0, B - 1)][:, None]
+    if window:
+        causal = causal & (positions > tok_pos[:, None] - window)
+    in_seq = positions < kv_lens[seq][:, None]
     mask = (causal & in_seq)[:, None, :]
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -210,6 +238,8 @@ def ragged_paged_attention_blockwise(
     kv_lens: jnp.ndarray,  # [B]
     page_size: int,
     block_pages: int = 8,
+    window: int = 0,
+    pos_base=None,
 ) -> jnp.ndarray:
     """Non-materializing ragged attention: the jnp serving path.
 
@@ -225,9 +255,13 @@ def ragged_paged_attention_blockwise(
     n_rep = H // Hk
     BLK = block_pages * page_size
     n_blocks = -(-max_pages // block_pages)  # static ceiling
-    rows = page_table[jnp.clip(tok_seq, 0, B - 1)]  # [T, max_pages]
+    seq = jnp.clip(tok_seq, 0, B - 1)
+    rows = page_table[seq]  # [T, max_pages]
     end = tok_pos + 1  # per-token causal frontier (0 for padding)
-    needed = jnp.max(-(-jnp.maximum(end, 0) // BLK))
+    # Where a token's walk starts: position 0 — or, over a window layer's
+    # table, the position its row's first listed page stands for.
+    first = 0 if pos_base is None else pos_base[seq]
+    needed = jnp.max(-(-jnp.maximum(end - first, 0) // BLK))
 
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     qf = q.astype(jnp.float32) * scale  # [T, H, hd]
@@ -238,7 +272,9 @@ def ragged_paged_attention_blockwise(
             i * block_pages + jnp.arange(block_pages), 0, max_pages - 1
         )
         pages = rows[:, pidx]  # [T, block_pages]
-        pos = i * BLK + jnp.arange(BLK, dtype=jnp.int32)
+        pos = i * BLK + jnp.arange(BLK, dtype=jnp.int32)[None, :]
+        if pos_base is not None:
+            pos = pos + first[:, None]
         slots = (pages[:, :, None] * page_size
                  + jnp.arange(page_size)[None, None, :]).reshape(T, BLK)
         k = repeat_kv(kv_gather(k_cache, layer, slots, hd).astype(
@@ -246,8 +282,9 @@ def ragged_paged_attention_blockwise(
         v = repeat_kv(kv_gather(v_cache, layer, slots, hd).astype(
             jnp.float32), n_rep)
         logits = jnp.einsum("thd,tlhd->thl", qf, k)  # [T, H, BLK]
-        keep = (pos[None, :] <= tok_pos[:, None]) \
-            & (pos[None, :] < end[:, None])  # [T, BLK]
+        keep = (pos <= tok_pos[:, None]) & (pos < end[:, None])  # [T, BLK]
+        if window:
+            keep = keep & (pos > tok_pos[:, None] - window)
         logits = jnp.where(keep[:, None, :], logits, NEG_INF)
         blk_m = jnp.max(logits, axis=-1)  # [T, H]
         new_m = jnp.maximum(m, blk_m)
@@ -266,6 +303,88 @@ def ragged_paged_attention_blockwise(
     )
     out = acc / jnp.maximum(l, 1e-30)[..., None]  # [T, H, hd]
     return out.astype(q.dtype)
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("k", "v"), meta_fields=("rows",))
+@dataclasses.dataclass(frozen=True)
+class WindowRing:
+    """The window layers' K and V: `[window layers, (slots + 1) * rows,
+    Hk*hd]` each, the pool's row layout (the kernels DMA pages out of it as
+    out of the pool). Slot s owns rows [s * rows, (s + 1) * rows) of every
+    layer for life — no allocator, no table on the host — and position p of
+    its sequence lives at row s * rows + p % rows: a ring that keeps the
+    last `rows` positions, of which a query reads its window. `rows`
+    (static: `ModelConfig.ring_rows`) is whole pages. Slot `slots` is the
+    trash slot padding tokens and idle rows write (as the conv state's and
+    `recent`'s). Never reset: a row that a request has not written yet lies
+    past its causal frontier and is masked."""
+    k: jnp.ndarray
+    v: jnp.ndarray
+    rows: int
+
+    @property
+    def nbytes(self) -> int:
+        return self.k.nbytes + self.v.nbytes
+
+    @property
+    def trash(self) -> int:
+        """The trash slot: the slots' count."""
+        return self.k.shape[1] // self.rows - 1
+
+    def write(self, layer, slots, k, v) -> "WindowRing":
+        """The rings with rows `slots` (`ring_write_slots`) of window layer
+        `layer` set to a stream's k and v."""
+        return WindowRing(kv_write(self.k, layer, slots, k),
+                          kv_write(self.v, layer, slots, v), self.rows)
+
+
+def alloc_ring(layers: int, max_slots: int, rows: int, lanes: int,
+               dtype=jnp.bfloat16):
+    """The rings of a model with `layers` window layers (zeros), or None
+    for a model that has none — a pytree without leaves."""
+    if not layers:
+        return None
+    shape = (layers, (max_slots + 1) * rows, lanes)
+    return WindowRing(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), rows)
+
+
+def ring_write_slots(slots, positions, valid, rows: int, trash: int):
+    """The ring row each token writes: `slots` its sequence's slot,
+    `positions` its position, `valid` false for padding tokens and idle
+    rows — they write the trash slot (`trash`: the slots' count)."""
+    return jnp.where(valid, slots, trash) * rows + jnp.maximum(
+        positions, 0) % rows
+
+
+def ring_first_page(kv_lens, q_lens, window: int, page_size: int):
+    """The page of a row's sequence that a window launch's walk starts at:
+    the one that holds the earliest position the span's first query sees,
+    kv - q - (window - 1). numpy or jax arrays alike: `ring_table` builds the
+    launch's table from it, and the engine counts from it what the launch
+    walks (`swa_walk_rows`)."""
+    return (kv_lens - q_lens - (window - 1)).clip(0) // page_size
+
+
+def ring_table(slots, kv_lens, q_lens, window: int, rows: int,
+               page_size: int, max_span: int):
+    """(page table [B, cols], pos_base [B]) of a window layer's launch: row
+    b's span of `q_lens[b]` tokens ends at context `kv_lens[b]`, its first
+    query sees positions from kv - q - (window - 1) on, and the table lists
+    the ring pages of slot `slots[b]` from the page that holds that position
+    (`pos_base`: the position the page starts at). `cols` (static) holds
+    the window before a span of `max_span` tokens, in whole blocks of eight
+    pages (both kernels' and the jnp twin's block divide it); a column past
+    the span's last page names a ring page whose positions lie past the
+    causal frontier, so what it holds is masked."""
+    ring_pages = rows // page_size
+    cols = -(-(window + max_span + page_size - 2) // page_size)
+    cols = -(-cols // 8) * 8
+    first = ring_first_page(kv_lens, q_lens, window, page_size)
+    page = (first[:, None] + jnp.arange(cols, dtype=jnp.int32)[None, :]
+            ) % ring_pages
+    return (slots[:, None] * ring_pages + page).astype(jnp.int32), \
+        (first * page_size).astype(jnp.int32)
 
 
 def _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, *meta):
@@ -318,6 +437,8 @@ def ragged_attention_any(
     interpret: bool = False,
     mesh=None,  # the GSPMD mesh the caller's jit runs over (None inside
     #             a shard_map or on one device)
+    window: int = 0,  # a window layer: the caches are its rings,
+    pos_base=None,  # `page_table` / `pos_base` its `ring_table`
 ) -> jnp.ndarray:
     """The ONE pallas-vs-jnp ragged-attention dispatch (mirror of
     paged_decode_attention_any), shared by models/llama.forward_ragged so
@@ -329,17 +450,21 @@ def ragged_attention_any(
             ragged_paged_attention_pallas,
         )
 
-        def kernel(q, kc, vc, layer, page_table, q_start, q_lens, kv_lens):
+        def kernel(q, kc, vc, layer, page_table, q_start, q_lens, kv_lens,
+                   *base):
             kq, vq, scales = _split_quant(kc, vc)
+            if window:  # (no mesh: config.validate_slot_state)
+                scales = dict(scales, window=window, pos_base=base[0])
             return ragged_paged_attention_pallas(
                 q, kq, vq, layer, page_table, q_start, q_lens, kv_lens,
                 page_size, interpret=interpret, **scales)
 
         return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, layer,
-                                 page_table, q_start, q_lens, kv_lens)
+                                 page_table, q_start, q_lens, kv_lens,
+                                 *([pos_base] if window else []))
     return ragged_paged_attention_blockwise(
         q, k_cache, v_cache, layer, page_table, tok_seq, tok_pos, kv_lens,
-        page_size
+        page_size, window=window, pos_base=pos_base if window else None
     )
 
 
@@ -354,6 +479,8 @@ def paged_decode_attention_any(
     page_size: int,
     interpret: bool = False,
     mesh=None,  # see ragged_attention_any
+    window: int = 0,  # as ragged_attention_any's
+    pos_base=None,
 ) -> jnp.ndarray:
     """The ONE pallas-vs-jnp decode-attention dispatch
     (models/llama.forward_decode). The pallas import stays deferred: the
@@ -363,14 +490,18 @@ def paged_decode_attention_any(
             paged_decode_attention_pallas,
         )
 
-        def kernel(q, kc, vc, layer, page_table, seq_lens):
+        def kernel(q, kc, vc, layer, page_table, seq_lens, *base):
             kq, vq, scales = _split_quant(kc, vc)
+            if window:
+                scales = dict(scales, window=window, pos_base=base[0])
             return paged_decode_attention_pallas(
                 q, kq, vq, layer, page_table, seq_lens, page_size,
                 interpret=interpret, **scales)
 
         return _per_tensor_shard(mesh, kernel, q, k_cache, v_cache, layer,
-                                 page_table, seq_lens)
+                                 page_table, seq_lens,
+                                 *([pos_base] if window else []))
     return paged_decode_attention(
-        q, k_cache, v_cache, layer, page_table, seq_lens, page_size
+        q, k_cache, v_cache, layer, page_table, seq_lens, page_size,
+        window=window, pos_base=pos_base if window else None
     )

@@ -359,9 +359,9 @@ def inner_report(group: int) -> dict:
 
 
 def make_inner(name, *, rows, group, num_kv_heads, head_dim, page_size,
-               subs=1):
+               subs=1, window=0):
     cls = {"mxu": Mxu, "vpu": Vpu}[name or choose_inner(rows, group)]
-    return cls(rows, group, num_kv_heads, head_dim, page_size, subs)
+    return cls(rows, group, num_kv_heads, head_dim, page_size, subs, window)
 
 
 def programs_height(stream_len: int) -> int:
@@ -422,6 +422,18 @@ def whole_blocks(page_table, inner):
     short = -page_table.shape[1] % inner.block_pages
     page_table = page_table.astype(jnp.int32)
     return jnp.pad(page_table, ((0, 0), (0, short))) if short else page_table
+
+
+def split_window(refs, window: int):
+    """(base_ref, the other refs) of a kernel's refs after ITS OWN scalar
+    prefetch: a window layer's launch carries one more, `[B]` int32 in SMEM —
+    the position the first listed page of each row's table stands for (a
+    multiple of the page size). Its table lists the pages from that position
+    on, so a walk streams only blocks that can hold keys inside the window
+    (block b of row r starts at position base[r] + b * block), not the
+    context before them; None without a window, and then nothing of this is
+    traced."""
+    return (refs[0], refs[1:]) if window else (None, refs)
 
 
 def split_refs(refs):
@@ -515,6 +527,10 @@ class _Inner:
     head_dim: int
     page_size: int
     subs: int = 1  # tiles a program holds (ragged kernel: TALL // G_TILE)
+    # A WINDOW layer's launch (0: none): a query at position p sees the
+    # positions p - window < j <= p only. The walk then starts at the page
+    # that holds the earliest of them (`split_window`); the mask here.
+    window: int = 0
 
     @property
     def lanes(self):
@@ -569,8 +585,11 @@ class Vpu(_Inner):
         done_reading()  # values are loaded: the slot may refill now
         scale = 1.0 / (self.head_dim ** 0.5)
         # Valid-position mask for this page (the last may be partial).
-        valid = pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, (self.page_size, self.num_kv_heads), 0) < kv
+        pos = pos0 + jax.lax.broadcasted_iota(
+            jnp.int32, (self.page_size, self.num_kv_heads), 0)
+        valid = pos < kv
+        if self.window:
+            valid = valid & (pos >= kv - self.window)
         for g in range(self.group):  # static unroll; group is small (1-8)
             qg = q_ref[0, g:g + 1, :].astype(jnp.float32)  # [1, lanes]
             # scores[t, h] = sum_d q[h-seg d] * k[t, d]: masked-lane
@@ -725,6 +744,8 @@ class Mxu(_Inner):
         pos = pos0 + iota(len(held))
         if self.rows == 1:
             valid = pos < kv
+            if self.window:
+                valid = valid & (pos >= kv - self.window)
         else:
             # Row-head i*M + g*rows + r is token tile_start + r: inside
             # this sequence's span it sees positions up to its own (which
@@ -736,6 +757,8 @@ class Mxu(_Inner):
             else:
                 valid = ((tok >= qs) & (tok < qs + ql)
                          & (pos <= kv - ql + (tok - qs)))
+            if self.window:
+                valid = valid & (pos > kv - ql + (tok - qs) - self.window)
         if len(bufs) == 4:  # int8: dequantise the block, then the same
             _, seg_t = _segments(self.num_kv_heads, self.head_dim)
             k_all, v_all = _load_block(bufs, slot, seg_t)

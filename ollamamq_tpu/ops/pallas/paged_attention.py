@@ -58,7 +58,11 @@ from jax.experimental import pallas as pl
 from ollamamq_tpu.ops.pallas.kv_contract import (PageStream, cdiv,
                                                  make_inner, mod,
                                                  ring_grid_spec, split_refs,
-                                                 whole_blocks)
+                                                 split_window, whole_blocks)
+
+# A window layer's launch on the device trace: a name of its own, so that
+# what reads the full layers' launches by name does not count these.
+WINDOW_NAME = "swa_decode_attention_pallas"
 
 # Pages in flight: two blocks under the Mxu body, eight pages under the
 # Vpu body. Re-measured with the ring running over the launch's rows (my
@@ -81,6 +85,7 @@ def _decode_kernel(
     nbuf: int,
     max_pages: int,
 ):
+    base_ref, refs = split_window(refs, inner.window)
     q_ref, hbm, o_ref, bufs, state, sems, at_ref = split_refs(refs)
     b = pl.program_id(0)
     nb = pl.num_programs(0)
@@ -93,7 +98,10 @@ def _decode_kernel(
     # page_table out of bounds (the jnp reference implicitly truncates the
     # context the same way).
     def pages_of(row):
-        return lax.min(cdiv(seq_lens_ref[row], page_size), max_pages)
+        rows = seq_lens_ref[row]
+        if base_ref is not None:  # a window layer: from its first page on
+            rows = lax.sub(rows, base_ref[row])
+        return lax.min(cdiv(rows, page_size), max_pages)
 
     # The ring runs over the launch's rows as ONE stream of blocks: row
     # b's block p sits in slot (at + p) % nbuf, `at` the blocks of the
@@ -150,8 +158,11 @@ def _decode_kernel(
                          lax.select(own, num_pages, succ_pages),
                          cond=lax.bitwise_or(own, has_succ))
 
-        inner.update(q_ref, bufs, slot, (0, 0, 1, seq_len),
-                     lax.mul(p, bp * page_size), state, refill)
+        pos0 = lax.mul(p, bp * page_size)
+        if base_ref is not None:
+            pos0 = lax.add(base_ref[b], pos0)
+        inner.update(q_ref, bufs, slot, (0, 0, 1, seq_len), pos0, state,
+                     refill)
         return ()
 
     jax.lax.fori_loop(0, n, body, ())
@@ -161,7 +172,8 @@ def _decode_kernel(
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("page_size", "interpret", "inner"))
+                   static_argnames=("page_size", "interpret", "inner",
+                                    "window"))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
     k_cache: jnp.ndarray,  # [L, S, Hk*hd] (int8 when k_scale is passed)
@@ -175,18 +187,22 @@ def paged_decode_attention_pallas(
     v_scale=None,
     inner: str | None = None,  # tests and microbenchmarks only: the
     #   serving path leaves the inner product to kv_contract.choose_inner
+    window: int = 0,  # a window layer's launch: a row sees its last
+    #   `window` positions, and `page_table` lists its pages from position
+    pos_base=None,  # [B] on (ops/attention.py:ring_table; WINDOW_NAME)
 ) -> jnp.ndarray:
     B, H, hd = q.shape
     max_pages = page_table.shape[1]
     lanes = k_cache.shape[-1]
     Hk = lanes // hd
     inner = make_inner(inner, rows=1, group=H // Hk, num_kv_heads=Hk,
-                       head_dim=hd, page_size=page_size)
+                       head_dim=hd, page_size=page_size, window=window)
 
     pools = [k_cache, v_cache]
     if k_scale is not None:  # an int8 pool's scale planes
         pools += [k_scale, v_scale]
-    nbuf, grid_spec = ring_grid_spec(inner, RING, (B,), 3, pools)
+    base = [pos_base.astype(jnp.int32)] if window else []
+    nbuf, grid_spec = ring_grid_spec(inner, RING, (B,), 3 + len(base), pools)
     kernel = functools.partial(
         _decode_kernel,
         inner=inner,
@@ -199,8 +215,8 @@ def paged_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_packed.shape, q.dtype),
-        interpret=interpret,
+        interpret=interpret, **({"name": WINDOW_NAME} if window else {}),
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      whole_blocks(page_table, inner), seq_lens.astype(jnp.int32),
+      whole_blocks(page_table, inner), seq_lens.astype(jnp.int32), *base,
       q_packed, *pools)
     return inner.unpack_o(out)

@@ -99,13 +99,16 @@ from ollamamq_tpu.ops.pallas.kv_contract import (G_TILE, PageStream, cdiv,
                                                  make_inner, mod,
                                                  programs_height,
                                                  ring_grid_spec, split_refs,
-                                                 whole_blocks)
+                                                 split_window, whole_blocks)
 
 # Pages in flight: one block is the least a ring holds, and it holds two.
 # 12 or 16 pages (3 or 4 blocks) read 3-15 % slower on 64 rows over
 # 200-380 tokens at every shape and 12 % faster at 2048 tokens (my chip
 # run, PR 38; `paged_attention.RING` has the reason).
 RING = 4
+# A window layer's launch on the device trace: a name of its own, so that
+# what reads the full layers' launches by name does not count these.
+WINDOW_NAME = "swa_ragged_attention_pallas"
 
 
 def _ragged_kernel(
@@ -122,6 +125,7 @@ def _ragged_kernel(
     max_pages: int,
     num_seqs: int,
 ):
+    base_ref, refs = split_window(refs, inner.window)
     q_ref, hbm, o_ref, bufs, state, sems, at_ref = split_refs(refs)
     subs = inner.subs  # tiles a program holds; 1: no tall body is traced
     height = subs * G_TILE
@@ -148,6 +152,8 @@ def _ragged_kernel(
             lax.lt(qs, hi), lax.gt(end, lo)))
         # kv - ql + (last_tok - qs) + 1, last_tok = min(hi, end) - 1
         frontier = lax.sub(lax.add(lax.sub(kv, ql), lax.min(hi, end)), qs)
+        if base_ref is not None:  # a window layer: from its first page on
+            frontier = lax.sub(frontier, base_ref[row])
         return lax.select(
             overlaps, lax.min(cdiv(frontier, page_size), max_pages), 0)
 
@@ -200,8 +206,10 @@ def _ragged_kernel(
                              lax.select(own, ahead, lax.sub(ahead, n)),
                              lax.select(own, pages, succ_pages))
 
-            inner.update(q_ref, bufs, slot, span,
-                         lax.mul(b, bp * page_size), state, refill, sub)
+            pos0 = lax.mul(b, bp * page_size)
+            if base_ref is not None:
+                pos0 = lax.add(base_ref[row], pos0)
+            inner.update(q_ref, bufs, slot, span, pos0, state, refill, sub)
             return ()
 
         jax.lax.fori_loop(0, n, body, ())
@@ -285,7 +293,8 @@ def _ragged_kernel(
     inner.finish(o_ref, state)
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("page_size", "interpret", "window"))
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,  # [T, H, hd] flattened mixed-batch queries
     k_cache: jnp.ndarray,  # [L, S, Hk*hd] (int8 when k_scale is passed)
@@ -299,6 +308,10 @@ def ragged_paged_attention_pallas(
     interpret: bool = False,
     k_scale=None,  # [L, S, Hk] f32 per-slot per-head scales (int8 pools)
     v_scale=None,
+    window: int = 0,  # a window layer's launch: a token sees its last
+    #   `window` positions, and `page_table` lists a row's pages from
+    pos_base=None,  # [B] position on (ops/attention.py:ring_table;
+    #   WINDOW_NAME on the trace)
 ) -> jnp.ndarray:
     T, H, hd = q.shape
     B, max_pages = page_table.shape
@@ -309,7 +322,7 @@ def ragged_paged_attention_pallas(
     height = programs_height(T)
     inner = make_inner(None, rows=G_TILE, group=H // Hk, num_kv_heads=Hk,
                        head_dim=hd, page_size=page_size,
-                       subs=height // G_TILE)
+                       subs=height // G_TILE, window=window)
 
     Tp = -(-T // height) * height
     n_tiles = Tp // G_TILE
@@ -323,7 +336,9 @@ def ragged_paged_attention_pallas(
     pools = [k_cache, v_cache]
     if k_scale is not None:  # an int8 pool's scale planes
         pools += [k_scale, v_scale]
-    nbuf, grid_spec = ring_grid_spec(inner, RING, (Tp // height,), 6, pools)
+    base = [pos_base.astype(jnp.int32)] if window else []
+    nbuf, grid_spec = ring_grid_spec(inner, RING, (Tp // height,),
+                                     6 + len(base), pools)
     kernel = functools.partial(
         _ragged_kernel,
         inner=inner,
@@ -337,9 +352,9 @@ def ragged_paged_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_packed.shape, q.dtype),
-        interpret=interpret,
+        interpret=interpret, **({"name": WINDOW_NAME} if window else {}),
     )(jnp.asarray(layer, jnp.int32).reshape(1), tile_first,
       q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
-      kv_lens.astype(jnp.int32), whole_blocks(page_table, inner),
+      kv_lens.astype(jnp.int32), whole_blocks(page_table, inner), *base,
       q_packed, *pools)
     return inner.unpack_o(out)[:T]

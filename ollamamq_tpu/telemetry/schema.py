@@ -368,6 +368,35 @@ ATTN_TALL_TOKENS_TOTAL = REGISTRY.counter(
     "kernel served a whole stretch at a time (kv_contract.TALL consecutive "
     "tokens inside one span share each K/V block's trip); 0 without the "
     "kernel", labels=("model",))
+HBM_SWA_RING_BYTES = REGISTRY.gauge(
+    "ollamamq_hbm_swa_ring_bytes",
+    "Bytes the window (sliding_attention) layers' per-slot K/V rings occupy "
+    "per model runtime (window layers x (slots + 1) x ring rows x K and V "
+    "rows; fixed, whatever the context lengths — ollamamq_hbm_kv_bytes is "
+    "then the FULL layers' pool alone; 0 for a model without such layers)",
+    labels=("model",))
+SWA_PAIRS_TOTAL = REGISTRY.counter(
+    "ollamamq_swa_pairs_total",
+    "In-window (query token, cached position) pairs of launched steps' "
+    "window attention, a window layer: a token at position p attends "
+    "min(p + 1, sliding_window)", labels=("model",))
+SWA_CTX_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_swa_ctx_rows_total",
+    "Cached K/V rows those steps' window launches have to read at the "
+    "least, a window layer: a span of n tokens ending at context kv reads "
+    "min(kv, n + sliding_window - 1) (a fused scan's pass: each active "
+    "slot's min(kv, sliding_window))", labels=("model",))
+SWA_WALK_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_swa_walk_rows_total",
+    "Rows those launches' walks cover, a window layer: from the ring page "
+    "each row's table starts at (the page that holds the first position "
+    "the span's first query sees) to the span's end", labels=("model",))
+SWA_FULL_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_swa_full_rows_total",
+    "Rows a walk of the same contexts from position 0 would have covered "
+    "(what a window served as a mask costs): ollamamq_swa_walk_rows_total "
+    "over this is the share of the context the window launches walk",
+    labels=("model",))
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

@@ -38,7 +38,11 @@ launch after its short trips at the head row's price, a trip. `--set
 kv_contract.TALL=32` sweeps the stretch, `--set
 ragged_attention.G_TILE=64,kv_contract.G_TILE=64` makes EVERY tile tall
 (what decode rows would pay), `--set kv_contract.TALL=8` is the kernel of
-one tile a program at every rung.
+one tile a program at every rung. Since PR 50 a third row a context,
+`raggedlong_window`: the same step as a WINDOW layer launches it (a window
+of 128 positions over per-slot rings; `rows_walked` of the context's rows) —
+with `--shapes 6`, (64, 8, 128), the two launches of
+`k-exaone-236b-a23b-ep8-d5.longctx`: the full layer's and a window layer's.
 
 `--traffic latent` times the latent-attention kernel instead
 (`ops/pallas/mla_attention.py:mla_sparse_paged_attention_pallas`) on the
@@ -87,7 +91,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ollamamq_tpu.ops import mla
 from ollamamq_tpu.ops.attention import (paged_decode_attention_any,
-                                        ragged_attention_any)
+                                        ragged_attention_any, ring_table)
 from ollamamq_tpu.ops.pallas import (kv_contract, mla_attention,
                                      paged_attention, ragged_attention)
 
@@ -95,7 +99,7 @@ MODULES = {m.__name__.rsplit(".", 1)[1]: m
            for m in (kv_contract, mla_attention, paged_attention,
                      ragged_attention)}
 SHAPES = ((28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
-          (30, 30, 128), (16, 2, 256))
+          (30, 30, 128), (16, 2, 256), (64, 8, 128))
 B, MP, PS, NP, LAYER = 64, 256, 32, 1024, 1
 # The jnp reference gathers a table's whole width, [B, width*PS, lanes]:
 # it is fed the columns a context here can reach and no more.
@@ -119,6 +123,11 @@ LAT_CHECKED = (0, 1, 2, 3, 4, 5, 12, 13, 15, 16, 23, 24, 255, 256, 511)
 # span's first tokens, both sides of tile and stretch edges, the last token.
 LONG_ROWS, LONG_SPAN = LAT_ROWS, LAT_SPAN
 LONG_CONTEXTS = (4096, 8192, 16384)
+# ...and, a third row a context (`raggedlong_window`), the same step as a
+# WINDOW layer launches it (K-EXAONE's, `--shapes 6`: PR 50): a window of 128
+# positions over per-slot rings of 672 rows, the walk from the ring page that
+# holds each span's first visible position (ops/attention.py:ring_table).
+WINDOW, RING_ROWS = 128, 672
 LONG_CHECKED = (0, 1, 2, 3, 4, 5, 6, 7, 8, 15, 16, 58, 59, 63, 64, 65, 71, 72,
                 127, 128, 255, 256, 300, 447, 448, 504, 511)
 
@@ -625,7 +634,48 @@ def main() -> int:
                         # compiler refuses is a row, not the end of the run
                         row["error"] = str(e)[:300]
                     print(json.dumps(row), flush=True)
+            if traffic == "raggedlong":
+                print(json.dumps(windowed(
+                    rng, (H, Hk, hd), q, checked, tok_seq, tok_pos, kv_len,
+                    q_start, q_len, T, context)), flush=True)
     return 0
+
+
+def windowed(rng, shape, q, checked, tok_seq, tok_pos, kv_len, q_start, q_len,
+             T, context) -> dict:
+    """The `raggedlong` step as a window layer's launch: the row of the
+    kernel over rings, against its jnp twin over the same table."""
+    H, Hk, hd = shape
+    rows = q_len.shape[0]
+    rk, rv = (jnp.asarray(rng.standard_normal(
+        (2, (rows + 1) * RING_ROWS, Hk * hd)), jnp.bfloat16)
+        for _ in range(2))
+    pt, base = ring_table(jnp.arange(rows, dtype=jnp.int32), kv_len, q_len,
+                          WINDOW, RING_ROWS, PS, T)
+    ref = ragged_attention_any(
+        "jnp", q[checked], rk, rv, LAYER, pt, tok_seq[checked],
+        tok_pos[checked], kv_len, q_start, q_len, PS, window=WINDOW,
+        pos_base=base)
+
+    def fn(q, kc, vc, pt, qs, ql, kl, base):
+        return ragged_attention.ragged_paged_attention_pallas(
+            q, kc, vc, LAYER, pt, qs, ql, kl, PS, window=WINDOW,
+            pos_base=base)
+
+    operands = (rk, rv, pt, q_start, q_len, kv_len, base)
+    row = {"shape": [H, Hk, hd], "traffic": "raggedlong_window", "tokens": T,
+           "context": context, "window": WINDOW}
+    try:
+        out = np.asarray(fn(q, *operands)[checked], np.float32)
+        row.update({
+            "ms_a_launch": round(timed(fn, q, *operands) * 1e3, 4),
+            "rows_walked": int((kv_len - base).sum()),
+            "max_abs_diff_vs_jnp": float(np.abs(
+                out - np.asarray(ref, np.float32)).max()),
+            "finite": bool(np.isfinite(out).all())})
+    except Exception as e:  # noqa: BLE001 — a row, not the end of the run
+        row["error"] = str(e)[:300]
+    return row
 
 
 if __name__ == "__main__":

@@ -198,7 +198,8 @@ def step_programs(mc, flags, topo, tokens: int,
           pool_sharding) for lanes in mc.kv_row_dims)
     state = jax.tree_util.tree_map(
         lambda a: s(a.shape, a.dtype),
-        jax.eval_shape(lambda: llama.alloc_slot_state(mc, S)))
+        jax.eval_shape(lambda: llama.alloc_slot_state(
+            mc, S, ring_rows=mc.ring_rows(eng.ragged_budget(flags), ps))))
     carried = (*pools, s((S + 1, rt.ecfg.repeat_last_n)), s((S,)), state)
     every = (True, True, True)  # penalties, masks, sampling: the superset
     # The jit itself, not the first-call wrapper that times its compile.

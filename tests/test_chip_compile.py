@@ -372,9 +372,11 @@ def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
     recent, last_ids = s((S + 1, W)), s((S,))
     # The per-slot state: None (no leaf) for a model without such layers,
     # the conv window's array, or a SlotState with the rule's state too.
+    # ...or a WindowState with the window layers' K/V rings.
     conv = jax.tree_util.tree_map(
         lambda a: s(a.shape, a.dtype),
-        jax.eval_shape(lambda: llama.alloc_slot_state(cfg, S)))
+        jax.eval_shape(lambda: llama.alloc_slot_state(
+            cfg, S, ring_rows=cfg.ring_rows(T, PS))))
     drafts = ()
     if which == "mq_spec_step":  # the ragged step of a --spec runtime whose
         rt.mtp = True  # proposer is the model's prediction module
