@@ -816,6 +816,19 @@ class ModelRuntime:
             self.draft_ids, self.len_ids = (
                 jnp.zeros((engine_cfg.max_slots + 1,), jnp.int32)
                 for _ in range(2))
+        # A program's results are committed to a device once any of its
+        # arguments is, and a re-laid stack is (`device_put` to a Format
+        # commits; a tree born in its formats is committed whole): a carry
+        # that started uncommitted would come back committed — another jit
+        # key — and the first program launched would compile twice. So on
+        # one device the carried state starts where the weights are held.
+        held = {d for x in jax.tree_util.tree_leaves(params)
+                if x.committed for d in x.devices()}
+        if mesh is None and len(held) == 1:
+            (self.kc, self.vc, self.recent, self.last_ids, self.slot_state,
+             self.draft_ids, self.len_ids) = jax.device_put(
+                (self.kc, self.vc, self.recent, self.last_ids,
+                 self.slot_state, self.draft_ids, self.len_ids), held.pop())
         self._draft_ok = np.zeros((engine_cfg.max_slots,), bool)
         self.spec_proposed = 0
         self.spec_accepted = 0

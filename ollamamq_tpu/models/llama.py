@@ -281,6 +281,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     return params
 
 
+def splits_heads_at_once(cfg: ModelConfig) -> bool:
+    """Does `_qkv` reshape the projections' results to heads straight away —
+    no q/k norm, or a per-head one — and not first norm the flat `[B, T, e]`
+    vector (OLMoE's and Olmo-Hybrid's full-width norm)? What `_qkv` branches
+    on, and what `weight_formats` holds `wq` / `wk` by: the chip's compiler
+    reads a projection that is split into heads at once with the contracted
+    dimension minor, one that is normed flat first row-major."""
+    return cfg.qk_norm_kind != "full"
+
+
 def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     """Project hidden -> q,k,v with head reshape. h: [B, T, D]."""
     B, T, _ = h.shape
@@ -291,7 +301,7 @@ def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
-    if cfg.qk_norm_kind == "full":
+    if not splits_heads_at_once(cfg):
         # Over all heads' lanes at once, BEFORE the split into heads (under
         # tp the lanes are sharded: GSPMD reduces the mean across shards).
         q = _norm(cfg, q, lp["q_norm"])
@@ -582,14 +592,27 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
         return qeinsum("bte,ed->btd", o.reshape(B, T, H * dv), lp["wo"])
 
 
+# Which stacks live on ONE device layer-major with the CONTRACTED dimension
+# minor — the order the chip's compiler reads them in — and not in the default
+# row-major order, from which every step program re-laid them a pass.
+#
 # The stacks `_latent_attention_op` contracts a head at a time ("btr,re->bte"
 # reshaped to heads, "bthn,chn->bthc", "bthc,chv->bthv": `h` a batch
-# dimension, the rank r / c contracted). The chip's compiler reads such an
-# operand head-major with the contracted rank MINOR; held in the default
-# row-major order, every step program re-laid the whole stack a pass (654 MB
-# at the published widths, 2.0 ms of a 15 ms pass: PERF.md section 6, PR 45).
-# So those stacks live on the device layer-major, rank minor.
+# dimension, the rank r / c contracted): 654 MB a pass at the published
+# widths, 2.0 ms of a 15 ms pass (PERF.md section 6, PR 45).
 CONTRACTED_MINOR = {"mla_wuq": (0, 2, 1), "mla_wukv": (0, 2, 1)}
+# `_qkv`'s q and k projections, WHERE their results are split into heads at
+# once (`splits_heads_at_once`): the compiler computes q heads-major with a
+# layer of `wq` as the `[e, d]` operand, and held row-major K-EXAONE's ragged
+# step re-laid each layer's 100 MB of it after slicing it out (0.88 ms of a
+# 17.1 ms step) and its decode scan the 503 MB stack (PERF.md section 6,
+# PR 51). Not where the flat result is normed first: OLMoE's and Olmo-Hybrid's
+# compiler reads `wq` ROW-major, and held rank-minor their decode scans re-lay
+# it. It is the norm, not the head counts: OLMoE's file with `qk_norm` "head"
+# re-lays `wq` and `wk` from row-major, with 4 K/V heads and its own
+# full-width norm nothing (AOT, ISSUE 51). `wv`, `wo`, `wq_gate` and the MLP
+# stacks are read as they lie.
+SPLIT_TO_HEADS_MINOR = {"wq": (0, 2, 1), "wk": (0, 2, 1)}
 
 
 def weight_formats(cfg: ModelConfig, params: dict) -> dict:
@@ -603,10 +626,11 @@ def weight_formats(cfg: ModelConfig, params: dict) -> dict:
     from jax.experimental.layout import Format, Layout
     from jax.sharding import SingleDeviceSharding
 
+    named = dict(CONTRACTED_MINOR)
+    if splits_heads_at_once(cfg):
+        named.update(SPLIT_TO_HEADS_MINOR)
     out = {}
-    if not cfg.kv_lora_rank:
-        return out
-    for name, order in CONTRACTED_MINOR.items():
+    for name, order in named.items():
         leaf = params["layers"].get(name)
         if not isinstance(leaf, (jax.Array, jax.ShapeDtypeStruct)):
             continue  # absent, or a QuantTensor

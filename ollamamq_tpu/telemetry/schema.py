@@ -324,9 +324,11 @@ WEIGHT_STACKS_RELAID = REGISTRY.gauge(
     "ollamamq_weight_stacks_relaid",
     "Weight leaves a model runtime holds in another device layout than the "
     "default row-major order, because the step programs' contractions read "
-    "that order (2 for a model with latent attention on one device: "
-    "mla_wuq, mla_wukv, rank minor; 0 otherwise). Names, logical shapes and "
-    "values are unchanged", labels=("model",))
+    "that order, contracted dimension minor, on one device (2 for a model "
+    "with latent attention: mla_wuq, mla_wukv; 2 for one whose q and k "
+    "projections are split into heads at once: wq, wk; 0 for one that norms "
+    "them at full width first, for int8 weights and under a mesh). Names, "
+    "logical shapes and values are unchanged", labels=("model",))
 WEIGHT_STACKS_RELAID_BYTES = REGISTRY.gauge(
     "ollamamq_weight_stacks_relaid_bytes",
     "Bytes of those leaves: what every step program re-laid a pass while "

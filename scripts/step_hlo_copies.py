@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """What a configuration's step programs MOVE that no user asked for: every
-`copy` and every materialised `dynamic-slice` of at least `--min-mb` in the
-programs the engine jits for it, compiled for a DESCRIBED TPU v5e — no chip:
+`copy` and every materialised `dynamic-slice` or `slice` of at least `--min-mb`
+in the programs the engine jits for it, compiled for a DESCRIBED TPU v5e — no
+chip:
 
     python scripts/step_hlo_copies.py benchmarks/configs/<name>.json \
         [--min-mb 32] [--tokens N] [--rehearse] [--default-layouts] \
@@ -11,16 +12,27 @@ A weight that the compiler reads in another order than it is stored in is
 re-laid once a launch of the program: `copy.126 bf16[6,1536,24576]`, the whole
 `mla_wuq` stack, was 1.40 ms of openPangu's 15 ms pass (PERF.md section 6,
 PR 45) and shows here as a `copy` whose result has the operand's shape and
-another layout. A layer's slice of a stack that a kernel or a contraction
-cannot read in place shows as a `...dynamic-slice_fusion` of the layer's shape.
+another layout; K-EXAONE's ragged step, its weights row-major, moves a layer
+of `wq` TEN times a step — a slice and a copy a layer: in the loop of two
+layers `constant_dynamic-slice_fusion.7 bf16[1,6144,8192]` and `copy.195` of
+it to `{1,2,0}`, for the three layers outside any loop
+`slice_bitcast_fusion#0..2` and three `copy bf16[8192,6144]` `{0,1}` ->
+`{1,0}` under `attn_qkv` — and as served the five slices alone (PR 51). A
+layer's slice of a stack that a kernel or a contraction cannot read in place
+shows as a `...dynamic-slice_fusion` of the layer's shape inside a run's
+loop, and as one result of a `slice` fusion (`fusion.698#1`) for the layers a
+run of ONE repeat leaves outside any loop: three of K-EXAONE's five, 0.92 ms
+of its 16.4 ms step where the loop's two are 0.27.
 
 The configuration file's keys make the `ModelConfig` and its `server_flags`
 the pool and the step shapes, as `benchmarks/serve.py` hands them to the CLI;
 the programs are the engine's own jit sites (`ModelRuntime._get_ragged_jit` at
 `--max-batch-tokens`, with the prediction module's carries under `--spec`;
 `_get_decode_jit` at `--decode-steps` otherwise), lowered with the weights'
-shapes in the formats `models/llama.py:weight_formats` names
-(`--default-layouts`: in row-major order, what the tree was before PR 45) and
+shapes in the formats `models/llama.py:weight_formats` names — the latent
+up-projections, and `wq` / `wk` where `_qkv` splits its projections into heads
+at once; nothing for a model that norms them flat first, whose compiler reads
+them row-major (`--default-layouts`: every weight in row-major order) and
 `--tp` over a mesh of the described chips. Nothing runs: a compile that
 passes is not a chip run, and an op listed here has no time until a trace
 gives it one. One JSON line a program, then one last line with the count.
@@ -52,43 +64,67 @@ _INSTR = re.compile(
     r"^\s*(?P<root>ROOT )?%(?P<name>[\w.\-]+) = (?P<dtype>\w+)"
     r"\[(?P<dims>[\d,]*)\](?P<layout>\{[^}]*\})? (?P<op>[\w\-]+)"
     r"\((?P<args>[^)]*)\)")
+# a fusion with a tuple for its result: `%name = (shape, shape, ...) fusion(`
+_TUPLE_FUSION = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = \((?P<shapes>.*)\) fusion"
+    r"\((?P<args>[^)]*)\)")
+_SHAPE = re.compile(r"(\w+)\[([\d,]*)\](\{[^}]*\})?")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>[\w.\-]+) \(")
 _CALLS = re.compile(r"calls=%([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-MOVES = ("copy", "dynamic-slice")
+MOVES = ("copy", "dynamic-slice", "slice")
 
 
 def moves(hlo: str, min_bytes: int) -> list:
     """The device ops of a compiled module's text that only MOVE at least
-    `min_bytes`: a `copy`, a `dynamic-slice`, or a fusion whose root is one
-    (XLA names it `...dynamic-slice_fusion`, `copy_fusion`). Only an
-    instruction of the entry, a loop's body or a branch is an op of its own
-    on the device: one inside a fused computation is part of that fusion's
-    read (a contraction that re-lays its operand as it reads it) and is not
-    listed. Each with its name, which of the two it `moves`, shape, the
-    result's layout, the first operand's name (a parameter's says which
-    weight), shape and layout, and the `op_name` the source gave it."""
-    computations, inside, fused = {}, None, set()
+    `min_bytes`: a `copy`, a `dynamic-slice`, a `slice`, or a fusion whose
+    root is one (XLA names it `...dynamic-slice_fusion`, `copy_fusion`,
+    `slice_bitcast_fusion`) or a tuple of them (the layers a run leaves
+    outside its loop, sliced out of a stack in ONE fusion: an entry a
+    result, `name#i`). Only an instruction of the entry, a loop's body or a
+    branch is an op of its own on the device: one inside a fused computation
+    is part of that fusion's read (a contraction that re-lays its operand as
+    it reads it) and is not listed. Each with its name, which of the three it
+    `moves`, shape, the result's layout, the first operand's name (a
+    parameter's says which weight), shape and layout, and the `op_name` the
+    source gave it."""
+    computations, tuples, tuple_roots = {}, {}, {}
+    at, fused = None, set()
     for line in hlo.splitlines():
         head = _COMPUTATION.match(line)
         if head:
-            inside = computations.setdefault(head["name"], [])
+            at = head["name"]
+            computations.setdefault(at, [])
+            tuples.setdefault(at, [])
             continue
         if " fusion(" in line:  # also one with a tuple for its result
             fused.update(_CALLS.findall(line))
-        m = _INSTR.match(line)
-        if m is not None and inside is not None:
-            inside.append((m, line))
+        if at is None:
+            continue
+        if "ROOT " in line and " tuple(" in line:
+            tuple_roots[at] = line
+        elif (m := _INSTR.match(line)) is not None:
+            computations[at].append((m, line))
+        elif (m := _TUPLE_FUSION.match(line)) is not None:
+            tuples[at].append((m, line))
 
-    def root_op(computation: str) -> str:
-        """What a fused computation's root does, through bitcasts."""
-        by_name = {m["name"]: m for m, _ in computations.get(computation, ())}
-        m = next((m for m, _ in computations.get(computation, ())
-                  if m["root"]), None)
-        while m is not None and m["op"] == "bitcast":
-            first = re.search(r"%([\w.\-]+)", m["args"])
-            m = by_name.get(first.group(1)) if first else None
-        return m["op"] if m is not None else ""
+    def root_ops(computation: str) -> list:
+        """What a fused computation's root does, through bitcasts: one op,
+        or one a result where the root is a tuple."""
+        instrs = computations.get(computation, ())
+        by_name = {m["name"]: m for m, _ in instrs}
+
+        def through(m):
+            while m is not None and m["op"] == "bitcast":
+                first = re.search(r"%([\w.\-]+)", m["args"])
+                m = by_name.get(first.group(1)) if first else None
+            return m["op"] if m is not None else ""
+
+        root = tuple_roots.get(computation)
+        if root is not None:
+            return [through(by_name.get(n)) for n in re.findall(
+                r"%([\w.\-]+)", root.split(" tuple(", 1)[1].split(")")[0])]
+        return [through(next((m for m, _ in instrs if m["root"]), None))]
 
     found = []
     for name, instrs in computations.items():
@@ -96,26 +132,31 @@ def moves(hlo: str, min_bytes: int) -> list:
             continue
         defined = {m["name"]: (f"{m['dtype']}[{m['dims']}]",
                                m["layout"] or "") for m, _ in instrs}
-        for m, line in instrs:
-            dims = [int(d) for d in m["dims"].split(",") if d]
-            n = math.prod(dims) * ITEMSIZE.get(m["dtype"], 0)
-            if n < max(min_bytes, 1):
-                continue
-            kind = m["op"] if m["op"] != "fusion" else next(
-                (op for op in map(root_op, _CALLS.findall(line))
-                 if op in MOVES), "")
-            if kind not in MOVES:
-                continue
-            first = re.search(r"%([\w.\-]+)", m["args"])
+        results = [(m["name"], m["op"], [(m["dtype"], m["dims"],
+                                         m["layout"])], m["args"], line)
+                   for m, line in instrs]
+        results += [(m["name"], "fusion", _SHAPE.findall(m["shapes"]),
+                     m["args"], line) for m, line in tuples[name]]
+        for instr, op, shapes, args, line in results:
+            kinds = [op] * len(shapes) if op != "fusion" else [
+                k for c in _CALLS.findall(line) for k in root_ops(c)]
+            first = re.search(r"%([\w.\-]+)", args)
             src = defined.get(first.group(1) if first else "", ("?", ""))
             op_name = _OP_NAME.search(line)
-            found.append({
-                "name": m["name"], "op": m["op"], "moves": kind,
-                "shape": f"{m['dtype']}[{m['dims']}]", "dims": dims,
-                "layout": m["layout"] or "", "bytes": n,
-                "from": first.group(1) if first else "",
-                "from_shape": src[0], "from_layout": src[1],
-                "op_name": op_name.group(1) if op_name else ""})
+            for i, ((dtype, dim, layout), kind) in enumerate(
+                    zip(shapes, kinds)):
+                dims = [int(d) for d in dim.split(",") if d]
+                n = math.prod(dims) * ITEMSIZE.get(dtype, 0)
+                if n < max(min_bytes, 1) or kind not in MOVES:
+                    continue
+                found.append({
+                    "name": instr + (f"#{i}" if len(shapes) > 1 else ""),
+                    "op": op, "moves": kind,
+                    "shape": f"{dtype}[{dim}]", "dims": dims,
+                    "layout": layout or "", "bytes": n,
+                    "from": first.group(1) if first else "",
+                    "from_shape": src[0], "from_layout": src[1],
+                    "op_name": op_name.group(1) if op_name else ""})
     return found
 
 
@@ -232,7 +273,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="the file's tiny `rehearse` sizes")
     ap.add_argument("--default-layouts", action="store_true",
-                    help="every weight row-major (before PR 45)")
+                    help="every weight row-major, not as served")
     ap.add_argument("--dump", help="write each program's compiled HLO here")
     args = ap.parse_args(argv)
 

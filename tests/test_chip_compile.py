@@ -648,18 +648,10 @@ def test_ragged_step_is_fed_one_host_array(v5e, monkeypatch, cfg):
     assert (fed[0].shape, fed[0].dtype) == ((words,), jnp.int32), fed
 
 
-@pytest.mark.parametrize("held", [False, True],
-                         ids=["row_major", "as_served"])
-def test_no_step_program_re_lays_a_latent_stack(v5e, capsys, held):
-    """`scripts/step_hlo_copies.py` on openPangu's configuration file at its
-    `rehearse` sizes (PR 45): with every weight row-major the chip's compiler
-    puts a `copy` of a layer of `mla_wuq` and of `mla_wukv` into the `--spec`
-    step (the re-layout that was 2.0 ms of a 15 ms pass at the published
-    widths); with the two stacks in the formats `llama.weight_formats` names
-    — as a runtime holds them — it puts none. (At these sizes the toy expert
-    stacks' 64 lanes get a copy of their own into the grouped matmul: the
-    check is of the stacks the rule names.) Within its own time limit: two
-    compiles of some ten seconds."""
+def _step_hlo_copies(capsys, name, *flags):
+    """`scripts/step_hlo_copies.py` on `benchmarks/configs/<name>.json`, in
+    this process and within its own time limit: the programs' lines and the
+    stacks whose shape a listed weight copy has."""
     import contextlib
     import json
     import signal
@@ -682,18 +674,75 @@ def test_no_step_program_re_lays_a_latent_stack(v5e, capsys, held):
             signal.signal(signal.SIGALRM, was)
 
     config = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                          "configs", "openpangu-ultra-moe-ep16-d5.json")
-    argv = [config, "--rehearse", "--min-mb", "0"]
+                          "configs", name + ".json")
     with time_limit(240):
-        assert step_hlo_copies.main(
-            argv + (["--default-layouts"] if not held else [])) == 0
+        assert step_hlo_copies.main([config, *flags]) == 0
     lines = [json.loads(line) for line in
              capsys.readouterr().out.splitlines() if line.startswith("{")]
     programs, last = lines[:-1], lines[-1]
-    assert [p["program"] for p in programs] == ["mq_ragged_step"]
-    assert last["programs"] == 1
+    assert last["programs"] == len(programs)
     re_laid = {name for p in programs for c in p["weight_copies"]
                for name in c["stacks"]}
+    return programs, re_laid
+
+
+@pytest.mark.parametrize("held", [False, True],
+                         ids=["row_major", "as_served"])
+def test_no_step_program_re_lays_a_latent_stack(v5e, capsys, held):
+    """`scripts/step_hlo_copies.py` on openPangu's configuration file at its
+    `rehearse` sizes (PR 45): with every weight row-major the chip's compiler
+    puts a `copy` of a layer of `mla_wuq` and of `mla_wukv` into the `--spec`
+    step (the re-layout that was 2.0 ms of a 15 ms pass at the published
+    widths); with the two stacks in the formats `llama.weight_formats` names
+    — as a runtime holds them — it puts none. (At these sizes the toy expert
+    stacks' 64 lanes get a copy of their own into the grouped matmul: the
+    check is of the stacks the rule names.) Within its own time limit: two
+    compiles of some ten seconds."""
+    programs, re_laid = _step_hlo_copies(
+        capsys, "openpangu-ultra-moe-ep16-d5", "--rehearse", "--min-mb", "0",
+        *(() if held else ("--default-layouts",)))
+    assert [p["program"] for p in programs] == ["mq_ragged_step"]
     latent = set(llama.CONTRACTED_MINOR)
     assert (re_laid & latent == set()) if held else (latent <= re_laid), \
         programs[0]["weight_copies"]
+
+
+@pytest.mark.parametrize("name,held", [
+    ("qwen2.5-7b-d14", False), ("qwen2.5-7b-d14", True),
+    ("olmoe-1b-7b-d10", True)],
+    ids=["split_row_major", "split_as_served", "full_norm"])
+def test_no_step_program_re_lays_wq_or_wk(v5e, capsys, name, held):
+    """The same check for `_qkv`'s projections (PR 51), at PUBLISHED widths
+    (both of a file's programs compile in ~25 s; K-EXAONE's and LFM2's
+    `rehearse` sizes list no program here: PERF.md section 7). Qwen2.5 splits q
+    and k into heads at once: with every weight row-major its ragged step
+    re-lays a layer of `wq` and of `wk` a layer and its decode scan both
+    stacks whole; as a runtime holds them — contracted dimension minor — no
+    program copies a weight. OLMoE norms the flat projection first: the rule
+    names nothing for it (as served IS row-major) and its programs re-lay
+    neither stack."""
+    programs, re_laid = _step_hlo_copies(
+        capsys, name, "--min-mb", "1",
+        *(() if held else ("--default-layouts",)))
+    assert [p["program"] for p in programs] \
+        == ["mq_ragged_step", "mq_decode_scan"]
+    qk = set(llama.SPLIT_TO_HEADS_MINOR)
+    if held:
+        assert re_laid & qk == set(), [p["weight_copies"] for p in programs]
+    else:
+        for p in programs:  # either program, each stack
+            assert qk <= {n for c in p["weight_copies"]
+                          for n in c["stacks"]}, p["weight_copies"]
+    # and what the rule names for the file's model (`benchmarks` is on the
+    # path since the script was imported)
+    import json
+
+    from benchmarks import serve
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", name + ".json")) as f:
+        mc = serve.model_config(json.load(f), False)
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(mc, jax.random.PRNGKey(0)))
+    assert set(llama.weight_formats(mc, shapes)) \
+        == (qk if name.startswith("qwen") else set())
