@@ -2885,6 +2885,10 @@ class ModelRuntime:
         h.no = self._launch_no
         rng[0] = self._next_rng()
         self._h2d = [0, 0]
+        # `dispatch` holds two jobs; while a capture runs each is a child
+        # span at its seam (stepprof.CHILD_SPANS), so that an idle gap of
+        # the chip that lies inside one names it.
+        _sp.seam("launch")
         try:
             h.toks_dev, h.n_emit_dev, self.kc, self.vc, self.recent, \
                 self.last_ids, self.slot_state = self._dispatch_ragged(
@@ -2897,6 +2901,7 @@ class ModelRuntime:
             self._jrec("batch", **batch_fields)
             self._ragged_failed(rows, e, core)
             return None
+        _sp.seam("note")
         self._note_queued(h)
         opened = int(is_first.sum())
         spans = [span for *_, span in rows]
@@ -3116,8 +3121,10 @@ class ModelRuntime:
         h = StepInFlight(rows, k_steps, _sp, None, [True] * len(active),
                          float(np.mean(self.seq_lens[active])))
         self._h2d = [0, 0]
+        _sp.seam("launch")
         h.toks_dev, self.kc, self.vc, self.recent, self.last_ids, \
             self.slot_state = self._dispatch_decode(k_steps, buf)
+        _sp.seam("note")
         self._note_queued(h)
         self._note_slot_state(_sp, 0, len(active),
                               len(active) * int(k_steps), 0)

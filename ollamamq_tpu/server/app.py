@@ -1174,13 +1174,22 @@ class Server:
         The output directory is operator-controlled (OLLAMAMQ_PROFILE_DIR
         env, never the request body), duration is clamped to [0.1, 30] s,
         and only one trace runs at a time. Body: `seconds`, and
-        `python_tracer` (bool, default true): false leaves the profiler's
-        Python tracer off, so the engine's own `mq.*` spans and jax's
-        annotations are the only host events — the cheap capture.
+        `python_tracer` (bool, default false): the profiler's Python
+        tracer stays off, so the engine's own `mq.*` spans and jax's
+        annotations are the only host events — the cheap capture, which
+        leaves the host it measures alone (PERF.md section 3, "What a
+        capture costs"). `true` adds a Python frame for every call of
+        every thread, for an operator who wants them and pays for them.
 
         While the capture runs the step profiler also enters every step
         and loop phase as a span on the profiler's clock
         (stepprof.SPAN_NAMES), each carrying the `seq` of its sample.
+        The trace's clock is the realtime clock less the profiler
+        session's start: `capture.origin_epoch_ns` is the realtime clock
+        read immediately before `start_trace`, and the one `mq.clock`
+        span entered as the capture begins carries the realtime clock at
+        its own start (`epoch_ns`) — either places the trace's events on
+        the epoch clock of the step samples (`ts`).
         """
         self._ident(request)
         body = await self._body_json(request)
@@ -1188,7 +1197,7 @@ class Server:
             seconds = max(0.1, min(float(body.get("seconds", 3.0)), 30.0))
         except (TypeError, ValueError):
             raise ApiError(400, "'seconds' must be a number")
-        python_tracer = body.get("python_tracer", True)
+        python_tracer = body.get("python_tracer", False)
         if not isinstance(python_tracer, bool):
             raise ApiError(400, "'python_tracer' must be a boolean")
         out_dir = os.environ.get("OLLAMAMQ_PROFILE_DIR", "/tmp/ollamamq-profile")
@@ -1198,20 +1207,25 @@ class Server:
         prof = stepprof.PROFILER
 
         def run_trace():
-            """(worker thread) -> (start_epoch, stop_epoch): the epoch
-            instants between which the device trace was recording."""
+            """(worker thread) -> (origin_epoch_ns, start_epoch,
+            stop_epoch, samples): the realtime clock as the profiler
+            session was about to start, the epoch instants between which
+            the device trace was recording, and the step samples that
+            ended between them."""
             import jax
 
-            if python_tracer:
-                # No options at all: the capture every earlier
-                # measurement was made with.
-                jax.profiler.start_trace(out_dir)
-            else:
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0
-                jax.profiler.start_trace(out_dir, profiler_options=opts)
+            # With the Python tracer: no options at all, jax's own
+            # default — the capture every measurement before PR 52 was
+            # made with, and not comparable with the ones since.
+            kw = {}
+            if not python_tracer:
+                kw["profiler_options"] = jax.profiler.ProfileOptions()
+                kw["profiler_options"].python_tracer_level = 0
+            origin_ns = time.time_ns()
+            jax.profiler.start_trace(out_dir, **kw)
+            t0 = time.time()
             try:
-                t0 = time.time()
+                prof.stamp_clock()
                 prof.capturing = True
                 time.sleep(seconds)
             finally:
@@ -1221,12 +1235,17 @@ class Server:
                 # start_trace, wedging the endpoint permanently.
                 prof.capturing = False
                 t1 = time.time()
+                # The capture's own samples, taken NOW: stop_trace takes
+                # many times the capture's length to return (43-51 s for
+                # 5 s on a v5e), and a busy engine turns the ring over
+                # in less.
+                samples = prof.window(t0, t1)
                 jax.profiler.stop_trace()
-            return t0, t1
+            return origin_ns, t0, t1, samples
 
         try:
-            t0, t1 = await asyncio.get_running_loop().run_in_executor(
-                None, run_trace)
+            origin_ns, t0, t1, samples = await \
+                asyncio.get_running_loop().run_in_executor(None, run_trace)
         except Exception as e:
             # A failed capture answers 500 and — via the finally below —
             # clears the capture-running flag, so the NEXT capture gets a
@@ -1235,16 +1254,16 @@ class Server:
         finally:
             self._profiling = False
         # The capture's own step accounting rides along: the samples
-        # that ended while the device trace was recording (stop_trace
-        # itself can take several times the capture's length to return),
-        # so a trace and its per-phase step samples land together and
-        # `seq` joins a sample to its mq.* spans in the trace.
-        samples = prof.window(t0, t1)
+        # that ended while the device trace was recording (run_trace took
+        # them before stop_trace), so a trace and its per-phase step
+        # samples land together and `seq` joins a sample to its mq.*
+        # spans in the trace.
         seqs = [s["seq"] for s in samples]
         return web.json_response({
             "status": "success", "trace_dir": out_dir, "seconds": seconds,
             "python_tracer": python_tracer,
-            "capture": {"start_epoch": t0, "stop_epoch": t1,
+            "capture": {"origin_epoch_ns": origin_ns,
+                        "start_epoch": t0, "stop_epoch": t1,
                         "first_seq": min(seqs, default=None),
                         "last_seq": max(seqs, default=None)},
             "stepprof": samples,

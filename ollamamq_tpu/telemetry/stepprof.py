@@ -45,7 +45,10 @@ Contracts the tests pin:
   * While a device capture runs (`PROFILER.capturing`, set by
     POST /debug/profile) every phase is also entered as a span on the
     profiler's clock (SPAN_NAMES), carrying the `seq` its sample will
-    have — one mechanism feeds samples, histograms and spans.
+    have — one mechanism feeds samples, histograms and spans. A phase
+    that holds more than one job has child spans at its seams
+    (CHILD_SPANS, `seam()`): spans only, never a sample field, and with
+    no capture running a seam costs one attribute test.
   * The program knows when its chip ran dry without a profiler: every
     launched step carries a DoneBracket — the last instant its result
     was seen NOT ready and the first it was seen ready, probed
@@ -110,8 +113,26 @@ LOOP_PHASES = (
 
 # The same phases as spans on the device trace's clock, emitted only
 # while a capture runs. Pinned to the README span table (gate 7).
-SPAN_NAMES = (tuple("mq." + p for p in PHASES)
-              + tuple("mq.loop." + p for p in LOOP_PHASES))
+PHASE_SPANS = (tuple("mq." + p for p in PHASES)
+               + tuple("mq.loop." + p for p in LOOP_PHASES))
+# The seams INSIDE a phase that holds more than one job (LoopClock.seam):
+# phase span -> its child spans `<phase span>.<child>`, emitted while a
+# capture runs and never otherwise — spans only, no sample field. A phase
+# gets children where a capture charged it more than a quarter of a cell's
+# idle seconds (PERF.md section 3 says which captures called for which).
+CHILD_SPANS = {
+    "mq.dispatch": (
+        "launch",   # the step's key, the upload of its packed buffer and
+        #             the jitted call, up to its return
+        "note",     # the done-bracket and what the launch notes on its
+        #             sample (slot state, attention and latent counters)
+    ),
+}
+# One span as a capture begins, on the thread that started it (never the
+# engine's): its `epoch_ns` is the realtime clock at its own start.
+CLOCK_SPAN = "mq.clock"
+SPAN_NAMES = PHASE_SPANS + tuple(
+    p + "." + c for p, cs in CHILD_SPANS.items() for c in cs) + (CLOCK_SPAN,)
 
 # The phase a mark OPENS: marks name the phase that ended, a span needs
 # its name when it begins, and the order is fixed.
@@ -194,13 +215,16 @@ class LoopClock:
     nothing queued."""
 
     __slots__ = ("name", "_prof", "_last", "_owner", "_open", "_span",
-                 "_loop", "_timers", "_seq", "_adopted", "_watch", "_ahead",
-                 "_cum", "_wait_end", "_cum_wait_end")
+                 "_child", "_loop", "_timers", "_seq", "_adopted", "_watch",
+                 "_ahead", "_cum", "_wait_end", "_cum_wait_end")
 
     def __init__(self, prof: "StepProfiler", name: str):
         self._prof = prof
         self.name = name
+        # While a capture runs: the open phase's (span, name, seq), and
+        # the child span open at one of its seams.
         self._span = None
+        self._child = None
         # Milliseconds charged to each phase, ever (never reset: a
         # bracket's snapshot of it must stay comparable).
         self._cum = dict.fromkeys(_SAMPLE_PHASES, 0.0)
@@ -227,9 +251,12 @@ class LoopClock:
         self._cum_wait_end = dict(self._cum)
 
     def _close_span(self) -> None:
+        child, self._child = self._child, None
+        if child is not None:
+            child.__exit__(None, None, None)
         span, self._span = self._span, None
         if span is not None:
-            span.__exit__(None, None, None)
+            span[0].__exit__(None, None, None)
 
     def _switch(self, t: float, owner: Optional["StepTimer"], phase: str,
                 charge: Optional[tuple] = None, probe: bool = True) -> None:
@@ -273,16 +300,35 @@ class LoopClock:
                 if self._seq is None:
                     self._seq = prof._reserve_seq()
                 seq = self._seq
-            span = prof.span_factory("mq.loop." + phase, seq=seq)
+            name = "mq.loop." + phase
+            span = prof.span_factory(name, seq=seq)
         else:
             if owner.seq is None:
                 owner.seq = prof._reserve_seq()
+            name, seq = "mq." + phase, owner.seq
             span = prof.span_factory(
-                "mq." + phase, seq=owner.seq, mode=owner.mode,
+                name, seq=seq, mode=owner.mode,
                 **{k: v for k, v in owner.fields.items()
                    if k in _SPAN_FIELDS})
         span.__enter__()
-        self._span = span
+        self._span = (span, name, seq)
+
+    def seam(self, child: str) -> None:
+        """A seam INSIDE the open phase, where it holds more than one
+        job: while a capture runs, the child span open under the phase's
+        span (if any) closes and `<phase span>.<child>` opens, carrying
+        its parent's `seq` (CHILD_SPANS; it closes with its parent at the
+        latest). A span only: no sample field, no histogram, no counter —
+        with no capture running a seam costs this one test."""
+        prof = self._prof
+        if prof.capturing and self._span is not None:
+            t0 = time.perf_counter_ns()
+            if self._child is not None:
+                self._child.__exit__(None, None, None)
+            _, name, seq = self._span
+            self._child = prof.span_factory(name + "." + child, seq=seq)
+            self._child.__enter__()
+            prof._overhead_ns += time.perf_counter_ns() - t0
 
     # -- the done-bracket of every step in flight ---------------------------
     def _open_key(self) -> str:
@@ -476,6 +522,10 @@ class StepTimer:
         """Look at the steps in flight from inside this step's phase."""
         self._clock.probe()
 
+    def seam(self, child: str) -> None:
+        """A seam inside this step's open phase (LoopClock.seam)."""
+        self._clock.seam(child)
+
     def collected(self) -> float:
         """The blocking read of the step's result has just returned:
         mark `collect`, and if no probe saw the step ready before, it is
@@ -608,6 +658,15 @@ class StepProfiler:
         their engine attached); None times the step alone."""
         return StepTimer(self, mode, clock)
 
+    def stamp_clock(self) -> None:
+        """One `mq.clock` span on the calling thread, carrying the
+        realtime clock at its own start (`epoch_ns`): entered by POST
+        /debug/profile as its capture begins, so that a trace's events
+        can be placed on the epoch clock of the samples' `ts`."""
+        if self.span_factory is not None:
+            with self.span_factory(CLOCK_SPAN, epoch_ns=time.time_ns()):
+                pass
+
     def _reserve_seq(self) -> int:
         with self._lock:
             self.seq += 1
@@ -615,7 +674,9 @@ class StepProfiler:
 
     def _record(self, sample: dict, total_ms: float,
                 seq: Optional[int] = None) -> None:
-        t0 = time.perf_counter_ns()
+        """(from StepTimer.finish alone, which meters this call with its
+        own: metering it here too counted a third of the profiler's time
+        twice, up to PR 51.)"""
         key = (sample["mode"], sample.get("T_pad", 0), sample.get("k_cap", 0))
         loop_ms = sum(sample["loop_" + ph + "_ms"] for ph in LOOP_PHASES)
         with self._lock:
@@ -668,7 +729,6 @@ class StepProfiler:
                 .inc(sample["h2d_transfers"])
             tm.STEP_H2D_BYTES_TOTAL.labels(mode=sample["mode"]) \
                 .inc(sample.get("h2d_bytes", 0))
-        self._overhead_ns += time.perf_counter_ns() - t0
 
     # -- compile ledger ----------------------------------------------------
     def record_compile(self, site: str, key, wall_ms: float,

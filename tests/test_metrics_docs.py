@@ -124,6 +124,12 @@ import pytest  # noqa: E402
      "| `notaloopphase` | bogus |\n"),
     ("SPANS_BEGIN", "SPANS_END", "`mq.loop.wait`",
      "| `mq.loop.bogus` | host span | bogus |\n"),
+    # PR 52: the child spans at a phase's seams and the capture's clock
+    # span are of the same closed vocabulary.
+    ("SPANS_BEGIN", "SPANS_END", "`mq.dispatch.note` |",
+     "| `mq.dispatch.bogus` | host span | bogus |\n"),
+    ("SPANS_BEGIN", "SPANS_END", "| `mq.clock` |",
+     "| `mq.clock.bogus` | host span | bogus |\n"),
 ])
 def test_checker_pins_loop_phase_and_span_tables(tmp_path, begin, end, row,
                                                  ghost):
@@ -133,12 +139,19 @@ def test_checker_pins_loop_phase_and_span_tables(tmp_path, begin, end, row,
     tables like PHASES: a missing row, a ghost row and stripped markers
     each fail the gate."""
     mod = _load()
-    from ollamamq_tpu.telemetry.stepprof import (LOOP_PHASES, PHASES,
-                                                 SPAN_NAMES)
+    from ollamamq_tpu.telemetry.stepprof import (CHILD_SPANS, CLOCK_SPAN,
+                                                 LOOP_PHASES, PHASE_SPANS,
+                                                 PHASES, SPAN_NAMES)
 
     assert set(LOOP_PHASES) == {"admit", "other", "wait"}
-    assert set(SPAN_NAMES) == ({"mq." + p for p in PHASES}
-                               | {"mq.loop." + p for p in LOOP_PHASES})
+    assert set(PHASE_SPANS) == ({"mq." + p for p in PHASES}
+                                | {"mq.loop." + p for p in LOOP_PHASES})
+    # A child span hangs under a phase span, and the names are unique.
+    assert set(CHILD_SPANS) <= set(PHASE_SPANS)
+    assert set(SPAN_NAMES) == (
+        set(PHASE_SPANS) | {CLOCK_SPAN}
+        | {p + "." + c for p, cs in CHILD_SPANS.items() for c in cs})
+    assert len(SPAN_NAMES) == len(set(SPAN_NAMES))
     begin, end = getattr(mod, begin), getattr(mod, end)
     with open(os.path.join(_REPO, "README.md"), encoding="utf-8") as f:
         full = f.read()

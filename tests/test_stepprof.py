@@ -16,7 +16,11 @@ Contracts pinned here:
     leaves NO partial sample and the decision journal stays clean;
   - profiler self-overhead stays under the 1% always-on budget;
   - the fleet router federates member `ollamamq_step_phase_ms` series
-    with a replica label.
+    with a replica label;
+  - a seam inside a phase (PR 52) is a span and nothing else: with no
+    capture it makes no object and touches no sample, with one its child
+    span opens inside the phase's span and closes before it, carrying its
+    parent's `seq`.
 """
 
 import json
@@ -259,6 +263,49 @@ def test_self_overhead_stays_under_one_percent():
     frac = PROFILER.overhead_fraction()
     assert PROFILER.seq > 0
     assert 0.0 <= frac < 0.01, f"profiler overhead {frac:.4f} >= 1%"
+
+
+def test_the_overhead_meter_counts_no_instant_twice():
+    """A loop that does nothing BUT profiler calls — a launch, the settle
+    of the step before it, the loop's phases — cannot be metered at more
+    than its own wall time, and the meter sees most of it. Up to PR 51
+    `_record` metered itself inside `finish`'s metering and the meter read
+    1.3 x the wall (PR 52)."""
+    prof = StepProfiler()
+    clock = stepprof.LoopClock(prof, "t")
+
+    def one(prev):
+        clock.tick()
+        clock.enter("admit")
+        clock.enter("other")
+        sp = prof.start("ragged", clock)
+        sp.note(T_pad=64, k_cap=0, tokens=50)
+        sp.mark("host_prep")
+        sp.seam("launch")
+        sp.launched(lambda: True, model="m", h2d_transfers=1, h2d_bytes=8)
+        sp.seam("note")
+        sp.mark("dispatch")
+        sp.park()
+        if prev is not None:
+            prev.resume("collect")
+            prev.collected()
+            prev.park()
+            prev.resume("detok")
+            prev.probe()
+            prev.mark("detok")
+            prev.finish(n_prefill=1, n_decode=2, padded_tokens=64)
+        return sp
+
+    prev = None
+    for _ in range(200):   # warm: first calls, label children, the ring
+        prev = one(prev)
+    prof._overhead_ns = 0
+    t0 = time.perf_counter_ns()
+    for _ in range(2000):
+        prev = one(prev)
+    wall_ns = time.perf_counter_ns() - t0
+    assert 0.5 * wall_ns <= prof._overhead_ns <= wall_ns, \
+        (prof._overhead_ns, wall_ns)
 
 
 # ------------------------------------------------- gapless engine thread
@@ -571,3 +618,99 @@ def test_window_slices_ring_by_capture_timestamps():
     assert len(inside) == 1 and inside[0]["mode"] == "fake"
     assert PROFILER.window(t_after + 10, t_after + 20) == []
     assert PROFILER.window(t_before - 20, t_before - 10) == []
+
+
+# --------------------------------------------------- seams inside a phase
+class _Span:
+    """A stand-in for jax.profiler.TraceAnnotation that logs its life."""
+
+    def __init__(self, log, name, **stats):
+        self.log, self.name, self.stats = log, name, stats
+
+    def __enter__(self):
+        self.log.append(("open", self.name, self.stats.get("seq")))
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name, self.stats.get("seq")))
+
+
+@pytest.mark.parametrize("capturing", [False, True])
+def test_a_seam_is_a_child_span_while_capturing_and_nothing_otherwise(
+        capturing, monkeypatch):
+    """The same marks and seams with and without a capture: the sample is
+    the same but for its clock readings; without a capture no span object
+    is made; with one the children chain inside their phase's span, are
+    closed before it (the profiler's spans nest last-in first-out), carry
+    its `seq`, and a phase without seams has none."""
+    import functools
+
+    prof, log = StepProfiler(), []
+    monkeypatch.setattr(prof, "span_factory", functools.partial(_Span, log))
+    prof.capturing = capturing
+    clock = stepprof.LoopClock(prof, "t")
+    clock.seam("early")              # no phase span open yet: nothing
+    clock.enter("other")
+    sp = prof.start("ragged", clock)
+    sp.mark("host_prep")
+    sp.seam("launch")
+    sp.seam("note")
+    sp.mark("dispatch")
+    sp.park()
+    clock.seam("hbm")                # a loop phase's seam
+    sp.resume("collect")
+    sp.mark("collect")
+    sp.mark("detok")
+    smp = sp.finish(tokens=3)
+    clock.enter("wait")              # closes the `other` finish() opened
+    clock.reset()
+    assert set(smp) >= {"total_ms", "dispatch_ms", "collect_ms",
+                        "loop_other_ms", "seq"}
+    assert not [k for k in smp if any(c in k for c in (
+        "launch", "note", "hbm"))]
+    assert abs(_phase_sum(smp) - smp["total_ms"]) < 0.01
+    if not capturing:
+        assert log == []
+        return
+    seq = smp["seq"]
+    assert [(what, name) for what, name, _ in log] == [
+        ("open", "mq.loop.other"), ("close", "mq.loop.other"),
+        ("open", "mq.host_prep"), ("close", "mq.host_prep"),
+        ("open", "mq.dispatch"),
+        ("open", "mq.dispatch.launch"), ("close", "mq.dispatch.launch"),
+        ("open", "mq.dispatch.note"), ("close", "mq.dispatch.note"),
+        ("close", "mq.dispatch"),
+        ("open", "mq.collect"), ("close", "mq.collect"),  # park()
+        ("open", "mq.loop.other"),
+        ("open", "mq.loop.other.hbm"), ("close", "mq.loop.other.hbm"),
+        ("close", "mq.loop.other"),
+        ("open", "mq.collect"), ("close", "mq.collect"),
+        ("open", "mq.detok"), ("close", "mq.detok"),
+        ("open", "mq.loop.other"), ("close", "mq.loop.other"),
+        ("open", "mq.loop.wait"), ("close", "mq.loop.wait")]
+    # Every open has its close, last in first out, and a child its
+    # parent's seq.
+    stack = []
+    for what, name, q in log:
+        if what == "open":
+            if stack:
+                assert name.startswith(stack[-1][0] + "."), (name, stack)
+                assert q == stack[-1][1], (name, q, stack[-1])
+            stack.append((name, q))
+        else:
+            assert stack.pop() == (name, q)
+    assert not stack
+    assert {q for _, name, q in log if name.startswith(
+        ("mq.host_prep", "mq.dispatch", "mq.collect", "mq.detok"))} == {seq}
+    # Clearing the flag inside a phase: the spans open then still close.
+    log.clear()
+    prof.capturing = True
+    sp = prof.start("ragged", clock)
+    sp.mark("host_prep")
+    sp.seam("launch")
+    prof.capturing = False
+    sp.seam("note")                  # no new child once the flag is clear
+    sp.mark("dispatch")
+    assert [(w, n) for w, n, _ in log] == [
+        ("open", "mq.host_prep"), ("close", "mq.host_prep"),
+        ("open", "mq.dispatch"), ("open", "mq.dispatch.launch"),
+        ("close", "mq.dispatch.launch"), ("close", "mq.dispatch")]

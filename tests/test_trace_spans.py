@@ -8,12 +8,19 @@ sites are named functions and jax.named_scopes name the stages inside
 the ragged and decode programs. Pinned here, on the CPU:
 
   - a real start_trace around fake-engine steps yields every name of
-    stepprof.SPAN_NAMES on ONE host line, never nested in one another,
+    stepprof.PHASE_SPANS on ONE host line, never nested in one another,
     with `seq` stats that are recorded samples' seqs, and nothing once
     the flag is cleared;
   - POST /debug/profile replies with `capture` bounds and exactly the
     samples that ended inside them, although the call lasts longer;
-    accepts `python_tracer: false`; clears the flag after a failure;
+    leaves the profiler's Python tracer off unless asked for it (PR 52);
+    clears the flag after a failure; stamps the realtime clock before
+    `start_trace` (`capture.origin_epoch_ns`) and enters one `mq.clock`
+    span, which place the trace on the samples' epoch clock;
+  - the real engine's child spans (stepprof.CHILD_SPANS: the seams inside
+    a phase that holds more than one job) lie inside a span of their
+    parent's name with the same `seq`, and no span at all is created
+    while no capture runs;
   - every scope name is in the lowered text of the engine's ragged and
     decode programs, whose module names are the stable jit names, and
     README's span table lists them all.
@@ -32,7 +39,9 @@ from aiohttp.test_utils import TestClient, TestServer
 from ollamamq_tpu.config import EngineConfig
 from ollamamq_tpu.ops.sampling import SamplingParams
 from ollamamq_tpu.telemetry import stepprof
-from ollamamq_tpu.telemetry.stepprof import PROFILER, SPAN_NAMES
+from ollamamq_tpu.telemetry.stepprof import (CHILD_SPANS, CLOCK_SPAN,
+                                             PHASE_SPANS, PROFILER,
+                                             SPAN_NAMES)
 from testutil import collect
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -66,9 +75,11 @@ def _burst(eng, tag, n=2, max_tokens=5):
         assert collect(r)[-1].kind == "done"
 
 
-def _mq_lines(profile_dir):
+def _mq_lines(profile_dir, clock=False):
     """{line name: [(span name, start_ns, end_ns, stats)]} for every host
-    line of the capture that holds an mq.* span."""
+    line of the capture that holds an mq.* span of the engine's phases —
+    or, with `clock`, the `mq.clock` span of the thread that started the
+    capture."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(os.path.join(
@@ -80,7 +91,8 @@ def _mq_lines(profile_dir):
         for line in plane.lines:
             spans = [(e.name, int(e.start_ns),
                       int(e.start_ns + e.duration_ns), dict(e.stats))
-                     for e in line.events if e.name.startswith("mq.")]
+                     for e in line.events if e.name.startswith("mq.")
+                     and (e.name == CLOCK_SPAN) == clock]
             if spans:
                 out[line.name] = sorted(spans, key=lambda s: s[1])
     return out
@@ -112,7 +124,8 @@ def test_capture_puts_one_unnested_span_chain_on_the_engine_thread(tmp_path):
     lines = _mq_lines(str(tmp_path))
     assert len(lines) == 1, f"mq.* spans on {len(lines)} host lines"
     (spans,) = lines.values()
-    assert {n for n, *_ in spans} == set(SPAN_NAMES)
+    # The fake's phases hold one job each: the chain, and no child span.
+    assert {n for n, *_ in spans} == set(PHASE_SPANS)
     prev_end = 0
     for name, start, end, stats in spans:
         assert start >= prev_end, f"{name} nested in / overlaps another mq.*"
@@ -138,6 +151,88 @@ def test_capture_puts_one_unnested_span_chain_on_the_engine_thread(tmp_path):
     assert seq_set < min(span_seqs) and max(span_seqs) <= seq_cleared + 1
     assert len([q for q in samples if q <= seq_set]) >= 3
     assert len([q for q in samples if q > seq_cleared + 1]) >= 3
+
+
+def _real_engine(model="test-tiny"):
+    import jax.numpy as jnp
+
+    from ollamamq_tpu.engine.engine import TPUEngine
+
+    eng = TPUEngine(EngineConfig(model=model, max_slots=4, num_pages=64,
+                                 page_size=8, max_pages_per_seq=16,
+                                 decode_steps_per_iter=2),
+                    models={model: None}, blocklist_path=None,
+                    dtype=jnp.float32)
+    eng.start()
+    return eng
+
+
+def _prompts(eng, tag, n=3, max_tokens=6):
+    tok = eng.runtimes["test-tiny"].tokenizer
+    reqs = [eng.enqueue_request(
+        f"{tag}{i}", "", "test-tiny",
+        prompt_tokens=tok.encode("count to ten, then back " * (i + 1)),
+        sampling=SamplingParams(max_tokens=max_tokens)) for i in range(n)]
+    for r in reqs:
+        assert collect(r)[-1].kind == "done"
+
+
+def test_no_span_without_a_capture_and_children_inside_their_parents(
+        tmp_path):
+    """The real engine's loop, ragged launches and settles: while no
+    capture runs not one span object is made (a mark and a seam pay an
+    attribute test each); while one runs every name is of the closed
+    vocabulary, and a child span (a seam inside a phase that holds more
+    than one job) lies inside a span of its parent's name on the same
+    line, with its parent's `seq`."""
+    import jax
+
+    made = []
+    real = PROFILER.span_factory
+
+    def factory(name, **stats):
+        made.append(name)
+        return real(name, **stats)
+
+    eng = _real_engine()
+    try:
+        _prompts(eng, "warm")          # compiles are out of the way
+        with unittest.mock.patch.object(PROFILER, "span_factory", factory):
+            _prompts(eng, "quiet")
+            assert made == [], f"spans with no capture running: {made[:5]}"
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                PROFILER.capturing = True
+                _prompts(eng, "in")
+            finally:
+                PROFILER.capturing = False
+                time.sleep(0.05)       # the span open at the clear closes
+                n_made = len(made)
+                _prompts(eng, "after")
+                jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    assert len(made) == n_made, "spans after the flag was cleared"
+    assert set(made) <= set(SPAN_NAMES), set(made) - set(SPAN_NAMES)
+    children = {p + "." + c: p for p, cs in CHILD_SPANS.items() for c in cs}
+    (spans,) = _mq_lines(str(tmp_path)).values()
+    assert {n for n, *_ in spans} >= set(children), \
+        set(children) - {n for n, *_ in spans}
+    phases = [sp for sp in spans if sp[0] in PHASE_SPANS]
+    prev_end = 0
+    for name, start, end, _ in phases:  # the chain itself stays unnested
+        assert start >= prev_end, f"{name} overlaps another phase span"
+        prev_end = end
+    for name, start, end, st in spans:
+        if name in children:
+            inside = [p for p in phases if p[0] == children[name]
+                      and p[1] <= start and end <= p[2]]
+            assert len(inside) == 1, (name, start, end)
+            assert inside[0][3]["seq"] == st["seq"], (name, st, inside[0])
+    kids = sorted((sp for sp in spans if sp[0] in children),
+                  key=lambda sp: sp[1])
+    for a, b in zip(kids, kids[1:]):   # siblings never overlap
+        assert a[2] <= b[1], (a, b)
 
 
 # ------------------------------------------------------------- the endpoint
@@ -166,7 +261,7 @@ def test_debug_profile_reply_holds_the_captures_own_samples(
     """The call outlasts the capture (stop_trace is slow — here made so)
     while steps keep running: `stepprof` holds only samples that ended
     between start_trace returning and stop_trace being called, `capture`
-    says which, and the cheap capture (`python_tracer: false`) passes
+    says which, and the cheap capture — the default since PR 52 — passes
     ProfileOptions with the Python tracer off."""
     import jax
 
@@ -181,6 +276,12 @@ def test_debug_profile_reply_holds_the_captures_own_samples(
     def slow_stop():
         seen["capturing_at_stop"] = PROFILER.capturing
         time.sleep(0.4)  # steps go on while the trace is written
+        # ... and on the chip for so long (43-51 s for a 5 s capture) that
+        # the sample ring turns over before stop_trace returns: the reply
+        # holds the capture's samples all the same (PR 52).
+        for _ in range(stepprof._RING):
+            PROFILER.start("fake").finish()
+        seen["ring_turned_over"] = min(s["ts"] for s in PROFILER.tail())
         real_stop()
 
     async def traffic(cl, stop):
@@ -198,8 +299,7 @@ def test_debug_profile_reply_holds_the_captures_own_samples(
                 unittest.mock.patch.object(jax.profiler, "stop_trace",
                                            slow_stop):
             t_call = time.time()
-            r = await cl.post("/debug/profile", json={
-                "seconds": 0.3, "python_tracer": False})
+            r = await cl.post("/debug/profile", json={"seconds": 0.3})
             t_reply = time.time()
         stop.set()
         await load
@@ -218,18 +318,38 @@ def test_debug_profile_reply_holds_the_captures_own_samples(
                    for s in got)
         seqs = [s["seq"] for s in got]
         assert (cap["first_seq"], cap["last_seq"]) == (min(seqs), max(seqs))
-        # Steps that ended while stop_trace ran are in the ring, not here.
+        # Steps that ended while stop_trace ran are in the ring, not here
+        # — and by the reply the ring held nothing of the capture any more.
         assert any(cap["stop_epoch"] < s["ts"] <= t_reply
                    for s in PROFILER.tail())
+        assert seen["ring_turned_over"] > cap["stop_epoch"]
         # The spans reached the trace, python tracer or not.
         (spans,) = _mq_lines(str(tmp_path)).values()
         assert {st["seq"] for n, _, _, st in spans
                 if n == "mq.dispatch"} <= set(seqs) | {max(seqs) + 1}
-        # Default: no options at all — the capture as it always was.
-        with unittest.mock.patch.object(jax.profiler, "start_trace", start):
-            r = await cl.post("/debug/profile", json={"seconds": 0.1})
-        assert r.status == 200 and (await r.json())["python_tracer"] is True
-        assert seen["options"] is None
+        # The realtime clock, read before start_trace and again as the one
+        # mq.clock span began: their difference is the span's place on the
+        # trace's clock, which starts with the profiler's session.
+        assert t_call * 1e9 <= cap["origin_epoch_ns"] \
+            <= cap["start_epoch"] * 1e9
+        ((name, start_ns, _, st),) = [
+            sp for line in _mq_lines(str(tmp_path), clock=True).values()
+            for sp in line]
+        assert abs(start_ns - (st["epoch_ns"] - cap["origin_epoch_ns"])) \
+            < 1_000_000, (start_ns, st, cap)
+        # Asked for: no options at all — jax's own default, the capture
+        # as it was before PR 52. And the default says the same as `false`.
+        for body, want in (({"python_tracer": True}, True),
+                           ({"python_tracer": False}, False)):
+            seen.pop("options", None)
+            with unittest.mock.patch.object(jax.profiler, "start_trace",
+                                            start):
+                r = await cl.post("/debug/profile",
+                                  json={"seconds": 0.1, **body})
+            assert r.status == 200
+            assert (await r.json())["python_tracer"] is want
+            assert (seen["options"] is None) is want
+            assert want or seen["options"].python_tracer_level == 0
         r = await cl.post("/debug/profile", json={"python_tracer": "no"})
         assert r.status == 400
 
