@@ -22,7 +22,7 @@ Design notes (TPU-first):
     by index (`scan_layers`). One traced body a DISTINCT layer of a
     period, whatever the depth: fast XLA compile. The per-sequence state —
     the KV pool ([attention layers, S, Hk*hd], the layout the kernels DMA
-    from) and the conv layers' state ([conv layers, slots + 1, K-1, D],
+    from) and the conv layers' state ([conv layers, K-1, slots, D],
     ops/shortconv.py) — is the scan's CARRY, never its xs/ys: a layer
     scatters the step's rows into `pool[a]` / `conv[c]` in place and
     attention reads the whole pool by layer index, so a step moves the
@@ -849,7 +849,7 @@ def forward_ragged(
     interpret: bool = False,
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
     moe_load: bool = False,  # also return the [Le, E] expert loads
-    conv_state=None,  # [Lc, slots+1, K-1, D] or a SlotState (donated; carry)
+    conv_state=None,  # [Lc, K-1, slots, D] or a SlotState (donated; carry)
     slot_ids=None,  # [B] each row's slot: its row of conv_state
     is_first=None,  # [B] the span is its request's first: state opens at 0
     hidden: bool = False,  # also return the last hiddens [T, D], last
@@ -888,6 +888,9 @@ def forward_ragged(
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
     state = split_state(conv_state)
+    if state.conv is not None:  # one plan for every layer with a window
+        conv_plan = shortconv.ragged_plan(
+            state.conv.shape[2], slot_ids, tok_seq, q_start, q_len, is_first)
     if state.ring is not None:  # one table for every window layer
         rows = state.ring.rows
         ring_slots = ring_write_slots(
@@ -927,11 +930,7 @@ def forward_ragged(
 
         def taps_fn(z):  # [1, T, D]
             nonlocal conv
-            rows = jax.lax.dynamic_index_in_dim(
-                conv, ix.op, 0, keepdims=False)[slot_ids]
-            taps, rows = shortconv.taps_ragged(z[0], rows, tok_seq, q_start,
-                                               q_len, is_first)
-            conv = conv.at[ix.op, slot_ids].set(rows)
+            taps, conv = shortconv.taps_ragged(z[0], conv, ix.op, conv_plan)
             return [t[None] for t in taps]
 
         def rule_fn(q, k, v, g, beta):  # [1, T, H, .]
@@ -1071,7 +1070,7 @@ def forward_decode(
     active=None,  # [B] int32/bool — live decode slots (None = all live)
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
     moe_load: bool = False,  # also return the [Le, E] expert loads
-    conv_state=None,  # [Lc, >= B, K-1, D] or a SlotState (donated; loop
+    conv_state=None,  # [Lc, K-1, >= B, D] or a SlotState (donated; loop
     # carry): row b is slot b's (of the rings too)
 ):
     """One decode step for the whole batch (row b is slot b); returns
@@ -1139,12 +1138,7 @@ def forward_decode(
 
         def taps_fn(z):  # [B, 1, D]
             nonlocal conv
-            rows = jax.lax.dynamic_slice_in_dim(
-                jax.lax.dynamic_index_in_dim(conv, ix.op, 0, keepdims=False),
-                0, B, axis=0)
-            taps, rows = shortconv.taps_decode(z[:, 0], rows, active)
-            conv = jax.lax.dynamic_update_slice(
-                conv, rows[None], (ix.op, 0, 0, 0))
+            taps, conv = shortconv.taps_decode(z[:, 0], conv, ix.op, active)
             return [t[:, None] for t in taps]
 
         def rule_fn(q, k, v, g, beta):  # [B, 1, H, .]

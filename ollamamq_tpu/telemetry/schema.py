@@ -264,7 +264,7 @@ HBM_KV_BYTES = REGISTRY.gauge(
 HBM_CONV_STATE_BYTES = REGISTRY.gauge(
     "ollamamq_hbm_conv_state_bytes",
     "Bytes the conv layers' per-slot state occupies per model runtime "
-    "(conv layers x (slots + 1) x (window - 1) x hidden; fixed, whatever "
+    "(conv layers x (window - 1) x slots x hidden; fixed, whatever "
     "the context lengths; 0 for a model without such layers)",
     labels=("model",))
 KV_BYTES_PER_TOKEN = REGISTRY.gauge(

@@ -37,7 +37,13 @@ them row-major (`--default-layouts`: every weight in row-major order) and
 passes is not a chip run, and an op listed here has no time until a trace
 gives it one. One JSON line a program, then one last line with the count.
 `--rehearse` compiles the file's tiny `rehearse` sizes (seconds; sizes no
-copy of 32 MB exists at: give `--min-mb 0`).
+copy of 32 MB exists at: give `--min-mb 0`). `--by-scope` adds to a program's
+line the compiler's own `estimated_cycles` summed by the model's stage
+(`jax.named_scope`: `lin_conv`, `mlp`, ...) a computation — the largest is
+one period of the layers' loop: what the compiler THINKS a stage costs (1.5
+cycles a ns), before any trace; against one it read the hybrid's matmuls
+1.3 x high, its window's gathers 1.2-1.7 x and its slices and in-place updates
+3-4 x (PERF.md section 5, PR 53).
 
 Not on the serving path: nothing imports this module.
 """
@@ -73,6 +79,9 @@ _COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>[\w.\-]+) \(")
 _CALLS = re.compile(r"calls=%([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 MOVES = ("copy", "dynamic-slice", "slice")
+_CYCLES = re.compile(r'"estimated_cycles":"?(\d+)')
+_ANY_INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = .*? ([\w\-]+)\(")
+FREE = ("parameter", "get-tuple-element", "tuple", "bitcast", "constant")
 
 
 def moves(hlo: str, min_bytes: int) -> list:
@@ -158,6 +167,36 @@ def moves(hlo: str, min_bytes: int) -> list:
                     "from_shape": src[0], "from_layout": src[1],
                     "op_name": op_name.group(1) if op_name else ""})
     return found
+
+
+def scope_cycles(hlo: str, scopes, at_least: int = 20_000) -> dict:
+    """{computation: {scope: [estimated cycles, ops, ops the compiler gave
+    no estimate]}} over the device ops of a compiled module's text (not the
+    insides of fusions), an op under the innermost of `scopes` its `op_name`
+    passes through ("-": none); computations of under `at_least` cycles
+    left out."""
+    fused = {c for line in hlo.splitlines() if " fusion(" in line
+             for c in _CALLS.findall(line)}
+    out, at = {}, None
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            at = head["name"]
+            continue
+        m = _ANY_INSTR.match(line)
+        if at is None or at in fused or m is None or m.group(1) in FREE:
+            continue
+        name = _OP_NAME.search(line)
+        inside = [p for p in (name.group(1) if name else "").split("/")
+                  if p in scopes]
+        cycles = _CYCLES.search(line)
+        cell = out.setdefault(at, {}).setdefault(
+            inside[-1] if inside else "-", [0, 0, 0])
+        cell[0] += int(cycles.group(1)) if cycles else 0
+        cell[1] += 1
+        cell[2] += cycles is None
+    return {c: by for c, by in out.items()
+            if sum(v[0] for v in by.values()) >= at_least}
 
 
 def weight_copies(found: list, params) -> list:
@@ -275,12 +314,15 @@ def main(argv=None) -> int:
     ap.add_argument("--default-layouts", action="store_true",
                     help="every weight row-major, not as served")
     ap.add_argument("--dump", help="write each program's compiled HLO here")
+    ap.add_argument("--by-scope", action="store_true",
+                    help="add the compiler's estimated cycles by stage")
     args = ap.parse_args(argv)
 
     import jax
 
     from benchmarks import serve
     from ollamamq_tpu import cli
+    from ollamamq_tpu.models import llama, moe
 
     with open(args.config) as f:
         cfg = json.load(f)
@@ -303,10 +345,13 @@ def main(argv=None) -> int:
         found = moves(hlo, int(args.min_mb * 2 ** 20))
         weights = weight_copies(found, params)
         n_weight += len(weights)
-        print(json.dumps({"config": cfg["name"], "program": name,
-                          "moves": found,
-                          "weight_copies": weights}),
-              flush=True)
+        line = {"config": cfg["name"], "program": name, "moves": found,
+                "weight_copies": weights}
+        if args.by_scope:
+            line["scope_cycles"] = scope_cycles(hlo, (
+                *llama.SCOPES, *llama.GATE_SCOPES, *llama.MTP_SCOPES,
+                *llama.CONV_SCOPES, *llama.LINEAR_SCOPES, *moe.SCOPES))
+        print(json.dumps(line), flush=True)
     print(json.dumps({"config": cfg["name"], "programs": len(lowered),
                       "weight_copies": n_weight}))
     return 0

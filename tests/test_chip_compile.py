@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from ollamamq_tpu.config import ModelConfig
+from ollamamq_tpu.config import LINEAR, ModelConfig
 from ollamamq_tpu.engine.engine import select_attn_impl
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.attention import (paged_decode_attention_any,
@@ -454,7 +454,7 @@ def test_lfm2_width_step_programs_carry_pool_and_conv_state_in_place(
     assert "ragged-dot" not in text
     assert text.count("tpu_custom_call") >= 3 * 4 + 1
     mem = compiled.memory_analysis()
-    conv_state = 7 * (B + 1) * 2 * 2048 * 2
+    conv_state = 7 * 2 * B * 2048 * 2  # a tap a plane, no trash row
     assert carried >= 2 * 2 * NP * PS * 512 * 2 + conv_state
     assert mem.alias_size_in_bytes >= carried, (mem, carried)
     assert mem.temp_size_in_bytes < 32 * 2048 * 1792 * 2 // 4, mem
@@ -479,7 +479,7 @@ def test_olmo_hybrid_width_step_programs_carry_the_rule_state_in_place(
     assert text.count("tpu_custom_call") >= 1 + 3  # attention, 3 linear layers
     mem = compiled.memory_analysis()
     rule = 6 * (B + 1) * 96 * 30 * 192 * 4
-    window = 6 * (B + 1) * 3 * 11520 * 2
+    window = 6 * 3 * B * 11520 * 2  # a tap a plane, no trash row
     assert carried >= 2 * 2 * NP * PS * 3840 * 2 + rule + window
     assert mem.alias_size_in_bytes >= carried, (mem, carried)
     assert mem.temp_size_in_bytes < rule // 10, mem
@@ -686,6 +686,20 @@ def _step_hlo_copies(capsys, name, *flags):
     return programs, re_laid
 
 
+def _file_model(name):
+    """(`benchmarks/configs/<name>.json` as a dict, its ModelConfig at the
+    published widths); `benchmarks` is on the path once `_step_hlo_copies`
+    has imported the script."""
+    import json
+
+    from benchmarks import serve
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    return cfg, serve.model_config(cfg, False)
+
+
 @pytest.mark.parametrize("held", [False, True],
                          ids=["row_major", "as_served"])
 def test_no_step_program_re_lays_a_latent_stack(v5e, capsys, held):
@@ -733,16 +747,47 @@ def test_no_step_program_re_lays_wq_or_wk(v5e, capsys, name, held):
         for p in programs:  # either program, each stack
             assert qk <= {n for c in p["weight_copies"]
                           for n in c["stacks"]}, p["weight_copies"]
-    # and what the rule names for the file's model (`benchmarks` is on the
-    # path since the script was imported)
-    import json
-
-    from benchmarks import serve
-
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                           "configs", name + ".json")) as f:
-        mc = serve.model_config(json.load(f), False)
+    # and what the rule names for the file's model
+    _, mc = _file_model(name)
     shapes = jax.eval_shape(
         lambda: llama.init_params(mc, jax.random.PRNGKey(0)))
     assert set(llama.weight_formats(mc, shapes)) \
         == (qk if name.startswith("qwen") else set())
+
+
+@pytest.mark.parametrize("name", [
+    "olmo-hybrid-7b-d16", "lfm2-8b-a1b-d18", "qwen3-next-80b-a3b-ep4-d12"])
+def test_no_step_program_copies_the_conv_window(v5e, capsys, name):
+    """The three configuration files whose models keep a convolution window
+    (PR 53), at PUBLISHED widths and a 64-token ragged step (their `rehearse`
+    sizes list no program here: PERF.md section 7; ~35 s a file): the window
+    is stored a tap a plane, [layers, K-1, slots, D], and neither step
+    program holds a `copy` of its shape, whole or a layer's — stored a slot
+    a sliver, Olmo-Hybrid's ragged step opened and closed with a copy of all
+    54 MB and its decode scan re-laid a layer's 4.4 MB twice a layer. (An
+    in-place `dynamic-update-slice` fusion keeps the window's shape for its
+    result and is no copy; the one READ of a layer's planes is a
+    `dynamic-slice`.) And by the compiler's own estimate (`--by-scope`) a
+    linear layer's `lin_conv` stage — 4.4 MB of window — costs under two
+    thirds of its `lin_in`, which streams 132 MB of weights: it was costed
+    ABOVE it."""
+    programs, _ = _step_hlo_copies(capsys, name, "--tokens", "64",
+                                   "--min-mb", "0.25", "--by-scope")
+    assert [p["program"] for p in programs] \
+        == ["mq_ragged_step", "mq_decode_scan"]
+    cfg, mc = _file_model(name)
+    slots = int(cfg["server_flags"][cfg["server_flags"].index("--max-slots")
+                                    + 1])
+    window = llama.split_state(jax.eval_shape(
+        lambda: llama.alloc_slot_state(mc, slots))).conv.shape
+    assert window[1:3] == (mc.state_window[0] - 1, slots), window
+    for p in programs:
+        copies = [m for m in p["moves"] if m["moves"] == "copy"
+                  and tuple(d for d in m["dims"] if d != 1)
+                  in (tuple(window), tuple(window[1:]))]
+        assert not copies, (p["program"], copies)
+        if mc.count(LINEAR):  # the computation of a period of the layers
+            period = max(p["scope_cycles"].values(),
+                         key=lambda by: by.get("lin_in", [0])[0])
+            assert 0 < period["lin_conv"][0] * 1.5 < period["lin_in"][0], \
+                (p["program"], period)

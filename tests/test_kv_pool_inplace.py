@@ -173,7 +173,7 @@ def test_a_hybrid_step_writes_its_rows_of_pool_and_conv_state_only():
     conv = jnp.asarray(rng.normal(size=shortconv.alloc_state(
         n_conv, n_slots, cfg.conv_L_cache, cfg.hidden_size).shape),
         jnp.float32)
-    assert conv.shape == (n_conv, n_slots + 1, 2, cfg.hidden_size)
+    assert conv.shape == (n_conv, 2, n_slots, cfg.hidden_size)
     ws = np.asarray([PT[s][p // PS] * PS + p % PS
                      for s, p in zip(TOK_SEQ, TOK_POS)], np.int32)
     tokens = rng.integers(1, cfg.vocab_size, size=10).astype(np.int32)
@@ -190,10 +190,12 @@ def test_a_hybrid_step_writes_its_rows_of_pool_and_conv_state_only():
         assert (after[~written] == before[~written]).all()
         assert (after[written] != before[written]).all()
     before, after = np.asarray(conv), np.asarray(conv2)
-    assert (after[:, [0, 2, 4]] == before[:, [0, 2, 4]]).all()
-    assert (after[:, 3] != before[:, 3]).all()       # a span of 9: both rows
-    # a span of ONE token: the old last row moved up, the new z behind it
-    assert (after[:, 1, 0] == before[:, 1, 1]).all()
+    # [conv layers, K-1, slots, D]: a tap a plane, a slot a row of it; the
+    # padding row's slot, n_slots, is none of them
+    assert (after[:, :, [0, 2, 4]] == before[:, :, [0, 2, 4]]).all()
+    assert (after[:, :, 3] != before[:, :, 3]).all()  # a span of 9: both taps
+    # a span of ONE token: the old last tap moved up, the new z behind it
+    assert (after[:, 0, 1] == before[:, 1, 1]).all()
     assert (after[:, 1, 1] != before[:, 1, 1]).all()
 
     active = jnp.asarray([0, 1, 0, 1, 0], jnp.int32)
@@ -204,10 +206,10 @@ def test_a_hybrid_step_writes_its_rows_of_pool_and_conv_state_only():
         jnp.asarray([0, 13, 0, 20, 0], jnp.int32), kc2, vc2, pt5, PS,
         active=active, conv_state=conv2)
     last = np.asarray(conv3)
-    assert (last[:, [0, 2, 4, 5]] == after[:, [0, 2, 4, 5]]).all()
+    assert (last[:, :, [0, 2, 4]] == after[:, :, [0, 2, 4]]).all()
     for slot in (1, 3):
-        assert (last[:, slot, 0] == after[:, slot, 1]).all()
-        assert (last[:, slot, 1] != after[:, slot, 1]).all()
+        assert (last[:, 0, slot] == after[:, 1, slot]).all()
+        assert (last[:, 1, slot] != after[:, 1, slot]).all()
 
 
 @pytest.mark.parametrize("kind", ["bf16", "int8"])

@@ -221,7 +221,7 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(chunks):
         at += n
         close(got[1], ref[at - 1])
     # slot 1 holds the state; the other slots kept the earlier request's
-    assert bool(jnp.all(st[2][:, [0, 2, 3]] == 3.0))
+    assert bool(jnp.all(st[2][:, :, [0, 2, 3]] == 3.0))
     got, _ = decode_scan(LFM2, params, st, {1: (toks[23:], 23)}, active=[1])
     close(got[1], ref[23:])
 
@@ -286,9 +286,9 @@ def test_the_fused_scan_beside_inactive_and_mid_prefill_slots():
     close(got[0], ref[0][10:])
     close(got[3], ref[3][2:])
     after = np.asarray(st[2])
-    assert (after[:, 1] == before[:, 1]).all()      # mid-prefill: kept
-    assert (after[:, 2] == 5.0).all()               # idle: kept
-    assert (after[:, 0] != before[:, 0]).any()      # live: rolled
+    assert (after[:, :, 1] == before[:, :, 1]).all()  # mid-prefill: kept
+    assert (after[:, :, 2] == 5.0).all()              # idle: kept
+    assert (after[:, :, 0] != before[:, :, 0]).any()  # live: rolled
     got, _, _ = ragged_step(LFM2, params, st, [(1, seqs[1][9:], 9)])
     close(got[1], ref[1][20])
 
@@ -415,7 +415,7 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert piped == settled
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(hybrid)
-    assert rt.slot_state.shape == (6, 5, 2, 64) and rt.conv_state_bytes > 0
+    assert rt.slot_state.shape == (6, 2, 4, 64) and rt.conv_state_bytes > 0
     # every launched step says what it did with the conv state, and
     # uploads ONE packed array
     assert all(s["h2d_transfers"] == 1 for s in samples)
@@ -530,6 +530,6 @@ def test_gauges_size_a_deployment():
     # K and V of 3 attention layers of 2 x 16 lanes, float32 here
     assert gauge(tm.KV_BYTES_PER_TOKEN, "test-tiny-lfm2") == 2 * 3 * 32 * 4
     assert gauge(tm.HBM_CONV_STATE_BYTES, "test-tiny-lfm2") \
-        == 6 * 5 * 2 * 64 * 4
+        == 6 * 2 * 4 * 64 * 4  # layers x taps x SLOTS (no trash row) x D
     assert gauge(tm.KV_BYTES_PER_TOKEN, "test-tiny") == 2 * 2 * 32 * 4
     assert gauge(tm.HBM_CONV_STATE_BYTES, "test-tiny") == 0

@@ -54,6 +54,14 @@ def state(mc, dtype, garbage=0.0, pages=NP):
         lambda a: a + jnp.asarray(garbage, a.dtype), st)
 
 
+def by_slot(slot_state):
+    """(window, rule state) as numpy, the slot axis at 1: the window is
+    stored a tap a plane, [layers, K-1, slots, D] (no trash row), the rule's
+    state [layers, slots + 1, ...]."""
+    return (np.asarray(slot_state.conv).swapaxes(1, 2),
+            np.asarray(slot_state.rule))
+
+
 def want(mc, params, tokens):
     """The reference's ONE full forward: [T, V] logits."""
     return np.asarray(olmo_hybrid_reference().logits(
@@ -218,6 +226,115 @@ def test_a_ragged_stream_continues_each_rows_own_state():
     assert bool(jnp.all(new[1, jnp.array([4, 6])] == state0[1, jnp.array([4, 6])]))
 
 
+# ------------------------ the window: a tap a plane, by slot, in place
+# A case is a list of steps over 4 slots: ("ragged", {slot: span length}) —
+# rows shuffled against slots, a padding row (slot 4: past the last) and
+# padding tokens behind the stream — or ("decode", active slots or None for
+# no mask). A slot's first span opens its request; ("reuse", slot) starts
+# another request on it.
+WINDOW_CASES = {
+    "decode_then_ragged_then_decode": [
+        ("ragged", {0: 4, 1: 5, 2: 3, 3: 6}), ("decode", None),
+        ("decode", None), ("ragged", {0: 2, 1: 1, 2: 3, 3: 2}),
+        ("decode", None), ("decode", None)],
+    "active_mask": [
+        ("ragged", {0: 4, 2: 3}), ("decode", [0]), ("decode", [0]),
+        ("ragged", {2: 3}), ("decode", [0, 2]), ("decode", [2])],
+    "spans_shorter_than_the_window": [
+        ("ragged", {0: 1, 1: 2, 3: 1}), ("ragged", {0: 1, 1: 1, 3: 2}),
+        ("decode", [0, 1, 3]), ("ragged", {0: 1, 3: 1}),
+        ("ragged", {0: 2, 1: 1})],
+    "first_spans_over_an_earlier_requests_state": [
+        ("ragged", {0: 1, 1: 2, 2: 5, 3: 3}), ("decode", None),
+        ("reuse", 1), ("reuse", 2), ("ragged", {0: 2, 1: 1, 2: 4}),
+        ("decode", [0, 1, 2])],
+    "a_row_without_tokens": [
+        ("ragged", {0: 3, 1: 4, 2: 2}), ("ragged", {0: 2, 1: 0, 2: 1}),
+        ("decode", [0, 2]), ("ragged", {0: 0, 1: 3, 2: 0})],
+}
+
+
+@pytest.mark.parametrize("n_prev", [2, 3])
+@pytest.mark.parametrize("case", WINDOW_CASES.values(),
+                         ids=WINDOW_CASES.keys())
+def test_the_window_by_slot_is_taps_full_of_the_whole_sequence(case, n_prev):
+    """ops/shortconv.py's two step schedules over a carried state [layers,
+    K-1, slots, D]: every token's taps are `taps_full`'s of its whole
+    sequence, the planes afterwards hold each served slot's last K-1
+    positions, and a slot no row serves (or an inactive one) and the other
+    layer's planes are bit-identical after a step."""
+    from ollamamq_tpu.ops import shortconv
+
+    S, D, P, layer = 4, 8, 16, 1
+    rng = np.random.default_rng(n_prev)
+    seqs = {}  # (slot, request) -> z [P, D] and its taps_full
+
+    def seq(slot, request):
+        if (slot, request) not in seqs:
+            z = jnp.asarray(rng.normal(size=(1, P, D)), jnp.float32)
+            seqs[slot, request] = (np.asarray(z[0]), [
+                np.asarray(t[0]) for t in shortconv.taps_full(z, n_prev + 1)])
+        return seqs[slot, request]
+
+    conv = shortconv.alloc_state(2, S, n_prev + 1, D, jnp.float32) + 7.0
+    assert conv.shape == (2, n_prev, S, D)
+    request, pos = [0] * S, [0] * S
+    for kind, arg in case:
+        if kind == "reuse":
+            request[arg], pos[arg] = request[arg] + 1, 0
+            continue
+        before = np.asarray(conv)
+        if kind == "decode":
+            live = list(range(S)) if arg is None else arg
+            z = np.stack([seq(s, request[s])[0][pos[s]] for s in range(S)])
+            active = None if arg is None else jnp.asarray(
+                [int(s in arg) for s in range(S)], jnp.int32)
+            taps, conv = shortconv.taps_decode(jnp.asarray(z), conv, layer,
+                                               active)
+            read = {s: [(s, pos[s])] for s in live}  # slot: (token, position)
+        else:
+            order = list(reversed(sorted(arg)))  # row r serves order[r]
+            T = sum(arg.values()) + 3
+            z = np.full((T, D), 9.0, np.float32)
+            slot_ids = np.asarray(order + [S], np.int32)
+            q_start = np.full(len(order) + 1, T, np.int32)
+            q_len, first = (np.zeros(len(order) + 1, np.int32)
+                            for _ in range(2))
+            tok_seq = np.full(T, len(order), np.int32)
+            read, at = {}, 0
+            for row, s in enumerate(order):
+                n = arg[s]
+                q_start[row], q_len[row], first[row] = at, n, pos[s] == 0
+                z[at:at + n] = seq(s, request[s])[0][pos[s]:pos[s] + n]
+                tok_seq[at:at + n] = row
+                read[s] = [(at + i, pos[s] + i) for i in range(n)]
+                at += n
+            live = [s for s in order if arg[s]]
+            taps, conv = shortconv.taps_ragged(
+                jnp.asarray(z), conv, layer, shortconv.ragged_plan(
+                    S, *(jnp.asarray(a) for a in (
+                        slot_ids, tok_seq, q_start, q_len, first))))
+        after = np.asarray(conv)
+        assert len(taps) == n_prev
+        for s, tokens in read.items():
+            want = seq(s, request[s])[1]
+            for j, tap in enumerate(taps):
+                for token, p in tokens:
+                    assert (np.asarray(tap)[token] == want[j][p]).all(), (
+                        kind, s, j, p)
+            pos[s] += len(tokens)
+        assert (after[0] == before[0]).all()  # the other layer's planes
+        for s in range(S):
+            if s not in live:
+                assert (after[1, :, s] == before[1, :, s]).all(), (kind, s)
+                continue
+            zs = seq(s, request[s])[0]
+            for i in range(n_prev):  # plane i: position pos - (K-1) + i
+                p = pos[s] - n_prev + i
+                assert (after[1, i, s] == (zs[p] if p >= 0 else 0)).all(), (
+                    kind, s, i)
+
+
 # --------------------------------------- logits, against the reference
 CHUNKINGS = {
     "two_halves": (11, 12),
@@ -243,7 +360,7 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(chunks):
         at += n
         close(got[1], ref[at - 1])
     # slot 1 holds the state; the other slots kept the earlier request's
-    for arr in st[2]:
+    for arr in by_slot(st[2]):
         assert bool(jnp.all(arr[:, jnp.array([0, 2, 3])] == 3.0))
     got, _ = decode_scan(OLMO, params, st, {1: (toks[23:], 23)}, active=[1])
     close(got[1], ref[23:])
@@ -319,13 +436,13 @@ def test_the_fused_scan_beside_inactive_and_mid_prefill_slots():
     st = state(OLMO, jnp.float32, garbage=5.0)
     _, st, _ = ragged_step(OLMO, params, st, [
         (0, seqs[0][:10], 0), (1, seqs[1][:9], 0), (3, seqs[3][:2], 0)])
-    before = jax.tree_util.tree_map(np.asarray, st[2])
+    before = by_slot(st[2])
     got, st = decode_scan(OLMO, params, st, {0: (seqs[0][10:], 10),
                                              3: (seqs[3][2:], 2)},
                           active=[0, 3])
     close(got[0], ref[0][10:])
     close(got[3], ref[3][2:])
-    for was, arr in zip(before, jax.tree_util.tree_map(np.asarray, st[2])):
+    for was, arr in zip(before, by_slot(st[2])):
         assert (arr[:, 1] == was[:, 1]).all()      # mid-prefill: kept
         assert (arr[:, 2] == 5.0).all()            # idle: kept
         assert (arr[:, 0] != was[:, 0]).any()      # live: advanced
@@ -466,7 +583,7 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(hybrid)
     # the state is a pytree of two arrays: the window and the rule's
-    assert rt.slot_state.conv.shape == (8, 5, 3, 2 * 32 + 64)
+    assert rt.slot_state.conv.shape == (8, 3, 4, 2 * 32 + 64)
     assert rt.slot_state.rule.shape == (8, 5, DK, H * DV)
     assert rt.slot_state.rule.dtype == jnp.float32
     assert rt.lin_state_bytes == 8 * 5 * DK * H * DV * 4
@@ -574,7 +691,7 @@ def test_gauges_and_counters_size_a_deployment(monkeypatch):
     # K and V of 2 attention layers of 4 x 16 lanes, float32 here
     assert value(tm.KV_BYTES_PER_TOKEN, NAME) == 2 * 2 * 64 * 4
     assert value(tm.HBM_LIN_STATE_BYTES, NAME) == 8 * 5 * DK * H * DV * 4
-    assert value(tm.HBM_CONV_STATE_BYTES, NAME) == 8 * 5 * 3 * 128 * 4
+    assert value(tm.HBM_CONV_STATE_BYTES, NAME) == 8 * 3 * 4 * 128 * 4
     assert value(tm.HBM_LIN_STATE_BYTES, "test-tiny") == 0
     before = [value(c, NAME) for c in (
         tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,

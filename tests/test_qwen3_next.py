@@ -25,7 +25,7 @@ from ollamamq_tpu.config import (ATTENTION, LINEAR, MODEL_CONFIGS,
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.sampling import SamplingParams
 from test_lfm2 import ATOL, close, decode_scan, ragged_step, seq_tokens
-from test_olmo_hybrid import state
+from test_olmo_hybrid import by_slot, state
 from test_step_overlap import _engine, _prompt, _rt, drive
 from testutil import qwen3_next_keys, qwen3_next_reference
 
@@ -137,7 +137,7 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(chunks):
         at += n
         close(got[1], ref[at - 1])
         assert load.shape == (8, 8)  # expert layers x experts HELD
-    for arr in st[2]:  # the other slots kept the earlier request's state
+    for arr in by_slot(st[2]):  # the other slots kept the earlier request's
         assert bool(jnp.all(arr[:, jnp.array([0, 2, 3])] == 3.0))
     got, _ = decode_scan(QN, params, st, {1: (toks[23:], 23)}, active=[1])
     close(got[1], ref[23:])
@@ -184,7 +184,7 @@ def test_the_served_tree_is_the_files_arithmetic():
     assert "10,846,170,624 B" in cfg["arithmetic"]
     st = jax.eval_shape(lambda: llama.alloc_slot_state(mc, 16))
     assert st.rule.shape == (9, 17, 128, 4096) and st.rule.dtype == jnp.float32
-    assert st.conv.shape == (9, 17, 3, 8192)
+    assert st.conv.shape == (9, 3, 16, 8192)
     assert mc.kv_row_dims == (512, 512) and mc.cache_layers == 3
 
 
