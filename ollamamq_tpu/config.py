@@ -28,10 +28,20 @@ from typing import Optional
 # A layer's operator (the published `layer_types` spellings) and its FFN.
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
 WINDOW = "sliding_attention"
-LAYER_KINDS = (ATTENTION, CONV, LINEAR, WINDOW)
+# Attention AND a state-space mixer (Mamba-2 / SSD) in ONE layer, both over
+# the same normed input, both added to the residual (Falcon-H1). This repo's
+# naming: the published config.json has no `layer_types`; a model whose
+# `mamba_d_ssm` is set has this kind in every layer.
+PARALLEL = "attention_ssm"
+LAYER_KINDS = (ATTENTION, CONV, LINEAR, WINDOW, PARALLEL)
 # The kinds whose layers keep a fixed-size state a SLOT beside the paged pool
-# (a window layer's: a ring of its last K and V rows).
-STATE_KINDS = (CONV, LINEAR, WINDOW)
+# (a window layer's: a ring of its last K and V rows; a parallel layer's: the
+# mixer's convolution window and float32 recurrent state — its K and V live
+# in the paged pool as a full-attention layer's do).
+STATE_KINDS = (CONV, LINEAR, WINDOW, PARALLEL)
+# The kinds whose K and V rows live in the paged pool: a layer of the pools
+# each, in layer order.
+PAGED_KINDS = (ATTENTION, PARALLEL)
 # The kinds that are attention over K and V: they share the attention
 # weights' stacks (`wq` ... one entry a layer of EITHER kind, in layer order).
 ATTENTION_KINDS = (ATTENTION, WINDOW)
@@ -263,6 +273,51 @@ class ModelConfig:
     # 0 (no module) or 1, and 1 with latent attention (no indexer) and
     # experts only.
     num_nextn_predict_layers: int = 0
+    # -- attention and a state-space mixer in every layer (Falcon-H1) --------
+    # `mamba_d_ssm` > 0 makes every layer PARALLEL: x + Attn(h m_in) m_out +
+    # SSM(h) m_ssm over ONE normed h, then the MLP. The mixer is Mamba-2's
+    # (SSD; ops/ssd.py): [z | x | B | C | dt] = (h ssm_in_multiplier) W_in,
+    # each segment times its entry of `ssm_multipliers`; x | B | C through a
+    # depthwise causal convolution of `mamba_d_conv` taps with a bias and a
+    # SiLU; per head (`mamba_n_heads` of `mamba_d_head`; B and C in
+    # `mamba_n_groups` groups of `mamba_d_state`) a float32 state S
+    # [d_head, d_state] a sequence, S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T,
+    # y_t = S_t C_t + D x_t; y * silu(z) through an RMSNorm over each group's
+    # channels, W_out. The published spellings (a configuration file's keys
+    # reach the field of their name); the keys the program implements at one
+    # value only are refused by name in `_check_ssm`.
+    mamba_d_ssm: int = 0
+    mamba_d_state: int = 0
+    mamba_d_head: int = 0
+    mamba_n_heads: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2  # read, not used: `mamba_d_ssm` says the width
+    mamba_chunk_size: int = 128  # read, not used: the program's chunk is its own
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    mamba_rms_norm: bool = True
+    mamba_norm_before_gate: bool = False
+    mamba_use_mlp: bool = True
+    attn_layer_indices: Optional[tuple] = None
+    mlp_expansion_factor: int = 0  # read, not used: `intermediate_size` says it
+    projectors_bias: bool = False
+    mlp_bias: bool = False
+    num_logits_to_keep: int = 1
+    # The family's muP multipliers, applied in the forward where the published
+    # code applies them (never folded into a stored tensor): on the embedding,
+    # the logits, the attention branch's input and output, k, the mixer's
+    # input and output, the five segments of its in-projection (z, x, B, C,
+    # dt) and the MLP's gate and output. 1 everywhere else.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0,) * 5
+    mlp_multipliers: tuple = (1.0, 1.0)
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head", "full"):
@@ -313,6 +368,7 @@ class ModelConfig:
                     f"{LINEAR!r}: the per-slot conv window has one width")
             if LINEAR in kinds:
                 self._check_linear()
+        self._check_ssm()
         if self.first_k_dense_replace is not None:
             if self.num_dense_layers not in (0, self.first_k_dense_replace):
                 raise ValueError(
@@ -598,12 +654,75 @@ class ModelConfig:
                 f"{self.name}: linear_conv_kernel_dim must be at least 2, "
                 f"got {self.linear_conv_kernel_dim}")
 
+    def _check_ssm(self) -> None:
+        """The state-space mixer's keys: the lists made hashable, a value the
+        program does not implement refused by the key's name, the sizes held
+        to each other."""
+        for key, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
+            value = tuple(getattr(self, key))  # a file's list: hashable
+            object.__setattr__(self, key, value)
+            if len(value) != n:
+                raise ValueError(
+                    f"{self.name}: {key} holds {len(value)} scalars, the "
+                    f"program applies {n}")
+        if self.attn_layer_indices is not None:
+            raise ValueError(
+                f"{self.name}: attn_layer_indices "
+                f"{list(self.attn_layer_indices)}: the program implements "
+                "only null (every layer attends)")
+        for key, only in (("mamba_proj_bias", False),
+                          ("projectors_bias", False), ("mlp_bias", False),
+                          ("mamba_rms_norm", True),
+                          ("mamba_norm_before_gate", False),
+                          ("mamba_use_mlp", True), ("num_logits_to_keep", 1)):
+            if getattr(self, key) != only:
+                raise ValueError(
+                    f"{self.name}: {key} {getattr(self, key)}: the program "
+                    f"implements only {only}")
+        parallel = self.layer_types is not None \
+            and PARALLEL in self.layer_types
+        if not self.mamba_d_ssm:
+            if parallel:
+                raise ValueError(
+                    f"{self.name}: layer_types holds {PARALLEL!r} and "
+                    "mamba_d_ssm is 0: the mixer has no width")
+            return
+        if self.layer_types is not None \
+                and set(self.layer_types) != {PARALLEL}:
+            raise ValueError(
+                f"{self.name}: mamba_d_ssm {self.mamba_d_ssm} with "
+                f"layer_types {sorted(set(self.layer_types))}: a model with "
+                f"a state-space mixer has {PARALLEL!r} in every layer")
+        if self.mamba_n_heads * self.mamba_d_head != self.mamba_d_ssm:
+            raise ValueError(
+                f"{self.name}: mamba_n_heads {self.mamba_n_heads} x "
+                f"mamba_d_head {self.mamba_d_head} is not mamba_d_ssm "
+                f"{self.mamba_d_ssm}")
+        if self.mamba_d_state < 1 or self.mamba_n_groups < 1 \
+                or self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError(
+                f"{self.name}: mamba_d_state {self.mamba_d_state} and "
+                f"mamba_n_groups {self.mamba_n_groups} must be at least 1, "
+                f"and mamba_n_heads {self.mamba_n_heads} a multiple of the "
+                "groups: a group of B and C serves a whole number of heads")
+        if self.mamba_d_conv < 2:
+            raise ValueError(
+                f"{self.name}: mamba_d_conv must be at least 2, got "
+                f"{self.mamba_d_conv}")
+        if self.is_encoder or self.num_experts or self.kv_lora_rank \
+                or self.norm_order != "pre" or self.sandwich_norm:
+            raise ValueError(
+                f"{self.name}: mamba_d_ssm {self.mamba_d_ssm}: the parallel "
+                "layer is served as a causal pre-norm block with K/V "
+                "attention and a dense MLP only")
+
     # -- the stack, layer by layer ------------------------------------------
     @property
     def kinds(self) -> tuple:
-        """Each layer's (operator, FFN): (ATTENTION | CONV | LINEAR | WINDOW,
-        DENSE | EXPERTS)."""
-        ops = self.layer_types or (ATTENTION,) * self.num_layers
+        """Each layer's (operator, FFN): (ATTENTION | CONV | LINEAR | WINDOW |
+        PARALLEL, DENSE | EXPERTS)."""
+        ops = self.layer_types or (
+            PARALLEL if self.mamba_d_ssm else ATTENTION,) * self.num_layers
         first_sparse = self.num_dense_layers if self.num_experts \
             else self.num_layers
         return tuple((op, DENSE if i < first_sparse else EXPERTS)
@@ -615,9 +734,15 @@ class ModelConfig:
 
     @property
     def attn_layers(self) -> int:
-        """Layers that are attention over K and V, window or full: the
-        entries of the attention weights' stacks."""
-        return sum(self.count(kind) for kind in ATTENTION_KINDS)
+        """Layers that hold attention over K and V — window, full, or beside
+        a state-space mixer: the entries of the attention weights' stacks."""
+        return sum(self.count(kind) for kind in ATTENTION_KINDS) \
+            + self.count(PARALLEL)
+
+    @property
+    def paged_layers(self) -> int:
+        """Layers whose K and V rows live in the paged pool (PAGED_KINDS)."""
+        return sum(self.count(kind) for kind in PAGED_KINDS)
 
     def rotates(self, kind: str) -> bool:
         """Do q and k of an attention layer of `kind` take RoPE?"""
@@ -671,7 +796,7 @@ class ModelConfig:
         """Layers of the paged pools: the FULL attention layers (a window
         layer's rows live in its slots' rings), and behind them the
         prediction module's block (its own cache rows)."""
-        return self.count(ATTENTION) + self.num_nextn_predict_layers
+        return self.paged_layers + self.num_nextn_predict_layers
 
     @property
     def kv_row_dims(self) -> tuple:
@@ -744,10 +869,24 @@ class ModelConfig:
         return 2 * self.linear_key_dim + self.linear_value_dim
 
     @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the state-space mixer's convolution: x | B | C."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """Lanes of the mixer's published in-projection: z | x | B | C | dt
+        (the last `mamba_n_heads` of them are held as a stack of their
+        own: models/llama.py:_ssm_op)."""
+        return self.mamba_d_ssm + self.ssm_conv_dim + self.mamba_n_heads
+
+    @property
     def state_window(self) -> tuple:
         """(taps, channels) of the per-slot conv window of this model's
-        conv or linear-attention layers (config refuses a stack with
-        both)."""
+        conv, linear-attention or parallel layers (config refuses a stack
+        with two of them)."""
+        if self.count(PARALLEL):
+            return self.mamba_d_conv, self.ssm_conv_dim
         if self.count(LINEAR):
             return self.linear_conv_kernel_dim, self.linear_conv_dim
         return self.conv_L_cache, self.hidden_size
@@ -786,6 +925,12 @@ class ModelConfig:
                      + 2 * self.linear_num_value_heads
                      + self.linear_value_head_dim
                      + self.linear_value_dim * d),
+            # attention, and beside it the mixer: in, the taps and their
+            # bias, A_log, D and dt_bias a head, the grouped norm, out.
+            PARALLEL: (attention + d * self.ssm_in_dim
+                       + self.ssm_conv_dim * (self.mamba_d_conv + 1)
+                       + 3 * self.mamba_n_heads + self.mamba_d_ssm
+                       + self.mamba_d_ssm * d),
         }
         n_experts = self.num_experts_per_tok if active else self.num_experts
         per_ffn = {
@@ -1035,6 +1180,42 @@ MODEL_CONFIGS = {
         moe_intermediate_size=32, first_k_dense_replace=1,
         scoring_func="sigmoid", use_expert_bias=True, norm_topk_prob=True,
         norm_topk_eps=1e-20, routed_scaling_factor=2.5,
+    ),
+    # Falcon-H1-34B (tiiuae/Falcon-H1-34B-Instruct config.json): every layer
+    # runs GQA attention (20 heads of 128 over 4 K/V heads: 2560 lanes under
+    # a hidden size of 5120, RoPE at theta 1e11) AND a Mamba-2 mixer (32
+    # heads of 128 under 2 groups of B/C of 256; a convolution of 4 taps with
+    # a bias over 5120 channels; a gated RMSNorm over the groups) on one
+    # normed input, then a SwiGLU MLP; the family's muP multipliers; a
+    # vocabulary of 261,120; untied head.
+    "falcon-h1:34b": ModelConfig(
+        name="falcon-h1:34b", vocab_size=261_120, hidden_size=5120,
+        intermediate_size=21_504, num_layers=72, num_heads=20, num_kv_heads=4,
+        head_dim=128, rope_theta=1e11, rms_norm_eps=1e-5,
+        max_seq_len=262_144, mamba_d_ssm=4096, mamba_d_state=256,
+        mamba_d_head=128, mamba_n_heads=32, mamba_n_groups=2, mamba_d_conv=4,
+        embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ),
+    # Tiny Falcon-H1: 4 mixer heads of 16 under 2 groups of B/C of 8, a q
+    # width (4 x 16) that is not the hidden size, every multiplier away
+    # from 1 (scalars of the published sizes' order).
+    "test-tiny-falcon-h1": ModelConfig(
+        name="test-tiny-falcon-h1", vocab_size=512, hidden_size=96,
+        intermediate_size=128, num_layers=3, num_heads=4, num_kv_heads=2,
+        head_dim=16, rope_theta=1e11, rms_norm_eps=1e-5, max_seq_len=512,
+        mamba_d_ssm=64, mamba_d_state=8, mamba_d_head=16, mamba_n_heads=4,
+        mamba_n_groups=2, mamba_d_conv=4, embedding_multiplier=5.65,
+        lm_head_multiplier=0.0078125, attention_in_multiplier=1.0,
+        attention_out_multiplier=0.0375, key_multiplier=0.011,
+        ssm_in_multiplier=0.25, ssm_out_multiplier=0.088,
+        ssm_multipliers=(0.354, 0.25, 0.177, 0.5, 0.354),
+        mlp_multipliers=(0.177, 0.0112),
     ),
     # Tiny DeepSeek-V3.2: latent attention with the indexer's selection (top
     # 16: well under the tests' contexts), YaRN, a dense layer then expert
@@ -1534,22 +1715,27 @@ def validate_tiers(spec: Optional[str], members) -> Optional[str]:
 
 
 def validate_slot_state(cfg: ModelConfig, spec: bool = False,
-                        mesh_shape=None,
-                        kv_dtype: str = "bfloat16") -> Optional[str]:
+                        mesh_shape=None, kv_dtype: str = "bfloat16",
+                        prefix_cache: bool = False) -> Optional[str]:
     """What a model with ANY per-slot state (conv layers' windows, the
     linear-attention layers' matrices, the window layers' K/V rings:
     STATE_KINDS) cannot be served with yet, told BEFORE any device work:
     returns an error string (None = valid). Each of these touches
     per-sequence state and knows only the paged KV pool; run on such a
     model it would serve K and V without the state beside them (ROADMAP
-    B-M5 names what each lacks; B-M2 for the window layers' rings)."""
+    B-M5 names what each lacks; B-M2 for the window layers' rings). A
+    model with parallel layers is refused the prefix cache and int8 pages
+    too: its attention reads the pool, but a shared or re-scaled page says
+    nothing of the mixer's state at its boundary."""
     held = [kind for kind in STATE_KINDS if cfg.count(kind)]
     if not held:
         return None
     shape = dict(mesh_shape or {})
     ring = cfg.count(WINDOW) > 0
     why = None
-    if spec and held == [WINDOW]:
+    if held == [PARALLEL]:
+        why = _parallel_refusal(spec, shape, kv_dtype, prefix_cache)
+    elif spec and held == [WINDOW]:
         why = ("--spec: a verify span writes the window layers' rings, and "
                "neither the draft cap nor the rollback has been written "
                "for them (ROADMAP B-M2)")
@@ -1567,6 +1753,27 @@ def validate_slot_state(cfg: ModelConfig, spec: bool = False,
         return None
     return (f"model {cfg.name} has {' and '.join(held)} layers "
             f"(layer_types) and cannot be served with {why}")
+
+
+def _parallel_refusal(spec: bool, shape: dict, kv_dtype: str,
+                      prefix_cache: bool) -> Optional[str]:
+    """`validate_slot_state`'s line for a model whose layers run attention
+    beside a state-space mixer (None: it can be served so)."""
+    if spec:
+        return ("--spec: a rejected draft has already advanced the mixer's "
+                "convolution window and recurrent state, and rollback "
+                "restores pages only (ROADMAP B-M5)")
+    if shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
+        return ("--tp / --ep: the mixer's weights (heads and B/C groups) and "
+                "its per-slot state have no partition specs (ROADMAP B-M5)")
+    if kv_dtype != "bfloat16":
+        return ("--kv-dtype int8: the layer's K/V pages could be scaled, "
+                "the mixer's float32 state beside them has no such form "
+                "and the pair has not been measured (ROADMAP B-M5)")
+    if prefix_cache:
+        return ("--prefix-cache: a cached page holds K and V of its tokens, "
+                "not the mixer's state at its boundary (ROADMAP B-M5)")
+    return None
 
 
 def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",

@@ -42,7 +42,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, LINEAR, WINDOW,
+from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, LINEAR, PARALLEL,
+                                 WINDOW,
                                  STATE_KINDS, EngineConfig, ModelConfig,
                                  get_model_config, smart_match,
                                  validate_latent_pool, validate_quant_config,
@@ -546,7 +547,8 @@ class ModelRuntime:
         err = validate_slot_state(
             model_cfg, spec=engine_cfg.spec,
             mesh_shape=dict(mesh.shape) if mesh is not None else {},
-            kv_dtype=engine_cfg.kv_dtype)
+            kv_dtype=engine_cfg.kv_dtype,
+            prefix_cache=engine_cfg.prefix_cache)
         if err is not None:
             raise ValueError(err)
         err = validate_latent_pool(
@@ -921,10 +923,15 @@ class ModelRuntime:
         # What a deployment is sized by: the fixed per-slot state, and
         # what each token of context adds to the pool.
         conv, rule, ring = llama.split_state(self.slot_state)
-        self.conv_state_bytes, self.lin_state_bytes, self.ring_bytes = (
-            0 if a is None else int(a.nbytes) for a in (conv, rule, ring))
+        ssm = None  # a mixer's state rides where the rule's does
+        if isinstance(self.slot_state, llama.SsmState):
+            rule, ssm = None, rule
+        (self.conv_state_bytes, self.lin_state_bytes, self.ring_bytes,
+         self.ssm_state_bytes) = (0 if a is None else int(a.nbytes)
+                                  for a in (conv, rule, ring, ssm))
         tm.HBM_CONV_STATE_BYTES.labels(model=name).set(self.conv_state_bytes)
         tm.HBM_LIN_STATE_BYTES.labels(model=name).set(self.lin_state_bytes)
+        tm.HBM_SSM_STATE_BYTES.labels(model=name).set(self.ssm_state_bytes)
         tm.HBM_SWA_RING_BYTES.labels(model=name).set(self.ring_bytes)
         self._tm_swa = [c.labels(model=name) for c in (
             tm.SWA_PAIRS_TOTAL, tm.SWA_CTX_ROWS_TOTAL,
@@ -939,11 +946,13 @@ class ModelRuntime:
                      self.kv_bytes / 1e6, model_cfg.cache_layers)
         elif self.slot_state is not None:
             log.info("%s: per-slot state %.1f MB (conv window %.1f MB, rule "
-                     "state %.1f MB, float32) for %d slots beside the KV "
-                     "pool's %.1f MB", name,
-                     (self.conv_state_bytes + self.lin_state_bytes) / 1e6,
+                     "state %.1f MB, mixer state %.1f MB, float32) for %d "
+                     "slots beside the KV pool's %.1f MB", name,
+                     (self.conv_state_bytes + self.lin_state_bytes
+                      + self.ssm_state_bytes) / 1e6,
                      self.conv_state_bytes / 1e6, self.lin_state_bytes / 1e6,
-                     engine_cfg.max_slots, self.kv_bytes / 1e6)
+                     self.ssm_state_bytes / 1e6, engine_cfg.max_slots,
+                     self.kv_bytes / 1e6)
         tm.KV_BYTES_PER_TOKEN.labels(model=name).set(
             kvc.kv_page_bytes(model_cfg, 1, jnp.dtype(dtype).itemsize,
                               engine_cfg.kv_dtype))
@@ -952,6 +961,9 @@ class ModelRuntime:
         self._tm_lin = [c.labels(model=name) for c in (
             tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
             tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL)]
+        self._tm_ssm = [c.labels(model=name) for c in (
+            tm.SSM_STATE_RESETS_TOTAL, tm.SSM_STATE_CARRIED_TOTAL,
+            tm.SSM_STEP_ROWS_TOTAL, tm.SSM_SPAN_TOKENS_TOTAL)]
         # Latent attention: the two pools' sizes (both are in kv_bytes) and
         # what the indexer scored and attention saw.
         if model_cfg.kv_lora_rank:
@@ -1354,17 +1366,22 @@ class ModelRuntime:
         says how the rule ran: `lin_step_rows` (row-passes through the one-
         token form: a ragged step's 1-token rows, a scan's active slots x
         its passes) and `lin_span_tokens` (tokens of longer spans, through
-        the chunked form). Nothing for a model with neither."""
+        the chunked form) — as `ssm_*`, the same four, for one whose layers
+        run a state-space mixer. Nothing for a model with none of them."""
         if self.cfg.count(CONV):
             _sp.note(conv_state_resets=resets, conv_state_carried=carried)
             self._tm_conv_resets.inc(resets)
             self._tm_conv_carried.inc(carried)
-        if self.cfg.count(LINEAR):
-            counts = (resets, carried, step_rows, span_tokens)
-            _sp.note(lin_state_resets=resets, lin_state_carried=carried,
-                     lin_step_rows=step_rows, lin_span_tokens=span_tokens)
-            for series, n in zip(self._tm_lin, counts):
-                series.inc(n)
+        counts = (resets, carried, step_rows, span_tokens)
+        for kind, prefix, series in ((LINEAR, "lin", self._tm_lin),
+                                     (PARALLEL, "ssm", self._tm_ssm)):
+            if self.cfg.count(kind):
+                _sp.note(**dict(zip((f"{prefix}_state_resets",
+                                     f"{prefix}_state_carried",
+                                     f"{prefix}_step_rows",
+                                     f"{prefix}_span_tokens"), counts)))
+                for counter, n in zip(series, counts):
+                    counter.inc(n)
 
     def _note_latent(self, _sp, spans, scan: bool = False,
                      stream_len: int = 0) -> None:
@@ -1433,7 +1450,7 @@ class ModelRuntime:
         and without the kernel). A layer's worth: every attention layer
         does the same. Nothing for an encoder, a model with latent
         attention or one with no attention layer."""
-        if self.cfg.kv_lora_rank or not self.cfg.count(ATTENTION):
+        if self.cfg.kv_lora_rank or not self.cfg.paged_layers:
             return
         n, kv = np.asarray(tokens, np.int64), np.asarray(kv, np.int64)
         pairs = n * (2 * kv - n + 1) // 2  # sum of kv-n+1 .. kv
@@ -1902,7 +1919,7 @@ class ModelRuntime:
         blob = {
             "version": 1, "kind": "stream", "model": self.name,
             "kv_dtype": self.kv_dtype, "page_size": self.ecfg.page_size,
-            "num_layers": self.cfg.count(ATTENTION),
+            "num_layers": self.cfg.paged_layers,
             "num_kv_heads": self.cfg.num_kv_heads,
             "head_dim": self.cfg.head_dim,
             "kv_len": int(self.seq_lens[slot]),
@@ -1952,7 +1969,7 @@ class ModelRuntime:
                 or int(blob.get("page_size", -1)) != self.ecfg.page_size
                 or blob.get("kv_dtype") != self.kv_dtype
                 or int(blob.get("num_layers", -1))
-                != self.cfg.count(ATTENTION)
+                != self.cfg.paged_layers
                 or int(blob.get("num_kv_heads", -1)) != self.cfg.num_kv_heads
                 or int(blob.get("head_dim", -1)) != self.cfg.head_dim):
             return False
@@ -2014,7 +2031,7 @@ class ModelRuntime:
         return {
             "version": 1, "kind": "prefix", "model": self.name,
             "kv_dtype": self.kv_dtype, "page_size": ps,
-            "num_layers": self.cfg.count(ATTENTION),
+            "num_layers": self.cfg.paged_layers,
             "num_kv_heads": self.cfg.num_kv_heads,
             "head_dim": self.cfg.head_dim,
             "n_pages": len(pages),
@@ -2033,7 +2050,7 @@ class ModelRuntime:
                 or int(blob.get("page_size", -1)) != self.ecfg.page_size
                 or blob.get("kv_dtype") != self.kv_dtype
                 or int(blob.get("num_layers", -1))
-                != self.cfg.count(ATTENTION)
+                != self.cfg.paged_layers
                 or int(blob.get("num_kv_heads", -1)) != self.cfg.num_kv_heads
                 or int(blob.get("head_dim", -1)) != self.cfg.head_dim):
             return 0
@@ -3450,6 +3467,7 @@ class ModelRuntime:
             # the per-slot state beside the pool (0 for a model without)
             "conv_state_bytes": self.conv_state_bytes,
             "lin_state_bytes": self.lin_state_bytes,
+            "ssm_state_bytes": self.ssm_state_bytes,
             "swa_ring_bytes": self.ring_bytes,
             "weights_dtype": self.weights_dtype,
             "kv_dtype": self.kv_dtype,
@@ -4833,6 +4851,7 @@ class TPUEngine:
                      "slot_state_bytes": int(
                          getattr(rt, "conv_state_bytes", 0)
                          + getattr(rt, "lin_state_bytes", 0)
+                         + getattr(rt, "ssm_state_bytes", 0)
                          + getattr(rt, "ring_bytes", 0))}
             alloc = getattr(rt, "alloc", None)
             if alloc is not None:
@@ -5168,7 +5187,8 @@ class TPUEngine:
         chips = self.chip_stats()
         hbm_used = sum(c["hbm_used"] for c in chips) or sum(
             r["param_bytes"] + r["kv_bytes"] + r.get("conv_state_bytes", 0)
-            + r.get("lin_state_bytes", 0) for r in runtime_stats)
+            + r.get("lin_state_bytes", 0) + r.get("ssm_state_bytes", 0)
+            for r in runtime_stats)
         hbm_total = sum(c["hbm_total"] for c in chips) or None
         return {
             "runtimes": runtime_stats,

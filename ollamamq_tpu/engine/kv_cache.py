@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ollamamq_tpu.config import ATTENTION, EngineConfig, ModelConfig
+from ollamamq_tpu.config import EngineConfig, ModelConfig
 
 TRASH_PAGE = 0
 
@@ -348,5 +348,5 @@ def kv_page_bytes(model_cfg: ModelConfig, page_size: int,
                 * sum(model_cfg.kv_row_dims) * bytes_per_el)
     per_tok_head = (model_cfg.head_dim + 4 if kv_dtype == "int8"
                     else model_cfg.head_dim * bytes_per_el)
-    return (2 * model_cfg.count(ATTENTION) * page_size
+    return (2 * model_cfg.paged_layers * page_size
             * model_cfg.num_kv_heads * per_tok_head)

@@ -1,5 +1,5 @@
 """Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2 / Olmo-Hybrid /
-Qwen3-Next / K-EXAONE) in pure functional JAX.
+Qwen3-Next / K-EXAONE / Falcon-H1) in pure functional JAX.
 
 A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))` — or, with
 `norm_order` "post", `x + norm(Op(x))`, `x + norm(FFN(x))`, or with
@@ -7,7 +7,9 @@ A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))` — or, with
 (`_attention_op`: over the whole context, or — a `sliding_attention` layer
 — over the last `sliding_window` positions, its K and V in a per-slot ring
 beside the pool), a gated short convolution (`_conv_op`) or gated
-delta-rule linear attention (`_linear_attention_op`); FFN is a dense
+delta-rule linear attention (`_linear_attention_op`) — or, a `PARALLEL`
+layer (Falcon-H1), attention AND a state-space mixer (`_ssm_op`) over the
+same normed input, both added to the residual; FFN is a dense
 SwiGLU (`_mlp`) or routed experts (models/moe.py). Each is defined ONCE and
 used by every forward; `ModelConfig.kinds` says which pair a layer is. A
 uniform stack (every family but the hybrids) is the case of one kind.
@@ -53,10 +55,11 @@ import jax
 import jax.numpy as jnp
 
 from ollamamq_tpu.config import (ATTENTION, ATTENTION_KINDS, CONV, DENSE,
-                                 EXPERTS, LINEAR, WINDOW, ModelConfig)
+                                 EXPERTS, LINEAR, PARALLEL, WINDOW,
+                                 ModelConfig)
 from ollamamq_tpu.models.moe import (SHARED, SHARED_GATE, STACKED,
                                      init_moe_layer_params, moe_mlp)
-from ollamamq_tpu.ops import gated_delta, mla, shortconv
+from ollamamq_tpu.ops import gated_delta, mla, shortconv, ssd
 from ollamamq_tpu.ops.attention import (
     WindowRing,
     alloc_ring,
@@ -94,12 +97,23 @@ CONV_SCOPES = ("conv_in", "conv_mix", "conv_out")
 # convolution over q | k | v with its window, the delta rule with its state,
 # the gated output norm and out-projection.
 LINEAR_SCOPES = ("lin_in", "lin_conv", "lin_rule", "lin_out")
+# ...and a state-space mixer's four, beside the attention's four inside a
+# parallel layer: the in-projection with its multipliers, the convolution
+# over x | B | C with its window, the recurrence with its state, the gated
+# grouped norm and out-projection.
+SSM_SCOPES = ("ssm_in", "ssm_conv", "ssm_scan", "ssm_out")
 # fold_in constant of the conv layers' init keys (the other weights' keys
 # are the ten of one split, as before the family existed).
 CONV_KEY = 0x636F6E76
 LINEAR_KEY = 0x6C696E72
 MLA_KEY = 0x6D6C6174
 GATED_KEY = 0x67617465
+SSM_KEY = 0x73736D78
+# Seeded random init of what a mixer holds beside its matrices, drawn AWAY
+# from the identity so that a forward which leaves one out computes another
+# model: the skip D around 1, the gated norm's weight around 1, the
+# convolution's bias around 0.
+SSM_D_SD, SSM_NORM_SD, SSM_CONV_BIAS_SD = 0.25, 0.1, 0.25
 # Standard deviation of a seeded-random ZERO-CENTRED norm weight (float32
 # draw, as moe.ROUTER_BIAS_SD): a stored zero is the identity whether a
 # forward multiplies by w or by 1 + w, so the weights are drawn away from it.
@@ -128,6 +142,8 @@ KIND_PARAMS = {
     CONV: ("conv_in", "conv_w", "conv_out"),
     LINEAR: ("lin_in", "lin_ba", "lin_conv_w", "lin_A_log", "lin_dt_bias",
              "lin_norm", "lin_out"),
+    PARALLEL: ("ssm_in", "ssm_dt", "ssm_conv_w", "ssm_conv_b", "ssm_A_log",
+               "ssm_D", "ssm_dt_bias", "ssm_norm", "ssm_out"),
     DENSE: ("w_gate", "w_up", "w_down"),
     EXPERTS: ("w_router", "router_bias") + SHARED + SHARED_GATE + STACKED,
 }
@@ -167,11 +183,19 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     n_mtp = cfg.num_nextn_predict_layers
     L, v = cfg.num_layers + n_mtp, cfg.vocab_size
     La, Lc, Ld = cfg.attn_layers + n_mtp, cfg.count(CONV), cfg.count(DENSE)
-    Ll = cfg.count(LINEAR)
+    Ll, Ls = cfg.count(LINEAR), cfg.count(PARALLEL)
     keys = jax.random.split(key, 10)
 
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dtype)
+    def w(k, shape, fan_in, over=1.0):
+        """N(0, 1 / fan_in) — divided by `over`, the muP scalar(s) a forward
+        multiplies the tensor's result by (Falcon-H1; 1 elsewhere: nothing
+        is traced): multiplier x tensor then has the plain tensor's scale,
+        which is what the published multipliers are for; the checkpoint's
+        own init scales are not in config.json."""
+        x = jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)
+        if not isinstance(over, jax.Array) and over == 1:
+            return x.astype(dtype)  # (no fourth float32 copy of a stack)
+        return (x / over).astype(dtype)
 
     gk = iter(jax.random.split(jax.random.fold_in(key, GATED_KEY), 8))
 
@@ -211,8 +235,11 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
                 idx_ww=w(mk[7], (La, d, Hi), d))
     elif La:
         layers.update(
-            wq=w(keys[0], (La, d, qd), d), wk=w(keys[1], (La, d, kvd), d),
-            wv=w(keys[2], (La, d, kvd), d), wo=w(keys[3], (La, qd, d), qd))
+            wq=w(keys[0], (La, d, qd), d, cfg.attention_in_multiplier),
+            wk=w(keys[1], (La, d, kvd), d,
+                 cfg.attention_in_multiplier * cfg.key_multiplier),
+            wv=w(keys[2], (La, d, kvd), d, cfg.attention_in_multiplier),
+            wo=w(keys[3], (La, qd, d), qd, cfg.attention_out_multiplier))
         if cfg.attn_output_gate:  # a gate a head, beside q
             layers["wq_gate"] = w(next(gk), (La, d, qd), d)
         if cfg.attn_bias:
@@ -257,19 +284,50 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
             lin_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
             lin_norm=jnp.ones((Ll, cfg.linear_value_head_dim), dtype),
             lin_out=w(lk[5], (Ll, vd, d), vd))
+    if Ls:
+        # State-space mixer (Mamba-2): in-projection to [z | x | B | C] and,
+        # a stack of its own, to dt (the published matrix's last
+        # `mamba_n_heads` columns: `_ssm_op` says why they are held apart),
+        # the depthwise taps [x | B | C channels, K] and their bias, per
+        # head the decay's A_log, the skip D and dt_bias (float32: they sit
+        # inside an exp or beside the float32 state; the delta rule's init
+        # ranges), the gated norm's weight over d_ssm, the out-projection.
+        sk = jax.random.split(jax.random.fold_in(key, SSM_KEY), 8)
+        di, cd, H = cfg.mamba_d_ssm, cfg.ssm_conv_dim, cfg.mamba_n_heads
+        K = cfg.mamba_d_conv
+        dt = jnp.exp(jax.random.uniform(
+            sk[4], (Ls, H), jnp.float32, *map(jnp.log, LINEAR_DT_RANGE)))
+        layers.update(
+            ssm_in=w(sk[0], (Ls, d, cfg.ssm_in_dim - H), d,
+                     cfg.ssm_in_multiplier * _ssm_segments(cfg, jnp.float32)),
+            ssm_dt=w(jax.random.fold_in(sk[0], 1), (Ls, d, H), d,
+                     cfg.ssm_in_multiplier * cfg.ssm_multipliers[4]),
+            ssm_conv_w=w(sk[1], (Ls, cd, K), K),
+            ssm_A_log=jnp.log(jax.random.uniform(
+                sk[3], (Ls, H), jnp.float32, *LINEAR_A_RANGE)),
+            ssm_D=1.0 + SSM_D_SD * jax.random.normal(sk[5], (Ls, H),
+                                                     jnp.float32),
+            ssm_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            ssm_norm=(1.0 + SSM_NORM_SD * jax.random.normal(
+                sk[6], (Ls, di), jnp.float32)).astype(dtype),
+            ssm_out=w(sk[7], (Ls, di, d), di, cfg.ssm_out_multiplier))
+        if cfg.mamba_conv_bias:
+            layers["ssm_conv_b"] = (SSM_CONV_BIAS_SD * jax.random.normal(
+                sk[2], (Ls, cd), jnp.float32)).astype(dtype)
     if Ld:
         layers.update(
-            w_gate=w(keys[4], (Ld, d, f), d), w_up=w(keys[5], (Ld, d, f), d),
-            w_down=w(keys[6], (Ld, f, d), f))
+            w_gate=w(keys[4], (Ld, d, f), d, cfg.mlp_multipliers[0]),
+            w_up=w(keys[5], (Ld, d, f), d),
+            w_down=w(keys[6], (Ld, f, d), f, cfg.mlp_multipliers[1]))
     if cfg.count(EXPERTS):
         layers.update(init_moe_layer_params(cfg, keys[9], dtype))
     params = {
-        "embed": w(keys[7], (v, d), d),
+        "embed": w(keys[7], (v, d), d, cfg.embedding_multiplier),
         "final_norm": norm_w((d,)),
         "layers": layers,
     }
     if not cfg.tie_embeddings and not cfg.is_encoder:
-        params["lm_head"] = w(keys[8], (v, d), d)
+        params["lm_head"] = w(keys[8], (v, d), d, cfg.lm_head_multiplier)
     if n_mtp:
         # u = [enorm(Emb(next token)) | hnorm(hidden)] W_eh; the module's
         # own norm before the (trunk's) head.
@@ -279,6 +337,25 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
                           2 * d),
             mtp_norm=jnp.ones((d,), dtype))
     return params
+
+
+def _ssm_segments(cfg: ModelConfig, dtype):
+    """`ssm_multipliers` along the lanes of `ssm_in`: one scalar on each of
+    its four segments [z | x | B | C] (the published `mup_vector`, whose
+    fifth scalar meets dt)."""
+    gs = cfg.mamba_n_groups * cfg.mamba_d_state
+    widths = (cfg.mamba_d_ssm, cfg.mamba_d_ssm, gs, gs)
+    return jnp.concatenate([jnp.full((n,), m, dtype) for n, m in
+                            zip(widths, cfg.ssm_multipliers)])
+
+
+def _embed(params: dict, cfg: ModelConfig, tokens: jnp.ndarray):
+    """The tokens' embeddings in the activation dtype, times the family's
+    `embedding_multiplier` where it has one."""
+    x = embed_lookup(params["embed"], tokens, _adtype(params))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
 
 
 def splits_heads_at_once(cfg: ModelConfig) -> bool:
@@ -297,6 +374,8 @@ def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     q = qeinsum("btd,de->bte", h, lp["wq"])
     k = qeinsum("btd,de->bte", h, lp["wk"])
     v = qeinsum("btd,de->bte", h, lp["wv"])
+    if cfg.key_multiplier != 1.0:
+        k = k * cfg.key_multiplier
     if cfg.attn_bias:
         q = q + lp["bq"]
         k = k + lp["bk"]
@@ -315,10 +394,15 @@ def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
     return q, k, v
 
 
-def _mlp(lp: dict, h: jnp.ndarray) -> jnp.ndarray:
+def _mlp(lp: dict, h: jnp.ndarray, multipliers=(1.0, 1.0)) -> jnp.ndarray:
+    """SwiGLU; `multipliers` (Falcon-H1's `mlp_multipliers`) scale the
+    gate's pre-activation and the output."""
     gate = qeinsum("btd,df->btf", h, lp["w_gate"])
     up = qeinsum("btd,df->btf", h, lp["w_up"])
-    return qeinsum("btf,fd->btd", jax.nn.silu(gate) * up, lp["w_down"])
+    if multipliers[0] != 1.0:
+        gate = gate * multipliers[0]
+    out = qeinsum("btf,fd->btd", jax.nn.silu(gate) * up, lp["w_down"])
+    return out if multipliers[1] == 1.0 else out * multipliers[1]
 
 
 def _ffn(cfg: ModelConfig, lp: dict, ffn: str, h: jnp.ndarray, valid=None,
@@ -336,14 +420,17 @@ def _ffn(cfg: ModelConfig, lp: dict, ffn: str, h: jnp.ndarray, valid=None,
     if ffn == EXPERTS:
         return moe_mlp(cfg, lp, h, valid=valid, mesh=mesh, impl=impl,
                        layer=layer)
-    return _mlp(lp, h), None
+    return _mlp(lp, h, cfg.mlp_multipliers), None
 
 
 @jax.named_scope("lm_head")
 def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     x = _norm(cfg, x, params["final_norm"])
     head = params.get("lm_head", params["embed"])
-    return logits_head(x, head)
+    logits = logits_head(x, head)
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
+    return logits
 
 
 class SlotState(NamedTuple):
@@ -365,8 +452,22 @@ class WindowState(NamedTuple):
     ring: Optional[WindowRing]
 
 
-def _as_given(conv, rule, ring):
-    """A forward's `conv_state` in the form the docstrings above name."""
+class SsmState(NamedTuple):
+    """...and of a model whose layers run a state-space mixer beside their
+    attention (PARALLEL): the window of the mixers' convolution and their
+    float32 recurrent state (ops/ssd.py's array; the layers' K and V live
+    in the paged pool). A pytree of its own: the other models' tuples keep
+    their fields."""
+    conv: jnp.ndarray
+    ssm: jnp.ndarray
+
+
+def _as_given(conv, rule, ring, like=None):
+    """A forward's `conv_state` in the form the docstrings above name
+    (`like`: the form it came in, which tells a mixer's state from the
+    rule's — both ride the loop's carry where `split_state` put them)."""
+    if isinstance(like, SsmState):
+        return SsmState(conv, rule)
     if ring is not None:
         return WindowState(conv, rule, ring)
     return conv if rule is None else SlotState(conv, rule)
@@ -378,8 +479,13 @@ def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16,
     `ring_rows`: rows of a slot's ring a window layer
     (`ModelConfig.ring_rows` of the longest span a step writes)."""
     window, width = cfg.state_window
-    conv = shortconv.alloc_state(cfg.count(CONV) + cfg.count(LINEAR),
-                                 max_slots, window, width, dtype)
+    conv = shortconv.alloc_state(
+        cfg.count(CONV) + cfg.count(LINEAR) + cfg.count(PARALLEL),
+        max_slots, window, width, dtype)
+    if cfg.count(PARALLEL):
+        return SsmState(conv, ssd.alloc_state(
+            cfg.count(PARALLEL), max_slots, cfg.mamba_n_heads,
+            cfg.mamba_d_state, cfg.mamba_d_head))
     rule = gated_delta.alloc_state(
         cfg.count(LINEAR), max_slots, cfg.linear_num_value_heads,
         cfg.linear_key_head_dim, cfg.linear_value_head_dim)
@@ -393,10 +499,14 @@ def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16,
 
 
 def split_state(conv_state) -> WindowState:
-    """(conv window, rule state, rings) of a forward's `conv_state`, in any
-    of its four forms; each may be None."""
+    """(conv window, recurrent state, rings) of a forward's `conv_state`, in
+    any of its five forms; each may be None. The recurrent state is the
+    delta rule's or a mixer's (`SsmState`): a model has one of the two, and
+    the layers' loop carries either in the same place."""
     if isinstance(conv_state, WindowState):
         return conv_state
+    if isinstance(conv_state, SsmState):
+        return WindowState(*conv_state, None)
     if isinstance(conv_state, SlotState):
         return WindowState(*conv_state, None)
     return WindowState(conv_state, None, None)
@@ -405,8 +515,10 @@ def split_state(conv_state) -> WindowState:
 class LayerIx(NamedTuple):
     """Where a layer of the scan stands among the layers of its operator's
     kind (an attention layer's row of the KV pool, a conv, linear or window
-    layer's of the per-slot state) and among those of its FFN's kind (an expert layer's block
-    of the expert stacks). int32 scalars, traced inside the scan."""
+    layer's of the per-slot state; a parallel layer's of BOTH — every layer
+    of such a model is one, so the two rows have one index) and among those
+    of its FFN's kind (an expert layer's block of the expert stacks). int32
+    scalars, traced inside the scan."""
     op: jnp.ndarray
     ffn: jnp.ndarray
 
@@ -439,7 +551,8 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
     """
     of_kind = {name: kind for kind, names in KIND_PARAMS.items()
                for name in names}
-    seen = dict.fromkeys((ATTENTION, CONV, LINEAR, WINDOW, DENSE, EXPERTS), 0)
+    seen = dict.fromkeys((ATTENTION, CONV, LINEAR, WINDOW, PARALLEL, DENSE,
+                          EXPERTS), 0)
     windowed = cfg.count(WINDOW) > 0
     loads = []
     for first, period, repeats in cfg.layer_plan():
@@ -456,6 +569,8 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state):
                 # ...and among the weights' stacks: a window layer reads
                 # the attention weights, which count both attention kinds.
                 held = dict(at)
+                if op == PARALLEL:  # ...and a parallel layer both kinds'
+                    held[ATTENTION] = at[op]
                 if windowed and op in ATTENTION_KINDS:
                     held[ATTENTION] = r * sum(
                         per[k] for k in ATTENTION_KINDS) + sum(
@@ -715,10 +830,82 @@ def _linear_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
                        lp["lin_out"])
 
 
+def _gated_group_norm(y, z, w, groups: int, eps: float):
+    """The mixer's output norm (`mamba_rms_norm` with `mamba_norm_before_gate`
+    false): the gate FIRST, g = y * silu(z) in float32, then an RMSNorm over
+    each of the `groups` groups of channels, in z's dtype times the weight."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    g = g.reshape(*g.shape[:-1], groups, -1)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(y.shape).astype(z.dtype) * w
+
+
+def _ssm_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray, taps_fn,
+            ssm_fn) -> jnp.ndarray:
+    """Mamba-2's mixer over normed hiddens h [B, T, D] (Falcon-H1's, with
+    its multipliers where the published code applies them): [z | x | B | C |
+    dt] = ((h ssm_in_multiplier) W_in) * mu, mu one of `ssm_multipliers` a
+    segment — W_in held as TWO stacks, `ssm_in` [D, z | x | B | C] and
+    `ssm_dt` [D, heads]: the whole matrix's 9248 lanes are no whole number
+    of 128-lane tiles, for such a shape the chip's default order is
+    contracted-minor, and the decode scan, which reads it row-major, re-laid
+    all 568 MB of the stack a launch (AOT for a v5e, PR 54); 9216 lanes are
+    72 tiles, and dt — the step of a float32 recurrence — gets a float32
+    result as the delta rule's gates do; x | B | C through the depthwise
+    causal convolution (with its bias) and a SiLU; the recurrence a head
+    (ops/ssd.py; B and C a group) plus the skip D x, float32; g = y * silu(z) through an RMSNorm over each
+    GROUP's channels (gate before norm), times the norm's weight; W_out.
+    Where the convolution's predecessors come from is the caller's
+    `taps_fn` (as a conv layer's), which state the recurrence continues its
+    `ssm_fn(C [B, T, G, ds], B, v [B, T, H, dh], g [B, T, H]) -> y [B, T, H,
+    dh] float32`."""
+    B, T, _ = h.shape
+    H, dh, G, ds = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
+                    cfg.mamba_d_state)
+    di, cd = cfg.mamba_d_ssm, cfg.ssm_conv_dim
+    f32 = jnp.float32
+    with jax.named_scope("ssm_in"):
+        if cfg.ssm_in_multiplier != 1.0:
+            h = h * cfg.ssm_in_multiplier
+        p = qeinsum("btd,de->bte", h, lp["ssm_in"])
+        dt = jnp.einsum("btd,de->bte", h, lp["ssm_dt"],
+                        preferred_element_type=f32)
+        if cfg.ssm_multipliers != (1.0,) * 5:
+            p = p * _ssm_segments(cfg, p.dtype)
+            dt = dt * cfg.ssm_multipliers[4]
+        z, xbc = p[..., :di], p[..., di:]
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(shortconv.short_conv(
+            lp["ssm_conv_w"], taps_fn(xbc), xbc, lp.get("ssm_conv_b")))
+    with jax.named_scope("ssm_scan"):
+        x = xbc[..., :di].reshape(B, T, H, dh)
+        v, g = ssd.inputs(x, dt, lp["ssm_A_log"], lp["ssm_dt_bias"])
+        y = ssm_fn(xbc[..., di + G * ds:].reshape(B, T, G, ds),
+                   xbc[..., di:di + G * ds].reshape(B, T, G, ds), v, g)
+        y = y + lp["ssm_D"].astype(f32)[:, None] * x.astype(f32)
+    with jax.named_scope("ssm_out"):
+        y = _gated_group_norm(y.reshape(B, T, di), z, lp["ssm_norm"], G,
+                              cfg.rms_norm_eps)
+        return qeinsum("bte,ed->btd", y.astype(h.dtype), lp["ssm_out"])
+
+
+def _parallel_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray, positions,
+                 attn_fn, taps_fn, ssm_fn) -> jnp.ndarray:
+    """A parallel layer's mixers over ONE normed h: Attn(h m_in) m_out +
+    SSM(h) m_ssm, the scalars the family's published multipliers."""
+    u = h if cfg.attention_in_multiplier == 1.0 \
+        else h * cfg.attention_in_multiplier
+    attn = _attention_op(cfg, lp, u, positions, attn_fn,
+                         rotate=cfg.rotates(PARALLEL))
+    mix = _ssm_op(cfg, lp, h, taps_fn, ssm_fn)
+    return attn * cfg.attention_out_multiplier \
+        + mix * cfg.ssm_out_multiplier
+
+
 def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
                 x: jnp.ndarray, positions: jnp.ndarray, attn_fn,
                 taps_fn=None, valid=None, mesh=None, impl: str = "jnp",
-                layer=None, rule_fn=None):
+                layer=None, rule_fn=None, ssm_fn=None):
     """One layer over [B, T, D] hiddens: the SINGLE definition of the
     layer math for every forward — full sequences, the ragged stream
     ([1, T, D]) and the decode batch ([B, 1, D]). Only the operator's
@@ -738,6 +925,8 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
         delta = _conv_op(cfg, lp, h, taps_fn)
     elif op == LINEAR:
         delta = _linear_attention_op(cfg, lp, h, taps_fn, rule_fn)
+    elif op == PARALLEL:
+        delta = _parallel_op(cfg, lp, h, positions, attn_fn, taps_fn, ssm_fn)
     elif cfg.kv_lora_rank:
         delta = _latent_attention_op(cfg, lp, h, positions, attn_fn)
     else:  # attention over K and V: the whole context, or a window
@@ -757,12 +946,14 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
 
 
 def _no_state(cfg: ModelConfig, valid=None) -> dict:
-    """taps_fn and rule_fn of a forward over whole sequences from position
-    0: shifted copies, and the chunked rule from an empty state."""
+    """taps_fn, rule_fn and ssm_fn of a forward over whole sequences from
+    position 0: shifted copies, and the chunked recurrence from an empty
+    state."""
     return {
         "taps_fn": lambda z: shortconv.taps_full(z, cfg.state_window[0]),
         "rule_fn": lambda q, k, v, g, beta: gated_delta.chunked(
-            q, k, v, g, beta, valid)[0]}
+            q, k, v, g, beta, valid)[0],
+        "ssm_fn": lambda c, b, v, g: ssd.chunked(c, b, v, g, valid)[0]}
 
 
 def _causal_fn(cfg: ModelConfig, seq_lens, op: str = ATTENTION):
@@ -798,7 +989,7 @@ def forward_prefill(
     the write is fully static-shaped — no dynamic trimming needed.
     """
     B, T = tokens.shape
-    x = embed_lookup(params["embed"], tokens, _adtype(params))
+    x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, T]
 
@@ -883,8 +1074,7 @@ def forward_ragged(
     prediction module reads: `forward_mtp`), last.
     """
     with jax.named_scope("embed"):
-        x = embed_lookup(params["embed"], tokens,
-                         _adtype(params))[None]  # [1,T,D]
+        x = _embed(params, cfg, tokens)[None]  # [1,T,D]
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
     state = split_state(conv_state)
@@ -941,9 +1131,17 @@ def forward_ragged(
                 interpret=interpret)
             return o[None]
 
+        def ssm_fn(c, b, v, g):  # [1, T, G | H, .]; its state rides `rule`
+            nonlocal rule
+            y, rule = ssd.ragged(
+                c[0], b[0], v[0], g[0], rule, ix.op, slot_ids, tok_seq,
+                tok_pos, q_start, q_len, is_first, impl=attn_impl,
+                interpret=interpret)
+            return y[None]
+
         x, load = _layer_step(cfg, lp, kinds, x, positions, attn_fn, taps_fn,
                               valid=valid, mesh=mesh, impl=attn_impl,
-                              layer=ix.ffn, rule_fn=rule_fn)
+                              layer=ix.ffn, rule_fn=rule_fn, ssm_fn=ssm_fn)
         return x, kc, vc, conv, rule, ring, load
 
     x, k_cache, v_cache, conv, rule, ring, load = scan_layers(
@@ -1053,7 +1251,7 @@ def _results(logits, k_cache, v_cache, conv_state, conv, rule, ring, load,
     conv_state' in the form `conv_state` was given in."""
     out = (logits, k_cache, v_cache)
     if conv_state is not None:
-        out += (_as_given(conv, rule, ring),)
+        out += (_as_given(conv, rule, ring, conv_state),)
     return out + (load,) if moe_load else out
 
 
@@ -1085,8 +1283,7 @@ def forward_decode(
     B = tokens.shape[0]
     valid = None if active is None else (active > 0)[:, None]
     with jax.named_scope("embed"):
-        x = embed_lookup(params["embed"], tokens,
-                         _adtype(params))[:, None, :]  # [B,1,D]
+        x = _embed(params, cfg, tokens)[:, None, :]  # [B,1,D]
     pos2 = positions[:, None]  # [B,1]
     write_slots = flat_slot_indices(page_table, pos2, page_size)[:, 0]  # [B]
     seq_lens = positions + 1
@@ -1148,9 +1345,15 @@ def forward_decode(
                 active, impl=attn_impl)
             return o[:, None]
 
+        def ssm_fn(c, b, v, g):  # [B, 1, G | H, .]
+            nonlocal rule
+            y, rule = ssd.decode(c[:, 0], b[:, 0], v[:, 0], g[:, 0], rule,
+                                 ix.op, active, impl=attn_impl)
+            return y[:, None]
+
         x, load = _layer_step(cfg, lp, kinds, x, pos2, attn_fn, taps_fn,
                               valid=valid, mesh=mesh, impl=attn_impl,
-                              layer=ix.ffn, rule_fn=rule_fn)
+                              layer=ix.ffn, rule_fn=rule_fn, ssm_fn=ssm_fn)
         return x, kc, vc, conv, rule, ring, load
 
     x, k_cache, v_cache, conv, rule, ring, load = scan_layers(
@@ -1173,7 +1376,7 @@ def forward_embed(
     backends run for /api/embed on e.g. llama3 (README.md /api/embed row).
     """
     B, T = tokens.shape
-    x = embed_lookup(params["embed"], tokens, _adtype(params))
+    x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
     valid = positions < seq_lens[:, None]

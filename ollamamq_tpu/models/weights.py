@@ -124,6 +124,12 @@ _HF_MOE_LAYOUTS = (
 )
 
 
+# The largest leaf `init_random` draws eagerly (three float32 copies of it
+# exist while it is made): the served dense stacks reach 0.95 G parameters
+# (Qwen2.5-7B's 14 gate matrices).
+EAGER_LEAF_PARAMS = 1 << 30
+
+
 def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
                 mesh=None) -> dict:
     """Seeded random weights. With a `mesh` the tree is generated inside
@@ -138,12 +144,17 @@ def init_random(cfg: ModelConfig, seed: int = 0, dtype=jnp.bfloat16,
     stacks are too large for that — OLMoE's 64 experts x 10 layers are
     1.34 G parameters a stack, 3 x 5.4 GB of float32 — so its tree is
     drawn under one jit, whose fused draw-scale-cast holds no float32
-    stack at all."""
+    stack at all; and so is a dense tree with a leaf of more than
+    EAGER_LEAF_PARAMS (Falcon-H1's embedding and head, 261,120 x 5120 =
+    1.34 G each: drawn eagerly, last, beside 7.8 GB of layers, the first
+    ran the chip out of memory; my chip run, PR 54)."""
     init = functools.partial(llama.init_params, cfg, dtype=dtype)
     key = jax.random.PRNGKey(seed)
-    if mesh is None and not cfg.num_experts:
-        return init(key)
     shapes = jax.eval_shape(init, key)
+    largest = max(x.size for x in jax.tree_util.tree_leaves(shapes))
+    if mesh is None and not cfg.num_experts \
+            and largest <= EAGER_LEAF_PARAMS:
+        return init(key)
     if mesh is None:
         # The stacks held in another device layout than the default
         # (llama.weight_formats) are BORN in it: the draw's own results,

@@ -37,6 +37,14 @@ Schedules (as ops/shortconv.py's):
   - `decode`: one token a slot (the fused scan's body): active slots
     advance, parked slots keep their state.
 
+`plain` (a trace-time switch on `step`, the chunked form and the three
+schedules; False traces exactly what it traced before the switch existed) is
+the same recurrence WITHOUT the correction and the norm: S_t = a_t S_{t-1} +
+k_t (b_t v_t)^T, o_t = S_t^T q_t, q and k as given. That is Mamba-2's SSD
+(ops/ssd.py: k = B, q = C, v = dt x, b = 1), which shares what says which
+tokens continue which state — the windows, the (row, window) loop, the
+active mask, the reset — and drops the chunk's triangular solve (T = I).
+
 State layout: [linear layers, slots + 1, dk, H * dv] float32 — the key
 dimension on sublanes, every head's values side by side on lanes. With
 dv = 192 a [.., H, dk, dv] array would be padded to 256 lanes in HBM (a
@@ -105,19 +113,28 @@ def _heads(state, n_heads: int):
     return state.reshape(*state.shape[:-1], n_heads, -1)
 
 
-def step(state, q, k, v, g, beta, reset=None):
+def _operands(q, k, heads: int, plain: bool):
+    """q, k a value head, float32: L2-normalised — or, `plain`, as given."""
+    q, k = (q.astype(_F32), k.astype(_F32)) if plain else normalise(q, k)
+    return a_value_head(q, k, heads)
+
+
+def step(state, q, k, v, g, beta, reset=None, plain: bool = False):
     """One token a row. state [..., dk, H * dv]; q, k [..., Hk, dk] as the
     convolution left them (Hk key heads, H a multiple of it); v [..., H,
     dv]; g, beta [..., H]; reset [...]: the row's state opens at zero.
     Returns (o [..., H, dv] float32, the state after the token)."""
-    q, k = a_value_head(*normalise(q, k), v.shape[-2])
+    q, k = _operands(q, k, v.shape[-2], plain)
     s = _heads(state, q.shape[-2])
     if reset is not None:
         s = jnp.where(reset[..., None, None, None], 0.0, s)
     kt, qt = jnp.swapaxes(k, -1, -2), jnp.swapaxes(q, -1, -2)  # [..., dk, H]
     s = s * jnp.exp(g)[..., None, :, None]
-    r = beta[..., None] * (v.astype(_F32)
-                           - jnp.sum(s * kt[..., None], axis=-3))
+    if plain:
+        r = beta[..., None] * v.astype(_F32)
+    else:
+        r = beta[..., None] * (v.astype(_F32)
+                               - jnp.sum(s * kt[..., None], axis=-3))
     s = s + kt[..., None] * r[..., None, :, :]
     return jnp.sum(s * qt[..., None], axis=-3), s.reshape(state.shape)
 
@@ -162,7 +179,7 @@ def _tri_inv(a):
     return x[..., 0, :, :]
 
 
-def _prepare(q, k, v, g, beta, same):
+def _prepare(q, k, v, g, beta, same, plain: bool = False):
     """A chunk's tokens solved against each other, for any number of chunks
     at once. q, k [N, C, Hk, dk] (as the convolution left them), v [N, C, H,
     dv], g, beta [N, C, H] (0 on tokens that take no part), same [N, C, C]
@@ -170,14 +187,19 @@ def _prepare(q, k, v, g, beta, same):
     Returns what `_apply` needs of each chunk, heads leading: u [N, H, C,
     dv] and w [N, H, C, dk] (the chunk's corrected values = u - w S for an
     incoming state S), attn [N, H, C, C], qg (q scaled by its decay), k and
-    gc [N, H, C] (each token's log decay since its row entered the chunk)."""
-    q, k = a_value_head(*normalise(q, k), v.shape[2])
+    gc [N, H, C] (each token's log decay since its row entered the chunk).
+    `plain`: no token corrects another, so u = beta v and there is no w."""
+    q, k = _operands(q, k, v.shape[2], plain)
     q, k, v = (jnp.moveaxis(x, 2, 1) for x in (q, k, v.astype(_F32)))
     g, beta = jnp.moveaxis(g, 2, 1), jnp.moveaxis(beta, 2, 1)  # [N, H, C]
     mask = same[:, None]  # [N, 1, C, C]
     gc = _mm("nij,nhj->nhi", same.astype(_F32), g)
     decay = jnp.exp(jnp.where(mask, gc[..., :, None] - gc[..., None, :],
                               -jnp.inf))
+    if plain:
+        return {"u": beta[..., None] * v,
+                "attn": _mm("nhik,nhjk->nhij", q, k) * decay,
+                "qg": q * jnp.exp(gc)[..., None], "k": k, "gc": gc}
     strict = mask & ~jnp.eye(same.shape[-1], dtype=bool)
     kk = _mm("nhik,nhjk->nhij", k, k)
     t = _tri_inv(jnp.where(strict, beta[..., None] * kk * decay, 0.0))
@@ -195,7 +217,9 @@ def _apply(s, c, member):
     (o [..., H, C, dv] — right at the row's tokens only —, the state after
     the row's last token of the chunk)."""
     m = member[..., None, :]  # [..., 1, C]
-    v_new = c["u"] - _mm("...hck,...hkd->...hcd", c["w"], s)
+    v_new = c["u"]
+    if "w" in c:  # the delta rule's correction against the incoming state
+        v_new = v_new - _mm("...hck,...hkd->...hcd", c["w"], s)
     o = _mm("...hck,...hkd->...hcd", c["qg"], s) \
         + _mm("...hij,...hjd->...hid", c["attn"], v_new)
     # gc falls along a row (g <= 0): its least value is the last token's.
@@ -217,7 +241,7 @@ def _from_heads(s):
     return s.reshape(*s.shape[:-2], -1)
 
 
-def chunked(q, k, v, g, beta, valid=None, state=None):
+def chunked(q, k, v, g, beta, valid=None, state=None, plain: bool = False):
     """Whole sequences from an empty (or a given) state. q, k [B, T, Hk,
     dk], v [B, T, H, dv], g, beta [B, T, H]; valid [B, T] bool (padding
     takes no part). Returns (o [B, T, H, dv] float32, state [B, dk, H *
@@ -237,7 +261,7 @@ def chunked(q, k, v, g, beta, valid=None, state=None):
 
     same = jnp.broadcast_to(jnp.tril(jnp.ones((CHUNK, CHUNK), bool)),
                             (b * n, CHUNK, CHUNK))
-    c = _prepare(cut(q), cut(k), cut(v), cut(g), cut(beta), same)
+    c = _prepare(cut(q), cut(k), cut(v), cut(g), cut(beta), same, plain)
     c = {name: jnp.moveaxis(x.reshape(b, n, *x.shape[1:]), 1, 0)
          for name, x in c.items()}  # chunks leading: the scan's xs
     s0 = jnp.zeros((b, h, dk, dv), _F32) if state is None \
@@ -251,9 +275,14 @@ def chunked(q, k, v, g, beta, valid=None, state=None):
 
 # -- the served schedules ----------------------------------------------------
 def _step_rows(impl, state, layer, slots, live, reset, q, k, v, g, beta,
-               interpret=False):
+               interpret=False, plain: bool = False):
     """`step` over rows of `state[layer]` named by `slots`, in place: live
     rows advance, the others keep their state and read zeros."""
+    if impl == "pallas" and plain:  # beta is 1 on every row a kernel sees
+        from ollamamq_tpu.ops.pallas.ssd_step import ssd_step_pallas
+
+        return ssd_step_pallas(state, layer, slots, live, reset, q, k, v, g,
+                               interpret=interpret)
     if impl == "pallas":
         from ollamamq_tpu.ops.pallas.gated_delta_step import (
             gated_delta_step_pallas)
@@ -262,7 +291,7 @@ def _step_rows(impl, state, layer, slots, live, reset, q, k, v, g, beta,
                                        v, g, beta, interpret=interpret)
     rows = jax.lax.dynamic_index_in_dim(state, layer, 0,
                                         keepdims=False)[slots]
-    o, new = step(rows, q, k, v, g, beta, reset)
+    o, new = step(rows, q, k, v, g, beta, reset, plain)
     new = jnp.where(live[:, None, None], new, rows)
     o = jnp.where(live[:, None, None], o, 0.0)
     return o, state.at[layer, slots].set(new)
@@ -288,7 +317,8 @@ def _row_major(x):
 
 
 def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
-           q_start, q_len, is_first, impl: str = "jnp", interpret=False):
+           q_start, q_len, is_first, impl: str = "jnp", interpret=False,
+           plain: bool = False):
     """The flattened stream of a ragged step (see the module docstring).
     q, k [T, Hk, dk], v [T, H, dv], g, beta [T, H]; state the whole carried
     array, `layer` this layer's index in it; slot_ids, q_start, q_len,
@@ -301,7 +331,7 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
     at = jnp.clip(q_start, 0, t - 1)
     o_rows, state = _step_rows(impl, state, layer, slot_ids, single,
                                is_first > 0, q[at], k[at], v[at], g[at],
-                               beta[at], interpret=interpret)
+                               beta[at], interpret=interpret, plain=plain)
     # 2. Longer spans: windows of CHUNK tokens of the stream, all solved at
     # once under the same-row mask; one-token rows and padding take no part.
     n = -(-t // CHUNK)
@@ -318,7 +348,7 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
         & jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
     c = _prepare(cut(q), cut(k), cut(v),
                  cut(jnp.where(part[:, None], g, 0.0)),
-                 cut(jnp.where(part[:, None], beta, 0.0)), same)
+                 cut(jnp.where(part[:, None], beta, 0.0)), same, plain)
     # 3. The (row, window) pairs the spans touch, rows in order and each
     # row's windows in order: pair p is row `b`, its window `w`.
     first_w = q_start // CHUNK
@@ -352,10 +382,12 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
     return o, state
 
 
-def decode(q, k, v, g, beta, state, layer, active=None, impl: str = "jnp"):
+def decode(q, k, v, g, beta, state, layer, active=None, impl: str = "jnp",
+           plain: bool = False):
     """One token a slot: q, k [S, Hk, dk], v [S, H, dv], g, beta [S, H]; row
     s of `state[layer]` is slot s's. Returns (o [S, H, dv], state')."""
     n = q.shape[0]
     live = jnp.ones((n,), bool) if active is None else active > 0
     return _step_rows(impl, state, layer, jnp.arange(n, dtype=jnp.int32),
-                      live, jnp.zeros((n,), bool), q, k, v, g, beta)
+                      live, jnp.zeros((n,), bool), q, k, v, g, beta,
+                      plain=plain)

@@ -8,9 +8,10 @@ import jax.numpy as jnp
 
 
 def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    """Inverse frequencies [head_dim//2], float32."""
+    """Inverse frequencies [head_dim//2], float32. (`theta` as a float: a
+    configuration file's 100000000000 is an int no int32 holds.)"""
     exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponents)
+    return 1.0 / (float(theta) ** exponents)
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,

@@ -52,14 +52,18 @@ import jax
 import jax.numpy as jnp
 
 
-def short_conv(w: jnp.ndarray, taps, z: jnp.ndarray) -> jnp.ndarray:
-    """sum_j w[:, j] * (taps + [z])[j]: `w` [D, K], `taps` the K-1
-    predecessors of `z`, oldest first, each shaped as `z` [..., D].
-    Accumulated in float32, returned in z's dtype."""
+def short_conv(w: jnp.ndarray, taps, z: jnp.ndarray,
+               bias=None) -> jnp.ndarray:
+    """sum_j w[:, j] * (taps + [z])[j] (+ `bias` [D], where the layer has
+    one: a state-space mixer's): `w` [D, K], `taps` the K-1 predecessors of
+    `z`, oldest first, each shaped as `z` [..., D]. Accumulated in float32,
+    returned in z's dtype."""
     wf = w.astype(jnp.float32)
     acc = wf[:, -1] * z.astype(jnp.float32)
     for j, tap in enumerate(taps):
         acc = acc + wf[:, j] * tap.astype(jnp.float32)
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
     return acc.astype(z.dtype)
 
 

@@ -59,7 +59,7 @@ def flops_per_token(cfg, context_len: float = 0.0) -> float:
     dense = 2.0 * active_param_count(cfg)
     # QK^T and attn x V: each 2 x ctx x q_dim MACs = 2 FLOPs, per layer.
     ctx = max(0.0, context_len)
-    attn = 4.0 * cfg.count("full_attention") * ctx * cfg.q_dim
+    attn = 4.0 * cfg.paged_layers * ctx * cfg.q_dim
     # ...and a window layer attends its last `sliding_window` positions.
     attn += 4.0 * cfg.count("sliding_attention") \
         * min(ctx, cfg.sliding_window) * cfg.q_dim

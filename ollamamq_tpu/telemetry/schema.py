@@ -309,6 +309,35 @@ LIN_SPAN_TOKENS_TOTAL = REGISTRY.counter(
     "Tokens of spans longer than one token that went through the delta "
     "rule's chunked form (the state read and written once a 64-token "
     "window a span touches)", labels=("model",))
+HBM_SSM_STATE_BYTES = REGISTRY.gauge(
+    "ollamamq_hbm_ssm_state_bytes",
+    "Bytes the state-space mixers' per-slot recurrent state occupies per "
+    "model runtime (layers x (slots + 1) x state dim x heads x head dim, "
+    "float32; fixed, whatever the context lengths; their convolution's "
+    "window is under ollamamq_hbm_conv_state_bytes and the same layers' K "
+    "and V under ollamamq_hbm_kv_bytes; 0 for a model without mixers)",
+    labels=("model",))
+SSM_STATE_RESETS_TOTAL = REGISTRY.counter(
+    "ollamamq_ssm_state_resets_total",
+    "Rows of launched steps whose slot's mixer state the program opened at "
+    "zero: a request's first span (every admission, every replay)",
+    labels=("model",))
+SSM_STATE_CARRIED_TOTAL = REGISTRY.counter(
+    "ollamamq_ssm_state_carried_total",
+    "Rows of launched steps that continued the mixer state an earlier step "
+    "left in their slot: later chunks of a prompt, decode rows, a fused "
+    "scan's active slots", labels=("model",))
+SSM_STEP_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_ssm_step_rows_total",
+    "Row-passes through the state-space recurrence's one-token form (a "
+    "state row read once and written once a layer): a ragged step's "
+    "1-token rows, a fused scan's active slots x its passes",
+    labels=("model",))
+SSM_SPAN_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_ssm_span_tokens_total",
+    "Tokens of spans longer than one token that went through the "
+    "recurrence's chunked form (the state read and written once a "
+    "64-token window a span touches)", labels=("model",))
 HBM_LATENT_POOL_BYTES = REGISTRY.gauge(
     "ollamamq_hbm_latent_pool_bytes",
     "Bytes the latent pool of a model with latent attention occupies "

@@ -350,7 +350,8 @@ def main(argv=None) -> int:
         if args.by_scope:
             line["scope_cycles"] = scope_cycles(hlo, (
                 *llama.SCOPES, *llama.GATE_SCOPES, *llama.MTP_SCOPES,
-                *llama.CONV_SCOPES, *llama.LINEAR_SCOPES, *moe.SCOPES))
+                *llama.CONV_SCOPES, *llama.LINEAR_SCOPES, *llama.SSM_SCOPES,
+                *moe.SCOPES))
         print(json.dumps(line), flush=True)
     print(json.dumps({"config": cfg["name"], "programs": len(lowered),
                       "weight_copies": n_weight}))

@@ -382,12 +382,14 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
                                    "test-tiny-lfm2",
                                    "test-tiny-olmo-hybrid",
                                    "test-tiny-deepseek-v32",
-                                   "test-tiny-qwen3-next"])
+                                   "test-tiny-qwen3-next",
+                                   "test-tiny-falcon-h1"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
     layers, of llama.CONV_SCOPES beside the attention layers'; with
-    linear-attention layers, of llama.LINEAR_SCOPES; with an attention
+    linear-attention layers, of llama.LINEAR_SCOPES; with a state-space
+    mixer beside the attention, of llama.SSM_SCOPES; with an attention
     output gate or a gated shared expert, of llama.GATE_SCOPES and
     moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES) is in the debug text of
     the engine's OWN ragged and decode programs, the modules are named
@@ -408,6 +410,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     scopes = llama.SCOPES + (moe.SCOPES if rt.cfg.num_experts else ()) \
         + (llama.CONV_SCOPES if rt.cfg.count("conv") else ()) \
         + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention") else ()) \
+        + (llama.SSM_SCOPES if rt.cfg.count("attention_ssm") else ()) \
         + (llama.GATE_SCOPES if rt.cfg.attn_output_gate else ()) \
         + (moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES
            if rt.cfg.shared_expert_gate else ())
@@ -465,7 +468,8 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     from ollamamq_tpu.ops import mla
 
     assert set(llama.SCOPES) | set(llama.CONV_SCOPES) | set(moe.SCOPES) \
-        | set(llama.LINEAR_SCOPES) | set(mla.SCOPES) \
+        | set(llama.LINEAR_SCOPES) | set(llama.SSM_SCOPES) \
+        | set(mla.SCOPES) \
         | set(moe.SHARED_SCOPES) | set(llama.GATE_SCOPES) \
         | set(moe.SHARED_GATE_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented

@@ -177,6 +177,33 @@ def olmo_hybrid_keys(mc) -> dict:
             "tie_word_embeddings": mc.tie_embeddings}
 
 
+def falcon_h1_reference():
+    """...and of the family that runs attention beside a state-space mixer in
+    every layer (benchmarks/reference/falcon_h1_decoder.py)."""
+    return _reference("falcon_h1_decoder")
+
+
+def falcon_h1_keys(mc) -> dict:
+    """What a configuration file says of the parallel-hybrid ModelConfig
+    `mc`, in the published spellings: all that reference reads."""
+    keys = {"num_hidden_layers": mc.num_layers, "hidden_size": mc.hidden_size,
+            "intermediate_size": mc.intermediate_size,
+            "num_attention_heads": mc.num_heads,
+            "num_key_value_heads": mc.num_kv_heads, "head_dim": mc.head_dim,
+            "rms_norm_eps": mc.rms_norm_eps, "rope_theta": mc.rope_theta,
+            "tie_word_embeddings": mc.tie_embeddings,
+            "ssm_multipliers": list(mc.ssm_multipliers),
+            "mlp_multipliers": list(mc.mlp_multipliers)}
+    for name in ("mamba_d_ssm", "mamba_d_state", "mamba_d_head",
+                 "mamba_n_heads", "mamba_n_groups", "mamba_d_conv",
+                 "mamba_conv_bias", "embedding_multiplier",
+                 "lm_head_multiplier", "attention_in_multiplier",
+                 "attention_out_multiplier", "key_multiplier",
+                 "ssm_in_multiplier", "ssm_out_multiplier"):
+        keys[name] = getattr(mc, name)
+    return keys
+
+
 def lfm2_keys(mc) -> dict:
     """What a configuration file says of the hybrid ModelConfig `mc`, in
     the published spellings: all that reference reads."""
