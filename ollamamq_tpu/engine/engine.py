@@ -496,7 +496,7 @@ class ModelRuntime:
     # What `_note_latent` writes onto a step's sample, in order.
     LATENT_FIELDS = ("mla_rows", "dsa_ctx_tokens", "dsa_selected_tokens",
                      "dsa_step_ctx_tokens", "dsa_step_selected_tokens",
-                     "mla_wide_tokens")
+                     "mla_wide_tokens", "mla_absorbed_rows")
     # ...and for latent attention with no indexer (nothing is scored or
     # selected: the `dsa_*` fields have no honest value there).
     DENSE_LATENT_FIELDS = ("mla_rows", "mla_pairs", "mla_ctx_rows")
@@ -756,8 +756,9 @@ class ModelRuntime:
         # serves a whole stretch at a time (`_note_attn`).
         self._tall_tokens = None
         # ...and the masked latent kernel's of which it attends in the
-        # expanded form (`_note_latent`).
-        self._wide_tokens = None
+        # expanded form, and of the rows a layer then takes through the
+        # absorbed form's contractions (`_note_latent`).
+        self._wide_tokens = self._absorbed_rows = None
         if self.attn_impl == "pallas":
             from ollamamq_tpu.ops.pallas.kv_contract import (inner_report,
                                                              tall_tokens)
@@ -765,11 +766,16 @@ class ModelRuntime:
                 model_cfg.num_heads // model_cfg.num_kv_heads)
             self._tall_tokens = tall_tokens
             if model_cfg.index_topk:
-                from ollamamq_tpu.ops.pallas.mla_attention import wide_tokens
-                self._wide_tokens = functools.partial(
-                    wide_tokens, heads=model_cfg.num_heads,
-                    lanes=model_cfg.latent_lanes, rank=model_cfg.kv_lora_rank,
-                    nope=model_cfg.qk_nope_head_dim, v=model_cfg.v_head_dim)
+                from ollamamq_tpu.ops.pallas.mla_attention import (
+                    absorbed_rows, wide_tokens)
+                self._wide_tokens, self._absorbed_rows = (
+                    functools.partial(
+                        fn, heads=model_cfg.num_heads,
+                        lanes=model_cfg.latent_lanes,
+                        rank=model_cfg.kv_lora_rank,
+                        nope=model_cfg.qk_nope_head_dim,
+                        v=model_cfg.v_head_dim)
+                    for fn in (wide_tokens, absorbed_rows))
         log.info("%s: attention=%s (%s)%s", name, self.attn_impl, why,
                  "".join(f" {k}={v}" for k, v in
                          (self.attn_inner or {}).items()))
@@ -981,7 +987,8 @@ class ModelRuntime:
                          else "not run without --spec")
         self._tm_dsa = [c.labels(model=name) for c in (
             tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
-            tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL)]
+            tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL,
+            tm.MLA_ABSORBED_ROWS_TOTAL)]
         self._tm_attn = [c.labels(model=name) for c in (
             tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
             tm.ATTN_TALL_TOKENS_TOTAL)]
@@ -1399,7 +1406,10 @@ class ModelRuntime:
         query of the launch); `mla_wide_tokens` the query tokens the
         attention kernel served in the EXPANDED form (spans of at least
         `mla_attention.WIDE` tokens on a rung that holds that body: the
-        kernel's own test, `wide_tokens`; 0 for a scan and on the jnp path);
+        kernel's own test, `wide_tokens`; 0 for a scan and on the jnp path),
+        and `mla_absorbed_rows` the stream rows a layer then took through
+        W_uk and W_uv (`absorbed_rows`: the lead where every row behind it
+        is a wide span's, else the rung; 0 where nothing is expanded);
         a layer's worth — every layer does the same.
         With NO indexer (every cached position is attended) instead:
         `mla_rows`, `mla_pairs` the causal (query, position) pairs — a
@@ -1418,7 +1428,7 @@ class ModelRuntime:
             _sp.note(**dict(zip(self.DENSE_LATENT_FIELDS, counts.tolist())))
             self._tm_dsa[0].inc(int(counts[0]))
             return
-        counts = np.zeros(6, np.int64)
+        counts = np.zeros(7, np.int64)
         spans = list(spans)
         for n, kv in spans:
             ctx = np.arange(kv - n + 1, kv + 1)
@@ -1428,9 +1438,11 @@ class ModelRuntime:
             if scan or n == 1:
                 counts[3:5] += both
         if self._wide_tokens is not None and not scan:
-            counts[5] = self._wide_tokens([n for n, _ in spans], stream_len)
+            tokens = [n for n, _ in spans]
+            counts[5] = self._wide_tokens(tokens, stream_len)
+            counts[6] = self._absorbed_rows(tokens, stream_len)
         _sp.note(**dict(zip(self.LATENT_FIELDS, counts.tolist())))
-        for series, n in zip(self._tm_dsa, counts[[0, 1, 2, 5]].tolist()):
+        for series, n in zip(self._tm_dsa, counts[[0, 1, 2, 5, 6]].tolist()):
             series.inc(n)
 
     def _note_attn(self, _sp, tokens, kv, scan: bool = False,

@@ -647,7 +647,8 @@ def _latent_rope(cfg: ModelConfig, x: jnp.ndarray, positions) -> jnp.ndarray:
 
 
 def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
-                         positions: jnp.ndarray, attn_fn) -> jnp.ndarray:
+                         positions: jnp.ndarray, attn_fn,
+                         few=None) -> jnp.ndarray:
     """Latent attention with the indexer's selection over normed hiddens h
     [B, T, D] (ops/mla.py has the mathematics): the projections, norms and
     RoPE here, in the ABSORBED form; the schedule (the write of the token's
@@ -661,7 +662,17 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     head)`, and may answer `(o_lat, o_v [B, T, H, dv], wide [B, T] bool)`:
     the tokens of `wide` have their result in `o_v`, through W_uv already
     (a prefill span wide enough to pay for expanding its context's keys and
-    values: ops/pallas/mla_attention.py)."""
+    values: ops/pallas/mla_attention.py).
+    `few` = (R, a scalar bool of the step's own spans), from a schedule
+    whose launch answers so (ops/mla.absorbed_lead; else None): where it
+    holds, every row of the stream from R on is a wide span's or padding —
+    no row's result reads their absorbed q (the tiles skip a wide span's
+    sequence) nor their `o_lat` — and the absorbed form's two contractions a
+    row run over the first R rows alone: q through W_uk in one branch of a
+    conditional, the rest of `q_abs` zeros born in the kernel's layout, and
+    `o_lat` through W_uv R rows a trip of a loop that updates `o_v` in
+    place, ONE trip; where it does not hold, over the rung (the other
+    branch; T / R trips). The same operands and sums a row either way."""
     B, T, _ = h.shape
     H, c = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -669,6 +680,23 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     wukv = lp["mla_wukv"].reshape(c, H, dn + dv)
     index = None  # no indexer: every cached position is attended
     expanded = o_v = None
+    pad = cfg.latent_lanes - cfg.latent_dim
+    lead, few = few or (T, None)
+
+    def absorbed(q, q_rope):
+        # q_nope W_uk^T meets c_kv itself; the softmax scale (and YaRN's
+        # mscale^2) rides q.
+        q_lat = jnp.einsum("bthn,chn->bthc", q[..., :dn], wukv[..., :dn],
+                           preferred_element_type=jnp.float32)
+        return (jnp.concatenate(
+            [q_lat, q_rope.astype(jnp.float32),
+             jnp.zeros(q_lat.shape[:3] + (pad,), jnp.float32)], axis=-1)
+            * cfg.attn_scale).astype(h.dtype)
+
+    def through_w_uv(o_lat):
+        return jnp.einsum("bthc,chv->bthv", o_lat, wukv[..., dn:],
+                          preferred_element_type=jnp.float32).astype(h.dtype)
+
     with jax.named_scope("mla_proj"):
         c_q = rmsnorm(qeinsum("btd,de->bte", h, lp["mla_wdq"]),
                       lp["mla_q_norm"], cfg.rms_norm_eps)
@@ -678,17 +706,24 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
         kv = qeinsum("btd,de->bte", h, lp["mla_wdkv"])
         c_kv = rmsnorm(kv[..., :c], lp["mla_kv_norm"], cfg.rms_norm_eps)
         k_rope = _latent_rope(cfg, kv[..., None, c:], positions)[..., 0, :]
-        pad = cfg.latent_lanes - cfg.latent_dim
         row = jnp.concatenate(
             [c_kv, k_rope, jnp.zeros((B, T, pad), c_kv.dtype)], axis=-1)
-        # Absorbed: q_nope W_uk^T meets c_kv itself; the softmax scale (and
-        # YaRN's mscale^2) rides q.
-        q_lat = jnp.einsum("bthn,chn->bthc", q[..., :dn], wukv[..., :dn],
-                           preferred_element_type=jnp.float32)
-        q_abs = (jnp.concatenate(
-            [q_lat, q_rope.astype(jnp.float32),
-             jnp.zeros((B, T, H, pad), jnp.float32)], axis=-1)
-            * cfg.attn_scale).astype(h.dtype)
+        if few is None:
+            q_abs = absorbed(q, q_rope)
+        else:
+            # The lead's rows outside the conditional, whose branches then
+            # hold no weight (the compiler re-lays one it meets as a
+            # branch's operand), and both results as the kernel's tiles read
+            # them, [rows of (token, head), lanes]: a branch's result is
+            # born in that order, and no copy follows the conditional.
+            q_abs = jax.lax.cond(
+                few,
+                lambda first: jnp.pad(
+                    first.reshape(B, lead * H, -1),
+                    ((0, 0), (0, (T - lead) * H), (0, 0))),
+                lambda _: absorbed(q, q_rope).reshape(B, T * H, -1),
+                absorbed(q[:, :lead], q_rope[:, :lead])
+            ).reshape(B, T, H, -1)
         if cfg.index_topk:
             index = _index_inputs(cfg, lp, h, c_q, positions)
             expanded = ((jnp.concatenate(
@@ -699,11 +734,25 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     o_lat = attn_fn(q_abs, row, index, expanded)
     if isinstance(o_lat, tuple):
         o_lat, o_v, wide = o_lat
+    assert few is None or o_v is not None, "the launch expands, or `few` lies"
     with jax.named_scope("attn_out"):
-        o = jnp.einsum("bthc,chv->bthv", o_lat, wukv[..., dn:],
-                       preferred_element_type=jnp.float32).astype(h.dtype)
-        if o_v is not None:
-            o = jnp.where(wide[..., None, None], o_v, o)
+        if o_v is None:
+            o = through_w_uv(o_lat)
+        elif few is None:
+            o = jnp.where(wide[..., None, None], o_v, through_w_uv(o_lat))
+        else:
+            def o_tile(i, o):
+                rows = [jax.lax.dynamic_slice_in_dim(a, i * lead, lead, 1)
+                        for a in (wide, o, o_lat)]
+                return jax.lax.dynamic_update_slice_in_dim(o, jnp.where(
+                    rows[0][..., None, None], rows[1],
+                    through_w_uv(rows[2])), i * lead, 1)
+
+            # (Not a second conditional: a branch that assembles `o` from
+            # `o_v` copies it three times over, a loop's carry is updated in
+            # place; PERF.md section 6, PR 55.)
+            o = jax.lax.fori_loop(0, jnp.where(few, 1, T // lead), o_tile,
+                                  o_v)
         return qeinsum("bte,ed->btd", o.reshape(B, T, H * dv), lp["wo"])
 
 
@@ -905,15 +954,16 @@ def _parallel_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray, positions,
 def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
                 x: jnp.ndarray, positions: jnp.ndarray, attn_fn,
                 taps_fn=None, valid=None, mesh=None, impl: str = "jnp",
-                layer=None, rule_fn=None, ssm_fn=None):
+                layer=None, rule_fn=None, ssm_fn=None, few=None):
     """One layer over [B, T, D] hiddens: the SINGLE definition of the
     layer math for every forward — full sequences, the ragged stream
     ([1, T, D]) and the decode batch ([B, 1, D]). Only the operator's
     schedule differs, injected as `attn_fn(q, k, v) -> [B, T, H, hd]`
     (attention layers), `taps_fn(z) -> predecessors` (conv and linear
     layers) and `rule_fn` (linear layers); a forward over a pool or a
-    state writes it inside them. Returns (x', expert load — None for a
-    dense FFN)."""
+    state writes it inside them; `few`: what a latent layer's schedule
+    says of the step's rows (`_latent_attention_op`). Returns (x', expert
+    load — None for a dense FFN)."""
     op, ffn = kinds
     pre = cfg.norm_order == "pre"  # else the norms weigh the OUTPUTS
 
@@ -928,7 +978,7 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
     elif op == PARALLEL:
         delta = _parallel_op(cfg, lp, h, positions, attn_fn, taps_fn, ssm_fn)
     elif cfg.kv_lora_rank:
-        delta = _latent_attention_op(cfg, lp, h, positions, attn_fn)
+        delta = _latent_attention_op(cfg, lp, h, positions, attn_fn, few)
     else:  # attention over K and V: the whole context, or a window
         delta = _attention_op(cfg, lp, h, positions, attn_fn,
                               rotate=cfg.rotates(op))
@@ -1088,6 +1138,12 @@ def forward_ragged(
         ring_pt, ring_base = ring_table(
             slot_ids, kv_len, q_len, cfg.sliding_window, rows, page_size,
             tokens.shape[0])
+    few = None
+    if cfg.index_topk:  # once for the layers: the masked kernel's launch
+        few = mla.absorbed_lead(
+            attn_impl, q_start, q_len, tokens.shape[0], cfg.num_heads,
+            cfg.latent_lanes, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+            cfg.v_head_dim)
 
     def body(x, lp, kinds, ix, kc, vc, conv, rule, ring):
         def attn_fn(q, k, v, expanded=None):  # [1, T, H, hd]
@@ -1141,7 +1197,8 @@ def forward_ragged(
 
         x, load = _layer_step(cfg, lp, kinds, x, positions, attn_fn, taps_fn,
                               valid=valid, mesh=mesh, impl=attn_impl,
-                              layer=ix.ffn, rule_fn=rule_fn, ssm_fn=ssm_fn)
+                              layer=ix.ffn, rule_fn=rule_fn, ssm_fn=ssm_fn,
+                              few=few)
         return x, kc, vc, conv, rule, ring, load
 
     x, k_cache, v_cache, conv, rule, ring, load = scan_layers(

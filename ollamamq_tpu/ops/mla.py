@@ -196,3 +196,24 @@ def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
     with jax.named_scope("mla_attend"):
         return sparse_attention(q_abs, scores, thr, lat_pool, layer,
                                 page_table, tok_seq, tok_pos, page_size, rank)
+
+
+def absorbed_lead(impl: str, q_start, q_lens, tokens: int, heads: int,
+                  lanes: int, rank: int, nope: int, v: int):
+    """(rows, few) for a layer whose launch over a stream of `tokens` holds
+    the masked kernel's expanded body, else None (the jnp path, a rung under
+    WIDE: every row is attended in the absorbed form, nothing to choose).
+    `few` — a scalar on the device, of the step's own spans — says that no
+    stream row at or behind `rows` reads the absorbed form: each is a wide
+    span's, whose result leaves the launch through W_uv already, or padding.
+    The layer then runs the absorbed form's contractions — W_uk before the
+    launch, W_uv behind it — over the first `rows` alone
+    (models/llama.py:_latent_attention_op)."""
+    if impl != "pallas":
+        return None
+    from ollamamq_tpu.ops.pallas import mla_attention as kernels
+
+    lead = kernels.absorbed_lead(tokens, heads, lanes, rank, nope, v)
+    if not lead:
+        return None
+    return lead, jnp.all(kernels.absorbed_few(q_start, q_lens))

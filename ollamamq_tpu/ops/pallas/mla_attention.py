@@ -813,3 +813,53 @@ def _wide_launch(q, w, nope, scores, thr):
                  pltpu.VMEM((group, T, LANES), f32),
                  pltpu.VMEM((group, T, v), f32),
                  pltpu.VMEM((group * w.shape[1], ATTEND_BLOCK), q.dtype)])
+
+
+# What a LAYER computes for a launch that holds the expanded body
+# (models/llama.py:_latent_attention_op). Rows from the stream's start that it
+# always hands the launch in the ABSORBED form: where every row behind them is
+# a wide span's or padding — a prompt's chunk behind a few decode rows, the
+# engine's common step — it takes these alone through W_uk and, behind the
+# launch, through W_uv, not the rung: one tile of the attention's, so that no
+# tile that walks reads another row's q (kept at the file's end: a kernel's
+# serialised body carries its source lines, and no other launch's program
+# should move).
+ABSORBED_LEAD = ATTEND_TILE
+
+
+def absorbed_lead(tokens: int, heads: int, lanes: int, rank: int, nope: int,
+                  v: int) -> int:
+    """Rows of that lead on a rung of `tokens`: 0 where there is nothing to
+    choose — the launch holds no expanded body, or the rung is not whole
+    tiles of the lead (every row is absorbed there, as ever)."""
+    whole = tokens % ABSORBED_LEAD == 0
+    return ABSORBED_LEAD * (whole and expands(tokens, heads, lanes, rank,
+                                              nope, v))
+
+
+def absorbed_few(q_start, q_len):
+    """May a layer leave a span (or, of arrays, each span) out of the
+    absorbed form behind the lead? Where it is one the expanded programs
+    serve (`_expanded`'s `want`), padding (a row of no tokens), or ends
+    inside the lead. Where this holds for EVERY row of a step, no row at or
+    behind ABSORBED_LEAD reads the absorbed form: a layer asks on the
+    device, of the step's own `q_start` / `q_lens`; the engine's counter on
+    the host (`absorbed_rows`)."""
+    return ((q_len >= WIDE) | (q_len <= 0)
+            | (q_start + q_len <= ABSORBED_LEAD))
+
+
+def absorbed_rows(spans, stream_len: int, heads: int, lanes: int, rank: int,
+                  nope: int, v: int) -> int:
+    """Stream rows of a ragged step that a layer takes through the absorbed
+    form's two contractions (W_uk before the launch, W_uv behind it):
+    ABSORBED_LEAD where the rows behind those are wide spans'
+    (`absorbed_few`), else the rung's `stream_len`; 0 where there is no lead
+    (`absorbed_lead`). `spans`: each row's tokens, in the stream's order from
+    its start."""
+    lead = absorbed_lead(stream_len, heads, lanes, rank, nope, v)
+    start, few = 0, True
+    for n in spans:
+        few &= bool(absorbed_few(start, n))
+        start += n
+    return lead if few or not lead else stream_len

@@ -374,6 +374,15 @@ MLA_WIDE_TOKENS_TOTAL = REGISTRY.counter(
     "tokens, whose programs expand each block's keys and values once a head "
     "group; 0 without the kernel, for a fused scan and with no indexer",
     labels=("model",))
+MLA_ABSORBED_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_mla_absorbed_rows_total",
+    "Stream rows of ragged steps whose launch holds that expanded body for "
+    "which a layer computed the ABSORBED form (q through W_uk before the "
+    "launch, the attended latent through W_uv behind it): "
+    "mla_attention.ABSORBED_LEAD a step where every row behind the lead is a "
+    "wide span's or padding, else the rung; a layer's worth. 0 where nothing "
+    "is expanded (every row is absorbed there, and not counted)",
+    labels=("model",))
 DSA_CTX_TOKENS_TOTAL = REGISTRY.counter(
     "ollamamq_dsa_ctx_tokens_total",
     "Cached positions the indexer scored for those query tokens, a layer: "

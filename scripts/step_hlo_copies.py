@@ -95,8 +95,9 @@ def moves(hlo: str, min_bytes: int) -> list:
     is part of that fusion's read (a contraction that re-lays its operand as
     it reads it) and is not listed. Each with its name, which of the three it
     `moves`, shape, the result's layout, the first operand's name (a
-    parameter's says which weight), shape and layout, and the `op_name` the
-    source gave it."""
+    parameter's says which weight), shape and layout, the `op_name` the
+    source gave it, and the computation it is an instruction `of` (a
+    conditional's branch, by name: `branches`)."""
     computations, tuples, tuple_roots = {}, {}, {}
     at, fused = None, set()
     for line in hlo.splitlines():
@@ -165,8 +166,25 @@ def moves(hlo: str, min_bytes: int) -> list:
                     "layout": layout or "", "bytes": n,
                     "from": first.group(1) if first else "",
                     "from_shape": src[0], "from_layout": src[1],
-                    "op_name": op_name.group(1) if op_name else ""})
+                    "op_name": op_name.group(1) if op_name else "",
+                    "of": name})
     return found
+
+
+def branches(hlo: str) -> dict:
+    """{conditional: its branch computations' names, in index order} of a
+    compiled module's text — `lax.cond`'s false branch is index 0. An op that
+    `moves` names the computation it is `of`: one inside a branch runs only
+    on the steps that take it."""
+    out = {}
+    for line in hlo.splitlines():
+        m = _ANY_INSTR.match(line)
+        if m is None or m.group(1) != "conditional":
+            continue
+        name = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+        listed = re.search(r"branch_computations=\{([^}]*)\}", line)
+        out[name] = re.findall(r"%([\w.\-]+)", listed.group(1))
+    return out
 
 
 def scope_cycles(hlo: str, scopes, at_least: int = 20_000) -> dict:

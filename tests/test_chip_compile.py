@@ -787,6 +787,100 @@ def test_no_step_program_re_lays_a_latent_stack(v5e, capsys, held):
         programs[0]["weight_copies"]
 
 
+def _file_ragged_step(v5e, name, tokens=None, rehearse=False):
+    """(`scripts/step_hlo_copies.py` as a module, the compiled text of the
+    ragged step of `benchmarks/configs/<name>.json` at a stream of `tokens`
+    — its `--max-batch-tokens` by default — with the weights in the formats
+    a runtime holds them in)."""
+    import json
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "scripts"))
+    import step_hlo_copies
+
+    from benchmarks import serve
+    from ollamamq_tpu import cli
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    flags = cli.build_parser().parse_args(
+        ["--models", cfg["name"]] + serve.server_flags(cfg, rehearse))
+    lowered, _ = step_hlo_copies.step_programs(
+        serve.model_config(cfg, rehearse), flags, v5e,
+        tokens or flags.max_batch_tokens)
+    return step_hlo_copies, lowered["mq_ragged_step"].compile().as_text()
+
+
+def _device_ops(script, hlo):
+    """[(computation, opcode, line)] of a compiled module's text, the
+    insides of fusions left out (`step_hlo_copies.moves` has the rule)."""
+    fused = {c for line in hlo.splitlines() if " fusion(" in line
+             for c in script._CALLS.findall(line)}
+    out, at = [], None
+    for line in hlo.splitlines():
+        head = script._COMPUTATION.match(line)
+        if head:
+            at = head["name"]
+        elif at not in fused and (m := script._ANY_INSTR.match(line)):
+            out.append((at, m.group(1), line))
+    return out
+
+
+def test_the_wide_rung_computes_the_absorbed_form_of_the_rung_in_a_branch(
+        v5e):
+    """DeepSeek-V3.2's configuration file, the 512-token ragged step as
+    served (PR 55): the absorbed q of the RUNG — the contraction
+    `bthn,chn->bthc` over 512 rows, its `bf16[512,128,640]` result and that
+    result's 84 MB re-layout for the kernel's tiles — is computed inside
+    the branch a conditional takes on a step with a narrow span behind the
+    lead; on the other branch (`few`: a prompt's chunk behind a few decode
+    rows) nothing of 32 MB is copied, and the contraction outside any branch
+    runs over the 32 rows of the lead. W_uv's contraction `bthc,chv->bthv`
+    runs over 32 rows a trip, inside a loop's body, and nowhere over the
+    rung."""
+    script, hlo = _file_ragged_step(v5e, "deepseek-v3.2-ep16-d5", 512)
+    conds = script.branches(hlo)
+    assert conds, conds  # one a traced layer body
+    full = {b[0] for b in conds.values()}  # lax.cond's false branch
+    few = {b[1] for b in conds.values()}
+    moved = script.moves(hlo, 32 * 2 ** 20)
+    q_abs = [m for m in moved if m["dims"] == [512, 128, 640]]
+    assert q_abs and all(m["of"] in full for m in q_abs), q_abs
+    assert not [m for m in moved if m["of"] in few], moved
+    seen = {}  # rows of the contraction -> the computations it is an op of
+    for at, op, line in _device_ops(script, hlo):
+        if "bthn,chn->bthc/dot_general" in line and op == "fusion":
+            dims = script._INSTR.match(line)["dims"].split(",")
+            n = 512 if "512" in dims[:2] else 32 if "32" in dims[:2] \
+                else None  # [512, 128, .] or [1, 32, 128, .]
+            seen.setdefault(n, set()).add(at)
+        if "bthc,chv->bthv/dot_general" in line and op == "fusion":
+            assert "/attn_out/while/body/" in line, line  # a tile a trip
+    assert set(seen) == {512, 32}, seen
+    assert seen[512] <= full and not seen[32] & full, seen
+
+
+# Instructions of openPangu's ragged `--spec` step at the file's `rehearse`
+# sizes, the insides of fusions left out, as the tree BEFORE PR 55 compiled
+# it: its layers run `_latent_attention_op` too, with no indexer and so no
+# expanded body, and PR 55 means to leave them what they were. Take the
+# number again (`len(_device_ops(...))`) only with a change that means to
+# move that program.
+OPENPANGU_REHEARSE_OPS = 1350
+
+
+def test_a_latent_layer_with_no_expanded_body_is_the_program_it_was(v5e):
+    """...and holds no conditional: where nothing is expanded a layer has
+    nothing to choose (openPangu: no indexer; a rung of DeepSeek's under
+    WIDE is `tests/test_deepseek_v32.py`'s, by its trace)."""
+    script, hlo = _file_ragged_step(v5e, "openpangu-ultra-moe-ep16-d5",
+                                    rehearse=True)
+    assert script.branches(hlo) == {}
+    assert len(_device_ops(script, hlo)) == OPENPANGU_REHEARSE_OPS
+
+
 @pytest.mark.parametrize("name,held", [
     ("qwen2.5-7b-d14", False), ("qwen2.5-7b-d14", True),
     ("olmoe-1b-7b-d10", True)],

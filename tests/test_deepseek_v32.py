@@ -13,6 +13,7 @@ interpret mode against their jnp twins; the shares' sum; what leaving a piece
 out costs; what the engine counts and refuses."""
 
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -522,12 +523,13 @@ def test_the_pallas_kernels_match_their_twins_in_interpret_mode(mix):
     assert np.abs(got - twin).max() <= 2 ** -8 * max(1.0, np.abs(twin).max())
 
 
-def _paged_stream(spans, ps, mp, n_pages, rng, pad_to=32):
+def _paged_stream(spans, ps, mp, n_pages, rng, pad_to=32, rows=0):
     """A ragged step's metadata for `spans` = (tokens, context at the span's
     end) a row, each sequence on scattered pages of its own: (page table
     with two spare rows, tok_seq, tok_pos, q_start, q_len, kv_len) as int32
-    arrays and the stream's real length; the stream is padded to `pad_to`."""
-    rows = len(spans) + 2
+    arrays and the stream's real length; the stream is padded to `pad_to`,
+    the table to `rows` where that is more."""
+    rows = max(rows, len(spans) + 2)
     pt = np.zeros((rows, mp), np.int32)
     perm = rng.permutation(np.arange(1, n_pages))
     used = 0
@@ -661,53 +663,165 @@ def test_a_wide_span_is_attended_in_the_expanded_form(name, wide_of_48):
         1.0, np.abs(twin[:T]).max())
 
 
-def test_a_layer_takes_a_wide_spans_rows_as_the_kernel_leaves_them(
-        wide_of_48):
-    """`_latent_attention_op` with the kernel's schedule (interpret mode)
-    against the jnp twin's, at head widths of whole lane tiles: the layer
-    builds the expanded form's q and `[W_uk | W_uv]^T`, the kernel serves
-    the wide span from them, and `attn_out` takes those rows through W_uv
-    already and the others through it — one answer, and `wide` names the
-    span's rows."""
-    mc = dataclasses.replace(
-        DS, name="wide-lanes", num_heads=16, num_kv_heads=16, head_dim=192,
-        kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
-        v_head_dim=128, index_head_dim=64)
-    assert mc.latent_lanes == 256
-    params = make_params(mc, dtype=jnp.bfloat16)
-    lp = {k: v[0] for k, v in params["layers"].items()
-          if k in llama.MLA_PARAMS or k == "wo"}
-    ps, n_pages, T = 8, 64, 64
-    spans = [(1, 90), (1, 33), (50, 300), (5, 20)]  # tokens, context
-    pt, ts, tp, qs, ql, kl, real = _paged_stream(
-        spans, ps, 40, n_pages, np.random.default_rng(3), pad_to=T)
-    rng = np.random.default_rng(4)
-    kc = jnp.asarray(rng.standard_normal((1, n_pages * ps, 256)) * 0.3,
-                     jnp.bfloat16).at[:, :, 192:].set(0)
-    vc = jnp.asarray(rng.standard_normal((1, n_pages * ps, 64)),
-                     jnp.bfloat16)
-    slots = jnp.where(tp >= 0, llama.flat_slot_indices(
-        pt[ts], jnp.maximum(tp, 0)[:, None], ps)[:, 0], 0)
-    h = jnp.asarray(rng.standard_normal((1, T, mc.hidden_size)),
-                    jnp.bfloat16)
-    seen = {}
+# A layer over a launch that holds the expanded body computes the ABSORBED
+# form — q through W_uk before the launch, the attended latent through W_uv
+# behind it — for the stream's first ABSORBED_LEAD rows alone where every row
+# behind them is a wide span's or padding (`few`, chosen on the device from
+# the step's own spans), and for the rung otherwise. (spans under a WIDE of
+# 48 on a rung of 64, the lead 32; `few`: which branch the step takes.)
+LEAD_CASES = {
+    # the cell's step in small: rows 2..56 are the span's, 57..63 padding
+    "decode_rows_and_a_wide_span": dict(
+        spans=[(1, 90), (1, 33), (55, 200)], few=True),
+    # a narrow span behind the lead reads the absorbed form: the rung
+    "a_wide_span_and_a_narrow_one_behind_the_lead": dict(
+        spans=[(1, 90), (1, 33), (50, 300), (5, 20)], few=False),
+    # a prompt's last chunk, under WIDE, alone on the wide rung
+    "a_narrow_span_alone": dict(spans=[(1, 90), (40, 200)], few=False),
+}
 
-    def layer(impl):
-        def attn_fn(q, row, index, expanded=None):
-            _, _, out = llama._latent_ragged(
-                mc, q, row, index, kc, vc, 0, slots, pt, ts, tp, qs, ql, kl,
-                ps, impl, True, expanded=expanded)
-            seen[impl] = out
-            return out
-        return np.asarray(llama._latent_attention_op(
-            mc, lp, h, jnp.maximum(tp, 0)[None], attn_fn), np.float32)[0]
 
-    twin, got = layer("jnp"), layer("pallas")
-    assert not isinstance(seen["jnp"], tuple)
-    wide = np.asarray(seen["pallas"][2])[0]
-    assert wide[2:52].all() and wide.sum() == 50
-    assert np.abs(got - twin)[:real].max() <= 2 ** -6 * max(
-        1.0, np.abs(twin[:real]).max())
+def _control_flow_outside_kernels(jaxpr) -> list:
+    """The `cond` and `while` equations of a traced function, by name, the
+    kernels' own aside."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name in ("cond", "while"):
+            found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _control_flow_outside_kernels(sub)
+    return found
+
+
+class TestALayerOverTheExpandedBody:
+    """`_latent_attention_op` with the kernel's schedule (interpret mode),
+    at head widths of whole lane tiles, under a WIDE of 48: one set of
+    weights, pools and traced kernels for the cases."""
+
+    @pytest.fixture(scope="class")
+    def layer(self):
+        from ollamamq_tpu.ops.pallas import mla_attention as ka
+
+        patch = pytest.MonkeyPatch()
+        patch.setattr(ka, "WIDE", 48)  # read as the kernels trace
+        jax.clear_caches()
+        mc = dataclasses.replace(
+            DS, name="wide-lanes", num_heads=16, num_kv_heads=16,
+            head_dim=192, kv_lora_rank=128, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, index_head_dim=64)
+        assert mc.latent_lanes == 256
+        params = make_params(mc, dtype=jnp.bfloat16)
+        lp = {k: v[0] for k, v in params["layers"].items()
+              if k in llama.MLA_PARAMS or k == "wo"}
+        ps, n_pages = 8, 64
+        rng = np.random.default_rng(4)
+        kc = jnp.asarray(rng.standard_normal((1, n_pages * ps, 256)) * 0.3,
+                         jnp.bfloat16).at[:, :, 192:].set(0)
+        vc = jnp.asarray(rng.standard_normal((1, n_pages * ps, 64)),
+                         jnp.bfloat16)
+        hidden = jnp.asarray(rng.standard_normal((1, 64, mc.hidden_size)),
+                             jnp.bfloat16)
+        widths = (mc.num_heads, mc.latent_lanes, mc.kv_lora_rank,
+                  mc.qk_nope_head_dim, mc.v_head_dim)
+
+        def stream(spans, T=64):
+            """(run(impl, few) -> the layer's [T, D] float32 and what the
+            schedule answered; few: ops/mla.absorbed_lead's answer; real)"""
+            pt, ts, tp, qs, ql, kl, real = _paged_stream(
+                spans, ps, 40, n_pages, np.random.default_rng(3), pad_to=T,
+                rows=6)  # one shape, one trace of the kernels, for the cases
+            slots = jnp.where(tp >= 0, llama.flat_slot_indices(
+                pt[ts], jnp.maximum(tp, 0)[:, None], ps)[:, 0], 0)
+            few = mla.absorbed_lead("pallas", qs, ql, T, *widths)
+
+            def run(impl, few=None, trace=False):
+                seen = []
+
+                def attn_fn(q, row, index, expanded=None):
+                    _, _, out = llama._latent_ragged(
+                        mc, q, row, index, kc, vc, 0, slots, pt, ts, tp, qs,
+                        ql, kl, ps, impl, True, expanded=expanded)
+                    seen.append(out)
+                    return out
+
+                def op(h):
+                    return llama._latent_attention_op(
+                        mc, lp, h, jnp.maximum(tp, 0)[None], attn_fn, few)
+
+                if trace:
+                    return jax.make_jaxpr(op)(hidden[:, :T])
+                op = jax.jit(op) if impl == "jnp" else op
+                return np.asarray(op(hidden[:, :T]), np.float32)[0], seen[0]
+            return run, few, real
+
+        yield types.SimpleNamespace(stream=stream, widths=widths, ka=ka)
+        patch.undo()
+        jax.clear_caches()
+
+    def test_takes_a_wide_spans_rows_as_the_kernel_leaves_them(self, layer):
+        """Against the jnp twin's schedule: the layer builds the expanded
+        form's q and `[W_uk | W_uv]^T`, the kernel serves the wide span from
+        them, and `attn_out` takes those rows through W_uv already and the
+        others through it — one answer, and `wide` names the span's rows."""
+        run, _, real = layer.stream(
+            LEAD_CASES["a_wide_span_and_a_narrow_one_behind_the_lead"][
+                "spans"])
+        (twin, plain), (got, answer) = run("jnp"), run("pallas")
+        assert not isinstance(plain, tuple)
+        wide = np.asarray(answer[2])[0]
+        assert wide[2:52].all() and wide.sum() == 50
+        assert np.abs(got - twin)[:real].max() <= 2 ** -6 * max(
+            1.0, np.abs(twin[:real]).max())
+
+    @pytest.mark.parametrize("name", sorted(LEAD_CASES))
+    def test_computes_the_absorbed_form_for_the_rows_that_read_it(
+            self, name, layer):
+        """Against the same layer with the absorbed form over every row of
+        the rung (`few` None: the formulation before): a wide span's rows to
+        the bit — `o_v` is untouched — and every other real row within one
+        bfloat16 rounding of it on the `few` branch (a contraction over 32
+        rows may be tiled otherwise than one over the rung), to the bit on
+        the other; all within the twin's bound; and the engine's count of
+        the rows is the branch the device took."""
+        case = LEAD_CASES[name]
+        run, (lead, few), real = layer.stream(case["spans"])
+        assert lead == layer.ka.ABSORBED_LEAD == 32
+        assert bool(few) == case["few"]
+        assert layer.ka.absorbed_rows(
+            [n for n, _ in case["spans"]], 64, *layer.widths) == (
+                lead if case["few"] else 64)
+        before, answer = run("pallas")
+        got, _ = run("pallas", (lead, few))
+        wide = np.array(answer[2])[0]
+        wide[real:] = False
+        others = ~wide
+        others[real:] = False
+        assert wide.sum() == sum(n for n, _ in case["spans"] if n >= 48)
+        assert np.array_equal(got[wide], before[wide])
+        if not case["few"]:
+            assert np.array_equal(got[others], before[others])
+        scale = max(1.0, np.abs(before[:real]).max())
+        assert np.abs(got - before)[others].max() <= 2 ** -8 * scale
+        twin, _ = run("jnp")
+        assert np.abs(got - twin)[:real].max() <= 2 ** -6 * max(
+            1.0, np.abs(twin[:real]).max())
+
+    def test_traces_no_conditional_where_nothing_is_expanded(self, layer):
+        """A rung under WIDE, and the jnp path on any: `few` is None and the
+        layer's trace holds no `cond` and no loop (on the wide rung one
+        of each: the conditional before the launch, the loop over W_uv's
+        tiles of rows behind it)."""
+        spans = [(1, 90), (20, 200)]
+        run, few, _ = layer.stream(spans, T=32)
+        assert few is None
+        assert mla.absorbed_lead("jnp", None, None, 64, *layer.widths) is None
+        assert _control_flow_outside_kernels(
+            run("pallas", trace=True).jaxpr) == []
+        run, few, _ = layer.stream(spans)
+        assert _control_flow_outside_kernels(
+            run("pallas", few, trace=True).jaxpr) == ["cond", "while"]
 
 
 def _kernel_jaxpr(fn, *shapes):
@@ -768,47 +882,57 @@ def test_no_other_launch_holds_the_expanded_body():
 # `ModelRuntime._note_latent` puts the kernel's own count of those tokens on
 # the step's sample: (spans, rung, wide tokens) at DeepSeek-V3.2's widths.
 WIDE_STEPS = {
-    "the_cells_step": ([(1, 9000)] * 5 + [(507, 12000)], 512, 507),
+    "the_cells_step": ([(1, 9000)] * 5 + [(507, 12000)], 512, 507, 32),
     "a_tail_and_a_head": ([(1, 9000)] * 4 + [(188, 16000), (320, 320)], 512,
-                          320),
-    "two_short_spans": ([(250, 8200), (262, 262)], 512, 0),
-    "a_rung_under_wide": ([(1, 9000), (255, 255)], 256, 0),
-    "decode_rows_alone": ([(1, 9000)] * 16, 16, 0),
+                          320, 512),
+    "a_wide_span_alone": ([(512, 4096)], 512, 512, 32),
+    "a_wide_span_and_padding": ([(1, 9000)] * 16 + [(400, 400)], 512, 400,
+                                32),
+    "two_short_spans": ([(250, 8200), (262, 262)], 512, 0, 512),
+    "a_rung_under_wide": ([(1, 9000), (255, 255)], 256, 0, 0),
+    "decode_rows_alone": ([(1, 9000)] * 16, 16, 0, 0),
 }
 
 
 @pytest.mark.parametrize("name", sorted(WIDE_STEPS))
 def test_step_sample_carries_mla_wide_tokens(name):
     """From a step's composition alone, beside `mla_rows`: a ragged step's,
-    where the kernel serves; nothing in a fused scan or on the jnp path."""
+    where the kernel serves; nothing in a fused scan or on the jnp path. And
+    `mla_absorbed_rows` beside it: the lead where every row behind it is a
+    wide span's, the rung where one is not, 0 where nothing is expanded."""
     import functools
     import types
 
     from ollamamq_tpu.engine.engine import ModelRuntime
-    from ollamamq_tpu.ops.pallas.mla_attention import WIDE, wide_tokens
+    from ollamamq_tpu.ops.pallas.mla_attention import (WIDE, absorbed_rows,
+                                                       wide_tokens)
     from ollamamq_tpu.telemetry import schema as tm
 
-    spans, rung, wide = WIDE_STEPS[name]
+    spans, rung, wide, absorbed = WIDE_STEPS[name]
     assert wide == sum(n for n, _ in spans if n >= WIDE) * (rung >= WIDE)
     series = [c.labels(model="wide-" + name) for c in (
         tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
-        tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL)]
+        tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL,
+        tm.MLA_ABSORBED_ROWS_TOTAL)]
     cfg = types.SimpleNamespace(kv_lora_rank=512, index_topk=2048)
+    widths = dict(heads=128, lanes=640, rank=512, nope=128, v=128)
     rt = types.SimpleNamespace(
         cfg=cfg, LATENT_FIELDS=ModelRuntime.LATENT_FIELDS, _tm_dsa=series,
-        _wide_tokens=functools.partial(wide_tokens, heads=128, lanes=640,
-                                       rank=512, nope=128, v=128))
+        _wide_tokens=functools.partial(wide_tokens, **widths),
+        _absorbed_rows=functools.partial(absorbed_rows, **widths))
     noted = {}
     sp = types.SimpleNamespace(note=noted.update)
     ModelRuntime._note_latent(rt, sp, spans, stream_len=rung)
     assert noted["mla_wide_tokens"] == wide
+    assert noted["mla_absorbed_rows"] == absorbed
     assert noted["mla_rows"] == sum(n for n, _ in spans)
-    assert series[3].value == wide
+    assert series[3].value == wide and series[4].value == absorbed
     ModelRuntime._note_latent(rt, sp, [(8, kv) for _, kv in spans], scan=True)
-    assert noted["mla_wide_tokens"] == 0
+    assert noted["mla_wide_tokens"] == noted["mla_absorbed_rows"] == 0
     rt._wide_tokens = None  # the jnp path: no kernel, nothing expanded
     ModelRuntime._note_latent(rt, sp, spans, stream_len=rung)
-    assert noted["mla_wide_tokens"] == 0 and series[3].value == wide
+    assert noted["mla_wide_tokens"] == noted["mla_absorbed_rows"] == 0
+    assert series[3].value == wide and series[4].value == absorbed
 
 
 # ------------------------------------------------- the engine, by id stream
