@@ -29,7 +29,7 @@ from ollamamq_tpu.telemetry.slo import AlertManager
 from ollamamq_tpu.testing.faults import FaultPlan
 from ollamamq_tpu.tools.journal import (check_no_dropped_streams,
                                         check_scale_pairing)
-from testutil import collect
+from testutil import _text, _wait, collect
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
             max_pages_per_seq=8,
@@ -98,19 +98,6 @@ def _run(router, user, prompt="the quick brown fox jumps over",
     return router.enqueue_request(user, "", "test-tiny",
                                   prompt_tokens=tokens, sampling=sp,
                                   raw_prompt=prompt)
-
-
-def _text(items):
-    return "".join(i.text for i in items if i.kind == "token")
-
-
-def _wait(pred, budget=30.0, period=0.01):
-    deadline = time.monotonic() + budget
-    while time.monotonic() < deadline:
-        if pred():
-            return True
-        time.sleep(period)
-    return False
 
 
 def _scale_recs(router, kind):

@@ -18,7 +18,7 @@ from ollamamq_tpu.engine.request import FinishReason
 from ollamamq_tpu.ops.sampling import SamplingParams
 from ollamamq_tpu.testing.faults import (DeviceLostError, FaultInjected,
                                          FaultPlan, FaultPlanError)
-from testutil import collect
+from testutil import _text, collect
 
 TINY = dict(model="test-tiny", max_slots=2, num_pages=64, page_size=8,
             max_pages_per_seq=16,
@@ -47,10 +47,6 @@ def _run(eng, user, prompt="the quick brown fox jumps", max_tokens=10,
         sampling=SamplingParams(max_tokens=max_tokens,
                                 deadline_ms=deadline_ms))
     return req
-
-
-def _text(items):
-    return "".join(i.text for i in items if i.kind == "token")
 
 
 # ---------------------------------------------------------------- fault plan

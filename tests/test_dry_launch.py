@@ -32,16 +32,11 @@ from ollamamq_tpu.ops.sampling import SamplingParams
 from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.telemetry import stepprof
 from ollamamq_tpu.telemetry.stepprof import PROFILER
+from test_degradation import TINY, _tpu_engine
+from test_stepprof import _accounted_ms, _fresh_profiler  # noqa: F401
 from testutil import collect
 
 GENERATIVE = ("ragged", "spec_verify", "decode", "fake")
-
-
-@pytest.fixture(autouse=True)
-def _fresh_profiler():
-    PROFILER.reset()
-    yield
-    PROFILER.reset()
 
 
 # ------------------------------------------------ the bracket's arithmetic
@@ -266,11 +261,6 @@ def _total(metric):
     return sum(c.value for _, c in metric.series())
 
 
-def _accounted_ms(sample):
-    return sample["total_ms"] + sum(
-        sample["loop_" + ph + "_ms"] for ph in stepprof.LOOP_PHASES)
-
-
 def _check_dry_fields(samples):
     gen = [s for s in samples if s["mode"] in GENERATIVE]
     assert gen
@@ -328,23 +318,6 @@ def test_a_fake_engine_run_is_dry_every_step_and_still_gapless():
     assert block["hi_ms"] == pytest.approx(hi, abs=1e-3)
     assert sum(block["by_phase_ms"].values()) == pytest.approx(lo, abs=1e-3)
     assert set(block["by_phase_ms"]) <= set(stepprof.DRY_PHASES)
-
-
-TINY = dict(model="test-tiny", max_slots=2, num_pages=64, page_size=8,
-            max_pages_per_seq=16,
-            decode_steps_per_iter=2)
-
-
-def _tpu_engine(**over):
-    import jax.numpy as jnp
-
-    from ollamamq_tpu.engine.engine import TPUEngine
-
-    eng = TPUEngine(EngineConfig(**{**TINY, **over}),
-                    models={"test-tiny": None}, blocklist_path=None,
-                    dtype=jnp.float32)
-    eng.start()
-    return eng
 
 
 def _run(eng, user, max_tokens=10):

@@ -34,7 +34,8 @@ from ollamamq_tpu.ops import gated_delta as gd
 from ollamamq_tpu.ops import ssd
 from ollamamq_tpu.ops.pallas.ssd_step import ssd_step_pallas
 from ollamamq_tpu.ops.sampling import SamplingParams
-from test_lfm2 import ATOL, B, NP, PS, close, page_table, seq_tokens
+from test_lfm2 import (ATOL, B, NP, PS, _arrivals, close, page_table,
+                       ragged_step, seq_tokens)
 from test_step_overlap import _engine, _prompt, _rt, both, drive
 from testutil import falcon_h1_keys, falcon_h1_reference
 
@@ -62,49 +63,6 @@ def state(mc, dtype=jnp.float32, garbage=0.0):
     kv = jnp.zeros((mc.cache_layers, NP * PS, mc.kv_dim), dtype)
     slot = llama.alloc_slot_state(mc, B, dtype)
     return kv, kv, jax.tree_util.tree_map(lambda a: a + garbage, slot)
-
-
-@functools.lru_cache(maxsize=None)
-def _ragged_jit(mc, impl="jnp"):
-    """ONE compiled `forward_ragged` a (model, kernel path): the stream and
-    its row tables are arguments, so every step of every test reuses it."""
-    def run(p, kc, vc, slot, tok, seq, pos, slots, out_idx, q_start, q_len,
-            kv_len, slot_ids, first):
-        return llama.forward_ragged(
-            p, mc, tok, seq, pos, slots, out_idx, kc, vc,
-            jnp.asarray(page_table()), q_start, q_len, kv_len, PS,
-            attn_impl=impl, interpret=impl == "pallas", conv_state=slot,
-            slot_ids=slot_ids, is_first=first)
-
-    return jax.jit(run)
-
-
-def ragged_step(mc, params, st, spans, pad_to=32, impl="jnp"):
-    """One `forward_ragged` over `spans` = [(row, tokens, start position)],
-    padded to `pad_to`; rows without a span are padding rows (slot B, the
-    trash row). Row r serves slot r; a span that starts at position 0 is its
-    request's first. (test_lfm2.ragged_step with its arrays as arguments.)"""
-    tok, seq, pos = [], [], []
-    q_start = np.full(B, pad_to, np.int32)
-    q_len, kv_len, first = (np.zeros(B, np.int32) for _ in range(3))
-    slot_ids = np.full(B, B, np.int32)
-    for row, toks, start in spans:
-        q_start[row], q_len[row] = len(tok), len(toks)
-        kv_len[row], first[row] = start + len(toks), start == 0
-        slot_ids[row] = row
-        tok += list(toks)
-        seq += [row] * len(toks)
-        pos += list(range(start, start + len(toks)))
-    n = len(tok)
-    tok, seq, pos = (np.asarray(a + [f] * (pad_to - n), np.int32)
-                     for a, f in ((tok, 0), (seq, 0), (pos, -1)))
-    at = np.maximum(pos, 0)
-    slots = np.where(pos >= 0, page_table()[seq, at // PS] * PS + at % PS, 0)
-    out_idx = np.clip(q_start + q_len - 1, 0, pad_to - 1)
-    logits, kc, vc, slot = _ragged_jit(mc, impl)(
-        params, *st, tok, seq, pos, slots.astype(np.int32), out_idx, q_start,
-        q_len, kv_len, slot_ids, first)
-    return {row: logits[row] for row, _, _ in spans}, (kc, vc, slot), None
 
 
 @functools.lru_cache(maxsize=None)
@@ -506,11 +464,6 @@ def _falcon_engine(**over):
 @pytest.fixture(scope="module")
 def falcon():
     return _falcon_engine()
-
-
-def _arrivals(n=6, lens=(5, 40, 9, 23, 14, 31), every=2, out=9):
-    return [(every * i, f"u{i}", _prompt(i, lens[i % len(lens)]),
-             SamplingParams(max_tokens=out + 2 * i)) for i in range(n)]
 
 
 def test_overlapped_against_serial_gives_the_same_ids(falcon, monkeypatch):

@@ -24,7 +24,7 @@ from ollamamq_tpu.ops.sampling import SamplingParams
 from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.testing.faults import FaultPlan
 from ollamamq_tpu.tools.journal import check_no_dropped_streams
-from testutil import collect, free_port
+from testutil import _text, collect, free_port
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
             max_pages_per_seq=8,
@@ -90,10 +90,6 @@ def _run(router, user, prompt="the quick brown fox jumps over", max_tokens=8,
         user, "", "test-tiny", prompt_tokens=tokens,
         sampling=SamplingParams(max_tokens=max_tokens, **sp_kw),
         raw_prompt=prompt)
-
-
-def _text(items):
-    return "".join(i.text for i in items if i.kind == "token")
 
 
 def _serving_member(router, req):
@@ -921,10 +917,10 @@ def test_http_member_drain_migrates_over_admin_migrate_wire():
     members = [HttpMember(f"h{i}", b.url, timeout_s=30, poll_period_s=0.1)
                for i, b in enumerate(backends)]
     router = FleetRouter(members, ecfg, blocklist_path=None,
-                         probe_period_s=0.05, eject_heartbeat_s=2.0,
+                         probe_period_s=0.05, eject_heartbeat_s=30.0,
                          reprobe_backoff_s=0.2, evac_grace_s=0.5,
-                         migrate_timeout_s=10.0)
-    router.start()
+                         migrate_timeout_s=10.0)  # (no eject here: at 2 s a
+    router.start()  # loaded machine starved a poll, and the stream came back)
     try:
         req = _run(router, "hm", "migrate me over http", max_tokens=16)
         mem = _serving_member(router, req)

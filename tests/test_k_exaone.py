@@ -16,6 +16,7 @@ twins, the ring's arithmetic and the engine's counters:
 test_window_cache.py.)"""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -27,7 +28,10 @@ import pytest
 from ollamamq_tpu.config import (ATTENTION, EXPERTS, MODEL_CONFIGS, WINDOW,
                                  EngineConfig, validate_slot_state)
 from ollamamq_tpu.models import llama, moe
-from testutil import _reference
+import test_lfm2
+from test_lfm2 import close, seq_tokens
+from testutil import (_reference, moe_mlp, once_a_sequence, prefill,
+                      seeded_params)
 
 NAME = "test-tiny-k-exaone"
 KX = MODEL_CONFIGS[NAME]
@@ -63,33 +67,16 @@ def keys(mc) -> dict:
 
 
 def make_params(mc=KX, seed=0):
-    """Seeded weights with norm weights that are not all ones, so a norm on
-    the wrong axis (or left out) cannot pass; the selection bias is drawn
-    non-zero by `init_params`."""
-    params = llama.init_params(mc, jax.random.PRNGKey(seed),
-                               dtype=jnp.float32)
-    for i, name in enumerate(("q_norm", "k_norm", "attn_norm", "mlp_norm")):
-        w = params["layers"][name]
-        params["layers"][name] = 1.0 + 0.5 * jax.random.normal(
-            jax.random.PRNGKey(100 + i), w.shape, jnp.float32)
-    return params
+    """(the selection bias is drawn non-zero by `init_params`)"""
+    return seeded_params(mc, ("q_norm", "k_norm", "attn_norm", "mlp_norm"),
+                         seed=seed)
 
 
+@once_a_sequence
 def want(mc, params, tokens):
     """The reference's ONE full forward: [T, V] logits."""
     return np.asarray(_reference("k_exaone_decoder").logits(
         keys(mc), params, jnp.asarray(tokens, jnp.int32)))
-
-
-def seq_tokens(seed, n, vocab=512):
-    return np.random.default_rng(seed).integers(3, vocab, size=n).tolist()
-
-
-def page_table():
-    pt = np.zeros((B, MP), np.int32)  # page 0: the trash page
-    for row in range(B):
-        pt[row] = 1 + row * MP + np.arange(MP)
-    return pt
 
 
 def state(mc=KX, garbage=0.0):
@@ -106,77 +93,15 @@ def oracle(mc, params, tokens):
     """The program's `forward_prefill` at the last position."""
     kv = state(mc)[0]
     toks = jnp.asarray(tokens, jnp.int32)
-    return np.asarray(llama.forward_prefill(
+    return np.asarray(prefill(
         params, mc, toks[None], jnp.asarray([len(tokens)]), kv, kv,
         jnp.asarray(page_table()[:1]), PS)[0][0])
 
 
-def ragged_step(mc, params, st, spans, pad_to=PAD):
-    """One `forward_ragged` over `spans` = [(row, tokens, start position)],
-    padded to `pad_to`; rows without a span are padding rows (the trash
-    slot). Row r serves slot r."""
-    kc, vc, slot = st
-    tok, seq, pos = [], [], []
-    q_start = np.full(B, pad_to, np.int32)
-    q_len, kv_len, first = (np.zeros(B, np.int32) for _ in range(3))
-    slot_ids = np.full(B, B, np.int32)
-    for row, toks, start in spans:
-        q_start[row], q_len[row] = len(tok), len(toks)
-        kv_len[row], first[row] = start + len(toks), start == 0
-        slot_ids[row] = row
-        tok += list(toks)
-        seq += [row] * len(toks)
-        pos += list(range(start, start + len(toks)))
-    n = len(tok)
-    tok, seq, pos = (jnp.asarray(a + [f] * (pad_to - n), jnp.int32)
-                     for a, f in ((tok, 0), (seq, 0), (pos, -1)))
-    pt = jnp.asarray(page_table())
-    slots = jnp.where(pos >= 0, pt[seq, jnp.maximum(pos, 0) // PS] * PS
-                      + jnp.maximum(pos, 0) % PS, 0)
-    out_idx = jnp.asarray(np.clip(q_start + q_len - 1, 0, pad_to - 1))
-    logits, kc, vc, slot, load = jax.jit(
-        lambda p, kc, vc, slot: llama.forward_ragged(
-            p, mc, tok, seq, pos, slots, out_idx, kc, vc, pt,
-            jnp.asarray(q_start), jnp.asarray(q_len), jnp.asarray(kv_len),
-            PS, moe_load=True, conv_state=slot,
-            slot_ids=jnp.asarray(slot_ids), is_first=jnp.asarray(first))
-    )(params, kc, vc, slot)
-    return {row: logits[row] for row, _, _ in spans}, (kc, vc, slot), load
-
-
-def decode_scan(mc, params, st, feed, active):
-    """A fused scan of `forward_decode` passes, teacher-forced: `feed` =
-    {row: (tokens, first position)} for the `active` rows; every other row
-    carries garbage tokens and the trash page. Row r is slot r."""
-    k = len(next(iter(feed.values()))[0])
-    toks = np.full((k, B), 7, np.int32)
-    pos0 = np.zeros(B, np.int32)
-    act = np.zeros(B, np.int32)
-    for row, (t, p) in feed.items():
-        toks[:, row], pos0[row] = t, p
-    act[list(active)] = 1
-    table = jnp.asarray(np.where(act[:, None] > 0, page_table(), 0
-                                 ).astype(np.int32))
-
-    def run(p, kc, vc, slot):
-        def step(carry, tok):
-            pos, kc, vc, slot = carry
-            logits, kc, vc, slot = llama.forward_decode(
-                p, mc, tok, pos, kc, vc, table, PS, active=jnp.asarray(act),
-                conv_state=slot)
-            return (pos + 1, kc, vc, slot), logits
-
-        (_, kc, vc, slot), logits = jax.lax.scan(
-            step, (jnp.asarray(pos0), kc, vc, slot), jnp.asarray(toks))
-        return logits, kc, vc, slot
-
-    logits, kc, vc, slot = jax.jit(run)(params, *st)
-    return {row: logits[:, row] for row in feed}, (kc, vc, slot)
-
-
-def close(got, ref, atol=ATOL):
-    np.testing.assert_allclose(np.asarray(got, np.float32), ref, atol=atol,
-                               rtol=0)
+# tests/test_lfm2.py's step and scan, at this file's rung and pages a row.
+ragged_step = functools.partial(test_lfm2.ragged_step, pad_to=PAD, mp=MP)
+decode_scan = functools.partial(test_lfm2.decode_scan, mp=MP)
+page_table = functools.partial(test_lfm2.page_table, MP)
 
 
 # ----------------------------------------------------------- the config
@@ -415,10 +340,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
         whole, jax.random.PRNGKey(5), jnp.float32)["layers"].items()
         if k in llama.KIND_PARAMS[EXPERTS]}
     h = jax.random.normal(jax.random.PRNGKey(6), (1, 24, KX.hidden_size))
-    full, load = moe.moe_mlp(whole, lp, h)
+    full, load = moe_mlp(whole, lp, h)
     assert int(load.sum()) == 24 * KX.num_experts_per_tok
     no_shared = {k: v for k, v in lp.items() if not k.startswith("ws_")}
-    shared = full - moe.moe_mlp(
+    shared = full - moe_mlp(
         dataclasses.replace(whole, num_shared_experts=None,
                             n_shared_experts=0), no_shared, h)[0]
     parts, loads = [], 0
@@ -426,7 +351,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         share = dataclasses.replace(KX, expert_offset=first)
         mine = dict(lp, **{k: lp[k][first:first + 4]
                            for k in moe.STACKED})
-        out, load = moe.moe_mlp(share, mine, h)
+        out, load = moe_mlp(share, mine, h)
         parts.append(out - shared)
         loads += int(load.sum())
     assert loads == 24 * KX.num_experts_per_tok  # every pair lands once

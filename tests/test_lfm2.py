@@ -16,6 +16,7 @@ streams of the SAME programs under different schedules: bit-identical.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -23,14 +24,14 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, MODEL_CONFIGS,
-                                 EngineConfig, ModelConfig,
                                  validate_slot_state, validate_quant_config)
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops import shortconv
 from ollamamq_tpu.ops.sampling import SamplingParams
 from ollamamq_tpu.testing.faults import FaultPlan
 from test_step_overlap import _engine, _prompt, _rt, both, drive
-from testutil import lfm2_keys, lfm2_reference
+from testutil import (embed, lfm2_keys, lfm2_reference, once_a_sequence,
+                      prefill, seeded_params, span_stream, whole_blocks)
 
 LFM2 = MODEL_CONFIGS["test-tiny-lfm2"]
 PS, MP, NP, B = 8, 8, 40, 4  # page size, pages a sequence / in the pool, rows
@@ -38,14 +39,8 @@ ATOL = 2e-4
 
 
 def make_params(mc, dtype=jnp.float32, seed=0):
-    """Seeded weights with norm weights that are not all ones, so a norm on
-    the wrong axis (or left out) cannot pass."""
-    params = llama.init_params(mc, jax.random.PRNGKey(seed), dtype=dtype)
-    for i, name in enumerate(("q_norm", "k_norm", "attn_norm", "mlp_norm")):
-        w = params["layers"][name]
-        params["layers"][name] = (1.0 + 0.5 * jax.random.normal(
-            jax.random.PRNGKey(100 + i), w.shape, jnp.float32)).astype(dtype)
-    return params
+    return seeded_params(mc, ("q_norm", "k_norm", "attn_norm", "mlp_norm"),
+                         dtype, seed)
 
 
 def state(mc, dtype, garbage=0.0):
@@ -57,49 +52,65 @@ def state(mc, dtype, garbage=0.0):
     return kv, kv, conv + jnp.asarray(garbage, dtype)
 
 
-def page_table():
-    pt = np.zeros((B, MP), np.int32)  # page 0: the trash page
+def page_table(mp=None):
+    mp = mp or MP  # (the module's, as it stands at the call)
+    pt = np.zeros((B, mp), np.int32)  # page 0: the trash page
     for row in range(B):
-        pt[row] = 1 + row * MP + np.arange(MP)
+        pt[row] = 1 + row * mp + np.arange(mp)
     return pt
 
 
-def ragged_step(mc, params, st, spans, pad_to=32):
+@functools.cache
+def _ragged_jit(mc, impl):
+    """ONE jitted `forward_ragged` a (config, kernel path): what a step is
+    made of comes in as arguments, so a second step of the same shapes
+    compiles nothing."""
+    def run(p, kc, vc, conv, tok, seq, pos, slots, out_idx, pt, q_start,
+            q_len, kv_len, slot_ids, first):
+        return llama.forward_ragged(
+            p, mc, tok, seq, pos, slots, out_idx, kc, vc, pt, q_start, q_len,
+            kv_len, PS, attn_impl=impl, interpret=impl == "pallas",
+            moe_load=True, conv_state=conv, slot_ids=slot_ids, is_first=first)
+
+    return jax.jit(run)
+
+
+def ragged_step(mc, params, st, spans, pad_to=32, mp=None, impl="jnp"):
     """One `forward_ragged` over `spans` = [(row, tokens, start position)],
     padded to `pad_to`; rows without a span are padding rows (slot B, the
     trash row). Row r serves slot r (tests/test_kv_pool_inplace.py has rows
     that are not their slots). A span that starts at position 0 is its
     request's first."""
-    kc, vc, conv = st
-    tok, seq, pos = [], [], []
-    q_start = np.full(B, pad_to, np.int32)
-    q_len, kv_len, first = (np.zeros(B, np.int32) for _ in range(3))
-    slot_ids = np.full(B, B, np.int32)
-    for row, toks, start in spans:
-        q_start[row], q_len[row] = len(tok), len(toks)
-        kv_len[row], first[row] = start + len(toks), start == 0
-        slot_ids[row] = row
-        tok += list(toks)
-        seq += [row] * len(toks)
-        pos += list(range(start, start + len(toks)))
-    n = len(tok)
-    tok, seq, pos = (jnp.asarray(a + [f] * (pad_to - n), jnp.int32)
-                     for a, f in ((tok, 0), (seq, 0), (pos, -1)))
-    pt = page_table()
-    slots = jnp.where(pos >= 0, jnp.asarray(pt)[seq, jnp.maximum(pos, 0) // PS]
-                      * PS + jnp.maximum(pos, 0) % PS, 0)
-    out_idx = jnp.asarray(np.clip(q_start + q_len - 1, 0, pad_to - 1))
-    logits, kc, vc, conv, load = jax.jit(
-        lambda p, kc, vc, conv: llama.forward_ragged(
-            p, mc, tok, seq, pos, slots, out_idx, kc, vc, jnp.asarray(pt),
-            jnp.asarray(q_start), jnp.asarray(q_len), jnp.asarray(kv_len),
-            PS, moe_load=True, conv_state=conv,
-            slot_ids=jnp.asarray(slot_ids), is_first=jnp.asarray(first))
-    )(params, kc, vc, conv)
+    pt = page_table(mp)
+    stream, (q_start, q_len, kv_len) = span_stream(spans, pad_to, pt, PS)
+    slot_ids = np.where(q_len > 0, np.arange(B), B).astype(np.int32)
+    first = ((q_len > 0) & (kv_len == q_len)).astype(np.int32)
+    out_idx = np.clip(q_start + q_len - 1, 0, pad_to - 1)
+    logits, kc, vc, conv, load = _ragged_jit(mc, impl)(
+        params, *st, *stream, out_idx, pt, q_start, q_len, kv_len, slot_ids,
+        first)
     return {row: logits[row] for row, _, _ in spans}, (kc, vc, conv), load
 
 
-def decode_scan(mc, params, st, feed, active):
+@functools.cache
+def _scan_jit(mc):
+    """...and ONE jitted scan of `forward_decode` passes a config."""
+    def run(p, kc, vc, conv, toks, pos0, table, act):
+        def step(carry, tok):
+            pos, kc, vc, conv = carry
+            logits, kc, vc, *conv = llama.forward_decode(
+                p, mc, tok, pos, kc, vc, table, PS, active=act,
+                conv_state=conv)  # (no state: `conv` None, nothing returned)
+            return (pos + 1, kc, vc, *(conv or [None])), logits
+
+        (_, kc, vc, conv), logits = jax.lax.scan(
+            step, (pos0, kc, vc, conv), toks)
+        return logits, kc, vc, conv
+
+    return jax.jit(run)
+
+
+def decode_scan(mc, params, st, feed, active, mp=None, pt=None):
     """A fused scan of `forward_decode` passes, teacher-forced: `feed` =
     {row: (tokens, first position)} for the `active` rows; every other row
     carries garbage tokens and the trash page. Row r is slot r. Returns
@@ -111,28 +122,17 @@ def decode_scan(mc, params, st, feed, active):
     for row, (t, p) in feed.items():
         toks[:, row], pos0[row] = t, p
     act[list(active)] = 1
-    table = np.where(act[:, None] > 0, page_table(), 0).astype(np.int32)
-
-    def run(p, kc, vc, conv):
-        def step(carry, tok):
-            pos, kc, vc, conv = carry
-            logits, kc, vc, conv = llama.forward_decode(
-                p, mc, tok, pos, kc, vc, jnp.asarray(table), PS,
-                active=jnp.asarray(act), conv_state=conv)
-            return (pos + 1, kc, vc, conv), logits
-
-        (_, kc, vc, conv), logits = jax.lax.scan(
-            step, (jnp.asarray(pos0), kc, vc, conv), jnp.asarray(toks))
-        return logits, kc, vc, conv
-
-    logits, kc, vc, conv = jax.jit(run)(params, *st)
+    pt = page_table(mp) if pt is None else pt
+    table = np.where(act[:, None] > 0, pt, 0).astype(np.int32)
+    logits, kc, vc, conv = _scan_jit(mc)(params, *st, toks, pos0, table, act)
     return {row: logits[:, row] for row in feed}, (kc, vc, conv)
 
 
+@once_a_sequence
 def want(mc, params, tokens):
     """The reference's ONE full forward: [T, V] logits."""
     return np.asarray(lfm2_reference().logits(
-        lfm2_keys(mc), params, jnp.asarray(tokens, jnp.int32)))
+        lfm2_keys(mc), params, whole_blocks(tokens)))[:len(tokens)]
 
 
 def seq_tokens(seed, n, vocab=512):
@@ -226,71 +226,110 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(chunks):
     close(got[1], ref[23:])
 
 
-def test_the_same_path_in_bfloat16_misses_the_tolerance():
-    params = make_params(LFM2)
+def bfloat16_misses(mc, params, want, st, kept=()):
+    """The same path with bfloat16 weights (but `kept`) and state: far from
+    the reference — the tolerance has teeth. Returns the state after."""
     toks = seq_tokens(1, 23)
-    ref = want(LFM2, params, toks)
+    ref = want(mc, params, toks)
     low = jax.tree_util.tree_map(
         lambda w: w.astype(jnp.bfloat16) if w.dtype == jnp.float32
         and w.ndim > 1 else w, params)
     low["final_norm"] = low["final_norm"].astype(jnp.bfloat16)
-    low["layers"]["router_bias"] = params["layers"]["router_bias"]
-    _, st, _ = ragged_step(LFM2, low, state(LFM2, jnp.bfloat16),
-                           [(0, toks[:11], 0)])
-    got, _, _ = ragged_step(LFM2, low, st, [(0, toks[11:], 11)])
+    for name in kept:
+        low["layers"][name] = params["layers"][name]
+    _, st, _ = ragged_step(mc, low, st, [(0, toks[:11], 0)])
+    got, _, _ = ragged_step(mc, low, st, [(0, toks[11:], 11)])
     err = float(np.max(np.abs(np.asarray(got[0], np.float32) - ref[22])))
     assert err > 10 * ATOL, err
+    return st
 
 
-def test_a_ragged_step_mixing_prefill_spans_with_decode_rows():
+def test_the_same_path_in_bfloat16_misses_the_tolerance():
+    bfloat16_misses(LFM2, make_params(LFM2), want,
+                    state(LFM2, jnp.bfloat16), kept=("router_bias",))
+
+
+def mixed_step(mc, params, st, want, check_load):
     """Row 0 decodes (a span of one token on carried state), row 1 sends the
     second chunk of its prompt, row 2 its first span, row 3 a whole short
     prompt — in ONE stream, after a step that left rows 0 and 1 mid-way."""
-    params = make_params(LFM2)
     seqs = {r: seq_tokens(10 + r, n) for r, n in enumerate((14, 20, 9, 3))}
-    ref = {r: want(LFM2, params, t) for r, t in seqs.items()}
-    st = state(LFM2, jnp.float32, garbage=-2.0)
-    got, st, _ = ragged_step(LFM2, params, st, [(0, seqs[0][:13], 0),
-                                                (1, seqs[1][:7], 0)])
+    ref = {r: want(mc, params, t) for r, t in seqs.items()}
+    got, st, _ = ragged_step(mc, params, st, [(0, seqs[0][:13], 0),
+                                              (1, seqs[1][:7], 0)])
     close(got[0], ref[0][12])
-    got, st, load = ragged_step(LFM2, params, st, [
+    got, st, load = ragged_step(mc, params, st, [
         (0, seqs[0][13:], 13), (1, seqs[1][7:], 7), (2, seqs[2][:5], 0),
         (3, seqs[3], 0)])
     for row, last in ((0, 13), (1, 19), (2, 4), (3, 2)):
         close(got[row], ref[row][last])
-    # every real token of the stream was routed in each expert layer
-    assert load.shape == (LFM2.count(EXPERTS), LFM2.num_experts)
-    assert int(load.sum()) == (1 + 13 + 5 + 3) * LFM2.num_experts_per_tok \
-        * LFM2.count(EXPERTS)
+    check_load(load)
     # padding rows and padding tokens wrote no slot's state (the trash row
     # takes them), and row 2's state continues: its second span agrees
-    got, st, _ = ragged_step(LFM2, params, st, [(2, seqs[2][5:], 5)])
+    got, st, _ = ragged_step(mc, params, st, [(2, seqs[2][5:], 5)])
     close(got[2], ref[2][8])
 
 
-def test_the_fused_scan_beside_inactive_and_mid_prefill_slots():
+def test_a_ragged_step_mixing_prefill_spans_with_decode_rows():
+    def routed(load):  # every real token of the stream, in each expert layer
+        assert load.shape == (LFM2.count(EXPERTS), LFM2.num_experts)
+        assert int(load.sum()) == (1 + 13 + 5 + 3) \
+            * LFM2.num_experts_per_tok * LFM2.count(EXPERTS)
+
+    mixed_step(LFM2, make_params(LFM2),
+               state(LFM2, jnp.float32, garbage=-2.0), want, routed)
+
+
+def fused_scan(mc, params, st, want, by_slot):
     """k = 8 decode passes in one scan: slots 0 and 3 live, slot 1 reserved
     mid-chunked-prefill (its state must survive the scan and carry into its
-    next span), slot 2 idle with an earlier request's state (kept as is)."""
-    params = make_params(LFM2)
+    next span), slot 2 idle with an earlier request's state, 5.0 (kept as
+    is). `by_slot(state)`: its arrays, the slot axis at 1."""
     seqs = {0: seq_tokens(20, 10 + 8), 1: seq_tokens(21, 21),
             3: seq_tokens(23, 2 + 8)}
-    ref = {r: want(LFM2, params, t) for r, t in seqs.items()}
-    st = state(LFM2, jnp.float32, garbage=5.0)
-    _, st, _ = ragged_step(LFM2, params, st, [
+    ref = {r: want(mc, params, t) for r, t in seqs.items()}
+    _, st, _ = ragged_step(mc, params, st, [
         (0, seqs[0][:10], 0), (1, seqs[1][:9], 0), (3, seqs[3][:2], 0)])
-    before = np.asarray(st[2])
-    got, st = decode_scan(LFM2, params, st, {0: (seqs[0][10:], 10),
-                                             3: (seqs[3][2:], 2)},
+    before = by_slot(st[2])
+    got, st = decode_scan(mc, params, st, {0: (seqs[0][10:], 10),
+                                           3: (seqs[3][2:], 2)},
                           active=[0, 3])
     close(got[0], ref[0][10:])
     close(got[3], ref[3][2:])
-    after = np.asarray(st[2])
-    assert (after[:, :, 1] == before[:, :, 1]).all()  # mid-prefill: kept
-    assert (after[:, :, 2] == 5.0).all()              # idle: kept
-    assert (after[:, :, 0] != before[:, :, 0]).any()  # live: rolled
-    got, _, _ = ragged_step(LFM2, params, st, [(1, seqs[1][9:], 9)])
+    for was, arr in zip(before, by_slot(st[2])):
+        assert (arr[:, 1] == was[:, 1]).all()      # mid-prefill: kept
+        assert (arr[:, 2] == 5.0).all()            # idle: kept
+        assert (arr[:, 0] != was[:, 0]).any()      # live: advanced
+    got, _, _ = ragged_step(mc, params, st, [(1, seqs[1][9:], 9)])
     close(got[1], ref[1][20])
+
+
+def test_the_fused_scan_beside_inactive_and_mid_prefill_slots():
+    fused_scan(LFM2, make_params(LFM2), state(LFM2, jnp.float32, garbage=5.0),
+               want, lambda conv: [np.asarray(conv).swapaxes(1, 2)])
+
+
+def keeps_the_filler_from_it(want, params, reference, keys, mc):
+    """`want` hands a reference that does not pad itself whole blocks with a
+    filler behind (`testutil.whole_blocks`). It is causal: another filler
+    moves no bit of the rows `want` keeps; and those rows are the rows of the
+    sequence forwarded bare (as one program: op by op it is a minute under
+    load), to the tolerance every case grants (two float32 forwards of
+    different lengths: a row where seeded Olmo-Hybrid is ill-conditioned
+    differs by 1e-4 between paddings to 32 and to 64 as well)."""
+    tokens = seq_tokens(41, 13)
+    got = want(mc, params, tokens)
+    other = whole_blocks(tokens).at[13:].set(99)
+    assert np.array_equal(got, reference.logits(keys, params, other)[:13])
+    bare = jax.jit(lambda p, t: reference.logits(keys, p, t))(
+        params, jnp.asarray(tokens, jnp.int32))
+    assert bare.shape == got.shape == (13, mc.vocab_size)
+    close(got, bare)
+
+
+def test_the_reference_keeps_the_filler_behind_a_sequence_from_it():
+    keeps_the_filler_from_it(want, make_params(LFM2), lfm2_reference(),
+                             lfm2_keys(LFM2), LFM2)
 
 
 def test_the_published_24_layer_list_at_tiny_widths():
@@ -317,16 +356,16 @@ def test_the_oracle_and_the_embedding_forward_follow():
     kc, vc, _ = state(LFM2, jnp.float32)
     batch = np.zeros((2, 24), np.int32)
     batch[0, :19], batch[1, :12] = toks, toks[:12]
-    logits, _, _ = llama.forward_prefill(
+    logits, _, _ = prefill(
         params, LFM2, jnp.asarray(batch), jnp.asarray([19, 12]), kc, vc,
         jnp.asarray(page_table()[:2]), PS)
     close(logits[0], ref[18])
     close(logits[1], ref[11])
     # the embedding is the masked mean of the final hidden states: padding
     # behind a sequence moves nothing (causal convolution, causal attention)
-    e1 = llama.forward_embed(params, LFM2, jnp.asarray(batch[:1]),
+    e1 = embed(params, LFM2, jnp.asarray(batch[:1]),
                              jnp.asarray([19]))
-    e2 = llama.forward_embed(params, LFM2, jnp.asarray(batch[:1, :19]),
+    e2 = embed(params, LFM2, jnp.asarray(batch[:1, :19]),
                              jnp.asarray([19]))
     np.testing.assert_allclose(np.asarray(e1), np.asarray(e2), atol=1e-5)
     hidden = lfm2_reference().hidden(lfm2_keys(LFM2), params,
@@ -427,43 +466,56 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert all(s["moe_assignments"] % (2 * 7) == 0 for s in samples)
 
 
-def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
+def reused_slot(make_engine, holds_state, monkeypatch):
     """A second request in a slot the first left: the program opens the
-    slot's state at zero (`is_first`), no host call clears it."""
+    slot's state at zero (`is_first`), no host call clears it. (ONE engine:
+    the probe on it fresh, then `first`, then the probe again.)"""
     probe = (0, "probe", _prompt(4, 19), SamplingParams(max_tokens=12))
-    fresh, _ = drive(_lfm2_engine(), [probe], False, monkeypatch)
-    eng = _lfm2_engine()
+    eng = make_engine()
+    fresh, _ = drive(eng, [probe], False, monkeypatch)
     first = (0, "first", _prompt(2, 37), SamplingParams(max_tokens=11))
     drive(eng, [first], False, monkeypatch)
-    rt = _rt(eng)
-    left = np.asarray(rt.slot_state)
-    assert np.abs(left[:, 0]).max() > 0          # slot 0 holds its state
+    holds_state(_rt(eng))
     reused, _ = drive(eng, [probe], False, monkeypatch)
     assert reused["probe"] == fresh["probe"]
     assert len(reused["probe"][0]) == 12
 
 
-def test_preempt_and_replay_gives_the_same_ids(monkeypatch):
-    """With the prefix cache asked for: a model with conv layers gets none
-    (a cached page carries no conv state), so the preempted request replays
-    from token 0 and its stream does not move."""
+def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
+    def holds_state(rt):  # slot 0 holds its state
+        assert np.abs(np.asarray(rt.slot_state)[:, 0]).max() > 0
+
+    reused_slot(_lfm2_engine, holds_state, monkeypatch)
+
+
+def preempted_and_replayed(unfaulted, make_engine, resets, monkeypatch):
+    """With the prefix cache asked for: a model with per-slot state gets none
+    (a cached page carries no state), so the preempted request replays from
+    token 0 and its stream does not move — it is the stream of `unfaulted`,
+    the module's engine, which no plan ever touched — and the replay opened
+    the slot's state anew (`resets`: the samples' field that counts it)."""
     arr = [(0, "victim", _prompt(1, 21), SamplingParams(max_tokens=14))]
-    base, _ = drive(_lfm2_engine(prefix_cache=True), arr, False, monkeypatch)
+    base, _ = drive(unfaulted, arr, False, monkeypatch)
     plan = FaultPlan([{"site": "extend", "kind": "alloc_fail", "at": [2]}])
-    eng = _lfm2_engine(plan=plan, prefix_cache=True)
+    eng = make_engine(plan=plan, prefix_cache=True)
     rt = _rt(eng)
     assert rt.prefix_cache is None
     got, samples = drive(eng, arr, False, monkeypatch)
     assert rt.preempt_count >= 1
     assert got == base and len(got["victim"][0]) == 14
-    # the replay opened the slot's state anew
-    assert sum(s.get("conv_state_resets", 0) for s in samples) >= 2
+    assert sum(s.get(resets, 0) for s in samples) >= 2
 
 
-def test_a_voided_step_leaves_nothing_a_later_request_can_see(monkeypatch):
+def test_preempt_and_replay_gives_the_same_ids(hybrid, monkeypatch):
+    preempted_and_replayed(hybrid, _lfm2_engine, "conv_state_resets",
+                           monkeypatch)
+
+
+def test_a_voided_step_leaves_nothing_a_later_request_can_see(hybrid,
+                                                              monkeypatch):
     """A fault between launch and settle voids the step in flight; the rows
     replay as new admissions, each of which resets its slot."""
-    base, _ = drive(_lfm2_engine(), _arrivals(n=4), False, monkeypatch)
+    base, _ = drive(hybrid, _arrivals(n=4), False, monkeypatch)
     plan = FaultPlan([{"site": "ragged", "kind": "exception", "at": [4]}])
     got, _ = drive(_lfm2_engine(plan=plan), _arrivals(n=4), False,
                    monkeypatch)

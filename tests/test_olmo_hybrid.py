@@ -16,16 +16,17 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, LINEAR, MODEL_CONFIGS,
-                                 EngineConfig, validate_quant_config,
+                                 validate_quant_config,
                                  validate_slot_state)
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops import gated_delta as gd
-from ollamamq_tpu.ops.sampling import SamplingParams
-from ollamamq_tpu.testing.faults import FaultPlan
-from test_lfm2 import (ATOL, B, NP, PS, close, decode_scan, ragged_step,
-                       seq_tokens)
+from test_lfm2 import (ATOL, B, NP, PS, _arrivals, bfloat16_misses, close,
+                       decode_scan, fused_scan, keeps_the_filler_from_it,
+                       mixed_step, page_table, preempted_and_replayed,
+                       ragged_step, reused_slot, seq_tokens)
 from test_step_overlap import _engine, _prompt, _rt, both, drive
-from testutil import olmo_hybrid_keys, olmo_hybrid_reference
+from testutil import (olmo_hybrid_keys, olmo_hybrid_reference,
+                      once_a_sequence, prefill, seeded_params, whole_blocks)
 
 OLMO = MODEL_CONFIGS["test-tiny-olmo-hybrid"]
 H, DK, DV = (OLMO.linear_num_value_heads, OLMO.linear_key_head_dim,
@@ -33,16 +34,8 @@ H, DK, DV = (OLMO.linear_num_value_heads, OLMO.linear_key_head_dim,
 
 
 def make_params(mc, dtype=jnp.float32, seed=0):
-    """Seeded weights with norm weights that are not all ones, so a norm on
-    the wrong axis (or left out, or on the wrong side of a sublayer) cannot
-    pass."""
-    params = llama.init_params(mc, jax.random.PRNGKey(seed), dtype=dtype)
-    for i, name in enumerate(("q_norm", "k_norm", "attn_norm", "mlp_norm",
-                              "lin_norm")):
-        w = params["layers"][name]
-        params["layers"][name] = (1.0 + 0.5 * jax.random.normal(
-            jax.random.PRNGKey(100 + i), w.shape, jnp.float32)).astype(dtype)
-    return params
+    return seeded_params(mc, ("q_norm", "k_norm", "attn_norm", "mlp_norm",
+                              "lin_norm"), dtype, seed)
 
 
 def state(mc, dtype, garbage=0.0, pages=NP):
@@ -62,10 +55,11 @@ def by_slot(slot_state):
             np.asarray(slot_state.rule))
 
 
+@once_a_sequence
 def want(mc, params, tokens):
     """The reference's ONE full forward: [T, V] logits."""
     return np.asarray(olmo_hybrid_reference().logits(
-        olmo_hybrid_keys(mc), params, jnp.asarray(tokens, jnp.int32)))
+        olmo_hybrid_keys(mc), params, whole_blocks(tokens)))[:len(tokens)]
 
 
 # ----------------------------------------------------------- the config
@@ -145,6 +139,10 @@ def serial(q, k, v, g, beta, s0):
     return jax.lax.scan(token, s0, (qn, kn, v, g, beta))
 
 
+# One program a length (a form, bare, is its ops dispatched one by one).
+serial, chunked, step = jax.jit(serial), jax.jit(gd.chunked), jax.jit(gd.step)
+
+
 def rule_inputs(seed, t):
     """Correlated keys (a positive mean, as a SiLU leaves them), strengths
     up to 2 and decays from 0.6 to 1: what makes the chunk's triangular
@@ -163,13 +161,13 @@ def test_chunked_and_step_are_the_token_serial_recurrence(t):
     s0 = jnp.asarray(np.random.default_rng(9).normal(size=(H, DK, DV)),
                      jnp.float32)
     s_ref, o_ref = serial(q, k, v, g, beta, s0)
-    o, s = gd.chunked(q[None], k[None], v[None], g[None], beta[None],
-                      state=gd._from_heads(s0)[None])
+    o, s = chunked(q[None], k[None], v[None], g[None], beta[None],
+                   state=gd._from_heads(s0)[None])
     close(o[0], np.asarray(o_ref), atol=2e-5)
     close(gd._to_heads(s[0], H), np.asarray(s_ref), atol=2e-5)
     s, outs = gd._from_heads(s0), []
     for i in range(min(t, 66)):
-        o_i, s = gd.step(s, q[i], k[i], v[i], g[i], beta[i])
+        o_i, s = step(s, q[i], k[i], v[i], g[i], beta[i])
         outs.append(o_i)
     close(jnp.stack(outs), np.asarray(o_ref)[:len(outs)], atol=2e-5)
     if t <= 66:
@@ -185,7 +183,7 @@ def test_repeated_keys_at_full_strength_stay_finite_and_exact():
     k = jnp.broadcast_to(k[:1], k.shape)
     beta, g = jnp.full((t, H), 2.0), jnp.zeros((t, H))
     s_ref, o_ref = serial(q, k, v, g, beta, jnp.zeros((H, DK, DV)))
-    o, s = gd.chunked(q[None], k[None], v[None], g[None], beta[None])
+    o, s = chunked(q[None], k[None], v[None], g[None], beta[None])
     # (a reflection a token: float32 rounding adds up along the sequence,
     # in the serial form as in the chunked one)
     close(o[0], np.asarray(o_ref), atol=5e-3)
@@ -346,108 +344,77 @@ CHUNKINGS = {
 
 @pytest.mark.parametrize("chunks", CHUNKINGS.values(), ids=CHUNKINGS.keys())
 def test_prefill_in_chunks_then_decode_matches_the_reference(chunks):
-    params = make_params(OLMO)
+    chunks_then_decode(OLMO, make_params(OLMO), want, chunks)
+
+
+def chunks_then_decode(mc, params, want, chunks, held=None):
+    """A 23-token prompt in `chunks` on slot 1, then six decode passes: every
+    logit read agrees with the reference. `held`: the shape of a step's
+    expert loads, for a stack that has any."""
     # (Seeds: the output norm over a value head's 16 numbers makes a few
     # positions of a few sequences ill-conditioned at this size — the rule's
     # output there is a difference of a few terms a thousand times its size
     # — and there EVERY float32 path, the oracle forward_prefill too, lies
     # up to 1e-3 from the reference. These sequences have none.)
     toks = seq_tokens(5, 23 + 6)
-    ref = want(OLMO, params, toks)
-    st, at = state(OLMO, jnp.float32, garbage=3.0), 0
+    ref = want(mc, params, toks)
+    st, at = state(mc, jnp.float32, garbage=3.0), 0
     for n in chunks:
-        got, st, _ = ragged_step(OLMO, params, st, [(1, toks[at:at + n], at)])
+        got, st, load = ragged_step(mc, params, st,
+                                    [(1, toks[at:at + n], at)])
         at += n
         close(got[1], ref[at - 1])
+        assert (load is None) if held is None else (load.shape == held)
     # slot 1 holds the state; the other slots kept the earlier request's
     for arr in by_slot(st[2]):
         assert bool(jnp.all(arr[:, jnp.array([0, 2, 3])] == 3.0))
-    got, _ = decode_scan(OLMO, params, st, {1: (toks[23:], 23)}, active=[1])
+    got, _ = decode_scan(mc, params, st, {1: (toks[23:], 23)}, active=[1])
     close(got[1], ref[23:])
 
 
 def test_a_long_prompt_in_two_chunks_across_window_boundaries(monkeypatch):
+    """(At this length a perturbation of 1e-7 moves the reference's own
+    logits by up to 9e-4 at some positions: the positions read here are not
+    among them.)"""
+    a_span_across_windows(OLMO, make_params(OLMO), want, ATOL, monkeypatch)
+
+
+def a_span_across_windows(mc, params, want, atol, monkeypatch):
     """150 tokens as 90 + 60 beside another row's 70: the rule's windows of
-    64 are crossed inside a span, between spans and between rows. (At this
-    length a perturbation of 1e-7 moves the reference's own logits by up to
-    9e-4 at some positions: the positions read here are not among them.)"""
+    64 are crossed inside a span, between spans and between rows."""
     import test_lfm2
 
     monkeypatch.setattr(test_lfm2, "MP", 24)  # 192 tokens a sequence
-    params = make_params(OLMO)
     toks, other = seq_tokens(4, 150), seq_tokens(6, 70)
-    ref, ref_other = want(OLMO, params, toks), want(OLMO, params, other)
-    st = state(OLMO, jnp.float32, garbage=1.5, pages=1 + B * 24)
-    got, st, _ = ragged_step(OLMO, params, st, [(2, toks[:90], 0)],
+    ref, ref_other = want(mc, params, toks), want(mc, params, other)
+    st = state(mc, jnp.float32, garbage=1.5, pages=1 + B * 24)
+    got, st, _ = ragged_step(mc, params, st, [(2, toks[:90], 0)],
                              pad_to=96)
-    close(got[2], ref[89])
-    got, st, _ = ragged_step(OLMO, params, st, [
+    close(got[2], ref[89], atol=atol)
+    got, st, _ = ragged_step(mc, params, st, [
         (0, other, 0), (2, toks[90:], 90)], pad_to=144)
-    close(got[0], ref_other[69])
-    close(got[2], ref[149])
+    close(got[0], ref_other[69], atol=atol)
+    close(got[2], ref[149], atol=atol)
 
 
 def test_the_same_path_in_bfloat16_misses_the_tolerance():
-    params = make_params(OLMO)
-    toks = seq_tokens(1, 23)
-    ref = want(OLMO, params, toks)
-    low = jax.tree_util.tree_map(
-        lambda w: w.astype(jnp.bfloat16) if w.ndim > 1 else w, params)
-    low["final_norm"] = low["final_norm"].astype(jnp.bfloat16)
-    for name in ("lin_A_log", "lin_dt_bias"):
-        low["layers"][name] = params["layers"][name]
-    _, st, _ = ragged_step(OLMO, low, state(OLMO, jnp.bfloat16),
-                           [(0, toks[:11], 0)])
+    st = bfloat16_misses(OLMO, make_params(OLMO), want,
+                         state(OLMO, jnp.bfloat16),
+                         kept=("lin_A_log", "lin_dt_bias"))
     assert st[2].rule.dtype == jnp.float32  # the accumulator stays float32
-    got, _, _ = ragged_step(OLMO, low, st, [(0, toks[11:], 11)])
-    err = float(np.max(np.abs(np.asarray(got[0], np.float32) - ref[22])))
-    assert err > 10 * ATOL, err
 
 
 def test_a_ragged_step_mixing_prefill_spans_with_decode_rows():
-    """Row 0 decodes (a span of one token on carried state), row 1 sends the
-    second chunk of its prompt, row 2 its first span, row 3 a whole short
-    prompt — in ONE stream, after a step that left rows 0 and 1 mid-way."""
-    params = make_params(OLMO)
-    seqs = {r: seq_tokens(10 + r, n) for r, n in enumerate((14, 20, 9, 3))}
-    ref = {r: want(OLMO, params, t) for r, t in seqs.items()}
-    st = state(OLMO, jnp.float32, garbage=-2.0)
-    got, st, _ = ragged_step(OLMO, params, st, [(0, seqs[0][:13], 0),
-                                                (1, seqs[1][:7], 0)])
-    close(got[0], ref[0][12])
-    got, st, load = ragged_step(OLMO, params, st, [
-        (0, seqs[0][13:], 13), (1, seqs[1][7:], 7), (2, seqs[2][:5], 0),
-        (3, seqs[3], 0)])
-    assert load is None  # a dense stack
-    for row, last in ((0, 13), (1, 19), (2, 4), (3, 2)):
-        close(got[row], ref[row][last])
-    got, st, _ = ragged_step(OLMO, params, st, [(2, seqs[2][5:], 5)])
-    close(got[2], ref[2][8])
+    def dense(load):
+        assert load is None  # a dense stack
+
+    mixed_step(OLMO, make_params(OLMO),
+               state(OLMO, jnp.float32, garbage=-2.0), want, dense)
 
 
 def test_the_fused_scan_beside_inactive_and_mid_prefill_slots():
-    """k = 8 decode passes in one scan: slots 0 and 3 live, slot 1 reserved
-    mid-chunked-prefill (its state must survive the scan and carry into its
-    next span), slot 2 idle with an earlier request's state (kept as is)."""
-    params = make_params(OLMO)
-    seqs = {0: seq_tokens(20, 10 + 8), 1: seq_tokens(21, 21),
-            3: seq_tokens(23, 2 + 8)}
-    ref = {r: want(OLMO, params, t) for r, t in seqs.items()}
-    st = state(OLMO, jnp.float32, garbage=5.0)
-    _, st, _ = ragged_step(OLMO, params, st, [
-        (0, seqs[0][:10], 0), (1, seqs[1][:9], 0), (3, seqs[3][:2], 0)])
-    before = by_slot(st[2])
-    got, st = decode_scan(OLMO, params, st, {0: (seqs[0][10:], 10),
-                                             3: (seqs[3][2:], 2)},
-                          active=[0, 3])
-    close(got[0], ref[0][10:])
-    close(got[3], ref[3][2:])
-    for was, arr in zip(before, by_slot(st[2])):
-        assert (arr[:, 1] == was[:, 1]).all()      # mid-prefill: kept
-        assert (arr[:, 2] == 5.0).all()            # idle: kept
-        assert (arr[:, 0] != was[:, 0]).any()      # live: advanced
-    got, _, _ = ragged_step(OLMO, params, st, [(1, seqs[1][9:], 9)])
-    close(got[1], ref[1][20])
+    fused_scan(OLMO, make_params(OLMO), state(OLMO, jnp.float32, garbage=5.0),
+               want, by_slot)
 
 
 def test_the_published_32_layer_list_at_tiny_widths():
@@ -480,11 +447,16 @@ def test_the_whole_sequence_forwards_agree_with_the_reference():
     tokens = np.zeros((2, 80), np.int32)
     tokens[0, :70], tokens[1, :9] = a, b
     kv = jnp.zeros((OLMO.count(ATTENTION), NP * PS, OLMO.kv_dim), jnp.float32)
-    logits, _, _ = llama.forward_prefill(
+    logits, _, _ = prefill(
         params, OLMO, jnp.asarray(tokens), jnp.asarray([70, 9], jnp.int32),
         kv, kv, jnp.zeros((2, 10), jnp.int32), PS)
     close(logits[0], want(OLMO, params, a)[69])
     close(logits[1], want(OLMO, params, b)[8])
+
+
+def test_the_reference_keeps_the_filler_behind_a_sequence_from_it():
+    keeps_the_filler_from_it(want, make_params(OLMO), olmo_hybrid_reference(),
+                             olmo_hybrid_keys(OLMO), OLMO)
 
 
 # ------------------------------------------- the Pallas step kernel
@@ -495,6 +467,14 @@ def test_the_whole_sequence_forwards_agree_with_the_reference():
     (4, 8, 16, [0, 0, 0, 0, 0, 0]),    # no live row: nothing but the trash row moves
 ], ids=["tiny", "pairs", "dv192", "none_live"])
 def test_the_step_kernel_in_interpret_mode_is_step(heads, dk, dv, live):
+    step_kernel_is_step(heads, heads, dk, dv, live)
+
+
+def step_kernel_is_step(key_heads, heads, dk, dv, live, strongest=2.0):
+    """`gated_delta_step_pallas` in interpret mode against `gd.step` on the
+    live rows, every other row and layer untouched, q and k at `key_heads`
+    heads; returns (the rows' states, q, k, v, g, beta, reset, `gd.step`'s
+    outputs) for what a caller adds (tests/test_qwen3_next_rule.py)."""
     from ollamamq_tpu.ops.pallas.gated_delta_step import (
         gated_delta_step_pallas, head_blocks)
 
@@ -505,9 +485,10 @@ def test_the_step_kernel_in_interpret_mode_is_step(heads, dk, dv, live):
     slots = jnp.asarray([5, 8, 0, 3, 8, 7], jnp.int32)  # 8: the trash row
     live = jnp.asarray(live, bool)
     reset = jnp.asarray([0, 0, 1, 0, 0, 0], bool)
-    q, k, v = f(n, heads, dk), f(n, heads, dk) + 1, f(n, heads, dv)
+    q, k, v = f(n, key_heads, dk), f(n, key_heads, dk) + 1, f(n, heads, dv)
     g = -jnp.abs(f(n, heads)) * 0.3
-    beta = jnp.asarray(rng.uniform(0, 2, size=(n, heads)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0, strongest, size=(n, heads)),
+                       jnp.float32)
     hg, hb = head_blocks(heads, dk, dv)
     assert heads % hb == 0 and hb % hg == 0
     o, new = gated_delta_step_pallas(state0, jnp.int32(1), slots, live,
@@ -523,21 +504,24 @@ def test_the_step_kernel_in_interpret_mode_is_step(heads, dk, dv, live):
     assert bool(jnp.all(new[1][untouched] == state0[1][untouched]))
     assert bool(jnp.all(new[0] == state0[0])) \
         and bool(jnp.all(new[2] == state0[2]))
+    return state0[1][slots], q, k, v, g, beta, reset, o_ref
 
 
 def test_the_served_forwards_through_the_kernel_match_the_jnp_path():
+    served_through_the_kernels(OLMO, make_params(OLMO), ATOL)
+
+
+def served_through_the_kernels(mc, params, atol):
     """forward_ragged with `attn_impl` pallas in interpret mode (the Pallas
     attention kernel and the step kernel) against the jnp path: one stream
     with a one-token row, a span and a first span."""
-    params = make_params(OLMO)
     seqs = {0: seq_tokens(40, 12), 1: seq_tokens(41, 30)}
-    st = state(OLMO, jnp.float32, garbage=0.5)
-    _, st, _ = ragged_step(OLMO, params, st, [(0, seqs[0][:11], 0)])
+    st = state(mc, jnp.float32, garbage=0.5)
+    _, st, _ = ragged_step(mc, params, st, [(0, seqs[0][:11], 0)])
     kc, vc, slot_state = st
     tok = jnp.asarray(seqs[0][11:] + seqs[1] + [0], jnp.int32)
     seq = jnp.asarray([0] + [1] * 30 + [0], jnp.int32)
     pos = jnp.asarray([11] + list(range(30)) + [-1], jnp.int32)
-    from test_lfm2 import page_table
     pt = jnp.asarray(page_table())
     slots = jnp.where(pos >= 0, pt[seq, jnp.maximum(pos, 0) // PS] * PS
                       + jnp.maximum(pos, 0) % PS, 0)
@@ -548,10 +532,12 @@ def test_the_served_forwards_through_the_kernel_match_the_jnp_path():
         q_len=jnp.asarray([1, 30, 0, 0]), kv_len=jnp.asarray([12, 30, 0, 0]),
         page_size=PS, conv_state=slot_state,
         slot_ids=jnp.asarray([0, 1, B, B]), is_first=jnp.asarray([0, 1, 0, 0]))
-    want_, *_, want_state = llama.forward_ragged(params, OLMO, tok, **args)
-    got, *_, got_state = llama.forward_ragged(
-        params, OLMO, tok, **args, attn_impl="pallas", interpret=True)
-    close(got[:2], np.asarray(want_[:2]))
+    forward = jax.jit(llama.forward_ragged, static_argnums=1, static_argnames=(
+        "page_size", "attn_impl", "interpret"))  # (bare: an op at a time)
+    want_, *_, want_state = forward(params, mc, tok, **args)
+    got, *_, got_state = forward(params, mc, tok, **args,
+                                 attn_impl="pallas", interpret=True)
+    close(got[:2], np.asarray(want_[:2]), atol=atol)
     close(got_state.rule[:, :2], np.asarray(want_state.rule[:, :2]),
           atol=5e-4)  # (deep layers' inputs differ by the kernels' rounding)
 
@@ -567,11 +553,6 @@ def _olmo_engine(**over):
 @pytest.fixture(scope="module")
 def hybrid():
     return _olmo_engine()
-
-
-def _arrivals(n=6, lens=(5, 40, 9, 23, 14, 31), every=2, out=9):
-    return [(every * i, f"u{i}", _prompt(i, lens[i % len(lens)]),
-             SamplingParams(max_tokens=out + 2 * i)) for i in range(n)]
 
 
 def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
@@ -605,35 +586,16 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
 
 
 def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
-    """A second request in a slot the first left: the program opens the
-    slot's state at zero (`is_first`), no host call clears it."""
-    probe = (0, "probe", _prompt(4, 19), SamplingParams(max_tokens=12))
-    fresh, _ = drive(_olmo_engine(), [probe], False, monkeypatch)
-    eng = _olmo_engine()
-    first = (0, "first", _prompt(2, 37), SamplingParams(max_tokens=11))
-    drive(eng, [first], False, monkeypatch)
-    rt = _rt(eng)
-    for left in map(np.asarray, rt.slot_state):
-        assert np.abs(left[:, 0]).max() > 0      # slot 0 holds its state
-    reused, _ = drive(eng, [probe], False, monkeypatch)
-    assert reused["probe"] == fresh["probe"]
-    assert len(reused["probe"][0]) == 12
+    def holds_state(rt):  # slot 0 holds its state
+        for left in map(np.asarray, rt.slot_state):
+            assert np.abs(left[:, 0]).max() > 0
+
+    reused_slot(_olmo_engine, holds_state, monkeypatch)
 
 
-def test_preempt_and_replay_gives_the_same_ids(monkeypatch):
-    """With the prefix cache asked for: a model with per-slot state gets
-    none (a cached page carries no state), so the preempted request replays
-    from token 0 and its stream does not move."""
-    arr = [(0, "victim", _prompt(1, 21), SamplingParams(max_tokens=14))]
-    base, _ = drive(_olmo_engine(prefix_cache=True), arr, False, monkeypatch)
-    plan = FaultPlan([{"site": "extend", "kind": "alloc_fail", "at": [2]}])
-    eng = _olmo_engine(plan=plan, prefix_cache=True)
-    rt = _rt(eng)
-    assert rt.prefix_cache is None
-    got, samples = drive(eng, arr, False, monkeypatch)
-    assert rt.preempt_count >= 1
-    assert got == base and len(got["victim"][0]) == 14
-    assert sum(s.get("lin_state_resets", 0) for s in samples) >= 2
+def test_preempt_and_replay_gives_the_same_ids(hybrid, monkeypatch):
+    preempted_and_replayed(hybrid, _olmo_engine, "lin_state_resets",
+                           monkeypatch)
 
 
 # ------------------------------- what else touches per-sequence state
@@ -678,10 +640,10 @@ def test_migration_is_refused_not_served_without_the_state(hybrid):
     assert hybrid.export_prefix(NAME, _prompt(1, 40)) is None
 
 
-def test_gauges_and_counters_size_a_deployment(monkeypatch):
+def test_gauges_and_counters_size_a_deployment(hybrid, monkeypatch):
     from ollamamq_tpu.telemetry import schema as tm
 
-    eng = _olmo_engine()
+    eng = hybrid
     _engine("test-tiny")
 
     def value(series, model):

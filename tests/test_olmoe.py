@@ -21,7 +21,7 @@ from ollamamq_tpu.models import llama
 from ollamamq_tpu.parallel.mesh import make_mesh
 from ollamamq_tpu.parallel.sharding import (kv_cache_spec,
                                             param_partition_specs)
-from testutil import olmoe_reference, reference_keys
+from testutil import olmoe_reference, reference_keys, seeded_params
 
 OLMOE = MODEL_CONFIGS["test-tiny-olmoe"]
 MIXTRAL = MODEL_CONFIGS["test-tiny-moe"]
@@ -33,16 +33,8 @@ ATOL = 2e-4
 
 
 def make_params(mc, dtype=jnp.float32, seed=0):
-    """Seeded weights with q/k norm weights that are not all ones, so a norm
-    applied to the wrong axis cannot pass."""
-    params = llama.init_params(mc, jax.random.PRNGKey(seed), dtype=dtype)
-    for i, name in enumerate(("q_norm", "k_norm", "attn_norm", "mlp_norm")):
-        if name in params["layers"]:
-            w = params["layers"][name]
-            params["layers"][name] = (1.0 + 0.5 * jax.random.normal(
-                jax.random.PRNGKey(100 + i), w.shape, jnp.float32)
-            ).astype(dtype)
-    return params
+    return seeded_params(mc, ("q_norm", "k_norm", "attn_norm", "mlp_norm"),
+                         dtype, seed)
 
 
 def pools(mc, dtype):

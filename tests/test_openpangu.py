@@ -18,6 +18,7 @@ launch at the program's own length carry, `test_step_overlap.force_proposer`),
 under the pipelined loop and under the loop settled in its tick (PR 44)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,16 +26,18 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, MODEL_CONFIGS, EngineConfig,
-                                 ModelConfig, validate_latent_pool,
-                                 validate_slot_state)
+                                 validate_latent_pool, validate_slot_state)
 from ollamamq_tpu.engine import kv_cache as kvc
 from ollamamq_tpu.engine.engine import ModelRuntime
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops import mla
 from ollamamq_tpu.ops.sampling import SamplingParams
+from test_deepseek_v32 import page_table, seq_tokens
 from test_step_overlap import (PROPOSERS, _engine, _prompt, _rt, drive,
                                force_proposer)
-from testutil import openpangu_keys, openpangu_reference
+from testutil import (moe_mlp, once_a_sequence, openpangu_keys,
+                      openpangu_reference, prefill, seeded_params,
+                      span_stream)
 
 NAME = "test-tiny-openpangu"
 PG = MODEL_CONFIGS[NAME]
@@ -50,20 +53,7 @@ MTP_NORMS = ("mtp_enorm", "mtp_hnorm", "mtp_norm", "final_norm")
 
 
 def make_params(mc=PG, dtype=jnp.float32, seed=0):
-    """Seeded weights with norm weights that are not all ones, so a norm left
-    out cannot pass."""
-    params = llama.init_params(mc, jax.random.PRNGKey(seed), dtype=dtype)
-
-    def about_one(i, w):
-        return (1.0 + 0.5 * jax.random.normal(
-            jax.random.PRNGKey(100 + i), w.shape, jnp.float32)).astype(dtype)
-
-    for i, name in enumerate(NORMS):
-        params["layers"][name] = about_one(i, params["layers"][name])
-    for i, name in enumerate(MTP_NORMS):
-        if name in params:
-            params[name] = about_one(20 + i, params[name])
-    return params
+    return seeded_params(mc, NORMS, dtype, seed, top_norms=MTP_NORMS)
 
 
 def pools(mc=PG, dtype=jnp.float32):
@@ -71,18 +61,7 @@ def pools(mc=PG, dtype=jnp.float32):
                              dtype=dtype)
 
 
-def page_table():
-    pt = np.zeros((B, MP), np.int32)
-    pages = np.random.default_rng(5).permutation(np.arange(1, NP))
-    for r in range(B):
-        pt[r] = pages[r * MP:(r + 1) * MP]
-    return pt
-
-
-def seq_tokens(seed, n):
-    return np.random.default_rng(seed).integers(3, 500, n).astype(np.int32)
-
-
+@once_a_sequence
 def want(params, tokens, mc=PG):
     """The reference's ONE full forward: ([T, V] trunk logits, [T - 1, V]
     module logits: row i the distribution of token i + 2)."""
@@ -99,43 +78,36 @@ def ragged_step(params, st, spans, follows, mc=PG, pad_to=32, impl="jnp",
     the row's span. Logits leave at each row's last position, or at
     `read[row]` (an offset into its span: the trunk's there AND at the last).
     Returns ({row: (trunk logits, module logits)}, (kc, vc))."""
-    kc, vc = st
-    tok, seq, pos, nxt = [], [], [], []
-    q_start = np.full(B, pad_to, np.int32)
-    q_len, kv_len = np.zeros(B, np.int32), np.zeros(B, np.int32)
-    for row, toks, start in spans:
-        q_start[row], q_len[row] = len(tok), len(toks)
-        kv_len[row] = start + len(toks)
-        tok += list(toks)
-        nxt += list(toks[1:]) + [follows[row]]
-        seq += [row] * len(toks)
-        pos += list(range(start, start + len(toks)))
-    n = len(tok)
-    tok, seq, pos, nxt = (np.asarray(a + [f] * (pad_to - n), np.int32)
-                          for a, f in ((tok, 0), (seq, 0), (pos, -1),
-                                       (nxt, 0)))
     pt = page_table()
-    slots = np.where(pos >= 0, pt[seq, np.maximum(pos, 0) // PS] * PS
-                     + np.maximum(pos, 0) % PS, 0)
+    stream, (q_start, q_len, kv_len) = span_stream(spans, pad_to, pt, PS)
+    nxt = [t for row, toks, _ in spans
+           for t in list(toks[1:]) + [follows[row]]]
+    nxt = np.asarray(nxt + [0] * (pad_to - len(nxt)), np.int32)
     at = q_start + q_len - 1
     for row, off in (read or {}).items():
         at[row] = q_start[row] + off
     at = np.clip(at, 0, pad_to - 1)
-
-    def run(p, kc, vc):
-        meta = tuple(map(jnp.asarray, (pt, q_start, q_len, kv_len)))
-        logits, kc, vc, _, hidden = llama.forward_ragged(
-            p, mc, *map(jnp.asarray, (tok, seq, pos, slots, at)), kc, vc,
-            *meta, PS, attn_impl=impl, moe_load=True, hidden=True)
-        draft, kc, load = llama.forward_mtp(
-            p, mc, hidden, *map(jnp.asarray, (nxt, seq, pos, slots, at)),
-            kc, *meta, PS, attn_impl=impl)
-        return logits, draft, kc, vc, load
-
-    logits, draft, kc, vc, load = jax.jit(run)(params, kc, vc)
+    logits, draft, kc, vc, load = _step_jit(mc, impl)(
+        params, *st, (*stream, at), nxt, (pt, q_start, q_len, kv_len))
     assert load.shape == (mc.num_experts,)
     return {row: (np.asarray(logits[row]), np.asarray(draft[row]))
             for row, _, _ in spans}, (kc, vc)
+
+
+@functools.cache
+def _step_jit(mc, impl):
+    """ONE jitted trunk-and-module step a (config, implementation): what a
+    step is made of comes in as arguments, so a second step of the same
+    shapes compiles nothing."""
+    def run(p, kc, vc, stream, nxt, meta):
+        logits, kc, vc, _, hidden = llama.forward_ragged(
+            p, mc, *stream, kc, vc, *meta, PS, attn_impl=impl, moe_load=True,
+            hidden=True)
+        draft, kc, load = llama.forward_mtp(
+            p, mc, hidden, nxt, *stream[1:], kc, *meta, PS, attn_impl=impl)
+        return logits, draft, kc, vc, load
+
+    return jax.jit(run)
 
 
 def serve(params, tokens, n_prompt, chunk, mc=PG):
@@ -306,7 +278,7 @@ def test_the_oracle_and_the_decode_scan_follow():
     kc, vc = pools()
     pt = jnp.asarray(page_table()[:2])
     both = np.stack([tokens[:40], np.pad(tokens[:25], (0, 15))])
-    logits, kc, vc = llama.forward_prefill(
+    logits, kc, vc = prefill(
         params, PG, jnp.asarray(both), jnp.asarray([40, 25]), kc, vc, pt, PS)
     assert np.abs(np.asarray(logits[0]) - ref[39]).max() < ATOL
     assert np.abs(np.asarray(logits[1]) - ref[24]).max() < ATOL
@@ -380,7 +352,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up():
           for k, v in params["layers"].items()
           if k in ("w_router",) + moe.SHARED + moe.STACKED}
     h = jax.random.normal(jax.random.PRNGKey(3), (1, 24, PG.hidden_size))
-    whole, load = moe.moe_mlp(uncut, lp, h, layer=0)
+    whole, load = moe_mlp(uncut, lp, h, layer=0)
     assert int(load.sum()) == 24 * 4
     shared = jnp.einsum(
         "btf,fd->btd", jax.nn.silu(h @ lp["ws_gate"]) * (h @ lp["ws_up"]),
@@ -390,7 +362,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up():
         share = dataclasses.replace(PG, expert_offset=first)
         held = dict(lp, **{k: lp[k][:, first:first + 4]
                            for k in moe.STACKED})
-        part, load = moe.moe_mlp(share, held, h, layer=0)
+        part, load = moe_mlp(share, held, h, layer=0)
         total = total + (part - shared)
         loads.append(int(load.sum()))
     assert sum(loads) == 24 * 4 and min(loads) >= 0
@@ -407,8 +379,8 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up():
     at8 = dataclasses.replace(PG, expert_offset=8)
     lp4 = dict(params["layers"], **{k: params["layers"][k][:, 8:12]
                                     for k in moe.STACKED})
-    part, _ = moe.moe_mlp(at8, dict(lp, **{k: lp[k][:, 8:12]
-                                           for k in moe.STACKED}), h, layer=0)
+    part, _ = moe_mlp(at8, dict(lp, **{k: lp[k][:, 8:12]
+                                       for k in moe.STACKED}), h, layer=0)
     assert float(jnp.abs(ref._experts(openpangu_keys(at8), mm, h[0], lp4, 0)
                          - part[0]).max()) < 1e-5
 

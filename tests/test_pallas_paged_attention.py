@@ -8,6 +8,7 @@ import pytest
 
 from ollamamq_tpu.ops.attention import paged_decode_attention
 from ollamamq_tpu.ops.pallas.paged_attention import paged_decode_attention_pallas
+from test_ragged_attention import _f32
 
 
 LAYERS = 3  # pool depth of the kernel cases: first, middle, last layer
@@ -70,10 +71,6 @@ BF16_TOL = dict(rtol=2 ** -8 + 4e-5, atol=3e-5)
 def _id(case):
     return "H{H}-Hk{Hk}-hd{hd}-".format(**case) + (
         "bf16" if "dtype" in case else "x".join(map(str, case["seq_lens"])))
-
-
-def _f32(x):
-    return x.astype(jnp.float32)
 
 
 @pytest.mark.parametrize("layer", range(LAYERS))

@@ -34,7 +34,7 @@ from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops import selective_scan as s6
 from ollamamq_tpu.ops.attention import causal_attention
 from ollamamq_tpu.ops.pallas.s6_step import s6_step_pallas
-from testutil import _reference
+from testutil import _reference, span_stream
 
 NAME = "test-tiny-phi4-flash"
 PHI = MODEL_CONFIGS[NAME]
@@ -111,27 +111,16 @@ def ragged_step(params, st, spans, emits=None, jit=None, **kw):
     padded to PAD; rows without a span are padding rows (the trash slot).
     Row r serves slot r; a span that starts at position 0 is its request's
     first; `emits`: the rows whose logits are sampled (default: all)."""
-    tok, seq, pos = [], [], []
-    q_start = np.full(B, PAD, np.int32)
-    q_len, kv_len, first, emit = (np.zeros(B, np.int32) for _ in range(4))
-    slot_ids = np.full(B, B, np.int32)
-    for row, toks, start in spans:
-        q_start[row], q_len[row] = len(tok), len(toks)
-        kv_len[row], first[row] = start + len(toks), start == 0
-        slot_ids[row] = row
-        emit[row] = emits is None or row in emits
-        tok += list(toks)
-        seq += [row] * len(toks)
-        pos += list(range(start, start + len(toks)))
-    n = len(tok)
-    tok, seq, pos = (np.asarray(a + [f] * (PAD - n), np.int32)
-                     for a, f in ((tok, 0), (seq, 0), (pos, -1)))
-    at = np.maximum(pos, 0)
-    slots = np.where(pos >= 0, page_table()[seq, at // PS] * PS + at % PS, 0)
+    stream, (q_start, q_len, kv_len) = span_stream(spans, PAD, page_table(),
+                                                   PS)
+    slot_ids = np.where(q_len > 0, np.arange(B), B).astype(np.int32)
+    first = ((q_len > 0) & (kv_len == q_len)).astype(np.int32)
+    emit = np.asarray([q_len[r] > 0 and (emits is None or r in emits)
+                       for r in range(B)], np.int32)
     out_idx = np.clip(q_start + q_len - 1, 0, PAD - 1)
     logits, kc, vc, slot = (jit or _ragged_jit(**kw))(
-        params, *st, tok, seq, pos, slots.astype(np.int32), out_idx, q_start,
-        q_len, kv_len, slot_ids, first, emit)
+        params, *st, *stream, out_idx, q_start, q_len, kv_len, slot_ids,
+        first, emit)
     return {row: np.asarray(logits[row]) for row, _, _ in spans}, \
         (kc, vc, slot)
 

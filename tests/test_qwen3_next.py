@@ -24,10 +24,11 @@ from ollamamq_tpu.config import (ATTENTION, LINEAR, MODEL_CONFIGS,
                                  validate_slot_state)
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.sampling import SamplingParams
-from test_lfm2 import ATOL, close, decode_scan, ragged_step, seq_tokens
-from test_olmo_hybrid import by_slot, state
+from test_lfm2 import ATOL
+from test_olmo_hybrid import a_span_across_windows, chunks_then_decode
 from test_step_overlap import _engine, _prompt, _rt, drive
-from testutil import qwen3_next_keys, qwen3_next_reference
+from testutil import (once_a_sequence, qwen3_next_keys, qwen3_next_reference,
+                      seeded_params)
 
 NAME = "test-tiny-qwen3-next"
 QN = MODEL_CONFIGS[NAME]
@@ -39,9 +40,10 @@ FILE = os.path.join(_REPO, "benchmarks", "configs",
 def make_params(mc=QN, seed=0):
     """The seeded weights as `init_params` draws them: the family's norm
     weights, gates and w_sg are drawn away from the identity there."""
-    return llama.init_params(mc, jax.random.PRNGKey(seed), dtype=jnp.float32)
+    return seeded_params(mc, (), seed=seed)
 
 
+@once_a_sequence
 def want(mc, params, tokens):
     """The reference's ONE full forward: [T, V] logits."""
     return np.asarray(qwen3_next_reference().logits(
@@ -127,40 +129,13 @@ CHUNKINGS = {
 
 @pytest.mark.parametrize("chunks", CHUNKINGS.values(), ids=CHUNKINGS.keys())
 def test_prefill_in_chunks_then_decode_matches_the_reference(chunks):
-    params = make_params()
-    toks = seq_tokens(5, 23 + 6)
-    ref = want(QN, params, toks)
-    st, at = state(QN, jnp.float32, garbage=3.0), 0
-    for n in chunks:
-        got, st, load = ragged_step(QN, params, st,
-                                    [(1, toks[at:at + n], at)])
-        at += n
-        close(got[1], ref[at - 1])
-        assert load.shape == (8, 8)  # expert layers x experts HELD
-    for arr in by_slot(st[2]):  # the other slots kept the earlier request's
-        assert bool(jnp.all(arr[:, jnp.array([0, 2, 3])] == 3.0))
-    got, _ = decode_scan(QN, params, st, {1: (toks[23:], 23)}, active=[1])
-    close(got[1], ref[23:])
+    chunks_then_decode(QN, make_params(), want, chunks,
+                       held=(8, 8))  # expert layers x experts HELD
 
 
 def test_a_span_across_window_boundaries_beside_another_row(monkeypatch):
-    """150 tokens as 90 + 60 beside another row's 70: the rule's windows of
-    64 are crossed inside a span, between spans and between rows, by rows
-    whose key heads each serve two value heads."""
-    import test_lfm2
-    from test_lfm2 import B
-
-    monkeypatch.setattr(test_lfm2, "MP", 24)  # 192 tokens a sequence
-    params = make_params()
-    toks, other = seq_tokens(4, 150), seq_tokens(6, 70)
-    ref, ref_other = want(QN, params, toks), want(QN, params, other)
-    st = state(QN, jnp.float32, garbage=1.5, pages=1 + B * 24)
-    got, st, _ = ragged_step(QN, params, st, [(2, toks[:90], 0)], pad_to=96)
-    close(got[2], ref[89], atol=5 * ATOL)
-    got, st, _ = ragged_step(QN, params, st, [
-        (0, other, 0), (2, toks[90:], 90)], pad_to=144)
-    close(got[0], ref_other[69], atol=5 * ATOL)
-    close(got[2], ref[149], atol=5 * ATOL)
+    """...by rows whose key heads each serve two value heads."""
+    a_span_across_windows(QN, make_params(), want, 5 * ATOL, monkeypatch)
 
 
 # ------------------------------- the file's arithmetic, the served tree

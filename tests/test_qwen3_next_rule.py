@@ -13,12 +13,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ollamamq_tpu.models import llama, moe
+from ollamamq_tpu.models import moe
 from ollamamq_tpu.ops import gated_delta as gd
-from test_lfm2 import ATOL, close, ragged_step, seq_tokens
-from test_olmo_hybrid import state
+from test_lfm2 import ATOL, close, seq_tokens
+from test_olmo_hybrid import served_through_the_kernels, step_kernel_is_step
 from test_qwen3_next import QN, _prefill, make_params, oracle, want
-from testutil import qwen3_next_keys, qwen3_next_reference
+from testutil import moe_mlp, qwen3_next_keys, qwen3_next_reference
 
 
 def _tiled(q, k, heads):
@@ -83,7 +83,7 @@ def test_the_shares_routed_parts_and_the_gated_shared_expert_once_add_up():
     lp = {k: v[0] if k not in moe.STACKED else v
           for k, v in params["layers"].items() if k in names}
     h = jax.random.normal(jax.random.PRNGKey(3), (1, 24, QN.hidden_size))
-    whole, load = moe.moe_mlp(uncut, lp, h, layer=0)
+    whole, load = moe_mlp(uncut, lp, h, layer=0)
     assert int(load.sum()) == 24 * 4
     shared = jax.nn.sigmoid(h @ lp["w_shared_gate"])[..., None] * jnp.einsum(
         "btf,fd->btd", jax.nn.silu(h @ lp["ws_gate"]) * (h @ lp["ws_up"]),
@@ -93,7 +93,7 @@ def test_the_shares_routed_parts_and_the_gated_shared_expert_once_add_up():
         share = dataclasses.replace(share4, expert_offset=first)
         held = dict(lp, **{k: lp[k][:, first:first + 4]
                            for k in moe.STACKED})
-        part, load = moe.moe_mlp(share, held, h, layer=0)
+        part, load = moe_mlp(share, held, h, layer=0)
         total = total + (part - shared)
         loads.append(int(load.sum()))
     assert sum(loads) == 24 * 4 and min(loads) >= 0
@@ -111,8 +111,8 @@ def test_the_shares_routed_parts_and_the_gated_shared_expert_once_add_up():
     at8 = dataclasses.replace(share4, expert_offset=8)
     lp8 = dict(params["layers"], **{k: params["layers"][k][:, 8:12]
                                     for k in moe.STACKED})
-    part, _ = moe.moe_mlp(at8, dict(lp, **{k: lp[k][:, 8:12]
-                                           for k in moe.STACKED}), h, layer=0)
+    part, _ = moe_mlp(at8, dict(lp, **{k: lp[k][:, 8:12]
+                                       for k in moe.STACKED}), h, layer=0)
     assert float(jnp.abs(ref._experts(qwen3_next_keys(at8), mm, h[0], lp8, 0)
                          - part[0]).max()) < 1e-5
 
@@ -125,34 +125,13 @@ def test_the_shares_routed_parts_and_the_gated_shared_expert_once_add_up():
 ], ids=["tiny", "dv128", "four_a_key_head"])
 def test_the_step_kernel_at_grouped_heads_in_interpret_mode_is_step(
         key_heads, heads, dk, dv, live):
-    from ollamamq_tpu.ops.pallas.gated_delta_step import (
-        gated_delta_step_pallas, head_blocks)
-
-    rng = np.random.default_rng(7)
-    n, layers, rows = len(live), 3, 9
-    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
-    state0 = f(layers, rows, dk, heads * dv)
-    slots = jnp.asarray([5, 8, 0, 3, 8, 7], jnp.int32)  # 8: the trash row
-    live = jnp.asarray(live, bool)
-    reset = jnp.asarray([0, 0, 1, 0, 0, 0], bool)
-    q, k, v = f(n, key_heads, dk), f(n, key_heads, dk) + 1, f(n, heads, dv)
-    g = -jnp.abs(f(n, heads)) * 0.3
-    beta = jnp.asarray(rng.uniform(0, 1, size=(n, heads)), jnp.float32)
-    hg, hb = head_blocks(heads, dk, dv)
-    assert heads % hb == 0 and hb % hg == 0
-    o, new = gated_delta_step_pallas(state0, jnp.int32(1), slots, live,
-                                     reset, q, k, v, g, beta, interpret=True)
-    o_ref, s_ref = gd.step(state0[1][slots], q, k, v, g, beta, reset)
+    rows, q, k, v, g, beta, reset, o_ref = step_kernel_is_step(
+        key_heads, heads, dk, dv, live, strongest=1.0)
     # ... and `step` at grouped heads is `step` on the repeated heads
     rep = heads // key_heads
-    o_rep, _ = gd.step(state0[1][slots], jnp.repeat(q, rep, axis=1),
+    o_rep, _ = gd.step(rows, jnp.repeat(q, rep, axis=1),
                        jnp.repeat(k, rep, axis=1), v, g, beta, reset)
     close(o_ref, np.asarray(o_rep), atol=1e-6)
-    for i in np.flatnonzero(np.asarray(live)):
-        close(o[i], np.asarray(o_ref[i]), atol=1e-5)
-        close(new[1, slots[i]], np.asarray(s_ref[i]), atol=1e-5)
-    assert bool(jnp.all(o[~live] == 0.0))
-    assert bool(jnp.all(new[0] == state0[0]))
 
 
 def test_the_published_widths_meet_head_blocks_of_sixteen():
@@ -168,10 +147,11 @@ def test_chunked_at_grouped_heads_is_the_token_serial_recurrence():
     t, hk, h, dk, dv = 70, 2, 4, 8, 16
     q, k, v = f(t, hk, dk), f(t, hk, dk) + 1, f(t, h, dv)
     g, beta = -jnp.abs(f(t, h)) * 0.3, jax.nn.sigmoid(f(t, h))
-    o, s = gd.chunked(q[None], k[None], v[None], g[None], beta[None])
-    st, outs = jnp.zeros((dk, h * dv)), []
+    o, s = jax.jit(gd.chunked)(q[None], k[None], v[None], g[None],
+                               beta[None])
+    st, outs, step = jnp.zeros((dk, h * dv)), [], jax.jit(gd.step)
     for i in range(t):
-        o_i, st = gd.step(st, q[i], k[i], v[i], g[i], beta[i])
+        o_i, st = step(st, q[i], k[i], v[i], g[i], beta[i])
         outs.append(o_i)
     close(o[0], np.asarray(jnp.stack(outs)), atol=2e-5)
     close(s[0], np.asarray(st), atol=2e-5)
@@ -182,36 +162,10 @@ def test_the_served_forwards_through_the_kernels_match_the_jnp_path(
     """forward_ragged with `attn_impl` pallas in interpret mode (the Pallas
     attention kernel at a partial rotary embedding's heads, the step kernel
     at grouped heads, the grouped matmul) against the jnp path."""
-    from test_lfm2 import B, PS, page_table
-
     gmm = moe.grouped_matmul
     monkeypatch.setattr(moe, "grouped_matmul", lambda impl, xs, w, sizes:
                         gmm(impl, xs, w, sizes, interpret=True))
-
-    params = make_params()
-    seqs = {0: seq_tokens(40, 12), 1: seq_tokens(41, 30)}
-    st = state(QN, jnp.float32, garbage=0.5)
-    _, st, _ = ragged_step(QN, params, st, [(0, seqs[0][:11], 0)])
-    kc, vc, slot_state = st
-    tok = jnp.asarray(seqs[0][11:] + seqs[1] + [0], jnp.int32)
-    seq = jnp.asarray([0] + [1] * 30 + [0], jnp.int32)
-    pos = jnp.asarray([11] + list(range(30)) + [-1], jnp.int32)
-    pt = jnp.asarray(page_table())
-    slots = jnp.where(pos >= 0, pt[seq, jnp.maximum(pos, 0) // PS] * PS
-                      + jnp.maximum(pos, 0) % PS, 0)
-    args = dict(
-        tok_seq=seq, tok_pos=pos, write_slots=slots,
-        out_idx=jnp.asarray([0, 30, 0, 0]), k_cache=kc, v_cache=vc,
-        page_table=pt, q_start=jnp.asarray([0, 1, 32, 32]),
-        q_len=jnp.asarray([1, 30, 0, 0]), kv_len=jnp.asarray([12, 30, 0, 0]),
-        page_size=PS, conv_state=slot_state,
-        slot_ids=jnp.asarray([0, 1, B, B]), is_first=jnp.asarray([0, 1, 0, 0]))
-    want_, *_, want_state = llama.forward_ragged(params, QN, tok, **args)
-    got, *_, got_state = llama.forward_ragged(
-        params, QN, tok, **args, attn_impl="pallas", interpret=True)
-    close(got[:2], np.asarray(want_[:2]), atol=5 * ATOL)
-    close(got_state.rule[:, :2], np.asarray(want_state.rule[:, :2]),
-          atol=5e-4)
+    served_through_the_kernels(QN, make_params(), 5 * ATOL)
 
 
 def test_the_pair_loop_pins_a_row_at_a_whole_lane_tile_key_dim():
@@ -228,7 +182,8 @@ def test_the_pair_loop_pins_a_row_at_a_whole_lane_tile_key_dim():
         jnp.asarray([1, 2], jnp.int32), jnp.zeros(t, jnp.int32),
         jnp.arange(t, dtype=jnp.int32), jnp.asarray([0, t], jnp.int32),
         jnp.asarray([t, 0], jnp.int32), jnp.asarray([1, 0], jnp.int32))
-    o_ref, s_ref = gd.chunked(q[None], k[None], v[None], g[None], beta[None])
+    o_ref, s_ref = jax.jit(gd.chunked)(q[None], k[None], v[None], g[None],
+                                       beta[None])
     close(o, np.asarray(o_ref[0]), atol=1e-6)
     close(new[1, 1], np.asarray(s_ref[0]), atol=1e-6)
     assert bool(jnp.all(new[0] == 0.0)) and bool(jnp.all(new[1, 2] == 0.0))

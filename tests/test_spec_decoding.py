@@ -38,6 +38,7 @@ from ollamamq_tpu.ops.sampling import SamplingParams, accept_prefix
 from ollamamq_tpu.telemetry.journal import (Journal, check_invariants,
                                             explain)
 from ollamamq_tpu.testing.faults import FaultPlan
+from test_ragged_engine import tick
 
 _IDS = itertools.count(1)
 
@@ -66,13 +67,6 @@ def make_rt(spec, copy_weights=False, **kw):
         rt.params["layers"]["w_down"] = jnp.zeros_like(
             rt.params["layers"]["w_down"])
     return rt
-
-
-def tick(rt, core):
-    """One engine-loop-shaped tick: mixed/spec dispatch, else fused."""
-    ran = rt.step_ragged(core)
-    if not ran and any(r is not None for r in rt.slot_req):
-        rt.step_decode(core, k_steps=1)
 
 
 def run_all(rt, prompts, max_tokens=48, max_ticks=4000):

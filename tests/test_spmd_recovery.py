@@ -156,7 +156,6 @@ if pid == 0:
                      mesh=mesh, dtype=jnp.float32)
     rt = eng.runtimes["test-tiny"]
     n_replicas = len(rt.replicas)
-    eng.start()
 
     def wait(req, budget=300):
         deadline = time.monotonic() + budget
@@ -172,6 +171,9 @@ if pid == 0:
                                 prompt_tokens=list(prompt),
                                 sampling=SamplingParams(max_tokens=5))
             for i in range(2)]
+    # Both are queued before the loop's first tick: which replica serves
+    # which is the placement's doing, not a race under load (ROADMAP C7).
+    eng.start()
     items = [wait(r) for r in reqs]
     served = {id(rep): rep.tokens_generated for rep in rt.replicas}
     eng.stop()

@@ -23,8 +23,10 @@ Contracts pinned here:
     parent's `seq`.
 """
 
+import itertools
 import json
 import re
+import threading
 import time
 
 import pytest
@@ -37,12 +39,8 @@ from ollamamq_tpu.telemetry.stepprof import (_COMPILE_RING, _HBM_RING,
                                              _RING, _SHAPE_KEYS, PHASES,
                                              PROFILER, StepProfiler)
 from ollamamq_tpu.testing.faults import FaultPlan
+from test_degradation import _tpu_engine
 from testutil import collect
-
-
-TINY = dict(model="test-tiny", max_slots=2, num_pages=64, page_size=8,
-            max_pages_per_seq=16,
-            decode_steps_per_iter=2)
 
 
 @pytest.fixture(autouse=True)
@@ -50,20 +48,6 @@ def _fresh_profiler():
     PROFILER.reset()
     yield
     PROFILER.reset()
-
-
-def _tpu_engine(plan=None, **over):
-    import jax.numpy as jnp
-
-    from ollamamq_tpu.engine.engine import TPUEngine
-
-    cfg = dict(TINY)
-    cfg.update(over)
-    eng = TPUEngine(EngineConfig(fault_plan=plan, **cfg),
-                    models={"test-tiny": None}, blocklist_path=None,
-                    dtype=jnp.float32)
-    eng.start()
-    return eng
 
 
 def _run(eng, user, prompt="the quick brown fox jumps", max_tokens=8):
@@ -326,13 +310,45 @@ def _accounted_ms(sample):
         sample["loop_" + ph + "_ms"] for ph in stepprof.LOOP_PHASES)
 
 
-def test_engine_thread_time_is_gapless_over_consecutive_samples():
+class _OneClock:
+    """`stepprof`'s `time` for a test of its bookkeeping: every mark and
+    every sample's `ts` read ONE counter a thread, a millisecond a reading,
+    so a reading nobody accounts for is a millisecond missing whatever the
+    load (on the real clocks a 1 % bound failed under it: ROADMAP C7)."""
+
+    def __init__(self):
+        self._of_thread = threading.local()
+
+    def perf_counter(self):
+        mine = self._of_thread.__dict__.setdefault("n", itertools.count(1))
+        return next(mine) * 1e-3
+
+    time = perf_counter
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def _gapless(run, share=0.0):
+    """A `ts` is read two or three readings behind its sample's last mark:
+    that offset, at the run's two ends, is all that may separate what the
+    samples account for from the time between them (`share` of it more on
+    the real clocks, where the thread may be descheduled in between)."""
+    wall_ms = (run[-1]["ts"] - run[0]["ts"]) * 1e3
+    accounted = sum(_accounted_ms(smp) for smp in run[1:])
+    assert wall_ms >= 50 and abs(accounted - wall_ms) <= 4 + share * wall_ms, \
+        f"{run[0]['thread']}: accounted {accounted:.3f} of {wall_ms:.3f} ms"
+
+
+def test_engine_thread_time_is_gapless_over_consecutive_samples(monkeypatch):
     """ACCEPTANCE (PR 24): over any run of consecutive samples of one
-    engine thread, sum(total_ms + loop_*_ms) is the wall time between
-    them to within 1 % — with idle ticks (condvar waits between bursts),
-    abandoned timers (a step that starts its timer and returns early),
-    two runtimes on one engine thread, and a second engine thread
-    recording into the same process-wide ring."""
+    engine thread, sum(total_ms + loop_*_ms) is the time between them —
+    on `_OneClock`, to the few readings between a last mark and its `ts` —
+    with idle ticks (condvar waits between bursts), abandoned timers (a
+    step that starts its timer and returns early), two runtimes on one
+    engine thread, and a second engine thread recording into the same
+    process-wide ring."""
+    monkeypatch.setattr(stepprof, "time", _OneClock())
     a = _fake_engine(models=("test-tiny", "test-tiny-qwen"))
     b = _fake_engine()
     # Every other step of one runtime first opens a timer it abandons.
@@ -373,18 +389,13 @@ def test_engine_thread_time_is_gapless_over_consecutive_samples():
     assert a.loop_clock.name != b.loop_clock.name
     for name, run in by_thread.items():
         assert len(run) >= 10, (name, len(run))
-        wall_ms = (run[-1]["ts"] - run[0]["ts"]) * 1e3
-        accounted = sum(_accounted_ms(smp) for smp in run[1:])
-        assert abs(accounted - wall_ms) <= 0.01 * wall_ms, \
-            f"{name}: accounted {accounted:.3f} ms of {wall_ms:.3f} ms"
+        _gapless(run)
         # ... and over any sub-run, not just the whole one.
-        mid = len(run) // 2
-        wall_ms = (run[-1]["ts"] - run[mid]["ts"]) * 1e3
-        accounted = sum(_accounted_ms(smp) for smp in run[mid + 1:])
-        assert abs(accounted - wall_ms) <= 0.01 * wall_ms + 0.05
-        # Idle ticks were waits, admission was seen, abandoned timers
-        # and the rest of the tick are `other`.
-        assert sum(smp["loop_wait_ms"] for smp in run) > 150.0
+        _gapless(run[len(run) // 2:])
+        # Idle ticks were waits (three bursts: a reading at each end of a
+        # wait), admission was seen, abandoned timers and the rest of the
+        # tick are `other`.
+        assert sum(smp["loop_wait_ms"] for smp in run) >= 3.0
         assert sum(smp["loop_admit_ms"] for smp in run) > 0.0
         assert sum(smp["loop_other_ms"] for smp in run) > 0.0
     # The abandoned timers' millisecond each went to `other`, not lost.
@@ -422,24 +433,29 @@ def test_loop_fields_on_every_sample_and_in_the_histogram():
         assert count("loop_" + ph) > before[ph], ph
 
 
-def test_real_engine_loop_is_gapless_too():
+def test_real_engine_loop_is_gapless_too(monkeypatch):
     """TPUEngine._loop_once carries the same marks: its samples account
-    for the engine thread's wall time (idle ticks abandon step_ragged's
-    timer every 50 ms — those fold into `other`)."""
-    eng = _tpu_engine()
-    try:
-        for u in ("g1", "g2"):
-            assert collect(_run(eng, u, max_tokens=6))[-1].kind == "done"
-            time.sleep(0.12)
-    finally:
-        eng.stop()
-    run = [smp for smp in PROFILER.tail()
-           if smp["thread"] == eng.loop_clock.name]
-    assert len(run) >= 4 and len(run) == len(PROFILER.tail())
-    wall_ms = (run[-1]["ts"] - run[0]["ts"]) * 1e3
-    accounted = sum(_accounted_ms(smp) for smp in run[1:])
-    assert abs(accounted - wall_ms) <= 0.01 * wall_ms, (accounted, wall_ms)
-    assert sum(smp["loop_wait_ms"] for smp in run) > 50.0
+    for the engine thread's time (idle ticks abandon step_ragged's timer
+    every 50 ms — those fold into `other`) — on `_OneClock` to the reading,
+    and on the real clocks to a fifth of the wall: what a loaded machine
+    may take between a mark and a `ts`, where a lost phase loses far more."""
+    for clock, share, waited in ((_OneClock(), 0.0, 1.0), (time, 0.2, 50.0)):
+        monkeypatch.setattr(stepprof, "time", clock)
+        PROFILER.reset()
+        eng = _tpu_engine()
+        try:
+            for u in ("g1", "g2"):
+                assert collect(_run(eng, u, max_tokens=6))[-1].kind == "done"
+                time.sleep(0.12)
+        finally:
+            eng.stop()
+        run = [smp for smp in PROFILER.tail()
+               if smp["thread"] == eng.loop_clock.name]
+        assert len(run) >= 4 and len(run) == len(PROFILER.tail())
+        _gapless(run, share)
+        # the idle wait between the two requests: a reading at each of its
+        # ends, or (a sleep only oversleeps) the better part of its 120 ms
+        assert sum(smp["loop_wait_ms"] for smp in run) >= waited
 
 
 def test_two_interleaved_steps_stay_gapless_and_keep_their_own_phases():

@@ -28,6 +28,7 @@ from ollamamq_tpu.ops.sampling import SamplingParams
 from ollamamq_tpu.server.app import Server, _LoopWaker
 from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.telemetry.stepprof import PROFILER
+from test_step_overlap import _prompt, _rt
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=96, page_size=8,
             max_pages_per_seq=16,
@@ -40,14 +41,6 @@ def _engine(**over):
     return TPUEngine(EngineConfig(**dict(TINY, **over)),
                      models={"test-tiny": None}, blocklist_path=None,
                      dtype=jnp.float32)
-
-
-def _rt(eng):
-    return next(iter(eng.runtimes.values()))
-
-
-def _prompt(i, n):
-    return [10 + (7 * i + 3 * j) % 200 for j in range(n)]
 
 
 def drive(eng, arrivals, hook=None):

@@ -25,7 +25,7 @@ from ollamamq_tpu.telemetry import schema as tm
 from ollamamq_tpu.testing.faults import FaultPlan
 from ollamamq_tpu.tools.journal import (check_no_dropped_streams,
                                         check_regroup_pairing)
-from testutil import collect
+from testutil import _text, _wait, collect
 
 TINY = dict(model="test-tiny", max_slots=4, num_pages=64, page_size=8,
             max_pages_per_seq=8,
@@ -112,21 +112,8 @@ def _run(router, user, prompt="the quick brown fox jumps over",
                                   raw_prompt=prompt)
 
 
-def _text(items):
-    return "".join(i.text for i in items if i.kind == "token")
-
-
 def _member(router, name):
     return next(m for m in router.members if m.name == name)
-
-
-def _wait(pred, budget=30.0, period=0.01):
-    deadline = time.monotonic() + budget
-    while time.monotonic() < deadline:
-        if pred():
-            return True
-        time.sleep(period)
-    return False
 
 
 # ------------------------------------------------------------- assignment

@@ -310,7 +310,8 @@ def test_debug_profile_reply_holds_the_captures_own_samples(
         assert seen["options"].python_tracer_level == 0
         assert seen["capturing_at_stop"] is False and not PROFILER.capturing
         assert t_call <= cap["start_epoch"] < cap["stop_epoch"] <= t_reply
-        assert 0.3 <= cap["stop_epoch"] - cap["start_epoch"] < 0.4
+        # (no upper bound on the capture: a sleep under load oversleeps)
+        assert cap["stop_epoch"] - cap["start_epoch"] >= 0.3
         assert t_reply - cap["stop_epoch"] >= 0.4   # the call lasted longer
         got = out["stepprof"]
         assert len(got) >= 10
@@ -327,16 +328,20 @@ def test_debug_profile_reply_holds_the_captures_own_samples(
         (spans,) = _mq_lines(str(tmp_path)).values()
         assert {st["seq"] for n, _, _, st in spans
                 if n == "mq.dispatch"} <= set(seqs) | {max(seqs) + 1}
-        # The realtime clock, read before start_trace and again as the one
-        # mq.clock span began: their difference is the span's place on the
-        # trace's clock, which starts with the profiler's session.
+        # The realtime clock as the one mq.clock span began, less the span's
+        # place on the trace's clock: the instant the profiler's session
+        # started — INSIDE start_trace, between the reading before it and
+        # its return (how long that call takes is the machine's load; the
+        # 10 ms stand for the instructions between the span's reading of
+        # the clock and its start; another clock misses by seconds).
         assert t_call * 1e9 <= cap["origin_epoch_ns"] \
             <= cap["start_epoch"] * 1e9
         ((name, start_ns, _, st),) = [
             sp for line in _mq_lines(str(tmp_path), clock=True).values()
             for sp in line]
-        assert abs(start_ns - (st["epoch_ns"] - cap["origin_epoch_ns"])) \
-            < 1_000_000, (start_ns, st, cap)
+        assert cap["origin_epoch_ns"] - 10_000_000 \
+            <= st["epoch_ns"] - start_ns \
+            <= cap["start_epoch"] * 1e9 + 1_000, (start_ns, st, cap)
         # Asked for: no options at all — jax's own default, the capture
         # as it was before PR 52. And the default says the same as `false`.
         for body, want in (({"python_tracer": True}, True),

@@ -14,7 +14,6 @@ programs at the published widths compile with pool and rings updated in
 place and the window launches under names of their own."""
 
 import os
-import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -24,9 +23,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, MODEL_CONFIGS, WINDOW,
-                                 EngineConfig, ModelConfig)
-from ollamamq_tpu.models import llama
-from ollamamq_tpu.ops.attention import (WindowRing, alloc_ring,
+                                 EngineConfig)
+from ollamamq_tpu.ops.attention import (alloc_ring,
                                         paged_decode_attention_any,
                                         ragged_attention_any,
                                         ragged_paged_attention,
@@ -341,68 +339,3 @@ def test_step_samples_carry_the_window_counters(monkeypatch):
         assert s["swa_full_rows"] == (
             s["attn_pairs"] if s["mode"] == "decode" else s["attn_ctx_rows"])
     assert any(s["swa_walk_rows"] < s["swa_full_rows"] for s in samples)
-
-
-# -------------------------------------------- the chip's compiler, no chip
-# K-EXAONE-236B-A23B's layers over the cell's five (dense + L L G L), a
-# small vocabulary and 4 of 128 experts held: 64 / 8 heads of 128 in both
-# kernels, rings of 672 rows a slot.
-KX_WIDTHS = ModelConfig(
-    name="chip-compile-k-exaone-widths", vocab_size=2048, hidden_size=6144,
-    intermediate_size=18432, num_layers=5, num_heads=64, num_kv_heads=8,
-    head_dim=128, max_seq_len=256 * 32, rope_theta=1e6, rms_norm_eps=1e-5,
-    qk_norm="head", sliding_window=128, sliding_window_pattern="LLLG",
-    layer_types=("sliding_attention",) * 3 + ("full_attention",
-                                              "sliding_attention"),
-    rope_layer_types=("sliding_attention",), num_experts=4,
-    router_experts=128, num_experts_per_tok=8, num_shared_experts=1,
-    moe_intermediate_size=2048, first_k_dense_replace=1,
-    scoring_func="sigmoid", use_expert_bias=True, norm_topk_prob=True,
-    norm_topk_eps=1e-20, routed_scaling_factor=2.5)
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-@pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
-def test_k_exaone_width_step_programs_carry_pool_and_rings_in_place(
-        v5e, which, monkeypatch):
-    """Window and full attention in one stack (PR 50), at K-EXAONE's widths:
-    both kernels at 8 kv heads of 128 under group 8 compile for the chip
-    with a window — under names of their own, three call sites for the four
-    window layers (two of them one scan's) beside ONE of the full layer's
-    name; the pool — for the ONE full layer — the rings (4 layers x 65 slots
-    x 672 rows), the penalty ring and the id carry all come back aliased."""
-    from test_chip_compile import B, NP, PS as CPS, _lower_step_program
-
-    lowered, _, carried = _lower_step_program(v5e, which, monkeypatch,
-                                              KX_WIDTHS)
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    swa = {"mq_ragged_step": ragged_attention.WINDOW_NAME,
-           "mq_decode_scan": paged_attention.WINDOW_NAME}[which]
-    full = {"mq_ragged_step": "ragged_paged_attention_pallas",
-            "mq_decode_scan": "paged_decode_attention_pallas"}[which]
-    assert len(re.findall(rf"%{swa}[.\d]* = ", text)) == 3
-    assert len(re.findall(rf"%{full}[.\d]* = ", text)) == 1
-    rows = KX_WIDTHS.ring_rows(64, CPS)
-    rings_b = 2 * 4 * (B + 1) * rows * 1024 * 2
-    pool_b = 2 * 1 * NP * CPS * 1024 * 2
-    assert carried >= rings_b + pool_b
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= carried, (mem, carried)

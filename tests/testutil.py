@@ -1,5 +1,6 @@
 """Shared test helpers (pytest puts this directory on sys.path)."""
 
+import functools
 import time
 
 
@@ -15,6 +16,19 @@ def collect(req, timeout=120):
         if item.kind in ("done", "error"):
             return items
     raise TimeoutError(f"request {req.req_id} did not finish; got {items}")
+
+
+def _text(items):
+    return "".join(i.text for i in items if i.kind == "token")
+
+
+def _wait(pred, budget=30.0, period=0.01):
+    deadline = time.monotonic() + budget
+    while time.monotonic() < deadline:
+        if pred():
+            return True
+        time.sleep(period)
+    return False
 
 
 def free_port() -> int:
@@ -108,16 +122,7 @@ def single_device_greedy_tokens(model, prompt, max_tokens=6, **ecfg_kw):
 def olmoe_reference():
     """The benchmark's plain float32 reference of the sparse family
     (benchmarks/reference/olmoe_decoder.py), as a module."""
-    import importlib.util
-    import os
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks", "reference",
-        "olmoe_decoder.py")
-    spec = importlib.util.spec_from_file_location("olmoe_decoder", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _reference("olmoe_decoder")
 
 
 def reference_keys(mc) -> dict:
@@ -132,6 +137,139 @@ def reference_keys(mc) -> dict:
             "tie_word_embeddings": mc.tie_embeddings}
 
 
+@functools.cache
+def _drawn(mc, dtype, seed, norms, top_norms):
+    import jax
+    import jax.numpy as jnp
+
+    from ollamamq_tpu.models import llama
+
+    def about(base, i, w):
+        return (base + 0.5 * jax.random.normal(
+            jax.random.PRNGKey(100 + i), w.shape, jnp.float32)).astype(dtype)
+
+    params = llama.init_params(mc, jax.random.PRNGKey(seed), dtype=dtype)
+    for i, name in enumerate(norms):
+        if name in params["layers"]:
+            params["layers"][name] = about(
+                0.0 if name.endswith("_bias") else 1.0, i,
+                params["layers"][name])
+    for i, name in enumerate(top_norms):
+        if name in params:
+            params[name] = about(1.0, 20 + i, params[name])
+    return params
+
+
+def seeded_params(mc, norms, dtype=None, seed=0, top_norms=()):
+    """`init_params(mc, PRNGKey(seed))` with the layers' `norms` (and the
+    `top_norms` beside `embed`) drawn about one — a `..._bias` about zero —
+    so a norm on the wrong axis, or left out, cannot pass. Drawn ONCE a
+    process: every caller gets dicts of its own over the same arrays."""
+    import jax.numpy as jnp
+
+    params = _drawn(mc, dtype or jnp.float32, seed, tuple(norms),
+                    tuple(top_norms))
+    return {**params, "layers": dict(params["layers"])}
+
+
+def once_a_sequence(want):
+    """`want(...)`, a reference's full forward of one sequence, computed once
+    a process for the same arguments: weights are the same where their ARRAYS
+    are (`seeded_params`'s; a case that swapped one gets a forward of its
+    own), tokens where their values are. What comes back is read-only."""
+    import jax
+    import numpy as np
+
+    seen = {}
+
+    def part(a):
+        if isinstance(a, dict):
+            return tuple(map(id, jax.tree_util.tree_leaves(a)))
+        if isinstance(a, (list, np.ndarray)):
+            return tuple(np.asarray(a).ravel().tolist())
+        return a
+
+    @functools.wraps(want)
+    def cached(*args, **kw):
+        key = (tuple(map(part, args)),
+               tuple((k, part(v)) for k, v in sorted(kw.items())))
+        if key not in seen:
+            out = want(*args, **kw)
+            for a in jax.tree_util.tree_leaves(out):
+                a.flags.writeable = False
+            seen[key] = (out, args, kw)  # (held: an id is its array's)
+        return seen[key][0]
+
+    return cached
+
+
+def span_stream(spans, pad_to, pt, ps):
+    """`spans` = [(row, tokens, start position)] as a ragged step's arrays,
+    the stream padded to `pad_to`, row r on the pages of `pt[r]`: ((tokens,
+    tok_seq, tok_pos, write_slots), (q_start, q_len, kv_len)), int32."""
+    import numpy as np
+
+    tok, seq, pos = [], [], []
+    q_start = np.full(len(pt), pad_to, np.int32)
+    q_len, kv_len = (np.zeros(len(pt), np.int32) for _ in range(2))
+    for row, toks, start in spans:
+        q_start[row], q_len[row] = len(tok), len(toks)
+        kv_len[row] = start + len(toks)
+        tok += list(toks)
+        seq += [row] * len(toks)
+        pos += list(range(start, start + len(toks)))
+    tok, seq, pos = (np.asarray(a + [f] * (pad_to - len(a)), np.int32)
+                     for a, f in ((tok, 0), (seq, 0), (pos, -1)))
+    at = np.maximum(pos, 0)
+    slots = np.where(pos >= 0, pt[seq, at // ps] * ps + at % ps, 0)
+    return (tok, seq, pos, slots), (q_start, q_len, kv_len)
+
+
+@functools.cache
+def _one_program(fn, nums=(), names=()):
+    """`fn` as ONE program a (static arguments, shapes): bare, a forward of
+    the program's dispatches — and compiles — an op at a time, 10–30 s a
+    case. (Not for a case that patches what `fn` calls: a cached trace does
+    not see the patch.)"""
+    import jax
+
+    return jax.jit(fn, static_argnums=nums, static_argnames=names)
+
+
+def prefill(*args):
+    """`llama.forward_prefill(params, cfg, ..., page_size)`."""
+    from ollamamq_tpu.models import llama
+
+    return _one_program(llama.forward_prefill, (1, 7))(*args)
+
+
+def embed(*args):
+    """`llama.forward_embed(params, cfg, tokens, seq_lens)`."""
+    from ollamamq_tpu.models import llama
+
+    return _one_program(llama.forward_embed, 1)(*args)
+
+
+def moe_mlp(cfg, lp, h, layer=None):
+    """`moe.moe_mlp(cfg, lp, h, layer=layer)`."""
+    from ollamamq_tpu.models import moe
+
+    return _one_program(moe.moe_mlp, 0, "layer")(cfg, lp, h, layer=layer)
+
+
+def whole_blocks(tokens, block=32):
+    """`tokens` as int32 with a filler behind, up to whole `block`s: a
+    reference that forwards a sequence op by op compiles every op once a
+    LENGTH, and causal attention, a causal convolution and a token-serial
+    rule keep what stands behind a position from it (the references that pad
+    themselves, `QUERY_BLOCK`, say so). The caller keeps `len(tokens)` rows."""
+    import jax.numpy as jnp
+
+    return jnp.full((-(-len(tokens) // block) * block,), 7, jnp.int32
+                    ).at[:len(tokens)].set(jnp.asarray(tokens, jnp.int32))
+
+
+@functools.cache
 def _reference(name: str):
     import importlib.util
     import os
