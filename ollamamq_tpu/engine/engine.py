@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import collections
 import copy
-import functools
 import itertools
 import logging
 import os
@@ -42,25 +41,21 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (ATTENTION, CONV, CROSS, EXPERTS, LINEAR,
-                                 MAMBA, PARALLEL, WINDOW,
-                                 STATE_KINDS, EngineConfig, ModelConfig,
+from ollamamq_tpu.config import (ATTENTION, STATE_KINDS, EngineConfig,
+                                 ModelConfig,
                                  get_model_config, smart_match,
                                  validate_latent_pool, validate_quant_config,
                                  validate_slot_state)
 from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
-from ollamamq_tpu.engine import step_pack
+from ollamamq_tpu.engine import step_program, step_work
 from ollamamq_tpu.engine.request import (FinishReason, Request, StreamItem,
                                          wake_batch)
 from ollamamq_tpu.engine.scheduler import make_policy
 from ollamamq_tpu.engine.tokenizer import load_tokenizer
-from ollamamq_tpu.models import llama, moe, weights
-from ollamamq_tpu.ops.attention import ring_first_page
-from ollamamq_tpu.ops.sampling import (accept_prefix, maybe_apply_penalties,
-                                       per_row_keys, sample_tokens_rowwise,
-                                       sampling_flags)
+from ollamamq_tpu.models import llama, weights
+from ollamamq_tpu.ops.sampling import sampling_flags
 from ollamamq_tpu.parallel.mesh import (make_mesh, replica_submesh,
                                         validate_tp_for_model)
 from ollamamq_tpu.parallel.sharding import kv_cache_spec, shard_params
@@ -269,14 +264,17 @@ class PeerDeadError(WorkerDesyncError):
     (reference detects a dead backend in ~10s, dispatcher.rs:385)."""
 
 
-def _sp_compile_evict(rt, cache, key_) -> None:
+def _sp_compile_evict(rt, cache, key_) -> bool:
     """faults.py "compile" site: a fired rule evicts the jit cache entry
     before the lookup, so the next fill re-traces — the injected
     recompile loop the compile_storm health alert is tested against.
-    Observer-style (draw): the eviction IS the enacted fault."""
+    Observer-style (draw): the eviction IS the enacted fault. True where
+    it fired: a step program's builder then hands out a fresh jit too."""
     fp = getattr(rt, "fault_plan", None)
     if fp is not None and key_ in cache and fp.draw("compile"):
         cache.pop(key_, None)
+        return True
+    return False
 
 
 def _sp_note_compile(rt, site: str, key_, cache, fn):
@@ -432,7 +430,7 @@ class StepInFlight:
     fused scan's are its active slots with span = `k_steps` (0 = a
     ragged step). `emits[i]`: whether the host emits an id for row i (a
     span inside a prompt samples nothing); `exited`: the stream tokens
-    that left a stack with an `exit_layer` there (`_note_exit`: what the
+    that left a stack with an `exit_layer` there (`StepWork.note`: what the
     step's FLOPs are reckoned by); where a row starts is not
     kept here: steps settle in launch order, so at its settle it is what
     the slot's settled ids say (`step_settle`), whatever the verify spans
@@ -495,23 +493,10 @@ class ModelRuntime:
     # output-length prediction.
     policy = None
 
-    # What `_note_latent` writes onto a step's sample, in order.
-    LATENT_FIELDS = ("mla_rows", "dsa_ctx_tokens", "dsa_selected_tokens",
-                     "dsa_step_ctx_tokens", "dsa_step_selected_tokens",
-                     "mla_wide_tokens", "mla_absorbed_rows")
-    # ...and for latent attention with no indexer (nothing is scored or
-    # selected: the `dsa_*` fields have no honest value there).
-    DENSE_LATENT_FIELDS = ("mla_rows", "mla_pairs", "mla_ctx_rows")
-    # What `_note_attn` writes (plain K/V attention: no latent pool).
-    ATTN_FIELDS = ("attn_pairs", "attn_ctx_rows", "attn_tall_tokens")
-
     # Engine performance plane (telemetry/stepprof.py): the per-step
     # "paid a compile" flag (_sp_note_compile sets, the step's finish
     # read-and-clears). A step's timer rides its StepInFlight handle.
     _stepprof_compiled = False
-    # Is this runtime's --spec proposer the model's own prediction module
-    # (set in __init__; False for a runtime built without it)?
-    mtp = False
     # The owning engine thread's loop clock (stepprof.LoopClock), attached
     # by _attach_hooks: step timers advance it, so step and loop phases
     # form one gapless chain. None (unit tests) times steps alone.
@@ -630,14 +615,9 @@ class ModelRuntime:
         # one), so one step's rows are all the carry ever has to hold.
         self.last_ids = jnp.zeros((engine_cfg.max_slots,), jnp.int32)
         # The per-slot state of the layers that keep one (fixed size, no
-        # pages): the conv layers' window (ops/shortconv.py's array), for a
-        # model with linear-attention layers a llama.SlotState of their
-        # convolution's window and the rule's float32 matrices
-        # (ops/gated_delta.py) — for a model with window layers a
-        # llama.WindowState with their K/V rings too
-        # (ops/attention.py:WindowRing: `ring_rows` rows a slot a
-        # layer whatever the context; the paged pool then holds the FULL
-        # layers only) — None for a model without such layers. With
+        # pages: a llama.SlotState — a window layer's ring is `ring_rows`
+        # rows a slot whatever the context, and the paged pool then holds
+        # the FULL layers only), None for a model without such layers. With
         # kc, vc, recent and last_ids a donated argument and result of
         # every step program. Never reset from the host: a request's first
         # span opens its slot's rows at zero inside the program
@@ -754,37 +734,22 @@ class ModelRuntime:
         # model's query group (ops/pallas/kv_contract.py): every launch of
         # that kernel, for the runtime's life. None without the kernels.
         self.attn_inner = None
-        # ...and the ragged kernel's own test of which stream tokens it
-        # serves a whole stretch at a time (`_note_attn`).
-        self._tall_tokens = None
-        # ...and the masked latent kernel's of which it attends in the
-        # expanded form, and of the rows a layer then takes through the
-        # absorbed form's contractions (`_note_latent`).
-        self._wide_tokens = self._absorbed_rows = None
         if self.attn_impl == "pallas":
-            from ollamamq_tpu.ops.pallas.kv_contract import (inner_report,
-                                                             tall_tokens)
+            from ollamamq_tpu.ops.pallas.kv_contract import inner_report
             self.attn_inner = inner_report(
                 model_cfg.num_heads // model_cfg.num_kv_heads)
-            self._tall_tokens = tall_tokens
-            if model_cfg.index_topk:
-                from ollamamq_tpu.ops.pallas.mla_attention import (
-                    absorbed_rows, wide_tokens)
-                self._wide_tokens, self._absorbed_rows = (
-                    functools.partial(
-                        fn, heads=model_cfg.num_heads,
-                        lanes=model_cfg.latent_lanes,
-                        rank=model_cfg.kv_lora_rank,
-                        nope=model_cfg.qk_nope_head_dim,
-                        v=model_cfg.v_head_dim)
-                    for fn in (wide_tokens, absorbed_rows))
+        # What a launched step's layers did (engine/step_work.py), and what
+        # of `engine_cfg` a step program's shapes depend on.
+        self.work = step_work.StepWork(
+            model_cfg, engine_cfg.page_size, name,
+            step_work.kernel_counts(model_cfg, self.attn_impl))
+        self.dims = step_program.StepDims.of(engine_cfg)
         log.info("%s: attention=%s (%s)%s", name, self.attn_impl, why,
                  "".join(f" {k}={v}" for k, v in
                          (self.attn_inner or {}).items()))
         # Ragged mixed-batch scheduling: prefill spans + decode tokens
         # pack into ONE token-budget dispatch (no bucket padding).
         g = max(1, engine_cfg.token_granule)
-        self._granule = g
         self._ragged_budget = ragged_budget(engine_cfg)
         # Allowed stream totals: a power-of-two ladder over the granule,
         # capped by the budget — one compile per rung; the composer TRIMS
@@ -802,23 +767,19 @@ class ModelRuntime:
         # Speculative decoding state (--spec): n-gram drafts verified on
         # the ragged span path. Host-side accounting feeds the accept-
         # rate gauge and the per-user auto-throttle; the actual accept/
-        # rollback machinery lives in _get_ragged_jit / step_ragged.
+        # rollback machinery lives in step_program.ragged_step / step_ragged.
         self.spec = bool(engine_cfg.spec) and engine_cfg.spec_k > 0
         # The proposer: the model's own multi-token-prediction module where
-        # it has one — ONE draft a row, computed on the device inside the
-        # step that verifies the last (llama.forward_mtp) and kept there:
-        # `draft_ids[slot]` (row S: the padding rows') is what the module
-        # predicts to follow the slot's next input token, a donated argument
-        # and result of every ragged step like `last_ids`; the host never
-        # sees a draft, only how many were accepted. `_draft_ok[slot]`: a
-        # step with the module has left the slot's draft there. N-gram
-        # prompt lookup on the host otherwise.
-        # Beside it, `len_ids[slot]`: the slot's LENGTH (the position of its
-        # next input token), which the same program keeps — a verify span
-        # adds 1 or 2 and only the device knows which until the step is
-        # collected. A decode or verify row's positions, write slots and
-        # `kv_len` are derived from it in the program, so the next step is
-        # composed and launched while this one runs (`may_overlap`).
+        # it has one — ONE draft a row, computed and kept on the device by
+        # the step that verifies the last: `draft_ids[slot]` (row S: the
+        # padding rows') and, beside it, `len_ids[slot]`, the slot's LENGTH,
+        # two more donated carries of every ragged step
+        # (step_program.ragged_step says what the program does with them);
+        # the host never sees a draft, only how many were accepted, so the
+        # next step is composed and launched while this one runs
+        # (`may_overlap`). `_draft_ok[slot]`: a step with the module has
+        # left the slot's draft there. N-gram prompt lookup on the host
+        # otherwise.
         self.mtp = self.spec and model_cfg.num_nextn_predict_layers > 0
         self.spec_k = 1 if self.mtp else engine_cfg.spec_k
         self.draft_ids = self.len_ids = None
@@ -873,12 +834,6 @@ class ModelRuntime:
         self._tm_prefill = tm.PREFILL_LATENCY_MS.labels(model=name)
         self._tm_occupancy = tm.BATCH_OCCUPANCY.labels(model=name)
         self._tm_padding = tm.BATCH_PADDING_WASTE.labels(model=name)
-        if model_cfg.num_experts:
-            self._tm_moe_assign = tm.MOE_ASSIGNMENTS_TOTAL.labels(model=name)
-            self._tm_moe_hit = tm.MOE_EXPERT_PAIRS_HIT_TOTAL.labels(
-                model=name)
-            self._tm_moe_max = tm.MOE_EXPERT_LOAD_MAX.labels(model=name)
-            self._tm_moe_mean = tm.MOE_EXPERT_LOAD_MEAN.labels(model=name)
         self._tm_pages = tm.KV_PAGES_USED.labels(model=name)
         self._tm_page_util = tm.KV_PAGE_UTILIZATION.labels(model=name)
         self._tm_mfu = tm.MFU.labels(model=name)
@@ -930,67 +885,18 @@ class ModelRuntime:
                      self.weight_stacks_relaid, relaid_bytes / 1e6)
         # What a deployment is sized by: the fixed per-slot state, and
         # what each token of context adds to the pool.
-        conv, rule, ring = llama.split_state(self.slot_state)
-        ssm = s6 = None  # a mixer's or a scan's state rides where the rule's does
-        if isinstance(self.slot_state, llama.SsmState):
-            rule, ssm = None, rule
-        elif isinstance(self.slot_state, llama.ScanState):
-            rule, s6 = None, rule
-        (self.conv_state_bytes, self.lin_state_bytes, self.ring_bytes,
-         self.ssm_state_bytes, self.s6_state_bytes) = (
-            0 if a is None else int(a.nbytes)
-            for a in (conv, rule, ring, ssm, s6))
-        tm.HBM_S6_STATE_BYTES.labels(model=name).set(self.s6_state_bytes)
-        tm.HBM_CONV_STATE_BYTES.labels(model=name).set(self.conv_state_bytes)
-        tm.HBM_LIN_STATE_BYTES.labels(model=name).set(self.lin_state_bytes)
-        tm.HBM_SSM_STATE_BYTES.labels(model=name).set(self.ssm_state_bytes)
-        tm.HBM_SWA_RING_BYTES.labels(model=name).set(self.ring_bytes)
-        self._tm_swa = [c.labels(model=name) for c in (
-            tm.SWA_PAIRS_TOTAL, tm.SWA_CTX_ROWS_TOTAL,
-            tm.SWA_WALK_ROWS_TOTAL, tm.SWA_FULL_ROWS_TOTAL)]
-        if ring is not None:
-            log.info("%s: window layers' K/V rings %.1f MB (%d layers x %d "
-                     "slots x %d rows x %d B, whatever the context) beside "
-                     "the paged pool's %.1f MB (%d full layers)", name,
-                     self.ring_bytes / 1e6, model_cfg.count(WINDOW),
-                     engine_cfg.max_slots, ring.rows,
-                     2 * model_cfg.kv_dim * jnp.dtype(dtype).itemsize,
+        self.state_bytes = step_work.state_bytes(self.slot_state, name)
+        if self.slot_state is not None:
+            log.info("%s: per-slot state %.1f MB for %d slots, whatever the "
+                     "context (%s) beside the pool's %.1f MB (%d paged "
+                     "layers)", name, sum(self.state_bytes.values()) / 1e6,
+                     engine_cfg.max_slots,
+                     ", ".join(f"{k} {n / 1e6:.1f} MB"
+                               for k, n in self.state_bytes.items() if n),
                      self.kv_bytes / 1e6, model_cfg.cache_layers)
-            if s6 is not None:
-                log.info("%s: %d mamba layers' scan state %.1f MB (float32) "
-                         "and conv window %.1f MB; %d cross layers read pool "
-                         "layer %d and keep none; rows nobody samples stop "
-                         "at layer %d", name, model_cfg.count(MAMBA),
-                         self.s6_state_bytes / 1e6,
-                         self.conv_state_bytes / 1e6, model_cfg.count(CROSS),
-                         model_cfg.count(ATTENTION) - 1,
-                         model_cfg.exit_layer)
-        elif self.slot_state is not None:
-            log.info("%s: per-slot state %.1f MB (conv window %.1f MB, rule "
-                     "state %.1f MB, mixer state %.1f MB, float32) for %d "
-                     "slots beside the KV pool's %.1f MB", name,
-                     (self.conv_state_bytes + self.lin_state_bytes
-                      + self.ssm_state_bytes) / 1e6,
-                     self.conv_state_bytes / 1e6, self.lin_state_bytes / 1e6,
-                     self.ssm_state_bytes / 1e6, engine_cfg.max_slots,
-                     self.kv_bytes / 1e6)
         tm.KV_BYTES_PER_TOKEN.labels(model=name).set(
             kvc.kv_page_bytes(model_cfg, 1, jnp.dtype(dtype).itemsize,
                               engine_cfg.kv_dtype))
-        self._tm_conv_resets = tm.CONV_STATE_RESETS_TOTAL.labels(model=name)
-        self._tm_conv_carried = tm.CONV_STATE_CARRIED_TOTAL.labels(model=name)
-        self._tm_lin = [c.labels(model=name) for c in (
-            tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
-            tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL)]
-        self._tm_ssm = [c.labels(model=name) for c in (
-            tm.SSM_STATE_RESETS_TOTAL, tm.SSM_STATE_CARRIED_TOTAL,
-            tm.SSM_STEP_ROWS_TOTAL, tm.SSM_SPAN_TOKENS_TOTAL)]
-        self._tm_s6 = [c.labels(model=name) for c in (
-            tm.S6_STATE_RESETS_TOTAL, tm.S6_STATE_CARRIED_TOTAL,
-            tm.S6_STEP_ROWS_TOTAL, tm.S6_SPAN_TOKENS_TOTAL)]
-        self._tm_exit = [c.labels(model=name) for c in (
-            tm.XATTN_ROWS_TOTAL, tm.XATTN_CTX_ROWS_TOTAL,
-            tm.EXIT_SKIPPED_TOKENS_TOTAL)]
         # Latent attention: the two pools' sizes (both are in kv_bytes) and
         # what the indexer scored and attention saw.
         if model_cfg.kv_lora_rank:
@@ -1006,13 +912,6 @@ class ModelRuntime:
                          model_cfg.count(ATTENTION),
                          "--spec drafts with it, on the device" if self.mtp
                          else "not run without --spec")
-        self._tm_dsa = [c.labels(model=name) for c in (
-            tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
-            tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL,
-            tm.MLA_ABSORBED_ROWS_TOTAL)]
-        self._tm_attn = [c.labels(model=name) for c in (
-            tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
-            tm.ATTN_TALL_TOKENS_TOTAL)]
 
     # -- capacity ----------------------------------------------------------
     def free_slots(self) -> int:
@@ -1123,7 +1022,7 @@ class ModelRuntime:
         # can target the verify span without perturbing plain mixed
         # dispatches (and vice versa).
         self._fault("spec_verify" if k_cap else "ragged")
-        lay = self._ragged_layout(T_pad)
+        lay = self.dims.ragged_layout(T_pad)
         fn = self._get_ragged_jit(
             T_pad, k_cap, sampling_flags(*lay.sampling(buf)))
         if not self.mtp:
@@ -1136,521 +1035,36 @@ class ModelRuntime:
             self.last_ids, self.slot_state, self.draft_ids, self.len_ids)
         return tuple(out)
 
-    def _ragged_layout(self, T_pad: int) -> step_pack.StepLayout:
-        e = self.ecfg
-        return step_pack.ragged_layout(T_pad, e.max_slots,
-                                       e.max_pages_per_seq, e.repeat_last_n)
-
-    def _decode_layout(self) -> step_pack.StepLayout:
-        return step_pack.decode_layout(self.ecfg.max_slots,
-                                       self.ecfg.max_pages_per_seq)
+    def _program(self, cache, site, key_, build, *shape, **kw):
+        """The per-runtime compile ledger and nothing else: the program
+        `build(cfg, dims, *shape, …)` (engine/step_program.py) is in `cache`
+        under `key_` once it was asked for, behind the wrapper that times
+        its first call (`_sp_note_compile`); a fired `compile` fault drops
+        it, and the builder's copy with it."""
+        evicted = _sp_compile_evict(self, cache, key_)
+        if key_ not in cache:
+            _sp_note_compile(self, site, key_, cache, build(
+                self.cfg, self.dims, *shape, attn_impl=self.attn_impl,
+                mesh=self.mesh, fresh=evicted, **kw))
+        return cache[key_]
 
     def _get_ragged_jit(self, T_pad: int, k_cap: int = 0,
                         flags=(True, True, True)):
-        """ONE mixed-batch step: forward the flattened [T_pad] token
-        stream (prefill spans + decode tokens + speculative verify
-        spans) through forward_ragged, then per-sequence penalty-ring
-        maintenance and sampling. Compiles once per
-        (padded token total, draft cap, sampling flags); the engine pads
-        totals to the token granule and uses only k_cap in {0, spec_k},
-        so the variant count stays small.
-
-        Speculative rows (is_spec=1) carry a (d+1)-token span
-        [last_token, draft_1..draft_d]: the forward reads a logit at
-        EVERY span position, greedy verification accepts the longest
-        prefix where draft == argmax (ops/sampling.accept_prefix), the
-        model's own next token caps the emission, and the penalty ring
-        advances by the ACCEPTED count — never by k — so ring state is
-        byte-identical to emitting the same tokens one step at a time.
-        A token < 0 in `tokens` is -1 - r: "the id row r of the step
-        before this one sampled", read from the `last_ids` carry (that
-        step may still be running; the host has not seen the id).
-        Returns (toks [S, k_cap+1], n_emit [S], caches', recent',
-        last_ids', conv'): row i emits toks[i, :n_emit[i]], the carry is
-        now this step's last id of every row, and each row's slot of the
-        per-slot state (`conv`: the window's array, or a llama.SlotState)
-        holds its span's last positions and, for linear-attention layers,
-        the rule's state after the span (opened at zero where the span is
-        its request's first: models/llama.py:forward_ragged). An MoE model's `toks` has three more
-        rows: the pass's expert-load counters (moe.LOAD_STATS) ride back
-        with the ids, in the transfer the collect makes anyway.
-
-        A runtime whose proposer is the model's prediction module (`mtp`)
-        takes and returns two more carries, [S + 1] by slot. `drafts`: a
-        spec row's draft is read from it into the stream (the host wrote a
-        placeholder), and after the trunk the module runs over the whole
-        stream — each position with the token that follows it: the next of
-        its span, what the trunk chose at a verify span's positions, the id
-        just sampled at a row's last, `next_tok` where a span ends inside
-        its prompt — and leaves at each row's slot its prediction of the
-        token after next, read at the row's last ACCEPTED position.
-        `lens`: each slot's length, the position of its next input token.
-        The host does not know it while a verify span is unsettled, so a
-        decode or verify row comes marked "from the carry" — `kv_len` < 0,
-        and `tok_pos` -2 - j at the span's j-th token — and the program
-        derives its positions `lens[slot] + j`, its write slots through the
-        row's page-table row and `kv_len = lens[slot] + q_len`; a prompt's
-        span comes host-written as ever. Every row leaves its slot's new
-        length: a span its end, a decode row one more, a verify span
-        `n_emit` more."""
-        key_ = ("ragged", T_pad, k_cap, flags)
-        _sp_compile_evict(self, self._prefill_jits, key_)
-        if key_ not in self._prefill_jits:
-            cfg, ps = self.cfg, self.ecfg.page_size
-            attn_impl, mesh = self.attn_impl, self.mesh
-            need_pen, need_mask, need_sample = flags
-            O = k_cap + 1
-            mtp = self.mtp
-
-            lay = self._ragged_layout(T_pad)
-
-            def mq_ragged_step(params, buf, kc, vc, recent, last_ids, conv,
-                               drafts=None, lens=None):
-                (tokens, tok_seq, tok_pos, write_slots, q_start, q_len,
-                 kv_len, ring_len, is_first, append, is_spec, next_tok,
-                 seed_rows, slot_ids, pt, temp, tk, tp, pen, pres, freq,
-                 seeds, rng) = lay.unpack(buf)
-                if mtp:
-                    # Rows from the carry: where they are is the device's
-                    # to say (the step before may still be running).
-                    start = lens[slot_ids]
-                    kv_len = jnp.where(kv_len < 0, start + q_len, kv_len)
-                    pos = start[tok_seq] - 2 - tok_pos
-                    MP = pt.shape[1]
-                    page = pt.reshape(-1)[
-                        tok_seq * MP + jnp.clip(pos // ps, 0, MP - 1)]
-                    carried = tok_pos < -1
-                    write_slots = jnp.where(carried, page * ps + pos % ps,
-                                            write_slots)
-                    tok_pos = jnp.where(carried, pos, tok_pos)
-                key = jax.random.PRNGKey(rng[0])
-                tokens = jnp.where(
-                    tokens < 0,
-                    last_ids[jnp.clip(-1 - tokens, 0, last_ids.shape[0] - 1)],
-                    tokens)
-                spec = is_spec > 0
-                if mtp:
-                    # A spec row's one draft: the module's, from the carry
-                    # (a row that is no spec row writes past the stream).
-                    tokens = tokens.at[
-                        jnp.where(spec, q_start + 1, T_pad)
-                    ].set(drafts[slot_ids], mode="drop")
-                # Logit read positions: non-spec rows read only their
-                # last valid token (every column aliases it — prefill
-                # spans can be longer than O); spec rows read every span
-                # position, so column j holds the argmax that verifies
-                # draft j+1 (and column `accepted` the bonus token).
-                j = jnp.arange(O)[None, :]
-                col = jnp.where(spec[:, None],
-                                jnp.minimum(j, q_len[:, None] - 1),
-                                q_len[:, None] - 1)
-                out_idx = jnp.clip(q_start[:, None] + col, 0, T_pad - 1)
-                logits, kc, vc, *rest = llama.forward_ragged(
-                    params, cfg, tokens, tok_seq, tok_pos, write_slots,
-                    out_idx, kc, vc, pt, q_start, q_len, kv_len, ps,
-                    attn_impl=attn_impl, mesh=mesh,
-                    moe_load=bool(cfg.num_experts), conv_state=conv,
-                    slot_ids=slot_ids, is_first=is_first, hidden=mtp,
-                    emits=append,
-                )  # [S, O, V]
-                if mtp:
-                    *rest, hidden = rest
-                if conv is not None:
-                    conv, *rest = rest
-                load = rest
-                greedy_all = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                last_logits = logits[:, -1, :]
-                if k_cap > 0:
-                    # Draft token j+1 sits in the stream right after the
-                    # span's input token; its verifier is greedy column j.
-                    jj = jnp.arange(k_cap)[None, :]
-                    draft_idx = jnp.clip(q_start[:, None] + 1 + jj, 0,
-                                         T_pad - 1)
-                    accepted = accept_prefix(tokens[draft_idx],
-                                             greedy_all[:, :k_cap],
-                                             q_len - 1)
-                    accepted = jnp.where(spec, accepted, 0)
-                else:
-                    accepted = jnp.zeros(q_start.shape[0], jnp.int32)
-                W = recent.shape[1]
-                rows = recent[slot_ids]  # [B, W]
-                # First span of a request: the ring opens from seed_rows
-                # (all -1 fresh, the cached prefix's last W tokens on a
-                # prefix-cache hit).
-                rows = jnp.where(is_first[:, None] > 0, seed_rows, rows)
-                # Slide each ring by roll_n tokens taken from the row's
-                # own stream span: span length for prefill rows, 0 for
-                # plain decode rows (their input token already rolled in
-                # when it was sampled), and the ACCEPTED count for spec
-                # rows — whose rolled tokens start one past the span's
-                # input token (the accepted drafts). new[j] is
-                # (rows ++ rolled)[roll_n + j] kept to the last W.
-                roll_n = jnp.where(spec, accepted, ring_len)
-                base = q_start + spec.astype(jnp.int32)
-                j_w = jnp.arange(W)[None, :]
-                cidx = roll_n[:, None] + j_w - W  # offset into the span
-                stream_idx = jnp.clip(base[:, None] + cidx, 0, T_pad - 1)
-                from_stream = tokens[stream_idx]  # [B, W]
-                row_idx = jnp.clip(roll_n[:, None] + j_w, 0, W - 1)
-                from_row = jnp.take_along_axis(rows, row_idx, axis=1)
-                new_rows = jnp.where(cidx >= 0, from_stream, from_row)
-                pen_logits = maybe_apply_penalties(last_logits, new_rows,
-                                                   pen, pres, freq,
-                                                   need_pen)
-                # kv_len IS the position being sampled in both shapes:
-                # n for a span ending a prompt of n tokens (prefill
-                # folded seq_lens) and positions+1 for a decode row.
-                row_keys = per_row_keys(key, seeds, kv_len)
-                tok = sample_tokens_rowwise(pen_logits, row_keys, temp, tk,
-                                            tp, need_mask, need_sample)
-                if k_cap > 0:
-                    # Spec rows take the model's own token at the first
-                    # rejected position (or past the last accepted draft)
-                    # — exactly the token non-speculative greedy would
-                    # sample next. Speculation is host-gated to greedy
-                    # no-penalty rows, so raw argmax IS that token.
-                    spec_next = jnp.take_along_axis(
-                        greedy_all, accepted[:, None], axis=1)[:, 0]
-                    tok = jnp.where(spec, spec_next, tok)
-                # Rows that EMIT (decode/spec rows, final prefill spans)
-                # roll the final token in; mid-prefill spans do not.
-                appended = jnp.concatenate([new_rows[:, 1:], tok[:, None]],
-                                           axis=1)
-                final_rows = jnp.where(append[:, None] > 0, appended,
-                                       new_rows)
-                recent = recent.at[slot_ids].set(final_rows)
-                # Emitted tokens, row-major: spec rows emit the accepted
-                # drafts (greedy columns 0..accepted-1 — accepted drafts
-                # ARE their verifying argmaxes) plus the bonus token at
-                # column `accepted`; every other row emits column 0.
-                n_emit = jnp.where(spec, accepted + 1, 1)
-                col0 = jnp.where(spec, greedy_all[:, 0], tok)
-                toks = jnp.concatenate([col0[:, None], greedy_all[:, 1:]],
-                                       axis=1)
-                if load:
-                    toks = jnp.concatenate([toks, jnp.broadcast_to(
-                        moe.load_stats(load[0])[:, None], (3, O))])
-                if not mtp:
-                    return toks, n_emit, kc, vc, recent, tok, conv
-                # The token that follows each stream position: the next of
-                # its span; at a verify span's positions what the trunk chose
-                # there (column j: the true successor while the drafts before
-                # it were accepted, and nothing reads the others); at a
-                # row's last position the id it just sampled, or the next
-                # prompt token where the span ends inside its prompt.
-                follows = jnp.roll(tokens, -1)
-                last = jnp.where(append > 0, tok, next_tok)
-                follows = follows.at[q_start + q_len - 1].set(
-                    last, mode="drop")
-                jj = jnp.arange(O)[None, :]
-                follows = follows.at[jnp.where(
-                    spec[:, None] & (jj < q_len[:, None]),
-                    q_start[:, None] + jj, T_pad)].set(
-                        greedy_all, mode="drop")
-                # ...and the draft is read at the row's last ACCEPTED
-                # position: the module's view of the token after the one
-                # this step emitted last.
-                at = jnp.clip(q_start + jnp.where(spec, accepted, q_len - 1),
-                              0, T_pad - 1)
-                draft_logits, kc, _ = llama.forward_mtp(
-                    params, cfg, hidden, follows, tok_seq, tok_pos,
-                    write_slots, at, kc, pt, q_start, q_len, kv_len, ps,
-                    attn_impl=attn_impl, mesh=mesh)
-                drafts = drafts.at[slot_ids].set(
-                    jnp.argmax(draft_logits, axis=-1).astype(jnp.int32))
-                lens = lens.at[slot_ids].set(
-                    kv_len - q_len + jnp.where(spec, n_emit, q_len))
-                return toks, n_emit, kc, vc, recent, tok, conv, drafts, lens
-
-            _sp_note_compile(self, "ragged", key_, self._prefill_jits,
-                             jax.jit(mq_ragged_step,
-                                     donate_argnums=(2, 3, 4, 5, 6, 7, 8)
-                                     if mtp else (2, 3, 4, 5, 6)))
-        return self._prefill_jits[key_]
-
-    def _note_moe_load(self, _sp, stats: np.ndarray) -> None:
-        """`stats` [passes, 3]: each forward pass's moe.LOAD_STATS as they
-        came back behind the sampled ids. Onto the step's sample (sums
-        over its passes; the largest load of any; the mean rows a (layer,
-        expert) pair got in a pass) and the /metrics series."""
-        cfg = self.cfg
-        n, hit, top = (int(stats[:, 0].sum()), int(stats[:, 1].sum()),
-                       int(stats[:, 2].max()))
-        mean = n / (len(stats) * cfg.count(EXPERTS) * cfg.num_experts)
-        _sp.note(moe_assignments=n, moe_pairs_hit=hit, moe_load_max=top,
-                 moe_load_mean=round(mean, 4))
-        self._tm_moe_assign.inc(n)
-        self._tm_moe_hit.inc(hit)
-        self._tm_moe_max.set(top)
-        self._tm_moe_mean.set(mean)
-
-    def _note_slot_state(self, _sp, resets: int, carried: int,
-                         step_rows: int, span_tokens: int) -> None:
-        """A launched step's use of the per-slot state, onto its sample
-        and the /metrics series: rows whose slot it opened at zero (a
-        request's first span) and rows that read the state an earlier step
-        left (a later chunk of a prompt, a decode row; a fused scan's
-        active slots) — as `conv_state_*` for a model with conv layers, as
-        `lin_state_*` for one with linear-attention layers, which also
-        says how the rule ran: `lin_step_rows` (row-passes through the one-
-        token form: a ragged step's 1-token rows, a scan's active slots x
-        its passes) and `lin_span_tokens` (tokens of longer spans, through
-        the chunked form) — as `ssm_*`, the same four, for one whose layers
-        run a state-space mixer, as `s6_*` for one with mamba layers (their
-        spans: token after token, a window of the stream a trip). Nothing for
-        a model with none of them."""
-        if self.cfg.count(CONV):
-            _sp.note(conv_state_resets=resets, conv_state_carried=carried)
-            self._tm_conv_resets.inc(resets)
-            self._tm_conv_carried.inc(carried)
-        counts = (resets, carried, step_rows, span_tokens)
-        for kind, prefix, series in ((LINEAR, "lin", self._tm_lin),
-                                     (PARALLEL, "ssm", self._tm_ssm),
-                                     (MAMBA, "s6", self._tm_s6)):
-            if self.cfg.count(kind):
-                _sp.note(**dict(zip((f"{prefix}_state_resets",
-                                     f"{prefix}_state_carried",
-                                     f"{prefix}_step_rows",
-                                     f"{prefix}_span_tokens"), counts)))
-                for counter, n in zip(series, counts):
-                    counter.inc(n)
-
-    # What `_note_exit` writes (a stack with an `exit_layer`).
-    EXIT_FIELDS = ("xattn_rows", "xattn_ctx_rows", "exit_skipped_tokens")
-
-    def _note_exit(self, _sp, tokens, kv, emits=None,
-                   scan: bool = False) -> int:
-        """A launched step's early exit, onto its sample and the /metrics
-        series, from its composition alone: `tokens`, `kv` and `emits` are
-        each row's span length, its context at the span's end and whether
-        its logits are sampled (with `scan` a fused scan's active slots with
-        its passes as tokens, every one sampled). `xattn_rows` the sampled rows that passed the layers
-        from `exit_layer` on, `xattn_ctx_rows` the cached rows ONE cross
-        layer's walks read for them (each sampled row's context: a cross
-        layer's worth, as `attn_ctx_rows` is the full layer's),
-        `exit_skipped_tokens` the stream tokens that stopped below
-        `exit_layer`, which is also what it returns. Nothing (and 0) for a
-        model whose rows all pass every layer."""
-        if not self.cfg.exit_layer:
-            return 0
-        n, kv = np.asarray(tokens, np.int64), np.asarray(kv, np.int64)
-        if scan:  # every pass of every slot is sampled, at its own context
-            rows = int(n.sum())
-            ctx = int((n * (2 * kv - n + 1) // 2).sum())
-        else:
-            emits = np.asarray(emits, bool)
-            rows, ctx = int(emits.sum()), int(kv[emits].sum())
-        counts = (rows, ctx, int(n.sum()) - rows)
-        _sp.note(**dict(zip(self.EXIT_FIELDS, counts)))
-        for series, c in zip(self._tm_exit, counts):
-            series.inc(c)
-        return counts[2]
-
-    def _note_latent(self, _sp, spans, scan: bool = False,
-                     stream_len: int = 0) -> None:
-        """A launched step's latent attention, onto its sample and the
-        /metrics series, from its composition alone: `spans` is (tokens,
-        context at the span's end) a row — a ragged step's spans
-        (`stream_len`: the rung the stream is padded to), or with `scan` a
-        fused scan's active slots with its passes as tokens.
-        `mla_rows` the query tokens, `dsa_ctx_tokens` the cached positions
-        the indexer scored for them (a token at position p scores p + 1),
-        `dsa_selected_tokens` those attention then saw (min(p + 1,
-        index_topk)), and `dsa_step_ctx_tokens` / `dsa_step_selected_tokens`
-        the part of each that ONE-TOKEN rows account for (a decode row, a
-        scan's pass: rows that share their cached positions with no other
-        query of the launch); `mla_wide_tokens` the query tokens the
-        attention kernel served in the EXPANDED form (spans of at least
-        `mla_attention.WIDE` tokens on a rung that holds that body: the
-        kernel's own test, `wide_tokens`; 0 for a scan and on the jnp path),
-        and `mla_absorbed_rows` the stream rows a layer then took through
-        W_uk and W_uv (`absorbed_rows`: the lead where every row behind it
-        is a wide span's, else the rung; 0 where nothing is expanded);
-        a layer's worth — every layer does the same.
-        With NO indexer (every cached position is attended) instead:
-        `mla_rows`, `mla_pairs` the causal (query, position) pairs — a
-        token at position p attends p + 1 — and `mla_ctx_rows` the cached
-        rows a launch has to read at the least: each span's context once
-        (a scan's pass: each slot's). A launch's worth: the trunk's layers
-        and the prediction module's block do the same.
-        Nothing for a model without latent attention."""
-        if not self.cfg.kv_lora_rank:
-            return
-        if not self.cfg.index_topk:
-            counts = np.zeros(3, np.int64)
-            for n, kv in spans:
-                pairs = n * (2 * kv - n + 1) // 2  # sum of kv-n+1 .. kv
-                counts += (n, pairs, pairs if scan else kv)
-            _sp.note(**dict(zip(self.DENSE_LATENT_FIELDS, counts.tolist())))
-            self._tm_dsa[0].inc(int(counts[0]))
-            return
-        counts = np.zeros(7, np.int64)
-        spans = list(spans)
-        for n, kv in spans:
-            ctx = np.arange(kv - n + 1, kv + 1)
-            both = (int(ctx.sum()),
-                    int(np.minimum(ctx, self.cfg.index_topk).sum()))
-            counts[:3] += (n,) + both
-            if scan or n == 1:
-                counts[3:5] += both
-        if self._wide_tokens is not None and not scan:
-            tokens = [n for n, _ in spans]
-            counts[5] = self._wide_tokens(tokens, stream_len)
-            counts[6] = self._absorbed_rows(tokens, stream_len)
-        _sp.note(**dict(zip(self.LATENT_FIELDS, counts.tolist())))
-        for series, n in zip(self._tm_dsa, counts[[0, 1, 2, 5, 6]].tolist()):
-            series.inc(n)
-
-    def _note_attn(self, _sp, tokens, kv, scan: bool = False,
-                   stream_len: int = 0) -> None:
-        """A launched step's plain (K and V pages, non-latent) attention,
-        onto its sample and the /metrics series, from its composition alone
-        (as `_note_latent` counts the latent kernels' work): `tokens` and
-        `kv` are each row's span length and its context at the span's end —
-        a ragged step's spans in stream order (`stream_len`: the rung the
-        stream is padded to), or with `scan` a fused scan's active slots
-        with its passes as tokens. `attn_pairs` the causal (query token,
-        cached position) pairs — a token at position p attends p + 1 —
-        `attn_ctx_rows` the cached rows the walks have to read at the
-        least: each span's context once (a scan's pass: each slot's), and
-        `attn_tall_tokens` the stream tokens the ragged kernel serves a
-        whole stretch at a time (`kv_contract.tall_tokens`; 0 for a scan
-        and without the kernel). A layer's worth: every attention layer
-        does the same. Nothing for an encoder, a model with latent
-        attention or one with no attention layer."""
-        if self.cfg.kv_lora_rank or not self.cfg.paged_layers:
-            return
-        n, kv = np.asarray(tokens, np.int64), np.asarray(kv, np.int64)
-        pairs = n * (2 * kv - n + 1) // 2  # sum of kv-n+1 .. kv
-        tall = 0
-        if self._tall_tokens is not None and not scan:
-            tall = self._tall_tokens(tokens, stream_len)
-        counts = (int(pairs.sum()), int((pairs if scan else kv).sum()), tall)
-        _sp.note(**dict(zip(self.ATTN_FIELDS, counts)))
-        for series, c in zip(self._tm_attn, counts):
-            series.inc(c)
-
-    # What `_note_swa` writes (a model with window layers).
-    SWA_FIELDS = ("swa_pairs", "swa_ctx_rows", "swa_walk_rows",
-                  "swa_full_rows")
-
-    def _note_swa(self, _sp, tokens, kv, scan: bool = False) -> None:
-        """A launched step's WINDOW attention, onto its sample and the
-        /metrics series, from its composition alone — `_note_attn`'s
-        sibling (that one stands for the FULL layers), with its arguments.
-        `swa_pairs` the in-window (query token, cached position) pairs: a
-        token at position p attends min(p + 1, sliding_window);
-        `swa_ctx_rows` the cached rows a window launch has to read at the
-        least: a span of n tokens that ends at context kv reads min(kv, n +
-        sliding_window - 1) of them (a scan's pass: each slot's min(kv,
-        sliding_window)); `swa_walk_rows` the rows the launch's walks DO
-        cover: from the page its table starts at
-        (ops/attention.py:ring_first_page, the table's own rule) to the
-        span's end; `swa_full_rows` what a walk of the same contexts from
-        position 0 would have covered (`attn_ctx_rows`' count). A window
-        layer's worth: every window layer does the same. Nothing for a model
-        without window layers."""
-        w = self.cfg.sliding_window
-        if not w:
-            return
-        ps = self.ecfg.page_size
-        n, kv = np.asarray(tokens, np.int64), np.asarray(kv, np.int64)
-        if scan:  # each pass is a span of one token at its own context
-            k = int(n.max(initial=0))
-            kv = (kv[:, None] - n[:, None] + 1 + np.arange(k)[None, :]
-                  )[np.arange(k)[None, :] < n[:, None]]
-            n = np.ones_like(kv)
-        # positions kv-n .. kv-1 attend min(p + 1, w): all w but the first
-        # w - 1 positions of a sequence, which attend p + 1
-        first = kv - n  # the span's first position
-        short = np.clip(w - 1 - first, 0, n)  # its tokens at p < w - 1
-        pairs = (n - short) * w + short * (2 * first + short + 1) // 2
-        walk = kv - ring_first_page(kv, n, w, ps) * ps
-        counts = tuple(int(a.sum()) for a in (
-            pairs, np.minimum(kv, n + w - 1), walk, kv))
-        _sp.note(**dict(zip(self.SWA_FIELDS, counts)))
-        for series, c in zip(self._tm_swa, counts):
-            series.inc(c)
+        return self._program(
+            self._prefill_jits, "ragged", ("ragged", T_pad, k_cap, flags),
+            step_program.ragged_step, T_pad, k_cap, flags, mtp=self.mtp)
 
     def _dispatch_decode(self, k_steps, buf):
         """`buf`: the scan's packed host inputs (step_pack.decode_layout)."""
         self._fault("decode")
         fn = self._get_decode_jit(
-            k_steps, sampling_flags(*self._decode_layout().sampling(buf)))
+            k_steps, sampling_flags(*self.dims.decode_layout().sampling(buf)))
         return fn(self.params, self._upload(buf), self.kc, self.vc,
                   self.recent, self.last_ids, self.slot_state)
 
     def _get_decode_jit(self, k_steps: int, flags=(True, True, True)):
-        key_ = (k_steps, flags)
-        _sp_compile_evict(self, self._decode_jits, key_)
-        if key_ not in self._decode_jits:
-            cfg, ps = self.cfg, self.ecfg.page_size
-            attn_impl = self.attn_impl
-            need_pen, need_mask, need_sample = flags
-            mesh = self.mesh
-
-            lay = self._decode_layout()
-
-            def mq_decode_scan(params, buf, kc, vc, recent, last_ids, conv):
-                (tokens, positions, active, pt, temp, tk, tp, pen, pres,
-                 freq, seeds, rng) = lay.unpack(buf)
-                key = jax.random.PRNGKey(rng[0])
-                S = tokens.shape[0]
-                # A token < 0 is -1 - r: the id row r of the (still
-                # unsettled) step before this one left in the carry.
-                tokens = jnp.where(
-                    tokens < 0, last_ids[jnp.clip(-1 - tokens, 0, S - 1)],
-                    tokens)
-
-                def step(carry, _):
-                    tokens, positions, kc, vc, recent, key, conv = carry
-                    logits, kc, vc, *rest = llama.forward_decode(
-                        params, cfg, tokens, positions, kc, vc, pt, ps,
-                        attn_impl=attn_impl, active=active, mesh=mesh,
-                        moe_load=bool(cfg.num_experts), conv_state=conv,
-                    )
-                    if conv is not None:
-                        conv, *rest = rest
-                    load = rest
-                    key, sub = jax.random.split(key)
-                    pen_logits = maybe_apply_penalties(logits, recent[:S],
-                                                       pen, pres, freq,
-                                                       need_pen)
-                    # Seeded streams fold in the position of the token being
-                    # SAMPLED (positions holds the incoming token's slot):
-                    # prefill folded n for the token at n, so the first
-                    # decode step must fold n+1, not n, or the two
-                    # consecutive sampling decisions share a key.
-                    row_keys = per_row_keys(sub, seeds, positions + 1)
-                    nxt = sample_tokens_rowwise(pen_logits, row_keys, temp,
-                                                tk, tp, need_mask,
-                                                need_sample)
-                    # Roll the sampled token into ACTIVE slots' rings only —
-                    # reserved (mid-chunked-prefill) slots must not collect
-                    # garbage tokens.
-                    rolled = jnp.concatenate(
-                        [recent[:S, 1:], nxt[:, None]], axis=1
-                    )
-                    new_rows = jnp.where(active[:, None] > 0, rolled, recent[:S])
-                    recent = recent.at[:S].set(new_rows)
-                    out = nxt
-                    if load:  # the pass's counters, behind the ids
-                        out = jnp.concatenate([nxt, moe.load_stats(load[0])])
-                    return (nxt, positions + 1, kc, vc, recent, key,
-                            conv), out
-
-                (tokens, positions, kc, vc, recent, key, conv), toks = \
-                    jax.lax.scan(
-                        step, (tokens, positions, kc, vc, recent, key, conv),
-                        None, length=k_steps)
-                # toks: [K, S] (MoE: [K, S+3]); the carry: each slot's
-                # last id (a scan's rows are the slots).
-                return toks, kc, vc, recent, tokens, conv
-
-            _sp_note_compile(self, "decode", key_, self._decode_jits,
-                             jax.jit(mq_decode_scan,
-                                     donate_argnums=(2, 3, 4, 5, 6)))
-        return self._decode_jits[key_]
+        return self._program(self._decode_jits, "decode", (k_steps, flags),
+                             step_program.decode_scan, k_steps, flags)
 
     # -- slot lifecycle ----------------------------------------------------
     def _clear_slot(self, slot: int) -> None:
@@ -1923,18 +1337,6 @@ class ModelRuntime:
                            **self._page_state())
         self.page_table[slot, :] = kvc.TRASH_PAGE
 
-    def _install_slot(self, slot: int, req: Request, n: int, tok: int,
-                      core: MQCore) -> None:
-        """Activate a freshly prefilled request in its decode slot and emit
-        the first sampled token (the sequence-parallel prefill: the id is
-        on the host already)."""
-        self._seat_slot(slot, req, n)
-        self.tokens_generated += 1
-        self._emit_row(slot, (tok,), core, n)
-        if self.slot_req[slot] is req:
-            # Token written at position n during the next decode step.
-            self.last_tokens[slot] = tok
-
     def _seat_slot(self, slot: int, req: Request, n: int) -> None:
         """Seat a request whose prompt's last span is dispatched in its
         decode slot: from here on it is a decode row."""
@@ -2202,7 +1604,7 @@ class ModelRuntime:
                     break
         return []
 
-    def _note_spec_outcome(self, req: Request, proposed: int,
+    def _spec_outcome(self, req: Request, proposed: int,
                            accepted: int) -> None:
         """Per-dispatch speculative accounting: totals, the accept-rate
         gauge, and the per-user auto-throttle — a user whose drafts keep
@@ -2823,7 +2225,7 @@ class ModelRuntime:
         # belong to padding row len(rows) (trash pages, position -1 =>
         # masked everywhere) and write into the trash page; padding rows
         # hold the layout's fill values.
-        lay = self._ragged_layout(T_pad)
+        lay = self.dims.ragged_layout(T_pad)
         buf = lay.new()
         (tokens, tok_seq, tok_pos, write_slots, q_start, q_len, kv_len,
          ring_len, is_first, append, is_spec, next_tok, seed_rows, slot_ids,
@@ -2987,16 +2389,10 @@ class ModelRuntime:
             self._ragged_failed(rows, e, core)
             return None
         _sp.seam("note")
-        self._note_queued(h)
-        opened = int(is_first.sum())
-        spans = [span for *_, span in rows]
-        self._note_slot_state(_sp, opened, len(rows) - opened,
-                              sum(n == 1 for n in spans),
-                              sum(n for n in spans if n > 1))
-        self._note_latent(_sp, zip(spans, row_kv), stream_len=T_pad)
-        self._note_attn(_sp, spans, row_kv, stream_len=T_pad)
-        self._note_swa(_sp, spans, row_kv)
-        h.exited = self._note_exit(_sp, spans, row_kv, emits)
+        self._queued(h)
+        h.exited = self.work.note(
+            _sp, [span for *_, span in rows], row_kv, emits,
+            stream_len=T_pad, opened=int(is_first.sum()))
         _sp.mark("dispatch")
         _sp.park()
 
@@ -3027,7 +2423,7 @@ class ModelRuntime:
                     n = len(req.prompt_tokens)
                     self._seat_slot(slot, req, n)
                     self._launched(h, idx, slot, req, n)
-        self._note_launch(h, prev)
+        self._launch_made(h, prev)
         return h
 
     def _launched(self, h: "StepInFlight", row: int, slot: int,
@@ -3042,7 +2438,7 @@ class ModelRuntime:
         if self._ends_by_count(slot, req):
             h.ending.add(slot)
 
-    def _note_queued(self, h: "StepInFlight") -> None:
+    def _queued(self, h: "StepInFlight") -> None:
         """The jitted call of step `h` has just returned: its program is
         queued behind the step launched before it. Opens h's done-bracket
         (probed with `is_ready()` on its ids: non-blocking, no transfer)
@@ -3052,7 +2448,7 @@ class ModelRuntime:
         h.sp.launched(h.toks_dev.is_ready, model=self.name,
                       h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
 
-    def _note_launch(self, h: "StepInFlight",
+    def _launch_made(self, h: "StepInFlight",
                      prev: Optional["StepInFlight"]) -> None:
         self.inflight = h
         if prev is not None:
@@ -3185,7 +2581,7 @@ class ModelRuntime:
         # never written after its launch (step_pack): the live per-slot
         # arrays advance below, while the program may still be reading
         # what it was handed.
-        lay = self._decode_layout()
+        lay = self.dims.decode_layout()
         buf = lay.new()
         (tokens, positions, active_mask, pt, temp, top_k, top_p, pen, pres,
          freq, seeds, rng) = lay.views(buf)
@@ -3211,23 +2607,16 @@ class ModelRuntime:
         h.toks_dev, self.kc, self.vc, self.recent, self.last_ids, \
             self.slot_state = self._dispatch_decode(k_steps, buf)
         _sp.seam("note")
-        self._note_queued(h)
-        self._note_slot_state(_sp, 0, len(active),
-                              len(active) * int(k_steps), 0)
-        self._note_latent(_sp, [(int(k_steps), int(self.seq_lens[i])
-                                 + int(k_steps)) for i in active], scan=True)
-        self._note_attn(_sp, [int(k_steps)] * len(active),
-                        self.seq_lens[active] + int(k_steps), scan=True)
-        self._note_swa(_sp, [int(k_steps)] * len(active),
-                       self.seq_lens[active] + int(k_steps), scan=True)
-        self._note_exit(_sp, [int(k_steps)] * len(active),
-                        self.seq_lens[active] + int(k_steps), scan=True)
+        self._queued(h)
+        self.work.note(_sp, [int(k_steps)] * len(active),
+                       (self.seq_lens[active] + int(k_steps)).tolist(),
+                       scan=True)
         _sp.mark("dispatch")
         _sp.park()
         for i in active:
             self._launched(h, i, i, self.slot_req[i],
                            int(self.seq_lens[i]) + k_steps, k_steps)
-        self._note_launch(h, prev)
+        self._launch_made(h, prev)
         return h
 
     def step_collect(self, h: "StepInFlight", core: MQCore) -> None:
@@ -3308,7 +2697,7 @@ class ModelRuntime:
                 self.last_tokens[slot] = last[i]
                 self._tok_step[slot] = None
         if self.cfg.num_experts:
-            self._note_moe_load(
+            self.work.note_moe_load(
                 _sp, toks[:, S:] if h.k_steps else toks[S:, :1].T)
         _sp.park()
 
@@ -3390,7 +2779,7 @@ class ModelRuntime:
                 if kind == "spec":
                     proposed, accepted = span - 1, n - 1
                     spec_accepted += accepted
-                    self._note_spec_outcome(req, proposed, accepted)
+                    self._spec_outcome(req, proposed, accepted)
                     self._jrec("spec_verify", req, slot=slot,
                                proposed=proposed, accepted=accepted,
                                rolled_back=proposed - accepted,
@@ -3536,11 +2925,7 @@ class ModelRuntime:
             "param_bytes": self.param_bytes,
             "kv_bytes": self.kv_bytes,
             # the per-slot state beside the pool (0 for a model without)
-            "conv_state_bytes": self.conv_state_bytes,
-            "lin_state_bytes": self.lin_state_bytes,
-            "ssm_state_bytes": self.ssm_state_bytes,
-            "s6_state_bytes": self.s6_state_bytes,
-            "swa_ring_bytes": self.ring_bytes,
+            **self.state_bytes,
             "weights_dtype": self.weights_dtype,
             "kv_dtype": self.kv_dtype,
             "attn_impl": self.attn_impl,
@@ -3999,6 +3384,10 @@ class TPUEngine:
         if rt.has_work():
             raise RuntimeError(f"model {name} has in-flight work")
         del self.runtimes[name]
+        # ...and the builders' copies of its step programs, so that the
+        # executables go with the runtime (an encoder's or the fake
+        # engine's runtime: none were built).
+        step_program.forget_config(getattr(rt, "cfg", None))
         return True
 
     def loaded_models(self) -> List[str]:
@@ -4920,12 +4309,8 @@ class TPUEngine:
         for name, rt in self.runtimes.items():
             entry = {"weight_bytes": int(getattr(rt, "param_bytes", 0)),
                      "kv_bytes": int(getattr(rt, "kv_bytes", 0)),
-                     "slot_state_bytes": int(
-                         getattr(rt, "conv_state_bytes", 0)
-                         + getattr(rt, "lin_state_bytes", 0)
-                         + getattr(rt, "ssm_state_bytes", 0)
-                         + getattr(rt, "s6_state_bytes", 0)
-                         + getattr(rt, "ring_bytes", 0))}
+                     "slot_state_bytes": sum(
+                         getattr(rt, "state_bytes", {}).values())}
             alloc = getattr(rt, "alloc", None)
             if alloc is not None:
                 entry.update(free=alloc.free_pages, used=alloc.used_pages,
@@ -5259,9 +4644,8 @@ class TPUEngine:
         # device's counters standing in for the pod — VERDICT r3 weak #6).
         chips = self.chip_stats()
         hbm_used = sum(c["hbm_used"] for c in chips) or sum(
-            r["param_bytes"] + r["kv_bytes"] + r.get("conv_state_bytes", 0)
-            + r.get("lin_state_bytes", 0) + r.get("ssm_state_bytes", 0)
-            + r.get("s6_state_bytes", 0)
+            r["param_bytes"] + r["kv_bytes"]
+            + sum(r.get(k, 0) for _, k, _ in step_work.STATE_BYTES)
             for r in runtime_stats)
         hbm_total = sum(c["hbm_total"] for c in chips) or None
         return {

@@ -81,9 +81,6 @@ class PageAllocator:
     def pages_needed(self, num_tokens: int) -> int:
         return max(1, -(-num_tokens // self.page_size))
 
-    def can_alloc(self, num_tokens: int) -> bool:
-        return self.pages_needed(num_tokens) <= len(self._free)
-
     def alloc(self, num_tokens: int) -> Optional[List[int]]:
         """Allocate pages to hold num_tokens; None if pool exhausted or the
         request exceeds the per-sequence page cap."""

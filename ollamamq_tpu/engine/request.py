@@ -320,10 +320,6 @@ class Request:
         self.emitted_len = len(self._detok_text)
         return chunk
 
-    @property
-    def full_text(self) -> str:
-        return self._detok_text[: self.emitted_len]
-
     def expired(self, now: Optional[float] = None) -> bool:
         """True when the request's deadline has passed."""
         if self.deadline is None:

@@ -1,0 +1,305 @@
+"""A step's work account: what a launched step's layers did, counted from
+its composition alone — each row's span length, its context at the span's
+end and whether its logits are sampled — onto the step's sample
+(`telemetry/stepprof.py`) and the /metrics series.
+
+ONE table, `KINDS`: a row a kind of layer — whether a configuration has it,
+the sample's fields, the series that count the same (a field's definition is
+its series' help, `telemetry/schema.py`; the count functions say what that
+leaves out) and the pure function that gives the counts. A `StepWork` binds
+the rows of the kinds its configuration has, once, and `note` walks them
+once a launch. A new kind of layer is one more row here and no line of
+`engine/engine.py`.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Callable, NamedTuple, Optional, Tuple
+
+import numpy as np
+
+from ollamamq_tpu.config import (CONV, EXPERTS, LINEAR, MAMBA, PARALLEL,
+                                 ModelConfig)
+from ollamamq_tpu.ops.attention import ring_first_page
+from ollamamq_tpu.telemetry import schema as tm
+
+
+class KernelCounts(NamedTuple):
+    """The Pallas kernels' own tests of how they serve a stream, each
+    `(tokens, stream_len) -> count`: the ragged kernel's (tokens served a
+    whole stretch at a time) and, with an indexer, the masked latent
+    kernel's (tokens attended in the expanded form; rows a layer then takes
+    through the absorbed form's contractions)."""
+    tall_tokens: Callable
+    wide_tokens: Optional[Callable] = None
+    absorbed_rows: Optional[Callable] = None
+
+
+def kernel_counts(cfg: ModelConfig, attn_impl: str) -> Optional[KernelCounts]:
+    """`cfg`'s on the Pallas path; None without the kernels."""
+    if attn_impl != "pallas":
+        return None
+    from ollamamq_tpu.ops.pallas.kv_contract import tall_tokens
+    if not cfg.index_topk:
+        return KernelCounts(tall_tokens)
+    from ollamamq_tpu.ops.pallas.mla_attention import (absorbed_rows,
+                                                       wide_tokens)
+    return KernelCounts(tall_tokens, *(
+        functools.partial(fn, heads=cfg.num_heads, lanes=cfg.latent_lanes,
+                          rank=cfg.kv_lora_rank, nope=cfg.qk_nope_head_dim,
+                          v=cfg.v_head_dim)
+        for fn in (wide_tokens, absorbed_rows)))
+
+
+class Step(NamedTuple):
+    """A launched step's composition. `tokens`, `kv`, `emits`: each row's
+    span length, its context at the span's end and whether its logits are
+    sampled — a ragged step's spans in stream order (`stream_len`: the rung
+    the stream is padded to; `opened`: the rows whose span is their
+    request's first), or with `scan` a fused scan's active slots with its
+    passes as tokens, every one sampled."""
+    tokens: list
+    kv: list
+    emits: Optional[list]
+    scan: bool
+    stream_len: int
+    opened: int
+    kernels: Optional[KernelCounts]
+
+
+def _pairs(n, kv):
+    """Causal (query token, cached position) pairs of spans of `n` tokens
+    ending at context `kv` (a token at p attends p + 1): Σ kv-n+1 .. kv."""
+    return n * (2 * kv - n + 1) // 2
+
+
+def slot_state_counts(cfg, page_size, s: Step) -> tuple:
+    """A step's use of the per-slot state: rows whose slot it opened at
+    zero (a request's first span), rows that read the state an earlier step
+    left (a later chunk, a decode row; a scan's active slots), then how the
+    recurrence ran: row-passes through the one-token form (1-token rows; a
+    scan's active slots x its passes) and tokens of longer spans, through
+    the chunked form. A model with conv layers only keeps the first two."""
+    carried = len(s.tokens) - s.opened
+    if s.scan:
+        return s.opened, carried, sum(s.tokens), 0
+    return (s.opened, carried, sum(n == 1 for n in s.tokens),
+            sum(n for n in s.tokens if n > 1))
+
+
+def latent_counts(cfg, page_size, s: Step) -> tuple:
+    """Latent attention under the indexer, a layer's worth: the query
+    tokens, the cached positions the indexer scored for them (a token at
+    position p scores p + 1) and those attention then saw (min(p + 1,
+    index_topk)); `dsa_step_*`: the part of the two that ONE-TOKEN rows
+    account for (a decode row, a scan's pass: rows that share their cached
+    positions with no other query of the launch); then the kernel's own
+    counts of the expanded form (0 for a scan and on the jnp path)."""
+    counts = np.zeros(7, np.int64)
+    for n, kv in zip(s.tokens, s.kv):
+        ctx = np.arange(kv - n + 1, kv + 1)
+        both = (int(ctx.sum()), int(np.minimum(ctx, cfg.index_topk).sum()))
+        counts[:3] += (n,) + both
+        if s.scan or n == 1:
+            counts[3:5] += both
+    if s.kernels is not None and not s.scan:
+        counts[5] = s.kernels.wide_tokens(s.tokens, s.stream_len)
+        counts[6] = s.kernels.absorbed_rows(s.tokens, s.stream_len)
+    return tuple(counts.tolist())
+
+
+def dense_latent_counts(cfg, page_size, s: Step) -> tuple:
+    """Latent attention with NO indexer (every cached position is attended:
+    the `dsa_*` fields have no honest value there), a launch's worth — the
+    trunk's layers and the prediction module's block do the same: the query
+    tokens, the causal pairs, and the cached rows a launch has to read at
+    the least: each span's context once (a scan's pass: each slot's)."""
+    n, kv = np.asarray(s.tokens, np.int64), np.asarray(s.kv, np.int64)
+    pairs = _pairs(n, kv)
+    return int(n.sum()), int(pairs.sum()), int((pairs if s.scan else kv).sum())
+
+
+def attn_counts(cfg, page_size, s: Step) -> tuple:
+    """Plain (K and V pages, non-latent) attention, a layer's worth: the
+    causal pairs, the cached rows the walks have to read at the least (each
+    span's context once; a scan's pass: each slot's) and the kernel's own
+    count of tall tokens (0 for a scan and without the kernel)."""
+    n, kv = np.asarray(s.tokens, np.int64), np.asarray(s.kv, np.int64)
+    pairs = _pairs(n, kv)
+    tall = 0
+    if s.kernels is not None and not s.scan:
+        tall = s.kernels.tall_tokens(s.tokens, s.stream_len)
+    return int(pairs.sum()), int((pairs if s.scan else kv).sum()), tall
+
+
+def swa_counts(cfg, page_size, s: Step) -> tuple:
+    """WINDOW attention, a window layer's worth (`attn_counts` stands for
+    the FULL layers): the in-window pairs, the cached rows a launch has to
+    read at the least (min(kv, n + sliding_window - 1) a span), the rows its
+    walks DO cover — from the page its table starts at
+    (ops/attention.py:ring_first_page, the table's own rule) to the span's
+    end — and what a walk from position 0 would have covered."""
+    w = cfg.sliding_window
+    n, kv = np.asarray(s.tokens, np.int64), np.asarray(s.kv, np.int64)
+    if s.scan:  # each pass is a span of one token at its own context
+        k = int(n.max(initial=0))
+        kv = (kv[:, None] - n[:, None] + 1 + np.arange(k)[None, :]
+              )[np.arange(k)[None, :] < n[:, None]]
+        n = np.ones_like(kv)
+    # positions kv-n .. kv-1 attend min(p + 1, w): all w but the first
+    # w - 1 positions of a sequence, which attend p + 1
+    first = kv - n  # the span's first position
+    short = np.clip(w - 1 - first, 0, n)  # its tokens at p < w - 1
+    pairs = (n - short) * w + short * (2 * first + short + 1) // 2
+    walk = kv - ring_first_page(kv, n, w, page_size) * page_size
+    return tuple(int(a.sum()) for a in (
+        pairs, np.minimum(kv, n + w - 1), walk, kv))
+
+
+def exit_counts(cfg, page_size, s: Step) -> tuple:
+    """A stack with an `exit_layer`: the sampled rows, which pass the layers
+    from there on; the cached rows ONE cross layer's walks read for them
+    (each sampled row's context); the stream tokens that stopped below."""
+    n, kv = np.asarray(s.tokens, np.int64), np.asarray(s.kv, np.int64)
+    if s.scan:  # every pass of every slot is sampled, at its own context
+        rows, ctx = int(n.sum()), int(_pairs(n, kv).sum())
+    else:
+        emits = np.asarray(s.emits, bool)
+        rows, ctx = int(emits.sum()), int(kv[emits].sum())
+    return rows, ctx, int(n.sum()) - rows
+
+
+class Kind(NamedTuple):
+    present: Callable  # (ModelConfig) -> does a model have such layers?
+    fields: Tuple[str, ...]  # what `note` writes onto a step's sample
+    series: tuple  # ...and the /metrics family of each (None: none)
+    counts: Callable  # (cfg, page_size, Step) -> a count a field
+
+
+def _recurrent(kind: str, prefix: str, *series) -> Kind:
+    """The four of `slot_state_counts` under a recurrence's own names."""
+    return Kind(lambda cfg: cfg.count(kind),
+                tuple(f"{prefix}_{f}" for f in (
+                    "state_resets", "state_carried", "step_rows",
+                    "span_tokens")), series, slot_state_counts)
+
+
+# In the order a sample carries them.
+KINDS = {
+    "conv": Kind(lambda cfg: cfg.count(CONV),
+                 ("conv_state_resets", "conv_state_carried"),
+                 (tm.CONV_STATE_RESETS_TOTAL, tm.CONV_STATE_CARRIED_TOTAL),
+                 slot_state_counts),
+    "lin": _recurrent(
+        LINEAR, "lin", tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
+        tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL),
+    "ssm": _recurrent(
+        PARALLEL, "ssm", tm.SSM_STATE_RESETS_TOTAL,
+        tm.SSM_STATE_CARRIED_TOTAL, tm.SSM_STEP_ROWS_TOTAL,
+        tm.SSM_SPAN_TOKENS_TOTAL),
+    "s6": _recurrent(
+        MAMBA, "s6", tm.S6_STATE_RESETS_TOTAL, tm.S6_STATE_CARRIED_TOTAL,
+        tm.S6_STEP_ROWS_TOTAL, tm.S6_SPAN_TOKENS_TOTAL),
+    "latent": Kind(
+        lambda cfg: cfg.kv_lora_rank and cfg.index_topk,
+        ("mla_rows", "dsa_ctx_tokens", "dsa_selected_tokens",
+         "dsa_step_ctx_tokens", "dsa_step_selected_tokens",
+         "mla_wide_tokens", "mla_absorbed_rows"),
+        (tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
+         tm.DSA_SELECTED_TOKENS_TOTAL, None, None,
+         tm.MLA_WIDE_TOKENS_TOTAL, tm.MLA_ABSORBED_ROWS_TOTAL),
+        latent_counts),
+    "dense_latent": Kind(
+        lambda cfg: cfg.kv_lora_rank and not cfg.index_topk,
+        ("mla_rows", "mla_pairs", "mla_ctx_rows"),
+        (tm.MLA_ROWS_TOTAL, None, None), dense_latent_counts),
+    # Nothing for an encoder, a model with latent attention or one with
+    # no attention layer.
+    "attn": Kind(
+        lambda cfg: not cfg.kv_lora_rank and cfg.paged_layers,
+        ("attn_pairs", "attn_ctx_rows", "attn_tall_tokens"),
+        (tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
+         tm.ATTN_TALL_TOKENS_TOTAL), attn_counts),
+    "swa": Kind(
+        lambda cfg: cfg.sliding_window,
+        ("swa_pairs", "swa_ctx_rows", "swa_walk_rows", "swa_full_rows"),
+        (tm.SWA_PAIRS_TOTAL, tm.SWA_CTX_ROWS_TOTAL, tm.SWA_WALK_ROWS_TOTAL,
+         tm.SWA_FULL_ROWS_TOTAL), swa_counts),
+    "exit": Kind(lambda cfg: cfg.exit_layer,
+                 ("xattn_rows", "xattn_ctx_rows", "exit_skipped_tokens"),
+                 (tm.XATTN_ROWS_TOTAL, tm.XATTN_CTX_ROWS_TOTAL,
+                  tm.EXIT_SKIPPED_TOKENS_TOTAL), exit_counts),
+}
+
+
+class StepWork:
+    """The rows of `KINDS` that `cfg` has, their series bound to `model`.
+    `kernels`: `kernel_counts(cfg, attn_impl)`."""
+
+    def __init__(self, cfg: ModelConfig, page_size: int, model: str,
+                 kernels: Optional[KernelCounts] = None):
+        self.cfg, self.page_size, self.kernels = cfg, page_size, kernels
+        self._rows = [
+            (k.fields, k.counts,
+             [(i, c.labels(model=model)) for i, c in enumerate(k.series)
+              if c is not None], name == "exit")
+            for name, k in KINDS.items() if k.present(cfg)]
+        if cfg.num_experts:
+            self._moe = [c.labels(model=model) for c in (
+                tm.MOE_ASSIGNMENTS_TOTAL, tm.MOE_EXPERT_PAIRS_HIT_TOTAL,
+                tm.MOE_EXPERT_LOAD_MAX, tm.MOE_EXPERT_LOAD_MEAN)]
+
+    def note(self, sp, tokens, kv, emits=None, *, scan: bool = False,
+             stream_len: int = 0, opened: int = 0) -> int:
+        """A launched step's work (the arguments are `Step`'s) onto its
+        sample `sp` and the series, each once; returns the stream tokens
+        that stopped below an `exit_layer` (0 for a model without one)."""
+        step = Step(tokens, kv, emits, scan, stream_len, opened,
+                    self.kernels)
+        skipped = 0
+        for fields, count, series, exits in self._rows:
+            counts = count(self.cfg, self.page_size, step)
+            sp.note(**dict(zip(fields, counts)))
+            for i, child in series:
+                child.inc(counts[i])
+            if exits:
+                skipped = counts[2]
+        return skipped
+
+    def note_moe_load(self, sp, stats: np.ndarray) -> None:
+        """`stats` [passes, 3]: each forward pass's moe.LOAD_STATS as they
+        came back behind the sampled ids. Onto the step's sample (sums
+        over its passes; the largest load of any; the mean rows a (layer,
+        expert) pair got in a pass) and the /metrics series."""
+        cfg = self.cfg
+        n, hit, top = (int(stats[:, 0].sum()), int(stats[:, 1].sum()),
+                       int(stats[:, 2].max()))
+        mean = n / (len(stats) * cfg.count(EXPERTS) * cfg.num_experts)
+        sp.note(moe_assignments=n, moe_pairs_hit=hit, moe_load_max=top,
+                moe_load_mean=round(mean, 4))
+        assign, pairs_hit, load_max, load_mean = self._moe
+        assign.inc(n)
+        pairs_hit.inc(hit)
+        load_max.set(top)
+        load_mean.set(mean)
+
+
+# The per-slot state's fields (llama.SlotState), each with the name its
+# bytes go by in a runtime's stats and the gauge that publishes them.
+STATE_BYTES = (("conv", "conv_state_bytes", tm.HBM_CONV_STATE_BYTES),
+               ("rule", "lin_state_bytes", tm.HBM_LIN_STATE_BYTES),
+               ("ssm", "ssm_state_bytes", tm.HBM_SSM_STATE_BYTES),
+               ("scan", "s6_state_bytes", tm.HBM_S6_STATE_BYTES),
+               ("ring", "swa_ring_bytes", tm.HBM_SWA_RING_BYTES))
+
+
+def state_bytes(state, model: str) -> dict:
+    """{stats name: bytes} of every field of a per-slot state (0: not kept;
+    `state` None: none is), each set on its gauge."""
+    out = {}
+    for field, name, gauge in STATE_BYTES:
+        held = getattr(state, field, None)
+        out[name] = 0 if held is None else int(held.nbytes)
+        gauge.labels(model=model).set(out[name])
+    return out

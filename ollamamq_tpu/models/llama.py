@@ -521,104 +521,73 @@ def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return logits
 
 
+_RECURRENT = ("rule", "ssm", "scan")
+
+
 class SlotState(NamedTuple):
-    """The per-slot state of a model with linear-attention layers, as the
-    forwards take it in `conv_state` and give it back: the window of the
-    layers' convolution (ops/shortconv.py's array) and the rule's matrices
-    (ops/gated_delta.py's). A model with conv layers only passes the
-    window's array bare, as before this family; a model with neither
-    passes None."""
-    conv: Optional[jnp.ndarray]
+    """The per-slot state of a model whose layers keep one, as the forwards
+    take it in `conv_state` and give it back — ONE record, a field a kind of
+    state, None (a pytree leaf the less) where the layers keep none of that
+    kind: `conv` the window of the layers' convolution (ops/shortconv.py's
+    array: conv, linear-attention, mixer and mamba layers'); then the float32
+    recurrent state, of which a model has ONE — `rule` the delta rule's
+    matrices (ops/gated_delta.py's), `ssm` a state-space mixer's (PARALLEL;
+    ops/ssd.py's), `scan` a selective scan's (ops/selective_scan.py's) — and
+    `ring` the window layers' K/V rings (ops/attention.py:WindowRing)."""
+    conv: Optional[jnp.ndarray] = None
     rule: Optional[jnp.ndarray] = None
+    ssm: Optional[jnp.ndarray] = None
+    scan: Optional[jnp.ndarray] = None
+    ring: Optional[WindowRing] = None
+
+    def loop_carry(self) -> tuple:
+        """(conv window, recurrent state, rings), each may be None: the
+        three places the layers' loop carries the state in, whichever
+        recurrence a model runs (`scan_layers`)."""
+        held = [getattr(self, f) for f in _RECURRENT
+                if getattr(self, f) is not None]
+        return self.conv, held[0] if held else None, self.ring
+
+    def after(self, conv, recurrent, ring) -> "SlotState":
+        """...and the loop's three results as a state of this one's form."""
+        return self._replace(conv=conv, ring=ring, **{
+            f: recurrent for f in _RECURRENT if getattr(self, f) is not None})
 
 
-class WindowState(NamedTuple):
-    """...and of a model with window layers: their K/V rings
-    (ops/attention.py:WindowRing) beside whatever else its layers keep."""
-    conv: Optional[jnp.ndarray]
-    rule: Optional[jnp.ndarray]
-    ring: Optional[WindowRing]
-
-
-class SsmState(NamedTuple):
-    """...and of a model whose layers run a state-space mixer beside their
-    attention (PARALLEL): the window of the mixers' convolution and their
-    float32 recurrent state (ops/ssd.py's array; the layers' K and V live
-    in the paged pool). A pytree of its own: the other models' tuples keep
-    their fields."""
-    conv: jnp.ndarray
-    ssm: jnp.ndarray
-
-
-class ScanState(NamedTuple):
-    """...and of a decoder-hybrid-decoder stack (`mb_per_layer`): the window
-    of the mamba layers' convolution, their float32 scan state
-    (ops/selective_scan.py's array) and the window layers' K/V rings."""
-    conv: jnp.ndarray
-    scan: jnp.ndarray
-    ring: WindowRing
-
-
-def _as_given(conv, rule, ring, like=None):
-    """A forward's `conv_state` in the form the docstrings above name
-    (`like`: the form it came in, which tells a mixer's or a scan's state
-    from the rule's — all ride the loop's carry where `split_state` put
-    them)."""
-    if isinstance(like, ScanState):
-        return ScanState(conv, rule, ring)
-    if isinstance(like, SsmState):
-        return SsmState(conv, rule)
-    if ring is not None:
-        return WindowState(conv, rule, ring)
-    return conv if rule is None else SlotState(conv, rule)
+def slot_state(conv_state) -> SlotState:
+    """A forward's `conv_state` as the record: a conv window's bare array is
+    its `conv`, None the record with nothing in it."""
+    if isinstance(conv_state, SlotState):
+        return conv_state
+    return SlotState(conv_state)
 
 
 def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16,
                      ring_rows: int = 0):
-    """What `conv_state` of the step forwards is for `cfg`, at zero.
+    """What `conv_state` of the step forwards is for `cfg`, at zero; None
+    for a model whose layers keep nothing a slot.
     `ring_rows`: rows of a slot's ring a window layer
     (`ModelConfig.ring_rows` of the longest span a step writes)."""
     window, width = cfg.state_window
-    conv = shortconv.alloc_state(
-        cfg.count(CONV) + cfg.count(LINEAR) + cfg.count(PARALLEL)
-        + cfg.count(MAMBA), max_slots, window, width, dtype)
-    if cfg.count(MAMBA):
-        return ScanState(
-            conv, selective_scan.alloc_state(
-                cfg.count(MAMBA), max_slots, S6_D_STATE, cfg.s6_inner),
-            alloc_ring(cfg.count(WINDOW), max_slots, ring_rows, cfg.kv_dim,
-                       dtype))
-    if cfg.count(PARALLEL):
-        return SsmState(conv, ssd.alloc_state(
-            cfg.count(PARALLEL), max_slots, cfg.mamba_n_heads,
-            cfg.mamba_d_state, cfg.mamba_d_head))
-    rule = gated_delta.alloc_state(
-        cfg.count(LINEAR), max_slots, cfg.linear_num_value_heads,
-        cfg.linear_key_head_dim, cfg.linear_value_head_dim)
     if cfg.count(WINDOW) and ring_rows < cfg.sliding_window:
         raise ValueError(
             f"{cfg.name}: a slot's ring holds the window at the least: "
             f"ring_rows {ring_rows}, sliding_window {cfg.sliding_window}")
-    ring = alloc_ring(cfg.count(WINDOW), max_slots, ring_rows, cfg.kv_dim,
-                      dtype)
-    return _as_given(conv, rule, ring)
-
-
-def split_state(conv_state) -> WindowState:
-    """(conv window, recurrent state, rings) of a forward's `conv_state`, in
-    any of its six forms; each may be None. The recurrent state is the
-    delta rule's, a mixer's (`SsmState`) or a selective scan's
-    (`ScanState`): a model has one of them, and the layers' loop carries it
-    in the same place."""
-    if isinstance(conv_state, WindowState):
-        return conv_state
-    if isinstance(conv_state, ScanState):
-        return WindowState(*conv_state)
-    if isinstance(conv_state, SsmState):
-        return WindowState(*conv_state, None)
-    if isinstance(conv_state, SlotState):
-        return WindowState(*conv_state, None)
-    return WindowState(conv_state, None, None)
+    state = SlotState(
+        shortconv.alloc_state(
+            cfg.count(CONV) + cfg.count(LINEAR) + cfg.count(PARALLEL)
+            + cfg.count(MAMBA), max_slots, window, width, dtype),
+        gated_delta.alloc_state(
+            cfg.count(LINEAR), max_slots, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+        ssd.alloc_state(
+            cfg.count(PARALLEL), max_slots, cfg.mamba_n_heads,
+            cfg.mamba_d_state, cfg.mamba_d_head),
+        selective_scan.alloc_state(
+            cfg.count(MAMBA), max_slots, S6_D_STATE, cfg.s6_inner),
+        alloc_ring(cfg.count(WINDOW), max_slots, ring_rows, cfg.kv_dim,
+                   dtype))
+    return state if jax.tree_util.tree_leaves(state) else None
 
 
 class LayerIx(NamedTuple):
@@ -1369,7 +1338,7 @@ def forward_ragged(
     interpret: bool = False,
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
     moe_load: bool = False,  # also return the [Le, E] expert loads
-    conv_state=None,  # [Lc, K-1, slots, D] or a SlotState (donated; carry)
+    conv_state=None,  # a SlotState ([Lc, K-1, slots, D]: its `conv`; carry)
     slot_ids=None,  # [B] each row's slot: its row of conv_state
     is_first=None,  # [B] the span is its request's first: state opens at 0
     hidden: bool = False,  # also return the last hiddens [T, D], last
@@ -1387,9 +1356,9 @@ def forward_ragged(
     leaves the span's last positions there (ops/shortconv.taps_ragged; a
     model with conv layers needs `conv_state`, `slot_ids`, `is_first`); a
     linear-attention layer does the same for its convolution and continues
-    each row's rule state through the row's span (ops/gated_delta.ragged;
-    `conv_state` is then a SlotState); a window layer writes the stream's
-    K/V into the ring of each row's slot (`WindowState.ring`) and every token
+    each row's rule state through the row's span (ops/gated_delta.ragged:
+    `SlotState.rule`); a window layer writes the stream's
+    K/V into the ring of each row's slot (`SlotState.ring`) and every token
     attends over its last `sliding_window` positions there, the walk
     starting at the ring page that holds the first of them
     (ops/attention.py:ring_table).
@@ -1427,7 +1396,7 @@ def forward_ragged(
         x = _embed(params, cfg, tokens)[None]  # [1,T,D]
     positions = jnp.maximum(tok_pos, 0)[None, :]  # [1, T] RoPE positions
     valid = (tok_pos >= 0)[None, :]
-    state = split_state(conv_state)
+    state = slot_state(conv_state)
     if state.conv is not None:  # one plan for every layer with a window
         conv_plan = shortconv.ragged_plan(
             state.conv.shape[2], slot_ids, tok_seq, q_start, q_len, is_first)
@@ -1536,7 +1505,7 @@ def forward_ragged(
         return (x[0][sampled][:, None], *carried, m[0][sampled][:, None])
 
     x, k_cache, v_cache, conv, rule, ring, *_, load = scan_layers(
-        cfg, body, x, params["layers"], k_cache, v_cache, *state, *memo,
+        cfg, body, x, params["layers"], k_cache, v_cache, *state.loop_carry(), *memo,
         at_exit=at_exit if exits else None)
     if exits:  # x [B, 1, D]: the sampled rows, through every layer
         logits = _logits(params, cfg, x)[:, 0]  # [B, V]
@@ -1548,8 +1517,8 @@ def forward_ragged(
     else:
         x_last = x[0][out_idx]  # [B, O, D]
         logits = _logits(params, cfg, x_last)  # [B, O, V]
-    out = _results(logits, k_cache, v_cache, conv_state, conv, rule, ring,
-                   load, moe_load)
+    out = _results(logits, k_cache, v_cache, conv_state,
+                   state.after(conv, rule, ring), load, moe_load)
     return out + (x[0],) if hidden else out
 
 
@@ -1658,13 +1627,12 @@ def forward_mtp(
     return logits, k_cache, load
 
 
-def _results(logits, k_cache, v_cache, conv_state, conv, rule, ring, load,
-             moe_load):
-    """(logits, caches'[, conv_state'][, load]) of a step forward;
-    conv_state' in the form `conv_state` was given in."""
+def _results(logits, k_cache, v_cache, conv_state, after, load, moe_load):
+    """(logits, caches'[, conv_state'][, load]) of a step forward:
+    `after`, the SlotState it leaves, where a `conv_state` was given."""
     out = (logits, k_cache, v_cache)
     if conv_state is not None:
-        out += (_as_given(conv, rule, ring, conv_state),)
+        out += (after,)
     return out + (load,) if moe_load else out
 
 
@@ -1681,8 +1649,8 @@ def forward_decode(
     active=None,  # [B] int32/bool — live decode slots (None = all live)
     mesh=None,  # the mesh this forward is jitted over (pallas under tp)
     moe_load: bool = False,  # also return the [Le, E] expert loads
-    conv_state=None,  # [Lc, K-1, >= B, D] or a SlotState (donated; loop
-    # carry): row b is slot b's (of the rings too)
+    conv_state=None,  # a SlotState ([Lc, K-1, >= B, D]: its `conv`; donated,
+    # the loop's carry): row b is slot b's (of the rings too)
 ):
     """One decode step for the whole batch (row b is slot b); returns
     (logits [B,V], caches'), then conv_state' where one was given and,
@@ -1700,7 +1668,7 @@ def forward_decode(
     pos2 = positions[:, None]  # [B,1]
     write_slots = flat_slot_indices(page_table, pos2, page_size)[:, 0]  # [B]
     seq_lens = positions + 1
-    state = split_state(conv_state)
+    state = slot_state(conv_state)
     if state.ring is not None:  # row b is slot b: one table, every layer
         rows = state.ring.rows
         every = jnp.arange(B, dtype=jnp.int32)
@@ -1790,10 +1758,10 @@ def forward_decode(
         return (x, kc, vc, conv, rule, ring, *handed.held, load)
 
     x, k_cache, v_cache, conv, rule, ring, *_, load = scan_layers(
-        cfg, body, x, params["layers"], k_cache, v_cache, *state, *memo)
+        cfg, body, x, params["layers"], k_cache, v_cache, *state.loop_carry(), *memo)
     logits = _logits(params, cfg, x)[:, 0, :]
-    return _results(logits, k_cache, v_cache, conv_state, conv, rule, ring,
-                    load, moe_load)
+    return _results(logits, k_cache, v_cache, conv_state,
+                    state.after(conv, rule, ring), load, moe_load)
 
 
 def forward_embed(

@@ -55,10 +55,6 @@ class ModelRegistry:
             key = smart_match(name, self._entries.keys())
             return self._entries.get(key) if key else None
 
-    def is_loaded(self, name: str) -> bool:
-        key = smart_match(name, self.engine.loaded_models())
-        return key is not None
-
     # -- mutations ------------------------------------------------------------
     def register(self, name: str, checkpoint_path: Optional[str] = None) -> RegistryEntry:
         cfg = get_model_config(name)

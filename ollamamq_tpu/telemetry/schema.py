@@ -185,11 +185,6 @@ SCHED_DECISIONS_TOTAL = REGISTRY.counter(
     "series stays 0", labels=("policy",))
 
 
-def total_shed() -> float:
-    """Sum of ollamamq_shed_total over all reasons (TUI chip)."""
-    return sum(child.value for _, child in SHED_TOTAL.series())
-
-
 # -- latency attribution / SLO / alerting (telemetry/attribution.py,
 # telemetry/slo.py, engine/health.py watchdog) ------------------------------
 REQUEST_PHASE_MS = REGISTRY.histogram(

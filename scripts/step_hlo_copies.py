@@ -6,7 +6,7 @@ chip:
 
     python scripts/step_hlo_copies.py benchmarks/configs/<name>.json \
         [--min-mb 32] [--tokens N] [--rehearse] [--default-layouts] \
-        [--dump DIR]
+        [--dump DIR] [--by-scope] [--text DIR]
 
 A weight that the compiler reads in another order than it is stored in is
 re-laid once a launch of the program: `copy.126 bf16[6,1536,24576]`, the whole
@@ -26,9 +26,9 @@ of its 16.4 ms step where the loop's two are 0.27.
 
 The configuration file's keys make the `ModelConfig` and its `server_flags`
 the pool and the step shapes, as `benchmarks/serve.py` hands them to the CLI;
-the programs are the engine's own jit sites (`ModelRuntime._get_ragged_jit` at
+the programs are the engine's own (`engine/step_program.py`: `ragged_step` at
 `--max-batch-tokens`, with the prediction module's carries under `--spec`;
-`_get_decode_jit` at `--decode-steps` otherwise), lowered with the weights'
+`decode_scan` at `--decode-steps` otherwise), lowered with the weights'
 shapes in the formats `models/llama.py:weight_formats` names — the latent
 up-projections, and `wq` / `wk` where `_qkv` splits its projections into heads
 at once; nothing for a model that norms them flat first, whose compiler reads
@@ -51,12 +51,13 @@ Not on the serving path: nothing imports this module.
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import math
 import os
 import re
 import sys
-from types import SimpleNamespace
+from typing import NamedTuple
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -233,6 +234,18 @@ def weight_copies(found: list, params) -> list:
             if f["moves"] == "copy" and tuple(f["dims"]) in named]
 
 
+_MOSAIC_BODY = re.compile(r'(\\22body\\22: \\22)[^\\]*(\\22)')
+
+
+def masked_text(lowered) -> str:
+    """A lowered program's StableHLO text with every Mosaic kernel's
+    serialised body masked: the body carries its source file's PATH and
+    LINES, so two checkouts, or an edit above a kernel, never give the same
+    bytes for the same kernel (compare the kernels' `jax.make_jaxpr` strings
+    beside it after an edit to a kernel file)."""
+    return _MOSAIC_BODY.sub(r"\1MASKED\2", lowered.as_text())
+
+
 def describe_v5e():
     from jax.experimental import topologies
 
@@ -240,38 +253,65 @@ def describe_v5e():
                                         topology_name="v5e:2x2")
 
 
-def step_programs(mc, flags, topo, tokens: int,
-                  default_layouts: bool = False):
-    """{jit name: jax.stages.Lowered} of the step programs a server of
-    `mc` under the CLI flags `flags` (an argparse namespace of
-    `cli.build_parser`) launches in its steady state, for the described
-    chips of `topo`, the ragged step at a stream of `tokens`; and the
-    abstract params they were lowered with."""
+class StepArgs(NamedTuple):
+    """The abstract arguments of a configuration's step programs (`step_args`)
+    and the mesh they are sharded over (None: one chip)."""
+    mesh: object
+    params: dict
+    carried: tuple  # the two pools, `recent`, `last_ids`, the per-slot state
+    drafts: tuple  # a prediction module's two carries, else ()
+    buf: object  # (words) -> the packed host input's (step_pack)
+
+    def lower(self, fn, words: int, drafts: bool = True):
+        """`fn`, a jit of `engine/step_program.py`, lowered for them."""
+        return fn.lower(self.params, self.buf(words), *self.carried,
+                        *(self.drafts if drafts else ()))
+
+    @property
+    def carried_bytes(self) -> int:
+        import jax
+
+        return sum(a.size * a.dtype.itemsize for a in
+                   jax.tree_util.tree_leaves((self.carried, self.drafts)))
+
+
+def step_args(mc, dims, devices, *, num_pages: int, ring_tokens: int,
+              tp: int = 1, mtp: bool = False,
+              default_layouts: bool = False) -> StepArgs:
+    """What a runtime of `mc` hands its step programs, as
+    `ShapeDtypeStruct`s on the described `devices`: the weights (in the
+    formats `models/llama.py:weight_formats` names unless `default_layouts`;
+    sharded over a `tp` mesh), the two pools of `num_pages` pages — K and V
+    rows, or a latent-attention model's latent rows and index keys: two
+    pools of different widths (`ModelConfig.kv_row_dims`) —, the penalty
+    ring, the id carry, the per-slot state (its rings sized for a stream of
+    `ring_tokens`; None, no leaf, for a model that keeps none) and with
+    `mtp` the prediction module's drafts and its rows' lengths. `dims`:
+    `step_program.StepDims`. The ONE place they are built:
+    `tests/chip_compile.py` lowers through it too."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, SingleDeviceSharding
+    from jax.sharding import (NamedSharding, PartitionSpec,
+                              SingleDeviceSharding)
 
-    from ollamamq_tpu.engine import engine as eng
     from ollamamq_tpu.models import llama
     from ollamamq_tpu.parallel.mesh import make_mesh
     from ollamamq_tpu.parallel.sharding import (kv_cache_spec,
                                                 param_partition_specs)
 
-    S, ps = flags.max_slots, flags.page_size
+    S, ps = dims.max_slots, dims.page_size
     shapes = jax.eval_shape(
         lambda: llama.init_params(mc, jax.random.PRNGKey(0)))
     mesh = None
-    if flags.tp > 1:
-        from jax.sharding import PartitionSpec
-
-        mesh = make_mesh(tp=flags.tp, devices=topo.devices[:flags.tp])
+    if tp > 1:
+        mesh = make_mesh(tp=tp, devices=devices[:tp])
         rep = NamedSharding(mesh, PartitionSpec())
         pool_sharding = NamedSharding(mesh, kv_cache_spec())
         param_shardings = jax.tree_util.tree_map(
             lambda spec: NamedSharding(mesh, spec),
             param_partition_specs(shapes))
     else:
-        rep = pool_sharding = SingleDeviceSharding(topo.devices[0])
+        rep = pool_sharding = SingleDeviceSharding(devices[0])
         param_shardings = jax.tree_util.tree_map(lambda _: rep, shapes)
 
     def s(shape, dt=jnp.int32, sharding=rep):
@@ -283,40 +323,48 @@ def step_programs(mc, flags, topo, tokens: int,
         for name, fmt in llama.weight_formats(mc, params).items():
             leaf = params["layers"][name]
             params["layers"][name] = s(leaf.shape, leaf.dtype, fmt)
-
-    rt = object.__new__(eng.ModelRuntime)
-    rt.cfg, rt.attn_impl, rt.mesh = mc, "pallas", mesh
-    rt.ecfg = SimpleNamespace(
-        page_size=ps, max_slots=S, max_pages_per_seq=flags.max_pages_per_seq,
-        repeat_last_n=eng.EngineConfig.repeat_last_n)
-    rt._prefill_jits, rt._decode_jits = {}, {}
-    rt.mtp = bool(flags.spec and mc.num_nextn_predict_layers)
     pools = tuple(
-        s((mc.cache_layers, flags.num_pages * ps, lanes), jnp.bfloat16,
+        s((mc.cache_layers, num_pages * ps, lanes), jnp.bfloat16,
           pool_sharding) for lanes in mc.kv_row_dims)
     state = jax.tree_util.tree_map(
         lambda a: s(a.shape, a.dtype),
         jax.eval_shape(lambda: llama.alloc_slot_state(
-            mc, S, ring_rows=mc.ring_rows(eng.ragged_budget(flags), ps))))
-    carried = (*pools, s((S + 1, rt.ecfg.repeat_last_n)), s((S,)), state)
+            mc, S, ring_rows=mc.ring_rows(ring_tokens, ps))))
+    return StepArgs(
+        mesh, params,
+        (*pools, s((S + 1, dims.repeat_last_n)), s((S,)), state),
+        (s((S + 1,)),) * 2 if mtp else (), lambda words: s((words,)))
+
+
+def step_programs(mc, flags, topo, tokens: int,
+                  default_layouts: bool = False):
+    """{jit name: jax.stages.Lowered} of the step programs a server of
+    `mc` under the CLI flags `flags` (an argparse namespace of
+    `cli.build_parser`) launches in its steady state, for the described
+    chips of `topo`, the ragged step at a stream of `tokens`; and the
+    abstract params they were lowered with."""
+    from ollamamq_tpu.engine import step_program
+    from ollamamq_tpu.engine.engine import EngineConfig, ragged_budget
+
+    dims = step_program.StepDims(
+        flags.page_size, flags.max_slots, flags.max_pages_per_seq,
+        EngineConfig.repeat_last_n)
+    mtp = bool(flags.spec and mc.num_nextn_predict_layers)
+    args = step_args(mc, dims, topo.devices, num_pages=flags.num_pages,
+                     ring_tokens=ragged_budget(flags), tp=flags.tp, mtp=mtp,
+                     default_layouts=default_layouts)
     every = (True, True, True)  # penalties, masks, sampling: the superset
-    # The jit itself, not the first-call wrapper that times its compile.
-    plain = eng._sp_note_compile
-    eng._sp_note_compile = \
-        lambda rt, site, key, cache, fn: cache.setdefault(key, fn)
-    try:
-        drafts = (s((S + 1,)),) * 2 if rt.mtp else ()
-        out = {"mq_ragged_step": rt._get_ragged_jit(
-            tokens, 1 if rt.mtp else 0, every).lower(
-                params, s((rt._ragged_layout(tokens).size,)), *carried,
-                *drafts)}
-        if not flags.spec:
-            out["mq_decode_scan"] = rt._get_decode_jit(
-                flags.decode_steps, every).lower(
-                    params, s((rt._decode_layout().size,)), *carried)
-    finally:
-        eng._sp_note_compile = plain
-    return out, params
+    built = dict(attn_impl="pallas", mesh=args.mesh)
+    out = {"mq_ragged_step": args.lower(
+        step_program.ragged_step(mc, dims, tokens, 1 if mtp else 0, every,
+                                 mtp=mtp, **built),
+        dims.ragged_layout(tokens).size)}
+    if not flags.spec:
+        out["mq_decode_scan"] = args.lower(
+            step_program.decode_scan(mc, dims, flags.decode_steps, every,
+                                     **built),
+            dims.decode_layout().size)
+    return out, args.params
 
 
 def main(argv=None) -> int:
@@ -334,6 +382,11 @@ def main(argv=None) -> int:
     ap.add_argument("--dump", help="write each program's compiled HLO here")
     ap.add_argument("--by-scope", action="store_true",
                     help="add the compiler's estimated cycles by stage")
+    ap.add_argument("--text", metavar="DIR",
+                    help="compile nothing: write each program's lowered "
+                    "StableHLO text there, the Mosaic bodies masked, and "
+                    "print its sha256 (did a change move a step program? run "
+                    "both trees AT ONE PATH and diff the directories)")
     args = ap.parse_args(argv)
 
     import jax
@@ -352,6 +405,18 @@ def main(argv=None) -> int:
     lowered, params = step_programs(
         mc, flags, topo, args.tokens or flags.max_batch_tokens,
         args.default_layouts)
+    if args.text:
+        os.makedirs(args.text, exist_ok=True)
+        for name, low in lowered.items():
+            text = masked_text(low)
+            with open(os.path.join(
+                    args.text, f"{cfg['name']}.{name}.stablehlo.txt"),
+                    "w") as f:
+                f.write(text)
+            print(json.dumps({
+                "config": cfg["name"], "program": name,
+                "sha256": hashlib.sha256(text.encode()).hexdigest()}))
+        return 0
     n_weight = 0
     for name, low in lowered.items():
         hlo = low.compile().as_text()

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from ollamamq_tpu.config import ModelConfig
-from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.attention import (paged_decode_attention_any,
                                         ragged_attention_any)
 from ollamamq_tpu.ops.quant import QuantKV
@@ -84,74 +83,52 @@ LOOP_CFG = ModelConfig(
     tie_embeddings=True)
 
 
-def _lower_step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
-    """One of the pipelined loop's two programs as the engine jits it,
-    lowered for one described chip: (lowered, the packed input's words,
-    the bytes of the state it carries)."""
-    from types import SimpleNamespace
+def _script():
+    """`scripts/step_hlo_copies.py`, imported (it puts the repo's root, and
+    so `benchmarks`, on the path)."""
+    import sys
 
-    from ollamamq_tpu.config import ATTENTION
-    from ollamamq_tpu.engine import engine as eng_mod
-    from ollamamq_tpu.engine.engine import ModelRuntime
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "scripts"))
+    import step_hlo_copies
 
-    one = SingleDeviceSharding(v5e.devices[0])
+    return step_hlo_copies
 
-    def s(shape, dt=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
-    # The jit itself, not the first-call wrapper that times the compile.
-    monkeypatch.setattr(eng_mod, "_sp_note_compile",
-                        lambda rt, site, key, cache, fn: cache.setdefault(
-                            key, fn))
-    S, W = B, 64
-    rt = object.__new__(ModelRuntime)
-    rt.cfg, rt.attn_impl, rt.mesh = cfg, "pallas", None
-    rt.ecfg = SimpleNamespace(page_size=PS, max_slots=S,
-                              max_pages_per_seq=MP, repeat_last_n=W)
-    rt._prefill_jits, rt._decode_jits = {}, {}
-    shapes = jax.eval_shape(
-        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
-    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), shapes)
-    # K and V rows — or a latent-attention model's latent rows and index
-    # keys: two pools of different widths (ModelConfig.kv_row_dims).
-    pool, pool2 = (s((cfg.cache_layers, NP * PS, lanes), jnp.bfloat16)
-                   for lanes in cfg.kv_row_dims)
-    recent, last_ids = s((S + 1, W)), s((S,))
-    # The per-slot state: None (no leaf) for a model without such layers,
-    # the conv window's array, or a SlotState with the rule's state too.
-    # ...or a WindowState with the window layers' K/V rings.
-    conv = jax.tree_util.tree_map(
-        lambda a: s(a.shape, a.dtype),
-        jax.eval_shape(lambda: llama.alloc_slot_state(
-            cfg, S, ring_rows=cfg.ring_rows(T, PS))))
-    drafts = ()
-    if which == "mq_spec_step":  # the ragged step of a --spec runtime whose
-        rt.mtp = True  # proposer is the model's prediction module
-        drafts = (s((S + 1,)),) * 2  # its drafts and its rows' lengths
-        fn = rt._get_ragged_jit(T, 1, (True, True, True))
-        words = rt._ragged_layout(T).size
-    elif which == "mq_ragged_step":
-        fn = rt._get_ragged_jit(T, 0, (True, True, True))
-        words = rt._ragged_layout(T).size
+def _lower_step_program(v5e, which, cfg=LOOP_CFG, fresh=False):
+    """One of the pipelined loop's two programs as the engine jits it
+    (`engine/step_program.py`'s builders; `fresh`: a jit object of its own,
+    traced anew), lowered for one described chip: (lowered, the packed
+    input's words, the bytes of the state it carries)."""
+    from ollamamq_tpu.engine import step_program as built_by
+
+    dims = built_by.StepDims(PS, B, MP, 64)
+    # `mq_spec_step`: the ragged step of a --spec runtime whose proposer is
+    # the model's prediction module, with its drafts and its rows' lengths.
+    mtp = which == "mq_spec_step"
+    args = _script().step_args(cfg, dims, v5e.devices, num_pages=NP,
+                               ring_tokens=T, mtp=mtp, default_layouts=True)
+    every, built = (True, True, True), dict(attn_impl="pallas", mesh=None,
+                                            fresh=fresh)
+    if which == "mq_decode_scan":
+        fn = built_by.decode_scan(cfg, dims, 8, every, **built)
+        words = dims.decode_layout().size
     else:
-        fn = rt._get_decode_jit(8, (True, True, True))
-        words = rt._decode_layout().size
-    carried = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
-        (pool, pool2, recent, last_ids, conv, drafts)))
+        fn = built_by.ragged_step(cfg, dims, T, int(mtp), every, mtp=mtp,
+                                  **built)
+        words = dims.ragged_layout(T).size
     # The step's host inputs are ONE packed int32 array (step_pack).
-    return fn.lower(params, s((words,)), pool, pool2, recent, last_ids,
-                    conv, *drafts), words, carried
+    return args.lower(fn, words), words, args.carried_bytes
 
 
 _STEP_PROGRAMS = {}
 
 
-def step_program(v5e, which, monkeypatch, cfg=LOOP_CFG):
+def step_program(v5e, which, cfg=LOOP_CFG):
     """`_lower_step_program`, compiled ONCE a (configuration, program) for
     the cases that read it: (lowered, compiled, words, bytes carried)."""
     if (cfg, which) not in _STEP_PROGRAMS:
-        lowered, words, carried = _lower_step_program(v5e, which,
-                                                      monkeypatch, cfg)
+        lowered, words, carried = _lower_step_program(v5e, which, cfg)
         _STEP_PROGRAMS[cfg, which] = (lowered, lowered.compile(), words,
                                       carried)
     return _STEP_PROGRAMS[cfg, which]
@@ -164,11 +141,8 @@ def _step_hlo_copies(capsys, name, *flags):
     import contextlib
     import json
     import signal
-    import sys
 
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "scripts"))
-    import step_hlo_copies
+    step_hlo_copies = _script()
 
     @contextlib.contextmanager
     def time_limit(seconds):

@@ -247,15 +247,14 @@ KX_WIDTHS = ModelConfig(
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
 def test_k_exaone_width_step_programs_carry_pool_and_rings_in_place(
-        v5e, which, monkeypatch):
+        v5e, which):
     """Window and full attention in one stack (PR 50), at K-EXAONE's widths:
     both kernels at 8 kv heads of 128 under group 8 compile for the chip
     with a window — under names of their own, three call sites for the four
     window layers (two of them one scan's) beside ONE of the full layer's
     name; the pool — for the ONE full layer — the rings (4 layers x 65 slots
     x 672 rows), the penalty ring and the id carry all come back aliased."""
-    _, compiled, _, carried = step_program(v5e, which, monkeypatch,
-                                            KX_WIDTHS)
+    _, compiled, _, carried = step_program(v5e, which, KX_WIDTHS)
     text = compiled.as_text()
     swa = {"mq_ragged_step": ragged_attention.WINDOW_NAME,
            "mq_decode_scan": paged_attention.WINDOW_NAME}[which]

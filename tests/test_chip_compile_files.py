@@ -34,8 +34,8 @@ def test_no_step_program_copies_the_conv_window(v5e, capsys, name):
     cfg, mc = _file_model(name)
     slots = int(cfg["server_flags"][cfg["server_flags"].index("--max-slots")
                                     + 1])
-    window = llama.split_state(jax.eval_shape(
-        lambda: llama.alloc_slot_state(mc, slots))).conv.shape
+    window = jax.eval_shape(
+        lambda: llama.alloc_slot_state(mc, slots)).conv.shape
     assert window[1:3] == (mc.state_window[0] - 1, slots), window
     for p in programs:
         copies = [m for m in p["moves"] if m["moves"] == "copy"

@@ -38,7 +38,7 @@ DEEPSEEK_CFG = ModelConfig(
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
 def test_deepseek_width_step_programs_carry_both_pools_in_place(
-        v5e, which, monkeypatch):
+        v5e, which):
     """Latent attention with the indexer's selection (PR 39), at
     DeepSeek-V3.2's widths: the indexer's, the selection's and the sparse
     attention's kernels compile for the chip, one launch each a traced layer
@@ -50,8 +50,7 @@ def test_deepseek_width_step_programs_carry_both_pools_in_place(
     context] float32 score a HEAD: the one [T, C] score a token is 2 MB here
     (64 x 8192 x 4 B), all 128 heads' would be 268 MB, the bound is a quarter
     of that above what the program holds without the indexer."""
-    _, compiled, _, carried = step_program(v5e, which, monkeypatch,
-                                            DEEPSEEK_CFG)
+    _, compiled, _, carried = step_program(v5e, which, DEEPSEEK_CFG)
     text = compiled.as_text()
     for name, n in (("mla_sparse_paged_attention_pallas", 2),
                     ("dsa_index_pallas", 2), ("dsa_select_pallas", 2)):
@@ -124,7 +123,7 @@ OPENPANGU_CFG = ModelConfig(
 
 
 def test_openpangu_width_spec_step_carries_the_pool_and_the_drafts_in_place(
-        v5e, monkeypatch):
+        v5e):
     """The `--spec` runtime's ragged step with the prediction module (PR 42),
     at openPangu-Ultra-MoE's widths: the dense latent attention kernel — the
     attention kernel with the selection's operands compiled out — compiles
@@ -137,7 +136,7 @@ def test_openpangu_width_spec_step_carries_the_pool_and_the_drafts_in_place(
     from ollamamq_tpu.ops.pallas.mla_attention import MTP_NAME
 
     _, compiled, _, carried = step_program(
-        v5e, "mq_spec_step", monkeypatch, OPENPANGU_CFG)
+        v5e, "mq_spec_step", OPENPANGU_CFG)
     text = compiled.as_text()
     names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
                        r"\"tpu_custom_call\"", text)

@@ -36,7 +36,7 @@ FALCON_H1_CFG = ModelConfig(
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
 def test_falcon_h1_width_step_programs_carry_the_mixer_state_in_place(
-        v5e, which, monkeypatch):
+        v5e, which):
     """Attention AND a state-space mixer in every layer (PR 54), at
     Falcon-H1-34B's widths: the attention kernels at 4 kv heads of 128 under
     20 q heads (group 5) and the recurrence's step kernel on [256, 4096]
@@ -47,8 +47,7 @@ def test_falcon_h1_width_step_programs_carry_the_mixer_state_in_place(
     the id carry all come back aliased; the temporaries stay under a QUARTER
     of the state (73 MB in the scan, a layer's slice of `wq` among them: a
     gather of a layer's 64 rows would be half of the state, 268 MB)."""
-    _, compiled, _, carried = step_program(v5e, which, monkeypatch,
-                                            FALCON_H1_CFG)
+    _, compiled, _, carried = step_program(v5e, which, FALCON_H1_CFG)
     text = compiled.as_text()
     assert "ssd_step_pallas" in text
     assert text.count("tpu_custom_call") >= 2  # attention, the step kernel

@@ -47,13 +47,13 @@ OLMO_HYBRID_CFG = ModelConfig(
 
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
-def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
+def test_step_programs_alias_pools_ring_and_id_carry(v5e, which):
     """The two programs of the pipelined loop as the engine jits them
     (PR 28 added the `last_ids` carry: a step launched behind an unsettled
     one reads a row's input token from it): both pools, the penalty ring
     and the carry are donated and come back aliased — the compiled program
     holds no second copy of any."""
-    _, compiled, _, carried = step_program(v5e, which, monkeypatch)
+    _, compiled, _, carried = step_program(v5e, which)
     S, W = B, 64
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
@@ -66,18 +66,18 @@ def test_step_programs_alias_pools_ring_and_id_carry(v5e, which, monkeypatch):
 @pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG],
                          ids=["uniform", "lfm2_widths"])
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
-def test_step_programs_lower_to_the_same_text_twice(v5e, which, monkeypatch,
-                                                    cfg):
-    """Two runtimes built one after the other lower a step program to
-    the same StableHLO text — a uniform stack's and one whose layers
-    differ (a scan over a period of kinds, expert matmuls): nothing in the
-    trace depends on what was built before it (a counter, an id, a cache's
-    order). A change that is
+def test_step_programs_lower_to_the_same_text_twice(v5e, which, cfg):
+    """A step program built twice (`engine/step_program.py`'s builders, each
+    time a jit object of its own: `fresh`) lowers to the same StableHLO
+    text — a uniform stack's and one whose layers differ (a scan over a
+    period of kinds, expert matmuls): nothing in the trace depends on what
+    was built before it (a counter, an id, a cache's order), and nothing on
+    a runtime — there is none here. A change that is
     to leave the step programs alone is shown to by comparing this text,
     hashed, between its parent and itself (ROADMAP C11) — which says
     something only if the text is a function of the code."""
     first, second = (
-        _lower_step_program(v5e, which, monkeypatch, cfg)[0].as_text()
+        _lower_step_program(v5e, which, cfg, fresh=True)[0].as_text()
         for _ in range(2))
     assert which in first and "stablehlo." in first
     assert first == second
@@ -85,7 +85,7 @@ def test_step_programs_lower_to_the_same_text_twice(v5e, which, monkeypatch,
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
 def test_lfm2_width_step_programs_carry_pool_and_conv_state_in_place(
-        v5e, which, monkeypatch):
+        v5e, which):
     """A stack whose layers differ (PR 32), at LFM2-8B-A1B's widths: the
     attention kernels at 8 kv heads of 64 (512 lanes, group 4) and the
     grouped expert matmul at [2048, 1792] compile for the chip; the KV pool
@@ -95,8 +95,7 @@ def test_lfm2_width_step_programs_carry_pool_and_conv_state_in_place(
     expert layer's gate matrix, 235 MB), and the scan traces each distinct
     layer of the period once: 3 grouped matmuls for each of its 4 layers,
     one attention kernel."""
-    _, compiled, _, carried = step_program(v5e, which, monkeypatch,
-                                            LFM2_CFG)
+    _, compiled, _, carried = step_program(v5e, which, LFM2_CFG)
     text = compiled.as_text()
     assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3 * 4
     assert "ragged-dot" not in text
@@ -110,7 +109,7 @@ def test_lfm2_width_step_programs_carry_pool_and_conv_state_in_place(
 
 @pytest.mark.parametrize("which", ["mq_ragged_step", "mq_decode_scan"])
 def test_olmo_hybrid_width_step_programs_carry_the_rule_state_in_place(
-        v5e, which, monkeypatch):
+        v5e, which):
     """Linear-attention layers (PR 35), at Olmo-Hybrid-7B's widths: the
     attention kernels at 30 kv heads of 128 (3840 lanes, group 1) and the
     rule's step kernel on [96, 5760] float32 rows compile for the chip; the
@@ -119,8 +118,7 @@ def test_olmo_hybrid_width_step_programs_carry_the_rule_state_in_place(
     held exactly — no lane padding — and never copied), the ring and the id
     carry all come back aliased; the temporaries stay under a TENTH of the
     rule's state (a gather of a layer's 64 rows would be a sixth)."""
-    _, compiled, _, carried = step_program(v5e, which, monkeypatch,
-                                            OLMO_HYBRID_CFG)
+    _, compiled, _, carried = step_program(v5e, which, OLMO_HYBRID_CFG)
     text = compiled.as_text()
     assert "gated_delta_step_pallas" in text
     assert text.count("tpu_custom_call") >= 1 + 3  # attention, 3 linear layers
@@ -134,15 +132,14 @@ def test_olmo_hybrid_width_step_programs_carry_the_rule_state_in_place(
 
 @pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG, OLMO_HYBRID_CFG],
                          ids=["dense", "lfm2", "olmo_hybrid"])
-def test_ragged_step_is_fed_one_host_array(v5e, monkeypatch, cfg):
+def test_ragged_step_is_fed_one_host_array(v5e, cfg):
     """One upload a step: besides `params`, the compiled ragged step has
     exactly ONE parameter that is not donated device state — the packed
     int32 buffer of its host inputs. The RNG key is made inside (no key
     parameter), so nothing else is dispatched or transferred for a step.
     The conv layers' state is one more donated argument (no leaf at all
     for a model without such layers), a linear-attention model's two."""
-    lowered, _, words, _ = step_program(v5e, "mq_ragged_step", monkeypatch,
-                                        cfg)
+    lowered, _, words, _ = step_program(v5e, "mq_ragged_step", cfg)
     _params, *rest = lowered.args_info[0]
     rest = jax.tree_util.tree_leaves(rest)
     fed = [a for a in rest if not a.donated]

@@ -392,10 +392,13 @@ def test_step_sample_carries_mla_wide_tokens(name):
     where the kernel serves; nothing in a fused scan or on the jnp path. And
     `mla_absorbed_rows` beside it: the lead where every row behind it is a
     wide span's, the rung where one is not, 0 where nothing is expanded."""
+    import dataclasses
     import functools
     import types
 
-    from ollamamq_tpu.engine.engine import ModelRuntime
+    from ollamamq_tpu.config import MODEL_CONFIGS
+    from ollamamq_tpu.engine.step_work import KernelCounts, StepWork
+    from ollamamq_tpu.ops.pallas.kv_contract import tall_tokens
     from ollamamq_tpu.ops.pallas.mla_attention import (WIDE, absorbed_rows,
                                                        wide_tokens)
     from ollamamq_tpu.telemetry import schema as tm
@@ -406,22 +409,24 @@ def test_step_sample_carries_mla_wide_tokens(name):
         tm.MLA_ROWS_TOTAL, tm.DSA_CTX_TOKENS_TOTAL,
         tm.DSA_SELECTED_TOKENS_TOTAL, tm.MLA_WIDE_TOKENS_TOTAL,
         tm.MLA_ABSORBED_ROWS_TOTAL)]
-    cfg = types.SimpleNamespace(kv_lora_rank=512, index_topk=2048)
+    cfg = dataclasses.replace(MODEL_CONFIGS["test-tiny-deepseek-v32"],
+                              kv_lora_rank=512, index_topk=2048)
     widths = dict(heads=128, lanes=640, rank=512, nope=128, v=128)
-    rt = types.SimpleNamespace(
-        cfg=cfg, LATENT_FIELDS=ModelRuntime.LATENT_FIELDS, _tm_dsa=series,
-        _wide_tokens=functools.partial(wide_tokens, **widths),
-        _absorbed_rows=functools.partial(absorbed_rows, **widths))
+    work = StepWork(cfg, 32, "wide-" + name, KernelCounts(
+        tall_tokens, functools.partial(wide_tokens, **widths),
+        functools.partial(absorbed_rows, **widths)))
+    tokens, kv = zip(*spans)
     noted = {}
     sp = types.SimpleNamespace(note=noted.update)
-    ModelRuntime._note_latent(rt, sp, spans, stream_len=rung)
+    work.note(sp, list(tokens), list(kv), stream_len=rung)
     assert noted["mla_wide_tokens"] == wide
     assert noted["mla_absorbed_rows"] == absorbed
     assert noted["mla_rows"] == sum(n for n, _ in spans)
     assert series[3].value == wide and series[4].value == absorbed
-    ModelRuntime._note_latent(rt, sp, [(8, kv) for _, kv in spans], scan=True)
+    work.note(sp, [8] * len(kv), list(kv), scan=True)
     assert noted["mla_wide_tokens"] == noted["mla_absorbed_rows"] == 0
-    rt._wide_tokens = None  # the jnp path: no kernel, nothing expanded
-    ModelRuntime._note_latent(rt, sp, spans, stream_len=rung)
+    # the jnp path: no kernel, nothing expanded
+    StepWork(cfg, 32, "wide-" + name).note(sp, list(tokens), list(kv),
+                                           stream_len=rung)
     assert noted["mla_wide_tokens"] == noted["mla_absorbed_rows"] == 0
     assert series[3].value == wide and series[4].value == absorbed

@@ -108,7 +108,7 @@ def test_prefill_in_chunks_then_decode_through_the_cache(params):
     close(out[1], ref[49])
     got, st = decode_scan(FALCON, params, st, {1: (toks[50:58], 50)}, [1])
     close(got[1], ref[50:58])
-    assert isinstance(st[2], llama.SsmState)
+    assert st[2].ssm is not None and st[2].ring is None
     assert st[2].ssm.shape == (FALCON.num_layers, B + 1, DS, H * DH)
     assert st[2].ssm.dtype == jnp.float32
     assert st[2].conv.shape == (FALCON.num_layers, 3, B, FALCON.ssm_conv_dim)
@@ -152,7 +152,7 @@ def test_a_bfloat16_state_misses_the_tolerance(params):
     worst = 0.0
     for i in range(32, 44):
         kc, vc, slot = st
-        st = (kc, vc, llama.SsmState(slot.conv, slot.ssm.astype(
+        st = (kc, vc, slot._replace(ssm=slot.ssm.astype(
             jnp.bfloat16).astype(jnp.float32)))
         got, st = decode_scan(FALCON, params, st, {0: (toks[i:i + 1], i)},
                               [0])
@@ -475,13 +475,14 @@ def test_overlapped_against_serial_gives_the_same_ids(falcon, monkeypatch):
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(falcon)
     n = FALCON.num_layers
-    assert isinstance(rt.slot_state, llama.SsmState)
+    assert rt.slot_state.ssm is not None
     assert rt.slot_state.conv.shape == (n, 3, 4, FALCON.ssm_conv_dim)
     assert rt.slot_state.ssm.shape == (n, 5, DS, H * DH)
     assert rt.kc.shape[0] == n  # the SAME layers' K and V, in the pool
-    assert rt.ssm_state_bytes == n * 5 * DS * H * DH * 4
-    assert rt.stats()["ssm_state_bytes"] == rt.ssm_state_bytes
-    assert rt.lin_state_bytes == 0
+    held = rt.state_bytes
+    assert held["ssm_state_bytes"] == n * 5 * DS * H * DH * 4
+    assert rt.stats()["ssm_state_bytes"] == held["ssm_state_bytes"]
+    assert held["lin_state_bytes"] == 0
     assert not any("lin_step_rows" in s or "conv_state_resets" in s
                    for s in samples)
     ragged = [s for s in samples if s["mode"] == "ragged"]

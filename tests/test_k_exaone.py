@@ -80,7 +80,7 @@ def want(mc, params, tokens):
 
 
 def state(mc=KX, garbage=0.0):
-    """(kc, vc, WindowState): an empty pool — of the FULL layers only — and
+    """(kc, vc, SlotState): an empty pool — of the FULL layers only — and
     rings that an earlier request left full of `garbage`."""
     kv = jnp.zeros((mc.count(ATTENTION), (1 + B * MP) * PS, mc.kv_dim),
                    jnp.float32)

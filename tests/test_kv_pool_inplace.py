@@ -189,7 +189,7 @@ def test_a_hybrid_step_writes_its_rows_of_pool_and_conv_state_only():
         before, after = np.asarray(before), np.asarray(after)
         assert (after[~written] == before[~written]).all()
         assert (after[written] != before[written]).all()
-    before, after = np.asarray(conv), np.asarray(conv2)
+    before, after = np.asarray(conv), np.asarray(conv2.conv)
     # [conv layers, K-1, slots, D]: a tap a plane, a slot a row of it; the
     # padding row's slot, n_slots, is none of them
     assert (after[:, :, [0, 2, 4]] == before[:, :, [0, 2, 4]]).all()
@@ -205,7 +205,7 @@ def test_a_hybrid_step_writes_its_rows_of_pool_and_conv_state_only():
         params, cfg, jnp.asarray([5, 6, 7, 8, 9], jnp.int32),
         jnp.asarray([0, 13, 0, 20, 0], jnp.int32), kc2, vc2, pt5, PS,
         active=active, conv_state=conv2)
-    last = np.asarray(conv3)
+    last = np.asarray(conv3.conv)
     assert (last[:, :, [0, 2, 4]] == after[:, :, [0, 2, 4]]).all()
     for slot in (1, 3):
         assert (last[:, 0, slot] == after[:, 1, slot]).all()

@@ -44,12 +44,12 @@ def make_params(mc, dtype=jnp.float32, seed=0):
 
 
 def state(mc, dtype, garbage=0.0):
-    """(kc, vc, conv): empty pools and a conv state that an earlier request
-    left full of `garbage`."""
+    """(kc, vc, SlotState): empty pools and a conv state that an earlier
+    request left full of `garbage`."""
     kv = jnp.zeros((mc.count(ATTENTION), NP * PS, mc.kv_dim), dtype)
     conv = shortconv.alloc_state(mc.count(CONV), B, mc.conv_L_cache,
                                  mc.hidden_size, dtype)
-    return kv, kv, conv + jnp.asarray(garbage, dtype)
+    return kv, kv, llama.SlotState(conv + jnp.asarray(garbage, dtype))
 
 
 def page_table(mp=None):
@@ -221,7 +221,7 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(chunks):
         at += n
         close(got[1], ref[at - 1])
     # slot 1 holds the state; the other slots kept the earlier request's
-    assert bool(jnp.all(st[2][:, :, [0, 2, 3]] == 3.0))
+    assert bool(jnp.all(st[2].conv[:, :, [0, 2, 3]] == 3.0))
     got, _ = decode_scan(LFM2, params, st, {1: (toks[23:], 23)}, active=[1])
     close(got[1], ref[23:])
 
@@ -306,7 +306,7 @@ def fused_scan(mc, params, st, want, by_slot):
 
 def test_the_fused_scan_beside_inactive_and_mid_prefill_slots():
     fused_scan(LFM2, make_params(LFM2), state(LFM2, jnp.float32, garbage=5.0),
-               want, lambda conv: [np.asarray(conv).swapaxes(1, 2)])
+               want, lambda st: [np.asarray(st.conv).swapaxes(1, 2)])
 
 
 def keeps_the_filler_from_it(want, params, reference, keys, mc):
@@ -454,7 +454,8 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert piped == settled
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(hybrid)
-    assert rt.slot_state.shape == (6, 2, 4, 64) and rt.conv_state_bytes > 0
+    assert rt.slot_state.conv.shape == (6, 2, 4, 64)
+    assert rt.state_bytes["conv_state_bytes"] > 0
     # every launched step says what it did with the conv state, and
     # uploads ONE packed array
     assert all(s["h2d_transfers"] == 1 for s in samples)
@@ -483,7 +484,7 @@ def reused_slot(make_engine, holds_state, monkeypatch):
 
 def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
     def holds_state(rt):  # slot 0 holds its state
-        assert np.abs(np.asarray(rt.slot_state)[:, 0]).max() > 0
+        assert np.abs(np.asarray(rt.slot_state.conv)[:, 0]).max() > 0
 
     reused_slot(_lfm2_engine, holds_state, monkeypatch)
 

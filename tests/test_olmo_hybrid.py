@@ -567,8 +567,8 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert rt.slot_state.conv.shape == (8, 3, 4, 2 * 32 + 64)
     assert rt.slot_state.rule.shape == (8, 5, DK, H * DV)
     assert rt.slot_state.rule.dtype == jnp.float32
-    assert rt.lin_state_bytes == 8 * 5 * DK * H * DV * 4
-    assert rt.stats()["lin_state_bytes"] == rt.lin_state_bytes
+    assert rt.state_bytes["lin_state_bytes"] == 8 * 5 * DK * H * DV * 4
+    assert rt.stats()["lin_state_bytes"] == 8 * 5 * DK * H * DV * 4
     # every launched step says what it did with the state, and uploads ONE
     # packed array
     assert all(s["h2d_transfers"] == 1 for s in samples)
@@ -587,8 +587,8 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
 
 def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
     def holds_state(rt):  # slot 0 holds its state
-        for left in map(np.asarray, rt.slot_state):
-            assert np.abs(left[:, 0]).max() > 0
+        for left in jax.tree_util.tree_leaves(rt.slot_state):
+            assert np.abs(np.asarray(left)[:, 0]).max() > 0
 
     reused_slot(_olmo_engine, holds_state, monkeypatch)
 

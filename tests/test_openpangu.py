@@ -696,7 +696,7 @@ def test_the_modules_scopes_are_in_the_lowered_step_and_in_the_readme(
     def lowered(rt):
         fn = rt._get_ragged_jit(16, rt.spec_k if rt.mtp else 0,
                                 (False, False, False))
-        lay = rt._ragged_layout(16)
+        lay = rt.dims.ragged_layout(16)
         args = (rt.params, jnp.zeros((lay.size,), jnp.int32), rt.kc, rt.vc,
                 rt.recent, rt.last_ids, rt.slot_state)
         args += (rt.draft_ids, rt.len_ids) if rt.mtp else ()

@@ -81,7 +81,7 @@ def page_table():
 
 
 def state(garbage=0.0):
-    """(kc, vc, ScanState): ONE empty pool layer, and per-slot state that an
+    """(kc, vc, SlotState): ONE empty pool layer, and per-slot state that an
     earlier request left full of `garbage`."""
     kv = jnp.zeros((PHI.cache_layers, (1 + B * MP) * PS, PHI.kv_dim),
                    jnp.float32)
@@ -179,7 +179,7 @@ def test_prefill_in_chunks_then_decode_through_pool_rings_and_state(
     for at, logits in got.items():
         np.testing.assert_allclose(logits, want[at], atol=ATOL, rtol=0)
     kc, _, slot = st
-    assert isinstance(slot, llama.ScanState)
+    assert slot.scan is not None and slot.rule is None
     assert kc.shape[0] == 1  # ONE pool layer under 4 attention layers
     assert slot.scan.shape == (PHI.count(MAMBA), B + 1, N, DI)
     assert slot.conv.shape == (PHI.count(MAMBA), 3, B, DI)
@@ -561,12 +561,13 @@ def test_the_engine_serves_it_and_counts_what_the_exit_saves(monkeypatch):
     assert piped == settled
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(eng)
-    assert isinstance(rt.slot_state, llama.ScanState)
+    assert rt.slot_state.scan is not None
     assert rt.kc.shape[0] == 1
-    assert rt.s6_state_bytes == PHI.count(MAMBA) * 5 * N * DI * 4
-    assert rt.stats()["s6_state_bytes"] == rt.s6_state_bytes
-    assert rt.ssm_state_bytes == rt.lin_state_bytes == 0
-    assert rt.ring_bytes > 0
+    held = rt.state_bytes
+    assert held["s6_state_bytes"] == PHI.count(MAMBA) * 5 * N * DI * 4
+    assert rt.stats()["s6_state_bytes"] == held["s6_state_bytes"]
+    assert held["ssm_state_bytes"] == held["lin_state_bytes"] == 0
+    assert held["swa_ring_bytes"] > 0
     ragged = [s for s in samples if s["mode"] == "ragged"]
     assert sum(s["s6_state_resets"] for s in ragged) == 6  # one a request
     assert sum(s["s6_span_tokens"] for s in ragged) >= sum(lens) - 6

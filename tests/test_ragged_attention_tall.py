@@ -85,13 +85,12 @@ def test_tall_tokens_counts_the_whole_stretches(name):
 
 @pytest.mark.parametrize("name", TALL_CASES)
 def test_step_sample_carries_attn_tall_tokens(name):
-    """`ModelRuntime._note_attn` puts the kernel's own count on the step's
-    sample and the /metrics series, beside `attn_pairs`: a ragged step's,
-    where the kernel serves; nothing tall in a fused scan or on the jnp
-    path."""
+    """`StepWork.note` puts the kernel's own count on the step's sample and
+    the /metrics series, beside `attn_pairs`: a ragged step's, where the
+    kernel serves; nothing tall in a fused scan or on the jnp path."""
     import types
 
-    from ollamamq_tpu.engine.engine import ModelRuntime
+    from ollamamq_tpu.engine.step_work import KernelCounts, StepWork
     from ollamamq_tpu.telemetry import schema as tm
 
     case, tall, T = _tall_case(name)
@@ -99,19 +98,19 @@ def test_step_sample_carries_attn_tall_tokens(name):
     series = [c.labels(model="tall-" + name) for c in (
         tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
         tm.ATTN_TALL_TOKENS_TOTAL)]
-    rt = types.SimpleNamespace(
-        cfg=MODEL_CONFIGS["test-tiny"], ATTN_FIELDS=ModelRuntime.ATTN_FIELDS,
-        _tm_attn=series, _tall_tokens=tall_tokens)
+    work = StepWork(MODEL_CONFIGS["test-tiny"], 8, "tall-" + name,
+                    KernelCounts(tall_tokens))
     noted = {}
     sp = types.SimpleNamespace(note=noted.update)
-    ModelRuntime._note_attn(rt, sp, list(spans), list(kv), stream_len=T)
+    work.note(sp, list(spans), list(kv), stream_len=T)
     assert noted["attn_tall_tokens"] == tall
     assert noted["attn_ctx_rows"] == sum(kv)
     assert series[2].value == tall
-    ModelRuntime._note_attn(rt, sp, list(spans), list(kv), scan=True)
+    work.note(sp, list(spans), list(kv), scan=True)
     assert noted["attn_tall_tokens"] == 0
-    rt._tall_tokens = None  # the jnp path: no kernel, nothing tall
-    ModelRuntime._note_attn(rt, sp, list(spans), list(kv), stream_len=T)
+    # the jnp path: no kernel, nothing tall
+    StepWork(MODEL_CONFIGS["test-tiny"], 8, "tall-" + name).note(
+        sp, list(spans), list(kv), stream_len=T)
     assert noted["attn_tall_tokens"] == 0 and series[2].value == tall
 
 

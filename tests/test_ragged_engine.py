@@ -194,7 +194,7 @@ def test_arrival_storm_never_starves_decode_rows():
     orig = rt._dispatch_ragged
 
     def spy(T_pad, k_cap, buf):
-        lay = rt._ragged_layout(T_pad)  # the step's one packed input
+        lay = rt.dims.ragged_layout(T_pad)  # the step's one packed input
         slot_ids, q_len = lay.view(buf, "slot_ids"), lay.view(buf, "q_len")
         dispatched.append({int(sl): int(n) for sl, n in zip(slot_ids, q_len)
                            if n > 0})

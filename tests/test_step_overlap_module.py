@@ -218,7 +218,7 @@ def test_only_the_module_runtime_lowers_the_length_carry(kind, monkeypatch):
     assert rt.mtp == (kind == "module")
     fn = rt._get_ragged_jit(16, rt.spec_k if rt.spec else 0,
                             (False, False, False))
-    lay = rt._ragged_layout(16)
+    lay = rt.dims.ragged_layout(16)
     args = (rt.params, jnp.zeros((lay.size,), jnp.int32), rt.kc, rt.vc,
             rt.recent, rt.last_ids, rt.slot_state)
     carries = (rt.draft_ids, rt.len_ids) if rt.mtp else ()

@@ -236,8 +236,11 @@ def test_self_overhead_stays_under_one_percent():
     """ACCEPTANCE: always-on means the profiler's own clock reads and
     ring appends — since PR 24 also the loop clock's ticks and phase
     switches of TPUEngine._loop_once — must cost < 1% of the
-    engine-thread time the samples account for."""
-    eng = _tpu_engine()
+    engine-thread time the samples account for — an engine of sizes of
+    its own, so that its step programs are compiled here as a served
+    process's are (the builders hand a second engine of one shape the
+    first one's programs: `engine/step_program.py`)."""
+    eng = _tpu_engine(max_slots=3)
     try:
         for u in ("o1", "o2"):
             items = collect(_run(eng, u, max_tokens=10))

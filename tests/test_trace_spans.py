@@ -484,8 +484,11 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         | set(moe.SHARED_SCOPES) | set(llama.GATE_SCOPES) \
         | set(moe.SHARED_GATE_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented
-    # ... and the five jit sites really are those functions.
-    with open(os.path.join(_REPO, "ollamamq_tpu", "engine",
-                           "engine.py"), encoding="utf-8") as f:
-        src = f.read()
+    # ... and the jit sites really are those functions: the two step
+    # programs' in their builders' module, the embedders' in the engine.
+    src = ""
+    for name in ("engine.py", "step_program.py"):
+        with open(os.path.join(_REPO, "ollamamq_tpu", "engine", name),
+                  encoding="utf-8") as f:
+            src += f.read()
     assert set(re.findall(r"jax\.jit\((\w+)", src)) == jit_names

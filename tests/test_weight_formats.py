@@ -151,7 +151,7 @@ def taken_formats(rt, monkeypatch, T=16):
         m.setattr(engine, "_sp_note_compile",
                   lambda rt, site, key, cache, fn: cache.setdefault(key, fn))
         fn = rt._get_ragged_jit(T, 1 if rt.mtp else 0, (False, False, False))
-    buf = jax.ShapeDtypeStruct((rt._ragged_layout(T).size,), jnp.int32)
+    buf = jax.ShapeDtypeStruct((rt.dims.ragged_layout(T).size,), jnp.int32)
     carries = (rt.draft_ids, rt.len_ids) if rt.mtp else ()
     compiled = fn.lower(rt.params, buf, rt.kc, rt.vc, rt.recent, rt.last_ids,
                         rt.slot_state, *carries).compile()

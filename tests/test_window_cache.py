@@ -238,15 +238,17 @@ def test_the_window_layers_bytes_are_fixed_and_the_pool_holds_full_layers():
         ring = rt.slot_state.ring
         assert ring.rows == rows
         assert ring.k.shape == (KX.count(WINDOW), 5 * rows, KX.kv_dim)
-        assert rt.ring_bytes == 4 * 5 * rows * row == ring.nbytes
+        assert rt.state_bytes["swa_ring_bytes"] \
+            == 4 * 5 * rows * row == ring.nbytes
         assert rt.kc.shape[0] == rt.vc.shape[0] == KX.count(ATTENTION) == 1
-        assert rt.stats()["swa_ring_bytes"] == rt.ring_bytes
+        assert rt.stats()["swa_ring_bytes"] == ring.nbytes
     assert large.kv_bytes == 4 * small.kv_bytes  # the pool grows; not these
     assert small.kv_bytes == 64 * 8 * row        # ...and is one layer's
     gauge = {k: v.value for k, v in tm.HBM_SWA_RING_BYTES._children.items()}
-    assert gauge[(NAME,)] == small.ring_bytes
+    assert gauge[(NAME,)] == small.state_bytes["swa_ring_bytes"]
     plain = _runtime_of("test-tiny")
-    assert plain.ring_bytes == 0 and plain.slot_state is None
+    assert plain.state_bytes["swa_ring_bytes"] == 0
+    assert plain.slot_state is None
 
 
 def _runtime_of(name):
@@ -283,12 +285,15 @@ def test_the_rings_are_the_same_arrays_after_a_long_request():
 
 def test_note_swa_counts_pairs_and_rows_by_position():
     """The step sample's counters against a brute force over positions."""
-    rt = _runtime()
-    w, ps = KX.sliding_window, 8
+    from ollamamq_tpu.engine.step_work import StepWork
 
-    class Sample:
+    w, ps = KX.sliding_window, 8
+    work = StepWork(KX, ps, NAME)
+
+    class Sample:  # what the window layers' row of the table noted
         def note(self, **kw):
-            self.noted = kw
+            if "swa_pairs" in kw:
+                self.noted = kw
 
     def brute_counts(spans):
         pairs = rows = walk = full = 0
@@ -303,16 +308,16 @@ def test_note_swa_counts_pairs_and_rows_by_position():
 
     sp = Sample()
     spans = [(1, 200), (16, 16), (5, 7), (12, 100), (1, 3)]
-    rt._note_swa(sp, [n for n, _ in spans], [kv for _, kv in spans])
+    work.note(sp, [n for n, _ in spans], [kv for _, kv in spans])
     assert sp.noted == brute_counts(spans)
     assert sp.noted["swa_walk_rows"] < sp.noted["swa_full_rows"] // 2
     # a scan of k passes: k spans of one token at successive contexts
-    rt._note_swa(sp, [4, 4], np.asarray([10, 300]), scan=True)
+    work.note(sp, [4, 4], [10, 300], scan=True)
     assert sp.noted == brute_counts(
         [(1, kv) for end in (10, 300) for kv in range(end - 3, end + 1)])
     # a model without window layers notes nothing
     sp.noted = None
-    _runtime_of("test-tiny")._note_swa(sp, [4], [10])
+    StepWork(MODEL_CONFIGS["test-tiny"], ps, "test-tiny").note(sp, [4], [10])
     assert sp.noted is None
 
 
