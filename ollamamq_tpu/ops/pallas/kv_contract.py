@@ -20,9 +20,10 @@ no environment variable, no model name):
       serves every query row-head that shares the K/V: scores `[M, blk] =
       q_t [M, W] · k_t [blk, W]ᵀ` on the MXU with the pool's dtype as
       operands (bf16 × bf16 products are exact) and float32 accumulation,
-      an online softmax on `[M, blk]` with the block's tokens along the
-      lanes (a block is 128 tokens: 4 pages of 32), `acc_t [M, W] +=
-      p · v_t` on the MXU again. `m_i`, `l_i`, `acc` are touched once a
+      an online softmax in float32 on `[M, blk]` with the block's tokens
+      along the lanes (a block is 128 tokens: 4 pages of 32), `acc_t [M, W]
+      += p · v_t` on the MXU again, P cast ONCE to the pool's dtype ("P
+      into P·V" below). `m_i`, `l_i`, `acc` are float32 and touched once a
       (block, tile), not once a (row, group, page).
         head_dim % 128 == 0: a lane tile IS a kv head, `k_buf[..., h*hd:
       (h+1)*hd]` is a free lane-tile slice of the buffer, M = rows*group
@@ -144,7 +145,8 @@ PR 48, call 1, one v5e):
 what decode rows would pay if they were not kept at 8: their trips cost
 2.0 µs for ONE live row.) A tall trip of 64 tokens does the work of eight
 short ones (4.9 µs) in 1.95-2.03, against 1.36 µs of MXU work at the bf16
-peak with P's three terms; 32 tokens a trip cost the same a token, and 128
+peak with the three bf16 terms P then went into P·V as (until PR 59,
+below); 32 tokens a trip cost the same a token, and 128
 a little less a token but leave a longer head to the short tiles, and at
 (30, 30, 128) the compiler refuses them (19.65 MB of scoped VMEM for a
 limit of 16): 64. What is left of the 8 k launch: 808 short trips (the
@@ -202,7 +204,9 @@ in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
     2 * TALL tokens or more holds TWO bodies — a program's tiles are a
     loop in the program too, and the tall walk beside it is one more copy
     of the inner product, its lane tiles a loop in the program beyond
-    `TALL_UNROLL`: 770 and 1466 equations there.
+    `TALL_UNROLL`: 770 and 1466 equations there (PR 59: P's split into
+    terms was 13 equations a lane tile — 394 and 934 on a rung of one
+    body, 705 and 1245 on one of two).
   - IN PYTHON: the lane tiles (`for t in range(self.tiles)` in
     `Mxu.update` / `finish`) and a block's pages (`PageStream`, at most 4:
     straight-line copies under the block's one predicate).
@@ -234,17 +238,55 @@ ragged kernel binds 117 nested jits a trace where the parent's bound 191
 and the decode kernel 97 for 196 (at (28, 4, 128); all of them left are
 the inner products' vector code).
 
-P into P·V, and what "float32" meant before: Mosaic's default-precision
-float32 matmul on a v5e rounds its operands to bf16 (my chip run, PR 33:
-`(1 + 2**-10) @ 1` comes back 1.0). So the Vpu body rounds BOTH its
-`(k * q) @ seg` products and the P of `p @ seg_t` to bf16's 8 bits. The
-Mxu body rounds neither: q·k has the stored bf16 values as operands, and
-against a bf16 pool P is split into `PV_TERMS` bf16 terms whose sum is P
-to float32's last bit, stacked along M and contracted ONCE with the
-stored bf16 V (each product exact, float32 accumulation; 3 terms cost
-under 3 % of a launch over 1, same run). Against a float32 (or
-dequantised int8) block both contractions are float32 matmuls at
-Mosaic's default precision, as before.
+P into P·V (PR 59): against a bf16 pool P enters the contraction at the
+pool's dtype, rounded once to nearest — `_dot(p.astype(v.dtype), v)`, what
+the published modelling code of every served model does (softmax in float32,
+cast to the query's dtype, `matmul(attn_weights, value)`), what both latent
+kernels (`mla_attention.py`) have done since they were written, and what
+Mosaic's default-precision float32 matmul does to the Vpu body's `p @ seg_t`
+anyway (it rounds its operands to bf16: my chip run, PR 33, `(1 + 2**-10) @
+1` comes back 1.0 — so the Vpu body rounds BOTH its `(k * q) @ seg` products
+and its P; the Mxu body's q·k has the stored bf16 values as operands and
+rounds nothing). `l` sums the float32 p, so an output moves by at most 2**-8
+of sum(p |v|) / sum(p) before its own rounding to bf16
+(`tests/test_ragged_attention.py:two_roundings`). Against a float32 (or
+dequantised int8) block both contractions are float32 matmuls at Mosaic's
+default precision, to the bit what they were.
+  Until PR 59 P went in as THREE bf16 terms whose sum is P to float32's
+last bit, stacked along M and contracted once: 1536 of the 2048 rows a lane
+tile of a tall trip streamed through the MXU were P's second and third
+terms, for an output that `finish` stores in bf16. "3 terms cost under 3 %
+of a launch over 1" (PR 33) was measured on 64 decode rows over 200-380
+tokens; the tall trip did not exist. Measured, parent → this, one v5e, both
+trees in one call (`scripts/attn_kernel_bench.py`; my chip run, PR 59, call
+2: every row twice a side, the two readings within 1 %). `--traffic
+raggedlong`, µs a tall trip / a short trip at 4 k, 8 k, 16 k, then ms a
+launch and ms a window layer's launch at 8 k:
+  (64, 8, 128)  5.89 5.77 5.71 → 4.84 4.71 4.65 / 1.22 1.19 1.17 → 0.93 0.90 0.89
+                3.493 → 2.795   window 0.248 → 0.213
+  (16, 2, 256)  2.04 1.98 1.94 → 1.33 1.28 1.25 / 0.64 0.62 0.61 → 0.69 0.67 0.65
+                1.370 → 1.099   window 0.103 → 0.086
+  (28, 4, 128)  3.05 2.98 2.95 → 2.53 2.46 2.43 / 0.67 0.65 0.63 → 0.70 0.68 0.66
+                1.831 → 1.628   window 0.148 → 0.135
+— the tall trip −18 % at 512 row-heads a 128-lane tile (the MXU's latch
+arithmetic, tiles × (rows + 128) cycles over four MXUs at 1.5 GHz, gives
+3.1 → 1.7 µs there: what is left is not the MXU's), −35 % at (16, 2, 256),
+−17 % at 448. 64 decode rows over 200-380 tokens, ms a launch (`decode`,
+`ragged64`, `ragged512`):
+  (28, 4, 128)   0.1253 → 0.1213   0.1290 → 0.1338   0.1727 → 0.1729
+  (8, 2, 128)    0.0904 → 0.0874   0.0959 → 0.0918   0.1091 → 0.1016
+  (32, 8, 64)    0.1348 → 0.1300   0.1378 → 0.1441   0.2528 → 0.2527
+  (16, 16, 128)  vpu               0.3501 → 0.3233   0.4159 → 0.3845
+  (30, 30, 128)  vpu               0.6175 → 0.5530   0.7348 → 0.6580
+  (16, 2, 256)   0.1267 → 0.1196   0.1233 → 0.1324   0.1501 → 0.1521
+  (64, 8, 128)   0.2000 → 0.1892   0.2256 → 0.1730   0.3223 → 0.2557
+(the decode kernel at group 1 is the Vpu body, untouched: 0.3337 → 0.3351,
+0.4956 → 0.4946; built with the Mxu body there 0.356 → 0.325, 0.635 → 0.570).
+The SHORT trip of the ragged kernel at 56-64 row-heads and FOUR lane tiles
+costs 4-7 % MORE with less work in it — (28, 4, 128), (32, 8, 64), (16, 2,
+256), repeatably, and with two, eight, sixteen or thirty tiles it costs
+4-24 % less: a schedule, not an operation; no bundle dump was read (PERF.md
+§7).
 
 Mosaic layout constraints (v5e compiler; `tests/test_chip_compile.py`
 asks it at every published shape):
@@ -289,11 +331,6 @@ TALL = 64
 TALL_UNROLL = 2
 _NN = (((1,), (0,)), ((), ()))  # [M, K] · [K, N]
 _NT = (((1,), (1,)), ((), ()))  # [M, K] · [N, K]ᵀ
-
-# bf16 terms P is split into before P·V against a bf16 pool: 3 terms of 8
-# significant bits carry float32's 24 (module docstring).
-PV_TERMS = 3
-
 
 def _dot(a, b, dims=_NN):
     return jax.lax.dot_general(a, b, dimension_numbers=dims,
@@ -698,18 +735,11 @@ class Mxu(_Inner):
                 pltpu.VMEM(held + (LANE,), jnp.float32)]
 
     def _pv(self, p, v):
-        """p [Mp, blk] f32 · v [blk, W] → [Mp, W] f32, P kept to float32's
-        last bit (module docstring)."""
-        if v.dtype != jnp.bfloat16:
-            return _dot(p, v.astype(jnp.float32))
-        terms, rest = [], p
-        for i in range(PV_TERMS):
-            terms.append(rest.astype(jnp.bfloat16))
-            if i + 1 < PV_TERMS:
-                rest = rest - terms[-1].astype(jnp.float32)
-        out = _dot(jnp.concatenate(terms, axis=0), v)  # [terms*Mp, W]
-        m = p.shape[0]
-        return sum(out[i * m:(i + 1) * m] for i in range(PV_TERMS))
+        """p [M, blk] f32 · v [blk, W] → [M, W] f32: P enters at the
+        block's dtype (module docstring)."""
+        if v.dtype == jnp.bfloat16:
+            return _dot(p.astype(v.dtype), v)
+        return _dot(p, v.astype(jnp.float32))
 
     def update(self, q_ref, bufs, slot, span, pos0, state, done_reading,
                sub=0):

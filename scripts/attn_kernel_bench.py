@@ -61,7 +61,7 @@ constant alone skips the absorbed row).
 
 A variant of a kernel's body is measured here before a whole cell: a
 module constant of the four kernel modules that this script sets
-(`--set kv_contract.PV_TERMS=1`, `--set paged_attention.RING=16,
+(`--set kv_contract.TALL_UNROLL=8`, `--set paged_attention.RING=16,
 ragged_attention.RING=16`), `jax.clear_caches()`, one more row a (shape,
 traffic); a variant that needs code gets a constant that lives for that
 run (PR 34 timed the successor walk and the lane-tile loop each unrolled

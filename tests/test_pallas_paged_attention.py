@@ -8,7 +8,7 @@ import pytest
 
 from ollamamq_tpu.ops.attention import paged_decode_attention
 from ollamamq_tpu.ops.pallas.paged_attention import paged_decode_attention_pallas
-from test_ragged_attention import _f32
+from test_ragged_attention import F32_TOL, _f32, assert_kernel_close
 
 
 LAYERS = 3  # pool depth of the kernel cases: first, middle, last layer
@@ -59,15 +59,12 @@ BLOCK_CASES = [
          seq_lens=[32, 0, 64, 96, 128, 160, 192, 224, 256, 0])
     for H, Hk, hd in HEAD_SHAPES]
 # q and the pool in bf16 against the float32 twin fed the same bf16
-# values. The kernel keeps float32 everywhere (exact bf16 products, f32
-# accumulation and softmax, P into P·V to float32's last bit), so what
-# separates it from the twin is the f32 tolerance below plus ONE rounding
-# of the output to bf16's 8 significant bits: half of a spacing of 2**-7
-# just above a power of two, 2**-8 relative.
+# values: the f32 tolerance plus the output's rounding and P's into P·V,
+# which `test_ragged_attention.two_roundings` derives from the case.
 PUBLISHED_CASES.append(dict(PUBLISHED_CASES[0], dtype=jnp.bfloat16))
 BLOCK_CASES.append(dict(BLOCK_CASES[0], dtype=jnp.bfloat16))
-F32_TOL = dict(rtol=2e-5, atol=2e-5)
-BF16_TOL = dict(rtol=2 ** -8 + 4e-5, atol=3e-5)
+
+
 def _id(case):
     return "H{H}-Hk{Hk}-hd{hd}-".format(**case) + (
         "bf16" if "dtype" in case else "x".join(map(str, case["seq_lens"])))
@@ -79,7 +76,6 @@ def _id(case):
 def test_pallas_matches_reference(case, layer, poison_trash_page):
     q, k, v, pt, sl = _case(**case)
     ps = case["PS_"]
-    ref = paged_decode_attention(_f32(q), _f32(k), _f32(v), layer, pt, sl, ps)
     clean = paged_decode_attention_pallas(q, k, v, layer, pt, sl, ps,
                                           interpret=True)
     # The kernel reads the trash page where a block runs past a row's last
@@ -91,9 +87,9 @@ def test_pallas_matches_reference(case, layer, poison_trash_page):
     live = np.asarray(sl) > 0  # a row of no context has no defined output
     np.testing.assert_array_equal(np.asarray(_f32(out))[live],
                                   np.asarray(_f32(clean))[live])
-    np.testing.assert_allclose(
-        np.asarray(_f32(out))[live], np.asarray(ref)[live],
-        **(BF16_TOL if q.dtype == jnp.bfloat16 else F32_TOL))
+    assert_kernel_close(
+        out[live], q.dtype, v, lambda v: paged_decode_attention(
+            _f32(q), _f32(k), v, layer, pt, sl, ps)[live])
 
 
 @pytest.mark.parametrize("inner", ["mxu", "vpu"])

@@ -31,6 +31,7 @@ from ollamamq_tpu.ops.attention import (alloc_ring,
                                         ring_first_page, ring_table,
                                         ring_write_slots)
 from ollamamq_tpu.ops.pallas import paged_attention, ragged_attention
+from test_ragged_attention import F32_TOL, _f32, assert_kernel_close
 
 NAME = "test-tiny-k-exaone"
 KX = MODEL_CONFIGS[NAME]
@@ -99,21 +100,24 @@ def test_ragged_kernel_with_a_window_agrees_with_its_twin(heads, which):
     q = jnp.asarray(np.random.default_rng(1).standard_normal((T, H, hd)),
                     jnp.bfloat16)
     pt, base = ring_table(slot_ids, kv_len, q_len, W, ROWS, PS, T)
-    args = (q, kr, vr, 1, pt, seq, pos, kv_len, q_start, q_len, PS)
-    twin = ragged_attention_any("jnp", *args, window=W, pos_base=base)
-    kern = ragged_attention_any("pallas", *args, interpret=True, window=W,
-                                pos_base=base)
-    full = ragged_paged_attention(q, kr, vr, 1, pt, seq, pos, kv_len, PS,
-                                  window=W, pos_base=base)
-    f32 = lambda a: np.asarray(a[:n], np.float32)  # noqa: E731
-    np.testing.assert_allclose(f32(kern), f32(twin), atol=4e-3, rtol=0)
-    np.testing.assert_allclose(f32(full), f32(twin), atol=4e-3, rtol=0)
+    args = (1, pt, seq, pos, kv_len, q_start, q_len, PS)
+
+    def twin(v):  # the float32 twin over the rings' values and `v`
+        return ragged_attention_any("jnp", _f32(q), _f32(kr), v, *args,
+                                    window=W, pos_base=base)[:n]
+
+    kern = ragged_attention_any("pallas", q, kr, vr, *args, interpret=True,
+                                window=W, pos_base=base)
+    full = ragged_paged_attention(_f32(q), _f32(kr), _f32(vr), 1, pt, seq,
+                                  pos, kv_len, PS, window=W, pos_base=base)
+    # bf16 rings: the output's rounding and P's, from the case's values
+    twin, _ = assert_kernel_close(kern[:n], q.dtype, vr, twin)
+    np.testing.assert_allclose(np.asarray(full[:n]), twin, **F32_TOL)
     for r, (slot, m, kv) in enumerate(spans):  # each span's ends
         for t, p in ((int(q_start[r]), kv - m), (int(q_start[r]) + m - 1,
                                                  kv - 1)):
             np.testing.assert_allclose(
-                np.asarray(twin[t], np.float32),
-                brute(q[t], kr, vr, 1, slot, p, hk), atol=6e-3, rtol=0)
+                twin[t], brute(q[t], kr, vr, 1, slot, p, hk), **F32_TOL)
 
 
 @pytest.mark.parametrize("heads,inner", [((8, 2, 128), None),
@@ -131,19 +135,19 @@ def test_decode_kernel_with_a_window_agrees_with_its_twin(heads, inner):
     assert pt.shape == (S, 8)  # the window and a page, in whole blocks
     q = jnp.asarray(np.random.default_rng(3).standard_normal((S, H, hd)),
                     jnp.bfloat16)
-    twin = paged_decode_attention_any("jnp", q, kr, vr, 0, pt, seq_lens, PS,
-                                      window=W, pos_base=base)
+    def twin(v):
+        return paged_decode_attention_any("jnp", _f32(q), _f32(kr), v, 0, pt,
+                                          seq_lens, PS, window=W,
+                                          pos_base=base)
+
     kern = paged_attention.paged_decode_attention_pallas(
         q, kr, vr, 0, pt, seq_lens, PS, interpret=True, inner=inner,
         window=W, pos_base=base)
-    np.testing.assert_allclose(np.asarray(kern, np.float32),
-                               np.asarray(twin, np.float32), atol=4e-3,
-                               rtol=0)
+    twin, _ = assert_kernel_close(kern, q.dtype, vr, twin)
     for s in range(S):
         np.testing.assert_allclose(
-            np.asarray(twin[s], np.float32),
-            brute(q[s], kr, vr, 0, s, int(seq_lens[s]) - 1, hk), atol=6e-3,
-            rtol=0)
+            twin[s], brute(q[s], kr, vr, 0, s, int(seq_lens[s]) - 1, hk),
+            **F32_TOL)
 
 
 def test_a_mask_alone_would_read_stale_rows():
