@@ -41,7 +41,20 @@ PARALLEL = "attention_ssm"
 # layer, a query and an out projection that attend over the K and V the
 # stack's ONE full-attention layer cached — no K, no V, no write of its own.
 MAMBA, GMU, CROSS = "mamba", "gated_memory", "cross_attention"
-LAYER_KINDS = (ATTENTION, CONV, LINEAR, WINDOW, PARALLEL, MAMBA, GMU, CROSS)
+# BLOCK-SPARSE attention over K/V pages (MiniCPM-SALA's `minicpm4` mixer,
+# InfLLM-V2; this repo's naming — the published list is `mixer_types`): a
+# query past `sparse_dense_len` attends the `sparse_topk` best BLOCKS of
+# `sparse_block_size` cached positions, chosen a KV group by a score over
+# mean-pooled keys (ops/block_select.py). Its K and V rows live in the paged
+# pool as a full layer's; its pooled keys in a pool of their own under the
+# same page table (llama.SlotState.pooled).
+SPARSE = "sparse_attention"
+LAYER_KINDS = (ATTENTION, CONV, LINEAR, WINDOW, PARALLEL, MAMBA, GMU, CROSS,
+               SPARSE)
+# The published `mixer_types` spellings (`minicpm_sala`) and the kinds they
+# are served as: `lightning-attn` is the LINEAR kind read with the
+# `lightning_*` keys (a constant decay a head, no convolution).
+MIXER_KINDS = {"minicpm4": SPARSE, "lightning-attn": LINEAR}
 # Mamba-1's sizes (the published modelling code's constants for its mixer,
 # not keys of config.json): channels = S6_EXPAND x hidden, a float32 state of
 # S6_D_STATE a channel, S6_D_CONV taps, dt through a rank of hidden / 16.
@@ -53,7 +66,7 @@ S6_EXPAND, S6_D_STATE, S6_D_CONV = 2, 16, 4
 STATE_KINDS = (CONV, LINEAR, WINDOW, PARALLEL, MAMBA)
 # The kinds whose K and V rows live in the paged pool: a layer of the pools
 # each, in layer order.
-PAGED_KINDS = (ATTENTION, PARALLEL)
+PAGED_KINDS = (ATTENTION, PARALLEL, SPARSE)
 # The kinds that are attention over K and V: they share the attention
 # weights' stacks (`wq` ... one entry a layer of EITHER kind, in layer order).
 ATTENTION_KINDS = (ATTENTION, WINDOW)
@@ -356,9 +369,67 @@ class ModelConfig:
     lm_head_bias: bool = False
     embd_pdrop: float = 0.0  # read, refused unless 0: dropout says nothing
     resid_pdrop: float = 0.0  # of a served forward
+    # -- block-sparse attention beside lightning attention (MiniCPM-SALA) ----
+    # The published `mixer_types` (one of MIXER_KINDS' keys a layer) says what
+    # `layer_types` says: either or both, agreeing. A "sparse_attention"
+    # layer is GQA attention whose query at position t (context n = t + 1)
+    # attends every cached position while n <= `sparse_dense_len`, and past
+    # it the `sparse_topk` best blocks of `sparse_block_size` positions:
+    # the first `sparse_init_blocks`, the last `sparse_window_size /
+    # sparse_block_size` up to its own, and the best of the others by the
+    # score of ops/block_select.py over keys mean-pooled `sparse_kernel_size`
+    # at a stride of `sparse_kernel_stride`. This repo's naming (the family's
+    # `sparse_config` group, which this model's config.json does not carry).
+    mixer_types: Optional[tuple] = None
+    sparse_kernel_size: int = 0
+    sparse_kernel_stride: int = 0
+    sparse_block_size: int = 0
+    sparse_topk: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window_size: int = 0
+    sparse_dense_len: int = 0
+    # `lightning_nh` > 0 reads every "linear_attention" layer as LIGHTNING
+    # attention: `lightning_nh` heads of `lightning_head_dim` (as many key
+    # and value heads: `lightning_nkv`), q and k RMS-normed a head (`qk_norm`)
+    # and, with `lightning_use_rope`, roped; per head a float32 state S
+    # [d, d] a sequence, S_t = lambda S_{t-1} + k_t v_t^T, o_t = S_t^T q_t
+    # d^-1/2 (`lightning_scale`), lambda = exp(-slope) a CONSTANT of (head,
+    # published layer index: `lightning_slopes`); an RMSNorm on o
+    # (`use_output_norm`), a sigmoid gate a lane (`use_output_gate`), W_o. No
+    # convolution, no decay or write-strength projection. The published
+    # spellings.
+    lightning_nh: int = 0
+    lightning_nkv: int = 0
+    lightning_head_dim: int = 0
+    lightning_use_rope: bool = True
+    lightning_scale: str = "1/sqrt(d)"  # read, held to this value
+    use_output_norm: bool = True  # read, held to true
+    use_output_gate: bool = True  # read, held to true
+    # RoPE on the ATTENTION layers' q and k (false: NoPE; the lightning
+    # layers' is `lightning_use_rope`), and the published spelling of
+    # `attn_output_gate` (either or both, agreeing).
+    attn_use_rope: bool = True
+    attn_use_output_gate: Optional[bool] = None
+    # The family's muP scalars: the embedding times `scale_emb` (folded into
+    # `embedding_multiplier`), every sublayer's output times `scale_depth` /
+    # sqrt(`scale_depth_layers`) before it joins the residual (0: no scaling;
+    # `scale_depth_layers`, this repo's naming: the PUBLISHED depth, which a
+    # stack cut in depth keeps — 0: `num_layers`), the logits divided by
+    # `hidden_size` / `dim_model_base` (folded into `lm_head_multiplier`).
+    # `layer_offset` (this repo's naming): the published index of the
+    # stack's first layer, where the stack is a stage of a deeper model: a
+    # lightning layer's decay is a function of it.
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    scale_depth_layers: int = 0
+    dim_model_base: int = 0
+    layer_offset: int = 0
+    mup_denominator: int = 32  # read, not used: a training-time constant
+    rand_init: bool = False  # read, held to false
 
     def __post_init__(self):
         self._derive_hybrid()
+        self._derive_mixers()
         if self.qk_norm not in (False, True, "head", "full"):
             raise ValueError(
                 f"{self.name}: qk_norm must be false, true, 'head' or "
@@ -405,8 +476,9 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: layer_types holds both {CONV!r} and "
                     f"{LINEAR!r}: the per-slot conv window has one width")
-            if LINEAR in kinds:
+            if LINEAR in kinds and not self.lightning_nh:
                 self._check_linear()
+        self._check_sparse()
         self._check_ssm()
         if self.first_k_dense_replace is not None:
             if self.num_dense_layers not in (0, self.first_k_dense_replace):
@@ -672,6 +744,119 @@ class ModelConfig:
                     "agree with layer_types (attention in each n-th layer, "
                     "linear_attention in the others)")
 
+    def _derive_mixers(self) -> None:
+        """`mixer_types`: the published list folded into `layer_types`, and
+        the published spellings of fields the program has under another
+        name folded into those, BEFORE the other checks read them."""
+        if self.mixer_types is not None:
+            mixers = tuple(self.mixer_types)  # a file's list: hashable
+            object.__setattr__(self, "mixer_types", mixers)
+            unknown = sorted(set(mixers) - set(MIXER_KINDS))
+            if unknown:
+                raise ValueError(
+                    f"{self.name}: mixer_types holds {unknown}; the program "
+                    f"runs {sorted(MIXER_KINDS)}")
+            want = tuple(MIXER_KINDS[m] for m in mixers)
+            if self.layer_types is not None \
+                    and tuple(self.layer_types) != want:
+                raise ValueError(
+                    f"{self.name}: layer_types does not agree with "
+                    f"mixer_types ({MIXER_KINDS})")
+            object.__setattr__(self, "layer_types", want)
+        gate = self.attn_use_output_gate
+        if gate is not None:
+            if self.attn_output_gate and not gate:
+                raise ValueError(
+                    f"{self.name}: attn_use_output_gate {gate} is not "
+                    f"attn_output_gate {self.attn_output_gate}")
+            object.__setattr__(self, "attn_output_gate", bool(gate))
+        for alias, field, value in (
+                ("scale_emb", "embedding_multiplier", self.scale_emb),
+                ("dim_model_base", "lm_head_multiplier",
+                 self.dim_model_base / self.hidden_size)):
+            if getattr(self, alias) in (0, 1.0):
+                continue
+            if getattr(self, field) not in (1.0, value):
+                raise ValueError(
+                    f"{self.name}: {alias} {getattr(self, alias)} is not "
+                    f"{field} {getattr(self, field)}")
+            object.__setattr__(self, field, value)
+
+    def _check_sparse(self) -> None:
+        """Block-sparse and lightning layers: the sizes held to each other, a
+        value the program does not implement refused by the key's name."""
+        kinds = self.layer_types or ()
+        sizes = {key: getattr(self, key) for key in (
+            "sparse_kernel_size", "sparse_kernel_stride", "sparse_block_size",
+            "sparse_topk", "sparse_init_blocks", "sparse_window_size",
+            "sparse_dense_len")}
+        if SPARSE not in kinds:
+            if any(sizes.values()):
+                raise ValueError(
+                    f"{self.name}: {sorted(k for k, v in sizes.items() if v)} "
+                    f"with no {SPARSE!r} layer in layer_types")
+        else:
+            kernel, stride, block = (self.sparse_kernel_size,
+                                     self.sparse_kernel_stride,
+                                     self.sparse_block_size)
+            local = self.sparse_window_size // max(block, 1)
+            if min(sizes.values()) < 1 or kernel != 2 * stride \
+                    or block % stride or block // stride != 4 \
+                    or self.sparse_window_size % block \
+                    or self.sparse_init_blocks + local > self.sparse_topk \
+                    or self.sparse_dense_len < self.sparse_topk * block:
+                raise ValueError(
+                    f"{self.name}: {sizes}: a sparse_attention layer is "
+                    "served with a pooling kernel of two strides, a block of "
+                    "four strides (the scores' max-pool: window 5, stride 4, "
+                    "padding 1), a window of whole blocks that fits "
+                    "sparse_topk beside the init blocks, and a "
+                    "sparse_dense_len of at least sparse_topk blocks")
+            if set(kinds) & {ATTENTION, WINDOW, PARALLEL} or self.kv_lora_rank \
+                    or self.is_encoder or self.num_nextn_predict_layers \
+                    or self.qk_norm_kind == "full" or self.attn_bias \
+                    or self.norm_order != "pre":
+                raise ValueError(
+                    f"{self.name}: {SPARSE!r} layers are served as the "
+                    "stack's only attention kind, with plain K/V heads, no "
+                    "bias, no full-width q/k norm, pre-norm blocks and no "
+                    "prediction module")
+        if not self.lightning_nh:
+            if self.layer_offset:
+                raise ValueError(
+                    f"{self.name}: layer_offset {self.layer_offset}: only a "
+                    "lightning layer's decay reads it (lightning_nh is 0)")
+            return
+        d = self.lightning_head_dim
+        if LINEAR not in kinds or self.lightning_nkv != self.lightning_nh \
+                or d < 2 or d % 2:
+            raise ValueError(
+                f"{self.name}: lightning_nh {self.lightning_nh} / "
+                f"lightning_nkv {self.lightning_nkv} / lightning_head_dim "
+                f"{d}: lightning attention is the linear_attention layers', "
+                "with as many key/value heads as heads and an even head size")
+        for key, only in (("lightning_scale", "1/sqrt(d)"),
+                          ("use_output_norm", True), ("use_output_gate", True),
+                          ("rand_init", False)):
+            if getattr(self, key) != only:
+                raise ValueError(
+                    f"{self.name}: {key} {getattr(self, key)!r}: the program "
+                    f"implements only {only!r}")
+        if self.qk_norm_kind != "head" or CONV in kinds or self.mamba_d_ssm \
+                or self.mb_per_layer or (self.lightning_use_rope
+                                         and self.rope_theta is None):
+            raise ValueError(
+                f"{self.name}: lightning attention is served with a per-head "
+                "q/k norm (qk_norm true), a rope_theta where "
+                "lightning_use_rope is true, and no conv, mixer or mamba "
+                "layer beside it")
+        if self.layer_offset < 0 or \
+                self.layer_offset + self.num_layers > self.published_depth:
+            raise ValueError(
+                f"{self.name}: layer_offset {self.layer_offset}: the stack's "
+                f"{self.num_layers} layers are not within the published "
+                f"{self.published_depth} (scale_depth_layers)")
+
     def _check_linear(self) -> None:
         """What the linear-attention layers cannot run with, key and value
         in the message."""
@@ -843,7 +1028,7 @@ class ModelConfig:
         """Layers that hold attention over K and V — window, full, or beside
         a state-space mixer: the entries of the attention weights' stacks."""
         return sum(self.count(kind) for kind in ATTENTION_KINDS) \
-            + self.count(PARALLEL)
+            + self.count(PARALLEL) + self.count(SPARSE)
 
     @property
     def paged_layers(self) -> int:
@@ -868,8 +1053,58 @@ class ModelConfig:
 
     def rotates(self, kind: str) -> bool:
         """Do q and k of an attention layer of `kind` take RoPE?"""
-        return self.rope_theta is not None and (
+        return self.rope_theta is not None and self.attn_use_rope and (
             self.rope_layer_types is None or kind in self.rope_layer_types)
+
+    @property
+    def published_depth(self) -> int:
+        """Layers of the published stack (`scale_depth_layers`), of which
+        this one may be a stage."""
+        return self.scale_depth_layers or self.num_layers
+
+    @property
+    def residual_multiplier(self) -> float:
+        """What a sublayer's output is multiplied by before it joins the
+        residual: `scale_depth` / sqrt(the published depth), or 1."""
+        if not self.scale_depth:
+            return 1.0
+        return self.scale_depth / math.sqrt(self.published_depth)
+
+    def lightning_level(self, layer):
+        """1 - l / (L - 1) + 1e-5: what scales a lightning layer's slopes, l
+        the PUBLISHED index of the layer at `layer` of this stack (an int,
+        or a traced int32 scalar inside a layer loop), L the published
+        depth."""
+        return 1.0 - (self.layer_offset + layer) \
+            / max(self.published_depth - 1, 1) + 1e-5
+
+    @property
+    def lightning_slopes(self) -> tuple:
+        """A lightning layer's decay, -log(lambda) a head, one tuple a
+        linear_attention layer in stack order: the Lightning Attention
+        slopes 2^(-8 (h + 1) / H) times `lightning_level` of the layer."""
+        H = self.lightning_nh
+        base = [2.0 ** (-8.0 * (h + 1) / H) for h in range(H)]
+        return tuple(tuple(b * self.lightning_level(i) for b in base)
+                     for i, (op, _) in enumerate(self.kinds) if op == LINEAR)
+
+    @property
+    def sparse_local_blocks(self) -> int:
+        return self.sparse_window_size // self.sparse_block_size
+
+    def pooled_rows(self, num_pages: int, page_size: int) -> int:
+        """Rows of a sparse layer's pooled-key pool: a page's
+        `page_size / sparse_kernel_stride` rows, page p's at p * that — the
+        page table names them as it names K and V rows."""
+        if not self.count(SPARSE):
+            return 0
+        if page_size % self.sparse_kernel_stride \
+                or self.sparse_block_size % page_size:
+            raise ValueError(
+                f"{self.name}: --page-size {page_size}: a page holds whole "
+                f"pooling strides ({self.sparse_kernel_stride}) and a block "
+                f"({self.sparse_block_size}) whole pages")
+        return num_pages * (page_size // self.sparse_kernel_stride)
 
     def ring_rows(self, max_span: int, page_size: int) -> int:
         """Rows of a slot's ring a window layer: whole pages that hold the
@@ -1011,7 +1246,7 @@ class ModelConfig:
             return self.mamba_d_conv, self.ssm_conv_dim
         if self.count(MAMBA):
             return S6_D_CONV, self.s6_inner
-        if self.count(LINEAR):
+        if self.count(LINEAR) and not self.lightning_nh:
             return self.linear_conv_kernel_dim, self.linear_conv_dim
         return self.conv_L_cache, self.hidden_size
 
@@ -1049,8 +1284,10 @@ class ModelConfig:
             attention += self.q_dim + 2 * self.kv_dim + d \
                 + 4 * self.head_dim + lanes
         cross = attention - 2 * (d + 1) * self.kv_dim
+        ld = self.lightning_nh * self.lightning_head_dim
         per_op = {
             ATTENTION: attention, WINDOW: attention, CROSS: cross,
+            SPARSE: attention,
             GMU: 2 * d * di,
             # in [x | z], the taps and their bias, x -> [dt | B | C], dt's
             # projection and bias, A_log, D, out
@@ -1061,7 +1298,10 @@ class ModelConfig:
             CONV: 3 * d * d + d * d + d * self.conv_L_cache,
             # q | k | v | z and the two gates in, the taps, A_log and
             # dt_bias, the output norm, out.
-            LINEAR: (d * (self.linear_conv_dim + self.linear_value_dim
+            # (lightning: q | k | v | the gate in, out, the three norms)
+            LINEAR: (5 * d * ld + 2 * self.lightning_head_dim + ld
+                     if self.lightning_nh else
+                     d * (self.linear_conv_dim + self.linear_value_dim
                           + 2 * self.linear_num_value_heads)
                      + self.linear_conv_dim * self.linear_conv_kernel_dim
                      + 2 * self.linear_num_value_heads
@@ -1384,6 +1624,48 @@ MODEL_CONFIGS = {
         intermediate_size=256, num_layers=8, num_heads=8, num_kv_heads=4,
         head_dim=16, sliding_window=16, mb_per_layer=2, layer_norm_eps=1e-5,
         tie_embeddings=True, max_seq_len=512,
+    ),
+    # MiniCPM-SALA (openbmb/MiniCPM-SALA config.json, model_type minicpm_sala;
+    # the `minicpm4` mixer is InfLLM-V2, arXiv:2506.07900): 8 block-sparse
+    # attention layers (32 / 2 heads of 128, NoPE, an output gate; past 8192
+    # positions the top 64 blocks of 64) at no period among 24 lightning
+    # linear-attention layers (32 heads of 128, roped, a constant decay a
+    # head and layer), muP scalars, an untied head over 73,448 ids. The
+    # `sparse_*` sizes are the family's convention (its config.json has no
+    # `sparse_config`): benchmarks/configs/minicpm-sala-d16.json, `assumed`.
+    "minicpm-sala:9b": ModelConfig(
+        name="minicpm-sala:9b", vocab_size=73_448, hidden_size=4096,
+        intermediate_size=16_384, num_layers=32, num_heads=32, num_kv_heads=2,
+        head_dim=128, rope_theta=10_000.0, rms_norm_eps=1e-6,
+        max_seq_len=524_288, qk_norm=True,
+        mixer_types=tuple(
+            "minicpm4" if i in (0, 9, 16, 17, 22, 29, 30, 31)
+            else "lightning-attn" for i in range(32)),
+        sparse_kernel_size=32, sparse_kernel_stride=16, sparse_block_size=64,
+        sparse_topk=64, sparse_init_blocks=1, sparse_window_size=2048,
+        sparse_dense_len=8192, lightning_nh=32, lightning_nkv=32,
+        lightning_head_dim=128, attn_use_rope=False,
+        attn_use_output_gate=True, scale_emb=12, scale_depth=1.4,
+        dim_model_base=256,
+    ),
+    # Tiny MiniCPM-SALA: six layers of a published eight (1 .. 6: the decay
+    # reads the published index), sparse layers at no period and two of them
+    # adjacent; blocks of 16 (two 8-token pages), pooled keys 8-by-4, the top
+    # 4 blocks past 64 positions — init 1, local 2, one chosen — so that the
+    # tests' contexts cross `sparse_dense_len` and the score decides a block.
+    "test-tiny-minicpm-sala": ModelConfig(
+        name="test-tiny-minicpm-sala", vocab_size=512, hidden_size=128,
+        intermediate_size=256, num_layers=6, num_heads=8, num_kv_heads=2,
+        head_dim=16, rope_theta=10_000.0, rms_norm_eps=1e-6, max_seq_len=512,
+        qk_norm=True,
+        mixer_types=("minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4", "minicpm4", "lightning-attn"),
+        sparse_kernel_size=8, sparse_kernel_stride=4, sparse_block_size=16,
+        sparse_topk=4, sparse_init_blocks=1, sparse_window_size=32,
+        sparse_dense_len=64, lightning_nh=8, lightning_nkv=8,
+        lightning_head_dim=16, attn_use_rope=False,
+        attn_use_output_gate=True, scale_emb=12, scale_depth=1.4,
+        scale_depth_layers=8, layer_offset=1, dim_model_base=32,
     ),
     # Tiny DeepSeek-V3.2: latent attention with the indexer's selection (top
     # 16: well under the tests' contexts), YaRN, a dense layer then expert
@@ -1896,12 +2178,14 @@ def validate_slot_state(cfg: ModelConfig, spec: bool = False,
     too: its attention reads the pool, but a shared or re-scaled page says
     nothing of the mixer's state at its boundary."""
     held = [kind for kind in STATE_KINDS if cfg.count(kind)]
-    if not held:
+    if not held and not cfg.count(SPARSE):
         return None
     shape = dict(mesh_shape or {})
     ring = cfg.count(WINDOW) > 0
     why = None
-    if held == [PARALLEL]:
+    if cfg.count(SPARSE):
+        why = _sparse_refusal(spec, shape, kv_dtype, prefix_cache)
+    elif held == [PARALLEL]:
         why = _parallel_refusal(spec, shape, kv_dtype, prefix_cache)
     elif MAMBA in held:
         why = _hybrid_refusal(spec, shape, kv_dtype, prefix_cache)
@@ -1943,6 +2227,30 @@ def _parallel_refusal(spec: bool, shape: dict, kv_dtype: str,
     if prefix_cache:
         return ("--prefix-cache: a cached page holds K and V of its tokens, "
                 "not the mixer's state at its boundary (ROADMAP B-M5)")
+    return None
+
+
+def _sparse_refusal(spec: bool, shape: dict, kv_dtype: str,
+                    prefix_cache: bool) -> Optional[str]:
+    """...and for a stack of block-sparse attention beside lightning layers:
+    a pooled-key pool beside K and V, block lists a kv group, a float32
+    matrix state a slot."""
+    if spec:
+        return ("--spec: a rejected draft has already advanced the lightning "
+                "layers' state and may have written a pooled key, and "
+                "rollback restores pages only (ROADMAP B-M10)")
+    if shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
+        return ("--tp / --ep: the pooled-key pool, the block lists (one a "
+                "kv group) and the lightning state have no partition specs "
+                "(ROADMAP B-M10)")
+    if kv_dtype != "bfloat16":
+        return ("--kv-dtype int8: a pooled key is the mean of bfloat16 K "
+                "rows, and the sparse walk has not been measured over "
+                "scale planes (ROADMAP B-M10)")
+    if prefix_cache:
+        return ("--prefix-cache: a cached page holds K and V of its tokens, "
+                "not its pooled keys nor the lightning state at its "
+                "boundary (ROADMAP B-M10)")
     return None
 
 

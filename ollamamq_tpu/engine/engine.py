@@ -625,7 +625,9 @@ class ModelRuntime:
         self.slot_state = llama.alloc_slot_state(
             model_cfg, engine_cfg.max_slots, dtype,
             ring_rows=model_cfg.ring_rows(ragged_budget(engine_cfg),
-                                          engine_cfg.page_size))
+                                          engine_cfg.page_size),
+            pooled_rows=model_cfg.pooled_rows(engine_cfg.num_pages,
+                                              engine_cfg.page_size))
         self.alloc = kvc.PageAllocator(
             engine_cfg.num_pages, engine_cfg.page_size, engine_cfg.max_pages_per_seq
         )

@@ -20,7 +20,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import numpy as np
 
 from ollamamq_tpu.config import (CONV, EXPERTS, LINEAR, MAMBA, PARALLEL,
-                                 ModelConfig)
+                                 SPARSE, ModelConfig)
 from ollamamq_tpu.ops.attention import ring_first_page
 from ollamamq_tpu.telemetry import schema as tm
 
@@ -157,6 +157,34 @@ def swa_counts(cfg, page_size, s: Step) -> tuple:
         pairs, np.minimum(kv, n + w - 1), walk, kv))
 
 
+def bsa_counts(cfg, page_size, s: Step) -> tuple:
+    """Block-sparse attention, a sparse layer's worth, each count split into
+    one-token rows (decode rows of a ragged step, a scan's passes: their
+    walks follow the block list) and the tokens of longer spans (served
+    under a block mask over the row's context). Of the queries PAST
+    `sparse_dense_len`: the blocks in their contexts, ceil(n / block); those
+    they keep, min(topk, that) — what the mathematics asks; those the walks
+    cover — the kept ones a one-token row, the context's a span token. Then
+    the queries at or under it (every block kept, the plain walk), and the
+    pooled-key rows the step's tokens completed (position p completes one
+    where p + 1 - kernel is a whole number of strides)."""
+    block, topk = cfg.sparse_block_size, cfg.sparse_topk
+    kernel, stride = cfg.sparse_kernel_size, cfg.sparse_kernel_stride
+    counts = np.zeros(8, np.int64)  # ctx, kept, walked: (step, span); dense;
+    for n, kv in zip(s.tokens, s.kv):  # pooled rows
+        ctx = np.arange(kv - n + 1, kv + 1)  # each token's context
+        j = ctx - kernel
+        counts[7] += int(((j >= 0) & (j % stride == 0)).sum())
+        counts[6] += int((ctx <= cfg.sparse_dense_len).sum())
+        blocks = -(-ctx[ctx > cfg.sparse_dense_len] // block)
+        kept = np.minimum(blocks, topk)
+        one = s.scan or n == 1
+        counts[0 if one else 1] += int(blocks.sum())
+        counts[2 if one else 3] += int(kept.sum())
+        counts[4 if one else 5] += int((kept if one else blocks).sum())
+    return tuple(counts.tolist())
+
+
 def exit_counts(cfg, page_size, s: Step) -> tuple:
     """A stack with an `exit_layer`: the sampled rows, which pass the layers
     from there on; the cached rows ONE cross layer's walks read for them
@@ -193,7 +221,13 @@ KINDS = {
                  slot_state_counts),
     "lin": _recurrent(
         LINEAR, "lin", tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
-        tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL),
+        tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL)._replace(
+            present=lambda cfg: cfg.count(LINEAR) and not cfg.lightning_nh),
+    # (the linear kind's other reading: a model has one of the two)
+    "lightning": _recurrent(
+        LINEAR, "lightning", None, None, tm.LIGHTNING_STEP_ROWS_TOTAL,
+        tm.LIGHTNING_SPAN_TOKENS_TOTAL)._replace(
+            present=lambda cfg: cfg.count(LINEAR) and cfg.lightning_nh),
     "ssm": _recurrent(
         PARALLEL, "ssm", tm.SSM_STATE_RESETS_TOTAL,
         tm.SSM_STATE_CARRIED_TOTAL, tm.SSM_STEP_ROWS_TOTAL,
@@ -217,7 +251,8 @@ KINDS = {
     # Nothing for an encoder, a model with latent attention or one with
     # no attention layer.
     "attn": Kind(
-        lambda cfg: not cfg.kv_lora_rank and cfg.paged_layers,
+        lambda cfg: not cfg.kv_lora_rank
+        and cfg.paged_layers - cfg.count(SPARSE),
         ("attn_pairs", "attn_ctx_rows", "attn_tall_tokens"),
         (tm.ATTN_PAIRS_TOTAL, tm.ATTN_CTX_ROWS_TOTAL,
          tm.ATTN_TALL_TOKENS_TOTAL), attn_counts),
@@ -226,6 +261,16 @@ KINDS = {
         ("swa_pairs", "swa_ctx_rows", "swa_walk_rows", "swa_full_rows"),
         (tm.SWA_PAIRS_TOTAL, tm.SWA_CTX_ROWS_TOTAL, tm.SWA_WALK_ROWS_TOTAL,
          tm.SWA_FULL_ROWS_TOTAL), swa_counts),
+    "bsa": Kind(
+        lambda cfg: cfg.count(SPARSE),
+        ("bsa_blocks_in_context_step", "bsa_blocks_in_context_span",
+         "bsa_blocks_kept_step", "bsa_blocks_kept_span",
+         "bsa_blocks_walked_step", "bsa_blocks_walked_span",
+         "bsa_dense_queries", "bsa_pooled_rows_written"),
+        (tm.BSA_BLOCKS_IN_CONTEXT_TOTAL, tm.BSA_BLOCKS_IN_CONTEXT_TOTAL,
+         tm.BSA_BLOCKS_KEPT_TOTAL, tm.BSA_BLOCKS_KEPT_TOTAL,
+         tm.BSA_BLOCKS_WALKED_TOTAL, tm.BSA_BLOCKS_WALKED_TOTAL, None, None),
+        bsa_counts),
     "exit": Kind(lambda cfg: cfg.exit_layer,
                  ("xattn_rows", "xattn_ctx_rows", "exit_skipped_tokens"),
                  (tm.XATTN_ROWS_TOTAL, tm.XATTN_CTX_ROWS_TOTAL,
@@ -291,7 +336,8 @@ STATE_BYTES = (("conv", "conv_state_bytes", tm.HBM_CONV_STATE_BYTES),
                ("rule", "lin_state_bytes", tm.HBM_LIN_STATE_BYTES),
                ("ssm", "ssm_state_bytes", tm.HBM_SSM_STATE_BYTES),
                ("scan", "s6_state_bytes", tm.HBM_S6_STATE_BYTES),
-               ("ring", "swa_ring_bytes", tm.HBM_SWA_RING_BYTES))
+               ("ring", "swa_ring_bytes", tm.HBM_SWA_RING_BYTES),
+               ("pooled", "bsa_pooled_bytes", tm.HBM_BSA_POOLED_BYTES))
 
 
 def state_bytes(state, model: str) -> dict:

@@ -56,11 +56,12 @@ import jax.numpy as jnp
 
 from ollamamq_tpu.config import (ATTENTION, ATTENTION_KINDS, CONV, CROSS,
                                  DENSE, EXPERTS, GMU, LINEAR, MAMBA, PARALLEL,
-                                 S6_D_CONV, S6_D_STATE, WINDOW, ModelConfig)
+                                 S6_D_CONV, S6_D_STATE, SPARSE, WINDOW,
+                                 ModelConfig)
 from ollamamq_tpu.models.moe import (SHARED, SHARED_GATE, STACKED,
                                      init_moe_layer_params, moe_mlp)
-from ollamamq_tpu.ops import (gated_delta, mla, selective_scan, shortconv,
-                              ssd)
+from ollamamq_tpu.ops import (block_select, gated_delta, mla, selective_scan,
+                              shortconv, ssd)
 from ollamamq_tpu.ops.attention import (
     WindowRing,
     alloc_ring,
@@ -113,6 +114,17 @@ SSM_SCOPES = ("ssm_in", "ssm_conv", "ssm_scan", "ssm_out")
 HYBRID_SCOPES = ("mamba_in", "mamba_scan", "mamba_out", "gmu",
                  "cross_attention", "diff_combine", "early_exit_gather")
 HYBRID_KEY = 0x70686934
+# ...and a block-sparse layer's four inside "kv_write" / beside "attention"
+# (the pooled keys a step's tokens complete; the block scores and the top-k;
+# the walk over the kept blocks' pages; a span under its block masks), and a
+# lightning layer's three: the projections with the q/k norm and RoPE, the
+# recurrence with its state, the output norm, gate and out-projection.
+BSA_SCOPES = ("bsa_pool_write", "bsa_select", "bsa_attention", "bsa_span")
+LIGHTNING_SCOPES = ("ltn_in", "ltn_rule", "ltn_out")
+LIGHTNING_KEY = 0x6C74676E
+# Seeded random init of a lightning layer's three norm weights, drawn around
+# 1 so that a forward which leaves one out computes another model.
+LIGHTNING_NORM_SD = 0.1
 # Seeded random init of what such a stack holds beside its matrices, drawn
 # AWAY from the identity (as the mixers' above): every bias — the norms', the
 # convolution's, the projections' — and the four lambda vectors N(0, 0.1), the
@@ -162,8 +174,10 @@ KIND_PARAMS = {
     # their own: no wk, no wv, and another count of layers)
     CROSS: ("xwq", "xbq", "xwo", "xbo", "xdiff_lambda", "xdiff_norm"),
     CONV: ("conv_in", "conv_w", "conv_out"),
+    # (the delta rule's, then the lightning reading's: a model has one)
     LINEAR: ("lin_in", "lin_ba", "lin_conv_w", "lin_A_log", "lin_dt_bias",
-             "lin_norm", "lin_out"),
+             "lin_norm", "lin_out", "ltn_wq", "ltn_wk", "ltn_wv", "ltn_wz",
+             "ltn_wo", "ltn_q_norm", "ltn_k_norm", "ltn_norm"),
     PARALLEL: ("ssm_in", "ssm_dt", "ssm_conv_w", "ssm_conv_b", "ssm_A_log",
                "ssm_D", "ssm_dt_bias", "ssm_norm", "ssm_out"),
     DENSE: ("w_gate", "w_up", "w_down"),
@@ -309,7 +323,24 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
             conv_in=w(ck[0], (Lc, d, 3 * d), d),
             conv_w=w(ck[1], (Lc, d, cfg.conv_L_cache), cfg.conv_L_cache),
             conv_out=w(ck[2], (Lc, d, d), d))
-    if Ll:
+    if Ll and cfg.lightning_nh:
+        # Lightning attention: q, k, v and the gate in, out; the per-head
+        # q/k norms and the output norm over all heads' lanes.
+        tk = jax.random.split(jax.random.fold_in(key, LIGHTNING_KEY), 8)
+        ld = cfg.lightning_nh * cfg.lightning_head_dim
+
+        def around_one(k, shape):
+            return (1.0 + LIGHTNING_NORM_SD * jax.random.normal(
+                k, shape, jnp.float32)).astype(dtype)
+
+        layers.update(
+            ltn_wq=w(tk[0], (Ll, d, ld), d), ltn_wk=w(tk[1], (Ll, d, ld), d),
+            ltn_wv=w(tk[2], (Ll, d, ld), d), ltn_wz=w(tk[3], (Ll, d, ld), d),
+            ltn_wo=w(tk[4], (Ll, ld, d), ld),
+            ltn_q_norm=around_one(tk[5], (Ll, cfg.lightning_head_dim)),
+            ltn_k_norm=around_one(tk[6], (Ll, cfg.lightning_head_dim)),
+            ltn_norm=around_one(tk[7], (Ll, ld)))
+    elif Ll:
         # Gated delta rule: in-projection to [q | k | v | z] (the first
         # three pass the convolution), the two gates [b | a] a head, the
         # depthwise taps [q | k | v channels, K], the decay's A_log and
@@ -533,24 +564,29 @@ class SlotState(NamedTuple):
     recurrent state, of which a model has ONE — `rule` the delta rule's
     matrices (ops/gated_delta.py's), `ssm` a state-space mixer's (PARALLEL;
     ops/ssd.py's), `scan` a selective scan's (ops/selective_scan.py's) — and
-    `ring` the window layers' K/V rings (ops/attention.py:WindowRing)."""
+    `ring` the window layers' K/V rings (ops/attention.py:WindowRing) — and
+    `pooled`, which is no slot's but the PAGES': the block-sparse layers'
+    pooled keys (ops/block_select.py), rows under the K/V pool's page table;
+    carried, donated and updated in place with the rest. (A lightning
+    layer's matrices are `rule`'s: the linear kind's state.)"""
     conv: Optional[jnp.ndarray] = None
     rule: Optional[jnp.ndarray] = None
     ssm: Optional[jnp.ndarray] = None
     scan: Optional[jnp.ndarray] = None
     ring: Optional[WindowRing] = None
+    pooled: Optional[jnp.ndarray] = None
 
     def loop_carry(self) -> tuple:
-        """(conv window, recurrent state, rings), each may be None: the
-        three places the layers' loop carries the state in, whichever
-        recurrence a model runs (`scan_layers`)."""
+        """(conv window, recurrent state, rings, pooled keys), each may be
+        None: the four places the layers' loop carries the state in,
+        whichever recurrence a model runs (`scan_layers`)."""
         held = [getattr(self, f) for f in _RECURRENT
                 if getattr(self, f) is not None]
-        return self.conv, held[0] if held else None, self.ring
+        return self.conv, held[0] if held else None, self.ring, self.pooled
 
-    def after(self, conv, recurrent, ring) -> "SlotState":
-        """...and the loop's three results as a state of this one's form."""
-        return self._replace(conv=conv, ring=ring, **{
+    def after(self, conv, recurrent, ring, pooled=None) -> "SlotState":
+        """...and the loop's four results as a state of this one's form."""
+        return self._replace(conv=conv, ring=ring, pooled=pooled, **{
             f: recurrent for f in _RECURRENT if getattr(self, f) is not None})
 
 
@@ -563,30 +599,43 @@ def slot_state(conv_state) -> SlotState:
 
 
 def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16,
-                     ring_rows: int = 0):
+                     ring_rows: int = 0, pooled_rows: int = 0):
     """What `conv_state` of the step forwards is for `cfg`, at zero; None
     for a model whose layers keep nothing a slot.
     `ring_rows`: rows of a slot's ring a window layer
-    (`ModelConfig.ring_rows` of the longest span a step writes)."""
+    (`ModelConfig.ring_rows` of the longest span a step writes);
+    `pooled_rows`: rows of a sparse layer's pooled-key pool
+    (`ModelConfig.pooled_rows` of the page pool's size)."""
     window, width = cfg.state_window
+    if cfg.count(SPARSE) and not pooled_rows:
+        raise ValueError(
+            f"{cfg.name}: a {SPARSE} layer's pooled keys need their pool: "
+            "pooled_rows is 0 (ModelConfig.pooled_rows)")
+    lightning = bool(cfg.lightning_nh)
     if cfg.count(WINDOW) and ring_rows < cfg.sliding_window:
         raise ValueError(
             f"{cfg.name}: a slot's ring holds the window at the least: "
             f"ring_rows {ring_rows}, sliding_window {cfg.sliding_window}")
     state = SlotState(
         shortconv.alloc_state(
-            cfg.count(CONV) + cfg.count(LINEAR) + cfg.count(PARALLEL)
-            + cfg.count(MAMBA), max_slots, window, width, dtype),
+            cfg.count(CONV) + (0 if lightning else cfg.count(LINEAR))
+            + cfg.count(PARALLEL) + cfg.count(MAMBA), max_slots, window,
+            width, dtype),
         gated_delta.alloc_state(
-            cfg.count(LINEAR), max_slots, cfg.linear_num_value_heads,
-            cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+            cfg.count(LINEAR), max_slots,
+            *((cfg.lightning_nh,) + (cfg.lightning_head_dim,) * 2
+              if lightning else (cfg.linear_num_value_heads,
+                                 cfg.linear_key_head_dim,
+                                 cfg.linear_value_head_dim))),
         ssd.alloc_state(
             cfg.count(PARALLEL), max_slots, cfg.mamba_n_heads,
             cfg.mamba_d_state, cfg.mamba_d_head),
         selective_scan.alloc_state(
             cfg.count(MAMBA), max_slots, S6_D_STATE, cfg.s6_inner),
         alloc_ring(cfg.count(WINDOW), max_slots, ring_rows, cfg.kv_dim,
-                   dtype))
+                   dtype),
+        block_select.alloc_pooled(cfg.count(SPARSE), pooled_rows, cfg.kv_dim,
+                                  dtype))
     return state if jax.tree_util.tree_leaves(state) else None
 
 
@@ -635,7 +684,7 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state, at_exit=None):
     of_kind = {name: kind for kind, names in KIND_PARAMS.items()
                for name in names}
     seen = dict.fromkeys((ATTENTION, CONV, LINEAR, WINDOW, PARALLEL, MAMBA,
-                          GMU, CROSS, DENSE, EXPERTS), 0)
+                          GMU, CROSS, SPARSE, DENSE, EXPERTS), 0)
     windowed = cfg.count(WINDOW) > 0
     loads = []
     for first, period, repeats in cfg.layer_plan():
@@ -654,8 +703,9 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state, at_exit=None):
                 # ...and among the weights' stacks: a window layer reads
                 # the attention weights, which count both attention kinds.
                 held = dict(at)
-                if op == PARALLEL:  # ...and a parallel layer both kinds'
-                    held[ATTENTION] = at[op]
+                if op in (PARALLEL, SPARSE):  # ...and a parallel layer both
+                    held[ATTENTION] = at[op]  # kinds'; a sparse layer's
+                    # (its stack's only attention kind) the attention's
                 if windowed and op in ATTENTION_KINDS:
                     held[ATTENTION] = r * sum(
                         per[k] for k in ATTENTION_KINDS) + sum(
@@ -869,6 +919,12 @@ SPLIT_TO_HEADS_MINOR = {"wq": (0, 2, 1), "wk": (0, 2, 1)}
 # launch from rank-minor, as it re-laid `xwq`'s 92 MB from row-major (AOT for
 # a v5e, PR 56: scripts/step_hlo_copies.py on the configuration's file).
 HYBRID_MINOR = {"wq": (0, 2, 1), "xwq": (0, 2, 1)}
+# A lightning layer's q and k projections, whose results are split into
+# heads at once (the per-head norm): held row-major, both step programs of
+# MiniCPM-SALA's file re-laid each 403 MB stack a launch (AOT for a v5e,
+# PR 60: scripts/step_hlo_copies.py). `ltn_wv` is read as it lies by the
+# ragged step (the decode scan re-lays it once a launch of eight passes).
+LIGHTNING_MINOR = {"ltn_wq": (0, 2, 1), "ltn_wk": (0, 2, 1)}
 
 
 def weight_formats(cfg: ModelConfig, params: dict) -> dict:
@@ -887,6 +943,8 @@ def weight_formats(cfg: ModelConfig, params: dict) -> dict:
         named.update(HYBRID_MINOR)
     elif splits_heads_at_once(cfg):
         named.update(SPLIT_TO_HEADS_MINOR)
+    if cfg.lightning_nh:
+        named.update(LIGHTNING_MINOR)
     out = {}
     for name, order in named.items():
         leaf = params["layers"].get(name)
@@ -971,6 +1029,40 @@ def _linear_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
         o = rmsnorm(o, lp["lin_norm"], cfg.rms_norm_eps).reshape(B, T, H * dv)
         return qeinsum("bte,ed->btd", (o * jax.nn.silu(z)).astype(h.dtype),
                        lp["lin_out"])
+
+
+def _lightning_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray, positions,
+                  ssm_fn, depth) -> jnp.ndarray:
+    """Lightning linear attention over normed hiddens h [B, T, D]: q, k, v =
+    h W; q and k RMS-normed a head and (`lightning_use_rope`) roped over the
+    whole head; per head S_t = lambda S_{t-1} + k_t v_t^T, o_t = S_t^T q_t
+    d^-1/2 — ops/ssd.py's recurrence (k for its B, q for its C, a write
+    strength of 1), its log decay the CONSTANT -slope of (head, published
+    layer index: `ModelConfig.lightning_level` of `depth`, this layer's
+    index in the stack, traced), broadcast over the tokens: no
+    projection computes it; y = (RMSNorm(o) over all heads' lanes *
+    sigmoid(h W_z)) W_o. Which state the recurrence continues is the
+    caller's `ssm_fn(q [B, T, H, d], k, v, g [B, T, H]) -> o float32`."""
+    B, T, _ = h.shape
+    H, d = cfg.lightning_nh, cfg.lightning_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("ltn_in"):
+        q, k, v = (qeinsum("btd,de->bte", h, lp[name]).reshape(B, T, H, d)
+                   for name in ("ltn_wq", "ltn_wk", "ltn_wv"))
+        z = qeinsum("btd,de->bte", h, lp["ltn_wz"])
+        q = _norm(cfg, q, lp["ltn_q_norm"])
+        k = _norm(cfg, k, lp["ltn_k_norm"])
+        if cfg.lightning_use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta, d)
+            k = apply_rope(k, positions, cfg.rope_theta, d)
+        base = 2.0 ** (-8.0 * jnp.arange(1, H + 1, dtype=f32) / H)
+        g = jnp.broadcast_to(-base * cfg.lightning_level(depth), (B, T, H))
+    with jax.named_scope("ltn_rule"):
+        o = ssm_fn(q.astype(f32) * d ** -0.5, k, v, g)
+    with jax.named_scope("ltn_out"):
+        o = rmsnorm(o.reshape(B, T, H * d), lp["ltn_norm"], cfg.rms_norm_eps)
+        o = o.astype(f32) * jax.nn.sigmoid(z.astype(f32))
+        return qeinsum("bte,ed->btd", o.astype(h.dtype), lp["ltn_wo"])
 
 
 def _gated_group_norm(y, z, w, groups: int, eps: float):
@@ -1209,6 +1301,8 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
                                    cross=op == CROSS)
     elif op == CONV:
         delta = _conv_op(cfg, lp, h, taps_fn)
+    elif op == LINEAR and cfg.lightning_nh:
+        delta = _lightning_op(cfg, lp, h, positions, ssm_fn, depth)
     elif op == LINEAR:
         delta = _linear_attention_op(cfg, lp, h, taps_fn, rule_fn)
     elif op == PARALLEL:
@@ -1220,6 +1314,9 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
                               rotate=cfg.rotates(op))
     if cfg.sandwich_norm:
         delta = norm(delta, "post_attn_norm")
+    scale = cfg.residual_multiplier  # `scale_depth`'s; 1: nothing is traced
+    if scale != 1.0:
+        delta = delta * scale
     x = x + (delta if pre else norm(delta, "attn_norm"))
     with jax.named_scope("mlp"):
         delta, load = _ffn(cfg, lp, ffn, norm(x, "mlp_norm") if pre else x,
@@ -1228,6 +1325,8 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
             delta = norm(delta, "mlp_norm")
         elif cfg.sandwich_norm:
             delta = norm(delta, "post_mlp_norm")
+        if scale != 1.0:
+            delta = delta * scale
     return x + delta, load
 
 
@@ -1251,6 +1350,12 @@ def _served_forwards_only(cfg: ModelConfig, forward: str) -> None:
             "form for mamba, gated-memory and cross layers (served: "
             "forward_ragged, forward_decode; the oracle: "
             "benchmarks/reference/phi4_flash_decoder.py)")
+    if cfg.count(SPARSE):
+        raise ValueError(
+            f"{cfg.name}: {forward} has no form for {SPARSE} layers: their "
+            "pooled keys live under a page table (served: forward_ragged, "
+            "forward_decode; the oracle: "
+            "benchmarks/reference/minicpm_sala_decoder.py)")
 
 
 def _causal_fn(cfg: ModelConfig, seq_lens, op: str = ATTENTION):
@@ -1421,9 +1526,27 @@ def forward_ragged(
         live_len = kv_len if emits is None else jnp.where(
             (emits > 0) & (q_len > 0), kv_len, 0)
 
-    def body(x, lp, kinds, ix, kc, vc, conv, rule, ring, *m):
+    if state.pooled is not None:  # the tokens that complete a pooled row
+        sizes = block_select.Sizes.of(cfg)
+        done_at, done = block_select.completing(
+            tok_pos, tok_pos >= 0, sizes,
+            tokens.shape[0] // sizes.stride + page_table.shape[0])
+
+    def body(x, lp, kinds, ix, kc, vc, conv, rule, ring, pooled, *m):
         def attn_fn(q, k, v, expanded=None):  # [1, T, H, hd]
-            nonlocal kc, vc, ring
+            nonlocal kc, vc, ring, pooled
+            if kinds[0] == SPARSE:
+                with jax.named_scope("kv_write"):
+                    kc = kv_write(kc, ix.op, write_slots, k[0])
+                    vc = kv_write(vc, ix.op, write_slots, v[0])
+                    with jax.named_scope("bsa_pool_write"):
+                        pooled = block_select.write_pooled(
+                            pooled, kc, ix.op, page_table[tok_seq[done_at]],
+                            tok_pos[done_at], done, sizes, page_size)
+                return _sparse_ragged(
+                    cfg, attn_impl, q[0], kc, vc, pooled, ix.op, page_table,
+                    tok_seq, tok_pos, kv_len, q_start, q_len, page_size,
+                    interpret, mesh)[None]
             if kinds[0] == CROSS:  # the full layer's rows, as it left them
                 with jax.named_scope("cross_attention"):
                     if not exits:
@@ -1497,14 +1620,14 @@ def forward_ragged(
                               layer=ix.ffn, rule_fn=rule_fn, ssm_fn=ssm_fn,
                               few=few, s6_fn=s6_fn, memo_fn=handed,
                               depth=ix.layer)
-        return (x, kc, vc, conv, rule, ring, *handed.held, load)
+        return (x, kc, vc, conv, rule, ring, pooled, *handed.held, load)
 
     @jax.named_scope("early_exit_gather")
     def at_exit(x, *carried):
         *carried, m = carried
         return (x[0][sampled][:, None], *carried, m[0][sampled][:, None])
 
-    x, k_cache, v_cache, conv, rule, ring, *_, load = scan_layers(
+    x, k_cache, v_cache, conv, rule, ring, pooled, *_, load = scan_layers(
         cfg, body, x, params["layers"], k_cache, v_cache, *state.loop_carry(), *memo,
         at_exit=at_exit if exits else None)
     if exits:  # x [B, 1, D]: the sampled rows, through every layer
@@ -1518,8 +1641,93 @@ def forward_ragged(
         x_last = x[0][out_idx]  # [B, O, D]
         logits = _logits(params, cfg, x_last)  # [B, O, V]
     out = _results(logits, k_cache, v_cache, conv_state,
-                   state.after(conv, rule, ring), load, moe_load)
+                   state.after(conv, rule, ring, pooled), load, moe_load)
     return out + (x[0],) if hidden else out
+
+
+def _sparse_rows(cfg, attn_impl, q, kc, vc, pooled, layer, page_table, n,
+                 live, page_size, interpret=False):
+    """One query a row over the blocks its kv groups keep: q [B, H, hd] at
+    contexts n [B] (live [B] bool: the others read nothing) over pool layer
+    `layer` — the block scores over the rows' pooled keys, the top-k, and
+    the walk over the kept blocks' pages, a (row, kv head) each
+    (ops/block_select.py; on the chip
+    ops/pallas/block_sparse_attention.py). A row at or under
+    `sparse_dense_len` walks its own pages."""
+    z = block_select.Sizes.of(cfg)
+    B, H, hd = q.shape
+    Hk = cfg.num_kv_heads
+    with jax.named_scope("bsa_select"):
+        # (J in whole lane tiles: rows past the table's are the last page's
+        # again, and lie past every context)
+        J = -(-page_table.shape[1] * page_size // z.stride // 128) * 128
+        at = block_select.pooled_slots(
+            page_table, jnp.broadcast_to(jnp.arange(J, dtype=jnp.int32),
+                                         (B, J)), z.stride, page_size)
+        pk = pooled[layer, at].reshape(B, J, Hk, hd)
+        probs = None
+        if attn_impl == "pallas":
+            from ollamamq_tpu.ops.pallas.bsa_select import bsa_select_pallas
+
+            probs = bsa_select_pallas(q, pk, n, z.kernel, z.stride,
+                                      interpret=interpret)
+        ids, count = block_select.select(q, pk, n, z, probs)
+        table, lens = block_select.walk_table(page_table, ids, count, n,
+                                              live, z, page_size)
+    with jax.named_scope("bsa_attention"):
+        rows = jnp.repeat(q, Hk, axis=0)  # row (b, g): every head of b
+        if attn_impl == "pallas":
+            from ollamamq_tpu.ops.pallas.block_sparse_attention import (
+                bsa_decode_attention_pallas)
+
+            o = bsa_decode_attention_pallas(rows, kc, vc, layer, table, lens,
+                                            page_size, interpret=interpret)
+        else:
+            o = paged_decode_attention(rows, kc, vc, layer, table, lens,
+                                       page_size)
+        # group g's heads from row (b, g)
+        o = o.reshape(B, Hk, Hk, H // Hk, hd)
+        return o[:, jnp.arange(Hk), jnp.arange(Hk)].reshape(B, H, hd)
+
+
+def _sparse_ragged(cfg, attn_impl, q, kc, vc, pooled, layer, page_table,
+                   tok_seq, tok_pos, kv_len, q_start, q_len, page_size,
+                   interpret=False, mesh=None):
+    """A ragged stream's block-sparse attention of one layer, q [T, H, hd].
+    A row whose context is at or under `sparse_dense_len` keeps every block:
+    the plain ragged walk serves it. A ONE-TOKEN row past it follows its
+    block list (`_sparse_rows`: the kept blocks' pages and no other). A
+    longer SPAN that ends past it is served under its tokens' block masks
+    over the row's whole context, in XLA (block_select.span_attention; a
+    loop over such rows, none in most steps): what the mathematics asks,
+    not yet what it allows to skip."""
+    z = block_select.Sizes.of(cfg)
+    T = q.shape[0]
+    past = kv_len > z.dense_len
+    one, span = past & (q_len == 1), past & (q_len > 1)
+    order = jnp.argsort(~span, stable=True).astype(jnp.int32)
+
+    def row(i, out):
+        b = order[i]
+        with jax.named_scope("bsa_span"):
+            o = block_select.span_attention(
+                q, kc, vc, pooled, layer, page_table[b], tok_pos, z,
+                page_size)
+        mine = (tok_seq == b) & (tok_pos >= 0)
+        return jnp.where(mine[:, None, None], o, out)
+
+    with jax.named_scope("attention"):
+        # (the rows served below walk their own span only here)
+        out = ragged_attention_any(
+            attn_impl, q, kc, vc, layer, page_table, tok_seq, tok_pos,
+            jnp.where(past, q_len, kv_len), q_start, q_len, page_size,
+            interpret=interpret, mesh=mesh)
+        o_rows = _sparse_rows(cfg, attn_impl, q[jnp.clip(q_start, 0, T - 1)],
+                              kc, vc, pooled, layer, page_table, kv_len, one,
+                              page_size, interpret)
+        out = out.at[jnp.where(one, q_start, T)].set(o_rows, mode="drop")
+        return jax.lax.fori_loop(0, jnp.sum(span).astype(jnp.int32), row,
+                                 out)
 
 
 def _cross_attend(attn_impl, q, kc, vc, layer, page_table, ctx_len,
@@ -1685,9 +1893,22 @@ def forward_decode(
         live_len = seq_lens if active is None else jnp.where(
             active > 0, seq_lens, 0)
 
-    def body(x, lp, kinds, ix, kc, vc, conv, rule, ring, *m):
+    def body(x, lp, kinds, ix, kc, vc, conv, rule, ring, pooled, *m):
         def attn_fn(q, k, v, expanded=None):  # [B, 1, H, hd]
-            nonlocal kc, vc, ring
+            nonlocal kc, vc, ring, pooled
+            if kinds[0] == SPARSE:
+                live = jnp.ones((B,), bool) if active is None else active > 0
+                with jax.named_scope("kv_write"):
+                    kc = kv_write(kc, ix.op, write_slots, k[:, 0])
+                    vc = kv_write(vc, ix.op, write_slots, v[:, 0])
+                    with jax.named_scope("bsa_pool_write"):
+                        pooled = block_select.write_pooled(
+                            pooled, kc, ix.op, page_table, positions, live,
+                            block_select.Sizes.of(cfg), page_size)
+                with jax.named_scope("attention"):
+                    return _sparse_rows(cfg, attn_impl, q[:, 0], kc, vc,
+                                        pooled, ix.op, page_table, seq_lens,
+                                        live, page_size)[:, None]
             if kinds[0] == CROSS:
                 with jax.named_scope("cross_attention"):
                     return _cross_attend(
@@ -1755,13 +1976,13 @@ def forward_decode(
                               valid=valid, mesh=mesh, impl=attn_impl,
                               layer=ix.ffn, rule_fn=rule_fn, ssm_fn=ssm_fn,
                               s6_fn=s6_fn, memo_fn=handed, depth=ix.layer)
-        return (x, kc, vc, conv, rule, ring, *handed.held, load)
+        return (x, kc, vc, conv, rule, ring, pooled, *handed.held, load)
 
-    x, k_cache, v_cache, conv, rule, ring, *_, load = scan_layers(
+    x, k_cache, v_cache, conv, rule, ring, pooled, *_, load = scan_layers(
         cfg, body, x, params["layers"], k_cache, v_cache, *state.loop_carry(), *memo)
     logits = _logits(params, cfg, x)[:, 0, :]
     return _results(logits, k_cache, v_cache, conv_state,
-                    state.after(conv, rule, ring), load, moe_load)
+                    state.after(conv, rule, ring, pooled), load, moe_load)
 
 
 def forward_embed(

@@ -68,8 +68,19 @@ def flops_per_token(cfg, context_len: float = 0.0,
     pair = 6.0 if cfg.mb_per_layer else 4.0
     # ...a cross layer walks the full layer's rows again (a token that
     # exits passes none of them),
-    walks = cfg.paged_layers + (0 if below else cfg.count("cross_attention"))
+    sparse = cfg.count("sparse_attention")
+    walks = cfg.paged_layers - sparse \
+        + (0 if below else cfg.count("cross_attention"))
     attn = pair * walks * ctx * cfg.q_dim
+    if sparse:
+        # ...a block-sparse layer's stops growing past `sparse_dense_len`,
+        # at the kept blocks' keys, and pays the block scores instead: a
+        # pooled key a `sparse_kernel_stride` positions, 2 FLOPs a lane.
+        kept = ctx if ctx <= cfg.sparse_dense_len else min(
+            ctx, cfg.sparse_topk * cfg.sparse_block_size)
+        attn += sparse * cfg.q_dim * (
+            4.0 * kept + (2.0 * ctx / cfg.sparse_kernel_stride
+                          if ctx > cfg.sparse_dense_len else 0.0))
     # ...and a window layer attends its last `sliding_window` positions.
     attn += pair * cfg.count("sliding_attention") \
         * min(ctx, cfg.sliding_window) * cfg.q_dim

@@ -333,6 +333,39 @@ SSM_SPAN_TOKENS_TOTAL = REGISTRY.counter(
     "Tokens of spans longer than one token that went through the "
     "recurrence's chunked form (the state read and written once a "
     "64-token window a span touches)", labels=("model",))
+HBM_BSA_POOLED_BYTES = REGISTRY.gauge(
+    "ollamamq_hbm_bsa_pooled_bytes",
+    "Bytes the block-sparse layers' pooled-key pool occupies per model "
+    "runtime (sparse layers x pages x page size / pooling stride x kv heads "
+    "x head dim; rows under the K/V pool's page table, carried beside the "
+    "per-slot state; 0 for a model without such layers)", labels=("model",))
+BSA_BLOCKS_IN_CONTEXT_TOTAL = REGISTRY.counter(
+    "ollamamq_bsa_blocks_in_context_total",
+    "Blocks of cached positions in the contexts of the sparse-attention "
+    "queries past sparse_dense_len of launched steps, one sparse layer's "
+    "worth: ceil(n / sparse_block_size) a query at context n (decode rows "
+    "and span tokens together)", labels=("model",))
+BSA_BLOCKS_KEPT_TOTAL = REGISTRY.counter(
+    "ollamamq_bsa_blocks_kept_total",
+    "...and the blocks those queries keep, what the mathematics asks a "
+    "walk to read: min(sparse_topk, blocks in context) a query",
+    labels=("model",))
+BSA_BLOCKS_WALKED_TOTAL = REGISTRY.counter(
+    "ollamamq_bsa_blocks_walked_total",
+    "...and the blocks the program's walks cover for them: a one-token "
+    "row's kept blocks (its walk follows the list), a longer span's whole "
+    "context a token (served under a block mask)", labels=("model",))
+LIGHTNING_STEP_ROWS_TOTAL = REGISTRY.counter(
+    "ollamamq_lightning_step_rows_total",
+    "Row-passes through the lightning recurrence's one-token form (a state "
+    "row read once and written once a lightning layer): a ragged step's "
+    "1-token rows, a fused scan's active slots x its passes",
+    labels=("model",))
+LIGHTNING_SPAN_TOKENS_TOTAL = REGISTRY.counter(
+    "ollamamq_lightning_span_tokens_total",
+    "Tokens of spans longer than one token that went through the lightning "
+    "recurrence's chunked form (the state read and written once a 64-token "
+    "window a span touches)", labels=("model",))
 HBM_S6_STATE_BYTES = REGISTRY.gauge(
     "ollamamq_hbm_s6_state_bytes",
     "Bytes the mamba layers' per-slot selective-scan state occupies per "

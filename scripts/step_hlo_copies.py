@@ -329,7 +329,8 @@ def step_args(mc, dims, devices, *, num_pages: int, ring_tokens: int,
     state = jax.tree_util.tree_map(
         lambda a: s(a.shape, a.dtype),
         jax.eval_shape(lambda: llama.alloc_slot_state(
-            mc, S, ring_rows=mc.ring_rows(ring_tokens, ps))))
+            mc, S, ring_rows=mc.ring_rows(ring_tokens, ps),
+            pooled_rows=mc.pooled_rows(num_pages, ps))))
     return StepArgs(
         mesh, params,
         (*pools, s((S + 1, dims.repeat_last_n)), s((S,)), state),

@@ -17,7 +17,8 @@ WITH = {"conv": "test-tiny-lfm2", "lin": "test-tiny-olmo-hybrid",
         "ssm": "test-tiny-falcon-h1", "s6": "test-tiny-phi4-flash",
         "latent": "test-tiny-deepseek-v32",
         "dense_latent": "test-tiny-openpangu", "attn": "test-tiny",
-        "swa": "test-tiny-k-exaone", "exit": "test-tiny-phi4-flash"}
+        "swa": "test-tiny-k-exaone", "exit": "test-tiny-phi4-flash",
+        "lightning": "test-tiny-minicpm-sala", "bsa": "test-tiny-minicpm-sala"}
 WITHOUT = {kind: "test-tiny-deepseek-v32" if kind == "attn" else "test-tiny"
            for kind in KINDS}
 

@@ -389,7 +389,8 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
                                    "test-tiny-deepseek-v32",
                                    "test-tiny-qwen3-next",
                                    "test-tiny-falcon-h1",
-                                   "test-tiny-phi4-flash"])
+                                   "test-tiny-phi4-flash",
+                                   "test-tiny-minicpm-sala"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
@@ -417,7 +418,10 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     rt = eng.runtimes[model]
     scopes = llama.SCOPES + (moe.SCOPES if rt.cfg.num_experts else ()) \
         + (llama.CONV_SCOPES if rt.cfg.count("conv") else ()) \
-        + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention") else ()) \
+        + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention")
+           and not rt.cfg.lightning_nh else ()) \
+        + (llama.LIGHTNING_SCOPES if rt.cfg.lightning_nh else ()) \
+        + (llama.BSA_SCOPES if rt.cfg.count("sparse_attention") else ()) \
         + (llama.SSM_SCOPES if rt.cfg.count("attention_ssm") else ()) \
         + (llama.HYBRID_SCOPES if rt.cfg.mb_per_layer else ()) \
         + (llama.GATE_SCOPES if rt.cfg.attn_output_gate else ()) \
@@ -463,8 +467,9 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         assert re.search(r"module @jit_%s\b" % names[site], text), \
             text[:200]
         for scope in scopes:
-            if scope == "early_exit_gather" and site == "decode":
-                continue
+            if scope in ("early_exit_gather", "bsa_span") \
+                    and site == "decode":
+                continue  # (the ragged program's alone)
             # A whole component of an op's name stack ("embed/gather"),
             # not a parameter name or a file path that contains the word.
             assert re.search(r'loc\("(?:[^"/]+/)*%s(?:/[^"]+)?"' % scope,
@@ -481,6 +486,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     assert set(llama.SCOPES) | set(llama.CONV_SCOPES) | set(moe.SCOPES) \
         | set(llama.LINEAR_SCOPES) | set(llama.SSM_SCOPES) \
         | set(llama.HYBRID_SCOPES) | set(mla.SCOPES) \
+        | set(llama.BSA_SCOPES) | set(llama.LIGHTNING_SCOPES) \
         | set(moe.SHARED_SCOPES) | set(llama.GATE_SCOPES) \
         | set(moe.SHARED_GATE_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented

@@ -447,3 +447,29 @@ def openpangu_keys(mc) -> dict:
     its own two)."""
     return {**deepseek_v32_keys(mc), "sandwich_norm": mc.sandwich_norm,
             "num_nextn_predict_layers": mc.num_nextn_predict_layers}
+
+
+def minicpm_sala_reference():
+    """...and of the family that runs block-sparse attention beside lightning
+    linear attention (benchmarks/reference/minicpm_sala_decoder.py)."""
+    return _reference("minicpm_sala_decoder")
+
+
+def minicpm_sala_keys(mc) -> dict:
+    """What a configuration file says of the block-sparse / lightning
+    ModelConfig `mc`, in the published spellings: all that reference reads."""
+    keys = {"num_hidden_layers": mc.num_layers, "hidden_size": mc.hidden_size,
+            "intermediate_size": mc.intermediate_size,
+            "num_attention_heads": mc.num_heads,
+            "num_key_value_heads": mc.num_kv_heads, "head_dim": mc.head_dim,
+            "rms_norm_eps": mc.rms_norm_eps, "rope_theta": mc.rope_theta,
+            "tie_word_embeddings": mc.tie_embeddings,
+            "mixer_types": list(mc.mixer_types)}
+    for name in ("sparse_kernel_size", "sparse_kernel_stride",
+                 "sparse_block_size", "sparse_topk", "sparse_init_blocks",
+                 "sparse_window_size", "sparse_dense_len", "lightning_nh",
+                 "lightning_head_dim", "lightning_use_rope", "scale_emb",
+                 "scale_depth", "scale_depth_layers", "dim_model_base",
+                 "layer_offset"):
+        keys[name] = getattr(mc, name)
+    return keys
