@@ -152,31 +152,57 @@ a little less a token but leave a longer head to the short tiles, and at
 limit of 16): 64. What is left of the 8 k launch: 808 short trips (the
 five decode rows' 320 at one live row of 8, the span's first 59 tokens'
 488) 0.50 ms, 439 tall trips 0.87 ms.
-  The tall trip's lane tiles are unrolled up to `TALL_UNROLL` = 2 of them
-and a loop in the program beyond — the one place where that loop is
-rolled — because a rung's second body is paid at every start
-(`setup_s`): unrolled it is one more copy of the inner product a lane
-tile, 1142 → 2347 equations at (16, 16, 128) and 1954 → 3985 at (30, 30,
-128), and the three rungs that carry it cost OLMoE's warm start +1.6-2.9 s
-of ~42 and the hybrid's +4.1-4.3 s of ~68 (the `warm_up` note's ms a
-rung: 512 tokens 8.89 → 10.28-10.56 s, 256 4.29 → 5.75-5.90, 128 4.86 →
-5.86-5.96 on the hybrid; my chip run, PR 48, call 2); rolled it is 324
-equations more at any width and the same rungs read +0.5 and +0.7 s, ~1 %
-(9.17-9.54 → 9.50-9.89, 4.28-4.69 → 4.59-4.68, 4.91-5.25 → 5.18-5.45;
-call 3). What the roll costs a launch depends on the rows a lane tile
-holds (`raggedlong` at 4 k, µs a tall trip, rolled / unrolled; call 3):
-  (28, 4, 128) 3.04 / 3.03   (32, 8, 64) 3.38 / 3.35   (16, 2, 256) 2.98 / 2.04
-  (16, 16, 128) 6.85 / 2.72  (30, 30, 128) 12.71 / 4.67
-— nothing at 448 or 512 row-heads a 128-lane tile, where a tile's own
-work (0.76 µs) hides the chain's latency; 2.5-2.7 × at the MHA shapes'
-128 row-heads a tile, where the unrolled loop overlapped sixteen or thirty
-short chains. Rolled, an MHA model's long chunk is still 1.8 × faster a
-launch than on tiles of 8 (4 k: 4.08 → 2.23 ms at (16, 16, 128), 7.31 →
-4.09 at (30, 30, 128), by the short trip's price) where unrolled it was
-3.0 ×: the price of a start-up that every cell pays against a launch no
-cell of those shapes runs. Two tiles unrolled cost no more equations than
-the loop (715 for 709) and are what `qwen3-next-80b-a3b-ep4-d12` runs.
-  The cells' other steps, parent → this, ms a launch (calls 1 and 3):
+  The tall trip's lane tiles are straight-line code up to `TALL_UNROLL` = 8
+of them (PR 61) and a loop in the program beyond — the one place where that
+loop is rolled. What decides is what a rung's second body costs every start
+(`setup_s`) against what the roll costs a launch, and both moved. PR 48
+rolled the loop beyond TWO tiles: unrolled it was one more copy of the inner
+product a lane tile — 1142 → 2347 equations at (16, 16, 128), 1954 → 3985 at
+(30, 30, 128), OLMoE's warm start +1.6-2.9 s of ~42 and the hybrid's +4.1-4.3
+of ~68 over the three rungs that carry it (my chip run, PR 48, call 2) where
+rolled the same rungs read +0.5 and +0.7 s (call 3) — and the roll then cost
+a launch NOTHING at 448-512 row-heads a 128-lane tile ((28, 4, 128) 3.04 /
+3.03 µs a tall trip rolled / unrolled, (32, 8, 64) 3.38 / 3.35; 2.5-2.7 × at
+the MHA shapes' 128 row-heads a tile: (16, 16, 128) 6.85 / 2.72, (30, 30, 128)
+12.71 / 4.67; call 3): three bf16 terms of P·V hid every chain's latency. PR
+59 took two of the terms out, and the roll came out as HALF the trip.
+Re-measured (my chip run, PR 61, call 1; `raggedlong`, one v5e, parent and
+change in one call, each row twice within 1 %), µs a tall trip at 4 k / 8 k /
+16 k rolled beyond two → unrolled, then ms a launch and ms a window layer's
+launch at 8 k:
+  (64, 8, 128)  4.84 4.71 4.65 → 2.51 2.36 2.30   2.797 → 1.765   0.211 → 0.149
+  (28, 4, 128)  2.53 2.46 2.43 → 1.52 1.46 1.42   1.625 → 1.185   0.133 → 0.110
+  (20, 4, 128)  2.28 2.23 2.20 → 1.22 1.19 1.15   1.621 → 1.162   0.114 → 0.085
+  (32, 8, 64)   2.85 2.62 2.51 → 1.83 1.61 1.50   1.703 → 1.264   0.199 → 0.174
+and `ragged512` (two 228-token spans over no prefix) 0.2569 → 0.2339, 0.1724
+→ 0.1618, 0.1852 → 0.1738, 0.2521 → 0.2426; `decode` and `ragged64` within
+1 % (a 64-token rung traces no tall body). The form in between at eight
+tiles — a loop of two trips, four unrolled tiles each — reads 3.62 µs a trip
+and 2.315 ms a launch at 8 k (a loop of four trips of two: 4.44, 2.679): 47 %
+of the full unroll's gain for half its copies, so all eight are unrolled. A
+loop's edge is what costs: whatever crosses it waits for the chain before it.
+  What the copies cost a start is the other half of PR 61. A copy's price was
+never its equations but its nested jits ("what a rung's trace is charged
+for", below): `fold` and `finish` bound ~23 a lane tile. Written with `lax`
+primitives a copy binds none and is 40 equations; a tall rung is 829 → 1105
+equations at (64, 8, 128), 673 → 789 at four tiles and 637 at two of 256
+lanes as before, and 304 more than the short rung's at any width where the
+loop stays (`tests/test_ragged_attention.py` holds the counts and the binds).
+A warm rung, parent → this (the `warm_up` note of K-EXAONE's, `.batch`'s,
+LFM2's and Falcon-H1's cells, both trees in one call and in both orders; my
+chip runs, PR 61):
+K-EXAONE, four warm runs a side, s a rung 512 / 256 / 128: 6.13 3.96 4.00 →
+6.23 4.02 4.11 (the decode scan 5.14 → 4.18, `warm_s` 31.8 → 31.2); LFM2 6.47
+3.43 3.48 → 6.58 3.61 3.60 (27.1 → 26.8); `.batch` 2.10 1.17 1.04 → 2.01 1.21
+1.17 (12.6 → 10.4); Falcon-H1 2.63 2.13 2.12 → 2.63 2.10 2.17 (16.3 → 16.1):
+-0.1 … +0.2 s a tall rung, and less warm-up in all four.
+  Beyond eight tiles the loop stays rolled: 10 (Phi-4-mini-flash, (40, 20,
+64)), 16 (OLMoE) and 30 (Olmo-Hybrid) are MHA shapes whose cells run no
+launch that the 2.5-2.7 × would show in, and every one of them would pay its
+start; rolled, an MHA model's long chunk is still 1.8 × faster a launch than
+on tiles of 8 (4 k: 4.08 → 2.23 ms at (16, 16, 128), 7.31 → 4.09 at (30, 30,
+128); PR 48, call 3). A long-prompt cell at such a shape is the judge of more.
+  The cells' other steps at PR 48, parent → this, ms a launch (calls 1, 3):
 `ragged64` and `decode` within 2 % at every shape (a 64-token rung traces
 the parent's body), `ragged512` (56 decode rows, two 228-token spans over
 no prefix: six whole stretches)
@@ -203,10 +229,10 @@ in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
     (`tests/test_ragged_attention.py` holds that). Since PR 48 a rung of
     2 * TALL tokens or more holds TWO bodies — a program's tiles are a
     loop in the program too, and the tall walk beside it is one more copy
-    of the inner product, its lane tiles a loop in the program beyond
-    `TALL_UNROLL`: 770 and 1466 equations there (PR 59: P's split into
-    terms was 13 equations a lane tile — 394 and 934 on a rung of one
-    body, 705 and 1245 on one of two).
+    of the inner product a lane tile up to `TALL_UNROLL` = 8 of them and
+    ONE copy, its lane tiles a loop in the program, beyond (PR 61: 369 and
+    837 equations on a rung of one body — 39 a lane tile and 213 of
+    everything else —, 789 and 1141 on one of two).
   - IN PYTHON: the lane tiles (`for t in range(self.tiles)` in
     `Mxu.update` / `finish`) and a block's pages (`PageStream`, at most 4:
     straight-line copies under the block's one predicate).
@@ -217,12 +243,12 @@ in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
     times as much: ragged 64 rows 0.180 → 0.361 at (28, 4, 128), 0.138 →
     0.214 at (8, 2, 128), 0.476 → 1.118 at (16, 16, 128); decode 0.170 →
     0.331 (PR 34, same run; OLMoE end to end 4170-4259 → 3363-3959
-    tokens/s over 20 s windows). A rolled tile loop serialises what the unrolled one lets
-    the scheduler overlap (tile t+1's MXU pushes under tile t's softmax).
-    So the body grows with the lane tiles alone — 16 at most among the
-    published shapes — and with nothing else. (The tall trip's tile loop
-    IS rolled beyond two tiles, PR 48: see above for what that buys a
-    start and costs a launch.)
+    tokens/s over 20 s windows). A rolled tile loop serialises what the
+    unrolled one lets the scheduler overlap (tile t+1's MXU pushes under
+    tile t's softmax). So the body grows with the lane tiles alone — 30 at
+    most among the published shapes — and with nothing else. (The tall
+    trip's tile loop IS rolled beyond eight tiles, PRs 48 and 61: see above
+    for what that buys a start and costs a launch.)
 `hd % 128 == 0` or `hd == 64` changes none of this: the packing is the
 wrapper's, the kernel sees `tiles` tiles of width W.
   What a rung's trace is charged for is not only equations (PR 38): on a
@@ -234,9 +260,24 @@ operators read +0.25 s a ragged rung on `.batch` (six rungs a start;
 `JAX_LOG_COMPILES` and a profile of the first call, my chip run, PR 38)
 though the kernel alone traced no slower anywhere; written with `lax`
 primitives (`add`, `mul`, `cdiv`, `mod`, `lax.select` … below) the
-ragged kernel binds 117 nested jits a trace where the parent's bound 191
-and the decode kernel 97 for 196 (at (28, 4, 128); all of them left are
-the inner products' vector code).
+ragged kernel bound 117 nested jits a trace where the parent's bound 191
+and the decode kernel 97 for 196 (at (28, 4, 128)); all of them left were
+the inner products' vector code — `jnp.where`, `maximum`, `exp`, `sum`, `*`,
+`+`, `-`, `/` on `[M, 128]` values, ~19 a lane tile in `Mxu.update`'s fold
+and 4 in `finish`, so 209 a trace of a tall rung at (64, 8, 128) and 353 at
+(16, 16, 128). Since PR 61 `Mxu`'s vector code is `lax` calls too
+(`lax.select` over `full_like`, `reduce_max` / `reduce_sum` with an explicit
+`broadcast_in_dim`, `lax.max` / `exp` / `sub` / `mul` / `add` / `div`): a
+trace of the ragged kernel binds 7-23 nested jits at ANY width, all of them
+the wrapper's (`pad`, `searchsorted`, the packing), and every kernel that
+`Mxu` builds is the parent's to the equation but for the wrappers
+themselves — a `jnp.where` was a `jit` equation around a weak scalar's
+`convert_element_type`, its `broadcast_in_dim` and the `select_n`; the last
+two are what is traced now, 6 equations fewer a lane tile, every other
+primitive where it was, with the avals it had (compared equation by equation
+at ten head shapes, both rungs, bf16 / float32 / int8 pools, with and
+without a window, and the decode kernel; PR 61). `Vpu` keeps its operators:
+one decode row of an MHA model, 64 light copies, no tall body.
 
 P into P·V (PR 59): against a bf16 pool P enters the contraction at the
 pool's dtype, rounded once to nearest — `_dot(p.astype(v.dtype), v)`, what
@@ -327,8 +368,10 @@ G_TILE = 8
 TALL = 64
 # Lane tiles up to which the tall trip's loop over them is unrolled in
 # Python, as the tile's trip is at any width; beyond, it is a loop in the
-# program (module docstring: what either costs a launch and a rung).
-TALL_UNROLL = 2
+# program. 2 until PR 61, when the roll was half of a tall trip at eight
+# tiles and 40 % of one at four (module docstring: what either costs a
+# launch and a rung); the MHA shapes — 10, 16, 30 tiles — stay rolled.
+TALL_UNROLL = 8
 _NN = (((1,), (0,)), ((), ()))  # [M, K] · [K, N]
 _NT = (((1,), (1,)), ((), ()))  # [M, K] · [N, K]ᵀ
 
@@ -660,6 +703,19 @@ def _lane_fit(x, n):
     return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
 
 
+def _where(pred, x, y):
+    """`jnp.where` on an array and a constant, as the two primitives it
+    is (the module docstring: "what a rung's trace is charged for")."""
+    if isinstance(x, float):
+        return lax.select(pred, lax.full_like(y, x), y)
+    return lax.select(pred, x, lax.full_like(x, y))
+
+
+def _rows(reduce, x):
+    """`[M, n]` → `[M, 1]`: a row's maximum or sum, kept as a column."""
+    return lax.broadcast_in_dim(reduce(x, (1,)), (x.shape[0], 1), (0,))
+
+
 class Mxu(_Inner):
     """One MXU contraction a (block, lane tile) for every row-head that
     shares it; see the module docstring."""
@@ -771,24 +827,33 @@ class Mxu(_Inner):
             return jax.lax.broadcasted_iota(
                 jnp.int32, held + (blk,), dim).reshape(m, blk)
 
-        pos = pos0 + iota(len(held))
+        pos = lax.add(pos0, iota(len(held)))
         if self.rows == 1:
-            valid = pos < kv
+            valid = lax.lt(pos, kv)
             if self.window:
-                valid = valid & (pos >= kv - self.window)
+                valid = lax.bitwise_and(
+                    valid, lax.ge(pos, lax.sub(kv, self.window)))
         else:
             # Row-head i*M + g*rows + r is token tile_start + r: inside
             # this sequence's span it sees positions up to its own (which
             # lies below kv), outside it nothing.
-            tok = tile_start + (iota(len(held) - 1) & (self.rows - 1))
+            tok = lax.add(tile_start, lax.bitwise_and(
+                iota(len(held) - 1), self.rows - 1))
             if tall:  # tile j's rows are `rows` tokens further on each
-                tok = tok + iota(0) * self.rows
-                valid = pos <= kv - ql + (tok - qs)
+                tok = lax.add(tok, lax.mul(iota(0), self.rows))
+
+            def own():  # the position of a row's token
+                return lax.add(lax.sub(kv, ql), lax.sub(tok, qs))
+
+            if tall:
+                valid = lax.le(pos, own())
             else:
-                valid = ((tok >= qs) & (tok < qs + ql)
-                         & (pos <= kv - ql + (tok - qs)))
+                valid = lax.bitwise_and(
+                    lax.ge(tok, qs), lax.lt(tok, lax.add(qs, ql)))
+                valid = lax.bitwise_and(valid, lax.le(pos, own()))
             if self.window:
-                valid = valid & (pos > kv - ql + (tok - qs) - self.window)
+                valid = lax.bitwise_and(
+                    valid, lax.gt(pos, lax.sub(own(), self.window)))
         if len(bufs) == 4:  # int8: dequantise the block, then the same
             _, seg_t = _segments(self.num_kv_heads, self.head_dim)
             k_all, v_all = _load_block(bufs, slot, seg_t)
@@ -806,18 +871,20 @@ class Mxu(_Inner):
             q = q_ref[:, t].reshape(m, W) if tall else q_ref[sub, t]
             if q.dtype != k.dtype:
                 q, k = q.astype(jnp.float32), k.astype(jnp.float32)
-            sc = jnp.where(valid, _dot(q, k, _NT) * scale, NEG_INF)
+            sc = _where(valid, lax.mul(_dot(q, k, _NT), scale), NEG_INF)
             m_prev = get(m_i, t)  # [M, 128], lane-replicated
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            m_new = lax.max(m_prev, _rows(lax.reduce_max, sc))
             # Rows outside the span, and blocks wholly beyond a row's
             # causal frontier, leave every score at NEG_INF: guard the
             # exps so the no-op update stays a no-op.
-            alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
-                              jnp.exp(m_prev - m_new))
-            p = jnp.where(valid, jnp.exp(sc - _lane_fit(m_new, blk)), 0.0)
-            put(l_i, t, get(l_i, t) * alpha
-                + jnp.sum(p, axis=1, keepdims=True))
-            put(acc, t, get(acc, t) * _lane_fit(alpha, W) + self._pv(p, v))
+            alpha = _where(lax.le(m_prev, NEG_INF / 2), 0.0,
+                           lax.exp(lax.sub(m_prev, m_new)))
+            p = _where(valid,
+                       lax.exp(lax.sub(sc, _lane_fit(m_new, blk))), 0.0)
+            put(l_i, t, lax.add(lax.mul(get(l_i, t), alpha),
+                                _rows(lax.reduce_sum, p)))
+            put(acc, t, lax.add(lax.mul(get(acc, t), _lane_fit(alpha, W)),
+                                self._pv(p, v)))
             put(m_i, t, m_new)
 
         if tall and self.tiles > TALL_UNROLL:
@@ -830,5 +897,5 @@ class Mxu(_Inner):
     def finish(self, o_ref, state):
         acc, _, l_i = state
         for t in range(self.tiles):  # every tile of the program at once
-            denom = _lane_fit(jnp.maximum(l_i[t], 1e-20), self.width)
-            o_ref[:, t] = (acc[t] / denom).astype(o_ref.dtype)
+            denom = _lane_fit(lax.max(l_i[t], 1e-20), self.width)
+            o_ref[:, t] = lax.div(acc[t], denom).astype(o_ref.dtype)

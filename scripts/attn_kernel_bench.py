@@ -2,12 +2,13 @@
 and head shape, on the chip: `chiprun -- python scripts/attn_kernel_bench.py`.
 
 For each published head shape (H, Hk, hd) — the sixth, (16, 2, 256), is
-Qwen3-Next's gated attention: two lane tiles a kv head — and each inner
-product a kernel can be built with (`ops/pallas/kv_contract.py`: the decode
-kernel takes either, the ragged kernel has one) it builds a bf16 pool at the
-CLI's defaults
-(1024 pages of 32 tokens, 64 slots) with 64 sequences of 200-380 tokens
-of context, checks the kernel against the jnp reference, and times
+Qwen3-Next's gated attention: two lane tiles a kv head; the seventh and
+eighth, (64, 8, 128) and (20, 4, 128), are K-EXAONE's and Falcon-H1's — and
+each inner product a kernel can be built with (`ops/pallas/kv_contract.py`:
+the decode kernel takes either, the ragged kernel has one) it builds a bf16
+pool at the CLI's defaults (1024 pages of 32 tokens, 64 slots) with 64
+sequences of 200-380 tokens of context, checks the kernel against the jnp
+reference, and times
 LAUNCHES calls chained inside one jit (each call's q is the last one's
 output, so they cannot overlap). Traffic: "decode" = the decode kernel,
 64 rows; "ragged64" = the ragged kernel on the same 64 decode rows (a
@@ -61,14 +62,16 @@ constant alone skips the absorbed row).
 
 A variant of a kernel's body is measured here before a whole cell: a
 module constant of the four kernel modules that this script sets
-(`--set kv_contract.TALL_UNROLL=8`, `--set paged_attention.RING=16,
-ragged_attention.RING=16`), `jax.clear_caches()`, one more row a (shape,
-traffic); a variant that needs code gets a constant that lives for that
-run (PR 34 timed the successor walk and the lane-tile loop each unrolled
-in Python and as a loop in the program so, PR 38 the page stream with a
-predicate a page and a block, PR 40 the latent kernel with its DMAs, its
-softmax, its `acc` update and its contractions each taken out; the results
-are in `kv_contract.py`'s and `mla_attention.py`'s docstrings).
+(`--set kv_contract.TALL_UNROLL=2` — the tall trip's lane tiles rolled
+beyond two, as they were served until PR 61 —, `--set
+paged_attention.RING=16,ragged_attention.RING=16`), `jax.clear_caches()`,
+one more row a (shape, traffic); a variant that needs code gets a constant
+that lives for that run (PR 34 timed the successor walk and the lane-tile
+loop each unrolled in Python and as a loop in the program so, PR 38 the
+page stream with a predicate a page and a block, PR 40 the latent kernel
+with its DMAs, its softmax, its `acc` update and its contractions each taken
+out, PR 61 the tall trip's eight lane tiles as two trips of four; the
+results are in `kv_contract.py`'s and `mla_attention.py`'s docstrings).
 """
 
 from __future__ import annotations
@@ -99,7 +102,7 @@ MODULES = {m.__name__.rsplit(".", 1)[1]: m
            for m in (kv_contract, mla_attention, paged_attention,
                      ragged_attention)}
 SHAPES = ((28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
-          (30, 30, 128), (16, 2, 256), (64, 8, 128))
+          (30, 30, 128), (16, 2, 256), (64, 8, 128), (20, 4, 128))
 B, MP, PS, NP, LAYER = 64, 256, 32, 1024, 1
 # The jnp reference gathers a table's whole width, [B, width*PS, lanes]:
 # it is fed the columns a context here can reach and no more.

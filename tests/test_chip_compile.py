@@ -76,13 +76,16 @@ def test_published_head_shapes_compile_for_v5e(v5e, compile_fn, tp, heads):
 # tiles merged along M for the tall trip (`q_ref[:, t]` and the state's
 # `[subs, Mp, .]` reshaped to `[subs * Mp, .]`, free where Mp is a multiple
 # of 16), a tile's state at a DYNAMIC index of the scratch in the other
-# body, scores of `[512, 128]` float32 and P's three terms in VMEM — at the
+# body, scores of `[512, 128]` float32 in VMEM — at the
 # widest block (30 lane tiles) and the widest tile (256 lanes) a cell has,
-# one chip and under the 4-way shard_map.
+# one chip and under the 4-way shard_map; and (PR 61) the tall trip's lane
+# tiles as straight-line code at the most there are of them, K-EXAONE's
+# eight, and at Falcon-H1's four.
 @pytest.mark.parametrize("tp,heads", [
     (1, (28, 4, 128)), (1, (8, 2, 128)), (1, (16, 16, 128)),
     (1, (32, 8, 64)), (1, (30, 30, 128)), (1, (16, 2, 256)),
-    (4, (28, 4, 128)), (4, (32, 8, 128))],
+    (4, (28, 4, 128)), (4, (32, 8, 128)),
+    (1, (64, 8, 128)), (1, (20, 4, 128))],
     ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else f"tp{v}")
 def test_tall_rung_compiles_for_v5e(v5e, tp, heads):
     _compile_at(v5e, _compile_ragged, tp, heads, 512)

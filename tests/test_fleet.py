@@ -169,17 +169,21 @@ def test_failover_byte_identity_fuzz(prefix_cache, spec):
         "pack my box with five dozen mugs",
         "the cat sat on the mat the cat",
     ]
+    # 40 tokens a stream: a crashed loop ends after its CURRENT iteration,
+    # which may be a fused scan of eight steps — streams of 12 tokens, the
+    # victim picked at 2, could all finish inside it, and then nothing is
+    # left to eject or to migrate (red in one run of two until PR 61).
     golden = _tpu_fleet(n=1, **over)
     try:
         gtexts = [_text(collect(_run(golden, f"u{i % 3}", p,
-                                     max_tokens=12)))
+                                     max_tokens=40)))
                   for i, p in enumerate(prompts)]
     finally:
         golden.stop()
 
     router = _tpu_fleet(n=2, **over)
     try:
-        reqs = [_run(router, f"u{i % 3}", p, max_tokens=12)
+        reqs = [_run(router, f"u{i % 3}", p, max_tokens=40)
                 for i, p in enumerate(prompts)]
         # Wait for real mid-stream state (some tokens emitted), then
         # kill whichever member is serving the most streams.

@@ -207,7 +207,8 @@ def _walk(jaxpr):
         yield from _inside(eqn)
 
 
-def _kernel_jaxpr(H, Hk, hd, T=64, B=64, PS=32, MP=8):
+def _kernel_jaxpr(H, Hk, hd, T=64, B=64, PS=32, MP=8,
+                  launch=ragged_paged_attention_pallas):
     """The ragged kernel's traced body at one head shape. Shapes only:
     nothing runs."""
     def s(*shape, dt=jnp.int32):
@@ -215,7 +216,7 @@ def _kernel_jaxpr(H, Hk, hd, T=64, B=64, PS=32, MP=8):
 
     pool = s(2, (MP * 4 + 2) * PS, Hk * hd, dt=jnp.bfloat16)
     closed = jax.make_jaxpr(
-        lambda *a: ragged_paged_attention_pallas(*a, PS))(
+        lambda *a: launch(*a, PS))(
             s(T, H, hd, dt=jnp.bfloat16), pool, pool, s(), s(B, MP), s(B),
             s(B), s(B))
     calls = [e for e in _walk(closed.jaxpr)
@@ -232,6 +233,12 @@ BODY_SHAPES = ((28, 4, 128),     # 4 lane tiles
                (16, 16, 128),    # 16
                (32, 8, 64),      # 4, two heads each
                (30, 30, 128))    # 30
+
+
+def _tiles(H, Hk, hd):
+    return kv_contract.make_inner("mxu", rows=8, group=H // Hk,
+                                  num_kv_heads=Hk, head_dim=hd,
+                                  page_size=32).tiles
 
 
 def test_traced_body_does_not_grow_with_tile_height_times_kv_heads():
@@ -256,11 +263,20 @@ def test_traced_body_does_not_grow_with_tile_height_times_kv_heads():
     # none of them a nested jit (`kv_contract.py`: scalars are `lax`
     # calls, because every operator on a tracer is one). PR 48: 446, 1142,
     # 446, 1954. PR 59 (P into P·V once, not as three terms: 13 a lane
-    # tile): 394, 934, 394, 1564.
-    assert len(many) <= 3 * len(few)
-    assert len(few) <= 420 and len(packed) <= 420
-    assert len(many) <= 1000 and len(widest) <= 1650
-    assert _count(few, "pjit") == 0
+    # tile): 394, 934, 394, 1564. PR 61 (the inner product's vector code
+    # as `lax` calls: a `jnp.where` was a `jit` equation around a weak
+    # scalar's `convert_element_type`, its broadcast and the `select_n` —
+    # the last two are what is left, 6 equations fewer a lane tile and
+    # every other primitive in its place): 369, 837, 369, 1383 — 39 a lane
+    # tile and 213 of everything else.
+    assert [len(e) for e in (few, many, packed, widest)] == [
+        213 + 39 * _tiles(*shape) for shape in BODY_SHAPES]
+    for body in (few, many, packed, widest):  # "pjit" until jax 0.7
+        assert _count(body, "jit") == _count(body, "pjit") == 0
+
+
+TALL_BODY_SHAPES = BODY_SHAPES + ((64, 8, 128),   # 8 lane tiles
+                                  (16, 2, 256))   # 2, of 256 lanes
 
 
 def test_a_tall_rung_holds_two_bodies_and_no_more():
@@ -268,28 +284,60 @@ def test_a_tall_rung_holds_two_bodies_and_no_more():
     more traces the tall body beside the tile's, for the program whose
     stretch of the stream is one span's — and still nothing a successor, a
     tile of the program or a block: the program's tiles are a loop in the
-    program. The tall trip's lane tiles are a loop in the program too
-    beyond `TALL_UNROLL` of them (ONE more copy of the inner product at
-    any width: what a rung costs every start does not grow with the kv
-    heads again), and unrolled up to it: one more copy a lane tile. When
-    written: 770, 1466, 770 and 2278 equations — 324 more than the short
-    rung's at any width — and 715 for 334 at (16, 2, 256), two tiles."""
-    short = [list(_walk(_kernel_jaxpr(*shape))) for shape in BODY_SHAPES]
+    program. The tall trip's lane tiles are straight-line code up to
+    `TALL_UNROLL` = 8 of them (PR 61: one more copy of the inner product a
+    lane tile, 40 equations each since a copy is `lax` calls — 420 more
+    than the short rung's at four tiles, 580 at eight, 342 at two of 256
+    lanes) and a loop in the program beyond (ONE more copy at any width,
+    304 equations: what a rung costs every start does not grow with the
+    kv heads of an MHA model again)."""
+    assert kv_contract.TALL_UNROLL == 8
+    short = [list(_walk(_kernel_jaxpr(*shape))) for shape in TALL_BODY_SHAPES]
     tall = [list(_walk(_kernel_jaxpr(*shape, T=2 * TALL)))
-            for shape in BODY_SHAPES]
+            for shape in TALL_BODY_SHAPES]
+    tiles = [_tiles(*shape) for shape in TALL_BODY_SHAPES]
+    assert tiles == [4, 16, 4, 30, 8, 2]
+    unrolled = [n if n <= kv_contract.TALL_UNROLL else 1 for n in tiles]
     assert [_count(two, "dot_general") - _count(one, "dot_general")
-            for one, two in zip(short, tall)] == [2, 2, 2, 2]
-    for one, two in zip(short, tall):
-        assert len(two) <= len(one) + 350
-        assert _count(two, "pjit") == 0
-    one, two = (list(_walk(_kernel_jaxpr(16, 2, 256, T=T)))
-                for T in (TALL, 2 * TALL))
-    assert kv_contract.TALL_UNROLL == 2  # (16, 2, 256): unrolled
-    assert _count(one, "dot_general") == 4 and _count(two, "dot_general") == 8
-    assert len(one) <= 350 and len(two) <= len(one) + 400
+            for one, two in zip(short, tall)] == [2 * n for n in unrolled]
+    for one, two, n in zip(short, tall, unrolled):
+        assert len(two) <= len(one) + 270 + 40 * n
+        assert _count(two, "jit") == _count(two, "pjit") == 0
     # ...whatever the rung: the programs are a grid, not a trace.
     assert len(list(_walk(_kernel_jaxpr(*BODY_SHAPES[0], T=512)))) == len(
         tall[0])
+
+
+@pytest.mark.parametrize("shape", [(64, 8, 128), (28, 4, 128),
+                                   (16, 16, 128)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_tracing_a_tall_rung_binds_no_nested_jit_a_lane_tile(shape,
+                                                             monkeypatch):
+    """What a rung's trace is charged for is its nested jits
+    (`kv_contract.py`: on a tracer every operator and every `jnp.where` /
+    `maximum` / `exp` / `sum` is one, ~1-2 ms each inside a serving
+    process), and until PR 61 a copy of the inner product bound ~19 of them
+    and `finish` 4 a lane tile: 209 a trace at (64, 8, 128), 137 at (28, 4,
+    128), 353 at (16, 16, 128), and the unrolled tall trip would have added
+    19 a tile. Counted without a chip: the binds of `jit` while the launch
+    is traced, wrapper and both bodies — 7 to 23 now at any width (the
+    wrapper's own `pad`, `searchsorted` and the packing's operators; fewer
+    where jax's caches already hold their traces), under a ceiling that a
+    single copy written with operators breaks."""
+    from jax.extend.core.primitives import jit_p
+
+    binds, bind = [], jit_p.bind
+
+    def counted(*args, **params):
+        binds.append(params.get("name"))
+        return bind(*args, **params)
+
+    monkeypatch.setattr(jit_p, "bind", counted)
+    # The launch's own function, not its jit: a trace that another case
+    # left in the cache would bind nothing.
+    _kernel_jaxpr(*shape, T=2 * TALL,
+                  launch=ragged_paged_attention_pallas.__wrapped__)
+    assert 0 < len(binds) <= 30, sorted(binds)
 
 
 def _stream_of_one_walk(walk_body):

@@ -182,18 +182,29 @@ _WINDOW = 128
 _RING_ROWS = _WINDOW + 512 + 32
 
 
-@pytest.mark.parametrize("window", [0, _WINDOW], ids=["full", "window"])
-def test_claimed_shape_keeps_two_roundings_and_no_bias(window):
-    q, k, v, pt, tok_seq, tok_pos, kv_len, qs, ql, PS = _case(**_CLAIMED)
+def _claimed(window):
+    """`_CLAIMED`'s batch as the full layer's launch reads it, or a window
+    layer's — K and V in per-slot rings whose rows hold seeded values:
+    (the case, k, v, the page table, each row's first listed position)."""
+    case = _case(**_CLAIMED)
+    q, k, v, pt, _, _, kv_len, _, ql, PS = case
     T, B, base = q.shape[0], ql.shape[0], None
-    assert tall_tokens([n for n, _ in _CLAIMED["spans"]], T) == 7 * TALL
-    if window:  # the rings' rows hold what the pool's did: seeded values
+    if window:
         rng = np.random.default_rng(65)
         k, v = (jnp.asarray(rng.standard_normal(
             (LAYERS, (B + 1) * _RING_ROWS, k.shape[-1])), k.dtype)
             for _ in range(2))
         pt, base = ring_table(jnp.arange(B, dtype=jnp.int32), kv_len, ql,
                               window, _RING_ROWS, PS, T)
+    return case, k, v, pt, base
+
+
+@pytest.mark.parametrize("window", [0, _WINDOW], ids=["full", "window"])
+def test_claimed_shape_keeps_two_roundings_and_no_bias(window):
+    case, k, v, pt, base = _claimed(window)
+    q, _, _, _, tok_seq, tok_pos, kv_len, qs, ql, PS = case
+    T = q.shape[0]
+    assert tall_tokens([n for n, _ in _CLAIMED["spans"]], T) == 7 * TALL
     v = (_f32(v) + 2).astype(v.dtype)
     out = ragged_paged_attention_pallas(q, k, v, 1, pt, qs, ql, kv_len, PS,
                                         interpret=True, window=window,
@@ -212,3 +223,33 @@ def test_claimed_shape_keeps_two_roundings_and_no_bias(window):
 
     ref, bound = assert_kernel_close(out, q.dtype, v, twin)
     assert abs((np.asarray(_f32(out)) - ref).mean()) < bound.mean() / 10
+
+
+@pytest.mark.parametrize("launch", ["64x8x128-full", "64x8x128-window",
+                                    "28x4x128"])
+def test_unrolled_lane_tiles_keep_every_bit_of_the_rolled_trip(launch,
+                                                               monkeypatch):
+    """The tall trip's lane tiles as straight-line code (PR 61: up to
+    `TALL_UNROLL` = 8 of them) against the same tiles as a loop in the
+    program, which is what they were beyond two: per tile the arithmetic is
+    the same in the same order, tile t's state is tile t's alone — the
+    launches are equal TO THE BIT, at K-EXAONE's eight tiles through both
+    its launches and at Qwen2.5-7B's four. The rolled launch is the
+    function under the jit, so no cache keeps a program traced under the
+    patched constant."""
+    window, base = _WINDOW if "window" in launch else 0, None
+    if launch == "28x4x128":
+        case = _case(**_tall_case("H28-Hk4-hd128-bf16")[0])
+        k, v, pt = case[1:4]
+    else:
+        case, k, v, pt, base = _claimed(window)
+    q, _, _, _, _, _, kv_len, qs, ql, PS = case
+    assert 2 < k.shape[-1] // 128 <= kv_contract.TALL_UNROLL
+    assert q.dtype == jnp.bfloat16
+    args = (q, k, v, 1, pt, qs, ql, kv_len, PS)
+    kw = dict(interpret=True, window=window, pos_base=base)
+    unrolled = ragged_paged_attention_pallas(*args, **kw)
+    monkeypatch.setattr(kv_contract, "TALL_UNROLL", 2)
+    rolled = ragged_paged_attention_pallas.__wrapped__(*args, **kw)
+    np.testing.assert_array_equal(np.asarray(_f32(unrolled)),
+                                  np.asarray(_f32(rolled)))
