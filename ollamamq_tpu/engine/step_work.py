@@ -22,6 +22,7 @@ import numpy as np
 from ollamamq_tpu.config import (CONV, EXPERTS, LINEAR, MAMBA, PARALLEL,
                                  SPARSE, ModelConfig)
 from ollamamq_tpu.ops.attention import ring_first_page
+from ollamamq_tpu.ops.gated_delta import CHUNK
 from ollamamq_tpu.telemetry import schema as tm
 
 
@@ -79,13 +80,20 @@ def slot_state_counts(cfg, page_size, s: Step) -> tuple:
     zero (a request's first span), rows that read the state an earlier step
     left (a later chunk, a decode row; a scan's active slots), then how the
     recurrence ran: row-passes through the one-token form (1-token rows; a
-    scan's active slots x its passes) and tokens of longer spans, through
-    the chunked form. A model with conv layers only keeps the first two."""
+    scan's active slots x its passes), tokens of longer spans, through the
+    chunked form, and the (row, window) pairs that form runs over them, a
+    layer's worth — a span of n > 1 tokens from stream token s touches
+    windows s // CHUNK .. (s + n - 1) // CHUNK (ops/gated_delta.ragged: on
+    the chip, the programs a head block of `chunk_rule_pallas`). A kind
+    keeps as many of the five as it has fields: conv layers the first two."""
     carried = len(s.tokens) - s.opened
     if s.scan:
-        return s.opened, carried, sum(s.tokens), 0
-    return (s.opened, carried, sum(n == 1 for n in s.tokens),
-            sum(n for n in s.tokens if n > 1))
+        return s.opened, carried, sum(s.tokens), 0, 0
+    n = np.asarray(s.tokens, np.int64)
+    at = np.cumsum(n) - n  # each row's first stream token
+    pairs = ((at + n - 1) // CHUNK - at // CHUNK + 1)[n > 1]
+    return (s.opened, carried, int((n == 1).sum()), int(n[n > 1].sum()),
+            int(pairs.sum()))
 
 
 def latent_counts(cfg, page_size, s: Step) -> tuple:
@@ -206,11 +214,14 @@ class Kind(NamedTuple):
 
 
 def _recurrent(kind: str, prefix: str, *series) -> Kind:
-    """The four of `slot_state_counts` under a recurrence's own names."""
+    """`slot_state_counts` under a recurrence's own names, a field a series
+    given (None: a field without one): the first four, and `chunk_pairs`
+    for a recurrence whose spans go through `gated_delta.ragged`."""
+    fields = ("state_resets", "state_carried", "step_rows", "span_tokens",
+              "chunk_pairs")[:len(series)]
     return Kind(lambda cfg: cfg.count(kind),
-                tuple(f"{prefix}_{f}" for f in (
-                    "state_resets", "state_carried", "step_rows",
-                    "span_tokens")), series, slot_state_counts)
+                tuple(f"{prefix}_{f}" for f in fields), series,
+                slot_state_counts)
 
 
 # In the order a sample carries them.
@@ -221,17 +232,18 @@ KINDS = {
                  slot_state_counts),
     "lin": _recurrent(
         LINEAR, "lin", tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
-        tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL)._replace(
+        tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL,
+        tm.LIN_CHUNK_PAIRS_TOTAL)._replace(
             present=lambda cfg: cfg.count(LINEAR) and not cfg.lightning_nh),
     # (the linear kind's other reading: a model has one of the two)
     "lightning": _recurrent(
         LINEAR, "lightning", None, None, tm.LIGHTNING_STEP_ROWS_TOTAL,
-        tm.LIGHTNING_SPAN_TOKENS_TOTAL)._replace(
+        tm.LIGHTNING_SPAN_TOKENS_TOTAL, None)._replace(
             present=lambda cfg: cfg.count(LINEAR) and cfg.lightning_nh),
     "ssm": _recurrent(
         PARALLEL, "ssm", tm.SSM_STATE_RESETS_TOTAL,
         tm.SSM_STATE_CARRIED_TOTAL, tm.SSM_STEP_ROWS_TOTAL,
-        tm.SSM_SPAN_TOKENS_TOTAL),
+        tm.SSM_SPAN_TOKENS_TOTAL, None),
     "s6": _recurrent(
         MAMBA, "s6", tm.S6_STATE_RESETS_TOTAL, tm.S6_STATE_CARRIED_TOTAL,
         tm.S6_STEP_ROWS_TOTAL, tm.S6_SPAN_TOKENS_TOTAL),

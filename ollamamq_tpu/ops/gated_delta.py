@@ -33,7 +33,10 @@ Schedules (as ops/shortconv.py's):
     [rows, T] layout), and a loop whose trip count is the number of (row,
     window) pairs the step's spans really touch carries each row's state
     through its windows (`_apply`): a 200-token span costs 4 or 5 trips
-    whatever else is in the stream, a decode row costs none.
+    whatever else is in the stream, a decode row costs none. On the chip
+    the pairs are one Pallas kernel a layer that keeps a row's state in
+    VMEM across its windows (ops/pallas/chunk_rule.py, at every shape its
+    `blocks` takes); the loop is its definition and the CPU's path.
   - `decode`: one token a slot (the fused scan's body): active slots
     advance, parked slots keep their state.
 
@@ -323,7 +326,12 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
     q, k [T, Hk, dk], v [T, H, dv], g, beta [T, H]; state the whole carried
     array, `layer` this layer's index in it; slot_ids, q_start, q_len,
     is_first [B] per row; tok_seq, tok_pos [T] each token's row and
-    position (-1: padding). Returns (o [T, H, dv] float32, state')."""
+    position (-1: padding). The rows lie in stream order — `q_start` does
+    not decrease with the row index, as the engine lays a step out
+    (tests/test_olmo_hybrid.py holds it to that): the pair loop does not
+    need it, the pair kernel does (its output block of a window is fetched
+    once, so a window it came back to would lose the earlier row's
+    tokens). Returns (o [T, H, dv] float32, state')."""
     t = q.shape[0]
     h, dv = v.shape[-2:]
     single, multi = q_len == 1, q_len > 1
@@ -355,10 +363,13 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
     n_w = jnp.where(multi, (q_start + q_len - 1) // CHUNK - first_w + 1, 0)
     ends = jnp.cumsum(n_w)
 
+    def of_pair(p):  # (its row, its window)
+        b = jnp.sum(ends <= p).astype(jnp.int32)
+        return b, first_w[b] + p - (ends[b] - n_w[b])
+
     def pair(p, carry):
         out, state = carry
-        b = jnp.sum(ends <= p).astype(jnp.int32)
-        w = first_w[b] + p - (ends[b] - n_w[b])
+        b, w = of_pair(p)
         ci = {name: jax.lax.dynamic_index_in_dim(x, w, 0, keepdims=False)
               for name, x in c.items()}
         member = jax.lax.dynamic_index_in_dim(row_of, w, 0,
@@ -374,9 +385,34 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
             state, _from_heads(s)[None, None], (layer, slot_ids[b], 0, 0))
         return out, state
 
-    out, state = jax.lax.fori_loop(
-        0, ends[-1], pair, (jnp.zeros((n, h, CHUNK, dv), _F32), state))
-    o = jnp.moveaxis(out, 1, 2).reshape(n * CHUNK, h, dv)[:t]
+    kernel = None  # the pairs' Pallas kernel, at the shapes it takes
+    if impl == "pallas":
+        from ollamamq_tpu.ops.pallas import chunk_rule
+
+        kernel = chunk_rule.blocks(h, q.shape[-1], dv, plain) and chunk_rule
+    if kernel:
+        # ONE launch over the pairs, a row's state in VMEM across its
+        # windows (`pair` is its definition): each pair's row, window and
+        # slot, past the last pair the last one's again — no pair: the
+        # trash row —, and what the pair does to the state.
+        some = ends[-1] > 0
+        b, w = jax.vmap(of_pair)(jnp.minimum(
+            jnp.arange(kernel.pair_bound(n, q_len.shape[0], t)),
+            jnp.maximum(ends[-1] - 1, 0)))
+        flags = (w == first_w[b]) * (
+            kernel.FIRST + kernel.OPENS * (is_first[b] > 0))
+        out, state = kernel.chunk_rule_pallas(
+            state, layer, jnp.where(some, slot_ids[b], state.shape[1] - 1),
+            jnp.where(some, w, 0), jnp.where(some, b, -2), flags, ends[-1],
+            row_of, c, interpret=interpret)
+        # [n, C, H * dv], the stream's own layout; tokens of no span hold
+        # what the kernel's buffers held
+        o = jnp.where(part[:, None, None],
+                      out.reshape(n * CHUNK, h, dv)[:t], 0.0)
+    else:
+        out, state = jax.lax.fori_loop(
+            0, ends[-1], pair, (jnp.zeros((n, h, CHUNK, dv), _F32), state))
+        o = jnp.moveaxis(out, 1, 2).reshape(n * CHUNK, h, dv)[:t]
     # a one-token row's output at its stream position (others: dropped)
     o = o.at[jnp.where(single, q_start, t)].set(o_rows, mode="drop")
     return o, state

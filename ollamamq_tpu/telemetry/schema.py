@@ -304,6 +304,13 @@ LIN_SPAN_TOKENS_TOTAL = REGISTRY.counter(
     "Tokens of spans longer than one token that went through the delta "
     "rule's chunked form (the state read and written once a 64-token "
     "window a span touches)", labels=("model",))
+LIN_CHUNK_PAIRS_TOTAL = REGISTRY.counter(
+    "ollamamq_lin_chunk_pairs_total",
+    "(Row, 64-token window) pairs the delta rule's chunked form ran over "
+    "the longer spans of launched steps, a linear layer's worth: a span of "
+    "n > 1 tokens from stream token s touches (s + n - 1) // 64 - s // 64 "
+    "+ 1 windows (on a TPU, the programs a head block of one "
+    "chunk_rule_pallas launch)", labels=("model",))
 HBM_SSM_STATE_BYTES = REGISTRY.gauge(
     "ollamamq_hbm_ssm_state_bytes",
     "Bytes the state-space mixers' per-slot recurrent state occupies per "

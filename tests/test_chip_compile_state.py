@@ -130,6 +130,74 @@ def test_olmo_hybrid_width_step_programs_carry_the_rule_state_in_place(
     assert mem.temp_size_in_bytes < rule // 10, mem
 
 
+# (H, Hk, dk, dv, plain, slots, layers) of the four callers of
+# `gated_delta.ragged` at their published widths and their cells' slots.
+CHUNK_RULE_SHAPES = {
+    "qwen3_next": (32, 16, 128, 128, False, 16, 9),
+    "olmo_hybrid": (30, 30, 96, 192, False, 64, 12),
+    "falcon_h1": (32, 2, 256, 128, True, 64, 6),
+    "minicpm_sala": (32, 32, 128, 128, True, 16, 12),
+}
+
+
+@pytest.mark.parametrize("model", CHUNK_RULE_SHAPES)
+def test_the_chunked_rules_pair_kernel_compiles_at_the_published_shapes(
+        v5e, model):
+    """`gated_delta.ragged` of a 512-token stream on the Pallas path (PR 62),
+    at each caller's heads and widths: the (row, window) pairs are ONE
+    `chunk_rule_pallas` custom call — fp32 contract precision, a head's
+    lanes no whole tile (Olmo-Hybrid), a 256-row state (Falcon-H1) and all —
+    beside the one-token rows' kernel, no `while` is left in the program,
+    and the carried state comes back aliased: the kernel updates it in
+    place."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ollamamq_tpu.ops import gated_delta
+
+    h, hk, dk, dv, plain, slots, layers = CHUNK_RULE_SHAPES[model]
+    t, one = 512, SingleDeviceSharding(v5e.devices[0])
+
+    def s(*shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    i32 = dict(dt=jnp.int32)
+    state = s(layers, slots + 1, dk, h * dv)
+    compiled = jax.jit(
+        lambda *a: gated_delta.ragged(*a, impl="pallas", plain=plain),
+        donate_argnums=5).lower(
+            s(t, hk, dk), s(t, hk, dk), s(t, h, dv), s(t, h), s(t, h), state,
+            s(**i32), s(slots, **i32), s(t, **i32), s(t, **i32),
+            s(slots, **i32), s(slots, **i32), s(slots, **i32)).compile()
+    text = compiled.as_text()
+    assert "chunk_rule_pallas" in text and " while(" not in text
+    assert text.count("tpu_custom_call") == 2  # the rows', the pairs'
+    mem = compiled.memory_analysis()
+    held = layers * (slots + 1) * dk * h * dv * 4
+    assert mem.alias_size_in_bytes >= held, (mem, held)
+    assert mem.temp_size_in_bytes < held // 10, mem
+
+
+def test_qwen3_nexts_ragged_rung_runs_the_pairs_in_the_kernel(v5e):
+    """The 512-token ragged step of `qwen3-next-80b-a3b-ep4-d12`'s file as
+    the engine builds it, lowered (no compile): its linear layers' rule
+    stage (`lin_rule`) holds `chunk_rule_pallas` and no `while` — the pair
+    loop that was 72 trips of ~27 fusions a step is not in the program."""
+    from chip_compile import _file_model, _script
+
+    script = _script()
+    cfg, mc = _file_model("qwen3-next-80b-a3b-ep4-d12")
+    from benchmarks import serve
+    from ollamamq_tpu import cli
+
+    flags = cli.build_parser().parse_args(
+        ["--models", cfg["name"]] + serve.server_flags(cfg, False))
+    lowered, _ = script.step_programs(mc, flags, v5e, 512)
+    text = lowered["mq_ragged_step"].as_text(debug_info=True)
+    assert "chunk_rule_pallas" in text
+    assert re.search(r'"lin_rule/[^"]*"', text)  # the stage is named there
+    assert not re.search(r'"lin_rule/[^"]*while', text)
+
+
 @pytest.mark.parametrize("cfg", [LOOP_CFG, LFM2_CFG, OLMO_HYBRID_CFG],
                          ids=["dense", "lfm2", "olmo_hybrid"])
 def test_ragged_step_is_fed_one_host_array(v5e, cfg):

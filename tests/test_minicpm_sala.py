@@ -492,7 +492,9 @@ def test_the_counters_follow_from_a_steps_composition():
     scan = step_work.Step([8, 8], [9007, 100], None, True, 0, 0, None)
     got = step_work.bsa_counts(big, 32, scan)
     assert got[:6] == (8 * 141, 0, 8 * 64, 0, 8 * 64, 0) and got[6] == 8
-    assert step_work.slot_state_counts(big, 32, scan) == (0, 2, 16, 0)
+    assert step_work.slot_state_counts(big, 32, scan) == (0, 2, 16, 0, 0)
+    # the span lies on stream tokens 1 .. 496: windows 0 .. 7 of the stream
+    assert step_work.slot_state_counts(big, 32, ragged) == (0, 3, 2, 496, 8)
 
 
 # ------------------------------------------------- the engine, by id stream

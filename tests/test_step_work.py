@@ -72,8 +72,9 @@ def test_note_returns_the_tokens_that_stopped_below_the_exit_layer():
     assert skipped == noted["exit_skipped_tokens"] == 0
     assert noted["xattn_rows"] == 8
     # the per-slot state: one row opened, two carried; a 1-token row through
-    # the step form, 21 tokens of spans through the chunked one
+    # the step form, 21 tokens of spans through the chunked one — two spans
+    # inside the stream's first 64-token window: two (row, window) pairs
     noted, _ = _noted("test-tiny-olmo-hybrid", scan=False)
-    assert [noted[f] for f in KINDS["lin"].fields] == [1, 2, 1, 21]
+    assert [noted[f] for f in KINDS["lin"].fields] == [1, 2, 1, 21, 2]
     noted, _ = _noted("test-tiny-lfm2", scan=True)
     assert (noted["conv_state_resets"], noted["conv_state_carried"]) == (0, 2)
