@@ -669,14 +669,23 @@ def main(argv=None) -> int:
     if quant_err is not None:
         log.error("%s", quant_err)
         return 2
-    from ollamamq_tpu.config import get_model_config, validate_latent_pool
+    from ollamamq_tpu.config import (get_model_config, validate_latent_pool,
+                                     validate_slot_state)
 
     for name in (m.strip() for m in args.models.split(",") if m.strip()):
         served = get_model_config(name)
+        shape = {"tensor": args.tp, "expert": args.ep}
         latent_err = served and validate_latent_pool(
             served, kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype,
-            prefix_cache=args.prefix_cache,
-            mesh_shape={"tensor": args.tp, "expert": args.ep})
+            prefix_cache=args.prefix_cache, mesh_shape=shape)
+        # ...and, where latent attention is a layer KIND beside layers that
+        # keep a per-slot state, what that state cannot be served with (the
+        # runtime raises the same line for every such model; here it ends
+        # the start before any device work, as the pool's does).
+        if served and served.kv_lora_rank and not latent_err:
+            latent_err = validate_slot_state(
+                served, spec=args.spec, mesh_shape=shape,
+                kv_dtype=args.kv_dtype, prefix_cache=args.prefix_cache)
         if latent_err:
             log.error("%s", latent_err)
             return 2

@@ -216,7 +216,7 @@ class ModelConfig:
     # `qk_rope_head_dim` lanes for all heads; values are `v_head_dim` wide.
     # The paged cache then holds that row (`latent_dim` lanes) and no V.
     # The published spellings, so a configuration file's keys reach them.
-    q_lora_rank: int = 0
+    q_lora_rank: Optional[int] = 0  # 0 / null: a full-rank q projection, `wq`
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -426,10 +426,43 @@ class ModelConfig:
     layer_offset: int = 0
     mup_denominator: int = 32  # read, not used: a training-time constant
     rand_init: bool = False  # read, held to false
+    # -- Kimi Delta Attention beside NoPE latent attention (Kimi-Linear) -----
+    # The published `linear_attn_config` group of a configuration file, taken
+    # whole (as `rope_parameters`): `kda_layers` / `full_attn_layers` (the
+    # layers' 1-based indices: together every layer once) say what
+    # `layer_types` says — "linear_attention" and "full_attention" — either
+    # or both, agreeing; `num_heads`, `head_dim` and `short_conv_kernel_size`
+    # are the linear kind's heads (as many key as value heads), head sizes
+    # and taps. With the group the "linear_attention" layers are read as
+    # KIMI DELTA ATTENTION, the kind's third reading beside the gated delta
+    # rule's and lightning's: the rule of ops/gated_delta.py with a decay a
+    # KEY CHANNEL, g = -exp(A_log[h]) softplus(x W_fa W_fb + dt_bias) in
+    # R^{H x dk} (a low-rank projection through `head_dim` lanes), b =
+    # sigmoid(x W_b); q, k, v each through its own projection and depthwise
+    # convolution (held as one: depthwise over [q | k | v]); y = W_o
+    # [RMSNorm_head(o; w) * sigmoid(x W_ga W_gb)]. Beside them the
+    # "full_attention" layers are LATENT attention (`kv_lora_rank`), a layer
+    # KIND of the stack: the latent pool has a layer an attention layer.
+    linear_attn_config: Optional[object] = None
+    # Latent attention WITHOUT a position embedding (the published key): q's
+    # and the shared key's `qk_rope_head_dim` lanes are carried unrotated;
+    # `rope_theta` is read and unused.
+    mla_use_nope: bool = False
+    # The family's published spellings of `num_experts_per_tok`,
+    # `router_score`, `norm_topk_prob`, `n_group` and `max_seq_len` (either
+    # or both, agreeing), and `use_grouped_topk`: read, and held to `n_group`
+    # (true with `num_expert_group` 1 and `topk_group` 1 is the plain top k).
+    num_experts_per_token: Optional[int] = None
+    moe_router_activation_func: Optional[str] = None
+    moe_renormalize: Optional[bool] = None
+    num_expert_group: Optional[int] = None
+    use_grouped_topk: Optional[bool] = None
+    model_max_length: Optional[int] = None
 
     def __post_init__(self):
         self._derive_hybrid()
         self._derive_mixers()
+        self._derive_kda()
         if self.qk_norm not in (False, True, "head", "full"):
             raise ValueError(
                 f"{self.name}: qk_norm must be false, true, 'head' or "
@@ -535,9 +568,21 @@ class ModelConfig:
         """The `exaone_moe` spellings of the router's fields, folded into
         the program's."""
         for alias, field in (("num_shared_experts", "n_shared_experts"),
-                             ("scoring_func", "router_score")):
+                             ("scoring_func", "router_score"),
+                             ("moe_router_activation_func", "router_score"),
+                             ("num_experts_per_token", "num_experts_per_tok"),
+                             ("moe_renormalize", "norm_topk_prob"),
+                             ("num_expert_group", "n_group"),
+                             ("model_max_length", "max_seq_len")):
             value = getattr(self, alias)
             if value is None:
+                continue
+            if alias == "num_expert_group" and value == 1 \
+                    and self.n_group <= 1 and self.topk_group <= 1:
+                # one group that is always chosen: no group limit (and no
+                # other reading when the config is rebuilt from itself)
+                object.__setattr__(self, "n_group", 0)
+                object.__setattr__(self, "topk_group", 0)
                 continue
             default = type(self).__dataclass_fields__[field].default
             if getattr(self, field) not in (default, value):
@@ -549,6 +594,13 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: scoring_func must be 'softmax' or 'sigmoid', "
                 f"got {self.router_score!r}")
+        grouped, groups = (self.use_grouped_topk,
+                           self.num_expert_group or self.n_group)
+        if grouped is not None and bool(grouped) != (groups >= 1):
+            raise ValueError(
+                f"{self.name}: use_grouped_topk {grouped} with n_group "
+                f"(num_expert_group) {groups}: grouped selection and its "
+                "groups come together")
         if self.n_group == 1 and self.topk_group == 1:
             # one group that is always chosen: no group limit
             object.__setattr__(self, "n_group", 0)
@@ -620,37 +672,64 @@ class ModelConfig:
 
     def _check_latent(self) -> None:
         """What latent attention and its indexer cannot run with."""
-        widths = (self.q_lora_rank, self.qk_nope_head_dim,
-                  self.qk_rope_head_dim, self.v_head_dim)
+        if self.q_lora_rank is None:  # the published null: a full-rank q
+            object.__setattr__(self, "q_lora_rank", 0)
+        widths = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                  self.v_head_dim)
         has = (self.index_n_heads, self.index_head_dim, self.index_topk)
         if not self.kv_lora_rank:
-            if any(widths) or any(has):
+            if self.q_lora_rank or any(widths) or any(has) \
+                    or self.mla_use_nope:
                 raise ValueError(
-                    f"{self.name}: q_lora_rank, qk_*_head_dim, v_head_dim "
-                    "and index_* belong to latent attention: kv_lora_rank "
-                    "is 0")
+                    f"{self.name}: q_lora_rank, qk_*_head_dim, v_head_dim, "
+                    "mla_use_nope and index_* belong to latent attention: "
+                    "kv_lora_rank is 0")
             return
-        if min(widths) < 1 or self.qk_rope_head_dim % 2:
+        if min(widths) < 1 or self.q_lora_rank < 0 \
+                or self.qk_rope_head_dim % 2:
             raise ValueError(
-                f"{self.name}: latent attention needs q_lora_rank, "
-                "qk_nope_head_dim, v_head_dim of at least 1 and an even "
-                f"qk_rope_head_dim; got {widths}")
+                f"{self.name}: latent attention needs qk_nope_head_dim, "
+                "v_head_dim of at least 1, an even qk_rope_head_dim and a "
+                "q_lora_rank of at least 1 (0 / null: a full-rank q); got "
+                f"{widths + (self.q_lora_rank,)}")
         if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
             raise ValueError(
                 f"{self.name}: head_dim {self.head_dim} is not "
                 f"qk_nope_head_dim + qk_rope_head_dim")
+        kinds = set(self.layer_types or ())
+        if kinds and (kinds - {ATTENTION, LINEAR} or not self.kda):
+            raise ValueError(
+                f"{self.name}: layer_types {sorted(kinds)} with kv_lora_rank "
+                f"{self.kv_lora_rank}: latent attention is served in every "
+                f"layer, or as the {ATTENTION!r} layers of a stack whose "
+                f"other layers are {LINEAR!r} ones read as Kimi Delta "
+                "Attention (linear_attn_config) - beside no window, conv, "
+                "mixer, mamba, sparse or lightning layer")
+        if kinds and (any(has) or self.num_nextn_predict_layers):
+            raise ValueError(
+                f"{self.name}: index_* {has} / num_nextn_predict_layers "
+                f"{self.num_nextn_predict_layers} with layer_types: neither "
+                "an indexer nor a prediction module is served where latent "
+                "attention is a layer kind beside linear_attention layers")
         if self.num_kv_heads != self.num_heads or self.attn_bias \
-                or self.qk_norm or self.is_encoder or self.layer_types \
-                or self.norm_order != "pre" or self.rope_theta is None:
+                or self.qk_norm or self.is_encoder \
+                or self.norm_order != "pre" \
+                or (self.rope_theta is None and not self.mla_use_nope):
             raise ValueError(
                 f"{self.name}: latent attention is served with as many kv "
                 "heads as heads, no attention bias, no q/k head norm, "
-                "pre-norm, a rotary embedding and attention in every layer")
-        if any(has) and (min(has) < 1
+                "pre-norm and a rotary embedding (or mla_use_nope: none)")
+        if self.mla_use_nope and (any(has) or self.rope_scaling):
+            raise ValueError(
+                f"{self.name}: mla_use_nope with index_* {has} / "
+                "rope_scaling: the indexer's keys and YaRN's softmax scale "
+                "belong to a rotary embedding")
+        if any(has) and (min(has) < 1 or self.q_lora_rank < 1
                          or self.index_head_dim < self.qk_rope_head_dim):
             raise ValueError(
                 f"{self.name}: an indexer needs index_n_heads, index_topk of "
-                "at least 1 and index_head_dim of at least qk_rope_head_dim "
+                "at least 1, index_head_dim of at least qk_rope_head_dim "
+                "and a q_lora_rank (its q reads the normed q latent) "
                 f"(all three 0: no indexer); got {has}")
 
     def _check_sandwich_and_module(self) -> None:
@@ -781,6 +860,77 @@ class ModelConfig:
                     f"{self.name}: {alias} {getattr(self, alias)} is not "
                     f"{field} {getattr(self, field)}")
             object.__setattr__(self, field, value)
+
+    def _derive_kda(self) -> None:
+        """`linear_attn_config`: the published group folded into
+        `layer_types` and the linear kind's fields BEFORE the other checks
+        read them, refused on disagreement with key and value; and the
+        family's published `head_dim` (hidden_size / heads, which no
+        published module reads) folded into what latent attention's
+        `head_dim` is here: qk_nope_head_dim + qk_rope_head_dim."""
+        if self.linear_attn_config is None:
+            return
+        group = dict(self.linear_attn_config)  # a file's dict: hashable
+        keys = ("full_attn_layers", "head_dim", "kda_layers", "num_heads",
+                "short_conv_kernel_size")
+        if sorted(group) != list(keys):
+            raise ValueError(
+                f"{self.name}: linear_attn_config holds {sorted(group)}; the "
+                f"program reads {list(keys)}")
+        kda, full = (tuple(group[k]) for k in ("kda_layers",
+                                               "full_attn_layers"))
+        if sorted(kda + full) != list(range(1, self.num_layers + 1)):
+            raise ValueError(
+                f"{self.name}: linear_attn_config kda_layers {list(kda)} and "
+                f"full_attn_layers {list(full)} do not name each of layers "
+                f"1..{self.num_layers} (num_hidden_layers) once")
+        want = tuple(LINEAR if i + 1 in kda else ATTENTION
+                     for i in range(self.num_layers))
+        if self.layer_types is not None and tuple(self.layer_types) != want:
+            raise ValueError(
+                f"{self.name}: layer_types {list(self.layer_types)} does not "
+                "agree with linear_attn_config (kda_layers "
+                f"{list(kda)}: {LINEAR!r}, full_attn_layers {list(full)}: "
+                f"{ATTENTION!r}, counted from 1)")
+        defaults = type(self).__dataclass_fields__
+        for field, key in (("linear_num_key_heads", "num_heads"),
+                           ("linear_num_value_heads", "num_heads"),
+                           ("linear_key_head_dim", "head_dim"),
+                           ("linear_value_head_dim", "head_dim"),
+                           ("linear_conv_kernel_dim",
+                            "short_conv_kernel_size")):
+            if getattr(self, field) not in (defaults[field].default,
+                                            group[key]):
+                raise ValueError(
+                    f"{self.name}: {field} {getattr(self, field)} is not "
+                    f"linear_attn_config's {key} {group[key]}")
+            object.__setattr__(self, field, group[key])
+        object.__setattr__(self, "layer_types", want)
+        object.__setattr__(self, "linear_attn_config", tuple(sorted(
+            (k, tuple(v) if isinstance(v, (list, tuple)) else v)
+            for k, v in group.items())))
+        if self.lightning_nh or self.linear_allow_neg_eigval:
+            raise ValueError(
+                f"{self.name}: linear_attn_config with lightning_nh "
+                f"{self.lightning_nh} / linear_allow_neg_eigval "
+                f"{self.linear_allow_neg_eigval}: the linear kind has one "
+                "reading, and Kimi Delta Attention's b is a sigmoid")
+        latent = self.qk_nope_head_dim + self.qk_rope_head_dim
+        if self.kv_lora_rank and self.head_dim != latent:
+            if self.head_dim * self.num_heads != self.hidden_size:
+                raise ValueError(
+                    f"{self.name}: head_dim {self.head_dim} is neither "
+                    f"qk_nope_head_dim + qk_rope_head_dim = {latent} (what "
+                    "latent attention's q and k are wide) nor the "
+                    f"published hidden_size / num_attention_heads = "
+                    f"{self.hidden_size // self.num_heads} (which no "
+                    "module reads)")
+            object.__setattr__(self, "head_dim", latent)
+
+    @property
+    def kda(self) -> bool:
+        """Are the linear_attention layers Kimi Delta Attention?"""
+        return self.linear_attn_config is not None
 
     def _check_sparse(self) -> None:
         """Block-sparse and lightning layers: the sizes held to each other, a
@@ -1268,7 +1418,8 @@ class ModelConfig:
         if self.kv_lora_rank:
             H, r, c = self.num_heads, self.q_lora_rank, self.kv_lora_rank
             attention = (
-                d * r + r + r * self.q_dim + d * self.latent_dim + c
+                (d * r + r + r * self.q_dim if r else d * self.q_dim)
+                + d * self.latent_dim + c
                 + c * H * (self.qk_nope_head_dim + self.v_head_dim)
                 + H * self.v_head_dim * d)
             if self.index_topk:
@@ -1299,8 +1450,20 @@ class ModelConfig:
             # q | k | v | z and the two gates in, the taps, A_log and
             # dt_bias, the output norm, out.
             # (lightning: q | k | v | the gate in, out, the three norms)
+            # (Kimi Delta Attention: q | k | v in, the taps, the decay's and
+            # the output gate's two low-rank matrices through
+            # `linear_key_head_dim` lanes, dt_bias a key channel, A_log and
+            # b's projection a head, the output norm, out)
             LINEAR: (5 * d * ld + 2 * self.lightning_head_dim + ld
                      if self.lightning_nh else
+                     d * self.linear_conv_dim
+                     + self.linear_conv_dim * self.linear_conv_kernel_dim
+                     + self.linear_key_head_dim * (
+                         2 * d + self.linear_key_dim + self.linear_value_dim)
+                     + self.linear_key_dim
+                     + (d + 1) * self.linear_num_value_heads
+                     + self.linear_value_head_dim + self.linear_value_dim * d
+                     if self.kda else
                      d * (self.linear_conv_dim + self.linear_value_dim
                           + 2 * self.linear_num_value_heads)
                      + self.linear_conv_dim * self.linear_conv_kernel_dim
@@ -1692,6 +1855,48 @@ MODEL_CONFIGS = {
     # expert, a sigmoid router with no groups and no bias over 16 experts of
     # which this program holds 4, and the multi-token-prediction module
     # (one more block, its own cache rows; `--spec` drafts with it).
+    # Kimi-Linear-48B-A3B-Instruct (moonshotai; arXiv:2510.26692): Kimi Delta
+    # Attention (a delta rule whose decay is a vector a head) in three of
+    # four layers, NoPE latent attention with a full-rank q in the fourth
+    # and the last, a dense first layer, then 256 experts of 1024 (top 8 by
+    # sigmoid score + a selection bias, one shared expert, scale 2.446).
+    # The published keys under their own names; `head_dim` 72 is what
+    # config.json has (no module reads it) and becomes 192 here.
+    "kimi-linear:48b-a3b": ModelConfig(
+        name="kimi-linear:48b-a3b", vocab_size=163_840, hidden_size=2304,
+        intermediate_size=9216, num_layers=27, num_heads=32, num_kv_heads=32,
+        head_dim=72, rope_theta=10_000.0, rms_norm_eps=1e-5,
+        model_max_length=1_048_576, q_lora_rank=None, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        mla_use_nope=True,
+        linear_attn_config={
+            "kda_layers": [i for i in range(1, 27) if i % 4],
+            "full_attn_layers": [4, 8, 12, 16, 20, 24, 27],
+            "num_heads": 32, "head_dim": 128, "short_conv_kernel_size": 4},
+        num_experts=256, num_experts_per_token=8,
+        moe_router_activation_func="sigmoid", moe_renormalize=True,
+        num_expert_group=1, topk_group=1, use_grouped_topk=True,
+        use_expert_bias=True, num_shared_experts=1,
+        routed_scaling_factor=2.446, moe_intermediate_size=1024,
+        first_k_dense_replace=1,
+    ),
+    # Two periods at toy widths, 4 of 16 experts held (the tests' size).
+    "test-tiny-kimi-linear": ModelConfig(
+        name="test-tiny-kimi-linear", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=4,
+        head_dim=16, rope_theta=10_000.0, rms_norm_eps=1e-5, max_seq_len=512,
+        q_lora_rank=None, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, mla_use_nope=True,
+        linear_attn_config={
+            "kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
+            "num_heads": 4, "head_dim": 8, "short_conv_kernel_size": 4},
+        num_experts=4, router_experts=16, expert_offset=0,
+        num_experts_per_token=4, moe_router_activation_func="sigmoid",
+        moe_renormalize=True, num_expert_group=1, topk_group=1,
+        use_grouped_topk=True, use_expert_bias=True, num_shared_experts=1,
+        routed_scaling_factor=2.446, moe_intermediate_size=32,
+        first_k_dense_replace=1,
+    ),
     "test-tiny-openpangu": ModelConfig(
         name="test-tiny-openpangu", vocab_size=512, hidden_size=64,
         intermediate_size=128, num_layers=3, num_heads=4, num_kv_heads=4,

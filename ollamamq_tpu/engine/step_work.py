@@ -96,6 +96,15 @@ def slot_state_counts(cfg, page_size, s: Step) -> tuple:
             int(pairs.sum()))
 
 
+def rule_counts(cfg, page_size, s: Step) -> tuple:
+    """`slot_state_counts`, and behind them the windows the delta rule's
+    chunked form SOLVED, a layer's worth: every window of the padded stream,
+    a span in it or not (`gated_delta._prepare` solves them all at once; a
+    scan has none)."""
+    windows = 0 if s.scan else -(-max(s.stream_len, sum(s.tokens)) // CHUNK)
+    return slot_state_counts(cfg, page_size, s) + (windows,)
+
+
 def latent_counts(cfg, page_size, s: Step) -> tuple:
     """Latent attention under the indexer, a layer's worth: the query
     tokens, the cached positions the indexer scored for them (a token at
@@ -216,9 +225,10 @@ class Kind(NamedTuple):
 def _recurrent(kind: str, prefix: str, *series) -> Kind:
     """`slot_state_counts` under a recurrence's own names, a field a series
     given (None: a field without one): the first four, and `chunk_pairs`
-    for a recurrence whose spans go through `gated_delta.ragged`."""
+    for a recurrence whose spans go through `gated_delta.ragged` (then
+    `prepare_windows`: `rule_counts`)."""
     fields = ("state_resets", "state_carried", "step_rows", "span_tokens",
-              "chunk_pairs")[:len(series)]
+              "chunk_pairs", "prepare_windows")[:len(series)]
     return Kind(lambda cfg: cfg.count(kind),
                 tuple(f"{prefix}_{f}" for f in fields), series,
                 slot_state_counts)
@@ -233,8 +243,9 @@ KINDS = {
     "lin": _recurrent(
         LINEAR, "lin", tm.LIN_STATE_RESETS_TOTAL, tm.LIN_STATE_CARRIED_TOTAL,
         tm.LIN_STEP_ROWS_TOTAL, tm.LIN_SPAN_TOKENS_TOTAL,
-        tm.LIN_CHUNK_PAIRS_TOTAL)._replace(
-            present=lambda cfg: cfg.count(LINEAR) and not cfg.lightning_nh),
+        tm.LIN_CHUNK_PAIRS_TOTAL, tm.LIN_PREPARE_WINDOWS_TOTAL)._replace(
+            present=lambda cfg: cfg.count(LINEAR) and not cfg.lightning_nh,
+            counts=rule_counts),
     # (the linear kind's other reading: a model has one of the two)
     "lightning": _recurrent(
         LINEAR, "lightning", None, None, tm.LIGHTNING_STEP_ROWS_TOTAL,

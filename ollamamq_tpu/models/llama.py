@@ -1,5 +1,5 @@
 """Decoder stacks (Llama / Qwen / Mixtral / OLMoE / LFM2 / Olmo-Hybrid /
-Qwen3-Next / K-EXAONE / Falcon-H1) in pure functional JAX.
+Qwen3-Next / K-EXAONE / Falcon-H1 / Kimi-Linear) in pure functional JAX.
 
 A layer is `x + Op(norm(x))`, then `x + FFN(norm(x))` — or, with
 `norm_order` "post", `x + norm(Op(x))`, `x + norm(FFN(x))`, or with
@@ -100,6 +100,15 @@ CONV_SCOPES = ("conv_in", "conv_mix", "conv_out")
 # convolution over q | k | v with its window, the delta rule with its state,
 # the gated output norm and out-projection.
 LINEAR_SCOPES = ("lin_in", "lin_conv", "lin_rule", "lin_out")
+# ...and, where the linear kind is read as Kimi Delta Attention, inside
+# "lin_in" the decay's and the output gate's low-rank projections with the
+# gate arithmetic, and inside "lin_rule" the window solve with a decay a key
+# channel (ops/gated_delta.py:_prepare_vector).
+KDA_SCOPES = ("kda_gates", "kda_prepare")
+KDA_KEY = 0x6B646131
+# Seeded random init of Kimi Delta Attention's gated head norm, drawn around
+# 1 so that a forward which leaves it out computes another model.
+KDA_NORM_SD = 0.1
 # ...and a state-space mixer's four, beside the attention's four inside a
 # parallel layer: the in-projection with its multipliers, the convolution
 # over x | B | C with its window, the recurrence with its state, the gated
@@ -150,6 +159,7 @@ ZERO_CENTRED_NORM_SD = 0.1
 # `wo`: q down, its norm, q up; kv down to [c_kv | k_rope], c_kv's norm,
 # kv up to a head's [k_nope | v]; the indexer's q (from the normed q
 # latent), k (from the hiddens) with its LayerNorm, and head weights.
+# (`q_lora_rank` 0: no q down, norm or up — a full-rank `wq`.)
 MLA_PARAMS = ("mla_wdq", "mla_q_norm", "mla_wuq", "mla_wdkv", "mla_kv_norm",
               "mla_wukv", "idx_wq", "idx_wk", "idx_k_norm", "idx_k_bias",
               "idx_ww")
@@ -174,9 +184,11 @@ KIND_PARAMS = {
     # their own: no wk, no wv, and another count of layers)
     CROSS: ("xwq", "xbq", "xwo", "xbo", "xdiff_lambda", "xdiff_norm"),
     CONV: ("conv_in", "conv_w", "conv_out"),
-    # (the delta rule's, then the lightning reading's: a model has one)
+    # (the delta rule's — Kimi Delta Attention's: no `lin_ba`, its gates'
+    # five —, then the lightning reading's: a model has one)
     LINEAR: ("lin_in", "lin_ba", "lin_conv_w", "lin_A_log", "lin_dt_bias",
-             "lin_norm", "lin_out", "ltn_wq", "ltn_wk", "ltn_wv", "ltn_wz",
+             "lin_norm", "lin_out", "kda_fa", "kda_fb", "kda_b", "kda_ga",
+             "kda_gb", "ltn_wq", "ltn_wk", "ltn_wv", "ltn_wz",
              "ltn_wo", "ltn_q_norm", "ltn_k_norm", "ltn_norm"),
     PARALLEL: ("ssm_in", "ssm_dt", "ssm_conv_w", "ssm_conv_b", "ssm_A_log",
                "ssm_D", "ssm_dt_bias", "ssm_norm", "ssm_out"),
@@ -275,10 +287,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         H, r, c = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
         od = H * cfg.v_head_dim
         Hi, di = cfg.index_n_heads, cfg.index_head_dim
+        if r:
+            layers.update(
+                mla_wdq=w(mk[0], (La, d, r), d),
+                mla_q_norm=jnp.ones((La, r), dtype),
+                mla_wuq=w(mk[1], (La, r, qd), r))
+        else:  # a full-rank q projection
+            layers["wq"] = w(mk[0], (La, d, qd), d)
         layers.update(
-            mla_wdq=w(mk[0], (La, d, r), d),
-            mla_q_norm=jnp.ones((La, r), dtype),
-            mla_wuq=w(mk[1], (La, r, qd), r),
             mla_wdkv=w(mk[2], (La, d, cfg.latent_dim), d),
             mla_kv_norm=jnp.ones((La, c), dtype),
             mla_wukv=w(mk[3], (La, c, H * (cfg.qk_nope_head_dim
@@ -340,6 +356,33 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
             ltn_q_norm=around_one(tk[5], (Ll, cfg.lightning_head_dim)),
             ltn_k_norm=around_one(tk[6], (Ll, cfg.lightning_head_dim)),
             ltn_norm=around_one(tk[7], (Ll, ld)))
+    elif Ll and cfg.kda:
+        # Kimi Delta Attention: in-projection to [q | k | v] (the three
+        # published projections side by side), the depthwise taps over them,
+        # the decay's low-rank pair (hidden -> head size -> a key channel a
+        # head) with dt_bias a channel and A_log a head (float32: inside an
+        # exp), b's projection a head, the output gate's low-rank pair, the
+        # gated norm over a value head, the out-projection.
+        kk = jax.random.split(jax.random.fold_in(key, KDA_KEY), 11)
+        cd, kd, vd = (cfg.linear_conv_dim, cfg.linear_key_dim,
+                      cfg.linear_value_dim)
+        H, r, K = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                   cfg.linear_conv_kernel_dim)
+        dt = jnp.exp(jax.random.uniform(
+            kk[4], (Ll, kd), jnp.float32, *map(jnp.log, LINEAR_DT_RANGE)))
+        layers.update(
+            lin_in=w(kk[0], (Ll, d, cd), d),
+            lin_conv_w=w(kk[1], (Ll, cd, K), K),
+            kda_fa=w(kk[2], (Ll, d, r), d), kda_fb=w(kk[5], (Ll, r, kd), r),
+            lin_A_log=jnp.log(jax.random.uniform(
+                kk[3], (Ll, H), jnp.float32, *LINEAR_A_RANGE)),
+            lin_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            kda_b=w(kk[6], (Ll, d, H), d),
+            kda_ga=w(kk[7], (Ll, d, r), d), kda_gb=w(kk[8], (Ll, r, vd), r),
+            lin_norm=(1.0 + KDA_NORM_SD * jax.random.normal(
+                kk[9], (Ll, cfg.linear_value_head_dim),
+                jnp.float32)).astype(dtype),
+            lin_out=w(kk[10], (Ll, vd, d), vd))
     elif Ll:
         # Gated delta rule: in-projection to [q | k | v | z] (the first
         # three pass the convolution), the two gates [b | a] a head, the
@@ -770,7 +813,10 @@ def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
 
 def _latent_rope(cfg: ModelConfig, x: jnp.ndarray, positions) -> jnp.ndarray:
     """RoPE (rotate-half, YaRN frequencies where the config scales them)
-    over the FIRST `qk_rope_head_dim` lanes of x [B, T, H, >= that]."""
+    over the FIRST `qk_rope_head_dim` lanes of x [B, T, H, >= that] — or,
+    `mla_use_nope`, x as it is: those lanes are carried unrotated."""
+    if cfg.mla_use_nope:
+        return x
     dr = cfg.qk_rope_head_dim
     yarn = cfg.yarn
     freqs = yarn_freqs(dr, cfg.rope_theta, yarn) if yarn \
@@ -785,8 +831,10 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
                          positions: jnp.ndarray, attn_fn,
                          few=None) -> jnp.ndarray:
     """Latent attention with the indexer's selection over normed hiddens h
-    [B, T, D] (ops/mla.py has the mathematics): the projections, norms and
-    RoPE here, in the ABSORBED form; the schedule (the write of the token's
+    [B, T, D] (ops/mla.py has the mathematics): the projections (q through
+    its low rank, or `q_lora_rank` 0 full-rank), norms and RoPE
+    (`mla_use_nope`: none) here, in the ABSORBED form; the schedule (the
+    write of the token's
     two cache rows, the indexer, the selection, the softmax) is the caller's
     `attn_fn(q_abs [B, T, H, lanes], row [B, T, lanes], (q_idx [B, T, Hi,
     di], k_idx [B, T, di], w_idx [B, T, Hi] float32)) -> [B, T, H, c]`, the
@@ -833,10 +881,13 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
                           preferred_element_type=jnp.float32).astype(h.dtype)
 
     with jax.named_scope("mla_proj"):
-        c_q = rmsnorm(qeinsum("btd,de->bte", h, lp["mla_wdq"]),
-                      lp["mla_q_norm"], cfg.rms_norm_eps)
-        q = qeinsum("btr,re->bte", c_q, lp["mla_wuq"]).reshape(
-            B, T, H, dn + dr)
+        if cfg.q_lora_rank:
+            c_q = rmsnorm(qeinsum("btd,de->bte", h, lp["mla_wdq"]),
+                          lp["mla_q_norm"], cfg.rms_norm_eps)
+            q = qeinsum("btr,re->bte", c_q, lp["mla_wuq"])
+        else:  # a full-rank q (and no indexer to read the q latent)
+            q = qeinsum("btd,de->bte", h, lp["wq"])
+        q = q.reshape(B, T, H, dn + dr)
         q_rope = _latent_rope(cfg, q[..., dn:], positions)
         kv = qeinsum("btd,de->bte", h, lp["mla_wdkv"])
         c_kv = rmsnorm(kv[..., :c], lp["mla_kv_norm"], cfg.rms_norm_eps)
@@ -1028,6 +1079,49 @@ def _linear_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     with jax.named_scope("lin_out"):
         o = rmsnorm(o, lp["lin_norm"], cfg.rms_norm_eps).reshape(B, T, H * dv)
         return qeinsum("bte,ed->btd", (o * jax.nn.silu(z)).astype(h.dtype),
+                       lp["lin_out"])
+
+
+def _kda_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray, taps_fn,
+            rule_fn) -> jnp.ndarray:
+    """Kimi Delta Attention over normed hiddens h [B, T, D] — the linear kind
+    where `ModelConfig.kda`: [q | k | v] = h W_in, each through its depthwise
+    causal convolution and a SiLU; the decay a KEY CHANNEL, g = -exp(A_log[h])
+    softplus(h W_fa W_fb + dt_bias) in R^{H x dk}, and b = sigmoid(h W_b),
+    float32; per head the rule (ops/gated_delta.py at its vector reading: q,
+    k L2-normalised there); y = (RMSNorm_head(o; w) * sigmoid(h W_ga W_gb))
+    W_out. `taps_fn`, `rule_fn`: as `_linear_attention_op`'s, g [B, T, H,
+    dk]."""
+    B, T, _ = h.shape
+    H, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    kd = cfg.linear_key_dim
+    f32 = jnp.float32
+    with jax.named_scope("lin_in"):
+        qkv = qeinsum("btd,de->bte", h, lp["lin_in"])
+        with jax.named_scope("kda_gates"):
+            a = jnp.einsum("btr,re->bte",
+                           qeinsum("btd,dr->btr", h, lp["kda_fa"]),
+                           lp["kda_fb"], preferred_element_type=f32)
+            g = -jnp.exp(lp["lin_A_log"].astype(f32))[:, None] \
+                * jax.nn.softplus(a.reshape(B, T, H, dk) + lp[
+                    "lin_dt_bias"].astype(f32).reshape(H, dk))
+            beta = jax.nn.sigmoid(jnp.einsum(
+                "btd,dh->bth", h, lp["kda_b"], preferred_element_type=f32))
+            gate = jnp.einsum("btr,re->bte",
+                              qeinsum("btd,dr->btr", h, lp["kda_ga"]),
+                              lp["kda_gb"], preferred_element_type=f32)
+    with jax.named_scope("lin_conv"):
+        c = jax.nn.silu(shortconv.short_conv(lp["lin_conv_w"], taps_fn(qkv),
+                                             qkv))
+    with jax.named_scope("lin_rule"):
+        o = rule_fn(c[..., :kd].reshape(B, T, H, dk),
+                    c[..., kd:2 * kd].reshape(B, T, H, dk),
+                    c[..., 2 * kd:].reshape(B, T, H, dv), g, beta)
+    with jax.named_scope("lin_out"):
+        o = rmsnorm(o, lp["lin_norm"], cfg.rms_norm_eps).reshape(B, T, H * dv)
+        return qeinsum("bte,ed->btd",
+                       (o * jax.nn.sigmoid(gate)).astype(h.dtype),
                        lp["lin_out"])
 
 
@@ -1303,6 +1397,8 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
         delta = _conv_op(cfg, lp, h, taps_fn)
     elif op == LINEAR and cfg.lightning_nh:
         delta = _lightning_op(cfg, lp, h, positions, ssm_fn, depth)
+    elif op == LINEAR and cfg.kda:
+        delta = _kda_op(cfg, lp, h, taps_fn, rule_fn)
     elif op == LINEAR:
         delta = _linear_attention_op(cfg, lp, h, taps_fn, rule_fn)
     elif op == PARALLEL:

@@ -124,6 +124,34 @@ _HF_MOE_LAYOUTS = (
 )
 
 
+# Kimi-Linear (the published `kimi_linear` tensor names, ASSUMED from the
+# published modelling code; no checkpoint can be read offline). A layer's
+# `self_attn` is Kimi Delta Attention or latent attention BY LAYER — both
+# have a `q_proj`, of different shapes — so the family has tables of its own,
+# a kind each; its expert block is `block_sparse_moe` with the selection
+# bias beside the router and the shared expert inside it (`_kimi_linear`).
+_KIMI_KDA = {  # published suffix under self_attn. -> (ours, transpose?)
+    "f_a_proj.weight": ("kda_fa", True), "f_b_proj.weight": ("kda_fb", True),
+    "b_proj.weight": ("kda_b", True), "g_a_proj.weight": ("kda_ga", True),
+    "g_b_proj.weight": ("kda_gb", True), "dt_bias": ("lin_dt_bias", False),
+    "o_norm.weight": ("lin_norm", False), "o_proj.weight": ("lin_out", True),
+}
+_KIMI_MLA = {
+    "q_proj.weight": ("wq", True),
+    "kv_a_proj_with_mqa.weight": ("mla_wdkv", True),
+    "kv_a_layernorm.weight": ("mla_kv_norm", False),
+    "kv_b_proj.weight": ("mla_wukv", True), "o_proj.weight": ("wo", True),
+}
+_KIMI_SHARED = {"gate_proj": "ws_gate", "up_proj": "ws_up",
+                "down_proj": "ws_down"}
+_KIMI_BIAS = "gate.e_score_correction_bias"
+# (before Mixtral's, whose block and router it shares: told apart by the
+# selection bias beside the router)
+_HF_MOE_LAYOUTS = ((
+    "block_sparse_moe", "gate.weight", ("w1", "w3", "w2"), _KIMI_BIAS),
+) + _HF_MOE_LAYOUTS
+
+
 # The largest leaf `init_random` draws eagerly (three float32 copies of it
 # exist while it is made): the served dense stacks reach 0.95 G parameters
 # (Qwen2.5-7B's 14 gate matrices).
@@ -189,7 +217,7 @@ def _unweave_qwen3_next(cfg: ModelConfig, layers: dict) -> None:
         layers["wq"], layers["wq_gate"] = (
             w[:, :, :, j].reshape(*w.shape[:2], cfg.q_dim) for j in (0, 1))
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-    if "lin_in" not in layers or not hk:
+    if "lin_in" not in layers or not hk or cfg.kda:  # (KDA: nothing woven)
         return
     r, dk, dv = hv // hk, cfg.linear_key_head_dim, cfg.linear_value_head_dim
 
@@ -202,6 +230,40 @@ def _unweave_qwen3_next(cfg: ModelConfig, layers: dict) -> None:
 
     layers["lin_in"] = parts(layers["lin_in"], (dk, dk, r * dv, r * dv))
     layers["lin_ba"] = parts(layers["lin_ba"], (r, r))
+
+
+def _kimi_linear(cfg: ModelConfig, grab, having, dtype) -> dict:
+    """A Kimi-Linear checkpoint's `self_attn` tensors and shared expert in
+    the served layout (`grab`, `having`: load_safetensors'): a KDA layer's
+    three projections side by side as `lin_in` and its three depthwise
+    convolutions as `lin_conv_w` ([q | k | v]: the same numbers), `A_log`
+    [1, 1, H, 1] as [H]; a latent layer's five; the shared expert from inside
+    the expert block."""
+    def stack(layers_at, make, float32=False):
+        return jnp.asarray(np.stack([make(i) for i in layers_at]),
+                           dtype=jnp.float32 if float32 else dtype)
+
+    def of(i, suffix, transpose=False):
+        return grab(f"model.layers.{i}.self_attn.{suffix}", transpose)
+
+    kda, mla = having("self_attn.f_a_proj.weight"), \
+        having("self_attn.kv_b_proj.weight")
+    out = {
+        "lin_in": stack(kda, lambda i: np.concatenate(
+            [of(i, f"{n}_proj.weight", True) for n in "qkv"], axis=1)),
+        "lin_conv_w": stack(kda, lambda i: np.concatenate(
+            [of(i, f"{n}_conv1d.weight")[:, 0, :] for n in "qkv"])),
+        "lin_A_log": stack(kda, lambda i: of(i, "A_log").reshape(-1), True)}
+    for table, at in ((_KIMI_KDA, kda), (_KIMI_MLA, mla)):
+        for suffix, (ours, tr) in table.items():
+            out[ours] = stack(at, lambda i, s=suffix, t=tr: of(i, s, t),
+                              ours in _FLOAT32_KEYS)
+    shared = having("block_sparse_moe.shared_experts.gate_proj.weight")
+    for name, ours in _KIMI_SHARED.items():
+        out[ours] = stack(shared, lambda i, n=name: grab(
+            f"model.layers.{i}.block_sparse_moe.shared_experts.{n}.weight",
+            True))
+    return out
 
 
 def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
@@ -245,9 +307,11 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
                 if f"model.layers.{i}.{suffix}" in raw]
 
     layers: dict = {}
+    if cfg.kda:
+        layers.update(_kimi_linear(cfg, grab, having, dtype))
     for hf_suffix, (ours, tr) in _HF_LAYER_MAP.items():
         at = having(hf_suffix)
-        if not at:
+        if not at or (cfg.kda and hf_suffix.startswith("self_attn.")):
             continue
         stack = np.stack(
             [grab(f"model.layers.{i}.{hf_suffix}", tr) for i in at])
@@ -274,7 +338,8 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
         # peak host memory stays near one f32 layer-stack (~2 GB for
         # 8x7b) above the raw checkpoint, instead of ~2.5x it.
         block, router, gate_up_down, bias = next(
-            lay for lay in _HF_MOE_LAYOUTS if having(f"{lay[0]}.{lay[1]}"))
+            lay for lay in _HF_MOE_LAYOUTS if having(f"{lay[0]}.{lay[1]}")
+            and (lay[3] != _KIMI_BIAS or having(f"{lay[0]}.{lay[3]}")))
         at = having(f"{block}.{router}")
 
         def estack(w_name: str, transpose: bool):

@@ -48,6 +48,22 @@ k_t (b_t v_t)^T, o_t = S_t^T q_t, q and k as given. That is Mamba-2's SSD
 tokens continue which state — the windows, the (row, window) loop, the
 active mask, the reset — and drops the chunk's triangular solve (T = I).
 
+A VECTOR decay (Kimi Delta Attention: `g` of rank one more than `beta`, [...,
+H, dk] — a trace-time reading as `plain` is; with a scalar gate every
+function traces exactly what it traced before the reading existed) decays
+every key
+channel on its own: S' = Diag(a_t) S_{t-1} scales ROWS of S. In the chunked
+form the decay between two tokens then sits INSIDE the contraction over the
+key channels, A_ij = b_i sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c]), and the
+factored form (k_i e^G_i) . (k_j e^-G_j) overflows at strong decay. `_prepare`
+forms no exponent above 0: the window is cut into the _SUB-token blocks
+`_tri_inv` uses; a block's tokens against an EARLIER block's are one
+contraction of two scaled operands, exp(G_i - R) and exp(R - G_j) with R the
+row's G before the block's first token (a row is a stretch of the stream, so
+the only row with tokens on both sides holds that token: G_i <= R <= G_j);
+inside a block G_i - G_j is formed directly, [_SUB, _SUB, dk] a head.
+Exponents are masked (-inf), never products.
+
 State layout: [linear layers, slots + 1, dk, H * dv] float32 — the key
 dimension on sublanes, every head's values side by side on lanes. With
 dv = 192 a [.., H, dk, dv] array would be padded to 256 lanes in HBM (a
@@ -59,6 +75,8 @@ path.
 """
 
 from __future__ import annotations
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -122,17 +140,27 @@ def _operands(q, k, heads: int, plain: bool):
     return a_value_head(q, k, heads)
 
 
+def a_channel(g, beta) -> bool:
+    """Is `g` a decay a KEY CHANNEL — of rank one more than `beta`, [..., H,
+    dk] — and not one a head? A trace-time reading, as `plain` is."""
+    return g.ndim > beta.ndim
+
+
 def step(state, q, k, v, g, beta, reset=None, plain: bool = False):
     """One token a row. state [..., dk, H * dv]; q, k [..., Hk, dk] as the
     convolution left them (Hk key heads, H a multiple of it); v [..., H,
-    dv]; g, beta [..., H]; reset [...]: the row's state opens at zero.
+    dv]; g, beta [..., H] (g [..., H, dk]: a decay a key channel); reset
+    [...]: the row's state opens at zero.
     Returns (o [..., H, dv] float32, the state after the token)."""
     q, k = _operands(q, k, v.shape[-2], plain)
     s = _heads(state, q.shape[-2])
     if reset is not None:
         s = jnp.where(reset[..., None, None, None], 0.0, s)
     kt, qt = jnp.swapaxes(k, -1, -2), jnp.swapaxes(q, -1, -2)  # [..., dk, H]
-    s = s * jnp.exp(g)[..., None, :, None]
+    if a_channel(g, beta):  # rows of S
+        s = s * jnp.swapaxes(jnp.exp(g), -1, -2)[..., None]
+    else:
+        s = s * jnp.exp(g)[..., None, :, None]
     if plain:
         r = beta[..., None] * v.astype(_F32)
     else:
@@ -191,10 +219,13 @@ def _prepare(q, k, v, g, beta, same, plain: bool = False):
     dv] and w [N, H, C, dk] (the chunk's corrected values = u - w S for an
     incoming state S), attn [N, H, C, C], qg (q scaled by its decay), k and
     gc [N, H, C] (each token's log decay since its row entered the chunk).
-    `plain`: no token corrects another, so u = beta v and there is no w."""
+    `plain`: no token corrects another, so u = beta v and there is no w.
+    g [N, C, H, dk] (a decay a key channel; not `plain`): gc [N, H, C, dk]."""
     q, k = _operands(q, k, v.shape[2], plain)
     q, k, v = (jnp.moveaxis(x, 2, 1) for x in (q, k, v.astype(_F32)))
     g, beta = jnp.moveaxis(g, 2, 1), jnp.moveaxis(beta, 2, 1)  # [N, H, C]
+    if a_channel(g, beta):  # [N, H, C, dk]
+        return _prepare_vector(q, k, v, g, beta, same)
     mask = same[:, None]  # [N, 1, C, C]
     gc = _mm("nij,nhj->nhi", same.astype(_F32), g)
     decay = jnp.exp(jnp.where(mask, gc[..., :, None] - gc[..., None, :],
@@ -213,6 +244,59 @@ def _prepare(q, k, v, g, beta, same, plain: bool = False):
             "k": k, "gc": gc}
 
 
+def _prepare_vector(q, k, v, g, beta, same):
+    """`_prepare` with a decay a key channel (the module docstring has the
+    block solve), heads leading: q, k, g [N, H, C, dk], v [N, H, C, dv], beta
+    [N, H, C]. No exponent above 0 is formed."""
+    n, h, c, dk = k.shape
+    nb = c // _SUB
+    gc = _mm("nij,nhjc->nhic", same.astype(_F32), g)  # [N, H, C, dk]
+
+    def blocked(x):  # [N, H, C, ...] -> [N, H, nb, _SUB, ...]
+        return x.reshape(n, h, nb, _SUB, *x.shape[3:])
+
+    qb, kb, gb = blocked(q), blocked(k), blocked(gc)
+    # Inside a block: G_i - G_j formed directly, masked before the exp.
+    same_b = same.reshape(n, nb, _SUB, nb, _SUB)
+    diag = jnp.stack([same_b[:, i, :, i, :] for i in range(nb)], axis=1)
+    decay = jnp.exp(jnp.where(
+        diag[:, None, ..., None],
+        gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
+    kd = kb[..., None, :, :] * decay  # [N, H, nb, i, j, dk]
+    kk_in = jnp.sum(kb[..., :, None, :] * kd, axis=-1)
+    qk_in = jnp.sum(qb[..., :, None, :] * kd, axis=-1)
+    # A block's tokens against the EARLIER blocks': both sides scaled to the
+    # row's G before the block's first token (the first token's own g taken
+    # off its G), members of that token's row only.
+    first = jnp.arange(nb) * _SUB
+    ref = gb[..., 0, :] - blocked(g)[..., 0, :]  # [N, H, nb, dk]
+    later = diag[..., 0]  # [N, I, i]: i of first(I)'s row (and behind it)
+    earlier = same[:, first, :] & (
+        jnp.arange(c)[None, :] < first[:, None])  # [N, I, j]
+    up = jnp.exp(jnp.where(later[:, None, ..., None],
+                           gb - ref[..., None, :], -jnp.inf))
+    down = jnp.exp(jnp.where(
+        earlier[:, None, ..., None],
+        ref[..., None, :] - gc[:, :, None], -jnp.inf))  # [N, H, I, C, dk]
+    k_down = k[:, :, None] * down
+    kk_off = _mm("nhbik,nhbjk->nhbij", kb * up, k_down).reshape(n, h, c, c)
+    qk_off = _mm("nhbik,nhbjk->nhbij", qb * up, k_down).reshape(n, h, c, c)
+
+    def whole(inside, off):  # the diagonal blocks laid over the rest
+        eye = jnp.eye(nb, dtype=_F32)[:, None, :, None]  # [nb, 1, nb, 1]
+        return off + (inside[..., None, :] * eye).reshape(n, h, c, c)
+
+    mask = same[:, None]
+    strict = mask & ~jnp.eye(c, dtype=bool)
+    t = _tri_inv(jnp.where(
+        strict, beta[..., None] * whole(kk_in, kk_off), 0.0))
+    u = _mm("nhij,nhjd->nhid", t, beta[..., None] * v)
+    w = _mm("nhij,nhjd->nhid", t, beta[..., None] * jnp.exp(gc) * k)
+    attn = jnp.where(mask, whole(qk_in, qk_off), 0.0)
+    return {"u": u, "w": w, "attn": attn, "qg": q * jnp.exp(gc), "k": k,
+            "gc": gc}
+
+
 def _apply(s, c, member):
     """One row's tokens of one chunk against the row's incoming state.
     s [..., H, dk, dv]; c: `_prepare`'s results at that chunk [..., H, C,
@@ -226,12 +310,18 @@ def _apply(s, c, member):
     o = _mm("...hck,...hkd->...hcd", c["qg"], s) \
         + _mm("...hij,...hjd->...hid", c["attn"], v_new)
     # gc falls along a row (g <= 0): its least value is the last token's.
-    g_last = jnp.min(jnp.where(m, c["gc"], jnp.inf), axis=-1)  # [..., H]
-    kd = c["k"] * jnp.exp(jnp.where(
-        m, g_last[..., None] - c["gc"], -jnp.inf))[..., None]
-    s = s * jnp.exp(g_last)[..., None, None] \
-        + _mm("...hck,...hcd->...hkd", kd, v_new)
-    return o, s
+    if c["gc"].ndim == c["k"].ndim:  # [..., H, C, dk]: a decay a key channel
+        mk = m[..., None]
+        g_last = jnp.min(jnp.where(mk, c["gc"], jnp.inf), axis=-2)
+        kd = c["k"] * jnp.exp(jnp.where(
+            mk, g_last[..., None, :] - c["gc"], -jnp.inf))
+        s = s * jnp.exp(g_last)[..., None]  # rows of S
+    else:
+        g_last = jnp.min(jnp.where(m, c["gc"], jnp.inf), axis=-1)  # [..., H]
+        kd = c["k"] * jnp.exp(jnp.where(
+            m, g_last[..., None] - c["gc"], -jnp.inf))[..., None]
+        s = s * jnp.exp(g_last)[..., None, None]
+    return o, s + _mm("...hck,...hcd->...hkd", kd, v_new)
 
 
 def _to_heads(state, n_heads: int):
@@ -246,16 +336,16 @@ def _from_heads(s):
 
 def chunked(q, k, v, g, beta, valid=None, state=None, plain: bool = False):
     """Whole sequences from an empty (or a given) state. q, k [B, T, Hk,
-    dk], v [B, T, H, dv], g, beta [B, T, H]; valid [B, T] bool (padding
-    takes no part). Returns (o [B, T, H, dv] float32, state [B, dk, H *
-    dv])."""
+    dk], v [B, T, H, dv], g, beta [B, T, H] (g [B, T, H, dk]: a decay a key
+    channel); valid [B, T] bool (padding takes no part). Returns (o [B, T,
+    H, dv] float32, state [B, dk, H * dv])."""
     b, t, _, dk = q.shape
     h, dv = v.shape[-2:]
     n = -(-t // CHUNK)
     pad = n * CHUNK - t
     if valid is None:
         valid = jnp.ones((b, t), bool)
-    g = jnp.where(valid[..., None], g, 0.0)
+    g = jnp.where(valid[(...,) + (None,) * (g.ndim - 2)], g, 0.0)
     beta = jnp.where(valid[..., None], beta, 0.0)
 
     def cut(x):  # [B, T, ...] -> [B * n, C, ...]
@@ -323,11 +413,11 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
            q_start, q_len, is_first, impl: str = "jnp", interpret=False,
            plain: bool = False):
     """The flattened stream of a ragged step (see the module docstring).
-    q, k [T, Hk, dk], v [T, H, dv], g, beta [T, H]; state the whole carried
-    array, `layer` this layer's index in it; slot_ids, q_start, q_len,
-    is_first [B] per row; tok_seq, tok_pos [T] each token's row and
-    position (-1: padding). The rows lie in stream order — `q_start` does
-    not decrease with the row index, as the engine lays a step out
+    q, k [T, Hk, dk], v [T, H, dv], g, beta [T, H] (g [T, H, dk]: a decay a
+    key channel); state the whole carried array, `layer` this layer's index
+    in it; slot_ids, q_start, q_len, is_first [B] per row; tok_seq, tok_pos
+    [T] each token's row and position (-1: padding). The rows lie in stream
+    order — `q_start` does not decrease with the row index, as the engine lays a step out
     (tests/test_olmo_hybrid.py holds it to that): the pair loop does not
     need it, the pair kernel does (its output block of a window is fetched
     once, so a window it came back to would lose the earlier row's
@@ -354,9 +444,14 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
     row_of = cut(jnp.where(part, tok_seq, -1), -1)  # [n, C]
     same = (row_of[:, :, None] == row_of[:, None, :]) \
         & jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
-    c = _prepare(cut(q), cut(k), cut(v),
-                 cut(jnp.where(part[:, None], g, 0.0)),
-                 cut(jnp.where(part[:, None], beta, 0.0)), same, plain)
+    # (a decay a key channel: the window solve under a scope of its own)
+    vector = a_channel(g, beta)
+    along_g = (slice(None),) + (None,) * (g.ndim - 1)  # `part` over g's axes
+    with jax.named_scope("kda_prepare") if vector \
+            else contextlib.nullcontext():
+        c = _prepare(cut(q), cut(k), cut(v),
+                     cut(jnp.where(part[along_g], g, 0.0)),
+                     cut(jnp.where(part[:, None], beta, 0.0)), same, plain)
     # 3. The (row, window) pairs the spans touch, rows in order and each
     # row's windows in order: pair p is row `b`, its window `w`.
     first_w = q_start // CHUNK
@@ -389,7 +484,8 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
     if impl == "pallas":
         from ollamamq_tpu.ops.pallas import chunk_rule
 
-        kernel = chunk_rule.blocks(h, q.shape[-1], dv, plain) and chunk_rule
+        kernel = chunk_rule.blocks(h, q.shape[-1], dv, plain,
+                                   vector) and chunk_rule
     if kernel:
         # ONE launch over the pairs, a row's state in VMEM across its
         # windows (`pair` is its definition): each pair's row, window and
@@ -420,8 +516,9 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
 
 def decode(q, k, v, g, beta, state, layer, active=None, impl: str = "jnp",
            plain: bool = False):
-    """One token a slot: q, k [S, Hk, dk], v [S, H, dv], g, beta [S, H]; row
-    s of `state[layer]` is slot s's. Returns (o [S, H, dv], state')."""
+    """One token a slot: q, k [S, Hk, dk], v [S, H, dv], g, beta [S, H] (g
+    [S, H, dk]: a decay a key channel); row s of `state[layer]` is slot s's.
+    Returns (o [S, H, dv], state')."""
     n = q.shape[0]
     live = jnp.ones((n,), bool) if active is None else active > 0
     return _step_rows(impl, state, layer, jnp.arange(n, dtype=jnp.int32),

@@ -26,6 +26,11 @@ float32 accumulation, in `_apply`'s order:
 The rows of two left operands that share a right operand ride one latch of
 it. kd (k scaled by each token's decay to the row's last token of the window)
 depends on which row the pair is, so the kernel scales k^T's columns itself.
+With a decay a KEY CHANNEL (`gc` [n, H, C, dk]: Kimi Delta Attention's, the
+trace-time reading of ops/gated_delta.py) the kernel gets gc transposed, a
+[dk, C] block a head — k^T's own layout: the row's last G is a [dk, 1]
+column, kd^T = k^T * exp(G_last - G^T) elementwise, and the state's decay
+that column broadcast along the head's lanes (rows of S).
 
 Layout: lanes are the heads' values side by side (h * dv + j) for the state,
 for u and for the output [windows, C, H * dv] — which is the stream's own
@@ -84,10 +89,13 @@ FIRST, OPENS = 1, 2  # a pair's flag: its row's first (the state is read),
 #                      and the row opens at zero there (it is not)
 
 
-def _block_bytes(hb: int, dk: int, dv: int, plain: bool) -> int:
+def _block_bytes(hb: int, dk: int, dv: int, plain: bool,
+                 vector: bool = False) -> int:
     """What a program of `hb` heads holds in VMEM: the state in and out, u
-    and the output, the two stacked left operands — every block twice (the
-    pipeline's two buffers), minor dimensions padded to 128 lanes."""
+    and the output, the two stacked left operands (`vector`: and a [dk, C]
+    block of G a head, where the scalar decay's [1, C] is not counted) —
+    every block twice (the pipeline's two buffers), minor dimensions padded
+    to 128 lanes."""
     def lanes(n):
         return -(-n // 128) * 128
 
@@ -95,10 +103,11 @@ def _block_bytes(hb: int, dk: int, dv: int, plain: bool) -> int:
     rows = 2 * CHUNK * lanes(hb * dv)
     on_s = hb * (CHUNK if plain else 2 * CHUNK) * lanes(dk)
     on_v = hb * (CHUNK + dk) * lanes(CHUNK)
-    return 2 * 4 * (state + rows + on_s + on_v)
+    gc = hb * dk * lanes(CHUNK) if vector else 0
+    return 2 * 4 * (state + rows + on_s + on_v + gc)
 
 
-def blocks(heads: int, dk: int, dv: int, plain: bool):
+def blocks(heads: int, dk: int, dv: int, plain: bool, vector: bool = False):
     """(heads a lane group, heads a block) the kernel runs `(H, dk, dv)` at,
     or None where it does not (the XLA pair loop does): the key dimension
     whole sublane tiles, lane groups as `gated_delta_step.head_blocks` cuts
@@ -107,7 +116,7 @@ def blocks(heads: int, dk: int, dv: int, plain: bool):
     if dk % 8:
         return None
     fit = [n for n in range(hg, heads + 1, hg) if heads % n == 0
-           and _block_bytes(n, dk, dv, plain) <= VMEM_BYTES]
+           and _block_bytes(n, dk, dv, plain, vector) <= VMEM_BYTES]
     return (hg, max(fit)) if fit else None
 
 
@@ -126,7 +135,7 @@ def _dot(a, b):
 
 def _kernel(layer_ref, slot_ref, win_ref, row_ref, flag_ref, n_ref, s_in_ref,
             u_ref, on_s_ref, on_v_ref, gc_ref, row_l_ref, row_s_ref, o_ref,
-            s_ref, *, hg, dv, plain):
+            s_ref, *, hg, dv, plain, vector=False):
     del layer_ref, slot_ref, win_ref  # the index maps read them
     p, n = pl.program_id(1), n_ref[0]
 
@@ -167,13 +176,18 @@ def _kernel(layer_ref, slot_ref, win_ref, row_ref, flag_ref, n_ref, s_in_ref,
                 v_new, qs = v_new - on_s[:c], on_s[c:]
             on_v, decay = [], []
             for h in group:
-                g = gc_ref[h]  # [1, C]; it falls along a row: min = last
+                # [1, C] (`vector`: [dk, C], a key channel a row); it falls
+                # along a row of the stream: min = last
+                g = gc_ref[h]
                 g_last = jnp.min(jnp.where(in_row, g, jnp.inf), axis=-1,
                                  keepdims=True)
                 d = jnp.exp(jnp.where(in_row, g_last - g, -jnp.inf))
-                on_v.append(_dot(on_v_ref[h] * jnp.where(below, d, 1.0),
-                                 v_new))
-                decay.append(jnp.broadcast_to(jnp.exp(g_last), (1, gl)))
+                left = jnp.concatenate(
+                    [on_v_ref[h, :c], on_v_ref[h, c:] * d], axis=0) \
+                    if vector else on_v_ref[h] * jnp.where(below, d, 1.0)
+                on_v.append(_dot(left, v_new))
+                decay.append(jnp.broadcast_to(jnp.exp(g_last),
+                                              (g.shape[0], gl)))
             on_v = own_lanes(on_v)
             s_ref[:, at] = s * own_lanes(decay) + on_v[c:]
             o_ref[:, at] = jnp.where(in_col, qs + on_v[:c], o_ref[:, at])
@@ -189,12 +203,14 @@ def chunk_rule_pallas(state, layer, slots, windows, rows, flags, n_pairs,
     window, `rows` its row (as `row_of` names it), `flags` FIRST at a row's
     first pair, + OPENS where the row opens at zero; row_of [n, C] int32
     each window token's row (-1: of no span); c: `gated_delta._prepare`'s
-    results, heads leading (no "w": the plain form). Returns (o [n, C, H *
-    dv] float32 — right at the spans' tokens only —, state')."""
+    results, heads leading (no "w": the plain form; "gc" [n, H, C, dk]: a
+    decay a key channel). Returns (o [n, C, H * dv] float32 — right at the
+    spans' tokens only —, state')."""
     n, h, chunk, dv = c["u"].shape
     dk = c["k"].shape[-1]
     plain = "w" not in c
-    hg, hb = blocks(h, dk, dv, plain)
+    vector = c["gc"].ndim == 4
+    hg, hb = blocks(h, dk, dv, plain, vector)
     nblk, lanes = h // hb, hb * dv
     u = jnp.moveaxis(c["u"], 1, 2).reshape(n, chunk, h * dv)
     on_s = c["qg"] if plain else jnp.concatenate([c["w"], c["qg"]], axis=2)
@@ -216,14 +232,16 @@ def chunk_rule_pallas(state, layer, slots, windows, rows, flags, n_pairs,
     state_spec = pl.BlockSpec((None, None, dk, lanes), state_block)
     n_pairs = jnp.asarray(n_pairs, jnp.int32)
     o, state = pl.pallas_call(
-        functools.partial(_kernel, hg=hg, dv=dv, plain=plain),
+        functools.partial(_kernel, hg=hg, dv=dv, plain=plain, **(
+            {"vector": True} if vector else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6, grid=(nblk, jnp.maximum(n_pairs, 1)),
             in_specs=[
                 state_spec, lane_spec,
                 pl.BlockSpec((None, hb) + on_s.shape[2:], head_block),
                 pl.BlockSpec((None, hb) + on_v.shape[2:], head_block),
-                pl.BlockSpec((None, hb, 1, chunk), head_block),
+                pl.BlockSpec((None, hb, dk if vector else 1, chunk),
+                             head_block),
                 pl.BlockSpec((None, 1, chunk), window),
                 pl.BlockSpec((None, chunk, 1), window)],
             out_specs=[lane_spec, state_spec]),
@@ -235,6 +253,7 @@ def chunk_rule_pallas(state, layer, slots, windows, rows, flags, n_pairs,
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       *(x.astype(jnp.int32) for x in (slots, windows, rows, flags)),
-      n_pairs.reshape(1), state, u, on_s, on_v, c["gc"][:, :, None, :],
+      n_pairs.reshape(1), state, u, on_s, on_v,
+      jnp.swapaxes(c["gc"], -1, -2) if vector else c["gc"][:, :, None, :],
       row_of[:, None, :], row_of[:, :, None])
     return o, state

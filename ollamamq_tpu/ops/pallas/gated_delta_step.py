@@ -22,7 +22,10 @@ live rows to the front and pads the list with the last live row (the trash
 row of the state if none is live), the index maps clamp the head-block
 index to the last one there too, so a skipped program names the block the
 program before it already holds and Pallas moves nothing; its body does not
-run. The Mosaic custom call carries this function's name on the device
+run. A decay a KEY CHANNEL (`g` [B, H, dk]: Kimi Delta Attention's, a
+trace-time reading as in ops/gated_delta.py) comes in as columns like k and
+q, [dk, heads], and scales the state's rows: the same broadcast along a
+head's lanes. The Mosaic custom call carries this function's name on the device
 trace (`gated_delta_step_pallas`), where the benchmark's readers find it.
 """
 
@@ -54,7 +57,7 @@ def head_blocks(heads: int, key_dim: int, value_dim: int) -> tuple:
 
 
 def _kernel(layer_ref, slot_ref, row_ref, live_ref, s_ref, qt_ref, kt_ref,
-            v_ref, a_ref, b_ref, o_ref, s_out_ref, *, hg, dv):
+            v_ref, a_ref, b_ref, o_ref, s_out_ref, *, hg, dv, vector=False):
     del layer_ref, slot_ref, row_ref  # the index maps read them
 
     @pl.when(pl.program_id(0) < live_ref[0])
@@ -74,7 +77,8 @@ def _kernel(layer_ref, slot_ref, row_ref, live_ref, s_ref, qt_ref, kt_ref,
         for i in range(lanes // gl):
             at = slice(i * gl, (i + 1) * gl)
             kx, qx = along_lanes(kt, i * hg), along_lanes(qt, i * hg)
-            s = s_ref[:, at] * a_ref[:, at]
+            s = s_ref[:, at] * (along_lanes(a_ref[...], i * hg) if vector
+                                else a_ref[:, at])
             r = b_ref[:, at] * (v_ref[:, at]
                                 - jnp.sum(s * kx, axis=0, keepdims=True))
             s = s + kx * r
@@ -89,8 +93,9 @@ def gated_delta_step_pallas(state, layer, slots, live, reset, q, k, v, g,
     it); layer an int32 scalar; slots [B] each row's state row; live, reset
     [B] bool; q, k [B, Hk, dk] as the convolution left them (the kernel
     takes them a VALUE head: `a_value_head` repeats a key head's for the
-    value heads it serves), v [B, H, dv], g, beta [B, H]. Returns (o [B, H, dv] float32 — zeros for rows that are
-    not live —, state')."""
+    value heads it serves), v [B, H, dv], g, beta [B, H] (g [B, H, dk]: a
+    decay a key channel). Returns (o [B, H, dv] float32 — zeros for rows
+    that are not live —, state')."""
     n, _, dk = q.shape
     h, dv = v.shape[-2:]
     hg, hb = head_blocks(h, dk, dv)
@@ -110,7 +115,9 @@ def gated_delta_step_pallas(state, layer, slots, live, reset, q, k, v, g,
     rows = order[jnp.minimum(jnp.arange(n), jnp.maximum(n_live - 1, 0))]
     slot_list = jnp.where(n_live > 0, slots[rows].astype(jnp.int32),
                           state.shape[1] - 1)
-    alpha = jnp.where(reset[:, None], 0.0, jnp.exp(g))  # opens at zero
+    vector = gated_delta.a_channel(g, beta)
+    alpha = jnp.where(reset[(slice(None),) + (None,) * (g.ndim - 1)], 0.0,
+                      jnp.exp(g))  # opens at zero
 
     def per_row(i, j, layer_ref, slot_ref, row_ref, live_ref):
         return (row_ref[i], 0, jnp.where(i < live_ref[0], j, nblk - 1))
@@ -126,11 +133,12 @@ def gated_delta_step_pallas(state, layer, slots, live, reset, q, k, v, g,
     col_spec = pl.BlockSpec((None, None, dk, hb), per_row_cols)
     state_spec = pl.BlockSpec((None, None, dk, lanes), state_block)
     o, state = pl.pallas_call(
-        functools.partial(_kernel, hg=hg, dv=dv),
+        functools.partial(_kernel, hg=hg, dv=dv, **(
+            {"vector": True} if vector else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(n, nblk),
-            in_specs=[state_spec, col_spec, col_spec, lane_spec, lane_spec,
-                      lane_spec],
+            in_specs=[state_spec, col_spec, col_spec, lane_spec,
+                      col_spec if vector else lane_spec, lane_spec],
             out_specs=[lane_spec, state_spec]),
         out_shape=[jax.ShapeDtypeStruct((n, 1, h * dv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -138,7 +146,7 @@ def gated_delta_step_pallas(state, layer, slots, live, reset, q, k, v, g,
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), slot_list, rows,
       n_live.reshape(1), state, columns(q), columns(k),
-      v.astype(jnp.float32).reshape(n, 1, h * dv), along_lanes(alpha),
-      along_lanes(beta))
+      v.astype(jnp.float32).reshape(n, 1, h * dv),
+      columns(alpha) if vector else along_lanes(alpha), along_lanes(beta))
     o = jnp.where(live[:, None, None], o.reshape(n, h, dv), 0.0)
     return o, state

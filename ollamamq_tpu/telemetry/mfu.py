@@ -84,6 +84,14 @@ def flops_per_token(cfg, context_len: float = 0.0,
     # ...and a window layer attends its last `sliding_window` positions.
     attn += pair * cfg.count("sliding_attention") \
         * min(ctx, cfg.sliding_window) * cfg.q_dim
+    if getattr(cfg, "kda", False):
+        # ...and Kimi Delta Attention's recurrence, which no parameter
+        # counts: a token decays, reads, corrects and reads again a [dk, dv]
+        # float32 state a head — 7 FLOPs an element (the decay a key
+        # channel, S^T k, the rank-one update, S^T q), whatever the context.
+        dense += 7.0 * cfg.count("linear_attention") \
+            * cfg.linear_num_value_heads * cfg.linear_key_head_dim \
+            * cfg.linear_value_head_dim
     return dense + attn
 
 

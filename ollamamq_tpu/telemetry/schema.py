@@ -311,6 +311,13 @@ LIN_CHUNK_PAIRS_TOTAL = REGISTRY.counter(
     "n > 1 tokens from stream token s touches (s + n - 1) // 64 - s // 64 "
     "+ 1 windows (on a TPU, the programs a head block of one "
     "chunk_rule_pallas launch)", labels=("model",))
+LIN_PREPARE_WINDOWS_TOTAL = REGISTRY.counter(
+    "ollamamq_lin_prepare_windows_total",
+    "64-token windows of the stream the delta rule's chunked form solved in "
+    "launched ragged steps, a linear layer's worth: every window of the "
+    "padded stream, ceil(stream tokens / 64), whether a span lies in it or "
+    "not (ops/gated_delta._prepare solves them all at once; 0 for a fused "
+    "scan, which has no window)", labels=("model",))
 HBM_SSM_STATE_BYTES = REGISTRY.gauge(
     "ollamamq_hbm_ssm_state_bytes",
     "Bytes the state-space mixers' per-slot recurrent state occupies per "

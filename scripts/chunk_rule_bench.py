@@ -6,8 +6,10 @@ the chip: `chiprun --timeout 1500 -- python scripts/chunk_rule_bench.py`
 For each of the four callers' published shapes (H, Hk, dk, dv, plain) —
 Qwen3-Next's rule (32, 16, 128, 128), Olmo-Hybrid's (30, 30, 96, 192: a
 head's lanes are no whole tile), Falcon-H1's mixer (32, 2, 256, 128, plain: B
-and C a group) and MiniCPM-SALA's lightning layers (32, 32, 128, 128, plain)
-— and each stream of 512 tokens over 64 rows
+and C a group), MiniCPM-SALA's lightning layers (32, 32, 128, 128, plain)
+and Kimi Delta Attention's (32, 32, 128, 128 at the VECTOR reading: a decay a
+key channel, g [T, H, dk] from -1e-3 to -20 a token a channel, both ends in
+one head) — and each stream of 512 tokens over 64 rows
 
   - `rows5`, `rows56`: 5 / 56 one-token rows and padding — no pair: what
     `ragged` costs a layer before its first pair (`_step_rows`, `_prepare`
@@ -58,11 +60,14 @@ from ollamamq_tpu.ops import gated_delta
 from ollamamq_tpu.ops.pallas import chunk_rule
 
 MODULES["chunk_rule"] = chunk_rule
-# (name, H, Hk, dk, dv, plain)
+# (name, H, Hk, dk, dv, plain); VECTOR: the shapes run with a decay a key
+# channel.
 SHAPES = (("qwen3-next", 32, 16, 128, 128, False),
           ("olmo-hybrid", 30, 30, 96, 192, False),
           ("falcon-h1", 32, 2, 256, 128, True),
-          ("minicpm-sala", 32, 32, 128, 128, True))
+          ("minicpm-sala", 32, 32, 128, 128, True),
+          ("kimi-linear", 32, 32, 128, 128, False))
+VECTOR = ("kimi-linear",)
 T, SLOTS, LAYERS, LAYER, LAUNCHES = 512, 64, 2, 1, 32
 CLOSE = 1e-5  # of the largest entry compared
 # stream: (one-token rows, spans, which rows open their state)
@@ -131,6 +136,9 @@ def main() -> int:
         if plain:  # as a mixer hands them: no norm behind it
             q, k = q * dk ** -0.5, k * dk ** -0.5
         g = -jnp.abs(f(T, h)) * 0.3
+        vector = name in VECTOR
+        if vector:  # a channel a decade from -1e-3 to -20, a token its own
+            g = -jnp.abs(f(T, h, dk)) * 10.0 ** jnp.linspace(-3, 1.3, dk)
         beta = jnp.ones((T, h)) if plain else jax.nn.sigmoid(f(T, h)) * 2
         state0 = f(LAYERS, SLOTS + 1, dk, h * dv)
 
@@ -159,7 +167,8 @@ def main() -> int:
                     names.pop("chunk_rule.blocks", None)
                     row = {"shape": name, "stream": which, "path": path,
                            "pairs": int(n_w.sum()), "set": names,
-                           "blocks": chunk_rule.blocks(h, dk, dv, plain)}
+                           "blocks": chunk_rule.blocks(h, dk, dv, plain,
+                                                       vector)}
                     try:
                         diffs, far = apart(jax.jit(fn)(v, state0, *meta),
                                            want, meta)

@@ -73,8 +73,18 @@ def test_note_returns_the_tokens_that_stopped_below_the_exit_layer():
     assert noted["xattn_rows"] == 8
     # the per-slot state: one row opened, two carried; a 1-token row through
     # the step form, 21 tokens of spans through the chunked one — two spans
-    # inside the stream's first 64-token window: two (row, window) pairs
+    # inside the stream's first 64-token window: two (row, window) pairs, and
+    # the one window of the 32-token stream solved
     noted, _ = _noted("test-tiny-olmo-hybrid", scan=False)
-    assert [noted[f] for f in KINDS["lin"].fields] == [1, 2, 1, 21, 2]
+    assert [noted[f] for f in KINDS["lin"].fields] == [1, 2, 1, 21, 2, 1]
+    # ...and the same beside latent attention as a layer KIND, whose rows
+    # are counted as the latent families' are (no `attn_*`, no `dsa_*`)
+    noted, _ = _noted("test-tiny-kimi-linear", scan=False)
+    assert [noted[f] for f in KINDS["lin"].fields] == [1, 2, 1, 21, 2, 1]
+    assert (noted["mla_rows"], noted["mla_pairs"], noted["mla_ctx_rows"]) \
+        == (22, 9 + 15 + sum(range(25, 41)), 9 + 5 + 40)
+    assert not any(f.startswith(("attn_", "dsa_")) for f in noted)
+    assert _noted("test-tiny-kimi-linear", scan=True)[0][
+        "lin_prepare_windows"] == 0
     noted, _ = _noted("test-tiny-lfm2", scan=True)
     assert (noted["conv_state_resets"], noted["conv_state_carried"]) == (0, 2)

@@ -390,7 +390,8 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
                                    "test-tiny-qwen3-next",
                                    "test-tiny-falcon-h1",
                                    "test-tiny-phi4-flash",
-                                   "test-tiny-minicpm-sala"])
+                                   "test-tiny-minicpm-sala",
+                                   "test-tiny-kimi-linear"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
@@ -421,6 +422,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         + (llama.LINEAR_SCOPES if rt.cfg.count("linear_attention")
            and not rt.cfg.lightning_nh else ()) \
         + (llama.LIGHTNING_SCOPES if rt.cfg.lightning_nh else ()) \
+        + (llama.KDA_SCOPES if rt.cfg.kda else ()) \
         + (llama.BSA_SCOPES if rt.cfg.count("sparse_attention") else ()) \
         + (llama.SSM_SCOPES if rt.cfg.count("attention_ssm") else ()) \
         + (llama.HYBRID_SCOPES if rt.cfg.mb_per_layer else ()) \
@@ -431,7 +433,9 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         from ollamamq_tpu.ops import mla
 
         scopes = tuple(s for s in scopes if s not in (
-            "attn_qkv", "kv_write", "attention")) + mla.SCOPES \
+            "attn_qkv", "kv_write", "attention")) + tuple(
+                s for s in mla.SCOPES  # (no indexer: no `dsa_*` stage)
+                if rt.cfg.index_topk or not s.startswith("dsa_")) \
             + moe.SHARED_SCOPES
     seen = {}
 
@@ -467,7 +471,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         assert re.search(r"module @jit_%s\b" % names[site], text), \
             text[:200]
         for scope in scopes:
-            if scope in ("early_exit_gather", "bsa_span") \
+            if scope in ("early_exit_gather", "bsa_span", "kda_prepare") \
                     and site == "decode":
                 continue  # (the ragged program's alone)
             # A whole component of an op's name stack ("embed/gather"),
@@ -487,6 +491,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         | set(llama.LINEAR_SCOPES) | set(llama.SSM_SCOPES) \
         | set(llama.HYBRID_SCOPES) | set(mla.SCOPES) \
         | set(llama.BSA_SCOPES) | set(llama.LIGHTNING_SCOPES) \
+        | set(llama.KDA_SCOPES) \
         | set(moe.SHARED_SCOPES) | set(llama.GATE_SCOPES) \
         | set(moe.SHARED_GATE_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented

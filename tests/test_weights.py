@@ -437,3 +437,77 @@ def test_safetensors_qwen3_next_layout_is_unwoven(tmp_path, caplog):
     for name in ("embed", "final_norm", "lm_head"):
         np.testing.assert_array_equal(np.asarray(params[name]),
                                       np.asarray(src[name]))
+
+
+def test_safetensors_kimi_linear_layout(tmp_path):
+    """The served tree of the tiny Kimi-Linear written under the published
+    `kimi_linear` names (three projections and three depthwise convolutions a
+    KDA layer, `A_log` [1, 1, H, 1], `q_proj` in BOTH kinds of layer, the
+    shared expert and the selection bias inside `block_sparse_moe`) loads
+    back to the same tree, the float32 buffers float32."""
+    import jax
+    import jax.numpy as jnp
+    from safetensors.numpy import save_file
+
+    from ollamamq_tpu.models import llama
+
+    cfg = MODEL_CONFIGS["test-tiny-kimi-linear"]
+    want = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    lp = {k: np.asarray(v) for k, v in want["layers"].items()}
+    t = {"model.embed_tokens.weight": np.asarray(want["embed"]),
+         "model.norm.weight": np.asarray(want["final_norm"]),
+         "lm_head.weight": np.asarray(want["lm_head"])}
+    kinds = {"linear_attention": 0, "full_attention": 0}
+    hd = cfg.linear_key_dim
+    for i, kind in enumerate(cfg.layer_types):
+        p, n = f"model.layers.{i}.", kinds[kind]
+        kinds[kind] += 1
+        t[p + "input_layernorm.weight"] = lp["attn_norm"][i]
+        t[p + "post_attention_layernorm.weight"] = lp["mlp_norm"][i]
+        a = p + "self_attn."
+        if kind == "linear_attention":
+            for j, name in enumerate("qkv"):
+                at = slice(j * hd, (j + 1) * hd)
+                t[a + f"{name}_proj.weight"] = lp["lin_in"][n][:, at].T
+                t[a + f"{name}_conv1d.weight"] = lp["lin_conv_w"][n][
+                    at, None, :]
+            t[a + "A_log"] = lp["lin_A_log"][n].reshape(1, 1, -1, 1)
+            t[a + "dt_bias"] = lp["lin_dt_bias"][n]
+            t[a + "o_norm.weight"] = lp["lin_norm"][n]
+            for ours, theirs in (
+                    ("kda_fa", "f_a_proj"), ("kda_fb", "f_b_proj"),
+                    ("kda_b", "b_proj"), ("kda_ga", "g_a_proj"),
+                    ("kda_gb", "g_b_proj"), ("lin_out", "o_proj")):
+                t[a + theirs + ".weight"] = lp[ours][n].T
+        else:
+            for ours, theirs in (("wq", "q_proj"),
+                                 ("mla_wdkv", "kv_a_proj_with_mqa"),
+                                 ("mla_wukv", "kv_b_proj"), ("wo", "o_proj")):
+                t[a + theirs + ".weight"] = lp[ours][n].T
+            t[a + "kv_a_layernorm.weight"] = lp["mla_kv_norm"][n]
+        if i < cfg.num_dense_layers:
+            for ours, theirs in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                                 ("w_down", "down_proj")):
+                t[p + f"mlp.{theirs}.weight"] = lp[ours][i].T
+            continue
+        e, m = i - cfg.num_dense_layers, p + "block_sparse_moe."
+        t[m + "gate.weight"] = lp["w_router"][e].T
+        t[m + "gate.e_score_correction_bias"] = lp["router_bias"][e]
+        for ours, theirs in (("ws_gate", "gate_proj"), ("ws_up", "up_proj"),
+                             ("ws_down", "down_proj")):
+            t[m + f"shared_experts.{theirs}.weight"] = lp[ours][e].T
+        for x in range(cfg.num_experts):
+            for ours, theirs in (("we_gate", "w1"), ("we_up", "w3"),
+                                 ("we_down", "w2")):
+                t[m + f"experts.{x}.{theirs}.weight"] = lp[ours][e, x].T
+    save_file({k: np.ascontiguousarray(v, np.float32) for k, v in t.items()},
+              str(tmp_path / "model.safetensors"))
+    got = weights.load_safetensors(cfg, str(tmp_path), dtype=jnp.float32)
+    assert set(got["layers"]) == set(want["layers"])
+    for name, w in want["layers"].items():
+        np.testing.assert_array_equal(np.asarray(got["layers"][name]),
+                                      np.asarray(w), err_msg=name)
+        assert got["layers"][name].dtype == w.dtype, name
+    for name in ("embed", "lm_head", "final_norm"):
+        np.testing.assert_array_equal(np.asarray(got[name]),
+                                      np.asarray(want[name]))

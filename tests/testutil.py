@@ -434,6 +434,40 @@ def qwen3_next_keys(mc) -> dict:
             "vocab_size": mc.vocab_size}
 
 
+def kimi_linear_reference():
+    """...and of Kimi Delta Attention beside NoPE latent attention
+    (benchmarks/reference/kimi_linear_decoder.py)."""
+    return _reference("kimi_linear_decoder")
+
+
+def kimi_linear_keys(mc) -> dict:
+    """What a configuration file says of the Kimi-Linear ModelConfig `mc`, in
+    the published spellings (and this repo's, for the share held): all that
+    reference reads."""
+    return {"hidden_size": mc.hidden_size,
+            "num_attention_heads": mc.num_heads,
+            "rms_norm_eps": mc.rms_norm_eps,
+            "layer_types": list(mc.layer_types),
+            "linear_attn_config": {k: list(v) if isinstance(v, tuple) else v
+                                   for k, v in mc.linear_attn_config},
+            "kv_lora_rank": mc.kv_lora_rank, "q_lora_rank": None,
+            "qk_nope_head_dim": mc.qk_nope_head_dim,
+            "qk_rope_head_dim": mc.qk_rope_head_dim,
+            "v_head_dim": mc.v_head_dim, "mla_use_nope": mc.mla_use_nope,
+            "num_dense_layers": mc.num_dense_layers,
+            "intermediate_size": mc.intermediate_size,
+            "num_experts": mc.num_experts,
+            "router_experts": mc.router_width,
+            "expert_offset": mc.expert_offset,
+            "num_experts_per_token": mc.num_experts_per_tok,
+            "moe_router_activation_func": mc.router_score,
+            "moe_renormalize": mc.norm_topk_prob,
+            "routed_scaling_factor": mc.routed_scaling_factor,
+            "moe_intermediate_size": mc.expert_width,
+            "num_shared_experts": mc.n_shared_experts,
+            "vocab_size": mc.vocab_size}
+
+
 def openpangu_reference():
     """...and of the latent-attention family with no indexer, sandwich norms
     and a prediction module (benchmarks/reference/openpangu_ultra_decoder.py)."""
