@@ -29,7 +29,7 @@ from ollamamq_tpu.telemetry import schema as tm
 class KernelCounts(NamedTuple):
     """The Pallas kernels' own tests of how they serve a stream, each
     `(tokens, stream_len) -> count`: the ragged kernel's (tokens served a
-    whole stretch at a time) and, with an indexer, the masked latent
+    whole stretch at a time) and, of a latent model, the latent attention
     kernel's (tokens attended in the expanded form; rows a layer then takes
     through the absorbed form's contractions)."""
     tall_tokens: Callable
@@ -42,7 +42,7 @@ def kernel_counts(cfg: ModelConfig, attn_impl: str) -> Optional[KernelCounts]:
     if attn_impl != "pallas":
         return None
     from ollamamq_tpu.ops.pallas.kv_contract import tall_tokens
-    if not cfg.index_topk:
+    if not cfg.kv_lora_rank:
         return KernelCounts(tall_tokens)
     from ollamamq_tpu.ops.pallas.mla_attention import (absorbed_rows,
                                                        wide_tokens)
@@ -105,25 +105,31 @@ def rule_counts(cfg, page_size, s: Step) -> tuple:
     return slot_state_counts(cfg, page_size, s) + (windows,)
 
 
+def _expanded_counts(s: Step) -> tuple:
+    """The latent kernel's own counts of the expanded form, a layer's worth
+    (0 for a scan and on the jnp path): the tokens it attends so, and the
+    rows a layer takes through the absorbed form's contractions."""
+    if s.kernels is None or s.scan:
+        return 0, 0
+    return (s.kernels.wide_tokens(s.tokens, s.stream_len),
+            s.kernels.absorbed_rows(s.tokens, s.stream_len))
+
+
 def latent_counts(cfg, page_size, s: Step) -> tuple:
     """Latent attention under the indexer, a layer's worth: the query
     tokens, the cached positions the indexer scored for them (a token at
     position p scores p + 1) and those attention then saw (min(p + 1,
     index_topk)); `dsa_step_*`: the part of the two that ONE-TOKEN rows
     account for (a decode row, a scan's pass: rows that share their cached
-    positions with no other query of the launch); then the kernel's own
-    counts of the expanded form (0 for a scan and on the jnp path)."""
-    counts = np.zeros(7, np.int64)
+    positions with no other query of the launch); then `_expanded_counts`."""
+    counts = np.zeros(5, np.int64)
     for n, kv in zip(s.tokens, s.kv):
         ctx = np.arange(kv - n + 1, kv + 1)
         both = (int(ctx.sum()), int(np.minimum(ctx, cfg.index_topk).sum()))
         counts[:3] += (n,) + both
         if s.scan or n == 1:
             counts[3:5] += both
-    if s.kernels is not None and not s.scan:
-        counts[5] = s.kernels.wide_tokens(s.tokens, s.stream_len)
-        counts[6] = s.kernels.absorbed_rows(s.tokens, s.stream_len)
-    return tuple(counts.tolist())
+    return tuple(counts.tolist()) + _expanded_counts(s)
 
 
 def dense_latent_counts(cfg, page_size, s: Step) -> tuple:
@@ -131,10 +137,13 @@ def dense_latent_counts(cfg, page_size, s: Step) -> tuple:
     the `dsa_*` fields have no honest value there), a launch's worth — the
     trunk's layers and the prediction module's block do the same: the query
     tokens, the causal pairs, and the cached rows a launch has to read at
-    the least: each span's context once (a scan's pass: each slot's)."""
+    the least: each span's context once (a scan's pass: each slot's); then
+    `_expanded_counts` (a trunk layer's: the module's launch expands
+    nothing)."""
     n, kv = np.asarray(s.tokens, np.int64), np.asarray(s.kv, np.int64)
     pairs = _pairs(n, kv)
-    return int(n.sum()), int(pairs.sum()), int((pairs if s.scan else kv).sum())
+    return (int(n.sum()), int(pairs.sum()),
+            int((pairs if s.scan else kv).sum())) + _expanded_counts(s)
 
 
 def attn_counts(cfg, page_size, s: Step) -> tuple:
@@ -269,8 +278,10 @@ KINDS = {
         latent_counts),
     "dense_latent": Kind(
         lambda cfg: cfg.kv_lora_rank and not cfg.index_topk,
-        ("mla_rows", "mla_pairs", "mla_ctx_rows"),
-        (tm.MLA_ROWS_TOTAL, None, None), dense_latent_counts),
+        ("mla_rows", "mla_pairs", "mla_ctx_rows", "mla_wide_tokens",
+         "mla_absorbed_rows"),
+        (tm.MLA_ROWS_TOTAL, None, None, tm.MLA_WIDE_TOKENS_TOTAL,
+         tm.MLA_ABSORBED_ROWS_TOTAL), dense_latent_counts),
     # Nothing for an encoder, a model with latent attention or one with
     # no attention layer.
     "attn": Kind(

@@ -839,8 +839,8 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     `attn_fn(q_abs [B, T, H, lanes], row [B, T, lanes], (q_idx [B, T, Hi,
     di], k_idx [B, T, di], w_idx [B, T, Hi] float32)) -> [B, T, H, c]`, the
     attended latent a head; the third argument is None for a model with no
-    indexer (`index_topk` 0). With an indexer the schedule also gets the
-    EXPANDED form's operands, `expanded=(q [B, T, H, dn + lanes - c]:
+    indexer (`index_topk` 0). The schedule also gets the EXPANDED form's
+    operands, `expanded=(q [B, T, H, dn + lanes - c]:
     [q_nope | q_rope | 0] scaled, w [H, dn + dv, c]: [W_uk,h | W_uv,h]^T a
     head)`, and may answer `(o_lat, o_v [B, T, H, dv], wide [B, T] bool)`:
     the tokens of `wide` have their result in `o_v`, through W_uv already
@@ -862,7 +862,7 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     Hi, di = cfg.index_n_heads, cfg.index_head_dim
     wukv = lp["mla_wukv"].reshape(c, H, dn + dv)
     index = None  # no indexer: every cached position is attended
-    expanded = o_v = None
+    o_v = None
     pad = cfg.latent_lanes - cfg.latent_dim
     lead, few = few or (T, None)
 
@@ -912,11 +912,11 @@ def _latent_attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
             ).reshape(B, T, H, -1)
         if cfg.index_topk:
             index = _index_inputs(cfg, lp, h, c_q, positions)
-            expanded = ((jnp.concatenate(
-                [q[..., :dn].astype(jnp.float32), q_rope.astype(jnp.float32),
-                 jnp.zeros((B, T, H, pad), jnp.float32)], axis=-1)
-                * cfg.attn_scale).astype(h.dtype),
-                jnp.transpose(wukv, (1, 2, 0)))
+        expanded = ((jnp.concatenate(
+            [q[..., :dn].astype(jnp.float32), q_rope.astype(jnp.float32),
+             jnp.zeros((B, T, H, pad), jnp.float32)], axis=-1)
+            * cfg.attn_scale).astype(h.dtype),
+            jnp.transpose(wukv, (1, 2, 0)))
     o_lat = attn_fn(q_abs, row, index, expanded)
     if isinstance(o_lat, tuple):
         o_lat, o_v, wide = o_lat
@@ -1609,7 +1609,7 @@ def forward_ragged(
             slot_ids, kv_len, q_len, cfg.sliding_window, rows, page_size,
             tokens.shape[0])
     few = None
-    if cfg.index_topk:  # once for the layers: the masked kernel's launch
+    if cfg.kv_lora_rank:  # once for the latent layers: their launch
         few = mla.absorbed_lead(
             attn_impl, q_start, q_len, tokens.shape[0], cfg.num_heads,
             cfg.latent_lanes, cfg.kv_lora_rank, cfg.qk_nope_head_dim,

@@ -10,8 +10,8 @@ lanes. Attention runs in the ABSORBED form: `q_abs[t, i] = [q_nope[t, i]
 W_uk,i^T | q_rope[t, i]] * scale` against the latent row, the output's latent
 `sum_s p(t, i, s) c_kv(s)` through W_uv,i afterwards — the expanded keys and
 values never exist in HBM. (One query token cannot pay for expanding its
-context. A prefill span of a few hundred tokens can: the masked Pallas kernel
-attends such a span in the EXPANDED form — `(q W_uk^T) . c = q . (c W_uk)^T`
+context. A prefill span of a few hundred tokens can: the Pallas kernels
+attend such a span in the EXPANDED form — `(q W_uk^T) . c = q . (c W_uk)^T`
 — a block's keys and values expanded in VMEM once a group of heads for all
 the span's tokens; ops/pallas/mla_attention.py, `WIDE`.)
 
@@ -160,7 +160,7 @@ def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
     None, idx_pool unread): attend alone, over every cached position.
     `name`: the attention launch's name on the device trace where it is not
     the kernel's own (the prediction module's). `expanded`: the expanded
-    form's (q [T, H, .], w [H, ., rank]), for the masked kernel alone — which
+    form's (q [T, H, .], w [H, ., rank]), for the Pallas kernels — which
     then may return (o, o_v, wide) (mla_sparse_paged_attention_pallas)."""
     if impl == "pallas":
         from ollamamq_tpu.ops.pallas import mla_attention as kernels
@@ -170,7 +170,7 @@ def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
                 return kernels.mla_dense_paged_attention_pallas(
                     q_abs, lat_pool, layer, page_table, q_start, q_lens,
                     kv_lens, page_size, rank, tile=tile, interpret=interpret,
-                    name=name)
+                    name=name, expanded=expanded)
         with jax.named_scope("dsa_index"):
             scores = kernels.dsa_index_pallas(
                 q_idx, w_idx, idx_pool, layer, page_table, q_start, q_lens,
@@ -201,7 +201,7 @@ def attend(impl: str, q_abs, q_idx, w_idx, lat_pool, idx_pool, layer,
 def absorbed_lead(impl: str, q_start, q_lens, tokens: int, heads: int,
                   lanes: int, rank: int, nope: int, v: int):
     """(rows, few) for a layer whose launch over a stream of `tokens` holds
-    the masked kernel's expanded body, else None (the jnp path, a rung under
+    the expanded body, else None (the jnp path, a rung under
     WIDE: every row is attended in the absorbed form, nothing to choose).
     `few` — a scalar on the device, of the step's own spans — says that no
     stream row at or behind `rows` reads the absorbed form: each is a wide

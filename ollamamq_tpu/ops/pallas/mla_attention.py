@@ -46,7 +46,8 @@ selection's two operands and its comparison compiled out (`masked` false) —
 a causal walk over the latent pages. There a span of at most SHORT (2)
 tokens — a decode row, or `--spec`'s verify span `[t, draft]` of one draft —
 folds the row-heads of those tokens alone, not the tile's (the masked
-kernel's path of its own is for one-token rows).
+kernel's path of its own is for one-token rows). A span of WIDE tokens or
+more is the expanded programs' there too (PR 64: the docstring's end).
 
 Names: exactly one launch a layer a forward pass carries `paged_attention`
 in its name (benchmarks/layer_metrics/_ops.py divides such launches by the
@@ -102,25 +103,27 @@ The expanded body (PR 49). What the absorbed trip multiplies is 640 + 512 =
 and expanding a cached position's key and value of one head from its latent
 row costs 512 x 256 x 2 FLOPs ONCE for every query token that attends it —
 nothing a decode row can pay, half the absorbed form's work for a span of
-512. So a launch of the masked kernel over a stream of WIDE..WIDE_STREAM
-tokens (`expands`: the 512-token rung; a trace-time choice, smaller rungs
-hold the tiles alone) takes the expanded form's operands too — q as `[q_nope
-| q_rope | 0]` head-major and `[W_uk,h | W_uv,h]^T` a head, the rank minor as
-the stack lives on the device (llama.CONTRACTED_MINOR: no re-laid copy) — and
-runs `heads / WIDE_GROUP` EXPANDED programs before its tiles, in the ONE
-`pallas_call` (the readers count launches by name): `_expanded`. A program's
-rows are the stream's tokens themselves; it walks the context of every span
-of at least WIDE tokens (run time, from `q_lens`) once, with the block's
-`[tokens, 256]` selection scores prefetched beside its pages; a block: the
-mask once for the group; `[K_h^T ; V_h^T]` of the group's 16 heads in ONE
-contraction `[16 x 256, 512] . [256, 512]^T` whose stationary operand is the
-block (a contraction a head re-latches the eight weight tiles a head: below);
-then a head at a time `[512, 256] . [256, 256]` scores, the tile's online
-softmax (`m` lane-replicated, `l` lane-partial), `[512, 256] . [128, 256]^T`
-into `acc [512, 128]`, WIDE_CHAIN heads in one straight-line body. The
-result leaves in `v_head_dim` lanes. The tiles skip a wide span's sequence,
-and a tile inside one returns at once; every other row — decode rows,
-shorter spans — is the absorbed tiles' to the bit.
+512. So a launch of either attention kernel over a stream of
+WIDE..WIDE_STREAM tokens (`expands`: the 512-token rung; a trace-time choice,
+smaller rungs hold the tiles alone) takes the expanded form's operands too —
+q as `[q_nope | q_rope | 0]` head-major and `[W_uk,h | W_uv,h]^T` a head, the
+rank minor as the stack lives on the device (llama.CONTRACTED_MINOR: no
+re-laid copy) — and runs `heads / WIDE_GROUP` EXPANDED programs before its
+tiles, in the ONE `pallas_call` (the readers count launches by name):
+`_expanded`. A program's rows are the stream's tokens themselves; it walks
+the context of every span of at least WIDE tokens (run time, from `q_lens`)
+once, with the block's `[tokens, 256]` selection scores prefetched beside its
+pages (the masked kernel's; a dense launch has neither the scores nor their
+buffers, and its mask is `mine & causal`); a block: the mask once for the
+group; `[K_h^T ; V_h^T]` of the group's 16 heads in ONE contraction `[16 x
+256, 512] . [256, 512]^T` whose stationary operand is the block (a
+contraction a head re-latches the eight weight tiles a head: below); then a
+head at a time `[512, 256] . [256, 256]` scores, the tile's online softmax
+(`m` lane-replicated, `l` lane-partial), `[512, 256] . [128, 256]^T` into
+`acc [512, 128]`, WIDE_CHAIN heads in one straight-line body. The result
+leaves in `v_head_dim` lanes. The tiles skip a wide span's sequence, and a
+tile inside one returns at once; every other row — decode rows, shorter
+spans — is the absorbed tiles' to the bit.
   Measured (my chip runs, PR 49, one v5e; the same script and step, `latent`
   against `latent_wide`; µs a (16 tokens, 256 keys) of the span, one-token
   trips taken off):
@@ -160,6 +163,19 @@ shorter spans — is the absorbed tiles' to the bit.
   WIDE: a program's rows are the rung's whatever the span's length, so the
   expanded form costs a span of L tokens what it costs 512 and the tiles
   cost it L / 512 of theirs: it wins from L = 318 (8 k, 16 k) to 352 (4 k).
+  The DENSE launch (PR 64; my chip run, PR 64, one v5e; the same script and
+  step, `latent_dense` against `latent_dense_wide`; ms a launch at 4 k / 8 k
+  / 12 k / 16 k of context). At Kimi-Linear's 32 heads — two expanded
+  programs; a tile of 32 tokens is 1024 row-heads — the absorbed tiles 1.039
+  / 2.006 / 2.973 / 3.936, this body **0.668 / 1.212 / 1.760 / 2.307** (−36 /
+  −40 / −41 / −41 %): 16.3 µs a (program, block) at 8 k, the masked kernel's
+  17.1 less its selection, where the tiles pay 3.59 a (tile, block). At
+  openPangu's 128 heads 3.687 / 7.061 / 10.420 / 13.785 → 2.548 / 4.569 /
+  6.597 / 8.631 (−31 / −35 / −37 / −37 %: the masked kernel's pair, re-read
+  in the same call at 3.707 / 7.044 / 10.387 / 13.718 → 2.543 / 4.511 / 6.474
+  / 8.444), and at its cell's contexts, where a walk is 4 or 8 blocks and a
+  program's fixed cost shows: 1 k 1.162 → 1.026 (−12 %), 2 k 2.002 → 1.534
+  (−23 %). One-token rows 0.9–1.6 µs a trip, the tiles' either way.
 """
 
 from __future__ import annotations
@@ -198,8 +214,8 @@ VMEM_LIMIT = 96 * 1024 * 1024
 SHORT = 2
 # The dense kernel's launch by the prediction module, on the device trace.
 MTP_NAME = "mtp_latent_attention_pallas"
-# The masked kernel's EXPANDED body (the docstring's last part): tokens of a
-# prefill span from which its programs expand each block's keys and values
+# An attention launch's EXPANDED body (the docstring's last part): tokens of
+# a prefill span from which its programs expand each block's keys and values
 # (under it the absorbed tiles cost less), heads a program, and heads a
 # straight-line body of a program's loop over them.
 WIDE = 320
@@ -217,7 +233,7 @@ def _walk(t, tile, refs, hbm, buf, sem, page_size, num_seqs, block, update,
     hi, base)` — rows [lo, hi) of the tile are the sequence's, row i at
     position base + i. `want(s)`: which of those sequences are walked at
     all; `also` = (start(b, slot), wait(slot)): what else travels with a
-    block (the masked kernel's expanded body: module docstring)."""
+    block (the expanded body's selection scores: module docstring)."""
     layer_ref, first_ref, q_start_ref, q_len_ref, kv_len_ref, pt_ref = refs
     ppb = block // page_size
     layer = layer_ref[0]
@@ -345,13 +361,16 @@ def _attend_kernel(*refs, tile, heads, rank, page_size, num_seqs, block,
     else:  # no selection: neither its scores nor its threshold is here
         q_ref, *refs = refs[6:]
     if wide:  # the expanded body's operands, result and scratch (_expanded)
-        hbm, *ins, o_ref, ow_ref, buf, sem, m_ref, l_ref, acc_ref = refs[:12]
+        hbm, *refs = refs
+        n = 4 if masked else 2  # q, w and, of a selection, thr and scores
+        ins, (o_ref, ow_ref, buf, sem, m_ref, l_ref, acc_ref, *scratch) = (
+            refs[:n], refs[n:])
         q_len_ref = meta[3]
 
         @pl.when(lax.lt(pl.program_id(0), wide.lead))
         def _():
-            _expanded(meta, ins, hbm, ow_ref, buf, sem, refs[12:], wide,
-                      rank, page_size, num_seqs, block)
+            _expanded(meta, ins, hbm, ow_ref, buf, sem, scratch, wide, rank,
+                      page_size, num_seqs, block)
     else:
         hbm, o_ref, buf, sem, m_ref, l_ref, acc_ref = refs
 
@@ -485,25 +504,35 @@ def _expanded(meta, ins, hbm, o_ref, buf, sem, scratch, wide, rank,
     (float32 sums, rounded to the pool's dtype as the absorbed q is), then a
     head at a time scores the rows `[q_nope | q_rope] . [K_h^T ; k_rope^T]`
     under the same mask and folds them into the same online softmax as a
-    tile does, `acc += p . V_h`: the result leaves in `v_head_dim` lanes."""
-    q_ref, w_ref, thr_ref, i_hbm = ins
-    ibuf, isem, bias_ref, m_ref, l_ref, acc_ref, kvt_ref = scratch
+    tile does, `acc += p . V_h`: the result leaves in `v_head_dim` lanes.
+    The dense kernel's launch hands no selection (`ins` is q and w alone):
+    nothing travels with a block — neither its operands nor its scratch is
+    here — and a row attends every position of its sequence up to its own."""
+    q_ref, w_ref, *selection = ins
+    *travels, bias_ref, m_ref, l_ref, acc_ref, kvt_ref = scratch
+    masked = bool(selection)
     q_len_ref = meta[3]
     tokens, per, dn = q_ref.shape[1], w_ref.shape[1], wide.nope
     m_ref[...] = jnp.full_like(m_ref, M_INIT)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def columns(b):
-        return pl.ds(pl.multiple_of(lax.mul(b, block), block), block)
+    also = None
+    if masked:
+        (thr_ref, i_hbm), (ibuf, isem) = selection, travels
 
-    def start(b, slot):  # the selection's scores of the block's positions
-        pltpu.make_async_copy(i_hbm.at[:, columns(b)], ibuf.at[slot],
-                              isem.at[slot]).start()
+        def columns(b):
+            return pl.ds(pl.multiple_of(lax.mul(b, block), block), block)
 
-    def wait(slot):
-        pltpu.make_async_copy(i_hbm.at[:, columns(0)], ibuf.at[slot],
-                              isem.at[slot]).wait()
+        def start(b, slot):  # the scores of the block's positions
+            pltpu.make_async_copy(i_hbm.at[:, columns(b)], ibuf.at[slot],
+                                  isem.at[slot]).start()
+
+        def wait(slot):
+            pltpu.make_async_copy(i_hbm.at[:, columns(0)], ibuf.at[slot],
+                                  isem.at[slot]).wait()
+
+        also = (start, wait)
 
     def contract(a, b, b_dim):
         return lax.dot_general(a, b, (((1,), (b_dim,)), ((), ())),
@@ -522,8 +551,10 @@ def _expanded(meta, ins, hbm, o_ref, buf, sem, scratch, wide, rank,
         row, mine = _rows_of((tokens, block), lo, hi)
         key = lax.add(lax.broadcasted_iota(jnp.int32, (tokens, block), 1),
                       key0)
-        keep = functools.reduce(jnp.logical_and, (
-            mine, key <= row + base, ibuf[slot] >= thr_ref[...]))
+        keep = [mine, key <= row + base]
+        if masked:
+            keep.append(ibuf[slot] >= thr_ref[...])
+        keep = functools.reduce(jnp.logical_and, keep)
         bias_ref[...] = jnp.where(keep, 0.0, NEG_INF)
         kvt_ref[...] = contract(w_ref[...].reshape(-1, rank), rows[:, :rank],
                                 1).astype(rows.dtype)
@@ -554,7 +585,7 @@ def _expanded(meta, ins, hbm, o_ref, buf, sem, scratch, wide, rank,
         lax.fori_loop(0, wide.group // wide.chain, heads, ())
 
     _walk(0, tokens, meta, hbm, buf, sem, page_size, num_seqs, block, update,
-          lambda s: lax.ge(q_len_ref[s], WIDE), (start, wait))
+          lambda s: lax.ge(q_len_ref[s], WIDE), also)
     for h in range(wide.group):
         o_ref[h] = (acc_ref[h] / jnp.maximum(
             jnp.sum(l_ref[h], axis=1, keepdims=True), 1e-30)
@@ -566,7 +597,7 @@ def _launch(kernel, tile, block, inputs, pool, out_lanes, out_dtype, scratch,
             name=None, wide=None):
     """One program a tile: the tile's blocks of `inputs` ([n_tiles, rows,
     lanes] each) in VMEM, the pool left in HBM, two buffers of `block`
-    tokens. `wide` (the masked attention kernel's, `_wide_launch`): its
+    tokens. `wide` (an attention kernel's, `_wide_launch`): its
     `lead` expanded programs run before the tiles', their operands behind
     the pool, their result and scratch last."""
     n_tiles = inputs[0].shape[0]
@@ -705,18 +736,21 @@ def mla_dense_paged_attention_pallas(q_abs, lat_pool, layer, page_table,
                                      q_start, q_lens, kv_lens, page_size: int,
                                      rank: int, tile: int | None = None,
                                      interpret: bool = False,
-                                     name: str | None = None):
+                                     name: str | None = None, expanded=None):
     """o [T, H, rank] with NO selection: every cached position up to the
     token's own (a model with no indexer). `name`: the launch's name on the
-    device trace, where it is not this function's."""
+    device trace, where it is not this function's (the prediction module's:
+    such a launch holds the tiles alone, whatever it is handed). `expanded`
+    and what then comes back: mla_sparse_paged_attention_pallas."""
     return _attention(q_abs, None, lat_pool, layer, page_table, q_start,
                       q_lens, kv_lens, page_size, rank, tile, interpret,
-                      name or "mla_dense_paged_attention_pallas")
+                      name or "mla_dense_paged_attention_pallas",
+                      None if name else expanded)
 
 
 def expands(tokens: int, heads: int, lanes: int, rank: int, nope: int,
             v: int) -> bool:
-    """Does the masked kernel's launch over a stream of `tokens` hold the
+    """Does an attention kernel's launch over a stream of `tokens` hold the
     expanded body? Where a span of WIDE tokens fits the rung and the rung a
     program's rows, and the head widths are whole lane tiles (the body
     slices and joins at them)."""
@@ -728,7 +762,7 @@ def expands(tokens: int, heads: int, lanes: int, rank: int, nope: int,
 
 def wide_tokens(spans, stream_len: int, heads: int, lanes: int, rank: int,
                 nope: int, v: int) -> int:
-    """Stream tokens of a ragged step that the masked kernel attends in the
+    """Stream tokens of a ragged step that a launch attends in the
     expanded form: those of its spans of at least WIDE tokens, on a rung
     (`stream_len` tokens, padding included) that holds the expanded body.
     `spans`: each row's tokens. The kernel's own test (`expands`,
@@ -751,7 +785,7 @@ def _attention(q_abs, selection, lat_pool, layer, page_table, q_start, q_lens,
         q, w = expanded
         nope = q.shape[-1] - (lanes - rank)
         if expands(T, H, lanes, rank, nope, w.shape[1] - nope):
-            wide = _wide_launch(q, w, nope, *selection)
+            wide = _wide_launch(q, w, nope, selection)
     kernel = functools.partial(_attend_kernel, tile=tile, heads=H, rank=rank,
                                page_size=page_size,
                                num_seqs=page_table.shape[0],
@@ -780,9 +814,11 @@ def _attention(q_abs, selection, lat_pool, layer, page_table, q_start, q_lens,
     return (out.reshape(-1, H, rank)[:T], jnp.swapaxes(o_v, 0, 1), served)
 
 
-def _wide_launch(q, w, nope, scores, thr):
+def _wide_launch(q, w, nope, selection):
     """What `_launch` and the kernel need of the expanded programs: q [T,
-    H, lanes] and the result head-major, a group of heads a program."""
+    H, lanes] and the result head-major, a group of heads a program; with a
+    `selection` (scores, thr) its threshold a row in VMEM, its scores left
+    in HBM and the two buffers a block of them travels through."""
     T, H, _ = q.shape
     group = min(WIDE_GROUP, H)
     v = w.shape[1] - nope
@@ -795,24 +831,28 @@ def _wide_launch(q, w, nope, scores, thr):
             memory_space=pltpu.VMEM)
 
     f32 = jnp.float32
+    operands = [jnp.swapaxes(q, 0, 1), w]
+    in_specs = [a_group((H, T, q.shape[-1])), a_group(w.shape)]
+    travels = []
+    if selection is not None:
+        scores, thr = selection
+        operands += [thr.astype(f32)[:, None], scores]
+        in_specs += [pl.BlockSpec((T, 1), lambda i, *_: (0, 0),
+                                  memory_space=pltpu.VMEM),
+                     pl.BlockSpec(memory_space=pl.ANY)]
+        travels = [pltpu.VMEM((2, T, ATTEND_BLOCK), f32),
+                   pltpu.SemaphoreType.DMA((2,))]
     return types.SimpleNamespace(
         static=types.SimpleNamespace(lead=lead, group=group, nope=nope,
                                      chain=min(WIDE_CHAIN, group)),
-        operands=[jnp.swapaxes(q, 0, 1), w, thr.astype(f32)[:, None],
-                  scores],
-        in_specs=[a_group((H, T, q.shape[-1])), a_group(w.shape),
-                  pl.BlockSpec((T, 1), lambda i, *_: (0, 0),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_spec=a_group((H, T, v)),
+        operands=operands, in_specs=in_specs, out_spec=a_group((H, T, v)),
         out_shape=jax.ShapeDtypeStruct((H, T, v), q.dtype),
-        scratch=[pltpu.VMEM((2, T, ATTEND_BLOCK), f32),
-                 pltpu.SemaphoreType.DMA((2,)),
-                 pltpu.VMEM((T, ATTEND_BLOCK), f32),
-                 pltpu.VMEM((group, T, LANES), f32),
-                 pltpu.VMEM((group, T, LANES), f32),
-                 pltpu.VMEM((group, T, v), f32),
-                 pltpu.VMEM((group * w.shape[1], ATTEND_BLOCK), q.dtype)])
+        scratch=travels + [
+            pltpu.VMEM((T, ATTEND_BLOCK), f32),
+            pltpu.VMEM((group, T, LANES), f32),
+            pltpu.VMEM((group, T, LANES), f32),
+            pltpu.VMEM((group, T, v), f32),
+            pltpu.VMEM((group * w.shape[1], ATTEND_BLOCK), q.dtype)])
 
 
 # What a LAYER computes for a launch that holds the expanded body

@@ -457,10 +457,10 @@ MLA_ROWS_TOTAL = REGISTRY.counter(
     labels=("model",))
 MLA_WIDE_TOKENS_TOTAL = REGISTRY.counter(
     "ollamamq_mla_wide_tokens_total",
-    "Those of them that the sparse latent attention kernel attended in the "
+    "Those of them that a latent attention launch attended in the "
     "EXPANDED form: tokens of prefill spans of at least mla_attention.WIDE "
     "tokens, whose programs expand each block's keys and values once a head "
-    "group; 0 without the kernel, for a fused scan and with no indexer",
+    "group; 0 without the kernel and for a fused scan",
     labels=("model",))
 MLA_ABSORBED_ROWS_TOTAL = REGISTRY.counter(
     "ollamamq_mla_absorbed_rows_total",

@@ -58,7 +58,14 @@ step as the engine launches it since PR 49, its span in the expanded form
 (`latent_wide`: the same launch with the expanded form's q and `[W_uk |
 W_uv]`, checked through W_uv) — ms a launch and µs a (tile, block) trip
 (`latent`'s docstring has the two normalisations; a `--set` of a `WIDE…`
-constant alone skips the absorbed row).
+constant alone skips the absorbed row). Since PR 64 the same three rows
+follow for the DENSE kernel (`mla_dense_paged_attention_pallas`: no
+selection, every cached position attended; `latent_dense_rows`,
+`latent_dense`, `latent_dense_wide`) at Kimi-Linear's 32 heads and at
+openPangu's 128, the latter also at 1 k and 2 k of context — its cell's
+prompts (a row's `shape` has the heads) — and a pair on the same rung with
+no wide span (`latent_dense_narrow`, `latent_dense_narrow_wide`: the launch
+without and with the expanded programs, which then serve nothing).
 
 A variant of a kernel's body is measured here before a whole cell: a
 module constant of the four kernel modules that this script sets
@@ -115,6 +122,13 @@ LAUNCHES = 64
 LAT_H, LAT_LANES, LAT_RANK, LAT_TOPK = 128, 640, 512, 2048
 LAT_ROWS, LAT_SPAN, LAT_LAUNCHES = 5, 507, 32
 LAT_CONTEXTS = (4096, 8192, 12288, 16384)
+# ...and the dense kernel's rows behind them: (heads, contexts beside those).
+LAT_DENSE = ((32, ()), (128, (1024, 2048)))
+# ...each with one more pair (`latent_dense_narrow`, `…_narrow_wide`): the
+# 512-token rung with NO span of WIDE tokens — two spans of a prompt's tail's
+# length — without and with the expanded form's operands: what a launch that
+# holds the expanded programs costs a step they serve nothing of.
+LAT_NARROW = (253, 254)
 # Stream tokens the jnp twin is asked for (it gathers [tokens, C, 640]
 # float32): the one-token rows, the span's first and last tokens, tokens at
 # both sides of a tile's edge and of the tile's halves.
@@ -348,7 +362,7 @@ def latent_batch(rows, mp):
     return (pt, q_start, q_len, kv_len), tok_seq, tok_pos, T
 
 
-def latent(args, variants) -> None:
+def latent(args, variants, heads=LAT_H, dense=False, contexts=None) -> None:
     """The latent-attention kernel's rows: for each context the cell's step
     in the absorbed form (`latent`: every row through the tiles), with the
     span in the expanded form (`latent_wide`, PR 49: the kernel as the
@@ -362,24 +376,35 @@ def latent(args, variants) -> None:
     `us_a_16_256` the same time over the trips tiles of 16 tokens and
     blocks of 256 would make: what tiles, widths and the two forms are
     compared by (it carries a wider block's over-read, and the expanded
-    form's walk of the whole span to its last frontier)."""
+    form's walk of the whole span to its last frontier). `dense`: the
+    kernel with no selection, at `heads` (the rows' names say `latent_dense`
+    where the masked kernel's say `latent`)."""
     ka = mla_attention
-    mp = max(args.contexts or LAT_CONTEXTS) // PS
+    contexts = contexts or args.contexts or LAT_CONTEXTS
+    mp = max(contexts) // PS
+    prefix = "latent_dense" if dense else "latent"
     key = jax.random.PRNGKey(args.seed)
     pool = (jax.random.normal(
-        key, (2, (1 + (LAT_ROWS + 1) * mp) * PS, LAT_LANES), jnp.float32)
+        key, (2, (1 + (LAT_ROWS + 1 + dense) * mp) * PS, LAT_LANES),
+        jnp.float32)
         * 0.3).astype(jnp.bfloat16).at[:, :, 576:].set(0)
     nope = LAT_V = 128
     # [W_uk,h | W_uv,h] a head, as the model holds it: [rank, H, nope + v]
     wukv = (jax.random.normal(jax.random.fold_in(key, 1), (
-        LAT_RANK, LAT_H, nope + LAT_V), jnp.float32) * nope ** -0.5
+        LAT_RANK, heads, nope + LAT_V), jnp.float32) * nope ** -0.5
     ).astype(jnp.bfloat16)
     w_t = jnp.transpose(wukv, (1, 2, 0))
 
-    def fn(layer, q, scores, thr, pool, pt, qs, ql, kl, *expanded):
-        return ka.mla_sparse_paged_attention_pallas(
-            q, scores, thr, pool, layer, pt, qs, ql, kl, PS, LAT_RANK,
-            **({"expanded": expanded} if expanded else {}))
+    def fn(layer, q, *operands):
+        """operands: the selection's two (the masked kernel's), the pool,
+        the step's four, the expanded form's two or none."""
+        n = 0 if dense else 2
+        kernel = ka.mla_dense_paged_attention_pallas if dense \
+            else ka.mla_sparse_paged_attention_pallas
+        expanded = operands[n + 5:]
+        return kernel(q, *operands[:n + 1], layer, *operands[n + 1:n + 5],
+                      PS, LAT_RANK,
+                      **({"expanded": expanded} if expanded else {}))
 
     def through_w_uv(out):
         """[T, H, v] of a launch's result, float32."""
@@ -398,55 +423,62 @@ def latent(args, variants) -> None:
             return LAYER * (1 - bad.astype(jnp.int32))
         return jax.lax.fori_loop(0, LAT_LAUNCHES, body, jnp.int32(LAYER))
 
-    for context in args.contexts or LAT_CONTEXTS:
+    for context in contexts:
         us_one = {}  # a variant's one-token trip, from its `latent_rows`
-        for traffic, spans in (("latent_rows", ()), ("latent", (LAT_SPAN,)),
-                               ("latent_wide", (LAT_SPAN,))):
+        for traffic, spans in ((prefix + "_rows", ()), (prefix, (LAT_SPAN,)),
+                               (prefix + "_wide", (LAT_SPAN,))) + dense * (
+                (prefix + "_narrow", LAT_NARROW),
+                (prefix + "_narrow_wide", LAT_NARROW)):
             meta, tok_seq, tok_pos, T = latent_batch(
                 [(1, context - 1)] * LAT_ROWS
                 + [(n, context - n) for n in spans], mp)
             k1, k2, k3 = jax.random.split(jax.random.fold_in(key, context), 3)
             q_nope, q_rope = (
-                (jax.random.normal(k, (T, LAT_H, n), jnp.float32) * 0.1
+                (jax.random.normal(k, (T, heads, n), jnp.float32) * 0.1
                  ).astype(jnp.bfloat16) for k, n in ((k1, nope), (k3, 64)))
-            pad = jnp.zeros((T, LAT_H, LAT_LANES - LAT_RANK - 64), jnp.float32)
+            pad = jnp.zeros((T, heads, LAT_LANES - LAT_RANK - 64), jnp.float32)
             q = jnp.concatenate([jnp.einsum(
                 "thn,chn->thc", q_nope, wukv[..., :nope],
                 preferred_element_type=jnp.float32),
                 q_rope.astype(jnp.float32), pad], -1).astype(jnp.bfloat16)
             expanded = ()
-            if traffic == "latent_wide":
+            if traffic.endswith("_wide"):
                 expanded = (jnp.concatenate(
                     [q_nope, q_rope, pad.astype(jnp.bfloat16)], -1), w_t)
             checked = np.asarray([t for t in LAT_CHECKED if t < T])
             for consts in variants:
-                if traffic == "latent" and consts and all(
+                if traffic == prefix and consts and all(
                         a.startswith(("WIDE", "_")) for _, a in consts):
                     continue  # a constant of the expanded body alone
                 with constants(consts) as names:
                     tile = getattr(ka, "ATTEND_TILE", ka.TILE)
                     block = getattr(ka, "ATTEND_BLOCK", ka.BLOCK)
                     span, one = latent_trips(*meta[1:], T, tile, block)
-                    row = {"shape": [LAT_H, LAT_LANES, LAT_RANK],
+                    row = {"shape": [heads, LAT_LANES, LAT_RANK],
                            "traffic": traffic, "tokens": T,
                            "context": context, "tile": tile, "block": block,
                            "trips_span": span, "trips_one": one,
                            "set": names}
                     try:
-                        scores = jax.random.normal(
-                            k2, (T, ka.context_lanes(mp, PS)), jnp.float32)
-                        thr = mla.select_threshold(
-                            scores, jnp.asarray(tok_pos), LAT_TOPK)
-                        operands = (q, scores, thr, pool,
-                                    *(jnp.asarray(a) for a in meta),
+                        selection = ()
+                        if not dense:
+                            scores = jax.random.normal(
+                                k2, (T, ka.context_lanes(mp, PS)),
+                                jnp.float32)
+                            selection = (scores, mla.select_threshold(
+                                scores, jnp.asarray(tok_pos), LAT_TOPK))
+                        pt, *rest = (jnp.asarray(a) for a in meta)
+                        operands = (q, *selection, pool, pt, *rest,
                                     *expanded)
                         out = fn(LAYER, *operands)
                         if expanded:
                             row["wide_tokens"] = int(out[2].sum())
                         out = np.asarray(through_w_uv(out)[checked])
                         ref = np.asarray(through_w_uv(mla.sparse_attention(
-                            q[checked], scores[checked], thr[checked], pool,
-                            LAYER, operands[4], jnp.asarray(tok_seq[checked]),
+                            q[checked], *(
+                                [s[checked] for s in selection]
+                                or (None, None)), pool,
+                            LAYER, pt, jnp.asarray(tok_seq[checked]),
                             jnp.asarray(tok_pos[checked]), PS, LAT_RANK)))
                         us = best_of_three(jax.jit(chain), *operands) \
                             / LAT_LAUNCHES * 1e6
@@ -457,7 +489,7 @@ def latent(args, variants) -> None:
                             "within_tolerance": bool(diff <= 2 ** -8 * max(
                                 1.0, np.abs(ref).max())),
                             "finite": bool(np.isfinite(out).all())})
-                        if traffic == "latent_rows":
+                        if traffic == prefix + "_rows":
                             us_one[str(names)] = us / one
                             row["us_a_one_token_trip"] = round(us / one, 4)
                         else:
@@ -533,7 +565,11 @@ def main() -> int:
                 print(json.dumps(row), flush=True)
     rng = np.random.default_rng(args.seed)
     if "latent" in args.traffic:
-        latent(args, ([] if args.only_set else [{}]) + args.variants)
+        rows = ([] if args.only_set else [{}]) + args.variants
+        latent(args, rows)
+        for heads, more in LAT_DENSE:
+            latent(args, rows, heads, dense=True, contexts=args.contexts or (
+                more + LAT_CONTEXTS))
     variants = [] if args.only_set else [("vpu", {}), ("mxu", {})]
     variants += [(None, v) for v in args.variants]
     # (traffic, decode rows, spans, context, where a span ends)

@@ -105,6 +105,43 @@ def test_the_sparse_latent_kernel_compiles_with_its_expanded_body(v5e,
                           compiled.as_text())) == 1
 
 
+@pytest.mark.parametrize("heads,pages,per_seq", [(32, 12672, 536),
+                                                 (128, 4656, 104)],
+                         ids=["kimi_linear", "openpangu"])
+def test_the_dense_latent_kernel_compiles_with_its_expanded_body(
+        v5e, heads, pages, per_seq):
+    """The dense latent attention kernel on the 512-token rung with the
+    expanded programs (PR 64) — the masked kernel's body with the
+    selection's operands, scratch and comparison compiled out — at
+    Kimi-Linear's 32 heads (two programs of 16) and at openPangu's 128
+    (eight), each over its cell's own pool and table: one Mosaic kernel the
+    chip's compiler takes under the file's VMEM limit, three results; with a
+    `name` (the prediction module's launch) the tiles alone, one result."""
+    from ollamamq_tpu.ops.pallas import mla_attention as ka
+
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    tokens, lanes, rank, rows = 512, 640, 512, 17
+    assert ka.expands(tokens, heads, lanes, rank, 128, 128)
+    for name, results in ((None, 3), (ka.MTP_NAME, 1)):
+        compiled = jax.jit(
+            lambda q, pool, pt, qs, ql, kl, qe, w:
+            ka.mla_dense_paged_attention_pallas(
+                q, pool, 1, pt, qs, ql, kl, PS, rank, name=name,
+                expanded=(qe, w))).lower(
+            s((tokens, heads, lanes), bf), s((2, pages * PS, lanes), bf),
+            s((rows, per_seq), i32), s((rows,), i32), s((rows,), i32),
+            s((rows,), i32), s((tokens, heads, 256), bf),
+            s((heads, 256, rank), bf)).compile()
+        assert len(jax.tree.leaves(compiled.out_info)) == results
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              compiled.as_text())) == 1
+
+
 # openPangu-Ultra-MoE's layers (config.py) over its dense layer and two
 # expert layers, 16 of the router's 256 experts held, a small vocabulary, and
 # the prediction module: the dense latent attention kernel at 128 heads over a
@@ -218,33 +255,44 @@ def _device_ops(script, hlo):
     return out
 
 
+@pytest.mark.parametrize("name,heads,min_mb", [
+    ("deepseek-v3.2-ep16-d5", 128, 32),
+    ("openpangu-ultra-moe-ep16-d5", 128, 32),
+    ("kimi-linear-48b-a3b-ep4-d8", 32, 16)])
 def test_the_wide_rung_computes_the_absorbed_form_of_the_rung_in_a_branch(
-        v5e):
-    """DeepSeek-V3.2's configuration file, the 512-token ragged step as
-    served (PR 55): the absorbed q of the RUNG — the contraction
-    `bthn,chn->bthc` over 512 rows, its `bf16[512,128,640]` result and that
-    result's 84 MB re-layout for the kernel's tiles — is computed inside
-    the branch a conditional takes on a step with a narrow span behind the
-    lead; on the other branch (`few`: a prompt's chunk behind a few decode
-    rows) nothing of 32 MB is copied, and the contraction outside any branch
-    runs over the 32 rows of the lead. W_uv's contraction `bthc,chv->bthv`
-    runs over 32 rows a trip, inside a loop's body, and nowhere over the
-    rung."""
-    script, hlo = _file_ragged_step(v5e, "deepseek-v3.2-ep16-d5", 512)
+        v5e, name, heads, min_mb):
+    """A latent model's configuration file, the 512-token ragged step as
+    served (PR 55: DeepSeek-V3.2's; since PR 64 every latent model's launch
+    on this rung holds the expanded body — openPangu's with its prediction
+    module behind the trunk, Kimi-Linear's two latent layers among its six
+    KDA layers, a full-rank q): the absorbed q of the RUNG — the contraction
+    `bthn,chn->bthc` over 512 rows, its `bf16[512,heads,640]` result and
+    that result's re-layout for the kernel's tiles (84 MB at 128 heads, 21
+    at 32) — is computed inside the branch a conditional takes on a step
+    with a narrow span behind the lead; on the other branch (`few`: a
+    prompt's chunk behind a few decode rows) nothing of `min_mb` is copied,
+    and the contraction outside any branch runs over the 32 rows of the
+    lead. W_uv's contraction `bthc,chv->bthv` runs over 32 rows a trip,
+    inside a loop's body, and nowhere over the rung. The prediction module's
+    block (`mtp_block`: its launch has a name and expands nothing) is the
+    rung's absorbed form outside any branch, as it was."""
+    script, hlo = _file_ragged_step(v5e, name, 512)
     conds = script.branches(hlo)
     assert conds, conds  # one a traced layer body
     full = {b[0] for b in conds.values()}  # lax.cond's false branch
     few = {b[1] for b in conds.values()}
-    moved = script.moves(hlo, 32 * 2 ** 20)
-    q_abs = [m for m in moved if m["dims"] == [512, 128, 640]]
+    moved = script.moves(hlo, min_mb * 2 ** 20)
+    q_abs = [m for m in moved if m["dims"] == [512, heads, 640]]
     assert q_abs and all(m["of"] in full for m in q_abs), q_abs
     assert not [m for m in moved if m["of"] in few], moved
     seen = {}  # rows of the contraction -> the computations it is an op of
     for at, op, line in _device_ops(script, hlo):
+        if "/mtp_block/" in line:
+            continue
         if "bthn,chn->bthc/dot_general" in line and op == "fusion":
             dims = script._INSTR.match(line)["dims"].split(",")
             n = 512 if "512" in dims[:2] else 32 if "32" in dims[:2] \
-                else None  # [512, 128, .] or [1, 32, 128, .]
+                else None  # [512, heads, .] or [1, 32, heads, .]
             seen.setdefault(n, set()).add(at)
         if "bthc,chv->bthv/dot_general" in line and op == "fusion":
             assert "/attn_out/while/body/" in line, line  # a tile a trip
@@ -254,8 +302,9 @@ def test_the_wide_rung_computes_the_absorbed_form_of_the_rung_in_a_branch(
 
 # Instructions of openPangu's ragged `--spec` step at the file's `rehearse`
 # sizes, the insides of fusions left out, as the tree BEFORE PR 55 compiled
-# it: its layers run `_latent_attention_op` too, with no indexer and so no
-# expanded body, and PR 55 means to leave them what they were. Take the
+# it: its layers run `_latent_attention_op` too, and at those sizes (head
+# widths of no whole lane tile, a rung of 64) no launch holds the expanded
+# body: PR 55 and PR 64 mean to leave them what they were. Take the
 # number again (`len(_device_ops(...))`) only with a change that means to
 # move that program.
 OPENPANGU_REHEARSE_OPS = 1350
@@ -263,8 +312,8 @@ OPENPANGU_REHEARSE_OPS = 1350
 
 def test_a_latent_layer_with_no_expanded_body_is_the_program_it_was(v5e):
     """...and holds no conditional: where nothing is expanded a layer has
-    nothing to choose (openPangu: no indexer; a rung of DeepSeek's under
-    WIDE is `tests/test_deepseek_v32.py`'s, by its trace)."""
+    nothing to choose (a rung under WIDE by its trace:
+    `tests/test_deepseek_v32_wide.py`, `tests/test_latent_dense_wide.py`)."""
     script, hlo = _file_ragged_step(v5e, "openpangu-ultra-moe-ep16-d5",
                                     rehearse=True)
     assert script.branches(hlo) == {}

@@ -85,7 +85,11 @@ def wide_of_48(monkeypatch):
     jax.clear_caches()
 
 
-def _wide_case(name, H=32):
+def _wide_case(name, H=32, dense=False):
+    """(the case, the stream's real length, the kernel's arguments, the
+    expanded form's operands, the twin's answer through W_uv, W_uv).
+    `dense`: no selection — the dense kernel's arguments
+    (tests/test_latent_dense_wide.py)."""
     from ollamamq_tpu.ops.pallas import mla_attention as ka
 
     case = WIDE_CASES[name]
@@ -114,7 +118,10 @@ def _wide_case(name, H=32):
         pad], axis=-1).astype(jnp.bfloat16)
     expanded = (jnp.concatenate([q_nope, q_rope, pad.astype(jnp.bfloat16)],
                                 axis=-1), jnp.transpose(wukv, (1, 2, 0)))
-    args = (q_abs, scores, thr, lat, 1, pt, qs, ql, kl, ps, rank)
+    if dense:
+        scores = thr = None
+    args = (q_abs, *(() if dense else (scores, thr)), lat, 1, pt, qs, ql, kl,
+            ps, rank)
 
     def w_uv(o):
         return np.asarray(jnp.einsum(

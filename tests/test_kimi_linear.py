@@ -525,6 +525,8 @@ def test_the_engine_serves_it_and_counts_both_kinds(engine, monkeypatch):
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     for s in samples:
         assert s["mla_pairs"] >= s["mla_ctx_rows"] >= 1
+        # (the jnp path serves here: nothing is expanded, and says so)
+        assert s["mla_wide_tokens"] == s["mla_absorbed_rows"] == 0
         assert "attn_pairs" not in s and "dsa_ctx_tokens" not in s
         assert s["lin_step_rows"] + s["lin_span_tokens"] >= 1
         assert s["moe_assignments"] >= 0

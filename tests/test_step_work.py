@@ -38,8 +38,11 @@ def _noted(model: str, scan: bool) -> dict:
 def test_the_table_names_every_kind_once():
     assert set(WITH) == set(WITHOUT) == set(KINDS)
     fields = [f for kind in KINDS.values() for f in kind.fields]
-    # `mla_rows` is both latent rows' (a model is one or the other)
-    assert len(fields) - 1 == len(set(fields))
+    # `mla_rows` and the expanded form's two counts are both latent rows'
+    # (a model is one or the other)
+    assert len(fields) - 3 == len(set(fields))
+    assert set(KINDS["latent"].fields) & set(KINDS["dense_latent"].fields) \
+        == {"mla_rows", "mla_wide_tokens", "mla_absorbed_rows"}
     assert all(len(k.fields) == len(k.series) for k in KINDS.values()
                if k.counts is not KINDS["conv"].counts)
 
