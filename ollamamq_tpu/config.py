@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 
 # A layer's operator (the published `layer_types` spellings) and its FFN.
@@ -86,6 +86,35 @@ def hybrid_layer_types(num_layers: int) -> tuple:
         (MAMBA if i % 2 == 0 else WINDOW) if i < half
         else MAMBA if i == half else ATTENTION if i == half + 1
         else GMU if i % 2 == 0 else CROSS for i in range(num_layers))
+
+
+class AttnShape(NamedTuple):
+    """One attention kind's head shape (`ModelConfig.attn_shape`): what the
+    projections, the cache rows, RoPE and the softmax of a layer of that kind
+    are built from. `qk_dim` lanes a head of q and k, `v_dim` a head of v and
+    of the attended values; `sink`: a learned logit a head in the softmax."""
+    heads: int
+    kv_heads: int
+    qk_dim: int
+    v_dim: int
+    theta: Optional[float]
+    sink: bool
+
+    @property
+    def q_lanes(self) -> int:
+        return self.heads * self.qk_dim
+
+    @property
+    def k_lanes(self) -> int:
+        return self.kv_heads * self.qk_dim
+
+    @property
+    def v_lanes(self) -> int:
+        return self.kv_heads * self.v_dim
+
+    @property
+    def o_lanes(self) -> int:  # `wo`'s input
+        return self.heads * self.v_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -260,7 +289,7 @@ class ModelConfig:
     # `full_attention_interval` n says what `layer_types` must: attention
     # in each n-th layer, counted from 1, linear attention in the others.
     first_k_dense_replace: Optional[int] = None
-    moe_layer_freq: int = 1
+    moe_layer_freq: object = 1  # ...or the published LIST a layer long
     ep_size: int = 1
     decoder_sparse_step: int = 1
     mlp_only_layers: tuple = ()
@@ -458,8 +487,44 @@ class ModelConfig:
     num_expert_group: Optional[int] = None
     use_grouped_topk: Optional[bool] = None
     model_max_length: Optional[int] = None
+    # -- window and full attention at DIFFERENT head shapes (MiMo-V2-Flash) --
+    # The published `hybrid_layer_pattern` (0 a full layer, 1 a window layer)
+    # says what `layer_types` says, and a LIST `moe_layer_freq` (0 a dense
+    # FFN, 1 experts: the dense ones lead) what `num_dense_layers` says:
+    # either or both, agreeing. A window layer has its own kv-head count
+    # (`swa_num_key_value_heads`) and RoPE base (`swa_rope_theta`);
+    # `swa_num_attention_heads`, `swa_head_dim` and `swa_v_head_dim` are read
+    # and held to the full layers' (`num_heads`, `head_dim`, `v_head_dim`).
+    # With any `swa_*` key the two kinds' weights are stacks of their own
+    # (`per_kind_attention`; `attn_shape(kind)` is what every caller on the
+    # attention path reads), and `v_head_dim` WITHOUT `kv_lora_rank` is the
+    # width of a value head of plain K/V attention (0: `head_dim`): K and V
+    # rows of different widths in pool and rings, `wo` from `num_heads x
+    # v_head_dim`. `add_swa_attention_sink_bias`: a learned float32 logit a
+    # head (`swa_sink`) joins a window layer's softmax as a column that every
+    # query sees and that carries no value; `add_full_attention_sink_bias` is
+    # read and refused unless false. `attention_value_scale` multiplies v
+    # after its projection (before the cache). `sliding_window_size` and
+    # `attention_chunk_size` are read and held to `sliding_window`;
+    # `layernorm_epsilon` is the family's spelling of `rms_norm_eps`,
+    # `topk_method` "noaux_tc" of `use_expert_bias`; a null `n_shared_experts`
+    # is 0 and a null `routed_scaling_factor` 1.
+    hybrid_layer_pattern: Optional[tuple] = None
+    swa_num_attention_heads: Optional[int] = None
+    swa_num_key_value_heads: Optional[int] = None
+    swa_head_dim: Optional[int] = None
+    swa_v_head_dim: Optional[int] = None
+    swa_rope_theta: Optional[float] = None
+    add_swa_attention_sink_bias: bool = False
+    add_full_attention_sink_bias: bool = False
+    attention_value_scale: Optional[float] = None
+    sliding_window_size: Optional[int] = None
+    attention_chunk_size: Optional[int] = None
+    layernorm_epsilon: Optional[float] = None
+    topk_method: Optional[str] = None
 
     def __post_init__(self):
+        self._derive_per_kind()
         self._derive_hybrid()
         self._derive_mixers()
         self._derive_kda()
@@ -564,6 +629,101 @@ class ModelConfig:
                 f"{self.name}: conv_bias true: the program's convolution "
                 "layers carry no bias")
 
+    def _derive_per_kind(self) -> None:
+        """The `mimo_v2_flash` spellings folded into the program's fields,
+        BEFORE the other checks read them, and what per-kind attention
+        cannot run with."""
+        def put(field, value):
+            object.__setattr__(self, field, value)
+
+        if self.n_shared_experts is None:
+            put("n_shared_experts", 0)
+        if self.routed_scaling_factor is None:
+            put("routed_scaling_factor", 1.0)
+        if self.topk_method is not None:
+            if self.topk_method != "noaux_tc":
+                raise ValueError(
+                    f"{self.name}: topk_method {self.topk_method!r}: the "
+                    "program implements only 'noaux_tc' (the top k by score "
+                    "+ selection bias: use_expert_bias)")
+            put("use_expert_bias", True)
+        if self.hybrid_layer_pattern is not None:
+            pattern = tuple(self.hybrid_layer_pattern)  # a file's list
+            put("hybrid_layer_pattern", pattern)
+            if set(pattern) - {0, 1}:
+                raise ValueError(
+                    f"{self.name}: hybrid_layer_pattern holds "
+                    f"{sorted(set(pattern) - {0, 1})}: 0 a full_attention "
+                    "layer, 1 a sliding_attention one")
+            kinds = tuple(WINDOW if p else ATTENTION for p in pattern)
+            if self.layer_types is not None \
+                    and tuple(self.layer_types) != kinds:
+                raise ValueError(
+                    f"{self.name}: hybrid_layer_pattern does not agree with "
+                    "layer_types (0 a full_attention layer, 1 a "
+                    "sliding_attention one)")
+            put("layer_types", kinds)
+        if not isinstance(self.moe_layer_freq, int):
+            freq = tuple(self.moe_layer_freq)  # a file's list
+            put("moe_layer_freq", 1)  # ...folded: every layer after the
+            dense = freq.index(1) if 1 in freq else len(freq)  # dense ones
+            if set(freq) - {0, 1} or 0 in freq[dense:] \
+                    or len(freq) != self.num_layers:
+                raise ValueError(
+                    f"{self.name}: moe_layer_freq {list(freq)}: one entry a "
+                    "layer, 0 (a dense FFN) in the leading layers and 1 "
+                    "(experts) in every layer after them")
+            if self.num_dense_layers not in (0, dense):
+                raise ValueError(
+                    f"{self.name}: moe_layer_freq {list(freq)} is not "
+                    f"num_dense_layers {self.num_dense_layers}")
+            put("num_dense_layers", dense)
+        for key in ("sliding_window_size", "attention_chunk_size"):
+            if getattr(self, key) not in (None, self.sliding_window):
+                raise ValueError(
+                    f"{self.name}: {key} {getattr(self, key)} is not "
+                    f"sliding_window {self.sliding_window}: the program "
+                    "serves one window width")
+        if self.add_full_attention_sink_bias:
+            raise ValueError(
+                f"{self.name}: add_full_attention_sink_bias true: the sink "
+                "is served in the window layers' softmax only (ROADMAP B-M2)")
+        per_kind = self.per_kind_attention
+        if not per_kind and (self.add_swa_attention_sink_bias
+                             or self.attention_value_scale is not None):
+            raise ValueError(
+                f"{self.name}: add_swa_attention_sink_bias / "
+                "attention_value_scale belong to per-kind attention (the "
+                "swa_* keys)")
+        if not per_kind:
+            return
+        for key, field in (("swa_num_attention_heads", "num_heads"),
+                           ("swa_head_dim", "head_dim"),
+                           ("swa_v_head_dim", "v_head_dim")):
+            if getattr(self, key) not in (None, getattr(self, field)):
+                raise ValueError(
+                    f"{self.name}: {key} {getattr(self, key)} is not {field} "
+                    f"{getattr(self, field)}: the two attention kinds differ "
+                    "in kv heads and RoPE base only")
+        if self.kv_lora_rank or self.attn_bias or self.qk_norm \
+                or self.attn_output_gate or self.mb_per_layer \
+                or self.mamba_d_ssm or self.rope_layer_types is not None \
+                or self.rope_theta is None or self.norm_order != "pre" \
+                or set(self.layer_types or ()) - set(ATTENTION_KINDS):
+            raise ValueError(
+                f"{self.name}: per-kind attention (the swa_* keys) is served "
+                "as plain K/V attention in a pre-norm stack of "
+                "full_attention and sliding_attention layers, RoPE on both: "
+                "no kv_lora_rank, attention bias, q/k norm, output gate, "
+                "rope_layer_types or other layer kind")
+        for kind in ATTENTION_KINDS:
+            shape = self.attn_shape(kind)
+            if shape.kv_heads < 1 or self.num_heads % shape.kv_heads \
+                    or shape.v_dim < 1:
+                raise ValueError(
+                    f"{self.name}: {kind}: {self.num_heads} heads over "
+                    f"{shape.kv_heads} kv heads of {shape.v_dim} value lanes")
+
     def _fold_router_spellings(self) -> None:
         """The `exaone_moe` spellings of the router's fields, folded into
         the program's."""
@@ -573,7 +733,8 @@ class ModelConfig:
                              ("num_experts_per_token", "num_experts_per_tok"),
                              ("moe_renormalize", "norm_topk_prob"),
                              ("num_expert_group", "n_group"),
-                             ("model_max_length", "max_seq_len")):
+                             ("model_max_length", "max_seq_len"),
+                             ("layernorm_epsilon", "rms_norm_eps")):
             value = getattr(self, alias)
             if value is None:
                 continue
@@ -678,6 +839,8 @@ class ModelConfig:
                   self.v_head_dim)
         has = (self.index_n_heads, self.index_head_dim, self.index_topk)
         if not self.kv_lora_rank:
+            if self.per_kind_attention:  # v_head_dim: a plain value head's
+                widths = widths[:2]
             if self.q_lora_rank or any(widths) or any(has) \
                     or self.mla_use_nope:
                 raise ValueError(
@@ -780,13 +943,13 @@ class ModelConfig:
     def _check_gated(self) -> None:
         """The partial rotary embedding, the attention output gate, the
         shared expert's own width and gate, `full_attention_interval`."""
-        rot = self.head_dim * self.partial_rotary_factor
-        if not 0 < self.partial_rotary_factor <= 1 or rot != int(rot) \
-                or int(rot) % 2:
+        # (floored, as the published modelling code does: 0.334 x 192 = 64)
+        rot = int(self.head_dim * self.partial_rotary_factor)
+        if not 0 < self.partial_rotary_factor <= 1 or rot < 2 or rot % 2:
             raise ValueError(
                 f"{self.name}: partial_rotary_factor "
                 f"{self.partial_rotary_factor} of head_dim {self.head_dim} "
-                "is not an even number of lanes")
+                "is not an even number of lanes (floored), 2 at the least")
         if self.kv_lora_rank and (self.partial_rotary_factor != 1
                                   or self.attn_output_gate
                                   or self.zero_centred_norm):
@@ -1306,13 +1469,44 @@ class ModelConfig:
         return self.paged_layers + self.num_nextn_predict_layers
 
     @property
+    def per_kind_attention(self) -> bool:
+        """Do the window and the full layers differ in head shape (any
+        `swa_*` key)? Their weights are then stacks of their own."""
+        return any(getattr(self, key) is not None for key in (
+            "swa_num_attention_heads", "swa_num_key_value_heads",
+            "swa_head_dim", "swa_v_head_dim", "swa_rope_theta"))
+
+    def attn_shape(self, kind: str = ATTENTION) -> AttnShape:
+        """The head shape of an attention layer of `kind` (plain K/V
+        attention): one for every kind unless `per_kind_attention`."""
+        v_dim = (self.v_head_dim if self.per_kind_attention else 0) \
+            or self.head_dim
+        if kind == WINDOW and self.per_kind_attention:
+            return AttnShape(
+                self.num_heads,
+                self.swa_num_key_value_heads or self.num_kv_heads,
+                self.head_dim, v_dim,
+                self.rope_theta if self.swa_rope_theta is None
+                else self.swa_rope_theta, self.add_swa_attention_sink_bias)
+        return AttnShape(self.num_heads, self.num_kv_heads, self.head_dim,
+                         v_dim, self.rope_theta, False)
+
+    @property
     def kv_row_dims(self) -> tuple:
         """Lanes a token a layer in each of the two paged pools: K and V
-        rows, or with latent attention the latent row and the index key
-        (0 lanes: no indexer, no second pool)."""
+        rows (a full layer's: a window layer's live in the rings,
+        `ring_row_dims`), or with latent attention the latent row and the
+        index key (0 lanes: no indexer, no second pool)."""
         if self.kv_lora_rank:
             return self.latent_lanes, self.index_head_dim
-        return self.kv_dim, self.kv_dim
+        shape = self.attn_shape(ATTENTION)
+        return shape.k_lanes, shape.v_lanes
+
+    @property
+    def ring_row_dims(self) -> tuple:
+        """Lanes a position a window layer in the K ring and in the V ring."""
+        shape = self.attn_shape(WINDOW)
+        return shape.k_lanes, shape.v_lanes
 
     @property
     def yarn(self) -> Optional[dict]:
@@ -1436,8 +1630,14 @@ class ModelConfig:
                 + 4 * self.head_dim + lanes
         cross = attention - 2 * (d + 1) * self.kv_dim
         ld = self.lightning_nh * self.lightning_head_dim
+        window = attention
+        if self.per_kind_attention:  # q, k, v, o of each kind's own shape
+            attention, window = (
+                d * a.q_lanes + d * (a.k_lanes + a.v_lanes) + a.o_lanes * d
+                + a.heads * a.sink
+                for a in map(self.attn_shape, ATTENTION_KINDS))
         per_op = {
-            ATTENTION: attention, WINDOW: attention, CROSS: cross,
+            ATTENTION: attention, WINDOW: window, CROSS: cross,
             SPARSE: attention,
             GMU: 2 * d * di,
             # in [x | z], the taps and their bias, x -> [dt | B | C], dt's
@@ -1727,6 +1927,31 @@ MODEL_CONFIGS = {
         moe_intermediate_size=32, first_k_dense_replace=1,
         scoring_func="sigmoid", use_expert_bias=True, norm_topk_prob=True,
         norm_topk_eps=1e-20, routed_scaling_factor=2.5,
+    ),
+    # MiMo-V2-Flash's architecture (XiaomiMiMo/MiMo-V2-Flash config.json) at
+    # toy sizes: the published 5 : 1 of window to full layers behind a leading
+    # full + dense layer (F W W W W F W), the two kinds at DIFFERENT kv-head
+    # counts (1 full / 2 window at 4 heads: groups 4 and 2), key heads of 24
+    # lanes beside value heads of 16, RoPE over the first 8 lanes at two
+    # bases, a sink in the window layers' softmax, v times 0.707, a sigmoid
+    # router with a selection bias over 16 experts of which this program
+    # holds 4, no shared expert, no scale.
+    "test-tiny-mimo-v2-flash": ModelConfig(
+        name="test-tiny-mimo-v2-flash", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=7, num_heads=4, num_kv_heads=1,
+        head_dim=24, v_head_dim=16, rope_theta=5_000_000.0,
+        partial_rotary_factor=0.334, layernorm_epsilon=1e-5, max_seq_len=512,
+        sliding_window=8, sliding_window_size=8, attention_chunk_size=8,
+        hybrid_layer_pattern=(0, 1, 1, 1, 1, 0, 1),
+        swa_num_attention_heads=4, swa_num_key_value_heads=2,
+        swa_head_dim=24, swa_v_head_dim=16, swa_rope_theta=10_000.0,
+        add_swa_attention_sink_bias=True, add_full_attention_sink_bias=False,
+        attention_value_scale=0.707,
+        num_experts=4, router_experts=16, expert_offset=0,
+        num_experts_per_tok=4, n_group=1, topk_group=1, n_shared_experts=None,
+        moe_intermediate_size=32, moe_layer_freq=(0, 1, 1, 1, 1, 1, 1),
+        scoring_func="sigmoid", topk_method="noaux_tc", norm_topk_prob=True,
+        norm_topk_eps=1e-20, routed_scaling_factor=None,
     ),
     # Falcon-H1-34B (tiiuae/Falcon-H1-34B-Instruct config.json): every layer
     # runs GQA attention (20 heads of 128 over 4 K/V heads: 2560 lanes under

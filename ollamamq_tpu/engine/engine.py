@@ -744,7 +744,8 @@ class ModelRuntime:
         # of `engine_cfg` a step program's shapes depend on.
         self.work = step_work.StepWork(
             model_cfg, engine_cfg.page_size, name,
-            step_work.kernel_counts(model_cfg, self.attn_impl))
+            step_work.kernel_counts(model_cfg, self.attn_impl),
+            kv_itemsize=jnp.dtype(dtype).itemsize)
         self.dims = step_program.StepDims.of(engine_cfg)
         log.info("%s: attention=%s (%s)%s", name, self.attn_impl, why,
                  "".join(f" {k}={v}" for k, v in

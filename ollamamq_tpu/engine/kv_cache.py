@@ -193,7 +193,10 @@ def alloc_kv_pool(
         return tuple(QuantKV(filled(0, shape, jnp.int8),
                              filled(1, sshape, jnp.float32))
                      for _ in range(2))
-    return filled(0, shape, dtype), filled(0, shape, dtype)
+    # K rows and V rows, each at its own width (`kv_row_dims`: alike for
+    # every model but one whose value heads are narrower than its key heads)
+    return tuple(filled(0, shape[:2] + (lanes,), dtype)
+                 for lanes in model_cfg.kv_row_dims)
 
 
 def kv_pool_bytes(model_cfg: ModelConfig, engine_cfg: EngineConfig,
@@ -343,7 +346,8 @@ def kv_page_bytes(model_cfg: ModelConfig, page_size: int,
     if model_cfg.kv_lora_rank:
         return (model_cfg.cache_layers * page_size
                 * sum(model_cfg.kv_row_dims) * bytes_per_el)
-    per_tok_head = (model_cfg.head_dim + 4 if kv_dtype == "int8"
-                    else model_cfg.head_dim * bytes_per_el)
+    if kv_dtype != "int8":
+        return (model_cfg.paged_layers * page_size
+                * sum(model_cfg.kv_row_dims) * bytes_per_el)
     return (2 * model_cfg.paged_layers * page_size
-            * model_cfg.num_kv_heads * per_tok_head)
+            * model_cfg.num_kv_heads * (model_cfg.head_dim + 4))

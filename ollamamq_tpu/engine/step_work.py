@@ -19,8 +19,8 @@ from typing import Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from ollamamq_tpu.config import (CONV, EXPERTS, LINEAR, MAMBA, PARALLEL,
-                                 SPARSE, ModelConfig)
+from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, LINEAR, MAMBA,
+                                 PARALLEL, SPARSE, WINDOW, ModelConfig)
 from ollamamq_tpu.ops.attention import ring_first_page
 from ollamamq_tpu.ops.gated_delta import CHUNK
 from ollamamq_tpu.telemetry import schema as tm
@@ -67,6 +67,7 @@ class Step(NamedTuple):
     stream_len: int
     opened: int
     kernels: Optional[KernelCounts]
+    kv_itemsize: int = 2  # bytes an element of pool and rings (bfloat16)
 
 
 def _pairs(n, kv):
@@ -183,6 +184,23 @@ def swa_counts(cfg, page_size, s: Step) -> tuple:
         pairs, np.minimum(kv, n + w - 1), walk, kv))
 
 
+def row_bytes(cfg, page_size, s: Step) -> tuple:
+    """Bytes ONE cached position of one full layer occupies in the pool and
+    of one window layer in the rings, AS STORED (K row and V row: their
+    lanes times the cache's element size) — a constant of the runtime, on
+    every sample beside the rows the walks read (`attn_ctx_rows`,
+    `swa_ctx_rows`), so that a reader can hold the stored layout to the
+    least the model's head shapes need."""
+    return tuple(stored_row_bytes(cfg, s.kv_itemsize).values())
+
+
+def stored_row_bytes(cfg, itemsize: int) -> dict:
+    """{attention kind: bytes a cached position of one layer of it as stored}
+    — a full layer's in the pool, a window layer's in the rings."""
+    return {kind: sum(lanes) * itemsize for kind, lanes in (
+        (ATTENTION, cfg.kv_row_dims), (WINDOW, cfg.ring_row_dims))}
+
+
 def bsa_counts(cfg, page_size, s: Step) -> tuple:
     """Block-sparse attention, a sparse layer's worth, each count split into
     one-token rows (decode rows of a ragged step, a scan's passes: their
@@ -295,6 +313,11 @@ KINDS = {
         ("swa_pairs", "swa_ctx_rows", "swa_walk_rows", "swa_full_rows"),
         (tm.SWA_PAIRS_TOTAL, tm.SWA_CTX_ROWS_TOTAL, tm.SWA_WALK_ROWS_TOTAL,
          tm.SWA_FULL_ROWS_TOTAL), swa_counts),
+    # (a model whose attention kinds differ in head shape: K and V rows of
+    # their own widths, a full layer's other than a window layer's)
+    "kv_rows": Kind(
+        lambda cfg: cfg.per_kind_attention,
+        ("attn_row_bytes", "swa_row_bytes"), (None, None), row_bytes),
     "bsa": Kind(
         lambda cfg: cfg.count(SPARSE),
         ("bsa_blocks_in_context_step", "bsa_blocks_in_context_span",
@@ -317,8 +340,13 @@ class StepWork:
     `kernels`: `kernel_counts(cfg, attn_impl)`."""
 
     def __init__(self, cfg: ModelConfig, page_size: int, model: str,
-                 kernels: Optional[KernelCounts] = None):
+                 kernels: Optional[KernelCounts] = None,
+                 kv_itemsize: int = 2):
         self.cfg, self.page_size, self.kernels = cfg, page_size, kernels
+        self.kv_itemsize = kv_itemsize
+        if KINDS["kv_rows"].present(cfg):
+            for kind, n in stored_row_bytes(cfg, kv_itemsize).items():
+                tm.KV_ROW_BYTES.labels(model=model, kind=kind).set(n)
         self._rows = [
             (k.fields, k.counts,
              [(i, c.labels(model=model)) for i, c in enumerate(k.series)
@@ -335,7 +363,7 @@ class StepWork:
         sample `sp` and the series, each once; returns the stream tokens
         that stopped below an `exit_layer` (0 for a model without one)."""
         step = Step(tokens, kv, emits, scan, stream_len, opened,
-                    self.kernels)
+                    self.kernels, self.kv_itemsize)
         skipped = 0
         for fields, count, series, exits in self._rows:
             counts = count(self.cfg, self.page_size, step)

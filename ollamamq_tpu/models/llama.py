@@ -68,11 +68,13 @@ from ollamamq_tpu.ops.attention import (
     causal_attention,
     bidirectional_attention,
     flat_slot_indices,
+    lay_heads,
     paged_decode_attention,
     paged_decode_attention_any,
     ragged_attention_any,
     ring_table,
     ring_write_slots,
+    split_head,
 )
 from ollamamq_tpu.ops.quant import embed_lookup, kv_write, logits_head, qeinsum
 from ollamamq_tpu.ops.rope import (apply_rope, apply_rope_freqs, rope_freqs,
@@ -87,6 +89,17 @@ SCOPES = ("embed", "attn_qkv", "kv_write", "attention", "attn_out", "mlp",
 # ...and, inside "attn_out", where a model with `attn_output_gate` multiplies
 # the attended values by its sigmoid gate.
 GATE_SCOPES = ("attn_gate",)
+# ...and of a model whose attention kinds differ in head shape
+# (`per_kind_attention`): where v is multiplied by `attention_value_scale`
+# (inside "attn_qkv") and where the jnp attentions add a window layer's sink
+# to the softmax (inside "attention"; ops/attention.py — the Pallas kernels
+# fold it in their last lines, inside the launch).
+PER_KIND_SCOPES = ("attn_vscale", "attn_sink")
+PER_KIND_KEY = 0x73776131
+# Standard deviation of a seeded-random sink logit (float32): of the order of
+# the scores' (q . k / sqrt(d) of unit-variance heads is ~1), so that a
+# forward which drops the sink computes another model.
+SINK_SD = 1.0
 # ...and the prediction module's three (`forward_mtp`): the two norms and
 # the projection of [embedding | hidden], its block (which holds a layer's
 # own scopes), its norm and the trunk's head.
@@ -179,6 +192,10 @@ KIND_PARAMS = {
                 "q_norm", "k_norm", "diff_lambda", "diff_norm") + MLA_PARAMS,
     MAMBA: ("s6_in", "s6_conv_w", "s6_conv_b", "s6_x", "s6_dt", "s6_dt_bias",
             "s6_A_log", "s6_D", "s6_out"),
+    # a window layer's where the two attention kinds differ in head shape
+    # (`per_kind_attention`: the full layers' are ATTENTION's four): the
+    # attention layers' names behind "swa_", and the sink's logit a head
+    WINDOW: ("swa_wq", "swa_wk", "swa_wv", "swa_wo", "swa_sink"),
     GMU: ("gmu_in", "gmu_out"),
     # a cross layer's: the attention layers' names behind an "x" (a stack of
     # their own: no wk, no wv, and another count of layers)
@@ -307,6 +324,24 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
                 idx_k_norm=jnp.ones((La, di), dtype),
                 idx_k_bias=jnp.zeros((La, di), dtype),
                 idx_ww=w(mk[7], (La, d, Hi), d))
+    elif La and cfg.per_kind_attention:
+        # Each kind's four projections at its own head shape, a stack a
+        # kind; the window layers' sink logits in float32 (inside an exp).
+        pk = jax.random.split(jax.random.fold_in(key, PER_KIND_KEY), 5)
+        full, win = map(cfg.attn_shape, ATTENTION_KINDS)
+        Lf, Lw = cfg.count(ATTENTION), cfg.count(WINDOW)
+        layers.update(
+            wq=w(keys[0], (Lf, d, full.q_lanes), d),
+            wk=w(keys[1], (Lf, d, full.k_lanes), d),
+            wv=w(keys[2], (Lf, d, full.v_lanes), d),
+            wo=w(keys[3], (Lf, full.o_lanes, d), full.o_lanes),
+            swa_wq=w(pk[0], (Lw, d, win.q_lanes), d),
+            swa_wk=w(pk[1], (Lw, d, win.k_lanes), d),
+            swa_wv=w(pk[2], (Lw, d, win.v_lanes), d),
+            swa_wo=w(pk[3], (Lw, win.o_lanes, d), win.o_lanes))
+        if win.sink:
+            layers["swa_sink"] = SINK_SD * jax.random.normal(
+                pk[4], (Lw, win.heads), jnp.float32)
     elif La:
         layers.update(
             wq=w(keys[0], (La, d, qd), d, cfg.attention_in_multiplier),
@@ -530,9 +565,22 @@ def splits_heads_at_once(cfg: ModelConfig) -> bool:
     return cfg.qk_norm_kind != "full"
 
 
-def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray):
-    """Project hidden -> q,k,v with head reshape. h: [B, T, D]."""
+def _qkv(cfg: ModelConfig, lp: dict, h: jnp.ndarray, shape=None):
+    """Project hidden -> q,k,v with head reshape. h: [B, T, D]. `shape`: the
+    layer's kind's `AttnShape` where the kinds differ (`per_kind_attention`:
+    its own kv heads, v narrower than k, v times `attention_value_scale`)."""
     B, T, _ = h.shape
+    if shape is not None:
+        q = qeinsum("btd,de->bte", h, lp["wq"]).reshape(
+            B, T, shape.heads, shape.qk_dim)
+        k = qeinsum("btd,de->bte", h, lp["wk"]).reshape(
+            B, T, shape.kv_heads, shape.qk_dim)
+        v = qeinsum("btd,de->bte", h, lp["wv"]).reshape(
+            B, T, shape.kv_heads, shape.v_dim)
+        if cfg.attention_value_scale is not None:
+            with jax.named_scope("attn_vscale"):
+                v = v * cfg.attention_value_scale
+        return q, k, v
     q = qeinsum("btd,de->bte", h, lp["wq"])
     k = qeinsum("btd,de->bte", h, lp["wk"])
     v = qeinsum("btd,de->bte", h, lp["wv"])
@@ -675,8 +723,8 @@ def alloc_slot_state(cfg: ModelConfig, max_slots: int, dtype=jnp.bfloat16,
             cfg.mamba_d_state, cfg.mamba_d_head),
         selective_scan.alloc_state(
             cfg.count(MAMBA), max_slots, S6_D_STATE, cfg.s6_inner),
-        alloc_ring(cfg.count(WINDOW), max_slots, ring_rows, cfg.kv_dim,
-                   dtype),
+        alloc_ring(cfg.count(WINDOW), max_slots, ring_rows,
+                   cfg.ring_row_dims, dtype),
         block_select.alloc_pooled(cfg.count(SPARSE), pooled_rows, cfg.kv_dim,
                                   dtype))
     return state if jax.tree_util.tree_leaves(state) else None
@@ -728,7 +776,9 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state, at_exit=None):
                for name in names}
     seen = dict.fromkeys((ATTENTION, CONV, LINEAR, WINDOW, PARALLEL, MAMBA,
                           GMU, CROSS, SPARSE, DENSE, EXPERTS), 0)
-    windowed = cfg.count(WINDOW) > 0
+    # (where the two attention kinds' stacks are their own, a window layer
+    # reads KIND_PARAMS[WINDOW] at its index among the window layers)
+    windowed = cfg.count(WINDOW) > 0 and not cfg.per_kind_attention
     loads = []
     for first, period, repeats in cfg.layer_plan():
         per = {k: sum(k in pair for pair in period) for k in seen}
@@ -782,9 +832,16 @@ def scan_layers(cfg: ModelConfig, body, x, layers, *state, at_exit=None):
     return (x, *state, load)
 
 
+def _stored(x: jnp.ndarray) -> jnp.ndarray:
+    """K or V heads [..., Hk, d] as `kv_write` takes them: as they are —
+    it flattens them to the cache's row — or, a head the cache stores split
+    (ops/attention.py:split_head), laid out as the row is."""
+    return lay_heads(x) if split_head(x.shape[-1])[1] else x
+
+
 def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
                   positions: jnp.ndarray, attn_fn,
-                  rotate: bool = True) -> jnp.ndarray:
+                  rotate: bool = True, kind: str = ATTENTION) -> jnp.ndarray:
     """Attention over normed hiddens h [B, T, D]: projections, q/k norm
     and RoPE (over a head's first `rotary_dim` lanes; `rotate`: the layer's
     kind takes it, `ModelConfig.rotates`) here; the schedule (the window or
@@ -794,6 +851,22 @@ def _attention_op(cfg: ModelConfig, lp: dict, h: jnp.ndarray,
     before `wo`."""
     B, T, _ = h.shape
     gate = None
+    if cfg.per_kind_attention:
+        # the layer's kind's own head shape, RoPE base and — a window
+        # layer's — weights (`swa_*`) and sink
+        shape = cfg.attn_shape(kind)
+        if kind == WINDOW:
+            lp = {**lp, **{name[4:]: lp[name]
+                           for name in KIND_PARAMS[WINDOW] if name in lp}}
+        with jax.named_scope("attn_qkv"):
+            q, k, v = _qkv(cfg, lp, h, shape)
+            q = apply_rope(q, positions, shape.theta, cfg.rotary_dim)
+            k = apply_rope(k, positions, shape.theta, cfg.rotary_dim)
+        attn = attn_fn(q, k, v, sink=lp["sink"]) if shape.sink \
+            else attn_fn(q, k, v)
+        with jax.named_scope("attn_out"):
+            return qeinsum("bte,ed->btd",
+                           attn.reshape(B, T, shape.o_lanes), lp["wo"])
     with jax.named_scope("attn_qkv"):
         q, k, v = _qkv(cfg, lp, h)
         if cfg.attn_output_gate:
@@ -963,6 +1036,12 @@ CONTRACTED_MINOR = {"mla_wuq": (0, 2, 1), "mla_wukv": (0, 2, 1)}
 # full-width norm nothing (AOT, ISSUE 51). `wv`, `wo`, `wq_gate` and the MLP
 # stacks are read as they lie.
 SPLIT_TO_HEADS_MINOR = {"wq": (0, 2, 1), "wk": (0, 2, 1)}
+# ...and the window layers' own q and k stacks where the attention kinds
+# differ in head shape (`per_kind_attention`: split into heads at once, no
+# norm): held row-major, MiMo-V2-Flash's ragged step re-laid a window layer's
+# 100 MB of `swa_wq` and 12.6 MB of `swa_wk` a layer a step (AOT for a v5e,
+# PR 65: scripts/step_hlo_copies.py on the configuration's file).
+PER_KIND_MINOR = {"swa_wq": (0, 2, 1), "swa_wk": (0, 2, 1)}
 # A decoder-hybrid-decoder stack's: the q projections of its attention AND
 # its cross layers, which `pair_queries` splits into heads at once — and NOT
 # `wk`, whose result is read as pairs of heads, a cache row's lanes as they
@@ -994,6 +1073,8 @@ def weight_formats(cfg: ModelConfig, params: dict) -> dict:
         named.update(HYBRID_MINOR)
     elif splits_heads_at_once(cfg):
         named.update(SPLIT_TO_HEADS_MINOR)
+    if cfg.per_kind_attention:
+        named.update(PER_KIND_MINOR)
     if cfg.lightning_nh:
         named.update(LIGHTNING_MINOR)
     out = {}
@@ -1407,7 +1488,7 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
         delta = _latent_attention_op(cfg, lp, h, positions, attn_fn, few)
     else:  # attention over K and V: the whole context, or a window
         delta = _attention_op(cfg, lp, h, positions, attn_fn,
-                              rotate=cfg.rotates(op))
+                              rotate=cfg.rotates(op), kind=op)
     if cfg.sandwich_norm:
         delta = norm(delta, "post_attn_norm")
     scale = cfg.residual_multiplier  # `scale_depth`'s; 1: nothing is traced
@@ -1464,7 +1545,8 @@ def _causal_fn(cfg: ModelConfig, seq_lens, op: str = ATTENTION):
             q_abs, row, *(index or (None,) * 3), seq_lens, cfg.kv_lora_rank,
             cfg.index_topk)
     window = cfg.sliding_window if op == WINDOW else 0
-    return lambda q, k, v: causal_attention(q, k, v, seq_lens, window)
+    return lambda q, k, v, sink=None: causal_attention(
+        q, k, v, seq_lens, window, sink)
 
 
 def forward_prefill(
@@ -1493,17 +1575,19 @@ def forward_prefill(
     slots = flat_slot_indices(page_table, positions, page_size)  # [B, T]
 
     def body(x, lp, kinds, ix, kc, vc):
-        def attn_fn(q, k, v, expanded=None):
+        def attn_fn(q, k, v, expanded=None, sink=None):
             nonlocal kc, vc
             if kinds[0] == WINDOW:  # its rows are no pool's: no state left
-                return _causal_fn(cfg, seq_lens, WINDOW)(q, k, v)
-            kc = kv_write(kc, ix.op, slots, k)  # K, or the latent row
+                return _causal_fn(cfg, seq_lens, WINDOW)(q, k, v, sink)
+            kc = kv_write(kc, ix.op, slots, _stored(k))  # K, or the latent row
             # V, or the index key (v: the indexer's q, k and head weights;
             # None with no indexer: no second pool)
             if not cfg.kv_lora_rank:
                 vc = kv_write(vc, ix.op, slots, v)
             elif v is not None:
                 vc = kv_write(vc, ix.op, slots, v[1])
+            if sink is not None:
+                return _causal_fn(cfg, seq_lens)(q, k, v, sink)
             return _causal_fn(cfg, seq_lens)(q, k, v)
 
         valid = positions < seq_lens[:, None]
@@ -1629,7 +1713,7 @@ def forward_ragged(
             tokens.shape[0] // sizes.stride + page_table.shape[0])
 
     def body(x, lp, kinds, ix, kc, vc, conv, rule, ring, pooled, *m):
-        def attn_fn(q, k, v, expanded=None):  # [1, T, H, hd]
+        def attn_fn(q, k, v, expanded=None, sink=None):  # [1, T, H, hd]
             nonlocal kc, vc, ring, pooled
             if kinds[0] == SPARSE:
                 with jax.named_scope("kv_write"):
@@ -1663,21 +1747,22 @@ def forward_ragged(
                 return out
             if kinds[0] == WINDOW:
                 with jax.named_scope("kv_write"):
-                    ring = ring.write(ix.op, ring_slots, k[0], v[0])
+                    ring = ring.write(ix.op, ring_slots, _stored(k[0]), v[0])
                 with jax.named_scope("attention"):
                     return ragged_attention_any(
                         attn_impl, q[0], ring.k, ring.v, ix.op, ring_pt,
                         tok_seq, tok_pos, kv_len, q_start, q_len, page_size,
                         interpret=interpret, mesh=mesh,
-                        window=cfg.sliding_window, pos_base=ring_base)[None]
+                        window=cfg.sliding_window, pos_base=ring_base,
+                        sink=sink)[None]
             with jax.named_scope("kv_write"):
-                kc = kv_write(kc, ix.op, write_slots, k[0])
+                kc = kv_write(kc, ix.op, write_slots, _stored(k[0]))
                 vc = kv_write(vc, ix.op, write_slots, v[0])
             with jax.named_scope("attention"):
                 out = ragged_attention_any(
                     attn_impl, q[0], kc, vc, ix.op, page_table, tok_seq,
                     tok_pos, kv_len, q_start, q_len, page_size,
-                    interpret=interpret, mesh=mesh,
+                    interpret=interpret, mesh=mesh, sink=sink,
                 )
             return out[None]
 
@@ -1990,7 +2075,7 @@ def forward_decode(
             active > 0, seq_lens, 0)
 
     def body(x, lp, kinds, ix, kc, vc, conv, rule, ring, pooled, *m):
-        def attn_fn(q, k, v, expanded=None):  # [B, 1, H, hd]
+        def attn_fn(q, k, v, expanded=None, sink=None):  # [B, 1, H, hd]
             nonlocal kc, vc, ring, pooled
             if kinds[0] == SPARSE:
                 live = jnp.ones((B,), bool) if active is None else active > 0
@@ -2012,13 +2097,14 @@ def forward_decode(
                         page_table, live_len, page_size)[:, None]
             if kinds[0] == WINDOW:
                 with jax.named_scope("kv_write"):
-                    ring = ring.write(ix.op, ring_slots, k[:, 0], v[:, 0])
+                    ring = ring.write(ix.op, ring_slots, _stored(k[:, 0]),
+                                      v[:, 0])
                 with jax.named_scope("attention"):
                     return paged_decode_attention_any(
                         attn_impl, q[:, 0], ring.k, ring.v, ix.op, ring_pt,
                         seq_lens, page_size, mesh=mesh,
-                        window=cfg.sliding_window,
-                        pos_base=ring_base)[:, None]
+                        window=cfg.sliding_window, pos_base=ring_base,
+                        sink=sink)[:, None]
             if cfg.kv_lora_rank:  # a stream of B one-token spans
                 q_idx = w_idx = None
                 with jax.named_scope("mla_cache_write"):
@@ -2033,12 +2119,12 @@ def forward_decode(
                     jnp.ones_like(rows), seq_lens, page_size,
                     cfg.kv_lora_rank, cfg.index_topk, tile=1)[:, None]
             with jax.named_scope("kv_write"):
-                kc = kv_write(kc, ix.op, write_slots, k[:, 0])
+                kc = kv_write(kc, ix.op, write_slots, _stored(k[:, 0]))
                 vc = kv_write(vc, ix.op, write_slots, v[:, 0])
             with jax.named_scope("attention"):
                 attn = paged_decode_attention_any(
                     attn_impl, q[:, 0], kc, vc, ix.op, page_table, seq_lens,
-                    page_size, mesh=mesh,
+                    page_size, mesh=mesh, sink=sink,
                 )  # [B,H,hd]
             return attn[:, None]
 

@@ -145,10 +145,22 @@ _KIMI_MLA = {
 _KIMI_SHARED = {"gate_proj": "ws_gate", "up_proj": "ws_up",
                 "down_proj": "ws_down"}
 _KIMI_BIAS = "gate.e_score_correction_bias"
-# (before Mixtral's, whose block and router it shares: told apart by the
-# selection bias beside the router)
-_HF_MOE_LAYOUTS = ((
-    "block_sparse_moe", "gate.weight", ("w1", "w3", "w2"), _KIMI_BIAS),
+# MiMo-V2-Flash (the published `mimo_v2_flash` tensor names, ASSUMED from the
+# family's modelling code; no checkpoint can be read offline): every layer's
+# `self_attn` has the four projections, of ANOTHER shape in a window layer
+# than in a full one — so they are stacked a kind (`_per_kind_attention`),
+# the window layers' behind "swa_" — and a window layer the sink's logit a
+# head, `attention_sink_bias`; its expert block is `mlp` with the selection
+# bias beside the router.
+_MIMO_ATTN = {"q_proj.weight": "wq", "k_proj.weight": "wk",
+              "v_proj.weight": "wv", "o_proj.weight": "wo"}
+_MIMO_SINK = "self_attn.attention_sink_bias"
+# (before Mixtral's and OLMoE's, whose block and router they share: told
+# apart by the selection bias beside the router)
+_HF_MOE_LAYOUTS = (
+    ("block_sparse_moe", "gate.weight", ("w1", "w3", "w2"), _KIMI_BIAS),
+    ("mlp", "gate.weight", ("gate_proj", "up_proj", "down_proj"),
+     _KIMI_BIAS),
 ) + _HF_MOE_LAYOUTS
 
 
@@ -266,6 +278,26 @@ def _kimi_linear(cfg: ModelConfig, grab, having, dtype) -> dict:
     return out
 
 
+def _per_kind_attention(cfg: ModelConfig, grab, dtype) -> dict:
+    """A checkpoint's `self_attn` tensors where the window and the full
+    layers differ in head shape (`ModelConfig.per_kind_attention`): a stack
+    an attention kind, the sink logits in float32."""
+    from ollamamq_tpu.config import ATTENTION, WINDOW
+
+    out = {}
+    for kind, pre in ((ATTENTION, ""), (WINDOW, "swa_")):
+        at = [i for i, (op, _) in enumerate(cfg.kinds) if op == kind]
+        for suffix, ours in _MIMO_ATTN.items():
+            out[pre + ours] = jnp.asarray(np.stack([
+                grab(f"model.layers.{i}.self_attn.{suffix}", True)
+                for i in at]), dtype=dtype)
+        if cfg.attn_shape(kind).sink:
+            out[pre + "sink"] = jnp.asarray(np.stack([
+                grab(f"model.layers.{i}.{_MIMO_SINK}", False)
+                for i in at]), dtype=jnp.float32)
+    return out
+
+
 def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
     """Load an HF-layout safetensors checkpoint into the stacked-layer tree."""
     from safetensors import safe_open
@@ -309,9 +341,12 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
     layers: dict = {}
     if cfg.kda:
         layers.update(_kimi_linear(cfg, grab, having, dtype))
+    if cfg.per_kind_attention:
+        layers.update(_per_kind_attention(cfg, grab, dtype))
+    own_attn = cfg.kda or cfg.per_kind_attention
     for hf_suffix, (ours, tr) in _HF_LAYER_MAP.items():
         at = having(hf_suffix)
-        if not at or (cfg.kda and hf_suffix.startswith("self_attn.")):
+        if not at or (own_attn and hf_suffix.startswith("self_attn.")):
             continue
         stack = np.stack(
             [grab(f"model.layers.{i}.{hf_suffix}", tr) for i in at])

@@ -27,12 +27,25 @@ argument, 0 for a full layer — over a page table that is arithmetic
 (`ring_table`): the ring pages of the row's slot from the page that holds the
 earliest position any of the span's queries sees, with the position that page
 stands for (`pos_base`), so a walk reads the window and not the context.
+
+K and V rows need not be alike (MiMo-V2-Flash: a key head of 192 lanes beside
+a value head of 128): every attention here reads the q·k width off q, the kv
+heads off the K pool's lanes and the p·v width off the V pool's. A head wider
+than a lane tile that does not fill whole tiles is STORED split (`lay_heads`:
+every head's whole tiles first, `[Hk x 128 | Hk x 64]` for 192 — the row holds
+no lane the model lacks, each head has an aligned tile, and two heads share
+the tile of their rests, which is the kernels' packed-heads case); the jnp
+twins put a gathered row's heads together again (`heads_of`). A window
+layer's softmax may carry a SINK, a learned float32 logit a head that every
+query sees and that carries no value: `sink` [H], one more term of the
+denominator (`_softmax`, the blockwise walk's last line).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +55,64 @@ from ollamamq_tpu.ops.quant import QuantKV, kv_gather, kv_write
 from ollamamq_tpu.parallel.mesh import AXIS_TENSOR
 
 NEG_INF = -1e30
+LANE = 128
+
+
+def split_head(dim: int) -> tuple:
+    """(lanes of a head stored first, lanes of its rest stored behind every
+    head's first part): a head of whole lane tiles, or one that fits in a
+    tile, is stored in one piece — rest 0."""
+    rest = dim % LANE if dim > LANE else 0
+    return dim - rest, rest
+
+
+def lay_heads(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., Hk, d] -> [..., Hk * d], a cache row as it is stored: the heads
+    side by side, or (`split_head`) every head's whole tiles and then every
+    head's rest."""
+    whole, rest = split_head(x.shape[-1])
+    flat = x.shape[:-2] + (-1,)
+    if not rest:
+        return x.reshape(flat)
+    return jnp.concatenate([x[..., :whole].reshape(flat),
+                            x[..., whole:].reshape(flat)], axis=-1)
+
+
+def heads_of(rows: jnp.ndarray, dim: int) -> jnp.ndarray:
+    """`lay_heads`' inverse: stored rows [..., Hk * dim] -> [..., Hk, dim]."""
+    whole, rest = split_head(dim)
+    if not rest:
+        return rows.reshape(rows.shape[:-1] + (-1, dim))
+    hk = rows.shape[-1] // dim
+    lead = rows.shape[:-1]
+    return jnp.concatenate(
+        [rows[..., :hk * whole].reshape(lead + (hk, whole)),
+         rows[..., hk * whole:].reshape(lead + (hk, rest))], axis=-1)
+
+
+def _gather_kv(k_cache, v_cache, layer, slots, hd: int):
+    """(k [*slots, Hk, hd], v [*slots, Hk, v lanes a head]) of `slots`."""
+    if split_head(hd)[1]:
+        k = heads_of(k_cache[layer, slots], hd)
+    else:
+        k = kv_gather(k_cache, layer, slots, hd)
+    # (a V row's lanes over the kv heads; a pool may be held [L, S, Hk, d])
+    return k, kv_gather(v_cache, layer, slots,
+                        math.prod(v_cache.shape[2:]) // k.shape[-2])
+
+
+def _softmax(logits: jnp.ndarray, sink=None):
+    """Softmax over the last axis of logits [., H, ..., keys], float32 — with
+    `sink` ([H], along axis 1) over one more column, the sink's logit, whose
+    weight is dropped: it carries no value."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    with jax.named_scope("attn_sink"):
+        b = sink.astype(jnp.float32).reshape(
+            (1, -1) + (1,) * (logits.ndim - 2))
+        m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), b)
+        p = jnp.exp(logits - m)
+        return p / (jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(b - m))
 
 
 def repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -57,6 +128,7 @@ def causal_attention(
     v: jnp.ndarray,  # [B, T, Hk, hd]
     seq_lens: jnp.ndarray,  # [B] valid lengths (padding masked out)
     window: int = 0,  # > 0: the last `window` positions only
+    sink=None,  # [H] float32: a logit a head in the softmax (`_softmax`)
 ) -> jnp.ndarray:
     """Causal self-attention over a padded prefill batch. f32 softmax."""
     B, T, H, hd = q.shape
@@ -73,7 +145,7 @@ def causal_attention(
     valid = pos[None, None, :] < seq_lens[:, None, None]  # [B, 1, k]
     mask = causal[None, None, :, :] & valid[:, None, :, :]
     logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = _softmax(logits, sink)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
 
@@ -118,6 +190,7 @@ def paged_chunk_attention(
     page_size: int,
     window: int = 0,  # a window layer: each query's last `window` positions,
     pos_base=None,  # [B] and the position the table's first page stands for
+    sink=None,  # [H] (`_softmax`)
 ) -> jnp.ndarray:
     """Chunked-prefill attention: the chunk's K/V are already scattered
     into the cache, so each query at global position start+i attends to
@@ -129,8 +202,7 @@ def paged_chunk_attention(
     index = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     slots = flat_slot_indices(page_table, index, page_size)  # [B, L]
     positions = index if pos_base is None else index + pos_base[:, None]
-    k = kv_gather(k_cache, layer, slots, hd)  # [B, L, Hk, hd] (int8 -> f32)
-    v = kv_gather(v_cache, layer, slots, hd)
+    k, v = _gather_kv(k_cache, v_cache, layer, slots, hd)  # [B, L, Hk, .]
     n_rep = H // k.shape[2]
     k = repeat_kv(k, n_rep)
     v = repeat_kv(v, n_rep)
@@ -145,7 +217,7 @@ def paged_chunk_attention(
     in_seq = positions[:, None, :] < (start + chunk_lens)[:, None, None]
     mask = (causal & in_seq)[:, None, :, :]
     logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = _softmax(logits, sink)
     out = jnp.einsum("bhcl,blhd->bchd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
 
@@ -160,6 +232,7 @@ def paged_decode_attention(
     page_size: int,
     window: int = 0,
     pos_base=None,
+    sink=None,
 ) -> jnp.ndarray:
     """Decode attention: each query attends to its own paged context.
 
@@ -171,7 +244,7 @@ def paged_decode_attention(
     out = paged_chunk_attention(
         q[:, None], k_cache, v_cache, layer, page_table,
         start=seq_lens - 1, chunk_lens=jnp.ones_like(seq_lens),
-        page_size=page_size, window=window, pos_base=pos_base,
+        page_size=page_size, window=window, pos_base=pos_base, sink=sink,
     )
     return out[:, 0]
 
@@ -188,6 +261,7 @@ def ragged_paged_attention(
     page_size: int,
     window: int = 0,
     pos_base=None,
+    sink=None,
 ) -> jnp.ndarray:
     """Ragged mixed-batch attention, materializing reference.
 
@@ -207,8 +281,7 @@ def ragged_paged_attention(
     index = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (T, L))
     slots = flat_slot_indices(rows, index, page_size)  # [T, L]
     positions = index if pos_base is None else index + pos_base[seq][:, None]
-    k = kv_gather(k_cache, layer, slots, hd)  # [T, L, Hk, hd] (int8 -> f32)
-    v = kv_gather(v_cache, layer, slots, hd)
+    k, v = _gather_kv(k_cache, v_cache, layer, slots, hd)  # [T, L, Hk, .]
     n_rep = H // k.shape[2]
     k = repeat_kv(k, n_rep)
     v = repeat_kv(v, n_rep)
@@ -222,7 +295,7 @@ def ragged_paged_attention(
     in_seq = positions < kv_lens[seq][:, None]
     mask = (causal & in_seq)[:, None, :]
     logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = _softmax(logits, sink)
     out = jnp.einsum("thl,tlhd->thd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
 
@@ -240,6 +313,7 @@ def ragged_paged_attention_blockwise(
     block_pages: int = 8,
     window: int = 0,
     pos_base=None,
+    sink=None,
 ) -> jnp.ndarray:
     """Non-materializing ragged attention: the jnp serving path.
 
@@ -251,8 +325,10 @@ def ragged_paged_attention_blockwise(
     tests/test_ragged_attention.py)."""
     T, H, hd = q.shape
     B, max_pages = page_table.shape
-    Hk = k_cache.shape[-1] // hd
+    Hk = math.prod(k_cache.shape[2:]) // hd
     n_rep = H // Hk
+    vd = math.prod(v_cache.shape[2:]) // Hk  # a value head's lanes: hd for
+    # every model but one whose K and V rows differ in width
     BLK = block_pages * page_size
     n_blocks = -(-max_pages // block_pages)  # static ceiling
     seq = jnp.clip(tok_seq, 0, B - 1)
@@ -277,10 +353,9 @@ def ragged_paged_attention_blockwise(
             pos = pos + first[:, None]
         slots = (pages[:, :, None] * page_size
                  + jnp.arange(page_size)[None, None, :]).reshape(T, BLK)
-        k = repeat_kv(kv_gather(k_cache, layer, slots, hd).astype(
-            jnp.float32), n_rep)  # [T,BLK,H,hd]
-        v = repeat_kv(kv_gather(v_cache, layer, slots, hd).astype(
-            jnp.float32), n_rep)
+        k, v = _gather_kv(k_cache, v_cache, layer, slots, hd)
+        k = repeat_kv(k.astype(jnp.float32), n_rep)  # [T,BLK,H,hd]
+        v = repeat_kv(v.astype(jnp.float32), n_rep)
         logits = jnp.einsum("thd,tlhd->thl", qf, k)  # [T, H, BLK]
         keep = (pos <= tok_pos[:, None]) & (pos < end[:, None])  # [T, BLK]
         if window:
@@ -297,11 +372,17 @@ def ragged_paged_attention_blockwise(
 
     m0 = jnp.full((T, H), NEG_INF, jnp.float32)
     l0 = jnp.zeros((T, H), jnp.float32)
-    a0 = jnp.zeros((T, H, hd), jnp.float32)
+    a0 = jnp.zeros((T, H, vd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(
         0, jnp.minimum(needed, n_blocks), body, (m0, l0, a0)
     )
-    out = acc / jnp.maximum(l, 1e-30)[..., None]  # [T, H, hd]
+    if sink is not None:  # one more term of the denominator, no value
+        with jax.named_scope("attn_sink"):
+            b = sink.astype(jnp.float32)[None, :]
+            top = jnp.maximum(m, b)
+            keep = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - top))
+            l, acc = l * keep + jnp.exp(b - top), acc * keep[..., None]
+    out = acc / jnp.maximum(l, 1e-30)[..., None]  # [T, H, vd]
     return out.astype(q.dtype)
 
 
@@ -310,8 +391,9 @@ def ragged_paged_attention_blockwise(
 @dataclasses.dataclass(frozen=True)
 class WindowRing:
     """The window layers' K and V: `[window layers, (slots + 1) * rows,
-    Hk*hd]` each, the pool's row layout (the kernels DMA pages out of it as
-    out of the pool). Slot s owns rows [s * rows, (s + 1) * rows) of every
+    Hk*hd]` each (K's lanes and V's may differ), the pool's row layout (the
+    kernels DMA pages out of it as out of the pool). Slot s owns rows
+    [s * rows, (s + 1) * rows) of every
     layer for life — no allocator, no table on the host — and position p of
     its sequence lives at row s * rows + p % rows: a ring that keeps the
     last `rows` positions, of which a query reads its window. `rows`
@@ -339,14 +421,17 @@ class WindowRing:
                           kv_write(self.v, layer, slots, v), self.rows)
 
 
-def alloc_ring(layers: int, max_slots: int, rows: int, lanes: int,
+def alloc_ring(layers: int, max_slots: int, rows: int, lanes: tuple,
                dtype=jnp.bfloat16):
     """The rings of a model with `layers` window layers (zeros), or None
-    for a model that has none — a pytree without leaves."""
+    for a model that has none — a pytree without leaves. `lanes`: of a K
+    row and of a V row (`ModelConfig.ring_row_dims`)."""
     if not layers:
         return None
-    shape = (layers, (max_slots + 1) * rows, lanes)
-    return WindowRing(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), rows)
+    k_lanes, v_lanes = lanes
+    shape = (layers, (max_slots + 1) * rows)
+    return WindowRing(jnp.zeros(shape + (k_lanes,), dtype),
+                      jnp.zeros(shape + (v_lanes,), dtype), rows)
 
 
 def ring_write_slots(slots, positions, valid, rows: int, trash: int):
@@ -439,6 +524,7 @@ def ragged_attention_any(
     #             a shard_map or on one device)
     window: int = 0,  # a window layer: the caches are its rings,
     pos_base=None,  # `page_table` / `pos_base` its `ring_table`
+    sink=None,  # [H] float32: a logit a head in the softmax (no mesh)
 ) -> jnp.ndarray:
     """The ONE pallas-vs-jnp ragged-attention dispatch (mirror of
     paged_decode_attention_any), shared by models/llama.forward_ragged so
@@ -455,6 +541,8 @@ def ragged_attention_any(
             kq, vq, scales = _split_quant(kc, vc)
             if window:  # (no mesh: config.validate_slot_state)
                 scales = dict(scales, window=window, pos_base=base[0])
+            if sink is not None:  # (closed over: no mesh either)
+                scales = dict(scales, sink=sink)
             return ragged_paged_attention_pallas(
                 q, kq, vq, layer, page_table, q_start, q_lens, kv_lens,
                 page_size, interpret=interpret, **scales)
@@ -464,7 +552,8 @@ def ragged_attention_any(
                                  *([pos_base] if window else []))
     return ragged_paged_attention_blockwise(
         q, k_cache, v_cache, layer, page_table, tok_seq, tok_pos, kv_lens,
-        page_size, window=window, pos_base=pos_base if window else None
+        page_size, window=window, pos_base=pos_base if window else None,
+        sink=sink,
     )
 
 
@@ -481,6 +570,7 @@ def paged_decode_attention_any(
     mesh=None,  # see ragged_attention_any
     window: int = 0,  # as ragged_attention_any's
     pos_base=None,
+    sink=None,
 ) -> jnp.ndarray:
     """The ONE pallas-vs-jnp decode-attention dispatch
     (models/llama.forward_decode). The pallas import stays deferred: the
@@ -494,6 +584,8 @@ def paged_decode_attention_any(
             kq, vq, scales = _split_quant(kc, vc)
             if window:
                 scales = dict(scales, window=window, pos_base=base[0])
+            if sink is not None:
+                scales = dict(scales, sink=sink)
             return paged_decode_attention_pallas(
                 q, kq, vq, layer, page_table, seq_lens, page_size,
                 interpret=interpret, **scales)
@@ -503,5 +595,5 @@ def paged_decode_attention_any(
                                  *([pos_base] if window else []))
     return paged_decode_attention(
         q, k_cache, v_cache, layer, page_table, seq_lens, page_size,
-        window=window, pos_base=pos_base if window else None
+        window=window, pos_base=pos_base if window else None, sink=sink,
     )

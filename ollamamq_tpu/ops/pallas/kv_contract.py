@@ -36,6 +36,41 @@ no environment variable, no model name):
       the wrapper keeps each row's own lanes of the result. Twice the
       MXU work, no relayout, and the kernel does not know: it sees
       `tiles` tiles of width W with `Mp` rows each.
+        head_dim = 128 + r, r | 128, beside a VALUE head of 128 (192 / 128:
+      MiMo-V2-Flash; `MxuSplit`, PR 65): the third layout case. The pools'
+      rows are stored SPLIT (`ops/attention.py:lay_heads`) — K `[Hk x 128 |
+      Hk x r]`, V `[Hk x 128]`: no lane the model lacks, every head an
+      aligned tile, 128 // r heads share the tile of their rests — so a
+      lane tile of the state is a kv head as at 128 lanes, and the scores
+      are TWO contractions a (block, head): q's first 128 lanes against the
+      head's tile, q's rest — zero-padded in the other heads' lanes of the
+      shared tile, the packed-heads case's trick — against that tile, summed
+      in float32; p · v one. q arrives `[.., M, 256]`, the output leaves
+      `[.., M, 128]` (`o_block`: the one shape whose output block is not
+      q's). Both widths are read off the operands (q's last axis, the V
+      pool's lanes over the kv heads): with them equal and no sink a launch
+      is what it was (`tests/test_mimo_v2_flash_kernels.py` holds operands,
+      grid, scratch and contractions a tile at four head shapes). M = rows x
+      16 in the full layers: a tile of 8 rows is 128 row-heads, a `TALL`
+      stretch 1024 a lane tile — kept at 64 tokens: Mosaic fits it in the
+      scoped VMEM (q 2 x 2 MB double-buffered at 256 lanes, the state 6 MB;
+      AOT, `tests/test_chip_compile_mimo.py`) and the trip does a stretch's
+      work for 4 kv heads in 3.3 µs where (64, 8, 128)'s takes 2.3 for 8 at
+      half the rows; `TALL` 32 there reads 2.49 ms a launch at 8 k for 64's
+      2.36 (939 tall trips of 1.87 µs for 439 of 3.39; PR 65, call 5). Measured, ms a launch (`scripts/attn_kernel_bench.py
+      --shapes 8 9`, one v5e; my chip run, PR 65, call 3; (64, 8, 128) in
+      call 2), `decode` / `ragged64` / `ragged512`, `raggedlong` at 4 k / 8
+      k / 16 k with µs a tall / a short trip, and the window launch (at the
+      new shapes WITH a sink):
+        (64, 4, 192/128)  0.190 0.213 0.319   1.202 2.343 4.627
+                          3.56 3.35 3.25 / 1.12 1.08 1.06   window 0.242
+        (64, 8, 192/128)  0.328 0.279 0.443   1.396 2.672 5.222
+                          3.82 3.48 3.31 / 1.46 1.42 1.39   window 0.329
+        (64, 8, 128)      0.188 0.171 0.233   0.896 1.767 3.502
+                          2.49 2.38 2.31 / 0.92 0.90 0.89   window 0.150
+      — a full layer's launch at 8 k costs 1.33 x K-EXAONE's for 1.25 x its
+      FLOPs a pair (320 lanes for 256); every row agrees with its jnp twin
+      to the bf16 output's last bit or two (max |diff| 0.0005-0.016).
   M == 1 → `Vpu`: the decode kernel of an MHA model (OLMoE, 16 × 128).
       The body both kernels had before PR 33: per (group, page) `k * q`
       over the whole `[page_size, Hk*hd]` page in float32 on the VPU,
@@ -250,7 +285,15 @@ in every cell (PR 33 was refused for it: 8 successors × 16 lane tiles =
     trip's tile loop IS rolled beyond eight tiles, PRs 48 and 61: see above
     for what that buys a start and costs a launch.)
 `hd % 128 == 0` or `hd == 64` changes none of this: the packing is the
-wrapper's, the kernel sees `tiles` tiles of width W.
+wrapper's, the kernel sees `tiles` tiles of width W (`MxuSplit`: and a
+second key tile a head, read in `_qk`).
+  A SINK (PR 65; a window layer's learned float32 logit a query head, one
+more column of the softmax that carries no value) is an optional operand:
+packed as `m_i` is (`Mxu.pack_sink`: `[tiles, Mp, 128]`, lane-replicated,
+rows in `pack_q`'s order), in VMEM, whole in every program, behind q; the
+walks never see it — `finish` rebases the state on max(m, b), adds exp(b -
+that) to l and nothing to acc. Absent (`split_sink`), nothing of it is
+traced.
   What a rung's trace is charged for is not only equations (PR 38): on a
 tracer every operator (`a + b`, `a < b`) and every `jnp.where` /
 `minimum` / `clip` is a NESTED JIT, and inside a serving process — whose
@@ -439,9 +482,23 @@ def inner_report(group: int) -> dict:
 
 
 def make_inner(name, *, rows, group, num_kv_heads, head_dim, page_size,
-               subs=1, window=0):
-    cls = {"mxu": Mxu, "vpu": Vpu}[name or choose_inner(rows, group)]
-    return cls(rows, group, num_kv_heads, head_dim, page_size, subs, window)
+               subs=1, window=0, v_dim=0, sink=False):
+    """The inner product of a launch. `head_dim`: lanes of a q and k head;
+    `v_dim`: of a v head where it is another (0: the same — then, and with
+    no `sink`, the inner product and its launch are what they were before
+    either existed: no operand, tile or scratch row more)."""
+    if v_dim in (0, head_dim) and not sink:
+        cls = {"mxu": Mxu, "vpu": Vpu}[name or choose_inner(rows, group)]
+        return cls(rows, group, num_kv_heads, head_dim, page_size, subs,
+                   window)
+    cls = Mxu if v_dim in (0, head_dim) else MxuSplit
+    if (name or "mxu") != "mxu":
+        raise ValueError("a sink or a value head of another width than the "
+                         "key's is served by the Mxu inner product")
+    inner = cls(rows, group, num_kv_heads, head_dim, page_size, subs, window,
+                v_dim or head_dim, sink)
+    inner.check()
+    return inner
 
 
 def programs_height(stream_len: int) -> int:
@@ -478,15 +535,24 @@ def ring_grid_spec(inner, ring, grid, num_scalar_prefetch, pools):
     ring's position (`RingWalk`)."""
     nbuf = max(2, ring // inner.block_pages)
     blk = inner.block_pages * inner.page_size
-    q_block = inner.q_block
-    q_spec = pl.BlockSpec(
-        q_block, lambda i, *_: (i,) + (0,) * (len(q_block) - 1),
-        memory_space=pltpu.VMEM)
+
+    def by_program(block):
+        return pl.BlockSpec(
+            block, lambda i, *_: (i,) + (0,) * (len(block) - 1),
+            memory_space=pltpu.VMEM)
+
+    q_spec = by_program(inner.q_block)
+    sink = []
+    if inner.sink:  # the packed sink, whole, in every program
+        sink = [pl.BlockSpec(inner.sink_block, lambda i, *_: (0, 0, 0),
+                             memory_space=pltpu.VMEM)]
     return nbuf, pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=grid,
-        in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=q_spec,
+        in_specs=[q_spec] + sink
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=q_spec if inner.o_block == inner.q_block
+        else by_program(inner.o_block),
         scratch_shapes=[pltpu.VMEM((nbuf, blk, p.shape[-1]), p.dtype)
                         for p in pools] + inner.scratch()
         + [pltpu.SemaphoreType.DMA((nbuf, len(pools))),
@@ -514,6 +580,14 @@ def split_window(refs, window: int):
     context before them; None without a window, and then nothing of this is
     traced."""
     return (refs[0], refs[1:]) if window else (None, refs)
+
+
+def split_sink(refs, sink: bool):
+    """(sink_ref, the other refs) of a kernel's refs after the scalar
+    prefetch and `split_window`: a launch with a sink carries it packed
+    (`Mxu.pack_sink`) behind q, in VMEM; None without one, and then nothing
+    of it is traced."""
+    return (refs[1], refs[:1] + refs[2:]) if sink else (None, refs)
 
 
 def split_refs(refs):
@@ -611,10 +685,22 @@ class _Inner:
     # positions p - window < j <= p only. The walk then starts at the page
     # that holds the earliest of them (`split_window`); the mask here.
     window: int = 0
+    # Lanes of a VALUE head where they are not `head_dim` (`MxuSplit`), and
+    # whether the launch carries a sink: a float32 logit a query head that
+    # joins the softmax's denominator in `finish` and carries no value.
+    v_dim: int = 0
+    sink: bool = False
 
     @property
     def lanes(self):
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def o_block(self):  # the output's block: q's, where V is as wide as K
+        return self.q_block
+
+    def o_shape(self, q_packed_shape):
+        return tuple(q_packed_shape[:-1]) + (self.o_block[-1],)
 
     def init(self, acc, m_i, l_i):
         acc[...] = jnp.zeros_like(acc)
@@ -790,6 +876,27 @@ class Mxu(_Inner):
                 pltpu.VMEM(held + (LANE,), jnp.float32),
                 pltpu.VMEM(held + (LANE,), jnp.float32)]
 
+    def check(self):
+        """(`make_inner`: what this inner product cannot serve raises.)"""
+
+    def _qk(self, q, k, bufs, slot, t):
+        """q [M, W] · k [blk, W]ᵀ of lane tile t → [M, blk] f32."""
+        return _dot(q, k, _NT)
+
+    @property
+    def sink_block(self):
+        return (self.tiles, self.mp, LANE)
+
+    def pack_sink(self, sink):
+        """[H] float32 → `sink_block`: a row-head's own logit, lane-
+        replicated as `m_i` is, rows in `pack_q`'s order (padding rows 0)."""
+        hpt = self.heads_per_tile
+        x = sink.astype(jnp.float32).reshape(self.tiles, hpt, self.group, 1)
+        x = jnp.broadcast_to(x, x.shape[:-1] + (self.rows,)).reshape(
+            self.tiles, hpt * self.m)
+        x = jnp.pad(x, ((0, 0), (0, self.mp - hpt * self.m)))
+        return jnp.broadcast_to(x[..., None], self.sink_block)
+
     def _pv(self, p, v):
         """p [M, blk] f32 · v [blk, W] → [M, W] f32: P enters at the
         block's dtype (module docstring)."""
@@ -868,10 +975,12 @@ class Mxu(_Inner):
                         for x in (k_all, v_all))
             else:
                 k, v = bufs[0][slot, :, lanes], bufs[1][slot, :, lanes]
-            q = q_ref[:, t].reshape(m, W) if tall else q_ref[sub, t]
+            q = q_ref[:, t].reshape(m, q_ref.shape[-1]) if tall \
+                else q_ref[sub, t]
             if q.dtype != k.dtype:
                 q, k = q.astype(jnp.float32), k.astype(jnp.float32)
-            sc = _where(valid, lax.mul(_dot(q, k, _NT), scale), NEG_INF)
+            sc = _where(valid, lax.mul(self._qk(q, k, bufs, slot, t), scale),
+                        NEG_INF)
             m_prev = get(m_i, t)  # [M, 128], lane-replicated
             m_new = lax.max(m_prev, _rows(lax.reduce_max, sc))
             # Rows outside the span, and blocks wholly beyond a row's
@@ -894,8 +1003,100 @@ class Mxu(_Inner):
                 fold(t)
         done_reading()
 
-    def finish(self, o_ref, state):
-        acc, _, l_i = state
+    def finish(self, o_ref, state, sink_ref=None):
+        acc, m_i, l_i = state
         for t in range(self.tiles):  # every tile of the program at once
-            denom = _lane_fit(lax.max(l_i[t], 1e-20), self.width)
-            o_ref[:, t] = lax.div(acc[t], denom).astype(o_ref.dtype)
+            if sink_ref is None:
+                denom = _lane_fit(lax.max(l_i[t], 1e-20), self.width)
+                o_ref[:, t] = lax.div(acc[t], denom).astype(o_ref.dtype)
+                continue
+            # One more column, the sink's logit b: the state rebased on
+            # max(m, b), exp(b - that) joins the sum, nothing joins acc.
+            b = lax.broadcast_in_dim(  # [Mp, 128] over the tiles held
+                sink_ref[t], m_i[t].shape, (1, 2))
+            top = lax.max(m_i[t], b)
+            keep = _where(lax.le(m_i[t], NEG_INF / 2), 0.0,
+                          lax.exp(lax.sub(m_i[t], top)))
+            denom = _lane_fit(lax.max(lax.add(
+                lax.mul(l_i[t], keep), lax.exp(lax.sub(b, top))), 1e-20),
+                self.width)
+            o_ref[:, t] = lax.div(
+                lax.mul(acc[t], _lane_fit(keep, self.width)),
+                denom).astype(o_ref.dtype)
+
+
+class MxuSplit(Mxu):
+    """`Mxu` for a key head of one whole lane tile and a rest (192 = 128 +
+    64) beside a value head of one tile (128): MiMo-V2-Flash's. The pools'
+    rows are stored split (`ops/attention.py:lay_heads`): K `[Hk x 128 | Hk x
+    64]`, V `[Hk x 128]`. A lane tile of the state is a kv head, as where
+    `head_dim` is 128 — K's tile t and V's tile t are head t's — and the
+    head's rest lies in a tile that LANE // rest heads share, behind every
+    head's first part: the packed-heads case's trick once more, q's rest
+    zero-padded in the other heads' lanes of that tile (`pack_q`), so the
+    scores are TWO contractions a (block, head), `[M, 128] · [blk, 128]ᵀ`
+    twice, summed in float32 (2 tiles of MXU work for 1.5 of lanes), and p ·
+    v one. q arrives `[.., M, 256]`, the output leaves `[.., M, 128]`."""
+
+    def check(self):
+        whole = self.head_dim - self.rest
+        if not (whole == self.v_dim == LANE and self.rest
+                and LANE % self.rest == 0
+                and self.num_kv_heads * self.rest % LANE == 0
+                and self.num_kv_heads <= TALL_UNROLL):
+            raise ValueError(
+                f"kv heads of {self.head_dim} key and {self.v_dim} value "
+                f"lanes x {self.num_kv_heads}: the split layout serves a key "
+                "head of one lane tile and a rest that divides one, whole "
+                f"tiles of rests, a value head of one tile and at most "
+                f"{TALL_UNROLL} kv heads")
+
+    @property
+    def rest(self):
+        return self.head_dim % LANE
+
+    heads_per_tile = 1
+    width = LANE
+
+    @property
+    def tiles(self):
+        return self.num_kv_heads
+
+    def pack_q(self, q):
+        """[N*rows, H, 192] → [N, Hk, Mp, 256]: row g*rows + r of tile t is
+        query head t*group + g of row r — its first 128 lanes, then a tile
+        with its rest where head t's rest lies in the shared K tile."""
+        rest, share = self.rest, LANE // self.rest
+        n = q.shape[0] // self.rows
+        x = q.reshape(n, self.rows, self.tiles, self.group, self.head_dim)
+        x = x.transpose(0, 2, 3, 1, 4).reshape(n, self.tiles, self.m,
+                                               self.head_dim)
+        at = jnp.eye(share, dtype=q.dtype)[
+            jnp.arange(self.tiles) % share]  # [Hk, share]
+        tail = (x[..., None, LANE:] * at[:, None, :, None]).reshape(
+            n, self.tiles, self.m, LANE)
+        x = jnp.concatenate([x[..., :LANE], tail], axis=-1)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, self.mp - self.m), (0, 0)))
+
+    def unpack_o(self, o):
+        n = o.shape[0]
+        x = o[:, :, :self.m].reshape(n, self.tiles, self.group, self.rows,
+                                     self.v_dim)
+        return x.transpose(0, 3, 1, 2, 4).reshape(
+            n * self.rows, self.tiles * self.group, self.v_dim)
+
+    @property
+    def q_block(self):
+        return (self.subs, self.tiles, self.mp, 2 * LANE)
+
+    @property
+    def o_block(self):
+        return (self.subs, self.tiles, self.mp, LANE)
+
+    def _qk(self, q, k, bufs, slot, t):
+        assert isinstance(t, int) and len(bufs) == 2, \
+            "the split layout: unrolled tiles over a bf16 / float32 pool"
+        at = (self.num_kv_heads + t // (LANE // self.rest)) * LANE
+        k_rest = bufs[0][slot, :, at:at + LANE].astype(k.dtype)
+        return lax.add(_dot(q[:, :LANE], k, _NT),
+                       _dot(q[:, LANE:], k_rest, _NT))

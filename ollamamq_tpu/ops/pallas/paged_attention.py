@@ -58,7 +58,8 @@ from jax.experimental import pallas as pl
 from ollamamq_tpu.ops.pallas.kv_contract import (PageStream, cdiv,
                                                  make_inner, mod,
                                                  ring_grid_spec, split_refs,
-                                                 split_window, whole_blocks)
+                                                 split_sink, split_window,
+                                                 whole_blocks)
 
 # A window layer's launch on the device trace: a name of its own, so that
 # what reads the full layers' launches by name does not count these.
@@ -86,6 +87,7 @@ def _decode_kernel(
     max_pages: int,
 ):
     base_ref, refs = split_window(refs, inner.window)
+    sink_ref, refs = split_sink(refs, inner.sink)
     q_ref, hbm, o_ref, bufs, state, sems, at_ref = split_refs(refs)
     b = pl.program_id(0)
     nb = pl.num_programs(0)
@@ -168,7 +170,10 @@ def _decode_kernel(
     jax.lax.fori_loop(0, n, body, ())
     at_ref[0] = mod(lax.add(at, n), nbuf)
 
-    inner.finish(o_ref, state)
+    if sink_ref is None:
+        inner.finish(o_ref, state)
+    else:
+        inner.finish(o_ref, state, sink_ref)
 
 
 @functools.partial(jax.jit,
@@ -189,14 +194,22 @@ def paged_decode_attention_pallas(
     #   serving path leaves the inner product to kv_contract.choose_inner
     window: int = 0,  # a window layer's launch: a row sees its last
     #   `window` positions, and `page_table` lists its pages from position
+    sink=None,  # [H] float32: a learned logit a head that joins the
+    #   softmax's denominator and carries no value (kv_contract.Mxu.finish)
     pos_base=None,  # [B] on (ops/attention.py:ring_table; WINDOW_NAME)
 ) -> jnp.ndarray:
     B, H, hd = q.shape
     max_pages = page_table.shape[1]
     lanes = k_cache.shape[-1]
     Hk = lanes // hd
+    # A value head's lanes: hd for every model but one whose K and V rows
+    # differ in width (kv_contract.MxuSplit); with those equal and no sink
+    # the launch is what it was before either existed.
+    v_dim = v_cache.shape[-1] // Hk
     inner = make_inner(inner, rows=1, group=H // Hk, num_kv_heads=Hk,
-                       head_dim=hd, page_size=page_size, window=window)
+                       head_dim=hd, page_size=page_size, window=window,
+                       v_dim=v_dim if v_dim != hd else 0,
+                       sink=sink is not None)
 
     pools = [k_cache, v_cache]
     if k_scale is not None:  # an int8 pool's scale planes
@@ -211,12 +224,14 @@ def paged_decode_attention_pallas(
     )
 
     q_packed = inner.pack_q(q)
+    packed_sink = [] if sink is None else [inner.pack_sink(sink)]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q_packed.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(inner.o_shape(q_packed.shape),
+                                       q.dtype),
         interpret=interpret, **({"name": WINDOW_NAME} if window else {}),
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       whole_blocks(page_table, inner), seq_lens.astype(jnp.int32), *base,
-      q_packed, *pools)
+      q_packed, *packed_sink, *pools)
     return inner.unpack_o(out)

@@ -99,7 +99,8 @@ from ollamamq_tpu.ops.pallas.kv_contract import (G_TILE, PageStream, cdiv,
                                                  make_inner, mod,
                                                  programs_height,
                                                  ring_grid_spec, split_refs,
-                                                 split_window, whole_blocks)
+                                                 split_sink, split_window,
+                                                 whole_blocks)
 
 # Pages in flight: one block is the least a ring holds, and it holds two.
 # 12 or 16 pages (3 or 4 blocks) read 3-15 % slower on 64 rows over
@@ -126,6 +127,7 @@ def _ragged_kernel(
     num_seqs: int,
 ):
     base_ref, refs = split_window(refs, inner.window)
+    sink_ref, refs = split_sink(refs, inner.sink)
     q_ref, hbm, o_ref, bufs, state, sems, at_ref = split_refs(refs)
     subs = inner.subs  # tiles a program holds; 1: no tall body is traced
     height = subs * G_TILE
@@ -290,7 +292,10 @@ def _ragged_kernel(
                 *entry(lax.mul(lax.add(prog, 1), subs),
                        lax.select(next_tall, height, G_TILE)), None)
 
-    inner.finish(o_ref, state)
+    if sink_ref is None:
+        inner.finish(o_ref, state)
+    else:
+        inner.finish(o_ref, state, sink_ref)
 
 
 @functools.partial(jax.jit,
@@ -310,6 +315,8 @@ def ragged_paged_attention_pallas(
     v_scale=None,
     window: int = 0,  # a window layer's launch: a token sees its last
     #   `window` positions, and `page_table` lists a row's pages from
+    sink=None,  # [H] float32: a learned logit a head that joins the
+    #   softmax's denominator and carries no value (kv_contract.Mxu.finish)
     pos_base=None,  # [B] position on (ops/attention.py:ring_table;
     #   WINDOW_NAME on the trace)
 ) -> jnp.ndarray:
@@ -317,12 +324,18 @@ def ragged_paged_attention_pallas(
     B, max_pages = page_table.shape
     lanes = k_cache.shape[-1]
     Hk = lanes // hd
+    # A value head's lanes: hd for every model but one whose K and V rows
+    # differ in width (kv_contract.MxuSplit); with those equal and no sink
+    # the launch is what it was before either existed.
+    v_dim = v_cache.shape[-1] // Hk
     # Always the Mxu inner product: a tile's G_TILE rows share each block,
     # and on a rung that holds a whole stretch a program's tiles do.
     height = programs_height(T)
     inner = make_inner(None, rows=G_TILE, group=H // Hk, num_kv_heads=Hk,
                        head_dim=hd, page_size=page_size,
-                       subs=height // G_TILE, window=window)
+                       subs=height // G_TILE, window=window,
+                       v_dim=v_dim if v_dim != hd else 0,
+                       sink=sink is not None)
 
     Tp = -(-T // height) * height
     n_tiles = Tp // G_TILE
@@ -348,13 +361,15 @@ def ragged_paged_attention_pallas(
     )
 
     q_packed = inner.pack_q(jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0))))
+    packed_sink = [] if sink is None else [inner.pack_sink(sink)]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q_packed.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(inner.o_shape(q_packed.shape),
+                                       q.dtype),
         interpret=interpret, **({"name": WINDOW_NAME} if window else {}),
     )(jnp.asarray(layer, jnp.int32).reshape(1), tile_first,
       q_start.astype(jnp.int32), q_lens.astype(jnp.int32),
       kv_lens.astype(jnp.int32), whole_blocks(page_table, inner), *base,
-      q_packed, *pools)
+      q_packed, *packed_sink, *pools)
     return inner.unpack_o(out)[:T]

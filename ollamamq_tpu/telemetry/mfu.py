@@ -12,7 +12,7 @@ accelerators (CPU meshes in CI) yield None and the engine publishes
 mfu=0 rather than a made-up number. OLLAMAMQ_PEAK_FLOPS overrides —
 that is also how CPU tests get a deterministic nonzero MFU.
 
-Stdlib only: the ModelConfig duck-types (param_count, count, q_dim),
+Stdlib only: the ModelConfig duck-types (param_count, count, attn_shape),
 so the doc checker and tests can import this without jax.
 """
 
@@ -54,6 +54,16 @@ def active_param_count(cfg) -> int:
     return cfg.param_count(active=True)
 
 
+def _pair_lanes(cfg, kind: str) -> float:
+    """What `q_dim` stands for in a (token, cached position) pair's FLOPs,
+    of an attention layer of `kind`: heads x (q . k lanes + p . v lanes) / 2
+    — `q_dim` itself where a value head is as wide as a key head (every
+    model but one with `v_head_dim` beside plain K/V attention: MiMo-V2-Flash,
+    64 x (192 + 128) x 2 FLOPs a pair)."""
+    a = cfg.attn_shape(kind)
+    return a.heads * (a.qk_dim + a.v_dim) / 2
+
+
 def flops_per_token(cfg, context_len: float = 0.0,
                     exits: bool = False) -> float:
     """Forward FLOPs to generate one token at the given KV context — or,
@@ -71,7 +81,7 @@ def flops_per_token(cfg, context_len: float = 0.0,
     sparse = cfg.count("sparse_attention")
     walks = cfg.paged_layers - sparse \
         + (0 if below else cfg.count("cross_attention"))
-    attn = pair * walks * ctx * cfg.q_dim
+    attn = pair * walks * ctx * _pair_lanes(cfg, "full_attention")
     if sparse:
         # ...a block-sparse layer's stops growing past `sparse_dense_len`,
         # at the kept blocks' keys, and pays the block scores instead: a
@@ -83,7 +93,7 @@ def flops_per_token(cfg, context_len: float = 0.0,
                           if ctx > cfg.sparse_dense_len else 0.0))
     # ...and a window layer attends its last `sliding_window` positions.
     attn += pair * cfg.count("sliding_attention") \
-        * min(ctx, cfg.sliding_window) * cfg.q_dim
+        * min(ctx, cfg.sliding_window) * _pair_lanes(cfg, "sliding_attention")
     if getattr(cfg, "kda", False):
         # ...and Kimi Delta Attention's recurrence, which no parameter
         # counts: a token decays, reads, corrects and reads again a [dk, dv]

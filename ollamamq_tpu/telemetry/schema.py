@@ -525,6 +525,17 @@ SWA_FULL_ROWS_TOTAL = REGISTRY.counter(
     "(what a window served as a mask costs): ollamamq_swa_walk_rows_total "
     "over this is the share of the context the window launches walk",
     labels=("model",))
+KV_ROW_BYTES = REGISTRY.gauge(
+    "ollamamq_kv_row_bytes",
+    "Bytes ONE cached position of one attention layer occupies AS STORED, K "
+    "row and V row, by the layer's kind (full_attention: in the paged pool; "
+    "sliding_attention: in the rings) — of a model whose attention kinds "
+    "differ in head shape (MiMo-V2-Flash: kv heads a kind, key heads wider "
+    "than value heads); `attn_row_bytes` / `swa_row_bytes` on a step sample. "
+    "The step programs' named scopes of such a model: `attn_vscale` (v "
+    "times attention_value_scale) and `attn_sink` (the jnp attentions' sink "
+    "term; the Pallas kernels fold it inside their launch)",
+    labels=("model", "kind"))
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

@@ -3,8 +3,11 @@ and head shape, on the chip: `chiprun -- python scripts/attn_kernel_bench.py`.
 
 For each published head shape (H, Hk, hd) — the sixth, (16, 2, 256), is
 Qwen3-Next's gated attention: two lane tiles a kv head; the seventh and
-eighth, (64, 8, 128) and (20, 4, 128), are K-EXAONE's and Falcon-H1's — and
-each inner product a kernel can be built with (`ops/pallas/kv_contract.py`:
+eighth, (64, 8, 128) and (20, 4, 128), are K-EXAONE's and Falcon-H1's; the
+ninth and tenth, (64, 4, 192, 128) and (64, 8, 192, 128), are MiMo-V2-Flash's
+full and window layers: a FOURTH number is a value head's lanes where they
+are not the key head's (the Mxu inner product alone; the window row of such a
+shape carries a sink) — and each inner product a kernel can be built with (`ops/pallas/kv_contract.py`:
 the decode kernel takes either, the ragged kernel has one) it builds a bf16
 pool at the CLI's defaults (1024 pages of 32 tokens, 64 slots) with 64
 sequences of 200-380 tokens of context, checks the kernel against the jnp
@@ -108,8 +111,13 @@ from ollamamq_tpu.ops.pallas import (kv_contract, mla_attention,
 MODULES = {m.__name__.rsplit(".", 1)[1]: m
            for m in (kv_contract, mla_attention, paged_attention,
                      ragged_attention)}
+# (H, Hk, hd) — or (H, Hk, hd, vd) where a value head is narrower than a key
+# head (MiMo-V2-Flash's two, `--shapes 8 9`: the full layers' group of 16 and
+# the window layers' group of 8, whose `raggedlong_window` row carries a sink;
+# their K rows are read in the split layout, `kv_contract.MxuSplit`).
 SHAPES = ((28, 4, 128), (8, 2, 128), (32, 8, 64), (16, 16, 128),
-          (30, 30, 128), (16, 2, 256), (64, 8, 128), (20, 4, 128))
+          (30, 30, 128), (16, 2, 256), (64, 8, 128), (20, 4, 128),
+          (64, 4, 192, 128), (64, 8, 192, 128))
 B, MP, PS, NP, LAYER = 64, 256, 32, 1024, 1
 # The jnp reference gathers a table's whole width, [B, width*PS, lanes]:
 # it is fed the columns a context here can reach and no more.
@@ -297,10 +305,15 @@ def best_of_three(fn, *args) -> float:
 
 def timed(fn, q, *args) -> float:
     """Seconds a launch: LAUNCHES calls chained through q in one jit."""
+    def again(_, q):  # (a value head narrower than the key head: the
+        out = fn(q, *args).astype(q.dtype)  # output fills q's first lanes)
+        if out.shape == q.shape:
+            return out
+        return jnp.concatenate([out, q[..., out.shape[-1]:]], axis=-1)
+
     @jax.jit
     def chain(q, *args):
-        return jax.lax.fori_loop(
-            0, LAUNCHES, lambda _, q: fn(q, *args).astype(q.dtype), q)
+        return jax.lax.fori_loop(0, LAUNCHES, again, q)
 
     return best_of_three(chain, q, *args) / LAUNCHES
 
@@ -589,14 +602,16 @@ def main() -> int:
                  c - (LONG_SPAN - head)),
                 ("raggedlong", LONG_ROWS, (LONG_SPAN,), c, c)]
     long_mp = max(args.contexts or LONG_CONTEXTS) // PS
-    for H, Hk, hd in (SHAPES[i] for i in args.shapes if traffics):
-        kc, vc = (jnp.asarray(rng.standard_normal((2, NP * PS, Hk * hd)),
-                              jnp.bfloat16) for _ in range(2))
+    for shape in (SHAPES[i] for i in args.shapes if traffics):
+        H, Hk, hd, vd = (shape + shape[2:])[:4]  # (vd: hd unless given)
+        kc, vc = (jnp.asarray(rng.standard_normal((2, NP * PS, Hk * d)),
+                              jnp.bfloat16) for d in (hd, vd))
         if "raggedlong" in args.traffic:  # a pool that holds every context
             long_pages = (LONG_ROWS + 1) * long_mp + 1
             kc_long, vc_long = (jax.random.normal(
-                key, (2, long_pages * PS, Hk * hd), jnp.bfloat16)
-                for key in jax.random.split(jax.random.PRNGKey(args.seed)))
+                key, (2, long_pages * PS, Hk * d), jnp.bfloat16)
+                for key, d in zip(jax.random.split(
+                    jax.random.PRNGKey(args.seed)), (hd, vd)))
         us_short = {}  # a variant's short trip, from its `raggedlong_head`
         for traffic, n_dec, spans, context, span_ends in traffics:
             long = traffic.startswith("raggedlong")
@@ -620,14 +635,15 @@ def main() -> int:
                     tok_seq[checked], tok_pos[checked], kv_len, q_start,
                     q_len, PS)
             for inner, consts in variants:
-                if traffic != "decode" and inner == "vpu":
-                    continue  # the ragged kernel has one inner product
+                if inner == "vpu" and (traffic != "decode" or vd != hd):
+                    continue  # the ragged kernel has one inner product, and
+                    # so has a value head narrower than the key head
                 with constants(consts) as names:
                     built = kv_contract.make_inner(
                         inner if traffic == "decode" else None,
                         rows=1 if traffic == "decode" else kv_contract.G_TILE,
                         group=H // Hk, num_kv_heads=Hk, head_dim=hd,
-                        page_size=PS)
+                        page_size=PS, v_dim=vd if vd != hd else 0)
                     bp = built.block_pages
                     short, tall = walks(traffic, kv_len, q_start, q_len, T)
                     pages = short + tall
@@ -647,7 +663,7 @@ def main() -> int:
                                           PS)
                         operands = (*pools, pt, q_start, q_len, kv_len)
                     row = {
-                        "shape": [H, Hk, hd], "traffic": traffic, "tokens": T,
+                        "shape": list(shape), "traffic": traffic, "tokens": T,
                         "context": context or "200-380",
                         "inner": built.name,
                         "set": names}
@@ -675,35 +691,40 @@ def main() -> int:
                     print(json.dumps(row), flush=True)
             if traffic == "raggedlong":
                 print(json.dumps(windowed(
-                    rng, (H, Hk, hd), q, checked, tok_seq, tok_pos, kv_len,
-                    q_start, q_len, T, context)), flush=True)
+                    rng, (H, Hk, hd, vd), q, checked, tok_seq, tok_pos,
+                    kv_len, q_start, q_len, T, context)), flush=True)
     return 0
 
 
 def windowed(rng, shape, q, checked, tok_seq, tok_pos, kv_len, q_start, q_len,
              T, context) -> dict:
     """The `raggedlong` step as a window layer's launch: the row of the
-    kernel over rings, against its jnp twin over the same table."""
-    H, Hk, hd = shape
+    kernel over rings, against its jnp twin over the same table — with a
+    SINK a head where the value head is narrower than the key head (the one
+    model that has either has both)."""
+    H, Hk, hd, vd = shape
     rows = q_len.shape[0]
     rk, rv = (jnp.asarray(rng.standard_normal(
-        (2, (rows + 1) * RING_ROWS, Hk * hd)), jnp.bfloat16)
-        for _ in range(2))
+        (2, (rows + 1) * RING_ROWS, Hk * d)), jnp.bfloat16)
+        for d in (hd, vd))
+    sink = {} if vd == hd else {"sink": jnp.asarray(
+        rng.standard_normal((H,)), jnp.float32)}
     pt, base = ring_table(jnp.arange(rows, dtype=jnp.int32), kv_len, q_len,
                           WINDOW, RING_ROWS, PS, T)
     ref = ragged_attention_any(
         "jnp", q[checked], rk, rv, LAYER, pt, tok_seq[checked],
         tok_pos[checked], kv_len, q_start, q_len, PS, window=WINDOW,
-        pos_base=base)
+        pos_base=base, **sink)
 
     def fn(q, kc, vc, pt, qs, ql, kl, base):
         return ragged_attention.ragged_paged_attention_pallas(
             q, kc, vc, LAYER, pt, qs, ql, kl, PS, window=WINDOW,
-            pos_base=base)
+            pos_base=base, **sink)
 
     operands = (rk, rv, pt, q_start, q_len, kv_len, base)
-    row = {"shape": [H, Hk, hd], "traffic": "raggedlong_window", "tokens": T,
-           "context": context, "window": WINDOW}
+    row = {"shape": list(shape[:3 if vd == hd else 4]),
+           "traffic": "raggedlong_window", "tokens": T, "context": context,
+           "window": WINDOW, "sink": bool(sink)}
     try:
         out = np.asarray(fn(q, *operands)[checked], np.float32)
         row.update({

@@ -27,6 +27,33 @@ def _compile_cache_stays_where_it_was():
         compilation_cache.reset_cache()
 
 
+# Memory mappings a worker may hold when a test file ends before its jit
+# caches are dropped. Every program XLA compiles for the CPU maps its code:
+# a model's test file leaves 5-9 thousand mappings behind (`/proc/self/maps`;
+# tests/test_k_exaone.py 8,352, tests/test_mimo_v2_flash.py 9,353), a worker
+# under `--dist loadfile` runs ~14 files, and the kernel's
+# `vm.max_map_count` is 65,530: past it the next compile's `mmap` fails and
+# the worker dies inside `backend_compile_and_load` (a segmentation fault or
+# an abort in whatever test compiles next, and a run that can hang on the
+# replacement worker: PR 65's two whole runs, before this). `jax.clear_caches`
+# gives all but ~700 back; what a later file needs again it compiles again.
+MAPS_HIGH = 40_000
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _jit_code_stays_under_the_map_limit():
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:  # (no procfs: nothing to count, nothing to do)
+        return
+    if held > MAPS_HIGH:
+        import jax
+
+        jax.clear_caches()
+
+
 FILE_BUDGET_S = 400  # summed case time a file may hold (SKILL.md, Tier-1)
 
 

@@ -82,7 +82,7 @@ def test_the_registered_family_and_its_plan():
 @pytest.mark.parametrize("bad,match", [
     (dict(linear_num_value_heads=3), "is not a multiple of"),
     (dict(linear_num_key_heads=8), "is not a multiple of"),
-    (dict(partial_rotary_factor=0.3), "not an even number of lanes"),
+    (dict(partial_rotary_factor=0.35), "not an even number of lanes"),
     (dict(partial_rotary_factor=0.0), "not an even number of lanes"),
     (dict(decoder_sparse_step=2), "decoder_sparse_step 2"),
     (dict(mlp_only_layers=[1]), "mlp_only_layers"),

@@ -391,7 +391,8 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
                                    "test-tiny-falcon-h1",
                                    "test-tiny-phi4-flash",
                                    "test-tiny-minicpm-sala",
-                                   "test-tiny-kimi-linear"])
+                                   "test-tiny-kimi-linear",
+                                   "test-tiny-mimo-v2-flash"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
@@ -401,7 +402,8 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     decoder stack, of llama.HYBRID_SCOPES — the gather of the sampled rows
     in the ragged program only: a decode pass samples every row; with an attention
     output gate or a gated shared expert, of llama.GATE_SCOPES and
-    moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES) is in the debug text of
+    moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES; with attention kinds of
+    different head shapes, of llama.PER_KIND_SCOPES) is in the debug text of
     the engine's OWN ragged and decode programs, the modules are named
     after the stable jit functions, and README lists every one of these
     names in its span table."""
@@ -427,6 +429,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         + (llama.SSM_SCOPES if rt.cfg.count("attention_ssm") else ()) \
         + (llama.HYBRID_SCOPES if rt.cfg.mb_per_layer else ()) \
         + (llama.GATE_SCOPES if rt.cfg.attn_output_gate else ()) \
+        + (llama.PER_KIND_SCOPES if rt.cfg.per_kind_attention else ()) \
         + (moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES
            if rt.cfg.shared_expert_gate else ())
     if rt.cfg.kv_lora_rank:  # latent attention: its stages for the three
@@ -493,6 +496,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         | set(llama.BSA_SCOPES) | set(llama.LIGHTNING_SCOPES) \
         | set(llama.KDA_SCOPES) \
         | set(moe.SHARED_SCOPES) | set(llama.GATE_SCOPES) \
+        | set(llama.PER_KIND_SCOPES) \
         | set(moe.SHARED_GATE_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented
     # ... and the jit sites really are those functions: the two step

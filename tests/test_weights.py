@@ -511,3 +511,57 @@ def test_safetensors_kimi_linear_layout(tmp_path):
     for name in ("embed", "lm_head", "final_norm"):
         np.testing.assert_array_equal(np.asarray(got[name]),
                                       np.asarray(want[name]))
+
+
+def test_safetensors_mimo_v2_flash_layout(tmp_path):
+    """The served tree of the tiny MiMo-V2-Flash written under the published
+    `mimo_v2_flash` names (`self_attn.{q,k,v,o}_proj` in EVERY layer, of
+    another shape in a window layer than in a full one; a window layer's
+    `attention_sink_bias`; the selection bias beside the router inside `mlp`)
+    loads back to the same tree: a stack an attention kind, the sink and the
+    bias float32."""
+    import jax
+    import jax.numpy as jnp
+    from safetensors.numpy import save_file
+
+    from ollamamq_tpu.models import llama
+
+    cfg = MODEL_CONFIGS["test-tiny-mimo-v2-flash"]
+    want = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    lp = {k: np.asarray(v) for k, v in want["layers"].items()}
+    t = {"model.embed_tokens.weight": np.asarray(want["embed"]),
+         "model.norm.weight": np.asarray(want["final_norm"]),
+         "lm_head.weight": np.asarray(want["lm_head"])}
+    seen = {"full_attention": 0, "sliding_attention": 0}
+    for i, kind in enumerate(cfg.layer_types):
+        p, n = f"model.layers.{i}.", seen[kind]
+        seen[kind] += 1
+        pre = "swa_" if kind == "sliding_attention" else ""
+        t[p + "input_layernorm.weight"] = lp["attn_norm"][i]
+        t[p + "post_attention_layernorm.weight"] = lp["mlp_norm"][i]
+        for ours in ("wq", "wk", "wv", "wo"):
+            t[p + f"self_attn.{ours[1]}_proj.weight"] = lp[pre + ours][n].T
+        if pre:
+            t[p + "self_attn.attention_sink_bias"] = lp["swa_sink"][n]
+        if i < cfg.num_dense_layers:
+            for ours, theirs in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                                 ("w_down", "down_proj")):
+                t[p + f"mlp.{theirs}.weight"] = lp[ours][i].T
+            continue
+        e, m = i - cfg.num_dense_layers, p + "mlp."
+        t[m + "gate.weight"] = lp["w_router"][e].T
+        t[m + "gate.e_score_correction_bias"] = lp["router_bias"][e]
+        for x in range(cfg.num_experts):
+            for ours, theirs in (("we_gate", "gate_proj"),
+                                 ("we_up", "up_proj"),
+                                 ("we_down", "down_proj")):
+                t[m + f"experts.{x}.{theirs}.weight"] = lp[ours][e, x].T
+    save_file({k: np.ascontiguousarray(v, np.float32) for k, v in t.items()},
+              str(tmp_path / "model.safetensors"))
+    got = weights.load_safetensors(cfg, str(tmp_path), dtype=jnp.float32)
+    assert set(got["layers"]) == set(want["layers"])
+    assert got["layers"]["wk"].shape != got["layers"]["swa_wk"].shape[1:]
+    for name, w in want["layers"].items():
+        np.testing.assert_array_equal(np.asarray(got["layers"][name]),
+                                      np.asarray(w), err_msg=name)
+        assert got["layers"][name].dtype == w.dtype, name

@@ -184,10 +184,10 @@ def test_window_zero_traces_what_it_traced_before():
 
 # ------------------------------------------------------------ the ring
 def test_ring_rows_and_the_trash_slot():
-    ring = alloc_ring(3, S, ROWS, 256)
+    ring = alloc_ring(3, S, ROWS, (256, 256))
     assert ring.k.shape == ring.v.shape == (3, (S + 1) * ROWS, 256)
     assert ring.rows == ROWS and ring.nbytes == 2 * 3 * 5 * ROWS * 256 * 2
-    assert alloc_ring(0, S, ROWS, 256) is None
+    assert alloc_ring(0, S, ROWS, (256, 256)) is None
     slots = jnp.asarray([0, 0, 3, 1], jnp.int32)
     pos = jnp.asarray([5, ROWS + 5, 2 * ROWS - 1, -1], jnp.int32)
     at = ring_write_slots(slots, pos, pos >= 0, ROWS, S)
