@@ -31,26 +31,37 @@ class KernelCounts(NamedTuple):
     `(tokens, stream_len) -> count`: the ragged kernel's (tokens served a
     whole stretch at a time) and, of a latent model, the latent attention
     kernel's (tokens attended in the expanded form; rows a layer then takes
-    through the absorbed form's contractions)."""
+    through the absorbed form's contractions); and, of a model whose delta
+    rule's shape the window solve's kernel takes, that kernel's (the windows
+    it solves)."""
     tall_tokens: Callable
     wide_tokens: Optional[Callable] = None
     absorbed_rows: Optional[Callable] = None
+    solved_windows: Optional[Callable] = None
 
 
 def kernel_counts(cfg: ModelConfig, attn_impl: str) -> Optional[KernelCounts]:
     """`cfg`'s on the Pallas path; None without the kernels."""
     if attn_impl != "pallas":
         return None
+    from ollamamq_tpu.ops.pallas import chunk_rule, chunk_solve
     from ollamamq_tpu.ops.pallas.kv_contract import tall_tokens
+    counts = KernelCounts(tall_tokens)
+    rule = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+            cfg.linear_value_head_dim, False, cfg.kda)
+    if KINDS["lin"].present(cfg) and chunk_rule.blocks(*rule) \
+            and chunk_solve.blocks(*rule):  # as `gated_delta.ragged` asks
+        counts = counts._replace(solved_windows=chunk_solve.solved_windows)
     if not cfg.kv_lora_rank:
-        return KernelCounts(tall_tokens)
+        return counts
     from ollamamq_tpu.ops.pallas.mla_attention import (absorbed_rows,
                                                        wide_tokens)
-    return KernelCounts(tall_tokens, *(
-        functools.partial(fn, heads=cfg.num_heads, lanes=cfg.latent_lanes,
-                          rank=cfg.kv_lora_rank, nope=cfg.qk_nope_head_dim,
-                          v=cfg.v_head_dim)
-        for fn in (wide_tokens, absorbed_rows)))
+    return counts._replace(**{
+        fn.__name__: functools.partial(
+            fn, heads=cfg.num_heads, lanes=cfg.latent_lanes,
+            rank=cfg.kv_lora_rank, nope=cfg.qk_nope_head_dim,
+            v=cfg.v_head_dim)
+        for fn in (wide_tokens, absorbed_rows)})
 
 
 class Step(NamedTuple):
@@ -99,10 +110,16 @@ def slot_state_counts(cfg, page_size, s: Step) -> tuple:
 
 def rule_counts(cfg, page_size, s: Step) -> tuple:
     """`slot_state_counts`, and behind them the windows the delta rule's
-    chunked form SOLVED, a layer's worth: every window of the padded stream,
-    a span in it or not (`gated_delta._prepare` solves them all at once; a
-    scan has none)."""
-    windows = 0 if s.scan else -(-max(s.stream_len, sum(s.tokens)) // CHUNK)
+    chunked form SOLVED, a layer's worth: with the solve's kernel those a
+    span touches (`chunk_solve.solved_windows`), else every window of the
+    padded stream, a span in it or not (`gated_delta._prepare` solves them
+    all at once); a scan has none."""
+    if s.scan:
+        windows = 0
+    elif s.kernels is not None and s.kernels.solved_windows:
+        windows = s.kernels.solved_windows(s.tokens, s.stream_len)
+    else:
+        windows = -(-max(s.stream_len, sum(s.tokens)) // CHUNK)
     return slot_state_counts(cfg, page_size, s) + (windows,)
 
 

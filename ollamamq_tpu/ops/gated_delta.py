@@ -36,7 +36,11 @@ Schedules (as ops/shortconv.py's):
     whatever else is in the stream, a decode row costs none. On the chip
     the pairs are one Pallas kernel a layer that keeps a row's state in
     VMEM across its windows (ops/pallas/chunk_rule.py, at every shape its
-    `blocks` takes); the loop is its definition and the CPU's path.
+    `blocks` takes); the loop is its definition and the CPU's path. The
+    solve in front of it is one kernel a layer too, over the windows a span
+    touches alone (ops/pallas/chunk_solve.py, at every shape ITS `blocks`
+    takes: not `plain`, which has no solve); `_prepare` is its definition,
+    the CPU's path and `chunked`'s.
   - `decode`: one token a slot (the fused scan's body): active slots
     advance, parked slots keep their state.
 
@@ -447,11 +451,25 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
     # (a decay a key channel: the window solve under a scope of its own)
     vector = a_channel(g, beta)
     along_g = (slice(None),) + (None,) * (g.ndim - 1)  # `part` over g's axes
+    kernel = solver = None  # the Pallas kernels, at the shapes they take
+    if impl == "pallas":
+        from ollamamq_tpu.ops.pallas import chunk_rule, chunk_solve
+
+        shape = (h, q.shape[-1], dv, plain, vector)
+        kernel = chunk_rule.blocks(*shape) and chunk_rule
+        solver = kernel and chunk_solve.blocks(*shape) and chunk_solve
     with jax.named_scope("kda_prepare") if vector \
             else contextlib.nullcontext():
-        c = _prepare(cut(q), cut(k), cut(v),
-                     cut(jnp.where(part[along_g], g, 0.0)),
-                     cut(jnp.where(part[:, None], beta, 0.0)), same, plain)
+        def gated():  # v, and the gates of the tokens that take part
+            return (cut(v), cut(jnp.where(part[along_g], g, 0.0)),
+                    cut(jnp.where(part[:, None], beta, 0.0)))
+
+        if solver:  # ONE launch over the windows a span touches
+            c = solver.chunk_solve_pallas(
+                *_operands(cut(q), cut(k), h, plain), *gated(), row_of,
+                interpret=interpret)
+        else:
+            c = _prepare(cut(q), cut(k), *gated(), same, plain)
     # 3. The (row, window) pairs the spans touch, rows in order and each
     # row's windows in order: pair p is row `b`, its window `w`.
     first_w = q_start // CHUNK
@@ -480,12 +498,6 @@ def ragged(q, k, v, g, beta, state, layer, slot_ids, tok_seq, tok_pos,
             state, _from_heads(s)[None, None], (layer, slot_ids[b], 0, 0))
         return out, state
 
-    kernel = None  # the pairs' Pallas kernel, at the shapes it takes
-    if impl == "pallas":
-        from ollamamq_tpu.ops.pallas import chunk_rule
-
-        kernel = chunk_rule.blocks(h, q.shape[-1], dv, plain,
-                                   vector) and chunk_rule
     if kernel:
         # ONE launch over the pairs, a row's state in VMEM across its
         # windows (`pair` is its definition): each pair's row, window and

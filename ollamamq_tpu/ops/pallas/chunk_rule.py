@@ -204,17 +204,26 @@ def chunk_rule_pallas(state, layer, slots, windows, rows, flags, n_pairs,
     first pair, + OPENS where the row opens at zero; row_of [n, C] int32
     each window token's row (-1: of no span); c: `gated_delta._prepare`'s
     results, heads leading (no "w": the plain form; "gc" [n, H, C, dk]: a
-    decay a key channel). Returns (o [n, C, H * dv] float32 — right at the
+    decay a key channel) — or `chunk_solve_pallas`'s, which are laid out as
+    the kernel reads them. Returns (o [n, C, H * dv] float32 — right at the
     spans' tokens only —, state')."""
-    n, h, chunk, dv = c["u"].shape
-    dk = c["k"].shape[-1]
-    plain = "w" not in c
-    vector = c["gc"].ndim == 4
+    laid = "on_s" in c  # as the kernel reads them: chunk_solve_pallas's
+    if laid:
+        u, on_s, on_v = c["u"], c["on_s"], c["on_v"]
+        (n, chunk), (h, dk) = u.shape[:2], on_s.shape[1::2]
+        dv, plain, vector = u.shape[2] // h, False, c["gc"].shape[2] > 1
+    else:
+        n, h, chunk, dv = c["u"].shape
+        dk = c["k"].shape[-1]
+        plain = "w" not in c
+        vector = c["gc"].ndim == 4
+        u = jnp.moveaxis(c["u"], 1, 2).reshape(n, chunk, h * dv)
+        on_s = c["qg"] if plain else jnp.concatenate([c["w"], c["qg"]],
+                                                     axis=2)
+        on_v = jnp.concatenate([c["attn"], jnp.swapaxes(c["k"], -1, -2)],
+                               axis=2)
     hg, hb = blocks(h, dk, dv, plain, vector)
     nblk, lanes = h // hb, hb * dv
-    u = jnp.moveaxis(c["u"], 1, 2).reshape(n, chunk, h * dv)
-    on_s = c["qg"] if plain else jnp.concatenate([c["w"], c["qg"]], axis=2)
-    on_v = jnp.concatenate([c["attn"], jnp.swapaxes(c["k"], -1, -2)], axis=2)
 
     def lane_block(j, p, layer_ref, slot_ref, win_ref, *_):
         return (win_ref[p], 0, j)
@@ -254,6 +263,6 @@ def chunk_rule_pallas(state, layer, slots, windows, rows, flags, n_pairs,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       *(x.astype(jnp.int32) for x in (slots, windows, rows, flags)),
       n_pairs.reshape(1), state, u, on_s, on_v,
-      jnp.swapaxes(c["gc"], -1, -2) if vector else c["gc"][:, :, None, :],
-      row_of[:, None, :], row_of[:, :, None])
+      c["gc"] if laid else jnp.swapaxes(c["gc"], -1, -2) if vector
+      else c["gc"][:, :, None, :], row_of[:, None, :], row_of[:, :, None])
     return o, state

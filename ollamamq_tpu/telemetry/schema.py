@@ -314,10 +314,14 @@ LIN_CHUNK_PAIRS_TOTAL = REGISTRY.counter(
 LIN_PREPARE_WINDOWS_TOTAL = REGISTRY.counter(
     "ollamamq_lin_prepare_windows_total",
     "64-token windows of the stream the delta rule's chunked form solved in "
-    "launched ragged steps, a linear layer's worth: every window of the "
-    "padded stream, ceil(stream tokens / 64), whether a span lies in it or "
-    "not (ops/gated_delta._prepare solves them all at once; 0 for a fused "
-    "scan, which has no window)", labels=("model",))
+    "launched ragged steps, a linear layer's worth: on a TPU, where the "
+    "solve is one chunk_solve_pallas launch a layer, the windows that hold "
+    "a token of a span longer than one token (beside "
+    "ollamamq_lin_chunk_pairs_total: how often the kernel engages); on the "
+    "XLA path (the CPU, a shape the kernel does not take) every window of "
+    "the padded stream, ceil(stream tokens / 64), whether a span lies in it "
+    "or not (ops/gated_delta._prepare solves them all at once); 0 for a "
+    "fused scan, which has no window", labels=("model",))
 HBM_SSM_STATE_BYTES = REGISTRY.gauge(
     "ollamamq_hbm_ssm_state_bytes",
     "Bytes the state-space mixers' per-slot recurrent state occupies per "

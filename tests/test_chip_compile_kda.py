@@ -19,8 +19,10 @@ def test_the_vector_decay_kernels_compile_at_the_published_shape(v5e):
     """`gated_delta.ragged` of a 512-token stream on the Pallas path at (32,
     128, 128) with g [T, H, dk]: the one-token rows' kernel with the decay as
     columns, the (row, window) pairs ONE `chunk_rule_pallas` custom call —
-    a [dk, C] block of G a head, eight heads a block —, the window solve in
-    XLA with no `while`, and the carried state aliased: updated in place."""
+    a [dk, C] block of G a head, eight heads a block —, the window solve ONE
+    `chunk_solve_pallas` custom call in front of it (PR 66: no `while`, and
+    none of `_prepare_vector`'s fusions over [windows, H, 4, 16, 16]), and
+    the carried state aliased: updated in place."""
     from jax.sharding import SingleDeviceSharding
 
     from ollamamq_tpu.ops import gated_delta
@@ -43,13 +45,14 @@ def test_the_vector_decay_kernels_compile_at_the_published_shape(v5e):
             s(slots, **i32)).compile()
     text = compiled.as_text()
     assert "chunk_rule_pallas" in text and "gated_delta_step_pallas" in text
-    assert " while(" not in text
-    assert text.count("tpu_custom_call") == 2  # the rows', the pairs'
+    assert "chunk_solve_pallas" in text and " while(" not in text
+    assert text.count("tpu_custom_call") == 3  # the rows', solve, pairs
+    assert "f32[8,32,4,16,16]" not in text
     mem = compiled.memory_analysis()
     held = layers * (slots + 1) * dk * h * dv * 4
     assert mem.alias_size_in_bytes >= held, (mem, held)
-    # the solve's [windows, H, 4, 16, 16, dk] decay is fused into its two
-    # sums: nothing of that size (134 MB) is left among the temporaries
+    # the kernels' operands and results only (the XLA solve's [windows, H,
+    # 4, 16, 16, dk] decay was 134 MB unless fused into its sums)
     assert mem.temp_size_in_bytes < 100e6, mem
 
 
@@ -96,7 +99,8 @@ def test_kimi_linear_file_compiles_whole_and_carries_its_state_in_place(v5e):
         text = compiled.as_text()
         want = {"mla_dense_paged_attention_pallas",
                 "gated_delta_step_pallas"} | (
-                    {"chunk_rule_pallas"} if prog == "mq_ragged_step"
+                    {"chunk_rule_pallas", "chunk_solve_pallas"}
+                    if prog == "mq_ragged_step"
                     else set())
         assert all(k in text for k in want), prog
         found = shc.moves(text, 8 << 20)

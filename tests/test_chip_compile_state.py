@@ -147,7 +147,8 @@ def test_the_chunked_rules_pair_kernel_compiles_at_the_published_shapes(
     at each caller's heads and widths: the (row, window) pairs are ONE
     `chunk_rule_pallas` custom call — fp32 contract precision, a head's
     lanes no whole tile (Olmo-Hybrid), a 256-row state (Falcon-H1) and all —
-    beside the one-token rows' kernel, no `while` is left in the program,
+    beside the one-token rows' kernel and, where the rule has a solve, ONE
+    `chunk_solve_pallas` (PR 66), no `while` is left in the program,
     and the carried state comes back aliased: the kernel updates it in
     place."""
     from jax.sharding import SingleDeviceSharding
@@ -170,7 +171,9 @@ def test_the_chunked_rules_pair_kernel_compiles_at_the_published_shapes(
             s(slots, **i32), s(slots, **i32), s(slots, **i32)).compile()
     text = compiled.as_text()
     assert "chunk_rule_pallas" in text and " while(" not in text
-    assert text.count("tpu_custom_call") == 2  # the rows', the pairs'
+    # the rows', the pairs', and — not `plain` — the window solve's (PR 66)
+    assert ("chunk_solve_pallas" in text) == (not plain)
+    assert text.count("tpu_custom_call") == (2 if plain else 3)
     mem = compiled.memory_analysis()
     held = layers * (slots + 1) * dk * h * dv * 4
     assert mem.alias_size_in_bytes >= held, (mem, held)
