@@ -403,12 +403,19 @@ struct Tui {
     if (sp && sp->type == mj::Value::OBJ) {
       double comp =
           sp->get("compiles") ? sp->get("compiles")->as_num() : 0;
+      /* ...of them, the first calls whose every program came out of
+       * the persistent compilation cache, and those that compiled one. */
+      double hit = sp->get("hit") ? sp->get("hit")->as_num() : 0;
+      double miss = sp->get("miss") ? sp->get("miss")->as_num() : 0;
       auto sp99 = sp->get("p99_ms");
       if (sp99 && sp99->type == mj::Value::NUM)
-        std::snprintf(l, sizeof l, " compiles %.0f · step p99 %.2fms",
-                      comp, sp99->as_num());
+        std::snprintf(l, sizeof l,
+                      " compiles %.0f (%.0f hit / %.0f miss) · step p99 "
+                      "%.2fms", comp, hit, miss, sp99->as_num());
       else
-        std::snprintf(l, sizeof l, " compiles %.0f · step p99 n/a", comp);
+        std::snprintf(l, sizeof l,
+                      " compiles %.0f (%.0f hit / %.0f miss) · step p99 n/a",
+                      comp, hit, miss);
       out.push_back(std::string(CYAN) + l + RST);
     }
     /* Fleet replicas chip (only under a fleet router): N healthy / M

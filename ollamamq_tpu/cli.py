@@ -537,6 +537,12 @@ def install_graceful_shutdown(engine, grace_s: float) -> None:
 
 
 def main(argv=None) -> int:
+    # The start-up ledger (telemetry/stepprof.py START_PHASES): `import`
+    # ends here; `backend` is all of this function that no later phase
+    # names, up to `serve` below.
+    from ollamamq_tpu.telemetry import stepprof
+
+    stepprof.PROFILER.startup_enter("backend")
     args = build_parser().parse_args(argv)
     use_tui = not args.no_tui and sys.stdout.isatty()
     setup_logging(use_tui, log_file=args.log_file,
@@ -963,6 +969,9 @@ def main(argv=None) -> int:
 
         engine = TPUEngine(ecfg, models=models, blocklist_path=args.blocklist,
                            fairness=fairness)
+    # Every runtime is built: `serve` lasts until the HTTP server's
+    # start-up hook (server/app.py) calls the process ready.
+    stepprof.PROFILER.startup_enter("serve")
     if standby is not None:
         # The standby's router stays UNSTARTED until promotion — no
         # member probes, no placements, just the replication tail.

@@ -71,6 +71,15 @@ log = logging.getLogger("ollamamq.engine")
 # The step profiler stays stdlib-only; this module imports jax, so it
 # hands over the span type the profiler opens while a capture runs.
 stepprof.PROFILER.span_factory = jax.profiler.TraceAnnotation
+# ...and what jax says of its own compiles, on the thread that compiles
+# (stepprof.JAX_SPANS begin with a scalar and end with a duration; the
+# persistent cache's hit or miss is an event): once a process.
+jax.monitoring.register_scalar_listener(
+    lambda event, value, **kw: stepprof.PROFILER.jax_begin(event))
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: stepprof.PROFILER.jax_event(event, secs))
+jax.monitoring.register_event_listener(
+    lambda event, **kw: stepprof.PROFILER.jax_event(event))
 _ENGINE_IDS = itertools.count(1)
 
 
@@ -278,31 +287,36 @@ def _sp_compile_evict(rt, cache, key_) -> bool:
 
 
 def _sp_note_compile(rt, site: str, key_, cache, fn):
-    """Wrap a freshly cached jit so its FIRST call — the one jax traces
-    and XLA-compiles synchronously — is timed and recorded exactly once
-    per cache key: journal `compile` record, ollamamq_compile_total/
+    """Wrap a freshly cached jit so its FIRST call — the one jax traces,
+    lowers, compiles (or fetches from the persistent cache) and runs
+    synchronously — is timed and recorded exactly once per cache key,
+    under an account that says what of its wall was which (stepprof.
+    Account): journal `compile` record, ollamamq_compile_total/
     _compile_ms, the stepprof compile ledger, and the in-flight step's
     `compiled` flag. The wrapper then replaces itself with the raw jit,
     so steady state pays nothing."""
     def first_call(*a, **kw):
-        t0 = time.monotonic()
+        t0, began = time.monotonic(), time.time()
         # Read by TPUEngine.compiling(); cleared with the `compiled` flag
         # when the step that paid this compile takes its sample — the
         # first execution of a fresh program is slow too.
         rt.compiling_since = t0
         try:
-            out = fn(*a, **kw)
+            with stepprof.PROFILER.account() as acct:
+                out = fn(*a, **kw)
         except BaseException:
             rt.compiling_since = None
             raise
         wall_ms = (time.monotonic() - t0) * 1e3
         cache[key_] = fn
         rt._stepprof_compiled = True
-        stepprof.PROFILER.record_compile(site, key_, wall_ms, len(cache))
+        ev = stepprof.PROFILER.record_compile(site, key_, wall_ms, acct,
+                                              t0=began)
         j = getattr(rt, "journal", None)
         if j is not None:
-            j.record("compile", model=rt.name, site=site, key=str(key_),
-                     wall_ms=round(wall_ms, 3), cache_size=len(cache))
+            j.record("compile", model=rt.name, **{
+                k: ev[k] for k in ("site", "key", "wall_ms")
+                + stepprof.COMPILE_SPLIT})
         return out
 
     cache[key_] = first_call
@@ -562,40 +576,46 @@ class ModelRuntime:
         # `preloaded_params`: host-side tree shared across dp replicas so a
         # checkpoint is read/parsed once, not once per replica; each replica
         # still device_puts its own copy via shard_params below.
+        # (The start-up ledger's `weights` and `place`, stepprof.
+        # START_PHASES; the rest of this constructor is its caller's
+        # `alloc`, build_model_runtimes.)
         tp_axis = mesh.shape.get("tensor", 1) if mesh is not None else 1
-        params = preloaded_params if preloaded_params is not None else (
-            weights.load_params(
-                model_cfg, checkpoint_path, seed=engine_cfg.seed, dtype=dtype,
-                weights_dtype=engine_cfg.weights_dtype,
-                # Random weights are drawn shard by shard on the mesh
-                # (the replicated-group rewrite below needs them whole).
-                mesh=mesh if tp_axis <= model_cfg.num_kv_heads else None,
+        with stepprof.PROFILER.phase("weights", name):
+            params = preloaded_params if preloaded_params is not None else (
+                weights.load_params(
+                    model_cfg, checkpoint_path, seed=engine_cfg.seed,
+                    dtype=dtype, weights_dtype=engine_cfg.weights_dtype,
+                    # Random weights are drawn shard by shard on the mesh
+                    # (the replicated-group rewrite below needs them whole).
+                    mesh=mesh if tp_axis <= model_cfg.num_kv_heads else None,
+                )
             )
-        )
-        if tp_axis > model_cfg.num_kv_heads:
-            # Replicated-group KV sharding (e.g. qwen2.5's 4 KV heads on
-            # tp=8): duplicate each KV head so every shard owns one copy.
-            # validate_tp_for_model already guaranteed divisibility.
-            r = tp_axis // model_cfg.num_kv_heads
-            params = weights.replicate_kv_heads(params, model_cfg, r)
-            import dataclasses as _dc
-
-            model_cfg = _dc.replace(model_cfg, num_kv_heads=tp_axis)
-            self.cfg = model_cfg
-            log.info("replicated KV heads x%d for tp=%d (%s)", r, tp_axis,
-                     name)
         kv_sharding = None
-        if mesh is not None:
-            from jax.sharding import NamedSharding
+        with stepprof.PROFILER.phase("place", name):
+            if tp_axis > model_cfg.num_kv_heads:
+                # Replicated-group KV sharding (e.g. qwen2.5's 4 KV heads
+                # on tp=8): duplicate each KV head so every shard owns one
+                # copy. validate_tp_for_model already guaranteed
+                # divisibility.
+                r = tp_axis // model_cfg.num_kv_heads
+                params = weights.replicate_kv_heads(params, model_cfg, r)
+                import dataclasses as _dc
 
-            params = shard_params(params, mesh)
-            kv_sharding = NamedSharding(mesh, kv_cache_spec())
-        # The stacks whose contraction reads another order than row-major
-        # live on the device in that order (models/llama.py:
-        # weight_formats). Every jit site is handed `self.params`, and a
-        # jit with no `in_shardings` compiles for the layout of the
-        # committed array it is given: no step program re-lays a stack.
-        weights.place_formats(model_cfg, params)
+                model_cfg = _dc.replace(model_cfg, num_kv_heads=tp_axis)
+                self.cfg = model_cfg
+                log.info("replicated KV heads x%d for tp=%d (%s)", r,
+                         tp_axis, name)
+            if mesh is not None:
+                from jax.sharding import NamedSharding
+
+                params = shard_params(params, mesh)
+                kv_sharding = NamedSharding(mesh, kv_cache_spec())
+            # The stacks whose contraction reads another order than
+            # row-major live on the device in that order (models/llama.py:
+            # weight_formats). Every jit site is handed `self.params`, and
+            # a jit with no `in_shardings` compiles for the layout of the
+            # committed array it is given: no step program re-lays a stack.
+            weights.place_formats(model_cfg, params)
         self.params = params
         self.kc, self.vc = kvc.alloc_kv_pool(
             model_cfg, engine_cfg, kv_sharding, dtype,
@@ -2971,11 +2991,13 @@ class EncoderRuntime:
         self.mesh = mesh
         self._failed = False
         self.tokenizer = load_tokenizer(checkpoint_path)
-        params = weights.load_params(model_cfg, checkpoint_path,
-                                     seed=engine_cfg.seed, dtype=dtype,
-                                     weights_dtype=engine_cfg.weights_dtype)
+        with stepprof.PROFILER.phase("weights", name):
+            params = weights.load_params(
+                model_cfg, checkpoint_path, seed=engine_cfg.seed, dtype=dtype,
+                weights_dtype=engine_cfg.weights_dtype)
         if mesh is not None:
-            params = shard_params(params, mesh)
+            with stepprof.PROFILER.phase("place", name):
+                params = shard_params(params, mesh)
         self.params = params
         self.pending: collections.deque = collections.deque()
         self._block_ver = -1  # force one startup sweep (disk-loaded blocklist)
@@ -3076,24 +3098,28 @@ def build_model_runtimes(name, cfg, engine_cfg, mesh, dtype, checkpoint_path,
     concurrently — the reference's "one request per backend, N backends"
     scale-out story with backends = mesh slices. The checkpoint is
     read/parsed once and shared host-side across replicas."""
-    if cfg.is_encoder:
-        return [encoder_cls(name, cfg, engine_cfg, mesh=mesh,
-                            checkpoint_path=checkpoint_path, dtype=dtype)]
-    if engine_cfg.dp > 1 and mesh is not None:
-        host_params = weights.load_params(
-            cfg, checkpoint_path, seed=engine_cfg.seed, dtype=dtype,
-            weights_dtype=engine_cfg.weights_dtype,
-        )
-        reps = [
-            model_cls(name, cfg, engine_cfg, mesh=replica_submesh(mesh, r),
-                      checkpoint_path=checkpoint_path, dtype=dtype,
-                      preloaded_params=host_params)
-            for r in range(engine_cfg.dp)
-        ]
-        del host_params  # replicas hold their own device copies
-        return reps
-    return [model_cls(name, cfg, engine_cfg, mesh=mesh,
-                      checkpoint_path=checkpoint_path, dtype=dtype)]
+    with stepprof.PROFILER.phase("alloc", name):  # the start-up ledger's:
+        # all of it but what the constructors name `weights` and `place`
+        if cfg.is_encoder:
+            return [encoder_cls(name, cfg, engine_cfg, mesh=mesh,
+                                checkpoint_path=checkpoint_path, dtype=dtype)]
+        if engine_cfg.dp > 1 and mesh is not None:
+            with stepprof.PROFILER.phase("weights", name):
+                host_params = weights.load_params(
+                    cfg, checkpoint_path, seed=engine_cfg.seed, dtype=dtype,
+                    weights_dtype=engine_cfg.weights_dtype,
+                )
+            reps = [
+                model_cls(name, cfg, engine_cfg,
+                          mesh=replica_submesh(mesh, r),
+                          checkpoint_path=checkpoint_path, dtype=dtype,
+                          preloaded_params=host_params)
+                for r in range(engine_cfg.dp)
+            ]
+            del host_params  # replicas hold their own device copies
+            return reps
+        return [model_cls(name, cfg, engine_cfg, mesh=mesh,
+                          checkpoint_path=checkpoint_path, dtype=dtype)]
 
 
 def merge_prefix_cache_stats(stats_list) -> Optional[dict]:
@@ -4672,7 +4698,10 @@ class TPUEngine:
             "rebuilds": self.rebuilds,
             # Scheduling policy + output-length predictor accuracy.
             "scheduler": self.scheduler_stats(),
-            # Engine performance plane: compile count + rolling step p99
-            # (the TUI `compiles N · step p99` chip's source).
+            # Engine performance plane: compile count (and how many came
+            # out of the persistent cache) + rolling step p99 (the TUI
+            # `compiles N (h hit / m miss) · step p99` chip's source), and
+            # the start-up ledger: ready by phase.
             "stepprof": stepprof.PROFILER.brief(),
+            "startup": stepprof.PROFILER.startup_snapshot(),
         }

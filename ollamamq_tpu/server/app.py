@@ -130,6 +130,20 @@ async def _cpu_register_server(app) -> None:
     stepprof.PROFILER.cpu_register("server")
 
 
+async def _startup_ready(app) -> None:
+    """The listening socket opens right behind the app's start-up hooks:
+    the start-up ledger's `serve` ends and `ready_s` is fixed (a no-op
+    without cli.main's chain, and the second time)."""
+    prof = stepprof.PROFILER
+    prof.startup_ready()
+    su = prof.startup_snapshot()
+    if su["ready_s"] is not None:
+        log.info("ready in %.1f s: %s; programs %s", su["ready_s"], ", ".join(
+            f"{r['phase']}{' ' + r['model'] if r['model'] else ''} "
+            f"{r['wall_s']:.1f}" for r in su["phases"] if r["in_ready"]),
+            su["programs"])
+
+
 async def _cpu_unregister_server(app) -> None:
     stepprof.PROFILER.cpu_unregister("server")
 
@@ -172,6 +186,7 @@ class Server:
         # thread of ollamamq_thread_cpu_seconds_total: it says so itself,
         # on the loop, as it starts and as it ends.
         app.on_startup.append(_cpu_register_server)
+        app.on_startup.append(_startup_ready)
         app.on_cleanup.append(_cpu_unregister_server)
         r = app.router
         r.add_route("GET", "/health", self.health)
@@ -1273,9 +1288,11 @@ class Server:
         """Engine performance plane: the always-on step profiler's
         bounded ring (telemetry/stepprof.py) — per-mode/per-phase
         p50/p99, the per-shape (mode, T_pad, k_cap) latency table, the
-        compile-event ledger, and the profiler's own overhead meter.
-        `?n=` bounds the recent-samples/compile-events tails
-        (default 128)."""
+        compile-event ledger (each first call split into trace / lower /
+        backend / first run, with the persistent cache's hit or miss),
+        the start-up ledger (`startup`: ready by phase) and the
+        profiler's own overhead meter. `?n=` bounds the
+        recent-samples/compile-events tails (default 128)."""
         self._ident(request)
         try:
             n = int(request.query.get("n", "128"))

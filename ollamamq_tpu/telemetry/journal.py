@@ -38,6 +38,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ollamamq_tpu.telemetry import schema as tm
+from ollamamq_tpu.telemetry.stepprof import COMPILE_SPLIT
 
 # Closed event vocabulary, lifecycle order. The README "Flight recorder"
 # table (between <!-- journal-events:begin/end --> markers) documents
@@ -244,9 +245,11 @@ EVENT_FIELDS: Dict[str, Tuple[tuple, tuple]] = {
                          "members_claimed")),
     "epoch_fence": (("epoch", "stale_epoch"), ("path", "caller")),
     # Compile events carry the shape key that missed, the wall ms the
-    # first call stalled compiling, and the cache size after the fill —
+    # first call held the dispatch path, and what that wall was
+    # (stepprof.COMPILE_SPLIT: its start, tracing / lowering / backend /
+    # first run, the programs and the persistent cache's word on them) —
     # enough to reconstruct the whole ladder from a journal tail.
-    "compile": (("site", "key", "wall_ms"), ("cache_size",)),
+    "compile": (("site", "key", "wall_ms"), COMPILE_SPLIT),
 }
 assert set(EVENT_FIELDS) == set(EVENTS)
 

@@ -759,11 +759,32 @@ COMPILE_TOTAL = REGISTRY.counter(
     "alert)", labels=("site",))
 COMPILE_MS = REGISTRY.histogram(
     "ollamamq_compile_ms",
-    "Wall milliseconds one XLA compile held the dispatch path (the "
-    "first call of a fresh jit cache entry traces + compiles "
-    "synchronously; that call's wall IS the compile cost the step paid)",
+    "Wall milliseconds a step program's FIRST CALL held the dispatch "
+    "path: tracing, lowering, the backend (XLA's and Mosaic's compile, "
+    "or the persistent cache's retrieval) and the program's first run, "
+    "all synchronous — the cost the step paid. The compile event splits "
+    "it (`trace_ms` / `lower_ms` / `backend_ms` / `first_run_ms`)",
     buckets=(1, 5, 10, 25, 50, 100, 250, 500, 1000, 2500, 5000, 10000,
              30000, 60000, 120000))
+COMPILE_PROGRAMS_TOTAL = REGISTRY.counter(
+    "ollamamq_compile_programs_total",
+    "Programs jax's backend built or fetched since process start — the "
+    "step programs' first calls, the eager programs of start-up and "
+    "whatever else compiled alike — by what the persistent compilation "
+    "cache said: cache=\"hit\" fetched, cache=\"miss\" compiled and "
+    "written, cache=\"off\" jax reported neither (no cache directory, or "
+    "a program under the cache's thresholds). hit over hit + miss says "
+    "whether a start was warm", labels=("cache",))
+STARTUP_SECONDS = REGISTRY.gauge(
+    "ollamamq_startup_seconds",
+    "Seconds of start-up by phase (stepprof.START_PHASES: import / "
+    "backend / weights / place / alloc / serve), set once when the HTTP "
+    "server starts to listen; the phases are contiguous on the main "
+    "thread and sum to ollamamq_ready_seconds", labels=("phase",))
+READY_SECONDS = REGISTRY.gauge(
+    "ollamamq_ready_seconds",
+    "Seconds from the kernel's process start to the HTTP server "
+    "starting to listen, set once; absent until then")
 
 # -- host / device ---------------------------------------------------------
 HBM_USED_BYTES = REGISTRY.gauge(

@@ -66,6 +66,20 @@ Contracts the tests pin:
     wall); a recompile loop (ladder bug, pallas-probe thrash, injected
     `compile` fault) shows up as a climbing `rate_per_min` and trips
     the health monitor's `compile_storm` alert after warmup.
+  * What jax did in that first call is on the event too: the engine
+    module registers `jax.monitoring` listeners that hand jax's own
+    compile events to `jax_begin` / `jax_event`, and they land on the
+    innermost ACCOUNT open on the thread that fired them (`account()`),
+    else on the process-wide `other`. A span's time is its SELF time —
+    a jit traced inside a trace, an eager op compiled while lowering,
+    is taken out of what encloses it — so an account's `trace_ms +
+    lower_ms + backend_ms` never passes the wall it was open for.
+  * Between process start and ready the main thread is in exactly one
+    of the START_PHASES (`startup_begin` / `phase()` /
+    `startup_enter` / `startup_ready`): their walls sum to `ready_s`
+    as a request's phases sum to its latency. A phase opened on
+    another thread, or after ready, is a row of its own and leaves
+    `ready_s` alone.
 
 Module-global `PROFILER` (same pattern as metrics.REGISTRY): the
 engine and FakeRuntime feed it; the server and TUI read it;
@@ -74,6 +88,8 @@ tests call `PROFILER.reset()` for isolation.
 
 from __future__ import annotations
 
+import contextlib
+import os
 import threading
 import time
 from collections import deque
@@ -153,11 +169,52 @@ DRY_PHASES = tuple(p for p in _SAMPLE_PHASES if p != "loop_wait")
 # but the set the engine emits today, for readers.
 MODES = ("ragged", "spec_verify", "decode", "embed", "fake")
 
+# CLOSED vocabulary of what the process does between its start and
+# ready, in order; pinned to the README start-up table (gate 8). The
+# `phase` label values of ollamamq_startup_seconds.
+START_PHASES = (
+    "import",   # the kernel's process start -> cli.main entered: the
+    #             interpreter and every import the entry point makes
+    "backend",  # everything from there that no later phase names: the
+    #             arguments, the compile cache's place, jax's first
+    #             device touch, the engine's own construction
+    "weights",  # weights.load_params: the seeded draw, or the read
+    "place",    # shard_params, replicate_kv_heads, weights.place_formats
+    "alloc",    # the pool, the rings, the slot state, `recent` /
+    #             `last_ids`, the allocator, the prefix cache — and the
+    #             rest of the runtime's constructor
+    "serve",    # runtimes built -> the HTTP server starts to listen
+)
+
+# jax.monitoring's names for what a first call is made of (jax 0.9): the
+# three spans (begun with a scalar of the same name, ended with a
+# duration) and the persistent cache's two outcomes. The retrieval time
+# jax reports on a hit lies INSIDE that program's backend span.
+JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_ms",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_ms",
+    "/jax/core/compile/backend_compile_duration": "backend_ms",
+}
+JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# cache= of ollamamq_compile_programs_total, and `cache` on a compile
+# event: every program fetched / one compiled / jax reported neither (no
+# cache directory, or a program under the cache's thresholds).
+CACHE_OUTCOMES = ("hit", "miss", "off")
+# What a compile event (and the journal's `compile` record) says beside
+# site, key and wall_ms: the call's start, the split of its wall, the
+# programs the backend was asked for and the cache's word on them.
+COMPILE_SPLIT = ("t0", "trace_ms", "lower_ms", "backend_ms", "first_run_ms",
+                 "programs", "cache")
+
 _RING = 2048          # sample ring (like --journal-ring's default)
 _SHAPE_KEYS = 64      # distinct (mode, T_pad, k_cap) keys kept
 _SHAPE_WINDOW = 256   # rolling per-shape totals window
 _COMPILE_RING = 256   # compile-event ring
 _HBM_RING = 512       # HBM/allocator timeline ring
+_START_RING = 64      # start-up rows of phases opened after ready
 _RATE_WINDOW_S = 60.0  # compile-rate lookback
 # thread= of ollamamq_thread_cpu_seconds_total (StepProfiler.cpu_register)
 CPU_THREADS = ("engine", "server")
@@ -169,6 +226,69 @@ def _pctl(window, q: float) -> Optional[float]:
         return None
     s = sorted(window)
     return s[min(len(s) - 1, int(q * len(s)))]
+
+
+def _process_start() -> float:
+    """The epoch second the kernel started this process (its start time
+    in /proc, which counts clock ticks since boot, against the boot
+    clock now: 10 ms fine); where the platform has neither, now — the
+    import of this module."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        up_s = time.clock_gettime(time.CLOCK_BOOTTIME)
+        return time.time() - (up_s - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return time.time()
+
+
+class Account:
+    """What jax did while this account was the innermost open on its
+    thread: self milliseconds of tracing, of lowering and of the backend
+    (the compile on a miss, the retrieval on a hit), the programs the
+    backend built or fetched, and what the persistent cache said."""
+
+    __slots__ = ("trace_ms", "lower_ms", "backend_ms", "programs",
+                 "cache_hits", "cache_misses", "closed")
+
+    def __init__(self):
+        self.trace_ms = self.lower_ms = self.backend_ms = 0.0
+        self.programs = self.cache_hits = self.cache_misses = 0
+        self.closed = False  # a start-up phase's, once its chain ended
+
+    def cache(self) -> str:
+        """CACHE_OUTCOMES: "hit" where every program came from the
+        persistent cache, "miss" where one was compiled, "off" where
+        jax reported neither."""
+        if not (self.cache_hits or self.cache_misses):
+            return "off"
+        return "hit" if (not self.cache_misses
+                         and self.cache_hits >= self.programs) else "miss"
+
+    def as_dict(self) -> dict:
+        return {"trace_ms": round(self.trace_ms, 3),
+                "lower_ms": round(self.lower_ms, 3),
+                "backend_ms": round(self.backend_ms, 3),
+                "programs": self.programs, "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses}
+
+
+class _PhaseRow:
+    """One (phase, model) of the start-up ledger: when it first opened,
+    the seconds it was open for, and its account."""
+
+    __slots__ = ("phase", "model", "t0", "wall_s", "in_ready", "acct")
+
+    def __init__(self, phase: str, model: str, t0: float, in_ready: bool):
+        self.phase, self.model, self.t0 = phase, model, t0
+        self.wall_s = 0.0
+        self.in_ready = in_ready  # a link of the chain that sums to ready_s
+        self.acct = Account()
+
+    def as_dict(self) -> dict:
+        return {"phase": self.phase, "model": self.model,
+                "t0": round(self.t0, 6), "wall_s": round(self.wall_s, 6),
+                "in_ready": self.in_ready, **self.acct.as_dict()}
 
 
 class DoneBracket:
@@ -626,6 +746,14 @@ class StepProfiler:
         # Not a sample's business: reset() leaves them alone.
         self._cpu_clocks: Dict[str, Dict[int, int]] = {}
         self._cpu_ended: Dict[str, float] = {}
+        # The start-up ledger's clock: the epoch clock at construction,
+        # advanced by the monotonic one (a stepped wall clock moves no
+        # phase). `process_start` is the kernel's, on the same clock.
+        self.process_start = _process_start()
+        self._epoch0 = time.time() - time.monotonic()
+        # Per thread: the open accounts (innermost last), jax's spans
+        # begun and not ended, the cache's word on the program in hand.
+        self._tls = threading.local()
         self._reset_locked()
 
     def _reset_locked(self) -> None:
@@ -647,6 +775,22 @@ class StepProfiler:
         self.compile_seq = 0
         self._compile_ts: deque = deque(maxlen=_COMPILE_RING)
         self.hbm: deque = deque(maxlen=_HBM_RING)
+        # Ledger events by `cache`, every program by it (the counter's
+        # face), and what jax did under no account.
+        self._compile_cache = dict.fromkeys(CACHE_OUTCOMES, 0)
+        self._programs = dict.fromkeys(CACHE_OUTCOMES, 0)
+        for row in getattr(self, "_su_rows", ()):
+            row.acct.closed = True
+        self.other = Account()
+        # The start-up chain: its thread (None: not begun, or ended), the
+        # instant its open phase began, the open rows (innermost last),
+        # every row of it; then the rows of phases opened outside it.
+        self._su_thread: Optional[int] = None
+        self._su_last = 0.0
+        self._su_stack: List[_PhaseRow] = []
+        self._su_rows: List[_PhaseRow] = []
+        self._su_later: deque = deque(maxlen=_START_RING)
+        self.ready_at: Optional[float] = None
 
     def reset(self) -> None:
         with self._lock:
@@ -732,24 +876,228 @@ class StepProfiler:
 
     # -- compile ledger ----------------------------------------------------
     def record_compile(self, site: str, key, wall_ms: float,
-                       cache_size: int) -> dict:
-        t0 = time.perf_counter_ns()
+                       account: Optional[Account] = None,
+                       t0: Optional[float] = None) -> dict:
+        """A step program's first call: `wall_ms` from its start (`t0`,
+        epoch) to its return, and the `account` that was open around it —
+        what of the wall was tracing, lowering and the backend; the rest
+        is the program's first run."""
+        n0 = time.perf_counter_ns()
+        a = account if account is not None else Account()
+        ts = time.time()
         ev = {
-            "ts": time.time(),
+            "ts": ts,
+            "t0": t0 if t0 is not None else ts - wall_ms / 1e3,
             "site": site,
             "key": str(key),
             "wall_ms": round(wall_ms, 3),
-            "cache_size": cache_size,
+            "first_run_ms": round(max(
+                0.0, wall_ms - a.trace_ms - a.lower_ms - a.backend_ms), 3),
+            "cache": a.cache(),
         }
+        sums = a.as_dict()
+        ev.update((k, sums[k]) for k in COMPILE_SPLIT if k in sums)
         with self._lock:
             self.compile_seq += 1
             ev["seq"] = self.compile_seq
             self.compiles.append(ev)
             self._compile_ts.append(time.monotonic())
+            self._compile_cache[ev["cache"]] += 1
         tm.COMPILE_TOTAL.labels(site=site).inc()
         tm.COMPILE_MS.observe(wall_ms)
-        self._overhead_ns += time.perf_counter_ns() - t0
+        self._overhead_ns += time.perf_counter_ns() - n0
         return ev
+
+    # -- accounts: what jax did, by who asked ------------------------------
+    def _thread(self):
+        """The calling thread's state: `accounts` (open, innermost last),
+        `spans` (jax's, begun and not ended: [event, seconds nested in
+        it]) and `outcome` (the cache's word on the program in hand)."""
+        tls = self._tls
+        if not hasattr(tls, "accounts"):
+            tls.accounts, tls.spans, tls.outcome = [], [], None
+        return tls
+
+    @contextlib.contextmanager
+    def account(self):
+        """Open an account on the calling thread until the block ends:
+        jax's events on this thread land on it, unless a block inside
+        opens another."""
+        acct, stack = Account(), self._thread().accounts
+        stack.append(acct)
+        try:
+            yield acct
+        finally:
+            stack.remove(acct)
+
+    def jax_begin(self, event: str) -> None:
+        """jax began one of JAX_SPANS on the calling thread (its scalar
+        listener's call): whatever ends before it does is nested in it."""
+        if event in JAX_SPANS:
+            spans = self._thread().spans
+            if len(spans) >= 64:  # begun and never ended: start over
+                del spans[:]
+            spans.append([event, 0.0])
+
+    def jax_event(self, event: str, seconds: Optional[float] = None) -> None:
+        """One of jax's compile events on the calling thread (its duration
+        and event listeners' call; others are ignored). A span is charged
+        its SELF time — its seconds minus those of the spans that began
+        and ended inside it — to the innermost open account of the
+        thread, else to `other`; a backend span is one program, labelled
+        by the cache's event that came inside it."""
+        field = JAX_SPANS.get(event)
+        outcome = JAX_CACHE_EVENTS.get(event)
+        if field is None and outcome is None:
+            return
+        n0 = time.perf_counter_ns()
+        tls = self._thread()
+        stack = tls.accounts
+        while stack and stack[-1].closed:
+            stack.pop()
+        with self._lock:  # (`other` is every thread's; events are rare)
+            a = stack[-1] if stack else self.other
+            if outcome == "hit":
+                tls.outcome, a.cache_hits = outcome, a.cache_hits + 1
+            elif outcome == "miss":
+                tls.outcome, a.cache_misses = outcome, a.cache_misses + 1
+            else:
+                spans, inner = tls.spans, 0.0
+                if any(sp[0] == event for sp in spans):
+                    while spans[-1][0] != event:  # begun, never ended
+                        spans.pop()
+                    inner = spans.pop()[1]
+                if spans:
+                    spans[-1][1] += seconds
+                ms = max(0.0, seconds - inner) * 1e3
+                setattr(a, field, getattr(a, field) + ms)
+                if field == "backend_ms":
+                    a.programs += 1
+                    label, tls.outcome = tls.outcome or "off", None
+                    self._programs[label] += 1
+                    tm.COMPILE_PROGRAMS_TOTAL.labels(cache=label).inc()
+        self._overhead_ns += time.perf_counter_ns() - n0
+
+    # -- start-up ledger ---------------------------------------------------
+    def _now(self) -> float:
+        return self._epoch0 + time.monotonic()
+
+    def _su_switch(self, now: float, phase: Optional[str], model: str = "",
+                   base: bool = False) -> None:
+        """(the chain's thread, lock held) Close the open phase's slice
+        at `now`; then open `phase` of `model` above it (`base`: in its
+        place, and of everything open), or with `phase` None go back to
+        the one below. The thread's innermost account is the open row's."""
+        stack, accounts = self._su_stack, self._thread().accounts
+        if stack:
+            stack[-1].wall_s += now - self._su_last
+            accounts.remove(stack[-1].acct)
+        self._su_last = now
+        if phase is None:
+            if len(stack) > 1:  # (the base stays: a block that outlived it)
+                stack.pop()
+        else:
+            if base:
+                del stack[:]
+            row = next((r for r in self._su_rows
+                        if (r.phase, r.model) == (phase, model)), None)
+            if row is None:
+                row = _PhaseRow(phase, model, now, True)
+                self._su_rows.append(row)
+            stack.append(row)
+        accounts.append(stack[-1].acct)
+
+    def startup_enter(self, phase: str) -> None:
+        """The main thread is in `phase` from now on, whatever was open.
+        The FIRST call — cli.main's first statement — begins the chain on
+        the calling thread: `import` ran from the kernel's process start
+        to now."""
+        if phase not in START_PHASES:
+            raise ValueError(f"unknown start-up phase {phase!r}")
+        now = self._now()
+        with self._lock:
+            if self._su_thread is None:
+                if self._su_rows or self.ready_at is not None:
+                    return  # the chain has ended
+                self._su_thread = threading.get_ident()
+                first = _PhaseRow("import", "", self.process_start, True)
+                first.wall_s = now - self.process_start
+                self._su_rows.append(first)
+            elif self._su_thread != threading.get_ident():
+                return
+            self._su_switch(now, phase, base=True)
+
+    @contextlib.contextmanager
+    def phase(self, phase: str, model: str = ""):
+        """`phase` (of START_PHASES) of `model` for the block. On the
+        chain's thread while the chain is open: a link of it — the phase
+        that was open resumes when the block ends. Anywhere else (a model
+        loaded later, a rebuild, a runtime a test builds): a row of its
+        own, `in_ready` false."""
+        if phase not in START_PHASES:
+            raise ValueError(f"unknown start-up phase {phase!r}")
+        me = threading.get_ident()
+        if self._su_thread != me:
+            row = _PhaseRow(phase, model, self._now(), False)
+            stack = self._thread().accounts
+            stack.append(row.acct)
+            try:
+                yield row.acct
+            finally:
+                stack.remove(row.acct)
+                row.wall_s = self._now() - row.t0
+                with self._lock:
+                    self._su_later.append(row)
+            return
+        with self._lock:
+            self._su_switch(self._now(), phase, model)
+            acct = self._su_stack[-1].acct
+        try:
+            yield acct
+        finally:
+            with self._lock:
+                if self._su_thread == me:  # not ended from another thread
+                    self._su_switch(self._now(), None)
+
+    def startup_ready(self) -> None:
+        """The HTTP server starts to listen (its start-up hook, whichever
+        thread runs it): the chain ends here, `ready_s` is fixed and the
+        gauges are set — once. Without a chain (no cli.main: an embedder,
+        a test's server) nothing is ready that was ever begun."""
+        now = self._now()
+        with self._lock:
+            if self._su_thread is None:
+                return
+            if self._su_stack:
+                self._su_stack[-1].wall_s += now - self._su_last
+            for row in self._su_rows:
+                row.acct.closed = True
+            del self._su_stack[:]
+            self._su_thread = None
+            self.ready_at = now
+            walls = dict.fromkeys(START_PHASES, 0.0)
+            for row in self._su_rows:
+                walls[row.phase] += row.wall_s
+        for ph, wall_s in walls.items():
+            tm.STARTUP_SECONDS.labels(phase=ph).set(wall_s)
+        tm.READY_SECONDS.set(now - self.process_start)
+
+    def startup_snapshot(self) -> dict:
+        """The `startup` block of /debug/stepprof and /metrics.json. The
+        `in_ready` rows' `wall_s` sum to `ready_s` once ready (before, an
+        open phase's running slice is not in its row yet)."""
+        with self._lock:
+            ready_at = self.ready_at
+            return {
+                "process_start": round(self.process_start, 6),
+                "ready_at": None if ready_at is None else round(ready_at, 6),
+                "ready_s": None if ready_at is None
+                else round(ready_at - self.process_start, 6),
+                "phases": [r.as_dict() for r in self._su_rows]
+                + [r.as_dict() for r in self._su_later],
+                "other": self.other.as_dict(),
+                "programs": dict(self._programs),
+            }
 
     def compile_count(self) -> int:
         with self._lock:
@@ -863,12 +1211,16 @@ class StepProfiler:
         return out[-n:] if n else out
 
     def brief(self) -> Optional[dict]:
-        """TUI chip payload: `compiles N · step p99 X ms`."""
+        """TUI chip payload: `compiles N (h hit / m miss) · step p99 X
+        ms` — of the ledger's N first calls, those whose every program
+        came out of the persistent cache and those that compiled one."""
         p99 = self.step_p99_ms()
         n = self.compile_count()
         if p99 is None and n == 0:
             return None
-        out = {"compiles": n}
+        with self._lock:
+            out = {"compiles": n, "hit": self._compile_cache["hit"],
+                   "miss": self._compile_cache["miss"]}
         if p99 is not None:
             out["p99_ms"] = round(p99, 3)
         return out
@@ -945,6 +1297,7 @@ class StepProfiler:
             "shapes": self.shape_table(),
             "recent": self.tail(n),
             "compile_events": compiles[-n:],
+            "startup": self.startup_snapshot(),
             "hbm_samples": len(self.hbm),
         }
 

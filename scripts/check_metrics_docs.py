@@ -40,7 +40,13 @@
      and the `mq.*` span names the profiler emits during a device
      capture (SPAN_NAMES) must match the `mq.`-prefixed names of the
      README span table (between `<!-- stepprof-spans:begin -->` /
-     `<!-- stepprof-spans:end -->`) exactly.
+     `<!-- stepprof-spans:end -->`) exactly;
+  8. start-up phases — the closed vocabulary of what the process does
+     between its start and ready (telemetry/stepprof.py START_PHASES:
+     the `phase` label values of `ollamamq_startup_seconds`) must match
+     the README start-up phase table (between the
+     `<!-- stepprof-start-phases:begin -->` / `...:end -->` markers)
+     exactly.
 
 Imports ONLY ollamamq_tpu.telemetry.schema/.attribution/.journal/
 .tracing — the declaration sites — so the check runs without jax, a
@@ -70,6 +76,8 @@ STEPPROF_BEGIN = "<!-- stepprof-phases:begin -->"
 STEPPROF_END = "<!-- stepprof-phases:end -->"
 LOOP_PHASES_BEGIN = "<!-- stepprof-loop-phases:begin -->"
 LOOP_PHASES_END = "<!-- stepprof-loop-phases:end -->"
+START_PHASES_BEGIN = "<!-- stepprof-start-phases:begin -->"
+START_PHASES_END = "<!-- stepprof-start-phases:end -->"
 SPANS_BEGIN = "<!-- stepprof-spans:begin -->"
 SPANS_END = "<!-- stepprof-spans:end -->"
 
@@ -174,6 +182,19 @@ def registered_loop_phases() -> set:
     return set(LOOP_PHASES)
 
 
+def documented_start_phases(readme_text: str) -> set:
+    """First-column names only: the meanings quote function names."""
+    return _documented(readme_text, START_PHASES_BEGIN, START_PHASES_END,
+                       r"(?m)^\| `([a-z_]+)` \|")
+
+
+def registered_start_phases() -> set:
+    sys.path.insert(0, _REPO)
+    from ollamamq_tpu.telemetry.stepprof import START_PHASES
+
+    return set(START_PHASES)
+
+
 def documented_span_names(readme_text: str) -> set:
     """The `mq.`-prefixed names of the span table (it also lists jit
     function and scope names, which tests/test_trace_spans.py pins
@@ -263,6 +284,12 @@ def main(argv) -> int:
         "mq.* span name(s) missing from the README span table "
         f"(between {SPANS_BEGIN} / {SPANS_END})",
         "documented mq.* span(s) the step profiler no longer emits")
+    rc |= _diff(
+        readme, "start-up phases", registered_start_phases(),
+        documented_start_phases(text),
+        "start-up phase(s) missing from the README start-up phase table "
+        f"(between {START_PHASES_BEGIN} / {START_PHASES_END})",
+        "documented start-up phase(s) the step profiler no longer has")
     if rc == 0:
         print(f"ok: {len(registered_metric_names())} metrics, "
               f"{len(registered_phase_names())} phases, "
@@ -270,7 +297,8 @@ def main(argv) -> int:
               f"{len(registered_journal_events())} journal events, "
               f"{len(registered_router_spans())} router spans, "
               f"{len(registered_stepprof_phases())} stepprof phases, "
-              f"{len(registered_loop_phases())} loop phases, and "
+              f"{len(registered_loop_phases())} loop phases, "
+              f"{len(registered_start_phases())} start-up phases, and "
               f"{len(registered_span_names())} mq.* spans, "
               "all documented")
     return rc

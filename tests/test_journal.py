@@ -134,7 +134,9 @@ _MINIMAL = {
     "epoch_fence": dict(epoch=3, stale_epoch=2, path="placement",
                         caller="router"),
     "compile": dict(site="ragged", key="('ragged', 256, 0)",
-                    wall_ms=812.5, cache_size=3),
+                    wall_ms=812.5, t0=1.7e9, trace_ms=300.0, lower_ms=200.0,
+                    backend_ms=250.0, first_run_ms=62.5, programs=1,
+                    cache="hit"),
 }
 
 

@@ -130,6 +130,12 @@ import pytest  # noqa: E402
      "| `mq.dispatch.bogus` | host span | bogus |\n"),
     ("SPANS_BEGIN", "SPANS_END", "| `mq.clock` |",
      "| `mq.clock.bogus` | host span | bogus |\n"),
+    # PR 67: what the process does between its start and ready
+    # (stepprof.START_PHASES), the first and the last of them.
+    ("START_PHASES_BEGIN", "START_PHASES_END", "| `import` | the kernel's",
+     "| `warm` | bogus |\n"),
+    ("START_PHASES_BEGIN", "START_PHASES_END", "| `serve` | every runtime",
+     "| `compile` | bogus |\n"),
 ])
 def test_checker_pins_loop_phase_and_span_tables(tmp_path, begin, end, row,
                                                  ghost):
@@ -141,9 +147,12 @@ def test_checker_pins_loop_phase_and_span_tables(tmp_path, begin, end, row,
     mod = _load()
     from ollamamq_tpu.telemetry.stepprof import (CHILD_SPANS, CLOCK_SPAN,
                                                  LOOP_PHASES, PHASE_SPANS,
-                                                 PHASES, SPAN_NAMES)
+                                                 PHASES, SPAN_NAMES,
+                                                 START_PHASES)
 
     assert set(LOOP_PHASES) == {"admit", "other", "wait"}
+    assert START_PHASES == ("import", "backend", "weights", "place", "alloc",
+                            "serve")
     assert set(PHASE_SPANS) == ({"mq." + p for p in PHASES}
                                 | {"mq.loop." + p for p in LOOP_PHASES})
     # A child span hangs under a phase span, and the names are unique.

@@ -70,7 +70,7 @@ def test_every_ring_is_bounded():
         t.finish(T_pad=(i % 100) * 8, k_cap=0, n_prefill=1, n_decode=0,
                  tokens=4, padded_tokens=8, compiled=False)
     for i in range(_COMPILE_RING + 50):
-        prof.record_compile("ragged", ("ragged", i), 1.0, i)
+        prof.record_compile("ragged", ("ragged", i), 1.0)
     for i in range(_HBM_RING + 50):
         prof.hbm_record({"models": {}})
     assert len(prof.samples) == _RING
@@ -733,3 +733,420 @@ def test_a_seam_is_a_child_span_while_capturing_and_nothing_otherwise(
         ("open", "mq.host_prep"), ("close", "mq.host_prep"),
         ("open", "mq.dispatch"), ("open", "mq.dispatch.launch"),
         ("close", "mq.dispatch.launch"), ("close", "mq.dispatch")]
+
+
+# ------------------------------------- accounts: what jax did, by who asked
+_TRACE, _LOWER, _BACKEND = stepprof.JAX_SPANS  # jax's names, in that order
+_HIT, _MISS = stepprof.JAX_CACHE_EVENTS
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _feed(prof, script):
+    """jax's listeners' calls, scripted: ("b", event) a span begins,
+    (event, seconds) it ends, (event,) an event."""
+    for step in script:
+        if step[0] == "b":
+            prof.jax_begin(step[1])
+        else:
+            prof.jax_event(*step)
+
+
+@pytest.mark.parametrize("script,want", [
+    # the six events of one program fetched from the persistent cache
+    ([("b", _TRACE), (_TRACE, 0.30), ("b", _LOWER), (_LOWER, 0.20),
+      ("b", _BACKEND), (_HIT,), (_RETRIEVAL, 0.04), (_BACKEND, 0.05)],
+     dict(trace_ms=300.0, lower_ms=200.0, backend_ms=50.0, programs=1,
+          cache_hits=1, cache_misses=0)),
+    # ...and of one that was compiled and written
+    ([("b", _TRACE), (_TRACE, 0.30), ("b", _LOWER), (_LOWER, 0.20),
+      ("b", _BACKEND), (_MISS,), (_BACKEND, 4.0)],
+     dict(trace_ms=300.0, lower_ms=200.0, backend_ms=4000.0, programs=1,
+          cache_hits=0, cache_misses=1)),
+    # a jit traced inside a trace is taken out of what encloses it
+    ([("b", _TRACE), ("b", _TRACE), (_TRACE, 0.2), ("b", _TRACE),
+      (_TRACE, 0.1), (_TRACE, 1.0)],
+     dict(trace_ms=1000.0, lower_ms=0.0, backend_ms=0.0, programs=0,
+          cache_hits=0, cache_misses=0)),
+    # an eager op compiled while lowering: every kind its SELF time
+    ([("b", _LOWER), ("b", _TRACE), (_TRACE, 0.01), ("b", _LOWER),
+      (_LOWER, 0.02), ("b", _BACKEND), (_BACKEND, 0.07), (_LOWER, 0.5)],
+     dict(trace_ms=10.0, lower_ms=420.0, backend_ms=70.0, programs=1,
+          cache_hits=0, cache_misses=0)),
+    # a duration whose beginning nobody saw counts whole; a span begun
+    # and never ended is dropped by the end of what encloses it
+    ([(_TRACE, 0.25), ("b", _LOWER), ("b", _TRACE), (_LOWER, 0.5)],
+     dict(trace_ms=250.0, lower_ms=500.0, backend_ms=0.0, programs=0,
+          cache_hits=0, cache_misses=0)),
+    # what jax reports beside: nobody's
+    ([("/jax/compilation_cache/compile_requests_use_cache",),
+      ("/jax/compilation_cache/compile_time_saved_sec", 3.0),
+      ("b", "/jax/some/scalar")],
+     dict(trace_ms=0.0, lower_ms=0.0, backend_ms=0.0, programs=0,
+          cache_hits=0, cache_misses=0)),
+], ids=["hit", "miss", "nested-trace", "eager-in-lower", "unpaired", "else"])
+def test_an_account_sums_a_scripted_feed_of_jax_events(script, want):
+    prof = StepProfiler()
+    with prof.account() as acct:
+        _feed(prof, script)
+    got = acct.as_dict()
+    assert got == pytest.approx(want), got
+    assert prof.other.as_dict() == stepprof.Account().as_dict()
+    # ...and outside any account the same feed is `other`'s.
+    _feed(prof, script)
+    assert prof.other.as_dict() == pytest.approx(want)
+    assert not prof._thread().spans and not prof._thread().accounts
+
+
+def test_events_on_another_thread_go_to_other():
+    prof = StepProfiler()
+    script = [("b", _BACKEND), (_MISS,), (_BACKEND, 2.0)]
+    with prof.account() as mine:
+        t = threading.Thread(target=_feed, args=(prof, script))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+        _feed(prof, [("b", _TRACE), (_TRACE, 0.5)])
+    assert mine.as_dict()["trace_ms"] == 500.0 and mine.programs == 0
+    assert prof.other.as_dict() == pytest.approx(dict(
+        trace_ms=0.0, lower_ms=0.0, backend_ms=2000.0, programs=1,
+        cache_hits=0, cache_misses=1))
+    assert prof.startup_snapshot()["programs"] == {
+        "hit": 0, "miss": 1, "off": 0}
+
+
+def test_nested_accounts_charge_the_innermost():
+    prof = StepProfiler()
+    with prof.account() as outer:
+        _feed(prof, [("b", _TRACE), (_TRACE, 0.1)])
+        with prof.account() as inner:
+            _feed(prof, [("b", _BACKEND), (_HIT,), (_BACKEND, 0.2)])
+        _feed(prof, [("b", _LOWER), (_LOWER, 0.3)])
+    assert (outer.trace_ms, outer.lower_ms, outer.backend_ms,
+            outer.programs) == pytest.approx((100.0, 300.0, 0.0, 0))
+    assert (inner.backend_ms, inner.programs, inner.cache_hits) \
+        == pytest.approx((200.0, 1, 1))
+    assert prof.other.programs == 0
+
+
+@pytest.mark.parametrize("programs,hits,misses,word", [
+    (0, 0, 0, "off"), (3, 0, 0, "off"), (2, 2, 0, "hit"), (2, 0, 2, "miss"),
+    (3, 2, 1, "miss"),
+    (3, 2, 0, "miss"),   # one under the thresholds: compiled all the same
+], ids=["nothing", "no-directory", "all-hit", "all-miss", "mixed", "partial"])
+def test_a_compile_events_cache_word(programs, hits, misses, word):
+    a = stepprof.Account()
+    a.programs, a.cache_hits, a.cache_misses = programs, hits, misses
+    prof = StepProfiler()
+    ev = prof.record_compile("ragged", ("ragged", 16, 0), 10.0, a)
+    assert ev["cache"] == word and ev["programs"] == programs
+    assert prof.brief() == {"compiles": 1, "hit": int(word == "hit"),
+                            "miss": int(word == "miss")}
+
+
+@pytest.mark.parametrize("wall_ms,spent", [
+    (100.0, (30.0, 20.0, 40.0)), (100.0, (60.0, 30.0, 20.0)), (5.0, ())],
+    ids=["inside", "clocks-disagree", "no-account"])
+def test_first_run_ms_is_what_the_split_leaves_and_never_negative(
+        wall_ms, spent):
+    prof, a = StepProfiler(), None
+    if spent:
+        a = stepprof.Account()
+        a.trace_ms, a.lower_ms, a.backend_ms = spent
+    began = time.time() - wall_ms / 1e3
+    ev = prof.record_compile("decode", (8, 0), wall_ms, a,
+                             t0=began if spent else None)
+    assert set(ev) == {"ts", "seq", "site", "key", "wall_ms",
+                       *stepprof.COMPILE_SPLIT}
+    assert "cache_size" not in ev
+    assert ev["first_run_ms"] == pytest.approx(
+        max(0.0, wall_ms - sum(spent)))
+    assert ev["t0"] == pytest.approx(ev["ts"] - wall_ms / 1e3, abs=0.05)
+    assert ev["t0"] <= ev["ts"]
+
+
+# ------------------------------------------------------- start-up ledger
+def _start_up(prof, models=("m",)):
+    """cli.main's calls, and a runtime's, at a few milliseconds each."""
+    prof.startup_enter("backend")
+    time.sleep(0.002)
+    for m in models:
+        with prof.phase("alloc", m):
+            with prof.phase("weights", m):
+                _feed(prof, [("b", _BACKEND), (_MISS,), (_BACKEND, 0.5)])
+                time.sleep(0.002)
+            with prof.phase("place", m):
+                time.sleep(0.002)
+            time.sleep(0.002)
+        time.sleep(0.001)   # back in `backend`
+    prof.startup_enter("serve")
+    time.sleep(0.002)
+
+
+@pytest.mark.parametrize("models", [("m",), ("a", "b")],
+                         ids=["one-model", "two-models"])
+def test_start_phases_are_contiguous_and_sum_to_ready_s(models):
+    from ollamamq_tpu.telemetry import schema as tm
+
+    prof = StepProfiler()
+    assert prof.startup_snapshot()["ready_s"] is None
+    before = time.time()
+    assert prof.process_start <= before
+    _start_up(prof, models)
+    prof.startup_ready()
+    prof.startup_ready()             # once: the second is nobody's
+    su = prof.startup_snapshot()
+    rows = su["phases"]
+    assert all(r["in_ready"] for r in rows)
+    assert [(r["phase"], r["model"]) for r in rows] == (
+        [("import", ""), ("backend", "")]
+        + [(ph, m) for m in models for ph in ("alloc", "weights", "place")]
+        + [("serve", "")])
+    assert {r["phase"] for r in rows} == set(stepprof.START_PHASES)
+    # Gapless by construction: the walls ARE ready_s, and each phase
+    # first opened where the one before it was still open or ended.
+    assert sum(r["wall_s"] for r in rows) == pytest.approx(
+        su["ready_s"], abs=1e-4)
+    assert su["ready_at"] - su["process_start"] == pytest.approx(
+        su["ready_s"], abs=1e-5)
+    assert rows[0]["t0"] == su["process_start"]
+    assert rows[1]["t0"] == pytest.approx(
+        rows[0]["t0"] + rows[0]["wall_s"], abs=1e-5)
+    t0s = [r["t0"] for r in rows]
+    assert t0s == sorted(t0s) and t0s[-1] <= su["ready_at"]
+    assert all(r["wall_s"] >= 0.002 for r in rows[1:])
+    # jax's events went to the phase that was open, of its model.
+    for r in rows:
+        assert r["programs"] == (1 if r["phase"] == "weights" else 0)
+        assert r["cache_misses"] == r["programs"]
+    assert su["other"]["programs"] == 0
+    # The gauges, set once: by phase over models, and their sum.
+    by_phase = {ph: sum(r["wall_s"] for r in rows if r["phase"] == ph)
+                for ph in stepprof.START_PHASES}
+    for ph, wall_s in by_phase.items():
+        assert tm.STARTUP_SECONDS.labels(phase=ph).value == pytest.approx(
+            wall_s, abs=1e-5)
+    assert tm.READY_SECONDS.value == pytest.approx(su["ready_s"], abs=1e-5)
+
+
+def test_a_model_loaded_later_appends_its_rows_and_leaves_ready_s_alone():
+    prof = StepProfiler()
+    _start_up(prof)
+    prof.startup_ready()
+    ready = prof.startup_snapshot()
+    # After ready (any thread), and a phase on ANOTHER thread at any time:
+    # rows of their own.
+    with prof.phase("weights", "late"):
+        _feed(prof, [("b", _BACKEND), (_HIT,), (_BACKEND, 0.1)])
+        time.sleep(0.002)
+    prof.startup_enter("backend")    # the chain has ended: nothing
+    su = prof.startup_snapshot()
+    assert (su["ready_s"], su["ready_at"]) == (ready["ready_s"],
+                                               ready["ready_at"])
+    assert su["phases"][:-1] == ready["phases"]
+    late = su["phases"][-1]
+    assert (late["phase"], late["model"], late["in_ready"]) == (
+        "weights", "late", False)
+    assert late["wall_s"] >= 0.002 and late["t0"] >= su["ready_at"]
+    assert (late["programs"], late["cache_hits"]) == (1, 1)
+    assert all(a.closed for a in prof._thread().accounts)
+
+    prof2 = StepProfiler()
+    prof2.startup_enter("backend")
+
+    def elsewhere():
+        with prof2.phase("alloc", "x"):
+            time.sleep(0.002)
+    t = threading.Thread(target=elsewhere)
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
+    prof2.startup_ready()
+    rows = prof2.startup_snapshot()["phases"]
+    assert [(r["phase"], r["in_ready"]) for r in rows] == [
+        ("import", True), ("backend", True), ("alloc", False)]
+    assert sum(r["wall_s"] for r in rows if r["in_ready"]) == pytest.approx(
+        prof2.startup_snapshot()["ready_s"], abs=1e-4)
+    with pytest.raises(ValueError):
+        prof2.startup_enter("warm")
+    with pytest.raises(ValueError):
+        with prof2.phase("compile"):
+            pass
+
+
+def test_the_startup_ledgers_rings_stay_bounded():
+    from ollamamq_tpu.telemetry.stepprof import _START_RING
+
+    prof = StepProfiler()
+    for i in range(_START_RING + 20):
+        with prof.phase("alloc", f"m{i}"):
+            pass
+    for _ in range(500):             # begun and never ended
+        prof.jax_begin(_TRACE)
+    assert len(prof.startup_snapshot()["phases"]) == _START_RING
+    assert len(prof._thread().spans) <= 64
+    prof.reset()
+    su = prof.startup_snapshot()
+    assert su["phases"] == [] and su["ready_s"] is None
+    json.dumps(prof.snapshot(n=8))
+
+
+# --------------------------- the real thing: jax, and its persistent cache
+def _fresh_jit():
+    """A new function object a call: jax traces and lowers it again, and
+    asks the backend (or the persistent cache) for the same program."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2.0
+
+    def ledger_probe(x):
+        return inner(x) + jnp.cos(x)
+    return jax.jit(ledger_probe)
+
+
+def test_a_real_first_call_reads_miss_then_hit_and_off_with_no_directory(
+        tmp_path):
+    """engine.py's listeners, jax's own events and the persistent cache
+    with its thresholds at 0: the same program compiled (`miss`: written),
+    fetched (`hit`), and with no cache directory neither (`off`). The
+    first call's wall holds its split."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ollamamq_tpu.engine import engine  # noqa: F401 — the listeners
+
+    names = ("jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {n: getattr(jax.config, n) for n in names}
+    x = jnp.ones((4,), jnp.float32)
+    x.block_until_ready()
+    events = []
+    try:
+        for n in names:
+            jax.config.update(n, 0)
+        for where in (str(tmp_path), str(tmp_path), None):
+            jax.config.update("jax_compilation_cache_dir", where)
+            compilation_cache.reset_cache()
+            fn = _fresh_jit()
+            t0 = time.monotonic()
+            with PROFILER.account() as acct:
+                fn(x).block_until_ready()
+            events.append(PROFILER.record_compile(
+                "ragged", ("probe", len(events)),
+                (time.monotonic() - t0) * 1e3, acct))
+    finally:
+        for n, v in before.items():
+            jax.config.update(n, v)
+        # (conftest puts the directory back and resets the cache)
+    assert [e["cache"] for e in events] == ["miss", "hit", "off"], events
+    for e in events:
+        assert e["programs"] >= 1
+        assert e["trace_ms"] > 0 and e["lower_ms"] > 0 and e["backend_ms"] > 0
+        assert e["trace_ms"] + e["lower_ms"] + e["backend_ms"] \
+            <= e["wall_ms"] + 0.01, e
+        assert e["first_run_ms"] == pytest.approx(
+            e["wall_ms"] - e["trace_ms"] - e["lower_ms"] - e["backend_ms"],
+            abs=0.01)
+    assert PROFILER.brief()["hit"] == 1 and PROFILER.brief()["miss"] == 1
+    programs = PROFILER.startup_snapshot()["programs"]
+    assert programs["hit"] >= 1 and programs["miss"] >= 1 \
+        and programs["off"] >= 1
+
+
+def test_the_engines_compile_events_and_journal_carry_the_split():
+    """A real tiny engine's first calls: every ledger event and its
+    journal record say what their wall was, and no `cache_size`."""
+    eng = _tpu_engine()
+    try:
+        items = collect(_run(eng, "split", prompt="short"))
+        assert items[-1].kind == "done", items[-1].error
+        events = list(PROFILER.compiles)
+        assert events
+        for e in events:
+            assert set(stepprof.COMPILE_SPLIT) <= set(e) \
+                and "cache_size" not in e
+            # (A step program is memoised by its builder: where an earlier
+            # test of this process built it, this runtime's first call
+            # traced and compiled nothing, and says so.)
+            assert e["cache"] in stepprof.CACHE_OUTCOMES
+            if e["programs"]:
+                assert e["trace_ms"] > 0 and e["lower_ms"] > 0, e
+            assert e["trace_ms"] + e["lower_ms"] + e["backend_ms"] \
+                <= e["wall_ms"] + 0.01, e
+            assert e["t0"] == pytest.approx(e["ts"] - e["wall_ms"] / 1e3,
+                                            abs=0.05)
+        jr = [r for r in eng.journal.tail(n=None) if r["kind"] == "compile"]
+        assert len(jr) == len(events)
+        for r, e in zip(jr, events):
+            assert {k: r[k] for k in stepprof.COMPILE_SPLIT} \
+                == {k: e[k] for k in stepprof.COMPILE_SPLIT}
+            assert "cache_size" not in r
+        # A runtime built outside cli.main's chain: rows of its own.
+        rows = PROFILER.startup_snapshot()["phases"]
+        assert rows and not any(r["in_ready"] for r in rows)
+    finally:
+        eng.stop()
+
+
+# ------------------------------------- where it is read: the server's face
+@pytest.mark.parametrize("path", ["/debug/stepprof", "/metrics.json"])
+def test_the_server_serves_the_startup_block(path, tmp_path):
+    """The fake engine behind the real server, started as cli.main starts
+    it: the app's start-up hook calls the process ready, and both
+    endpoints carry the same `startup` block; /metrics the three series."""
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from ollamamq_tpu.engine.fake import FakeEngine
+    from ollamamq_tpu.server.app import Server
+    from test_telemetry import parse_prom
+
+    async def main():
+        PROFILER.startup_enter("backend")
+        with PROFILER.phase("alloc", "test-tiny"):
+            eng = FakeEngine(EngineConfig(model="test-tiny", max_slots=8),
+                             models={"test-tiny": None},
+                             blocklist_path=f"{tmp_path}/blocked.json")
+        PROFILER.startup_enter("serve")
+        eng.start()
+        cl = TestClient(TestServer(Server(eng, timeout_s=30).build_app()))
+        await cl.start_server()
+        try:
+            PROFILER.jax_event(_BACKEND, 0.25)   # after ready: `other`'s
+            PROFILER.record_compile("decode", (4, 0), 12.0)
+            r = await cl.get(path)
+            assert r.status == 200
+            body = await r.json()
+            r = await cl.get("/metrics")
+            return body, parse_prom(await r.text())[2]
+        finally:
+            await cl.close()
+            eng.stop()
+
+    body, samples = asyncio.run(main())
+    su = body["startup"]
+    assert set(su) == {"process_start", "ready_at", "ready_s", "phases",
+                       "other", "programs"}
+    assert [r["phase"] for r in su["phases"]] == [
+        "import", "backend", "alloc", "serve"]
+    assert su["ready_s"] > 0 and sum(
+        r["wall_s"] for r in su["phases"]) == pytest.approx(
+            su["ready_s"], abs=1e-4)
+    assert su["other"]["programs"] == 1 and su["programs"]["off"] == 1
+    assert float(samples["ollamamq_ready_seconds"]) == pytest.approx(
+        su["ready_s"], abs=1e-5)
+    for r in su["phases"]:
+        assert float(samples[
+            f'ollamamq_startup_seconds{{phase="{r["phase"]}"}}']) \
+            == pytest.approx(r["wall_s"], abs=1e-5)
+    assert float(samples[
+        'ollamamq_compile_programs_total{cache="off"}']) >= 1
+    if path == "/debug/stepprof":
+        (ev,) = body["compile_events"]
+        assert ev["cache"] == "off" and ev["first_run_ms"] == 12.0
+    else:
+        assert body["stepprof"] == {"compiles": 1, "hit": 0, "miss": 0}

@@ -395,8 +395,10 @@ tmr.phases["dispatch"] = 12.34     # pin the rendered p99 exactly (a
 #                                     step's total is its phases' sum)
 tmr.finish(T_pad=0, k_cap=2, n_prefill=0, n_decode=1, tokens=2,
            padded_tokens=4, compiled=True)
-stepprof.PROFILER.record_compile("decode", "(2,)", 100.0, 1)
-stepprof.PROFILER.record_compile("ragged", "(16,)", 200.0, 2)
+stepprof.PROFILER.record_compile("decode", "(2,)", 100.0)
+hit = stepprof.Account()
+hit.programs = hit.cache_hits = 1
+stepprof.PROFILER.record_compile("ragged", "(16,)", 200.0, hit)
 admin_tui.run_tui(eng, None, refresh_ms=50)''')
 assert _CHILD_STEPPROF != _CHILD, "stepprof child patch failed to apply"
 
@@ -407,7 +409,7 @@ def test_tui_stepprof_chip_via_pty(tmp_path):
     count and rolling step p99 off the step profiler's brief()."""
     t = _PtyTui(tmp_path, child_src=_CHILD_STEPPROF)
     try:
-        assert t.wait_output(b"compiles 2"), _stderr(t)
+        assert t.wait_output(b"compiles 2 (1 hit / 0 miss)"), _stderr(t)
         assert t.wait_output(b"step p99 12.34ms"), _stderr(t)
         t.send("q")
         assert t.wait_output(b"TUI_EXIT_OK"), _stderr(t)
