@@ -22,7 +22,8 @@ ONE dispatch for every model of it:
   - The grouped matmul has a kernel and a twin, chosen with the attention
     kernels (`impl`, the forwards' `attn_impl`): "pallas" is jax's
     megablox `gmm` (op `gmm` on the device trace), tiled so that a
-    128-row tile meets a whole [2048, 1024] weight tile — a decode pass
+    128-row tile meets a [2048, 1024] weight tile, or the tiles nearest
+    that which DIVIDE the matrix (`gmm_tiling`, below) — a decode pass
     is bound by streaming each hit expert's weights once; anything else
     is `jax.lax.ragged_dot` (XLA's own: `ragged-dot` on a TPU trace, where
     its 512-row tiles make the same pass compute-bound, 2.3 x slower on a
@@ -62,6 +63,9 @@ expert got: the step programs reduce that to the counters of `load_stats`.
 
 from __future__ import annotations
 
+import functools
+import logging
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
@@ -69,6 +73,8 @@ from jax.sharding import PartitionSpec as PS
 from ollamamq_tpu.config import EXPERTS, ModelConfig
 from ollamamq_tpu.ops.quant import qeinsum
 from ollamamq_tpu.parallel.mesh import AXIS_EXPERT, AXIS_TENSOR
+
+log = logging.getLogger("ollamamq.moe")
 
 # Stage names inside llama's "mlp" scope on the device trace, in order.
 SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
@@ -81,10 +87,41 @@ STACKED = ("we_gate", "we_up", "we_down")
 # What load_stats() returns, in order (int32 each).
 LOAD_STATS = ("assignments", "pairs_hit", "load_max")
 # megablox tiling (rows, contraction, columns) of the "pallas" grouped
-# matmul, clipped to the matrices' own sizes: of the four measured on a v5e
-# at OLMoE's shapes (PERF.md section 6, PR 27) the one nearest the
-# weight-streaming floor from 128 to 4096 rows.
+# matmul where it walks the matrices in whole tiles: of the four measured on
+# a v5e at OLMoE's shapes (PERF.md section 6, PR 27) the one nearest the
+# weight-streaming floor from 128 to 4096 rows. Where it does not, gmm_tiling
+# takes tiles that do (PR 68). megablox masks a contraction remainder — the
+# last k tile's blocks go through float32, a compare and a select, and the
+# MXU contracts the whole tile, zeros and all — and a column remainder moves
+# a sliver of weights for a whole tile's contraction. One launch of a step's
+# rows on a v5e, µs (share of the hit experts' byte floor), the fixed tiles
+# clipped -> the tiles chosen (scripts/gmm_bench.py; my chip runs, PR 68):
+#
+#   kimi-linear  gate/up [2304, 1024]  357.4 (49 %) -> (2304, 1024) 236.4 (74 %)
+#                  (1152, 1024) 250.4: no mask, two k tiles; (768, 1024) 248.9
+#                down    [1024, 2304]  273.6 (64 %) -> (1024, 2304) 235.7 (74 %)
+#                  (1024, 768) 242.6, (1024, 1152) 240.8
+#   deepseek     gate/up [7168, 2048]  277.7 (67 %) -> (1792, 1024) 269.4 (69 %)
+#   openpangu    gate/up [7680, 2048]  214.6 (72 %) -> (1920, 1024) 215.8 (72 %)
+#   lfm2         gate/up [2048, 1792]  328.6 (81 %), kept: its remainder of 768
+#                  columns is byte-bound; (2048, 896) 326.0
+#   olmoe 247.2 / 257.9, qwen3-next 390.2 / 406.6, k-exaone 268.9 / 224.4,
+#   mimo 105.1 / 94.7 (gate/up / down): whole tiles already, kept; so are
+#   deepseek's, openpangu's and lfm2's down projections (250.3, 212.6, 329.1).
+#
+# One k tile beats several at the same bytes (k-exaone's down, k = 2048,
+# against its gate of three tiles; mimo's gate as (4096, 512) 96.8) — the
+# row block stays and an expert's weights are not fetched again where its
+# rows cross a row tile — and a wider column tile a narrower one (olmoe's
+# down (1024, 2048) 249.9, qwen3-next's (512, 2048) 388.8): ROADMAP A16.
 GMM_TILING = (128, 2048, 1024)
+# A column remainder under a tile / GMM_SLIVER is a sliver: a [tk, 1024]
+# tile step takes the MXU 128 x tk x 1024 multiply-adds whatever it moved,
+# and under ~545 columns of bf16 that is more than their bytes' time.
+GMM_SLIVER = 2
+# What a tiling's blocks may take of Mosaic's 16 MiB of scoped VMEM on a v5e
+# (gmm_vmem_bytes): the compiler took 14.5 MiB of them and refused 16.75.
+GMM_VMEM_BYTES = 14 * 2**20
 # Standard deviation of a seeded-random selection bias: a tenth of the
 # router logits', so it moves the choice of some tokens' k-th expert.
 ROUTER_BIAS_SD = 0.1
@@ -137,6 +174,45 @@ def init_moe_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     return out
 
 
+def _dividing(dim: int, cap: int) -> list:
+    """The multiples of 128 up to `cap` that divide `dim`, largest first."""
+    return [t for t in range(cap - cap % 128, 0, -128) if dim % t == 0]
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """What megablox's pipeline keeps in VMEM at a tiling: the weight, row
+    and output blocks, each double-buffered, and the float32 accumulator."""
+    return 2 * itemsize * (tk * tn + tm * tk + tm * tn) + 4 * tm * tn
+
+
+@functools.lru_cache(maxsize=None)
+def gmm_tiling(m: int, k: int, n: int, itemsize: int = 2,
+               caps: tuple = GMM_TILING, vmem: int = GMM_VMEM_BYTES) -> tuple:
+    """(tm, tk, tn) of the "pallas" grouped matmul of [m, k] rows with
+    [G, k, n] weights: `caps` where they divide the matrices, else tiles
+    that do (the table above GMM_TILING). A function of (k, n) alone,
+    static at trace time; logs its choice once a shape."""
+    tm, tk, tn = caps[0], min(caps[1], k), min(caps[2], n)
+
+    def fits(tk, tn):
+        return gmm_vmem_bytes(tm, tk, tn, itemsize) <= vmem
+
+    # Either way: the whole dimension where the blocks fit, else the largest
+    # tile that divides it, else the clip as it was.
+    if 0 < n % tn < tn // GMM_SLIVER:
+        # The last column tile would move a sliver of weights and contract
+        # a whole tile: compute-bound where the whole tiles are byte-bound.
+        tn = next((t for t in [n, *_dividing(n, tn)] if fits(tk, t)), tn)
+    if k % tk:
+        # The last contraction tile would be masked — a float32 pass over
+        # both blocks — and contracted whole, zeros and all; with one tile
+        # the row block also stays for the experts that share a row tile.
+        tk = next((t for t in [k, *_dividing(k, tk)] if fits(t, tn)), tk)
+    log.info("gmm tiles (tm, tk, tn) = %s for (m, k, n) = %s",
+             (tm, tk, tn), (m, k, n))
+    return tm, tk, tn
+
+
 def grouped_matmul(impl: str, xs, w, sizes, interpret: bool = False):
     """Row r of xs [M, k], in group g of the consecutive `sizes` [G], times
     w[g] ([G, k, n]) -> [M, n]; rows past sum(sizes) come back as whatever.
@@ -145,10 +221,8 @@ def grouped_matmul(impl: str, xs, w, sizes, interpret: bool = False):
         return jax.lax.ragged_dot(xs, w, sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    tm, tk, tn = GMM_TILING
-    return gmm(xs, w, sizes, xs.dtype,
-               (tm, min(tk, w.shape[1]), min(tn, w.shape[2])),
-               interpret=interpret)
+    tiling = gmm_tiling(xs.shape[0], *w.shape[1:], w.dtype.itemsize)
+    return gmm(xs, w, sizes, xs.dtype, tiling, interpret=interpret)
 
 
 def _expert_ffn(impl, xs, sizes, w_gate, w_up, w_down, layer=None):

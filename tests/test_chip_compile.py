@@ -226,6 +226,43 @@ def test_olmoe_width_step_forward_compiles_for_one_v5e_chip(v5e, which):
     assert mem.temp_size_in_bytes < one_matrix // 4, mem
 
 
+# (hidden, expert width, experts held, the tiles moe.gmm_tiling gives gate /
+# up and down) where the fixed (2048, 1024) left a masked contraction tile:
+# Kimi-Linear's 2304 whole on either side — a [2304, 1024] gate block and a
+# [1024, 2304] down block, 11.1 and 11.75 MiB of blocks, the most any cell
+# asks of Mosaic's scoped 16 — and openPangu's 7680 as four tiles of 1920.
+@pytest.mark.parametrize("d,f,experts,tiles", [
+    (2304, 1024, 64, ((2304, 1024), (1024, 2304))),
+    (7680, 2048, 16, ((1920, 1024), (2048, 1024)))],
+    ids=["kimi_linear", "openpangu"])
+def test_expert_ffn_compiles_with_tiles_that_divide(v5e, d, f, experts,
+                                                    tiles):
+    """`_expert_ffn` on the whole [L, E, ...] stacks, a 512-token step's
+    4096 rows, with the tiles chosen from (k, n) (PR 68): Mosaic takes the
+    blocks the VMEM arithmetic admitted, there are still exactly three `gmm`
+    a layer, and no stack is copied."""
+    from ollamamq_tpu.models import moe
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    L, m = 2, 4096
+    assert (moe.gmm_tiling(m, d, f)[1:], moe.gmm_tiling(m, f, d)[1:]) == tiles
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    compiled = jax.jit(
+        lambda xs, sizes, wg, wu, wd, layer: moe._expert_ffn(
+            "pallas", xs, sizes, wg, wu, wd, layer)
+    ).lower(s((m, d)), s((experts,), jnp.int32), s((L, experts, d, f)),
+            s((L, experts, d, f)), s((L, experts, f, d)),
+            s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3
+    assert "ragged-dot" not in text
+    stack = L * experts * d * f * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < stack // 4
+
+
 # ---------------------------------------------------------------------------
 # Window and full attention in one stack: the step programs at K-EXAONE's
 # widths (their interpret-mode twins: tests/test_window_cache.py).

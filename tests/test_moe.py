@@ -181,3 +181,81 @@ def test_engine_serves_moe_end_to_end():
         assert texts[0] == texts[1] and len(texts[0]) > 0
     finally:
         eng.stop()
+
+
+# ---------------------------------------------------------------------------
+# The grouped matmul's tiles (PR 68): chosen from the matrices' own (k, n).
+# ---------------------------------------------------------------------------
+
+# (hidden, expert width) of the eight sparse configuration files.
+EXPERT_SHAPES = {
+    "olmoe": (2048, 1024), "qwen3-next": (2048, 512),
+    "k-exaone": (6144, 2048), "mimo": (4096, 2048),
+    "kimi-linear": (2304, 1024), "deepseek": (7168, 2048),
+    "openpangu": (7680, 2048), "lfm2": (2048, 1792)}
+# Every dimension a whole number of the fixed tiles: today's programs.
+UNCHANGED = ("olmoe", "qwen3-next", "k-exaone", "mimo")
+
+
+@pytest.mark.parametrize("side", ["in", "out"])
+@pytest.mark.parametrize("name", EXPERT_SHAPES)
+def test_gmm_tiles_divide_the_matrices_they_walk(name, side):
+    """The eight configurations' gate / up ("in": k = hidden) and down
+    matrices: no contraction tile is masked, no column tile is a sliver, the
+    blocks fit the VMEM arithmetic, and where the fixed tiles already walked
+    the matrix whole they are the tiles still — the same programs."""
+    from ollamamq_tpu.models.moe import (GMM_SLIVER, GMM_TILING,
+                                         GMM_VMEM_BYTES, gmm_tiling,
+                                         gmm_vmem_bytes)
+
+    d, f = EXPERT_SHAPES[name]
+    k, n = (d, f) if side == "in" else (f, d)
+    tm, tk, tn = gmm_tiling(4096, k, n)
+    assert tm == GMM_TILING[0]
+    assert tk % 128 == 0 and k % tk == 0, (tk, k)
+    assert tn % 128 == 0 and (n % tn == 0 or n % tn >= tn // GMM_SLIVER)
+    assert gmm_vmem_bytes(tm, tk, tn, 2) <= GMM_VMEM_BYTES
+    fixed = (min(GMM_TILING[1], k), min(GMM_TILING[2], n))
+    if name in UNCHANGED:
+        assert (tk, tn) == fixed
+    if name == "kimi-linear":  # the whole 2304, rows or columns
+        assert (tk, tn) == ((2304, 1024) if side == "in" else (1024, 2304))
+    # a function of (k, n) alone: the rows change nothing
+    assert gmm_tiling(128, k, n) == (tm, tk, tn)
+    # a tile whose blocks would not fit falls to the next that divides
+    less = gmm_vmem_bytes(tm, tk, tn, 2) - 1
+    small = gmm_tiling(4096, k, n, vmem=less)
+    if (tk, tn) != fixed:
+        assert small != (tm, tk, tn) and k % small[1] == 0
+        assert small[1] <= GMM_TILING[1] and small[2] <= GMM_TILING[2]
+    else:
+        assert small == (tm, tk, tn)  # the fixed tiles are never refused
+
+
+def test_gmm_at_tiles_that_divide_agrees_with_ragged_dot():
+    """Interpret mode, toy shapes the fixed tiles' clip leaves remainders
+    on — k = 384 under a cap of 256, n = 640 with a sliver of 128 under a
+    cap of 512, and both with a VMEM budget that takes neither whole — the
+    caps scaled down through the function's arguments: whole and dividing
+    tiles give what `jax.lax.ragged_dot` gives to the bf16 output's last bit
+    or two, empty groups and unowned trailing rows included."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from ollamamq_tpu.models.moe import gmm_tiling
+
+    rng = np.random.default_rng(0)
+    sizes = jnp.asarray([0, 70, 0, 3, 130, 0, 21, 0], jnp.int32)  # 224 of 384
+    for k, n, caps, vmem, tiles in (
+            (384, 256, (128, 256, 256), 2**20, (128, 384, 256)),
+            (256, 640, (128, 256, 512), 2**21, (128, 256, 640)),
+            (384, 640, (128, 256, 512), 450_000, (128, 128, 128))):
+        assert gmm_tiling(384, k, n, 2, caps, vmem) == tiles
+        xs = jnp.asarray(rng.standard_normal((384, k)), jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((8, k, n)) / np.sqrt(k),
+                        jnp.bfloat16)
+        got = gmm(xs, w, sizes, xs.dtype, tiles, interpret=True)
+        want = jax.lax.ragged_dot(xs, w, sizes)
+        assert got.dtype == want.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(got[:224], np.float32),
+                                   np.asarray(want[:224], np.float32),
+                                   rtol=2**-7, atol=2**-9)
