@@ -676,7 +676,7 @@ def main(argv=None) -> int:
         log.error("%s", quant_err)
         return 2
     from ollamamq_tpu.config import (get_model_config, validate_latent_pool,
-                                     validate_slot_state)
+                                     validate_slot_state, validate_streams)
 
     for name in (m.strip() for m in args.models.split(",") if m.strip()):
         served = get_model_config(name)
@@ -692,6 +692,9 @@ def main(argv=None) -> int:
             latent_err = validate_slot_state(
                 served, spec=args.spec, mesh_shape=shape,
                 kv_dtype=args.kv_dtype, prefix_cache=args.prefix_cache)
+        if served and not latent_err:
+            latent_err = validate_streams(served, spec=args.spec,
+                                          mesh_shape=shape)
         if latent_err:
             log.error("%s", latent_err)
             return 2

@@ -73,6 +73,9 @@ ATTENTION_KINDS = (ATTENTION, WINDOW)
 DENSE, EXPERTS = "dense", "experts"
 # The longest period `ModelConfig.layer_plan` looks for.
 MAX_PERIOD = 8
+# The residual streams a token that `hc_mult` may ask for beside one: the n
+# the mapping kernels and their tests are written for.
+HC_STREAMS = 4
 # Keys of a published `rope_scaling` group of type "yarn".
 YARN_KEYS = ("type", "factor", "original_max_position_embeddings",
              "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
@@ -335,7 +338,9 @@ class ModelConfig:
     # forward_mtp). No part of the next-token distribution: `--spec` serves
     # it as the draft proposer; without `--spec` it is held and not run.
     # 0 (no module) or 1, and 1 with latent attention (no indexer) and
-    # experts only.
+    # experts only — and with ONE residual stream only: the module reads
+    # `hidden [T, D]`, and how it joins `hc_mult` streams no config.json
+    # says (`_check_streams`).
     num_nextn_predict_layers: int = 0
     # -- attention and a state-space mixer in every layer (Falcon-H1) --------
     # `mamba_d_ssm` > 0 makes every layer PARALLEL: x + Attn(h m_in) m_out +
@@ -522,6 +527,25 @@ class ModelConfig:
     attention_chunk_size: Optional[int] = None
     layernorm_epsilon: Optional[float] = None
     topk_method: Optional[str] = None
+    # -- a residual path of several streams (Xing4.0: manifold-constrained ---
+    # hyper-connections, arXiv:2512.24880) -----------------------------------
+    # `hc_mult` n > 1: a token's residual is n STREAMS X [n, D], the embedding
+    # laid on each. A sublayer F reads h = sum_j H_pre[j] X[j], and writes
+    # X'[i] = sum_j H_res[i, j] X[j] + H_post[i] F(norm(h)); the three
+    # mappings are a token's own, from ONE product of the flattened streams
+    # (an RMS norm over all n D lanes, no weight) with Phi [n D, 2 n + n^2]:
+    # H_pre = sigmoid(a_pre . + b) + `hc_eps`, H_post = 2 sigmoid(a_post . +
+    # b), H_res = `hc_sinkhorn_iters` Sinkhorn-Knopp iterations (columns, then
+    # rows, each over its sum + `hc_eps`) of exp(clip(a_res . + b,
+    # `mhc_h_res_clamp_min`, `mhc_h_res_clamp_max`)): doubly stochastic. The
+    # head reads the streams through a learned mix of the same form as H_pre
+    # (ops/hyper_connection.py has the mathematics). 0 / 1: one stream, `x +
+    # F(norm(x))`, and nothing of this is traced. The published spellings.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
 
     def __post_init__(self):
         self._derive_per_kind()
@@ -616,6 +640,7 @@ class ModelConfig:
         self._check_share()
         self._check_gated()
         self._check_sandwich_and_module()
+        self._check_streams()
         if not 0 <= self.num_dense_layers <= self.num_layers:
             raise ValueError(
                 f"{self.name}: num_dense_layers {self.num_dense_layers} is "
@@ -915,7 +940,48 @@ class ModelConfig:
                 "module is one more latent-attention expert layer "
                 "(kv_lora_rank, num_experts, a layer after the dense ones) "
                 "and is served with no indexer (its block has rows in the "
-                "latent pool only)")
+                "latent pool only) and over one residual stream (hc_mult 0 "
+                "/ 1: it reads one hidden a token)")
+
+    def _check_streams(self) -> None:
+        """A residual path of `hc_mult` streams: the stream counts the
+        kernels are written for, and what it is not served beside."""
+        if self.hc_mult not in (0, 1, HC_STREAMS):
+            raise ValueError(
+                f"{self.name}: hc_mult {self.hc_mult}: the program serves "
+                f"one residual stream (0 / 1) or {HC_STREAMS} "
+                "(ops/pallas/hyper_connection.py lays a token's "
+                f"{HC_STREAMS} x {HC_STREAMS} matrix on {HC_STREAMS ** 2} "
+                "lanes)")
+        if self.hc_sinkhorn_iters < 1:
+            raise ValueError(
+                f"{self.name}: hc_sinkhorn_iters {self.hc_sinkhorn_iters}: "
+                "at least one Sinkhorn-Knopp iteration makes H_res")
+        if not self.hc_eps > 0 \
+                or not self.mhc_h_res_clamp_min < self.mhc_h_res_clamp_max:
+            raise ValueError(
+                f"{self.name}: hc_eps {self.hc_eps} / mhc_h_res_clamp_min "
+                f"{self.mhc_h_res_clamp_min} / mhc_h_res_clamp_max "
+                f"{self.mhc_h_res_clamp_max}: a positive epsilon and a clamp "
+                "whose ends are in order")
+        if not self.streams:
+            return
+        if self.num_nextn_predict_layers:
+            raise ValueError(
+                f"{self.name}: num_nextn_predict_layers "
+                f"{self.num_nextn_predict_layers} with hc_mult "
+                f"{self.hc_mult}: the prediction module reads ONE hidden a "
+                "token (forward_mtp's `hidden [T, D]`), and how it joins "
+                f"{self.hc_mult} streams is not in config.json "
+                "(ROADMAP B-M12)")
+        if self.norm_order != "pre" or self.sandwich_norm \
+                or self.residual_multiplier != 1.0 or self.mb_per_layer \
+                or self.is_encoder:
+            raise ValueError(
+                f"{self.name}: hc_mult {self.hc_mult}: the streams are mixed "
+                "around pre-norm sublayers with no second norm, no residual "
+                "multiplier, no `exit_layer` gather (mb_per_layer) and no "
+                "encoder (ROADMAP B-M12)")
 
     def _check_share(self) -> None:
         """Group-limited routing, shared experts and the share held here."""
@@ -1428,6 +1494,17 @@ class ModelConfig:
         return -(-need // page_size) * page_size
 
     @property
+    def streams(self) -> int:
+        """Residual streams a token, where there are several (`hc_mult` > 1);
+        0 for the one-stream path, on which nothing of them is traced."""
+        return self.hc_mult if self.hc_mult > 1 else 0
+
+    @property
+    def hc_maps(self) -> int:
+        """Lanes of a sublayer's mapping product: H_pre | H_post | H_res."""
+        return 2 * self.streams + self.streams ** 2
+
+    @property
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
@@ -1688,6 +1765,13 @@ class ModelConfig:
         norms = (4 if self.sandwich_norm else 2) * d
         if self.layer_norm_eps is not None:  # a LayerNorm: weight and bias
             norms *= 2
+        n, read_out = self.streams, 0
+        if n:
+            # a sublayer's connection (Phi, its biases, the three scalars),
+            # two a layer — with the norms: every layer has them — and the
+            # read-out before the head (Phi_h, its bias, its scalar)
+            norms += 2 * (n * d * self.hc_maps + self.hc_maps + 3)
+            read_out = n * d * n + n + 1
         layers = sum(per_op[op] + per_ffn[ffn] + norms
                      for op, ffn in self.kinds[:first_layers])
         if self.num_nextn_predict_layers:
@@ -1696,7 +1780,8 @@ class ModelConfig:
             layers += (per_op[ATTENTION] + per_ffn[EXPERTS] + norms
                        + 3 * d + 2 * d * d)
         embed = v * d * (1 if self.tie_embeddings else 2)
-        return layers + embed + d * (1 + (self.layer_norm_eps is not None))
+        return (layers + embed + read_out
+                + d * (1 + (self.layer_norm_eps is not None)))
 
     def qk_norm_params(self) -> int:
         """q/k norm weights of one layer."""
@@ -2133,6 +2218,52 @@ MODEL_CONFIGS = {
         first_k_dense_replace=1, router_score="sigmoid", norm_topk_prob=True,
         norm_topk_eps=1e-20, routed_scaling_factor=2.5,
         num_nextn_predict_layers=1,
+    ),
+    # Xing4.0-29B-A4B (XingChen-AGI; `model_type` xing4_0): DeepSeek-V3's
+    # latent attention (q through rank 768, YaRN x 64 from 4096) and expert
+    # layers (64 experts of 1024, 4 a token by sigmoid score + selection
+    # bias, gates normalised and x 2, one shared expert; ALL experts on the
+    # chip: `ep_size` 1) after two dense layers — around a residual path of
+    # FOUR streams mixed by manifold-constrained hyper-connections
+    # (`hc_mult`; ops/hyper_connection.py). The published prediction module
+    # (`num_nextn_predict_layers` 1) is not served over four streams and is
+    # left out (ROADMAP B-M12). 29.5 B parameters: no one chip holds it; the
+    # benchmark serves a first pipeline stage
+    # (benchmarks/configs/xing4.0-29b-a4b-d6.json).
+    "xing4.0:29b-a4b": ModelConfig(
+        name="xing4.0:29b-a4b", vocab_size=131072, hidden_size=3584,
+        intermediate_size=9216, num_layers=40, num_heads=32, num_kv_heads=32,
+        head_dim=192, rope_theta=10_000.0, rms_norm_eps=1e-6,
+        max_seq_len=262144, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling={"type": "yarn", "factor": 64,
+                      "original_max_position_embeddings": 4096,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                      "mscale_all_dim": 1},
+        num_experts=64, num_experts_per_tok=4, n_shared_experts=1,
+        moe_intermediate_size=1024, first_k_dense_replace=2,
+        router_score="sigmoid", use_expert_bias=True, norm_topk_prob=True,
+        norm_topk_eps=1e-20, routed_scaling_factor=2.0,
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0,
+    ),
+    # ...and its tiny twin: four streams of 64 around latent attention, one
+    # dense layer and two expert layers (8 experts, 2 a token, all held).
+    "test-tiny-xing4": ModelConfig(
+        name="test-tiny-xing4", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=24, rope_theta=10_000.0, rms_norm_eps=1e-6, max_seq_len=512,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16,
+        rope_scaling={"type": "yarn", "factor": 4,
+                      "original_max_position_embeddings": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1},
+        num_experts=8, num_experts_per_tok=2, n_shared_experts=1,
+        moe_intermediate_size=32, first_k_dense_replace=1,
+        router_score="sigmoid", use_expert_bias=True, norm_topk_prob=True,
+        norm_topk_eps=1e-20, routed_scaling_factor=2.0,
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0,
     ),
 }
 
@@ -2740,6 +2871,29 @@ def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",
         return None
     return (f"model {cfg.name} has latent attention (kv_lora_rank) and "
             f"cannot be served with {why}")
+
+
+def validate_streams(cfg: ModelConfig, spec: bool = False,
+                     mesh_shape=None) -> Optional[str]:
+    """What a model whose residual path is several streams (`hc_mult` > 1)
+    cannot be served with yet, told BEFORE any device work: returns an error
+    string (None = valid). ROADMAP B-M12 names what each lacks."""
+    if not cfg.streams:
+        return None
+    shape = dict(mesh_shape or {})
+    why = None
+    if spec:
+        why = ("--spec: a verify span's logits are read through the "
+               "streams' read-out at every draft position, which has not "
+               "been held to the reference (ROADMAP B-M12)")
+    elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
+        why = ("--tp / --ep: how [tokens, streams, hidden] and the mapping "
+               "product's weights are sharded is not decided (ROADMAP "
+               "B-M12)")
+    if why is None:
+        return None
+    return (f"model {cfg.name} has a residual path of {cfg.streams} streams "
+            f"(hc_mult) and cannot be served with {why}")
 
 
 def validate_quant_config(weights_dtype: str, kv_dtype: str,

@@ -45,7 +45,7 @@ from ollamamq_tpu.config import (ATTENTION, STATE_KINDS, EngineConfig,
                                  ModelConfig,
                                  get_model_config, smart_match,
                                  validate_latent_pool, validate_quant_config,
-                                 validate_slot_state)
+                                 validate_slot_state, validate_streams)
 from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
@@ -556,6 +556,11 @@ class ModelRuntime:
             model_cfg, kv_dtype=engine_cfg.kv_dtype,
             weights_dtype=engine_cfg.weights_dtype,
             prefix_cache=engine_cfg.prefix_cache,
+            mesh_shape=dict(mesh.shape) if mesh is not None else {})
+        if err is not None:
+            raise ValueError(err)
+        err = validate_streams(
+            model_cfg, spec=engine_cfg.spec,
             mesh_shape=dict(mesh.shape) if mesh is not None else {})
         if err is not None:
             raise ValueError(err)

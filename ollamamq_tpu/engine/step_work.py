@@ -259,6 +259,15 @@ def exit_counts(cfg, page_size, s: Step) -> tuple:
     return rows, ctx, int(n.sum()) - rows
 
 
+def mhc_counts(cfg, page_size, s: Step) -> tuple:
+    """A residual path of several streams: the step's real tokens, each of
+    which passes every application of the connection (a scan's: its active
+    slots x its passes), and the applications a forward pass — two a layer
+    (around the operator, around the FFN) and the read-out before the head,
+    which runs over the sampled rows alone in a ragged step."""
+    return int(sum(s.tokens)), 2 * cfg.num_layers + 1
+
+
 class Kind(NamedTuple):
     present: Callable  # (ModelConfig) -> does a model have such layers?
     fields: Tuple[str, ...]  # what `note` writes onto a step's sample
@@ -349,6 +358,8 @@ KINDS = {
                  ("xattn_rows", "xattn_ctx_rows", "exit_skipped_tokens"),
                  (tm.XATTN_ROWS_TOTAL, tm.XATTN_CTX_ROWS_TOTAL,
                   tm.EXIT_SKIPPED_TOKENS_TOTAL), exit_counts),
+    "mhc": Kind(lambda cfg: cfg.streams, tm.MHC_SAMPLE_FIELDS, (None, None),
+                mhc_counts),
 }
 
 

@@ -60,8 +60,8 @@ from ollamamq_tpu.config import (ATTENTION, ATTENTION_KINDS, CONV, CROSS,
                                  ModelConfig)
 from ollamamq_tpu.models.moe import (SHARED, SHARED_GATE, STACKED,
                                      init_moe_layer_params, moe_mlp)
-from ollamamq_tpu.ops import (block_select, gated_delta, mla, selective_scan,
-                              shortconv, ssd)
+from ollamamq_tpu.ops import (block_select, gated_delta, hyper_connection,
+                              mla, selective_scan, shortconv, ssd)
 from ollamamq_tpu.ops.attention import (
     WindowRing,
     alloc_ring,
@@ -96,6 +96,24 @@ GATE_SCOPES = ("attn_gate",)
 # fold it in their last lines, inside the launch).
 PER_KIND_SCOPES = ("attn_vscale", "attn_sink")
 PER_KIND_KEY = 0x73776131
+# ...and of a stack whose residual path is several streams (`hc_mult`): the
+# mappings and the weighted sum a sublayer reads, the mix it writes back
+# through (both around every sublayer, the second inside "mlp" too), and the
+# streams' read-out inside "lm_head" (ops/hyper_connection.py; on the chip
+# the launches of ops/pallas/hyper_connection.py).
+MHC_SCOPES = ("mhc_mix_in", "mhc_mix_out", "mhc_read_out")  # = schema's
+MHC_KEY = 0x6D686331
+# A sublayer's connection, stacked over ALL layers as the norms are (no entry
+# of KIND_PARAMS): Phi [maps, n D] maps-major, the three scalars, the biases —
+# float32, inside a sigmoid or an exp — for the attention and the FFN sublayer.
+MHC_PARAMS = ("hc_attn_phi", "hc_attn_alpha", "hc_attn_b",
+              "hc_mlp_phi", "hc_mlp_alpha", "hc_mlp_b")
+# Seeded random init, drawn so that the DYNAMIC part matters: Phi N(0, 1 / n D)
+# under scalars around 1 gives logits of standard deviation ~1 across tokens;
+# the biases N(0, sd) with H_res's diagonal lifted, so that H_res is neither
+# uniform nor the identity (a token's own stream keeps ~e^lift times the
+# weight of another's before the iterations).
+MHC_ALPHA_SD, MHC_BIAS_SD, MHC_RES_LIFT = 0.25, 0.5, 1.5
 # Standard deviation of a seeded-random sink logit (float32): of the order of
 # the scores' (q . k / sqrt(d) of unit-variance heads is ~1), so that a
 # forward which drops the sink computes another model.
@@ -476,11 +494,34 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
             w_down=w(keys[6], (Ld, f, d), f, cfg.mlp_multipliers[1]))
     if cfg.count(EXPERTS):
         layers.update(init_moe_layer_params(cfg, keys[9], dtype))
+    n = cfg.streams
+    if n:
+        ck = iter(jax.random.split(jax.random.fold_in(key, MHC_KEY), 9))
+
+        def connection(lead, maps, lift):
+            """(Phi, alpha, b) of `lead` connections of `maps` maps."""
+            f32 = jnp.float32
+            return (jax.random.normal(next(ck), lead + (maps, n * d), f32)
+                    / jnp.sqrt(n * d),
+                    1.0 + MHC_ALPHA_SD * jax.random.normal(
+                        next(ck), lead + (3 if lift else 1,), f32),
+                    MHC_BIAS_SD * jax.random.normal(
+                        next(ck), lead + (maps,), f32) + (jnp.concatenate(
+                            [jnp.zeros((2 * n,), f32), MHC_RES_LIFT
+                             * jnp.eye(n, dtype=f32).reshape(-1)])
+                            if lift else 0.0))
+
+        for name in ("hc_attn", "hc_mlp"):
+            layers.update(zip((f"{name}_phi", f"{name}_alpha", f"{name}_b"),
+                              connection((L,), cfg.hc_maps, True)))
     params = {
         "embed": w(keys[7], (v, d), d, cfg.embedding_multiplier),
         "final_norm": norm_w((d,)),
         "layers": layers,
     }
+    if n:  # the read-out before the head: H_pre's form, its own weights
+        params.update(zip(("hc_head_phi", "hc_head_alpha", "hc_head_b"),
+                          connection((), n, False)))
     if cfg.layer_norm_eps is not None:
         params["final_norm_b"] = bias((d,))
     if not cfg.tie_embeddings and not cfg.is_encoder:
@@ -552,6 +593,9 @@ def _embed(params: dict, cfg: ModelConfig, tokens: jnp.ndarray):
     x = embed_lookup(params["embed"], tokens, _adtype(params))
     if cfg.embedding_multiplier != 1.0:
         x = x * cfg.embedding_multiplier
+    if cfg.streams:  # laid on each of the residual streams: [..., n, D]
+        x = jnp.broadcast_to(x[..., None, :],
+                             x.shape[:-1] + (cfg.streams, x.shape[-1]))
     return x
 
 
@@ -633,8 +677,55 @@ def _ffn(cfg: ModelConfig, lp: dict, ffn: str, h: jnp.ndarray, valid=None,
     return _mlp(lp, h, cfg.mlp_multipliers), None
 
 
+def _stream_in(cfg: ModelConfig, lp: dict, name: str, x: jnp.ndarray,
+               impl: str):
+    """What a sublayer reads of the residual and what it writes back
+    through: (x, None) on the one-stream path — nothing is traced —, else
+    the streams x [B, T, n, D] under the sublayer's own mappings
+    (`name`_phi / _alpha / _b: ops/hyper_connection.mix_in), as (h [B, T, D],
+    maps [B T, .])."""
+    if not cfg.streams:
+        return x, None
+    with jax.named_scope("mhc_mix_in"):
+        h, maps = hyper_connection.mix_in(
+            x.reshape((-1,) + x.shape[2:]), lp[name + "_phi"],
+            lp[name + "_alpha"], lp[name + "_b"],
+            hyper_connection.consts(cfg), impl)
+    return h.reshape(x.shape[:2] + h.shape[1:]), maps
+
+
+def _stream_out(cfg: ModelConfig, x: jnp.ndarray, delta: jnp.ndarray, maps,
+                impl: str) -> jnp.ndarray:
+    """The residual after a sublayer's result `delta`: `x + delta` — or the
+    streams mixed through `_stream_in`'s maps plus H_post times delta."""
+    if maps is None:
+        return x + delta
+    with jax.named_scope("mhc_mix_out"):
+        out = hyper_connection.mix_out(
+            x.reshape((-1,) + x.shape[2:]),
+            delta.reshape((-1,) + delta.shape[2:]), maps,
+            hyper_connection.consts(cfg), impl)
+    return out.reshape(x.shape)
+
+
+def _read_out(params: dict, cfg: ModelConfig, x: jnp.ndarray,
+              impl: str = "jnp") -> jnp.ndarray:
+    """The ONE vector a token the final norm reads: x itself — or, of
+    streams [..., n, D], their learned mix (hyper_connection.read_out)."""
+    if not cfg.streams:
+        return x
+    with jax.named_scope("mhc_read_out"):
+        y = hyper_connection.read_out(
+            x.reshape((-1,) + x.shape[-2:]), params["hc_head_phi"],
+            params["hc_head_alpha"], params["hc_head_b"],
+            hyper_connection.consts(cfg), impl)
+    return y.reshape(x.shape[:-2] + y.shape[-1:])
+
+
 @jax.named_scope("lm_head")
-def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray,
+            impl: str = "jnp") -> jnp.ndarray:
+    x = _read_out(params, cfg, x, impl)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     head = params.get("lm_head", params["embed"])
     logits = logits_head(x, head)
@@ -1446,7 +1537,9 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
                 taps_fn=None, valid=None, mesh=None, impl: str = "jnp",
                 layer=None, rule_fn=None, ssm_fn=None, few=None, s6_fn=None,
                 memo_fn=None, depth=None):
-    """One layer over [B, T, D] hiddens: the SINGLE definition of the
+    """One layer over [B, T, D] hiddens (the streams [B, T, n, D] of a
+    residual path of several: `_stream_in` / `_stream_out` around each
+    sublayer, the plain sum otherwise): the SINGLE definition of the
     layer math for every forward — full sequences, the ragged stream
     ([1, T, D]) and the decode batch ([B, 1, D]). Only the operator's
     schedule differs, injected as `attn_fn(q, k, v) -> [B, T, H, hd]`
@@ -1465,7 +1558,8 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
     def norm(y, name):
         return _norm(cfg, y, lp[name], lp.get(name + "_b"))
 
-    h = norm(x, "attn_norm") if pre else x
+    h, maps = _stream_in(cfg, lp, "hc_attn", x, impl)
+    h = norm(h, "attn_norm") if pre else h
     if op == MAMBA:
         delta, m = _mamba_op(cfg, lp, h, taps_fn, s6_fn)
         memo_fn(m)
@@ -1494,9 +1588,11 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
     scale = cfg.residual_multiplier  # `scale_depth`'s; 1: nothing is traced
     if scale != 1.0:
         delta = delta * scale
-    x = x + (delta if pre else norm(delta, "attn_norm"))
+    x = _stream_out(cfg, x, delta if pre else norm(delta, "attn_norm"),
+                    maps, impl)
+    h, maps = _stream_in(cfg, lp, "hc_mlp", x, impl)
     with jax.named_scope("mlp"):
-        delta, load = _ffn(cfg, lp, ffn, norm(x, "mlp_norm") if pre else x,
+        delta, load = _ffn(cfg, lp, ffn, norm(h, "mlp_norm") if pre else h,
                            valid=valid, mesh=mesh, impl=impl, layer=layer)
         if not pre:
             delta = norm(delta, "mlp_norm")
@@ -1504,7 +1600,7 @@ def _layer_step(cfg: ModelConfig, lp: dict, kinds: Tuple[str, str],
             delta = norm(delta, "post_mlp_norm")
         if scale != 1.0:
             delta = delta * scale
-    return x + delta, load
+    return _stream_out(cfg, x, delta, maps, impl), load
 
 
 def _no_state(cfg: ModelConfig, valid=None) -> dict:
@@ -1599,7 +1695,8 @@ def forward_prefill(
     x, k_cache, v_cache, _ = scan_layers(cfg, body, x, params["layers"],
                                          k_cache, v_cache)
     last = jnp.clip(seq_lens - 1, 0, T - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,D]
+    x_last = jnp.take_along_axis(
+        x, last.reshape((B,) + (1,) * (x.ndim - 1)), axis=1)  # [B,1,D]
     logits = _logits(params, cfg, x_last)[:, 0, :]  # [B, V]
     return logits, k_cache, v_cache
 
@@ -1817,10 +1914,10 @@ def forward_ragged(
             logits = logits[:, None]
     elif out_idx.ndim == 1:
         x_last = x[0][out_idx]  # [B, D]
-        logits = _logits(params, cfg, x_last[None])[0]  # [B, V]
+        logits = _logits(params, cfg, x_last[None], attn_impl)[0]  # [B, V]
     else:
         x_last = x[0][out_idx]  # [B, O, D]
-        logits = _logits(params, cfg, x_last)  # [B, O, V]
+        logits = _logits(params, cfg, x_last, attn_impl)  # [B, O, V]
     out = _results(logits, k_cache, v_cache, conv_state,
                    state.after(conv, rule, ring, pooled), load, moe_load)
     return out + (x[0],) if hidden else out
@@ -2162,7 +2259,7 @@ def forward_decode(
 
     x, k_cache, v_cache, conv, rule, ring, pooled, *_, load = scan_layers(
         cfg, body, x, params["layers"], k_cache, v_cache, *state.loop_carry(), *memo)
-    logits = _logits(params, cfg, x)[:, 0, :]
+    logits = _logits(params, cfg, x, attn_impl)[:, 0, :]
     return _results(logits, k_cache, v_cache, conv_state,
                     state.after(conv, rule, ring, pooled), load, moe_load)
 
@@ -2192,7 +2289,8 @@ def forward_embed(
             valid=valid, layer=ix.ffn, **_no_state(cfg, valid))
 
     x, _ = scan_layers(cfg, body, x, params["layers"])
-    x = _norm(cfg, x, params["final_norm"]).astype(jnp.float32)
+    x = _norm(cfg, _read_out(params, cfg, x),
+              params["final_norm"]).astype(jnp.float32)
     mask = (positions < seq_lens[:, None]).astype(jnp.float32)[:, :, None]
     pooled = jnp.sum(x * mask, axis=1) / jnp.maximum(jnp.sum(mask, axis=1), 1.0)
     return pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)

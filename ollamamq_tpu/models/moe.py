@@ -109,12 +109,21 @@ LOAD_STATS = ("assignments", "pairs_hit", "load_max")
 #   mimo 105.1 / 94.7 (gate/up / down): whole tiles already, kept; so are
 #   deepseek's, openpangu's and lfm2's down projections (250.3, 212.6, 329.1).
 #
+#   xing4.0      gate/up [3584, 1024]  629.1 (87 %) -> (1792, 1024) 628.1 (88 %)
+#                down    [1024, 3584]  (1024, 1024) 649.1 (85 %): the rule's,
+#                  three and a HALF column tiles -> (1024, 1792) 628.0 (88 %),
+#                  two whole ones (GMM_SHAPE_TILES: this shape alone; 256 rows
+#                  on 61 of 64 experts, my chip run, PR 69); (512, 1792) 628.8
+#
 # One k tile beats several at the same bytes (k-exaone's down, k = 2048,
 # against its gate of three tiles; mimo's gate as (4096, 512) 96.8) — the
 # row block stays and an expert's weights are not fetched again where its
 # rows cross a row tile — and a wider column tile a narrower one (olmoe's
 # down (1024, 2048) 249.9, qwen3-next's (512, 2048) 388.8): ROADMAP A16.
 GMM_TILING = (128, 2048, 1024)
+# (k, n) -> (tk, tn) where one launch measured another tiling at least 3 %
+# faster than the rule's choice, for that shape alone (the table above).
+GMM_SHAPE_TILES = {(1024, 3584): (1024, 1792)}
 # A column remainder under a tile / GMM_SLIVER is a sliver: a [tk, 1024]
 # tile step takes the MXU 128 x tk x 1024 multiply-adds whatever it moved,
 # and under ~545 columns of bf16 that is more than their bytes' time.
@@ -196,6 +205,12 @@ def gmm_tiling(m: int, k: int, n: int, itemsize: int = 2,
 
     def fits(tk, tn):
         return gmm_vmem_bytes(tm, tk, tn, itemsize) <= vmem
+
+    measured = GMM_SHAPE_TILES.get((k, n)) if caps == GMM_TILING else None
+    if measured and fits(*measured):
+        log.info("gmm tiles (tm, tk, tn) = %s for (m, k, n) = %s (measured)",
+                 (tm, *measured), (m, k, n))
+        return (tm, *measured)
 
     # Either way: the whole dimension where the blocks fit, else the largest
     # tile that divides it, else the clip as it was.

@@ -107,9 +107,24 @@ _HF_LAYER_MAP.update({
     "linear_attn.norm.weight": ("lin_norm", False),
     "linear_attn.out_proj.weight": ("lin_out", True),
 })
+# Xing4.0 (`xing4_0`: the hyper-connection's tensor names are ASSUMED — no
+# checkpoint or modelling code can be read offline, and config.json names
+# none; a loader of real weights must check names, the maps' order H_pre |
+# H_post | H_res and that Phi is stored [maps, streams x hidden] as the
+# program holds it): a sublayer's `attn_hc` / `mlp_hc` module with its mapping
+# matrix, its three scalars and its biases, float32 (inside a sigmoid or an
+# exp); the read-out's `model.hc_head.*` beside the final norm (_HC_HEAD).
+_HF_LAYER_MAP.update({
+    f"{module}.{hf}": (f"{ours}_{name}", False)
+    for module, ours in (("attn_hc", "hc_attn"), ("mlp_hc", "hc_mlp"))
+    for hf, name in (("phi.weight", "phi"), ("alpha", "alpha"),
+                     ("bias", "b"))})
+_HC_HEAD = {"model.hc_head.phi.weight": "hc_head_phi",
+            "model.hc_head.alpha": "hc_head_alpha",
+            "model.hc_head.bias": "hc_head_b"}
 _HF_SHARED_GATE = "mlp.shared_expert_gate.weight"  # [1, D]
 _HF_LINEAR_TAPS = "linear_attn.conv1d.weight"  # [q | k | v channels, 1, K]
-_FLOAT32_KEYS = ("lin_A_log", "lin_dt_bias")
+_FLOAT32_KEYS = ("lin_A_log", "lin_dt_bias") + llama.MHC_PARAMS
 # The depthwise Conv1d weight [D, 1, K] (cross-correlation behind K-1 zeros
 # of left padding: tap K-1 meets the token itself, as `conv_w`'s).
 _HF_CONV_TAPS = "conv.conv.weight"
@@ -419,6 +434,9 @@ def load_safetensors(cfg: ModelConfig, path: str, dtype=jnp.bfloat16) -> dict:
     }
     if "lm_head.weight" in raw and not cfg.tie_embeddings:
         params["lm_head"] = jnp.asarray(grab("lm_head.weight", False), dtype=dtype)
+    if cfg.streams:  # the streams' read-out before the head (ASSUMED names)
+        params.update({ours: jnp.asarray(grab(name, False), jnp.float32)
+                       for name, ours in _HC_HEAD.items()})
     return params
 
 

@@ -102,6 +102,14 @@ def flops_per_token(cfg, context_len: float = 0.0,
         dense += 7.0 * cfg.count("linear_attention") \
             * cfg.linear_num_value_heads * cfg.linear_key_head_dim \
             * cfg.linear_value_head_dim
+    n = getattr(cfg, "streams", 0)
+    if n:
+        # ...and a residual path of n streams: the mappings' product is in
+        # the parameters (Phi); the weighted sums are not — 2 n + n^2
+        # multiply-adds a lane of the hidden size an application, two
+        # applications a layer, and n a lane in the read-out.
+        dense += 2.0 * cfg.hidden_size * (
+            2 * cfg.num_layers * (2 * n + n * n) + n)
     return dense + attn
 
 

@@ -540,6 +540,16 @@ KV_ROW_BYTES = REGISTRY.gauge(
     "times attention_value_scale) and `attn_sink` (the jnp attentions' sink "
     "term; the Pallas kernels fold it inside their launch)",
     labels=("model", "kind"))
+# A residual path of several streams (`hc_mult`; ops/hyper_connection.py):
+# what a step sample of such a model carries — the step's real tokens that
+# pass the connection (a ragged step's stream tokens, a fused scan's active
+# slots x its passes) and the applications a forward pass (two a layer and
+# the read-out before the head) — and the step programs' named scopes around
+# the calls, kernel or jnp twin. Sample fields and op metadata only: /metrics
+# exports nothing for them (a traced benchmark run reads them:
+# benchmarks/layer_metrics/_mhc.py).
+MHC_SAMPLE_FIELDS = ("mhc_rows", "mhc_apps")
+MHC_SCOPES = ("mhc_mix_in", "mhc_mix_out", "mhc_read_out")
 QUANT_LOGIT_ERR = REGISTRY.gauge(
     "ollamamq_quant_logit_err",
     "Max absolute logit error of the int8-quantized weights vs their "

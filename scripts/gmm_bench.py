@@ -60,7 +60,9 @@ STEPS = {
     "qwen3-next-80b-a3b-ep4-d12": (512, 98),
     "k-exaone-236b-a23b-ep8-d5": (512, 27),
     "kimi-linear-48b-a3b-ep4-d8": (512, 45),
-    "mimo-v2-flash-ep16-d7": (512, 14)}
+    "mimo-v2-flash-ep16-d7": (512, 14),
+    # (PR 69: a decode pass of 64 rows, 256 pairs over ALL 64 experts)
+    "xing4.0-29b-a4b-d6": (64, 95)}
 # --rehearse-cpu: (hidden, width, held, routed, top-k, layers), caps.
 TOY = (384, 256, 4, 8, 2, 2)
 TOY_CAPS = (128, 256, 256)

@@ -19,7 +19,7 @@ WITH = {"conv": "test-tiny-lfm2", "lin": "test-tiny-olmo-hybrid",
         "dense_latent": "test-tiny-openpangu", "attn": "test-tiny",
         "swa": "test-tiny-k-exaone", "exit": "test-tiny-phi4-flash",
         "lightning": "test-tiny-minicpm-sala", "bsa": "test-tiny-minicpm-sala",
-        "kv_rows": "test-tiny-mimo-v2-flash"}
+        "kv_rows": "test-tiny-mimo-v2-flash", "mhc": "test-tiny-xing4"}
 WITHOUT = {kind: "test-tiny-deepseek-v32" if kind == "attn" else "test-tiny"
            for kind in KINDS}
 

@@ -392,7 +392,8 @@ def test_debug_profile_clears_the_span_flag_after_a_failed_capture(
                                    "test-tiny-phi4-flash",
                                    "test-tiny-minicpm-sala",
                                    "test-tiny-kimi-linear",
-                                   "test-tiny-mimo-v2-flash"])
+                                   "test-tiny-mimo-v2-flash",
+                                   "test-tiny-xing4"])
 def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
     """Scopes change op metadata only; every name of llama.SCOPES (and,
     for an MoE model, of moe.SCOPES inside `mlp`; for a model with conv
@@ -430,6 +431,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         + (llama.HYBRID_SCOPES if rt.cfg.mb_per_layer else ()) \
         + (llama.GATE_SCOPES if rt.cfg.attn_output_gate else ()) \
         + (llama.PER_KIND_SCOPES if rt.cfg.per_kind_attention else ()) \
+        + (llama.MHC_SCOPES if rt.cfg.streams else ()) \
         + (moe.SHARED_SCOPES + moe.SHARED_GATE_SCOPES
            if rt.cfg.shared_expert_gate else ())
     if rt.cfg.kv_lora_rank:  # latent attention: its stages for the three
@@ -496,7 +498,7 @@ def test_scope_and_jit_names_in_the_lowered_ragged_and_decode_programs(model):
         | set(llama.BSA_SCOPES) | set(llama.LIGHTNING_SCOPES) \
         | set(llama.KDA_SCOPES) \
         | set(moe.SHARED_SCOPES) | set(llama.GATE_SCOPES) \
-        | set(llama.PER_KIND_SCOPES) \
+        | set(llama.PER_KIND_SCOPES) | set(llama.MHC_SCOPES) \
         | set(moe.SHARED_GATE_SCOPES) | jit_names | set(SPAN_NAMES) \
         <= documented
     # ... and the jit sites really are those functions: the two step
