@@ -433,6 +433,11 @@ def serve_embed_batch(rt, core: "MQCore", pending, max_len: int,
     return True
 
 
+def _host_ids_ready() -> bool:
+    """The done-probe of a step whose ids are a host array."""
+    return True
+
+
 class StepInFlight:
     """One launched step: the device futures it left behind and the
     host's plan of it — all `step_collect` and `step_settle` need to
@@ -468,6 +473,24 @@ class StepInFlight:
         self.dt = 0.0
         self.prev: Optional["StepInFlight"] = None  # unsettled step before
         self.no = 0  # which launch composed it (ModelRuntime._launch_no)
+
+    def futures(self, toks_dev, n_emit_dev=None) -> None:
+        """The jitted call has returned the step's futures: keep the ones
+        `step_collect` will read and ask for their transfer to the host
+        NOW, behind the program that makes them — the read then waits for
+        the step alone, never for a transfer it has yet to ask for (a
+        replicated array moves one shard: jax's own rule). A host array
+        (a dispatch seam that answers with numpy) has nothing to ask for.
+        The request only schedules: whatever is wrong with the step, or
+        with the request itself, is `step_collect`'s to meet at its read."""
+        self.toks_dev, self.n_emit_dev = toks_dev, n_emit_dev
+        for a in (toks_dev, n_emit_dev):
+            start = getattr(a, "copy_to_host_async", None)
+            if start is not None:
+                try:
+                    start()
+                except Exception:
+                    pass
 
 
 class ModelRuntime:
@@ -2405,9 +2428,8 @@ class ModelRuntime:
         # the chip that lies inside one names it.
         _sp.seam("launch")
         try:
-            h.toks_dev, h.n_emit_dev, self.kc, self.vc, self.recent, \
-                self.last_ids, self.slot_state = self._dispatch_ragged(
-                    T_pad, k_cap, buf)
+            toks, n_emit, self.kc, self.vc, self.recent, self.last_ids, \
+                self.slot_state = self._dispatch_ragged(T_pad, k_cap, buf)
         except Exception as e:
             # The step before is untouched by this failure: settle it
             # (its ids are good, and the replay below folds them in),
@@ -2416,6 +2438,7 @@ class ModelRuntime:
             self._jrec("batch", **batch_fields)
             self._ragged_failed(rows, e, core)
             return None
+        h.futures(toks, n_emit)
         _sp.seam("note")
         self._queued(h)
         h.exited = self.work.note(
@@ -2469,11 +2492,13 @@ class ModelRuntime:
     def _queued(self, h: "StepInFlight") -> None:
         """The jitted call of step `h` has just returned: its program is
         queued behind the step launched before it. Opens h's done-bracket
-        (probed with `is_ready()` on its ids: non-blocking, no transfer)
+        (probed with `is_ready()` on its ids: non-blocking, no transfer;
+        ids that never were on a device are ready)
         and notes on its sample how long the chip had had nothing queued
         — `dry_lo_ms`, `dry_hi_ms`, `dry_phase` — with what the launch
         uploaded."""
-        h.sp.launched(h.toks_dev.is_ready, model=self.name,
+        h.sp.launched(getattr(h.toks_dev, "is_ready", None) or _host_ids_ready,
+                      model=self.name,
                       h2d_transfers=self._h2d[0], h2d_bytes=self._h2d[1])
 
     def _launch_made(self, h: "StepInFlight",
@@ -2632,8 +2657,9 @@ class ModelRuntime:
                          float(np.mean(self.seq_lens[active])))
         self._h2d = [0, 0]
         _sp.seam("launch")
-        h.toks_dev, self.kc, self.vc, self.recent, self.last_ids, \
+        toks, self.kc, self.vc, self.recent, self.last_ids, \
             self.slot_state = self._dispatch_decode(k_steps, buf)
+        h.futures(toks)
         _sp.seam("note")
         self._queued(h)
         self.work.note(_sp, [int(k_steps)] * len(active),
@@ -2686,6 +2712,8 @@ class ModelRuntime:
         # the host came back late (the time in between is the host's
         # lateness, not the step's time on the device).
         t_done = _sp.collected()
+        if h.fields is not None:  # (a scan has its sample alone)
+            h.fields["collect_ready"] = _sp.fields["collect_ready"]
         h.toks, h.toks_dev, h.n_emit_dev = toks, None, None
         h.state = "collected"
         # The step's time on the device: from its launch, or from when
@@ -4065,14 +4093,17 @@ class TPUEngine:
             return True
         return rt.has_capacity(kind)
 
-    def _admit(self) -> int:
+    def _admit(self, tick: bool = True) -> int:
+        """`tick` False: a second pass inside one loop iteration (behind
+        a scan's blocking read) — the scheduler's clock does not move."""
         admitted = 0
         pol = self.policy
         # One batch tick on the scheduler clock — the anti-starvation
         # aging runs on admission passes, which fire once per engine
         # loop iteration in the live engine AND once per virtual tick in
         # the synchronous replay/simulate drivers.
-        pol.on_admit_tick()
+        if tick:
+            pol.on_admit_tick()
         # Retry orphans: ids popped before their Request was registered
         # (two-step submit flow); give them a 5 s grace. Expiry always runs;
         # the capacity gate only defers placement of registered requests.
@@ -4177,6 +4208,23 @@ class TPUEngine:
             if not items:
                 break
         return admitted
+
+    def _admit_behind_scan(self) -> None:
+        """A scan's blocking read has just returned, and the thread sat in
+        it for most of the scan: whoever arrived meanwhile is placed NOW,
+        so that the step launched next takes the prompt in — not a
+        one-pass scan (k = 1: "an admission could land") with a second
+        blocking read and a second idle gap of the chip behind it. A
+        control-plane fault here is the next tick's to raise, not the
+        runtime's to die of."""
+        if not (self.core.total_queued() or self._orphans):
+            return
+        self.loop_clock.enter("admit")
+        try:
+            self._admit(tick=False)
+        except Exception:
+            log.exception("admission behind a scan failed")
+        self.loop_clock.enter("other")
 
     def _place(self, req: Request, user: str, model: str) -> bool:
         # Late re-check (dispatcher.rs:503-512): client gone OR user/IP
@@ -4378,7 +4426,10 @@ class TPUEngine:
         the host's work of step N hides behind step N+1 on the device.
         A fused scan in flight is collected first (ids only): nothing is
         queued behind an unfinished scan, so an arrival never waits for
-        two. Where the next composition needs the host to have seen the
+        two — and requests that arrived while the thread was blocked in
+        that read are admitted right behind it (a second admission pass,
+        the scheduler's clock not moved), so the next launch is theirs.
+        Where the next composition needs the host to have seen the
         ids, or state must be at rest, the depth falls to zero and the
         step is settled in the tick that launched it: a runtime whose
         `--spec` proposer is the n-gram lookup, CPU multi-host (one
@@ -4422,6 +4473,7 @@ class TPUEngine:
                         did_work = True
                         if prev.k_steps:
                             rt.step_collect(prev, self.core)
+                            self._admit_behind_scan()
                     # Ragged mixed batch: admission + ONE token-budget
                     # dispatch packing prefill spans AND every live
                     # decode slot (each advances one token inside it).

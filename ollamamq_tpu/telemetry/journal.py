@@ -149,7 +149,7 @@ EVENT_FIELDS: Dict[str, Tuple[tuple, tuple]] = {
     "batch": (("slots", "batch_size", "tokens", "occupancy"),
               ("reqs", "pending", "free_pages", "bucket", "mode",
                "padded_tokens", "n_prefill", "n_decode", "n_spec",
-               "spec_tokens", "spec_accepted")),
+               "spec_tokens", "spec_accepted", "collect_ready")),
     "chunk": (("slot", "pos"), ("tokens", "cached")),
     "install": (("slot",), ("n_prompt",)),
     # Speculation decisions carry their inputs/outcomes: `k` drafts from

@@ -649,9 +649,14 @@ class StepTimer:
     def collected(self) -> float:
         """The blocking read of the step's result has just returned:
         mark `collect`, and if no probe saw the step ready before, it is
-        seen ready now. Returns the instant it was seen ready."""
-        self.mark("collect")
+        seen ready now. Returns the instant it was seen ready. Notes
+        `collect_ready`: 1 where a probe had seen it ready before the
+        read began — `resume("collect")`'s own at the latest: the host
+        came late and the read waited for no step — else 0."""
         b, clock = self.done, self._clock
+        self.fields["collect_ready"] = int(
+            b is not None and b.seen_ready_at is not None)
+        self.mark("collect")
         if b is None:
             return clock._last
         if b.seen_ready_at is None:
@@ -771,6 +776,9 @@ class StepProfiler:
         # the chip dry, and for how long (sum of the samples' fields).
         self._dry = {"launches": 0, "steps": 0, "lo_ms": 0.0, "hi_ms": 0.0,
                      "by_phase_ms": {}}
+        # Steps that were read back, and those whose ids a probe had seen
+        # ready before the read began (the samples' `collect_ready`).
+        self._collect = {"steps": 0, "ready": 0}
         self.compiles: deque = deque(maxlen=_COMPILE_RING)
         self.compile_seq = 0
         self._compile_ts: deque = deque(maxlen=_COMPILE_RING)
@@ -854,6 +862,10 @@ class StepProfiler:
                     d["lo_ms"] += dry_lo
                     by, ph = d["by_phase_ms"], sample["dry_phase"]
                     by[ph] = by.get(ph, 0.0) + dry_lo
+            ready = sample.get("collect_ready")
+            if ready is not None:
+                self._collect["steps"] += 1
+                self._collect["ready"] += ready
         if dry_lo is not None:
             model = sample.get("model", "")
             if sample["dry_hi_ms"] > 0.0:
@@ -1194,6 +1206,13 @@ class StepProfiler:
                     "by_phase_ms": {k: round(v, 4) for k, v
                                     in sorted(d["by_phase_ms"].items())}}
 
+    def collect_summary(self) -> dict:
+        """How many steps were read back, and how many of them had been
+        seen ready before the read began (`collect_ready`): the host was
+        late for those, and their `collect_ms` is the transfer's alone."""
+        with self._lock:
+            return dict(self._collect)
+
     def step_p99_ms(self) -> Optional[float]:
         with self._lock:
             totals = [s["total_ms"] for s in self.samples]
@@ -1236,6 +1255,7 @@ class StepProfiler:
             "padding_waste": round(self.padding_waste(), 4),
             "overhead_fraction": round(self.overhead_fraction(), 6),
             "dry": self.dry_summary(),
+            "collect": self.collect_summary(),
         }
 
     # -- the threads' CPU clocks, read only at a scrape ---------------------
