@@ -675,28 +675,19 @@ def main(argv=None) -> int:
     if quant_err is not None:
         log.error("%s", quant_err)
         return 2
-    from ollamamq_tpu.config import (get_model_config, validate_latent_pool,
-                                     validate_slot_state, validate_streams)
+    # ...and what a model's per-sequence state cannot be served with.
+    from ollamamq_tpu.config import get_model_config
+    from ollamamq_tpu.engine.kv_cache import refusal
 
     for name in (m.strip() for m in args.models.split(",") if m.strip()):
         served = get_model_config(name)
-        shape = {"tensor": args.tp, "expert": args.ep}
-        latent_err = served and validate_latent_pool(
-            served, kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype,
-            prefix_cache=args.prefix_cache, mesh_shape=shape)
-        # ...and, where latent attention is a layer KIND beside layers that
-        # keep a per-slot state, what that state cannot be served with (the
-        # runtime raises the same line for every such model; here it ends
-        # the start before any device work, as the pool's does).
-        if served and served.kv_lora_rank and not latent_err:
-            latent_err = validate_slot_state(
-                served, spec=args.spec, mesh_shape=shape,
-                kv_dtype=args.kv_dtype, prefix_cache=args.prefix_cache)
-        if served and not latent_err:
-            latent_err = validate_streams(served, spec=args.spec,
-                                          mesh_shape=shape)
-        if latent_err:
-            log.error("%s", latent_err)
+        refused = served and refusal(
+            served, spec=args.spec,
+            mesh_shape={"tensor": args.tp, "expert": args.ep},
+            kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype,
+            prefix_cache=args.prefix_cache)
+        if refused:
+            log.error("%s", refused)
             return 2
     if args.fault_plan:
         # Schema-check the plan BEFORE any engine/device work: a typo'd

@@ -2725,177 +2725,6 @@ def validate_tiers(spec: Optional[str], members) -> Optional[str]:
     return None
 
 
-def validate_slot_state(cfg: ModelConfig, spec: bool = False,
-                        mesh_shape=None, kv_dtype: str = "bfloat16",
-                        prefix_cache: bool = False) -> Optional[str]:
-    """What a model with ANY per-slot state (conv layers' windows, the
-    linear-attention layers' matrices, the window layers' K/V rings:
-    STATE_KINDS) cannot be served with yet, told BEFORE any device work:
-    returns an error string (None = valid). Each of these touches
-    per-sequence state and knows only the paged KV pool; run on such a
-    model it would serve K and V without the state beside them (ROADMAP
-    B-M5 names what each lacks; B-M2 for the window layers' rings). A
-    model with parallel layers is refused the prefix cache and int8 pages
-    too: its attention reads the pool, but a shared or re-scaled page says
-    nothing of the mixer's state at its boundary."""
-    held = [kind for kind in STATE_KINDS if cfg.count(kind)]
-    if not held and not cfg.count(SPARSE):
-        return None
-    shape = dict(mesh_shape or {})
-    ring = cfg.count(WINDOW) > 0
-    why = None
-    if cfg.count(SPARSE):
-        why = _sparse_refusal(spec, shape, kv_dtype, prefix_cache)
-    elif held == [PARALLEL]:
-        why = _parallel_refusal(spec, shape, kv_dtype, prefix_cache)
-    elif MAMBA in held:
-        why = _hybrid_refusal(spec, shape, kv_dtype, prefix_cache)
-    elif spec and held == [WINDOW]:
-        why = ("--spec: a verify span writes the window layers' rings, and "
-               "neither the draft cap nor the rollback has been written "
-               "for them (ROADMAP B-M2)")
-    elif spec:
-        why = ("--spec: a rejected draft has already advanced the per-slot "
-               "conv / recurrent state, and rollback restores pages only")
-    elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
-        why = (f"--tp / --ep: the {' and '.join(held)} layers' weights and "
-               "state have no partition specs"
-               + (" (the rings: ROADMAP B-M2)" if ring else ""))
-    elif ring and kv_dtype != "bfloat16":
-        why = ("--kv-dtype int8: the window layers' rings hold bfloat16 "
-               "rows and no scale planes (ROADMAP B-M2)")
-    if why is None:
-        return None
-    return (f"model {cfg.name} has {' and '.join(held)} layers "
-            f"(layer_types) and cannot be served with {why}")
-
-
-def _parallel_refusal(spec: bool, shape: dict, kv_dtype: str,
-                      prefix_cache: bool) -> Optional[str]:
-    """`validate_slot_state`'s line for a model whose layers run attention
-    beside a state-space mixer (None: it can be served so)."""
-    if spec:
-        return ("--spec: a rejected draft has already advanced the mixer's "
-                "convolution window and recurrent state, and rollback "
-                "restores pages only (ROADMAP B-M5)")
-    if shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
-        return ("--tp / --ep: the mixer's weights (heads and B/C groups) and "
-                "its per-slot state have no partition specs (ROADMAP B-M5)")
-    if kv_dtype != "bfloat16":
-        return ("--kv-dtype int8: the layer's K/V pages could be scaled, "
-                "the mixer's float32 state beside them has no such form "
-                "and the pair has not been measured (ROADMAP B-M5)")
-    if prefix_cache:
-        return ("--prefix-cache: a cached page holds K and V of its tokens, "
-                "not the mixer's state at its boundary (ROADMAP B-M5)")
-    return None
-
-
-def _sparse_refusal(spec: bool, shape: dict, kv_dtype: str,
-                    prefix_cache: bool) -> Optional[str]:
-    """...and for a stack of block-sparse attention beside lightning layers:
-    a pooled-key pool beside K and V, block lists a kv group, a float32
-    matrix state a slot."""
-    if spec:
-        return ("--spec: a rejected draft has already advanced the lightning "
-                "layers' state and may have written a pooled key, and "
-                "rollback restores pages only (ROADMAP B-M10)")
-    if shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
-        return ("--tp / --ep: the pooled-key pool, the block lists (one a "
-                "kv group) and the lightning state have no partition specs "
-                "(ROADMAP B-M10)")
-    if kv_dtype != "bfloat16":
-        return ("--kv-dtype int8: a pooled key is the mean of bfloat16 K "
-                "rows, and the sparse walk has not been measured over "
-                "scale planes (ROADMAP B-M10)")
-    if prefix_cache:
-        return ("--prefix-cache: a cached page holds K and V of its tokens, "
-                "not its pooled keys nor the lightning state at its "
-                "boundary (ROADMAP B-M10)")
-    return None
-
-
-def _hybrid_refusal(spec: bool, shape: dict, kv_dtype: str,
-                    prefix_cache: bool) -> Optional[str]:
-    """...and for a decoder-hybrid-decoder stack: mamba layers' scan state
-    and window layers' rings a slot, ONE pool layer that the cross layers
-    read too, and rows that leave the stack half-way."""
-    if spec:
-        return ("--spec: a verify span reads a logit at every draft "
-                "position, and this stack's upper layers run one sampled "
-                "row a sequence; a rejected draft has also advanced the "
-                "scan state and the rings (ROADMAP B-M9)")
-    if shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
-        return ("--tp / --ep: the scan's per-channel state and weights and "
-                "the rings have no partition specs (ROADMAP B-M9)")
-    if kv_dtype != "bfloat16":
-        return ("--kv-dtype int8: the one pool layer is read by every "
-                "cross layer and the rings hold bfloat16 rows; neither has "
-                "been measured with scale planes (ROADMAP B-M9)")
-    if prefix_cache:
-        return ("--prefix-cache: a cached page holds the full layer's K "
-                "and V, not the scan state or the rings at its boundary "
-                "(ROADMAP B-M9)")
-    return None
-
-
-def validate_latent_pool(cfg: ModelConfig, kv_dtype: str = "bfloat16",
-                         weights_dtype: str = "bfloat16",
-                         prefix_cache: bool = False,
-                         mesh_shape=None) -> Optional[str]:
-    """What a model with latent attention (`kv_lora_rank`: a latent pool
-    and, with an indexer, an index-key pool where K and V were) cannot be
-    served with yet, told BEFORE any device work: returns an error string
-    (None = valid). Each of these knows K and V pools of kv_heads x head_dim
-    lanes only (ROADMAP B-M3 names what each lacks). `--spec` is served: a
-    verify span is a ragged span like any other, its logits read a draft
-    position through the latent pool, a rejected draft's rows lie past the
-    rolled-back length."""
-    if not cfg.kv_lora_rank:
-        return None
-    shape = dict(mesh_shape or {})
-    why = None
-    if kv_dtype != "bfloat16":
-        why = ("--kv-dtype int8: the page writer's scales are one a kv head "
-               "and a latent row has no heads")
-    elif weights_dtype != "bfloat16":
-        why = ("--weights-dtype int8: the low-rank projections are "
-               "absorbed into q and the output in bfloat16")
-    elif prefix_cache:
-        why = ("--prefix-cache: the radix tree shares K and V pages, not "
-               "latent and index-key pages")
-    elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
-        why = ("--tp / --ep: the latent and index-key pools and the "
-               "low-rank projections have no partition specs")
-    if why is None:
-        return None
-    return (f"model {cfg.name} has latent attention (kv_lora_rank) and "
-            f"cannot be served with {why}")
-
-
-def validate_streams(cfg: ModelConfig, spec: bool = False,
-                     mesh_shape=None) -> Optional[str]:
-    """What a model whose residual path is several streams (`hc_mult` > 1)
-    cannot be served with yet, told BEFORE any device work: returns an error
-    string (None = valid). ROADMAP B-M12 names what each lacks."""
-    if not cfg.streams:
-        return None
-    shape = dict(mesh_shape or {})
-    why = None
-    if spec:
-        why = ("--spec: a verify span's logits are read through the "
-               "streams' read-out at every draft position, which has not "
-               "been held to the reference (ROADMAP B-M12)")
-    elif shape.get("tensor", 1) > 1 or shape.get("expert", 1) > 1:
-        why = ("--tp / --ep: how [tokens, streams, hidden] and the mapping "
-               "product's weights are sharded is not decided (ROADMAP "
-               "B-M12)")
-    if why is None:
-        return None
-    return (f"model {cfg.name} has a residual path of {cfg.streams} streams "
-            f"(hc_mult) and cannot be served with {why}")
-
-
 def validate_quant_config(weights_dtype: str, kv_dtype: str,
                           model_names=()) -> Optional[str]:
     """Fail-fast validation of the quantization flags BEFORE any device

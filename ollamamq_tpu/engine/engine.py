@@ -41,11 +41,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ollamamq_tpu.config import (ATTENTION, STATE_KINDS, EngineConfig,
-                                 ModelConfig,
+from ollamamq_tpu.config import (ATTENTION, EngineConfig, ModelConfig,
                                  get_model_config, smart_match,
-                                 validate_latent_pool, validate_quant_config,
-                                 validate_slot_state, validate_streams)
+                                 validate_quant_config)
 from ollamamq_tpu.core import MQCore, Fairness, Family
 from ollamamq_tpu.core.mqcore import BlockedError, StuckQueue
 from ollamamq_tpu.engine import kv_cache as kvc
@@ -201,6 +199,11 @@ class QueueFullError(Exception):
         super().__init__(
             f"{scope.replace('_', ' ')}: admission cap {limit} reached; "
             f"retry after ~{retry_after_s:.0f}s")
+
+
+def _prefix_cache(rt):
+    """A runtime's radix tree; None where it holds or shares no pages."""
+    return getattr(getattr(rt, "cache", None), "prefix_cache", None)
 
 
 class MigrationError(RuntimeError):
@@ -568,23 +571,12 @@ class ModelRuntime:
             model_names=(name,))
         if err is not None:
             raise ValueError(err)
-        err = validate_slot_state(
+        err = kvc.refusal(
             model_cfg, spec=engine_cfg.spec,
             mesh_shape=dict(mesh.shape) if mesh is not None else {},
             kv_dtype=engine_cfg.kv_dtype,
-            prefix_cache=engine_cfg.prefix_cache)
-        if err is not None:
-            raise ValueError(err)
-        err = validate_latent_pool(
-            model_cfg, kv_dtype=engine_cfg.kv_dtype,
             weights_dtype=engine_cfg.weights_dtype,
-            prefix_cache=engine_cfg.prefix_cache,
-            mesh_shape=dict(mesh.shape) if mesh is not None else {})
-        if err is not None:
-            raise ValueError(err)
-        err = validate_streams(
-            model_cfg, spec=engine_cfg.spec,
-            mesh_shape=dict(mesh.shape) if mesh is not None else {})
+            prefix_cache=engine_cfg.prefix_cache)
         if err is not None:
             raise ValueError(err)
         self.weights_dtype = engine_cfg.weights_dtype
@@ -645,9 +637,21 @@ class ModelRuntime:
             # committed array it is given: no step program re-lays a stack.
             weights.place_formats(model_cfg, params)
         self.params = params
-        self.kc, self.vc = kvc.alloc_kv_pool(
-            model_cfg, engine_cfg, kv_sharding, dtype,
-            kv_dtype=engine_cfg.kv_dtype)
+        # A program's results are committed to a device once any of its
+        # arguments is, and a re-laid stack is (`device_put` to a Format
+        # commits; a tree born in its formats is committed whole): a carry
+        # that started uncommitted would come back committed — another jit
+        # key — and the first program launched would compile twice. So on
+        # one device the carried state starts where the weights are held.
+        held = {d for x in jax.tree_util.tree_leaves(params)
+                if x.committed for d in x.devices()}
+        carried_on = held.pop() if mesh is None and len(held) == 1 else None
+        # Pool, per-slot state and page books: `cache.kc`, `.vc`, `.slot_state`
+        # are, with recent and last_ids, donated to every step program.
+        self.cache = kvc.SeqCache(
+            name, model_cfg, engine_cfg, max_span=ragged_budget(engine_cfg),
+            dtype=dtype, sharding=kv_sharding, device=carried_on,
+            record=self._jrec, blocked=self._alloc_blocked)
         # Repeat-penalty state: ring of each slot's last-W context token ids
         # (-1 = empty), llama.cpp repeat_last_n semantics. Row S is a trash
         # row so padded/inactive scatter targets never touch a live slot.
@@ -662,46 +666,7 @@ class ModelRuntime:
         # right before can be unsettled at a launch (the loop's depth is
         # one), so one step's rows are all the carry ever has to hold.
         self.last_ids = jnp.zeros((engine_cfg.max_slots,), jnp.int32)
-        # The per-slot state of the layers that keep one (fixed size, no
-        # pages: a llama.SlotState — a window layer's ring is `ring_rows`
-        # rows a slot whatever the context, and the paged pool then holds
-        # the FULL layers only), None for a model without such layers. With
-        # kc, vc, recent and last_ids a donated argument and result of
-        # every step program. Never reset from the host: a request's first
-        # span opens its slot's rows at zero inside the program
-        # (`is_first`); a ring's rows past a sequence's end are masked.
-        self.slot_state = llama.alloc_slot_state(
-            model_cfg, engine_cfg.max_slots, dtype,
-            ring_rows=model_cfg.ring_rows(ragged_budget(engine_cfg),
-                                          engine_cfg.page_size),
-            pooled_rows=model_cfg.pooled_rows(engine_cfg.num_pages,
-                                              engine_cfg.page_size))
-        self.alloc = kvc.PageAllocator(
-            engine_cfg.num_pages, engine_cfg.page_size, engine_cfg.max_pages_per_seq
-        )
-        # Automatic prefix caching: host-side radix tree of finished
-        # prompts' full KV pages (engine/prefix_cache.py). Under SPMD only
-        # the primary's admission path ever walks it — the page tables it
-        # produces already broadcast on the op wire.
-        self.prefix_cache = None
-        if engine_cfg.prefix_cache and self.slot_state is not None:
-            # A cached page holds K and V of its tokens, not the per-slot
-            # state at its boundary: a hit would resume a sequence whose
-            # convolutions (and recurrences) start from nothing. Until a
-            # page can carry a state snapshot, such a model has no
-            # prefix cache (a preempted request replays from token 0).
-            log.warning("%s: prefix cache off: its %s layers' per-slot state "
-                        "is not cached with the pages", name,
-                        " / ".join(k for k in STATE_KINDS
-                                   if model_cfg.count(k)))
-        elif engine_cfg.prefix_cache:
-            from ollamamq_tpu.engine.prefix_cache import PrefixCache
-
-            self.prefix_cache = PrefixCache(
-                engine_cfg.page_size, self.alloc, model=name,
-                min_pages=engine_cfg.prefix_cache_min_pages)
-
-        S, MP = engine_cfg.max_slots, engine_cfg.max_pages_per_seq
+        S = engine_cfg.max_slots
         # Slots mid-chunked-prefill: reserved (not schedulable) but not yet
         # decoding — slot_req stays None so decode skips them.
         self.reserved_slots: set = set()
@@ -712,11 +677,6 @@ class ModelRuntime:
         self._stalled_slots: set = set()
         self._stall_since: Optional[float] = None
         self.slot_req: List[Optional[Request]] = [None] * S
-        self.slot_pages: List[List[int]] = [[] for _ in range(S)]
-        # Pinned prefix-cache nodes per slot (always a PREFIX of
-        # slot_pages: shared tree pages first, private pages after).
-        self.slot_pins: List[list] = [[] for _ in range(S)]
-        self.page_table = np.full((S, MP), kvc.TRASH_PAGE, np.int32)
         self.seq_lens = np.zeros((S,), np.int32)
         # A slot's next input token; -1 - row while the step that samples
         # it (as its row `row`) is unsettled: the device reads it from
@@ -838,19 +798,11 @@ class ModelRuntime:
             self.draft_ids, self.len_ids = (
                 jnp.zeros((engine_cfg.max_slots + 1,), jnp.int32)
                 for _ in range(2))
-        # A program's results are committed to a device once any of its
-        # arguments is, and a re-laid stack is (`device_put` to a Format
-        # commits; a tree born in its formats is committed whole): a carry
-        # that started uncommitted would come back committed — another jit
-        # key — and the first program launched would compile twice. So on
-        # one device the carried state starts where the weights are held.
-        held = {d for x in jax.tree_util.tree_leaves(params)
-                if x.committed for d in x.devices()}
-        if mesh is None and len(held) == 1:
-            (self.kc, self.vc, self.recent, self.last_ids, self.slot_state,
-             self.draft_ids, self.len_ids) = jax.device_put(
-                (self.kc, self.vc, self.recent, self.last_ids,
-                 self.slot_state, self.draft_ids, self.len_ids), held.pop())
+        if carried_on is not None:  # (beside the cache's arrays)
+            (self.recent, self.last_ids, self.draft_ids,
+             self.len_ids) = jax.device_put(
+                (self.recent, self.last_ids, self.draft_ids, self.len_ids),
+                carried_on)
         self._draft_ok = np.zeros((engine_cfg.max_slots,), bool)
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -912,10 +864,8 @@ class ModelRuntime:
         self.param_bytes = sum(
             x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params)
         )
-        self.kv_bytes = sum(
-            x.size * x.dtype.itemsize
-            for x in jax.tree_util.tree_leaves((self.kc, self.vc))
-        )
+        self.kv_bytes = self.cache.kv_bytes
+        self.state_bytes = self.cache.state_bytes
         # Where this runtime's weights live (stats: which member of a
         # fleet, which slice of a dp mesh, sits on which device).
         self.devices = sorted(
@@ -924,7 +874,6 @@ class ModelRuntime:
         # HBM density scoreboard: what weights and KV actually cost on
         # this runtime — the quantization PR's before/after lever.
         tm.HBM_WEIGHT_BYTES.labels(model=name).set(self.param_bytes)
-        tm.HBM_KV_BYTES.labels(model=name).set(self.kv_bytes)
         self.weight_stacks_relaid, relaid_bytes = weights.relaid(
             model_cfg, params)
         tm.WEIGHT_STACKS_RELAID.labels(model=name).set(
@@ -934,35 +883,12 @@ class ModelRuntime:
             log.info("%s: %d weight stacks (%.1f MB) held in the device "
                      "layout their contractions read", name,
                      self.weight_stacks_relaid, relaid_bytes / 1e6)
-        # What a deployment is sized by: the fixed per-slot state, and
-        # what each token of context adds to the pool.
-        self.state_bytes = step_work.state_bytes(self.slot_state, name)
-        if self.slot_state is not None:
-            log.info("%s: per-slot state %.1f MB for %d slots, whatever the "
-                     "context (%s) beside the pool's %.1f MB (%d paged "
-                     "layers)", name, sum(self.state_bytes.values()) / 1e6,
-                     engine_cfg.max_slots,
-                     ", ".join(f"{k} {n / 1e6:.1f} MB"
-                               for k, n in self.state_bytes.items() if n),
-                     self.kv_bytes / 1e6, model_cfg.cache_layers)
-        tm.KV_BYTES_PER_TOKEN.labels(model=name).set(
-            kvc.kv_page_bytes(model_cfg, 1, jnp.dtype(dtype).itemsize,
-                              engine_cfg.kv_dtype))
-        # Latent attention: the two pools' sizes (both are in kv_bytes) and
-        # what the indexer scored and attention saw.
-        if model_cfg.kv_lora_rank:
-            for gauge, pool in ((tm.HBM_LATENT_POOL_BYTES, self.kc),
-                                (tm.HBM_INDEX_POOL_BYTES, self.vc)):
-                gauge.labels(model=name).set(pool.nbytes)
-            log.info("%s: latent pool %s %.1f MB, index-key pool %s %.1f MB",
-                     name, self.kc.shape, self.kc.nbytes / 1e6,
-                     self.vc.shape, self.vc.nbytes / 1e6)
-            if model_cfg.num_nextn_predict_layers:
-                log.info("%s: prediction module held (its block's rows are "
-                         "layer %d of the latent pool); %s", name,
-                         model_cfg.count(ATTENTION),
-                         "--spec drafts with it, on the device" if self.mtp
-                         else "not run without --spec")
+        if model_cfg.kv_lora_rank and model_cfg.num_nextn_predict_layers:
+            log.info("%s: prediction module held (its block's rows are "
+                     "layer %d of the latent pool); %s", name,
+                     model_cfg.count(ATTENTION),
+                     "--spec drafts with it, on the device" if self.mtp
+                     else "not run without --spec")
 
     # -- capacity ----------------------------------------------------------
     def free_slots(self) -> int:
@@ -984,14 +910,14 @@ class ModelRuntime:
         embed_ok = len(self.pending_embed) < 4 * self.ecfg.max_slots
         if kind == "embed":
             return embed_ok
-        evictable = (self.prefix_cache.evictable_pages
-                     if self.prefix_cache is not None else 0)
+        pc = self.cache.prefix_cache
+        evictable = pc.evictable_pages if pc is not None else 0
         gen_ok = (
             len(self.pending_prefill) < 2 * self.ecfg.max_slots
             and self.free_slots() > 0
             # Unreferenced cached pages count as capacity: allocator
             # exhaustion under a full cache evicts, never rejects.
-            and self.alloc.free_pages + evictable >= 2
+            and self.cache.alloc.free_pages + evictable >= 2
         )
         return gen_ok if kind == "generate" else (gen_ok or embed_ok)
 
@@ -1047,6 +973,10 @@ class ModelRuntime:
         if self.fault_plan is not None:
             self.fault_plan.check(site)
 
+    def _alloc_blocked(self, site: str) -> bool:
+        """The fault plan's allocation seam ("alloc", "extend")."""
+        return self.fault_plan is not None and self.fault_plan.blocked(site)
+
     # -- decision journal seams --------------------------------------------
     def _jrec(self, kind: str, req=None, **fields) -> None:
         """Journal one decision with this runtime's model name; no-op
@@ -1054,13 +984,6 @@ class ModelRuntime:
         j = self.journal
         if j is not None:
             j.record(kind, req=req, model=self.name, **fields)
-
-    def _page_state(self) -> dict:
-        """Allocator post-state for page events: the inputs the
-        pages-conserved invariant (free+used+cached==pool) checks."""
-        a = self.alloc
-        return {"free": a.free_pages, "used": a.used_pages,
-                "cached": a.cached_pages, "pool": a.num_pages - 1}
 
     # -- dispatch seams (SPMD subclass broadcasts before dispatching) ------
     # Each returns (sampled_tokens, kc', vc', recent'); the caller assigns
@@ -1076,15 +999,23 @@ class ModelRuntime:
         lay = self.dims.ragged_layout(T_pad)
         fn = self._get_ragged_jit(
             T_pad, k_cap, sampling_flags(*lay.sampling(buf)))
+        c = self.cache
         if not self.mtp:
-            return fn(self.params, self._upload(buf), self.kc, self.vc,
-                      self.recent, self.last_ids, self.slot_state)
+            return fn(self.params, self._upload(buf), c.kc, c.vc,
+                      self.recent, self.last_ids, c.slot_state)
         # The module's drafts and the rows' lengths stay on the device:
         # two more carries.
         *out, self.draft_ids, self.len_ids = fn(
-            self.params, self._upload(buf), self.kc, self.vc, self.recent,
-            self.last_ids, self.slot_state, self.draft_ids, self.len_ids)
+            self.params, self._upload(buf), c.kc, c.vc, self.recent,
+            self.last_ids, c.slot_state, self.draft_ids, self.len_ids)
         return tuple(out)
+
+    def _took_back(self, out: tuple) -> tuple:
+        """A step program's results, its donated carries (the last five)
+        taken back into their places."""
+        c = self.cache
+        *_, c.kc, c.vc, self.recent, self.last_ids, c.slot_state = out
+        return out
 
     def _program(self, cache, site, key_, build, *shape, **kw):
         """The per-runtime compile ledger and nothing else: the program
@@ -1110,8 +1041,9 @@ class ModelRuntime:
         self._fault("decode")
         fn = self._get_decode_jit(
             k_steps, sampling_flags(*self.dims.decode_layout().sampling(buf)))
-        return fn(self.params, self._upload(buf), self.kc, self.vc,
-                  self.recent, self.last_ids, self.slot_state)
+        c = self.cache
+        return fn(self.params, self._upload(buf), c.kc, c.vc,
+                  self.recent, self.last_ids, c.slot_state)
 
     def _get_decode_jit(self, k_steps: int, flags=(True, True, True)):
         return self._program(self._decode_jits, "decode", (k_steps, flags),
@@ -1157,25 +1089,19 @@ class ModelRuntime:
             pol.observe_finish(req, model=self.name)
         # Pass req: an installed slot's prompt KV is fully written, so
         # its full prompt pages are insertable into the prefix cache.
-        self._release_slot_pages(slot, req)
+        self.cache.release(slot, req)
         self._clear_slot(slot)
         req.stats.completion_tokens = len(req.generated_ids)
-        if reason == FinishReason.CANCELLED:
-            core.mark_dropped(req.user)
-        elif reason in (FinishReason.KV_EXHAUSTED, FinishReason.ERROR,
-                        FinishReason.DEADLINE):
-            # Honest failure: the client keeps the text generated so far
-            # (flushed) but the request counts dropped, not processed.
-            if flush:
-                chunk = req.flush_text()
-                if chunk:
-                    req.stream.push(StreamItem("token", text=chunk))
+        chunk = (req.flush_text()
+                 if flush and reason != FinishReason.CANCELLED else "")
+        if chunk:
+            req.stream.push(StreamItem("token", text=chunk))
+        if reason in (FinishReason.CANCELLED, FinishReason.KV_EXHAUSTED,
+                      FinishReason.ERROR, FinishReason.DEADLINE):
+            # (An honest failure: the client keeps the text generated so far,
+            # flushed, but the request counts dropped, not processed.)
             core.mark_dropped(req.user)
         else:
-            if flush:
-                chunk = req.flush_text()
-                if chunk:
-                    req.stream.push(StreamItem("token", text=chunk))
             core.mark_done(req.user, tokens=len(req.generated_ids))
         req.finish(reason, error=error)
 
@@ -1279,122 +1205,16 @@ class ModelRuntime:
                 return i
         return None
 
-    # -- prefix-cache seams ------------------------------------------------
-    def _match_prefix(self, tokens: List[int]):
-        """(nodes, pages) of the longest cached prefix, or ([], []) when
-        below the reuse threshold."""
-        nodes, pages = self.prefix_cache.match(tokens)
-        if len(nodes) < self.prefix_cache.min_pages:
-            return [], []
-        return nodes, pages
-
-    def _pc_miss(self) -> None:
-        if self.prefix_cache is not None:
-            self.prefix_cache.note_miss()
-
-    def _alloc_pages(self, num_tokens: int) -> Optional[List[int]]:
-        """alloc() with the prefix-cache eviction backstop: free-list
-        exhaustion reclaims unreferenced cached pages (LRU sweep) instead
-        of failing admission."""
-        if self.fault_plan is not None and self.fault_plan.blocked("alloc"):
-            return None  # injected allocation pressure
-        pages = self.alloc.alloc(num_tokens)
-        if pages is None and self.prefix_cache is not None:
-            short = self.alloc.pages_needed(num_tokens) - self.alloc.free_pages
-            if short > 0:
-                freed = self.prefix_cache.evict(short)
-                if freed > 0:
-                    self._jrec("page_evict", n=freed, **self._page_state())
-                    pages = self.alloc.alloc(num_tokens)
-        if pages is not None:
-            self._jrec("page_alloc", n=len(pages), **self._page_state())
-        return pages
-
-    def _alloc_tail(self, held: int, num_tokens: int) -> Optional[List[int]]:
-        """Private tail pages for a cache-hit admission already holding
-        `held` shared pages; same eviction backstop as _alloc_pages."""
-        need = self.alloc.pages_needed(num_tokens) - held
-        pages = self.alloc.alloc_n(need, held=held)
-        if pages is None and self.prefix_cache is not None:
-            short = need - self.alloc.free_pages
-            if short > 0:
-                freed = self.prefix_cache.evict(short)
-                if freed > 0:
-                    self._jrec("page_evict", n=freed, **self._page_state())
-                    pages = self.alloc.alloc_n(need, held=held)
-        if pages is not None:
-            self._jrec("page_alloc", n=len(pages), **self._page_state())
-        return pages
-
-    def _extend_pages(self, pages: List[int], new_total_tokens: int) -> bool:
-        """Decode-time page growth with the eviction backstop."""
-        if self.fault_plan is not None and self.fault_plan.blocked("extend"):
-            return False  # injected allocation pressure
-        before = len(pages)
-        if self.alloc.extend(pages, new_total_tokens):
-            if len(pages) > before:
-                self._jrec("page_alloc", n=len(pages) - before,
-                           **self._page_state())
-            return True
-        if self.prefix_cache is None:
-            return False
-        need = self.alloc.pages_needed(new_total_tokens) - len(pages)
-        if need <= 0 or len(pages) + need > self.alloc.max_pages_per_seq:
-            return False  # per-seq cap: eviction can't help
-        freed = self.prefix_cache.evict(need - self.alloc.free_pages)
-        if freed > 0:
-            self._jrec("page_evict", n=freed, **self._page_state())
-            if self.alloc.extend(pages, new_total_tokens):
-                if len(pages) > before:
-                    self._jrec("page_alloc", n=len(pages) - before,
-                               **self._page_state())
-                return True
-        return False
-
-    def _release_slot_pages(self, slot: int,
-                            req: Optional[Request] = None) -> None:
-        """Free a slot's KV pages and reset its page-table row.
-
-        With the prefix cache on: always release the slot's pins; when
-        the finishing request is known (`req` passed — the slot was
-        installed, so the prompt's KV is fully written) its full prompt
-        pages MERGE into the tree instead of returning to the free list.
-        Callers without a req (mid-prefill cancel, runtime failure) free
-        every private page and only unpin."""
-        pages = self.slot_pages[slot]
-        pc = self.prefix_cache
-        if pc is None:
-            n_freed = len(pages)
-            self.alloc.free(pages)
-            if n_freed:
-                self._jrec("page_free", n=n_freed, slot=slot,
-                           **self._page_state())
-        else:
-            pins = self.slot_pins[slot]
-            keep = len(pins)  # shared tree pages lead slot_pages
-            if req is not None and req.prompt_tokens:
-                full = min(len(req.prompt_tokens) // self.ecfg.page_size,
-                           len(pages))
-                if full > keep:
-                    pc.insert(req.prompt_tokens, pages[:full])
-                    keep = full
-            n_freed = len(pages) - keep
-            self.alloc.free(pages[keep:])
-            pc.release(pins)
-            self.slot_pages[slot] = []
-            self.slot_pins[slot] = []
-            if n_freed > 0:
-                self._jrec("page_free", n=n_freed, slot=slot,
-                           **self._page_state())
-        self.page_table[slot, :] = kvc.TRASH_PAGE
-
-    def _seat_slot(self, slot: int, req: Request, n: int) -> None:
-        """Seat a request whose prompt's last span is dispatched in its
-        decode slot: from here on it is a decode row."""
+    def _seat_slot(self, slot: int, req: Request, n: int,
+                   kv_len: Optional[int] = None) -> None:
+        """Seat a request in its decode slot, its prompt's last span
+        dispatched — or (`kv_len`) a migrated stream's pages landed: from
+        here on it is a decode row."""
         self._jrec("install", req, slot=slot, n_prompt=n)
         self.slot_req[slot] = req
-        self._tm_prompt_tokens.inc(n)
-        self.seq_lens[slot] = n
+        if kv_len is None:
+            self._tm_prompt_tokens.inc(n)
+        self.seq_lens[slot] = n if kv_len is None else kv_len
         self.temp[slot] = req.sampling.temperature
         self.top_k[slot] = req.sampling.top_k
         self.top_p[slot] = req.sampling.top_p
@@ -1410,8 +1230,14 @@ class ModelRuntime:
         (queued / mid-prefill / chunking work replays cheaply via
         recompute — only written decode state is worth shipping). The
         detached slot keeps its pages (reserved, undispatchable) until
-        release_export resolves the two-phase handoff."""
-        if self.slot_state is not None or self.cfg.kv_lora_rank:
+        release_export resolves the two-phase handoff.
+
+        The blob is the portable wire state of the slot: its page run
+        (int8 payload + scale rows for quantized pools — ~2x cheaper to
+        move), the decode cursor (written kv_len + the pending last
+        token, mirroring the install convention), the penalty ring row,
+        request state, and the scheduler predictor's view of the user."""
+        if kvc.unserved(self.cfg, "migrate"):
             # The blob has no place for the per-slot state, and pages
             # without it resume another sequence; nor for latent and
             # index-key pages: not exportable (the caller's fallback
@@ -1422,27 +1248,10 @@ class ModelRuntime:
                 break
         else:
             return None
-        blob = self._migration_snapshot(slot, req)
-        self.slot_req[slot] = None
-        self.reserved_slots.add(slot)
-        self._stalled_slots.discard(slot)
-        return {"slot": slot, "req": req}, blob
-
-    def _migration_snapshot(self, slot: int, req: Request) -> dict:
-        """The portable wire state of one decode slot: its page run
-        (int8 payload + scale rows for quantized pools — ~2x cheaper to
-        move), the decode cursor (written kv_len + the pending last
-        token, mirroring the install convention), the penalty ring row,
-        request state, and the scheduler predictor's view of the user."""
-        pages = list(self.slot_pages[slot])
-        data = kvc.gather_page_run(self.kc, self.vc, pages,
-                                   self.ecfg.page_size, self.cfg.head_dim)
+        c = self.cache
+        pages = list(c.slot_pages[slot])
         blob = {
-            "version": 1, "kind": "stream", "model": self.name,
-            "kv_dtype": self.kv_dtype, "page_size": self.ecfg.page_size,
-            "num_layers": self.cfg.paged_layers,
-            "num_kv_heads": self.cfg.num_kv_heads,
-            "head_dim": self.cfg.head_dim,
+            **c.header("stream"),
             "kv_len": int(self.seq_lens[slot]),
             "last_token": int(self.last_tokens[slot]),
             "n_pages": len(pages),
@@ -1452,12 +1261,15 @@ class ModelRuntime:
             # (exact stream continuity); the wire packer drops it and the
             # importer builds a fresh one off the carried detok text.
             "_inc_decode": req._inc_decode,
-            **data,
+            **c.gather(pages),
         }
         pol = self.policy
         if pol is not None:
             blob["predictor"] = pol.predictor.export_user(req.user)
-        return blob
+        self.slot_req[slot] = None
+        self.reserved_slots.add(slot)
+        self._stalled_slots.discard(slot)
+        return {"slot": slot, "req": req}, blob
 
     def release_export(self, handle: dict) -> None:
         """Resolve a detached export (commit OR abort): the pages go the
@@ -1466,7 +1278,7 @@ class ModelRuntime:
         cache), the rest return to the free list."""
         slot, req = handle["slot"], handle["req"]
         self.reserved_slots.discard(slot)
-        self._release_slot_pages(slot, req)
+        self.cache.release(slot, req)
         self._clear_slot(slot)
 
     def import_request(self, blob: dict, req: Request) -> bool:
@@ -1475,114 +1287,35 @@ class ModelRuntime:
         into this pool, and resume the decode cursor exactly where the
         source froze it — no token is ever recomputed. False when the
         blob's shape doesn't match this runtime or capacity is gone
-        (the caller falls back to recompute replay). A model with conv
-        layers refuses every blob: pages come without its state."""
-        if self.slot_state is not None:
-            raise MigrationError(
-                f"{self.name}: a migrated stream carries KV pages, not the "
-                f"{' / '.join(k for k in STATE_KINDS if self.cfg.count(k))} "
-                "layers' state; replay the request instead")
-        if self.cfg.kv_lora_rank:
-            raise MigrationError(
-                f"{self.name}: a migrated stream carries K and V pages, not "
-                "latent and index-key pages; replay the request instead")
-        if (blob.get("kind") != "stream"
-                or int(blob.get("page_size", -1)) != self.ecfg.page_size
-                or blob.get("kv_dtype") != self.kv_dtype
-                or int(blob.get("num_layers", -1))
-                != self.cfg.paged_layers
-                or int(blob.get("num_kv_heads", -1)) != self.cfg.num_kv_heads
-                or int(blob.get("head_dim", -1)) != self.cfg.head_dim):
-            return False
-        n = int(blob["n_pages"])
-        if n <= 0 or n > self.alloc.max_pages_per_seq:
+        (the caller falls back to recompute replay). A model that holds
+        more than K and V pages refuses every blob: pages come without
+        its state."""
+        why = kvc.unserved(self.cfg, "migrate")
+        if why:
+            raise MigrationError(f"{self.name}: {why}")
+        if not self.cache.accepts(blob, "stream"):
             return False
         slot = self._claim_slot()
-        if slot is None:
+        if slot is None or not self.cache.install(slot, blob):
             return False
-        pages = self._alloc_tail(0, n * self.ecfg.page_size)
-        if pages is None:
-            return False
-        self.kc, self.vc = kvc.scatter_page_run(
-            self.kc, self.vc, pages, self.ecfg.page_size, blob)
         self.recent = self.recent.at[slot].set(
             jnp.asarray(np.asarray(blob["recent"], np.int32)))
-        self.slot_pages[slot] = pages
-        self.slot_pins[slot] = []
-        self.page_table[slot, :] = kvc.make_page_table_row(
-            pages, self.ecfg.max_pages_per_seq)
-        s = req.sampling
-        self.slot_req[slot] = req
-        self.seq_lens[slot] = int(blob["kv_len"])
-        self.last_tokens[slot] = int(blob["last_token"])
-        self.temp[slot] = s.temperature
-        self.top_k[slot] = s.top_k
-        self.top_p[slot] = s.top_p
-        self.rep_pen[slot] = s.repeat_penalty
-        self.pres_pen[slot] = s.presence_penalty
-        self.freq_pen[slot] = s.frequency_penalty
-        self.seeds[slot] = s.seed
         if req._inc_decode is None:
             req._inc_decode = self.tokenizer.make_incremental_decoder()
         pol = self.policy
         if pol is not None and blob.get("predictor"):
             pol.predictor.import_user(req.user, blob["predictor"])
-        self._jrec("install", req, slot=slot,
-                   n_prompt=len(req.prompt_tokens))
+        self._seat_slot(slot, req, len(req.prompt_tokens),
+                        kv_len=int(blob["kv_len"]))
+        self.last_tokens[slot] = int(blob["last_token"])
         return True
 
+    # (The SPMD runtime overrides both; TPUEngine reaches them by name.)
     def export_prefix(self, tokens: List[int]):
-        """Affinity-miss prefix shipping, source side: the longest cached
-        full-page prefix of `tokens` as a wire blob (pages pinned only
-        for the device->host copy). None when nothing caches."""
-        pc = self.prefix_cache
-        if pc is None:
-            return None
-        nodes, pages = pc.match(list(tokens))
-        if not pages:
-            return None
-        pc.pin(nodes)
-        try:
-            data = kvc.gather_page_run(self.kc, self.vc, pages,
-                                       self.ecfg.page_size,
-                                       self.cfg.head_dim)
-        finally:
-            pc.release(nodes)
-        ps = self.ecfg.page_size
-        return {
-            "version": 1, "kind": "prefix", "model": self.name,
-            "kv_dtype": self.kv_dtype, "page_size": ps,
-            "num_layers": self.cfg.paged_layers,
-            "num_kv_heads": self.cfg.num_kv_heads,
-            "head_dim": self.cfg.head_dim,
-            "n_pages": len(pages),
-            "prefix_tokens": [int(t) for t in tokens[:len(pages) * ps]],
-            **data,
-        }
+        return self.cache.export_prefix(tokens)
 
     def import_prefix(self, blob: dict) -> int:
-        """Affinity-miss prefix shipping, target side: land shipped
-        prefix pages in this pool and merge them into the radix tree, so
-        the request admitted next prefills only the tail. Plain alloc_n
-        (no eviction backstop): shipping a remote prefix must never
-        evict locally-earned cache. Returns pages adopted (0 = no-op)."""
-        pc = self.prefix_cache
-        if (pc is None or blob.get("kind") != "prefix"
-                or int(blob.get("page_size", -1)) != self.ecfg.page_size
-                or blob.get("kv_dtype") != self.kv_dtype
-                or int(blob.get("num_layers", -1))
-                != self.cfg.paged_layers
-                or int(blob.get("num_kv_heads", -1)) != self.cfg.num_kv_heads
-                or int(blob.get("head_dim", -1)) != self.cfg.head_dim):
-            return 0
-        n = int(blob["n_pages"])
-        pages = self.alloc.alloc_n(n) if n > 0 else None
-        if pages is None:
-            return 0
-        self._jrec("page_alloc", n=n, **self._page_state())
-        self.kc, self.vc = kvc.scatter_page_run(
-            self.kc, self.vc, pages, self.ecfg.page_size, blob)
-        return pc.insert([int(t) for t in blob["prefix_tokens"]], pages)
+        return self.cache.import_prefix(blob)
 
     # -- speculative decoding (n-gram draft + ragged verify) ---------------
     # Accept-rate warmup sample per user before the auto-throttle may
@@ -1681,27 +1414,6 @@ class ModelRuntime:
                      "%.2f < %.2f over %d proposed)", self.name, req.user,
                      row[1] / row[0], min_rate, row[0])
 
-    def _rollback_spec(self, slot: int, req: Request, kv_before: int,
-                       kv_after: int) -> int:
-        """Release the page claim of rejected draft tokens: the slot
-        keeps exactly the pages its ACCEPTED context needs. Shared
-        prefix-tree pages lead slot_pages and are floored out of the
-        truncation — speculation must never free a page the radix tree
-        owns. Rejected positions on device need no un-write: they sit
-        past the rolled-back kv_len, masked by attention and overwritten
-        by the next real decode step."""
-        self.spec_rollbacks += 1
-        keep = len(self.slot_pins[slot])
-        freed = self.alloc.rollback_to(self.slot_pages[slot], kv_after,
-                                       keep=keep)
-        if freed:
-            self.page_table[slot, :] = kvc.make_page_table_row(
-                self.slot_pages[slot], self.ecfg.max_pages_per_seq)
-        self._jrec("spec_rollback", req, slot=slot, kv_before=kv_before,
-                   kv_after=kv_after, freed=freed, source=self.proposer,
-                   **self._page_state())
-        return freed
-
     def _drop_expired_slot(self, slot: int, core: MQCore) -> None:
         """Deadline enforcement at the speculative composer: an expired
         request must not burn a k-token verify span (the same
@@ -1798,16 +1510,23 @@ class ModelRuntime:
             except Exception:
                 pass
             self._jrec("preempt", req, slot=slot, why="kv_pressure",
-                       n=req.preemptions, free_pages=self.alloc.free_pages,
+                       n=req.preemptions,
+                       free_pages=self.cache.alloc.free_pages,
                        victim_served=served, vip=vip)
-        req.prompt_tokens = replay[:written]
-        self._release_slot_pages(slot, req if written else None)
-        req.prompt_tokens = replay
-        req._replay_gen = len(req.generated_ids)
-        self._clear_slot(slot)
+        self._release_for_replay(slot, req, replay, written)
         hook = self.on_preempt
         if hook is not None:
             hook(req)  # False => hook finished it (blocked/expired)
+
+    def _release_for_replay(self, slot: int, req: Request, replay: List[int],
+                            written: int) -> None:
+        """Release `slot` with its `written` tokens' pages merged into the
+        prefix cache, and leave `req` to replay prompt + generated ids."""
+        req.prompt_tokens = replay[:written]
+        self.cache.release(slot, req if written else None)
+        req.prompt_tokens = replay
+        req._replay_gen = len(req.generated_ids)
+        self._clear_slot(slot)
 
     def _page_exhausted(self, slot: int, need_tokens: int,
                         core: MQCore) -> None:
@@ -1816,9 +1535,9 @@ class ModelRuntime:
         with preemption off — error explicitly as kv_exhausted. A genuine
         per-sequence context-cap hit is still an honest LENGTH (that IS
         the context budget, not pool pressure)."""
-        pages = self.slot_pages[slot]
-        if (self.alloc.pages_needed(need_tokens) > self.alloc.max_pages_per_seq
-                or len(pages) >= self.alloc.max_pages_per_seq):
+        pages, alloc = self.cache.slot_pages[slot], self.cache.alloc
+        if (alloc.pages_needed(need_tokens) > alloc.max_pages_per_seq
+                or len(pages) >= alloc.max_pages_per_seq):
             self._finish_slot(slot, FinishReason.LENGTH, core)
             return
         if self.on_preempt is None or not self.ecfg.preempt:
@@ -1834,24 +1553,18 @@ class ModelRuntime:
             for _ in range(len(self.slot_req)):
                 victim = self._pick_victim()
                 if victim is None:
-                    # Nobody preemptible: hold the reservation (slot +
-                    # pages), sit out dispatches until pages free up.
-                    self.slot_req[slot].trace_event(
-                        "kv_stall", pages=len(pages))
-                    self._jrec("kv_stall", self.slot_req[slot], slot=slot,
-                               free_pages=self.alloc.free_pages,
-                               need=need_tokens)
-                    self._stalled_slots.add(slot)
-                    return
+                    break
                 self._preempt_slot(victim, core)
                 if self.slot_req[slot] is None:
                     return  # this slot WAS the victim
-                if self._extend_pages(pages, need_tokens):
+                if self.cache.extend(slot, need_tokens):
                     self._stalled_slots.discard(slot)
                     return
+            # Nobody (left) preemptible: hold the reservation (slot +
+            # pages), sit out dispatches until pages free up.
             self.slot_req[slot].trace_event("kv_stall", pages=len(pages))
             self._jrec("kv_stall", self.slot_req[slot], slot=slot,
-                       free_pages=self.alloc.free_pages, need=need_tokens)
+                       free_pages=alloc.free_pages, need=need_tokens)
             self._stalled_slots.add(slot)
         finally:
             self._preempt_core = None
@@ -1945,43 +1658,24 @@ class ModelRuntime:
                     error=f"prompt length {n} exceeds maximum {max_prompt}",
                 )
                 continue
-            nodes, shared = ([], [])
-            if self.prefix_cache is not None:
-                nodes, shared = self._match_prefix(req.prompt_tokens)
+            c = self.cache
             slot = self._claim_slot()
             if slot is None:
                 break
-            if nodes:
-                # Pin BEFORE the tail allocation: its eviction backstop
-                # must never reclaim the very pages we matched.
-                self.prefix_cache.pin(nodes)
-                tail = self._alloc_tail(len(shared), n + 1)
-                if tail is None:
-                    self.prefix_cache.release(nodes)
-                    break  # wait for frees
-                prefix_len = len(shared) * self.ecfg.page_size
-                self.slot_pins[slot] = list(nodes)
-                self.slot_pages[slot] = list(shared) + tail
-                self.prefix_cache.note_hit(prefix_len)
+            prefix_len = c.admit(slot, req.prompt_tokens)
+            if prefix_len is None:
+                break  # pool exhausted; retry after frees
+            if prefix_len:
                 req.trace_event("prefix_hit", cached_tokens=prefix_len,
                                 tokens=n)
-                req._chunk_pos = prefix_len
-                req._chunk_base = prefix_len
-            else:
-                pages = self._alloc_pages(n + 1)
-                if pages is None:
-                    break  # pool exhausted; retry after frees
-                self._pc_miss()
-                self.slot_pages[slot] = pages
-                req._chunk_pos = 0
-                req._chunk_base = 0
+            req._chunk_pos = req._chunk_base = prefix_len
             self.pending_prefill.popleft()
             req.stats.prefill_started_at = time.monotonic()
             # The row stays OFF the shared page table until install —
-            # decode steps write through self.page_table and a reserved
+            # decode steps write through cache.page_table and a reserved
             # slot must keep pointing at the trash page meanwhile.
             req._pt_row = kvc.make_page_table_row(
-                self.slot_pages[slot], self.ecfg.max_pages_per_seq
+                c.slot_pages[slot], self.ecfg.max_pages_per_seq
             )[None, :]
             req._prefill_slot = slot
             self.reserved_slots.add(slot)
@@ -1996,7 +1690,7 @@ class ModelRuntime:
             self.chunking.remove(req)
         except ValueError:
             pass
-        self._release_slot_pages(slot)
+        self.cache.release(slot)
         self.reserved_slots.discard(slot)
 
     def step_ragged(self, core: MQCore) -> bool:
@@ -2069,6 +1763,15 @@ class ModelRuntime:
                 if r is not None and i not in self._stalled_slots
                 and i not in ending]
 
+    def _retry_stalled(self, n: int) -> None:
+        """Reservation-holders first: pages may have freed since they
+        stalled — growth by the step's `n` positions puts them back into
+        the batch."""
+        for i in sorted(self._stalled_slots):
+            if self.slot_req[i] is None \
+                    or self.cache.extend(i, int(self.seq_lens[i]) + n):
+                self._stalled_slots.discard(i)
+
     def _reach(self, slot: int) -> int:
         """The slot's length in the longer case of what is unsettled."""
         return int(self.seq_lens[slot]) + int(self._slack[slot])
@@ -2080,16 +1783,17 @@ class ModelRuntime:
         first, which may itself free pages, make the slot's length exact
         — or finish this very slot."""
         need = self._reach(slot) + n
-        if self._extend_pages(self.slot_pages[slot], need):
+        c = self.cache
+        if c.extend(slot, need):
             return
         if self.inflight is not None:
-            req, free = self.slot_req[slot], self.alloc.free_pages
+            req, free = self.slot_req[slot], c.alloc.free_pages
             self.settle_inflight(core)
             if self.slot_req[slot] is not req:
                 return
             exact = self._reach(slot) + n
-            if (self.alloc.free_pages > free or exact < need) \
-                    and self._extend_pages(self.slot_pages[slot], exact):
+            if (c.alloc.free_pages > free or exact < need) \
+                    and c.extend(slot, exact):
                 return  # the settled step's finishes freed the pages
             need = exact
         self._page_exhausted(slot, need, core)
@@ -2143,12 +1847,7 @@ class ModelRuntime:
         # — rejected drafts' pages roll back after the verify — but a
         # draft is dropped, never stalled on, when the pool can't cover
         # it: speculation is an optimization, not a page priority.
-        for i in sorted(self._stalled_slots):
-            if self.slot_req[i] is None:
-                self._stalled_slots.discard(i)
-            elif self._extend_pages(self.slot_pages[i],
-                                    int(self.seq_lens[i]) + 1):
-                self._stalled_slots.discard(i)
+        self._retry_stalled(1)
         spec_plan: Dict[int, List[int]] = {}  # slot -> draft tokens
         self._launch_no += 1
         live = self._live_rows()
@@ -2170,15 +1869,13 @@ class ModelRuntime:
                     continue
                 drafts = self._propose_drafts(r, i)[:max(0, spec_budget)]
             # (Behind an unsettled verify span: pages for the LONGER case.)
-            if drafts and not self._extend_pages(
-                    self.slot_pages[i], self._reach(i) + 1 + len(drafts)):
+            if drafts and not self.cache.extend(
+                    i, self._reach(i) + 1 + len(drafts)):
                 drafts = []  # no headroom to speculate: plain decode row
             if not drafts:
                 self._grow_or_settle(i, 1, core)
             if self.slot_req[i] is not None and i not in self._stalled_slots:
-                self.page_table[i, :] = kvc.make_page_table_row(
-                    self.slot_pages[i], self.ecfg.max_pages_per_seq
-                )
+                self.cache.publish(i)
                 if drafts:
                     spec_plan[i] = drafts
                     spec_budget -= len(drafts)
@@ -2311,7 +2008,7 @@ class ModelRuntime:
                 # step before this one sampled" (the carry).
                 tokens[off] = self.last_tokens[slot]
                 tok_seq[off] = idx
-                row = self.page_table[slot]
+                row = self.cache.page_table[slot]
                 if carry_pos is not None:
                     tok_pos[off] = -2
                     kv_len[idx] = -1
@@ -2335,7 +2032,7 @@ class ModelRuntime:
                 d = len(drafts)
                 tokens[off:off + d + 1] = [self.last_tokens[slot]] + drafts
                 tok_seq[off:off + d + 1] = idx
-                row = self.page_table[slot]
+                row = self.cache.page_table[slot]
                 if carry_pos is not None:
                     tok_pos[off:off + d + 1] = carry_pos[:d + 1]
                     kv_len[idx] = -1
@@ -2397,7 +2094,7 @@ class ModelRuntime:
             batch_size=len(rows), tokens=int(T_real),
             occupancy=round(len(rows) / max(1, S), 4),
             pending=(len(self.pending_prefill) + len(self.chunking)),
-            free_pages=self.alloc.free_pages,
+            free_pages=self.cache.alloc.free_pages,
             mode="ragged", padded_tokens=int(T_pad),
             n_decode=n_decode - len(spec_rows),
             n_prefill=n_prefill)
@@ -2428,8 +2125,8 @@ class ModelRuntime:
         # the chip that lies inside one names it.
         _sp.seam("launch")
         try:
-            toks, n_emit, self.kc, self.vc, self.recent, self.last_ids, \
-                self.slot_state = self._dispatch_ragged(T_pad, k_cap, buf)
+            toks, n_emit, *_ = self._took_back(
+                self._dispatch_ragged(T_pad, k_cap, buf))
         except Exception as e:
             # The step before is untouched by this failure: settle it
             # (its ids are good, and the replay below folds them in),
@@ -2470,7 +2167,7 @@ class ModelRuntime:
                     except ValueError:
                         pass
                     self.reserved_slots.discard(slot)
-                    self.page_table[slot, :] = req._pt_row[0]
+                    self.cache.publish(slot)
                     n = len(req.prompt_tokens)
                     self._seat_slot(slot, req, n)
                     self._launched(h, idx, slot, req, n)
@@ -2527,7 +2224,7 @@ class ModelRuntime:
         for kind, slot, req, _cpos, _span in rows:
             if kind == "prefill":
                 if self.slot_req[slot] is req:  # seated by its final span
-                    self._release_slot_pages(slot)
+                    self.cache.release(slot)
                     self._clear_slot(slot)
                 elif req in self.chunking:
                     self._drop_chunking(req, slot)
@@ -2547,14 +2244,10 @@ class ModelRuntime:
                 # postmortem) must see the seat change hands.
                 self._jrec("preempt", r, slot=slot, why="dispatch_fault",
                            n=r.retries + 1,
-                           free_pages=self.alloc.free_pages)
+                           free_pages=self.cache.alloc.free_pages)
                 replay = r.prompt_tokens + r.generated_ids[r._replay_gen:]
                 written = len(replay) - 1 if r.generated_ids else len(replay)
-                r.prompt_tokens = replay[:written]
-                self._release_slot_pages(slot, r if written else None)
-                r.prompt_tokens = replay
-                r._replay_gen = len(r.generated_ids)
-                self._clear_slot(slot)
+                self._release_for_replay(slot, r, replay, written)
                 if desync or not self._retry_requeue(
                         r, self.pending_prefill, msg):
                     core.mark_dropped(r.user)
@@ -2590,14 +2283,7 @@ class ModelRuntime:
         # and rides the handle. Early returns and faulted dispatches
         # abandon it.
         _sp = stepprof.PROFILER.start("decode", self.loop_clock)
-        # Reservation-holders first: pages may have freed since they
-        # stalled — growth success puts them back into the batch.
-        for i in sorted(self._stalled_slots):
-            if self.slot_req[i] is None:
-                self._stalled_slots.discard(i)
-            elif self._extend_pages(self.slot_pages[i],
-                                    int(self.seq_lens[i]) + k_steps):
-                self._stalled_slots.discard(i)
+        self._retry_stalled(k_steps)
         # Ensure page headroom for k_steps new tokens per active slot.
         for i in self._live_rows():
             if self.slot_req[i] is None:
@@ -2606,9 +2292,7 @@ class ModelRuntime:
             # reservation, or error explicitly (kv_exhausted).
             self._grow_or_settle(i, k_steps, core)
             if self.slot_req[i] is not None and i not in self._stalled_slots:
-                self.page_table[i, :] = kvc.make_page_table_row(
-                    self.slot_pages[i], self.ecfg.max_pages_per_seq
-                )
+                self.cache.publish(i)
         prev = self._settle_before_compile(
             self._decode_jits,
             (k_steps, sampling_flags(self.temp, self.top_k, self.top_p,
@@ -2641,7 +2325,7 @@ class ModelRuntime:
         tokens[:] = self.last_tokens
         positions[:] = self.seq_lens  # position of the incoming token
         active_mask[active] = 1
-        pt[:] = self.page_table
+        pt[:] = self.cache.page_table
         temp[:], top_k[:], top_p[:] = self.temp, self.top_k, self.top_p
         pen[:], pres[:], freq[:] = self.rep_pen, self.pres_pen, self.freq_pen
         seeds[:], rng[0] = self.seeds, self._next_rng()
@@ -2657,8 +2341,7 @@ class ModelRuntime:
                          float(np.mean(self.seq_lens[active])))
         self._h2d = [0, 0]
         _sp.seam("launch")
-        toks, self.kc, self.vc, self.recent, self.last_ids, \
-            self.slot_state = self._dispatch_decode(k_steps, buf)
+        toks, *_ = self._took_back(self._dispatch_decode(k_steps, buf))
         h.futures(toks)
         _sp.seam("note")
         self._queued(h)
@@ -2853,8 +2536,10 @@ class ModelRuntime:
                         keep = (int(self._claim[slot])
                                 if self._claim_no[slot] > h.no
                                 else self._reach(slot) + 1)
-                        spec_pages += self._rollback_spec(
-                            slot, req, max(ctx0 - 1 + span, keep), keep)
+                        self.spec_rollbacks += 1
+                        spec_pages += self.cache.rollback(
+                            slot, req, max(ctx0 - 1 + span, keep), keep,
+                            source=self.proposer)
         _sp.note(stream_items=self._stream_items,
                  stream_wakeups=woken.wakeups)
         if self.mtp:
@@ -2868,9 +2553,10 @@ class ModelRuntime:
         S = self.ecfg.max_slots
         self._tm_occupancy.set(
             sum(r is not None for r in self.slot_req) / max(1, S))
-        self._tm_pages.set(self.alloc.used_pages)
+        alloc = self.cache.alloc
+        self._tm_pages.set(alloc.used_pages)
         self._tm_page_util.set(
-            self.alloc.used_pages / max(1, self.alloc.num_pages - 1))
+            alloc.used_pages / max(1, alloc.num_pages - 1))
         # MFU over EVERY real token the step processed (prefill spans do
         # the same per-token matmuls as decode rows). _orig_cfg, not
         # self.cfg: replicated-group KV inflates kv_dim as a layout
@@ -2965,8 +2651,8 @@ class ModelRuntime:
             "active_slots": self.active_count(),
             "max_slots": self.ecfg.max_slots,
             "pending_prefill": len(self.pending_prefill),
-            "pages_used": self.alloc.used_pages,
-            "pages_total": self.alloc.num_pages - 1,
+            "pages_used": self.cache.alloc.used_pages,
+            "pages_total": self.cache.alloc.num_pages - 1,
             "step_latency_ms": round(self.step_latency_ms, 3),
             "step_p50_ms": pctl(self.step_window, 0.50),
             "step_p99_ms": pctl(self.step_window, 0.99),
@@ -2989,8 +2675,9 @@ class ModelRuntime:
             "weight_stacks_relaid": self.weight_stacks_relaid,
             "devices": self.devices,
             # None = caching disabled (the TUI renders "cache n/a").
-            "prefix_cache": (self.prefix_cache.stats()
-                             if self.prefix_cache is not None else None),
+            "prefix_cache": (self.cache.prefix_cache.stats()
+                             if self.cache.prefix_cache is not None
+                             else None),
             # None = speculation disabled on this runtime.
             "spec": ({
                 "proposed": self.spec_proposed,
@@ -3625,7 +3312,7 @@ class TPUEngine:
         reps = rt.replicas if isinstance(rt, ReplicaSet) else [rt]
         best = 0
         for rep in reps:
-            pc = getattr(rep, "prefix_cache", None)
+            pc = _prefix_cache(rep)
             if pc is None:
                 continue
             try:
@@ -3771,43 +3458,30 @@ class TPUEngine:
 
     def export_prefix(self, model: str, tokens) -> Optional[dict]:
         """Affinity-miss prefix shipping, source side (router seam)."""
-        def _do():
-            rt = self.resolve_runtime(model)
-            if rt is None:
-                return None
-            reps = rt.replicas if isinstance(rt, ReplicaSet) else [rt]
-            for rep in reps:
-                fn = getattr(rep, "export_prefix", None)
-                if fn is not None:
-                    blob = fn(list(tokens))
-                    if blob is not None:
-                        return blob
-            return None
-
-        try:
-            return self.call_on_loop(_do, timeout=10.0)
-        except TimeoutError:
-            return None
+        return self._ship_prefix(model, "export_prefix", list(tokens), None)
 
     def import_prefix(self, model: str, blob: dict) -> int:
         """Affinity-miss prefix shipping, target side: pages adopted."""
+        return self._ship_prefix(model, "import_prefix", blob, 0)
+
+    def _ship_prefix(self, model: str, side: str, arg, nothing):
+        """`side` of the first replica of `model` that answers with anything."""
         def _do():
             rt = self.resolve_runtime(model)
             if rt is None:
-                return 0
+                return nothing
             reps = rt.replicas if isinstance(rt, ReplicaSet) else [rt]
             for rep in reps:
-                fn = getattr(rep, "import_prefix", None)
-                if fn is not None:
-                    n = fn(blob)
-                    if n:
-                        return n
-            return 0
+                fn = getattr(rep, side, None)
+                got = fn(arg) if fn is not None else nothing
+                if got:
+                    return got
+            return nothing
 
         try:
             return self.call_on_loop(_do, timeout=10.0)
         except TimeoutError:
-            return 0
+            return nothing
 
     def _count_shed(self, reason: str) -> None:
         tm.SHED_TOTAL.labels(reason=reason).inc()
@@ -4352,8 +4026,8 @@ class TPUEngine:
         # before the replacement loads, or a large model could never
         # recover (params + KV would be resident twice).
         rt.params = None
-        if hasattr(rt, "kc"):
-            rt.kc = rt.vc = None
+        if getattr(rt, "cache", None) is not None:
+            rt.cache.drop()
         self._failed_runtimes.append(rt)
 
     def _loop(self) -> None:
@@ -4393,11 +4067,9 @@ class TPUEngine:
                      "kv_bytes": int(getattr(rt, "kv_bytes", 0)),
                      "slot_state_bytes": sum(
                          getattr(rt, "state_bytes", {}).values())}
-            alloc = getattr(rt, "alloc", None)
-            if alloc is not None:
-                entry.update(free=alloc.free_pages, used=alloc.used_pages,
-                             cached=alloc.cached_pages,
-                             pool=alloc.num_pages - 1)
+            cache = getattr(rt, "cache", None)
+            if cache is not None:
+                entry.update(cache.page_state())
             models[name] = entry
         stepprof.PROFILER.hbm_record({"models": models})
 
@@ -4634,7 +4306,7 @@ class TPUEngine:
             if isinstance(rt, ModelRuntime):
                 for i, req in enumerate(rt.slot_req):
                     if req is not None:
-                        rt._release_slot_pages(i)
+                        rt.cache.release(i)
                         rt.seq_lens[i] = 0
                         rt.slot_req[i] = None
                         self._retry_or_error(req, msg, replay=True)
@@ -4650,7 +4322,7 @@ class TPUEngine:
                     self._retry_or_error(pending.popleft(), msg)
             if hasattr(rt, "reserved_slots"):
                 for slot in list(rt.reserved_slots):
-                    rt._release_slot_pages(slot)
+                    rt.cache.release(slot)
                 rt.reserved_slots.clear()
         except Exception:
             log.exception("error while failing runtime %s", rt.name)
@@ -4673,7 +4345,7 @@ class TPUEngine:
         engine subclass — runtimes without a cache are skipped."""
         models: Dict[str, list] = {}
         for rt in self._step_targets():
-            pc = getattr(rt, "prefix_cache", None)
+            pc = _prefix_cache(rt)
             if pc is not None:
                 models.setdefault(rt.name, []).append(pc.stats())
         merged = {name: merge_prefix_cache_stats(reps)
@@ -4684,14 +4356,11 @@ class TPUEngine:
         """Evict every unreferenced cached page on every runtime. Runs on
         the engine thread: the tree and allocator are engine-loop state."""
         def _do() -> int:
-            freed = 0
-            for rt in self._step_targets():
-                pc = getattr(rt, "prefix_cache", None)
-                if pc is not None:
-                    freed += pc.flush()
-            return freed
+            return sum(pc.flush()
+                       for pc in map(_prefix_cache, self._step_targets())
+                       if pc is not None)
 
-        if not any(getattr(rt, "prefix_cache", None) is not None
+        if not any(_prefix_cache(rt) is not None
                    for rt in self._step_targets()):
             return 0  # nothing to flush (also: FakeEngine's loop has no
             #           call_on_loop drain — don't park on it)

@@ -1,6 +1,6 @@
 """Scheduling policies: the decision seams extracted from the engine.
 
-Three decision points, one interface (ROADMAP item 4; PAPERS.md "Optimal
+Three decision points, one interface (ROADMAP C5 and B-W2; PAPERS.md "Optimal
 Scheduling Algorithms for LLM Inference: Theory and Practice" for the
 SRPT result, UELLM for prediction-driven scheduling):
 

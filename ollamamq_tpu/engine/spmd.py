@@ -566,24 +566,16 @@ class SPMDModelRuntime(ModelRuntime):
         # desync worker replay state. Single-process behaves like
         # ModelRuntime (the fleet CLI already forbids --replicas+--spmd;
         # this guards the bare /admin/migrate surface too).
-        if self._spmd:
-            return None
-        return super().export_request(rid)
+        return None if self._spmd else super().export_request(rid)
 
     def import_request(self, blob, req):
-        if self._spmd:
-            return False
-        return super().import_request(blob, req)
+        return False if self._spmd else super().import_request(blob, req)
 
     def export_prefix(self, tokens):
-        if self._spmd:
-            return None
-        return super().export_prefix(tokens)
+        return None if self._spmd else super().export_prefix(tokens)
 
     def import_prefix(self, blob):
-        if self._spmd:
-            return 0
-        return super().import_prefix(blob)
+        return 0 if self._spmd else super().import_prefix(blob)
 
     def _dispatch_decode(self, k_steps, buf):
         if not self._spmd:
@@ -1052,14 +1044,10 @@ def _replay(rt, op, a, b, payload):
     device output of the replayed computation."""
     if op == OP_DECODE:
         (buf,) = payload
-        toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state = \
-            ModelRuntime._dispatch_decode(rt, a, buf)
-        return (toks, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state)
+        return rt._took_back(ModelRuntime._dispatch_decode(rt, a, buf))
     elif op in (OP_RAGGED, OP_SPEC):
         (buf,) = payload  # a=T_pad, b=k_cap (0 on OP_RAGGED)
-        toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state = \
-            ModelRuntime._dispatch_ragged(rt, a, b, buf)
-        return (toks, n_emit, rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state)
+        return rt._took_back(ModelRuntime._dispatch_ragged(rt, a, b, buf))
     elif op == OP_ENCODE:
         B, bucket = a, b
         tokens, lens = payload

@@ -539,7 +539,7 @@ def ragged_attention_any(
         def kernel(q, kc, vc, layer, page_table, q_start, q_lens, kv_lens,
                    *base):
             kq, vq, scales = _split_quant(kc, vc)
-            if window:  # (no mesh: config.validate_slot_state)
+            if window:  # (no mesh: kv_cache.refusal)
                 scales = dict(scales, window=window, pos_base=base[0])
             if sink is not None:  # (closed over: no mesh either)
                 scales = dict(scales, sink=sink)

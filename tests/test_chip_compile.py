@@ -98,7 +98,7 @@ def test_int8_kv_kernel_compiles_or_is_selected_away(v5e, compile_fn):
     construction must never select it (nothing is discovered at the
     first dispatch). Today Mosaic refuses the [page_size, Hk] f32
     scale-row DMA — slice not aligned to the 128-lane tiling — and
-    select_attn_impl answers jnp (kernel repair: ROADMAP A5)."""
+    select_attn_impl answers jnp (kernel repair: ROADMAP A1)."""
     one = SingleDeviceSharding(v5e.devices[0])
     try:
         compile_fn(_shapes(lambda spec: one, kv_dtype=jnp.int8))

@@ -21,7 +21,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, MODEL_CONFIGS, EngineConfig,
-                                 ModelConfig, validate_latent_pool)
+                                 ModelConfig)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.engine import kv_cache as kvc
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops.sampling import SamplingParams
@@ -130,7 +131,8 @@ def test_the_tiny_family_its_plan_its_pools_and_its_counts():
     kc, vc = pools()
     assert kc.shape == (3, NP * PS, 128) and vc.shape == (3, NP * PS, 16)
     ecfg = EngineConfig(num_pages=NP, page_size=PS)
-    assert kvc.kv_pool_bytes(DS, ecfg, 4) == kc.nbytes + vc.nbytes
+    assert ecfg.num_pages * kvc.kv_page_bytes(DS, ecfg.page_size, 4) \
+        == kc.nbytes + vc.nbytes
     assert kvc.kv_page_bytes(DS, PS) == 3 * PS * (128 + 16) * 2
     params = make_params()
     leaves = jax.tree_util.tree_leaves(params)
@@ -392,9 +394,9 @@ def test_the_engine_serves_it_and_counts_what_the_selection_did(monkeypatch):
     settled, _ = drive(_engine(NAME), _arrivals(), False, monkeypatch)
     assert got == settled
     rt = _rt(eng)
-    assert rt.kc.shape[-1] == 128 and rt.vc.shape[-1] == 16
-    assert rt.kv_bytes == rt.kc.nbytes + rt.vc.nbytes
-    assert rt.prefix_cache is None and rt.export_request(1) is None
+    assert rt.cache.kc.shape[-1] == 128 and rt.cache.vc.shape[-1] == 16
+    assert rt.kv_bytes == rt.cache.kc.nbytes + rt.cache.vc.nbytes
+    assert rt.cache.prefix_cache is None and rt.export_request(1) is None
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     for s in samples:
         assert s["mla_rows"] >= 1
@@ -421,11 +423,11 @@ def test_the_engine_serves_it_and_counts_what_the_selection_did(monkeypatch):
 ], ids=["kv_int8", "w_int8", "prefix_cache", "tp", "ep"])
 def test_what_the_latent_pools_cannot_do_yet_is_refused_by_one_line(kw,
                                                                     match):
-    err = validate_latent_pool(DS, **kw)
+    err = refusal(DS, **kw)
     assert err is not None and match in err and "\n" not in err
     assert err.startswith(f"model {NAME} has latent attention")
-    assert validate_latent_pool(MODEL_CONFIGS["test-tiny"], **kw) is None
-    assert validate_latent_pool(DS) is None
+    assert refusal(MODEL_CONFIGS["test-tiny"], **kw) is None
+    assert refusal(DS) is None
 
 
 @pytest.mark.parametrize("over,match", [

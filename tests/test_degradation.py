@@ -133,9 +133,9 @@ def test_preemption_round_trip_byte_identical(prefix_cache):
         if prefix_cache:
             # The replay re-admission walks the tree seeded by the
             # preemption's page insert: recompute is mostly cached.
-            assert rt.prefix_cache.stats()["hits"] >= 1
+            assert rt.cache.prefix_cache.stats()["hits"] >= 1
         # Invariant: no page leaked across preempt/replay.
-        assert rt.alloc.used_pages == 0
+        assert rt.cache.alloc.used_pages == 0
     finally:
         eng.stop()
     assert items[-1].kind == "done", items[-1].error

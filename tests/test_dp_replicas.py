@@ -211,7 +211,7 @@ def test_cancel_reaches_replica_held_request(dp_engine):
     rs = dp_engine.runtimes["test-tiny-gqa"]
     for rt in rs.replicas:
         rt.tokenizer.eos_id = -1  # keep generating until cancelled
-    free_before = [rt.alloc.free_pages for rt in rs.replicas]
+    free_before = [rt.cache.alloc.free_pages for rt in rs.replicas]
     tok = rs.tokenizer
     rid = dp_engine.core.enqueue("dp-cancel", "", "test-tiny-gqa")
     req = Request(rid, "dp-cancel", "test-tiny-gqa", tok.encode("cancel me"),
@@ -225,10 +225,10 @@ def test_cancel_reaches_replica_held_request(dp_engine):
     items = collect(req)
     assert items[-1].finish_reason == FinishReason.CANCELLED
     deadline = time.monotonic() + 10
-    while ([rt.alloc.free_pages for rt in rs.replicas] != free_before
+    while ([rt.cache.alloc.free_pages for rt in rs.replicas] != free_before
            and time.monotonic() < deadline):
         time.sleep(0.01)
-    assert [rt.alloc.free_pages for rt in rs.replicas] == free_before
+    assert [rt.cache.alloc.free_pages for rt in rs.replicas] == free_before
     for rt in rs.replicas:
         rt.tokenizer.eos_id = 2  # restore for other tests
 

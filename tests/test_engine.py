@@ -91,7 +91,7 @@ def test_cancellation_reclaims_pages():
     try:
         rt = eng.runtimes["test-tiny"]
         rt.tokenizer.eos_id = -1  # never sample EOS: keep the seq running
-        free_before = rt.alloc.free_pages
+        free_before = rt.cache.alloc.free_pages
         tok = rt.tokenizer
         rid = eng.core.enqueue("canceller", "", "test-tiny")
         req = Request(rid, "canceller", "test-tiny", tok.encode("to be cancelled"),
@@ -106,9 +106,9 @@ def test_cancellation_reclaims_pages():
         items = collect(req)
         assert items[-1].finish_reason == FinishReason.CANCELLED
         deadline = time.monotonic() + 10
-        while rt.alloc.free_pages < free_before and time.monotonic() < deadline:
+        while rt.cache.alloc.free_pages < free_before and time.monotonic() < deadline:
             time.sleep(0.01)
-        assert rt.alloc.free_pages == free_before  # KV pages reclaimed
+        assert rt.cache.alloc.free_pages == free_before  # KV pages reclaimed
         snap = eng.core.snapshot()
         assert snap["users"]["canceller"]["dropped"] >= 1
     finally:
@@ -129,7 +129,7 @@ def test_late_block_drops_queued_and_midgen():
     try:
         rt = eng.runtimes["test-tiny"]
         rt.tokenizer.eos_id = -1  # keep the mid-gen sequence running
-        free_before = rt.alloc.free_pages
+        free_before = rt.cache.alloc.free_pages
         tok = rt.tokenizer
         rid1 = eng.core.enqueue("mallory", "", "test-tiny")
         r1 = Request(rid1, "mallory", "test-tiny", tok.encode("one"),
@@ -151,9 +151,9 @@ def test_late_block_drops_queued_and_midgen():
         assert i1[-1].finish_reason == FinishReason.CANCELLED
         assert i2[-1].finish_reason == FinishReason.CANCELLED
         deadline = time.monotonic() + 10
-        while rt.alloc.free_pages < free_before and time.monotonic() < deadline:
+        while rt.cache.alloc.free_pages < free_before and time.monotonic() < deadline:
             time.sleep(0.01)
-        assert rt.alloc.free_pages == free_before  # KV pages reclaimed
+        assert rt.cache.alloc.free_pages == free_before  # KV pages reclaimed
         snap = eng.core.snapshot()
         assert snap["users"]["mallory"]["dropped"] >= 2
         assert snap["users"]["mallory"]["queued"] == 0
@@ -456,14 +456,14 @@ def test_oversized_prompt_rejected_cleanly(engine):
     """A prompt over max_context must error its own request only — no page
     leak, no collateral damage to other requests (code-review regression)."""
     rt = engine.runtimes["test-tiny"]
-    free_before = rt.alloc.free_pages
+    free_before = rt.cache.alloc.free_pages
     # 200 tokens: fits the shared engine's largest bucket (64)? No — but use
     # a prompt that fits the bucket yet exceeds max_context if possible;
     # here max_context=128 > bucket 64, so the bucket check fires. Both
     # paths must produce a clean ERROR.
     items, req = run_request(engine, prompt="y" * 300)
     assert items[-1].kind == "error"
-    assert rt.alloc.free_pages == free_before
+    assert rt.cache.alloc.free_pages == free_before
     # Engine still serves new work afterwards.
     items2, _ = run_request(engine, prompt="ok", max_tokens=3)
     assert items2[-1].kind == "done"
@@ -576,7 +576,7 @@ def test_cancel_during_chunked_prefill():
     try:
         rt = eng.runtimes["test-tiny"]
         tok = rt.tokenizer
-        free_before = rt.alloc.free_pages
+        free_before = rt.cache.alloc.free_pages
         req = eng.enqueue_request("c", "", "test-tiny",
                                   prompt_tokens=tok.encode("w" * 200),
                                   sampling=SamplingParams(max_tokens=3))
@@ -588,9 +588,9 @@ def test_cancel_during_chunked_prefill():
         items = collect(req)
         assert items[-1].finish_reason in (FinishReason.CANCELLED,)
         deadline = time.monotonic() + 10
-        while rt.alloc.free_pages < free_before and time.monotonic() < deadline:
+        while rt.cache.alloc.free_pages < free_before and time.monotonic() < deadline:
             time.sleep(0.01)
-        assert rt.alloc.free_pages == free_before
+        assert rt.cache.alloc.free_pages == free_before
         assert not rt.reserved_slots
     finally:
         eng.stop()
@@ -657,7 +657,7 @@ def test_kv_pool_pressure_waits_and_recovers():
             done += 1
         assert done == 8
         rt = eng.runtimes["test-tiny"]
-        assert rt.alloc.used_pages == 0  # everything reclaimed
+        assert rt.cache.alloc.used_pages == 0  # everything reclaimed
         snap = eng.core.snapshot()
         assert all(snap["users"][f"p{i}"]["processed"] == 1 for i in range(8))
     finally:

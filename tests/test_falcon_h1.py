@@ -28,7 +28,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (MODEL_CONFIGS, PARALLEL, ModelConfig,
-                                 validate_quant_config, validate_slot_state)
+                                 validate_quant_config)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops import gated_delta as gd
 from ollamamq_tpu.ops import ssd
@@ -449,11 +450,11 @@ def test_attention_bias_reaches_the_program(params):
     (dict(prefix_cache=True), "--prefix-cache: a cached page"),
 ], ids=["spec", "tp", "ep", "int8", "prefix_cache"])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
-    err = validate_slot_state(FALCON, **kw)
+    err = refusal(FALCON, **kw)
     assert err and match in err and NAME in err and "ROADMAP B-M5" in err
     assert f"{PARALLEL} layers (layer_types)" in err
-    assert validate_slot_state(MODEL_CONFIGS["test-tiny"], **kw) is None
-    assert validate_slot_state(FALCON, mesh_shape={"data": 2}) is None
+    assert refusal(MODEL_CONFIGS["test-tiny"], **kw) is None
+    assert refusal(FALCON, mesh_shape={"data": 2}) is None
 
 
 # ------------------------------------------------- the engine, by id stream
@@ -475,10 +476,10 @@ def test_overlapped_against_serial_gives_the_same_ids(falcon, monkeypatch):
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(falcon)
     n = FALCON.num_layers
-    assert rt.slot_state.ssm is not None
-    assert rt.slot_state.conv.shape == (n, 3, 4, FALCON.ssm_conv_dim)
-    assert rt.slot_state.ssm.shape == (n, 5, DS, H * DH)
-    assert rt.kc.shape[0] == n  # the SAME layers' K and V, in the pool
+    assert rt.cache.slot_state.ssm is not None
+    assert rt.cache.slot_state.conv.shape == (n, 3, 4, FALCON.ssm_conv_dim)
+    assert rt.cache.slot_state.ssm.shape == (n, 5, DS, H * DH)
+    assert rt.cache.kc.shape[0] == n  # the SAME layers' K and V, in the pool
     held = rt.state_bytes
     assert held["ssm_state_bytes"] == n * 5 * DS * H * DH * 4
     assert rt.stats()["ssm_state_bytes"] == held["ssm_state_bytes"]
@@ -507,7 +508,7 @@ def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(falcon, monkeypatch):
     first = (0, "first", _prompt(2, 37), SamplingParams(max_tokens=11))
     drive(eng, [first], False, monkeypatch)
     rt = _rt(eng)
-    assert np.abs(np.asarray(rt.slot_state.ssm[:, 0])).max() > 0
+    assert np.abs(np.asarray(rt.cache.slot_state.ssm[:, 0])).max() > 0
     reused, _ = drive(eng, [probe], False, monkeypatch)
     assert reused["probe"] == fresh["probe"]
     assert len(reused["probe"][0]) == 12

@@ -26,7 +26,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, EXPERTS, MODEL_CONFIGS, WINDOW,
-                                 EngineConfig, validate_slot_state)
+                                 EngineConfig)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.models import llama, moe
 import test_lfm2
 from test_lfm2 import close, seq_tokens
@@ -201,10 +202,10 @@ def test_the_published_lists_that_agree_are_taken():
 def test_what_knows_only_pages_is_refused_with_a_window_layer(kw, match):
     import re
 
-    err = validate_slot_state(KX, **kw)
+    err = refusal(KX, **kw)
     assert err and re.search(match, err) and "sliding_attention" in err
-    assert validate_slot_state(KX, mesh_shape={"data": 2}) is None
-    assert validate_slot_state(MODEL_CONFIGS["test-tiny"], **kw) is None
+    assert refusal(KX, mesh_shape={"data": 2}) is None
+    assert refusal(MODEL_CONFIGS["test-tiny"], **kw) is None
     from ollamamq_tpu.engine.engine import ModelRuntime
 
     if "spec" in kw or "kv_dtype" in kw:

@@ -30,8 +30,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, EXPERTS, LINEAR, MODEL_CONFIGS,
-                                 ModelConfig, validate_latent_pool,
-                                 validate_slot_state)
+                                 ModelConfig)
+from ollamamq_tpu.engine.kv_cache import refusal, state_held
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops import gated_delta as gd
 from ollamamq_tpu.ops.sampling import SamplingParams
@@ -215,29 +215,31 @@ def test_a_stack_the_program_cannot_run_is_refused_with_key_and_value(case):
 
 
 SERVED_WITHOUT = {
-    "spec": (validate_slot_state, dict(spec=True),
+    "spec": (dict(spec=True),
              "--spec: a rejected draft has already advanced"),
-    "tp": (validate_slot_state, dict(mesh_shape={"tensor": 4}),
+    # (the state's line is the first a start meets; the pools' follows it)
+    "tp": (dict(mesh_shape={"tensor": 4}),
            "--tp / --ep: the linear_attention layers' weights and state"),
-    "ep": (validate_latent_pool, dict(mesh_shape={"expert": 4}),
-           "--tp / --ep: the latent and index-key pools"),
-    "kv_int8": (validate_latent_pool, dict(kv_dtype="int8"),
+    "ep": (dict(mesh_shape={"expert": 4}),
+           "--tp / --ep: the linear_attention layers' weights and state"),
+    "kv_int8": (dict(kv_dtype="int8"),
                 "--kv-dtype int8: the page writer's scales"),
-    "weights_int8": (validate_latent_pool, dict(weights_dtype="int8"),
+    "weights_int8": (dict(weights_dtype="int8"),
                      "--weights-dtype int8: the low-rank projections"),
-    "prefix_cache": (validate_latent_pool, dict(prefix_cache=True),
+    "prefix_cache": (dict(prefix_cache=True),
                      "--prefix-cache: the radix tree shares K and V pages"),
 }
 
 
 @pytest.mark.parametrize("flag", sorted(SERVED_WITHOUT))
 def test_a_flag_that_knows_neither_pool_nor_state_is_one_line(flag):
-    """Both validators apply to this stack, told before any device work: a
-    string, one line, naming the model and the flag."""
-    validate, kw, match = SERVED_WITHOUT[flag]
-    why = validate(KL, **kw)
+    """Both kinds of state apply to this stack, told before any device work:
+    a string, one line, naming the model and the flag."""
+    kw, match = SERVED_WITHOUT[flag]
+    why = refusal(KL, **kw)
     assert why and "\n" not in why and NAME in why and match in why
-    assert validate_slot_state(KL) is None is validate_latent_pool(KL)
+    assert refusal(KL) is None
+    assert state_held(KL) == ["conv / linear", "latent"]
 
 
 @pytest.mark.parametrize("flags,match", [
@@ -517,11 +519,12 @@ def test_the_engine_serves_it_and_counts_both_kinds(engine, monkeypatch):
     assert all(len(ids[0]) == 9 + 2 * i
                for i, ids in enumerate(got[f"u{i}"] for i in range(5)))
     rt = _rt(engine)
-    assert rt.kc.shape[0] == 2 and rt.kc.shape[-1] == KL.latent_lanes
-    assert rt.vc.shape[-1] == 0
-    assert rt.slot_state.rule.shape == (6, 5, 8, 4 * 8)
-    assert rt.slot_state.conv.shape == (6, 3, 4, 3 * 32)
-    assert rt.prefix_cache is None
+    assert rt.cache.kc.shape[0] == 2
+    assert rt.cache.kc.shape[-1] == KL.latent_lanes
+    assert rt.cache.vc.shape[-1] == 0
+    assert rt.cache.slot_state.rule.shape == (6, 5, 8, 4 * 8)
+    assert rt.cache.slot_state.conv.shape == (6, 3, 4, 3 * 32)
+    assert rt.cache.prefix_cache is None
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     for s in samples:
         assert s["mla_pairs"] >= s["mla_ctx_rows"] >= 1

@@ -24,7 +24,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, CONV, EXPERTS, MODEL_CONFIGS,
-                                 validate_slot_state, validate_quant_config)
+                                 validate_quant_config)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops import shortconv
 from ollamamq_tpu.ops.sampling import SamplingParams
@@ -454,7 +455,7 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert piped == settled
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(hybrid)
-    assert rt.slot_state.conv.shape == (6, 2, 4, 64)
+    assert rt.cache.slot_state.conv.shape == (6, 2, 4, 64)
     assert rt.state_bytes["conv_state_bytes"] > 0
     # every launched step says what it did with the conv state, and
     # uploads ONE packed array
@@ -484,7 +485,7 @@ def reused_slot(make_engine, holds_state, monkeypatch):
 
 def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
     def holds_state(rt):  # slot 0 holds its state
-        assert np.abs(np.asarray(rt.slot_state.conv)[:, 0]).max() > 0
+        assert np.abs(np.asarray(rt.cache.slot_state.conv)[:, 0]).max() > 0
 
     reused_slot(_lfm2_engine, holds_state, monkeypatch)
 
@@ -500,7 +501,7 @@ def preempted_and_replayed(unfaulted, make_engine, resets, monkeypatch):
     plan = FaultPlan([{"site": "extend", "kind": "alloc_fail", "at": [2]}])
     eng = make_engine(plan=plan, prefix_cache=True)
     rt = _rt(eng)
-    assert rt.prefix_cache is None
+    assert rt.cache.prefix_cache is None
     got, samples = drive(eng, arr, False, monkeypatch)
     assert rt.preempt_count >= 1
     assert got == base and len(got["victim"][0]) == 14
@@ -530,11 +531,11 @@ def test_a_voided_step_leaves_nothing_a_later_request_can_see(hybrid,
     (dict(mesh_shape={"expert": 2}), "--tp / --ep: the conv layers"),
 ], ids=["spec", "tp", "ep"])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
-    err = validate_slot_state(LFM2, **kw)
+    err = refusal(LFM2, **kw)
     assert err and match in err and "test-tiny-lfm2" in err
     # ...and a model without conv layers is not asked
-    assert validate_slot_state(MODEL_CONFIGS["test-tiny-moe"], **kw) is None
-    assert validate_slot_state(LFM2, mesh_shape={"data": 2}) is None
+    assert refusal(MODEL_CONFIGS["test-tiny-moe"], **kw) is None
+    assert refusal(LFM2, mesh_shape={"data": 2}) is None
 
 
 def test_the_runtime_refuses_them_at_construction():

@@ -31,7 +31,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, EXPERTS, MODEL_CONFIGS, WINDOW,
-                                 ModelConfig, validate_slot_state)
+                                 ModelConfig)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.engine.step_work import KINDS, StepWork
 from ollamamq_tpu.models import llama, moe
 from ollamamq_tpu.ops import attention
@@ -206,7 +207,7 @@ def test_the_configuration_file_reaches_the_program_key_by_key():
                       (dict(mesh_shape={"tensor": 2}), "--tp / --ep"),
                       (dict(mesh_shape={"expert": 2}), "--tp / --ep"),
                       (dict(kv_dtype="int8"), "--kv-dtype int8")):
-        err = validate_slot_state(mc, **kw)
+        err = refusal(mc, **kw)
         assert err and match in err and "B-M2" in err, err
 
 

@@ -27,8 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ollamamq_tpu.config import (LINEAR, MODEL_CONFIGS, SPARSE, ModelConfig,
-                                 validate_slot_state)
+from ollamamq_tpu.config import (LINEAR, MODEL_CONFIGS, SPARSE, ModelConfig)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.engine import step_work
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops import block_select as bs
@@ -148,9 +148,9 @@ def test_a_stack_the_program_cannot_run_is_refused_at_construction(edit, match):
     (dict(prefix_cache=True), "--prefix-cache: a cached page"),
 ], ids=["spec", "tp", "ep", "int8", "prefix_cache"])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
-    err = validate_slot_state(SALA, **kw)
+    err = refusal(SALA, **kw)
     assert err and match in err and NAME in err and "ROADMAP B-M10" in err
-    assert validate_slot_state(SALA, mesh_shape={"data": 2}) is None
+    assert refusal(SALA, mesh_shape={"data": 2}) is None
     with pytest.raises(ValueError, match="has no form for sparse_attention"):
         llama.forward_prefill(None, SALA, jnp.zeros((1, 4), jnp.int32), None,
                               None, None, None, PS)
@@ -518,9 +518,9 @@ def test_overlapped_against_serial_gives_the_same_ids(sala, monkeypatch):
     assert piped == settled
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(sala)
-    assert rt.slot_state.rule.shape == (3, 5, 16, 128)
-    assert rt.slot_state.pooled.shape == (3, 96 * 2, SALA.kv_dim)
-    assert rt.kc.shape[0] == 3
+    assert rt.cache.slot_state.rule.shape == (3, 5, 16, 128)
+    assert rt.cache.slot_state.pooled.shape == (3, 96 * 2, SALA.kv_dim)
+    assert rt.cache.kc.shape[0] == 3
     held = rt.state_bytes
     assert held["lin_state_bytes"] == 3 * 5 * 16 * 128 * 4
     assert held["bsa_pooled_bytes"] == 3 * 192 * SALA.kv_dim * 4

@@ -16,8 +16,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, LINEAR, MODEL_CONFIGS,
-                                 validate_quant_config,
-                                 validate_slot_state)
+                                 validate_quant_config)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops import gated_delta as gd
 from test_lfm2 import (ATOL, B, NP, PS, _arrivals, bfloat16_misses, close,
@@ -627,9 +627,9 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(hybrid)
     # the state is a pytree of two arrays: the window and the rule's
-    assert rt.slot_state.conv.shape == (8, 3, 4, 2 * 32 + 64)
-    assert rt.slot_state.rule.shape == (8, 5, DK, H * DV)
-    assert rt.slot_state.rule.dtype == jnp.float32
+    assert rt.cache.slot_state.conv.shape == (8, 3, 4, 2 * 32 + 64)
+    assert rt.cache.slot_state.rule.shape == (8, 5, DK, H * DV)
+    assert rt.cache.slot_state.rule.dtype == jnp.float32
     assert rt.state_bytes["lin_state_bytes"] == 8 * 5 * DK * H * DV * 4
     assert rt.stats()["lin_state_bytes"] == 8 * 5 * DK * H * DV * 4
     # every launched step says what it did with the state, and uploads ONE
@@ -650,7 +650,7 @@ def test_overlapped_against_serial_gives_the_same_ids(hybrid, monkeypatch):
 
 def test_a_reused_slot_gives_the_ids_a_fresh_engine_gives(monkeypatch):
     def holds_state(rt):  # slot 0 holds its state
-        for left in jax.tree_util.tree_leaves(rt.slot_state):
+        for left in jax.tree_util.tree_leaves(rt.cache.slot_state):
             assert np.abs(np.asarray(left)[:, 0]).max() > 0
 
     reused_slot(_olmo_engine, holds_state, monkeypatch)
@@ -670,11 +670,11 @@ def test_preempt_and_replay_gives_the_same_ids(hybrid, monkeypatch):
      "--tp / --ep: the linear_attention layers"),
 ], ids=["spec", "tp", "ep"])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
-    err = validate_slot_state(OLMO, **kw)
+    err = refusal(OLMO, **kw)
     assert err and match in err and NAME in err
     assert "linear_attention layers (layer_types)" in err
-    assert validate_slot_state(MODEL_CONFIGS["test-tiny"], **kw) is None
-    assert validate_slot_state(OLMO, mesh_shape={"data": 2}) is None
+    assert refusal(MODEL_CONFIGS["test-tiny"], **kw) is None
+    assert refusal(OLMO, mesh_shape={"data": 2}) is None
 
 
 def test_the_runtime_refuses_them_at_construction(caplog):
@@ -689,7 +689,7 @@ def test_the_runtime_refuses_them_at_construction(caplog):
         weights.quantize_params_int8(make_params(OLMO), OLMO)
     with caplog.at_level("WARNING"):
         rt = _rt(_olmo_engine(prefix_cache=True))
-    assert rt.prefix_cache is None
+    assert rt.cache.prefix_cache is None
     assert any("prefix cache off" in r.message and NAME in r.message
                and "linear_attention" in r.message for r in caplog.records)
 

@@ -25,8 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ollamamq_tpu.config import (ATTENTION, MODEL_CONFIGS, EngineConfig,
-                                 validate_latent_pool, validate_slot_state)
+from ollamamq_tpu.config import (ATTENTION, MODEL_CONFIGS, EngineConfig)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.engine import kv_cache as kvc
 from ollamamq_tpu.engine.engine import ModelRuntime
 from ollamamq_tpu.models import llama, moe
@@ -142,7 +142,8 @@ def test_the_tiny_family_its_plan_its_pool_and_its_counts():
     # the module's block has its own rows; no indexer, no second pool
     assert kc.shape == (4, NP * PS, 128) and vc.shape == (4, NP * PS, 0)
     ecfg = EngineConfig(num_pages=NP, page_size=PS)
-    assert kvc.kv_pool_bytes(PG, ecfg, 4) == kc.nbytes and vc.nbytes == 0
+    assert ecfg.num_pages * kvc.kv_page_bytes(PG, ecfg.page_size, 4) \
+        == kc.nbytes and vc.nbytes == 0
     params = make_params()
     n = sum(a.size for a in jax.tree_util.tree_leaves(params))
     ref, keys = openpangu_reference(), openpangu_keys(PG)
@@ -621,9 +622,9 @@ class SimpleReq:
     ("test-tiny-olmo-hybrid", "--spec: a rejected draft has already"),
 ], ids=["conv", "recurrent"])
 def test_spec_with_per_sequence_state_is_still_refused(model, match):
-    err = validate_slot_state(MODEL_CONFIGS[model], spec=True)
+    err = refusal(MODEL_CONFIGS[model], spec=True)
     assert err is not None and match in err and "\n" not in err
-    assert validate_slot_state(PG, spec=True) is None
+    assert refusal(PG, spec=True) is None
     with pytest.raises(ValueError, match="--spec"):
         _engine(model, spec=True)
 
@@ -634,16 +635,16 @@ def test_spec_with_per_sequence_state_is_still_refused(model, match):
     (dict(mesh_shape={"tensor": 2}), "--tp / --ep: the latent"),
 ], ids=["kv_int8", "prefix_cache", "tp"])
 def test_what_the_latent_pool_cannot_do_yet_is_still_refused(kw, match):
-    err = validate_latent_pool(PG, **kw)
+    err = refusal(PG, **kw)
     assert err is not None and match in err and "\n" not in err
-    assert validate_latent_pool(PG) is None
+    assert refusal(PG) is None
 
 
 def test_without_spec_the_module_is_held_and_not_run(monkeypatch):
     eng = _engine(NAME)
     rt = _rt(eng)
     assert not rt.mtp and rt.draft_ids is None and rt.may_overlap()
-    assert rt.kc.shape[0] == 4  # its rows are there all the same
+    assert rt.cache.kc.shape[0] == 4  # its rows are there all the same
     assert "mtp_eh_proj" in rt.params
 
 
@@ -697,8 +698,8 @@ def test_the_modules_scopes_are_in_the_lowered_step_and_in_the_readme(
         fn = rt._get_ragged_jit(16, rt.spec_k if rt.mtp else 0,
                                 (False, False, False))
         lay = rt.dims.ragged_layout(16)
-        args = (rt.params, jnp.zeros((lay.size,), jnp.int32), rt.kc, rt.vc,
-                rt.recent, rt.last_ids, rt.slot_state)
+        args = (rt.params, jnp.zeros((lay.size,), jnp.int32), rt.cache.kc, rt.cache.vc,
+                rt.recent, rt.last_ids, rt.cache.slot_state)
         args += (rt.draft_ids, rt.len_ids) if rt.mtp else ()
         return fn.lower(*jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)

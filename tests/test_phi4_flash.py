@@ -28,8 +28,8 @@ import numpy as np
 import pytest
 
 from ollamamq_tpu.config import (ATTENTION, CROSS, GMU, MAMBA, MODEL_CONFIGS,
-                                 WINDOW, ModelConfig, hybrid_layer_types,
-                                 validate_slot_state)
+                                 WINDOW, ModelConfig, hybrid_layer_types)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops import selective_scan as s6
 from ollamamq_tpu.ops.attention import causal_attention
@@ -523,9 +523,9 @@ def test_the_stack_is_derived_and_what_is_not_implemented_is_refused():
     (dict(kv_dtype="int8"), "--kv-dtype int8"),
     (dict(prefix_cache=True), "--prefix-cache")])
 def test_features_that_know_only_the_kv_pool_are_refused(kw, match):
-    err = validate_slot_state(PHI, **kw)
+    err = refusal(PHI, **kw)
     assert err and match in err and NAME in err and "ROADMAP B-M9" in err
-    assert validate_slot_state(PHI, mesh_shape={"data": 2}) is None
+    assert refusal(PHI, mesh_shape={"data": 2}) is None
 
 
 def test_a_verify_span_and_the_last_hiddens_are_refused_by_name(params):
@@ -561,8 +561,8 @@ def test_the_engine_serves_it_and_counts_what_the_exit_saves(monkeypatch):
     assert piped == settled
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     rt = _rt(eng)
-    assert rt.slot_state.scan is not None
-    assert rt.kc.shape[0] == 1
+    assert rt.cache.slot_state.scan is not None
+    assert rt.cache.kc.shape[0] == 1
     held = rt.state_bytes
     assert held["s6_state_bytes"] == PHI.count(MAMBA) * 5 * N * DI * 4
     assert rt.stats()["s6_state_bytes"] == held["s6_state_bytes"]

@@ -70,11 +70,11 @@ def run_request(rt: ModelRuntime, core: MQCore, prompt, max_tokens=6,
 
 
 def pool_invariant(rt: ModelRuntime) -> None:
-    a = rt.alloc
+    a = rt.cache.alloc
     assert a.free_pages + a.used_pages + a.cached_pages == a.num_pages - 1
     assert a.used_pages >= 0
-    if rt.prefix_cache is not None:
-        rt.prefix_cache.check()
+    if rt.cache.prefix_cache is not None:
+        rt.cache.prefix_cache.check()
 
 
 # -- radix tree unit behavior ----------------------------------------------
@@ -157,9 +157,9 @@ def test_identical_streams_cache_on_vs_off():
         ids_on = run_request(rt_on, core, prompt)
         assert ids_off == ids_on, f"prompt {i}: {ids_off} != {ids_on}"
         pool_invariant(rt_on)
-    assert rt_on.prefix_cache.hits >= 3
-    assert rt_on.prefix_cache.tokens_saved >= 3 * 4 * PS
-    assert rt_off.alloc.used_pages == 0  # everything reclaimed
+    assert rt_on.cache.prefix_cache.hits >= 3
+    assert rt_on.cache.prefix_cache.tokens_saved >= 3 * 4 * PS
+    assert rt_off.cache.alloc.used_pages == 0  # everything reclaimed
 
     # Repeat-penalty streams must match too: the chunked tail seeds the
     # penalty ring with the cached prefix's last repeat_last_n tokens.
@@ -181,7 +181,7 @@ def test_cancel_mid_prefill_with_partially_cached_pages():
     base = rng.randint(3, 500, size=96).tolist()  # 12 full pages
     run_request(rt_on, core, base)  # populate the tree
     pool_invariant(rt_on)
-    cached = rt_on.prefix_cache.cached_pages
+    cached = rt_on.cache.prefix_cache.cached_pages
     assert cached == 12
 
     # A longer prompt sharing the cached prefix: admission pins 12 pages
@@ -194,18 +194,18 @@ def test_cancel_mid_prefill_with_partially_cached_pages():
     req._inc_decode = rt_on.tokenizer.make_incremental_decoder()
     rt_on.pending_prefill.append(req)
     assert rt_on.step_ragged(core)  # hit: pinned, first tail span runs
-    assert rt_on.prefix_cache.hits >= 1
+    assert rt_on.cache.prefix_cache.hits >= 1
     assert req in rt_on.chunking
     assert req._chunk_base == 96 and 96 < req._chunk_pos < len(victim)
-    assert rt_on.prefix_cache.stats()["pinned_pages"] == 12
+    assert rt_on.cache.prefix_cache.stats()["pinned_pages"] == 12
     req.cancelled.set()
     assert not rt_on.step_ragged(core)  # reaped, nothing left to dispatch:
     #                                     pins released, tail freed
     assert req not in rt_on.chunking
     assert not rt_on.reserved_slots
     pool_invariant(rt_on)
-    assert rt_on.prefix_cache.cached_pages == cached  # nothing leaked in
-    assert rt_on.prefix_cache.stats()["pinned_pages"] == 0
+    assert rt_on.cache.prefix_cache.cached_pages == cached  # nothing leaked in
+    assert rt_on.cache.prefix_cache.stats()["pinned_pages"] == 0
 
     # The same prompt run fresh still matches the cache-off stream.
     ids_on = run_request(rt_on, core, victim)
@@ -227,15 +227,15 @@ def test_full_cache_evicts_instead_of_failing_admission():
         run_request(rt, core, rng.randint(3, 500, size=48).tolist(),
                     max_tokens=2)
     pool_invariant(rt)
-    assert rt.alloc.cached_pages == 12
-    assert rt.alloc.free_pages < 8
+    assert rt.cache.alloc.cached_pages == 12
+    assert rt.cache.alloc.free_pages < 8
     assert rt.has_capacity("generate")  # evictable pages count as capacity
     # A fresh 56-token prompt needs 8 pages: admission must evict, not
     # fail or wait forever.
     ids = run_request(rt, core, rng.randint(3, 500, size=56).tolist(),
                       max_tokens=2)
     assert len(ids) == 2
-    assert rt.prefix_cache.evictions > 0
+    assert rt.cache.prefix_cache.evictions > 0
     pool_invariant(rt)
 
 

@@ -312,7 +312,7 @@ def test_page_conservation_fuzz_quantized():
         ran = rt.step_ragged(core)
         if not ran and any(s is not None for s in rt.slot_req):
             rt.step_decode(core, k_steps=1)
-        a = rt.alloc
+        a = rt.cache.alloc
         assert a.free_pages + a.used_pages + a.cached_pages \
             == a.num_pages - 1, "page conservation broken"
         if issued >= 18 and all(r.stats.finished_at for r in reqs):

@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ollamamq_tpu.config import (ATTENTION, LINEAR, MODEL_CONFIGS,
-                                 validate_slot_state)
+from ollamamq_tpu.config import (ATTENTION, LINEAR, MODEL_CONFIGS)
+from ollamamq_tpu.engine.kv_cache import refusal
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops.sampling import SamplingParams
 from test_lfm2 import ATOL
@@ -76,7 +76,7 @@ def test_the_registered_family_and_its_plan():
     assert QN.rotary_dim == 8 and QN.router_width == 16
     assert [(f, len(p), n) for f, p, n in QN.layer_plan()] == [(0, 4, 2)]
     # per-slot state: --tp / --ep and --spec are refused, as the other hybrid
-    assert "layer_types" in validate_slot_state(QN, spec=True)
+    assert "layer_types" in refusal(QN, spec=True)
 
 
 @pytest.mark.parametrize("bad,match", [
@@ -180,9 +180,9 @@ def test_the_engine_serves_it_and_counts_its_attention(monkeypatch):
     assert all(len(ids[0]) == 9 + 2 * i
                for i, ids in enumerate(got[f"u{i}"] for i in range(5)))
     rt = _rt(eng)
-    assert rt.kc.shape[0] == 2 and rt.kc.shape[-1] == QN.kv_dim
-    assert rt.slot_state.rule.shape == (6, 5, 8, 4 * 16)
-    assert rt.prefix_cache is None
+    assert rt.cache.kc.shape[0] == 2 and rt.cache.kc.shape[-1] == QN.kv_dim
+    assert rt.cache.slot_state.rule.shape == (6, 5, 8, 4 * 16)
+    assert rt.cache.prefix_cache is None
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     for s in samples:
         assert s["attn_pairs"] >= s["attn_ctx_rows"] >= 1

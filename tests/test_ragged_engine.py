@@ -174,7 +174,7 @@ def test_mid_prefill_cancel_leaves_survivors_identical():
     out = run_all(rt, prompts, cancel_mid_prefill=0)
     assert out[0] is None
     assert out[1:] == clean[1:]
-    assert rt.alloc.used_pages == 0
+    assert rt.cache.alloc.used_pages == 0
     assert not rt.reserved_slots and not rt.chunking
 
 
@@ -265,7 +265,7 @@ def test_prompt_beyond_the_context_limit_is_refused():
         tick(rt, core)
     assert fits.stream.drain()[-1].finish_reason.value == "length"
     assert len(fits.generated_ids) == 1
-    assert rt.alloc.used_pages == 0 and not rt.reserved_slots
+    assert rt.cache.alloc.used_pages == 0 and not rt.reserved_slots
 
 
 def test_ragged_dispatch_fault_retries_and_streams_survive():

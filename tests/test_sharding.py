@@ -138,7 +138,7 @@ def test_tp_over_kv_heads_replicated_groups():
         rt8 = eng8.runtimes["test-tiny-gqa"]
         assert rt8.cfg.num_kv_heads == 8  # 4 heads replicated x2
         # KV cache sharded over all 8 devices, one (duplicated) head each.
-        assert len(rt8.kc.sharding.device_set) == 8
+        assert len(rt8.cache.kc.sharding.device_set) == 8
         ids8 = run(eng8, "tp8")
         ids1 = run(eng1, "tp1")
         assert ids8 == ids1, f"{ids8} != {ids1}"

@@ -238,7 +238,7 @@ def test_spec_on_off_byte_identical_fuzz(regime):
         S = off_rt.ecfg.max_slots
         assert np.array_equal(np.asarray(off_rt.recent)[:S],
                               np.asarray(on_rt.recent)[:S])
-        assert on_rt.alloc.used_pages == 0
+        assert on_rt.cache.alloc.used_pages == 0
         if copy:
             assert on_rt.spec_accepted > 0, "copy regime accepted nothing"
 
@@ -255,7 +255,7 @@ def test_spec_on_off_identical_with_prefix_cache(prefix_cache):
     on_rt = make_rt(True, copy_weights=True, prefix_cache=prefix_cache)
     on = run_all(on_rt, prompts)
     assert off == on
-    assert on_rt.alloc.used_pages == 0
+    assert on_rt.cache.alloc.used_pages == 0
 
 
 def test_spec_verify_fault_retries_and_streams_survive():
@@ -294,9 +294,9 @@ def test_preemption_during_speculation_resumes_byte_identical():
     pressured = run_all(rt, prompts, max_tokens=32, max_ticks=8000)
     assert pressured == baseline
     assert rt.preempt_count > 0, "pool never pressured: test is vacuous"
-    assert rt.alloc.used_pages == 0
-    assert rt.alloc.free_pages + rt.alloc.cached_pages \
-        == rt.alloc.num_pages - 1
+    assert rt.cache.alloc.used_pages == 0
+    assert rt.cache.alloc.free_pages + rt.cache.alloc.cached_pages \
+        == rt.cache.alloc.num_pages - 1
 
 
 # ------------------------------------------------------- deadline bugfix
@@ -328,7 +328,7 @@ def test_expired_request_never_burns_a_verify_span():
         "a verify span was composed for an expired request"
     assert [r for r in recs if r["kind"] == "deadline_drop"
             and r.get("req_id") == req.req_id]
-    assert rt.alloc.used_pages == 0
+    assert rt.cache.alloc.used_pages == 0
 
 
 # ------------------------------------------------- journal + invariants

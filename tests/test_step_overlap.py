@@ -104,7 +104,7 @@ def drive(eng, arrivals, settle_every_step, monkeypatch, during=None):
         out[name] = (ids, "".join(i.text for i in items if i.kind == "token"),
                      items[-1].finish_reason)
     # At rest: every page back, every slot empty, nothing reserved.
-    assert rt.alloc.used_pages == 0, rt.alloc.used_pages
+    assert rt.cache.alloc.used_pages == 0, rt.cache.alloc.used_pages
     assert all(r is None for r in rt.slot_req) and not rt.reserved_slots
     assert not rt._ahead.any()
     return out, PROFILER.tail()
@@ -257,10 +257,10 @@ def test_a_prefix_cache_hit_under_overlap(cached, monkeypatch):
             SamplingParams(max_tokens=8)),
            (15, "other", _prompt(3, 12), SamplingParams(max_tokens=10))]
     rt = _rt(cached)
-    hits0 = rt.prefix_cache.stats()["hits"]
+    hits0 = rt.cache.prefix_cache.stats()["hits"]
     piped, settled, _ = both(cached, arr, monkeypatch)
     assert piped == settled
-    assert rt.prefix_cache.stats()["hits"] >= hits0 + 2  # once a loop
+    assert rt.cache.prefix_cache.stats()["hits"] >= hits0 + 2  # once a loop
 
 
 @pytest.mark.parametrize("model,over", [
@@ -348,7 +348,7 @@ def test_a_late_finish_frees_its_pages_once_and_they_serve_again(
            (1, "b", _prompt(1, 100), SamplingParams(max_tokens=12)),
            (3, "probe", probe, SamplingParams(max_tokens=7))]
     seq0 = eng.journal.snapshot()["seq"]
-    hits0 = rt.prefix_cache.stats()["hits"] if cache else 0
+    hits0 = rt.cache.prefix_cache.stats()["hits"] if cache else 0
     out, samples = drive(eng, arr, False, monkeypatch)
     assert out["a"][2] == FinishReason.STOP and out["a"][0] == ids[:k]
     assert out["probe"] == alone["probe"]
@@ -356,7 +356,7 @@ def test_a_late_finish_frees_its_pages_once_and_they_serve_again(
     recs = [r for r in eng.journal.tail(None) if r["seq"] > seq0]
     assert journal_mod.check_invariants(recs) == []
     if cache:
-        assert rt.prefix_cache.stats()["hits"] == hits0 + 1  # a's pages
+        assert rt.cache.prefix_cache.stats()["hits"] == hits0 + 1  # a's pages
     else:
         frees = [r for r in recs if r["kind"] == "page_free"]
         assert len(frees) == 3  # one per request, none twice
@@ -396,7 +396,10 @@ def test_export_request_during_overlap_sees_settled_state(dense,
         def snap():
             req = reqs["u0"]
             assert rt.inflight is None
-            blob = rt._migration_snapshot(rt.slot_req.index(req), req)
+            handle, blob = rt.export_request(req.req_id)
+            # a snapshot only: seat the request again where it was
+            rt.slot_req[handle["slot"]] = req
+            rt.reserved_slots.discard(handle["slot"])
             seen.update(kv_len=blob["kv_len"], last=blob["last_token"],
                         ids=list(req.generated_ids),
                         n_prompt=len(req.prompt_tokens))

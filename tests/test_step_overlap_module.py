@@ -219,8 +219,8 @@ def test_only_the_module_runtime_lowers_the_length_carry(kind, monkeypatch):
     fn = rt._get_ragged_jit(16, rt.spec_k if rt.spec else 0,
                             (False, False, False))
     lay = rt.dims.ragged_layout(16)
-    args = (rt.params, jnp.zeros((lay.size,), jnp.int32), rt.kc, rt.vc,
-            rt.recent, rt.last_ids, rt.slot_state)
+    args = (rt.params, jnp.zeros((lay.size,), jnp.int32), rt.cache.kc, rt.cache.vc,
+            rt.recent, rt.last_ids, rt.cache.slot_state)
     carries = (rt.draft_ids, rt.len_ids) if rt.mtp else ()
     assert rt.mtp or rt.draft_ids is rt.len_ids is None
 
@@ -233,7 +233,7 @@ def test_only_the_module_runtime_lowers_the_length_carry(kind, monkeypatch):
     assert len(jax.tree.leaves(lowered.args_info)) == n_in
     outs = jax.tree.leaves(lowered.out_info)
     # ids, n_emit, two pools, the ring, last_ids (+ state) (+ two carries)
-    assert len(outs) == 6 + len(jax.tree.leaves(rt.slot_state)) \
+    assert len(outs) == 6 + len(jax.tree.leaves(rt.cache.slot_state)) \
         + len(carries)
     donated = [a.donated for a in jax.tree.leaves(lowered.args_info)]
     n_params = len(jax.tree.leaves(rt.params))

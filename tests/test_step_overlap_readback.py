@@ -272,7 +272,7 @@ def _arrive_during_scans(eng, monkeypatch, late):
             break
     eng._settle_all()
     assert all(r.stats.finished_at for r in reqs.values())
-    assert rt.alloc.used_pages == 0
+    assert rt.cache.alloc.used_pages == 0
     return reqs, PROFILER.tail()
 
 

@@ -575,7 +575,7 @@ def test_real_engine_samples_say_what_the_pipeline_did():
         sum(s["overlapped"] for s in gen)
     assert total(tm.STEP_WASTED_ROWS_TOTAL) - w0 == \
         sum(s["wasted_rows"] for s in gen)
-    assert rt.alloc.used_pages == 0
+    assert rt.cache.alloc.used_pages == 0
 
 
 # -------------------------------------------------------------- federation

@@ -63,7 +63,7 @@ def drive(eng, arrivals, hook=None):
         tick += 1
         assert tick < 3000
     eng._settle_all()
-    assert rt.alloc.used_pages == 0 and not rt._ahead.any()
+    assert rt.cache.alloc.used_pages == 0 and not rt._ahead.any()
     return ({n: (r, r.stream.drain()) for n, r in reqs.items()},
             PROFILER.tail())
 

@@ -153,8 +153,8 @@ def taken_formats(rt, monkeypatch, T=16):
         fn = rt._get_ragged_jit(T, 1 if rt.mtp else 0, (False, False, False))
     buf = jax.ShapeDtypeStruct((rt.dims.ragged_layout(T).size,), jnp.int32)
     carries = (rt.draft_ids, rt.len_ids) if rt.mtp else ()
-    compiled = fn.lower(rt.params, buf, rt.kc, rt.vc, rt.recent, rt.last_ids,
-                        rt.slot_state, *carries).compile()
+    compiled = fn.lower(rt.params, buf, rt.cache.kc, rt.cache.vc, rt.recent, rt.last_ids,
+                        rt.cache.slot_state, *carries).compile()
     return compiled.input_formats[0][0]["layers"]
 
 
@@ -165,7 +165,7 @@ def carried_where_the_weights_are(rt):
     launched would compile twice (`tests/test_stepprof.py`'s gapless engine
     loop saw that as a second 400 ms dispatch)."""
     carried = jax.tree_util.tree_leaves(
-        (rt.kc, rt.vc, rt.recent, rt.last_ids, rt.slot_state, rt.draft_ids,
+        (rt.cache.kc, rt.cache.vc, rt.recent, rt.last_ids, rt.cache.slot_state, rt.draft_ids,
          rt.len_ids))
     return all(x.committed and x.devices() == rt.params["embed"].devices()
                for x in carried)

@@ -239,12 +239,12 @@ def test_the_window_layers_bytes_are_fixed_and_the_pool_holds_full_layers():
     rows = KX.ring_rows(32, 8)
     assert rows == 8 + 32 + 8
     for rt in (small, large):
-        ring = rt.slot_state.ring
+        ring = rt.cache.slot_state.ring
         assert ring.rows == rows
         assert ring.k.shape == (KX.count(WINDOW), 5 * rows, KX.kv_dim)
         assert rt.state_bytes["swa_ring_bytes"] \
             == 4 * 5 * rows * row == ring.nbytes
-        assert rt.kc.shape[0] == rt.vc.shape[0] == KX.count(ATTENTION) == 1
+        assert rt.cache.kc.shape[0] == rt.cache.vc.shape[0] == KX.count(ATTENTION) == 1
         assert rt.stats()["swa_ring_bytes"] == ring.nbytes
     assert large.kv_bytes == 4 * small.kv_bytes  # the pool grows; not these
     assert small.kv_bytes == 64 * 8 * row        # ...and is one layer's
@@ -252,7 +252,7 @@ def test_the_window_layers_bytes_are_fixed_and_the_pool_holds_full_layers():
     assert gauge[(NAME,)] == small.state_bytes["swa_ring_bytes"]
     plain = _runtime_of("test-tiny")
     assert plain.state_bytes["swa_ring_bytes"] == 0
-    assert plain.slot_state is None
+    assert plain.cache.slot_state is None
 
 
 def _runtime_of(name):
@@ -265,7 +265,7 @@ def _runtime_of(name):
 
 def test_prefix_cache_and_migration_are_off_with_a_window_layer():
     rt = _runtime(prefix_cache=True)
-    assert rt.prefix_cache is None  # a page of the full layers rebuilds no ring
+    assert rt.cache.prefix_cache is None  # a page of the full layers rebuilds no ring
     assert rt.export_request(1) is None
 
 
@@ -339,7 +339,7 @@ def test_step_samples_carry_the_window_counters(monkeypatch):
     got, samples = drive(eng, arrivals, False, monkeypatch)
     assert all(len(ids) == 10 for ids, _, _ in got.values())
     rt = _rt(eng)
-    assert rt.kc.shape[0] == 1 and rt.slot_state.ring.k.shape[0] == 4
+    assert rt.cache.kc.shape[0] == 1 and rt.cache.slot_state.ring.k.shape[0] == 4
     assert {s["mode"] for s in samples} == {"ragged", "decode"}
     for s in samples:
         assert s["swa_pairs"] >= s["swa_ctx_rows"] >= 1

@@ -23,8 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ollamamq_tpu.config import (ATTENTION, MODEL_CONFIGS, EngineConfig,
-                                 validate_streams)
+from ollamamq_tpu.config import (ATTENTION, MODEL_CONFIGS, EngineConfig)
+from ollamamq_tpu.engine.kv_cache import (STATE_REFUSES, refusal,
+                                          state_held)
 from ollamamq_tpu.engine import step_work
 from ollamamq_tpu.models import llama
 from ollamamq_tpu.ops import hyper_connection as hc
@@ -156,10 +157,15 @@ def test_streams_the_program_cannot_run_are_refused_by_the_keys_name(
 ], ids=["spec", "tp", "ep"])
 def test_what_four_streams_are_not_served_with_is_told_before_the_device(
         kw, match):
-    why = validate_streams(XG, **kw)
-    assert match in why and "ROADMAP B-M12" in why and "4 streams" in why
-    assert validate_streams(XG) is None
-    assert validate_streams(MODEL_CONFIGS["test-tiny"], **kw) is None
+    why = refusal(XG, **kw)
+    assert match in why
+    if "spec" in kw:  # the one flag of the three that the streams alone refuse
+        assert "ROADMAP B-M12" in why and "4 streams" in why
+    else:  # the pools' line is asked first; the streams' stands behind it
+        assert "latent" in why and state_held(XG) == ["latent", "streams"]
+        assert "ROADMAP B-M12" in STATE_REFUSES["streams"][1]["mesh"]
+    assert refusal(XG) is None
+    assert refusal(MODEL_CONFIGS["test-tiny"], **kw) is None
 
 
 # ------------------------------------------- the forwards, in float32 logits
